@@ -166,17 +166,16 @@ def test_shape_bytes_async_start_takes_result_not_sum():
 
 
 def test_bench_summary_line_is_compact_and_parseable():
-    """bench.py must end with a small self-sufficient JSON line (the
-    driver's bounded stdout tail truncated the r3 single-line format)."""
+    """bench.py must end with a small self-sufficient JSON line (a
+    bounded stdout tail can truncate the full record) that names the
+    device the numbers were measured on."""
     import json as _json
 
     b = _load_bench()
 
     suite = {
-        "backend": "cpu_fallback (probe skipped)",
         "decode_64k": {"pct_hbm_roofline": 88.1, "us_per_step": 711.0,
-                       "kv_tokens_per_sec": 9.0e7,
-                       "measured_earlier_this_round": True},
+                       "kv_tokens_per_sec": 9.0e7},
         "train_fwd_bwd_16k": {"fwd": {"mfu_pct": 63.1},
                               "fwd_bwd": {"mfu_pct": 75.6}},
         "tree_vs_ring_cpu8": {"tree_speedup_vs_ring": 1.013,
@@ -189,12 +188,15 @@ def test_bench_summary_line_is_compact_and_parseable():
         "train_fwd_bwd": {"error": "RuntimeError: boom"},
     }
     record = {"metric": "m", "value": 1.0, "unit": "tokens/sec",
-              "vs_baseline": 2.0, "suite": suite}
-    line = _json.dumps(b._summary_line(record, suite))
+              "vs_baseline": 2.0, "platform": "tpu",
+              "device_kind": "TPU v5 lite", "device_count": 1,
+              "suite": suite}
+    line = _json.dumps(b._summary_line(record))
     assert len(line) < 2000  # survives any bounded tail
     parsed = _json.loads(line)
-    assert parsed["backend"].startswith("cpu_fallback")
-    assert parsed["records"]["decode_64k"]["replayed"] is True
+    assert (parsed["platform"], parsed["device_kind"],
+            parsed["device_count"]) == ("tpu", "TPU v5 lite", 1)
+    assert parsed["records"]["decode_64k"]["pct_roofline"] == 88.1
     assert parsed["records"]["train_fwd_bwd_16k"]["fwd_mfu_pct"] == 63.1
     assert parsed["records"]["tree_vs_ring_decode_cpu8"]["ctx_2048_vs_ring"] == 1.4
     assert parsed["records"]["decode_gqa_1m"] == "skipped"
@@ -255,27 +257,31 @@ def test_slope_record_fields_guards():
         return SlopeStats(per_step=per, slopes=slopes, spread_pct=spread,
                           small=ts, large=ts)
 
+    from tree_attention_tpu.bench.ici import peaks
+
+    hbm = peaks("TPU v5 lite").hbm_bytes_per_s
     kv = 512 * 1024 * 1024  # 512 MB stream
-    clean = kv / (0.9 * b.HBM_ROOFLINE)
-    per, f = b._slope_record_fields(slope(clean, 1.2, (clean,)), kv)
+    clean = kv / (0.9 * hbm)
+    per, f = b._slope_record_fields(slope(clean, 1.2, (clean,)), kv, hbm)
     assert per == clean and "timing_suspect" not in f
     assert "timing_note" not in f and f["slope_spread_pct"] == 1.2
 
-    fast = kv / (1.5 * b.HBM_ROOFLINE)  # 1.5x the spec: impossible
-    _, f = b._slope_record_fields(slope(fast, 0.5, (fast,)), kv)
+    fast = kv / (1.5 * hbm)  # 1.5x the spec: impossible
+    _, f = b._slope_record_fields(slope(fast, 0.5, (fast,)), kv, hbm)
     assert "timing_suspect" in f
 
-    _, f = b._slope_record_fields(slope(clean, 38.4, (clean, clean * 1.4)), kv)
+    _, f = b._slope_record_fields(
+        slope(clean, 38.4, (clean, clean * 1.4)), kv, hbm
+    )
     assert "timing_note" in f and "timing_suspect" not in f
 
     # Deflation fault: a min cycle far below the median cycle is an
-    # early-resolved fetch even when its implied bandwidth stays under the
-    # spec ceiling (observed 2026-08-01: sub-peak but impossible sweep
-    # cells in a bad transport window).
-    slow = kv / (0.5 * b.HBM_ROOFLINE)       # contended window: 50% roofline
+    # early-resolved fence even when its implied bandwidth stays under the
+    # spec ceiling.
+    slow = kv / (0.5 * hbm)                  # contended window: 50% roofline
     deflated = 0.55 * slow                   # "faster" cycle, still sub-spec
     _, f = b._slope_record_fields(
-        slope(deflated, 80.0, (deflated, slow, slow * 1.02)), kv
+        slope(deflated, 80.0, (deflated, slow, slow * 1.02)), kv, hbm
     )
     assert "timing_suspect" in f and "deflation" in f["timing_suspect"]
     assert f["pct_hbm_roofline"] < 105  # the ceiling guard alone misses it
@@ -283,12 +289,14 @@ def test_slope_record_fields_guards():
     # The r5 q8q capture's shape ([359, 359, 497]): min == median, genuine
     # contention — stays a note, not a suspect flag.
     _, f = b._slope_record_fields(
-        slope(clean, 38.4, (clean, clean, clean * 1.38)), kv
+        slope(clean, 38.4, (clean, clean, clean * 1.38)), kv, hbm
     )
     assert "timing_note" in f and "timing_suspect" not in f
 
     # With only two cycles, median == mean and the deflation test cannot
     # tell a deflated min from one contended sibling — it must stay quiet
     # (callers that want the defence run repeats >= 3).
-    _, f = b._slope_record_fields(slope(slow, 150.0, (slow, slow * 2.5)), kv)
+    _, f = b._slope_record_fields(
+        slope(slow, 150.0, (slow, slow * 2.5)), kv, hbm
+    )
     assert "timing_suspect" not in f

@@ -14,9 +14,7 @@ The four parity contracts of the ragged decode stack:
     quantize-at-final-chunk) — produce bit-identical tokens.
 
 Everything here is CPU-safe and fast-tier: plain jnp paths plus the Pallas
-kernels in interpret mode, shard_map only through ``parallel/compat``
-(``cpu_mesh``) — it must stay collected on this container's legacy JAX
-(see tests/conftest.py).
+kernels in interpret mode, meshes from ``cpu_mesh``.
 """
 
 import json
@@ -305,8 +303,8 @@ def test_ragged_position_composes_with_data_axis(params):
 
 
 def test_serving_mesh_matches_single_device(params):
-    """The same trace over a seq-sharded slot cache (tree merge per tick,
-    shard_map via parallel/compat) reproduces the single-device tokens."""
+    """The same trace over a seq-sharded slot cache (tree merge per tick)
+    reproduces the single-device tokens."""
     mesh = cpu_mesh(2)
     B, Tp, n_new = 2, 12, 4
     prompt = jax.random.randint(jax.random.PRNGKey(5), (B, Tp), 0,

@@ -25,8 +25,7 @@ Bit-exactness in (c) holds at matched tiling: the configs pin
 both layouts fold identical KV tiles in identical order (the same
 alignment trick the PR-5 hit-vs-cold suite uses for chunk == block).
 
-Everything is CPU-safe and fast-tier (interpret-mode kernels, no
-shard_map outside ``parallel/compat``).
+Everything is CPU-safe and fast-tier (interpret-mode kernels).
 """
 
 import json

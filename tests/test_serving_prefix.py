@@ -13,8 +13,7 @@ The reuse contract has two halves:
     eviction never free a block a live request holds and never
     over-commit the pool, under random admit/retire interleavings.
 
-Everything here is CPU-safe and fast-tier (collected on this container's
-legacy JAX — no shard_map outside ``parallel/compat``).
+Everything here is CPU-safe and fast-tier.
 """
 
 import json

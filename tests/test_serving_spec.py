@@ -22,8 +22,8 @@ Plus the layers underneath:
 - the paged pool invariant: rolled-back blocks unmap without leaking
   capacity (used == 0, reserved == 0 after every serve).
 
-Everything is CPU-safe fast-tier (Pallas in interpret mode, shard_map
-via ``parallel/compat``'s cpu_mesh).
+Everything is CPU-safe fast-tier (Pallas in interpret mode, meshes from
+``cpu_mesh``).
 """
 
 import numpy as np

@@ -1,7 +1,6 @@
 """Tile-size table lookups (``ops/tuning.py``).
 
-The tables are measured artifacts (tools/measure_campaign.py /
-tools/experiments_r3.py on v5e); these tests pin the lookup *semantics* —
+The tables are measured artifacts (on-chip sweeps on v5e); these tests pin the lookup *semantics* —
 bucket edges, the q8/exact split, and None-default resolution through the
 kernels — not the measured values themselves, which later campaigns may
 move.

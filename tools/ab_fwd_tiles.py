@@ -1,12 +1,10 @@
 """Focused fwd-kernel tile A/B at the 16k yardstick shape.
 
-The full grid sweep (``tools/tune_sweep.py fwd``) needs ~20 compiles and
-was untrustworthy all afternoon on 2026-08-01 (transport deflation fault,
-``measurements/r5/README.md``); this tool instead times a HANDFUL of
-candidate tiles with the exact protocol that held 0.2–0.9%% spreads in the
-same session (``tools/race_stock_flash.py``: chains 2/16, iters=5,
-min-stat, repeats=3) plus the shared deflation/floor screens, so a tile
-default change can be judged on data that carries its own error bar.
+The full grid sweep (``tools/tune_sweep.py fwd``) needs ~20 compiles;
+this tool instead times a HANDFUL of candidate tiles with the protocol of
+``tools/race_stock_flash.py`` (chains 2/16, iters=5, min-stat, repeats=3)
+plus the shared deflation/floor screens, so a tile default change can be
+judged on data that carries its own error bar.
 
 Motivation: prefetch-zero culling (commit c00c835) removes a per-Q-row
 cold fetch, which weighs ~2x heavier at bq=512 (32 rows at 16k) than at
@@ -25,7 +23,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from tree_attention_tpu.bench.ici import BF16_PEAK  # noqa: E402
+from tree_attention_tpu.bench.ici import peaks  # noqa: E402
 from tree_attention_tpu.utils.profiling import (  # noqa: E402
     chain_slope,
     deflation_suspect,
@@ -78,15 +76,18 @@ def bench_tile(T, bq, bk, mode, n_small, n_large):
     flops = 4.0 * (B * H * (T * (T + 1)) // 2) * D  # shared causal basis
     if mode != "fwd":
         flops *= 3.5
+    bf16_peak = peaks().bf16_flops_per_s
     rec = {
         "T": T, "mode": mode, "bq": bq, "bk": bk,
         "us_per_step": round(s.per_step * 1e6, 1),
-        "mfu_pct_shared_basis": round(flops / s.per_step / BF16_PEAK * 100, 1),
+        "mfu_pct_shared_basis": round(
+            flops / s.per_step / bf16_peak * 100, 1
+        ),
         "slope_cycles_us": [round(c * 1e6, 2) for c in s.slopes],
         "slope_spread_pct": round(s.spread_pct, 1),
     }
     suspect = deflation_suspect(s)
-    if suspect is None and s.per_step < flops / (BF16_PEAK * 1.05):
+    if suspect is None and s.per_step < flops / (bf16_peak * 1.05):
         suspect = "implied MFU above the bf16 peak: fence failure"
     if suspect:
         rec["suspect"] = suspect

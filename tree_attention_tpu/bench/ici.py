@@ -28,9 +28,9 @@ Terms:
   :func:`payloads_from_comm_record` extracts the same quantities from a
   live comm-accounting record, so the closed form is checkable against
   the compiled HLO every bench run.
-- **ICI constants** — published v5e figures (assumptions, stated so they
-  can be attacked): per-hop latency ALPHA ≈ 1 µs, per-link one-way
-  bandwidth BETA ≈ 45 GB/s (2D torus). Parametric throughout.
+- **ICI constants** — the modelled chip's row of :data:`PEAKS`
+  (assumptions, stated so they can be attacked): per-hop latency ≈ 1 µs,
+  per-link one-way bandwidth ≈ 45 GB/s (2D torus). Parametric throughout.
 
 Cost model (latency-dominated regime — the payloads are KB-scale):
 
@@ -46,20 +46,67 @@ north-star section quotes, re-priced from the records on disk.
 
 from __future__ import annotations
 
+import dataclasses
 import glob
 import json
 import math
 import os
 from typing import Any, Dict, List, Optional, Tuple
 
-# Published hardware constants (see module docstring) — the package's ONE
-# definition of each (bench.py, the harness fence guards, and the race
-# tool import from here). ALPHA/BETA are *assumptions* — the model is
-# parametric so a pod owner can re-price.
-HBM_BW = 819e9          # v5e spec HBM bandwidth, B/s
-BF16_PEAK = 197e12      # v5e spec bf16 matmul peak, FLOP/s
-ALPHA = 1e-6            # ICI per-hop latency, s (published figure ~1 us)
-BETA = 4.5e10           # ICI per-link one-way bandwidth, B/s (v5e)
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published peaks of one chip, as ``jax.Device.device_kind`` names it."""
+
+    hbm_bytes_per_s: float
+    bf16_flops_per_s: float
+    ici_hop_latency_s: float      # an assumption of the merge-cost model
+    ici_link_bytes_per_s: float   # per link, one way
+    source: str
+
+
+# The package's ONE table of hardware peaks, keyed by ``device_kind``
+# (bench.py, the harness fence guard and the tile tools all read it). A
+# roofline share is only ever computed against the row of the device the
+# number was measured on: a kind with no row is an error, never a default.
+PEAKS: Dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(
+        hbm_bytes_per_s=819e9,
+        bf16_flops_per_s=197e12,
+        ici_hop_latency_s=1e-6,
+        ici_link_bytes_per_s=4.5e10,
+        source=(
+            "Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+            "16 GB HBM at 819 GB/s, 1,600 Gbit/s interconnect per chip "
+            "(four links, 45 GB/s a link one way); the ~1 us hop latency "
+            "is an assumption of the merge-cost model"
+        ),
+    ),
+}
+
+
+def peaks(device_kind: Optional[str] = None) -> ChipPeaks:
+    """The :data:`PEAKS` row for ``device_kind`` (default: the first
+    attached device's). Raises for a kind the table does not list."""
+    if device_kind is None:
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}: add a "
+            f"row with its source to bench/ici.PEAKS (known: {sorted(PEAKS)})"
+        ) from None
+
+
+# The merge-cost model below prices a pod of one NAMED chip; its defaults
+# are that chip's row, and every entry point takes overrides.
+MODEL_CHIP = "TPU v5 lite"
+HBM_BW = PEAKS[MODEL_CHIP].hbm_bytes_per_s
+ALPHA = PEAKS[MODEL_CHIP].ici_hop_latency_s
+BETA = PEAKS[MODEL_CHIP].ici_link_bytes_per_s
 
 # Fallback for the measured term when no bench records are available
 # (e.g. a fresh checkout before any bench run): the r3/r4 chip campaigns
@@ -121,14 +168,13 @@ def decode_record_pcts(
     """The one exclusion rule for "chip decode records worth pricing a TPU
     model from", shared by the in-run path (bench.py, full records under
     ``pct_hbm_roofline``) and the on-disk capture path (summary records
-    under ``pct_roofline``): decode records only, no ``_cpu`` fallback
-    workloads (their pct is vs the TPU spec but measured on the host CPU),
-    and nothing the capture flagged ``timing_suspect``.
+    under ``pct_roofline``): decode records only, and nothing the capture
+    flagged ``timing_suspect``.
     """
     return [
         rec[key]
         for name, rec in records.items()
-        if name.startswith("decode") and not name.endswith("_cpu")
+        if name.startswith("decode")
         and isinstance(rec, dict)
         and isinstance(rec.get(key), (int, float))
         and "timing_suspect" not in rec
@@ -182,10 +228,6 @@ def load_bench_roofline_fracs(
         except (OSError, ValueError):
             continue
         parsed = data.get("parsed") or {}
-        if "CPUFALLBACK" in str(parsed.get("metric", "")):
-            # A capture whose headline fell back to the CPU backend has no
-            # chip decode records worth pricing a TPU model from.
-            continue
         pcts = decode_record_pcts(parsed.get("records") or {})
         if pcts:
             return pcts, path

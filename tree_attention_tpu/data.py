@@ -29,13 +29,11 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from tree_attention_tpu.parallel.mesh import AXIS_DATA, AXIS_SEQ
 from tree_attention_tpu.utils.config import RunConfig
-
-from tree_attention_tpu.parallel.compat import shard_map
 
 # Single source of truth for the canonical (reference) workload defaults.
 _REF = RunConfig()

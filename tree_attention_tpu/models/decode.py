@@ -44,7 +44,7 @@ from typing import Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from tree_attention_tpu import obs
@@ -81,13 +81,15 @@ _CACHE_QUANTIZE = obs.counter(
     "whole-cache int8 quantizations (quantize-after-prefill)",
 )
 from tree_attention_tpu.ops.decode import flash_decode
-from tree_attention_tpu.parallel.compat import shard_map
 from tree_attention_tpu.parallel.mesh import (
     AXIS_DATA,
     AXIS_MODEL,
     AXIS_SEQ,
     prune_axes,
 )
+from tree_attention_tpu.utils.logging import get_logger
+
+log = get_logger("models.decode")
 
 
 @jax.tree_util.register_dataclass
@@ -896,11 +898,8 @@ def forward_step(
         )
     if paged:
         from tree_attention_tpu.ops import _on_tpu, _pallas_available
-        from tree_attention_tpu.ops.decode import _AUTO_PALLAS
 
-        on_kernels = (
-            _AUTO_PALLAS and _on_tpu(params["embed"]) and _pallas_available()
-        )
+        on_kernels = _on_tpu(params["embed"]) and _pallas_available()
         # Under a >1-way seq mesh the contiguous view would re-route
         # decode_attention onto the tree-merge branch (the view is
         # replicated, not seq-sharded) — keep the block-table path there.
@@ -935,6 +934,14 @@ def forward_step(
             hoist_view = not on_kernels
         else:
             hoist_view = seq_shards == 1 and not on_kernels
+        # Trace time under jit: one line per step-program build.
+        log.debug(
+            "forward_step: paged%s step (Tq=%d) on %s",
+            " int8" if quant else "", Tq,
+            "the hoisted reference view" if hoist_view
+            else "the block-table dispatch"
+            + (" (Pallas kernels)" if on_kernels else " (reference gather)"),
+        )
     if hoist_view:
         idx = jnp.clip(cache.table, 0, cache.blocks - 1)  # (B, NB)
 

@@ -13,26 +13,28 @@ done right). Implementations:
 - ``"auto"``      — decode shapes (Tq < 128) resolve to the flash-decode
   kernel on TPU (any context length; no score transient) and to ``naive``
   elsewhere when the score transient is small; large-Tq shapes resolve to
-  ``pallas`` on TPU (``TREE_ATTN_AUTO_PALLAS=0`` opts out of both kernels;
-  the decode paths read the variable once at import, so set it before
-  importing the package) and ``blockwise`` elsewhere. Pass an explicit impl
-  when a specific kernel or backward path must be used.
+  ``pallas`` on TPU and ``blockwise`` elsewhere. Pass an explicit impl
+  when a specific kernel or backward path must be used. Every resolution
+  is logged once per program build at debug level (``tree_attention_tpu.ops``:
+  which kernel or reference path serves the call).
 """
 
 from __future__ import annotations
 
-import os
 from typing import Optional, Tuple
 
 import jax
 
 from tree_attention_tpu.ops.decode import flash_decode  # noqa: F401
+from tree_attention_tpu.utils.logging import get_logger
 from tree_attention_tpu.ops.reference import (  # noqa: F401
     attention_blockwise,
     attention_naive,
     finalize,
     merge_partials,
 )
+
+log = get_logger("ops")
 
 _IMPLS = ("auto", "naive", "blockwise", "pallas", "pallas_decode")
 
@@ -157,15 +159,10 @@ def flash_attention(
         # 3. Large-Tq shapes on TPU -> "pallas" (Q-tiled): verified correct
         #    on-chip and ~4x the blockwise fwd throughput / ~2.3x fwd+bwd
         #    (bf16 operands on the MXU fast path, f32 accumulation).
-        #    TREE_ATTN_AUTO_PALLAS=0 opts out of both TPU kernels.
         # 4. Everything else -> "blockwise" (pure XLA, any backend).
         Tq, Tk = q.shape[2], k.shape[2]
         transient_bytes = 3 * q.shape[0] * q.shape[1] * Tq * Tk * 4
-        pallas_ok = (
-            os.environ.get("TREE_ATTN_AUTO_PALLAS", "1") != "0"
-            and _on_tpu(q)
-            and _pallas_available()
-        )
+        pallas_ok = _on_tpu(q) and _pallas_available()
         # custom_vjp=False is the documented forward-mode-AD escape hatch;
         # raw Pallas forwards have no autodiff rules, so that request keeps
         # the jnp impls whenever one is viable at the shape.
@@ -178,6 +175,12 @@ def flash_attention(
             impl = "naive"
         else:
             impl = "blockwise"
+        # Trace time under jit: one line per program build.
+        log.debug(
+            "flash_attention: impl=auto -> %s (Tq=%d, Tk=%d, %s)", impl, Tq,
+            Tk, "Pallas kernel" if impl.startswith("pallas")
+            else "reference path",
+        )
     # None picks tuned defaults. The bwd kernels get their own (VMEM-capped)
     # default Q tile only when the caller left block_q to the table; an
     # explicit block_q flows to both passes unchanged so tuning sweeps

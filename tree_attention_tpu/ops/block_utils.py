@@ -20,18 +20,6 @@ NEG_INF = float("-inf")
 LANES = 128
 
 
-def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` across its rename (older JAX spells it
-    ``TPUCompilerParams``; the fields are the same). One home, so every
-    kernel's ``compiler_params=`` stays version-portable."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kwargs)
-
-
 def matmul_precision(*dtypes):
     """Contraction precision for the ops-layer matmuls, by operand dtype.
 

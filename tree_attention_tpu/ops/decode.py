@@ -23,7 +23,6 @@ future.
 from __future__ import annotations
 
 import numbers
-import os
 from typing import Optional, Tuple
 
 import jax
@@ -32,15 +31,9 @@ import jax.numpy as jnp
 from tree_attention_tpu import obs
 from tree_attention_tpu.ops.block_utils import pad_to_block
 from tree_attention_tpu.ops.reference import attention_blockwise, merge_partials
+from tree_attention_tpu.utils.logging import get_logger
 
-# Read once at import: this gate sits on the per-layer hot dispatch path of
-# every decode step (a scan body traces it L times per compile, and eager
-# callers hit it per call); the env var is a process-level opt-out, not a
-# runtime toggle — flipping it after import is not supported here (the
-# already-jitted callers it would need to invalidate cannot see an env flip
-# anyway; ops/__init__.flash_attention keeps per-call reads for its
-# eager-auto path).
-_AUTO_PALLAS = os.environ.get("TREE_ATTN_AUTO_PALLAS", "1") != "0"
+log = get_logger("ops")
 
 # Dispatch accounting (trace-time under an enclosing jit — see
 # obs.metrics): which decode path served the call, and how many KV/query
@@ -59,7 +52,19 @@ _DECODE_KV_TOKENS = obs.counter(
 )
 
 
+# Paths of ``_account_dispatch`` that do NOT run a Pallas kernel.
+_REFERENCE_PATHS = ("chunked_vmap", "paged_local_partial_reference")
+
+
 def _account_dispatch(path: str, kv_tokens: int) -> None:
+    """Make the kernel-or-reference choice visible: one debug line per
+    dispatch resolution (trace time under jit, so once per program build)
+    and, when the registry is armed, the dispatch counters."""
+    log.debug(
+        "decode dispatch: %s (%s, kv_tokens=%d)", path,
+        "reference path" if path in _REFERENCE_PATHS else "Pallas kernel",
+        kv_tokens,
+    )
     if not obs.REGISTRY.enabled:
         return
     _DECODE_DISPATCH.labels(path=path).inc()
@@ -203,7 +208,7 @@ def flash_decode(
     # state) and streams at the HBM roofline at any context length.
     from tree_attention_tpu.ops import _on_tpu, _pallas_available
 
-    if _AUTO_PALLAS and _on_tpu(q) and _pallas_available():
+    if _on_tpu(q) and _pallas_available():
         # Kernel choice and tile defaults live in ops.tuning (shared with
         # flash_attention's auto gate). Prefill-sized Tq takes the Q-tiled
         # kernel: the decode kernel's group packing would spill into
@@ -395,7 +400,7 @@ def paged_local_partial(
             "paged_local_partial needs a per-slot (B,) q_position"
         )
 
-    if not quant and _AUTO_PALLAS and _on_tpu(q) and _pallas_available():
+    if not quant and _on_tpu(q) and _pallas_available():
         from tree_attention_tpu.ops.pallas_decode import (
             attention_pallas_decode,
         )
@@ -451,7 +456,7 @@ def paged_local_partial(
         "bhgqk,bhkd->bhgqd", p, vb.astype(jnp.float32),
         precision=matmul_precision(jnp.float32),
     )
-    _account_dispatch("paged_local_partial", NB * blk)
+    _account_dispatch("paged_local_partial_reference", NB * blk)
     return finalize(
         acc.reshape(B, Hq, Tq, D),
         m.reshape(B, Hq, Tq),

@@ -40,7 +40,6 @@ from tree_attention_tpu.ops.block_utils import (
     matmul_precision,
     static_offsets,
     tile_live,
-    tpu_compiler_params,
 )
 
 
@@ -306,7 +305,7 @@ def _attention_pallas_fwd(
         # sequential (scratch carries the online-softmax state across it).
         # Declaring that lets Mosaic split the parallel dims across cores on
         # megacore parts (v5p/v4); no-op on single-core chips (v5e).
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -46,7 +46,6 @@ from tree_attention_tpu.ops.block_utils import (
 from tree_attention_tpu.ops.block_utils import (
     LANES as _LANES,
     matmul_precision,
-    tpu_compiler_params,
 )
 
 
@@ -298,7 +297,7 @@ def _attention_bwd_pallas(
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         # dq accumulates across the (sequential) KV dim; the rest are
         # independent — see the fwd kernel's note on megacore splitting.
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -340,7 +339,7 @@ def _attention_bwd_pallas(
             pltpu.VMEM((bk, D), jnp.float32),
         ],
         # dk/dv accumulate across the (sequential) grouped-Q dim.
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -48,7 +48,6 @@ from tree_attention_tpu.ops.block_utils import (
     matmul_precision,
     offsets_smem as _offsets_smem,
     pad_to_block as _pad_dim,
-    tpu_compiler_params,
 )
 
 # The wrappers below are jitted, so their Python bodies run once per
@@ -646,7 +645,7 @@ def _paged_decode_call(
         ],
         # Only the split-KV (table) dim is sequential, as in the
         # contiguous kernels.
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -1066,7 +1065,7 @@ def attention_pallas_decode_q8q(
             pltpu.VMEM((bq, _LANES), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -1288,7 +1287,7 @@ def attention_pallas_decode(
         ],
         # Only the split-KV dim is sequential (carried online-softmax state);
         # batch-head and Q-tile dims can split across megacore parts.
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -10,8 +10,8 @@ this repo is benched on. Lookup is by bucket:
   only trade-off is fewer grid steps (bigger bk) vs VMEM and ragged-tail
   waste.
 
-- training (Tq >= 128): ``_TRAIN_TILES`` keyed by sequence length, from the
-  round-3 ``tools/measure_campaign.py`` sweep (fwd-first, fwd+bwd tiebreak).
+- training (Tq >= 128): ``_TRAIN_TILES`` keyed by sequence length, from an
+  on-chip sweep (fwd-first, fwd+bwd tiebreak).
 
 Callers pass ``block_size=None`` / ``block_q=None`` end to end to land here;
 any explicit value wins unchanged. ``block_q`` is threaded through the
@@ -22,8 +22,8 @@ from __future__ import annotations
 
 from typing import Optional
 
-# context-length upper bound -> block_k. Measured on v5e (tools/tune_sweep.py
-# round 2; tools/experiments_r3.py 2026-07-31): bigger contexts amortise the
+# context-length upper bound -> block_k. Measured on v5e
+# (tools/tune_sweep.py, 2026-07-31): bigger contexts amortise the
 # ~360 ns/tile fixed cost over more streaming — 64k MHA measures 92.5% of
 # the HBM roofline at bk=4096 vs 89.9% at 2048, and 1M GQA 91.6% at 4096
 # with high run variance at 2048. VMEM caps the top end.

@@ -36,10 +36,8 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from tree_attention_tpu.parallel.compat import shard_map
 
 from tree_attention_tpu import obs
 from tree_attention_tpu.ops import flash_attention, resolve_impl_for_mesh
