@@ -339,8 +339,27 @@ def _train_record(T=4096, n_small=16, n_large=64):
     return rec
 
 
-# What a record from a CPU-pinned child process says about its device.
-_CPU_CHILD_DEVICE = {"platform": "cpu", "device_kind": "cpu"}
+def _cpu_child_env(n_devices):
+    """Environment of a child process pinned to the CPU with ``n_devices``
+    virtual devices (this process holds the chip). The child is a single
+    process with no rank contract: inherited telemetry sinks would resolve
+    to the PARENT's paths and truncate the trace file it still has open."""
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env.pop("TA_METRICS_OUT", None)
+    env.pop("TA_TRACE_EVENTS", None)
+    flags = env.get("XLA_FLAGS", "")
+    if "xla_force_host_platform_device_count" not in flags:
+        env["XLA_FLAGS"] = (
+            f"{flags} --xla_force_host_platform_device_count={n_devices}"
+        ).strip()
+    return env
+
+
+def _cpu_child_device(n_devices):
+    """What a record from such a child says about its device."""
+    return {"platform": "cpu", "device_kind": "cpu",
+            "device_count": n_devices}
 
 
 def _comparator_subprocess(args, timeout=900):
@@ -350,24 +369,11 @@ def _comparator_subprocess(args, timeout=900):
     Returns the CLI's JSON record, stamped ``"platform": "cpu"``: its
     timings are host timings and never sit unlabelled beside chip
     records."""
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    # The child is a single process with no rank contract: inherited
-    # telemetry sinks would resolve to the PARENT's paths and truncate the
-    # trace file it still has open. The parent's registry already counts
-    # the comparator phase via its own spans/counters.
-    env.pop("TA_METRICS_OUT", None)
-    env.pop("TA_TRACE_EVENTS", None)
-    flags = env.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        env["XLA_FLAGS"] = (
-            f"{flags} --xla_force_host_platform_device_count=8".strip()
-        )
     proc = subprocess.run(
         [sys.executable, "-m", "tree_attention_tpu", "--mode", "bench",
          "--device", "cpu", "--n-virtual-cpu", "8", "--mesh", "seq=8",
          "--causal"] + args,
-        env=env, cwd=os.path.dirname(os.path.abspath(__file__)),
+        env=_cpu_child_env(8), cwd=os.path.dirname(os.path.abspath(__file__)),
         stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
         timeout=timeout,
     )
@@ -379,8 +385,7 @@ def _comparator_subprocess(args, timeout=900):
     for line in proc.stdout.splitlines():
         line = line.strip()
         if line.startswith("{"):
-            return {**json.loads(line), **_CPU_CHILD_DEVICE,
-                    "device_count": 8}
+            return {**json.loads(line), **_cpu_child_device(8)}
     raise RuntimeError("comparator subprocess printed no JSON")
 
 
@@ -559,7 +564,7 @@ def _tree_vs_ring_decode_record():
     transferable measurement: BASELINE.md's ICI model prices it for real
     hardware, which is what makes the ≥2×-vs-ring north star falsifiable.
     """
-    rec = {**_CPU_CHILD_DEVICE, "device_count": 8}
+    rec = _cpu_child_device(8)
     for ctx, iters in ((64000, 4), (2048, 6)):
         # Per-context isolation: one context's failure must not erase the
         # other's minutes of serialised host compute.
@@ -785,15 +790,6 @@ def _serving_seq_sharded_record():
     flag BEFORE jax init, and this process holds the chip: the record runs
     in a clean CPU subprocess like the comparator benches and says
     ``"platform": "cpu"``."""
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env.pop("TA_METRICS_OUT", None)
-    env.pop("TA_TRACE_EVENTS", None)
-    flags = env.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        env["XLA_FLAGS"] = (
-            f"{flags} --xla_force_host_platform_device_count=2".strip()
-        )
     code = (
         "import json\n"
         "from tree_attention_tpu.bench.serving import "
@@ -802,7 +798,7 @@ def _serving_seq_sharded_record():
     )
     proc = subprocess.run(
         [sys.executable, "-c", code],
-        env=env, cwd=os.path.dirname(os.path.abspath(__file__)),
+        env=_cpu_child_env(2), cwd=os.path.dirname(os.path.abspath(__file__)),
         stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
         timeout=900,
     )
@@ -814,8 +810,7 @@ def _serving_seq_sharded_record():
     for line in reversed(proc.stdout.splitlines()):
         line = line.strip()
         if line.startswith("{"):
-            return {**json.loads(line), **_CPU_CHILD_DEVICE,
-                    "device_count": 2}
+            return {**json.loads(line), **_cpu_child_device(2)}
     raise RuntimeError("seq-sharded subprocess printed no JSON")
 
 

@@ -1,0 +1,183 @@
+"""``chip_smoke.py`` off the chip: it refuses to run (and prints no result),
+its phase functions work end to end at a tiny size in interpret mode, and the
+compile-cache helper places the cache where the environment says.
+
+The real run — Yi-6B widths, compiled kernels — only happens on a TPU through
+the chip tool; this keeps the script's control flow from rotting between chip
+runs. The phases that compile the most programs on the CPU (int8 serve, train,
+the program listing, the four-chip path) are marked ``slow`` to keep the
+default tier inside its clock.
+"""
+
+import importlib.util
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _load_smoke():
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(REPO, "chip_smoke.py"))
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules["chip_smoke"] = mod  # dataclasses resolve the module by name
+    spec.loader.exec_module(mod)
+    return mod
+
+
+smoke = _load_smoke()
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _restore_process_state():
+    """The phases call ``cli.main`` in this process, which configures the
+    package logger (handlers, ``propagate = False``) and installs signal
+    handlers: put both back, or later modules' ``caplog`` sees nothing."""
+    import logging
+    import signal
+
+    logger = logging.getLogger("tree_attention_tpu")
+    saved = (logger.level, logger.propagate, list(logger.handlers))
+    signals = {sig: signal.getsignal(sig)
+               for sig in (signal.SIGTERM, signal.SIGINT, signal.SIGUSR1)}
+    yield
+    for h in list(logger.handlers):
+        logger.removeHandler(h)
+    logger.setLevel(saved[0])
+    logger.propagate = saved[1]
+    for h in saved[2]:
+        logger.addHandler(h)
+    for sig, handler in signals.items():
+        signal.signal(sig, handler)
+
+# Width 128, 2 layers, interpret mode: every phase in seconds on the CPU.
+TINY = smoke.Sizes(
+    model_dim=128, heads=4, kv_heads=2, vocab=512, dtype="float32",
+    serve_layers=2, train_layers=2, sharded_layers=2,
+    slots=2, prompt_len=24, prompt_jitter=8, max_new=4,
+    prefix_len=16, prefix_block=8, prefill_chunk=16,
+    requests=4, requests_int8=2,
+    agree_prompt=32, agree_steps=2,
+    train_seq=128, train_steps=3,
+    interpret=True,
+    tol_kernel=2e-2, tol_kernel_int8=6e-2, tol_grad=6e-2,
+    tol_logits=1e-3, tol_logits_rms=1e-4,
+    tree_heads=4, tree_ctx=1024,
+)
+
+
+def test_refuses_to_run_without_a_tpu():
+    """As the driver runs it, but on this CPU: non-zero, and no result."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py")],
+        env=env, cwd=REPO, capture_output=True, text=True, timeout=120,
+    )
+    assert proc.returncode != 0
+    assert '"ok": true' not in proc.stdout
+    assert proc.stdout.strip() == ""
+    assert "no CPU fallback" in proc.stderr
+
+
+def test_phase_device_names_what_jax_found(tmp_path):
+    d = smoke.phase_device(str(tmp_path))
+    assert d["platform"] == "cpu" and d["device_count"] == len(jax.devices())
+    assert d["jax"] == jax.__version__
+    assert d["compile_cache_dir"] == str(tmp_path)
+    assert isinstance(d["native_library_loaded"], bool)
+
+
+def test_phase_kernels_against_reference():
+    d = smoke.phase_kernels(TINY)
+    assert set(d["kernels"]) == {
+        "paged_decode_tq1", "paged_chunk_tq64", "paged_tree_verify_tq8",
+        "paged_int8_q8q_block_scales", "paged_int8_q8_block_scales",
+        "paged_local_blocks_partial", "prefill_fwd", "bwd_dq", "bwd_dkv",
+    }
+    assert all(k["ok"] for k in d["kernels"].values())
+
+
+@pytest.mark.parametrize("int8", [
+    pytest.param(False, id="exact"),
+    pytest.param(True, id="int8", marks=pytest.mark.slow),
+])
+def test_phase_serve_through_the_cli(int8):
+    d = smoke.phase_serve(TINY, int8=int8)
+    n = TINY.requests_int8 if int8 else TINY.requests
+    assert d["requests"] == n and d["tokens_generated"] == n * TINY.max_new
+    assert d["prefix"]["hits"] >= 1
+    assert d["dispatch_counters"]["forward_step_dispatch_total"]
+
+
+def test_phase_ingress_streams_cancels_and_drains():
+    d = smoke.phase_ingress(TINY)
+    assert d["outcomes"] == {"budget": 3, "cancelled": 1}
+    assert d["client"]["streamed"]["completion_tokens"] == TINY.max_new
+    assert d["client"]["shared_b"]["prefix_hit_tokens"] >= TINY.prefix_len
+
+
+def test_phase_agreement_with_the_plain_forward():
+    d = smoke.phase_agreement(TINY)
+    assert d["max_abs_err"] <= TINY.tol_logits
+
+
+@pytest.mark.slow
+def test_phase_train_and_programs():
+    assert smoke.phase_train(TINY)["losses"][-1] < 6.3
+    d = smoke.phase_programs(TINY)
+    # Interpret mode leaves no tpu_custom_call behind: the phase lists the
+    # programs and (off the chip) gates nothing on them.
+    assert "serve.mixed.tq1" in d["kernels_found"]
+    assert "serve_int8.stage_chunk.tq16" in d["kernels_found"]
+    assert "train.step" in d["kernels_found"]
+    assert d["train_state_bytes_per_param"] > 0
+
+
+@pytest.mark.slow
+def test_four_chip_phases_on_virtual_devices(capsys, tmp_path):
+    """Rehearsal 2: the ``--chips 4`` path on the virtual CPU devices (one
+    ``seq`` mesh over all of them, as on the chips)."""
+    run = smoke.Run(str(tmp_path))
+    smoke.run_four_chips(run, TINY)
+    lines = {l["phase"]: l for l in map(
+        json.loads, capsys.readouterr().out.splitlines())}
+    assert run.ok, lines
+    n = len(jax.devices())
+    assert len(lines["tree_decode"]["shard_device_ids"]) == n
+    sharded = lines["serve_seq_sharded"]
+    assert set(sharded["blocks_per_shard"].values()) == {
+        sharded["pool_blocks"] // n}
+    assert lines["fleet_placement"]["replicas"] == n
+
+
+def test_run_prints_one_json_line_per_phase_and_fails_closed(capsys, tmp_path):
+    run = smoke.Run(str(tmp_path))
+    assert run.phase("good", lambda: {"x": 1})
+    assert not run.phase("bad", lambda: smoke.check(False, "nope"))
+    lines = [json.loads(l) for l in capsys.readouterr().out.splitlines()]
+    assert [(l["phase"], l["ok"]) for l in lines] == [
+        ("good", True), ("bad", False)]
+    assert "nope" in lines[1]["error"] and not run.ok
+    assert smoke.phase_times(run)["wall_s_by_phase"].keys() == {"good", "bad"}
+
+
+def test_compile_cache_helper_honours_the_environment(monkeypatch):
+    from tree_attention_tpu import cli
+
+    seen = {}
+    monkeypatch.setattr(
+        jax.config, "update", lambda k, v: seen.__setitem__(k, v))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+    assert cli.configure_compile_cache() == "/some/dir"
+    assert "jax_compilation_cache_dir" not in seen  # JAX reads the env itself
+    seen.clear()
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert cli.configure_compile_cache() == os.path.join(REPO, ".jax_cache")
+    assert seen["jax_compilation_cache_dir"] == os.path.join(
+        REPO, ".jax_cache")
+    assert seen["jax_persistent_cache_min_compile_time_secs"] < 1.0

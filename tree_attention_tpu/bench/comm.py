@@ -118,8 +118,12 @@ def collective_stats(fn: Callable[..., Any], *args: Any) -> Dict[str, Any]:
     loop-free by construction (the ring's hop chain is unrolled); callers
     measuring scan-based programs must multiply by trip count themselves.
     """
-    compiled = jax.jit(fn).lower(*args).compile()
-    text = compiled.as_text()
+    return collectives_in_hlo(jax.jit(fn).lower(*args).compile().as_text())
+
+
+def collectives_in_hlo(text: str) -> Dict[str, Any]:
+    """:func:`collective_stats` over an already-compiled module's text
+    (``compiled.as_text()``)."""
     ops: Dict[str, Dict[str, int]] = {}
     for line in text.splitlines():
         m = _OP_RE.match(line)
@@ -137,6 +141,30 @@ def collective_stats(fn: Callable[..., Any], *args: Any) -> Dict[str, Any]:
         "payload_bytes_total": sum(r["payload_bytes"] for r in ops.values()),
         "has_loop": bool(re.search(r"\bwhile\(", text)),
     }
+
+
+# A Pallas TPU kernel in optimized HLO: a custom call to Mosaic whose
+# op_name metadata ends ".../<pallas_call name>/pallas_call" (the kernels in
+# ops/ all pass ``name=``).
+_PALLAS_CALL_RE = re.compile(
+    r'custom_call_target="tpu_custom_call".*?op_name="[^"]*?([^/"]+)/pallas_call"'
+)
+
+
+def pallas_kernels(text: str) -> Dict[str, int]:
+    """Pallas kernels compiled into a module, by name: ``{name: calls}``
+    parsed from ``compiled.as_text()``. Empty means the program runs no
+    Pallas kernel on the TPU (interpret mode and the reference paths leave
+    no ``tpu_custom_call`` behind). A call inside a loop body (the layer
+    scan) appears once."""
+    found: Dict[str, int] = {}
+    for line in text.splitlines():
+        if "tpu_custom_call" not in line:
+            continue
+        m = _PALLAS_CALL_RE.search(line)
+        name = m.group(1) if m else "unnamed"
+        found[name] = found.get(name, 0) + 1
+    return found
 
 
 def assert_loop_free(stats: Dict[str, Any], what: str) -> None:

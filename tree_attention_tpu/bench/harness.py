@@ -98,8 +98,13 @@ class BenchResult:
     extra: Dict[str, Any] = dataclasses.field(default_factory=dict)
 
     def as_dict(self) -> Dict[str, Any]:
+        dev = jax.devices()[0]
         d = {
             "name": self.name,
+            # Every record names the device it was measured on: a host
+            # timing must never read as a chip's.
+            "platform": dev.platform,
+            "device_kind": dev.device_kind,
             "workload": self.workload,
             "tokens_per_sec": round(self.tokens_per_sec, 1),
             "tokens_per_sec_per_device": round(

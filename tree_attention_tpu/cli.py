@@ -21,11 +21,12 @@ trace), ``bench`` (the harness; prints one JSON record on stdout).
 
 from __future__ import annotations
 
+import dataclasses
 import json
 import logging
 import os
 import sys
-from typing import Optional
+from typing import Any, Callable, Optional
 
 from tree_attention_tpu import obs
 from tree_attention_tpu.utils.config import RunConfig, parse_args
@@ -109,6 +110,12 @@ def _relaunch(cfg: RunConfig, argv: Optional[list]) -> int:
             "point rather than completing one fixed budget"
         )
     cmd = [sys.executable, "-m", "tree_attention_tpu", *child_args]
+    from tree_attention_tpu.host_runtime import require_cpu_children
+
+    try:
+        require_cpu_children(f"--launch {cfg.launch}", child_args)
+    except RuntimeError as e:
+        raise SystemExit(str(e)) from None
     log.info("launching %d coordinated processes: %s", cfg.launch, cmd)
     # The coordinator address travels to the children via inherited env;
     # restore the parent's env afterwards so a later in-process run doesn't
@@ -499,14 +506,29 @@ def _run_generate(cfg: RunConfig, mesh) -> int:
     return 0
 
 
-def _run_serve(cfg: RunConfig, mesh) -> int:
-    """Continuous batching over a synthetic request trace: the slot
-    scheduler admits/retires requests while one compiled ragged decode step
-    serves every live slot per tick (``tree_attention_tpu/serving``)."""
+@dataclasses.dataclass
+class ServeSetup:
+    """What ``--mode serve`` builds from its flags before any traffic: the
+    model, the slot capacity, and the engine factory (one call per engine —
+    the fleet tier makes several)."""
+
+    tcfg: Any                       # TransformerConfig at the slot capacity
+    params: Any
+    cache_len: int
+    host_blocks: int
+    decode_slots: Optional[int]     # --serve-disagg only
+    make_engine: Callable[[], Any]  # -> SlotServer | DisaggServer
+
+
+def build_serve_engine(cfg: RunConfig, mesh) -> ServeSetup:
+    """Validate the serve flags and build the model and the engine factory
+    — the ONE construction every serve front end shares (the synthetic
+    trace, ``--serve-http``, ``--serve-fleet``) and ``chip_smoke.py`` calls
+    to inspect the engine the CLI serves with."""
     import jax
 
     from tree_attention_tpu.models import init_params
-    from tree_attention_tpu.serving import SlotServer, synthetic_trace
+    from tree_attention_tpu.serving import SlotServer
 
     if cfg.max_new_tokens < 1:
         raise SystemExit("--max-new-tokens must be >= 1")
@@ -532,6 +554,7 @@ def _run_serve(cfg: RunConfig, mesh) -> int:
         )
     if cfg.serve_fleet and cfg.replicas < 1:
         raise SystemExit("--replicas must be >= 1")
+    decode_slots: Optional[int] = None
     if cfg.serve_disagg:
         if cfg.serve_fleet:
             raise SystemExit(
@@ -636,9 +659,7 @@ def _run_serve(cfg: RunConfig, mesh) -> int:
             f"capacity {cache_len} (prompt-len + jitter + max-new-tokens, "
             f"rounded)"
         )
-    import dataclasses as _dc
-
-    tcfg = _transformer_config(_dc.replace(cfg, seq_len=cache_len))
+    tcfg = _transformer_config(dataclasses.replace(cfg, seq_len=cache_len))
     params = init_params(jax.random.PRNGKey(cfg.seed), tcfg)
     if cfg.slo_ttft <= 0 or cfg.slo_tbt <= 0:
         raise SystemExit("--slo-ttft and --slo-tbt must be > 0")
@@ -657,7 +678,7 @@ def _run_serve(cfg: RunConfig, mesh) -> int:
             DraftModelDrafter,
         )
 
-        draft_cfg = _dc.replace(
+        draft_cfg = dataclasses.replace(
             tcfg, n_layers=max(tcfg.n_layers // 2, 1)
         )
         drafter = DraftModelDrafter(
@@ -700,7 +721,24 @@ def _run_serve(cfg: RunConfig, mesh) -> int:
             )
         return SlotServer(params, tcfg, **engine_kw)
 
+    return ServeSetup(
+        tcfg=tcfg, params=params, cache_len=cache_len,
+        host_blocks=host_blocks, decode_slots=decode_slots,
+        make_engine=make_engine,
+    )
+
+
+def _run_serve(cfg: RunConfig, mesh) -> int:
+    """Continuous batching over a synthetic request trace: the slot
+    scheduler admits/retires requests while one compiled ragged decode step
+    serves every live slot per tick (``tree_attention_tpu/serving``)."""
     from tree_attention_tpu.host_runtime import heartbeat
+    from tree_attention_tpu.serving import synthetic_trace
+
+    setup = build_serve_engine(cfg, mesh)
+    tcfg, cache_len = setup.tcfg, setup.cache_len
+    host_blocks, decode_slots = setup.host_blocks, setup.decode_slots
+    make_engine = setup.make_engine
 
     if cfg.serve_fleet:
         # The fleet tier (ISSUE 11): --replicas in-process engines, each

@@ -25,7 +25,7 @@ import subprocess
 import tempfile
 import time
 import threading
-from typing import List, Optional, Sequence, Tuple
+from typing import List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -87,20 +87,20 @@ def _account_gang_result(statuses: Sequence[int]) -> None:
 # is package data, pyproject ``[tool.setuptools.package-data]``) so an
 # installed wheel can build the runtime on first use, same as a source
 # checkout. When the install location is read-only (system site-packages),
-# the build lands in ``~/.cache/tree-attention-tpu`` instead.
+# the build lands in ``~/.cache/tree-attention-tpu`` instead. Either way the
+# build directory is keyed by the SOURCE's content hash.
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                            "native")
 _SRC_PATH = os.path.join(_NATIVE_DIR, "treeattn_host.cc")
 
 
 def _build_dir() -> str:
-    if os.access(_NATIVE_DIR, os.W_OK):
-        return os.path.join(_NATIVE_DIR, "build")
-    # Read-only install: build into the user cache, keyed by the SOURCE
-    # content hash — two venvs with different package versions must not
-    # share one .so (the mtime staleness check cannot catch a newer .so
-    # built from a different install's source, and ctypes would bind old
-    # prototypes to a mismatched library).
+    """Where this source's library is built: a directory named by the
+    source's content hash, so a library is only ever loaded if it was built
+    from exactly this ``treeattn_host.cc``. An mtime says nothing after a
+    copy or a checkout, and ctypes would bind today's prototypes to a stale
+    or foreign ``.so``; two installs with different sources never share
+    one either."""
     import hashlib
 
     try:
@@ -108,9 +108,14 @@ def _build_dir() -> str:
             key = hashlib.sha256(f.read()).hexdigest()[:12]
     except OSError:
         key = "unknown"
-    return os.path.join(
-        os.path.expanduser("~"), ".cache", "tree-attention-tpu", key
+    base = (
+        os.path.join(_NATIVE_DIR, "build")
+        if os.access(_NATIVE_DIR, os.W_OK)
+        else os.path.join(
+            os.path.expanduser("~"), ".cache", "tree-attention-tpu"
+        )
     )
+    return os.path.join(base, key)
 
 
 def _so_path() -> str:
@@ -145,11 +150,7 @@ def load_native() -> Optional[ctypes.CDLL]:
             return _lib
         _lib_tried = True
         so = _so_path()
-        stale = not os.path.exists(so) or (
-            os.path.exists(_SRC_PATH)
-            and os.path.getmtime(_SRC_PATH) > os.path.getmtime(so)
-        )
-        if stale and not _compile():
+        if not os.path.exists(so) and not _compile():
             return None
         try:
             lib = ctypes.CDLL(so)
@@ -183,13 +184,6 @@ def load_native() -> Optional[ctypes.CDLL]:
             ctypes.POINTER(ctypes.c_char_p), ctypes.c_int,
             ctypes.POINTER(ctypes.c_int),
         ]
-        if not hasattr(lib, "ta_launch_processes_supervised"):
-            # A prebuilt .so from before this symbol existed whose mtime
-            # defeated the staleness check: treat the native runtime as
-            # unavailable rather than AttributeError-ing at call time.
-            log.warning("stale libtreeattn_host.so (missing supervised "
-                        "launcher); using the pure-python fallbacks")
-            return None
         lib.ta_launch_processes_supervised.restype = ctypes.c_int
         lib.ta_launch_processes_supervised.argtypes = [
             ctypes.POINTER(ctypes.c_char_p), ctypes.c_int,
@@ -618,6 +612,41 @@ def maybe_inject_fault(step: int) -> None:
                     args={"rank": rank, "step": step})
     obs.TRACER.flush()  # os._exit skips atexit; don't lose the event
     os._exit(86)
+
+
+def require_cpu_children(what: str, argv: Sequence[str],
+                         env: Optional[Mapping[str, str]] = None) -> None:
+    """Refuse to start several JAX child processes that are not held to
+    the CPU platform.
+
+    A TPU host's chips belong to one process at a time: N children that
+    each take whatever JAX finds would each claim every chip, and all but
+    the first fail or hang. The parent cannot look for a TPU itself without
+    claiming it, so the rule is syntactic — the children's platform must be
+    pinned by ``JAX_PLATFORMS=cpu`` in their environment or by ``--device
+    cpu`` / ``--n-virtual-cpu N`` in their arguments. On a TPU, one process
+    drives every chip (``--mesh seq=N``, or in-process replicas).
+    """
+    env = os.environ if env is None else env
+    if env.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        return
+    args = list(argv)
+    for i, a in enumerate(args):
+        flag, _, val = a.partition("=")
+        if not val and i + 1 < len(args):
+            val = args[i + 1]
+        if (flag == "--device" and val == "cpu") or (
+            flag == "--n-virtual-cpu" and val not in ("", "0")
+        ):
+            return
+    raise RuntimeError(
+        f"{what} starts several JAX processes on this host, and a TPU "
+        "host's chips belong to one process at a time: children that are "
+        "not pinned to the CPU would each claim every chip and fail or "
+        "hang. Pin them (--device cpu, --n-virtual-cpu N, or "
+        "JAX_PLATFORMS=cpu) for the emulated multi-process shape; on a "
+        "TPU run ONE process over the chips (--mesh seq=N)."
+    )
 
 
 def launch_local(

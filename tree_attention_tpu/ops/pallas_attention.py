@@ -309,6 +309,7 @@ def _attention_pallas_fwd(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_fwd",
     )(offs, qp, kp, vp)
 
     out = out[:, :Tq].reshape(B, Hq, Tq, D)

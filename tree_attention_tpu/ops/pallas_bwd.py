@@ -301,6 +301,7 @@ def _attention_bwd_pallas(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(offs, qp, kp, vp, dop, res_b)
 
     # ---- dK, dV ----
@@ -343,6 +344,7 @@ def _attention_bwd_pallas(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(offs, qp, kp, vp, dop, res_b)
 
     return (

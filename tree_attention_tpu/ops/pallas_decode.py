@@ -440,7 +440,7 @@ def _flash_decode_paged_kernel(
             precision=matmul_precision(q_ref.dtype, k_tile.dtype),
         ) * scale
         if ks_ref is not None:
-            s = s * ks_ref[0, 0, 0]  # this block's K dequant scalar
+            s = s * _block_scale(ks_ref, si, bq, bk)  # this block's K dequant
 
         s = _decode_visibility_mask(
             s, qi, si, bq=bq, bk=bk, tq=tq, tk=tk,
@@ -449,7 +449,8 @@ def _flash_decode_paged_kernel(
         )
         _decode_softmax_fold(
             s, v_ref[0, 0], m_scr, l_scr, acc_scr, si=si, bk=bk, tk=tk,
-            v_scale=None if vs_ref is None else vs_ref[0, 0, 0],
+            v_scale=(None if vs_ref is None
+                     else _block_scale(vs_ref, si, bq, bk)),
         )
 
     @pl.when(si == n_s - 1)
@@ -530,7 +531,7 @@ def _flash_decode_paged_q8q_kernel(
         )
         s = s_i.astype(jnp.float32) * qs_ref[0][:, :1]
         if ks_ref is not None:
-            s = s * ks_ref[0, 0, 0]  # this block's K dequant scalar
+            s = s * _block_scale(ks_ref, si, bq, bk)  # this block's K dequant
 
         s = _decode_visibility_mask(
             s, qi, si, bq=bq, bk=bk, tq=tq, tk=tk,
@@ -539,7 +540,8 @@ def _flash_decode_paged_q8q_kernel(
         )
         _decode_softmax_fold(
             s, v_ref[0, 0], m_scr, l_scr, acc_scr, si=si, bk=bk, tk=tk,
-            v_scale=None if vs_ref is None else vs_ref[0, 0, 0],
+            v_scale=(None if vs_ref is None
+                     else _block_scale(vs_ref, si, bq, bk)),
         )
 
     @pl.when(si == n_s - 1)
@@ -573,26 +575,56 @@ def _paged_kv_map(n_kv_heads: int, local: bool = False):
     return index_map
 
 
+# The per-block scale operand streams in tiles of one f32 sublane group:
+# the TPU lowering needs a block's second-minor dim divisible by 8 (a
+# one-row block is refused), so each DMA brings 8 logical blocks' scalars
+# and the body picks its own row.
+_SCALE_ROWS = 8
+
+
 def _paged_scale_map(bh, qi, si, offs_ref, tbl_ref):
     """Per-block scale operand map (ISSUE 13): the scales were pre-
     gathered per LOGICAL block (see :func:`_block_scale_rows`), so grid
-    step ``si`` just reads row ``si`` — no second table dereference."""
+    step ``si`` reads the 8-row tile holding row ``si`` — no second table
+    dereference, and no re-fetch while ``si`` stays inside the tile."""
     del qi, offs_ref, tbl_ref
-    return (bh, si, 0)
+    return (bh, si // _SCALE_ROWS, 0)
+
+
+def _block_scale(ref, si, bq: int, bk: int):
+    """Grid step ``si``'s dequant scalar as a ``(bq, bk)`` tile, ready to
+    multiply the score/probability tile: its row of the ``(1, _SCALE_ROWS,
+    LANES)`` block :func:`_paged_scale_map` loaded. The operand is already
+    lane-broadcast, so the row only spreads over the sublanes and is cut
+    (or repeated) along the lanes to ``bk`` — Mosaic has no broadcast of a
+    ``(1, 1)`` tile over sublanes and lanes at once."""
+    row = ref[0, pl.ds(si % _SCALE_ROWS, 1), :]  # (1, LANES)
+    if bk <= _LANES:
+        return jnp.broadcast_to(row[:, :bk], (bq, bk))
+    reps, rem = divmod(bk, _LANES)
+    if rem:
+        raise ValueError(
+            f"per-block scales need a pool block <= {_LANES} or a multiple "
+            f"of it, got {bk}"
+        )
+    return jnp.broadcast_to(
+        jnp.concatenate([row] * reps, axis=1), (bq, bk)
+    )
 
 
 def _block_scale_rows(scale: jax.Array, block_table: jax.Array) -> jax.Array:
     """Arrange ``(N, Hkv)`` per-block scale scalars into the
-    ``(B·Hkv, NB, LANES)`` lane-broadcast operand the paged kernels read
-    — one scalar per (slot, head, logical block), gathered through the
-    table once per call (O(B·NB·Hkv) floats, noise next to the KV bytes
-    the grid streams). The same VMEM idiom as the q8q per-row Q scales
-    and the tree bitmasks."""
+    ``(B·Hkv, NB8, LANES)`` lane-broadcast operand the paged kernels read
+    (``NB8`` = the table width rounded up to :data:`_SCALE_ROWS`; the pad
+    rows are never read) — one scalar per (slot, head, logical block),
+    gathered through the table once per call (O(B·NB·Hkv) floats, noise
+    next to the KV bytes the grid streams). The same VMEM idiom as the q8q
+    per-row Q scales and the tree bitmasks."""
     N, Hkv = scale.shape
     B, NB = block_table.shape
     g = scale[jnp.clip(block_table, 0, N - 1)]      # (B, NB, Hkv)
-    g = jnp.moveaxis(g, 2, 1).reshape(B * Hkv, NB)
-    return jnp.broadcast_to(g[:, :, None], (B * Hkv, NB, _LANES))
+    g = _pad_dim(jnp.moveaxis(g, 2, 1).reshape(B * Hkv, NB), 1, _SCALE_ROWS)
+    return jnp.broadcast_to(g[:, :, None], (*g.shape, _LANES))
 
 
 def _paged_decode_call(
@@ -649,6 +681,9 @@ def _paged_decode_call(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        # A stable name per kernel body, carried into the compiled module
+        # (the custom call's op_name) and the profiler trace.
+        name=kernel_body.__name__.strip("_").removesuffix("_kernel"),
     )(offs, tbl, *tensors)
 
 
@@ -803,8 +838,8 @@ def attention_pallas_decode_q8(
             pl.BlockSpec((1, bq, D), _paged_q_map),
             pl.BlockSpec((1, 1, blk, D), _paged_kv_map(Hkv)),
             pl.BlockSpec((1, 1, blk, D), _paged_kv_map(Hkv)),
-            pl.BlockSpec((1, 1, _LANES), _paged_scale_map),
-            pl.BlockSpec((1, 1, _LANES), _paged_scale_map),
+            pl.BlockSpec((1, _SCALE_ROWS, _LANES), _paged_scale_map),
+            pl.BlockSpec((1, _SCALE_ROWS, _LANES), _paged_scale_map),
         ]
         if tree_mask is not None:
             tensors.insert(1, _tree_bits_rows(tree_mask, G, Hkv, bq, n_q))
@@ -986,8 +1021,8 @@ def attention_pallas_decode_q8q(
                 _block_scale_rows(v_scale, block_table),
             ]
             in_specs += [
-                pl.BlockSpec((1, 1, _LANES), _paged_scale_map),
-                pl.BlockSpec((1, 1, _LANES), _paged_scale_map),
+                pl.BlockSpec((1, _SCALE_ROWS, _LANES), _paged_scale_map),
+                pl.BlockSpec((1, _SCALE_ROWS, _LANES), _paged_scale_map),
             ]
         if tree_mask is not None:
             tensors.insert(2, _tree_bits_rows(tree_mask, G, Hkv, bq, n_q))
@@ -1069,6 +1104,7 @@ def attention_pallas_decode_q8q(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_decode_q8q",
     )(*tensors)
 
     out = out[:, :r]
@@ -1291,6 +1327,7 @@ def attention_pallas_decode(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_decode",
     )(*tensors)
 
     out = out[:, :r].reshape(B, Hq, Tq, D).astype(out_dtype)

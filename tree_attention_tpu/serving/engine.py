@@ -1750,6 +1750,31 @@ class SlotServer:
                                                   axis=0)
         return staging, new_cache, tok_vec, lp_vec, last
 
+    def lower_programs(self, tq: int) -> Dict[str, Any]:
+        """Lower — from the engine's live state, dispatching and donating
+        nothing — the programs a tick at Tq bucket ``tq`` runs: the fused
+        ``mixed`` step (``tq == 1`` is the pure-decode tick) and, where
+        admission stages prompts (int8 chunked), the ``stage_chunk``
+        program. ``.compile().as_text()`` of each shows what a served tick
+        executes, e.g. which Pallas kernels are in it; with the programs
+        already run, the compile is a cache hit."""
+        S = self.slots
+        zeros = lambda n, dt: jnp.zeros((n,), dt)
+        lowered = {"mixed": self._mixed.lower(
+            self.params, jnp.zeros((S, tq), jnp.int32),
+            zeros(S, jnp.int32), zeros(S, bool), zeros(S, jnp.int32),
+            zeros(S, bool), self.cache, self._keys,
+            jnp.asarray(self._temp_np), jnp.asarray(self._topk_np),
+            zeros(S, jnp.int32), self._lp,
+        )}
+        if self._staged_prefill:
+            lowered["stage_chunk"] = self._stage_chunk.lower(
+                self.params, jnp.zeros((1, tq), jnp.int32),
+                zeros(1, jnp.int32), self._staging, zeros(1, bool),
+                zeros(1, jnp.int32),
+            )
+        return lowered
+
     # -- ingress-facing control (thread-safe) ------------------------------
 
     def prefix_stats(self) -> Dict[str, Any]:

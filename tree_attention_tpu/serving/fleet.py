@@ -44,7 +44,10 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
-from tree_attention_tpu.host_runtime import _rank_exit_outcome
+from tree_attention_tpu.host_runtime import (
+    _rank_exit_outcome,
+    require_cpu_children,
+)
 from tree_attention_tpu.serving.ingress import IngressServer
 from tree_attention_tpu.serving.router import FleetRouter
 from tree_attention_tpu.utils.logging import get_logger
@@ -205,6 +208,11 @@ class ProcessReplica:
             env = dict(os.environ)
             env["TA_REPLICA"] = self.name  # ps/log attribution, the
             # JAX_PROCESS_INDEX idiom of launch_local
+            # Replicas on a TPU host are in-process engines, one per
+            # device; a child process must be held to the CPU.
+            require_cpu_children(
+                f"ProcessReplica {self.name!r}", self.argv, env
+            )
             self._proc = subprocess.Popen(self.argv, env=env)
         deadline = time.monotonic() + self.start_timeout_s
         while time.monotonic() < deadline:
