@@ -1,0 +1,10 @@
+"""Share of the traced window in which the device ran nothing while the
+engine was in any other phase of a tick: ingest, sweep, admit, plan, pack,
+publish, emit, account. The four ``idle_in_*`` add up to ``device_idle_pct``
+of the same run."""
+from benchmark import phases
+
+
+def read(run):
+    shares = phases.idle_shares(run)
+    return shares[phases.HOST] if shares else None

@@ -38,7 +38,7 @@ import urllib.request
 
 import pytest
 
-from tree_attention_tpu.obs.flight import FlightRecorder
+from tree_attention_tpu.obs.flight import FlightRecorder, TickPhases
 from tree_attention_tpu.obs.http import MetricsHTTPServer
 from tree_attention_tpu.obs.metrics import (
     MetricsRegistry,
@@ -712,6 +712,9 @@ class TestDisabledOverhead:
         tracer = SpanTracer()  # inactive
         flight = FlightRecorder()  # disarmed
         tick_rec = {"tick": 0}  # prebuilt, as the engine's guard requires
+        # The tick-phase stamper (ISSUE 24) keeps the same contract: off,
+        # begin() latches and mark()/finish() return at one check.
+        phases = TickPhases()
         # The speculative-decoding hooks (ISSUE 8) ride the same guard:
         # the engine's verify commit calls these module-level metrics
         # only under REGISTRY.enabled — exercised here through the real
@@ -743,6 +746,10 @@ class TestDisabledOverhead:
             tracer.instant("event")
             flight.record(tick_rec)
             flight.record(None)  # the disabled-guard calling shape
+            phases.begin(0.0)
+            phases.mark("sweep")
+            phases.mark("dispatch", 7, "mixed", 256)  # positional: no dict
+            phases.finish(None)
 
         hot_path()  # warm any lazy caches before measuring
         tracemalloc.start()

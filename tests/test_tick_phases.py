@@ -1,0 +1,339 @@
+"""The tick from inside (ISSUE 24): phase stamps in ``SlotServer.serve``.
+
+One set of stamps, three sinks — the tick's flight record (``phases``,
+``t_end``, ``kind``, ``tq``, ``rows_computed``, ``rows_useful``), the
+profiler's ``tick:<phase>`` annotations, and ``tick:<phase>`` complete
+events in the ``--trace-events`` JSONL. CPU toy engine, contiguous layout
+(the stamps sit in layout-independent loop code).
+"""
+
+import functools
+import json
+import time
+import tracemalloc
+import types
+
+import numpy as np
+import pytest
+import jax
+import jax.numpy as jnp
+
+from tree_attention_tpu import obs
+from tree_attention_tpu.models import TransformerConfig, init_params
+from tree_attention_tpu.obs import flight as flight_mod
+from tree_attention_tpu.obs.flight import FLIGHT, TICK_PHASES, TickPhases
+from tree_attention_tpu.serving import Request
+from tree_attention_tpu.serving import SlotServer as _SlotServer
+
+SlotServer = functools.partial(_SlotServer, kv_layout="contiguous")
+
+CFG = TransformerConfig(
+    vocab_size=128, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
+    d_head=16, d_ff=128, max_seq_len=256, dtype=jnp.float32,
+    attn_impl="blockwise", attn_block_size=16,
+)
+SLOTS, CHUNK = 2, 4
+
+
+@pytest.fixture(scope="module")
+def params():
+    return init_params(jax.random.PRNGKey(0), CFG)
+
+
+def _requests(n, prompt_len, n_new, key=31):
+    prompt = np.asarray(jax.random.randint(
+        jax.random.PRNGKey(key), (n, prompt_len), 0, CFG.vocab_size))
+    return [Request(uid=i, prompt=prompt[i], max_new_tokens=n_new)
+            for i in range(n)]
+
+
+def _recorded(server, reqs, executed_only=True):
+    """Serve with the flight recorder armed; the executed ticks' records."""
+    FLIGHT.clear()
+    FLIGHT.arm()
+    try:
+        report = server.serve(reqs)
+    finally:
+        FLIGHT.disarm()
+    recs = [r for r in FLIGHT.snapshot()["records"] if "t_s" in r]
+    FLIGHT.clear()
+    if executed_only:                    # no fast-forward in the trace
+        assert len(recs) == report.ticks
+    return report, recs
+
+
+@pytest.fixture(scope="module")
+def served(params, tmp_path_factory):
+    """One chunked run, 3 requests through 2 slots, with the recorder AND
+    the span tracer on: the records and the JSONL of the same ticks."""
+    path = tmp_path_factory.mktemp("phases") / "trace.jsonl"
+    server = SlotServer(params, CFG, slots=SLOTS, cache_len=32,
+                        prefill_chunk=CHUNK)
+    obs.TRACER.start(str(path))
+    try:
+        t_before = time.monotonic()
+        report, recs = _recorded(server, _requests(3, 9, 4))
+    finally:
+        obs.TRACER.close()
+    events = [json.loads(ln) for ln in path.read_text().splitlines()]
+    return server, report, recs, events, t_before
+
+
+def test_phases_are_named_in_loop_order_and_tile_the_tick(served):
+    _, _, recs, _, t_before = served
+    order = {name: i for i, name in enumerate(TICK_PHASES)}
+    for rec, nxt in zip(recs, recs[1:] + [None]):
+        names = [p[0] for p in rec["phases"]]
+        starts = [p[1] for p in rec["phases"]]
+        assert set(names) <= set(TICK_PHASES)
+        # Loop order, each phase at most once.
+        assert [order[n] for n in names] == sorted({order[n] for n in names})
+        assert names[0] == "ingest" and names[-1] == "account"
+        assert starts == sorted(starts)
+        # Absolute monotonic stamps, at or after the tick's own top.
+        assert starts[0] >= t_before
+        assert rec["t_end"] >= starts[-1]
+        if nxt is not None:
+            assert rec["t_end"] <= nxt["phases"][0][1]
+        # A tick that fetched has the fetch and the emit pass behind it.
+        assert ("fetch" in names) == rec["host_sync"] == ("emit" in names)
+
+
+def test_tick_top_is_the_first_stamp(served):
+    """``t_s`` and the first phase are the same clock read: the gap between
+    records is the loop waiting, with no unstamped work in it."""
+    _, _, recs, _, _ = served
+    first = recs[0]
+    for rec in recs:
+        assert rec["phases"][0][1] - first["phases"][0][1] == pytest.approx(
+            rec["t_s"] - first["t_s"], abs=2e-6)
+
+
+def test_kind_and_tq_agree_with_the_chunk_plan(served):
+    server, _, recs, _, _ = served
+    kinds = {r["kind"] for r in recs}
+    assert kinds == {"mixed", "decode"}
+    for r in recs:
+        if r["kind"] == "mixed":
+            assert r["chunk_tokens"] > 0
+            assert r["tq"] == server._chunk_bucket(
+                max(n for _, n, _ in r["chunk_plan"]))
+        else:
+            assert r["chunk_tokens"] == 0 and r["tq"] == 1
+        assert r["rows_computed"] == SLOTS * r["tq"]
+
+
+def test_rows_useful_counts_the_rows_that_carried_a_token(served):
+    _, _, recs, _, _ = served
+    for r in recs:
+        assert 0 < r["rows_useful"] <= r["rows_computed"]
+        if r["kind"] == "decode":
+            assert r["rows_useful"] == r["occupancy"]
+        else:
+            assert r["rows_useful"] == r["chunk_tokens"] + r["occupancy"]
+
+
+def test_trace_events_hold_the_phases_inside_the_tick_span(served):
+    _, report, recs, events, _ = served
+    spans = [e for e in events if e["ph"] == "X"]
+    ticks = [e for e in spans if e["name"] == "serving:tick"]
+    assert len(ticks) == report.ticks
+    by_tick = {e["args"]["tick"]: e for e in ticks}
+    phase_events = [e for e in spans if e["name"].startswith("tick:")]
+    assert phase_events and {e["cat"] for e in phase_events} == {"serving"}
+    assert len(phase_events) == sum(len(r["phases"]) for r in recs)
+    it = iter(phase_events)
+    for rec in recs:
+        tick = by_tick[rec["tick"]]
+        for name, start in rec["phases"]:
+            e = next(it)                 # written in order, tick by tick
+            assert e["name"] == "tick:" + name
+            assert e["ts"] == round(start * 1e9) // 1000   # the same stamp
+            assert e["pid"] == tick["pid"] and e["tid"] == tick["tid"]
+            assert tick["ts"] <= e["ts"]
+            assert e["ts"] + e["dur"] <= tick["ts"] + tick["dur"]
+
+
+@pytest.mark.parametrize("kw, want", [
+    (dict(admission="whole"), {"awaits", "decode"}),
+    (dict(prefill_chunk=CHUNK, quantize=True), {"staged", "decode"}),
+    (dict(prefill_chunk=CHUNK, speculate=True, draft_k=3), {"verify"}),
+], ids=["whole", "staged", "verify"])
+def test_the_other_tick_kinds(params, kw, want):
+    server = SlotServer(params, CFG, slots=SLOTS, cache_len=32, **kw)
+    _, recs = _recorded(server, _requests(2, 9, 4, key=33))
+    assert {r["kind"] for r in recs} == want
+    for r in recs:
+        assert r["rows_useful"] <= r["rows_computed"] == SLOTS * r["tq"]
+        if r["kind"] == "awaits":
+            assert r["tq"] == 0 and "dispatch" not in dict(r["phases"])
+        if r["kind"] == "staged":
+            # The stage programs run under 'pack'; tq is the decode
+            # program's, if one ran in the same tick.
+            assert r["chunk_tokens"] > 0 and r["tq"] in (0, 1)
+        if r["kind"] == "verify":
+            assert r["tq"] >= 1 and "dispatch" in dict(r["phases"])
+
+
+class _FakeAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs enter/leave."""
+
+    log = []
+
+    def __init__(self, name, **kwargs):
+        self.name, self.kwargs = name, kwargs
+
+    def __enter__(self):
+        _FakeAnnotation.log.append(("enter", self.name, self.kwargs))
+
+    def __exit__(self, *exc):
+        _FakeAnnotation.log.append(("leave", self.name, self.kwargs))
+
+
+def _iterations(names):
+    """Annotation names grouped by loop iteration (each opens 'ingest')."""
+    groups = []
+    for n in names:
+        if n == "tick:ingest":
+            groups.append([])
+        groups[-1].append(n)
+    return groups
+
+
+def test_each_phase_is_a_profiler_annotation_left_at_the_next_mark(
+        params, monkeypatch):
+    import jax.profiler
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _FakeAnnotation)
+    _FakeAnnotation.log = []
+    server = SlotServer(params, CFG, slots=SLOTS, cache_len=32,
+                        prefill_chunk=CHUNK)
+    _, recs = _recorded(server, _requests(2, 6, 3, key=34))
+    log = _FakeAnnotation.log
+    # Strictly alternating enter/leave of the same annotation: never two
+    # open at once, none left open at the end.
+    assert len(log) % 2 == 0
+    for (a, name_a, _), (b, name_b, _) in zip(log[0::2], log[1::2]):
+        assert (a, b) == ("enter", "leave") and name_a == name_b
+    entered = [(n, kw) for what, n, kw in log if what == "enter"]
+    groups = _iterations([n for n, _ in entered])
+    # The executed ticks, then the drained exit, which ran the top of the
+    # loop and abandoned its stamps.
+    assert groups[:-1] == [["tick:" + p[0] for p in r["phases"]]
+                           for r in recs]
+    assert groups[-1] == ["tick:ingest", "tick:sweep", "tick:admit"]
+    dispatches = [kw for n, kw in entered if n == "tick:dispatch"]
+    assert dispatches == [
+        {"tick": r["tick"], "kind": r["kind"], "tq": r["tq"]}
+        for r in recs if "dispatch" in dict(r["phases"])]
+    assert all(kw == {} for n, kw in entered if n != "tick:dispatch")
+
+
+def test_the_real_annotation_takes_the_arguments_the_stamper_gives():
+    """No fake: the stamper drives ``jax.profiler.TraceAnnotation`` itself
+    (no session runs, so nothing is kept — it must just not raise)."""
+    FLIGHT.arm()
+    try:
+        ph = TickPhases()
+        ph.begin(1.0)
+        ph.mark("dispatch", 7, "mixed", 256)
+        rec = {}
+        ph.finish(rec)
+    finally:
+        FLIGHT.disarm()
+    assert [p[0] for p in rec["phases"]] == ["ingest", "dispatch"]
+    assert rec["phases"][0][1] == 1.0 and rec["t_end"] >= rec["phases"][1][1]
+
+
+def test_a_repeated_mark_and_an_abandoned_tick():
+    _FakeAnnotation.log = []
+    FLIGHT.arm()
+    try:
+        ph = TickPhases()
+        ph._annotation = _FakeAnnotation
+        ph.begin(0.5)
+        ph.mark("pack")
+        ph.mark("pack")                  # names the open phase: no new one
+        ph.abandon()                     # an idle iteration: nothing kept
+        assert not ph.on
+        assert [what for what, _, _ in _FakeAnnotation.log] == [
+            "enter", "leave", "enter", "leave"]
+        ph.finish({})                    # off again: no-ops
+        ph.mark("plan")
+        ph.abandon()
+        ph.begin(0.75)
+        rec = {}
+        ph.finish(rec)
+    finally:
+        FLIGHT.disarm()
+    assert [p[0] for p in rec["phases"]] == ["ingest"]
+
+
+def test_an_idle_iteration_leaves_no_record_and_no_open_annotation(
+        params, monkeypatch):
+    """Arrival ticks far apart: the loop fast-forwards between them, and
+    those iterations stamp ingest..admit and then abandon."""
+    import jax.profiler
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _FakeAnnotation)
+    _FakeAnnotation.log = []
+    reqs = _requests(2, 5, 2, key=35)
+    reqs[1].arrival_tick = 50
+    server = SlotServer(params, CFG, slots=SLOTS, cache_len=32,
+                        prefill_chunk=CHUNK)
+    report, recs = _recorded(server, reqs, executed_only=False)
+    assert len(recs) < report.ticks      # the clock jumped to tick 50
+    assert all(r["phases"][-1][0] == "account" for r in recs)
+    log = _FakeAnnotation.log
+    assert [w for w, _, _ in log[0::2]] == ["enter"] * (len(log) // 2)
+    assert [w for w, _, _ in log[1::2]] == ["leave"] * (len(log) // 2)
+    groups = _iterations([n for w, n, _ in log if w == "enter"])
+    idle = [g for g in groups if g[-1] != "tick:account"]
+    # The fast-forward between the arrivals and the drained exit: stamped
+    # to the end of admission, then abandoned with nothing left behind.
+    assert len(idle) == 2 and len(groups) == len(recs) + 2
+    assert all(g == ["tick:ingest", "tick:sweep", "tick:admit"]
+               for g in idle)
+
+
+def test_off_means_no_clock_read_and_no_allocation(monkeypatch):
+    """The disabled path: begin() latches off with two attribute checks;
+    mark(), abandon() and finish() are one check and a return."""
+    assert not FLIGHT.enabled and not obs.TRACER.active
+
+    def no_clock():
+        raise AssertionError("the stamper read the clock while off")
+
+    ph = TickPhases()
+
+    def tick():
+        ph.begin(0.0)
+        ph.mark("sweep")
+        ph.mark("dispatch", 3, "decode", 1)
+        ph.abandon()
+        ph.finish(None)
+
+    tick()
+    assert ph._annotation is None        # JAX's profiler never imported
+    monkeypatch.setattr(flight_mod, "time",
+                        types.SimpleNamespace(monotonic=no_clock))
+    tracemalloc.start()
+    try:
+        base = tracemalloc.get_traced_memory()[0]
+        for _ in range(5000):
+            tick()
+        grown = tracemalloc.get_traced_memory()[0] - base
+    finally:
+        tracemalloc.stop()
+    assert grown < 4096, f"the disabled stamper allocated {grown} B"
+    assert ph._marks is None and ph._open is None
+
+
+def test_the_engine_serves_the_same_tokens_stamped_or_not(params):
+    reqs = lambda: _requests(3, 9, 4, key=36)          # noqa: E731
+    kw = dict(slots=SLOTS, cache_len=32, prefill_chunk=CHUNK)
+    plain = SlotServer(params, CFG, **kw).serve(reqs())
+    stamped, _ = _recorded(SlotServer(params, CFG, **kw), reqs())
+    assert ({r.uid: r.tokens for r in plain.results}
+            == {r.uid: r.tokens for r in stamped.results})
+    assert plain.ticks == stamped.ticks

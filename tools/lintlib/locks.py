@@ -21,7 +21,7 @@ rules keep that sound:
 - **signal paths never emit** — everything reachable from
   ``obs.flush`` and the installed signal handlers may *write sinks*
   but must not call the emission APIs (``inc`` / ``observe`` /
-  ``labels`` / ``instant`` / ``counter_event`` / ``record``): an
+  ``labels`` / ``instant`` / ``record``): an
   emission inside a handler allocates and re-enters emission locks at
   the exact moment they may be held.
 
@@ -59,8 +59,7 @@ _MUTATING_METHODS = {
     "append", "appendleft", "extend", "add", "clear", "pop", "popleft",
     "popitem", "remove", "discard", "update", "setdefault", "insert",
 }
-_EMISSION_APIS = {"inc", "dec", "observe", "labels", "instant",
-                  "counter_event"}
+_EMISSION_APIS = {"inc", "dec", "observe", "labels", "instant"}
 # Crash-path entries double as roots so per-file analysis still covers
 # the cross-module hop (obs.flush -> REGISTRY.write_json lives in
 # another file; rooting write_json itself closes the gap).

@@ -57,7 +57,7 @@ _METRIC_CTORS = {"counter", "gauge", "histogram"}
 _METRIC_MUTS = {"inc", "dec", "observe", "set"}
 
 #: Call targets whose ``args=`` payload is a tracer emission.
-_TRACER_FNS = {"instant", "span", "counter_event"}
+_TRACER_FNS = {"instant", "span"}
 
 #: Request-ledger accumulation seams — each builds a payload (kwargs
 #: dict, keyword defaults) before REQLOG's internal early-return, so the
@@ -122,15 +122,14 @@ def _tracer_call_kind(call: ast.Call) -> Optional[str]:
     return last if last in _TRACER_FNS else None
 
 
-def _args_payload(call: ast.Call, fname: str) -> Optional[ast.expr]:
-    """The ``args`` argument of a span/instant/counter_event call
-    (positional slot 2 for span/instant, 1 for counter_event)."""
+def _args_payload(call: ast.Call) -> Optional[ast.expr]:
+    """The ``args`` argument of a span/instant call (positional slot
+    2)."""
     for kw in call.keywords:
         if kw.arg == "args":
             return kw.value
-    pos = 1 if fname == "counter_event" else 2
-    if len(call.args) > pos:
-        return call.args[pos]
+    if len(call.args) > 2:
+        return call.args[2]
     return None
 
 
@@ -161,7 +160,7 @@ class _Walker(GuardWalker):
             return
         fname = _tracer_call_kind(e)
         if fname is not None:
-            payload = _args_payload(e, fname)
+            payload = _args_payload(e)
             self._check_payload(e, payload, guards, fname)
             return
         # some_span.set(...) — args attach to a live span object.

@@ -64,7 +64,6 @@ from tree_attention_tpu.obs.tracing import (  # noqa: F401
     new_trace_id,
     parse_traceparent,
     span,
-    traced,
 )
 from tree_attention_tpu.obs.flight import (  # noqa: F401
     FLIGHT,

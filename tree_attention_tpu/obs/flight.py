@@ -26,6 +26,12 @@ answers).
 Disabled (the default) is free: :meth:`record` is one attribute check and
 an early return; call sites must build their record dict only under an
 ``if FLIGHT.enabled:`` guard — the same contract as span args.
+
+The tick from inside: a :class:`TickPhases` (one per ``serve()`` run — a
+process may run several engine threads) stamps the boundaries between the
+phases of one engine tick and hands the one set of stamps to three sinks —
+the tick's flight record, the ``jax.profiler`` trace, and the
+``--trace-events`` JSONL.
 """
 
 from __future__ import annotations
@@ -37,7 +43,15 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional
 
+from tree_attention_tpu.obs.tracing import TRACER
+
 DEFAULT_CAPACITY = 256
+
+#: The phases of one ``SlotServer.serve`` tick, in the order the loop runs
+#: them; each lasts until the next mark (ARCHITECTURE.md "Observability").
+TICK_PHASES = ("ingest", "sweep", "admit", "plan", "pack", "table_sync",
+               "dispatch", "publish", "fetch", "emit", "account")
+_ANNOTATION = {p: "tick:" + p for p in TICK_PHASES}
 
 
 class FlightRecorder:
@@ -167,3 +181,105 @@ class FlightRecorder:
 
 #: The process-wide recorder the serving engine feeds.
 FLIGHT = FlightRecorder()
+
+
+class TickPhases:
+    """Phase stamps inside one engine tick: one clock read a boundary,
+    three sinks.
+
+    The loop calls :meth:`begin` at the tick's top, :meth:`mark` at each
+    boundary (it closes the phase that was open and opens the next) and
+    :meth:`finish` as it builds the tick's flight record. While on, every
+    phase is also a ``jax.profiler.TraceAnnotation("tick:<phase>")``,
+    entered at its mark and left at the next: the profiler keeps it only
+    while a trace session runs, and then the engine's phases lie over the
+    device's operations on the profiler's own clock.
+
+    On means ``FLIGHT.enabled or TRACER.active``, latched per tick by
+    :meth:`begin` so that a tick is stamped whole or not at all. Off (the
+    default), :meth:`mark` and :meth:`finish` are one attribute check and
+    an early return: no clock read, no list, no dict — call them with
+    positional arguments only.
+    """
+
+    __slots__ = ("on", "_marks", "_open", "_annotation")
+
+    def __init__(self):
+        self.on = False
+        self._marks: Optional[List[List[Any]]] = None
+        self._open: Any = None            # the entered TraceAnnotation
+        self._annotation: Any = None      # jax.profiler.TraceAnnotation
+
+    def begin(self, now: float) -> None:
+        """Tick top: latch on/off; ``now`` (the tick's own
+        ``time.monotonic()`` stamp) opens ``ingest``."""
+        self.on = FLIGHT.enabled or TRACER.active
+        if not self.on:
+            return
+        if self._annotation is None:
+            # Not at import: the obs package must stay importable (and
+            # cheap) without JAX.
+            from jax.profiler import TraceAnnotation
+
+            self._annotation = TraceAnnotation
+        self._marks = []
+        self._enter("ingest", now, None, None, None)
+
+    def mark(self, name: str, tick: Optional[int] = None,
+             kind: Optional[str] = None, tq: Optional[int] = None) -> None:
+        """Close the open phase and open ``name``; a mark that names the
+        phase already open changes nothing. ``tick``/``kind``/``tq`` ride
+        on the profiler annotation (the ``dispatch`` mark passes them)."""
+        if not self.on:
+            return
+        if self._marks[-1][0] == name:
+            return
+        now = time.monotonic()
+        self._open.__exit__(None, None, None)
+        self._enter(name, now, tick, kind, tq)
+
+    def _enter(self, name, now, tick, kind, tq) -> None:
+        self._marks.append([name, now])
+        if kind is None:
+            self._open = self._annotation(_ANNOTATION[name])
+        else:
+            self._open = self._annotation(_ANNOTATION[name], tick=tick,
+                                          kind=kind, tq=tq)
+        self._open.__enter__()
+
+    def _leave(self) -> List[List[Any]]:
+        """Switch off until the next :meth:`begin`, leave the open
+        annotation, and hand back the tick's stamps."""
+        self.on = False
+        self._open.__exit__(None, None, None)
+        marks, self._open, self._marks = self._marks, None, None
+        return marks
+
+    def abandon(self) -> None:
+        """The iteration executed no tick (idle, fast-forward, an error):
+        leave the open annotation and forget the stamps."""
+        if self.on:
+            self._leave()
+
+    def finish(self, rec: Optional[Dict[str, Any]]) -> None:
+        """Tick end: stamp ``t_end``, close the last phase, write
+        ``phases`` (``[name, start]`` pairs, absolute ``time.monotonic()``
+        seconds) and ``t_end`` into the flight record being built (``None``
+        when the recorder is off) and, when the span tracer is active, one
+        complete ``tick:<phase>`` event a phase."""
+        if not self.on:
+            return
+        t_end = time.monotonic()
+        marks = self._leave()
+        if rec is not None:
+            rec["phases"] = marks
+            rec["t_end"] = t_end
+        if TRACER.active:
+            ends = [m[1] for m in marks[1:]] + [t_end]
+            for (name, start), end in zip(marks, ends):
+                # Whole nanoseconds first, then floored to microseconds
+                # as the tracer's spans are: the events nest in the
+                # tick's span to the digit.
+                ts = round(start * 1e9) // 1000
+                TRACER._emit_complete(_ANNOTATION[name], "serving", ts,
+                                      round(end * 1e9) // 1000 - ts, None)

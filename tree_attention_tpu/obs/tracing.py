@@ -3,7 +3,7 @@
 ``jax.profiler`` (``utils/profiling.trace``) captures what the *device* did;
 nothing captured where the *host* spent a run's wall clock — compile vs
 launch vs timing cycles vs checkpoint IO. This tracer fills that gap with
-explicit spans (context manager or decorator) emitted as Chrome trace
+explicit spans (a context manager) emitted as Chrome trace
 events, loadable in Perfetto / ``chrome://tracing`` alongside the device
 profile:
 
@@ -30,12 +30,11 @@ registry's disabled path.
 from __future__ import annotations
 
 import atexit
-import functools
 import json
 import os
 import threading
 import time
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 
 # -- W3C-traceparent-style request context -----------------------------------
@@ -232,15 +231,6 @@ class SpanTracer:
             **({"args": args} if args else {}),
         })
 
-    def counter_event(self, name: str, values: Dict[str, float]) -> None:
-        """Chrome counter track ("C") — a value series over the timeline."""
-        if not self.active:
-            return
-        self._emit({
-            "name": name, "ph": "C", "ts": time.monotonic_ns() // 1000,
-            "pid": self._pid, "tid": self._tid(), "args": values,
-        })
-
     def flow(self, phase: str, fid: int, name: str = "request",
              cat: str = "serving") -> None:
         """Chrome-trace flow event binding cross-process arrows.
@@ -323,24 +313,3 @@ def flow(phase: str, fid: int, name: str = "request",
     """Module-level shorthand for ``TRACER.flow``."""
     TRACER.flow(phase, fid, name, cat)
 
-
-def traced(name: Optional[str] = None, cat: str = "host") -> Callable:
-    """Decorator form: ``@traced()`` wraps the call in a span.
-
-    The wrapper costs one flag check when tracing is off — cheap enough for
-    per-call host functions, still not for per-element inner loops.
-    """
-
-    def deco(fn: Callable) -> Callable:
-        span_name = name or f"{fn.__module__.split('.')[-1]}.{fn.__qualname__}"
-
-        @functools.wraps(fn)
-        def wrapper(*a, **kw):
-            if not TRACER.active:
-                return fn(*a, **kw)
-            with _Span(TRACER, span_name, cat, None):
-                return fn(*a, **kw)
-
-        return wrapper
-
-    return deco

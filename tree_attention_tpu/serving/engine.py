@@ -138,7 +138,7 @@ from jax import lax
 from jax.sharding import Mesh
 
 from tree_attention_tpu import obs
-from tree_attention_tpu.obs.flight import FLIGHT
+from tree_attention_tpu.obs.flight import FLIGHT, TickPhases
 from tree_attention_tpu.obs.metrics import percentile
 from tree_attention_tpu.obs.slo import SLOMonitor
 from tree_attention_tpu.models.decode import (
@@ -3985,6 +3985,10 @@ class SlotServer:
         host0 = (self._host_pool.stats()
                  if self._host_pool is not None else None)
         t0 = time.monotonic()
+        # The tick from inside (obs/flight.py): one stamp per phase
+        # boundary, off unless the flight recorder or the span tracer is
+        # on. Marks sit BETWEEN the mirror[...] regions below.
+        phases = TickPhases()
 
         try:
             while True:
@@ -3993,7 +3997,11 @@ class SlotServer:
                         f"serve() exceeded max_ticks={max_ticks} with "
                         f"{len(pending)} pending request(s)"
                     )
+                # Created at the tick's top so that every phase nests in
+                # it; an iteration that executes no tick drops it unsaid.
+                tick_span = obs.span("serving:tick", cat="serving")
                 now = time.monotonic()
+                phases.begin(now)  # opens 'ingest'
                 self._tick_prefix_hits = 0
                 self._tick_prefix_reused = 0
                 self._tick_restored = 0
@@ -4036,6 +4044,7 @@ class SlotServer:
                 # its deadline also just expired); EOS/budget from the
                 # PREVIOUS tick already retired, so a request finishing
                 # and expiring on the same tick keeps its happy outcome.
+                phases.mark("sweep")
                 cancels, draining = self._take_control()
                 cancels |= set(cancel_carry)
                 if cancels:
@@ -4122,6 +4131,7 @@ class SlotServer:
                 # admission is pure bookkeeping (the chunks run inside the
                 # tick); the staged (quantized) variant holds one prompt in
                 # flight at a time, so admission waits for the stage.
+                phases.mark("admit")
                 free = self._free_slots()
                 while free and pending:
                     if self._staged_prefill and self._prefill_fifo:
@@ -4179,6 +4189,7 @@ class SlotServer:
                     # /healthz contract — an idle server is not a
                     # stalled one) and block briefly for submissions
                     # (wakes early on submit/close).
+                    phases.abandon()
                     if FLIGHT.enabled:
                         rec = None
                         # lint: mirror[sweep-only] begin
@@ -4219,6 +4230,7 @@ class SlotServer:
                 # only). While a tree family decodes, chunks clamp to
                 # the int32 tree-bitmask width — the sibling bundle
                 # must never be forced onto a Tq > 32 program.
+                phases.mark("plan")
                 plan = (self._plan_chunks(
                             max_n=32 if self._tree_fams else None)
                         if self.admission == "chunked" else [])
@@ -4236,21 +4248,28 @@ class SlotServer:
                         if f.forked
                     ))
 
-                # The per-tick mixed-step span: occupancy, chunk-budget
-                # spent, and queue depth tagged on the one program the
-                # tick dispatches (host_sync set before close).
-                tick_span = obs.span(
-                    "serving:tick", cat="serving",
-                    args=None if not obs.TRACER.active else {
-                        "tick": tick, "occupancy": len(live_idx),
-                        "prefilling": len(self._prefill_fifo),
-                        "chunk_tokens": chunk_tokens,
-                        "queue_depth": queue_depth,
-                    },
-                )
+                # The per-tick span: occupancy, chunk-budget spent, and
+                # queue depth tagged on the one program the tick
+                # dispatches (host_sync set before close). It closes after
+                # the flight record, so it covers the tick's every phase.
+                if obs.TRACER.active:
+                    tick_span.set(
+                        tick=tick, occupancy=len(live_idx),
+                        prefilling=len(self._prefill_fifo),
+                        chunk_tokens=chunk_tokens,
+                        queue_depth=queue_depth,
+                    )
                 with tick_span:
                     ran_staged = False
+                    # What the tick program ran as, for the flight record
+                    # and the profiler's tick:dispatch annotation.
+                    tick_kind = "awaits"
+                    tick_tq = 0
+                    n_vec = None
                     if self._staged_prefill and plan:
+                        # The stage programs pack and launch per chunk.
+                        phases.mark("pack")
+                        tick_kind = "staged"
                         for slot, n, last in plan:
                             self._run_staged_chunk(slot, n, last)
                         plan = []
@@ -4314,6 +4333,8 @@ class SlotServer:
                                 max(n for _, n, _ in plan)
                             ))
                         spec_width = tq
+                        phases.mark("pack")
+                        tick_kind, tick_tq = "verify", tq
                         mat = np.zeros((self.slots, tq), np.int32)
                         n_vec = np.zeros((self.slots,), np.int32)
                         reset = np.zeros((self.slots,), bool)
@@ -4405,7 +4426,9 @@ class SlotServer:
                             reset[slot] = first
                             reset_val[slot] = self._prefill_start[slot]
                             emit[slot] = last
+                        phases.mark("table_sync")
                         self._sync_table()
+                        phases.mark("dispatch", tick, tick_kind, tick_tq)
                         args = (
                             self.params, jnp.asarray(mat), self.tok,
                             jnp.asarray(use_dev0), jnp.asarray(n_vec),
@@ -4451,6 +4474,7 @@ class SlotServer:
                                 self.cache = self._spec_lin(
                                     *args, self.cache, *extra,
                                 )
+                        phases.mark("publish")
                         stepped = True
                         for slot, n, last in plan:
                             # Stash prompt-end logits for slots whose
@@ -4469,6 +4493,8 @@ class SlotServer:
                         # straight into each slot's region of the batch
                         # cache at its running offset.
                         tq = self._chunk_bucket(max(n for _, n, _ in plan))
+                        phases.mark("pack")
+                        tick_kind, tick_tq = "mixed", tq
                         mat = np.zeros((self.slots, tq), np.int32)
                         n_vec = np.zeros((self.slots,), np.int32)
                         reset = np.zeros((self.slots,), bool)
@@ -4504,7 +4530,9 @@ class SlotServer:
                         sidx = np.asarray(
                             [len(t) for t in self._slot_tokens], np.int32
                         )
+                        phases.mark("table_sync")
                         self._sync_table()
+                        phases.mark("dispatch", tick, tick_kind, tick_tq)
                         self.tok, self._lp, fused_dev, last_dev, \
                             self.cache = self._mixed(
                                 self.params, jnp.asarray(mat),
@@ -4515,6 +4543,7 @@ class SlotServer:
                                 jnp.asarray(self._topk_np),
                                 jnp.asarray(sidx), self._lp,
                             )
+                        phases.mark("publish")
                         stepped = True
                         for slot, n, last in plan:
                             # Stash prompt-end logits for slots whose
@@ -4535,6 +4564,10 @@ class SlotServer:
                         # bucket, tokens carried on device (awaiting slots
                         # hold their parked first token through n=0 /
                         # emit=False).
+                        phases.mark("pack")
+                        if not ran_staged:
+                            tick_kind = "decode"
+                        tick_tq = 1
                         n_vec = np.zeros((self.slots,), np.int32)
                         emit = np.zeros((self.slots,), bool)
                         reset = np.zeros((self.slots,), bool)
@@ -4556,7 +4589,9 @@ class SlotServer:
                         sidx = np.asarray(
                             [len(t) for t in self._slot_tokens], np.int32
                         )
+                        phases.mark("table_sync")
                         self._sync_table()
+                        phases.mark("dispatch", tick, tick_kind, tick_tq)
                         self.tok, self._lp, fused_dev, _, \
                             self.cache = self._mixed(
                                 self.params, self.tok[:, None],
@@ -4568,6 +4603,7 @@ class SlotServer:
                                 jnp.asarray(self._topk_np),
                                 jnp.asarray(sidx), self._lp,
                             )
+                        phases.mark("publish")
                         stepped = True
 
                     awaits = [i for i, st in enumerate(self._slot_state)
@@ -4589,6 +4625,7 @@ class SlotServer:
                         # live inside this block. A verify tick fetches
                         # its fused (S, 1+Tq) output instead: the token
                         # vector AND every row argmax in the same sync.
+                        phases.mark("fetch")
                         lp_valid = False
                         if all_tok_dev is not None:
                             # lint: allow[host-sync] THE one per-tick fetch (verify ticks: token/logprob vectors + every row draw, one fused array)
@@ -4619,6 +4656,7 @@ class SlotServer:
                             # lint: allow[host-sync] rides the same sync point (the parked first-token logprobs)
                             self._lp_host = np.asarray(self._lp)
                             lp_valid = True
+                        phases.mark("emit")
                         now2 = time.monotonic()
                         if live_idx:
                             decode_ticks += 1
@@ -4764,89 +4802,104 @@ class SlotServer:
                         tick_span.set(host_sync=host_sync,
                                       tokens=tokens_this_tick)
 
-                if self._paged:
-                    if self._pool.used > self._peak_blocks_used:
-                        self._peak_blocks_used = self._pool.used
-                    self._pool.publish_gauges()  # registry-guarded inside
-                if self._host_pool is not None:
-                    # The staged D2H flush point: demotions this tick's
-                    # evictions enqueued complete as ONE batched gather,
-                    # after the tick's dispatches (the fetch overlaps
-                    # where the loop would otherwise idle toward the
-                    # next tick's host work).
-                    self._flush_demotions()
-                    self._host_pool.publish_gauge()  # registry-guarded
-
-                # The flight recorder's per-tick record (the black box a
-                # post-mortem replays); record dict built only when armed.
-                if FLIGHT.enabled:
-                    rec = {
-                        "tick": tick,
-                        "t_s": round(now - t0, 6),
-                        "occupancy": len(live_idx),
-                        "states": list(self._slot_state),
-                        "lengths": [self._prefill_pos[i]
-                                    if self._slot_state[i] == "prefill"
-                                    else len(self._slot_tokens[i])
-                                    for i in range(self.slots)],
-                        "chunk_plan": [[s, int(n), bool(last)]
-                                       for s, n, last in plan_rec],
-                        "chunk_tokens": chunk_tokens,
-                        "tokens_emitted": tokens_this_tick,
-                        "host_sync": host_sync,
-                        "queue_depth": queue_depth,
-                        "pending": len(pending),
-                        "prefix_hits": self._tick_prefix_hits,
-                        "prefix_reused": self._tick_prefix_reused,
-                        # Robustness arcs this tick (ISSUE 10): the
-                        # black box must show a storm the way it showed
-                        # a wedge.
-                        "cancelled": self._tick_cancelled,
-                        "deadline_expired": self._tick_deadline,
-                        "shed": self._tick_shed,
-                        # Copy-on-write forks this tick (ISSUE 15) and
-                        # the ancestor blocks they shared instead of
-                        # copying.
-                        "forks": self._tick_forks,
-                        "shared_blocks": self._tick_fork_shared,
-                        # Token-tree sibling decode this tick (ISSUE
-                        # 20): branches advanced in-slot, branches
-                        # retired out of their bundles.
-                        "tree_branches": self._tick_tree_branches,
-                        "branch_retired": self._tick_branch_retired,
-                        "draining": draining,
-                    }
+                    phases.mark("account")
                     if self._paged:
-                        # Block occupancy + internal fragmentation (the
-                        # fraction of mapped block capacity no written
-                        # token occupies) — the paged black-box truths.
-                        mapped = sum(self._slot_nblocks)
-                        written = 0
-                        for i in range(self.slots):
-                            st = self._slot_state[i]
-                            if st == "prefill":
-                                written += self._prefill_pos[i]
-                            elif st in ("await", "live"):
-                                written += (
-                                    len(self._slot_req[i].prompt)
-                                    + max(len(self._slot_tokens[i]) - 1, 0)
-                                )
-                        rec["kv_blocks_used"] = self._pool.used
-                        rec["kv_blocks_free"] = self._pool.free_count
-                        rec["kv_frag"] = round(
-                            1.0 - written / (mapped * self.kv_block), 4
-                        ) if mapped else 0.0
-                        if self._host_pool is not None:
-                            rec["host_blocks_used"] = self._host_pool.used
-                            rec["restored_blocks"] = self._tick_restored
-                    if self._speculate:
-                        s_slots, s_prop, s_acc = self._tick_spec
-                        rec["spec_verify"] = {
-                            "slots": s_slots,
-                            "proposed": s_prop,
-                            "accepted": s_acc,
+                        if self._pool.used > self._peak_blocks_used:
+                            self._peak_blocks_used = self._pool.used
+                        self._pool.publish_gauges()  # registry-guarded inside
+                    if self._host_pool is not None:
+                        # The staged D2H flush point: demotions this tick's
+                        # evictions enqueued complete as ONE batched gather,
+                        # after the tick's dispatches (the fetch overlaps
+                        # where the loop would otherwise idle toward the
+                        # next tick's host work).
+                        self._flush_demotions()
+                        self._host_pool.publish_gauge()  # registry-guarded
+
+                    # The flight recorder's per-tick record (the black box a
+                    # post-mortem replays); record dict built only when armed.
+                    if FLIGHT.enabled:
+                        rec = {
+                            "tick": tick,
+                            "t_s": round(now - t0, 6),
+                            # What the tick program ran as: its kind, the
+                            # Tq bucket (0: nothing dispatched; a staged
+                            # tick's is its decode program's), the rows
+                            # it computed and those that carried a token.
+                            "kind": tick_kind,
+                            "tq": tick_tq,
+                            "rows_computed": self.slots * tick_tq,
+                            "rows_useful": (0 if n_vec is None
+                                            else int(n_vec.sum())),
+                            "occupancy": len(live_idx),
+                            "states": list(self._slot_state),
+                            "lengths": [self._prefill_pos[i]
+                                        if self._slot_state[i] == "prefill"
+                                        else len(self._slot_tokens[i])
+                                        for i in range(self.slots)],
+                            "chunk_plan": [[s, int(n), bool(last)]
+                                           for s, n, last in plan_rec],
+                            "chunk_tokens": chunk_tokens,
+                            "tokens_emitted": tokens_this_tick,
+                            "host_sync": host_sync,
+                            "queue_depth": queue_depth,
+                            "pending": len(pending),
+                            "prefix_hits": self._tick_prefix_hits,
+                            "prefix_reused": self._tick_prefix_reused,
+                            # Robustness arcs this tick (ISSUE 10): the
+                            # black box must show a storm the way it showed
+                            # a wedge.
+                            "cancelled": self._tick_cancelled,
+                            "deadline_expired": self._tick_deadline,
+                            "shed": self._tick_shed,
+                            # Copy-on-write forks this tick (ISSUE 15) and
+                            # the ancestor blocks they shared instead of
+                            # copying.
+                            "forks": self._tick_forks,
+                            "shared_blocks": self._tick_fork_shared,
+                            # Token-tree sibling decode this tick (ISSUE
+                            # 20): branches advanced in-slot, branches
+                            # retired out of their bundles.
+                            "tree_branches": self._tick_tree_branches,
+                            "branch_retired": self._tick_branch_retired,
+                            "draining": draining,
                         }
-                    FLIGHT.record(rec)
+                        if self._paged:
+                            # Block occupancy + internal fragmentation (the
+                            # fraction of mapped block capacity no written
+                            # token occupies) — the paged black-box truths.
+                            mapped = sum(self._slot_nblocks)
+                            written = 0
+                            for i in range(self.slots):
+                                st = self._slot_state[i]
+                                if st == "prefill":
+                                    written += self._prefill_pos[i]
+                                elif st in ("await", "live"):
+                                    written += (
+                                        len(self._slot_req[i].prompt)
+                                        + max(len(self._slot_tokens[i]) - 1, 0)
+                                    )
+                            rec["kv_blocks_used"] = self._pool.used
+                            rec["kv_blocks_free"] = self._pool.free_count
+                            rec["kv_frag"] = round(
+                                1.0 - written / (mapped * self.kv_block), 4
+                            ) if mapped else 0.0
+                            if self._host_pool is not None:
+                                rec["host_blocks_used"] = self._host_pool.used
+                                rec["restored_blocks"] = self._tick_restored
+                        if self._speculate:
+                            s_slots, s_prop, s_acc = self._tick_spec
+                            rec["spec_verify"] = {
+                                "slots": s_slots,
+                                "proposed": s_prop,
+                                "accepted": s_acc,
+                            }
+                        # finish() stamps t_end as the record is built
+                        # and puts the tick's phases into it.
+                        phases.finish(rec)
+                        FLIGHT.record(rec)
+                    else:
+                        phases.finish(None)  # the span trace alone
                 self.slo.maybe_export(now)
 
                 # Every executed tick advances the clock by exactly one;
@@ -4857,6 +4910,7 @@ class SlotServer:
         except BaseException as e:
             # The black-box contract: a wedged/crashed tick loop leaves
             # its last ticks on disk before the exception propagates.
+            phases.abandon()
             FLIGHT.dump_if_armed(f"engine_error:{type(e).__name__}")
             if obs.TRACER.active:
                 obs.instant("engine_error", cat="serving", args={
