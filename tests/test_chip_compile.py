@@ -12,7 +12,10 @@ from ``python chip_smoke.py`` on the chip.
 """
 
 import functools
+import json
+import math
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
@@ -171,3 +174,133 @@ def test_kernel_compiles_for_v5e(case):
     text = _compiled_text(builder)
     assert "tpu_custom_call" in text
     assert kernel in pallas_kernels(text), pallas_kernels(text)
+
+
+# -- the step the tick programs run: the KV pool stays in place (ISSUE 25) ---
+#
+# ``forward_step`` carries the paged pool through the layer loop and writes
+# each layer's rows into it in place; the compiled tick must hold no slice,
+# layout copy, restack or whole-pool copy of it. The parent of that change
+# held about five passes over the pool a tick (PERF.md section 6, PR 25), all
+# of them XLA's own choices that no CPU test can see. Each case compiles the
+# step as the engine's tick programs call it (``n_tokens``, cache donated) at
+# one configuration's widths and reads the optimized HLO.
+
+# Compiled at the cells' own depth (the loop compiles once whatever it is): a
+# pool that fits the chip's fast memory is staged there whole by the compiler
+# (3 layers of Yi's int8 pool, 51 MB, were: slice-start/done into S(1)), which
+# no cell's pool does.
+STEP_CONFIGS = ("yi-6b", "mistral-7b-v0.3")  # benchmark/configs/<name>.json
+# Results that only name a buffer (or hand the loop's state round) and move
+# no byte of it.
+_MOVES_NOTHING = {"parameter", "bitcast", "get-tuple-element", "tuple",
+                  "while"}
+_INSTR = re.compile(r"^\s+(?:ROOT )?(%[\w.\-]+) = (.*?)\s([a-z][a-z0-9\-]*)\(")
+
+
+def _materialised(text):
+    """(name, result type, opcode, body of the computation it calls) of every
+    instruction of the module that is not inside a fused computation: what
+    gets a buffer of its own."""
+    fused = set(re.findall(r"calls=(%[\w.\-]+)", text))
+    comps, name = {}, None
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            name = line.split()[1 if line.startswith("ENTRY") else 0]
+            comps[name] = []
+        elif name is not None and line.startswith(" "):
+            comps[name].append(line)
+    for comp, lines in comps.items():
+        if comp in fused:
+            continue
+        for line in lines:
+            m = _INSTR.match(line)
+            if m:
+                called = re.search(r"calls=(%[\w.\-]+)", line)
+                inner = "\n".join(comps.get(called.group(1), ())) \
+                    if called else ""
+                yield m.group(1), m.group(2), m.group(3), inner
+
+
+def _compile_step(monkeypatch, config, tq, int8):
+    from tree_attention_tpu.models import decode
+    from tree_attention_tpu.models.transformer import (
+        TransformerConfig, init_params)
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                           "configs", f"{config}.json")) as f:
+        c = json.load(f)
+    hkv, serving = c["num_key_value_heads"], c["serving"]
+    cfg = TransformerConfig(
+        vocab_size=c["vocab_size"], d_model=c["hidden_size"],
+        n_heads=c["num_attention_heads"], n_kv_heads=hkv,
+        d_head=c["assumed"]["head_dim"], d_ff=c["intermediate_size"],
+        n_layers=c["num_hidden_layers"], dtype=jnp.bfloat16)
+    blk = serving["kv_block"]
+    slots, nb = serving["slots"], serving["cache_len"] // blk
+    # A spare block a slot: the logical view (slots x nb blocks, which the
+    # Q-tiled path gathers) is then smaller than one layer of the pool, and
+    # an array of either size says which of the two it is.
+    blocks = slots * (nb + 1)
+    chip = lambda tree: jax.tree.map(
+        lambda a: _s(a.shape, a.dtype), tree)
+    params = chip(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    cache = chip(jax.eval_shape(lambda: decode.init_paged_cache(
+        cfg, slots, serving["cache_len"], blocks, block=blk, quantize=int8)))
+
+    def step(params, tokens, cache, n_tokens):
+        return decode.forward_step(params, tokens, cache, cfg,
+                                   n_tokens=n_tokens)
+
+    # forward_step asks the default backend whether the kernels apply; the
+    # backend here is the CPU, the target the described chip.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = jax.jit(step, donate_argnums=(2,)).lower(
+        params, _s((slots, tq), jnp.int32), cache, _s((slots,), jnp.int32)
+    ).compile()
+    layer = blocks * hkv * blk * cfg.d_head
+    view = slots * nb * hkv * blk * cfg.d_head
+    return compiled, layer, view, cfg.n_layers
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("tq", [1, 256])
+@pytest.mark.parametrize("config", STEP_CONFIGS)
+def test_step_keeps_the_pool_in_place(monkeypatch, config, tq, int8):
+    if _chip() is None:
+        pytest.skip("the v5e:2x2 topology cannot be described here")
+    compiled, layer, view, layers = _compile_step(monkeypatch, config, tq, int8)
+    text = compiled.as_text()
+    # The kernels (flash_decode_paged*, or flash_fwd for a bf16 chunk), not
+    # the hoisted view of the CPU's runs.
+    assert pallas_kernels(text), "no Pallas kernel in the compiled step"
+
+    dtype = "s8" if int8 else "bf16"
+    writes, views, moved = [], [], []
+    for name, result, opcode, inner in _materialised(text):
+        sizes = [math.prod(int(d) for d in dims.split(","))
+                 for dims in re.findall(rf"\b{dtype}\[([\d,]+),{D}\]", result)]
+        if not sizes or max(sizes) * D < view or opcode in _MOVES_NOTHING:
+            continue
+        if opcode == "scatter" or " scatter(" in inner:
+            writes.append(name)       # _paged_pool_write, in place (below)
+        elif max(sizes) * D == view:
+            views.append((name, opcode, result))
+        else:
+            moved.append((name, opcode, result))
+    assert not moved, moved
+    assert len(writes) == 2, writes  # K's and V's, once in the loop's body
+    # The logical view of a slot's blocks, gathered per layer for the
+    # Q-tiled prefill kernel of a chunk tick: ROADMAP queue 1 item 3 (the
+    # mixed tick), not the pool. A decode tick holds none.
+    assert all(op in ("fusion", "copy") for _, op, _ in views), views
+    if tq == 1:
+        assert not views, views
+    # In place: the donated K and V pools are the output's buffers, and a
+    # decode tick needs no scratch as large as a layer of the pool.
+    mem = compiled.memory_analysis()
+    pool_bytes = layers * layer * (1 if int8 else 2)
+    assert mem.alias_size_in_bytes >= 2 * pool_bytes, mem
+    if tq == 1:
+        assert mem.temp_size_in_bytes < layer * (1 if int8 else 2), mem

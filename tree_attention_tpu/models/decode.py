@@ -126,6 +126,13 @@ class PagedKVCache:
     valid pool index (0): the causal mask hides every position past
     ``length[i]``, so a garbage block is never *visible*, but the gather
     and the Pallas index maps still dereference it.
+
+    :func:`forward_step` never slices a replicated pool by layer: it
+    carries ``k``/``v`` whole through the layer loop and reaches layer
+    ``l`` by offset, ``table + l·N`` into the ``(L·N, Hkv, block, D)``
+    view — a bitcast, so the pool that enters a tick, the kernels'
+    operand and the pool that leaves are one buffer (see
+    :func:`_paged_pool_write` for what that asks of the write).
     """
 
     k: jax.Array       # (L, N, Hkv, block, D) pool
@@ -568,37 +575,85 @@ def _paged_pool_write(
     table: jax.Array,
     start: jax.Array,
     n: jax.Array,
+    layer: Union[int, jax.Array],
 ) -> jax.Array:
-    """Scatter each slot's new token rows through its block table.
+    """Write each slot's new token rows into layer ``layer`` of the pool.
 
-    One layer's piece of the paged mixed-Tq step: ``pool`` is
-    ``(N, Hkv, block, D)``, ``rows`` ``(B, Hkv, Tq, D)``, ``start``/``n``
-    per-slot ``(B,)`` vectors. Token ``j`` of slot ``i`` (valid iff
-    ``j < n[i]``) lands at physical block ``table[i, (start[i]+j)//block]``
-    row ``(start[i]+j) % block``; invalid rows scatter to index ``N`` and
-    DROP, so the paged write needs none of the contiguous path's
-    clamp-and-shift machinery — ragged and near-capacity cases fall out
-    of the drop semantics. Distinct slots never share a *writable* block
-    (shared prefix blocks sit below ``start``), so indices never collide.
+    The paged mixed-Tq step's one write: ``pool`` is the WHOLE pool
+    ``(L, N, Hkv, block, D)``, ``rows`` one layer's ``(B, Hkv, Tq, D)``,
+    ``start``/``n`` per-slot ``(B,)`` vectors. Token ``j`` of slot ``i``
+    (valid iff ``j < n[i]``) lands at physical block
+    ``table[i, (start[i]+j)//block]`` row ``(start[i]+j) % block`` of that
+    layer; invalid rows DROP, so the paged write needs none of the
+    contiguous path's clamp-and-shift machinery — ragged and near-capacity
+    cases fall out of the drop semantics.
+
+    It moves whole BLOCKS: the ``Tq`` rows of a slot touch at most
+    ``(Tq + block - 2)//block + 1`` consecutive logical blocks; each is
+    read, overlaid with the rows that fall in it, and scattered back to
+    ``layer·N + pb`` of the pool viewed as ``(L·N, Hkv, block, D)`` — a
+    bitcast. A block no valid row falls in (an idle slot, padding, past
+    capacity, a table entry outside the pool) is sent to index ``L·N``
+    and dropped. Distinct slots never share a *writable* block (shared
+    prefix blocks sit below ``start``, and only whole blocks are shared),
+    so no two entries name one block and a block's other rows come back
+    as they were.
+
+    Why it is shaped as it is (ISSUE 25; the compiled tick is the
+    criterion, ``tests/test_chip_compile.py``):
+
+    - Indexing the pool's block and row dims apart (``pool.at[pb, :, off]``,
+      the parent's write) made XLA's TPU compiler hold the pool in the
+      scatter's preferred layout (block, row, head, D) while the Pallas
+      kernels pin the default one: it copied the pool between the two in
+      every layer and tick. A scatter that indexes the major dim alone
+      wants no other layout, so the entry pool, the loop's carry, the
+      kernels' operand and the output stay ONE buffer. (Not the view
+      ``(L·N, Hkv·block·D)``: under the (8, 128) tiling that is no
+      bitcast, and XLA rebuilt the pool round it.)
+    - One ``D``-row per (slot, head, token), into ``(L·N·Hkv·block, D)``,
+      compiles as clean but costs ~70 ns a row on the chip, dropped rows
+      included: 18 ms of Yi-6B's mixed tick and 73 ms of Mistral-7B's
+      (PERF.md section 6, PR 25). A block is 64-128 KB and a chunk tick
+      moves 5 a slot.
+    - The drop index lies past the WHOLE flat pool: the old per-layer
+      sentinel ``N`` is block 0 of layer 1 there.
     """
-    N, _, block, _ = pool.shape
-    B, Hkv, Tq, D = rows.shape
-    pos = start[:, None] + jnp.arange(Tq, dtype=jnp.int32)[None, :]  # (B, Tq)
-    lb = jnp.clip(pos // block, 0, table.shape[1] - 1)
-    pb = jnp.take_along_axis(table, lb, axis=1)
-    valid = (
-        (jnp.arange(Tq, dtype=jnp.int32)[None, :] < n[:, None])
+    L, N, Hkv, block, D = pool.shape
+    B, _, Tq, _ = rows.shape
+    NB = table.shape[1]
+    nblk = (Tq + block - 2) // block + 1  # blocks Tq consecutive rows touch
+    lb = (start // block)[:, None] + jnp.arange(nblk, dtype=jnp.int32)
+    pb = jnp.take_along_axis(table, jnp.clip(lb, 0, NB - 1), axis=1)
+    # Token position of every row of every touched block, and the new row
+    # (if any) that belongs there.
+    pos = lb[:, :, None] * block + jnp.arange(block, dtype=jnp.int32)
+    j = pos - start[:, None, None]  # (B, nblk, block)
+    take = (
+        (j >= 0) & (j < n[:, None, None])
         # Over-capacity safety: the contiguous path RAISES on overflow
         # eagerly; under jit this mask keeps a buggy caller's overflow
         # from landing in another slot's pool block through the clipped
         # table index above.
-        & (pos < table.shape[1] * block)
+        & (pos < NB * block)
     )
-    pb = jnp.where(valid, pb, N)  # OOB -> dropped
-    flat = jnp.moveaxis(rows, 2, 1).reshape(B * Tq, Hkv, D)
-    return pool.at[pb.reshape(-1), :, (pos % block).reshape(-1), :].set(
-        flat.astype(pool.dtype), mode="drop"
+    # A table entry outside the pool would land in another LAYER.
+    live = take.any(axis=-1) & (pb >= 0) & (pb < N)
+    flat = pool.reshape(L * N, Hkv, block, D)
+    old = flat[layer * N + jnp.clip(pb, 0, N - 1)]  # (B, nblk, Hkv, block, D)
+    new = jnp.take_along_axis(
+        rows.astype(pool.dtype),
+        jnp.clip(j, 0, Tq - 1).reshape(B, 1, nblk * block, 1),
+        axis=2,
+    ).reshape(B, Hkv, nblk, block, D)
+    merged = jnp.where(
+        take[:, :, None, :, None], jnp.swapaxes(new, 1, 2), old
     )
+    idx = jnp.where(live, layer * N + pb, L * N)
+    flat = flat.at[idx.reshape(-1)].set(
+        merged.reshape(B * nblk, Hkv, block, D), mode="drop"
+    )
+    return flat.reshape(pool.shape)
 
 
 def _paged_pool_write_seq(
@@ -614,12 +669,14 @@ def _paged_pool_write_seq(
     """:func:`_paged_pool_write` over a sequence-SHARDED pool (ISSUE 18).
 
     ``pool`` is one layer's ``(N, Hkv, block, D)`` slice sharded on the
-    block axis over ``seq_axis``; the (replicated) ``table`` carries
-    GLOBAL block ids. Under ``shard_map`` each shard rebases the table to
-    its own id range ``[s·N/W, (s+1)·N/W)`` and points every entry it
-    does NOT own at its local ``N/W`` sentinel — which is exactly
-    :func:`_paged_pool_write`'s OOB→drop index, so the local scatter
-    writes precisely the rows whose blocks live here and drops the rest.
+    block axis over ``seq_axis`` (a flat ``(L·N)`` view would cut that
+    axis by layers, so this pool is not carried whole: the layer loop
+    slices it, see :func:`forward_step`); the (replicated) ``table``
+    carries GLOBAL block ids. Under ``shard_map`` each shard rebases the
+    table to its own id range ``[s·N/W, (s+1)·N/W)`` and points every
+    entry it does NOT own at ``N/W``, outside its local pool — which
+    :func:`_paged_pool_write` drops, so the local scatter writes
+    precisely the rows whose blocks live here and drops the rest.
     No collectives: a block is owned by exactly one shard, so the union
     of the local writes IS the replicated write, bit for bit.
     """
@@ -630,7 +687,7 @@ def _paged_pool_write_seq(
         s = lax.axis_index(seq_axis)
         loc = table_l - s * n_local
         loc = jnp.where((loc >= 0) & (loc < n_local), loc, n_local)
-        return _paged_pool_write(pool_l, rows_l, loc, start_l, n_l)
+        return _paged_pool_write(pool_l[None], rows_l, loc, start_l, n_l, 0)[0]
 
     return shard_map(
         body,
@@ -806,6 +863,17 @@ def forward_step(
         causal masking bit-for-bit. Not supported on the sequence-sharded
         contiguous tree-decode path (the paged pool is replicated, so
         paged serving under a mesh takes the flash paths and works).
+
+    A replicated paged pool (:class:`PagedKVCache`,
+    :class:`PagedQuantKVCache`) rides the layer loop as loop-carried
+    state: each layer's rows are written into the whole pool in place
+    (:func:`_paged_pool_write`) and attention — the block-table kernels
+    on TPU, the hoisted reference view elsewhere — addresses layer ``l``
+    through ``table + l·N``. Nothing slices, restacks or copies the pool,
+    and the compiled tick must stay so
+    (``tests/test_chip_compile.py::test_step_keeps_the_pool_in_place``).
+    The sequence-sharded pool and the contiguous caches are scanned
+    per layer as ``xs``/``ys``.
 
     Returns:
       ``logits``: ``(B, Tq, vocab)`` float32; the updated cache
@@ -991,19 +1059,39 @@ def forward_step(
             & (pos_all % blk_sz == 0)
             & (pos_all < NBt * blk_sz)
         )
-        scale_tgt = jnp.where(
-            entered, write_pb, cache.blocks
-        ).reshape(-1)  # invalid rows scatter OOB and drop
 
-    def body(x, layer_and_cache):
-        parts = list(layer_and_cache)
-        layer, k_cache, v_cache = parts[:3]
-        parts = parts[3:]
+    # A replicated paged pool rides the layer loop WHOLE, as loop-carried
+    # state, and layer l is addressed by offset — ``table + l·N`` into the
+    # ``(L·N, Hkv, block, D)`` view — never by slicing the pool (ISSUE 25).
+    # As scanned ``xs``/``ys`` the compiled tick copied each layer's pool
+    # out, into the write's layout and back, into a fresh stacked buffer,
+    # and that whole buffer into the donated output: about five passes
+    # over the pool, for K and for V, to append one row a slot. The
+    # sequence-sharded pool still takes that route (its block axis is the
+    # sharded one; a flat view would cut it by layers), as do the
+    # contiguous caches.
+    carried = paged and not seq_sharded
+
+    def body(carry, xs):
+        parts = list(xs)
+        layer = parts.pop(0)
+        base = 0  # layer l's first block in the flat pool
+        k_s = v_s = None
+        if carried:
+            x, k_cache, v_cache = carry[:3]
+            if quant:
+                k_s, v_s = carry[3:]
+            l = parts.pop(0)
+            base = l * cache.blocks
+        else:
+            x = carry
+            k_cache, v_cache = parts[:2]
+            parts = parts[2:]
         k_view = v_view = None
         if hoist_view:
             k_view, v_view = parts[:2]
             parts = parts[2:]
-        if quant:
+        if quant and not carried:
             k_s, v_s = parts
         h = rms_norm(x, layer["ln1"], cfg.norm_eps)
         q = _heads(h @ layer["wq"], cfg.n_heads, cfg.d_head)
@@ -1020,18 +1108,27 @@ def forward_step(
         # (paged; entered blocks inherit it, see above).
         k_deq = v_deq = None
         if quant and paged:
-            k_anchor = k_s[anchor_pb][:, :, None, None]  # (B, Hkv, 1, 1)
-            v_anchor = v_s[anchor_pb][:, :, None, None]
+            # The scales as (blocks, Hkv) rows: every layer's when carried
+            # (a bitcast), this layer's (base 0) when scanned.
+            hkv = k_s.shape[-1]
+            k_sf, v_sf = k_s.reshape(-1, hkv), v_s.reshape(-1, hkv)
+            k_anchor = k_sf[base + anchor_pb][:, :, None, None]
+            v_anchor = v_sf[base + anchor_pb][:, :, None, None]  # (B,Hkv,1,1)
             k_new = _quantize_rows(k_new, k_anchor)
             v_new = _quantize_rows(v_new, v_anchor)
             vals_k = jnp.broadcast_to(
-                k_anchor[:, None, :, 0, 0], (B, Tq, k_s.shape[1])
-            ).reshape(-1, k_s.shape[1])
+                k_anchor[:, None, :, 0, 0], (B, Tq, hkv)
+            ).reshape(-1, hkv)
             vals_v = jnp.broadcast_to(
-                v_anchor[:, None, :, 0, 0], (B, Tq, v_s.shape[1])
-            ).reshape(-1, v_s.shape[1])
-            k_s = k_s.at[scale_tgt].set(vals_k, mode="drop")
-            v_s = v_s.at[scale_tgt].set(vals_v, mode="drop")
+                v_anchor[:, None, :, 0, 0], (B, Tq, hkv)
+            ).reshape(-1, hkv)
+            # Rows that enter no block scatter past every layer and drop.
+            scale_tgt = jnp.where(
+                entered, base + write_pb, k_sf.shape[0]
+            ).reshape(-1)
+            k_sf = k_sf.at[scale_tgt].set(vals_k, mode="drop")
+            v_sf = v_sf.at[scale_tgt].set(vals_v, mode="drop")
+            k_s, v_s = k_sf.reshape(k_s.shape), v_sf.reshape(v_s.shape)
             if hoist_view:
                 # The view holds DEQUANTIZED rows: mirror exactly what
                 # the pool now holds (quantize-then-dequantize), so
@@ -1065,10 +1162,10 @@ def forward_step(
                 )
             else:
                 k_cache = _paged_pool_write(
-                    k_cache, k_new, cache.table, start, n_valid
+                    k_cache, k_new, cache.table, start, n_valid, l
                 )
                 v_cache = _paged_pool_write(
-                    v_cache, v_new, cache.table, start, n_valid
+                    v_cache, v_new, cache.table, start, n_valid, l
                 )
             if hoist_view:
                 # Mirror the new rows into the hoisted logical view (the
@@ -1117,14 +1214,24 @@ def forward_step(
             block_size=cfg.attn_block_size,
             tree_mask=tree_mask,
         )
-        if paged and not hoist_view:
+        ak, av, ak_s, av_s = k_cache, v_cache, k_s, v_s
+        if hoist_view:
+            ak, av = k_view, v_view
+        elif carried:
+            # The kernels and the reference gather take a pool and a
+            # table: hand them every layer's blocks (a bitcast of the
+            # carry) and this layer's addresses.
+            ak = k_cache.reshape((-1,) + k_cache.shape[2:])
+            av = v_cache.reshape((-1,) + v_cache.shape[2:])
+            attn_kw["block_table"] = base + cache.table
+            if quant:
+                ak_s, av_s = k_sf, v_sf
+        elif paged:
             attn_kw["block_table"] = cache.table
-            if seq_sharded:
-                attn_kw["kv_shard"] = "seq"
-        ak, av = (k_view, v_view) if hoist_view else (k_cache, v_cache)
+            attn_kw["kv_shard"] = "seq"
         if quant and not (paged and hoist_view):
             out, _ = decode_attention(
-                q, ak, av, k_scale=k_s, v_scale=v_s,
+                q, ak, av, k_scale=ak_s, v_scale=av_s,
                 quant_kernel=quant_kernel, **attn_kw,
             )
         else:
@@ -1136,17 +1243,29 @@ def forward_step(
             )
         x = x + _unheads(out) @ layer["wo"]
         x = x + _mlp_block(layer, rms_norm(x, layer["ln2"], cfg.norm_eps))
-        ys = (k_cache, v_cache)
+        new = (k_cache, v_cache)
         if paged and quant:
-            ys = ys + (k_s, v_s)  # entered blocks' inherited scales
-        return x, ys
+            new = new + (k_s, v_s)  # entered blocks' inherited scales
+        return ((x,) + new, None) if carried else (x, new)
 
-    xs = (params["layers"], cache.k, cache.v)
+    xs = (params["layers"],)
+    init = x
+    if carried:
+        init = (x, cache.k, cache.v)
+        if quant:
+            init = init + (cache.k_scale, cache.v_scale)
+        xs = xs + (jnp.arange(cache.k.shape[0], dtype=jnp.int32),)
+    else:
+        xs = xs + (cache.k, cache.v)
     if hoist_view:
         xs = xs + (k_view0, v_view0)
-    if quant:
+    if quant and not carried:
         xs = xs + (cache.k_scale, cache.v_scale)
-    x, scanned = lax.scan(body, x, xs)
+    out_carry, scanned = lax.scan(body, init, xs)
+    if carried:
+        x, scanned = out_carry[0], out_carry[1:]
+    else:
+        x = out_carry
     new_k, new_v = scanned[0], scanned[1]
     x = rms_norm(x, params["ln_f"], cfg.norm_eps)
     logits = (x @ params["wout"]).astype(jnp.float32)
