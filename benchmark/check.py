@@ -2,9 +2,10 @@
 
 After the window has closed and the engine is freed, a sample of the
 requests the window finished, drawn from the seed with the longest in it, is
-run once each through :mod:`benchmark.reference`. For every served token the
-gap by which its reference logit lies below the reference's best is read;
-the widest and the mean of those gaps are each held to a limit the
+run once each through the plain reference of the configuration's family
+(``references/<family>.py``, handed in as ``ref``). For every served token
+the gap by which its reference logit lies below the reference's best is
+read; the widest and the mean of those gaps are each held to a limit the
 configuration file states (set from chip readings, see PERF.md). Exact
 counts (full lengths, no leaked blocks, no compile in the window) have the
 limit 0.
@@ -12,11 +13,9 @@ limit 0.
 
 from __future__ import annotations
 
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
-
-from benchmark import reference
 
 
 def sample(recs, seed: int, min_tokens: int, max_requests: int) -> List[Any]:
@@ -40,12 +39,32 @@ def sample(recs, seed: int, min_tokens: int, max_requests: int) -> List[Any]:
     return picked
 
 
-def gap_numbers(config: Dict[str, Any], picked, weights: Dict[str, Any],
+def served_gaps(ref, weights: Dict[str, Any], w, prompt: np.ndarray,
+                served: np.ndarray, *, control: Optional[str] = None
+                ) -> Tuple[np.ndarray, np.ndarray]:
+    """For each served token, how far its reference logit lies below the
+    reference's best at that position, in one pass over the prompt and the
+    served tokens. With ``control`` the token judged at each position is not
+    the served one but the one the lower precision puts first there.
+    Returns ``(gaps, judged tokens)``."""
+    n = len(served)
+    tokens = np.concatenate([np.asarray(prompt), np.asarray(served[:-1])])
+    rows = np.arange(len(prompt) - 1, len(prompt) - 1 + n)
+    logits = ref.logits_at(weights, w, tokens, rows)
+    judged = np.asarray(served)
+    if control is not None:
+        judged = ref.logits_at(weights, w, tokens, rows,
+                               quant=control).argmax(-1)
+    gaps = logits.max(-1) - logits[np.arange(n), judged]
+    return gaps, judged
+
+
+def gap_numbers(ref, config: Dict[str, Any], picked, weights: Dict[str, Any],
                 control: Optional[str] = None) -> Dict[str, Any]:
     """The compared numbers over ``picked``: widest and mean gap."""
-    w = reference.Widths.of(config)
-    gaps = [reference.served_gaps(weights, w, r.prompt,
-                                  np.asarray(r.tokens), control=control)[0]
+    w = ref.Widths.of(config)
+    gaps = [served_gaps(ref, weights, w, r.prompt, np.asarray(r.tokens),
+                        control=control)[0]
             for r in picked]
     allg = np.concatenate(gaps) if gaps else np.zeros((0,))
     return {

@@ -4,13 +4,21 @@ The system under test is built by ``tree_attention_tpu.cli.build_serve_engine``
 (the one construction every serve front end shares) and fed through the
 ``RequestSource`` seam of ``SlotServer.serve``. Everything that measures lives
 under the benchmark's own directories.
+
+Nothing here names a model. The configuration file names its family, and
+what depends on the architecture is reached through the family's two files,
+found by that name like a generator or a metric's reader
+(``benchmark/spec.py``): ``adapters/<family>.py`` builds the engine from the
+serving flags made here, and ``references/<family>.py`` is the plain
+reference ``judge`` compares with. The one model key read here is the
+vocabulary's size, the range the traffic draws its ids from.
 """
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import gc
+import math
 import time
 from typing import Any, Callable, Dict, List, Optional
 
@@ -84,15 +92,12 @@ def load_peaks(cell: Cell, device_kind: str) -> Dict[str, Any]:
 
 def serve_flags(config: Dict[str, Any], traffic: Dict[str, Any], seed: int,
                 device: str) -> List[str]:
+    """The serving half of the engine's flags; the family's adapter adds the
+    model's."""
     s = config["serving"]
     max_new = max(grid.values(traffic["outputs"]))
     flags = [
         "--mode", "serve", "--device", device,
-        "--model-dim", str(config["hidden_size"]),
-        "--heads", str(config["num_attention_heads"]),
-        "--kv-heads", str(config["num_key_value_heads"]),
-        "--vocab-size", str(config["vocab_size"]),
-        "--n-layers", str(config["num_hidden_layers"]),
         "--dtype", str(config["torch_dtype"]),
         "--slots", str(s["slots"]),
         "--prompt-len", str(int(s["cache_len"]) - max_new),
@@ -116,65 +121,11 @@ def model_seed(seed: int) -> int:
     return int(seed) % (2 ** 31 - 1)
 
 
-@contextlib.contextmanager
-def published_model(config: Dict[str, Any], seed: int):
-    """``build_serve_engine`` takes neither a feed-forward width, a rotary
-    base or a norm epsilon (the CLI derives the first from the hidden size
-    and leaves the others at the model's defaults) nor weights (it draws its
-    own, leaf by leaf in float32: 12.8 GB at its peak for 6.6 GB of Yi-6B
-    weights, my chip run, PR 23). Until it does (Open question in PERF.md),
-    the published values go into the ``TransformerConfig`` the CLI builds, at
-    the one place it builds it, and the benchmark's weights, made in one
-    jitted call, take the place of ``init_params``' where the CLI calls it."""
-    import tree_attention_tpu.models as models
-    from benchmark import reference
-    from tree_attention_tpu import cli
-
-    config_fn, init_fn = cli._transformer_config, models.init_params
-
-    def with_published(cfg):
-        return dataclasses.replace(
-            config_fn(cfg), d_ff=int(config["intermediate_size"]),
-            rope_theta=float(config["rope_theta"]),
-            norm_eps=float(config["rms_norm_eps"]))
-
-    def benchmark_weights(key, tcfg):
-        del key, tcfg                   # the seed is in the flags
-        w = reference.init_weights(seed, reference.Widths.of(config))
-        per_layer = ("ln1", "wq", "wk", "wv", "wo", "ln2", "w1", "w3", "w2")
-        return {"embed": w["embed"], "ln_f": w["ln_f"], "wout": w["wout"],
-                "layers": {n: w[n] for n in per_layer}}
-
-    cli._transformer_config = with_published
-    models.init_params = benchmark_weights
-    try:
-        yield
-    finally:
-        cli._transformer_config = config_fn
-        models.init_params = init_fn
-
-
-def build(config: Dict[str, Any], traffic: Dict[str, Any], seed: int,
-          device: str):
-    from tree_attention_tpu import cli
-    from tree_attention_tpu.utils.config import parse_args
-
-    cfg = parse_args(serve_flags(config, traffic, seed, device))
-    with published_model(config, model_seed(seed)):
-        setup = cli.build_serve_engine(cfg, None)
-    t = setup.tcfg
-    got = (t.d_model, t.d_ff, t.n_heads, t.n_kv_heads, t.d_head, t.n_layers,
-           t.vocab_size, t.rope_theta, t.norm_eps)
-    want = (config["hidden_size"], config["intermediate_size"],
-            config["num_attention_heads"], config["num_key_value_heads"],
-            config.get("head_dim", config["hidden_size"]
-                       // config["num_attention_heads"]),
-            config["num_hidden_layers"], config["vocab_size"],
-            float(config["rope_theta"]), float(config["rms_norm_eps"]))
-    if got != want:
-        raise SpecError(f"the engine was built at {got}, the configuration "
-                        f"file says {want}")
-    return setup, setup.make_engine()
+def build(cell: Cell, seed: int, device: str):
+    """The cell's engine, through its family's adapter: ``(setup, server)``."""
+    return cell.adapter().build(
+        cell.config, serve_flags(cell.config, cell.traffic, seed, device),
+        model_seed(seed), device, cell.reference())
 
 
 def warm_prompts(traffic: Dict[str, Any], chunk: int,
@@ -250,8 +201,7 @@ def measure(cell: Cell, seed: int, seconds: float, trace: bool, *,
     slots = int(config["serving"]["slots"])
 
     t_build = time.monotonic()
-    setup, server = build(config, traffic, seed,
-                          "tpu" if require_tpu else "cpu")
+    setup, server = build(cell, seed, "tpu" if require_tpu else "cpu")
     t_warm = time.monotonic()
     warm(server, traffic, config, seed)
     gen = cell.spec.load_module(
@@ -323,16 +273,16 @@ def judge(run: Run, say, control: Optional[str] = None) -> Dict[str, Any]:
     """The comparison that decides ``correct``: a seeded sample of the
     finished requests against the plain reference, and the exact counts.
     Says every number compared beside its limit."""
-    from benchmark import reference
-
-    config = run.cell.config
+    config, ref = run.cell.config, run.cell.reference()
+    if control is not None and control not in ref.CONTROLS:
+        raise SpecError(f"the {config['family']} reference has no control "
+                        f"{control!r} (it has {list(ref.CONTROLS)})")
     t_check = time.monotonic()
     picked = check.sample(run.recs, run.seed,
                           int(config["correct"]["min_tokens"]),
                           int(config["correct"]["max_requests"]))
-    weights = reference.init_weights(model_seed(run.seed),
-                                     reference.Widths.of(config))
-    numbers = check.gap_numbers(config, picked, weights)
+    weights = ref.init_weights(model_seed(run.seed), ref.Widths.of(config))
+    numbers = check.gap_numbers(ref, config, picked, weights)
     leak = run.leak
     numbers["failed_requests"] = reduce.failures(run.recs, run.t_end)["failed"]
     numbers["blocks_leaked"] = leak["blocks_used"] - leak["blocks_cached"] \
@@ -347,7 +297,8 @@ def judge(run: Run, say, control: Optional[str] = None) -> Dict[str, Any]:
         "reference_s": time.monotonic() - t_check,
         "rows": ruling["compared"]})
     if control is not None:
-        ctl = check.gap_numbers(config, picked, weights, control=control)
+        ctl = check.gap_numbers(ref, config, picked, weights,
+                                control=control)
         say({"info": "control", "precision": control,
              "gap_max": ctl["gap_max"], "gap_mean": ctl["gap_mean"],
              "correct": check.verdict(ctl, config["correct"]["limits"])
@@ -391,6 +342,17 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     if run.trace is not None:
         device["busy_s"] = run.trace["busy_s"]
         device["window_s"] = run.trace["window_s"]
-        line["breakdown"] = {"device_ops": run.trace["device_ops"][:10],
-                             "idle_gaps": run.trace["idle_gaps"][:10]}
+        ops, gaps = run.trace["device_ops"], run.trace["idle_gaps"]
+        # The result line may carry ten of each; a builder's own traced run
+        # reads twenty here (a cost spread over many operations can lie
+        # wholly under the cut at ten).
+        say({"info": "breakdown", "device_ops": ops[:20],
+             "idle_gaps": gaps[:20]})
+        line["breakdown"] = {"device_ops": ops[:10], "idle_gaps": gaps[:10]}
+    # Last in the line: each number compared beside its limit (a gap where
+    # nothing was served is infinite: no JSON number, so null).
+    line["compared"] = {
+        r["number"]: {"value": r["value"] if math.isfinite(r["value"])
+                      else None, "limit": r["limit"]}
+        for r in ruling["compared"]}
     return line

@@ -11,7 +11,10 @@ from benchmark import ticks
 def in_decode_ticks(run, kernel: str) -> Optional[Dict[str, Any]]:
     """Seconds of ``kernel``'s events that start inside decode ticks (no
     chunk tokens) of the traced window, the count of those ticks, and the
-    cost of their calls. None where there is no trace or no such event."""
+    cost of their calls: the configuration's family says what the cost
+    function wants of the model and how many calls a tick makes
+    (``adapters/<family>.py`` ``kernel_call``). None where there is no
+    trace, no such event, or the family never launches the kernel."""
     tr = run.trace
     if not tr or not run.flight or "offset_s" not in tr:
         return None
@@ -26,7 +29,10 @@ def in_decode_ticks(run, kernel: str) -> Optional[Dict[str, Any]]:
                     for name, s, d in ev if kernel in name)
     if not events:
         return None
-    cfg = run.cell.config
+    call = run.cell.adapter().kernel_call(run.cell.config, kernel)
+    if call is None:
+        return None
+    of_model, calls_a_tick = call
     costs = run.cell.spec.load_module("kernel_costs", kernel + ".py")
     seconds = need_bytes = need_flops = 0.0
     i = 0
@@ -36,14 +42,9 @@ def in_decode_ticks(run, kernel: str) -> Optional[Dict[str, Any]]:
         while i < len(events) and events[i][0] < b:
             seconds += events[i][1] / n_dev
             i += 1
-        c = costs.cost(
-            contexts=ticks.live_contexts(run.recs, a), q_rows=1,
-            heads=cfg["num_attention_heads"],
-            kv_heads=cfg["num_key_value_heads"],
-            head=cfg.get("head_dim",
-                         cfg["hidden_size"] // cfg["num_attention_heads"]),
-            dtype_bytes=2)
-        need_bytes += c["bytes"] * cfg["num_hidden_layers"]
-        need_flops += c["flops"] * cfg["num_hidden_layers"]
+        c = costs.cost(contexts=ticks.live_contexts(run.recs, a), q_rows=1,
+                       **of_model)
+        need_bytes += c["bytes"] * calls_a_tick
+        need_flops += c["flops"] * calls_a_tick
     return {"seconds": seconds, "ticks": len(spans), "bytes": need_bytes,
             "flops": need_flops}

@@ -1,12 +1,14 @@
-"""The plain reference: a decoder-only transformer's forward pass in float32
-at the highest matmul precision, with no kernels, no cache and no batching.
+"""The plain reference of the ``llama_dense`` family: a decoder-only
+transformer's forward pass in float32 at the highest matmul precision, with
+no kernels, no cache and no batching. What every family's file gives is in
+``README.md`` beside this file.
 
 Independent of the program: it imports nothing of ``tree_attention_tpu`` and
 takes nothing the program made. The weights are the benchmark's:
 :func:`init_weights` makes them from the seed, once for the program to serve
 and, after the program is freed, again for the reference.
 
-The architecture is the one the two configurations publish (Llama-style:
+The architecture is the one this family's configurations publish (Llama-style:
 RMSNorm before each block, rotary embedding on split halves, grouped-query
 causal attention scaled by 1/sqrt(head size), SwiGLU, untied output head).
 
@@ -21,7 +23,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -29,6 +31,7 @@ import numpy as np
 from jax import lax
 
 HIGHEST = lax.Precision.HIGHEST
+CONTROLS = ("int8", "fp8")      # the lower precisions ``quant`` takes
 
 
 @dataclasses.dataclass(frozen=True)
@@ -199,23 +202,3 @@ def logits_at(weights: Dict[str, Any], w: Widths, tokens: np.ndarray,
     out = _head(x[jnp.asarray(rows)], weights["ln_f"], weights["wout"],
                 w=w, quant=quant)
     return np.asarray(out)
-
-
-def served_gaps(weights: Dict[str, Any], w: Widths, prompt: np.ndarray,
-                served: np.ndarray, *, control: Optional[str] = None
-                ) -> Tuple[np.ndarray, np.ndarray]:
-    """For each served token, how far its reference logit lies below the
-    reference's best at that position, in one pass over the prompt and the
-    served tokens. With ``control`` the token judged at each position is not
-    the served one but the one the lower precision puts first there.
-    Returns ``(gaps, judged tokens)``."""
-    n = len(served)
-    tokens = np.concatenate([np.asarray(prompt), np.asarray(served[:-1])])
-    rows = np.arange(len(prompt) - 1, len(prompt) - 1 + n)
-    ref = logits_at(weights, w, tokens, rows)
-    judged = np.asarray(served)
-    if control is not None:
-        judged = logits_at(weights, w, tokens, rows,
-                           quant=control).argmax(-1)
-    gaps = ref.max(-1) - ref[np.arange(n), judged]
-    return gaps, judged

@@ -4,7 +4,9 @@
 
 Runs one cell once on the machine it is started on and prints, as the last
 line of its standard output, one JSON object with ``correct``, ``attempted``,
-``failed``, ``metrics`` and ``device``. Exits with another code than 0, and
+``failed``, ``metrics`` and ``device`` and, last in it, ``compared``: each
+number the ``correct`` gate compared beside its limit, which are also the last
+lines of its standard error. Exits with another code than 0, and
 prints no result, where JAX finds no TPU or too few chips, or where the
 program is not in the checkout.
 """
@@ -72,7 +74,13 @@ def main(argv=None) -> int:
         # A CPU's timings are never written under a metric's name.
         line["rehearsal"] = {"metrics_not_reported": sorted(line["metrics"])}
         line["metrics"] = {}
+        line["compared"] = line.pop("compared")         # stays last
     say(line)
+    # The same numbers as the last lines of standard error, which is what a
+    # record of a run that was not correct keeps.
+    for name, row in line["compared"].items():
+        print(f"compared {name} {row['value']} limit {row['limit']}",
+              file=sys.stderr)
     return 0
 
 

@@ -1,9 +1,13 @@
 """Find a cell's files by the names in the benchmark file.
 
-Everything that belongs to one configuration, one traffic mix, one generator
-kind or one per-layer metric is a file of its own under one of the
-directories the benchmark file lists in ``paths``. A later PR adds a cell by
-adding files and entries; nothing here names a cell, a mix or a metric.
+Everything that belongs to one configuration, one model family, one traffic
+mix, one generator kind or one per-layer metric is a file of its own under
+one of the directories the benchmark file lists in ``paths``. A
+configuration file names its family, and the family's plain reference
+(``references/<family>.py``) and its way into the engine
+(``adapters/<family>.py``) are found by that name. A later PR adds a cell,
+or an architecture, by adding files and entries; nothing here names a cell,
+a mix, a metric or a model.
 """
 
 from __future__ import annotations
@@ -12,6 +16,7 @@ import dataclasses
 import importlib.util
 import json
 import os
+import sys
 from typing import Any, Dict, List, Optional
 
 
@@ -31,6 +36,16 @@ class Cell:
     per_layer: List[Dict[str, Any]]
     spec: "Spec"
 
+    def reference(self):
+        """The plain reference of the configuration's family."""
+        return self.spec.load_module(
+            "references", self.config["family"] + ".py")
+
+    def adapter(self):
+        """The family's way into the engine."""
+        return self.spec.load_module(
+            "adapters", self.config["family"] + ".py")
+
 
 class Spec:
     """A parsed benchmark file and the directories it searches."""
@@ -45,6 +60,7 @@ class Spec:
             raise SpecError(f"cannot read {path}: {e}") from None
         self.dirs = [os.path.normpath(os.path.join(self.root, p))
                      for p in self.data["paths"]]
+        self._modules: Dict[str, Any] = {}
 
     def find(self, *parts: str) -> str:
         """The first ``<dir>/<parts...>`` that exists over ``paths``."""
@@ -61,13 +77,18 @@ class Spec:
 
     def load_module(self, *parts: str):
         """Import ``<dir>/<parts...>`` by path (a metric's name may hold
-        characters a module name may not)."""
+        characters a module name may not), once for this benchmark file: a
+        reference's jitted parts compile once a process."""
         path = self.find(*parts)
-        name = "_bench_" + "_".join(parts).replace(".", "_").replace("-", "_")
-        mod_spec = importlib.util.spec_from_file_location(name, path)
-        mod = importlib.util.module_from_spec(mod_spec)
-        mod_spec.loader.exec_module(mod)
-        return mod
+        if path not in self._modules:
+            name = "_bench_" + "_".join(parts).replace(".", "_") \
+                .replace("-", "_")
+            mod_spec = importlib.util.spec_from_file_location(name, path)
+            mod = importlib.util.module_from_spec(mod_spec)
+            sys.modules[name] = mod     # a dataclass looks its module up
+            mod_spec.loader.exec_module(mod)
+            self._modules[path] = mod
+        return self._modules[path]
 
     def _reported_in(self, metric: Dict[str, Any], cell_name: str,
                      e2e_names: Optional[List[str]] = None) -> bool:
@@ -92,6 +113,10 @@ class Spec:
             raise SpecError(f"workload {name!r} names no known config")
         with open(os.path.join(self.root, c["file"])) as f:
             config = json.load(f)
+        if not isinstance(config.get("family"), str):
+            raise SpecError(
+                f"{c['file']} names no family: give it a \"family\" key, the "
+                f"name of its references/<family>.py and adapters/<family>.py")
         traffic = self.load_json("traffic", w["traffic"] + ".json")
         e2e = [m for m in self.data["end_to_end"]
                if self._reported_in(m, name)]

@@ -13,7 +13,7 @@ import time
 import numpy as np
 import pytest
 
-from benchmark import check, harness, reference
+from benchmark import check, harness
 from benchmark.spec import Spec
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -25,6 +25,11 @@ REHEARSAL = os.path.join(ROOT, "benchmark", "tests", "rehearsal",
 @pytest.fixture(scope="module")
 def small():
     return Spec(REHEARSAL).cell("small_sat")
+
+
+@pytest.fixture(scope="module")
+def reference(small):
+    return small.reference()
 
 
 def test_lower_precision_in_the_programs_place_is_not_correct(small):
@@ -43,7 +48,7 @@ def test_lower_precision_in_the_programs_place_is_not_correct(small):
     assert got["gap_mean"] < limits["gap_mean"] / 3
 
 
-def test_int8_moves_the_token_the_reference_puts_first(small):
+def test_int8_moves_the_token_the_reference_puts_first(small, reference):
     """The reference's own greedy tokens read a gap of exactly 0; the same
     positions judged by the int8 forward do not."""
     w = reference.Widths.of(small.config)
@@ -58,11 +63,12 @@ def test_int8_moves_the_token_the_reference_puts_first(small):
                                      pad_to=128)
         served.append(int(logits[0].argmax()))
     served = np.asarray(served, np.int32)
-    own, _ = reference.served_gaps(weights, w, prompt, served)
+    own, _ = check.served_gaps(reference, weights, w, prompt, served)
     assert own.max() == 0.0
-    other, judged = reference.served_gaps(weights, w, prompt, served,
-                                          control="int8")
-    fp8, _ = reference.served_gaps(weights, w, prompt, served, control="fp8")
+    other, judged = check.served_gaps(reference, weights, w, prompt, served,
+                                      control="int8")
+    fp8, _ = check.served_gaps(reference, weights, w, prompt, served,
+                               control="fp8")
     assert (other >= 0).all() and fp8.mean() > other.mean() >= 0.0
     assert (judged != served).any() or other.max() == 0.0
     ruling = check.verdict({"gap_max": float(fp8.max()),
@@ -71,7 +77,8 @@ def test_int8_moves_the_token_the_reference_puts_first(small):
     assert ruling["correct"] is False and len(ruling["compared"]) == 2
 
 
-def test_the_same_seed_gives_the_same_weights_and_another_seed_others(small):
+def test_the_same_seed_gives_the_same_weights_and_another_seed_others(
+        small, reference):
     w = reference.Widths.of(small.config)
     a, b, c = (reference.init_weights(s, w) for s in (5, 5, 2 ** 31 + 6))
     assert a["wq"].dtype.name == "bfloat16" and a["ln1"].dtype.name == "float32"

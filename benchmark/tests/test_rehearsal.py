@@ -20,8 +20,15 @@ def drive(capsys, *extra, workload="tiny_sat", seconds="1", trace="0",
     rc = run.main(["--benchmark", REHEARSAL, "--workload", workload,
                    "--seed", seed, "--seconds", seconds, "--trace", trace,
                    *extra])
-    out = [ln for ln in capsys.readouterr().out.splitlines() if ln.strip()]
-    return rc, [json.loads(ln) for ln in out if ln.startswith("{")]
+    said = capsys.readouterr()
+    out = [ln for ln in said.out.splitlines() if ln.strip()]
+    lines = [json.loads(ln) for ln in out if ln.startswith("{")]
+    if rc == 0:                # and as the last lines of standard error
+        rows = lines[-1]["compared"]
+        assert said.err.splitlines()[-len(rows):] == [
+            f"compared {k} {v['value']} limit {v['limit']}"
+            for k, v in rows.items()]
+    return rc, lines
 
 
 def test_without_a_chip_the_command_fails_and_prints_no_result(capsys):
@@ -49,6 +56,11 @@ def test_rehearsal_names_the_cpu_and_reports_no_metric(capsys):
         "gap_max", "gap_mean", "failed_requests", "blocks_leaked",
         "compiles_in_window"}
     assert all("limit" in r and "value" in r for r in compared["rows"])
+    # The same numbers come last in the result line and on standard error.
+    assert list(last)[-1] == "compared"
+    assert last["compared"] == {r["number"]: {"value": r["value"],
+                                              "limit": r["limit"]}
+                                for r in compared["rows"]}
 
 
 def test_traced_rehearsal_reads_the_layer_metrics_it_can(capsys):
