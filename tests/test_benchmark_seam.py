@@ -1,0 +1,234 @@
+"""The seam by which the benchmark finds a model family by name, guarded by
+tier-1 (``benchmark/tests/test_seam.py`` holds the slower rehearsals, which
+tier-1 does not run), and the cell that came through it: ``dsv2_codegen_sat``.
+"""
+
+import json
+import os
+import re
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark.spec import Spec, SpecError  # noqa: E402
+
+BENCH = os.path.join(ROOT, "BENCHMARK.json")
+REHEARSAL = os.path.join(ROOT, "benchmark", "tests", "rehearsal",
+                         "BENCHMARK.json")
+CELL = "dsv2_codegen_sat"
+
+# What only a family's own files, the configuration files, the kernels' cost
+# functions and the benchmark's tests may say: the dense block's keys and
+# leaves, and the latent / expert family's.
+FAMILY_WORDS = ("hidden_size", "num_key_value_heads", "num_attention_heads",
+                "intermediate_size", "head_dim", "rope_theta", "rms_norm_eps",
+                "_transformer_config", "init_params", "kv_lora_rank",
+                "q_lora_rank", "n_routed_experts", "first_k_dense_replace",
+                "num_experts_per_tok", "rope_scaling")
+LEAVES = ("embed", "ln1", "wq", "wk", "wv", "wo", "ln2", "w1", "w3", "w2",
+          "ln_f", "wout", "wqa", "wqb", "wkva", "wkvb", "router", "we1")
+THE_FAMILYS_OWN = ("references", "adapters", "configs", "kernel_costs",
+                   "tests")
+
+
+def _sources(but=()):
+    top = os.path.join(ROOT, "benchmark")
+    for base, dirs, files in os.walk(top):
+        dirs[:] = [d for d in dirs if d != "__pycache__"
+                   and not (base == top and d in but)]
+        for f in files:
+            if not f.endswith(".pyc"):
+                path = os.path.join(base, f)
+                with open(path, errors="replace") as fh:
+                    yield os.path.relpath(path, top), fh.read()
+
+
+def test_no_key_of_a_family_outside_its_own_files():
+    leaf = re.compile(r"""["'](%s)["']""" % "|".join(LEAVES))
+    seen = 0
+    for rel, text in _sources(but=THE_FAMILYS_OWN):
+        seen += 1
+        for word in FAMILY_WORDS:
+            assert word not in text, (rel, word)
+        assert not leaf.search(text), (rel, leaf.search(text).group(0))
+        if rel.endswith(".py"):
+            assert "llama_dense" not in text, rel
+            assert "deepseek_mla_moe" not in text, rel
+    assert seen >= 45                   # the harness, the readers, the mixes
+    for family in ("llama_dense", "deepseek_mla_moe"):
+        with open(os.path.join(ROOT, "benchmark", "adapters",
+                               family + ".py")) as f:
+            assert "hidden_size" in f.read()     # the test's own control
+
+
+def test_a_configuration_without_a_family_is_a_spec_error(tmp_path):
+    with open(REHEARSAL) as f:
+        bench = json.load(f)
+    with open(os.path.join(os.path.dirname(REHEARSAL), "configs",
+                           "tiny.json")) as f:
+        config = json.load(f)
+    del config["family"]
+    os.makedirs(tmp_path / "configs")
+    (tmp_path / "configs" / "tiny.json").write_text(json.dumps(config))
+    bench["paths"] = [os.path.dirname(REHEARSAL),
+                      os.path.join(ROOT, "benchmark")]
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    with pytest.raises(SpecError, match="names no family"):
+        Spec(str(tmp_path / "BENCHMARK.json")).cell("tiny_sat")
+    config["family"] = "no_such_family"
+    (tmp_path / "configs" / "tiny.json").write_text(json.dumps(config))
+    cell = Spec(str(tmp_path / "BENCHMARK.json")).cell("tiny_sat")
+    with pytest.raises(SpecError, match="no_such_family"):
+        cell.reference()
+
+
+def test_a_familys_files_are_found_by_its_name_over_paths():
+    spec = Spec(REHEARSAL)
+    tied, dense = spec.cell("tiny_tied_sat"), spec.cell("tiny_sat")
+    rehearsal = os.path.dirname(REHEARSAL)
+    assert tied.reference().__file__ == os.path.join(
+        rehearsal, "references", "toy_tied.py")
+    assert tied.adapter().__file__ == os.path.join(
+        rehearsal, "adapters", "toy_tied.py")
+    assert dense.reference().__file__ == os.path.join(
+        ROOT, "benchmark", "references", "llama_dense.py")
+    bench = Spec(BENCH)
+    for w in bench.data["workloads"]:
+        cell = bench.cell(w["name"])
+        for mod in (cell.reference(),):
+            assert all(hasattr(mod, n) for n in (
+                "Widths", "init_weights", "logits_at", "CONTROLS"))
+        assert cell.adapter().build and cell.adapter().kernel_call
+
+
+def test_the_new_cell_resolves_every_file_it_names():
+    spec = Spec(BENCH)
+    cell = spec.cell(CELL)
+    assert cell.chips == 1 and cell.config["family"] == "deepseek_mla_moe"
+    assert cell.reference().__file__.endswith(
+        os.path.join("references", "deepseek_mla_moe.py"))
+    assert cell.adapter().__file__.endswith(
+        os.path.join("adapters", "deepseek_mla_moe.py"))
+    assert cell.traffic["kind"] == "backlog"
+    spec.load_module("generators", cell.traffic["kind"] + ".py").Generator
+    assert [m["name"] for m in cell.end_to_end] == [
+        "out_tok_s", "tbt_p50_ms", "tbt_p99_ms", "setup_s"]
+    names = [m["name"] for m in cell.per_layer]
+    for name in names:
+        assert spec.load_module("layer_metrics", name + ".py").read
+    for name in ("mla_decode_ms_tick", "mla_decode_paged_roofline",
+                 "moe_ffn_ms_tick", "moe_grouped_matmul_roofline",
+                 "experts_touched_pct", "expert_rows_max_over_mean",
+                 "occupancy_pct", "kv_blocks_peak_pct", "hbm_peak_gb",
+                 "tick_rows_useful_pct", "decode_tick_p50_ms",
+                 "device_idle_pct"):
+        assert name in names, name
+    for kernel in ("mla_decode_paged", "moe_grouped_matmul"):
+        assert cell.adapter().kernel_call(cell.config, kernel) is not None
+        assert spec.load_module("kernel_costs", kernel + ".py").cost
+    # The other cells do not read the new family's metrics, nor it theirs.
+    for w in spec.data["workloads"][:3]:
+        other = [m["name"] for m in spec.cell(w["name"]).per_layer]
+        assert "mla_decode_ms_tick" not in other
+    assert "flash_decode_paged_roofline" not in names
+    # The traffic: the grids the issue gives, in 4 balanced groups of 4.
+    from benchmark import grid
+    prompts = [128, 128, 192, 192, 256, 256, 320, 384, 384, 448, 512, 512,
+               576, 640, 704, 768]
+    outputs = [128, 160, 192, 256, 256, 320, 320, 384, 384, 448, 512, 512,
+               576, 640, 704, 768]
+    assert cell.traffic["prompts"] == grid.balanced_groups(prompts, 4)
+    assert cell.traffic["outputs"] == grid.balanced_groups(outputs, 4)
+    assert sorted(grid.values(cell.traffic["prompts"])) == prompts
+    assert cell.traffic["backlog_x_slots"] == 2
+    assert cell.traffic["midlife"] == "slots"
+    assert cell.config["serving"] == {
+        "slots": 16, "cache_len": 2560, "kv_layout": "paged", "kv_block": 64,
+        "admission": "chunked", "prefill_chunk": 256, "prefix_cache": True}
+
+
+def test_reduced_and_published_agree_and_the_cut_is_5_16_billion():
+    with open(BENCH) as f:
+        entry = next(c for c in json.load(f)["configs"]
+                     if c["name"] == "deepseek-v2")
+    with open(os.path.join(ROOT, entry["file"])) as f:
+        c = json.load(f)
+    assert entry["reduced"] == c["reduced"] == [
+        "num_hidden_layers", "n_routed_experts", "vocab_size"]
+    assert c["published"] == {"num_hidden_layers": 60,
+                              "n_routed_experts": 160, "vocab_size": 102400}
+    assert entry["source"] == c["source"]
+    assert c["deployment"]["experts_total"] == 160
+    assert c["deployment"]["chips_per_layer"] * c["n_routed_experts"] == 160
+    # Every published key of the catalog's entry, uncut but the three.
+    catalog = "/opt/skills/guides/model-configs/architectures.jsonl"
+    if os.path.exists(catalog):
+        with open(catalog) as f:
+            row = next(json.loads(l) for l in f
+                       if json.loads(l)["name"] == "DeepSeek-V2")
+        assert entry["source"] == row["source_url"]
+        for k, v in row["config"].items():
+            if k not in c["reduced"]:
+                assert c[k] == v, k
+    # The held parameters, reckoned from the file.
+    D, H = c["hidden_size"], c["num_attention_heads"]
+    attn = (D * c["q_lora_rank"]
+            + c["q_lora_rank"] * H * (c["qk_nope_head_dim"]
+                                      + c["qk_rope_head_dim"])
+            + D * (c["kv_lora_rank"] + c["qk_rope_head_dim"])
+            + c["kv_lora_rank"] * H * (c["qk_nope_head_dim"]
+                                       + c["v_head_dim"])
+            + H * c["v_head_dim"] * D)
+    assert attn == pytest.approx(149.2e6, rel=0.002)
+    expert = 3 * D * c["moe_intermediate_size"]
+    dense = attn + 3 * D * c["intermediate_size"]
+    moe = (attn + c["n_shared_experts"] * expert
+           + D * c["deployment"]["experts_total"]
+           + c["n_routed_experts"] * expert)
+    n_moe = c["num_hidden_layers"] - c["first_k_dense_replace"]
+    total = (c["first_k_dense_replace"] * dense + n_moe * moe
+             + 2 * c["vocab_size"] * D)
+    assert total == pytest.approx(5.16e9, rel=0.01)
+    assert n_moe >= 4 and c["n_routed_experts"] >= 8
+    assert c["vocab_size"] * 8 >= c["published"]["vocab_size"]
+
+
+def test_both_cost_functions_against_a_hand_count_at_one_tick():
+    spec = Spec(BENCH)
+    cell = spec.cell(CELL)
+    of_model, calls = cell.adapter().kernel_call(cell.config,
+                                                 "mla_decode_paged")
+    assert of_model == {"heads": 128, "rank": 512, "row": 576,
+                        "dtype_bytes": 2} and calls == 5
+    pad = 64                    # 576 values lie on 640 lanes in the pool
+    assert "64 zero lanes" in cell.config["assumed"]["row_padding"]
+    mla = spec.load_module("kernel_costs", "mla_decode_paged.py").cost
+    # Two slots of 1,000 and 500 tokens, one query row each: the rows once
+    # (576 values and the pad to 640 lanes, 2 bytes each); 128 absorbed queries
+    # in (a row wide) and 128 latent outputs out (512 wide) a slot;
+    # q.row over 576 and p.row over 512, 2 operations a multiply-add.
+    one = mla(contexts=[1000, 500], q_rows=1, **of_model)
+    assert one["bytes"] == 1500 * (576 + pad) * 2 \
+        + 2 * 128 * ((576 + pad) + 512) * 2
+    assert one["flops"] == 2 * 128 * (576 + 512) * 1500
+    # 242 operations a byte of cache at 128 heads, unpadded: near the ridge.
+    assert 2 * 128 * (576 + 512) / (576 * 2) == pytest.approx(241.8, abs=0.1)
+
+    of_model, calls = cell.adapter().kernel_call(cell.config,
+                                                 "moe_grouped_matmul")
+    assert calls == 4 and of_model["experts_held"] == 40
+    moe = spec.load_module("kernel_costs", "moe_grouped_matmul.py").cost
+    # 18 experts touched by 24 pairs: three matrices of 5120 x 1536 an
+    # expert, once; a pair's row in and out of each product.
+    one = moe(experts_touched=18, pairs=24, **of_model)
+    assert one["bytes"] == 18 * 3 * 5120 * 1536 * 2 \
+        + 24 * 2 * (5120 + 1536) * 2
+    assert one["flops"] == 24 * 6 * 5120 * 1536
+    assert moe(contexts=[10], q_rows=1, **of_model) == {
+        "bytes": 0.0, "flops": 0.0}
+    assert cell.adapter().kernel_call(cell.config, "flash_decode_paged") \
+        is None

@@ -520,15 +520,62 @@ class ServeSetup:
     make_engine: Callable[[], Any]  # -> SlotServer | DisaggServer
 
 
-def build_serve_engine(cfg: RunConfig, mesh) -> ServeSetup:
+def load_model_config(path: str) -> dict:
+    """``--model-config``'s file: a JSON object in a published
+    ``config.json``'s own keys (``models.transformer.model_from_config``
+    says which, and what a file cut to one chip's share adds)."""
+    import json
+
+    try:
+        with open(path) as f:
+            model = json.load(f)
+    except (OSError, ValueError) as e:
+        raise SystemExit(f"--model-config {path}: {e}") from None
+    if not isinstance(model, dict) or "hidden_size" not in model:
+        raise SystemExit(
+            f"--model-config {path}: not a model configuration (a JSON "
+            f"object with hidden_size, num_hidden_layers, ...)")
+    return model
+
+
+# What a latent-attention / expert model is not served with: the flag's
+# test and the mechanism's name, refused at build and never served wrong.
+_LATENT_REFUSALS = (
+    (lambda c: c.kv_layout != "paged",
+     "--kv-layout contiguous (a latent pool is paged)"),
+    (lambda c: c.kv_quant != "none", "--kv-quant (int8 latent rows)"),
+    (lambda c: c.kv_shard == "seq",
+     "--kv-shard seq (a sequence-sharded latent pool)"),
+    (lambda c: c.kv_tiering == "on" and c.host_blocks > 0,
+     "--host-blocks (the host tier for a one-array pool)"),
+    (lambda c: c.speculate,
+     "--speculate (the latent kernel takes no tree_mask)"),
+    (lambda c: c.serve_disagg,
+     "--serve-disagg (the handoff for a one-array pool)"),
+    (lambda c: c.admission != "chunked", "--admission whole"),
+)
+
+
+def build_serve_engine(cfg: RunConfig, mesh, *, model=None,
+                       params=None) -> ServeSetup:
     """Validate the serve flags and build the model and the engine factory
     — the ONE construction every serve front end shares (the synthetic
     trace, ``--serve-http``, ``--serve-fleet``) and ``chip_smoke.py`` calls
-    to inspect the engine the CLI serves with."""
+    to inspect the engine the CLI serves with.
+
+    ``model`` is the model as data: a dict in a published ``config.json``'s
+    keys (what ``--model-config <file>`` loads); it takes the place of the
+    model flags (``--model-dim``, ``--heads``, ...), which build the
+    Llama-style block as before. ``params`` are the weights to serve, in
+    the served type and the block's own layout; without them the program
+    draws its own from ``--seed``, leaf by leaf in the served type."""
     import jax
 
     from tree_attention_tpu.models import init_params
     from tree_attention_tpu.serving import SlotServer
+
+    if model is None and cfg.model_config:
+        model = load_model_config(cfg.model_config)
 
     if cfg.max_new_tokens < 1:
         raise SystemExit("--max-new-tokens must be >= 1")
@@ -659,8 +706,28 @@ def build_serve_engine(cfg: RunConfig, mesh) -> ServeSetup:
             f"capacity {cache_len} (prompt-len + jitter + max-new-tokens, "
             f"rounded)"
         )
-    tcfg = _transformer_config(dataclasses.replace(cfg, seq_len=cache_len))
-    params = init_params(jax.random.PRNGKey(cfg.seed), tcfg)
+    if model is not None:
+        from tree_attention_tpu.models.transformer import model_from_config
+
+        try:
+            tcfg = model_from_config(
+                model, dtype=_dtype(cfg), max_seq_len=max(cache_len, 128),
+                attn_impl=cfg.impl, attn_block_size=cfg.block_size,
+                seq_layout=cfg.seq_layout,
+            )
+        except (KeyError, ValueError) as e:
+            raise SystemExit(f"model configuration: {e!r}") from None
+        if not tcfg.dense_block:
+            for refused, what in _LATENT_REFUSALS:
+                if refused(cfg):
+                    raise SystemExit(
+                        f"a latent-attention / expert model is not served "
+                        f"with {what}: not built yet (ROADMAP 2A)")
+    else:
+        tcfg = _transformer_config(
+            dataclasses.replace(cfg, seq_len=cache_len))
+    if params is None:
+        params = init_params(jax.random.PRNGKey(cfg.seed), tcfg)
     if cfg.slo_ttft <= 0 or cfg.slo_tbt <= 0:
         raise SystemExit("--slo-ttft and --slo-tbt must be > 0")
     # The paged layout has ONE device budget (--kv-blocks) and one host

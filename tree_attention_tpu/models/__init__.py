@@ -6,18 +6,23 @@ with parameters, a loss, and a sharded training step.
 """
 
 from tree_attention_tpu.models.transformer import (  # noqa: F401
+    ExpertLayer,
+    LatentAttention,
     TransformerConfig,
+    YarnRope,
     count_params,
     cross_entropy_loss,
     forward,
     init_params,
     loss_fn,
+    model_from_config,
     param_shardings,
     param_specs,
 )
 from tree_attention_tpu.models.decode import (  # noqa: F401
     KVCache,
     PagedKVCache,
+    PagedLatentCache,
     PagedQuantKVCache,
     QuantKVCache,
     decode_attention,

@@ -40,7 +40,7 @@ both take the per-slot ``(B,)`` ``q_position``.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple, Union
+from typing import Any, Dict, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -151,6 +151,61 @@ class PagedKVCache:
     @property
     def blocks(self) -> int:
         return self.k.shape[1]
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class PagedLatentCache:
+    """The paged cache of a latent-attention model: ONE pool of
+    ``[c_kv | k_rope | pad]`` rows, ``(L, N, block, row)``, under the
+    same block tables, lengths, allocator and radix tree as
+    :class:`PagedKVCache`. A row serves every head as key and (its first
+    ``kv_rank`` lanes) as value, so there is no second pool to hold it
+    again, and no head axis. ``row`` is a multiple of the chip's 128 lanes
+    (``LatentAttention.row_pad`` zero lanes after the values): at 576 the
+    TPU compiler copied the whole pool into a layout of its own before
+    every launch of the kernel (PERF.md, PR 27). It rides
+    :func:`forward_step`'s layer loops as carry and is written by the one
+    :func:`_paged_pool_write`, which sees it as ``(L, N, 1, block, row)``
+    (a bitcast)."""
+
+    kv: jax.Array      # (L, N, block, row) pool
+    table: jax.Array   # (B, NB) int32 — physical block per logical block
+    length: jax.Array  # (B,) int32 — tokens written so far, per slot
+
+    @property
+    def capacity(self) -> int:
+        return self.table.shape[1] * self.kv.shape[2]
+
+    @property
+    def block(self) -> int:
+        return self.kv.shape[2]
+
+    @property
+    def blocks(self) -> int:
+        return self.kv.shape[1]
+
+
+def cache_pools(cache) -> Dict[str, jax.Array]:
+    """A paged cache's block pools by field name, every one ``(L, N, ...)``
+    with the block on axis 1: what a block copy, a leak check or a byte
+    count has to visit, whatever the model caches."""
+    if isinstance(cache, PagedLatentCache):
+        return {"kv": cache.kv}
+    pools = {"k": cache.k, "v": cache.v}
+    if isinstance(cache, PagedQuantKVCache):
+        pools.update(k_scale=cache.k_scale, v_scale=cache.v_scale)
+    return pools
+
+
+def cache_token_bytes(cache) -> int:
+    """Bytes one cached token takes over all layers, read from the arrays
+    (scales of an int8 pool not counted: they are per block)."""
+    if isinstance(cache, PagedLatentCache):
+        return int(cache.kv.shape[0] * cache.kv.shape[3]
+                   * cache.kv.dtype.itemsize)
+    k = cache.k  # (L, B|N, Hkv, T|block, D)
+    return int(2 * k.shape[0] * k.shape[2] * k.shape[4] * k.dtype.itemsize)
 
 
 @jax.tree_util.register_dataclass
@@ -365,20 +420,13 @@ def copy_pool_block(cache, src: jax.Array, dst: jax.Array):
     block a forked branch will append into needs its own copy, and this
     is that copy. ``src == dst`` degenerates to an identical-bytes
     self-write (the engine's no-partial-tail arc reuses one compiled
-    program that way). Works on :class:`PagedKVCache` and
-    :class:`PagedQuantKVCache`."""
+    program that way). Works on every paged cache (:func:`cache_pools`)."""
     src = jnp.asarray(src, jnp.int32)
     dst = jnp.asarray(dst, jnp.int32)
-    new = dict(
-        k=cache.k.at[:, dst].set(cache.k[:, src]),
-        v=cache.v.at[:, dst].set(cache.v[:, src]),
-    )
-    if isinstance(cache, PagedQuantKVCache):
-        new.update(
-            k_scale=cache.k_scale.at[:, dst].set(cache.k_scale[:, src]),
-            v_scale=cache.v_scale.at[:, dst].set(cache.v_scale[:, src]),
-        )
-    return dataclasses.replace(cache, **new)
+    return dataclasses.replace(cache, **{
+        name: pool.at[:, dst].set(pool[:, src])
+        for name, pool in cache_pools(cache).items()
+    })
 
 
 def insert_dequant_prefix(
@@ -524,6 +572,25 @@ def init_paged_cache(
                 f"over {n_sh} '{seq_axis}' shards — round the pool up"
             )
     nb = -(-max_len // block)
+    if cfg.mla is not None:
+        if quantize:
+            raise ValueError(
+                "int8 latent rows are not built: a latent-attention "
+                "model's pool is served exact")
+        if seq_sharded:
+            raise ValueError(
+                "a sequence-sharded latent pool (kv_shard='seq') is not "
+                "built: the tree merge has no latent kernel")
+        shape = (cfg.n_layers, blocks, block, cfg.mla.row)
+        pool = (
+            jax.jit(lambda: jnp.zeros(shape, cfg.dtype),
+                    out_shardings=NamedSharding(mesh, P()))()
+            if mesh is not None else jnp.zeros(shape, cfg.dtype)
+        )
+        return PagedLatentCache(
+            kv=pool, table=jnp.zeros((batch_size, nb), jnp.int32),
+            length=jnp.zeros((batch_size,), jnp.int32),
+        )
     shape = (cfg.n_layers, blocks, cfg.n_kv_heads, block, cfg.d_head)
     dtype = jnp.int8 if quantize else cfg.dtype
     sscale = None
@@ -799,6 +866,81 @@ def _masked_window_write(
     return lax.dynamic_update_slice_in_dim(buf, merged, ws, axis=1)
 
 
+def _latent_layers(
+    params: Params,
+    x: jax.Array,
+    cache: PagedLatentCache,
+    cfg: TransformerConfig,
+    positions: jax.Array,
+    n_valid: jax.Array,
+    stats: Optional[Dict[str, Any]],
+) -> Tuple[jax.Array, jax.Array]:
+    """The layer loops of a latent-attention / expert model: the leading
+    dense-FFN stack under one scan, the expert stack under another, the
+    whole latent pool the carry of both and the layer's blocks reached by
+    offset (``table + l·N``, the layer index running through both stacks).
+    Every row — decode rows and chunk rows alike — attends in absorbed
+    form against the pool it has just been written to."""
+    from tree_attention_tpu.models.experts import (
+        EXPERT_LEAVES, expert_layer, held_counts,
+    )
+    from tree_attention_tpu.models.latent import (
+        latent_attention, latent_out, latent_qkv,
+    )
+
+    start, table, N = cache.length, cache.table, cache.blocks
+    Tq = x.shape[1]
+    valid = jnp.arange(Tq, dtype=jnp.int32)[None, :] < n_valid[:, None]
+
+    def attend(layer, x, pool, l):
+        h = rms_norm(x, layer["ln1"], cfg.norm_eps)
+        rows, q_abs = latent_qkv(layer, h, positions, cfg)
+        pool = _paged_pool_write(
+            pool[:, :, None], rows, table, start, n_valid, l)[:, :, 0]
+        out_lat, _ = latent_attention(
+            q_abs, pool.reshape((-1,) + pool.shape[2:]), l * N + table,
+            q_offset=start, cfg=cfg,
+        )
+        return x + latent_out(layer, out_lat), pool
+
+    def dense_body(carry, xs):
+        layer, l = xs
+        x, pool = attend(layer, *carry, l)
+        x = x + _mlp_block(layer, rms_norm(x, layer["ln2"], cfg.norm_eps))
+        return (x, pool), None
+
+    def expert_body(carry, xs):
+        layer, l = xs
+        x, pool = attend(layer, *carry, l)
+        h32 = rms_norm(x.astype(jnp.float32), layer["ln2"], cfg.norm_eps)
+        y, chosen = expert_layer(
+            layer, h32.astype(x.dtype), cfg.moe, router_input=h32,
+            experts=experts, first=(l - n_dense) * cfg.moe.held,
+        )
+        return (x + y, pool), held_counts(chosen, valid, cfg.moe)
+
+    carry = (x, cache.kv)
+    n_dense = cfg.n_dense_layers
+    if n_dense:
+        carry, _ = lax.scan(dense_body, carry, (
+            params["dense"], jnp.arange(n_dense, dtype=jnp.int32)))
+    if cfg.n_layers > n_dense:
+        # Every layer's experts as ONE stack (a bitcast), a layer's reached
+        # by offset like the pool's blocks: scanned as ``xs`` the loop would
+        # copy a layer's experts out of the stack before the kernel read
+        # one of them.
+        stack = params["layers"]
+        experts = tuple(
+            stack[n].reshape((-1,) + stack[n].shape[2:])
+            for n in EXPERT_LEAVES)
+        carry, rows = lax.scan(expert_body, carry, (
+            {n: a for n, a in stack.items() if n not in EXPERT_LEAVES},
+            jnp.arange(n_dense, cfg.n_layers, dtype=jnp.int32)))
+        if stats is not None:
+            stats["expert_rows"] = rows
+    return carry
+
+
 def forward_step(
     params: Params,
     tokens: jax.Array,
@@ -815,8 +957,20 @@ def forward_step(
     positions: Optional[jax.Array] = None,
     tree_mask: Optional[jax.Array] = None,
     kv_shard: str = "replicated",
+    stats: Optional[Dict[str, Any]] = None,
 ) -> Tuple[jax.Array, Union[KVCache, QuantKVCache]]:
     """Run ``Tq`` new tokens through the model against the cache.
+
+    The layer body is chosen by the model data: the rotary-GQA block with
+    the dense SwiGLU below, or, where ``cfg.mla`` / ``cfg.moe`` are set,
+    latent attention against a :class:`PagedLatentCache` with a leading
+    stack of dense-FFN layers and a stack of expert layers
+    (:func:`_latent_layers`). Checks, positions, the embedding, the final
+    norm, the head and the length bookkeeping are the same code for both.
+    ``stats``, if given, is filled with the step's counters as traced
+    arrays (read them in the same trace): ``expert_rows`` ``(expert
+    layers, held + 1)`` int32, the valid rows routed to each held expert
+    and, last, the pairs routed to experts held elsewhere.
 
     ``kv_shard="seq"`` (paged caches under a >1-way ``seq_axis`` mesh
     only — see :func:`init_paged_cache`) declares the pool
@@ -891,7 +1045,19 @@ def forward_step(
 
     B, Tq = tokens.shape
     start = cache.length  # (B,) per-slot offsets
-    paged = isinstance(cache, (PagedKVCache, PagedQuantKVCache))
+    latent = isinstance(cache, PagedLatentCache)
+    if latent != (not cfg.dense_block):
+        raise ValueError(
+            "a latent-attention / expert model is served from the paged "
+            "latent pool (init_paged_cache) and no other cache; the dense "
+            f"block from no latent pool (got {type(cache).__name__})"
+        )
+    if latent and (tree_mask is not None or kv_shard == "seq"):
+        raise ValueError(
+            "the latent kernel takes no tree_mask and no sequence-sharded "
+            "pool"
+        )
+    paged = latent or isinstance(cache, (PagedKVCache, PagedQuantKVCache))
     if not paged and n_tokens is not None and Tq > cache.capacity:
         # The masked write is a Tq-row window into the token axis; a window
         # wider than the buffer cannot be placed at any offset.
@@ -940,7 +1106,18 @@ def forward_step(
     if obs.REGISTRY.enabled:
         kind = ("paged_quant" if quant else "paged") if paged \
             else ("quant" if quant else "exact")
-        _STEP_DISPATCH.labels(cache=kind).inc()
+        _STEP_DISPATCH.labels(
+            cache="paged_latent" if latent else kind).inc()
+    grew = Tq if n_tokens is None else n_tokens
+    if latent:
+        x, pool = _latent_layers(
+            params, x, cache, cfg, positions,
+            jnp.full((B,), Tq, jnp.int32) if n_tokens is None else n_tokens,
+            stats,
+        )
+        x = rms_norm(x, params["ln_f"], cfg.norm_eps)
+        return (x @ params["wout"]).astype(jnp.float32), PagedLatentCache(
+            kv=pool, table=cache.table, length=start + grew)
 
     # Satellite fix (ISSUE 8): off the TPU Pallas kernels — the eager/CPU
     # proxy and interpret-mode runs — a paged step used to re-gather
@@ -1269,7 +1446,6 @@ def forward_step(
     new_k, new_v = scanned[0], scanned[1]
     x = rms_norm(x, params["ln_f"], cfg.norm_eps)
     logits = (x @ params["wout"]).astype(jnp.float32)
-    grew = Tq if n_tokens is None else n_tokens
     if paged and quant:
         new_cache: Union[KVCache, QuantKVCache, PagedKVCache,
                          PagedQuantKVCache] = PagedQuantKVCache(

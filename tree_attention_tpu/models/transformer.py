@@ -43,8 +43,83 @@ Params = Dict[str, Any]
 
 
 @dataclasses.dataclass(frozen=True)
+class YarnRope:
+    """YaRN rotary scaling: see :func:`~.latent.rope_frequencies`."""
+
+    factor: float
+    original_len: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentAttention:
+    """A latent-attention (MLA) block: low-rank query and key/value
+    projections, ``rope`` rotary dimensions beside ``nope`` plain ones in
+    every query/key head, and a cache of one ``[c_kv | k_rope]`` row a token
+    (``models/latent.py``). In the pool the row is followed by zero lanes
+    up to the next multiple of the chip's 128: short of one, the TPU
+    compiler copies the whole pool into a layout of its own before every
+    launch of the kernel."""
+
+    q_rank: int            # 0: queries are projected directly
+    kv_rank: int
+    nope: int
+    rope: int
+    v_head: int
+    yarn: Optional[YarnRope] = None
+
+    @property
+    def row_pad(self) -> int:
+        return -(self.kv_rank + self.rope) % 128
+
+    @property
+    def row(self) -> int:
+        """Lanes a cached token takes in one layer, padding included."""
+        return self.kv_rank + self.rope + self.row_pad
+
+
+@dataclasses.dataclass(frozen=True)
+class ExpertLayer:
+    """A routed-expert feed-forward layer (``models/experts.py``) and the
+    share of it held here: the router scores all ``n_experts``, this
+    program computes experts ``[held_first, held_first + held)``."""
+
+    n_experts: int         # the router's width
+    held: int
+    held_first: int
+    per_token: int
+    width: int             # one routed expert's SwiGLU width
+    shared_width: int      # the always-on shared experts, as one SwiGLU; 0: none
+    n_groups: int = 1
+    top_groups: int = 1
+    scale: float = 1.0     # on the chosen scores when they are not renormed
+    renorm: bool = False
+    first_dense: int = 0   # leading layers that keep the dense SwiGLU
+
+    def __post_init__(self):
+        if self.n_experts % self.n_groups:
+            raise ValueError(
+                f"{self.n_experts} experts do not split into "
+                f"{self.n_groups} routing groups")
+        if not 0 <= self.held_first <= self.held_first + self.held \
+                <= self.n_experts:
+            raise ValueError(
+                f"experts held [{self.held_first}, "
+                f"{self.held_first + self.held}) lie outside the router's "
+                f"{self.n_experts}")
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
-    """Static architecture hyperparameters (hashable: usable as a jit static)."""
+    """Static architecture hyperparameters (hashable: usable as a jit static).
+
+    The block is chosen by the data: ``mla`` set means latent attention in
+    every layer (else rotary GQA), ``moe`` set means every layer after its
+    ``first_dense`` is a routed-expert layer (else the dense SwiGLU).
+    """
 
     vocab_size: int = 32768
     d_model: int = 512
@@ -65,6 +140,8 @@ class TransformerConfig:
     attn_impl: str = "auto"      # flash_attention impl selector
     attn_block_size: Optional[int] = None  # None -> impl-appropriate
     remat: bool = True           # checkpoint each layer body under scan
+    mla: Optional[LatentAttention] = None
+    moe: Optional[ExpertLayer] = None
 
     def __post_init__(self):
         if self.n_heads % self.n_kv_heads:
@@ -81,6 +158,100 @@ class TransformerConfig:
     def kv_dim(self) -> int:
         return self.n_kv_heads * self.d_head
 
+    @property
+    def dense_block(self) -> bool:
+        """The Llama-style block this module's ``forward`` computes."""
+        return self.mla is None and self.moe is None
+
+    @property
+    def n_dense_layers(self) -> int:
+        if self.moe is None:
+            return self.n_layers
+        return min(self.moe.first_dense, self.n_layers)
+
+
+def model_from_config(config: Dict[str, Any], *, dtype: Any = None,
+                      max_seq_len: int = 65536,
+                      **overrides: Any) -> TransformerConfig:
+    """The model as data: a :class:`TransformerConfig` from a model's
+    published ``config.json`` keys. ``kv_lora_rank`` selects latent
+    attention, ``n_routed_experts`` the expert layer; without them the keys
+    are those of a Llama-style dense decoder. A file cut to one chip's
+    share says so under ``deployment``: ``experts_total`` (the router's
+    width; ``n_routed_experts`` is then how many are held here) and
+    ``expert_share`` (which share of them, 0-based; consecutive ranges).
+    ``vocab_size`` is the rows held."""
+    c = config
+    heads = int(c["num_attention_heads"])
+    mla = moe = None
+    d_head = int(c.get("head_dim") or int(c["hidden_size"]) // heads)
+    kv_heads = int(c.get("num_key_value_heads", heads))
+    if c.get("kv_lora_rank"):
+        rs = c.get("rope_scaling") or None
+        yarn = None
+        if rs is not None:
+            if rs.get("type", rs.get("rope_type")) != "yarn":
+                raise ValueError(
+                    f"rope_scaling type {rs.get('type')!r}: only 'yarn' "
+                    f"is built")
+            yarn = YarnRope(
+                factor=float(rs["factor"]),
+                original_len=int(rs["original_max_position_embeddings"]),
+                beta_fast=float(rs.get("beta_fast", 32)),
+                beta_slow=float(rs.get("beta_slow", 1)),
+                mscale=float(rs.get("mscale", 1.0)),
+                mscale_all_dim=float(rs.get("mscale_all_dim", 0.0)),
+            )
+        mla = LatentAttention(
+            q_rank=int(c.get("q_lora_rank") or 0),
+            kv_rank=int(c["kv_lora_rank"]),
+            nope=int(c["qk_nope_head_dim"]), rope=int(c["qk_rope_head_dim"]),
+            v_head=int(c["v_head_dim"]),
+            yarn=yarn,
+        )
+        d_head, kv_heads = mla.nope + mla.rope, heads
+    if c.get("n_routed_experts"):
+        if mla is None:
+            raise ValueError(
+                "routed experts under rotary-GQA attention are not built: "
+                "an expert layer is served with latent attention")
+        if c.get("scoring_func", "softmax") != "softmax":
+            raise ValueError(
+                f"router scoring {c['scoring_func']!r}: only 'softmax' "
+                f"is built")
+        if int(c.get("moe_layer_freq", 1)) != 1:
+            raise ValueError("moe_layer_freq other than 1 is not built")
+        dep = c.get("deployment") or {}
+        held = int(c["n_routed_experts"])
+        total = int(dep.get("experts_total", held))
+        grouped = c.get("topk_method", "greedy") == "group_limited_greedy"
+        moe = ExpertLayer(
+            n_experts=total, held=held,
+            held_first=int(dep.get("expert_share", 0)) * held,
+            per_token=int(c["num_experts_per_tok"]),
+            width=int(c["moe_intermediate_size"]),
+            shared_width=int(c.get("n_shared_experts") or 0)
+            * int(c["moe_intermediate_size"]),
+            n_groups=int(c["n_group"]) if grouped else 1,
+            top_groups=int(c["topk_group"]) if grouped else 1,
+            scale=float(c.get("routed_scaling_factor", 1.0)),
+            renorm=bool(c.get("norm_topk_prob", False)),
+            first_dense=int(c.get("first_k_dense_replace", 0)),
+        )
+    if dtype is None:
+        dtype = jnp.dtype(str(c.get("torch_dtype", "bfloat16")))
+    kw = dict(
+        vocab_size=int(c["vocab_size"]), d_model=int(c["hidden_size"]),
+        n_layers=int(c["num_hidden_layers"]), n_heads=heads,
+        n_kv_heads=kv_heads, d_head=d_head,
+        d_ff=int(c["intermediate_size"]), max_seq_len=max_seq_len,
+        rope_theta=float(c.get("rope_theta", 10000.0)),
+        norm_eps=float(c.get("rms_norm_eps", 1e-6)), dtype=dtype,
+        mla=mla, moe=moe,
+    )
+    kw.update(overrides)
+    return TransformerConfig(**kw)
+
 
 # ---------------------------------------------------------------------------
 # Parameter initialisation and sharding specs (two pytrees, one shape)
@@ -95,6 +266,10 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Params:
     scaled by ``(2·n_layers)^-1/2`` so the residual stream's variance stays O(1)
     at init regardless of depth.
     """
+    if not cfg.dense_block:
+        from tree_attention_tpu.models.experts import init_block_params
+
+        return init_block_params(key, cfg)
     k_embed, k_layers, k_out = jax.random.split(key, 3)
     L, D = cfg.n_layers, cfg.d_model
     std = 0.02
@@ -140,6 +315,10 @@ def param_specs(
     batch-sharded).
     """
     del data_axis
+    if not cfg.dense_block:
+        raise NotImplementedError(
+            "param_specs: sharding a latent-attention / expert block's "
+            "parameters (experts over the model axis) is not built")
     m = model_axis
     return {
         "embed": P(None, m),
@@ -297,6 +476,11 @@ def forward(
     """
     from tree_attention_tpu.parallel.mesh import prune_axes
 
+    if not cfg.dense_block:
+        raise NotImplementedError(
+            "forward: the full-sequence (training) pass builds the dense "
+            "block only; a latent-attention / expert model is served "
+            "through models.decode.forward_step")
     axes = prune_axes(
         mesh, {"data": data_axis, "seq": seq_axis, "model": model_axis}
     )

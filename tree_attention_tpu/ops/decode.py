@@ -53,7 +53,8 @@ _DECODE_KV_TOKENS = obs.counter(
 
 
 # Paths of ``_account_dispatch`` that do NOT run a Pallas kernel.
-_REFERENCE_PATHS = ("chunked_vmap", "paged_local_partial_reference")
+_REFERENCE_PATHS = ("chunked_vmap", "paged_local_partial_reference",
+                    "mla_paged_reference")
 
 
 def _account_dispatch(path: str, kv_tokens: int) -> None:

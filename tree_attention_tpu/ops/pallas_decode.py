@@ -687,6 +687,166 @@ def _paged_decode_call(
     )(offs, tbl, *tensors)
 
 
+def _mla_decode_paged_kernel(
+    offs_ref,  # SMEM (2, B) scalar-prefetch: per-batch [q_offset|kv_offset]
+    tbl_ref,   # SMEM (B, NB) scalar-prefetch block table (index maps only)
+    *refs,     # q_ref, kv_ref x blocks_per_step, out_ref, lse_ref,
+               # m_scr, l_scr, acc_scr:
+               #   q_ref   VMEM (1, bq, W) — packed (head x Tq) queries,
+               #           each row [q_lat rank | q_rope]
+               #   kv_ref  VMEM (1, block, W) — latent pool block
+               #           tbl[b, si * blocks_per_step + j]
+               #   out_ref VMEM (1, bq, rank); lse_ref VMEM (1, bq, LANES)
+               #   m/l_scr VMEM (bq, LANES) f32; acc_scr VMEM (bq, rank) f32
+    scale: float,
+    tq: int,
+    block_q: int,
+    block: int,
+    rank: int,
+    blocks_per_step: int,
+):
+    """Latent (MLA) attention in absorbed form over a paged pool of
+    ``[c_kv | k_rope]`` rows: ONE row a token serves every query head, so
+    all heads (x Tq rows) pack into the Q-tile sublanes like a GQA group
+    with one KV head. Scores contract the whole row (latent + rotary
+    part), values are the row's first ``rank`` lanes: the pool block is
+    streamed once and used for both. A grid step folds
+    ``blocks_per_step`` logical blocks (one operand each, every one the
+    same pool through its own table entry): a latent block is a few tens
+    of KB, so one a step would leave the step's fixed cost in charge."""
+    del tbl_ref  # consumed by the index maps
+    q_ref = refs[0]
+    kv_refs = refs[1:1 + blocks_per_step]
+    out_ref, lse_ref, m_scr, l_scr, acc_scr = refs[1 + blocks_per_step:]
+    qi = pl.program_id(1)
+    si = pl.program_id(2)
+    n_s = pl.num_programs(2)
+    bq, bk = block_q, block * blocks_per_step
+    tk = n_s * bk
+
+    b = pl.program_id(0)
+    q_offset = offs_ref[0, b]
+    kv_offset = offs_ref[1, b]
+
+    @pl.when(si == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when((kv_offset + si * bk) <= (q_offset + tq - 1))
+    def _compute():
+        kv = kv_refs[0][0] if blocks_per_step == 1 else jnp.concatenate(
+            [r[0] for r in kv_refs], axis=0)           # (bk, W)
+        s = lax.dot_general(
+            q_ref[0], kv,
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=matmul_precision(q_ref.dtype, kv.dtype),
+        ) * scale
+        s = _decode_visibility_mask(
+            s, qi, si, bq=bq, bk=bk, tq=tq, tk=tk,
+            q_offset=q_offset, kv_offset=kv_offset, causal=True,
+        )
+        _decode_softmax_fold(
+            s, kv[:, :rank], m_scr, l_scr, acc_scr, si=si, bk=bk, tk=tk,
+        )
+
+    @pl.when(si == n_s - 1)
+    def _finalize():
+        _decode_finalize(out_ref, lse_ref, m_scr, l_scr, acc_scr)
+
+
+def _mla_kv_map(j: int, blocks_per_step: int):
+    def index_map(b, qi, si, offs_ref, tbl_ref):
+        del qi, offs_ref
+        return (tbl_ref[b, si * blocks_per_step + j], 0, 0)
+
+    return index_map
+
+
+def attention_pallas_mla_paged(
+    q: jax.Array,
+    pool: jax.Array,
+    block_table: jax.Array,
+    *,
+    q_offset,
+    scale: float,
+    rank: int,
+    interpret: Optional[bool] = None,
+) -> Tuple[jax.Array, jax.Array]:
+    """Absorbed latent attention against a paged latent pool.
+
+    ``q`` is ``(B, H, Tq, W)``, each row ``[q_lat (rank) | q_rope | 0
+    pad]``; ``pool`` is ``(N, block, W)`` rows ``[c_kv (rank) | k_rope
+    | pad]`` and batch row ``b``'s logical block ``j`` is pool row
+    ``block_table[b, j]``. Row ``t`` of slot ``b`` sits at position
+    ``q_offset[b] + t`` and sees every position up to its own. Returns
+    ``(out, lse)`` like its siblings: ``out`` ``(B, H, Tq, rank)`` is
+    ``softmax(q·row) · row[:rank]`` (still latent: the caller multiplies
+    by the value up-projection), ``lse`` ``(B, H, Tq)`` float32, so a
+    partial over some of the blocks merges with any other by the repo's
+    ``(out, lse)`` monoid. The device event is ``mla_decode_paged``.
+    """
+    B, H, Tq, W = q.shape
+    if pool.ndim != 3 or pool.shape[2] != W:
+        raise ValueError(
+            f"a latent pool is (N, block, {W}) for queries of width {W}, "
+            f"got {pool.shape}"
+        )
+    N, block, _ = pool.shape
+    NB = block_table.shape[1]
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    if obs.REGISTRY.enabled:
+        _KERNEL_BUILDS.labels(kernel="mla_paged").inc()
+    # Every head reads the same rows: pack heads x Tq into the sublanes.
+    # Decode (Tq = 1) is one 128-head tile a slot; chunk rows take tiles of
+    # 1024 so that a slot's blocks are walked by few tiles.
+    r = H * Tq
+    bq = min(-(-r // 8) * 8, 128 if Tq == 1 else 1024)
+    qp = _pad_dim(q.reshape(B, r, W), 1, bq)
+    n_q = qp.shape[1] // bq
+    per = next(p for p in (4, 2, 1) if NB % p == 0)
+    in_specs = [pl.BlockSpec((1, bq, W), _paged_q_map)] + [
+        pl.BlockSpec((1, block, W), _mla_kv_map(j, per))
+        for j in range(per)
+    ]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, n_q, NB // per),
+        in_specs=in_specs,
+        out_specs=[
+            pl.BlockSpec((1, bq, rank), _paged_q_map),
+            pl.BlockSpec((1, bq, _LANES), _paged_q_map),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((bq, _LANES), jnp.float32),
+            pltpu.VMEM((bq, _LANES), jnp.float32),
+            pltpu.VMEM((bq, rank), jnp.float32),
+        ],
+    )
+    out, lse = pl.pallas_call(
+        functools.partial(
+            _mla_decode_paged_kernel, scale=scale, tq=Tq, block_q=bq,
+            block=block, rank=rank, blocks_per_step=per,
+        ),
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((B, n_q * bq, rank), q.dtype),
+            jax.ShapeDtypeStruct((B, n_q * bq, _LANES), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        ),
+        interpret=interpret,
+        name="mla_decode_paged",
+    )(_offsets_smem(q_offset, 0, B), jnp.asarray(block_table, jnp.int32),
+      qp, *([pool] * per))
+    return (out[:, :r].reshape(B, H, Tq, rank),
+            lse[:, :r, 0].reshape(B, H, Tq))
+
+
 def _tree_bits_rows(
     tree_mask: jax.Array, G: int, Hkv: int, bq: int, n_q: int
 ) -> jax.Array:

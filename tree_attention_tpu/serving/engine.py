@@ -145,6 +145,7 @@ from tree_attention_tpu.models.decode import (
     KVCache,
     PagedKVCache,
     PagedQuantKVCache,
+    cache_token_bytes,
     QuantKVCache,
     compact_decode_window,
     copy_pool_block,
@@ -205,6 +206,19 @@ _REQUESTS = obs.counter(
 _PREFILL_CHUNKS = obs.counter(
     "serving_prefill_chunks_total",
     "prefill chunks scheduled into serving ticks (fused or staged)",
+)
+_MOE_ROWS = obs.histogram(
+    "moe_rows_per_expert",
+    "rows a held expert got in one layer of one fetched tick",
+    buckets=(0, 1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024, 4096),
+)
+_MOE_PAIRS_TOTAL = obs.counter(
+    "moe_pairs_total",
+    "row-expert pairs routed by fetched ticks, held here or elsewhere",
+)
+_MOE_PAIRS_HERE = obs.counter(
+    "moe_pairs_here",
+    "row-expert pairs that fell on experts held here (computed)",
 )
 _TTFT = obs.histogram(
     "serving_ttft_seconds",
@@ -848,6 +862,26 @@ class SlotServer:
             raise ValueError(
                 f"prefill_budget must be >= 1, got {prefill_budget}"
             )
+        if not cfg.dense_block:
+            # What a one-array latent pool is not built for is refused
+            # here, by name, never served wrong.
+            for on, what in (
+                (kv_layout != "paged",
+                 "the contiguous layout (kv_layout='contiguous')"),
+                (quantize, "int8 latent rows (quantize=True)"),
+                (kv_shard == "seq",
+                 "a sequence-sharded latent pool (kv_shard='seq')"),
+                (bool(host_blocks), "the host tier (host_blocks > 0)"),
+                (speculate, "speculation (the latent kernel takes no "
+                            "tree_mask)"),
+                (admission != "chunked",
+                 "whole-prompt admission (admission='whole')"),
+            ):
+                if on:
+                    raise ValueError(
+                        f"a latent-attention / expert model does not serve "
+                        f"with {what}: not built for a one-array latent "
+                        f"pool")
         self.params = params
         self.cfg = cfg
         self.slots = slots
@@ -916,7 +950,9 @@ class SlotServer:
         # here is also in _families (the join/best-of machinery is
         # shared); the per-tick counters feed the flight recorder.
         self._tree_fams: Dict[int, _ForkFamily] = {}
-        self._tree_sampling = bool(tree_sampling)
+        # The latent kernel takes no tree_mask: such a model's families
+        # fork into slots on shared blocks.
+        self._tree_sampling = bool(tree_sampling) and cfg.dense_block
         self._tick_tree_branches = 0
         self._tick_branch_retired = 0
         self._slot_shared: List[set] = [set() for _ in range(slots)]
@@ -967,12 +1003,6 @@ class SlotServer:
         # rebase happens only inside the device-side shard_map bodies.
         self.kv_shard = kv_shard
         self._kv_seq_sharded = kv_shard == "seq" and self._seq_shards > 1
-        # Bytes a contiguous-layout hit gathers per matched token — the
-        # cost a paged hit deletes (the bytes_moved span arg).
-        self._kv_token_bytes = (
-            2 * cfg.n_layers * cfg.n_kv_heads * cfg.d_head
-            * jnp.dtype(cfg.dtype).itemsize
-        )
         # int8 pool bytes per token — what an int8 paged hit's dequant
         # gather into staging actually moves (ISSUE 13).
         self._kv_token_bytes_q = 2 * cfg.n_layers * cfg.n_kv_heads \
@@ -1083,6 +1113,18 @@ class SlotServer:
             if quantize:
                 cache = quantize_cache(cache)  # empty -> fallback scales
         self.cache = cache
+        # Bytes a cached token takes over all layers, read from the pool
+        # the model built (a latent row is not 2·Hkv·D): what a
+        # contiguous-layout hit gathers per matched token, the cost a
+        # paged hit deletes (the bytes_moved span arg), and the report's
+        # ``kv.token_bytes``.
+        self._kv_token_bytes = cache_token_bytes(cache)
+        # Expert layers' row counts on the tick's fetch: (layers, held+1),
+        # None for a model without experts.
+        self._expert_rows_shape: Optional[Tuple[int, int]] = None
+        if cfg.moe is not None and cfg.n_layers > cfg.n_dense_layers:
+            self._expert_rows_shape = (
+                cfg.n_layers - cfg.n_dense_layers, cfg.moe.held + 1)
         self.tok = jnp.zeros((slots,), jnp.int32)
 
         # Host mirror of slot state (the scheduler's view; device state is
@@ -1344,6 +1386,25 @@ class SlotServer:
                                                   axis=0)
         return cache, tok_vec
 
+    def _account_expert_rows(self, extra: np.ndarray) -> Dict[str, int]:
+        """Read the expert layers' row counts off the tick's fetch (the
+        rows below the slots') into the registry, and return the flight
+        record's three numbers: ``expert_pairs`` (row-expert pairs
+        computed here), ``experts_touched`` (held experts with >= 1 row,
+        summed over layers), ``expert_rows_max`` (the fullest expert)."""
+        layers, width = self._expert_rows_shape
+        rows = extra.reshape(-1)[:layers * width].reshape(layers, width)
+        here = rows[:, :-1]
+        pairs = int(here.sum())
+        if obs.REGISTRY.enabled:
+            _MOE_PAIRS_HERE.inc(pairs)
+            _MOE_PAIRS_TOTAL.inc(int(rows.sum()))
+            for n in here.reshape(-1):
+                _MOE_ROWS.observe(float(n))
+        return {"expert_pairs": pairs,
+                "experts_touched": int((here > 0).sum()),
+                "expert_rows_max": int(here.max())}
+
     def _sample_emit(self, last, keys, temp, topk, idx):
         """The ONE per-slot sampling call every emitting program shares
         (models.decode.sample_slots): argmax where the slot's
@@ -1390,8 +1451,10 @@ class SlotServer:
         kw = dict(self._fs_kw)
         if self.quantize:
             kw["quant_kernel"] = self.quant_kernel
+        stats: Dict[str, Any] = {}
         logits, new_cache = forward_step(
-            params, tokens, cache, self.cfg, n_tokens=n_tok, **kw
+            params, tokens, cache, self.cfg, n_tokens=n_tok, stats=stats,
+            **kw
         )
         row = jnp.maximum(n_tok - 1, 0)
         last = jnp.take_along_axis(logits, row[:, None, None], axis=1)[:, 0]
@@ -1403,6 +1466,13 @@ class SlotServer:
              lax.bitcast_convert_type(lp_out, jnp.int32)[:, None]],
             axis=1,
         )
+        if "expert_rows" in stats:
+            # The expert layers' row counts ride the tick's one fetch as
+            # further rows of the same array (tracing on or off: one
+            # program), ``_expert_rows_shape`` says how to read them.
+            flat = stats["expert_rows"].reshape(-1)
+            flat = jnp.pad(flat, (0, flat.shape[0] % 2))
+            fused = jnp.concatenate([fused, flat.reshape(-1, 2)], axis=0)
         # ``last`` rides out as a device carry: a fork family samples
         # its siblings' first tokens from the PARENT's exact prompt-end
         # logits row (bit-identical to the parent's own sample point —
@@ -4610,6 +4680,7 @@ class SlotServer:
                               if st == "await"]
                     host_sync = bool(awaits or live_idx)
                     tokens_this_tick = 0
+                    expert_rows = None
                     alltok_host = None
                     alllp_host = None
                     if host_sync:
@@ -4642,11 +4713,15 @@ class SlotServer:
                         elif fused_dev is not None:
                             # lint: allow[host-sync] THE one per-tick fetch (token vector + bitcast logprobs, one fused array)
                             fh = np.asarray(fused_dev)
-                            self._tok_host = fh[:, 0]
+                            self._tok_host = fh[:self.slots, 0]
                             self._lp_host = np.ascontiguousarray(
-                                fh[:, 1]
+                                fh[:self.slots, 1]
                             ).view(np.float32)
                             lp_valid = True
+                            if self._expert_rows_shape is not None and (
+                                    FLIGHT.enabled or obs.REGISTRY.enabled):
+                                expert_rows = self._account_expert_rows(
+                                    fh[self.slots:])
                         else:
                             # Awaits-only tick (a synchronous whole
                             # admission parked tokens, nothing stepped):
@@ -4887,6 +4962,8 @@ class SlotServer:
                             if self._host_pool is not None:
                                 rec["host_blocks_used"] = self._host_pool.used
                                 rec["restored_blocks"] = self._tick_restored
+                        if expert_rows is not None:
+                            rec.update(expert_rows)
                         if self._speculate:
                             s_slots, s_prop, s_acc = self._tick_spec
                             rec["spec_verify"] = {
@@ -4973,6 +5050,8 @@ class SlotServer:
                 "blocks_used": self._pool.used,
                 "blocks_free": self._pool.free_count,
                 "peak_blocks_used": self._peak_blocks_used,
+                # Read from the pool the model built, all layers.
+                "token_bytes": self._kv_token_bytes,
             }
             if self._forks_life - fork0[0]:
                 # Copy-on-write fork accounting for THIS run (ISSUE 15).

@@ -72,6 +72,9 @@ class RunConfig:
     model_dim: int = 256
     n_layers: int = 2
     vocab_size: int = 4096
+    # The model as data: a JSON file in a published config.json's keys; takes
+    # the place of the model flags above (serve mode).
+    model_config: Optional[str] = None
 
     # Generate mode.
     temperature: float = 0.8
@@ -256,6 +259,12 @@ def build_arg_parser() -> argparse.ArgumentParser:
     p.add_argument("--model-dim", type=int, default=d.model_dim)
     p.add_argument("--n-layers", type=int, default=d.n_layers)
     p.add_argument("--vocab-size", type=int, default=d.vocab_size)
+    p.add_argument("--model-config", default=d.model_config, metavar="FILE",
+                   help="serve mode: the model as data — a JSON file in a "
+                        "published config.json's own keys (latent attention, "
+                        "routed experts, YaRN rotary, or a Llama-style dense "
+                        "decoder); replaces --model-dim/--heads/--kv-heads/"
+                        "--n-layers/--vocab-size")
     p.add_argument("--temperature", type=float, default=d.temperature,
                    help="generate/serve mode: sampling temperature "
                         "(0 = greedy; serve mode threads per-slot PRNG "
