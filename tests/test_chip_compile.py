@@ -76,15 +76,26 @@ def _s(shape, dtype=jnp.bfloat16):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=_chip())
 
 
-def _paged(kernel, tq, *, int8=False, tree=False, **kw):
+# The benchmark cells' decode shapes (slots, KV heads, table entries): the
+# table widths the step rule can fold (``tuning.paged_decode_step``: 4 and 8
+# entries a step); the smoke's 33 entries divide by none, so its cases above
+# compile the one-entry step.
+# Their pools hold 16 layers, as the tick programs' do.
+MISTRAL = dict(slots=16, hkv=8, nb=40, layers=16)
+YI = dict(slots=8, hkv=4, nb=64, layers=16)
+
+
+def _paged(kernel, tq, *, int8=False, tree=False, slots=B, hkv=HKV, nb=NB,
+           layers=1, **kw):
     """(fn, abstract args) of one paged decode kernel call at Tq = tq."""
-    pool = _s((N, HKV, BLK, D), jnp.int8 if int8 else jnp.bfloat16)
-    args = [_s((B, HQ, tq, D)), pool, pool]
+    n = layers * slots * nb
+    pool = _s((n, hkv, BLK, D), jnp.int8 if int8 else jnp.bfloat16)
+    args = [_s((slots, HQ, tq, D)), pool, pool]
     if int8:
-        args += [_s((N, HKV), jnp.float32)] * 2
-    args += [_s((B, NB), jnp.int32), _s((B,), jnp.int32)]
+        args += [_s((n, hkv), jnp.float32)] * 2
+    args += [_s((slots, nb), jnp.int32), _s((slots,), jnp.int32)]
     if tree:
-        args.append(_s((B, tq, tq), jnp.bool_))
+        args.append(_s((slots, tq, tq), jnp.bool_))
 
     def fn(*a):
         *tensors, table, pos = a[:len(a) - tree]
@@ -153,6 +164,42 @@ CASES = {
     "paged_int8_q8_tree_verify_tq8": (
         lambda: _paged(attention_pallas_decode_q8, 8, int8=True, tree=True),
         "flash_decode_paged"),
+    # The cells' shapes: every head of 4 (Mistral) / 8 (Yi) entries a step.
+    "paged_decode_mistral7b_tq1": (
+        lambda: _paged(attention_pallas_decode, 1, **MISTRAL),
+        "flash_decode_paged"),
+    "paged_decode_yi6b_tq1": (
+        lambda: _paged(attention_pallas_decode, 1, **YI),
+        "flash_decode_paged"),
+    "paged_chunk_mistral7b_tq64": (
+        lambda: _paged(attention_pallas_decode, 64, **MISTRAL),
+        "flash_decode_paged"),
+    "paged_chunk_yi6b_tq112": (
+        lambda: _paged(attention_pallas_decode, 112, **YI),
+        "flash_decode_paged"),
+    "paged_tree_verify_yi6b_tq8": (
+        lambda: _paged(attention_pallas_decode, 8, tree=True, **YI),
+        "flash_decode_paged"),
+    "paged_local_blocks_mistral7b": (
+        lambda: _paged(attention_pallas_decode, 1, local_blocks=True,
+                       **MISTRAL),
+        "flash_decode_paged"),
+    "paged_int8_q8_block_scales_mistral7b": (
+        lambda: _paged(attention_pallas_decode_q8, 1, int8=True, **MISTRAL),
+        "flash_decode_paged"),
+    "paged_int8_q8q_block_scales_yi6b": (
+        lambda: _paged(attention_pallas_decode_q8q, 1, int8=True, **YI),
+        "flash_decode_paged_q8q"),
+    "paged_int8_q8q_tree_verify_yi6b_tq8": (
+        lambda: _paged(attention_pallas_decode_q8q, 8, int8=True, tree=True,
+                       **YI),
+        "flash_decode_paged_q8q"),
+    # 32 KV heads at a chunk's 128 packed rows a head: every head's Q-side
+    # state in one step is refused for VMEM; the step rule cuts the heads.
+    "paged_chunk_mha32_tq127_heads_cut": (
+        lambda: _paged(attention_pallas_decode, 127, hkv=32, nb=64,
+                       layers=16),
+        "flash_decode_paged"),
     "prefill_fwd": (_prefill, "flash_fwd"),
     "train_fwd": (_train_fwd_bwd, "flash_fwd"),
     "bwd_dq": (_train_fwd_bwd, "flash_bwd_dq"),
@@ -174,6 +221,20 @@ def test_kernel_compiles_for_v5e(case):
     text = _compiled_text(builder)
     assert "tpu_custom_call" in text
     assert kernel in pallas_kernels(text), pallas_kernels(text)
+    if "_mistral7b" in case or "_yi6b" in case:
+        # The pool goes into the call as it is: no copy, slice or change of
+        # layout of a pool-sized array before the launch (what a 576-lane
+        # latent row cost before PR 27 padded it). Not asked of the smoke's
+        # one-layer pools: the compiler stages those whole in fast memory.
+        pool = max(math.prod(a.shape) for a in builder()[1])
+        moved = [
+            (name, opcode, result)
+            for name, result, opcode, _ in _materialised(text)
+            if opcode not in _MOVES_NOTHING and any(
+                math.prod(int(d) for d in dims.split(",")) >= pool
+                for dims in re.findall(r"\[([\d,]+)\]", result))
+        ]
+        assert not moved, moved
 
 
 # -- the step the tick programs run: the KV pool stays in place (ISSUE 25) ---

@@ -127,6 +127,19 @@ def _random_pool_case(seed, *, int8=False):
             jnp.asarray(table), jnp.asarray(lengths), blk)
 
 
+def _step_tile(pool, table, q):
+    """The KV tile one grid step of the paged kernel folds: the pool block
+    times the table entries a step takes (``tuning.paged_decode_step``;
+    ISSUE 28). The contiguous kernel at this ``block_size`` folds the same
+    columns in the same order."""
+    from tree_attention_tpu.ops.tuning import paged_decode_step
+
+    hkv, blk, d = pool.shape[1:]
+    rows = -(-(q.shape[1] // hkv) * q.shape[2] // 8) * 8
+    return blk * paged_decode_step(hkv, blk, d, pool.dtype.itemsize,
+                                   table.shape[1], min(rows, 128))[1]
+
+
 @pytest.mark.parametrize("seed", [0, 1, 2])
 def test_paged_kernel_bit_exact_vs_gathered(seed):
     """The exact paged kernel == gather + unpaged kernel at the same
@@ -134,7 +147,8 @@ def test_paged_kernel_bit_exact_vs_gathered(seed):
     q, pk, pv, table, lengths, blk = _random_pool_case(seed)
     kg, vg = gather_paged_kv(pk, pv, table)
     ref_o, ref_l = attention_pallas_decode(
-        q, kg, vg, causal=True, q_offset=lengths, block_size=blk
+        q, kg, vg, causal=True, q_offset=lengths,
+        block_size=_step_tile(pk, table, q)
     )
     pg_o, pg_l = attention_pallas_decode(
         q, pk, pv, causal=True, q_offset=lengths, block_table=table
@@ -151,7 +165,7 @@ def test_paged_kernel_bit_exact_int8(kernel):
     q, kq, vq, scale, table, lengths, blk = _random_pool_case(3, int8=True)
     kg, vg = gather_paged_kv(kq, vq, table)
     ref_o, ref_l = fn(q, kg, vg, scale, scale, causal=True,
-                      q_offset=lengths, block_size=blk)
+                      q_offset=lengths, block_size=_step_tile(kq, table, q))
     pg_o, pg_l = fn(q, kq, vq, scale, scale, causal=True,
                     q_offset=lengths, block_table=table)
     assert (np.asarray(ref_o) == np.asarray(pg_o)).all()

@@ -25,6 +25,10 @@ Layout (chosen by measurement on v5e; see the design notes below):
   in VMEM scratch — the in-kernel mirror of
   :func:`tree_attention_tpu.ops.reference.merge_partials`, so the emitted
   ``(out, lse)`` plugs into the cross-device tree merge unchanged.
+- **The paged kernels take fat steps.** A pool block is a few tens of KB a
+  head, so a step of one head of one block is all fixed cost: the paged
+  grid runs over slots, and a step takes every KV head of several table
+  entries (``_paged_decode_step``; ``ops/tuning.py`` ``paged_decode_step``).
 - Causal masking uses global offsets from SMEM (they are traced values
   inside jitted decode steps); tiles whose every KV position is masked skip
   both matmuls via ``pl.when``.
@@ -55,10 +59,14 @@ from tree_attention_tpu.ops.block_utils import (
 # recompile storm (e.g. a caller advancing a static q_position per token)
 # shows up here as a runaway count. Execution totals live in the host
 # loops (bench/harness.py, cli.py).
+# ``heads`` and ``entries`` say what one grid step takes of the KV stream: KV
+# heads, and table entries of a paged pool (0: no table, the step is a
+# ``block_k`` tile of a contiguous buffer).
 _KERNEL_BUILDS = obs.counter(
     "pallas_decode_kernel_builds_total",
-    "flash-decode kernel program builds (one per distinct shape/config)",
-    labels=("kernel",),
+    "flash-decode kernel program builds (one per distinct shape/config), "
+    "by the KV heads and table entries a grid step takes",
+    labels=("kernel", "heads", "entries"),
 )
 
 
@@ -116,7 +124,9 @@ def _decode_visibility_mask(s, qi, si, *, bq, bk, tq, tk,
 def _decode_softmax_fold(s, v_tile, m_scr, l_scr, acc_scr, *, si, bk, tk,
                          v_scale=None):
     """Fold one masked score tile and its V tile into the running
-    online-softmax state — shared by both decode kernels.
+    online-softmax state — shared by the decode kernels. The paged kernels
+    fold every head of a step at once: their tiles and state carry a
+    leading head dimension, ``(heads, bq, bk)`` against ``(heads, bk, D)``.
 
     P·V with the FA2 p-downcast (probabilities are in [0,1], bf16 relative
     error stays small), f32 accumulation. When Tk is ragged the last tile's
@@ -132,8 +142,8 @@ def _decode_softmax_fold(s, v_tile, m_scr, l_scr, acc_scr, *, si, bk, tk,
     (p·s)·v_q``, one scalar multiply on the probability tile instead of
     a per-element dequant of the V stream.
     """
-    m_prev = m_scr[:, :1]  # (bq, 1)
-    l_prev = l_scr[:, :1]
+    m_prev = m_scr[..., :1]  # (bq, 1)
+    l_prev = l_scr[..., :1]
     m_blk = jnp.max(s, axis=-1, keepdims=True)
     m_new = jnp.maximum(m_prev, m_blk)
     m_safe = jnp.where(m_new == NEG_INF, 0.0, m_new)
@@ -144,14 +154,16 @@ def _decode_softmax_fold(s, v_tile, m_scr, l_scr, acc_scr, *, si, bk, tk,
         p = p * v_scale
     if v_tile.dtype == jnp.int8:
         v_tile = v_tile.astype(jnp.bfloat16)
+    lead = tuple(range(s.ndim - 2))  # the paged kernels' heads
     if tk % bk:
         row_ok = (
-            si * bk + lax.broadcasted_iota(jnp.int32, v_tile.shape, 0)
+            si * bk
+            + lax.broadcasted_iota(jnp.int32, v_tile.shape, len(lead))
         ) < tk
         v_tile = jnp.where(row_ok, v_tile, 0)
     acc_scr[...] = acc_scr[...] * alpha + lax.dot_general(
         p.astype(v_tile.dtype), v_tile,
-        dimension_numbers=(((1,), (0,)), ((), ())),
+        dimension_numbers=(((len(lead) + 1,), (len(lead),)), (lead, lead)),
         preferred_element_type=jnp.float32,
         precision=matmul_precision(v_tile.dtype, v_tile.dtype),
     )
@@ -160,10 +172,10 @@ def _decode_softmax_fold(s, v_tile, m_scr, l_scr, acc_scr, *, si, bk, tk,
 
 
 def _decode_finalize(out_ref, lse_ref, m_scr, l_scr, acc_scr):
-    """Emit (out, lse) from the final online-softmax state — shared by both
+    """Emit (out, lse) from the final online-softmax state — shared by the
     decode kernels. Rows with no visible keys emit 0 / -inf."""
-    m = m_scr[:, :1]
-    l = l_scr[:, :1]
+    m = m_scr[..., :1]
+    l = l_scr[..., :1]
     empty = l <= 0.0
     l_safe = jnp.where(empty, 1.0, l)
     out_ref[0] = (
@@ -340,76 +352,96 @@ def _flash_decode_q8q_kernel(
         _decode_finalize(out_ref, lse_ref, m_scr, l_scr, acc_scr)
 
 
-def _flash_decode_paged_kernel(
+def _paged_decode_step(
     offs_ref,  # SMEM (2, B) scalar-prefetch: per-batch [q_offset|kv_offset]
     tbl_ref,   # SMEM (B, NB) scalar-prefetch block table — read by the
-               # K/V index maps, not the body: grid step si streams pool
-               # block table[b, si] (PagedAttention, arXiv:2309.06180)
-    *refs,     # q_ref, [tb_ref when tree], k_ref, v_ref, out_ref, lse_ref,
-               # m_scr, l_scr, acc_scr:
-               #   q_ref   VMEM (1, bq, D) — packed (group × Tq) queries
-               #   tb_ref  VMEM (1, bq, LANES) int32 — tree bitmasks
-               #   k/v_ref VMEM (1, 1, block, D) — pool block tbl[b, si]
-               #   out_ref VMEM (1, bq, D); lse_ref VMEM (1, bq, LANES)
-               #   m/l_scr VMEM (bq, LANES) f32; acc_scr VMEM (bq, D) f32
-    scale: float,
+               # K/V index maps (PagedAttention, arXiv:2309.06180); the
+               # body reads it only for a signed table's ownership
+    refs,      # lead (q_ref, or q_ref and qs_ref), [tb_ref when tree],
+               # k_ref x entries, v_ref x entries, [ks_ref, vs_ref when
+               # block_scales], out_ref, lse_ref, m_scr, l_scr, acc_scr:
+               #   q_ref   VMEM (1, heads, bq, D) — each head's packed
+               #           (group x Tq) queries
+               #   qs_ref  VMEM (1, heads, bq, LANES) f32 — per-row Q scales
+               #   tb_ref  VMEM (1, heads, bq, LANES) int32 — tree bitmasks
+               #   k/v_ref VMEM (1, heads, block, D) — every head of pool
+               #           block tbl[b, si * entries + j], one operand an
+               #           entry (the same pool through its own index map)
+               #   ks/vs_ref VMEM (1, heads, 8, LANES) f32 — the 8-row scale
+               #           tile that holds this step's entries
+               #   out_ref VMEM (1, heads, bq, D)
+               #   lse_ref VMEM (1, heads, bq, LANES)
+               #   m/l_scr VMEM (heads, bq, LANES) f32
+               #   acc_scr VMEM (heads, bq, D) f32
+    scores,    # (*lead refs, k_tile (heads, entries * block, D)) ->
+               # (heads, bq, entries * block) f32 scores, softmax scale
+               # applied
+    *,
+    n_lead: int,
     causal: bool,
     tq: int,
     block_q: int,
-    block_k: int,
-    n_kv_heads: int,
-    tree: bool = False,
-    block_scales: bool = False,
-    local_blocks: bool = False,
+    block: int,
+    entries: int,
+    head_groups: int,
+    tree: bool,
+    block_scales: bool,
+    local_blocks: bool,
 ):
-    """Block-table variant of :func:`_flash_decode_kernel`: the split-KV
-    grid dimension walks each slot's LOGICAL blocks and the BlockSpec
-    index maps dereference the scalar-prefetched table, so fragmented /
-    non-monotone physical layouts stream exactly like a contiguous
-    buffer. The logical capacity ``NB·block`` is block-divisible by
-    construction, so the ragged-tail mask is statically off; the causal
-    mask against each slot's own ``q_offset`` hides every unwritten (or
-    garbage-mapped) position, and the per-slot liveness cull skips whole
-    blocks past the slot's length — a short slot reads only its own few
-    blocks of the pool.
+    """One grid step of the paged decode kernels: every KV head of
+    ``entries`` consecutive table entries of one slot.
+
+    The split-KV grid dimension walks a slot's LOGICAL blocks ``entries``
+    at a time and the index maps dereference the scalar-prefetched table,
+    so fragmented / non-monotone physical layouts stream like a contiguous
+    buffer. The step's blocks are put end to end in logical order into one
+    ``(heads, entries * block, D)`` K and V tile, so the mask and the
+    online-softmax fold see a tile of ``entries * block`` columns exactly
+    as the contiguous kernel sees one of its own, and every head's scores,
+    softmax state and accumulator are worked as one batched array: a loop
+    over the heads, each with its own slice of the scratch, ran the heads
+    one after the other and took 1.5-1.8 times as long on the chip
+    (``ops/tuning.py``). A pool block is
+    a few tens of KB a head: one head of one entry a step left the step's
+    fixed cost (a quarter of a microsecond) in charge of the kernel's time
+    (``ops/tuning.py`` ``paged_decode_step`` has the numbers and the rule
+    for ``heads`` and ``entries``). The logical capacity ``NB * block`` is
+    step-divisible by construction, so the ragged-tail mask is statically
+    off; the causal mask against each slot's own ``q_offset`` hides every
+    unwritten (or garbage-mapped) position, and the per-slot liveness cull
+    skips whole steps past the slot's length.
 
     ``block_scales`` (ISSUE 13, the shareable-int8 pool): two extra
     lane-broadcast operands carry each logical block's K and V
     dequantization SCALARS — K's multiplies the score tile after the
     matmul (a scalar commutes out of the dot product, so no per-element
     K dequant rides the KV stream), V's folds into ``p`` (see
-    :func:`_decode_softmax_fold`).
+    :func:`_decode_softmax_fold`). A step spans ``entries`` rows of the
+    8-row scale tile, one scalar over each entry's ``block`` columns.
 
     ``local_blocks`` (ISSUE 18, the sequence-sharded pool): the table is
     SIGNED — a negative entry marks a logical block another shard owns.
-    The index map clamps the DMA to pool row 0 (some valid row must
-    stream), and the body's liveness gate skips folding it, so the
-    online-softmax state accumulates exactly this shard's partial; rows
-    whose every block is remote finalize to the ``(0, -inf)`` merge
-    identity that :func:`tree_attention_tpu.parallel.tree._weigh`
-    absorbs."""
-    if not local_blocks:
-        del tbl_ref  # consumed by the index maps
-    ks_ref = vs_ref = None
-    if tree and block_scales:
-        q_ref, tb_ref, k_ref, v_ref, ks_ref, vs_ref, out_ref, lse_ref, \
-            m_scr, l_scr, acc_scr = refs
-    elif tree:
-        q_ref, tb_ref, k_ref, v_ref, out_ref, lse_ref, \
-            m_scr, l_scr, acc_scr = refs
-    elif block_scales:
-        q_ref, k_ref, v_ref, ks_ref, vs_ref, out_ref, lse_ref, \
-            m_scr, l_scr, acc_scr = refs
-        tb_ref = None
-    else:
-        q_ref, k_ref, v_ref, out_ref, lse_ref, m_scr, l_scr, acc_scr = refs
-        tb_ref = None
+    The index map clamps its DMA to pool row 0 (some valid row must
+    stream); the body masks a remote entry's columns and skips a step
+    whose entries are all remote, so the online-softmax state accumulates
+    exactly this shard's partial; rows whose every block is remote
+    finalize to the ``(0, -inf)`` merge identity that
+    :func:`tree_attention_tpu.parallel.tree._weigh` absorbs."""
+    refs = list(refs)
+    lead, refs = refs[:n_lead], refs[n_lead:]
+    tb_ref = refs.pop(0) if tree else None
+    k_refs, v_refs, refs = refs[:entries], refs[entries:2 * entries], \
+        refs[2 * entries:]
+    ks_ref, vs_ref = (refs.pop(0), refs.pop(0)) if block_scales \
+        else (None, None)
+    out_ref, lse_ref, m_scr, l_scr, acc_scr = refs
     qi = pl.program_id(1)
     si = pl.program_id(2)
     n_s = pl.num_programs(2)
-    tk = n_s * block_k  # logical capacity; block-divisible by construction
+    bq, bk = block_q, entries * block
+    tk = n_s * bk  # logical capacity; step-divisible by construction
 
-    b = pl.program_id(0) // n_kv_heads
+    b = pl.program_id(0) // head_groups
     q_offset = offs_ref[0, b]
     kv_offset = offs_ref[1, b]
 
@@ -418,159 +450,157 @@ def _flash_decode_paged_kernel(
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    bq, bk = block_q, block_k
 
     live = si * bk < tk
     if causal:
         live &= (kv_offset + si * bk) <= (q_offset + tq - 1)
     if local_blocks:
-        live &= tbl_ref[b, si] >= 0
+        owner = [tbl_ref[b, si * entries + j] for j in range(entries)]
+        live &= functools.reduce(jnp.maximum, owner) >= 0
+
+    def by_entry(values):
+        """``values[j]`` (a scalar, or an array whose last dim is ``bk``)
+        over entry ``j``'s ``block`` columns: compares and selects only."""
+        col = lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+        row = jnp.broadcast_to(values[0], jnp.broadcast_shapes(
+            jnp.shape(values[0]), (1, bk)))
+        for j in range(1, entries):
+            row = jnp.where(col >= j * block, values[j], row)
+        return row
+
+    def block_scale(ref):
+        """The step's dequant scalars as a ``(heads, 1, bk)`` tile, ready
+        to multiply the score / probability tile. The operand is already
+        lane-broadcast, so a row is only cut (or repeated) along the lanes
+        to ``bk``, and the multiply spreads it over the sublanes — Mosaic
+        has no broadcast of a ``(1, 1)`` tile over sublanes and lanes at
+        once."""
+        first = (si * entries) % _SCALE_ROWS
+        rows = []
+        for j in range(entries):
+            row = ref[0, :, pl.ds(first + j, 1), :]  # (heads, 1, LANES)
+            if bk > _LANES:
+                row = jnp.concatenate([row] * -(-bk // _LANES), axis=2)
+            rows.append(row[:, :, :bk])
+        return by_entry(rows)
+
+    def tile(entry_refs):
+        """The step's blocks end to end: ``(heads, bk, D)``."""
+        if entries == 1:
+            return entry_refs[0][0]
+        return jnp.concatenate([r[0] for r in entry_refs], axis=1)
 
     @pl.when(live)
     def _compute():
-        k_tile = k_ref[0, 0]
+        s = scores(*lead, tile(k_refs))  # (heads, bq, bk)
+        if ks_ref is not None:
+            s = s * block_scale(ks_ref)  # each block's K dequant
+        # One mask for every head: packed row j is query j % Tq in all.
+        s = _decode_visibility_mask(
+            s, qi, si, bq=bq, bk=bk, tq=tq, tk=tk,
+            q_offset=q_offset, kv_offset=kv_offset, causal=causal,
+            tree_bits=None if tb_ref is None else tb_ref[0, 0][:, :1],
+        )
+        if local_blocks and entries > 1:
+            # A remote entry inside a live step: its columns are masked
+            # (its DMA brought pool row 0, any finite rows).
+            s = jnp.where(by_entry(owner) >= 0, s, NEG_INF)
+        _decode_softmax_fold(
+            s, tile(v_refs), m_scr, l_scr, acc_scr, si=si, bk=bk, tk=tk,
+            v_scale=None if vs_ref is None else block_scale(vs_ref),
+        )
+
+    @pl.when(si == n_s - 1)
+    def _finalize():
+        _decode_finalize(out_ref, lse_ref, m_scr, l_scr, acc_scr)
+
+
+def _flash_decode_paged_kernel(offs_ref, tbl_ref, *refs, scale: float,
+                               **step):
+    """Block-table variant of :func:`_flash_decode_kernel`; the step is
+    :func:`_paged_decode_step`'s. bf16 (or any float) pool, or an int8
+    pool cast tile by tile to bf16 (exact for [-127, 127])."""
+
+    def scores(q_ref, k_tile):
         if k_tile.dtype == jnp.int8:
             k_tile = k_tile.astype(jnp.bfloat16)
-        s = lax.dot_general(
+        return lax.dot_general(
             q_ref[0],
             k_tile,
-            dimension_numbers=(((1,), (1,)), ((), ())),
+            dimension_numbers=(((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
             precision=matmul_precision(q_ref.dtype, k_tile.dtype),
         ) * scale
-        if ks_ref is not None:
-            s = s * _block_scale(ks_ref, si, bq, bk)  # this block's K dequant
 
-        s = _decode_visibility_mask(
-            s, qi, si, bq=bq, bk=bk, tq=tq, tk=tk,
-            q_offset=q_offset, kv_offset=kv_offset, causal=causal,
-            tree_bits=None if tb_ref is None else tb_ref[0][:, :1],
-        )
-        _decode_softmax_fold(
-            s, v_ref[0, 0], m_scr, l_scr, acc_scr, si=si, bk=bk, tk=tk,
-            v_scale=(None if vs_ref is None
-                     else _block_scale(vs_ref, si, bq, bk)),
-        )
-
-    @pl.when(si == n_s - 1)
-    def _finalize():
-        _decode_finalize(out_ref, lse_ref, m_scr, l_scr, acc_scr)
+    _paged_decode_step(offs_ref, tbl_ref, refs, scores, n_lead=1, **step)
 
 
-def _flash_decode_paged_q8q_kernel(
-    offs_ref,  # SMEM (2, B) scalar-prefetch
-    tbl_ref,   # SMEM (B, NB) scalar-prefetch block table
-    *refs,     # q_ref, qs_ref, [tb_ref when tree], k_ref, v_ref, out_ref,
-               # lse_ref, m_scr, l_scr, acc_scr:
-               #   q_ref   VMEM (1, bq, D) int8 — per-row-quantized,
-               #           scale-folded Q
-               #   qs_ref  VMEM (1, bq, LANES) f32 — per-row Q scales
-               #   tb_ref  VMEM (1, bq, LANES) int32 — tree bitmasks
-               #   k/v_ref VMEM (1, 1, block, D) int8 — pool block
-               #           tbl[b, si]
-    causal: bool,
-    tq: int,
-    block_q: int,
-    block_k: int,
-    n_kv_heads: int,
-    tree: bool = False,
-    block_scales: bool = False,
-):
+def _flash_decode_paged_q8q_kernel(offs_ref, tbl_ref, *refs, **step):
     """Block-table variant of :func:`_flash_decode_q8q_kernel` — same
-    int8-MXU score path, KV streamed through the scalar-prefetched
-    table (see :func:`_flash_decode_paged_kernel`). With
-    ``block_scales`` (ISSUE 13) the per-BLOCK K/V dequant scalars ride
-    two extra lane-broadcast operands: K's joins the per-row Q scale in
-    the post-matmul rescale (both are scalars w.r.t. the int8 dot, so
-    the MXU path stays int8 × int8 → int32), V's folds into ``p``."""
-    del tbl_ref
-    ks_ref = vs_ref = None
-    if tree and block_scales:
-        q_ref, qs_ref, tb_ref, k_ref, v_ref, ks_ref, vs_ref, out_ref, \
-            lse_ref, m_scr, l_scr, acc_scr = refs
-    elif tree:
-        q_ref, qs_ref, tb_ref, k_ref, v_ref, out_ref, lse_ref, \
-            m_scr, l_scr, acc_scr = refs
-    elif block_scales:
-        q_ref, qs_ref, k_ref, v_ref, ks_ref, vs_ref, out_ref, lse_ref, \
-            m_scr, l_scr, acc_scr = refs
-        tb_ref = None
-    else:
-        q_ref, qs_ref, k_ref, v_ref, out_ref, lse_ref, \
-            m_scr, l_scr, acc_scr = refs
-        tb_ref = None
-    qi = pl.program_id(1)
-    si = pl.program_id(2)
-    n_s = pl.num_programs(2)
-    tk = n_s * block_k
+    int8-MXU score path (``q_ref`` int8, per-row-quantized and scale-folded;
+    ``qs_ref`` its per-row scales), KV streamed through the
+    scalar-prefetched table by :func:`_paged_decode_step`. With
+    ``block_scales`` (ISSUE 13) the per-BLOCK K scalars join the per-row Q
+    scale in the post-matmul rescale (both are scalars w.r.t. the int8 dot,
+    so the MXU path stays int8 x int8 -> int32), V's fold into ``p``."""
 
-    b = pl.program_id(0) // n_kv_heads
-    q_offset = offs_ref[0, b]
-    kv_offset = offs_ref[1, b]
-
-    @pl.when(si == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    bq, bk = block_q, block_k
-
-    live = si * bk < tk
-    if causal:
-        live &= (kv_offset + si * bk) <= (q_offset + tq - 1)
-
-    @pl.when(live)
-    def _compute():
+    def scores(q_ref, qs_ref, k_tile):
         s_i = lax.dot_general(
             q_ref[0],
-            k_ref[0, 0],
-            dimension_numbers=(((1,), (1,)), ((), ())),
+            k_tile,
+            dimension_numbers=(((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.int32,
         )
-        s = s_i.astype(jnp.float32) * qs_ref[0][:, :1]
-        if ks_ref is not None:
-            s = s * _block_scale(ks_ref, si, bq, bk)  # this block's K dequant
+        return s_i.astype(jnp.float32) * qs_ref[0][..., :1]
 
-        s = _decode_visibility_mask(
-            s, qi, si, bq=bq, bk=bk, tq=tq, tk=tk,
-            q_offset=q_offset, kv_offset=kv_offset, causal=causal,
-            tree_bits=None if tb_ref is None else tb_ref[0][:, :1],
-        )
-        _decode_softmax_fold(
-            s, v_ref[0, 0], m_scr, l_scr, acc_scr, si=si, bk=bk, tk=tk,
-            v_scale=(None if vs_ref is None
-                     else _block_scale(vs_ref, si, bq, bk)),
-        )
-
-    @pl.when(si == n_s - 1)
-    def _finalize():
-        _decode_finalize(out_ref, lse_ref, m_scr, l_scr, acc_scr)
+    _paged_decode_step(offs_ref, tbl_ref, refs, scores, n_lead=2, **step)
 
 
 def _paged_q_map(bh, qi, si, offs_ref, tbl_ref):
-    """Q/out/lse index map of the paged decode grid (table unused)."""
+    """Q/out/lse index map of the latent paged decode grid (table unused)."""
     del si, offs_ref, tbl_ref
     return (bh, qi, 0)
 
 
-def _paged_kv_map(n_kv_heads: int, local: bool = False):
-    """K/V index map: grid step ``si`` loads pool block
-    ``table[b, si]`` of head ``bh % Hkv`` — the block-table indirection
-    happens HERE, in the prefetch-driven DMA schedule, not in the body.
+def _paged_rows_map(head_groups: int):
+    """Index map of the paged decode grid's per-row operands (q, q scales,
+    tree bits, out, lse), all ``(B, Hkv, rows, lanes)``: grid dim 0 runs
+    over slots x head groups (one group, every head, unless
+    ``paged_decode_step`` had to split them)."""
+
+    def index_map(bh, qi, si, offs_ref, tbl_ref):
+        del si, offs_ref, tbl_ref
+        return (bh // head_groups, bh % head_groups, qi, 0)
+
+    return index_map
+
+
+def _paged_kv_map(j: int, entries: int, head_groups: int,
+                  local: bool = False):
+    """K/V index map of a step's ``j``-th entry: grid step ``si`` loads
+    every head (of this head group) of pool block
+    ``table[b, si * entries + j]`` — the block-table indirection happens
+    HERE, in the prefetch-driven DMA schedule, not in the body.
 
     ``local`` (ISSUE 18): the table is signed; a negative entry marks a
     block this shard does not own. The DMA engine still needs SOME valid
-    pool row, so the map clamps to 0 — the body's ``tbl_ref[b, si] >= 0``
-    gate drops the streamed tile before it touches the softmax state."""
+    pool row, so the map clamps to 0 — the body masks the entry's columns
+    before they touch the softmax state.
+
+    Entries past a slot's length all read 0 (the engine keeps them there),
+    so the first step past the length streams ``entries`` copies of pool
+    block 0 and the steps after it, whose indices no longer change, stream
+    nothing. Holding those steps at the slot's last live step instead (so
+    that they stream nothing at all) was tried on the chip and lost: the
+    extra scalar work in every index map cost more than the fetch it saved
+    (``ops/tuning.py``)."""
 
     def index_map(bh, qi, si, offs_ref, tbl_ref):
         del qi, offs_ref
-        t = tbl_ref[bh // n_kv_heads, si]
+        t = tbl_ref[bh // head_groups, si * entries + j]
         if local:
             t = jnp.maximum(t, 0)
-        return (t, bh % n_kv_heads, 0, 0)
+        return (t, bh % head_groups, 0, 0)
 
     return index_map
 
@@ -578,102 +608,131 @@ def _paged_kv_map(n_kv_heads: int, local: bool = False):
 # The per-block scale operand streams in tiles of one f32 sublane group:
 # the TPU lowering needs a block's second-minor dim divisible by 8 (a
 # one-row block is refused), so each DMA brings 8 logical blocks' scalars
-# and the body picks its own row.
+# and the body picks its step's rows: the entries a step takes divide 8
+# (``tuning.PAGED_STEP_ENTRIES``), so a step never straddles two tiles.
 _SCALE_ROWS = 8
 
 
-def _paged_scale_map(bh, qi, si, offs_ref, tbl_ref):
+def _paged_scale_map(entries: int, head_groups: int):
     """Per-block scale operand map (ISSUE 13): the scales were pre-
     gathered per LOGICAL block (see :func:`_block_scale_rows`), so grid
-    step ``si`` reads the 8-row tile holding row ``si`` — no second table
-    dereference, and no re-fetch while ``si`` stays inside the tile."""
-    del qi, offs_ref, tbl_ref
-    return (bh, si // _SCALE_ROWS, 0)
+    step ``si`` reads the 8-row tile holding rows ``si * entries ...`` —
+    no second table dereference, and no re-fetch while the step stays
+    inside the tile."""
 
+    def index_map(bh, qi, si, offs_ref, tbl_ref):
+        del qi, offs_ref, tbl_ref
+        return (bh // head_groups, bh % head_groups,
+                (si * entries) // _SCALE_ROWS, 0)
 
-def _block_scale(ref, si, bq: int, bk: int):
-    """Grid step ``si``'s dequant scalar as a ``(bq, bk)`` tile, ready to
-    multiply the score/probability tile: its row of the ``(1, _SCALE_ROWS,
-    LANES)`` block :func:`_paged_scale_map` loaded. The operand is already
-    lane-broadcast, so the row only spreads over the sublanes and is cut
-    (or repeated) along the lanes to ``bk`` — Mosaic has no broadcast of a
-    ``(1, 1)`` tile over sublanes and lanes at once."""
-    row = ref[0, pl.ds(si % _SCALE_ROWS, 1), :]  # (1, LANES)
-    if bk <= _LANES:
-        return jnp.broadcast_to(row[:, :bk], (bq, bk))
-    reps, rem = divmod(bk, _LANES)
-    if rem:
-        raise ValueError(
-            f"per-block scales need a pool block <= {_LANES} or a multiple "
-            f"of it, got {bk}"
-        )
-    return jnp.broadcast_to(
-        jnp.concatenate([row] * reps, axis=1), (bq, bk)
-    )
+    return index_map
 
 
 def _block_scale_rows(scale: jax.Array, block_table: jax.Array) -> jax.Array:
     """Arrange ``(N, Hkv)`` per-block scale scalars into the
-    ``(B·Hkv, NB8, LANES)`` lane-broadcast operand the paged kernels read
+    ``(B, Hkv, NB8, LANES)`` lane-broadcast operand the paged kernels read
     (``NB8`` = the table width rounded up to :data:`_SCALE_ROWS`; the pad
     rows are never read) — one scalar per (slot, head, logical block),
     gathered through the table once per call (O(B·NB·Hkv) floats, noise
     next to the KV bytes the grid streams). The same VMEM idiom as the q8q
     per-row Q scales and the tree bitmasks."""
     N, Hkv = scale.shape
-    B, NB = block_table.shape
     g = scale[jnp.clip(block_table, 0, N - 1)]      # (B, NB, Hkv)
-    g = _pad_dim(jnp.moveaxis(g, 2, 1).reshape(B * Hkv, NB), 1, _SCALE_ROWS)
-    return jnp.broadcast_to(g[:, :, None], (*g.shape, _LANES))
+    g = _pad_dim(jnp.moveaxis(g, 2, 1), 2, _SCALE_ROWS)
+    return jnp.broadcast_to(g[..., None], (*g.shape, _LANES))
 
 
 def _paged_decode_call(
     kernel_body,
     kernel_kwargs,
-    tensors,
-    in_specs,
+    label: str,
+    rows,
+    k: jax.Array,
+    v: jax.Array,
     *,
+    scales=None,
+    tree: bool,
+    group: int,
+    tq: int,
+    bq: int,
+    causal: bool,
+    local_blocks: bool = False,
     q_offset,
     kv_offset,
     block_table: jax.Array,
-    batch: int,
-    n_q: int,
-    bq: int,
-    d: int,
     out_dtype,
     interpret: bool,
 ) -> Tuple[jax.Array, jax.Array]:
     """Shared ``pallas_call`` plumbing of the paged decode kernels.
 
-    Per-batch offsets AND the ``(B, NB)`` block table ride scalar
-    prefetch (``PrefetchScalarGridSpec``), the grid's sequential split-KV
-    dimension is the table width — one step per logical block — and the
-    K/V index maps dereference the table, so the DMA pipeline prefetches
-    physical blocks in logical order with no gather copy."""
+    ``rows`` holds the kernel's per-row operands (q; for q8q its row scales
+    too; last the tree bitmasks when ``tree``), each
+    ``(B, Hkv, n_q * bq, lanes)``; ``k`` / ``v`` are the
+    ``(N, Hkv, block, D)`` pools and ``scales`` the per-block ``(N, Hkv)``
+    pair of an int8 pool, if it has them. Per-batch offsets AND the
+    ``(B, NB)`` block table ride scalar prefetch
+    (``PrefetchScalarGridSpec``). ``tuning.paged_decode_step`` reads the
+    shapes and says how many heads and table entries a grid step takes: the
+    grid is ``(B x head groups, n_q, NB / entries)``, the pools are handed
+    in once an entry, each with its own index map into the table, so the
+    DMA pipeline prefetches physical blocks in logical order with no gather
+    copy. Returns ``(out, lse)`` as ``(B, Hq, Tq, D)`` in ``out_dtype`` and
+    ``(B, Hq, Tq)``."""
+    from tree_attention_tpu.ops.tuning import paged_decode_step
+
+    B, Hkv, n_rows, D = rows[0].shape
+    block = k.shape[2]
     NB = block_table.shape[1]
-    BH = tensors[0].shape[0]  # B * Hkv
-    offs = _offsets_smem(q_offset, kv_offset, batch)
-    tbl = jnp.asarray(block_table, jnp.int32)
+    n_q = n_rows // bq
+    heads, entries = paged_decode_step(
+        Hkv, block, D, k.dtype.itemsize, NB, bq)
+    head_groups = Hkv // heads
+    if obs.REGISTRY.enabled:
+        _KERNEL_BUILDS.labels(
+            kernel=label, heads=heads, entries=entries).inc()
+    rows_map = _paged_rows_map(head_groups)
+    tensors = list(rows)
+    in_specs = [
+        pl.BlockSpec((1, heads, bq, t.shape[3]), rows_map) for t in tensors
+    ]
+    for pool in (k, v):
+        tensors += [pool] * entries
+        in_specs += [
+            pl.BlockSpec(
+                (1, heads, block, D),
+                _paged_kv_map(j, entries, head_groups, local=local_blocks))
+            for j in range(entries)
+        ]
+    if scales is not None:
+        scale_map = _paged_scale_map(entries, head_groups)
+        tensors += [_block_scale_rows(s, block_table) for s in scales]
+        in_specs += [
+            pl.BlockSpec((1, heads, _SCALE_ROWS, _LANES), scale_map)
+        ] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(BH, n_q, NB),
+        grid=(B * head_groups, n_q, NB // entries),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, bq, d), _paged_q_map),
-            pl.BlockSpec((1, bq, _LANES), _paged_q_map),
+            pl.BlockSpec((1, heads, bq, D), rows_map),
+            pl.BlockSpec((1, heads, bq, _LANES), rows_map),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, _LANES), jnp.float32),
-            pltpu.VMEM((bq, _LANES), jnp.float32),
-            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((heads, bq, _LANES), jnp.float32),
+            pltpu.VMEM((heads, bq, _LANES), jnp.float32),
+            pltpu.VMEM((heads, bq, D), jnp.float32),
         ],
     )
-    return pl.pallas_call(
-        functools.partial(kernel_body, **kernel_kwargs),
+    out, lse = pl.pallas_call(
+        functools.partial(
+            kernel_body, **kernel_kwargs, causal=causal, tq=tq, block_q=bq,
+            block=block, entries=entries, head_groups=head_groups, tree=tree,
+            block_scales=scales is not None, local_blocks=local_blocks,
+        ),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((BH, n_q * bq, d), out_dtype),
-            jax.ShapeDtypeStruct((BH, n_q * bq, _LANES), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, n_rows, D), out_dtype),
+            jax.ShapeDtypeStruct((B, Hkv, n_rows, _LANES), jnp.float32),
         ],
         # Only the split-KV (table) dim is sequential, as in the
         # contiguous kernels.
@@ -684,7 +743,11 @@ def _paged_decode_call(
         # A stable name per kernel body, carried into the compiled module
         # (the custom call's op_name) and the profiler trace.
         name=kernel_body.__name__.strip("_").removesuffix("_kernel"),
-    )(offs, tbl, *tensors)
+    )(_offsets_smem(q_offset, kv_offset, B),
+      jnp.asarray(block_table, jnp.int32), *tensors)
+    r = group * tq
+    return (out[:, :, :r].reshape(B, Hkv * group, tq, D),
+            lse[:, :, :r, 0].reshape(B, Hkv * group, tq))
 
 
 def _mla_decode_paged_kernel(
@@ -798,8 +861,6 @@ def attention_pallas_mla_paged(
     NB = block_table.shape[1]
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    if obs.REGISTRY.enabled:
-        _KERNEL_BUILDS.labels(kernel="mla_paged").inc()
     # Every head reads the same rows: pack heads x Tq into the sublanes.
     # Decode (Tq = 1) is one 128-head tile a slot; chunk rows take tiles of
     # 1024 so that a slot's blocks are walked by few tiles.
@@ -808,6 +869,8 @@ def attention_pallas_mla_paged(
     qp = _pad_dim(q.reshape(B, r, W), 1, bq)
     n_q = qp.shape[1] // bq
     per = next(p for p in (4, 2, 1) if NB % p == 0)
+    if obs.REGISTRY.enabled:
+        _KERNEL_BUILDS.labels(kernel="mla_paged", heads=1, entries=per).inc()
     in_specs = [pl.BlockSpec((1, bq, W), _paged_q_map)] + [
         pl.BlockSpec((1, block, W), _mla_kv_map(j, per))
         for j in range(per)
@@ -870,6 +933,16 @@ def _tree_bits_rows(
     rows = _pad_dim(rows.reshape(B, Hkv, G * Tq), 2, bq)
     rows = rows.reshape(B * Hkv, n_q * bq, 1)
     return jnp.broadcast_to(rows, (B * Hkv, n_q * bq, _LANES))
+
+
+def _paged_rows(q_rows, tb):
+    """The paged call's per-row operands: the kernel's own, then the packed
+    tree bitmasks (if any) with the slot and head dims apart, as the paged
+    grid indexes them."""
+    if tb is None:
+        return q_rows
+    B, Hkv, n_rows, _ = q_rows[0].shape
+    return [*q_rows, tb.reshape(B, Hkv, n_rows, _LANES)]
 
 
 def resolve_q8_kernel(kernel: str):
@@ -982,42 +1055,20 @@ def attention_pallas_decode_q8(
             interpret = jax.default_backend() != "tpu"
         out_dtype = q.dtype
         sm = (D ** -0.5) if scale is None else scale
-        r = G * Tq
-        bq = min(-(-r // 8) * 8, 128)
+        bq = min(-(-(G * Tq) // 8) * 8, 128)
         qp = _pad_dim(
-            q.astype(jnp.bfloat16).reshape(B, Hkv, r, D), 2, bq
-        ).reshape(B * Hkv, -1, D)
-        n_q = qp.shape[1] // bq
-        blk = k_q.shape[2]
-        if obs.REGISTRY.enabled:
-            _KERNEL_BUILDS.labels(kernel="paged_q8_block").inc()
-        tensors = [qp, k_q, v_q,
-                   _block_scale_rows(k_scale, block_table),
-                   _block_scale_rows(v_scale, block_table)]
-        in_specs = [
-            pl.BlockSpec((1, bq, D), _paged_q_map),
-            pl.BlockSpec((1, 1, blk, D), _paged_kv_map(Hkv)),
-            pl.BlockSpec((1, 1, blk, D), _paged_kv_map(Hkv)),
-            pl.BlockSpec((1, _SCALE_ROWS, _LANES), _paged_scale_map),
-            pl.BlockSpec((1, _SCALE_ROWS, _LANES), _paged_scale_map),
-        ]
-        if tree_mask is not None:
-            tensors.insert(1, _tree_bits_rows(tree_mask, G, Hkv, bq, n_q))
-            in_specs.insert(1, pl.BlockSpec((1, bq, _LANES), _paged_q_map))
+            q.astype(jnp.bfloat16).reshape(B, Hkv, G * Tq, D), 2, bq)
+        tb = None if tree_mask is None else _tree_bits_rows(
+            tree_mask, G, Hkv, bq, qp.shape[2] // bq)
         out, lse = _paged_decode_call(
-            _flash_decode_paged_kernel,
-            dict(scale=sm, causal=causal, tq=Tq, block_q=bq, block_k=blk,
-                 n_kv_heads=Hkv, tree=tree_mask is not None,
-                 block_scales=True),
-            tensors,
-            in_specs,
+            _flash_decode_paged_kernel, dict(scale=sm), "paged_q8_block",
+            _paged_rows([qp], tb), k_q, v_q, scales=(k_scale, v_scale),
+            tree=tb is not None, group=G, tq=Tq, bq=bq, causal=causal,
             q_offset=q_offset, kv_offset=kv_offset,
-            block_table=block_table, batch=B, n_q=n_q, bq=bq, d=D,
-            out_dtype=jnp.bfloat16, interpret=interpret,
+            block_table=block_table, out_dtype=jnp.bfloat16,
+            interpret=interpret,
         )
-        out = out[:, :r].reshape(B, Hq, Tq, D).astype(out_dtype)
-        lse = lse[:, :r, 0].reshape(B, Hq, Tq)
-        return out, lse
+        return out.astype(out_dtype), lse
     if k_scale.shape != (B, Hkv, 1, D) or v_scale.shape != (B, Hkv, 1, D):
         raise ValueError(
             f"scales must be (B, Hkv, 1, D) = {(B, Hkv, 1, D)}, got "
@@ -1154,63 +1205,33 @@ def attention_pallas_decode_q8q(
     q_i, qs = quantize_symmetric_int8(qf, axis=3)
 
     bq = min(-(-r // 8) * 8, 128)
-    qp = _pad_dim(q_i, 2, bq).reshape(B * Hkv, -1, D)
-    n_q = qp.shape[1] // bq
+    qp = _pad_dim(q_i, 2, bq)                       # (B, Hkv, n_q * bq, D)
+    n_q = qp.shape[2] // bq
     # Padded rows get scale 0 — their int32 scores then rescale to exactly
     # 0 everywhere, a harmless finite value (the host slices those rows
     # away; under causality they alias a real row's mask anyway).
     qsp = jnp.broadcast_to(
-        _pad_dim(qs, 2, bq).reshape(B * Hkv, n_q * bq, 1),
-        (B * Hkv, n_q * bq, _LANES),
-    )
+        _pad_dim(qs, 2, bq), (B, Hkv, n_q * bq, _LANES))
 
+    tb = None if tree_mask is None else _tree_bits_rows(
+        tree_mask, G, Hkv, bq, n_q)
     if block_table is not None:
-        if obs.REGISTRY.enabled:
-            _KERNEL_BUILDS.labels(kernel="paged_q8q").inc()
-        blk = k_q.shape[2]
-        tensors = [qp, qsp, k_q, v_q]
-        in_specs = [
-            pl.BlockSpec((1, bq, D), _paged_q_map),
-            pl.BlockSpec((1, bq, _LANES), _paged_q_map),
-            pl.BlockSpec((1, 1, blk, D), _paged_kv_map(Hkv)),
-            pl.BlockSpec((1, 1, blk, D), _paged_kv_map(Hkv)),
-        ]
-        if per_block:
-            tensors += [
-                _block_scale_rows(k_scale, block_table),
-                _block_scale_rows(v_scale, block_table),
-            ]
-            in_specs += [
-                pl.BlockSpec((1, _SCALE_ROWS, _LANES), _paged_scale_map),
-                pl.BlockSpec((1, _SCALE_ROWS, _LANES), _paged_scale_map),
-            ]
-        if tree_mask is not None:
-            tensors.insert(2, _tree_bits_rows(tree_mask, G, Hkv, bq, n_q))
-            in_specs.insert(
-                2, pl.BlockSpec((1, bq, _LANES), _paged_q_map)
-            )
         out, lse = _paged_decode_call(
-            _flash_decode_paged_q8q_kernel,
-            dict(causal=causal, tq=Tq, block_q=bq, block_k=blk,
-                 n_kv_heads=Hkv, tree=tree_mask is not None,
-                 block_scales=per_block),
-            tensors,
-            in_specs,
+            _flash_decode_paged_q8q_kernel, {}, "paged_q8q",
+            _paged_rows([qp, qsp], tb), k_q, v_q,
+            scales=(k_scale, v_scale) if per_block else None,
+            tree=tb is not None, group=G, tq=Tq, bq=bq, causal=causal,
             q_offset=q_offset, kv_offset=kv_offset,
-            block_table=block_table, batch=B, n_q=n_q, bq=bq, d=D,
-            out_dtype=jnp.bfloat16, interpret=interpret,
+            block_table=block_table, out_dtype=jnp.bfloat16,
+            interpret=interpret,
         )
-        out = out[:, :r]
-        if per_block:
-            # V dequant already happened in-kernel (per-block scalars
-            # fold into p); no per-channel epilogue remains.
-            out = out.reshape(B, Hq, Tq, D).astype(out_dtype)
-        else:
+        if not per_block:
+            # (per-block scalars dequantized V in-kernel, folded into p;
+            # per-channel scales apply to the normalised accumulator here)
             out = (
                 out.astype(jnp.float32).reshape(B, Hkv, r, D) * v_scale
-            ).reshape(B, Hq, Tq, D).astype(out_dtype)
-        lse = lse[:, :r, 0].reshape(B, Hq, Tq)
-        return out, lse
+            ).reshape(B, Hq, Tq, D)
+        return out.astype(out_dtype), lse
 
     if block_size is None:
         from tree_attention_tpu.ops.tuning import decode_block_k_q8
@@ -1220,11 +1241,13 @@ def attention_pallas_decode_q8q(
     kp = k_q.reshape(B * Hkv, Tk, D)
     vp = v_q.reshape(B * Hkv, Tk, D)
     n_s = -(-Tk // bk)
+    qp = qp.reshape(B * Hkv, n_q * bq, D)
+    qsp = qsp.reshape(B * Hkv, n_q * bq, _LANES)
 
     offs = _offsets_smem(q_offset, kv_offset, B)
 
     if obs.REGISTRY.enabled:
-        _KERNEL_BUILDS.labels(kernel="q8q").inc()
+        _KERNEL_BUILDS.labels(kernel="q8q", heads=1, entries=0).inc()
     tensors = [offs, qp, qsp, kp, vp]
     in_specs = [
         pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -1233,8 +1256,8 @@ def attention_pallas_decode_q8q(
         pl.BlockSpec((1, bk, D), lambda bh, qi, si: (bh, si, 0)),
         pl.BlockSpec((1, bk, D), lambda bh, qi, si: (bh, si, 0)),
     ]
-    if tree_mask is not None:
-        tensors.insert(3, _tree_bits_rows(tree_mask, G, Hkv, bq, n_q))
+    if tb is not None:
+        tensors.insert(3, tb)
         in_specs.insert(
             3,
             pl.BlockSpec((1, bq, _LANES), lambda bh, qi, si: (bh, qi, 0)),
@@ -1313,14 +1336,21 @@ def attention_pallas_decode(
     With ``block_table`` (a ``(B, NB)`` int32 array) the call is **paged**:
     ``k``/``v`` are ``(N, Hkv, block, D)`` pools and batch row ``b``'s
     logical KV block ``j`` lives in pool row ``block_table[b, j]``. The
-    table rides scalar prefetch, the index maps dereference it, and the
-    split-KV tile IS the pool block (``block_size`` is ignored — one grid
-    step per logical block; on a real TPU keep the pool block >= the
-    dtype's min sublane tile, 8/16/32 for f32/bf16/int8). Every entry
-    must be a valid pool index; entries past a slot's length are masked
-    but still dereferenced (the engine keeps them at 0). Bit-exact with
-    gathering ``pool[table]`` into a contiguous buffer and calling the
-    unpaged kernel — the tiles stream identical rows in identical order.
+    table rides scalar prefetch and the index maps dereference it. A grid
+    step takes every KV head of several consecutive table entries
+    (``ops/tuning.py`` ``paged_decode_step`` works out how many from the
+    shapes: 1 MB of K + V a step, 4 entries at 8 KV heads and 8 at 4 for
+    64-token bf16 blocks of 128; a width that 2 does not divide gets one
+    entry a step), so the split-KV tile is that many pool blocks end to end
+    and the grid is ``(B, Q tiles, NB / entries)``; ``block_size`` is
+    ignored. On a real TPU keep the pool block >= the dtype's min sublane
+    tile, 8/16/32 for f32/bf16/int8. Every entry must be a valid pool
+    index; entries past a slot's length are masked but still dereferenced
+    (the engine keeps them at 0). Bit-exact with gathering ``pool[table]``
+    into a contiguous buffer and calling the unpaged kernel at
+    ``block_size = entries * block`` — the tiles stream identical rows in
+    identical order; at another tile the fold order differs and the results
+    agree to float tolerance.
 
     ``tree_mask`` (a ``(B, Tq, Tq)`` bool array; requires ``causal`` and
     ``Tq <= 32``) switches on the speculative tree-verification window
@@ -1330,8 +1360,9 @@ def attention_pallas_decode(
 
     ``local_blocks`` (ISSUE 18, requires ``block_table``): the table is a
     SIGNED per-shard local view — negative entries mark logical blocks
-    owned by other shards of a sequence-sharded pool. Those grid steps
-    clamp their DMA to row 0 and the body culls them, so the returned
+    owned by other shards of a sequence-sharded pool. Those entries clamp
+    their DMA to row 0 and the body masks their columns (a step that owns
+    none of its entries is skipped), so the returned
     ``(out, lse)`` is this shard's flash PARTIAL over its own blocks
     (rows with no local blocks emit the ``(0, -inf)`` merge identity).
     """
@@ -1379,44 +1410,25 @@ def attention_pallas_decode(
         )
 
     # Pack each KV head's queries (its whole GQA group × Tq rows) into the
-    # Q-tile sublanes: (B, Hq, Tq, D) -> (B·Hkv, r8, D).
+    # Q-tile sublanes: (B, Hq, Tq, D) -> (B, Hkv, r8, D).
     r = G * Tq
     bq = min(-(-r // 8) * 8, 128)
-    qp = _pad_dim(q.reshape(B, Hkv, r, D), 2, bq).reshape(B * Hkv, -1, D)
-    n_q = qp.shape[1] // bq
+    qp = _pad_dim(q.reshape(B, Hkv, r, D), 2, bq)
+    n_q = qp.shape[2] // bq
 
+    tb = None if tree_mask is None else _tree_bits_rows(
+        tree_mask, G, Hkv, bq, n_q)
     if block_table is not None:
-        if obs.REGISTRY.enabled:
-            _KERNEL_BUILDS.labels(
-                kernel="paged_q8" if k.dtype == jnp.int8 else "paged"
-            ).inc()
-        tensors = [qp, k, v]
-        kv_map = _paged_kv_map(Hkv, local=local_blocks)
-        in_specs = [
-            pl.BlockSpec((1, bq, D), _paged_q_map),
-            pl.BlockSpec((1, 1, k.shape[2], D), kv_map),
-            pl.BlockSpec((1, 1, k.shape[2], D), kv_map),
-        ]
-        if tree_mask is not None:
-            tensors.insert(1, _tree_bits_rows(tree_mask, G, Hkv, bq, n_q))
-            in_specs.insert(
-                1, pl.BlockSpec((1, bq, _LANES), _paged_q_map)
-            )
         out, lse = _paged_decode_call(
-            _flash_decode_paged_kernel,
-            dict(scale=s, causal=causal, tq=Tq, block_q=bq,
-                 block_k=k.shape[2], n_kv_heads=Hkv,
-                 tree=tree_mask is not None,
-                 local_blocks=local_blocks),
-            tensors,
-            in_specs,
+            _flash_decode_paged_kernel, dict(scale=s),
+            "paged_q8" if k.dtype == jnp.int8 else "paged",
+            _paged_rows([qp], tb), k, v, tree=tb is not None, group=G,
+            tq=Tq, bq=bq, causal=causal, local_blocks=local_blocks,
             q_offset=q_offset, kv_offset=kv_offset,
-            block_table=block_table, batch=B, n_q=n_q, bq=bq, d=D,
-            out_dtype=q.dtype, interpret=interpret,
+            block_table=block_table, out_dtype=q.dtype, interpret=interpret,
         )
-        out = out[:, :r].reshape(B, Hq, Tq, D).astype(out_dtype)
-        lse = lse[:, :r, 0].reshape(B, Hq, Tq)
-        return out, lse
+        return out.astype(out_dtype), lse
+    qp = qp.reshape(B * Hkv, n_q * bq, D)
 
     if block_size is None:
         from tree_attention_tpu.ops.tuning import decode_block_k, decode_block_k_q8
@@ -1445,7 +1457,8 @@ def attention_pallas_decode(
         # int8 operands here are the q8 (bf16-cast) path riding the base
         # kernel; the q8q wrapper has its own pallas_call and label.
         _KERNEL_BUILDS.labels(
-            kernel="q8" if k.dtype == jnp.int8 else "exact"
+            kernel="q8" if k.dtype == jnp.int8 else "exact",
+            heads=1, entries=0,
         ).inc()
     tensors = [offs, qp, kp, vp]
     in_specs = [
@@ -1454,8 +1467,8 @@ def attention_pallas_decode(
         pl.BlockSpec((1, bk, D), lambda bh, qi, si: (bh, si, 0)),
         pl.BlockSpec((1, bk, D), lambda bh, qi, si: (bh, si, 0)),
     ]
-    if tree_mask is not None:
-        tensors.insert(2, _tree_bits_rows(tree_mask, G, Hkv, bq, n_q))
+    if tb is not None:
+        tensors.insert(2, tb)
         in_specs.insert(
             2,
             pl.BlockSpec((1, bq, _LANES), lambda bh, qi, si: (bh, qi, 0)),

@@ -20,7 +20,7 @@ dispatcher and the custom VJP.
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Optional, Tuple
 
 # context-length upper bound -> block_k. Measured on v5e
 # (tools/tune_sweep.py, 2026-07-31): bigger contexts amortise the
@@ -57,6 +57,103 @@ def decode_block_k_q8(tk: int) -> int:
         if tk <= bound:
             return bk
     raise AssertionError("unreachable")
+
+
+# The paged decode kernel's grid step (flash_decode_paged*): a pool block is
+# the configuration's ``kv_block`` tokens (64 in both benchmark
+# configurations, tied to the radix cache's ``prefix_block``), so the tile
+# cannot grow by a setting; a step grows instead by taking every KV head of a
+# block (contiguous in the ``(N, Hkv, block, D)`` pool: one DMA) and several
+# table entries. With one head of one entry a step (16 KB of K and of V,
+# 40 ns of HBM time) the kernel's time was its step count times 239-265 ns
+# whatever the bytes (PERF_LEDGER.jsonl, PR 27: 19.59 ms over 81,920 steps a
+# tick at Mistral-7B's shapes, 8.69 ms over 32,768 at Yi-6B's): 5-6% of the
+# HBM roofline.
+#
+# Measured on v5e 2026-09-28 (a scratch sweep of the kernel alone, 128 calls
+# in one program over a 16-layer pool, as a tick makes them; us a call, best
+# of 7; in brackets the share of 819 GB/s the slots' live K + V bytes reach).
+# "served": slots at the lengths the cells serve (Mistral 200-1,500 of 2,560
+# tokens, Yi 300-3,700 of 4,096); "full": every slot at its capacity.
+#
+#   (heads, entries)      Mistral 16 x 8 x 40     Yi-6B 8 x 4 x 64
+#   a step                served      full        served      full
+#   (1, 1) the old grid   1306 ( 5%)  2271 ( 9%)  628 ( 7%)   912 ( 9%)
+#   (1, 8) heads off       585 (12%)   712 (29%)  251 (17%)   286 (29%)
+#   (all, 1)               208 (34%)   408 (50%)  181 (24%)   275 (30%)
+#   (all, 2)               158 (44%)   279 (74%)  122 (35%)   169 (48%)
+#   (all, 4)               144 (49%)   234 (88%)   94 (45%)   119 (69%)
+#   (all, 8)               154 (45%)   234 (88%)   86 (50%)   101 (81%)
+#
+# Heads in the step matter more than entries (a block's heads are one DMA),
+# and the step wants 1 MB: (8, 4) at Mistral's 8 KV heads, (4, 8) at Yi's 4;
+# past it nothing is gained and the steps that straddle a slot's end waste
+# more. A step costs 0.33 us (Mistral, one entry) to 2.9 us (2 MB): no
+# longer a constant, the bytes show. What is left in "served" is the steps
+# past a slot's length (each still costs a step, ~0.3 us) and the whole
+# steps at its tail. The heads are folded as one batched array: a Python
+# loop over the heads, each on its own slice of the scratch, took 223 / 416
+# us at (8, 4) and 142 / 203 at (4, 4) (the stores to one scratch ordered
+# the heads one after the other). Also tried and dropped: holding a step
+# past a slot's length at the slot's last live step, so that the pipeline
+# streams nothing for it (else the first such step fetches pool block 0
+# `entries` times). With the live step worked out in the index maps (a
+# division each) served / full read 258 / 458 (Mistral, head loop); handed
+# in as a third row of the offsets and a `min` in each map, 153 / 235
+# against 144 / 234 without (Mistral) and 91 / 104 against 86 / 101 (Yi):
+# the scalar work in every map of every step costs more than the fetch.
+PAGED_STEP_TARGET_BYTES = 1 << 20
+PAGED_STEP_ENTRIES = (1, 2, 4, 8)  # divisors of the 8-row scale tile
+# What a step may hold of the 16 MB of scoped VMEM a v5e kernel gets by
+# default: its K and V tiles twice (the pipeline double-buffers them), and
+# for every head in the step the Q-side tiles (q, out, lse and the per-row
+# scale / tree operands, double-buffered too) and the m / l / acc scratch.
+# The rest is left to the score and probability tiles the body makes.
+PAGED_STEP_VMEM_BYTES = 12 << 20
+
+
+def paged_decode_step(
+    n_kv_heads: int,
+    block: int,
+    d: int,
+    itemsize: int,
+    table_width: int,
+    block_q: int,
+) -> Tuple[int, int]:
+    """``(heads, entries)`` one grid step of the paged decode kernel takes,
+    worked out from the shapes the call sees.
+
+    ``heads``: every KV head of a pool block, unless the heads' Q-side state
+    at ``block_q`` packed rows would not fit under
+    :data:`PAGED_STEP_VMEM_BYTES` with one entry beside it (many heads at a
+    chunk's 128 rows); then the largest divisor of the head count that does.
+    ``entries``: the smallest of :data:`PAGED_STEP_ENTRIES` that divides the
+    table width and brings the step's K + V bytes to
+    :data:`PAGED_STEP_TARGET_BYTES`; where none reaches it (an int8 pool, a
+    few heads), the largest that divides and fits. An int8 pool halves an
+    entry's bytes, so the same rule hands it twice the entries.
+    """
+    entry = 2 * block * d * itemsize  # one head's K and V of one entry
+    # Per head, worst case (float32 q and out): q, out (D lanes) and lse,
+    # q-scale, tree bits (128 lanes), all twice; acc (D) and m, l (128).
+    rows = block_q * 4 * (5 * d + 8 * 128)
+
+    def fits(heads: int, entries: int) -> bool:
+        return heads * (2 * entries * entry + rows) <= PAGED_STEP_VMEM_BYTES
+
+    heads = next(
+        (h for h in range(n_kv_heads, 0, -1)
+         if n_kv_heads % h == 0 and fits(h, 1)),
+        1,
+    )
+    entries = 1
+    for per in PAGED_STEP_ENTRIES:
+        if table_width % per or not fits(heads, per):
+            continue
+        entries = per
+        if heads * per * entry >= PAGED_STEP_TARGET_BYTES:
+            break
+    return heads, entries
 
 
 # The one home of the TPU kernel-dispatch policy shared by flash_attention's
