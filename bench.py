@@ -623,8 +623,8 @@ def _serving_prefix_record():
     """Shared-prefix flood (ISSUE 5): TTFT p50/p95 with the radix prefix
     KV cache on vs off over a trace where >= 50% of requests share a
     512-token prompt prefix (RadixAttention, arXiv:2312.07104), plus the
-    chain_slope-priced ratio of one shared-prefix prefill vs the donated
-    pool gather that replaces it on a hit. CPU proxy; the avoided-prefill
+    chain_slope-priced shared-prefix prefill over the host table update
+    that replaces it on a hit. CPU proxy; the avoided-prefill
     structure transfers. See tree_attention_tpu/bench/serving.py."""
     from tree_attention_tpu.bench.serving import bench_serving_prefix_flood
 
@@ -646,11 +646,10 @@ def _serving_spec_record():
 
 
 def _serving_paged_record():
-    """Paged KV flood (ISSUE 6): paged vs contiguous layouts at EQUAL
-    pool bytes over the PR-5 shared-prefix flood — the chain_slope-priced
-    pool->slot gather vs the host table update that replaces it on a
-    paged hit (bytes_moved == 0), TTFT p50/p95 for both layouts, and
-    max concurrent requests when the paged pool is over-subscribed
+    """Paged KV flood (ISSUE 6): the PR-5 shared-prefix flood on the
+    paged pool — the host table update that is all a hit pays
+    (bytes_moved == 0), TTFT p50/p95, and max concurrent requests when
+    the same pool bytes are over-subscribed with more slots
     (PagedAttention, arXiv:2309.06180). CPU proxy; the zero-copy and
     capacity structure transfers. See tree_attention_tpu/bench/serving.py."""
     from tree_attention_tpu.bench.serving import bench_serving_paged_flood
@@ -1047,13 +1046,10 @@ def _summarize_record(name, rec):
         if reused is not None:
             out["tokens_reused_ratio"] = reused
     if name == "serving_paged_flood":
-        slope = rec.get("slope", {})
-        if "gather_avoided_ratio" in slope:
-            out["gather_avoided_ratio"] = slope["gather_avoided_ratio"]
         trace = rec.get("trace", {})
-        for key in ("ttft_p50_improvement", "max_concurrent_improvement"):
-            if key in trace:
-                out[key] = trace[key]
+        if "max_concurrent_improvement" in trace:
+            out["max_concurrent_improvement"] = \
+                trace["max_concurrent_improvement"]
         moved = trace.get("paged", {}).get("hit_bytes_moved")
         if moved is not None:
             out["paged_hit_bytes_moved"] = moved

@@ -76,14 +76,26 @@ class TestCLI:
     def test_bench_ring_decode_comparator(self):
         # The decode-shape race (VERDICT r3 item 1): tree vs ring (vs
         # Ulysses when heads divide) with HLO-measured comm accounting.
-        record, _ = run_cli(
-            "--device", "cpu", "--seq-len", "256", "--q-len", "1",
-            "--heads", "4", "--head-dim", "16", "--dtype", "float32",
-            "--iters", "3", "--warmup", "1",
-            "--mode", "bench", "--comparator", "ring-decode",
-            "--n-virtual-cpu", "4", "--mesh", "seq=4", "--causal",
-            timeout=300,
-        )
+        # The record's structure is the subject, not a CPU's timing: on a
+        # host that other test workers load, the slope guard may refuse
+        # to print a time the noise swamps ("measurement noise exceeds
+        # the workload", exit 1). That verdict is the guard working; ask
+        # again, a bounded number of times.
+        for attempt in range(4):
+            try:
+                record, _ = run_cli(
+                    "--device", "cpu", "--seq-len", "256", "--q-len", "1",
+                    "--heads", "4", "--head-dim", "16", "--dtype", "float32",
+                    "--iters", "8", "--warmup", "1",
+                    "--mode", "bench", "--comparator", "ring-decode",
+                    "--n-virtual-cpu", "4", "--mesh", "seq=4", "--causal",
+                    timeout=300,
+                )
+                break
+            except AssertionError as e:
+                if attempt == 3 or \
+                        "measurement noise exceeds the workload" not in str(e):
+                    raise
         assert {"tree", "ring", "ulysses", "tree_speedup_vs_ring"} <= set(record)
         n = 4
         assert record["tree"]["comm"]["ops"]["all-reduce"]["count"] == 2

@@ -322,7 +322,7 @@ def test_grouped_matmul_kernel_equals_ragged_dot():
 
 def _engine(tcfg, params, **kw):
     args = dict(slots=3, cache_len=96, prefill_chunk=16, prefix_cache=True,
-                prefix_block=8, kv_layout="paged", admission="chunked")
+                prefix_block=8, admission="chunked")
     args.update(kw)
     return SlotServer(params, tcfg, **args)
 
@@ -435,7 +435,6 @@ def test_expert_counters_ride_the_fetch_and_stay_off_when_off(ref, adapter):
 
 
 @pytest.mark.parametrize("kw, named", [
-    (dict(kv_layout="contiguous"), "contiguous layout"),
     (dict(quantize=True), "int8 latent rows"),
     (dict(kv_shard="seq"), "sequence-sharded"),
     (dict(host_blocks=4), "host tier"),
@@ -450,7 +449,6 @@ def test_engine_refuses_what_a_latent_pool_does_not_carry(ref, adapter, kw,
 
 
 @pytest.mark.parametrize("flags, named", [
-    (["--kv-layout", "contiguous"], "--kv-layout contiguous"),
     (["--kv-quant", "int8"], "--kv-quant"),
     (["--kv-shard", "seq"], "--kv-shard seq"),
     (["--host-blocks", "4", "--prefix-cache", "--prefix-block", "8"],

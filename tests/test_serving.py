@@ -3,7 +3,9 @@
 The four parity contracts of the ragged decode stack:
 
 (a) **equal-length slots reproduce lockstep generate() token-for-token**
-    (exact and quantized cache) — raggedness is a strict generalisation;
+    (exact pool; the int8 pool reproduces a plain ``forward_step`` loop
+    over a hand-built int8 pool, ``paged_int8_stream``) — raggedness is
+    a strict generalisation;
 (b) **mixed lengths match per-request single-stream decode** — no slot
     reads another slot's cache rows, ever;
 (c) **scheduler property**: a random admit/retire trace delivers every
@@ -12,6 +14,13 @@ The four parity contracts of the ragged decode stack:
     chunks fused into the per-tick mixed-Tq step — for chunk sizes that
     do and do not divide the prompt, exact AND int8 (staged
     quantize-at-final-chunk) — produce bit-identical tokens.
+
+The engine under test is the one that serves: the paged pool, at pages
+of ``attn_block_size`` tokens so that the engine and its references fold
+identical KV tiles in identical order (two pages a slot at ``cache_len``
+32). Cases that share a configuration share one engine (``engine``
+fixture): a drained engine serves the next trace from a clean state, and
+every instance pays its own compiles.
 
 Everything here is CPU-safe and fast-tier: plain jnp paths plus the Pallas
 kernels in interpret mode, meshes from ``cpu_mesh``.
@@ -34,21 +43,9 @@ from tree_attention_tpu.models import (
 from tree_attention_tpu.ops import attention_naive
 from tree_attention_tpu.ops.decode import default_num_splits, flash_decode
 from tree_attention_tpu.parallel import cpu_mesh
-import functools
+from tree_attention_tpu.serving import Request, SlotServer, synthetic_trace
 
-from tree_attention_tpu.serving import Request, synthetic_trace
-from tree_attention_tpu.serving import SlotServer as _SlotServer
-
-# This module pins the LAYOUT-INDEPENDENT serving machinery (the ragged
-# mixed-Tq contract, scheduler lifecycle, chunked==whole, SLO/obs) — it
-# runs on the contiguous layout to keep the tier-1 time budget: the
-# paged layout compiles bigger per-instance programs (gather/scatter
-# through the block table), measured +146s over this file on the CI
-# box. Paged coverage is NOT lost: tests/test_serving_paged.py pins
-# paged == contiguous token-for-token across exact/int8 × chunked/whole
-# (so every parity here transfers transitively), and
-# tests/test_serving_prefix.py exercises the full paged default.
-SlotServer = functools.partial(_SlotServer, kv_layout="contiguous")
+from tests.test_serving_paged import paged_int8_stream
 
 CFG = TransformerConfig(
     vocab_size=128,
@@ -65,9 +62,27 @@ CFG = TransformerConfig(
 )
 
 
+KV_BLOCK = CFG.attn_block_size
+
+
 @pytest.fixture(scope="module")
 def params():
     return init_params(jax.random.PRNGKey(0), CFG)
+
+
+@pytest.fixture(scope="module")
+def engine(params):
+    """``engine(**kw)``: the ``SlotServer`` of that configuration, built
+    once for the module."""
+    built = {}
+
+    def get(**kw):
+        key = tuple(sorted(kw.items()))
+        if key not in built:
+            built[key] = SlotServer(params, CFG, kv_block=KV_BLOCK, **kw)
+        return built[key]
+
+    return get
 
 
 def _single_stream(params, prompt, n_new, cache_len=64):
@@ -232,32 +247,35 @@ def _as_requests(prompt, n_new, **kw):
     ]
 
 
-def test_equal_slots_reproduce_lockstep_generate(params):
+def test_equal_slots_reproduce_lockstep_generate(params, engine):
     B, Tp, n_new = 3, 12, 6
     prompt = jax.random.randint(jax.random.PRNGKey(2), (B, Tp), 0,
                                 CFG.vocab_size)
     ref = np.asarray(generate(params, prompt, n_new, CFG, cache_len=32))
-    server = SlotServer(params, CFG, slots=B, cache_len=32)
+    server = engine(slots=B, cache_len=32)
     report = server.serve(_as_requests(prompt, n_new))
     got = np.stack([np.asarray(r.tokens) for r in report.results])
     np.testing.assert_array_equal(got, ref)
     assert report.tokens_generated == B * n_new
 
 
-def test_equal_slots_reproduce_lockstep_generate_quantized(params):
-    """Same contract through the int8 cache: per-slot quantize-after-
-    prefill must equal the lockstep quantized path token-for-token."""
+def _int8_stream(params, prompt, n_new, cache_len=32):
+    return paged_int8_stream(params, CFG, prompt, n_new,
+                             cache_len=cache_len, kv_block=KV_BLOCK)
+
+
+def test_equal_slots_reproduce_lockstep_generate_quantized(params, engine):
+    """Same contract through the int8 pool: each slot's per-block
+    quantize-after-prefill must equal, token-for-token, a plain
+    ``forward_step`` loop over its own hand-built int8 pool — whatever
+    its neighbours hold."""
     B, Tp, n_new = 2, 12, 5
     prompt = jax.random.randint(jax.random.PRNGKey(3), (B, Tp), 0,
                                 CFG.vocab_size)
-    ref = np.asarray(generate(
-        params, prompt, n_new, CFG, cache_len=32,
-        quantize_after_prefill=True,
-    ))
-    server = SlotServer(params, CFG, slots=B, cache_len=32, quantize=True)
+    server = engine(slots=B, cache_len=32, quantize=True)
     report = server.serve(_as_requests(prompt, n_new))
-    got = np.stack([np.asarray(r.tokens) for r in report.results])
-    np.testing.assert_array_equal(got, ref)
+    for r in report.results:
+        assert r.tokens == _int8_stream(params, prompt[r.uid], n_new)
 
 
 # ---------------------------------------------------------------------------
@@ -265,7 +283,7 @@ def test_equal_slots_reproduce_lockstep_generate_quantized(params):
 # ---------------------------------------------------------------------------
 
 
-def test_mixed_lengths_match_single_stream(params):
+def test_mixed_lengths_match_single_stream(params, engine):
     base = jax.random.randint(jax.random.PRNGKey(4), (4, 16), 0,
                               CFG.vocab_size)
     reqs = [
@@ -278,7 +296,7 @@ def test_mixed_lengths_match_single_stream(params):
         Request(uid=3, prompt=np.asarray(base[3][:9]), max_new_tokens=6,
                 arrival_tick=5),
     ]
-    server = SlotServer(params, CFG, slots=2, cache_len=32)
+    server = engine(slots=2, cache_len=32)
     report = server.serve(reqs)
     assert len(report.results) == len(reqs)
     for res in report.results:
@@ -302,16 +320,15 @@ def test_ragged_position_composes_with_data_axis(params):
     np.testing.assert_array_equal(np.asarray(toks), np.asarray(ref))
 
 
-def test_serving_mesh_matches_single_device(params):
-    """The same trace over a seq-sharded slot cache (tree merge per tick)
+def test_serving_mesh_matches_single_device(params, engine):
+    """The same trace over a sequence-sharded pool (tree merge per tick)
     reproduces the single-device tokens."""
     mesh = cpu_mesh(2)
     B, Tp, n_new = 2, 12, 4
     prompt = jax.random.randint(jax.random.PRNGKey(5), (B, Tp), 0,
                                 CFG.vocab_size)
-    ref_server = SlotServer(params, CFG, slots=B, cache_len=32)
-    ref = ref_server.serve(_as_requests(prompt, n_new))
-    mesh_server = SlotServer(params, CFG, slots=B, cache_len=32, mesh=mesh)
+    ref = engine(slots=B, cache_len=32).serve(_as_requests(prompt, n_new))
+    mesh_server = engine(slots=B, cache_len=32, mesh=mesh, kv_shard="seq")
     got = mesh_server.serve(_as_requests(prompt, n_new))
     for a, b in zip(ref.results, got.results):
         assert a.tokens == b.tokens, (a.uid, a.tokens, b.tokens)
@@ -322,7 +339,7 @@ def test_serving_mesh_matches_single_device(params):
 # ---------------------------------------------------------------------------
 
 
-def test_scheduler_property_random_trace(params):
+def test_scheduler_property_random_trace(params, engine):
     """Random prompts/lengths/budgets/arrivals through few slots: every
     request finishes with exactly its budget, token-identical to its own
     single-stream decode (no slot cross-talk), and scheduling invariants
@@ -337,7 +354,7 @@ def test_scheduler_property_random_trace(params):
             max_new_tokens=int(rng.integers(1, 8)),
             arrival_tick=int(rng.integers(0, 10)),
         ))
-    server = SlotServer(params, CFG, slots=3, cache_len=32)
+    server = engine(slots=3, cache_len=32)
     report = server.serve(reqs, max_ticks=500)
     assert sorted(r.uid for r in report.results) == list(range(7))
     for res in report.results:
@@ -353,7 +370,7 @@ def test_scheduler_property_random_trace(params):
     assert report.tokens_generated == sum(r.max_new_tokens for r in reqs)
 
 
-def test_eos_retires_slot_early(params):
+def test_eos_retires_slot_early(params, engine):
     """A sampled EOS frees the slot immediately (outcome 'eos', truncated
     output) — pinned against the request's own single-stream decode."""
     prompt = np.asarray(
@@ -361,7 +378,7 @@ def test_eos_retires_slot_early(params):
     )
     ref = _single_stream(params, prompt, 6, cache_len=32)
     eos = ref[2]  # force an early stop at the third sampled token
-    server = SlotServer(params, CFG, slots=2, cache_len=32)
+    server = engine(slots=2, cache_len=32)
     report = server.serve([
         Request(uid=0, prompt=prompt, max_new_tokens=6, eos_id=eos)
     ])
@@ -370,11 +387,11 @@ def test_eos_retires_slot_early(params):
     assert res.tokens == ref[:3]  # EOS included, nothing after
 
 
-def test_single_token_budget_retires_at_admit(params):
+def test_single_token_budget_retires_at_admit(params, engine):
     """max_new_tokens=1 finishes on the prefill sample alone — the trace
     drains entirely in the admit phase with zero decode ticks and must
     terminate cleanly (regression: the empty-queue fast-forward crashed)."""
-    server = SlotServer(params, CFG, slots=2, cache_len=32)
+    server = engine(slots=2, cache_len=32)
     prompt = jax.random.randint(jax.random.PRNGKey(9), (3, 6), 0,
                                 CFG.vocab_size)
     report = server.serve(_as_requests(prompt, 1))
@@ -387,38 +404,37 @@ def test_single_token_budget_retires_at_admit(params):
     assert report.tokens_generated == 3
 
 
-def test_admit_rejects_overcapacity(params):
-    server = SlotServer(params, CFG, slots=1, cache_len=16)
+def test_admit_rejects_overcapacity(engine):
+    server = engine(slots=1, cache_len=16)
     with pytest.raises(ValueError, match="capacity"):
         server.serve([
             Request(uid=0, prompt=np.zeros(12, np.int32), max_new_tokens=8)
         ])
 
 
-def test_serve_rejects_zero_token_budget(params):
+def test_serve_rejects_zero_token_budget(engine):
     """The prefill itself samples one token, so a zero budget is
     unservable — same contract as generate()."""
-    server = SlotServer(params, CFG, slots=1, cache_len=16)
+    server = engine(slots=1, cache_len=16)
     with pytest.raises(ValueError, match="max_new_tokens"):
         server.serve([
             Request(uid=0, prompt=np.zeros(4, np.int32), max_new_tokens=0)
         ])
 
 
-def test_serving_data_axis_mesh(params):
-    """A mesh with a data axis serves too: the B=1 prefill drops the data
-    axis (1 cannot shard over it) while the batched step keeps the full
-    spec (regression — the first admit crashed in shard_map)."""
+def test_serving_data_axis_mesh(params, engine):
+    """A mesh with a data axis serves too, over a sequence-sharded pool:
+    the B=1 prefill drops the data axis (1 cannot shard over it) while
+    the batched step keeps the full spec (regression — the first admit
+    crashed in shard_map)."""
     mesh = cpu_mesh(4, {"data": 2, "seq": 2})
     B, Tp, n_new = 2, 10, 4
     prompt = jax.random.randint(jax.random.PRNGKey(10), (B, Tp), 0,
                                 CFG.vocab_size)
-    got = SlotServer(params, CFG, slots=B, cache_len=16, mesh=mesh).serve(
+    got = engine(slots=B, cache_len=16, mesh=mesh, kv_shard="seq").serve(
         _as_requests(prompt, n_new)
     )
-    ref = SlotServer(params, CFG, slots=B, cache_len=16).serve(
-        _as_requests(prompt, n_new)
-    )
+    ref = engine(slots=B, cache_len=16).serve(_as_requests(prompt, n_new))
     for a, b in zip(ref.results, got.results):
         assert a.tokens == b.tokens, (a.uid, a.tokens, b.tokens)
 
@@ -514,7 +530,7 @@ def test_mixed_tq_forward_step_masked_window(params):
 
 @pytest.mark.parametrize("chunk", [4, 5])  # 4 divides the 12-token prompt,
                                            # 5 leaves a 2-token final chunk
-def test_chunked_equals_whole_admission_exact(params, chunk):
+def test_chunked_equals_whole_admission_exact(params, engine, chunk):
     """The tentpole parity: chunked admission (prefill fused into the tick
     at `chunk` tokens per slot per tick) is token-for-token identical to
     legacy whole-prompt admission, for chunk sizes that do and do not
@@ -522,12 +538,10 @@ def test_chunked_equals_whole_admission_exact(params, chunk):
     B, Tp, n_new = 3, 12, 6
     prompt = jax.random.randint(jax.random.PRNGKey(13), (B, Tp), 0,
                                 CFG.vocab_size)
-    whole = SlotServer(params, CFG, slots=B, cache_len=32,
-                       admission="whole")
+    whole = engine(slots=B, cache_len=32, admission="whole")
     ref = whole.serve(_as_requests(prompt, n_new))
-    chunked = SlotServer(params, CFG, slots=B, cache_len=32,
-                         admission="chunked", prefill_chunk=chunk,
-                         prefill_budget=chunk)
+    chunked = engine(slots=B, cache_len=32, admission="chunked",
+                     prefill_chunk=chunk, prefill_budget=chunk)
     got = chunked.serve(_as_requests(prompt, n_new))
     for a, b in zip(ref.results, got.results):
         assert a.tokens == b.tokens, (a.uid, a.tokens, b.tokens)
@@ -539,25 +553,26 @@ def test_chunked_equals_whole_admission_exact(params, chunk):
 
 
 @pytest.mark.parametrize("chunk", [4, 5])
-def test_chunked_equals_whole_admission_quantized(params, chunk):
-    """Same parity through the int8 cache: the staged exact prefill +
+def test_chunked_equals_whole_admission_quantized(params, engine, chunk):
+    """Same parity through the int8 pool: the staged exact prefill +
     quantize-at-final-chunk must reproduce the whole-prompt
-    quantize-after-prefill bit-for-bit (same rows, same frozen scales)."""
+    quantize-after-prefill bit-for-bit (same rows, the same per-block
+    frozen scales) — and both the plain int8 loop over each prompt."""
     B, Tp, n_new = 2, 12, 5
     prompt = jax.random.randint(jax.random.PRNGKey(14), (B, Tp), 0,
                                 CFG.vocab_size)
-    whole = SlotServer(params, CFG, slots=B, cache_len=32,
-                       admission="whole", quantize=True)
+    whole = engine(slots=B, cache_len=32, admission="whole", quantize=True)
     ref = whole.serve(_as_requests(prompt, n_new))
-    chunked = SlotServer(params, CFG, slots=B, cache_len=32,
-                         admission="chunked", quantize=True,
-                         prefill_chunk=chunk, prefill_budget=chunk)
+    chunked = engine(slots=B, cache_len=32, admission="chunked",
+                     quantize=True, prefill_chunk=chunk,
+                     prefill_budget=chunk)
     got = chunked.serve(_as_requests(prompt, n_new))
     for a, b in zip(ref.results, got.results):
         assert a.tokens == b.tokens, (a.uid, a.tokens, b.tokens)
+        assert b.tokens == _int8_stream(params, prompt[b.uid], n_new)
 
 
-def test_mid_prefill_arrival(params):
+def test_mid_prefill_arrival(params, engine):
     """Requests arriving while another slot is mid-prefill are admitted
     into free slots and everyone still matches single-stream decode — the
     scheduler interleaves chunks and decode without cross-talk."""
@@ -577,8 +592,8 @@ def test_mid_prefill_arrival(params):
                     np.int32),
                 max_new_tokens=3, arrival_tick=2),
     ]
-    server = SlotServer(params, CFG, slots=2, cache_len=32,
-                        prefill_chunk=4, prefill_budget=4)
+    server = engine(slots=2, cache_len=32, prefill_chunk=4,
+                    prefill_budget=4)
     report = server.serve(reqs, max_ticks=300)
     assert sorted(r.uid for r in report.results) == [0, 1, 2]
     for res in report.results:
@@ -588,15 +603,15 @@ def test_mid_prefill_arrival(params):
         ), f"request {res.uid} diverged under mid-prefill arrival"
 
 
-def test_eos_on_final_chunk(params):
+def test_eos_on_final_chunk(params, engine):
     """EOS sampled ON the final prefill chunk retires the slot before it
     ever decodes: outcome 'eos', exactly one token out."""
     prompt = np.asarray(
         jax.random.randint(jax.random.PRNGKey(16), (11,), 0, CFG.vocab_size)
     )
     first = _single_stream(params, prompt, 1, cache_len=32)[0]
-    server = SlotServer(params, CFG, slots=2, cache_len=32,
-                        prefill_chunk=4, prefill_budget=4)
+    server = engine(slots=2, cache_len=32, prefill_chunk=4,
+                    prefill_budget=4)
     report = server.serve([
         Request(uid=0, prompt=prompt, max_new_tokens=6, eos_id=first)
     ])
@@ -605,40 +620,42 @@ def test_eos_on_final_chunk(params):
     assert res.tokens == [first]
 
 
-def test_chunked_admission_mesh_parity(params):
-    """Chunked admission on a seq-sharded mesh (mixed-Tq step through the
-    tree merge, masked window writes on sharded buffers) reproduces the
-    single-device chunked tokens."""
+def test_chunked_admission_mesh_parity(params, engine):
+    """Chunked admission on a sequence-sharded pool (mixed-Tq step through
+    the tree merge, each shard writing the rows its own blocks hold)
+    reproduces the single-device chunked tokens."""
     mesh = cpu_mesh(2)
     B, Tp, n_new = 2, 12, 4
     prompt = jax.random.randint(jax.random.PRNGKey(17), (B, Tp), 0,
                                 CFG.vocab_size)
     kw = dict(slots=B, cache_len=32, prefill_chunk=5, prefill_budget=5)
-    ref = SlotServer(params, CFG, **kw).serve(_as_requests(prompt, n_new))
-    got = SlotServer(params, CFG, mesh=mesh, **kw).serve(
+    ref = engine(**kw).serve(_as_requests(prompt, n_new))
+    got = engine(mesh=mesh, kv_shard="seq", **kw).serve(
         _as_requests(prompt, n_new)
     )
     for a, b in zip(ref.results, got.results):
         assert a.tokens == b.tokens, (a.uid, a.tokens, b.tokens)
 
 
-def test_chunked_quantized_mesh_parity(params):
-    """The staged (quantized) chunked admission on a seq-sharded mesh:
-    staging, quantize-at-final-chunk, and insert all reshard correctly
-    and reproduce the single-device tokens."""
+def test_chunked_quantized_mesh_parity(params, engine):
+    """The staged (quantized) chunked admission on a sequence-sharded
+    int8 pool: staging, quantize-at-final-chunk, and insert all reshard
+    correctly and reproduce the single-device tokens, which are the
+    plain int8 loop's — a request's tokens do not depend on the mesh."""
     mesh = cpu_mesh(2)
     prompt = jax.random.randint(jax.random.PRNGKey(19), (2, 12), 0,
                                 CFG.vocab_size)
     kw = dict(slots=2, cache_len=32, quantize=True, prefill_chunk=5)
-    ref = SlotServer(params, CFG, **kw).serve(_as_requests(prompt, 5))
-    got = SlotServer(params, CFG, mesh=mesh, **kw).serve(
+    ref = engine(**kw).serve(_as_requests(prompt, 5))
+    got = engine(mesh=mesh, kv_shard="seq", **kw).serve(
         _as_requests(prompt, 5)
     )
     for a, b in zip(ref.results, got.results):
         assert a.tokens == b.tokens, (a.uid, a.tokens, b.tokens)
+        assert a.tokens == _int8_stream(params, prompt[a.uid], 5)
 
 
-def test_prefill_chunk_metrics(params):
+def test_prefill_chunk_metrics(engine):
     """serving_prefill_chunks_total counts scheduled chunks; TTFT/TBT
     histograms record once the registry is armed."""
     from tree_attention_tpu import obs
@@ -647,8 +664,8 @@ def test_prefill_chunk_metrics(params):
     try:
         reg = obs.REGISTRY
         chunks0 = reg.counter("serving_prefill_chunks_total").value()
-        server = SlotServer(params, CFG, slots=2, cache_len=32,
-                            prefill_chunk=4, prefill_budget=4)
+        server = engine(slots=2, cache_len=32, prefill_chunk=4,
+                        prefill_budget=4)
         prompt = jax.random.randint(jax.random.PRNGKey(18), (2, 10), 0,
                                     CFG.vocab_size)
         server.serve(_as_requests(prompt, 3))
@@ -668,14 +685,14 @@ def test_prefill_chunk_metrics(params):
 # ---------------------------------------------------------------------------
 
 
-def _traced_serve(params, tmp_path, reqs, **server_kw):
+def _traced_serve(engine, tmp_path, reqs, **server_kw):
     """Serve a trace with the span tracer armed; returns (report, events)."""
     from tree_attention_tpu import obs
 
     path = tmp_path / "serve_trace.jsonl"
     obs.TRACER.start(str(path))
     try:
-        server = SlotServer(params, CFG, **server_kw)
+        server = engine(**server_kw)
         report = server.serve(reqs)
     finally:
         obs.TRACER.close()
@@ -683,14 +700,14 @@ def _traced_serve(params, tmp_path, reqs, **server_kw):
     return report, events
 
 
-def test_request_spans_rid_propagation(params, tmp_path):
+def test_request_spans_rid_propagation(engine, tmp_path):
     """The tentpole trace contract: every request's life is one span plus
     queued/admitted/first_token/retired instants, all carrying its rid —
     loading the file shows each request from enqueue to retire."""
     prompt = jax.random.randint(jax.random.PRNGKey(20), (3, 10), 0,
                                 CFG.vocab_size)
     report, events = _traced_serve(
-        params, tmp_path, _as_requests(prompt, 4),
+        engine, tmp_path, _as_requests(prompt, 4),
         slots=2, cache_len=32, prefill_chunk=4, prefill_budget=4,
     )
     uids = {r.uid for r in report.results}
@@ -722,13 +739,13 @@ def test_request_spans_rid_propagation(params, tmp_path):
             if c["args"]["rid"] == min(uids)] == ["1/3", "2/3", "3/3"]
 
 
-def test_tick_spans_tag_occupancy_and_queue(params, tmp_path):
+def test_tick_spans_tag_occupancy_and_queue(engine, tmp_path):
     """Per-tick mixed-step spans carry occupancy, chunk-budget spent, and
     queue depth — the three numbers a stall post-mortem starts from."""
     prompt = jax.random.randint(jax.random.PRNGKey(21), (4, 8), 0,
                                 CFG.vocab_size)
     report, events = _traced_serve(
-        params, tmp_path, _as_requests(prompt, 3),
+        engine, tmp_path, _as_requests(prompt, 3),
         slots=2, cache_len=32, prefill_chunk=4,
     )
     ticks = [e for e in events if e["ph"] == "X"
@@ -746,9 +763,10 @@ def test_tick_spans_tag_occupancy_and_queue(params, tmp_path):
         == report.tokens_generated
 
 
-def test_flight_recorder_records_serving_ticks(params):
+def test_flight_recorder_records_serving_ticks(engine):
     """The engine feeds the ring one record per tick: occupancy vector,
-    slot states, chunk plan, host-sync flag, queue depth."""
+    slot states, chunk plan, host-sync flag, queue depth, and the pool's
+    block occupancy."""
     from tree_attention_tpu.obs.flight import FLIGHT
 
     prompt = jax.random.randint(jax.random.PRNGKey(22), (2, 9), 0,
@@ -756,8 +774,7 @@ def test_flight_recorder_records_serving_ticks(params):
     FLIGHT.clear()
     FLIGHT.arm()
     try:
-        server = SlotServer(params, CFG, slots=2, cache_len=32,
-                            prefill_chunk=4)
+        server = engine(slots=2, cache_len=32, prefill_chunk=4)
         report = server.serve(_as_requests(prompt, 3))
     finally:
         FLIGHT.disarm()
@@ -766,7 +783,10 @@ def test_flight_recorder_records_serving_ticks(params):
     recs = snap["records"]
     assert [r["tick"] for r in recs] == sorted(r["tick"] for r in recs)
     assert {"states", "chunk_plan", "tokens_emitted", "host_sync",
-            "queue_depth", "occupancy", "t_s"} <= set(recs[0])
+            "queue_depth", "occupancy", "t_s", "kv_blocks_used",
+            "kv_frag"} <= set(recs[0])
+    # Two 9-token prompts at pages of 16: one block each while they live.
+    assert max(r["kv_blocks_used"] for r in recs) == 2
     # Chunk ticks then live decode then drained.
     assert any(r["chunk_tokens"] > 0 for r in recs)
     assert any(r["occupancy"] == 2 for r in recs)
@@ -785,7 +805,9 @@ def test_flight_dump_on_engine_error(params, tmp_path):
     FLIGHT.clear()
     FLIGHT.arm(str(path))
     try:
-        server = SlotServer(params, CFG, slots=1, cache_len=32)
+        # Its own engine: the guard leaves requests in their slots.
+        server = SlotServer(params, CFG, slots=1, cache_len=32,
+                            kv_block=KV_BLOCK)
         with pytest.raises(RuntimeError, match="max_ticks"):
             server.serve(_as_requests(prompt, 8), max_ticks=3)
     finally:
@@ -803,7 +825,9 @@ def test_serve_report_slo_goodput_bounds(params):
     prompt = jax.random.randint(jax.random.PRNGKey(24), (2, 8), 0,
                                 CFG.vocab_size)
 
+    # Engines of its own: the window's counts are asserted below.
     relaxed = SlotServer(params, CFG, slots=2, cache_len=32,
+                         kv_block=KV_BLOCK,
                          slo_ttft=3600.0, slo_tbt=3600.0)
     rep = relaxed.serve(_as_requests(prompt, 3))
     assert rep.slo["goodput"] == 1.0
@@ -813,21 +837,22 @@ def test_serve_report_slo_goodput_bounds(params):
     )
 
     strict = SlotServer(params, CFG, slots=2, cache_len=32,
+                        kv_block=KV_BLOCK,
                         slo_ttft=1e-12, slo_tbt=1e-12)
     rep = strict.serve(_as_requests(prompt, 3))
     assert rep.slo["goodput"] == 0.0
     assert rep.as_dict()["slo"]["slo"] == {"ttft_s": 1e-12, "tbt_s": 1e-12}
 
 
-def test_slo_gauges_live_after_serve(params):
+def test_slo_gauges_live_after_serve(engine):
     """serve() publishes the windowed SLO gauges when the registry is
     armed — what a /metrics scrape sees."""
     from tree_attention_tpu import obs
 
     obs.enable()
     try:
-        server = SlotServer(params, CFG, slots=2, cache_len=32,
-                            slo_ttft=3600.0, slo_tbt=3600.0)
+        server = engine(slots=2, cache_len=32, slo_ttft=3600.0,
+                        slo_tbt=3600.0)
         prompt = jax.random.randint(jax.random.PRNGKey(25), (2, 8), 0,
                                     CFG.vocab_size)
         server.serve(_as_requests(prompt, 3))
@@ -846,7 +871,7 @@ def test_slo_gauges_live_after_serve(params):
         obs.disable()
 
 
-def test_serving_metrics_flow(params):
+def test_serving_metrics_flow(engine):
     """The four serving metrics record when the registry is armed."""
     from tree_attention_tpu import obs
 
@@ -854,7 +879,7 @@ def test_serving_metrics_flow(params):
     try:
         reg = obs.REGISTRY
         tokens0 = reg.counter("serving_tokens_total").value()
-        server = SlotServer(params, CFG, slots=2, cache_len=32)
+        server = engine(slots=2, cache_len=32)
         prompt = jax.random.randint(jax.random.PRNGKey(7), (2, 8), 0,
                                     CFG.vocab_size)
         report = server.serve(_as_requests(prompt, 3))

@@ -448,12 +448,6 @@ class TestTransferAudit:
         # deferral generation are all untouched.
         assert (alloc.available(), alloc.reserved, alloc.gen) == before
 
-    def test_engine_rejects_contiguous_shared_pool(self, params):
-        with pytest.raises(ValueError, match="paged"):
-            SlotServer(params, CFG, slots=1, cache_len=CACHE_LEN,
-                       kv_layout="contiguous",
-                       block_pool=BlockAllocator(4))
-
     def test_engine_rejects_mismatched_kv_blocks(self, params):
         with pytest.raises(ValueError, match="contradicts"):
             SlotServer(params, CFG, slots=1, cache_len=CACHE_LEN,
@@ -523,12 +517,6 @@ class TestCLIValidation:
 
         with pytest.raises(SystemExit, match="exclusive"):
             _run_serve(self._cfg(serve_fleet=True), None)
-
-    def test_requires_paged_layout(self):
-        from tree_attention_tpu.cli import _run_serve
-
-        with pytest.raises(SystemExit, match="paged"):
-            _run_serve(self._cfg(kv_layout="contiguous"), None)
 
     def test_decode_slots_must_remain(self):
         from tree_attention_tpu.cli import _run_serve

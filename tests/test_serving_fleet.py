@@ -494,6 +494,13 @@ class TestRouterHardening:
                 conn.close()
             assert payloads[0]["choices"][0]["token_ids"] == [5]
             assert payloads[-1].get("finish_reason") == "error"
+            # The router's handler thread closes its books (finish():
+            # inflight back to 0) AFTER it wrote [DONE]; the client saw
+            # [DONE] first, so wait for the books, bounded.
+            deadline = time.monotonic() + 5.0
+            while (router.stats()["replicas"]["mort"]["inflight"]
+                   and time.monotonic() < deadline):
+                time.sleep(0.01)
             assert router.stats()["replicas"]["mort"]["state"] == "down"
             assert router.stats()["replicas"]["mort"]["inflight"] == 0
         finally:

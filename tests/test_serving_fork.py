@@ -407,9 +407,6 @@ def test_validation_rejects_unforkable_shapes(params):
         eng.serve([_req(0, _prompt(13), n=eng.slots + 1)])
     with pytest.raises(ValueError, match="fork_at must be >= 1"):
         eng.serve([_req(0, _prompt(13), fork_at=0)])
-    contig = engine(params, slots=2, kv_layout="contiguous")
-    with pytest.raises(ValueError, match="paged"):
-        contig.serve([_req(0, _prompt(13), n=2)])
     # The disaggregated pair's workers reject families via _fork_ok.
     eng2 = engine(params, slots=4, temperature=0.5)
     eng2._fork_ok = False

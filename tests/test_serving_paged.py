@@ -13,21 +13,26 @@ Three contracts, mirroring the layered design:
     (free / slot-private / tree-cached), reservations, and LRU leaf
     eviction never double-free, leak, or touch a referenced block under
     hundreds of random admit/advance/publish/retire interleavings.
-(c) **Serving parity** — a paged server emits token-for-token what the
-    contiguous server emits (exact AND int8 × chunked AND whole
-    admission), a paged radix hit moves ZERO device KV bytes (span args
+(c) **Serving parity** — the engine emits token-for-token what a
+    reference that is not the engine emits (exact: lockstep ``generate``
+    on a contiguous ``KVCache``; int8: a plain ``forward_step`` loop over
+    a hand-built B=1 int8 pool, :func:`paged_int8_stream`; × chunked AND
+    whole admission), a radix hit moves ZERO device KV bytes (span args
     + pool counters prove it, not just code inspection), admissions
     DEFER when the pool is over-subscribed instead of corrupting state,
     and a request that can never fit fails with a clear message.
 
 Bit-exactness in (c) holds at matched tiling: the configs pin
 ``attn_block_size == kv_block`` and a block-divisible ``cache_len``, so
-both layouts fold identical KV tiles in identical order (the same
-alignment trick the PR-5 hit-vs-cold suite uses for chunk == block).
+the engine and its references fold identical KV tiles in identical
+order (the same alignment trick the PR-5 hit-vs-cold suite uses for
+chunk == block).
 
 Everything is CPU-safe and fast-tier (interpret-mode kernels).
 """
 
+import dataclasses
+import functools
 import json
 
 import numpy as np
@@ -43,6 +48,7 @@ from tree_attention_tpu.models import (
     forward_step,
     init_params,
 )
+from tree_attention_tpu.models.decode import quantize_paged_blocks
 from tree_attention_tpu.ops.decode import flash_decode, gather_paged_kv
 from tree_attention_tpu.ops.pallas_decode import (
     attention_pallas_decode,
@@ -56,7 +62,7 @@ from tree_attention_tpu.serving import (
     SlotServer,
 )
 
-# attn_block_size == kv_block == 4 keeps contiguous and paged runs
+# attn_block_size == kv_block == 4 keeps the engine and its references
 # folding identical tiles (see module docstring); cache_len 32 divides.
 CFG = TransformerConfig(
     vocab_size=128,
@@ -72,7 +78,7 @@ CFG = TransformerConfig(
     attn_block_size=4,
 )
 
-PAGED_KW = dict(kv_layout="paged", kv_block=4)
+PAGED_KW = dict(kv_block=4)
 PREFIX_KW = dict(prefix_cache=True, prefix_block=4)
 CHUNK_KW = dict(prefill_chunk=4, prefill_budget=8)
 
@@ -97,6 +103,54 @@ def _single_stream(params, prompt, n_new, cache_len=32):
         generate(params, jnp.asarray(prompt)[None], n_new, CFG,
                  cache_len=cache_len)
     )[0].tolist()
+
+
+@functools.lru_cache(maxsize=None)
+def _jitted_step(cfg):
+    return jax.jit(functools.partial(forward_step, cfg=cfg))
+
+
+def paged_int8_stream(params, cfg, prompt, n_new, *, cache_len, kv_block):
+    """What int8 serving promises, without the engine: the greedy tokens
+    of a plain ``forward_step`` loop over a B=1 int8 pool. The prompt is
+    prefilled exactly on a contiguous ``KVCache``; its rows, quantized
+    block by block under each block's own scale
+    (``quantize_paged_blocks``: the quantize-after-prefill contract at
+    block granularity), are laid into the pool by hand through an
+    identity table; every later row is appended by the model's own step
+    (under the anchor block's scale)."""
+    prompt = np.asarray(prompt, np.int32)
+    plen = len(prompt)
+    nb = -(-cache_len // kv_block)
+    step = _jitted_step(cfg)
+    exact = init_cache(cfg, 1, cache_len)
+    logits, exact = step(params, jnp.asarray(prompt)[None], exact)
+    live = (np.arange(cache_len) < plen)[None, None, None, :, None]
+    kq, vq, ks, vs = quantize_paged_blocks(
+        jnp.where(live, exact.k, 0), jnp.where(live, exact.v, 0),
+        kv_block, plen,
+    )
+
+    def pool(rows):  # (L, 1, Hkv, T, D) -> (L, nb, Hkv, block, D)
+        L, _, H, T, D = rows.shape
+        flat = np.zeros((L, H, nb * kv_block, D), np.int8)
+        flat[:, :, :T] = np.asarray(rows)[:, 0]
+        return jnp.asarray(np.moveaxis(
+            flat.reshape(L, H, nb, kv_block, D), 2, 1))
+
+    cache = dataclasses.replace(
+        init_paged_cache(cfg, 1, cache_len, nb, block=kv_block,
+                         quantize=True),
+        k=pool(kq), v=pool(vq), k_scale=ks, v_scale=vs,
+        table=jnp.arange(nb, dtype=jnp.int32)[None],
+        length=jnp.asarray([plen], jnp.int32),
+    )
+    toks = [int(jnp.argmax(logits[0, plen - 1]))]
+    for _ in range(n_new - 1):
+        logits, cache = step(
+            params, jnp.asarray([[toks[-1]]], jnp.int32), cache)
+        toks.append(int(jnp.argmax(logits[0, 0])))
+    return toks
 
 
 # ---------------------------------------------------------------------------
@@ -379,7 +433,7 @@ def test_paged_prefix_block_mismatch_rejected(params):
     clear error, never a silently-overridden granularity."""
     with pytest.raises(ValueError, match="kv_block"):
         SlotServer(params, CFG, slots=1, cache_len=32, prefix_cache=True,
-                   prefix_block=8, kv_layout="paged", kv_block=4)
+                   prefix_block=8, kv_block=4)
 
 
 def test_allocator_reserve_then_evict():
@@ -418,32 +472,33 @@ def test_allocator_reserve_then_evict():
 
 @pytest.mark.parametrize("quantize", [False, True], ids=["exact", "int8"])
 @pytest.mark.parametrize("admission", ["chunked", "whole"])
-def test_paged_matches_contiguous_serving(params, quantize, admission):
-    """Paged decode == contiguous decode token-for-token, through the
-    full engine (prefill, insert, per-tick mixed step, retire)."""
+def test_paged_serving_matches_reference(params, quantize, admission):
+    """The engine (prefill, insert, per-tick mixed step, retire) emits
+    token-for-token what a reference that is not the engine emits:
+    lockstep ``generate`` on a contiguous ``KVCache`` (exact), a plain
+    ``forward_step`` loop over a hand-built int8 pool (int8)."""
     prompt = _prompt(11)
-    kw = dict(slots=2, cache_len=32, admission=admission,
-              quantize=quantize, **CHUNK_KW)
-    paged = SlotServer(params, CFG, **kw, **PAGED_KW)
-    contig = SlotServer(params, CFG, **kw, kv_layout="contiguous")
+    server = SlotServer(params, CFG, slots=2, cache_len=32,
+                        admission=admission, quantize=quantize,
+                        **CHUNK_KW, **PAGED_KW)
     # One request per serve: the multi-request/occupancy machinery is
-    # layout-independent (pinned by test_serving.py) and the shared
-    # tier-1 budget is tight — this cell pins the layout parity only.
-    rp = paged.serve([_req(0, prompt)], max_ticks=400)
-    rc = contig.serve([_req(0, prompt)], max_ticks=400)
-    for p, c in zip(rp.results, rc.results):
-        assert p.tokens == c.tokens, f"uid {p.uid} diverged"
-    if not quantize:
-        assert rp.results[0].tokens == _single_stream(params, prompt, 5)
-    assert rp.kv["layout"] == "paged"
-    assert rp.kv["blocks_used"] == 0  # everything freed at retire
+    # pinned by test_serving.py — this cell pins the reference parity.
+    rep = server.serve([_req(0, prompt)], max_ticks=400)
+    if quantize:
+        ref = paged_int8_stream(params, CFG, prompt, 5, cache_len=32,
+                                kv_block=4)
+    else:
+        ref = _single_stream(params, prompt, 5)
+    assert rep.results[0].tokens == ref
+    assert rep.kv["layout"] == "paged"
+    assert rep.kv["blocks_used"] == 0  # everything freed at retire
 
 
 def test_paged_hit_moves_zero_bytes(params, tmp_path):
-    """The headline contract: a radix hit on the paged layout is a host
-    table update — the report's byte counter AND the trace instant both
-    record 0 device KV bytes moved (the contiguous layout's gather cost
-    shows up in the same counter, so the 0 is measured, not assumed)."""
+    """The headline contract: a radix hit is a host table update — the
+    report's byte counter AND the trace instant both record 0 device KV
+    bytes moved (an int8 hit's dequant gather shows up in the same
+    counter — test_serving_tiered.py — so the 0 is measured)."""
     from tree_attention_tpu import obs
 
     prompt = _prompt(13)
@@ -467,26 +522,17 @@ def test_paged_hit_moves_zero_bytes(params, tmp_path):
     hits = [e for e in events
             if e["ph"] == "i" and e["name"] == "prefix_hit"]
     assert len(hits) == 1 and hits[0]["args"]["bytes_moved"] == 0
-    # The contiguous layout's same counter is nonzero — the comparison
-    # that makes the 0 meaningful.
-    contig = SlotServer(params, CFG, slots=2, cache_len=32,
-                        **CHUNK_KW, **PREFIX_KW, kv_layout="contiguous")
-    contig.serve([_req(0, prompt)])
-    chit = contig.serve([_req(1, prompt)])
-    assert chit.prefix["hit_bytes_moved"] > 0
-    assert chit.results[0].tokens == hit.results[0].tokens
 
 
 def test_paged_oversubscription_defers(params):
     """A pool smaller than the working set DEFERS admissions (requests
-    wait their turn, FIFO) and still serves every request correctly —
-    the >S-logical-requests behavior contiguous layouts cannot have."""
+    wait their turn, FIFO) and still serves every request correctly."""
     prompt = _prompt(14)
     single = _single_stream(params, prompt, 5)
     # Each request needs ceil((13+5)/4) = 5 blocks; 6 admit one at a time.
     server = SlotServer(params, CFG, slots=3, cache_len=32,
                         prefill_chunk=4, prefill_budget=12,
-                        kv_layout="paged", kv_block=4, kv_blocks=6)
+                        kv_block=4, kv_blocks=6)
     report = server.serve([_req(i, prompt) for i in range(3)],
                           max_ticks=2000)
     assert len(report.results) == 3
@@ -499,12 +545,12 @@ def test_paged_impossible_request_fails_clean(params):
     """Worst case beyond the WHOLE pool: a clear admission-time error
     naming the flag, never a shape error inside a jitted gather."""
     server = SlotServer(params, CFG, slots=1, cache_len=32,
-                        kv_layout="paged", kv_block=4, kv_blocks=4)
+                        kv_block=4, kv_blocks=4)
     with pytest.raises(ValueError, match="kv-blocks"):
         server.serve([_req(0, _prompt(15), n_new=4)])  # needs 5 > 4
 
 
-def test_paged_sharing_beats_contiguous_capacity(params):
+def test_paged_sharing_is_capacity(params):
     """At a pool FAR below slots × cache_len, shared-prefix admissions
     still run concurrently — block sharing is real capacity, the claim
     the serving_paged_flood bench measures at scale."""
@@ -516,7 +562,7 @@ def test_paged_sharing_beats_contiguous_capacity(params):
                         .astype(np.int32)])
         for _ in range(3)
     ]
-    # 3 slots × 8 blocks contiguous-equivalent = 24; pool holds 12.
+    # 3 slots × 8 blocks at full length = 24; pool holds 12.
     server = SlotServer(params, CFG, slots=3, cache_len=32,
                         kv_blocks=12, **CHUNK_KW, **PREFIX_KW, **PAGED_KW)
     reqs = [_req(i, p, n_new=4, tick=i * 8) for i, p in enumerate(prompts)]
@@ -565,14 +611,27 @@ def test_paged_cli_flags_parse():
 
     from tree_attention_tpu.utils.config import parse_args
 
-    cfg = parse_args(["--mode", "serve", "--kv-layout", "contiguous",
+    cfg = parse_args(["--mode", "serve", "--kv-layout", "paged",
                       "--kv-block", "32", "--kv-blocks", "64"])
-    assert cfg.kv_layout == "contiguous"
+    assert not hasattr(cfg, "kv_layout")  # accepted, read nowhere
     assert cfg.kv_block == 32 and cfg.kv_blocks == 64
     cfg = parse_args(["--mode", "serve", "--host-blocks", "16",
                       "--kv-tiering", "off"])
     assert cfg.host_blocks == 16 and cfg.kv_tiering == "off"
-    assert parse_args(["--mode", "serve"]).kv_layout == "paged"
     assert parse_args(["--mode", "serve"]).kv_tiering == "on"
     with pytest.raises(SystemExit):
         parse_args(["--mode", "serve", "--prefix-pool-blocks", "8"])
+
+
+def test_cli_contiguous_layout_is_a_parse_error(capsys):
+    """Serving has one KV layout: the flag survives for callers that
+    pass ``--kv-layout paged``, any other value exits with a message
+    that names the paged pool."""
+    from tree_attention_tpu.utils.config import parse_args
+
+    with pytest.raises(SystemExit) as e:
+        parse_args(["--mode", "serve", "--kv-layout", "contiguous"])
+    assert e.value.code == 2
+    said = capsys.readouterr().err.strip().splitlines()[-1]
+    assert "--kv-layout" in said and "'contiguous'" in said
+    assert "paged" in said

@@ -2,16 +2,18 @@
 
 The reuse contract has two halves:
 
-(a) **bit-exact parity** — a prefix-hit admission (pool gather + suffix
-    prefill) emits token-for-token what a cold full prefill of the same
-    prompt emits, on the exact AND int8 cache, under chunked AND whole
+(a) **bit-exact parity** — a prefix-hit admission (the matched blocks
+    referenced in place + suffix prefill) emits token-for-token what a
+    cold full prefill of the same prompt emits, on the exact AND int8 cache, under chunked AND whole
     admission, single device and compat ``cpu_mesh``. The test configs
     align chunk and block boundaries so every compiled program a hit runs
     is literally the cold run's program over the same rows — any
     divergence is a real reuse bug, not float noise.
 (b) **allocator safety** — the radix tree's ref-counting and LRU
-    eviction never free a block a live request holds and never
-    over-commit the pool, under random admit/retire interleavings.
+    eviction never free a block a live request holds and never retain
+    more than the tree's cap (``prefix_pool_blocks``), under random
+    admit/retire interleavings of a ``PagedPrefixIndex`` over a
+    ``BlockAllocator``.
 
 Everything here is CPU-safe and fast-tier.
 """
@@ -30,7 +32,8 @@ from tree_attention_tpu.models import (
 )
 from tree_attention_tpu.parallel import cpu_mesh
 from tree_attention_tpu.serving import (
-    PrefixCache,
+    BlockAllocator,
+    PagedPrefixIndex,
     Request,
     SlotServer,
     synthetic_trace,
@@ -176,8 +179,8 @@ def test_prefix_shared_prefix_diverging_suffixes(params):
 
 
 def test_prefix_mesh_parity(params):
-    """Prefix reuse on a seq-sharded mesh (replicated pool, sharded slot
-    cache) reproduces the single-device tokens, exact and int8."""
+    """Prefix reuse under a mesh (replicated pool) reproduces the
+    single-device tokens, exact and int8."""
     mesh = cpu_mesh(2)
     prompt = _prompt(5)
     for quantize in (False, True):
@@ -293,15 +296,35 @@ def test_prefix_flight_fields(params):
 # (b) radix allocator: ref-counting + LRU under random interleavings
 # ---------------------------------------------------------------------------
 
-_TINY = TransformerConfig(
-    vocab_size=16, d_model=8, n_layers=1, n_heads=2, n_kv_heads=1,
-    d_head=4, d_ff=16, max_seq_len=64, dtype=jnp.float32,
-)
 
 
-def _tree_nodes(pc):
+def _index(blocks, max_cached=None, block=2):
+    alloc = BlockAllocator(blocks)
+    return PagedPrefixIndex(block=block, alloc=alloc,
+                            max_cached=max_cached), alloc
+
+
+def _publish(idx, alloc, prompt):
+    """One request's life up to its publish, as the engine drives the
+    index: match (pin the path), reserve and allocate private blocks for
+    the unmatched full blocks, hand them over (``adopt``), free what the
+    tree did not take. Returns ``(matched, path, adopted)`` with ``path``
+    pinned until the caller releases it."""
+    matched, held = idx.match(prompt)
+    nb_full = len(prompt) // idx.block
+    new = range(len(held), nb_full)
+    assert alloc.reserve(len(new))
+    phys = {j: alloc.alloc() for j in new}
+    path, adopted = idx.adopt(prompt, phys, held)
+    for j, bid in phys.items():
+        if j not in adopted:
+            alloc.free_private(bid)
+    return matched, path, adopted
+
+
+def _tree_nodes(idx):
     out = []
-    stack = list(pc._root.children.values())
+    stack = list(idx._root.children.values())
     while stack:
         n = stack.pop()
         out.append(n)
@@ -309,24 +332,27 @@ def _tree_nodes(pc):
     return out
 
 
-def _check_invariants(pc):
-    nodes = _tree_nodes(pc)
+def _check_invariants(idx, alloc):
+    nodes = _tree_nodes(idx)
     held = {n.block_id for n in nodes}
-    free = set(pc._free)
-    # Pool never over-commits: every block is either free or held by
-    # exactly one node, and the two sets partition [0, P).
+    free = set(alloc._free)
+    # The tree never over-commits: it owns exactly the blocks that are
+    # not free (every private block was adopted or given back), one node
+    # a block, within its retention cap.
     assert not held & free
-    assert held | free == set(range(pc.blocks))
-    assert len(held) == len(nodes)  # no block aliased by two nodes
+    assert held | free == set(range(alloc.blocks))
+    assert len(held) == len(nodes) == idx.blocks_used
+    assert idx.blocks_used <= idx.max_cached
+    assert alloc.reserved == 0
     assert all(n.refs >= 0 for n in nodes)
 
 
 def test_radix_refcount_lru_property():
-    """Random admit/retire interleavings over a tiny pool: referenced
-    blocks are never freed, the pool never over-commits, and matches
-    always return true prefixes of what was inserted."""
+    """Random admit/retire interleavings over a tiny retention cap:
+    referenced blocks are never freed, the tree never outgrows its cap,
+    and matches always return true prefixes of what was published."""
     rng = np.random.default_rng(42)
-    pc = PrefixCache(_TINY, block=2, blocks=5)
+    idx, alloc = _index(16, max_cached=5)
     live = []  # (held_nodes, prompt)
     for step in range(300):
         action = rng.random()
@@ -335,82 +361,81 @@ def test_radix_refcount_lru_property():
             # tiny alphabet so prefixes collide often.
             plen = int(rng.integers(1, 13))
             prompt = rng.integers(0, 3, size=plen).astype(np.int32)
-            matched, path = pc.match(prompt)
-            assert matched % pc.block == 0
+            matched, path, adopted = _publish(idx, alloc, prompt)
+            assert matched % idx.block == 0
             assert matched <= max(plen - 1, 0)
-            # Matched nodes must spell the prompt's own prefix.
+            assert len(path) <= plen // idx.block
+            # The held nodes must spell the prompt's own prefix.
             for j, node in enumerate(path):
                 assert node.key == tuple(
                     int(t) for t in prompt[j * 2:(j + 1) * 2]
                 )
-            full_path, new_ids, start = pc.insert(prompt)
-            assert start == len(full_path) - len(new_ids)
-            assert len(full_path) <= plen // pc.block
-            pc.release(path)  # admit-refs swap for the publish path
-            live.append((full_path, prompt))
+            live.append((path, prompt))
         else:
             # "Retire" a random live request.
-            idx = int(rng.integers(0, len(live)))
-            path, _ = live.pop(idx)
-            pc.release(path)
-        _check_invariants(pc)
-        # No node held by a live request was evicted: its block id must
-        # still be owned by a node spelling the same key.
-        current = {id(n) for n in _tree_nodes(pc)}
+            path, _ = live.pop(int(rng.integers(0, len(live))))
+            idx.release(path)
+        _check_invariants(idx, alloc)
+        # No node held by a live request was evicted: it must still be
+        # in the tree, on the device tier.
+        current = {id(n) for n in _tree_nodes(idx)}
         for path, _ in live:
             for node in path:
                 assert id(node) in current, "pinned node was evicted"
     # Drain everything: all blocks become evictable, none leak.
     for path, _ in live:
-        pc.release(path)
-    assert all(n.refs == 0 for n in _tree_nodes(pc))
-    _check_invariants(pc)
+        idx.release(path)
+    assert idx.total_pins() == 0
+    assert idx.evictable_blocks() == idx.blocks_used
+    _check_invariants(idx, alloc)
 
 
 def test_radix_lru_evicts_least_recently_used_leaf():
-    pc = PrefixCache(_TINY, block=2, blocks=2)
+    idx, alloc = _index(8, max_cached=2)
     a = np.asarray([0, 0, 9], np.int32)   # one full block [0,0]
     b = np.asarray([1, 1, 9], np.int32)   # one full block [1,1]
     c = np.asarray([2, 2, 9], np.int32)   # forces an eviction
-    pa, _, _ = pc.insert(a)
-    pb, _, _ = pc.insert(b)
-    pc.release(pa)
-    pc.release(pb)
+    for prompt in (a, b):
+        idx.release(_publish(idx, alloc, prompt)[1])
     # Touch A (a match refreshes recency) -> B is the LRU victim.
-    _, path = pc.match(a)
-    pc.release(path)
-    pcc, _, _ = pc.insert(c)
-    pc.release(pcc)
-    assert pc.match(a)[0] == 2  # A survived
-    pc.release(pc.match(a)[1])
-    assert pc.match(b)[0] == 0  # B was evicted
-    assert pc.evictions == 1
+    _, path = idx.match(a)
+    idx.release(path)
+    idx.release(_publish(idx, alloc, c)[1])
+    matched, path = idx.match(a)
+    assert matched == 2  # A survived
+    idx.release(path)
+    assert idx.match(b)[0] == 0  # B was evicted
+    assert idx.evictions == 1
+    assert alloc.used == idx.blocks_used == 2  # B's block went back
 
 
 def test_radix_pinned_pool_stops_publish():
-    """When every block is referenced, insert() stops early instead of
-    evicting pinned data — partial paths are valid prefixes."""
-    pc = PrefixCache(_TINY, block=2, blocks=2)
+    """When every retained block is referenced, adopt() stops early
+    instead of evicting pinned data — partial paths are valid prefixes —
+    and the blocks the tree did not take stay the request's own."""
+    idx, alloc = _index(8, max_cached=2)
     long = np.asarray([0, 1, 2, 3, 4, 5, 6, 7], np.int32)  # 4 blocks
-    path, new_ids, start = pc.insert(long)
-    assert len(new_ids) == 2 and start == 0  # pool-bound, not prompt-bound
-    # Still pinned: a second long insert gets nothing.
+    _, path, adopted = _publish(idx, alloc, long)
+    assert adopted == [0, 1] and len(path) == 2  # cap-bound, not prompt-bound
+    assert alloc.used == 2  # the two refused blocks were given back
+    # Still pinned: a second long publish gets nothing.
     other = np.asarray([7, 6, 5, 4], np.int32)
-    p2, ids2, _ = pc.insert(other)
+    _, p2, ids2 = _publish(idx, alloc, other)
     assert ids2 == [] and p2 == []
-    pc.release(path)
-    # Released: now the other prompt can claim (evict) the blocks.
-    p3, ids3, _ = pc.insert(other)
-    assert len(ids3) == 2
-    pc.release(p2)
-    pc.release(p3)
+    idx.release(path)
+    # Released: now the other prompt can claim (evict) the retention.
+    _, p3, ids3 = _publish(idx, alloc, other)
+    assert ids3 == [0, 1]
+    assert idx.match(long)[0] == 0
+    idx.release(p3)
 
 
-def test_prefix_block_must_be_pow2():
+def test_prefix_block_must_be_pow2(params):
     with pytest.raises(ValueError, match="power of two"):
-        PrefixCache(_TINY, block=3, blocks=2)
-    with pytest.raises(ValueError, match=">= 1"):
-        PrefixCache(_TINY, block=2, blocks=0)
+        _index(4, block=3)
+    with pytest.raises(ValueError, match="power of two"):
+        SlotServer(params, CFG, slots=1, cache_len=32, prefix_cache=True,
+                   prefix_block=3)
 
 
 # ---------------------------------------------------------------------------

@@ -72,7 +72,7 @@ CFG = TransformerConfig(
 # docstring names.
 TIER_KW = dict(
     slots=2, cache_len=32, prefill_chunk=4, prefill_budget=4,
-    prefix_cache=True, prefix_block=4, kv_layout="paged", kv_block=4,
+    prefix_cache=True, prefix_block=4, kv_block=4,
     kv_blocks=12, host_blocks=16,
 )
 
@@ -521,13 +521,9 @@ class TestDemoteRestoreParity:
         assert "demotions" not in rep.kv
         assert cold == ref["cold"]
 
-    def test_tiering_requires_paged_and_prefix(self, params):
-        with pytest.raises(ValueError, match="paged"):
-            SlotServer(params, CFG, slots=1, cache_len=32,
-                       kv_layout="contiguous", host_blocks=4)
+    def test_tiering_requires_prefix(self, params):
         with pytest.raises(ValueError, match="prefix_cache"):
-            SlotServer(params, CFG, slots=1, cache_len=32,
-                       kv_layout="paged", host_blocks=4)
+            SlotServer(params, CFG, slots=1, cache_len=32, host_blocks=4)
 
 
 # ---------------------------------------------------------------------------
@@ -674,8 +670,7 @@ def test_int8_hit_with_non_divisible_cache_len(params):
     and crashed every such hit."""
     server = SlotServer(params, CFG, slots=1, cache_len=28,
                         prefill_chunk=4, prefill_budget=4, quantize=True,
-                        prefix_cache=True, prefix_block=8,
-                        kv_layout="paged", kv_block=8)
+                        prefix_cache=True, prefix_block=8, kv_block=8)
     p = _prompt(11, n=26)
     cold = server.serve([_req(0, p, n_new=2)])
     hit = server.serve([_req(1, p, n_new=2)])

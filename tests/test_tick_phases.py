@@ -3,11 +3,11 @@
 One set of stamps, three sinks — the tick's flight record (``phases``,
 ``t_end``, ``kind``, ``tq``, ``rows_computed``, ``rows_useful``), the
 profiler's ``tick:<phase>`` annotations, and ``tick:<phase>`` complete
-events in the ``--trace-events`` JSONL. CPU toy engine, contiguous layout
-(the stamps sit in layout-independent loop code).
+events in the ``--trace-events`` JSONL. CPU toy engine on the paged pool
+at pages of ``attn_block_size`` tokens; the cases of the chunked shape
+share one engine (a drained engine serves the next trace clean).
 """
 
-import functools
 import json
 import time
 import tracemalloc
@@ -22,10 +22,7 @@ from tree_attention_tpu import obs
 from tree_attention_tpu.models import TransformerConfig, init_params
 from tree_attention_tpu.obs import flight as flight_mod
 from tree_attention_tpu.obs.flight import FLIGHT, TICK_PHASES, TickPhases
-from tree_attention_tpu.serving import Request
-from tree_attention_tpu.serving import SlotServer as _SlotServer
-
-SlotServer = functools.partial(_SlotServer, kv_layout="contiguous")
+from tree_attention_tpu.serving import Request, SlotServer
 
 CFG = TransformerConfig(
     vocab_size=128, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
@@ -33,11 +30,17 @@ CFG = TransformerConfig(
     attn_impl="blockwise", attn_block_size=16,
 )
 SLOTS, CHUNK = 2, 4
+ENGINE_KW = dict(slots=SLOTS, cache_len=32, kv_block=CFG.attn_block_size)
 
 
 @pytest.fixture(scope="module")
 def params():
     return init_params(jax.random.PRNGKey(0), CFG)
+
+
+@pytest.fixture(scope="module")
+def chunked(params):
+    return SlotServer(params, CFG, prefill_chunk=CHUNK, **ENGINE_KW)
 
 
 def _requests(n, prompt_len, n_new, key=31):
@@ -63,12 +66,11 @@ def _recorded(server, reqs, executed_only=True):
 
 
 @pytest.fixture(scope="module")
-def served(params, tmp_path_factory):
+def served(chunked, tmp_path_factory):
     """One chunked run, 3 requests through 2 slots, with the recorder AND
     the span tracer on: the records and the JSONL of the same ticks."""
     path = tmp_path_factory.mktemp("phases") / "trace.jsonl"
-    server = SlotServer(params, CFG, slots=SLOTS, cache_len=32,
-                        prefill_chunk=CHUNK)
+    server = chunked
     obs.TRACER.start(str(path))
     try:
         t_before = time.monotonic()
@@ -160,7 +162,7 @@ def test_trace_events_hold_the_phases_inside_the_tick_span(served):
     (dict(prefill_chunk=CHUNK, speculate=True, draft_k=3), {"verify"}),
 ], ids=["whole", "staged", "verify"])
 def test_the_other_tick_kinds(params, kw, want):
-    server = SlotServer(params, CFG, slots=SLOTS, cache_len=32, **kw)
+    server = SlotServer(params, CFG, **ENGINE_KW, **kw)
     _, recs = _recorded(server, _requests(2, 9, 4, key=33))
     assert {r["kind"] for r in recs} == want
     for r in recs:
@@ -201,14 +203,12 @@ def _iterations(names):
 
 
 def test_each_phase_is_a_profiler_annotation_left_at_the_next_mark(
-        params, monkeypatch):
+        chunked, monkeypatch):
     import jax.profiler
 
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", _FakeAnnotation)
     _FakeAnnotation.log = []
-    server = SlotServer(params, CFG, slots=SLOTS, cache_len=32,
-                        prefill_chunk=CHUNK)
-    _, recs = _recorded(server, _requests(2, 6, 3, key=34))
+    _, recs = _recorded(chunked, _requests(2, 6, 3, key=34))
     log = _FakeAnnotation.log
     # Strictly alternating enter/leave of the same annotation: never two
     # open at once, none left open at the end.
@@ -270,7 +270,7 @@ def test_a_repeated_mark_and_an_abandoned_tick():
 
 
 def test_an_idle_iteration_leaves_no_record_and_no_open_annotation(
-        params, monkeypatch):
+        chunked, monkeypatch):
     """Arrival ticks far apart: the loop fast-forwards between them, and
     those iterations stamp ingest..admit and then abandon."""
     import jax.profiler
@@ -279,9 +279,7 @@ def test_an_idle_iteration_leaves_no_record_and_no_open_annotation(
     _FakeAnnotation.log = []
     reqs = _requests(2, 5, 2, key=35)
     reqs[1].arrival_tick = 50
-    server = SlotServer(params, CFG, slots=SLOTS, cache_len=32,
-                        prefill_chunk=CHUNK)
-    report, recs = _recorded(server, reqs, executed_only=False)
+    report, recs = _recorded(chunked, reqs, executed_only=False)
     assert len(recs) < report.ticks      # the clock jumped to tick 50
     assert all(r["phases"][-1][0] == "account" for r in recs)
     log = _FakeAnnotation.log
@@ -329,11 +327,10 @@ def test_off_means_no_clock_read_and_no_allocation(monkeypatch):
     assert ph._marks is None and ph._open is None
 
 
-def test_the_engine_serves_the_same_tokens_stamped_or_not(params):
+def test_the_engine_serves_the_same_tokens_stamped_or_not(chunked):
     reqs = lambda: _requests(3, 9, 4, key=36)          # noqa: E731
-    kw = dict(slots=SLOTS, cache_len=32, prefill_chunk=CHUNK)
-    plain = SlotServer(params, CFG, **kw).serve(reqs())
-    stamped, _ = _recorded(SlotServer(params, CFG, **kw), reqs())
+    plain = chunked.serve(reqs())
+    stamped, _ = _recorded(chunked, reqs())
     assert ({r.uid: r.tokens for r in plain.results}
             == {r.uid: r.tokens for r in stamped.results})
     assert plain.ticks == stamped.ticks

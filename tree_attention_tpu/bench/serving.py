@@ -37,9 +37,7 @@ from tree_attention_tpu.models import (
     init_cache,
     init_params,
 )
-from tree_attention_tpu.models.decode import insert_prefix_blocks
 from tree_attention_tpu.serving import (
-    PrefixCache,
     Request,
     SlotServer,
     synthetic_trace,
@@ -528,49 +526,6 @@ def bench_serving_flood(
 # ---------------------------------------------------------------------------
 
 
-def slope_prefix_gather(
-    cfg: TransformerConfig,
-    *,
-    cache_len: int,
-    block: int,
-    matched: int,
-    n_small: int = 4,
-    n_large: int = 16,
-    iters: int = 3,
-    repeats: int = 3,
-):
-    """chain_slope the prefix-hit gather: one donated pool->slot copy of
-    ``matched`` tokens (the work that REPLACES a whole-prefix prefill on
-    a hit). The chained carry is BOTH destination buffers stacked — each
-    copy reads its own previous windows (the read-modify-write merge),
-    so the chain is dependent, nothing hoists out of the scan, and
-    neither the K nor the V half can be dead-code-eliminated (a K-only
-    carry would let XLA prune the V gather and halve the measured cost).
-    The per-step stack repack adds a buffer copy the real hit path does
-    not pay, so the estimate errs CONSERVATIVE (gather priced high,
-    ``prefill_avoided_ratio`` low)."""
-    nb = matched // block
-    pc = PrefixCache(cfg, block=block, blocks=nb)
-    ids = jnp.arange(nb, dtype=jnp.int32)
-    cache0 = init_cache(cfg, 1, cache_len)
-    len0 = cache0.length
-    matched_v = jnp.int32(matched)
-
-    def step(kv):
-        from tree_attention_tpu.models.decode import KVCache
-
-        cache = KVCache(k=kv[0], v=kv[1], length=len0)
-        out = insert_prefix_blocks(
-            cache, pc.pool_k, pc.pool_v, ids, matched_v, jnp.int32(0)
-        )
-        return jnp.stack([out.k, out.v])
-
-    return chain_slope(
-        step, jnp.stack([cache0.k, cache0.v]), n_small=n_small,
-        n_large=n_large, iters=iters, repeats=repeats,
-    )
-
-
 def time_paged_hit_host_update(
     *,
     prefix_len: int,
@@ -580,11 +535,11 @@ def time_paged_hit_host_update(
 ) -> float:
     """Microseconds for ONE paged prefix hit's entire device-visible
     cost: the radix match + pinning + writing the matched pool ids into
-    a host table row (+ the release the retire path pays). This is the
-    operation that REPLACES the contiguous layout's pool→slot gather —
-    the whole point of ISSUE 6 — so it is priced by the same min-over-
-    repeats discipline the gather slope uses. Host wall time: there is
-    nothing to fetch-fence because nothing is dispatched."""
+    a host table row (+ the release the retire path pays). This is ALL
+    a hit costs in place of the matched prefix's prefill — the whole
+    point of ISSUE 6 — priced min-over-repeats like the slopes. Host
+    wall time: there is nothing to fetch-fence because nothing is
+    dispatched."""
     import time as _time
 
     from tree_attention_tpu.serving.block_pool import BlockAllocator
@@ -649,35 +604,29 @@ def bench_serving_paged_flood(
     cfg: Optional[TransformerConfig] = None,
     seed: int = 0,
 ) -> Dict[str, Any]:
-    """The paged-KV record (ISSUE 6): paged vs contiguous at EQUAL pool
-    bytes on the PR-5 shared-prefix flood.
+    """The paged-KV record (ISSUE 6): the PR-5 shared-prefix flood on
+    the paged pool, at ``slots`` slots and over-subscribed.
 
-    The contiguous arm holds ``slots × cache_len`` of slot cache plus an
-    ``extra_pool_blocks``-block prefix pool; the paged arms get exactly
-    that total as ONE ``--kv-blocks`` budget. Three measurements:
+    The pool is ONE ``--kv-blocks`` budget of ``slots × cache_len``
+    tokens plus ``extra_pool_blocks`` for retained prefixes. Three
+    measurements:
 
-    - **Slope** — the PR-5 chain_slope-priced pool→slot gather (what a
-      contiguous hit pays) against :func:`time_paged_hit_host_update`
-      (what a paged hit pays: a radix walk + a host table-row write).
-      ``gather_avoided_ratio`` is the per-hit saving; the paged arm's
+    - **Slope** — :func:`time_paged_hit_host_update`: what a hit pays (a
+      radix walk + a host table-row write); the arms'
       ``prefix.hit_bytes_moved == 0`` in the trace repeats is the same
       claim measured end-to-end.
-    - **TTFT trace** — the identical flood through both layouts at the
-      SAME slot count, min-over-repeats TTFT p50/p95;
-      ``ttft_p50_improvement`` (gather over paged) should be >= 1: the
-      paged hit removes the gather from every shared admission's
-      critical path.
-    - **Capacity trace** — the paged layout at ``oversub_slots`` slots
-      and the SAME pool bytes: shared prefix blocks mean concurrent
-      hits cost one block each instead of a full cache_len region, so
-      ``max_concurrent_requests`` rises where the contiguous layout is
-      pinned at ``slots``. ``max_concurrent_improvement`` is the
-      headline; all-at-start arrivals make the concurrency demand real.
+    - **TTFT trace** — the flood at ``slots`` slots, min-over-repeats
+      TTFT p50/p95.
+    - **Capacity trace** — ``oversub_slots`` slots over the SAME pool
+      bytes: shared prefix blocks mean concurrent hits cost one block
+      each instead of a full ``cache_len`` of blocks, so
+      ``max_concurrent_requests`` rises past what ``slots`` full-length
+      slots hold. ``max_concurrent_improvement`` is the headline;
+      all-at-start arrivals make the concurrency demand real.
 
     CPU proxy by design: the eager paged path re-gathers the logical
-    view every tick (the Pallas kernel reads blocks in place on TPU), so
-    tokens/sec slightly favors contiguous here — the record reports it
-    honestly; the structural wins (zero-copy hits, capacity) transfer.
+    view every tick (the Pallas kernel reads blocks in place on TPU);
+    the structural wins (zero-copy hits, capacity) transfer.
     """
     cfg = cfg or serving_model_config(max_seq_len=cache_len)
     params = init_params(jax.random.PRNGKey(seed), cfg)
@@ -696,41 +645,25 @@ def bench_serving_paged_flood(
         prefix_seed=seed + 1000,
     )
 
-    # --- slope: the gather a hit used to pay vs the table update ---
+    # --- slope: the table update that is all a hit pays ---
     with obs.span("bench_serving_paged:slope", cat="bench"):
-        s_gather = slope_prefix_gather(
-            cfg, cache_len=cache_len, block=kv_block, matched=prefix_len,
-        )
         host_us = time_paged_hit_host_update(
             prefix_len=prefix_len, kv_block=kv_block,
         )
     slope_rec = {
-        "us_per_prefix_gather": round(s_gather.per_step * 1e6, 1),
         "us_per_hit_host_update": round(host_us, 2),
         "prefix_len": prefix_len,
         "kv_block": kv_block,
-        "gather_avoided_ratio": round(
-            s_gather.per_step * 1e6 / max(host_us, 1e-9), 1
-        ),
-        "spread_pct": round(s_gather.spread_pct, 1),
     }
 
     # --- traces ---
-    def run_arm(layout: str, n_slots: int) -> Dict[str, Any]:
-        if layout == "contiguous":
-            server = SlotServer(
-                params, cfg, slots=n_slots, cache_len=cache_len,
-                prefill_chunk=prefill_chunk, prefix_cache=True,
-                prefix_block=kv_block, prefix_pool_blocks=extra_pool_blocks,
-                kv_layout="contiguous",
-            )
-        else:
-            server = SlotServer(
-                params, cfg, slots=n_slots, cache_len=cache_len,
-                prefill_chunk=prefill_chunk, prefix_cache=True,
-                prefix_block=kv_block, kv_layout="paged",
-                kv_block=kv_block, kv_blocks=pool_blocks,
-            )
+    def run_arm(n_slots: int) -> Dict[str, Any]:
+        server = SlotServer(
+            params, cfg, slots=n_slots, cache_len=cache_len,
+            prefill_chunk=prefill_chunk, prefix_cache=True,
+            prefix_block=kv_block,
+            kv_block=kv_block, kv_blocks=pool_blocks,
+        )
         server.serve(synthetic_trace(**trace_kw))  # compiles + warm pool
         runs = []
         for r in range(repeats):
@@ -757,8 +690,7 @@ def bench_serving_paged_flood(
 
     trace_rec: Dict[str, Any] = {}
     with obs.span("bench_serving_paged:trace", cat="bench"):
-        trace_rec["gather"] = run_arm("contiguous", slots)
-        trace_rec["paged"] = run_arm("paged", slots)
+        trace_rec["paged"] = run_arm(slots)
         # Capacity arm: more slots, SAME pool bytes, all queued at start
         # so the concurrency demand is real.
         burst = dict(trace_kw, arrival_every=0,
@@ -766,7 +698,7 @@ def bench_serving_paged_flood(
         osrv = SlotServer(
             params, cfg, slots=oversub_slots, cache_len=cache_len,
             prefill_chunk=prefill_chunk, prefix_cache=True,
-            prefix_block=kv_block, kv_layout="paged",
+            prefix_block=kv_block,
             kv_block=kv_block, kv_blocks=pool_blocks,
         )
         osrv.serve(synthetic_trace(**burst))
@@ -778,12 +710,7 @@ def bench_serving_paged_flood(
             "kv": orep.kv,
             "prefix": orep.prefix,
         }
-    paged_p50 = trace_rec["paged"]["ttft_p50_s"]
-    if paged_p50 > 0:
-        trace_rec["ttft_p50_improvement"] = round(
-            trace_rec["gather"]["ttft_p50_s"] / paged_p50, 2
-        )
-    base_cc = trace_rec["gather"]["max_concurrent_requests"]
+    base_cc = trace_rec["paged"]["max_concurrent_requests"]
     if base_cc > 0:
         trace_rec["max_concurrent_improvement"] = round(
             trace_rec["paged_oversub"]["max_concurrent_requests"]
@@ -791,13 +718,10 @@ def bench_serving_paged_flood(
         )
 
     log.info(
-        "paged flood: gather %(g).1fus vs host update %(h).2fus "
-        "(%(r).0fx); TTFT p50 %(cp).4fs gather vs %(pp).4fs paged; "
+        "paged flood: hit host update %(h).2fus; TTFT p50 %(pp).4fs; "
         "max concurrent %(mc)d vs %(mo)d at equal pool bytes",
-        dict(g=slope_rec["us_per_prefix_gather"],
-             h=slope_rec["us_per_hit_host_update"],
-             r=slope_rec["gather_avoided_ratio"],
-             cp=trace_rec["gather"]["ttft_p50_s"], pp=paged_p50,
+        dict(h=slope_rec["us_per_hit_host_update"],
+             pp=trace_rec["paged"]["ttft_p50_s"],
              mc=base_cc,
              mo=trace_rec["paged_oversub"]["max_concurrent_requests"]),
     )
@@ -845,7 +769,7 @@ def bench_serving_prefix_flood(
     protocol:
 
     - **Slope** — chain_slope (min-over->=3-cycles) prices the whole
-      ``prefix_len``-token B=1 prefill against the donated pool gather
+      ``prefix_len``-token B=1 prefill against the host table update
       that replaces it on a hit; their ratio (``prefill_avoided_ratio``)
       is the deterministic per-hit saving, independent of trace timing.
     - **Trace** — the real engine over shared-prefix traces
@@ -861,8 +785,8 @@ def bench_serving_prefix_flood(
       reported improvement is the claimed share's, not a 100%-hit
       replay's.
 
-    CPU proxy by design: the structure (a 512-token prefill vs a block
-    gather) transfers; absolute seconds do not.
+    CPU proxy by design: the structure (a 512-token prefill vs a table
+    update) transfers; absolute seconds do not.
     """
     cfg = cfg or serving_model_config(max_seq_len=cache_len)
     params = init_params(jax.random.PRNGKey(seed), cfg)
@@ -879,40 +803,30 @@ def bench_serving_prefix_flood(
         prefix_seed=seed + 1000,  # one prefix population across repeats
     )
 
-    # --- slope: one shared-prefix prefill vs the gather replacing it ---
+    # --- slope: one shared-prefix prefill vs the update replacing it ---
     bucket = _bucket(prefix_len, cache_len)
     with obs.span("bench_serving_prefix:slope", cat="bench"):
         s_prefill = slope_whole_prefill(params, cfg, bucket=bucket)
-        s_gather = slope_prefix_gather(
-            cfg, cache_len=cache_len, block=prefix_block,
-            matched=prefix_len,
+        host_us = time_paged_hit_host_update(
+            prefix_len=prefix_len, kv_block=prefix_block,
         )
     slope_rec = {
         "us_per_prefix_prefill": round(s_prefill.per_step * 1e6, 1),
-        "us_per_prefix_gather": round(s_gather.per_step * 1e6, 1),
+        "us_per_hit_host_update": round(host_us, 2),
         "prefix_len": prefix_len,
         "prefix_block": prefix_block,
         "prefill_avoided_ratio": round(
-            s_prefill.per_step / s_gather.per_step, 2
+            s_prefill.per_step * 1e6 / max(host_us, 1e-9), 2
         ),
-        "spread_pct": round(
-            max(s_prefill.spread_pct, s_gather.spread_pct), 1
-        ),
+        "spread_pct": round(s_prefill.spread_pct, 1),
     }
 
     # --- trace: the real engine, cache on vs off ---
     def run_mode(prefix_on: bool) -> Dict[str, Any]:
-        # Pinned to the CONTIGUOUS layout: this record prices the PR-5
-        # gather-based design it is named for (dedicated prefix pool,
-        # pool->slot copies) so round-over-round comparisons stay
-        # apples-to-apples; the paged successor has its own record
-        # (serving_paged_flood) measuring the same flood on the default
-        # layout.
         server = SlotServer(
             params, cfg, slots=slots, cache_len=cache_len,
             prefill_chunk=prefill_chunk, prefix_cache=prefix_on,
             prefix_block=prefix_block, prefix_pool_blocks=pool_blocks,
-            kv_layout="contiguous",
         )
         server.serve(synthetic_trace(**trace_kw))  # compiles + warm pool
         runs = []
@@ -2712,7 +2626,7 @@ def bench_serving_tiered_kv(
         server = SlotServer(
             params, cfg, slots=slots, cache_len=cache_len,
             prefill_chunk=prefill_chunk, prefix_cache=True,
-            prefix_block=kv_block, kv_layout="paged", kv_block=kv_block,
+            prefix_block=kv_block, kv_block=kv_block,
             kv_blocks=pool_blocks, host_blocks=hb,
         )
         # Pass 1: cold — pays the jit compiles AND publishes every
@@ -2793,7 +2707,7 @@ def bench_serving_tiered_kv(
             server = SlotServer(
                 params, cfg, slots=int8_slots, cache_len=int8_cache_len,
                 prefill_chunk=prefill_chunk, quantize=quant,
-                kv_layout="paged", kv_block=kv_block, kv_blocks=blocks,
+                kv_block=kv_block, kv_blocks=blocks,
             )
             server.serve(synthetic_trace(**burst_kw, seed=seed + 3))
             rep = server.serve(synthetic_trace(**burst_kw, seed=seed + 4))
@@ -3119,7 +3033,7 @@ def bench_serving_seq_sharded(
         return SlotServer(
             params, cfg, slots=slots, cache_len=cache_len, mesh=mesh,
             prefill_chunk=prefill_chunk, quantize=quantize,
-            kv_layout="paged", kv_block=kv_block, kv_blocks=blocks,
+            kv_block=kv_block, kv_blocks=blocks,
             kv_shard=kv_shard,
         )
 

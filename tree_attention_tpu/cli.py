@@ -541,8 +541,6 @@ def load_model_config(path: str) -> dict:
 # What a latent-attention / expert model is not served with: the flag's
 # test and the mechanism's name, refused at build and never served wrong.
 _LATENT_REFUSALS = (
-    (lambda c: c.kv_layout != "paged",
-     "--kv-layout contiguous (a latent pool is paged)"),
     (lambda c: c.kv_quant != "none", "--kv-quant (int8 latent rows)"),
     (lambda c: c.kv_shard == "seq",
      "--kv-shard seq (a sequence-sharded latent pool)"),
@@ -609,11 +607,6 @@ def build_serve_engine(cfg: RunConfig, mesh, *, model=None,
                 "disaggregated fleet tier is not built yet; run one "
                 "disaggregated pair per process)"
             )
-        if cfg.kv_layout != "paged":
-            raise SystemExit(
-                "--serve-disagg requires --kv-layout paged: the zero-"
-                "copy handoff IS paged-block ownership transfer"
-            )
         if cfg.admission != "chunked":
             raise SystemExit(
                 "--serve-disagg requires --admission chunked (the "
@@ -650,45 +643,25 @@ def build_serve_engine(cfg: RunConfig, mesh, *, model=None,
         raise SystemExit("--host-blocks must be >= 0")
     host_blocks = cfg.host_blocks if cfg.kv_tiering == "on" else 0
     if host_blocks:
-        if cfg.kv_layout != "paged":
-            raise SystemExit(
-                "--host-blocks KV tiering requires --kv-layout paged "
-                "(the tier demotes pool blocks; the contiguous layout "
-                "has none)"
-            )
         if not cfg.prefix_cache:
             raise SystemExit(
                 "--host-blocks KV tiering requires --prefix-cache "
                 "(demotion is what radix eviction becomes; with no "
                 "radix tree nothing ever demotes)"
             )
-    if cfg.kv_shard == "seq" and cfg.kv_layout != "paged":
-        raise SystemExit(
-            "--kv-shard seq requires --kv-layout paged (sequence "
-            "sharding partitions the block pool; the contiguous layout "
-            "has none)"
-        )
     if cfg.kv_block is not None and (cfg.kv_block < 1
                                      or cfg.kv_block & (cfg.kv_block - 1)):
         raise SystemExit("--kv-block must be a power of two >= 1")
     if cfg.kv_blocks is not None and cfg.kv_blocks < 1:
         raise SystemExit("--kv-blocks must be >= 1")
-    if cfg.kv_layout == "paged" and cfg.prefix_cache \
+    if cfg.prefix_cache \
             and cfg.kv_block is not None and cfg.kv_block != cfg.prefix_block:
         # The engine enforces this too (radix matching happens at page
         # granularity); surface it as the clean flag-error every other
         # serve-mode misuse gets, not a traceback.
         raise SystemExit(
             f"--prefix-block {cfg.prefix_block} must equal --kv-block "
-            f"{cfg.kv_block} under --kv-layout paged (or pass only one "
-            f"of them)"
-        )
-    if cfg.kv_layout == "contiguous" and (cfg.kv_block is not None
-                                          or cfg.kv_blocks is not None):
-        log.warning(
-            "--kv-block/--kv-blocks only apply to --kv-layout paged; "
-            "the contiguous layout allocates slots * cache_len and a "
-            "separate prefix pool (the flags are ignored)"
+            f"{cfg.kv_block} (or pass only one of them)"
         )
     # The cache is sized from the trace itself: longest possible prompt
     # plus the per-request budget, through the same rounding rule
@@ -730,7 +703,7 @@ def build_serve_engine(cfg: RunConfig, mesh, *, model=None,
         params = init_params(jax.random.PRNGKey(cfg.seed), tcfg)
     if cfg.slo_ttft <= 0 or cfg.slo_tbt <= 0:
         raise SystemExit("--slo-ttft and --slo-tbt must be > 0")
-    # The paged layout has ONE device budget (--kv-blocks) and one host
+    # The paged pool has ONE device budget (--kv-blocks) and one host
     # budget (--host-blocks); the PR-6-deprecated --prefix-pool-blocks
     # alias is gone (ISSUE 13) — the engine API keeps the retention-cap
     # kwarg for tests, but the CLI no longer exposes the old split.
@@ -764,7 +737,6 @@ def build_serve_engine(cfg: RunConfig, mesh, *, model=None,
         slo_tbt=cfg.slo_tbt,
         prefix_cache=cfg.prefix_cache,
         prefix_block=cfg.prefix_block,
-        kv_layout=cfg.kv_layout,
         kv_block=cfg.kv_block,
         kv_blocks=kv_blocks,
         kv_shard=cfg.kv_shard,
@@ -781,7 +753,7 @@ def build_serve_engine(cfg: RunConfig, mesh, *, model=None,
             from tree_attention_tpu.serving.disagg import DisaggServer
 
             disagg_kw = {k: v for k, v in engine_kw.items()
-                         if k not in ("slots", "admission", "kv_layout")}
+                         if k not in ("slots", "admission")}
             return DisaggServer(
                 params, tcfg, prefill_slots=cfg.prefill_slots,
                 decode_slots=decode_slots, **disagg_kw,
@@ -863,7 +835,6 @@ def _run_serve(cfg: RunConfig, mesh) -> int:
             },
             "slots": cfg.slots,
             "cache_len": cache_len,
-            "kv_layout": cfg.kv_layout,
         })
         return 0
 
@@ -914,7 +885,6 @@ def _run_serve(cfg: RunConfig, mesh) -> int:
                         "default_deadline_s": cfg.default_deadline},
             "slots": cfg.slots,
             "cache_len": cache_len,
-            "kv_layout": cfg.kv_layout,
             **({"disagg": {"prefill_slots": cfg.prefill_slots,
                            "decode_slots": decode_slots}}
                if cfg.serve_disagg else {}),
@@ -948,7 +918,6 @@ def _run_serve(cfg: RunConfig, mesh) -> int:
         "cache_len": cache_len,
         "admission": cfg.admission,
         "prefill_chunk": cfg.prefill_chunk,
-        "kv_layout": cfg.kv_layout,
         **({"disagg": {"prefill_slots": cfg.prefill_slots,
                        "decode_slots": decode_slots}}
            if cfg.serve_disagg else {}),

@@ -444,8 +444,8 @@ def insert_dequant_prefix(
     int8 blocks IN PLACE through its table, but the suffix's exact
     staged prefill needs the prefix as activations-grade rows — this
     places ``matched`` dequantized tokens (``int8 · per-block scale``)
-    at positions ``[0, matched)`` of staging slot 0 and sets its length,
-    mirroring :func:`insert_prefix_blocks`. Re-quantizing these rows at
+    at positions ``[0, matched)`` of staging slot 0 and sets its length
+    (one donated gather, whatever the match). Re-quantizing these rows at
     final chunk reproduces the original int8 bytes exactly (absmax/127
     scaling round-trips int8 code points), so shared blocks never need
     rewriting.
@@ -777,7 +777,7 @@ def paged_insert_slot(
 ) -> Union[PagedKVCache, PagedQuantKVCache]:
     """Place a B=1 prefilled cache's rows into one slot's mapped blocks.
 
-    The paged mirror of the engine's contiguous insert: ``k_rows`` /
+    What whole-prompt and int8 staged admission end with: ``k_rows`` /
     ``v_rows`` are ``(L, 1, Hkv, T, D)`` (a mini/staging cache, possibly
     already int8), token positions ``[lo, plen)`` scatter through the
     slot's table row (``plen``/``lo`` may be traced; rows outside drop),
@@ -1466,96 +1466,6 @@ def forward_step(
     return logits, new_cache
 
 
-def insert_prefix_blocks(
-    cache: KVCache,
-    pool_k: jax.Array,
-    pool_v: jax.Array,
-    ids: jax.Array,
-    matched: jax.Array,
-    slot: jax.Array,
-) -> KVCache:
-    """Copy ``matched`` tokens of pooled prefix KV into one cache slot.
-
-    The prefix-cache hit path (:mod:`tree_attention_tpu.serving
-    .prefix_cache`): ``pool_k``/``pool_v`` are ``(P, L, Hkv, block, D)``
-    block pools, ``ids`` the ``(nb,)`` pool rows holding the matched
-    prefix in prompt order (padded entries may repeat a valid id — rows at
-    token positions ``>= matched`` are masked off), and the copy lands at
-    token positions ``[0, matched)`` of slot ``slot``, setting that slot's
-    ``length`` to ``matched``. One gather + one read-modify-write window —
-    bytes at ``>= nb * block`` are untouched, bytes in ``[matched,
-    nb * block)`` keep their previous values, so the slot is exactly "a
-    prefill of the matched prefix happened here". ``nb * block`` must not
-    exceed the cache capacity (callers bucket ``nb`` under that cap).
-    """
-    nb = ids.shape[0]
-    block = pool_k.shape[3]
-    span = nb * block
-    matched = jnp.asarray(matched, jnp.int32)
-
-    def place(buf: jax.Array, pool: jax.Array) -> jax.Array:
-        rows = jnp.moveaxis(pool[ids], 0, 2)  # (L, Hkv, nb, block, D)
-        L, Hkv = rows.shape[0], rows.shape[1]
-        rows = rows.reshape(L, Hkv, span, rows.shape[-1])
-        cur = lax.dynamic_index_in_dim(buf, slot, axis=1, keepdims=False)
-        window = lax.dynamic_slice_in_dim(cur, 0, span, axis=2)
-        valid = (
-            jnp.arange(span, dtype=jnp.int32) < matched
-        )[None, None, :, None]
-        merged = jnp.where(valid, rows.astype(buf.dtype), window)
-        cur = lax.dynamic_update_slice_in_dim(cur, merged, 0, axis=2)
-        return lax.dynamic_update_index_in_dim(buf, cur, slot, axis=1)
-
-    length = lax.dynamic_update_index_in_dim(
-        cache.length, matched, slot, axis=0
-    )
-    return KVCache(
-        k=place(cache.k, pool_k), v=place(cache.v, pool_v), length=length
-    )
-
-
-def extract_prefix_blocks(
-    pool_k: jax.Array,
-    pool_v: jax.Array,
-    cache_k: jax.Array,
-    cache_v: jax.Array,
-    slot: jax.Array,
-    ids: jax.Array,
-    start_block: jax.Array,
-) -> Tuple[jax.Array, jax.Array]:
-    """Publish one slot's prefix KV rows into pool blocks (the scatter).
-
-    Inverse of :func:`insert_prefix_blocks`: token rows ``[start_block *
-    block, (start_block + nb) * block)`` of slot ``slot`` land in pool
-    rows ``ids`` (prompt order). Padded ``ids`` entries point past the
-    pool (``>= P``) and are DROPPED by the scatter, so one compiled
-    program per ``nb`` bucket serves every publish size; the source
-    window clamps at capacity and shifts to compensate (the
-    :func:`_masked_window_write` trick), so clamped garbage rows only
-    ever pair with dropped ids. Returns the updated ``(pool_k, pool_v)``.
-    """
-    nb = ids.shape[0]
-    block = pool_k.shape[3]
-    span = nb * block
-
-    def grab(buf: jax.Array, pool: jax.Array) -> jax.Array:
-        cur = lax.dynamic_index_in_dim(buf, slot, axis=1, keepdims=False)
-        cap = cur.shape[2]
-        s0 = jnp.asarray(start_block, jnp.int32) * block
-        ws = jnp.clip(s0, 0, cap - span)
-        window = lax.dynamic_slice_in_dim(cur, ws, span, axis=2)
-        shift = s0 - ws  # > 0 only when the window straddles capacity
-        rows = jnp.take(
-            window, jnp.arange(span, dtype=jnp.int32) + shift, axis=2,
-            mode="clip",
-        )
-        L, Hkv, _, D = rows.shape
-        rows = jnp.moveaxis(rows.reshape(L, Hkv, nb, block, D), 2, 0)
-        return pool.at[ids].set(rows.astype(pool.dtype), mode="drop")
-
-    return grab(cache_k, pool_k), grab(cache_v, pool_v)
-
-
 def _compact_window_slot(
     buf: jax.Array, start: jax.Array, src: jax.Array, n: jax.Array
 ) -> jax.Array:
@@ -1965,13 +1875,13 @@ def decode_attention(
     if mesh is not None and mesh.shape.get(ax["seq"] or "", 1) > 1:
         if tree_mask is not None:
             # The tree merge has no window-mask plumbing; the serving
-            # engine falls back to chain drafts on this topology (paged
-            # serving replicates its pool and rides the flash paths, so
-            # tree speculation under a mesh wants kv_layout="paged").
+            # engine falls back to chain drafts where its step comes
+            # here (a replicated exact pool rides the flash paths, which
+            # take the mask).
             raise ValueError(
                 "tree_mask is not supported on the sequence-sharded "
-                "tree-decode path; use the paged layout (replicated "
-                "pool, flash kernels) or linear drafts"
+                "tree-decode path; use a replicated pool (flash "
+                "kernels) or linear drafts"
             )
         mesh_kw = dict(
             mesh=mesh,

@@ -36,7 +36,6 @@ from tree_attention_tpu.serving.router import (  # noqa: F401
 )
 from tree_attention_tpu.serving.prefix_cache import (  # noqa: F401
     PagedPrefixIndex,
-    PrefixCache,
 )
 from tree_attention_tpu.serving.speculation import (  # noqa: F401
     DraftModelDrafter,

@@ -200,7 +200,6 @@ class DisaggServer:
         self.cfg = cfg
         self.params = params
         self.quantize = quantize
-        self.kv_layout = "paged"
         self.kv_block = kv_block
         npb = -(-cache_len // kv_block)
         self.kv_blocks = (
@@ -265,7 +264,7 @@ class DisaggServer:
             quant_kernel=quant_kernel, temperature=temperature,
             top_k=top_k,
             admission="chunked", slo_ttft=slo_ttft, slo_tbt=slo_tbt,
-            slo_window=slo_window, kv_layout="paged", kv_block=kv_block,
+            slo_window=slo_window, kv_block=kv_block,
             kv_shard=kv_shard,
             block_pool=self.pool, prefix_index=self.prefix_index,
         )

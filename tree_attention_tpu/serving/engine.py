@@ -4,8 +4,9 @@ The lockstep :func:`~tree_attention_tpu.models.decode.generate` decodes one
 batch whose rows start, step and stop together — requests with different
 prompt lengths, arrival times or stop points cannot share it, so aggregate
 tokens/sec dies at real traffic. This engine holds a **fixed batch of S cache
-slots** (one :class:`~tree_attention_tpu.models.decode.KVCache` of batch S
-with per-slot lengths) plus a request queue, and runs a tick loop:
+slots** (one :class:`~tree_attention_tpu.models.decode.PagedKVCache`: a
+block pool under S block tables, with per-slot lengths) plus a request
+queue, and runs a tick loop:
 
 1. **Admit** — every free slot takes the oldest pending request whose
    arrival time has passed; the slot enters the ``prefilling`` state with
@@ -13,8 +14,8 @@ with per-slot lengths) plus a request queue, and runs a tick loop:
 2. **Step** — ONE compiled **mixed** step advances the whole batch: every
    live slot contributes its one decode token, and up to ``prefill_budget``
    prompt tokens of the prefilling slots ride along as fixed-size chunks
-   (``prefill_chunk``), written **directly into each slot's region of the
-   batch cache** at that slot's running offset via the ragged mixed-Tq
+   (``prefill_chunk``), written **directly into each slot's blocks of the
+   pool** at that slot's running offset via the ragged mixed-Tq
    ``forward_step`` (per-slot ``n_tokens``). No B=1 mini cache, no insert
    copy, no per-admit host sync: a long prompt costs each live slot at most
    one chunk of extra latency per tick instead of a whole-prompt stall —
@@ -40,47 +41,37 @@ Variants:
 - ``quantize=True`` serves from an int8 cache. Chunked admission then runs
   its chunks against ONE preallocated exact **staging** cache (int8 rows
   cannot hold exact prefill activations), and at final-chunk completion the
-  staged prefix is masked, quantized under its own frozen per-channel
-  scales, and inserted — the quantize-after-prefill contract, per slot.
+  staged prefix is masked, quantized block by block under each block's
+  own frozen scales, and inserted — the quantize-after-prefill contract,
+  per block.
   One prompt stages at a time; decode ticks never wait for more than a
   chunk of prefill work either way.
 
-- ``prefix_cache=True`` (ISSUE 5) reuses shared prompt prefixes across
-  requests: a host-side radix tree over prompt blocks maps to a
-  device-resident ref-counted KV block pool
-  (:mod:`~tree_attention_tpu.serving.prefix_cache`, RadixAttention,
-  arXiv:2312.07104). On admit, the longest cached prefix is copied
-  pool -> slot (or pool -> staging under int8) with one jitted donated
-  gather and only the unmatched suffix rides the chunk budget; when a
-  prompt's prefill completes, its full blocks are published slot -> pool
-  with one jitted scatter (int8 publishes the exact staged rows, so a
-  later hit re-quantizes under its own frozen scales — the
-  quantize-after-prefill rule survives bit-for-bit).
-
-- ``kv_layout="paged"`` (ISSUE 6, the default) replaces the per-slot
-  contiguous cache with ONE ref-counted block pool under every slot AND
-  the prefix cache (vLLM's PagedAttention, arXiv:2309.06180): each slot
-  is a host-side block table into the pool
+- **One KV layout: the paged pool** (ISSUE 6; vLLM's PagedAttention,
+  arXiv:2309.06180). ONE ref-counted block pool lies under every slot AND
+  the prefix cache: each slot is a host-side block table into the pool
   (:class:`~tree_attention_tpu.models.decode.PagedKVCache`), physical
   blocks are allocated on demand by a reservation-based host allocator
-  (:mod:`~tree_attention_tpu.serving.block_pool`), and prefix reuse is
+  (:mod:`~tree_attention_tpu.serving.block_pool`). Admissions that cannot
+  reserve their worst-case block count simply wait in the queue, so the
+  pool can be sized well under ``slots × cache_len``. int8 serving pages
+  the slot cache with per-BLOCK scale scalars riding the pool (ISSUE 13).
+
+- ``prefix_cache=True`` (ISSUE 5) reuses shared prompt prefixes across
+  requests: a host-side radix tree over prompt blocks
+  (:mod:`~tree_attention_tpu.serving.prefix_cache`, RadixAttention,
+  arXiv:2312.07104) whose nodes own pool blocks. Reuse is
   **reference-in-place** — a radix hit bumps pins and writes pool ids
-  into the slot's table (zero KV bytes moved, vs. the contiguous
-  layout's pool→slot gather), while prefill completion publishes by
-  HANDING blocks over to the tree. Admissions that cannot reserve their
-  worst-case block count simply wait in the queue, so the pool can be
-  sized well under ``slots × cache_len`` and the slot count can exceed
-  what a contiguous layout could hold at equal bytes. int8 serving pages
-  the slot cache with per-BLOCK scale scalars riding the pool (ISSUE 13),
-  so quantized blocks publish into and hit from the same radix tree as
-  exact ones — the quantize-after-prefill contract holds at block
-  granularity, and hits dequant-gather the matched blocks into the
-  staging cache. With ``host_blocks > 0`` the pool grows a host-RAM
-  demotion tier under it: radix eviction demotes refcount-0 blocks
-  (staged D2H, one batched gather per tick) instead of freeing them, and
-  a hit on a demoted path restores it with one batched H2D scatter — the
-  effective prefix cache becomes host-RAM-sized.
-  ``kv_layout="contiguous"`` keeps the PR-5 layout.
+  into the slot's table (zero KV bytes moved), only the unmatched suffix
+  rides the chunk budget, and prefill completion publishes by HANDING
+  blocks over to the tree. Quantized blocks publish into and hit from
+  the same tree as exact ones — the quantize-after-prefill contract
+  holds at block granularity, and an int8 hit dequant-gathers the
+  matched blocks into the staging cache. With ``host_blocks > 0`` the
+  pool grows a host-RAM demotion tier under it: radix eviction demotes
+  refcount-0 blocks (staged D2H, one batched gather per tick) instead of
+  freeing them, and a hit on a demoted path restores it with one batched
+  H2D scatter — the effective prefix cache becomes host-RAM-sized.
 
 - ``speculate=True`` (ISSUE 8) turns every live slot's tick into a
   **draft-and-verify** step (speculative decoding, arXiv:2211.17192): a
@@ -115,10 +106,9 @@ Variants:
   no-leak invariant the chaos harness asserts. The HTTP front door
   lives in :mod:`~tree_attention_tpu.serving.ingress`.
 
-Works on one device and on a sequence-sharded mesh (the contiguous cache
-is seq-sharded and rides the tree merge; the paged pool is replicated —
-block offsets cannot stay aligned with a sequence shard — and rides the
-flash/Pallas paths).
+Works on one device and on a mesh: the pool is replicated (flash/Pallas
+paths) or, with ``kv_shard="seq"``, range-partitioned over the mesh's
+sequence shards, where every tick's decode attention runs the tree merge.
 """
 
 from __future__ import annotations
@@ -143,10 +133,7 @@ from tree_attention_tpu.obs.metrics import percentile
 from tree_attention_tpu.obs.slo import SLOMonitor
 from tree_attention_tpu.models.decode import (
     KVCache,
-    PagedKVCache,
-    PagedQuantKVCache,
     cache_token_bytes,
-    QuantKVCache,
     compact_decode_window,
     copy_pool_block,
     forward_step,
@@ -155,7 +142,6 @@ from tree_attention_tpu.models.decode import (
     init_paged_cache,
     insert_dequant_prefix,
     paged_insert_slot,
-    quantize_cache,
     quantize_paged_blocks,
     sample_rows,
     sample_slots,
@@ -166,7 +152,10 @@ from tree_attention_tpu.serving.block_pool import (
     ShardedBlockAllocator,
 )
 from tree_attention_tpu.serving.host_pool import HostBlockPool
-from tree_attention_tpu.serving.prefix_cache import TIER_DEVICE
+from tree_attention_tpu.serving.prefix_cache import (
+    TIER_DEVICE,
+    PagedPrefixIndex,
+)
 from tree_attention_tpu.serving.speculation import (
     Drafter,
     DraftProposal,
@@ -440,8 +429,7 @@ class ServeReport:
     # Prefix-reuse accounting for THIS run (diff of the pool's lifetime
     # stats over the serve() call); empty when the cache is off.
     prefix: Dict[str, Any] = dataclasses.field(default_factory=dict)
-    # Paged-pool accounting (block occupancy at run end + peak); empty
-    # under the contiguous layout.
+    # Paged-pool accounting (block occupancy at run end + peak).
     kv: Dict[str, Any] = dataclasses.field(default_factory=dict)
     # Speculative-decoding accounting for THIS run (proposed/accepted
     # draft tokens, acceptance_rate, verify ticks); empty when off.
@@ -680,12 +668,13 @@ class SlotServer:
       slots: batch size S of the slot cache — the max concurrent requests.
       cache_len: per-slot KV capacity; every admitted request needs
         ``prompt_len + max_new_tokens <= cache_len``.
-      mesh (+ axis names): sequence-shard the slot cache over a mesh; the
-        ragged decode step runs the tree merge per tick.
-      quantize: serve from an int8 cache — each request prefills exactly
-        (staged, under chunked admission) then quantizes that slot's rows
-        under its own frozen per-channel scales (the quantize-after-prefill
-        contract, per slot).
+      mesh (+ axis names): run the tick programs over a mesh; with
+        ``kv_shard="seq"`` the pool is sharded over its sequence axis and
+        the ragged decode step runs the tree merge per tick.
+      quantize: serve from an int8 pool — each request prefills exactly
+        (staged, under chunked admission) then quantizes its blocks, each
+        under its own frozen scales (the quantize-after-prefill contract,
+        per block).
       quant_kernel: which q8 kernel decode ticks run (``"q8q"`` / ``"q8"``).
       temperature / seed: sampling (0 = greedy, the deterministic default).
       prefill_chunk: max prompt tokens one tick may write for one slot
@@ -707,33 +696,25 @@ class SlotServer:
         gauges only publish while the metrics registry records.
       prefix_cache: enable shared-prompt KV reuse — admissions match
         their prompt against a radix tree of published prefixes and skip
-        prefill for the matched blocks (reference-in-place under the
-        paged layout — zero KV bytes moved, int8 included since
-        per-block scales made its blocks shareable (ISSUE 13); one pool
-        gather under the contiguous layout, whose int8 per-slot frozen
-        scales keep the exact sidecar pool).
-      prefix_block: tokens per prefix pool block (power of two; the
-        match/publish granularity). Under the paged layout this is also
-        the default page size (``kv_block``) so matching stays
-        block-aligned with the tables.
+        prefill for the matched blocks (reference-in-place — zero KV
+        bytes moved, int8 included since per-block scales made its
+        blocks shareable, ISSUE 13).
+      prefix_block: tokens per prefix block (power of two; the
+        match/publish granularity). It is also the default page size
+        (``kv_block``) so matching stays block-aligned with the tables.
       prefix_pool_blocks: how many blocks the prefix tree may RETAIN
-        (LRU-evicted at refcount 0). Under the contiguous layout this
-        sizes the separate device pool (default 64); under the paged
-        layout it is only a retention cap on the shared pool (default
-        None = bounded by the pool itself). The CLI's
+        (LRU-evicted at refcount 0): a retention cap on the shared pool
+        (default None = bounded by the pool itself). The CLI's
         ``--prefix-pool-blocks`` is deprecated in favor of the unified
         ``--kv-blocks`` budget.
-      kv_layout: ``"paged"`` (default — one block pool under every slot,
-        block-table decode, copy-free prefix hits) or ``"contiguous"``
-        (the PR-5 layout: per-slot contiguous regions + gather hits).
       kv_block: tokens per pool block (power of two). Default: follows
         ``prefix_block`` when the prefix cache is on (match granularity
         == page size), else 64. On a real TPU keep it >= the dtype's
         minimum sublane tile (8 f32 / 16 bf16 / 32 int8).
       kv_blocks: TOTAL pool capacity in blocks — the one KV memory
         budget (slots and prefix cache share it). Default:
-        ``slots × ceil(cache_len / kv_block)``, the contiguous layout's
-        capacity at equal bytes. Size it smaller to over-subscribe:
+        ``slots × ceil(cache_len / kv_block)``: every slot can fill its
+        table. Size it smaller to over-subscribe:
         admissions whose worst case cannot be reserved wait in the
         queue, and a request that could never fit fails validation with
         a clear message.
@@ -754,14 +735,14 @@ class SlotServer:
         trees verified under the tree-attention mask, SpecInfer
         arXiv:2305.09781), or any :class:`~tree_attention_tpu.serving
         .speculation.Drafter` instance (e.g. ``DraftModelDrafter``).
-        Tree proposals fall back to their root-path chain on the one
-        topology without mask plumbing (contiguous layout on a >1-way
-        seq mesh).
+        Tree proposals fall back to their root-path chain where the
+        verify step runs the tree merge (an int8 or sequence-sharded
+        pool on a >1-way seq mesh), which takes no mask.
       block_pool: bring-your-own :class:`BlockAllocator` (disaggregated
         serving, ISSUE 12: two engines — a prefill worker and a decode
         worker — share ONE pool ledger so a finished prefill's blocks
-        hand over by pure ownership transfer). Paged layout only;
-        ``kv_blocks`` defaults to (and must equal) the pool's capacity.
+        hand over by pure ownership transfer). ``kv_blocks`` defaults
+        to (and must equal) the pool's capacity.
         The DEVICE pool arrays are shared by the orchestrator
         (:class:`~tree_attention_tpu.serving.disagg.DisaggServer`
         rebinds both caches to one array set and relays after every
@@ -772,9 +753,9 @@ class SlotServer:
         .PagedPrefixIndex` over ``block_pool`` (the disaggregated pair
         shares one radix tree: the prefill worker matches/adopts, the
         decode worker holds the request's pins until retire). Implies
-        the prefix cache is on; paged serving only (int8 included since
-        per-block scales made int8 blocks shareable, ISSUE 13), and the
-        index's block size must equal ``kv_block``.
+        the prefix cache is on (int8 included since per-block scales
+        made int8 blocks shareable, ISSUE 13); the index's block size
+        must equal ``kv_block``.
       host_blocks: KV tiering (ISSUE 13) — capacity of the host-RAM
         demotion tier in blocks (``--host-blocks``; 0 = off). Radix
         eviction then DEMOTES refcount-0 blocks into pinned host memory
@@ -782,8 +763,8 @@ class SlotServer:
         instead of freeing them, and a prefix hit on a demoted path
         restores it with one batched H2D scatter into freshly allocated
         device blocks — the effective prefix cache becomes
-        host-RAM-sized. Requires the paged layout and the prefix cache
-        (demotion IS radix eviction).
+        host-RAM-sized. Requires the prefix cache (demotion IS radix
+        eviction).
     """
 
     def __init__(
@@ -808,7 +789,6 @@ class SlotServer:
         prefix_cache: bool = False,
         prefix_block: int = 64,
         prefix_pool_blocks: Optional[int] = None,
-        kv_layout: str = "paged",
         kv_block: Optional[int] = None,
         kv_blocks: Optional[int] = None,
         kv_shard: str = "replicated",
@@ -826,34 +806,12 @@ class SlotServer:
             raise ValueError(
                 f"admission must be 'chunked' or 'whole', got {admission!r}"
             )
-        if kv_layout not in ("paged", "contiguous"):
-            raise ValueError(
-                f"kv_layout must be 'paged' or 'contiguous', "
-                f"got {kv_layout!r}"
-            )
         if kv_shard not in ("replicated", "seq"):
             raise ValueError(
                 f"kv_shard must be 'replicated' or 'seq', got {kv_shard!r}"
             )
-        if kv_shard == "seq" and kv_layout != "paged":
-            raise ValueError(
-                "kv_shard='seq' shards the paged block pool; the "
-                "contiguous layout already shards the token axis via "
-                "the mesh"
-            )
-        if block_pool is not None and kv_layout != "paged":
-            raise ValueError(
-                "block_pool sharing requires kv_layout='paged' (the "
-                "contiguous layout has no block ledger to share)"
-            )
         if host_blocks < 0:
             raise ValueError(f"host_blocks must be >= 0, got {host_blocks}")
-        if host_blocks and kv_layout != "paged":
-            raise ValueError(
-                "host_blocks KV tiering requires kv_layout='paged' (the "
-                "tier demotes pool blocks; the contiguous layout has "
-                "none)"
-            )
         if prefill_chunk < 1:
             raise ValueError(
                 f"prefill_chunk must be >= 1, got {prefill_chunk}"
@@ -866,8 +824,6 @@ class SlotServer:
             # What a one-array latent pool is not built for is refused
             # here, by name, never served wrong.
             for on, what in (
-                (kv_layout != "paged",
-                 "the contiguous layout (kv_layout='contiguous')"),
                 (quantize, "int8 latent rows (quantize=True)"),
                 (kv_shard == "seq",
                  "a sequence-sharded latent pool (kv_shard='seq')"),
@@ -993,8 +949,6 @@ class SlotServer:
             from tree_attention_tpu.parallel.mesh import AXIS_SEQ
 
             self._seq_shards = max(mesh.shape.get(AXIS_SEQ, 1), 1)
-        self.kv_layout = kv_layout
-        self._paged = kv_layout == "paged"
         # Sequence-sharded pool (ISSUE 18): per-device pool bytes drop to
         # 1/W; the allocator range-partitions global block ids over the
         # mesh's seq shards and decode attention runs the shard_map'd
@@ -1007,118 +961,106 @@ class SlotServer:
         # gather into staging actually moves (ISSUE 13).
         self._kv_token_bytes_q = 2 * cfg.n_layers * cfg.n_kv_heads \
             * cfg.d_head
-        if self._paged:
-            if kv_block is None:
-                # Matching granularity == page size keeps radix hits
-                # table-aligned (a matched prefix IS whole table entries).
-                kv_block = prefix_block if prefix_cache else 64
-            elif prefix_cache and kv_block != prefix_block:
-                # Honoring only one of them silently would make the
-                # recorded config contradict the running granularity.
-                raise ValueError(
-                    f"paged layout: prefix_block ({prefix_block}) must "
-                    f"equal kv_block ({kv_block}) — radix matching "
-                    f"happens at page granularity (pass one of them, or "
-                    f"equal values)"
-                )
-            self.kv_block = kv_block
-            self._npb = -(-cache_len // kv_block)  # table width (blocks)
-            if block_pool is not None:
-                # Shared-pool mode (disaggregation): the allocator is the
-                # ONE ledger both workers admit/retire against, so this
-                # engine's view of capacity must be the pool's — a
-                # different kv_blocks would let _validate accept requests
-                # the shared pool can never hold (or reject ones it can).
-                if kv_blocks is not None and kv_blocks != block_pool.blocks:
-                    raise ValueError(
-                        f"kv_blocks {kv_blocks} contradicts the shared "
-                        f"block_pool's capacity {block_pool.blocks}"
-                    )
-                if kv_shard == "seq" and (
-                    not isinstance(block_pool, ShardedBlockAllocator)
-                    or block_pool.shards != self._seq_shards
-                ):
-                    # Both workers' device pools must agree on the id →
-                    # shard placement rule, and the shared ledger is
-                    # where that rule lives.
-                    raise ValueError(
-                        "kv_shard='seq' with a shared block_pool needs a "
-                        f"ShardedBlockAllocator over {self._seq_shards} "
-                        "shards (one placement rule for every worker)"
-                    )
-                self.kv_blocks = block_pool.blocks
-                self._pool = block_pool
-            else:
-                self.kv_blocks = (
-                    slots * self._npb if kv_blocks is None else kv_blocks
-                )
-                if kv_shard == "seq":
-                    # Round UP to a whole number of per-shard slices: the
-                    # device pool and the ledger must split evenly, and
-                    # extra blocks only ever ADD capacity.
-                    w = self._seq_shards
-                    self.kv_blocks = -(-self.kv_blocks // w) * w
-                    self._pool = ShardedBlockAllocator(self.kv_blocks, w)
-                else:
-                    self._pool = BlockAllocator(self.kv_blocks)
-            # KV tiering (ISSUE 13): the host-RAM demotion tier under
-            # the device pool. Created here (the prefix index attaches
-            # to it below); the allocator's flusher hook lets a dry
-            # reservation force the staged D2H batch mid-tick, but the
-            # steady-state flush point is the end of the tick loop.
-            self.host_blocks = host_blocks
-            self._host_pool: Optional[HostBlockPool] = None
-            self._tick_restored = 0
-            if host_blocks:
-                if prefix_index is not None:
-                    raise ValueError(
-                        "host_blocks tiering with a shared prefix_index: "
-                        "build the index with its own host_pool instead "
-                        "(the tier belongs to the shared tree, not one "
-                        "engine)"
-                    )
-                if not prefix_cache:
-                    raise ValueError(
-                        "host_blocks KV tiering requires prefix_cache=True "
-                        "(demotion is what radix eviction becomes; with "
-                        "no radix tree nothing ever demotes)"
-                    )
-                self.attach_host_tier(HostBlockPool(
-                    host_blocks,
-                    n_layers=cfg.n_layers,
-                    n_kv_heads=cfg.n_kv_heads,
-                    block=kv_block,
-                    d_head=cfg.d_head,
-                    dtype=np.int8 if quantize else np.dtype(
-                        jnp.dtype(cfg.dtype).name),
-                    quantized=quantize,
-                ))
-            self._host_table = np.zeros((slots, self._npb), np.int32)
-            self._table_dirty = False  # device table starts all-zero too
-            self._slot_nblocks = [0] * slots
-            self._slot_private: List[set] = [set() for _ in range(slots)]
-            self._slot_reserve = [0] * slots
-            self._peak_blocks_used = 0
-            self._defer_gen = -1  # see the admit loop's generation latch
-            cache: Union[KVCache, QuantKVCache, PagedKVCache,
-                         PagedQuantKVCache] = init_paged_cache(
-                cfg, slots, cache_len, self.kv_blocks,
-                block=kv_block, quantize=quantize, kv_shard=kv_shard, **kw
+        if kv_block is None:
+            # Matching granularity == page size keeps radix hits
+            # table-aligned (a matched prefix IS whole table entries).
+            kv_block = prefix_block if prefix_cache else 64
+        elif prefix_cache and kv_block != prefix_block:
+            # Honoring only one of them silently would make the
+            # recorded config contradict the running granularity.
+            raise ValueError(
+                f"prefix_block ({prefix_block}) must "
+                f"equal kv_block ({kv_block}) — radix matching "
+                f"happens at page granularity (pass one of them, or "
+                f"equal values)"
             )
+        self.kv_block = kv_block
+        self._npb = -(-cache_len // kv_block)  # table width (blocks)
+        if block_pool is not None:
+            # Shared-pool mode (disaggregation): the allocator is the
+            # ONE ledger both workers admit/retire against, so this
+            # engine's view of capacity must be the pool's — a
+            # different kv_blocks would let _validate accept requests
+            # the shared pool can never hold (or reject ones it can).
+            if kv_blocks is not None and kv_blocks != block_pool.blocks:
+                raise ValueError(
+                    f"kv_blocks {kv_blocks} contradicts the shared "
+                    f"block_pool's capacity {block_pool.blocks}"
+                )
+            if kv_shard == "seq" and (
+                not isinstance(block_pool, ShardedBlockAllocator)
+                or block_pool.shards != self._seq_shards
+            ):
+                # Both workers' device pools must agree on the id →
+                # shard placement rule, and the shared ledger is
+                # where that rule lives.
+                raise ValueError(
+                    "kv_shard='seq' with a shared block_pool needs a "
+                    f"ShardedBlockAllocator over {self._seq_shards} "
+                    "shards (one placement rule for every worker)"
+                )
+            self.kv_blocks = block_pool.blocks
+            self._pool = block_pool
         else:
-            self.host_blocks = 0
-            self._host_pool = None
-            self._tick_restored = 0
-            cache = init_cache(cfg, slots, cache_len, **kw)
-            if quantize:
-                cache = quantize_cache(cache)  # empty -> fallback scales
-        self.cache = cache
+            self.kv_blocks = (
+                slots * self._npb if kv_blocks is None else kv_blocks
+            )
+            if kv_shard == "seq":
+                # Round UP to a whole number of per-shard slices: the
+                # device pool and the ledger must split evenly, and
+                # extra blocks only ever ADD capacity.
+                w = self._seq_shards
+                self.kv_blocks = -(-self.kv_blocks // w) * w
+                self._pool = ShardedBlockAllocator(self.kv_blocks, w)
+            else:
+                self._pool = BlockAllocator(self.kv_blocks)
+        # KV tiering (ISSUE 13): the host-RAM demotion tier under
+        # the device pool. Created here (the prefix index attaches
+        # to it below); the allocator's flusher hook lets a dry
+        # reservation force the staged D2H batch mid-tick, but the
+        # steady-state flush point is the end of the tick loop.
+        self.host_blocks = host_blocks
+        self._host_pool: Optional[HostBlockPool] = None
+        self._tick_restored = 0
+        if host_blocks:
+            if prefix_index is not None:
+                raise ValueError(
+                    "host_blocks tiering with a shared prefix_index: "
+                    "build the index with its own host_pool instead "
+                    "(the tier belongs to the shared tree, not one "
+                    "engine)"
+                )
+            if not prefix_cache:
+                raise ValueError(
+                    "host_blocks KV tiering requires prefix_cache=True "
+                    "(demotion is what radix eviction becomes; with "
+                    "no radix tree nothing ever demotes)"
+                )
+            self.attach_host_tier(HostBlockPool(
+                host_blocks,
+                n_layers=cfg.n_layers,
+                n_kv_heads=cfg.n_kv_heads,
+                block=kv_block,
+                d_head=cfg.d_head,
+                dtype=np.int8 if quantize else np.dtype(
+                    jnp.dtype(cfg.dtype).name),
+                quantized=quantize,
+            ))
+        self._host_table = np.zeros((slots, self._npb), np.int32)
+        self._table_dirty = False  # device table starts all-zero too
+        self._slot_nblocks = [0] * slots
+        self._slot_private: List[set] = [set() for _ in range(slots)]
+        self._slot_reserve = [0] * slots
+        self._peak_blocks_used = 0
+        self._defer_gen = -1  # see the admit loop's generation latch
+        self.cache = init_paged_cache(
+            cfg, slots, cache_len, self.kv_blocks,
+            block=kv_block, quantize=quantize, kv_shard=kv_shard, **kw
+        )
         # Bytes a cached token takes over all layers, read from the pool
-        # the model built (a latent row is not 2·Hkv·D): what a
-        # contiguous-layout hit gathers per matched token, the cost a
-        # paged hit deletes (the bytes_moved span arg), and the report's
+        # the model built (a latent row is not 2·Hkv·D): the report's
         # ``kv.token_bytes``.
-        self._kv_token_bytes = cache_token_bytes(cache)
+        self._kv_token_bytes = cache_token_bytes(self.cache)
         # Expert layers' row counts on the tick's fetch: (layers, held+1),
         # None for a model without experts.
         self._expert_rows_shape: Optional[Tuple[int, int]] = None
@@ -1141,9 +1083,8 @@ class SlotServer:
         self._prefill_pos: List[int] = [0] * slots
         # Where each slot's prefill STARTED (0 cold, the matched length on
         # a prefix hit) — the first consumed chunk resets the slot's
-        # device length to exactly this value (a no-op where a contiguous
-        # gather already set it; load-bearing under the paged layout,
-        # where a hit is pure host bookkeeping).
+        # device length to exactly this value (a hit is pure host
+        # bookkeeping: this is the one place the device learns it).
         self._prefill_start: List[int] = [0] * slots
         self._prompt_np: List[Optional[np.ndarray]] = [None] * slots
         self._prefill_fifo: List[int] = []  # prefilling slots, admit order
@@ -1179,12 +1120,10 @@ class SlotServer:
 
         # Prefix reuse (ISSUE 5/6): the radix tree, plus the per-slot ref
         # ledger — nodes a slot matched or published stay pinned
-        # (unevictable) until that slot retires. Paged serving — int8
-        # included, since per-block scales ride the pool (ISSUE 13) —
-        # uses the in-place index over the unified pool (zero-copy
-        # hits); the contiguous layout keeps the PR-5 gather pool.
+        # (unevictable) until that slot retires. The index lies over
+        # the unified pool (zero-copy hits) — int8 included, since
+        # per-block scales ride the pool (ISSUE 13).
         self._prefix: Optional[Any] = None
-        self._paged_prefix = False
         self._slot_nodes: List[List[Any]] = [[] for _ in range(slots)]
         self._tick_prefix_hits = 0
         self._tick_prefix_reused = 0
@@ -1193,15 +1132,8 @@ class SlotServer:
             # Shared-radix mode (disaggregation): both workers hold pins
             # in ONE tree — the prefill worker matches and adopts, the
             # decode worker inherits the request's pins at handoff and
-            # releases them at retire. Any paged index can be shared —
-            # int8 included, since per-block scales ride the shared pool
-            # (ISSUE 13) — but the contiguous gather pool owns its own
-            # device buffers and cannot.
-            if not self._paged:
-                raise ValueError(
-                    "prefix_index sharing requires paged serving "
-                    "(kv_layout='paged')"
-                )
+            # releases them at retire (int8 included, since per-block
+            # scales ride the shared pool, ISSUE 13).
             if block_pool is None or prefix_index.alloc is not block_pool:
                 raise ValueError(
                     "prefix_index must be built over the same shared "
@@ -1214,7 +1146,6 @@ class SlotServer:
                     f"page granularity)"
                 )
             self._prefix = prefix_index
-            self._paged_prefix = True
         elif prefix_cache:
             if prefix_block > cache_len:
                 # Checked before the pool allocates: a block wider than a
@@ -1223,33 +1154,14 @@ class SlotServer:
                     f"prefix_block {prefix_block} exceeds cache_len "
                     f"{cache_len}"
                 )
-            if self._paged:
-                # The in-place index serves int8 too (ISSUE 13): blocks
-                # carry per-BLOCK scales in the pool, so a published
-                # int8 block is self-contained and shareable — the PR-5
-                # exact sidecar pool survives only for the contiguous
-                # layout.
-                from tree_attention_tpu.serving.prefix_cache import (
-                    PagedPrefixIndex,
-                )
-
-                self._prefix = PagedPrefixIndex(
-                    block=self.kv_block, alloc=self._pool,
-                    max_cached=prefix_pool_blocks,
-                    host_pool=self._host_pool,
-                )
-                self._paged_prefix = True
-            else:
-                from tree_attention_tpu.serving.prefix_cache import (
-                    PrefixCache,
-                )
-
-                self._prefix = PrefixCache(
-                    cfg, block=prefix_block,
-                    blocks=(64 if prefix_pool_blocks is None
-                            else prefix_pool_blocks),
-                    mesh=mesh,
-                )
+            # The in-place index serves int8 too (ISSUE 13): blocks
+            # carry per-BLOCK scales in the pool, so a published int8
+            # block is self-contained and shareable.
+            self._prefix = PagedPrefixIndex(
+                block=self.kv_block, alloc=self._pool,
+                max_cached=prefix_pool_blocks,
+                host_pool=self._host_pool,
+            )
 
         # Reusable host scratch for the legacy whole-prompt admission's
         # padded prompt matrix, one per bucket — the chunked path never
@@ -1261,9 +1173,9 @@ class SlotServer:
         # activations; allocating per admit is the cost this engine
         # removes). One prompt stages at a time. With the prefix cache on,
         # WHOLE int8 admission routes through the same staging cache too
-        # (the pool stores exact rows; hits land in staging and the
-        # publish reads exact staged rows back out), so it is allocated
-        # for that combination as well.
+        # (a hit's matched blocks land there dequantized, for the
+        # suffix to attend), so it is allocated for that combination as
+        # well.
         self._staged_prefill = quantize and admission == "chunked"
         self._needs_staging = quantize and (
             admission == "chunked" or self._prefix is not None
@@ -1272,7 +1184,7 @@ class SlotServer:
             self._staging: KVCache = init_cache(
                 cfg, 1, cache_len, **self._prefill_kw
             )
-            if self._paged_prefix:
+            if self._prefix is not None:
                 # int8 paged hits (ISSUE 13): the slot references the
                 # matched int8 blocks in place, but the suffix's exact
                 # staged prefill needs the prefix as activations-grade
@@ -1313,18 +1225,15 @@ class SlotServer:
         # committed-length ledger (the rollback truth — the device length
         # over-counts by the rejected rows until the next step's reset),
         # and the verify-step programs. The tree program only exists where
-        # the mask is plumbed; the one unplumbed topology (contiguous
-        # cache on a >1-way seq mesh rides the tree merge) falls back to
+        # the mask is plumbed; elsewhere proposals fall back to
         # root-path chains, which are exactly causal.
         self._drafter: Optional[Drafter] = None
-        # Tree masks need a mask-plumbed attention path: the contiguous
-        # tree merge has none, and the paged-QUANT off-kernel path runs
-        # its dequantized view through the same merge under a seq mesh
-        # (ISSUE 13) — both fall back to root-path chains there.
+        # Tree masks need a mask-plumbed attention path: the tree merge
+        # has none, and under a seq mesh both the sequence-sharded pool
+        # and the paged-QUANT off-kernel path (its dequantized view,
+        # ISSUE 13) run through it.
         self._tree_ok = not (
-            self._seq_shards > 1
-            and (kv_layout == "contiguous" or quantize
-                 or kv_shard == "seq")
+            self._seq_shards > 1 and (quantize or kv_shard == "seq")
         )
         # Verify chunks ride power-of-two Tq buckets like prefill chunks;
         # the bucket must fit the cache's write window, so the draft size
@@ -1431,12 +1340,10 @@ class SlotServer:
         live decode slot, a chunk for a prefilling slot, 0 for everything
         else (inert: nothing written, length frozen). ``reset`` sets a
         slot's length to ``reset_val[i]`` before the write — 0 for a cold
-        first chunk (the slot reuses a retired slot's region), the
-        matched prefix length on a prefix hit (where a contiguous gather
-        already set the device length this is a no-op; under the paged
-        layout the hit was pure host bookkeeping and THIS is where the
-        device learns it). Each slot samples from its own last valid row
-        under its own key/temperature/top-k (``keys``/``temp``/``topk``/
+        first chunk (the slot index is reused), the matched prefix
+        length on a prefix hit (the hit was pure host bookkeeping and
+        THIS is where the device learns it). Each slot samples from its
+        own last valid row under its own key/temperature/top-k (``keys``/``temp``/``topk``/
         ``idx`` — ISSUE 15; temperature-0 slots are exact argmax);
         ``emit`` keeps the sample (decode slots and final-chunk slots)
         or holds the slot's row-0 token AND its parked logprob
@@ -1489,8 +1396,7 @@ class SlotServer:
         DEVICE token vector (an ``await`` slot's first token only exists
         there until the next batched fetch). On the FIRST suffix chunk
         the slot's length resets to ``start`` (= the matched prefix
-        length): a no-op where the contiguous hit gather already set it,
-        the one place the device learns the hit under the paged layout.
+        length): the one place the device learns the hit.
         Emits the first sampled token into the token vector on the final
         chunk."""
         S, tq = self.slots, rows.shape[0]
@@ -1655,7 +1561,7 @@ class SlotServer:
 
         ``prompt`` is padded to its bucket; rows at positions >= plen are
         pad garbage, so after the step they are zeroed — the inserted slot
-        (and, under ``quantize``, its frozen per-channel scales) is then
+        (and, under ``quantize``, its blocks' frozen scales) is then
         bit-identical to an unpadded prefill, and one compile serves the
         whole bucket.
         """
@@ -1682,62 +1588,30 @@ class SlotServer:
         )
         tok, lp = tok_s[0], lp_s[0]
         if self.quantize:
-            if self._paged:
-                # Per-BLOCK quantization (ISSUE 13): each prompt block's
-                # scale is its own absmax, so the published blocks are
-                # self-contained and shareable through the radix tree.
-                kq, vq, ks, vs = quantize_paged_blocks(
-                    k, v, self.kv_block, plen
-                )
-                return (kq, vq, ks, vs, tok, lp), last
-            qc = quantize_cache(KVCache(k=k, v=v, length=mini.length))
-            return (qc.k, qc.v, qc.k_scale, qc.v_scale, tok, lp), last
+            # Per-BLOCK quantization (ISSUE 13): each prompt block's
+            # scale is its own absmax, so the published blocks are
+            # self-contained and shareable through the radix tree.
+            kq, vq, ks, vs = quantize_paged_blocks(
+                k, v, self.kv_block, plen
+            )
+            return (kq, vq, ks, vs, tok, lp), last
         return (k, v, tok, lp), last
 
     def _insert_fn(self, cache, tok_vec, lp_vec, slot, payload, plen):
         """Place a bucket-sized prefilled B=1 cache into slot ``slot`` of
-        the batch cache (k/v rows, per-slot length, first token). The
-        slot's rows beyond the bucket keep stale bytes from the previous
-        occupant — every row >= the new length is masked future, and
-        decode overwrites them before they can become visible. Under the
-        paged layout the rows scatter through the slot's block table
-        (the engine mapped blocks covering ``[0, plen)`` first)."""
+        the batch cache (k/v rows, per-slot length, first token): the
+        rows scatter through the slot's block table (the engine mapped
+        blocks covering ``[0, plen)`` first)."""
+        plen_i = jnp.asarray(plen, jnp.int32)
         if self.quantize:
             k_new, v_new, ks_new, vs_new, first, lp = payload
+            new_cache = paged_insert_slot(
+                cache, slot, k_new, v_new, plen_i, ks_new, vs_new
+            )
         else:
             k_new, v_new, first, lp = payload
+            new_cache = paged_insert_slot(cache, slot, k_new, v_new, plen_i)
         lp_vec = lax.dynamic_update_index_in_dim(lp_vec, lp, slot, axis=0)
-        if self._paged:
-            plen_i = jnp.asarray(plen, jnp.int32)
-            if self.quantize:
-                new_cache = paged_insert_slot(
-                    cache, slot, k_new, v_new, plen_i, ks_new, vs_new
-                )
-            else:
-                new_cache = paged_insert_slot(
-                    cache, slot, k_new, v_new, plen_i
-                )
-            tok_vec = lax.dynamic_update_index_in_dim(
-                tok_vec, first, slot, axis=0
-            )
-            return new_cache, tok_vec, lp_vec
-        put = lambda buf, new: lax.dynamic_update_slice(
-            buf, new.astype(buf.dtype), (0, slot, 0, 0, 0)
-        )
-        length = lax.dynamic_update_index_in_dim(
-            cache.length, jnp.asarray(plen, jnp.int32), slot, axis=0
-        )
-        if self.quantize:
-            new_cache = QuantKVCache(
-                k=put(cache.k, k_new), v=put(cache.v, v_new),
-                k_scale=put(cache.k_scale, ks_new),
-                v_scale=put(cache.v_scale, vs_new),
-                length=length,
-            )
-        else:
-            new_cache = KVCache(
-                k=put(cache.k, k_new), v=put(cache.v, v_new), length=length
-            )
         tok_vec = lax.dynamic_update_index_in_dim(tok_vec, first, slot, axis=0)
         return new_cache, tok_vec, lp_vec
 
@@ -1760,12 +1634,10 @@ class SlotServer:
                         key, temp, topk, lo=0):
         """The final chunk: finish the staged exact prefill, sample the
         first token from the last valid row, mask the stale tail, quantize
-        the staged prompt (per-slot frozen channel scales on the
-        contiguous layout; per-BLOCK scalars on the paged one — the
-        quantize-after-prefill contract, at each layout's granularity),
-        and insert slot rows + scales + length + first token into the
-        batch cache — one dispatch, no host sync (the token rides the
-        per-tick fetch). Under the paged layout the insert scatters
+        the staged prompt (per-BLOCK scalars — the quantize-after-prefill
+        contract at block granularity), and insert slot rows + scales +
+        length + first token into the batch cache — one dispatch, no host
+        sync (the token rides the per-tick fetch). The insert scatters
         through the slot's block table, skipping token positions below
         ``lo`` — a prefix hit's matched blocks are SHARED (their staged
         rows are the dequantized originals, which re-quantize to
@@ -1789,35 +1661,15 @@ class SlotServer:
         )[None, None, None, :, None]
         k_masked = jnp.where(valid, staging.k, 0)
         v_masked = jnp.where(valid, staging.v, 0)
-        if self._paged:
-            kq, vq, ks, vs = quantize_paged_blocks(
-                k_masked, v_masked, self.kv_block, plen
-            )
-            new_cache = paged_insert_slot(
-                cache, slot, kq, vq, jnp.asarray(plen, jnp.int32),
-                ks, vs, lo=lo,
-            )
-            tok_vec = lax.dynamic_update_index_in_dim(tok_vec, first,
-                                                      slot, axis=0)
-            return staging, new_cache, tok_vec, lp_vec, last
-        qc = quantize_cache(KVCache(
-            k=k_masked,
-            v=v_masked,
-            length=staging.length,
-        ))
-        put = lambda buf, new: lax.dynamic_update_index_in_dim(
-            buf, new[:, 0], slot, axis=1
+        kq, vq, ks, vs = quantize_paged_blocks(
+            k_masked, v_masked, self.kv_block, plen
         )
-        new_cache = QuantKVCache(
-            k=put(cache.k, qc.k), v=put(cache.v, qc.v),
-            k_scale=put(cache.k_scale, qc.k_scale),
-            v_scale=put(cache.v_scale, qc.v_scale),
-            length=lax.dynamic_update_index_in_dim(
-                cache.length, jnp.asarray(plen, jnp.int32), slot, axis=0
-            ),
+        new_cache = paged_insert_slot(
+            cache, slot, kq, vq, jnp.asarray(plen, jnp.int32),
+            ks, vs, lo=lo,
         )
-        tok_vec = lax.dynamic_update_index_in_dim(tok_vec, first, slot,
-                                                  axis=0)
+        tok_vec = lax.dynamic_update_index_in_dim(tok_vec, first,
+                                                  slot, axis=0)
         return staging, new_cache, tok_vec, lp_vec, last
 
     def lower_programs(self, tq: int) -> Dict[str, Any]:
@@ -1926,13 +1778,12 @@ class SlotServer:
         (``blocks_used == blocks_cached``). A disconnect storm that
         violates this leaked memory."""
         out = {
-            "blocks_private": (sum(len(s) for s in self._slot_private)
-                               if self._paged else 0),
-            "blocks_used": self._pool.used if self._paged else 0,
-            "blocks_reserved": self._pool.reserved if self._paged else 0,
+            "blocks_private": sum(len(s) for s in self._slot_private),
+            "blocks_used": self._pool.used,
+            "blocks_reserved": self._pool.reserved,
             # CoW-shared fork ancestors still refcounted by some slot
             # (ISSUE 15) — 0 after a drain, like blocks_private.
-            "blocks_shared": self._pool.shared_count if self._paged else 0,
+            "blocks_shared": self._pool.shared_count,
             "blocks_cached": 0,
             "pins": 0,
         }
@@ -1943,10 +1794,8 @@ class SlotServer:
         if self._prefix is not None:
             out["blocks_cached"] = self._prefix.blocks_used
             out["pins"] = self._prefix.total_pins()
-        elif self._paged:
-            # No prefix tree: every used block is slot-private, so a
-            # drained engine must be at used == 0 exactly.
-            pass
+        # With no prefix tree every used block is slot-private, so a
+        # drained engine must be at used == 0 exactly.
         return out
 
     def slots_snapshot(self) -> List[Dict[str, Any]]:
@@ -1966,8 +1815,7 @@ class SlotServer:
                 "index": self._slot_index[i] if req is not None else 0,
                 "tokens": len(self._slot_tokens[i]),
                 "clen": self._slot_clen[i],
-                **({"nblocks": self._slot_nblocks[i]}
-                   if self._paged else {}),
+                "nblocks": self._slot_nblocks[i],
             })
         return out
 
@@ -2090,13 +1938,12 @@ class SlotServer:
 
     def _tree_sibling_ok(self, req: Request) -> bool:
         """Can this n>1 / best-of-n request decode as a token tree in
-        ONE slot? Requires the tree-mask attention path, a paged pool,
-        and the whole family's worst-case row bundle fitting both the
-        verify Tq cap (int32 bitmask: 32 rows) and the cache window.
+        ONE slot? Requires the tree-mask attention path and the whole
+        family's worst-case row bundle fitting both the verify Tq cap (int32 bitmask: 32 rows) and the cache window.
         False falls back to the PR-15 fork-slot path — same tokens,
         k slots."""
         k = self._branches(req)
-        if k <= 1 or not self._tree_sampling or not self._paged:
+        if k <= 1 or not self._tree_sampling:
             return False
         if self._speculate or not self._tree_ok or not self._fork_ok:
             return False
@@ -2135,11 +1982,6 @@ class SlotServer:
             raise ValueError(f"request {req.uid}: fork_at must be >= 1")
         branches = self._branches(req)
         if branches > 1:
-            if not self._paged:
-                raise ValueError(
-                    f"request {req.uid}: n/best_of > 1 forks over "
-                    f"shared KV blocks — it requires kv_layout='paged'"
-                )
             if self._speculate:
                 raise ValueError(
                     f"request {req.uid}: n/best_of > 1 is not supported "
@@ -2174,43 +2016,42 @@ class SlotServer:
                 f"request {req.uid}: prompt {plen} + max_new "
                 f"{req.max_new_tokens} exceeds slot capacity {self.cache_len}"
             )
-        if self._paged:
-            # The clean over-subscription failure: a request whose worst
-            # case exceeds the WHOLE pool can never be admitted — reject
-            # it here, in English, instead of wedging the queue (a
-            # merely-scarce pool defers admission instead; see serve()).
-            need = -(-(plen + req.max_new_tokens) // self.kv_block)
-            if need > self.kv_blocks:
-                raise ValueError(
-                    f"request {req.uid}: worst case needs {need} KV "
-                    f"blocks (prompt {plen} + max_new "
-                    f"{req.max_new_tokens} at --kv-block {self.kv_block}) "
-                    f"but the --kv-blocks pool holds {self.kv_blocks}; "
-                    f"raise --kv-blocks or shrink the request"
+        # The clean over-subscription failure: a request whose worst
+        # case exceeds the WHOLE pool can never be admitted — reject
+        # it here, in English, instead of wedging the queue (a
+        # merely-scarce pool defers admission instead; see serve()).
+        need = -(-(plen + req.max_new_tokens) // self.kv_block)
+        if need > self.kv_blocks:
+            raise ValueError(
+                f"request {req.uid}: worst case needs {need} KV "
+                f"blocks (prompt {plen} + max_new "
+                f"{req.max_new_tokens} at --kv-block {self.kv_block}) "
+                f"but the --kv-blocks pool holds {self.kv_blocks}; "
+                f"raise --kv-blocks or shrink the request"
+            )
+        branches = self._branches(req)
+        if branches > 1:
+            if self._tree_sibling_ok(req):
+                # Tree-sibling worst case: the ONE slot's frozen
+                # ancestor rows plus every branch's packed suffix
+                # window (never more than the fork-slot family
+                # below — the suffix rows share every ancestor).
+                fam = -(-self._tree_span(req) // self.kv_block)
+            else:
+                # Each sibling's worst case is its NEW blocks only —
+                # everything below the fork point is shared (the CoW
+                # economics this subsystem exists for).
+                fam = need + (branches - 1) * (
+                    need - (plen - 1) // self.kv_block
                 )
-            branches = self._branches(req)
-            if branches > 1:
-                if self._tree_sibling_ok(req):
-                    # Tree-sibling worst case: the ONE slot's frozen
-                    # ancestor rows plus every branch's packed suffix
-                    # window (never more than the fork-slot family
-                    # below — the suffix rows share every ancestor).
-                    fam = -(-self._tree_span(req) // self.kv_block)
-                else:
-                    # Each sibling's worst case is its NEW blocks only —
-                    # everything below the fork point is shared (the CoW
-                    # economics this subsystem exists for).
-                    fam = need + (branches - 1) * (
-                        need - (plen - 1) // self.kv_block
-                    )
-                if fam > self.kv_blocks:
-                    raise ValueError(
-                        f"request {req.uid}: a {branches}-branch family "
-                        f"worst-cases at {fam} KV blocks (shared "
-                        f"ancestors counted once) but the pool holds "
-                        f"{self.kv_blocks}; raise --kv-blocks or shrink "
-                        f"the request"
-                    )
+            if fam > self.kv_blocks:
+                raise ValueError(
+                    f"request {req.uid}: a {branches}-branch family "
+                    f"worst-cases at {fam} KV blocks (shared "
+                    f"ancestors counted once) but the pool holds "
+                    f"{self.kv_blocks}; raise --kv-blocks or shrink "
+                    f"the request"
+                )
 
     # -- paged-pool bookkeeping -------------------------------------------
 
@@ -2235,7 +2076,7 @@ class SlotServer:
         until the forks consume it."""
         total = -(-(len(req.prompt) + req.max_new_tokens) // self.kv_block)
         matched, nodes = 0, []
-        if self._paged_prefix:
+        if self._prefix is not None:
             matched, nodes = self._prefix.match(
                 np.asarray(req.prompt, np.int32), record=False
             )
@@ -2256,7 +2097,7 @@ class SlotServer:
             if nodes:
                 self._prefix.release(nodes)
             return None
-        if self._paged_prefix:
+        if self._prefix is not None:
             self._prefix.record_match(matched)
         return matched, nodes, needed, fam_extra
 
@@ -2265,8 +2106,6 @@ class SlotServer:
         ``slot`` — called before every dispatch that writes the slot.
         Allocation is backed by the admission's reservation, so it cannot
         fail; a full free list recycles LRU refcount-0 prefix leaves."""
-        if not self._paged:
-            return
         need = -(-tokens_needed // self.kv_block)
         grew = self._slot_nblocks[slot] < need
         while self._slot_nblocks[slot] < need:
@@ -2289,8 +2128,8 @@ class SlotServer:
     def _sync_table(self) -> None:
         """Push the host block table to the device when it changed — the
         ONE host→device transfer a table update costs (a few hundred
-        int32s; the contiguous layout's prefix hit moved the KV itself)."""
-        if self._paged and self._table_dirty:
+        int32s)."""
+        if self._table_dirty:
             self.cache = dataclasses.replace(
                 self.cache, table=jnp.asarray(self._host_table)
             )
@@ -2356,7 +2195,7 @@ class SlotServer:
 
     def _admit(self, req: Request, slot: int, tick: int,
                visible_at: float,
-               resv: Optional[Tuple[int, List[Any], int, int]] = None) -> float:
+               resv: Tuple[int, List[Any], int, int]) -> float:
         # Queue wait ends the moment the scheduler takes the request —
         # BEFORE any prefill work runs (prefill, including a first-bucket
         # jit compile, is service time, not queueing).
@@ -2397,18 +2236,14 @@ class SlotServer:
             plen = len(self._prompt_np[slot])
             self._hist_buf[slot, :plen] = self._prompt_np[slot]
             self._hist_len[slot] = plen
-        if self._paged:
-            # The reservation was taken (and the radix path pinned) by
-            # _paged_reserve in the admit loop — here the slot takes
-            # ownership of both.
-            _, _, needed, _ = resv
-            self._slot_reserve[slot] = needed
-            self._slot_private[slot] = set()
-            self._slot_nblocks[slot] = 0
-        if self._paged_prefix:
-            matched = self._paged_hit(req, slot, tick, resv)
-        else:
-            matched = self._prefix_admit(req, slot, tick)
+        # The reservation was taken (and the radix path pinned) by
+        # _paged_reserve in the admit loop — here the slot takes
+        # ownership of both.
+        _, _, needed, _ = resv
+        self._slot_reserve[slot] = needed
+        self._slot_private[slot] = set()
+        self._slot_nblocks[slot] = 0
+        matched = self._paged_hit(req, slot, tick, resv)
         self._prefill_start[slot] = matched
         self._slot_prefix_hit[slot] = matched
         # The request's life as ONE span (admit -> retire; rid in args so
@@ -2444,7 +2279,7 @@ class SlotServer:
                 arrival_tick=req.arrival_tick,
                 admit_tick=tick,
                 queue_wait_s=waited,
-                nblocks=self._slot_nblocks[slot] if self._paged else 0,
+                nblocks=self._slot_nblocks[slot],
             )
             if self._adm_restored or self._adm_demoted:
                 obs.REQLOG.note(req.uid,
@@ -2464,39 +2299,6 @@ class SlotServer:
         if obs.REGISTRY.enabled:
             _QUEUE_WAIT.observe(waited)
         return waited
-
-    def _prefix_admit(self, req: Request, slot: int, tick: int) -> int:
-        """Match the prompt against the radix tree; on a hit, dispatch the
-        ONE donated pool gather (into the batch slot, or into the staging
-        cache under int8 — pool rows are exact and int8 slots re-quantize
-        at final chunk). Pins the matched path until retire. Returns the
-        matched token count (0 when disabled or cold)."""
-        if self._prefix is None:
-            return 0
-        matched, nodes = self._prefix.match(self._prompt_np[slot])
-        self._slot_nodes[slot] = nodes
-        if not matched:
-            return 0
-        if self.quantize:
-            self._staging = self._prefix.copy_into(
-                self._staging, 0, nodes, matched
-            )
-        else:
-            self.cache = self._prefix.copy_into(
-                self.cache, slot, nodes, matched
-            )
-        moved = matched * self._kv_token_bytes  # the gather's device bytes
-        self._hit_bytes_moved += moved
-        self._tick_prefix_hits += 1
-        self._tick_prefix_reused += matched
-        if obs.TRACER.active:
-            obs.instant("prefix_hit", cat="serving", args={
-                "rid": req.uid, "slot": slot, "tick": tick,
-                "matched_tokens": matched,
-                "prompt_len": len(req.prompt),
-                "bytes_moved": moved,
-            })
-        return matched
 
     def _restore_demoted(self, slot: int, nodes: List[Any]) -> int:
         """Bring a pinned path's host-tier nodes back onto the device:
@@ -2587,8 +2389,7 @@ class SlotServer:
             # rewritten (paged_insert_slot's ``lo``). The bucket cap is
             # FLOOR-div (the staged window nb*kv_block must fit inside
             # the staging cache — ceil would overhang a cache_len that
-            # is not block-divisible; same rule as PrefixCache's
-            # _nb_bucket); a matched path is at most
+            # is not block-divisible); a matched path is at most
             # (cache_len - 1) // kv_block blocks, so the cap holds.
             nb = _bucket(len(nodes), self.cache_len // self.kv_block,
                          floor=1)
@@ -2615,46 +2416,30 @@ class SlotServer:
         return matched
 
     def _publish_prefix(self, slot: int) -> None:
-        """At final-chunk completion: put the prompt's full blocks into
-        the pool and swap the slot's pinned path for the published one.
-
-        Paged exact serving publishes by ADOPTION — ownership of the
-        slot's private prompt blocks moves to the radix tree through the
-        allocator's ledger, the KV bytes stay exactly where the prefill
-        scattered them, and the slot keeps reading them through its
-        unchanged table (zero device work). The contiguous and int8
-        paths keep the PR-5 donated scatter — reading exact rows from
-        the batch cache slot, or from the staging cache under int8
-        (whose rows ARE the exact prefill, pre-quantization)."""
+        """At final-chunk completion: hand the prompt's full blocks to
+        the radix tree and swap the slot's pinned path for the published
+        one. Publishing is ADOPTION — ownership of the slot's private
+        prompt blocks moves to the tree through the allocator's ledger,
+        the KV bytes (int8 blocks with their per-block scales included)
+        stay exactly where the prefill scattered them, and the slot
+        keeps reading them through its unchanged table (zero device
+        work)."""
         if self._prefix is None:
             return
-        if self._paged_prefix:
-            prompt = self._prompt_np[slot]
-            nb_full = len(prompt) // self.kv_block
-            private = self._slot_private[slot]
-            phys = {
-                j: int(self._host_table[slot, j]) for j in range(nb_full)
-                if int(self._host_table[slot, j]) in private
-            }
-            path, adopted = self._prefix.adopt(
-                prompt, phys, self._slot_nodes[slot]
-            )
-            for j in adopted:
-                private.discard(int(self._host_table[slot, j]))
-            # The admit-time pins carried over into ``path`` (plus the
-            # freshly created nodes); retire releases them all at once.
-            self._slot_nodes[slot] = path
-            return
-        path, new_ids, start = self._prefix.insert(self._prompt_np[slot])
-        if new_ids:
-            if self.quantize:
-                self._prefix.publish_from(self._staging, 0, new_ids, start)
-            else:
-                self._prefix.publish_from(self.cache, slot, new_ids, start)
-        # Insert re-pinned the full path; only then drop the admit-time
-        # refs (a transiently ref-0 matched node could otherwise be
-        # evicted by the insert's own allocations).
-        self._prefix.release(self._slot_nodes[slot])
+        prompt = self._prompt_np[slot]
+        nb_full = len(prompt) // self.kv_block
+        private = self._slot_private[slot]
+        phys = {
+            j: int(self._host_table[slot, j]) for j in range(nb_full)
+            if int(self._host_table[slot, j]) in private
+        }
+        path, adopted = self._prefix.adopt(
+            prompt, phys, self._slot_nodes[slot]
+        )
+        for j in adopted:
+            private.discard(int(self._host_table[slot, j]))
+        # The admit-time pins carried over into ``path`` (plus the
+        # freshly created nodes); retire releases them all at once.
         self._slot_nodes[slot] = path
 
     def _admit_whole(self, req: Request, slot: int, matched: int = 0) -> None:
@@ -2664,16 +2449,17 @@ class SlotServer:
         Three shapes:
 
         - cold, exact (the legacy path): whole-prompt prefill on a
-          bucket-sized mini cache, then insert into the slot's region;
-        - prefix hit, exact: the gather already placed ``matched`` tokens
-          in the slot, so only the suffix runs — synchronous single-slot
-          chunks through a mixed-step-shaped program (one compile per
+          bucket-sized mini cache, then insert through the slot's table;
+        - prefix hit, exact: the slot's table already references the
+          ``matched`` tokens' blocks, so only the suffix runs —
+          synchronous single-slot chunks through a mixed-step-shaped program (one compile per
           chunk bucket, same bounded set as the tick's; other slots ride
           inert);
         - int8 with the prefix cache on (hit or cold): the staged path
           runs to completion synchronously — exact chunks into the
           staging cache, quantize + insert at the final chunk — because
-          both the hit gather and the publish need exact staged rows.
+          a hit's suffix attends the dequantized prefix as exact staged
+          rows.
         """
         plen = len(req.prompt)
         if self.quantize and self._prefix is not None:
@@ -3014,10 +2800,10 @@ class SlotServer:
         carry keeps the fork pending without burning retries), or
         ``"retry"`` (slot/block scarcity — bounded retries, then the
         fork expires)."""
-        if self._speculate or not self._paged:
+        if self._speculate:
             log.warning(
-                "fork(%d) ignored: forking needs a paged, "
-                "non-speculative engine", uid,
+                "fork(%d) ignored: forking needs a non-speculative "
+                "engine", uid,
             )
             return "done"
         parent = None
@@ -3419,8 +3205,6 @@ class SlotServer:
         need (their rows belonged to retired branches; host bookkeeping
         only — any in-flight gather already dispatched against the old
         table) and return the excess reservation to the pool."""
-        if not self._paged:
-            return
         while self._slot_nblocks[slot] > need:
             j = self._slot_nblocks[slot] - 1
             bid = int(self._host_table[slot, j])
@@ -3602,7 +3386,7 @@ class SlotServer:
         request's remaining token budget — the satellite contract: a
         drafter proposing past ``max_new_tokens`` is truncated here, not
         trusted), fall back to the root-path chain where the tree mask
-        cannot run (the seq-sharded contiguous topology, or a tick whose
+        cannot run (the tree merge under a seq mesh, or a tick whose
         prefill chunks widen Tq past the int32 bitmask — ``tree_ok``),
         and pack with the committed tip as row 0. A ``None`` or empty
         proposal packs to one row — a plain decode tick."""
@@ -3775,10 +3559,9 @@ class SlotServer:
                 self.cache, jnp.asarray(compact_start),
                 jnp.asarray(compact_src), jnp.asarray(compact_n),
             )
-        if self._paged:
-            for i in spec_plan:
-                if self._slot_state[i] == "live":  # retired slots freed
-                    self._spec_unmap(i)
+        for i in spec_plan:
+            if self._slot_state[i] == "live":  # retired slots freed
+                self._spec_unmap(i)
         self._spec_proposed += t_prop
         self._spec_accepted += t_acc
         self._spec_verifies += t_ver
@@ -3849,9 +3632,7 @@ class SlotServer:
                 )
             if self._slot_req[slot].uid in self._families:
                 self._slot_logits[slot] = last_row[0]
-            # The staging cache now holds the prompt's EXACT rows (the
-            # quantized copy went into the slot) — publish before the
-            # next prompt overwrites them.
+            # The quantized blocks are in the slot: hand them to the tree.
             self._publish_prefix(slot)
         else:
             self._staging = self._stage_chunk(
@@ -3872,31 +3653,30 @@ class SlotServer:
             # The request's pinned prefix path becomes evictable.
             self._prefix.release(self._slot_nodes[slot])
             self._slot_nodes[slot] = []
-        if self._paged:
-            # Blocks the tree adopted stay cached (pins just dropped);
-            # the slot's remaining private blocks — decode tail, partial
-            # prompt block, unpublished spans — go back to the free list,
-            # along with any unspent worst-case reservation (early EOS).
-            for bid in self._slot_private[slot]:
-                self._pool.free_private(bid)
-            self._slot_private[slot] = set()
-            # CoW-shared fork ancestors (ISSUE 15): this owner's
-            # refcount drops on EVERY exit arc; the last branch's
-            # release frees the block.
-            for bid in self._slot_shared[slot]:
-                self._pool.release_shared(bid)
-            self._slot_shared[slot] = set()
-            self._live_reset.pop(slot, None)
-            if self._slot_reserve[slot]:
-                self._pool.unreserve(self._slot_reserve[slot])
-                self._slot_reserve[slot] = 0
-            self._host_table[slot, :] = 0  # stale ids must never be read
-            self._slot_nblocks[slot] = 0
-            self._table_dirty = True
-            # The pin releases above can grow EVICTABILITY without
-            # touching the free list — clear the admit loop's deferral
-            # latch so the queue head retries.
-            self._pool.gen += 1
+        # Blocks the tree adopted stay cached (pins just dropped);
+        # the slot's remaining private blocks — decode tail, partial
+        # prompt block, unpublished spans — go back to the free list,
+        # along with any unspent worst-case reservation (early EOS).
+        for bid in self._slot_private[slot]:
+            self._pool.free_private(bid)
+        self._slot_private[slot] = set()
+        # CoW-shared fork ancestors (ISSUE 15): this owner's
+        # refcount drops on EVERY exit arc; the last branch's
+        # release frees the block.
+        for bid in self._slot_shared[slot]:
+            self._pool.release_shared(bid)
+        self._slot_shared[slot] = set()
+        self._live_reset.pop(slot, None)
+        if self._slot_reserve[slot]:
+            self._pool.unreserve(self._slot_reserve[slot])
+            self._slot_reserve[slot] = 0
+        self._host_table[slot, :] = 0  # stale ids must never be read
+        self._slot_nblocks[slot] = 0
+        self._table_dirty = True
+        # The pin releases above can grow EVICTABILITY without
+        # touching the free list — clear the admit loop's deferral
+        # latch so the queue head retries.
+        self._pool.gen += 1
 
     def _tree_retire_all(self, slot: int, fam: _ForkFamily, tick: int,
                          outcome: str,
@@ -4049,9 +3829,8 @@ class SlotServer:
                  self._spec_ticks, self._spec_verifies)
         fork0 = (self._forks_life, self._fork_shared_life)
         tree0 = (self._tree_fams_life, self._tree_branches_life)
-        if self._paged:
-            self._peak_blocks_used = self._pool.used
-            self._defer_gen = -1  # stale latch must not defer a fresh run
+        self._peak_blocks_used = self._pool.used
+        self._defer_gen = -1  # stale latch must not defer a fresh run
         host0 = (self._host_pool.stats()
                  if self._host_pool is not None else None)
         t0 = time.monotonic()
@@ -4218,24 +3997,22 @@ class SlotServer:
                                 and self._tree_sibling_ok(pending[0]))
                     if (1 if tree_adm else branches) > len(free):
                         break
-                    resv = None
-                    if self._paged:
-                        # Worst-case block reservation (minus what a
-                        # prefix hit shares). Failure DEFERS: the
-                        # request stays queued — FIFO, no skip-ahead —
-                        # until retires/evictions free blocks. This is
-                        # what lets --slots exceed the pool's contiguous
-                        # equivalent instead of failing on a shape. The
-                        # generation latch skips the O(prompt) re-match
-                        # + O(tree) evictability recount on ticks where
-                        # availability cannot have grown since the last
-                        # failed attempt.
-                        if self._defer_gen == self._pool.gen:
-                            break
-                        resv = self._paged_reserve(pending[0])
-                        if resv is None:
-                            self._defer_gen = self._pool.gen
-                            break
+                    # Worst-case block reservation (minus what a
+                    # prefix hit shares). Failure DEFERS: the
+                    # request stays queued — FIFO, no skip-ahead —
+                    # until retires/evictions free blocks. This is
+                    # what lets --slots exceed what the pool holds at
+                    # full length instead of failing on a shape. The
+                    # generation latch skips the O(prompt) re-match
+                    # + O(tree) evictability recount on ticks where
+                    # availability cannot have grown since the last
+                    # failed attempt.
+                    if self._defer_gen == self._pool.gen:
+                        break
+                    resv = self._paged_reserve(pending[0])
+                    if resv is None:
+                        self._defer_gen = self._pool.gen
+                        break
                     req = pending.popleft()
                     slot = free.pop(0)
                     vis = visible_wall.pop(req.uid, now)
@@ -4878,10 +4655,9 @@ class SlotServer:
                                       tokens=tokens_this_tick)
 
                     phases.mark("account")
-                    if self._paged:
-                        if self._pool.used > self._peak_blocks_used:
-                            self._peak_blocks_used = self._pool.used
-                        self._pool.publish_gauges()  # registry-guarded inside
+                    if self._pool.used > self._peak_blocks_used:
+                        self._peak_blocks_used = self._pool.used
+                    self._pool.publish_gauges()  # registry-guarded inside
                     if self._host_pool is not None:
                         # The staged D2H flush point: demotions this tick's
                         # evictions enqueued complete as ONE batched gather,
@@ -4939,29 +4715,28 @@ class SlotServer:
                             "branch_retired": self._tick_branch_retired,
                             "draining": draining,
                         }
-                        if self._paged:
-                            # Block occupancy + internal fragmentation (the
-                            # fraction of mapped block capacity no written
-                            # token occupies) — the paged black-box truths.
-                            mapped = sum(self._slot_nblocks)
-                            written = 0
-                            for i in range(self.slots):
-                                st = self._slot_state[i]
-                                if st == "prefill":
-                                    written += self._prefill_pos[i]
-                                elif st in ("await", "live"):
-                                    written += (
-                                        len(self._slot_req[i].prompt)
-                                        + max(len(self._slot_tokens[i]) - 1, 0)
-                                    )
-                            rec["kv_blocks_used"] = self._pool.used
-                            rec["kv_blocks_free"] = self._pool.free_count
-                            rec["kv_frag"] = round(
-                                1.0 - written / (mapped * self.kv_block), 4
-                            ) if mapped else 0.0
-                            if self._host_pool is not None:
-                                rec["host_blocks_used"] = self._host_pool.used
-                                rec["restored_blocks"] = self._tick_restored
+                        # Block occupancy + internal fragmentation (the
+                        # fraction of mapped block capacity no written
+                        # token occupies) — the paged black-box truths.
+                        mapped = sum(self._slot_nblocks)
+                        written = 0
+                        for i in range(self.slots):
+                            st = self._slot_state[i]
+                            if st == "prefill":
+                                written += self._prefill_pos[i]
+                            elif st in ("await", "live"):
+                                written += (
+                                    len(self._slot_req[i].prompt)
+                                    + max(len(self._slot_tokens[i]) - 1, 0)
+                                )
+                        rec["kv_blocks_used"] = self._pool.used
+                        rec["kv_blocks_free"] = self._pool.free_count
+                        rec["kv_frag"] = round(
+                            1.0 - written / (mapped * self.kv_block), 4
+                        ) if mapped else 0.0
+                        if self._host_pool is not None:
+                            rec["host_blocks_used"] = self._host_pool.used
+                            rec["restored_blocks"] = self._tick_restored
                         if expert_rows is not None:
                             rec.update(expert_rows)
                         if self._speculate:
@@ -5036,46 +4811,44 @@ class SlotServer:
                 "evictions": p1["evictions"] - prefix0["evictions"],
                 "pool_blocks_used": p1["pool_blocks_used"],
                 "pool_blocks": p1["pool_blocks"],
-                # Device KV bytes the run's hits copied pool->slot: the
-                # gather cost under the contiguous layout, identically 0
-                # under paged exact serving (reference-in-place).
+                # Device KV bytes the run's hits copied: 0 for exact
+                # serving (reference-in-place); an int8 hit's dequant
+                # gather into the staging cache.
                 "hit_bytes_moved": self._hit_bytes_moved - hit_bytes0,
             }
-        kv_snap: Dict[str, Any] = {}
-        if self._paged:
-            kv_snap = {
-                "layout": "paged",
-                "block": self.kv_block,
-                "pool_blocks": self.kv_blocks,
-                "blocks_used": self._pool.used,
-                "blocks_free": self._pool.free_count,
-                "peak_blocks_used": self._peak_blocks_used,
-                # Read from the pool the model built, all layers.
-                "token_bytes": self._kv_token_bytes,
-            }
-            if self._forks_life - fork0[0]:
-                # Copy-on-write fork accounting for THIS run (ISSUE 15).
-                kv_snap["forks"] = self._forks_life - fork0[0]
-                kv_snap["fork_blocks_shared"] = (
-                    self._fork_shared_life - fork0[1]
-                )
-            if self._tree_fams_life - tree0[0]:
-                # Token-tree sibling accounting for THIS run (ISSUE 20).
-                kv_snap["tree_families"] = (
-                    self._tree_fams_life - tree0[0]
-                )
-                kv_snap["tree_branch_ticks"] = (
-                    self._tree_branches_life - tree0[1]
-                )
-            if self._host_pool is not None:
-                h1 = self._host_pool.stats()
-                kv_snap.update({
-                    "host_blocks": h1["host_blocks"],
-                    "host_blocks_used": h1["host_blocks_used"],
-                    "demotions": h1["demotions"] - host0["demotions"],
-                    "restores": h1["restores"] - host0["restores"],
-                    "host_drops": h1["host_drops"] - host0["host_drops"],
-                })
+        kv_snap: Dict[str, Any] = {
+            "layout": "paged",
+            "block": self.kv_block,
+            "pool_blocks": self.kv_blocks,
+            "blocks_used": self._pool.used,
+            "blocks_free": self._pool.free_count,
+            "peak_blocks_used": self._peak_blocks_used,
+            # Read from the pool the model built, all layers.
+            "token_bytes": self._kv_token_bytes,
+        }
+        if self._forks_life - fork0[0]:
+            # Copy-on-write fork accounting for THIS run (ISSUE 15).
+            kv_snap["forks"] = self._forks_life - fork0[0]
+            kv_snap["fork_blocks_shared"] = (
+                self._fork_shared_life - fork0[1]
+            )
+        if self._tree_fams_life - tree0[0]:
+            # Token-tree sibling accounting for THIS run (ISSUE 20).
+            kv_snap["tree_families"] = (
+                self._tree_fams_life - tree0[0]
+            )
+            kv_snap["tree_branch_ticks"] = (
+                self._tree_branches_life - tree0[1]
+            )
+        if self._host_pool is not None:
+            h1 = self._host_pool.stats()
+            kv_snap.update({
+                "host_blocks": h1["host_blocks"],
+                "host_blocks_used": h1["host_blocks_used"],
+                "demotions": h1["demotions"] - host0["demotions"],
+                "restores": h1["restores"] - host0["restores"],
+                "host_drops": h1["host_drops"] - host0["host_drops"],
+            })
         spec_snap: Dict[str, Any] = {}
         if self._speculate:
             prop = self._spec_proposed - spec0[0]

@@ -6,55 +6,38 @@ slot server re-runs full prefill for every admission, paying Tree-
 Attention prefill compute for tokens whose KV rows already sit on the
 device. RadixAttention (Zheng et al., *SGLang*, arXiv:2312.07104) showed
 that a radix tree over prompt token sequences, mapping prefixes to cached
-KV blocks, turns that duplicate prefill into a gather. This module is
-that idea fitted to the slot engine's contracts:
+KV blocks, removes that duplicate prefill. This module is that idea
+fitted to the slot engine's contracts:
 
 - **Host-side radix tree** at ``block``-token granularity (power of two,
   bucket-friendly): each node owns ONE pool block — the KV rows of one
   ``block``-token span — keyed by that span's token tuple under its
   parent. A path from the root spells a prompt prefix; matching is a walk.
-- **Device-resident block pool**: preallocated ``(P, L, Hkv, block, D)``
-  K and V buffers (exact model dtype — int8 slots re-quantize on insert
-  under their own frozen scales, so the pool must keep exact rows).
-  Copies in and out are ONE jitted donated gather/scatter each
-  (:func:`~tree_attention_tpu.models.decode.insert_prefix_blocks` /
-  :func:`~tree_attention_tpu.models.decode.extract_prefix_blocks`), with
-  the block-count ``nb`` padded to a small power-of-two bucket set so no
-  hit or publish size ever recompiles.
+- **Blocks of the ONE paged pool**: a node's block is a block of the pool
+  every slot already reads through its block table, so a hit is a
+  host-side table update and a publish is an ownership transfer — no
+  device bytes move either way (:class:`PagedPrefixIndex`).
 - **Ref-counted LRU eviction**: a node is pinned (``refs > 0``) from the
   admission that matched or published it until that request retires;
   eviction only ever takes a refcount-0 *leaf* (evicting an interior node
   would orphan its children's prefix), least-recently-used first. The
-  pool can therefore never over-commit and never frees a block a request
+  tree can therefore never over-commit and never frees a block a request
   still depends on — the property test in
   ``tests/test_serving_prefix.py`` hammers exactly this.
 
 Matches are capped at ``len(prompt) - 1`` tokens (rounded down to the
 block size): the suffix must keep at least one token, because sampling
-the first output token needs at least one forward row. Under a mesh the
-pool is **replicated** — pool blocks land at arbitrary token offsets of a
-sequence-sharded cache, so no static sharding of the block axis can stay
-aligned with its destination shard; replication keeps the gather local
-per shard (the pool is small next to the slot cache it feeds).
+the first output token needs at least one forward row.
 """
 
 from __future__ import annotations
 
 from typing import Any, Dict, List, Optional, Tuple
 
-import jax
-import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from tree_attention_tpu import obs
-from tree_attention_tpu.models.decode import (
-    KVCache,
-    extract_prefix_blocks,
-    insert_prefix_blocks,
-)
 from tree_attention_tpu.serving.block_pool import BlockAllocator
-from tree_attention_tpu.models.transformer import TransformerConfig
 from tree_attention_tpu.utils.logging import get_logger
 
 log = get_logger("serving.prefix")
@@ -72,7 +55,7 @@ _MISSES = obs.counter(
 )
 _TOKENS_REUSED = obs.counter(
     "serving_prefix_tokens_reused_total",
-    "prompt tokens whose prefill was replaced by a pool gather",
+    "prompt tokens whose prefill was replaced by a prefix hit",
 )
 _POOL_USED = obs.gauge(
     "serving_prefix_pool_blocks_used",
@@ -84,8 +67,8 @@ def _block_key(toks: List[int], j: int, block: int) -> Tuple[int, ...]:
     """The radix key of block ``j``: that span's token tuple. Callers on
     the admission hot path convert the prompt with ONE ``tolist()`` and
     slice here at C speed — per-element ``int()`` over numpy scalars
-    measured slower than the device gather the paged hit replaces, which
-    would have made the host the new bottleneck."""
+    measured slower than a device gather of the matched blocks would be,
+    which would have made the host the bottleneck of a copy-free hit."""
     return tuple(toks[j * block:(j + 1) * block])
 
 
@@ -114,17 +97,41 @@ class _Node:
         self.tier = TIER_DEVICE
 
 
-class _RadixBase:
-    """The radix walk/pin/LRU machinery BOTH prefix indexes share.
+class PagedPrefixIndex:
+    """Radix prefix index over the UNIFIED paged pool — reference in place.
 
-    One definition of the discipline — pin-as-you-visit, LRU touch, the
-    one-suffix-token match cap, refcount-0-leaf victim selection, the
-    hit/miss stats vocabulary — so the gather-based :class:`PrefixCache`
-    and the reference-in-place :class:`PagedPrefixIndex` can never
-    silently diverge on it.
+    A host radix tree at ``block``-token granularity with the pin /
+    LRU-leaf discipline of the module docstring, whose nodes reference
+    blocks of the ONE pool every slot already reads through its block
+    table (:class:`~tree_attention_tpu.models.decode.PagedKVCache`), so
+    both halves of prefix reuse move ZERO device bytes:
+
+    - a **hit** pins the matched path and hands the engine its block ids;
+      the engine writes them into the slot's table row — a host-side
+      integer update;
+    - a **publish** ADOPTS the prefilling slot's private blocks
+      (:meth:`adopt`): ownership moves to the tree via the allocator's
+      ledger, the KV bytes stay exactly where the prefill wrote them.
+
+    ``max_cached`` bounds how many blocks the tree may retain (the
+    deprecated ``prefix_pool_blocks`` view of the world — useful for
+    tests and for bounding cold-cache memory); ``None`` lets retention
+    grow to whatever the pool's eviction pressure allows. The index
+    registers itself as the allocator's evictor, so slot allocations
+    under a full free list recycle LRU refcount-0 leaves automatically.
+
+    **Sequence-sharded pools (ISSUE 18)** need no changes here: radix
+    keys are host-side token tuples and node payloads are GLOBAL block
+    ids — which mesh shard physically holds a block's pool row is an
+    allocator detail (``ShardedBlockAllocator.shard_of``), invisible to
+    matching, pinning, adoption, and eviction. A hit under
+    ``kv_shard="seq"`` is the same host-side table update; the decode
+    merge finds the reused rows wherever they live.
     """
 
-    def _init_tree(self, block: int) -> None:
+    def __init__(self, *, block: int, alloc: BlockAllocator,
+                 max_cached: Optional[int] = None,
+                 host_pool: Optional[Any] = None):
         if block < 1 or block & (block - 1):
             raise ValueError(f"prefix block must be a power of two, "
                              f"got {block}")
@@ -137,13 +144,43 @@ class _RadixBase:
         self.misses = 0
         self.tokens_reused = 0
         self.evictions = 0
+        self.alloc = alloc
+        self.max_cached = max_cached
+        self._cached = 0  # DEVICE blocks the tree currently owns
+        self._host_cached = 0  # demoted nodes (host-tier rows)
+        # KV tiering (ISSUE 13): with a host pool attached, eviction
+        # DEMOTES the LRU victim's block into it (the node survives with
+        # its tier bit flipped) instead of freeing, and a later match on
+        # the demoted path restores it — see host_pool.py's module
+        # docstring for the block's full journey.
+        self.host = host_pool
+        alloc.set_evictor(self.evict_one, self.evictable_blocks)
+
+    # -- stats (the engine snapshots + diffs these per run) ---------------
+
+    @property
+    def blocks_used(self) -> int:
+        return self._cached
+
+    def stats(self) -> Dict[str, Any]:
+        out = {
+            "hits": self.hits,
+            "misses": self.misses,
+            "tokens_reused": self.tokens_reused,
+            "evictions": self.evictions,
+            "pool_blocks_used": self._cached,
+            "pool_blocks": (self.max_cached if self.max_cached is not None
+                            else self.alloc.blocks),
+        }
+        if self.host is not None:
+            out.update(self.host.stats())
+        return out
+
+    # -- the radix walk / pin / LRU machinery ---------------------------
 
     def _touch(self, node: _Node) -> None:
         self._clock += 1
         node.last_use = self._clock
-
-    def _key(self, prompt: np.ndarray, j: int) -> Tuple[int, ...]:
-        return _block_key(prompt.tolist(), j, self.block)
 
     def _pinned_walk(self, prompt: np.ndarray) -> List[_Node]:
         """Pin + LRU-touch the longest cached path over the prompt's
@@ -230,252 +267,7 @@ class _RadixBase:
                 best = n
         return best
 
-    def _lru_leaf(self) -> Optional[_Node]:
-        """The least-recently-used refcount-0 leaf, or None when every
-        block is pinned (directly or through a pinned descendant)."""
-        return self._lru_scan(lambda n: not n.children and not n.refs)
-
-
-class PrefixCache(_RadixBase):
-    """Device block pool + host radix tree over prompt prefixes.
-
-    Args:
-      cfg: the served model (fixes the pool's ``(L, Hkv, D)`` and dtype).
-      block: tokens per pool block (power of two; matches/publishes happen
-        at this granularity).
-      blocks: pool capacity ``P`` in blocks.
-      mesh: replicate the pool over this mesh (see module docstring).
-    """
-
-    def __init__(
-        self,
-        cfg: TransformerConfig,
-        *,
-        block: int = 64,
-        blocks: int = 64,
-        mesh: Optional[Mesh] = None,
-    ):
-        self._init_tree(block)
-        if blocks < 1:
-            raise ValueError(f"prefix pool needs >= 1 block, got {blocks}")
-        self.blocks = blocks
-        shape = (blocks, cfg.n_layers, cfg.n_kv_heads, block, cfg.d_head)
-        if mesh is not None:
-            sharding = NamedSharding(mesh, P())  # replicated (see above)
-            zeros = jax.jit(
-                lambda: jnp.zeros(shape, cfg.dtype), out_shardings=sharding
-            )
-            self.pool_k = zeros()
-            self.pool_v = zeros()
-        else:
-            self.pool_k = jnp.zeros(shape, cfg.dtype)
-            self.pool_v = jnp.zeros(shape, cfg.dtype)
-        self._free: List[int] = list(range(blocks))
-        self._copy = jax.jit(insert_prefix_blocks, donate_argnums=(0,))
-        self._publish = jax.jit(extract_prefix_blocks, donate_argnums=(0, 1))
-
-    # -- host radix tree --------------------------------------------------
-
-    @property
-    def blocks_used(self) -> int:
-        return self.blocks - len(self._free)
-
-    def stats(self) -> Dict[str, Any]:
-        return {
-            "hits": self.hits,
-            "misses": self.misses,
-            "tokens_reused": self.tokens_reused,
-            "evictions": self.evictions,
-            "pool_blocks_used": self.blocks_used,
-            "pool_blocks": self.blocks,
-        }
-
-    def match(self, prompt: np.ndarray) -> Tuple[int, List[_Node]]:
-        """Longest cached prefix of ``prompt`` in whole blocks, capped so
-        at least one suffix token remains. Returns ``(matched_tokens,
-        path)`` with every path node ref-pinned and LRU-touched — the
-        caller owns the refs until it calls :meth:`release` (the serving
-        engine holds them for the request's lifetime)."""
-        path = self._pinned_walk(prompt)
-        matched = len(path) * self.block
-        self.record_match(matched)
-        return matched, path
-
-    def insert(self, prompt: np.ndarray) -> Tuple[List[_Node], List[int],
-                                                  int]:
-        """Ensure nodes exist for ``prompt``'s full-block prefix.
-
-        Walks/extends the tree, allocating pool blocks (evicting LRU
-        refcount-0 leaves as needed) for the missing tail; stops early —
-        partial paths are valid prefixes — when the pool is fully pinned.
-        Every path node is ref-pinned as it is visited, so an eviction
-        triggered later in the same insert can never take an earlier path
-        node. Returns ``(path, new_ids, start_block)``: the ref-held
-        path, the freshly allocated pool rows still needing KV data, and
-        the block index their data starts at.
-        """
-        nb_full = len(prompt) // self.block
-        toks = prompt.tolist()
-        node = self._root
-        path: List[_Node] = []
-        j = 0
-        while j < nb_full:
-            child = node.children.get(_block_key(toks, j, self.block))
-            if child is None:
-                break
-            child.refs += 1
-            self._touch(child)
-            path.append(child)
-            node = child
-            j += 1
-        start = j
-        new_ids: List[int] = []
-        while j < nb_full:
-            bid = self._alloc()
-            if bid is None:
-                log.debug("prefix pool pinned full; publish stops at "
-                          "block %d/%d", j, nb_full)
-                break
-            child = _Node(_block_key(toks, j, self.block), node, bid)
-            child.refs = 1
-            self._touch(child)
-            node.children[child.key] = child
-            path.append(child)
-            new_ids.append(bid)
-            node = child
-            j += 1
-        return path, new_ids, start
-
-    def _alloc(self) -> Optional[int]:
-        if not self._free:
-            victim = self._lru_leaf()
-            if victim is None:
-                return None
-            self._evict(victim)
-        bid = self._free.pop()
-        if obs.REGISTRY.enabled:
-            _POOL_USED.set(self.blocks_used)
-        return bid
-
-    def _evict(self, node: _Node) -> None:
-        assert not node.children and node.refs == 0
-        del node.parent.children[node.key]
-        self._free.append(node.block_id)
-        self.evictions += 1
-        if obs.REGISTRY.enabled:
-            _POOL_USED.set(self.blocks_used)
-
-    # -- device copies ----------------------------------------------------
-
-    def _nb_bucket(self, n: int, capacity: int) -> int:
-        """Power-of-two block-count bucket, capped so the copy window fits
-        the cache (``nb * block <= capacity``) — the small fixed set of
-        compiled gather/scatter programs. The ONE bucket rule is the
-        engine's :func:`~tree_attention_tpu.serving.engine._bucket`."""
-        from tree_attention_tpu.serving.engine import _bucket
-
-        return _bucket(n, capacity // self.block, floor=1)
-
-    def copy_into(self, cache: KVCache, slot: int, nodes: List[_Node],
-                  matched: int) -> KVCache:
-        """The hit path: one jitted donated gather placing ``matched``
-        pooled tokens at offset 0 of ``slot`` (length set to ``matched``).
-        ``cache`` must be an exact :class:`KVCache` (the batch slot cache,
-        or the B=1 staging cache under int8 serving)."""
-        nb = self._nb_bucket(len(nodes), cache.capacity)
-        ids = np.zeros((nb,), np.int32)  # pad gathers block 0; rows masked
-        ids[:len(nodes)] = [n.block_id for n in nodes]
-        return self._copy(
-            cache, self.pool_k, self.pool_v, jnp.asarray(ids),
-            jnp.int32(matched), jnp.int32(slot),
-        )
-
-    def publish_from(self, cache: KVCache, slot: int, new_ids: List[int],
-                     start_block: int) -> None:
-        """The publish path: one jitted donated scatter copying the slot's
-        freshly prefilled blocks ``[start_block, start_block + len(new_ids))``
-        into their pool rows (padded ids point past the pool and drop)."""
-        if not new_ids:
-            return
-        nb = self._nb_bucket(len(new_ids), cache.capacity)
-        ids = np.full((nb,), self.blocks, np.int32)  # OOB pad -> dropped
-        ids[:len(new_ids)] = new_ids
-        self.pool_k, self.pool_v = self._publish(
-            self.pool_k, self.pool_v, cache.k, cache.v,
-            jnp.int32(slot), jnp.asarray(ids), jnp.int32(start_block),
-        )
-
-
-class PagedPrefixIndex(_RadixBase):
-    """Radix prefix index over the UNIFIED paged pool — reference in place.
-
-    The paged mirror of :class:`PrefixCache`: the same host radix tree at
-    ``block``-token granularity, the same pin/LRU-leaf discipline, but
-    nodes reference blocks of the ONE pool every slot already reads
-    through its block table (:class:`~tree_attention_tpu.models.decode
-    .PagedKVCache`), so both halves of prefix reuse move ZERO device
-    bytes:
-
-    - a **hit** pins the matched path and hands the engine its block ids;
-      the engine writes them into the slot's table row — a host-side
-      integer update where the contiguous path paid a pool→slot gather;
-    - a **publish** ADOPTS the prefilling slot's private blocks
-      (:meth:`adopt`): ownership moves to the tree via the allocator's
-      ledger, the KV bytes stay exactly where the prefill wrote them.
-
-    ``max_cached`` bounds how many blocks the tree may retain (the
-    deprecated ``prefix_pool_blocks`` view of the world — useful for
-    tests and for bounding cold-cache memory); ``None`` lets retention
-    grow to whatever the pool's eviction pressure allows. The index
-    registers itself as the allocator's evictor, so slot allocations
-    under a full free list recycle LRU refcount-0 leaves automatically.
-
-    **Sequence-sharded pools (ISSUE 18)** need no changes here: radix
-    keys are host-side token tuples and node payloads are GLOBAL block
-    ids — which mesh shard physically holds a block's pool row is an
-    allocator detail (``ShardedBlockAllocator.shard_of``), invisible to
-    matching, pinning, adoption, and eviction. A hit under
-    ``kv_shard="seq"`` is the same host-side table update; the decode
-    merge finds the reused rows wherever they live.
-    """
-
-    def __init__(self, *, block: int, alloc: "BlockAllocator",
-                 max_cached: Optional[int] = None,
-                 host_pool: Optional[Any] = None):
-        self._init_tree(block)
-        self.alloc = alloc
-        self.max_cached = max_cached
-        self._cached = 0  # DEVICE blocks the tree currently owns
-        self._host_cached = 0  # demoted nodes (host-tier rows)
-        # KV tiering (ISSUE 13): with a host pool attached, eviction
-        # DEMOTES the LRU victim's block into it (the node survives with
-        # its tier bit flipped) instead of freeing, and a later match on
-        # the demoted path restores it — see host_pool.py's module
-        # docstring for the block's full journey.
-        self.host = host_pool
-        alloc.set_evictor(self.evict_one, self.evictable_blocks)
-
-    # -- stats (same vocabulary as PrefixCache; the engine snapshots) -----
-
-    @property
-    def blocks_used(self) -> int:
-        return self._cached
-
-    def stats(self) -> Dict[str, Any]:
-        out = {
-            "hits": self.hits,
-            "misses": self.misses,
-            "tokens_reused": self.tokens_reused,
-            "evictions": self.evictions,
-            "pool_blocks_used": self._cached,
-            "pool_blocks": (self.max_cached if self.max_cached is not None
-                            else self.alloc.blocks),
-        }
-        if self.host is not None:
-            out.update(self.host.stats())
-        return out
-
-    # -- match / pin (identical contract to PrefixCache.match) ------------
+    # -- match / pin ------------------------------------------------------
 
     def match(self, prompt: np.ndarray,
               record: bool = True) -> Tuple[int, List[_Node]]:
@@ -518,8 +310,7 @@ class PagedPrefixIndex(_RadixBase):
         orphaned subtree whose block leaks). Dropped before returning,
         so availability accounting is untouched. Adoption stops early
         when the retention budget is pinned full — partial paths are
-        valid prefixes, exactly like PrefixCache's pinned-pool publish
-        stop. Returns ``(path, adopted_logical)``: the pinned nodes
+        valid prefixes. Returns ``(path, adopted_logical)``: the pinned nodes
         this request now holds (held + created) and which logical
         blocks changed owner.
         """

@@ -89,8 +89,7 @@ class DraftProposal:
 
     def chain_prefix(self) -> "DraftProposal":
         """The root path through first children — the fallback when the
-        verify path cannot run a tree mask (seq-sharded contiguous
-        cache): keep following each node's first packed child."""
+        verify path cannot run a tree mask: keep following each node's first packed child."""
         keep: List[int] = []
         cur = -1
         while True:

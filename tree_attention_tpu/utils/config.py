@@ -99,7 +99,6 @@ class RunConfig:
     prefix_block: int = 64   # pool block granularity (tokens, pow2)
     prefix_share: float = 0.0  # trace: fraction of requests sharing a prefix
     prefix_len: int = 0      # trace: shared prefix length (tokens)
-    kv_layout: str = "paged"  # paged (one block pool) | contiguous (PR-5)
     kv_block: Optional[int] = None  # tokens per pool block (pow2; None ->
     #                                 prefix-block with the cache on, else 64)
     kv_blocks: Optional[int] = None  # TOTAL pool capacity in blocks (None ->
@@ -321,20 +320,16 @@ def build_arg_parser() -> argparse.ArgumentParser:
                    default=d.prefix_cache,
                    help="serve mode: enable the radix prefix KV cache — "
                         "admissions reuse KV blocks of previously served "
-                        "prompt prefixes (one pool gather replaces their "
-                        "prefill; RadixAttention, arXiv:2312.07104)")
+                        "prompt prefixes in place (no prefill, no copy; "
+                        "RadixAttention, arXiv:2312.07104)")
     p.add_argument("--prefix-block", type=int, default=d.prefix_block,
                    help="serve mode: prefix pool block size in tokens "
                         "(power of two; the match/publish granularity)")
-    p.add_argument("--kv-layout", choices=["paged", "contiguous"],
-                   default=d.kv_layout,
-                   help="serve mode: 'paged' (default) holds every "
-                        "slot's KV as a block table over ONE ref-counted "
-                        "pool (PagedAttention, arXiv:2309.06180) — "
-                        "copy-free prefix hits, on-demand allocation, "
-                        "admissions defer when the pool is full; "
-                        "'contiguous' keeps the per-slot regions + "
-                        "gather hits")
+    p.add_argument("--kv-layout", choices=["paged"], default="paged",
+                   help="serve mode: accepted and read nowhere — serving "
+                        "has ONE KV layout, the paged pool (every slot's "
+                        "KV is a block table over one ref-counted pool; "
+                        "PagedAttention, arXiv:2309.06180)")
     p.add_argument("--kv-block", type=int, default=d.kv_block,
                    help="serve mode: tokens per KV pool block (power of "
                         "two; default --prefix-block with the prefix "
@@ -343,7 +338,7 @@ def build_arg_parser() -> argparse.ArgumentParser:
                    help="serve mode: TOTAL paged pool capacity in blocks "
                         "— the one KV memory budget slots and the prefix "
                         "cache share (default: slots * ceil(cache_len / "
-                        "kv_block), the contiguous layout's bytes). "
+                        "kv_block): every slot can fill its table). "
                         "Smaller over-subscribes: admissions wait for "
                         "free blocks instead of failing")
     p.add_argument("--kv-shard", choices=["replicated", "seq"],
@@ -356,11 +351,11 @@ def build_arg_parser() -> argparse.ArgumentParser:
                         "merges them with the tree monoid (one pmax + "
                         "two psum per tick). Max servable context grows "
                         "~linearly with W at fixed per-device KV bytes. "
-                        "Requires --kv-layout paged; 'replicated' "
-                        "(default) keeps the pool on every shard")
+                        "'replicated' (default) keeps the pool on every "
+                        "shard")
     p.add_argument("--host-blocks", type=int, default=d.host_blocks,
                    help="serve mode: host-RAM KV tier capacity in blocks "
-                        "(0 = no tier). With the paged layout + prefix "
+                        "(0 = no tier). With the prefix "
                         "cache, radix eviction DEMOTES refcount-0 blocks "
                         "into pinned host memory (async D2H, one batched "
                         "gather per tick) instead of freeing them, and a "
@@ -453,8 +448,7 @@ def build_arg_parser() -> argparse.ArgumentParser:
                         "pool; decode ticks never carry prefill rows, so "
                         "TBT stops paying for admission storms. "
                         "Composable with --serve-http (the ingress "
-                        "drives the disaggregated pair unchanged); "
-                        "paged layout only")
+                        "drives the disaggregated pair unchanged)")
     p.add_argument("--prefill-slots", type=int, default=d.prefill_slots,
                    help="--serve-disagg: prefill-pool slot count "
                         "(prompts concurrently in chunked prefill or "
