@@ -283,7 +283,7 @@ def _materialised(text):
                 yield m.group(1), m.group(2), m.group(3), inner
 
 
-def _compile_step(monkeypatch, config, tq, int8):
+def _compile_step(monkeypatch, config, tq, int8, packed=False):
     from tree_attention_tpu.models import decode
     from tree_attention_tpu.models.transformer import (
         TransformerConfig, init_params)
@@ -314,12 +314,23 @@ def _compile_step(monkeypatch, config, tq, int8):
         return decode.forward_step(params, tokens, cache, cfg,
                                    n_tokens=n_tokens)
 
+    def packed_step(params, chunk, cache, members, slots_i32):
+        # C = 1, the engine's default: one chunk beside a row a slot.
+        return decode.forward_packed_step(
+            params, chunk, members, members, slots_i32, slots_i32, cache,
+            cfg)
+
     # forward_step asks the default backend whether the kernels apply; the
     # backend here is the CPU, the target the described chip.
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    compiled = jax.jit(step, donate_argnums=(2,)).lower(
-        params, _s((slots, tq), jnp.int32), cache, _s((slots,), jnp.int32)
-    ).compile()
+    if packed:
+        compiled = jax.jit(packed_step, donate_argnums=(2,)).lower(
+            params, _s((1, tq), jnp.int32), cache, _s((1,), jnp.int32),
+            _s((slots,), jnp.int32)).compile()
+    else:
+        compiled = jax.jit(step, donate_argnums=(2,)).lower(
+            params, _s((slots, tq), jnp.int32), cache,
+            _s((slots,), jnp.int32)).compile()
     layer = blocks * hkv * blk * cfg.d_head
     view = slots * nb * hkv * blk * cfg.d_head
     return compiled, layer, view, cfg.n_layers
@@ -365,6 +376,75 @@ def test_step_keeps_the_pool_in_place(monkeypatch, config, tq, int8):
     assert mem.alias_size_in_bytes >= 2 * pool_bytes, mem
     if tq == 1:
         assert mem.temp_size_in_bytes < layer * (1 if int8 else 2), mem
+
+
+# -- the packed tick: only the rows that carry a token (ISSUE 30) -----------
+#
+# A tick with a prompt chunk runs ``forward_packed_step``: a chunk group of C
+# members beside one decode row a slot. Compiled for the chip at a cell's
+# widths it must hold what the padded step held (no pool-sized result but the
+# in-place writes, the kernels on the path) and none of the padding: no array
+# with a vocabulary axis beyond one row a slot, and no activation laid out as
+# a Tq-row matrix a slot.
+
+_ARRAY = re.compile(r"\b(bf16|f32|s8)\[([\d,]+)\]")
+
+
+def _padding_arrays(text, slots, tq, vocab, d_model):
+    """Arrays of the compiled module that only a padded tick would hold."""
+    out = set()
+    for dtype, dims in _ARRAY.findall(text):
+        dims = [int(d) for d in dims.split(",")]
+        if vocab in dims:
+            rest = math.prod(dims) // vocab
+            if rest > slots and rest != d_model:   # not logits, embed, wout
+                out.add((dtype, tuple(dims)))
+        elif len(dims) >= 3 and slots in dims \
+                and tq in dims[dims.index(slots) + 1:]:
+            out.add((dtype, tuple(dims)))
+    return sorted(out)
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("tq", [256, 16])
+@pytest.mark.parametrize("config", STEP_CONFIGS)
+def test_packed_tick_computes_only_its_rows(monkeypatch, config, tq, int8):
+    if _chip() is None:
+        pytest.skip("the v5e:2x2 topology cannot be described here")
+    compiled, layer, _, layers = _compile_step(
+        monkeypatch, config, tq, int8, packed=True)
+    text = compiled.as_text()
+    kernels = pallas_kernels(text)
+    # The chunk group's kernel and the decode group's: Q-tiled flash_fwd
+    # for a bf16 chunk of 128 rows or more, the paged decode kernels below
+    # that, for int8 and for the row a slot.
+    assert any(k.startswith("flash_decode_paged") for k in kernels), kernels
+    if tq >= 128 and not int8:
+        assert "flash_fwd" in kernels, kernels
+
+    dtype = "s8" if int8 else "bf16"
+    writes, moved = [], []
+    for name, result, opcode, inner in _materialised(text):
+        sizes = [math.prod(int(d) for d in dims.split(","))
+                 for dims in re.findall(rf"\b{dtype}\[([\d,]+),{D}\]", result)]
+        if not sizes or max(sizes) * D < layer or opcode in _MOVES_NOTHING:
+            continue
+        if opcode == "scatter" or " scatter(" in inner:
+            writes.append(name)
+        else:
+            moved.append((name, opcode, result))
+    assert not moved, moved
+    # K's and V's, for the chunk group and for the decode group.
+    assert len(writes) == 4, writes
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * layers * layer * (1 if int8 else 2)
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                           "configs", f"{config}.json")) as f:
+        c = json.load(f)
+    padding = _padding_arrays(text, c["serving"]["slots"], tq,
+                              c["vocab_size"], c["hidden_size"])
+    assert not padding, padding
 
 
 # -- the latent (MLA) pool and the expert layer (ISSUE 27) ------------------
@@ -443,8 +523,11 @@ def test_latent_and_expert_kernels_compile_for_v5e(case):
             + "an operand of mla_decode_paged is copied before the launch"
 
 
-@pytest.mark.parametrize("tq", [1, 256])
-def test_latent_step_compiles_and_keeps_the_pool_in_place(monkeypatch, tq):
+@pytest.mark.parametrize("tq,packed", [(1, False), (256, False),
+                                       (256, True), (16, True)],
+                         ids=["tq1", "tq256", "packed256", "packed16"])
+def test_latent_step_compiles_and_keeps_the_pool_in_place(monkeypatch, tq,
+                                                          packed):
     if _chip() is None:
         pytest.skip("the v5e:2x2 topology cannot be described here")
     from tree_attention_tpu.models import decode
@@ -469,13 +552,30 @@ def test_latent_step_compiles_and_keeps_the_pool_in_place(monkeypatch, tq):
                                             n_tokens=n_tokens, stats=stats)
         return logits, cache, stats["expert_rows"]
 
+    def packed_step(params, chunk, cache, members, slots_i32):
+        # The tick with a prompt chunk (ISSUE 30): C = 1 beside a row a slot.
+        stats = {}
+        logits, cache = decode.forward_packed_step(
+            params, chunk, members, members, slots_i32, slots_i32, cache,
+            cfg, stats=stats)
+        return logits, cache, stats["expert_rows"]
+
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    compiled = jax.jit(step, donate_argnums=(2,)).lower(
-        params, _s((slots, tq), jnp.int32), cache, _s((slots,), jnp.int32)
-    ).compile()
+    if packed:
+        compiled = jax.jit(packed_step, donate_argnums=(2,)).lower(
+            params, _s((1, tq), jnp.int32), cache, _s((1,), jnp.int32),
+            _s((slots,), jnp.int32)).compile()
+    else:
+        compiled = jax.jit(step, donate_argnums=(2,)).lower(
+            params, _s((slots, tq), jnp.int32), cache,
+            _s((slots,), jnp.int32)).compile()
     text = compiled.as_text()
     kernels = pallas_kernels(text)
     assert "mla_decode_paged" in kernels and "moe_grouped_matmul" in kernels
+    if packed:
+        padding = _padding_arrays(text, slots, tq, cfg.vocab_size,
+                                  cfg.d_model)
+        assert not padding, padding
     row = cfg.mla.row
     pool = cfg.n_layers * blocks * blk * row
     experts = cfg.moe.held * cfg.d_model * cfg.moe.width

@@ -774,7 +774,8 @@ def test_flight_recorder_records_serving_ticks(engine):
     FLIGHT.clear()
     FLIGHT.arm()
     try:
-        server = engine(slots=2, cache_len=32, prefill_chunk=4)
+        server = engine(slots=2, cache_len=32, prefill_chunk=4,
+                        prefill_budget=8)  # both prompts chunk side by side
         report = server.serve(_as_requests(prompt, 3))
     finally:
         FLIGHT.disarm()

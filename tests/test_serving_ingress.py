@@ -156,7 +156,9 @@ def test_cancel_mid_prefill_releases_everything(params):
     slot frees, its pinned radix path releases, its paged blocks (and
     unspent worst-case reservation) return to the pool — and the engine
     keeps serving the other slot untouched."""
-    eng = base_engine(params)
+    # Two chunks a tick: B's one-chunk prompt finishes beside A's first.
+    eng = engine(params, prefix_cache=True, prefix_block=16,
+                 prefill_budget=16)
     a = Request(uid=0, prompt=LONG_PROMPT, max_new_tokens=8)
     b = Request(uid=1, prompt=SHORT_PROMPT, max_new_tokens=6,
                 on_token=lambda t: eng.cancel(0))  # fires mid-A-prefill

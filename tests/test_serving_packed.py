@@ -1,0 +1,112 @@
+"""The packed mixed tick in the engine (ISSUE 30).
+
+A tick that carries a prompt chunk computes a chunk group of ``C`` members
+beside one decode row a slot. ``C`` is fixed when the engine is built
+(``ceil(prefill_budget / prefill_chunk)``), so:
+
+- the set of tick programs depends on the Tq buckets alone: serving eight
+  prompts at once builds no program that one prompt served alone did not
+  (what the benchmark's warm-up relies on: it serves one prompt alone, and a
+  compile inside the measured window fails the run);
+- the chunk plan stops at ``C`` slots, in FIFO order;
+- the flight record says what the tick computed: ``chunk_group`` members,
+  ``rows_computed == C·tq + S``.
+
+Tokens stay those of each request's own single-stream decode.
+"""
+
+import numpy as np
+import pytest
+import jax
+
+from tree_attention_tpu.models import init_params
+from tree_attention_tpu.obs.flight import FLIGHT
+from tree_attention_tpu.serving.engine import Request, SlotServer
+
+from tests.test_serving import CFG, KV_BLOCK, _single_stream
+
+SLOTS, CHUNK = 8, 16
+# One prompt that alone takes every bucket the engine has (16, then a tail
+# of 5 rows in the bucket of 8), as the harness's warm-up prompt does.
+WARM_LEN = CHUNK + 5
+
+
+@pytest.fixture(scope="module")
+def params():
+    return init_params(jax.random.PRNGKey(0), CFG)
+
+
+def _requests(lens, n_new=3, seed=5):
+    rng = np.random.default_rng(seed)
+    return [Request(uid=i, max_new_tokens=n_new,
+                    prompt=rng.integers(0, CFG.vocab_size, n).astype(np.int32))
+            for i, n in enumerate(lens)]
+
+
+def _programs(server):
+    return {name: getattr(server, name)._cache_size()
+            for name in ("_mixed", "_packed")}
+
+
+@pytest.mark.parametrize("group", [1, 2])
+def test_a_full_house_builds_no_program_one_prompt_did_not(params, group):
+    server = SlotServer(params, CFG, slots=SLOTS, cache_len=64,
+                        kv_block=KV_BLOCK, prefill_chunk=CHUNK,
+                        prefill_budget=group * CHUNK)
+    assert server._chunk_group == group
+    server.serve(_requests([WARM_LEN]))
+    warm = _programs(server)
+    # The decode tick; one packed program a bucket (16 and 8).
+    assert warm == {"_mixed": 1, "_packed": 2}
+
+    lens = [WARM_LEN, 7, 16, 30, 3, 21, 12, 25]
+    reqs = _requests(lens, seed=9)
+    FLIGHT.clear()
+    FLIGHT.arm()
+    try:
+        report = server.serve(reqs)
+    finally:
+        FLIGHT.disarm()
+    recs = FLIGHT.snapshot()["records"]
+    FLIGHT.clear()
+    assert _programs(server) == warm
+
+    for res in report.results:
+        req = reqs[res.uid]
+        assert res.tokens == _single_stream(
+            params, req.prompt, req.max_new_tokens, cache_len=64), res.uid
+
+    # All eight are admitted on the first tick, slot i to request i, so
+    # FIFO order is slot order: every plan is the first `group` slots
+    # still prefilling.
+    mixed = [r for r in recs if r["kind"] == "mixed"]
+    assert mixed and any(len(r["chunk_plan"]) == group for r in mixed)
+    left = {i: n for i, n in enumerate(lens)}
+    for r in mixed:
+        plan = r["chunk_plan"]
+        want = sorted(left)[:group]
+        assert [s for s, _, _ in plan] == want, (r["tick"], plan)
+        for s, n, last in plan:
+            assert n == min(CHUNK, left[s])
+            left[s] -= n
+            assert last == (left[s] == 0)
+            if last:
+                del left[s]
+        assert r["chunk_group"] == group
+        assert r["rows_computed"] == group * r["tq"] + SLOTS
+        assert r["rows_useful"] == r["chunk_tokens"] + r["occupancy"]
+    assert not left
+    for r in recs:
+        if r["kind"] == "decode":
+            assert r["chunk_group"] == 0 and r["rows_computed"] == SLOTS
+
+
+def test_the_default_budget_is_one_chunk(params):
+    server = SlotServer(params, CFG, slots=4, cache_len=64,
+                        kv_block=KV_BLOCK, prefill_chunk=CHUNK)
+    assert server.prefill_budget == CHUNK and server._chunk_group == 1
+    # The group never outgrows the slots, whatever the budget.
+    wide = SlotServer(params, CFG, slots=2, cache_len=64,
+                      kv_block=KV_BLOCK, prefill_chunk=CHUNK,
+                      prefill_budget=10 * CHUNK)
+    assert wide._chunk_group == 2

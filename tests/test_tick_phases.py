@@ -120,9 +120,15 @@ def test_kind_and_tq_agree_with_the_chunk_plan(served):
             assert r["chunk_tokens"] > 0
             assert r["tq"] == server._chunk_bucket(
                 max(n for _, n, _ in r["chunk_plan"]))
+            # A tick with a chunk is packed: the chunk group's rows and
+            # one decode row a slot, never a Tq-row matrix a slot.
+            assert r["chunk_group"] == server._chunk_group
+            assert r["rows_computed"] == \
+                r["chunk_group"] * r["tq"] + SLOTS
         else:
             assert r["chunk_tokens"] == 0 and r["tq"] == 1
-        assert r["rows_computed"] == SLOTS * r["tq"]
+            assert r["chunk_group"] == 0
+            assert r["rows_computed"] == SLOTS
 
 
 def test_rows_useful_counts_the_rows_that_carried_a_token(served):

@@ -61,6 +61,7 @@ _SCOPE = (
 #: engine.py's discovered table — see _check_table_drift.
 SLOTSERVER_DONATIONS: Dict[str, Tuple[int, ...]] = {
     "_mixed": (6,),
+    "_packed": (9,),
     "_insert": (0, 1, 2),
     "_stage_chunk": (3,),
     "_stage_final": (3, 4, 5, 6),

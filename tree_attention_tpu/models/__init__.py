@@ -26,6 +26,7 @@ from tree_attention_tpu.models.decode import (  # noqa: F401
     PagedQuantKVCache,
     QuantKVCache,
     decode_attention,
+    forward_packed_step,
     forward_step,
     generate,
     init_cache,

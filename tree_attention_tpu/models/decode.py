@@ -22,6 +22,11 @@ driver decodes one token against freshly random KV and discards the result
   the padded ``(B, Tq)`` token matrix — the shape a stall-free serving tick
   needs, where decode slots (one token) and prefill chunks (up to ``Tq``
   tokens) share ONE compiled program.
+- :func:`forward_packed_step` — the same step over only the rows that carry
+  a token: a compact chunk group ``(C, Tq)`` beside one decode row a slot,
+  ``C·Tq + S`` rows where the padded matrix has ``S·Tq``. Both run ONE layer
+  body (:func:`_step_layers`) over groups of rows (:class:`_RowGroup`: a
+  group is a table and lengths); the serving tick with a prompt chunk.
 - :func:`generate` — prefill + ``lax.scan`` of single-token steps, greedy or
   temperature sampling, donate-friendly (all slots in lockstep — the
   equal-lengths special case of the ragged machinery).
@@ -40,7 +45,7 @@ both take the per-slot ``(B,)`` ``q_position``.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional, Tuple, Union
+from typing import Any, Dict, NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -866,13 +871,94 @@ def _masked_window_write(
     return lax.dynamic_update_slice_in_dim(buf, merged, ws, axis=1)
 
 
+class _RowGroup(NamedTuple):
+    """One group of a step's rows: ``batch`` cache views of ``tq`` rows each.
+
+    A group is nothing but a table and lengths. Member ``i`` writes its first
+    ``n[i]`` rows (all ``tq`` where ``n`` is None) at ``[start[i], start[i] +
+    n[i])`` of the view its ``table`` row names (its own buffer row, for a
+    contiguous cache) and attends from there. The padded step
+    (:func:`forward_step`) is ONE group, every slot with ``Tq`` rows, and its
+    arrays are the group's as they lie (``lo`` None). A packed step
+    (:func:`forward_packed_step`) lays its groups end to end on one row axis,
+    ``(1, R, ...)``, group ``g`` from row ``lo`` on: everything row-wise in a
+    layer (norms, projections, the feed-forward half) runs on the ``R`` rows at
+    once, so the weights stream once, and only the pool write and attention
+    run group by group.
+    """
+
+    lo: Optional[int]
+    batch: int
+    tq: int
+    start: jax.Array
+    n: Optional[jax.Array]
+    table: Optional[jax.Array]
+    tree_mask: Optional[jax.Array]
+
+    @property
+    def n_valid(self) -> jax.Array:
+        if self.n is None:
+            return jnp.full((self.batch,), self.tq, jnp.int32)
+        return self.n
+
+    @property
+    def valid(self) -> jax.Array:
+        """``(batch, tq)``: the rows that carry a token."""
+        return (jnp.arange(self.tq, dtype=jnp.int32)[None, :]
+                < self.n_valid[:, None])
+
+    def take(self, a: jax.Array) -> jax.Array:
+        """The group's rows of a step array ``(1, H, R, D)`` as the ops
+        layer's ``(batch, H, tq, D)``."""
+        if self.lo is None:
+            return a
+        _, H, _, D = a.shape
+        rows = a[0, :, self.lo:self.lo + self.batch * self.tq]
+        return rows.reshape(H, self.batch, self.tq, D).transpose(1, 0, 2, 3)
+
+
+def _join_rows(groups: Tuple[_RowGroup, ...], outs) -> jax.Array:
+    """The inverse of :meth:`_RowGroup.take` over every group: per-group
+    ``(batch, H, tq, D)`` back onto the step's row axis."""
+    if groups[0].lo is None:
+        return outs[0]
+    return jnp.concatenate([
+        o.transpose(1, 0, 2, 3).reshape(o.shape[1], -1, o.shape[3])
+        for o in outs
+    ], axis=1)[None]
+
+
+def _check_block_cache(cache: Any, cfg: TransformerConfig) -> None:
+    """The model's block and the cache's kind go together."""
+    if isinstance(cache, PagedLatentCache) != (not cfg.dense_block):
+        raise ValueError(
+            "a latent-attention / expert model is served from the paged "
+            "latent pool (init_paged_cache) and no other cache; the dense "
+            f"block from no latent pool (got {type(cache).__name__})"
+        )
+
+
+def _count_step(cache: Any) -> None:
+    """One step program traced (or run eagerly), by the cache it steps."""
+    if not obs.REGISTRY.enabled:
+        return
+    quant = isinstance(cache, (QuantKVCache, PagedQuantKVCache))
+    if isinstance(cache, PagedLatentCache):
+        kind = "paged_latent"
+    elif isinstance(cache, (PagedKVCache, PagedQuantKVCache)):
+        kind = "paged_quant" if quant else "paged"
+    else:
+        kind = "quant" if quant else "exact"
+    _STEP_DISPATCH.labels(cache=kind).inc()
+
+
 def _latent_layers(
     params: Params,
     x: jax.Array,
     cache: PagedLatentCache,
     cfg: TransformerConfig,
     positions: jax.Array,
-    n_valid: jax.Array,
+    groups: Tuple[_RowGroup, ...],
     stats: Optional[Dict[str, Any]],
 ) -> Tuple[jax.Array, jax.Array]:
     """The layer loops of a latent-attention / expert model: the leading
@@ -880,7 +966,7 @@ def _latent_layers(
     whole latent pool the carry of both and the layer's blocks reached by
     offset (``table + l·N``, the layer index running through both stacks).
     Every row — decode rows and chunk rows alike — attends in absorbed
-    form against the pool it has just been written to."""
+    form against the pool it has just been written to, group by group."""
     from tree_attention_tpu.models.experts import (
         EXPERT_LEAVES, expert_layer, held_counts,
     )
@@ -888,20 +974,25 @@ def _latent_layers(
         latent_attention, latent_out, latent_qkv,
     )
 
-    start, table, N = cache.length, cache.table, cache.blocks
-    Tq = x.shape[1]
-    valid = jnp.arange(Tq, dtype=jnp.int32)[None, :] < n_valid[:, None]
+    N = cache.blocks
+    valid = groups[0].valid
+    if groups[0].lo is not None:
+        valid = jnp.concatenate([g.valid.reshape(-1) for g in groups])[None]
 
     def attend(layer, x, pool, l):
         h = rms_norm(x, layer["ln1"], cfg.norm_eps)
         rows, q_abs = latent_qkv(layer, h, positions, cfg)
-        pool = _paged_pool_write(
-            pool[:, :, None], rows, table, start, n_valid, l)[:, :, 0]
-        out_lat, _ = latent_attention(
-            q_abs, pool.reshape((-1,) + pool.shape[2:]), l * N + table,
-            q_offset=start, cfg=cfg,
-        )
-        return x + latent_out(layer, out_lat), pool
+        outs = []
+        for g in groups:
+            pool = _paged_pool_write(
+                pool[:, :, None], g.take(rows), g.table, g.start, g.n_valid,
+                l)[:, :, 0]
+            out_lat, _ = latent_attention(
+                g.take(q_abs), pool.reshape((-1,) + pool.shape[2:]),
+                l * N + g.table, q_offset=g.start, cfg=cfg,
+            )
+            outs.append(out_lat)
+        return x + latent_out(layer, _join_rows(groups, outs)), pool
 
     def dense_body(carry, xs):
         layer, l = xs
@@ -941,6 +1032,374 @@ def _latent_layers(
     return carry
 
 
+def _step_layers(
+    params: Params,
+    x: jax.Array,
+    positions: jax.Array,
+    groups: Tuple[_RowGroup, ...],
+    cache: Any,
+    cfg: TransformerConfig,
+    *,
+    mesh: Optional[Mesh],
+    axes: Dict[str, Optional[str]],
+    num_splits: Optional[int],
+    quant_kernel: str,
+    kv_shard: str,
+    stats: Optional[Dict[str, Any]],
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """Every layer of a step over ``groups`` of rows: THE layer body, which
+    the padded step (one group) and the packed step (a chunk group beside a
+    decode group) share. ``x`` and ``positions`` lie as :class:`_RowGroup`
+    says. Returns the final residual and the cache's arrays the step
+    rewrote, by field name."""
+    if isinstance(cache, PagedLatentCache):
+        x, pool = _latent_layers(
+            params, x, cache, cfg, positions, groups, stats)
+        return x, {"kv": pool}
+    paged = isinstance(cache, (PagedKVCache, PagedQuantKVCache))
+    quant = isinstance(cache, (QuantKVCache, PagedQuantKVCache))
+
+    # Satellite fix (ISSUE 8): off the TPU Pallas kernels — the eager/CPU
+    # proxy and interpret-mode runs — a paged step used to re-gather
+    # ``pool[table]`` once PER LAYER inside the scan (flash_decode's
+    # fallback materialises the logical view per call). Hoist that to ONE
+    # gather for the whole step: build each group's logical (L, B, Hkv,
+    # NB·block, D) views up front, write each layer's new rows into both the
+    # pool (the persistent state) and its view slice (a cheap Tq-row window
+    # write), and run the contiguous attention path on the view. Bit-exact
+    # with the per-layer gather — identical rows in identical order. On TPU
+    # the paged kernels stream blocks in place and this path never runs.
+    hoist_view = False
+    paged_quant = paged and quant
+    seq_sharded = False
+    if paged:
+        from tree_attention_tpu.ops import _on_tpu, _pallas_available
+
+        on_kernels = _on_tpu(params["embed"]) and _pallas_available()
+        # Under a >1-way seq mesh the contiguous view would re-route
+        # decode_attention onto the tree-merge branch (the view is
+        # replicated, not seq-sharded) — keep the block-table path there.
+        seq_shards = (
+            max(mesh.shape.get(axes["seq"] or "", 1), 1)
+            if mesh is not None else 1
+        )
+        seq_sharded = kv_shard == "seq" and seq_shards > 1
+        if seq_sharded and any(g.tree_mask is not None for g in groups):
+            raise ValueError(
+                "tree_mask is not supported under kv_shard='seq' "
+                "(paged_tree_decode has no window-mask plumbing); use "
+                "chain drafts or the replicated pool"
+            )
+        if seq_sharded:
+            # The hoisted contiguous view is a REPLICATED materialisation
+            # of the pool — the exact thing kv_shard='seq' exists to
+            # avoid. Attention stays on the block-table path, whose
+            # sharded dispatch gathers per shard inside shard_map.
+            hoist_view = False
+        elif paged_quant:
+            # Per-block scales (ISSUE 13): on TPU the q8 kernels read
+            # them as a block-indexed lane-broadcast operand; everywhere
+            # else the whole step runs on a DEQUANTIZED logical view
+            # (int8 · per-block scale, built once per step) through the
+            # exact attention paths — mesh included, since the view is
+            # replicated and the tree merge handles it like a contiguous
+            # cache. The pool stays int8 + scales; only attention's
+            # operand is dequantized, so CPU and TPU agree to int8
+            # quantization-step resolution and the engine's token-parity
+            # contracts see one consistent numeric story per topology.
+            hoist_view = not on_kernels
+        else:
+            hoist_view = seq_shards == 1 and not on_kernels
+        # Trace time under jit: one line per step-program build.
+        log.debug(
+            "forward_step: paged%s step (rows %s) on %s",
+            " int8" if quant else "",
+            " + ".join(f"{g.batch}x{g.tq}" for g in groups),
+            "the hoisted reference view" if hoist_view
+            else "the block-table dispatch"
+            + (" (Pallas kernels)" if on_kernels else " (reference gather)"),
+        )
+    views0: Tuple[jax.Array, ...] = ()
+    if hoist_view:
+        def _view(pool: jax.Array, idx: jax.Array,
+                  scales: Optional[jax.Array] = None) -> jax.Array:
+            rows = jnp.moveaxis(pool[:, idx], 2, 3)  # (L, B, Hkv, NB, blk, D)
+            if scales is not None:
+                s = jnp.swapaxes(scales[:, idx], 2, 3)  # (L, B, Hkv, NB)
+                rows = (
+                    rows.astype(jnp.float32) * s[..., None, None]
+                ).astype(cfg.dtype)
+            L, Bv, Hkv, NB, blk, D = rows.shape
+            return rows.reshape(L, Bv, Hkv, NB * blk, D)
+
+        for g in groups:
+            idx = jnp.clip(g.table, 0, cache.blocks - 1)  # (B, NB)
+            views0 += (
+                (_view(cache.k, idx, cache.k_scale),
+                 _view(cache.v, idx, cache.v_scale)) if paged_quant
+                else (_view(cache.k, idx), _view(cache.v, idx))
+            )
+    anchors = []
+    if paged_quant:
+        # The anchor rule (see PagedQuantKVCache): every row this step
+        # writes for member i quantizes under the scale of the block
+        # holding the member's last pre-write row, and each block the
+        # write ENTERS (its first row) inherits that scale — so a
+        # block's rows and its pool scale always agree, across decode
+        # appends, speculative rollback re-writes, and remapped blocks.
+        blk_sz = cache.block
+        for g in groups:
+            NBt = g.table.shape[1]
+            anchor_pb = jnp.clip(
+                jnp.take_along_axis(
+                    g.table,
+                    jnp.clip((g.start - 1) // blk_sz, 0, NBt - 1)[:, None],
+                    axis=1,
+                )[:, 0],
+                0, cache.blocks - 1,
+            )  # (B,) physical anchor block per member
+            pos_all = g.start[:, None] + jnp.arange(
+                g.tq, dtype=jnp.int32)[None, :]
+            write_pb = jnp.take_along_axis(
+                g.table, jnp.clip(pos_all // blk_sz, 0, NBt - 1), axis=1
+            )  # (B, Tq)
+            entered = (
+                g.valid
+                & (pos_all % blk_sz == 0)
+                & (pos_all < NBt * blk_sz)
+            )
+            anchors.append((anchor_pb, write_pb, entered))
+
+    # A replicated paged pool rides the layer loop WHOLE, as loop-carried
+    # state, and layer l is addressed by offset — ``table + l·N`` into the
+    # ``(L·N, Hkv, block, D)`` view — never by slicing the pool (ISSUE 25).
+    # As scanned ``xs``/``ys`` the compiled tick copied each layer's pool
+    # out, into the write's layout and back, into a fresh stacked buffer,
+    # and that whole buffer into the donated output: about five passes
+    # over the pool, for K and for V, to append one row a slot. The
+    # sequence-sharded pool still takes that route (its block axis is the
+    # sharded one; a flat view would cut it by layers), as do the
+    # contiguous caches.
+    carried = paged and not seq_sharded
+
+    def attend(gi, q, k_new, v_new, k_cache, v_cache, k_s, v_s, views,
+               l, base):
+        """The attention half of a layer for group ``gi``: its new K/V
+        rows into the cache, its queries against what the cache then
+        holds. Returns the heads' output and the cache arrays."""
+        g = groups[gi]
+        B, Tq = g.batch, g.tq
+        start, n_valid = g.start, g.n_valid
+        k_view = v_view = None
+        if hoist_view:
+            k_view, v_view = views[2 * gi:2 * gi + 2]
+        # Write member i's new rows at its own [start[i], start[i]+Tq): a
+        # vmapped dynamic-update over batch (per-slot token offsets). Under
+        # a mesh GSPMD turns it into per-shard masked writes on the seq dim.
+        # Quantized caches quantize the rows first — under the per-slot
+        # frozen scales (contiguous) or the per-block anchor scale
+        # (paged; entered blocks inherit it, see above).
+        k_deq = v_deq = None
+        k_sf = v_sf = None
+        if quant and paged:
+            anchor_pb, write_pb, entered = anchors[gi]
+            # The scales as (blocks, Hkv) rows: every layer's when carried
+            # (a bitcast), this layer's (base 0) when scanned.
+            hkv = k_s.shape[-1]
+            k_sf, v_sf = k_s.reshape(-1, hkv), v_s.reshape(-1, hkv)
+            k_anchor = k_sf[base + anchor_pb][:, :, None, None]
+            v_anchor = v_sf[base + anchor_pb][:, :, None, None]  # (B,Hkv,1,1)
+            k_new = _quantize_rows(k_new, k_anchor)
+            v_new = _quantize_rows(v_new, v_anchor)
+            vals_k = jnp.broadcast_to(
+                k_anchor[:, None, :, 0, 0], (B, Tq, hkv)
+            ).reshape(-1, hkv)
+            vals_v = jnp.broadcast_to(
+                v_anchor[:, None, :, 0, 0], (B, Tq, hkv)
+            ).reshape(-1, hkv)
+            # Rows that enter no block scatter past every layer and drop.
+            scale_tgt = jnp.where(
+                entered, base + write_pb, k_sf.shape[0]
+            ).reshape(-1)
+            k_sf = k_sf.at[scale_tgt].set(vals_k, mode="drop")
+            v_sf = v_sf.at[scale_tgt].set(vals_v, mode="drop")
+            k_s, v_s = k_sf.reshape(k_s.shape), v_sf.reshape(v_s.shape)
+            if hoist_view:
+                # The view holds DEQUANTIZED rows: mirror exactly what
+                # the pool now holds (quantize-then-dequantize), so
+                # attention over the view == attention over the pool.
+                k_deq = (
+                    k_new.astype(jnp.float32) * k_anchor
+                ).astype(k_view.dtype)
+                v_deq = (
+                    v_new.astype(jnp.float32) * v_anchor
+                ).astype(v_view.dtype)
+        elif quant:
+            k_new = _quantize_rows(k_new, k_s)
+            v_new = _quantize_rows(v_new, v_s)
+        if paged:
+            # Paged write: scatter through the block table — valid rows
+            # land in their slot's mapped blocks, padded rows drop. The
+            # contiguous path's window clamp machinery is unnecessary
+            # here (see _paged_pool_write).
+            if seq_sharded:
+                k_cache = _paged_pool_write_seq(
+                    k_cache, k_new, g.table, start, n_valid,
+                    mesh=mesh, seq_axis=axes["seq"],
+                )
+                v_cache = _paged_pool_write_seq(
+                    v_cache, v_new, g.table, start, n_valid,
+                    mesh=mesh, seq_axis=axes["seq"],
+                )
+            else:
+                k_cache = _paged_pool_write(
+                    k_cache, k_new, g.table, start, n_valid, l
+                )
+                v_cache = _paged_pool_write(
+                    v_cache, v_new, g.table, start, n_valid, l
+                )
+            if hoist_view:
+                # Mirror the new rows into the hoisted logical view (the
+                # pre-scan gather predates this layer's write) — a cheap
+                # Tq-row window write, vs re-gathering the whole pool.
+                wv = jax.vmap(_masked_window_write, in_axes=(0, 0, 0, 0))
+                mk = k_new if k_deq is None else k_deq
+                mv = v_new if v_deq is None else v_deq
+                k_view = wv(
+                    k_view, mk.astype(k_view.dtype), start, n_valid
+                )
+                v_view = wv(
+                    v_view, mv.astype(v_view.dtype), start, n_valid
+                )
+        elif g.n is None:
+            write = jax.vmap(
+                lambda buf, rows, s: lax.dynamic_update_slice_in_dim(
+                    buf, rows, s, axis=1
+                )
+            )
+            k_cache = write(k_cache, k_new.astype(k_cache.dtype), start)
+            v_cache = write(v_cache, v_new.astype(v_cache.dtype), start)
+        else:
+            # Mixed-Tq masked write: only rows < n_tokens[i] may land. A
+            # plain Tq-row dynamic-update would (a) write pad garbage the
+            # causal mask has to hide until it is overwritten and (b)
+            # CLAMP near capacity (dynamic_update_slice semantics), sliding
+            # garbage over a decode slot's newest valid rows. Instead:
+            # read the Tq-row window at a clamped offset, overlay exactly
+            # the valid rows at their true absolute positions, write it
+            # back — cache bytes outside [start, start+n) are untouched.
+            write = jax.vmap(_masked_window_write, in_axes=(0, 0, 0, 0))
+            k_cache = write(
+                k_cache, k_new.astype(k_cache.dtype), start, g.n
+            )
+            v_cache = write(
+                v_cache, v_new.astype(v_cache.dtype), start, g.n
+            )
+
+        data = axes["data"]
+        if data and mesh is not None and B % mesh.shape[data]:
+            data = None  # a packed group need not divide over the batch axis
+        attn_kw = dict(
+            q_position=start,
+            mesh=mesh,
+            data_axis=data,
+            seq_axis=axes["seq"],
+            model_axis=axes["model"],
+            block_size=cfg.attn_block_size,
+            tree_mask=g.tree_mask,
+        )
+        ak, av, ak_s, av_s = k_cache, v_cache, k_s, v_s
+        if hoist_view:
+            ak, av = k_view, v_view
+        elif carried:
+            # The kernels and the reference gather take a pool and a
+            # table: hand them every layer's blocks (a bitcast of the
+            # carry) and this layer's addresses.
+            ak = k_cache.reshape((-1,) + k_cache.shape[2:])
+            av = v_cache.reshape((-1,) + v_cache.shape[2:])
+            attn_kw["block_table"] = base + g.table
+            if quant:
+                ak_s, av_s = k_sf, v_sf
+        elif paged:
+            attn_kw["block_table"] = g.table
+            attn_kw["kv_shard"] = "seq"
+        if quant and not (paged and hoist_view):
+            out, _ = decode_attention(
+                q, ak, av, k_scale=ak_s, v_scale=av_s,
+                quant_kernel=quant_kernel, **attn_kw,
+            )
+        else:
+            # Exact caches — and the paged-quant DEQUANTIZED view (the
+            # off-kernel path; see the hoist_view comment above).
+            out, _ = decode_attention(
+                q, ak, av,
+                impl=cfg.attn_impl, num_splits=num_splits, **attn_kw,
+            )
+        return out, k_cache, v_cache, k_s, v_s
+
+    def body(carry, xs):
+        parts = list(xs)
+        layer = parts.pop(0)
+        l, base = None, 0  # layer l's first block in the flat pool
+        k_s = v_s = None
+        if carried:
+            x, k_cache, v_cache = carry[:3]
+            if quant:
+                k_s, v_s = carry[3:]
+            l = parts.pop(0)
+            base = l * cache.blocks
+        else:
+            x = carry
+            k_cache, v_cache = parts[:2]
+            parts = parts[2:]
+        views = None
+        if hoist_view:
+            views, parts = parts[:len(views0)], parts[len(views0):]
+        if quant and not carried:
+            k_s, v_s = parts
+        h = rms_norm(x, layer["ln1"], cfg.norm_eps)
+        q = _heads(h @ layer["wq"], cfg.n_heads, cfg.d_head)
+        k_new = _heads(h @ layer["wk"], cfg.n_kv_heads, cfg.d_head)
+        v_new = _heads(h @ layer["wv"], cfg.n_kv_heads, cfg.d_head)
+        q = rope(q, positions, cfg.rope_theta)
+        k_new = rope(k_new, positions, cfg.rope_theta)
+        outs = []
+        for gi, g in enumerate(groups):
+            out, k_cache, v_cache, k_s, v_s = attend(
+                gi, g.take(q), g.take(k_new), g.take(v_new),
+                k_cache, v_cache, k_s, v_s, views, l, base,
+            )
+            outs.append(out)
+        x = x + _unheads(_join_rows(groups, outs)) @ layer["wo"]
+        x = x + _mlp_block(layer, rms_norm(x, layer["ln2"], cfg.norm_eps))
+        new = (k_cache, v_cache)
+        if paged and quant:
+            new = new + (k_s, v_s)  # entered blocks' inherited scales
+        return ((x,) + new, None) if carried else (x, new)
+
+    xs = (params["layers"],)
+    init = x
+    if carried:
+        init = (x, cache.k, cache.v)
+        if quant:
+            init = init + (cache.k_scale, cache.v_scale)
+        xs = xs + (jnp.arange(cache.k.shape[0], dtype=jnp.int32),)
+    else:
+        xs = xs + (cache.k, cache.v)
+    xs = xs + views0
+    if quant and not carried:
+        xs = xs + (cache.k_scale, cache.v_scale)
+    out_carry, scanned = lax.scan(body, init, xs)
+    if carried:
+        x, scanned = out_carry[0], out_carry[1:]
+    else:
+        x = out_carry
+    pools = {"k": scanned[0], "v": scanned[1]}
+    if paged and quant:
+        pools.update(k_scale=scanned[2], v_scale=scanned[3])
+    return x, pools
+
+
 def forward_step(
     params: Params,
     tokens: jax.Array,
@@ -961,12 +1420,14 @@ def forward_step(
 ) -> Tuple[jax.Array, Union[KVCache, QuantKVCache]]:
     """Run ``Tq`` new tokens through the model against the cache.
 
-    The layer body is chosen by the model data: the rotary-GQA block with
-    the dense SwiGLU below, or, where ``cfg.mla`` / ``cfg.moe`` are set,
-    latent attention against a :class:`PagedLatentCache` with a leading
-    stack of dense-FFN layers and a stack of expert layers
-    (:func:`_latent_layers`). Checks, positions, the embedding, the final
-    norm, the head and the length bookkeeping are the same code for both.
+    The layer body (:func:`_step_layers`, shared with
+    :func:`forward_packed_step`; this step is its one-group case) is
+    chosen by the model data: the rotary-GQA block with the dense SwiGLU,
+    or, where ``cfg.mla`` / ``cfg.moe`` are set, latent attention against
+    a :class:`PagedLatentCache` with a leading stack of dense-FFN layers
+    and a stack of expert layers (:func:`_latent_layers`). Checks,
+    positions, the embedding, the final norm, the head and the length
+    bookkeeping are the same code for both.
     ``stats``, if given, is filled with the step's counters as traced
     arrays (read them in the same trace): ``expert_rows`` ``(expert
     layers, held + 1)`` int32, the valid rows routed to each held expert
@@ -1046,12 +1507,7 @@ def forward_step(
     B, Tq = tokens.shape
     start = cache.length  # (B,) per-slot offsets
     latent = isinstance(cache, PagedLatentCache)
-    if latent != (not cfg.dense_block):
-        raise ValueError(
-            "a latent-attention / expert model is served from the paged "
-            "latent pool (init_paged_cache) and no other cache; the dense "
-            f"block from no latent pool (got {type(cache).__name__})"
-        )
+    _check_block_cache(cache, cfg)
     if latent and (tree_mask is not None or kv_shard == "seq"):
         raise ValueError(
             "the latent kernel takes no tree_mask and no sequence-sharded "
@@ -1100,38 +1556,6 @@ def forward_step(
             )
     if positions is None:
         positions = start[:, None] + jnp.arange(Tq, dtype=jnp.int32)
-
-    x = jnp.take(params["embed"], tokens, axis=0)
-    quant = isinstance(cache, (QuantKVCache, PagedQuantKVCache))
-    if obs.REGISTRY.enabled:
-        kind = ("paged_quant" if quant else "paged") if paged \
-            else ("quant" if quant else "exact")
-        _STEP_DISPATCH.labels(
-            cache="paged_latent" if latent else kind).inc()
-    grew = Tq if n_tokens is None else n_tokens
-    if latent:
-        x, pool = _latent_layers(
-            params, x, cache, cfg, positions,
-            jnp.full((B,), Tq, jnp.int32) if n_tokens is None else n_tokens,
-            stats,
-        )
-        x = rms_norm(x, params["ln_f"], cfg.norm_eps)
-        return (x @ params["wout"]).astype(jnp.float32), PagedLatentCache(
-            kv=pool, table=cache.table, length=start + grew)
-
-    # Satellite fix (ISSUE 8): off the TPU Pallas kernels — the eager/CPU
-    # proxy and interpret-mode runs — a paged step used to re-gather
-    # ``pool[table]`` once PER LAYER inside the scan (flash_decode's
-    # fallback materialises the logical view per call). Hoist that to ONE
-    # gather for the whole step: build the logical (L, B, Hkv, NB·block, D)
-    # views up front, write each layer's new rows into both the pool (the
-    # persistent state) and its view slice (a cheap Tq-row window write),
-    # and run the contiguous attention path on the view. Bit-exact with
-    # the per-layer gather — identical rows in identical order. On TPU the
-    # paged kernels stream blocks in place and this path never runs.
-    hoist_view = False
-    paged_quant = paged and quant
-    seq_sharded = False
     if kv_shard not in ("replicated", "seq"):
         raise ValueError(
             f"kv_shard must be 'replicated' or 'seq', got {kv_shard!r}"
@@ -1141,329 +1565,118 @@ def forward_step(
             "kv_shard='seq' shards the paged block pool; contiguous "
             "caches shard the token axis via the mesh instead"
         )
-    if paged:
-        from tree_attention_tpu.ops import _on_tpu, _pallas_available
 
-        on_kernels = _on_tpu(params["embed"]) and _pallas_available()
-        # Under a >1-way seq mesh the contiguous view would re-route
-        # decode_attention onto the tree-merge branch (the view is
-        # replicated, not seq-sharded) — keep the block-table path there.
-        seq_shards = (
-            max(mesh.shape.get(axes["seq"] or "", 1), 1)
-            if mesh is not None else 1
-        )
-        seq_sharded = kv_shard == "seq" and seq_shards > 1
-        if seq_sharded and tree_mask is not None:
-            raise ValueError(
-                "tree_mask is not supported under kv_shard='seq' "
-                "(paged_tree_decode has no window-mask plumbing); use "
-                "chain drafts or the replicated pool"
-            )
-        if seq_sharded:
-            # The hoisted contiguous view is a REPLICATED materialisation
-            # of the pool — the exact thing kv_shard='seq' exists to
-            # avoid. Attention stays on the block-table path, whose
-            # sharded dispatch gathers per shard inside shard_map.
-            hoist_view = False
-        elif paged_quant:
-            # Per-block scales (ISSUE 13): on TPU the q8 kernels read
-            # them as a block-indexed lane-broadcast operand; everywhere
-            # else the whole step runs on a DEQUANTIZED logical view
-            # (int8 · per-block scale, built once per step) through the
-            # exact attention paths — mesh included, since the view is
-            # replicated and the tree merge handles it like a contiguous
-            # cache. The pool stays int8 + scales; only attention's
-            # operand is dequantized, so CPU and TPU agree to int8
-            # quantization-step resolution and the engine's token-parity
-            # contracts see one consistent numeric story per topology.
-            hoist_view = not on_kernels
-        else:
-            hoist_view = seq_shards == 1 and not on_kernels
-        # Trace time under jit: one line per step-program build.
-        log.debug(
-            "forward_step: paged%s step (Tq=%d) on %s",
-            " int8" if quant else "", Tq,
-            "the hoisted reference view" if hoist_view
-            else "the block-table dispatch"
-            + (" (Pallas kernels)" if on_kernels else " (reference gather)"),
-        )
-    if hoist_view:
-        idx = jnp.clip(cache.table, 0, cache.blocks - 1)  # (B, NB)
-
-        def _view(pool: jax.Array,
-                  scales: Optional[jax.Array] = None) -> jax.Array:
-            rows = jnp.moveaxis(pool[:, idx], 2, 3)  # (L, B, Hkv, NB, blk, D)
-            if scales is not None:
-                s = jnp.swapaxes(scales[:, idx], 2, 3)  # (L, B, Hkv, NB)
-                rows = (
-                    rows.astype(jnp.float32) * s[..., None, None]
-                ).astype(cfg.dtype)
-            L, Bv, Hkv, NB, blk, D = rows.shape
-            return rows.reshape(L, Bv, Hkv, NB * blk, D)
-
-        if paged_quant:
-            k_view0 = _view(cache.k, cache.k_scale)
-            v_view0 = _view(cache.v, cache.v_scale)
-        else:
-            k_view0, v_view0 = _view(cache.k), _view(cache.v)
-    if paged_quant:
-        # The anchor rule (see PagedQuantKVCache): every row this step
-        # writes for slot i quantizes under the scale of the block
-        # holding the slot's last pre-write row, and each block the
-        # write ENTERS (its first row) inherits that scale — so a
-        # block's rows and its pool scale always agree, across decode
-        # appends, speculative rollback re-writes, and remapped blocks.
-        blk_sz = cache.block
-        NBt = cache.table.shape[1]
-        anchor_pb = jnp.clip(
-            jnp.take_along_axis(
-                cache.table,
-                jnp.clip((start - 1) // blk_sz, 0, NBt - 1)[:, None],
-                axis=1,
-            )[:, 0],
-            0, cache.blocks - 1,
-        )  # (B,) physical anchor block per slot
-        n_valid_all = (
-            jnp.full((B,), Tq, jnp.int32) if n_tokens is None else n_tokens
-        )
-        pos_all = start[:, None] + jnp.arange(Tq, dtype=jnp.int32)[None, :]
-        write_pb = jnp.take_along_axis(
-            cache.table, jnp.clip(pos_all // blk_sz, 0, NBt - 1), axis=1
-        )  # (B, Tq)
-        entered = (
-            (jnp.arange(Tq, dtype=jnp.int32)[None, :]
-             < n_valid_all[:, None])
-            & (pos_all % blk_sz == 0)
-            & (pos_all < NBt * blk_sz)
-        )
-
-    # A replicated paged pool rides the layer loop WHOLE, as loop-carried
-    # state, and layer l is addressed by offset — ``table + l·N`` into the
-    # ``(L·N, Hkv, block, D)`` view — never by slicing the pool (ISSUE 25).
-    # As scanned ``xs``/``ys`` the compiled tick copied each layer's pool
-    # out, into the write's layout and back, into a fresh stacked buffer,
-    # and that whole buffer into the donated output: about five passes
-    # over the pool, for K and for V, to append one row a slot. The
-    # sequence-sharded pool still takes that route (its block axis is the
-    # sharded one; a flat view would cut it by layers), as do the
-    # contiguous caches.
-    carried = paged and not seq_sharded
-
-    def body(carry, xs):
-        parts = list(xs)
-        layer = parts.pop(0)
-        base = 0  # layer l's first block in the flat pool
-        k_s = v_s = None
-        if carried:
-            x, k_cache, v_cache = carry[:3]
-            if quant:
-                k_s, v_s = carry[3:]
-            l = parts.pop(0)
-            base = l * cache.blocks
-        else:
-            x = carry
-            k_cache, v_cache = parts[:2]
-            parts = parts[2:]
-        k_view = v_view = None
-        if hoist_view:
-            k_view, v_view = parts[:2]
-            parts = parts[2:]
-        if quant and not carried:
-            k_s, v_s = parts
-        h = rms_norm(x, layer["ln1"], cfg.norm_eps)
-        q = _heads(h @ layer["wq"], cfg.n_heads, cfg.d_head)
-        k_new = _heads(h @ layer["wk"], cfg.n_kv_heads, cfg.d_head)
-        v_new = _heads(h @ layer["wv"], cfg.n_kv_heads, cfg.d_head)
-        q = rope(q, positions, cfg.rope_theta)
-        k_new = rope(k_new, positions, cfg.rope_theta)
-
-        # Write slot i's new rows at its own [start[i], start[i]+Tq): a
-        # vmapped dynamic-update over batch (per-slot token offsets). Under
-        # a mesh GSPMD turns it into per-shard masked writes on the seq dim.
-        # Quantized caches quantize the rows first — under the per-slot
-        # frozen scales (contiguous) or the per-block anchor scale
-        # (paged; entered blocks inherit it, see above).
-        k_deq = v_deq = None
-        if quant and paged:
-            # The scales as (blocks, Hkv) rows: every layer's when carried
-            # (a bitcast), this layer's (base 0) when scanned.
-            hkv = k_s.shape[-1]
-            k_sf, v_sf = k_s.reshape(-1, hkv), v_s.reshape(-1, hkv)
-            k_anchor = k_sf[base + anchor_pb][:, :, None, None]
-            v_anchor = v_sf[base + anchor_pb][:, :, None, None]  # (B,Hkv,1,1)
-            k_new = _quantize_rows(k_new, k_anchor)
-            v_new = _quantize_rows(v_new, v_anchor)
-            vals_k = jnp.broadcast_to(
-                k_anchor[:, None, :, 0, 0], (B, Tq, hkv)
-            ).reshape(-1, hkv)
-            vals_v = jnp.broadcast_to(
-                v_anchor[:, None, :, 0, 0], (B, Tq, hkv)
-            ).reshape(-1, hkv)
-            # Rows that enter no block scatter past every layer and drop.
-            scale_tgt = jnp.where(
-                entered, base + write_pb, k_sf.shape[0]
-            ).reshape(-1)
-            k_sf = k_sf.at[scale_tgt].set(vals_k, mode="drop")
-            v_sf = v_sf.at[scale_tgt].set(vals_v, mode="drop")
-            k_s, v_s = k_sf.reshape(k_s.shape), v_sf.reshape(v_s.shape)
-            if hoist_view:
-                # The view holds DEQUANTIZED rows: mirror exactly what
-                # the pool now holds (quantize-then-dequantize), so
-                # attention over the view == attention over the pool.
-                k_deq = (
-                    k_new.astype(jnp.float32) * k_anchor
-                ).astype(k_view.dtype)
-                v_deq = (
-                    v_new.astype(jnp.float32) * v_anchor
-                ).astype(v_view.dtype)
-        elif quant:
-            k_new = _quantize_rows(k_new, k_s)
-            v_new = _quantize_rows(v_new, v_s)
-        if paged:
-            # Paged write: scatter through the block table — valid rows
-            # land in their slot's mapped blocks, padded rows drop. The
-            # contiguous path's window clamp machinery is unnecessary
-            # here (see _paged_pool_write).
-            n_valid = (
-                jnp.full((B,), Tq, jnp.int32) if n_tokens is None
-                else n_tokens
-            )
-            if seq_sharded:
-                k_cache = _paged_pool_write_seq(
-                    k_cache, k_new, cache.table, start, n_valid,
-                    mesh=mesh, seq_axis=axes["seq"],
-                )
-                v_cache = _paged_pool_write_seq(
-                    v_cache, v_new, cache.table, start, n_valid,
-                    mesh=mesh, seq_axis=axes["seq"],
-                )
-            else:
-                k_cache = _paged_pool_write(
-                    k_cache, k_new, cache.table, start, n_valid, l
-                )
-                v_cache = _paged_pool_write(
-                    v_cache, v_new, cache.table, start, n_valid, l
-                )
-            if hoist_view:
-                # Mirror the new rows into the hoisted logical view (the
-                # pre-scan gather predates this layer's write) — a cheap
-                # Tq-row window write, vs re-gathering the whole pool.
-                wv = jax.vmap(_masked_window_write, in_axes=(0, 0, 0, 0))
-                mk = k_new if k_deq is None else k_deq
-                mv = v_new if v_deq is None else v_deq
-                k_view = wv(
-                    k_view, mk.astype(k_view.dtype), start, n_valid
-                )
-                v_view = wv(
-                    v_view, mv.astype(v_view.dtype), start, n_valid
-                )
-        elif n_tokens is None:
-            write = jax.vmap(
-                lambda buf, rows, s: lax.dynamic_update_slice_in_dim(
-                    buf, rows, s, axis=1
-                )
-            )
-            k_cache = write(k_cache, k_new.astype(k_cache.dtype), start)
-            v_cache = write(v_cache, v_new.astype(v_cache.dtype), start)
-        else:
-            # Mixed-Tq masked write: only rows < n_tokens[i] may land. A
-            # plain Tq-row dynamic-update would (a) write pad garbage the
-            # causal mask has to hide until it is overwritten and (b)
-            # CLAMP near capacity (dynamic_update_slice semantics), sliding
-            # garbage over a decode slot's newest valid rows. Instead:
-            # read the Tq-row window at a clamped offset, overlay exactly
-            # the valid rows at their true absolute positions, write it
-            # back — cache bytes outside [start, start+n) are untouched.
-            write = jax.vmap(_masked_window_write, in_axes=(0, 0, 0, 0))
-            k_cache = write(
-                k_cache, k_new.astype(k_cache.dtype), start, n_tokens
-            )
-            v_cache = write(
-                v_cache, v_new.astype(v_cache.dtype), start, n_tokens
-            )
-
-        attn_kw = dict(
-            q_position=start,
-            mesh=mesh,
-            data_axis=axes["data"],
-            seq_axis=axes["seq"],
-            model_axis=axes["model"],
-            block_size=cfg.attn_block_size,
-            tree_mask=tree_mask,
-        )
-        ak, av, ak_s, av_s = k_cache, v_cache, k_s, v_s
-        if hoist_view:
-            ak, av = k_view, v_view
-        elif carried:
-            # The kernels and the reference gather take a pool and a
-            # table: hand them every layer's blocks (a bitcast of the
-            # carry) and this layer's addresses.
-            ak = k_cache.reshape((-1,) + k_cache.shape[2:])
-            av = v_cache.reshape((-1,) + v_cache.shape[2:])
-            attn_kw["block_table"] = base + cache.table
-            if quant:
-                ak_s, av_s = k_sf, v_sf
-        elif paged:
-            attn_kw["block_table"] = cache.table
-            attn_kw["kv_shard"] = "seq"
-        if quant and not (paged and hoist_view):
-            out, _ = decode_attention(
-                q, ak, av, k_scale=ak_s, v_scale=av_s,
-                quant_kernel=quant_kernel, **attn_kw,
-            )
-        else:
-            # Exact caches — and the paged-quant DEQUANTIZED view (the
-            # off-kernel path; see the hoist_view comment above).
-            out, _ = decode_attention(
-                q, ak, av,
-                impl=cfg.attn_impl, num_splits=num_splits, **attn_kw,
-            )
-        x = x + _unheads(out) @ layer["wo"]
-        x = x + _mlp_block(layer, rms_norm(x, layer["ln2"], cfg.norm_eps))
-        new = (k_cache, v_cache)
-        if paged and quant:
-            new = new + (k_s, v_s)  # entered blocks' inherited scales
-        return ((x,) + new, None) if carried else (x, new)
-
-    xs = (params["layers"],)
-    init = x
-    if carried:
-        init = (x, cache.k, cache.v)
-        if quant:
-            init = init + (cache.k_scale, cache.v_scale)
-        xs = xs + (jnp.arange(cache.k.shape[0], dtype=jnp.int32),)
-    else:
-        xs = xs + (cache.k, cache.v)
-    if hoist_view:
-        xs = xs + (k_view0, v_view0)
-    if quant and not carried:
-        xs = xs + (cache.k_scale, cache.v_scale)
-    out_carry, scanned = lax.scan(body, init, xs)
-    if carried:
-        x, scanned = out_carry[0], out_carry[1:]
-    else:
-        x = out_carry
-    new_k, new_v = scanned[0], scanned[1]
+    x = jnp.take(params["embed"], tokens, axis=0)
+    _count_step(cache)
+    group = _RowGroup(
+        lo=None, batch=B, tq=Tq, start=start, n=n_tokens,
+        table=cache.table if paged else None, tree_mask=tree_mask,
+    )
+    x, pools = _step_layers(
+        params, x, positions, (group,), cache, cfg, mesh=mesh, axes=axes,
+        num_splits=num_splits, quant_kernel=quant_kernel, kv_shard=kv_shard,
+        stats=stats,
+    )
     x = rms_norm(x, params["ln_f"], cfg.norm_eps)
     logits = (x @ params["wout"]).astype(jnp.float32)
-    if paged and quant:
-        new_cache: Union[KVCache, QuantKVCache, PagedKVCache,
-                         PagedQuantKVCache] = PagedQuantKVCache(
-            k=new_k, v=new_v, k_scale=scanned[2], v_scale=scanned[3],
-            table=cache.table, length=start + grew,
+    grew = Tq if n_tokens is None else n_tokens
+    return logits, dataclasses.replace(cache, length=start + grew, **pools)
+
+
+def forward_packed_step(
+    params: Params,
+    chunk_tokens: jax.Array,
+    chunk_slot: jax.Array,
+    chunk_n: jax.Array,
+    tokens: jax.Array,
+    n_tokens: jax.Array,
+    cache: Union[PagedKVCache, PagedQuantKVCache, PagedLatentCache],
+    cfg: TransformerConfig,
+    *,
+    mesh: Optional[Mesh] = None,
+    data_axis: Optional[str] = AXIS_DATA,
+    seq_axis: str = AXIS_SEQ,
+    model_axis: Optional[str] = AXIS_MODEL,
+    num_splits: Optional[int] = None,
+    quant_kernel: str = "q8q",
+    kv_shard: str = "replicated",
+    stats: Optional[Dict[str, Any]] = None,
+) -> Tuple[jax.Array, Any]:
+    """A step that computes only the rows that carry a token: a compact
+    **chunk group** beside **one decode row a slot** (ISSUE 30).
+
+    What :func:`forward_step` does with ``(S, Tq)`` tokens and ``n_tokens``
+    when a few slots take a prompt chunk and the rest one token, in ``C·Tq +
+    S`` rows where the padded step computes ``S·Tq``:
+
+    - ``chunk_tokens`` ``(C, Tq)``: member ``j`` of the chunk group is slot
+      ``chunk_slot[j]``, its first ``chunk_n[j]`` rows are valid. Its cache
+      view is the same pool under ``table[chunk_slot[j]]`` from
+      ``length[chunk_slot[j]]`` on: a gathered table and length, nothing
+      else. A member with ``chunk_n == 0`` is padding: it writes nothing,
+      moves no length and no slot samples from it, whatever slot it names.
+    - ``tokens`` ``(S,)``, ``n_tokens`` ``(S,)`` in {0, 1}: the decode group,
+      the ``Tq = 1`` step of every slot.
+
+    A slot has rows in at most one group (the caller's contract: a chunking
+    slot rides the decode group with ``n_tokens == 0``), so the groups'
+    writes cannot collide, and no two members with rows name one slot. In a
+    layer everything row-wise runs on the ``C·Tq + S`` rows at once, so the
+    weights stream once; the pool write and attention run per group through
+    the code the padded step runs (:func:`_step_layers`). The head runs on
+    ONE row a slot: its last valid chunk row where it is a member with rows,
+    its decode row otherwise.
+
+    Returns ``logits`` ``(S, vocab)`` float32 (a slot with no row anywhere
+    gets its inert decode row's, which the caller ignores) and the cache
+    with ``length += n_tokens``, ``length[chunk_slot] += chunk_n``.
+    """
+    axes = prune_axes(
+        mesh, {"data": data_axis, "seq": seq_axis, "model": model_axis}
+    )
+    latent = isinstance(cache, PagedLatentCache)
+    if not (latent or isinstance(cache, (PagedKVCache, PagedQuantKVCache))):
+        raise ValueError(
+            "a packed step's groups are views of ONE paged pool; got "
+            f"{type(cache).__name__}"
         )
-    elif paged:
-        new_cache = PagedKVCache(
-            k=new_k, v=new_v, table=cache.table, length=start + grew
+    _check_block_cache(cache, cfg)
+    if kv_shard not in ("replicated", "seq") or (latent and kv_shard == "seq"):
+        raise ValueError(
+            f"kv_shard must be 'replicated' or 'seq' (a latent pool: "
+            f"'replicated'), got {kv_shard!r}"
         )
-    elif quant:
-        new_cache = QuantKVCache(
-            k=new_k, v=new_v, k_scale=cache.k_scale, v_scale=cache.v_scale,
-            length=start + grew,
-        )
-    else:
-        new_cache = KVCache(k=new_k, v=new_v, length=start + grew)
-    return logits, new_cache
+    C, Tq = chunk_tokens.shape
+    S = cache.table.shape[0]
+    length = cache.length
+    c_start = length[chunk_slot]
+    groups = (
+        _RowGroup(lo=0, batch=C, tq=Tq, start=c_start, n=chunk_n,
+                  table=cache.table[chunk_slot], tree_mask=None),
+        _RowGroup(lo=C * Tq, batch=S, tq=1, start=length, n=n_tokens,
+                  table=cache.table, tree_mask=None),
+    )
+    c_pos = c_start[:, None] + jnp.arange(Tq, dtype=jnp.int32)
+    positions = jnp.concatenate([c_pos.reshape(-1), length])[None]
+    rows = jnp.concatenate([chunk_tokens.reshape(-1), tokens.reshape(-1)])
+    x = jnp.take(params["embed"], rows[None], axis=0)  # (1, C·Tq + S, D)
+    _count_step(cache)
+    x, pools = _step_layers(
+        params, x, positions, groups, cache, cfg, mesh=mesh, axes=axes,
+        num_splits=num_splits, quant_kernel=quant_kernel, kv_shard=kv_shard,
+        stats=stats,
+    )
+    # One row a slot: padding members scatter past the last slot and drop.
+    src = (C * Tq + jnp.arange(S, dtype=jnp.int32)).at[
+        jnp.where(chunk_n > 0, chunk_slot, S)
+    ].set(
+        jnp.arange(C, dtype=jnp.int32) * Tq + jnp.maximum(chunk_n - 1, 0),
+        mode="drop",
+    )
+    last = rms_norm(x[0, src], params["ln_f"], cfg.norm_eps)
+    logits = (last @ params["wout"]).astype(jnp.float32)
+    new_len = (length + n_tokens).at[chunk_slot].add(chunk_n)
+    return logits, dataclasses.replace(cache, length=new_len, **pools)
 
 
 def _compact_window_slot(

@@ -270,7 +270,12 @@ class DisaggServer:
         )
         self.prefill = SlotServer(
             params, cfg, slots=prefill_slots, seed=seed,
-            prefill_chunk=prefill_chunk, prefill_budget=prefill_budget,
+            prefill_chunk=prefill_chunk,
+            # No decode row rides a prefill worker's tick, so there is no
+            # inter-token gap for its budget to protect: every prefilling
+            # slot advances a chunk a tick unless the caller bounds it.
+            prefill_budget=(prefill_slots * prefill_chunk
+                            if prefill_budget is None else prefill_budget),
             **common,
         )
         self.decode = SlotServer(
@@ -856,27 +861,22 @@ class DisaggServer:
                             pf._run_staged_chunk(slot, n, last)
                         self._relay_pool(pf, dc)
                     elif plan:
+                        # The packed tick with no decode row live: the
+                        # chunk group and S inert rows.
                         tq = pf._chunk_bucket(max(n for _, n, _ in plan))
-                        mat = np.zeros((pf.slots, tq), np.int32)
-                        n_vec = np.zeros((pf.slots,), np.int32)
                         reset = np.zeros((pf.slots,), bool)
                         reset_val = np.zeros((pf.slots,), np.int32)
                         emit = np.zeros((pf.slots,), bool)
-                        for slot, n, last in plan:
-                            pf._ensure_blocks(
-                                slot, pf._prefill_pos[slot] + n
-                            )
-                            rows, first = pf._consume_chunk(slot, n, last)
-                            mat[slot, :n] = rows
-                            n_vec[slot] = n
-                            reset[slot] = first
-                            reset_val[slot] = pf._prefill_start[slot]
-                            emit[slot] = last
+                        chunk_tok, chunk_slot, chunk_n = \
+                            pf._pack_chunk_group(plan, tq, reset,
+                                                 reset_val, emit)
                         sidx = np.zeros((pf.slots,), np.int32)
                         pf._sync_table()
-                        pf.tok, pf._lp, _, _, pf.cache = pf._mixed(
-                            pf.params, jnp.asarray(mat),
-                            jnp.asarray(n_vec), jnp.asarray(reset),
+                        pf.tok, pf._lp, _, _, pf.cache = pf._packed(
+                            pf.params, jnp.asarray(chunk_tok),
+                            jnp.asarray(chunk_slot), jnp.asarray(chunk_n),
+                            jnp.asarray(sidx), jnp.asarray(sidx),
+                            jnp.asarray(reset),
                             jnp.asarray(reset_val), jnp.asarray(emit),
                             pf.cache, pf._keys,
                             jnp.asarray(pf._temp_np),

@@ -11,18 +11,23 @@ queue, and runs a tick loop:
 1. **Admit** — every free slot takes the oldest pending request whose
    arrival time has passed; the slot enters the ``prefilling`` state with
    nothing on the device yet.
-2. **Step** — ONE compiled **mixed** step advances the whole batch: every
-   live slot contributes its one decode token, and up to ``prefill_budget``
-   prompt tokens of the prefilling slots ride along as fixed-size chunks
+2. **Step** — ONE compiled step advances the whole batch: every live slot
+   contributes its one decode token, and up to ``prefill_budget`` prompt
+   tokens of the prefilling slots ride along as fixed-size chunks
    (``prefill_chunk``), written **directly into each slot's blocks of the
-   pool** at that slot's running offset via the ragged mixed-Tq
-   ``forward_step`` (per-slot ``n_tokens``). No B=1 mini cache, no insert
-   copy, no per-admit host sync: a long prompt costs each live slot at most
-   one chunk of extra latency per tick instead of a whole-prompt stall —
-   the Sarathi-style stall-free batching shape (arXiv:2403.02310). Chunk
-   sizes come from a small fixed power-of-two bucket set, so occupancy
-   changes and chunk mixtures never recompile (pure-decode ticks reuse the
-   same program at Tq=1).
+   pool** at that slot's running offset. A tick that carries a chunk is
+   **packed** (``forward_packed_step``): a compact chunk group of ``C``
+   members beside one decode row a slot, ``C·Tq + S`` rows and never
+   ``S·Tq``, so a prompt chunk costs a decode tick's weight stream plus
+   its own rows' compute. ``C`` is fixed at build (``ceil(prefill_budget
+   / prefill_chunk)``), so how many slots happen to chunk at once never
+   chooses a program. No B=1 mini cache, no insert copy, no per-admit host
+   sync: a long prompt costs each live slot at most one chunk of extra
+   latency per tick instead of a whole-prompt stall — the Sarathi-style
+   stall-free batching shape (arXiv:2403.02310). Chunk sizes come from a
+   small fixed power-of-two bucket set, so occupancy changes and chunk
+   mixtures never recompile (pure-decode ticks are the padded
+   ``forward_step`` at Tq=1, where padding and packing are the same rows).
 3. **Retire** — a slot whose request hit EOS or its token budget frees
    immediately and is refilled on the next admission pass.
 
@@ -136,6 +141,7 @@ from tree_attention_tpu.models.decode import (
     cache_token_bytes,
     compact_decode_window,
     copy_pool_block,
+    forward_packed_step,
     forward_step,
     gather_kv_blocks,
     init_cache,
@@ -682,11 +688,12 @@ class SlotServer:
         spikes for live slots, more ticks per prompt.
       prefill_budget: max TOTAL prompt tokens per tick across prefilling
         slots — the Sarathi-style token budget; live decode tokens always
-        ride for free. Default: ``slots * prefill_chunk`` (every
-        prefilling slot advances one chunk per tick). The padded mixed
-        program computes ``S x Tq`` rows whether one chunk rides or all
-        of them, so concurrent chunks cost no extra compute; a smaller
-        budget only bounds KV-write traffic per tick.
+        ride beside them. Default: ``prefill_chunk``, one chunk a tick.
+        A tick computes the rows it carries (``C·Tq + S`` with ``C =
+        min(slots, ceil(prefill_budget / prefill_chunk))`` members in its
+        chunk group, fixed at build), so the budget is what a tick with
+        prompt work costs every live slot in inter-token latency: a
+        larger one admits prompts faster and stretches those ticks.
       admission: ``"chunked"`` (default — stall-free, fused into the tick)
         or ``"whole"`` (legacy blocking whole-prompt prefill + insert).
       slo_ttft / slo_tbt / slo_window: the sliding-window SLO monitor's
@@ -870,9 +877,14 @@ class SlotServer:
         self.admission = admission
         self.prefill_chunk = min(prefill_chunk, cache_len)
         self.prefill_budget = (
-            slots * self.prefill_chunk if prefill_budget is None
-            else prefill_budget
+            self.prefill_chunk if prefill_budget is None else prefill_budget
         )
+        # Members of a packed tick's chunk group: what the budget admits
+        # at one chunk each. Fixed for the engine's life, so the tick
+        # programs are keyed by the Tq bucket alone (a program keyed by how
+        # many slots chunk at once would first compile under load).
+        self._chunk_group = min(
+            slots, -(-self.prefill_budget // self.prefill_chunk))
         # Per-slot sampling state (ISSUE 15). Each slot's PRNG key is
         # its REQUEST's key (fold_in(base, seed-or-uid) then the branch
         # index); the j-th emitted token folds j in — see
@@ -1204,6 +1216,7 @@ class SlotServer:
         # updates the (L,S,Hkv,Tmax,D) cache in place instead of copying
         # it (backends without donation just copy).
         self._mixed = jax.jit(self._mixed_fn, donate_argnums=(6,))
+        self._packed = jax.jit(self._packed_fn, donate_argnums=(9,))
         self._prefill = jax.jit(self._prefill_fn)
         self._insert = jax.jit(self._insert_fn, donate_argnums=(0, 1, 2))
         if self._needs_staging:
@@ -1215,7 +1228,7 @@ class SlotServer:
             )
         if self._prefix is not None:
             # Whole-admission prefix hits prefill only the suffix — device-
-            # built single-slot chunks through the SAME mixed-step family
+            # built single-slot chunks through the packed tick's step
             # (every other slot rides inert with its parked token intact).
             self._whole_suffix = jax.jit(
                 self._whole_suffix_fn, donate_argnums=(7,)
@@ -1331,42 +1344,26 @@ class SlotServer:
             b *= 2
         return min(b, self.prefill_chunk)
 
-    def _mixed_fn(self, params, tokens, n_tok, reset, reset_val, emit,
-                  cache, keys, temp, topk, idx, lp_vec):
-        """THE per-tick program: one mixed-Tq forward_step for every slot.
-
-        ``tokens`` is ``(S, Tq)`` (Tq = 1 on pure-decode ticks, a chunk
-        bucket otherwise); slot ``i`` consumes ``n_tok[i]`` rows — 1 for a
-        live decode slot, a chunk for a prefilling slot, 0 for everything
-        else (inert: nothing written, length frozen). ``reset`` sets a
-        slot's length to ``reset_val[i]`` before the write — 0 for a cold
-        first chunk (the slot index is reused), the matched prefix
-        length on a prefix hit (the hit was pure host bookkeeping and
-        THIS is where the device learns it). Each slot samples from its
-        own last valid row under its own key/temperature/top-k (``keys``/``temp``/``topk``/
-        ``idx`` — ISSUE 15; temperature-0 slots are exact argmax);
-        ``emit`` keeps the sample (decode slots and final-chunk slots)
-        or holds the slot's row-0 token AND its parked logprob
-        (everything else — in particular a parked first token rides
-        through unchanged). Returns the token vector, the logprob
-        vector, ONE fused ``(S, 2)`` int32 fetch vehicle (tokens +
-        bitcast logprobs — the per-tick host sync stays a single
-        array), and the cache.
-        """
-        length = jnp.where(reset, reset_val, cache.length)
-        cache = dataclasses.replace(cache, length=length)
+    def _step_kw(self) -> Dict[str, Any]:
         kw = dict(self._fs_kw)
         if self.quantize:
             kw["quant_kernel"] = self.quant_kernel
-        stats: Dict[str, Any] = {}
-        logits, new_cache = forward_step(
-            params, tokens, cache, self.cfg, n_tokens=n_tok, stats=stats,
-            **kw
-        )
-        row = jnp.maximum(n_tok - 1, 0)
-        last = jnp.take_along_axis(logits, row[:, None, None], axis=1)[:, 0]
+        return kw
+
+    def _emit_fused(self, last, held, emit, keys, temp, topk, idx, lp_vec,
+                    stats):
+        """The tail every tick program shares: sample each slot from its
+        row of ``last`` ``(S, V)`` under its own key/temperature/top-k
+        (``keys``/``temp``/``topk``/``idx`` — ISSUE 15; temperature-0
+        slots are exact argmax); ``emit`` keeps the sample (decode slots
+        and final-chunk slots) or holds the slot's ``held`` token AND its
+        parked logprob (everything else — in particular a parked first
+        token rides through unchanged). Returns the token vector, the
+        logprob vector and ONE fused ``(S, 2)`` int32 fetch vehicle (tokens
+        + bitcast logprobs — the per-tick host sync stays a single array).
+        """
         tok_s, lp_s = self._sample_emit(last, keys, temp, topk, idx)
-        nxt = jnp.where(emit, tok_s, tokens[:, 0])
+        nxt = jnp.where(emit, tok_s, held)
         lp_out = jnp.where(emit, lp_s, lp_vec)
         fused = jnp.concatenate(
             [nxt[:, None],
@@ -1380,6 +1377,34 @@ class SlotServer:
             flat = stats["expert_rows"].reshape(-1)
             flat = jnp.pad(flat, (0, flat.shape[0] % 2))
             fused = jnp.concatenate([fused, flat.reshape(-1, 2)], axis=0)
+        return nxt, lp_out, fused
+
+    def _mixed_fn(self, params, tokens, n_tok, reset, reset_val, emit,
+                  cache, keys, temp, topk, idx, lp_vec):
+        """The padded tick program: one mixed-Tq forward_step for every
+        slot. The serve loop runs it at Tq = 1, the pure-decode tick (a
+        tick with a prompt chunk is :meth:`_packed_fn`'s).
+
+        ``tokens`` is ``(S, Tq)``; slot ``i`` consumes ``n_tok[i]`` rows —
+        1 for a live decode slot, 0 for everything else (inert: nothing
+        written, length frozen). ``reset`` sets a slot's length to
+        ``reset_val[i]`` before the write (a forked child's one reset to
+        its fork point). Each slot samples from its own last valid row
+        (:meth:`_emit_fused`). Returns the token vector, the logprob
+        vector, the fused fetch vehicle, the sampled rows' logits, and
+        the cache.
+        """
+        length = jnp.where(reset, reset_val, cache.length)
+        cache = dataclasses.replace(cache, length=length)
+        stats: Dict[str, Any] = {}
+        logits, new_cache = forward_step(
+            params, tokens, cache, self.cfg, n_tokens=n_tok, stats=stats,
+            **self._step_kw()
+        )
+        row = jnp.maximum(n_tok - 1, 0)
+        last = jnp.take_along_axis(logits, row[:, None, None], axis=1)[:, 0]
+        nxt, lp_out, fused = self._emit_fused(
+            last, tokens[:, 0], emit, keys, temp, topk, idx, lp_vec, stats)
         # ``last`` rides out as a device carry: a fork family samples
         # its siblings' first tokens from the PARENT's exact prompt-end
         # logits row (bit-identical to the parent's own sample point —
@@ -1387,28 +1412,57 @@ class SlotServer:
         # written KV row. Fetched never, read only at fork time.
         return nxt, lp_out, fused, last, new_cache
 
+    def _packed_fn(self, params, chunk_tok, chunk_slot, chunk_n, dec_tok,
+                   dec_n, reset, reset_val, emit, cache, keys, temp, topk,
+                   idx, lp_vec):
+        """THE program of a tick that carries a prompt chunk: a compact
+        chunk group beside one decode row a slot (``forward_packed_step``;
+        ``C·Tq + S`` rows where the padded program computes ``S·Tq``).
+
+        ``chunk_tok`` is ``(C, Tq)`` (``C`` fixed at build, Tq a chunk
+        bucket): member ``j`` is slot ``chunk_slot[j]`` consuming
+        ``chunk_n[j]`` prompt rows, 0 for a member no slot fills.
+        ``dec_tok`` ``(S,)`` holds every slot's decode token, ``dec_n`` 1
+        for a live decode slot and 0 for everything else (a chunking slot
+        included: a slot has rows in one group). ``reset`` sets a slot's
+        length to ``reset_val[i]`` before the write — 0 for a cold first
+        chunk (the slot index is reused), the matched prefix length on a
+        prefix hit (the hit was pure host bookkeeping and THIS is where
+        the device learns it). Each slot samples from the ONE row the step
+        gives it (its last valid chunk row, or its decode row); the head
+        never sees a ``(·, Tq, V)`` array. Returns what :meth:`_mixed_fn`
+        returns.
+        """
+        length = jnp.where(reset, reset_val, cache.length)
+        cache = dataclasses.replace(cache, length=length)
+        stats: Dict[str, Any] = {}
+        last, new_cache = forward_packed_step(
+            params, chunk_tok, chunk_slot, chunk_n, dec_tok, dec_n, cache,
+            self.cfg, stats=stats, **self._step_kw()
+        )
+        nxt, lp_out, fused = self._emit_fused(
+            last, dec_tok, emit, keys, temp, topk, idx, lp_vec, stats)
+        return nxt, lp_out, fused, last, new_cache
+
     def _whole_suffix_fn(self, params, rows, slot, n, last, first, start,
                          cache, tok_vec, keys, temp, topk, idx, lp_vec):
         """One suffix chunk of a whole-admission prefix hit: slot ``slot``
         consumes ``n`` of the ``rows`` (a padded ``(Tq,)`` chunk of its
-        prompt) while every other slot rides inert — their parked tokens
-        pass through untouched because the token matrix is built from the
-        DEVICE token vector (an ``await`` slot's first token only exists
-        there until the next batched fetch). On the FIRST suffix chunk
-        the slot's length resets to ``start`` (= the matched prefix
-        length): the one place the device learns the hit.
-        Emits the first sampled token into the token vector on the final
-        chunk."""
-        S, tq = self.slots, rows.shape[0]
-        tokens = jnp.zeros((S, tq), jnp.int32).at[:, 0].set(tok_vec)
-        tokens = lax.dynamic_update_slice(tokens, rows[None, :], (slot, 0))
-        one_hot = jnp.arange(S, dtype=jnp.int32) == slot
-        n_vec = jnp.where(one_hot, n, 0).astype(jnp.int32)
-        emit = one_hot & last
-        reset = one_hot & first
-        reset_val = jnp.where(one_hot, start, 0).astype(jnp.int32)
-        return self._mixed_fn(params, tokens, n_vec, reset, reset_val,
-                              emit, cache, keys, temp, topk, idx, lp_vec)
+        prompt) as the one member of a packed step's chunk group while
+        every other slot rides inert — their parked tokens pass through
+        untouched because the held tokens are the DEVICE token vector (an
+        ``await`` slot's first token only exists there until the next
+        batched fetch). On the FIRST suffix chunk the slot's length resets
+        to ``start`` (= the matched prefix length): the one place the
+        device learns the hit. Emits the first sampled token into the
+        token vector on the final chunk."""
+        one_hot = jnp.arange(self.slots, dtype=jnp.int32) == slot
+        return self._packed_fn(
+            params, rows[None, :], jnp.reshape(slot, (1,)),
+            jnp.reshape(n, (1,)).astype(jnp.int32), tok_vec,
+            jnp.zeros((self.slots,), jnp.int32), one_hot & first,
+            jnp.where(one_hot, start, 0).astype(jnp.int32), one_hot & last,
+            cache, keys, temp, topk, idx, lp_vec)
 
     def _sibling_first_fn(self, tok_vec, lp_vec, row, key, temp, topk,
                           slot):
@@ -1481,9 +1535,7 @@ class SlotServer:
         tokens = mat.at[:, 0].set(jnp.where(use_dev0, tok_vec, mat[:, 0]))
         length = jnp.where(reset, reset_val, cache.length)
         cache = dataclasses.replace(cache, length=length)
-        kw = dict(self._fs_kw)
-        if self.quantize:
-            kw["quant_kernel"] = self.quant_kernel
+        kw = self._step_kw()
         if depth is not None:
             kw["positions"] = length[:, None] + depth
             kw["tree_mask"] = bits
@@ -1675,20 +1727,28 @@ class SlotServer:
     def lower_programs(self, tq: int) -> Dict[str, Any]:
         """Lower — from the engine's live state, dispatching and donating
         nothing — the programs a tick at Tq bucket ``tq`` runs: the fused
-        ``mixed`` step (``tq == 1`` is the pure-decode tick) and, where
-        admission stages prompts (int8 chunked), the ``stage_chunk``
-        program. ``.compile().as_text()`` of each shows what a served tick
+        ``mixed`` step (``tq == 1`` is the pure-decode tick, the padded
+        program; a chunk bucket is the packed one) and, where admission
+        stages prompts (int8 chunked), the ``stage_chunk`` program.
+        ``.compile().as_text()`` of each shows what a served tick
         executes, e.g. which Pallas kernels are in it; with the programs
         already run, the compile is a cache hit."""
-        S = self.slots
+        S, C = self.slots, self._chunk_group
         zeros = lambda n, dt: jnp.zeros((n,), dt)
-        lowered = {"mixed": self._mixed.lower(
-            self.params, jnp.zeros((S, tq), jnp.int32),
+        per_slot = (
             zeros(S, jnp.int32), zeros(S, bool), zeros(S, jnp.int32),
             zeros(S, bool), self.cache, self._keys,
             jnp.asarray(self._temp_np), jnp.asarray(self._topk_np),
             zeros(S, jnp.int32), self._lp,
-        )}
+        )
+        if tq == 1:
+            lowered = {"mixed": self._mixed.lower(
+                self.params, jnp.zeros((S, 1), jnp.int32), *per_slot)}
+        else:
+            lowered = {"mixed": self._packed.lower(
+                self.params, jnp.zeros((C, tq), jnp.int32),
+                zeros(C, jnp.int32), zeros(C, jnp.int32),
+                zeros(S, jnp.int32), *per_slot)}
         if self._staged_prefill:
             lowered["stage_chunk"] = self._stage_chunk.lower(
                 self.params, jnp.zeros((1, tq), jnp.int32),
@@ -2534,18 +2594,19 @@ class SlotServer:
     ) -> List[Tuple[int, int, bool]]:
         """Sarathi-style budget pass: FIFO over prefilling slots, each
         taking up to a chunk, the tick taking at most ``prefill_budget``
-        prompt tokens total. ``max_n`` clamps the per-slot chunk below
-        the configured size — ticks that carry a token-tree sibling
-        bundle (ISSUE 20) must keep Tq within the int32 tree-bitmask
-        limit, so their chunks shrink to fit. Returns (slot, n,
-        is_final) triples."""
+        prompt tokens total and at most as many slots as the packed
+        tick's chunk group has members. ``max_n`` clamps the per-slot
+        chunk below the configured size — ticks that carry a token-tree
+        sibling bundle (ISSUE 20) must keep Tq within the int32
+        tree-bitmask limit, so their chunks shrink to fit. Returns
+        (slot, n, is_final) triples."""
         plan: List[Tuple[int, int, bool]] = []
         budget = self.prefill_budget
         chunk = self.prefill_chunk
         if max_n is not None:
             chunk = min(chunk, max_n)
         for slot in self._prefill_fifo:
-            if budget <= 0:
+            if budget <= 0 or len(plan) == self._chunk_group:
                 break
             plen = len(self._slot_req[slot].prompt)
             pos = self._prefill_pos[slot]
@@ -3606,6 +3667,31 @@ class SlotServer:
             })
         return rows, pos == self._prefill_start[slot]
 
+    def _pack_chunk_group(
+        self, plan: List[Tuple[int, int, bool]], tq: int,
+        reset: np.ndarray, reset_val: np.ndarray, emit: np.ndarray,
+    ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """Consume this tick's planned chunks into the packed tick's chunk
+        group: member ``j`` is the plan's ``j``-th slot (blocks mapped,
+        host position advanced — :meth:`_consume_chunk`), the members the
+        plan leaves over stay padding (``n = 0``). The per-SLOT operands
+        ``reset``/``reset_val``/``emit`` are filled in place. Returns the
+        group's ``(C, tq)`` tokens, ``(C,)`` slots and ``(C,)`` counts."""
+        C = self._chunk_group
+        chunk_tok = np.zeros((C, tq), np.int32)
+        chunk_slot = np.zeros((C,), np.int32)
+        chunk_n = np.zeros((C,), np.int32)
+        for j, (slot, n, last) in enumerate(plan):
+            self._ensure_blocks(slot, self._prefill_pos[slot] + n)
+            rows, first = self._consume_chunk(slot, n, last)
+            chunk_tok[j, :n] = rows
+            chunk_slot[j] = slot
+            chunk_n[j] = n
+            reset[slot] = first
+            reset_val[slot] = self._prefill_start[slot]
+            emit[slot] = last
+        return chunk_tok, chunk_slot, chunk_n
+
     def _run_staged_chunk(self, slot: int, n: int, last: bool) -> None:
         """Quantized chunked admission: advance one slot's staged exact
         prefill by ``n`` tokens; the final chunk quantizes + inserts."""
@@ -4112,6 +4198,7 @@ class SlotServer:
                     # and the profiler's tick:dispatch annotation.
                     tick_kind = "awaits"
                     tick_tq = 0
+                    tick_group = 0  # members of a packed tick's chunk group
                     n_vec = None
                     if self._staged_prefill and plan:
                         # The stage programs pack and launch per chunk.
@@ -4335,14 +4422,16 @@ class SlotServer:
                                 if last:
                                     self._publish_prefix(slot)
                     elif plan:
-                        # The fused mixed tick: decode rows + prefill
-                        # chunks in ONE compiled program; chunks write
-                        # straight into each slot's region of the batch
-                        # cache at its running offset.
+                        # The fused mixed tick, PACKED: one decode row a
+                        # slot beside a compact chunk group, in ONE
+                        # compiled program; chunks write straight into
+                        # each slot's blocks of the pool at its running
+                        # offset.
                         tq = self._chunk_bucket(max(n for _, n, _ in plan))
                         phases.mark("pack")
                         tick_kind, tick_tq = "mixed", tq
-                        mat = np.zeros((self.slots, tq), np.int32)
+                        tick_group = self._chunk_group
+                        dec_tok = np.zeros((self.slots,), np.int32)
                         n_vec = np.zeros((self.slots,), np.int32)
                         reset = np.zeros((self.slots,), bool)
                         reset_val = np.zeros((self.slots,), np.int32)
@@ -4352,7 +4441,7 @@ class SlotServer:
                                 i, len(self._slot_req[i].prompt)
                                 + len(self._slot_tokens[i])
                             )
-                            mat[i, 0] = self._tok_host[i]
+                            dec_tok[i] = self._tok_host[i]
                             n_vec[i] = 1
                             emit[i] = True
                         # Freshly forked children (ISSUE 15): their one
@@ -4364,16 +4453,9 @@ class SlotServer:
                             if self._slot_state[i] == "live":
                                 reset[i] = True
                                 reset_val[i] = self._live_reset.pop(i)
-                        for slot, n, last in plan:
-                            self._ensure_blocks(
-                                slot, self._prefill_pos[slot] + n
-                            )
-                            rows, first = self._consume_chunk(slot, n, last)
-                            mat[slot, :n] = rows
-                            n_vec[slot] = n
-                            reset[slot] = first
-                            reset_val[slot] = self._prefill_start[slot]
-                            emit[slot] = last
+                        chunk_tok, chunk_slot, chunk_n = \
+                            self._pack_chunk_group(plan, tq, reset,
+                                                   reset_val, emit)
                         sidx = np.asarray(
                             [len(t) for t in self._slot_tokens], np.int32
                         )
@@ -4381,8 +4463,10 @@ class SlotServer:
                         self._sync_table()
                         phases.mark("dispatch", tick, tick_kind, tick_tq)
                         self.tok, self._lp, fused_dev, last_dev, \
-                            self.cache = self._mixed(
-                                self.params, jnp.asarray(mat),
+                            self.cache = self._packed(
+                                self.params, jnp.asarray(chunk_tok),
+                                jnp.asarray(chunk_slot),
+                                jnp.asarray(chunk_n), jnp.asarray(dec_tok),
                                 jnp.asarray(n_vec), jnp.asarray(reset),
                                 jnp.asarray(reset_val),
                                 jnp.asarray(emit), self.cache,
@@ -4675,13 +4759,20 @@ class SlotServer:
                             "t_s": round(now - t0, 6),
                             # What the tick program ran as: its kind, the
                             # Tq bucket (0: nothing dispatched; a staged
-                            # tick's is its decode program's), the rows
-                            # it computed and those that carried a token.
+                            # tick's is its decode program's), the members
+                            # of its chunk group (0: a padded program), the
+                            # rows it computed (a packed tick: the chunk
+                            # group's and one a slot) and those that
+                            # carried a token.
                             "kind": tick_kind,
                             "tq": tick_tq,
-                            "rows_computed": self.slots * tick_tq,
-                            "rows_useful": (0 if n_vec is None
-                                            else int(n_vec.sum())),
+                            "chunk_group": tick_group,
+                            "rows_computed": (
+                                tick_group * tick_tq + self.slots
+                                if tick_group else self.slots * tick_tq),
+                            "rows_useful": (
+                                0 if n_vec is None else int(n_vec.sum())
+                                + (chunk_tokens if tick_group else 0)),
                             "occupancy": len(live_idx),
                             "states": list(self._slot_state),
                             "lengths": [self._prefill_pos[i]

@@ -298,8 +298,9 @@ def build_arg_parser() -> argparse.ArgumentParser:
                         "spike a long prompt inflicts on live slots")
     p.add_argument("--prefill-budget", type=int, default=d.prefill_budget,
                    help="serve mode: max TOTAL prompt tokens per tick "
-                        "across prefilling slots (default: slots * chunk, "
-                        "i.e. every prefilling slot advances one chunk) — "
+                        "across prefilling slots (default: one chunk). A "
+                        "tick computes the rows it carries, so this is what "
+                        "a tick with prompt work costs the live slots — "
                         "the Sarathi-style stall-free token budget")
     p.add_argument("--admission", choices=["chunked", "whole"],
                    default=d.admission,
