@@ -534,7 +534,8 @@ def load_model_config(path: str) -> dict:
     if not isinstance(model, dict) or "hidden_size" not in model:
         raise SystemExit(
             f"--model-config {path}: not a model configuration (a JSON "
-            f"object with hidden_size, num_hidden_layers, ...)")
+            f"object in a published config.json's keys: hidden_size, the "
+            f"layer count, ...)")
     return model
 
 

@@ -495,7 +495,7 @@ def init_cache(
     model_axis: Optional[str] = AXIS_MODEL,
 ) -> KVCache:
     """Allocate an empty cache; sequence-sharded over ``mesh`` when given."""
-    shape = (cfg.n_layers, batch_size, cfg.n_kv_heads, max_len, cfg.d_head)
+    shape = (cfg.cache_layers, batch_size, cfg.n_kv_heads, max_len, cfg.d_head)
     if mesh is not None:
         ax = prune_axes(
             mesh, {"data": data_axis, "seq": seq_axis, "model": model_axis}
@@ -586,7 +586,7 @@ def init_paged_cache(
             raise ValueError(
                 "a sequence-sharded latent pool (kv_shard='seq') is not "
                 "built: the tree merge has no latent kernel")
-        shape = (cfg.n_layers, blocks, block, cfg.mla.row)
+        shape = (cfg.cache_layers, blocks, block, cfg.mla.row)
         pool = (
             jax.jit(lambda: jnp.zeros(shape, cfg.dtype),
                     out_shardings=NamedSharding(mesh, P()))()
@@ -596,7 +596,7 @@ def init_paged_cache(
             kv=pool, table=jnp.zeros((batch_size, nb), jnp.int32),
             length=jnp.zeros((batch_size,), jnp.int32),
         )
-    shape = (cfg.n_layers, blocks, cfg.n_kv_heads, block, cfg.d_head)
+    shape = (cfg.cache_layers, blocks, cfg.n_kv_heads, block, cfg.d_head)
     dtype = jnp.int8 if quantize else cfg.dtype
     sscale = None
     if mesh is not None:
@@ -620,7 +620,7 @@ def init_paged_cache(
         _CACHE_CAPACITY.set(nb * block)
         _CACHE_ALLOCS.labels(sharded=str(mesh is not None).lower()).inc()
     if quantize:
-        sshape = (cfg.n_layers, blocks, cfg.n_kv_heads)
+        sshape = (cfg.cache_layers, blocks, cfg.n_kv_heads)
         ones = (
             jax.jit(lambda: jnp.ones(sshape, jnp.float32),
                     out_shardings=sscale)
@@ -964,9 +964,14 @@ def _latent_layers(
     """The layer loops of a latent-attention / expert model: the leading
     dense-FFN stack under one scan, the expert stack under another, the
     whole latent pool the carry of both and the layer's blocks reached by
-    offset (``table + l·N``, the layer index running through both stacks).
-    Every row — decode rows and chunk rows alike — attends in absorbed
-    form against the pool it has just been written to, group by group."""
+    offset (``table + l·N``, the pool's layer index running through both
+    stacks: one for every attention, so ``sublayers`` of them a model
+    layer). Where the routed experts are a branch beside the dense FFNs
+    (``cfg.moe.branch``), the expert stack's body is the third one below:
+    every sublayer's attention and dense FFN in turn, the branch computed
+    where it leaves and added where it rejoins. Every row — decode rows
+    and chunk rows alike — attends in absorbed form against the pool it
+    has just been written to, group by group."""
     from tree_attention_tpu.models.experts import (
         EXPERT_LEAVES, expert_layer, held_counts,
     )
@@ -1010,6 +1015,25 @@ def _latent_layers(
         )
         return (x + y, pool), held_counts(chosen, valid, cfg.moe)
 
+    def branch_body(carry, xs):
+        layer, i = xs
+        x, pool = carry
+        leaves, rejoins = cfg.moe.branch
+        for j in range(cfg.sublayers):
+            sub = layer["sub"][j]
+            x, pool = attend(sub, x, pool, i * cfg.sublayers + j)
+            h32 = rms_norm(x.astype(jnp.float32), sub["ln2"], cfg.norm_eps)
+            h = h32.astype(x.dtype)
+            if j == leaves:
+                m, chosen = expert_layer(
+                    layer, h, cfg.moe, router_input=h32,
+                    experts=experts, first=i * cfg.moe.held,
+                )
+            x = x + _mlp_block(sub, h)
+            if j == rejoins:
+                x = x + m
+        return (x, pool), held_counts(chosen, valid, cfg.moe)
+
     carry = (x, cache.kv)
     n_dense = cfg.n_dense_layers
     if n_dense:
@@ -1024,9 +1048,10 @@ def _latent_layers(
         experts = tuple(
             stack[n].reshape((-1,) + stack[n].shape[2:])
             for n in EXPERT_LEAVES)
-        carry, rows = lax.scan(expert_body, carry, (
-            {n: a for n, a in stack.items() if n not in EXPERT_LEAVES},
-            jnp.arange(n_dense, cfg.n_layers, dtype=jnp.int32)))
+        carry, rows = lax.scan(
+            expert_body if cfg.moe.branch is None else branch_body, carry, (
+                {n: a for n, a in stack.items() if n not in EXPERT_LEAVES},
+                jnp.arange(n_dense, cfg.n_layers, dtype=jnp.int32)))
         if stats is not None:
             stats["expert_rows"] = rows
     return carry

@@ -2,13 +2,17 @@
 
 The router scores every expert at its published width in float32; the top
 ``per_token`` are chosen (group-limited: the best ``top_groups`` of
-``n_groups`` equal groups first, a group scored by its best expert; ties to
-the lowest index); the layer computes ``Σ w_i · SwiGLU_i(x)`` over the chosen
-experts IT HOLDS (``[held_first, held_first + held)``) and adds the shared
-experts. Pairs routed to an expert held elsewhere add nothing here: that
-partial sum is what goes on, in this program and in the benchmark's plain
+``n_groups`` equal groups first, a group scored by its best expert; or
+corrected: the top of ``scores + bias``, weighted by the scores themselves;
+ties to the lowest index); the layer computes ``Σ w_i · SwiGLU_i(x)`` over the
+chosen experts IT HOLDS (``[held_first, held_first + held)``) and adds the
+shared experts. Pairs routed to an expert held elsewhere add nothing here:
+that partial sum is what goes on, in this program and in the benchmark's plain
 reference alike, and the shares of all holders plus the shared experts counted
-once add up to the uncut layer (``tests/test_latent_moe.py``).
+once add up to the uncut layer (``tests/test_latent_moe.py``). A pair on a
+zero-compute expert (the router's last ``n_zero`` ids) adds ``w · x``: no
+weights, no matmul, computed by every holder for its own rows, so it too
+counts once over the shares.
 
 The product over held experts is a grouped matmul over the (row, expert)
 pairs sorted by expert (``ops/pallas_moe.py``): no expert is computed for a
@@ -17,12 +21,14 @@ row chose is not read.
 
 Also here: the parameters of a model whose block is not the dense one
 (:func:`init_block_params`): a leading stack of dense-FFN layers and a stack of
-expert layers, each scanned on its own.
+expert layers, each scanned on its own; or, where the routed experts are a
+branch beside several attention + dense-FFN sublayers, one stack of such
+layers with each sublayer's leaves named apart under ``sub`` (a list).
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -37,11 +43,18 @@ from tree_attention_tpu.models.transformer import (
 Params = Dict[str, Any]
 
 
-def route(scores: jax.Array, ex: ExpertLayer) -> Tuple[jax.Array, jax.Array]:
+def route(scores: jax.Array, ex: ExpertLayer,
+          bias: Optional[jax.Array] = None) -> Tuple[jax.Array, jax.Array]:
     """``scores`` ``(R, n_experts)`` float32 (the softmax of the router's
     logits) -> chosen experts ``(R, per_token)`` int32 and their weights
-    ``(R, per_token)`` float32."""
+    ``(R, per_token)`` float32. With ``bias`` ``(n_experts,)`` the choice
+    is the top of ``scores + bias``; the weights are the chosen experts'
+    uncorrected scores."""
     R, E = scores.shape
+    if bias is not None:
+        _, idx = lax.top_k(scores + bias, ex.per_token)
+        w = jnp.take_along_axis(scores, idx, axis=-1)
+        return idx.astype(jnp.int32), _weigh(w, ex)
     masked = scores
     if ex.n_groups > 1:
         per = E // ex.n_groups
@@ -51,11 +64,13 @@ def route(scores: jax.Array, ex: ExpertLayer) -> Tuple[jax.Array, jax.Array]:
             jnp.arange(R)[:, None], top_g].set(True)
         masked = jnp.where(jnp.repeat(keep, per, axis=1), scores, 0.0)
     w, idx = lax.top_k(masked, ex.per_token)
+    return idx.astype(jnp.int32), _weigh(w, ex)
+
+
+def _weigh(w: jax.Array, ex: ExpertLayer) -> jax.Array:
     if ex.renorm and ex.per_token > 1:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
-    else:
-        w = w * ex.scale
-    return idx.astype(jnp.int32), w
+        return w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return w * ex.scale
 
 
 def router_scores(p: Params, x: jax.Array) -> jax.Array:
@@ -87,7 +102,8 @@ def expert_layer(p: Params, x: jax.Array, ex: ExpertLayer, *,
     experts with this layer's at ``first`` on: a layer loop hands every
     layer the one stack and never slices it. ``router_input`` is ``x``
     before it was rounded to the served type (float32), where the caller
-    has it: one rounding fewer before the near-ties are decided."""
+    has it: one rounding fewer before the near-ties are decided, and
+    before a zero-compute expert hands it back."""
     from tree_attention_tpu.ops.pallas_moe import grouped_matmul, row_tile
 
     if experts is None:
@@ -96,8 +112,9 @@ def expert_layer(p: Params, x: jax.Array, ex: ExpertLayer, *,
     B, T, D = x.shape
     R, K = B * T, ex.per_token
     xf = x.reshape(R, D)
-    idx, w = route(router_scores(
-        p, xf if router_input is None else router_input.reshape(R, D)), ex)
+    x_in = xf if router_input is None else router_input.reshape(R, D)
+    idx, w = route(router_scores(p, x_in), ex,
+                   p["router_bias"] if ex.corrected else None)
     local = idx - ex.held_first
     here = (local >= 0) & (local < ex.held)
     # Pairs sorted by held expert; a pair whose expert lives elsewhere
@@ -120,21 +137,40 @@ def expert_layer(p: Params, x: jax.Array, ex: ExpertLayer, *,
         jnp.where(here[..., None],
                   pairs.astype(jnp.float32) * w[..., None], 0.0),
         axis=1,
-    ).astype(x.dtype)
+    )
+    if ex.n_zero:
+        # Identity experts: one weighted sum a row, no weights to read.
+        w_zero = jnp.sum(jnp.where(idx >= ex.n_routed, w, 0.0), axis=-1)
+        y = y + w_zero[:, None] * x_in.astype(jnp.float32)
+    y = y.astype(x.dtype)
     if ex.shared_width:
         y = y + swiglu(xf, p["ws1"], p["ws3"], p["ws2"])
     return y.reshape(B, T, D), idx.reshape(B, T, K)
 
 
+def counts_width(ex: ExpertLayer) -> int:
+    """Entries :func:`held_counts` gives for one layer."""
+    return ex.held + (3 if ex.n_zero else 1)
+
+
 def held_counts(idx: jax.Array, valid: jax.Array,
                 ex: ExpertLayer) -> jax.Array:
-    """Rows on each held expert ``(held,)`` int32, and in the last entry
-    the pairs routed elsewhere: ``idx`` ``(B, T, per_token)`` chosen
+    """Rows on each held expert ``(held,)`` int32, then the pairs on
+    routed experts held elsewhere; where the layer has zero-compute
+    experts two entries more: the pairs on those, and the most routed
+    (real) experts any one row chose. ``idx`` ``(B, T, per_token)`` chosen
     experts, ``valid`` ``(B, T)`` the rows that carry a token."""
     local = idx - ex.held_first
     slot = jnp.where((local >= 0) & (local < ex.held), local, ex.held)
-    return jnp.zeros((ex.held + 1,), jnp.int32).at[slot.reshape(-1)].add(
-        jnp.repeat(valid.reshape(-1), ex.per_token).astype(jnp.int32))
+    ones = jnp.repeat(valid.reshape(-1), ex.per_token).astype(jnp.int32)
+    counts = jnp.zeros((counts_width(ex),), jnp.int32)
+    if not ex.n_zero:
+        return counts.at[slot.reshape(-1)].add(ones)
+    zero = idx >= ex.n_routed
+    slot = jnp.where(zero, ex.held + 1, slot)
+    real = jnp.sum(~zero, axis=-1, dtype=jnp.int32)
+    return counts.at[slot.reshape(-1)].add(ones).at[ex.held + 2].set(
+        jnp.max(jnp.where(valid, real, 0)))
 
 
 # ---------------------------------------------------------------------------
@@ -158,6 +194,12 @@ def _dense_layer(key, cfg: TransformerConfig, res_std: float) -> Params:
     }
 
 
+# The std of a seeded correction on the choice: of the order of the gaps
+# between neighbouring scores at the top, so it changes some choices and
+# not most.
+ROUTER_BIAS_STD = 1e-3
+
+
 def _expert_layer_leaves(key, cfg: TransformerConfig,
                          res_std: float) -> Params:
     ex, D = cfg.moe, cfg.d_model
@@ -172,11 +214,20 @@ def _expert_layer_leaves(key, cfg: TransformerConfig,
     # Expert by expert: the float32 draw of the stacked tensor never exists.
     we1, we3, we2 = lax.map(one_expert, jax.random.split(k_e, ex.held))
     out = {
-        "ln1": jnp.ones((D,), jnp.float32), "ln2": jnp.ones((D,), jnp.float32),
-        **init_latent_layer(k_a, cfg, res_std),
         "router": _normal(k_r, (D, ex.n_experts), 0.02, cfg.dtype),
         "we1": we1, "we3": we3, "we2": we2,
     }
+    if ex.corrected:
+        out["router_bias"] = _normal(
+            jax.random.fold_in(k_r, 1), (ex.n_experts,), ROUTER_BIAS_STD,
+            jnp.float32)
+    if ex.branch is not None:
+        out["sub"] = [_dense_layer(k, cfg, res_std)
+                      for k in jax.random.split(k_a, cfg.sublayers)]
+    else:
+        out.update(
+            ln1=jnp.ones((D,), jnp.float32), ln2=jnp.ones((D,), jnp.float32),
+            **init_latent_layer(k_a, cfg, res_std))
     if ex.shared_width:
         s1, s2, s3 = jax.random.split(k_s, 3)
         out.update(
@@ -191,7 +242,12 @@ def init_block_params(key: jax.Array, cfg: TransformerConfig) -> Params:
     """Parameters of a model whose block is chosen by ``cfg.mla`` /
     ``cfg.moe``: ``dense`` (the leading dense-FFN layers) and ``layers``
     (the expert layers; all of them dense where ``cfg.moe`` is None), each
-    stacked on a leading layer axis. One jitted call; every leaf is drawn
+    stacked on a leading layer axis. A layer with a routed branch
+    (``cfg.moe.branch``) holds the router and the experts as any expert
+    layer does, and under ``sub`` a list of its attention + dense-FFN
+    sublayers' leaves, each on the layer axis alone (a second axis would
+    have the layer loop copy both sublayers' weights out of the stack
+    before either is read). One jitted call; every leaf is drawn
     layer by layer (expert by expert) in float32 and rounded at once to
     the served type, so the peak is the weights themselves."""
     import functools

@@ -1,8 +1,9 @@
 """Latent attention (MLA) and YaRN rotary frequencies.
 
 A latent-attention layer caches ONE row a token a layer for all of its heads:
-``[c_kv (kv_rank) | k_rope (rope)]``, the normed key/value latent and the one
-rotary key every head shares. Per-head keys and values are up-projections of
+``[c_kv (kv_rank) | k_rope (rope)]``, the normed key/value latent (times
+``LatentAttention.kv_scale`` where the model scales it) and the one rotary key
+every head shares. Per-head keys and values are up-projections of
 ``c_kv``. Two orders of the same arithmetic:
 
 - **expanded** (the published order): ``[k_nope | v] = c_kv · W_kvb`` for every
@@ -116,14 +117,20 @@ def latent_qkv(p: Params, h: jax.Array, positions: jax.Array,
     H = cfg.n_heads
     freqs = rope_frequencies(la.rope, cfg.rope_theta, la.yarn)
     amp = rope_amplitude(la.yarn)
+
+    def gain(ln, scale):   # a scaled latent: the scale rides the norm's gain
+        return ln if scale == 1.0 else ln * scale
+
     c_q = h
     if la.q_rank:
-        c_q = rms_norm(h @ p["wqa"], p["q_ln"], cfg.norm_eps)
+        c_q = rms_norm(h @ p["wqa"], gain(p["q_ln"], la.q_scale),
+                       cfg.norm_eps)
     q = (c_q @ p["wqb"]).reshape(B, T, H, la.nope + la.rope)
     q_nope, q_rope = q[..., :la.nope], q[..., la.nope:]
     q_rope = rope_halves(q_rope, positions, freqs, amp)
     kva = h @ p["wkva"]                                   # (B, T, rank+rope)
-    c_kv = rms_norm(kva[..., :la.kv_rank], p["kv_ln"], cfg.norm_eps)
+    c_kv = rms_norm(kva[..., :la.kv_rank], gain(p["kv_ln"], la.kv_scale),
+                    cfg.norm_eps)
     k_rope = rope_halves(kva[..., la.kv_rank:], positions, freqs, amp)
     q_lat = jnp.einsum("bthn,hnc->bthc", q_nope, p["wkb"])
 

@@ -29,7 +29,7 @@ TPU-first design choices:
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -62,7 +62,9 @@ class LatentAttention:
     (``models/latent.py``). In the pool the row is followed by zero lanes
     up to the next multiple of the chip's 128: short of one, the TPU
     compiler copies the whole pool into a layout of its own before every
-    launch of the kernel."""
+    launch of the kernel. ``q_scale`` / ``kv_scale`` multiply the normed
+    low-rank latents ``c_q`` / ``c_kv`` (the cached row holds the scaled
+    ``c_kv``; the rotary key is not scaled)."""
 
     q_rank: int            # 0: queries are projected directly
     kv_rank: int
@@ -70,6 +72,8 @@ class LatentAttention:
     rope: int
     v_head: int
     yarn: Optional[YarnRope] = None
+    q_scale: float = 1.0
+    kv_scale: float = 1.0
 
     @property
     def row_pad(self) -> int:
@@ -85,9 +89,12 @@ class LatentAttention:
 class ExpertLayer:
     """A routed-expert feed-forward layer (``models/experts.py``) and the
     share of it held here: the router scores all ``n_experts``, this
-    program computes experts ``[held_first, held_first + held)``."""
+    program computes experts ``[held_first, held_first + held)``. The
+    router's last ``n_zero`` ids are zero-compute experts: they have no
+    weights, give back the row they are handed, and are computed where the
+    row lives, so every holder computes them for its own rows."""
 
-    n_experts: int         # the router's width
+    n_experts: int         # the router's width, zero-compute experts included
     held: int
     held_first: int
     per_token: int
@@ -98,6 +105,15 @@ class ExpertLayer:
     scale: float = 1.0     # on the chosen scores when they are not renormed
     renorm: bool = False
     first_dense: int = 0   # leading layers that keep the dense SwiGLU
+    n_zero: int = 0
+    # The top ``per_token`` are taken of ``scores + bias`` (a per-expert
+    # correction held with the weights); the weights stay the scores.
+    corrected: bool = False
+    # None: the expert layer stands in the layer's FFN's place. ``(leaves,
+    # rejoins)``: a branch beside the dense FFNs, computed from the normed
+    # residual that sublayer ``leaves``'s FFN reads and added to the
+    # residual after sublayer ``rejoins``'s FFN.
+    branch: Optional[Tuple[int, int]] = None
 
     def __post_init__(self):
         if self.n_experts % self.n_groups:
@@ -105,11 +121,23 @@ class ExpertLayer:
                 f"{self.n_experts} experts do not split into "
                 f"{self.n_groups} routing groups")
         if not 0 <= self.held_first <= self.held_first + self.held \
-                <= self.n_experts:
+                <= self.n_routed:
             raise ValueError(
                 f"experts held [{self.held_first}, "
                 f"{self.held_first + self.held}) lie outside the router's "
-                f"{self.n_experts}")
+                f"{self.n_routed} routed experts")
+        if self.corrected and self.n_groups > 1:
+            raise ValueError(
+                "a corrected choice inside routing groups is not built")
+        if self.branch is not None and self.first_dense:
+            raise ValueError(
+                "leading dense layers before layers with a routed branch "
+                "are not built")
+
+    @property
+    def n_routed(self) -> int:
+        """Experts with weights: the router's ids below this."""
+        return self.n_experts - self.n_zero
 
 
 @dataclasses.dataclass(frozen=True)
@@ -118,7 +146,11 @@ class TransformerConfig:
 
     The block is chosen by the data: ``mla`` set means latent attention in
     every layer (else rotary GQA), ``moe`` set means every layer after its
-    ``first_dense`` is a routed-expert layer (else the dense SwiGLU).
+    ``first_dense`` is a routed-expert layer (else the dense SwiGLU). A
+    layer is ``sublayers`` pairs of one attention and one dense FFN; with
+    more than one, the routed experts are a branch beside them
+    (``moe.branch``). The cache holds one layer of rows per attention:
+    ``cache_layers``, not ``n_layers``, is its depth.
     """
 
     vocab_size: int = 32768
@@ -142,6 +174,7 @@ class TransformerConfig:
     remat: bool = True           # checkpoint each layer body under scan
     mla: Optional[LatentAttention] = None
     moe: Optional[ExpertLayer] = None
+    sublayers: int = 1
 
     def __post_init__(self):
         if self.n_heads % self.n_kv_heads:
@@ -149,6 +182,16 @@ class TransformerConfig:
                 f"n_heads ({self.n_heads}) must be a multiple of "
                 f"n_kv_heads ({self.n_kv_heads})"
             )
+        branch = self.moe.branch if self.moe is not None else None
+        if (self.sublayers > 1 and branch is None) or (
+                branch is not None and (
+                    self.mla is None
+                    or not 0 <= branch[0] <= branch[1] < self.sublayers)):
+            raise ValueError(
+                f"{self.sublayers} sublayers a layer with routed branch "
+                f"{branch}: several sublayers are built as latent "
+                f"attention + dense FFN pairs with the routed experts a "
+                f"branch that leaves and rejoins inside the layer")
 
     @property
     def q_dim(self) -> int:
@@ -164,28 +207,59 @@ class TransformerConfig:
         return self.mla is None and self.moe is None
 
     @property
+    def cache_layers(self) -> int:
+        """The cache's depth: one layer of rows for every attention."""
+        return self.n_layers * self.sublayers
+
+    @property
     def n_dense_layers(self) -> int:
         if self.moe is None:
             return self.n_layers
         return min(self.moe.first_dense, self.n_layers)
 
 
+def _key(c: Dict[str, Any], *names: str) -> Any:
+    """The first of a quantity's published names that ``c`` has."""
+    for n in names:
+        if n in c:
+            return c[n]
+    raise KeyError(" / ".join(names))
+
+
 def model_from_config(config: Dict[str, Any], *, dtype: Any = None,
                       max_seq_len: int = 65536,
                       **overrides: Any) -> TransformerConfig:
     """The model as data: a :class:`TransformerConfig` from a model's
-    published ``config.json`` keys. ``kv_lora_rank`` selects latent
-    attention, ``n_routed_experts`` the expert layer; without them the keys
-    are those of a Llama-style dense decoder. A file cut to one chip's
-    share says so under ``deployment``: ``experts_total`` (the router's
-    width; ``n_routed_experts`` is then how many are held here) and
+    published ``config.json`` keys, under the names its family publishes
+    them (layers: ``num_hidden_layers`` / ``num_layers``; the dense FFN:
+    ``intermediate_size`` / ``ffn_hidden_size``; an expert's:
+    ``moe_intermediate_size`` / ``expert_ffn_hidden_size``; experts a
+    token: ``num_experts_per_tok`` / ``moe_topk``). ``kv_lora_rank``
+    selects latent attention (``mla_scale_q_lora`` / ``mla_scale_kv_lora``:
+    the normed latents times ``(hidden / rank)^1/2``), ``n_routed_experts``
+    the expert layer (``zero_expert_num`` identity experts after the
+    routed ones in the router's width); without them the keys are those of
+    a Llama-style dense decoder.
+
+    What no published key says is said by two groups of this repo's own. A
+    file cut to one chip's share says so under ``deployment``:
+    ``experts_total`` (the routed experts of the whole layer;
+    ``n_routed_experts`` is then how many are held here) and
     ``expert_share`` (which share of them, 0-based; consecutive ranges).
-    ``vocab_size`` is the rows held."""
+    ``vocab_size`` is the rows held. A layer that is not one attention and
+    one FFN says what it is under ``block``: ``sublayers`` (attention +
+    dense-FFN pairs a layer), ``routed_branch`` ``[leaves, rejoins]`` (the
+    routed experts are computed beside the dense FFNs from the normed
+    residual sublayer ``leaves``'s FFN reads, and added after sublayer
+    ``rejoins``'s FFN) and ``corrected_choice`` (the top experts are taken
+    of scores + a per-expert bias)."""
     c = config
     heads = int(c["num_attention_heads"])
+    hidden = int(c["hidden_size"])
     mla = moe = None
-    d_head = int(c.get("head_dim") or int(c["hidden_size"]) // heads)
-    kv_heads = int(c.get("num_key_value_heads", heads))
+    d_head = int(c.get("head_dim") or hidden // heads)
+    kv_heads = int(c.get("num_key_value_heads") or heads)
+    block = c.get("block") or {}
     if c.get("kv_lora_rank"):
         rs = c.get("rope_scaling") or None
         yarn = None
@@ -202,12 +276,16 @@ def model_from_config(config: Dict[str, Any], *, dtype: Any = None,
                 mscale=float(rs.get("mscale", 1.0)),
                 mscale_all_dim=float(rs.get("mscale_all_dim", 0.0)),
             )
+        q_rank, kv_rank = int(c.get("q_lora_rank") or 0), int(c["kv_lora_rank"])
         mla = LatentAttention(
-            q_rank=int(c.get("q_lora_rank") or 0),
-            kv_rank=int(c["kv_lora_rank"]),
+            q_rank=q_rank, kv_rank=kv_rank,
             nope=int(c["qk_nope_head_dim"]), rope=int(c["qk_rope_head_dim"]),
             v_head=int(c["v_head_dim"]),
             yarn=yarn,
+            q_scale=(hidden / q_rank) ** 0.5
+            if c.get("mla_scale_q_lora") and q_rank else 1.0,
+            kv_scale=(hidden / kv_rank) ** 0.5
+            if c.get("mla_scale_kv_lora") else 1.0,
         )
         d_head, kv_heads = mla.nope + mla.rope, heads
     if c.get("n_routed_experts"):
@@ -221,33 +299,45 @@ def model_from_config(config: Dict[str, Any], *, dtype: Any = None,
                 f"is built")
         if int(c.get("moe_layer_freq", 1)) != 1:
             raise ValueError("moe_layer_freq other than 1 is not built")
+        n_zero = int(c.get("zero_expert_num") or 0)
+        if n_zero and c.get("zero_expert_type", "identity") != "identity":
+            raise ValueError(
+                f"zero-compute experts of type {c['zero_expert_type']!r}: "
+                f"only 'identity' is built")
         dep = c.get("deployment") or {}
         held = int(c["n_routed_experts"])
         total = int(dep.get("experts_total", held))
         grouped = c.get("topk_method", "greedy") == "group_limited_greedy"
+        width = int(_key(c, "moe_intermediate_size",
+                         "expert_ffn_hidden_size"))
+        branch = block.get("routed_branch")
         moe = ExpertLayer(
-            n_experts=total, held=held,
+            n_experts=total + n_zero, held=held,
             held_first=int(dep.get("expert_share", 0)) * held,
-            per_token=int(c["num_experts_per_tok"]),
-            width=int(c["moe_intermediate_size"]),
-            shared_width=int(c.get("n_shared_experts") or 0)
-            * int(c["moe_intermediate_size"]),
+            per_token=int(_key(c, "num_experts_per_tok", "moe_topk")),
+            width=width,
+            shared_width=int(c.get("n_shared_experts") or 0) * width,
             n_groups=int(c["n_group"]) if grouped else 1,
             top_groups=int(c["topk_group"]) if grouped else 1,
             scale=float(c.get("routed_scaling_factor", 1.0)),
             renorm=bool(c.get("norm_topk_prob", False)),
             first_dense=int(c.get("first_k_dense_replace", 0)),
+            n_zero=n_zero,
+            corrected=bool(block.get("corrected_choice", False)),
+            branch=None if branch is None else (int(branch[0]),
+                                                int(branch[1])),
         )
     if dtype is None:
         dtype = jnp.dtype(str(c.get("torch_dtype", "bfloat16")))
     kw = dict(
-        vocab_size=int(c["vocab_size"]), d_model=int(c["hidden_size"]),
-        n_layers=int(c["num_hidden_layers"]), n_heads=heads,
-        n_kv_heads=kv_heads, d_head=d_head,
-        d_ff=int(c["intermediate_size"]), max_seq_len=max_seq_len,
+        vocab_size=int(c["vocab_size"]), d_model=hidden,
+        n_layers=int(_key(c, "num_hidden_layers", "num_layers")),
+        n_heads=heads, n_kv_heads=kv_heads, d_head=d_head,
+        d_ff=int(_key(c, "intermediate_size", "ffn_hidden_size")),
+        max_seq_len=max_seq_len,
         rope_theta=float(c.get("rope_theta", 10000.0)),
         norm_eps=float(c.get("rms_norm_eps", 1e-6)), dtype=dtype,
-        mla=mla, moe=moe,
+        mla=mla, moe=moe, sublayers=int(block.get("sublayers", 1)),
     )
     kw.update(overrides)
     return TransformerConfig(**kw)

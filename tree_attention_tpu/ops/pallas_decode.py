@@ -65,7 +65,8 @@ from tree_attention_tpu.ops.block_utils import (
 _KERNEL_BUILDS = obs.counter(
     "pallas_decode_kernel_builds_total",
     "flash-decode kernel program builds (one per distinct shape/config), "
-    "by the KV heads and table entries a grid step takes",
+    "by the KV heads and table entries a grid step takes (mla_paged: the "
+    "query heads packed over the one latent row)",
     labels=("kernel", "heads", "entries"),
 )
 
@@ -862,15 +863,16 @@ def attention_pallas_mla_paged(
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     # Every head reads the same rows: pack heads x Tq into the sublanes.
-    # Decode (Tq = 1) is one 128-head tile a slot; chunk rows take tiles of
-    # 1024 so that a slot's blocks are walked by few tiles.
+    # Decode (Tq = 1) is one tile of up to 128 heads a slot (half a tile at
+    # 64 heads); chunk rows take tiles of 1024 so that a slot's blocks are
+    # walked by few tiles.
     r = H * Tq
     bq = min(-(-r // 8) * 8, 128 if Tq == 1 else 1024)
     qp = _pad_dim(q.reshape(B, r, W), 1, bq)
     n_q = qp.shape[1] // bq
     per = next(p for p in (4, 2, 1) if NB % p == 0)
     if obs.REGISTRY.enabled:
-        _KERNEL_BUILDS.labels(kernel="mla_paged", heads=1, entries=per).inc()
+        _KERNEL_BUILDS.labels(kernel="mla_paged", heads=H, entries=per).inc()
     in_specs = [pl.BlockSpec((1, bq, W), _paged_q_map)] + [
         pl.BlockSpec((1, block, W), _mla_kv_map(j, per))
         for j in range(per)

@@ -240,7 +240,7 @@ class DisaggServer:
 
             self.host_pool = HostBlockPool(
                 host_blocks,
-                n_layers=cfg.n_layers,
+                n_layers=cfg.cache_layers,
                 n_kv_heads=cfg.n_kv_heads,
                 block=kv_block,
                 d_head=cfg.d_head,

@@ -209,7 +209,12 @@ _MOE_ROWS = obs.histogram(
 )
 _MOE_PAIRS_TOTAL = obs.counter(
     "moe_pairs_total",
-    "row-expert pairs routed by fetched ticks, held here or elsewhere",
+    "row-expert pairs routed by fetched ticks: held here, held elsewhere "
+    "or zero-compute",
+)
+_MOE_PAIRS_ZERO = obs.counter(
+    "moe_pairs_zero",
+    "row-expert pairs that fell on zero-compute experts (no weights read)",
 )
 _MOE_PAIRS_HERE = obs.counter(
     "moe_pairs_here",
@@ -971,7 +976,7 @@ class SlotServer:
         self._kv_seq_sharded = kv_shard == "seq" and self._seq_shards > 1
         # int8 pool bytes per token — what an int8 paged hit's dequant
         # gather into staging actually moves (ISSUE 13).
-        self._kv_token_bytes_q = 2 * cfg.n_layers * cfg.n_kv_heads \
+        self._kv_token_bytes_q = 2 * cfg.cache_layers * cfg.n_kv_heads \
             * cfg.d_head
         if kv_block is None:
             # Matching granularity == page size keeps radix hits
@@ -1050,7 +1055,7 @@ class SlotServer:
                 )
             self.attach_host_tier(HostBlockPool(
                 host_blocks,
-                n_layers=cfg.n_layers,
+                n_layers=cfg.cache_layers,
                 n_kv_heads=cfg.n_kv_heads,
                 block=kv_block,
                 d_head=cfg.d_head,
@@ -1073,12 +1078,15 @@ class SlotServer:
         # the model built (a latent row is not 2·Hkv·D): the report's
         # ``kv.token_bytes``.
         self._kv_token_bytes = cache_token_bytes(self.cache)
-        # Expert layers' row counts on the tick's fetch: (layers, held+1),
-        # None for a model without experts.
+        # Expert layers' row counts on the tick's fetch: (layers, what
+        # ``experts.held_counts`` gives a layer), None for a model without
+        # experts.
         self._expert_rows_shape: Optional[Tuple[int, int]] = None
         if cfg.moe is not None and cfg.n_layers > cfg.n_dense_layers:
+            from tree_attention_tpu.models.experts import counts_width
+
             self._expert_rows_shape = (
-                cfg.n_layers - cfg.n_dense_layers, cfg.moe.held + 1)
+                cfg.n_layers - cfg.n_dense_layers, counts_width(cfg.moe))
         self.tok = jnp.zeros((slots,), jnp.int32)
 
         # Host mirror of slot state (the scheduler's view; device state is
@@ -1311,21 +1319,38 @@ class SlotServer:
     def _account_expert_rows(self, extra: np.ndarray) -> Dict[str, int]:
         """Read the expert layers' row counts off the tick's fetch (the
         rows below the slots') into the registry, and return the flight
-        record's three numbers: ``expert_pairs`` (row-expert pairs
-        computed here), ``experts_touched`` (held experts with >= 1 row,
-        summed over layers), ``expert_rows_max`` (the fullest expert)."""
+        record's numbers, over the rows that carry a token and all expert
+        layers. Of the held routed experts: ``expert_pairs`` (row-expert
+        pairs computed here), ``experts_touched`` (held experts with >= 1
+        row, summed over layers), ``expert_rows_max`` (the fullest
+        expert). Of the router: ``routed_rows`` (its decisions: rows x
+        layers), ``routed_pairs`` (``per_token`` a decision),
+        ``zero_pairs`` (pairs on zero-compute experts) and
+        ``real_row_max`` (the most routed experts, held here or
+        elsewhere, one decision chose)."""
+        ex = self.cfg.moe
         layers, width = self._expert_rows_shape
         rows = extra.reshape(-1)[:layers * width].reshape(layers, width)
-        here = rows[:, :-1]
+        here = rows[:, :ex.held]
         pairs = int(here.sum())
+        zero = int(rows[:, ex.held + 1].sum()) if ex.n_zero else 0
+        routed = pairs + int(rows[:, ex.held].sum()) + zero
+        if ex.n_zero:
+            real_max = int(rows[:, ex.held + 2].max())
+        else:
+            real_max = ex.per_token if routed else 0
         if obs.REGISTRY.enabled:
             _MOE_PAIRS_HERE.inc(pairs)
-            _MOE_PAIRS_TOTAL.inc(int(rows.sum()))
+            _MOE_PAIRS_ZERO.inc(zero)
+            _MOE_PAIRS_TOTAL.inc(routed)
             for n in here.reshape(-1):
                 _MOE_ROWS.observe(float(n))
         return {"expert_pairs": pairs,
                 "experts_touched": int((here > 0).sum()),
-                "expert_rows_max": int(here.max())}
+                "expert_rows_max": int(here.max()),
+                "routed_rows": routed // ex.per_token,
+                "routed_pairs": routed, "zero_pairs": zero,
+                "real_row_max": real_max}
 
     def _sample_emit(self, last, keys, temp, topk, idx):
         """The ONE per-slot sampling call every emitting program shares
@@ -1619,7 +1644,7 @@ class SlotServer:
         """
         cfg = self.cfg
         bucket = prompt.shape[1]
-        shape = (cfg.n_layers, 1, cfg.n_kv_heads, bucket, cfg.d_head)
+        shape = (cfg.cache_layers, 1, cfg.n_kv_heads, bucket, cfg.d_head)
         mini = KVCache(
             k=jnp.zeros(shape, cfg.dtype),
             v=jnp.zeros(shape, cfg.dtype),
