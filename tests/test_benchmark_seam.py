@@ -263,11 +263,14 @@ def test_the_double_layer_cell_resolves_every_file_it_names():
     names = [m["name"] for m in cell.per_layer]
     for name in names:
         assert spec.load_module("layer_metrics", name + ".py").read
-    # Every per-layer metric the other latent cell reports, and its own two.
+    # Every per-layer metric the other latent cell reports, and its own two
+    # (what later PRs appended for every cell comes after them: PR 32's).
     theirs = [m["name"] for m in spec.cell(CELL).per_layer]
-    assert names == theirs + ["zero_expert_pairs_pct",
-                              "real_experts_row_max_over_mean"]
-    for m in cell.per_layer[-2:]:
+    later = ["tick_ahead_pct"]
+    assert theirs[-len(later):] == later
+    assert names == theirs[:-len(later)] + [
+        "zero_expert_pairs_pct", "real_experts_row_max_over_mean"] + later
+    for m in cell.per_layer[-2 - len(later):-len(later)]:
         assert m["workloads"] == [LC_CELL] and m["layer"] == "expert layer"
         assert (m["source"], m["moves"]) == ("program_counter", "tbt_p50_ms")
     for w in spec.data["workloads"][:4]:
@@ -397,3 +400,37 @@ def test_the_double_layers_adapter_refuses_another_block_at_once():
         adapter._hold_to_file(old, cell.config)
     with pytest.raises(SpecError, match="cannot read"):
         adapter.build({"family": "longcat_scmoe"}, [], 0, "cpu", None)
+
+
+@pytest.mark.parametrize("flight, want", [
+    # A parent's records have no such field: nothing to read, not zero.
+    ([{"t_s": 1.0, "phases": [["ingest", 1.0], ["fetch", 1.1]]}], None),
+    (None, None),
+    # Three fetched ticks in the window, two dispatched ahead; a tick that
+    # fetched nothing (chunks only) and one before the window do not count.
+    ([{"t_s": 1.0, "ahead": False, "sync_reason": "first",
+       "phases": [["ingest", 1.0], ["fetch", 1.1]]},
+      {"t_s": 2.0, "ahead": True, "phases": [["ingest", 2.0], ["fetch", 2.1]]},
+      {"t_s": 3.0, "ahead": True, "phases": [["ingest", 3.0], ["fetch", 3.1]]},
+      {"t_s": 4.0, "ahead": True, "phases": [["ingest", 4.0], ["account", 4.1]]},
+      {"t_s": 0.5, "ahead": True, "phases": [["ingest", 0.5], ["fetch", 0.6]]},
+      {"tick": 9, "sweep_only": True}], 100.0 * 2 / 3),
+], ids=["parent", "no-recorder", "window"])
+def test_tick_ahead_pct_reads_the_look_ahead_counter(flight, want):
+    """The look-ahead's per-layer metric (ISSUE 32): found by name like the
+    others, listed for every cell (it moves ``tbt_p50_ms``, which every
+    cell reports), after the entries that were there."""
+    import types
+
+    spec = Spec(BENCH)
+    listed = json.load(open(BENCH))["per_layer"]
+    assert listed[-1] == {
+        "name": "tick_ahead_pct", "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "admission and scheduling",
+        "moves": "tbt_p50_ms"}
+    for w in json.load(open(BENCH))["workloads"]:
+        assert "tick_ahead_pct" in [
+            m["name"] for m in spec.cell(w["name"]).per_layer]
+    read = spec.load_module("layer_metrics", "tick_ahead_pct.py").read
+    run = types.SimpleNamespace(flight=flight, t_open=1.0, t_end=10.0)
+    assert read(run) == (want if want is None else pytest.approx(want))

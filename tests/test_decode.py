@@ -318,13 +318,16 @@ def test_q8_long_horizon_drift_bounded(quant_kernel):
     logits_q, quant = forward_step(params, prompt, quant, CFG)
     quant = quantize_cache(quant)
 
+    # One program a cache type for the 48 steps: dispatched eagerly a step
+    # is a launch a primitive (the interpreted int8 kernel's included).
+    step_e = jax.jit(lambda p, t, c: forward_step(p, t, c, CFG))
+    step_q = jax.jit(lambda p, t, c: forward_step(
+        p, t, c, CFG, quant_kernel=quant_kernel))
     tok = jnp.argmax(logits_e[:, -1], axis=-1)[:, None]
     max_err, agree = 0.0, 0
     for _ in range(n_steps):
-        logits_e, exact = forward_step(params, tok, exact, CFG)
-        logits_q, quant = forward_step(
-            params, tok, quant, CFG, quant_kernel=quant_kernel
-        )
+        logits_e, exact = step_e(params, tok, exact)
+        logits_q, quant = step_q(params, tok, quant)
         le = np.asarray(logits_e[:, -1], np.float32)
         lq = np.asarray(logits_q[:, -1], np.float32)
         max_err = max(max_err, float(np.abs(le - lq).max()))

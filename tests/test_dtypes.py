@@ -148,7 +148,9 @@ def test_tree_attention_sharded_half_precision(name):
     n = 4
     ref_out, ref_lse = attention_naive(qj, kj, vj, causal=True)
     qz, kz, vz = (shard_zigzag(x, 2, n) for x in (qj, kj, vj))
-    out, lse = tree_attention(
+    from tests.jitted import jitted
+
+    out, lse = jitted(tree_attention)(
         qz, kz, vz, mesh=cpu_mesh(n), causal=True, layout="zigzag",
         impl="naive", q_chunk=12,
     )

@@ -104,7 +104,9 @@ def test_fuzz_tree_attention_matches_oracle(seed):
         ks, vs = shard_zigzag(k, 2, n), shard_zigzag(v, 2, n)
     else:
         qs, ks, vs = q, k, v
-    out, lse = tree_attention(
+    from tests.jitted import jitted
+
+    out, lse = jitted(tree_attention)(
         qs, ks, vs, mesh=cpu_mesh(n), causal=causal, layout=layout,
         impl="naive", q_chunk=q_chunk,
     )

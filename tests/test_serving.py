@@ -750,7 +750,11 @@ def test_tick_spans_tag_occupancy_and_queue(engine, tmp_path):
     )
     ticks = [e for e in events if e["ph"] == "X"
              and e["name"] == "serving:tick"]
-    assert len(ticks) == report.ticks
+    # One span an executed tick, and one more each time the loop lands
+    # a pending tail before it idles (ISSUE 32): the drained exit here.
+    assert len([e for e in ticks if not e["args"].get("drain")]) \
+        == report.ticks
+    assert len([e for e in ticks if e["args"].get("drain")]) == 1
     for e in ticks:
         args = e["args"]
         assert {"tick", "occupancy", "prefilling", "chunk_tokens",

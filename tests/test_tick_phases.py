@@ -101,14 +101,29 @@ def test_phases_are_named_in_loop_order_and_tile_the_tick(served):
         assert ("fetch" in names) == rec["host_sync"] == ("emit" in names)
 
 
-def test_tick_top_is_the_first_stamp(served):
-    """``t_s`` and the first phase are the same clock read: the gap between
-    records is the loop waiting, with no unstamped work in it."""
+def test_t_s_is_when_the_program_before_it_was_fetched(served):
+    """A record is one program's and is written by the iteration that
+    lands its tail, one later than the one that dispatched it (ISSUE 32).
+    ``t_s`` of a program dispatched ahead is the moment the program
+    before it came back: the clock read that follows the ``emit`` mark of
+    the record before. The first program of a busy spell has the tick's
+    top, which precedes every stamp in its record."""
     _, _, recs, _, _ = served
-    first = recs[0]
-    for rec in recs:
-        assert rec["phases"][0][1] - first["phases"][0][1] == pytest.approx(
-            rec["t_s"] - first["t_s"], abs=2e-6)
+    assert [r["tick"] for r in recs] == list(range(len(recs)))
+    assert recs[0]["ahead"] is False and recs[0]["sync_reason"] == "first"
+    assert all(r["ahead"] and "sync_reason" not in r for r in recs[1:])
+    stamps = [r["t_s"] for r in recs]
+    assert stamps == sorted(set(stamps))
+    # serve()'s own zero, from each pair: one value to a clock read's cost.
+    zero = [dict(before["phases"])["emit"] - rec["t_s"]
+            for before, rec in zip(recs, recs[1:]) if before["host_sync"]]
+    assert zero and max(zero) - min(zero) < 1e-3
+    for before, rec in zip(recs, recs[1:]):
+        # Behind the dispatch of its own program, inside the iteration
+        # that landed the one before.
+        at = rec["t_s"] + min(zero)
+        assert dict(before["phases"])["dispatch"] < at <= before["t_end"]
+    assert recs[0]["t_s"] + max(zero) <= recs[0]["phases"][0][1]
 
 
 def test_kind_and_tq_agree_with_the_chunk_plan(served):
@@ -145,10 +160,22 @@ def test_trace_events_hold_the_phases_inside_the_tick_span(served):
     _, report, recs, events, _ = served
     spans = [e for e in events if e["ph"] == "X"]
     ticks = [e for e in spans if e["name"] == "serving:tick"]
-    assert len(ticks) == report.ticks
-    by_tick = {e["args"]["tick"]: e for e in ticks}
+    drains = [e for e in ticks if e["args"].get("drain")]
+    assert len(ticks) - len(drains) == report.ticks and len(drains) == 1
+    # The span a record's phases lie in is the iteration that landed it:
+    # the next tick's, or the drain span the loop opens for the last one.
+    by_tick = {e["args"]["tick"] - 1: e for e in ticks
+               if not e["args"].get("drain")}
+    by_tick[drains[0]["args"]["tick"]] = drains[0]
     phase_events = [e for e in spans if e["name"].startswith("tick:")]
     assert phase_events and {e["cat"] for e in phase_events} == {"serving"}
+    # The first iteration dispatched and landed nothing: its stamps are
+    # in the trace and in no record.
+    first = round(recs[0]["phases"][0][1] * 1e9) // 1000
+    primer = [e for e in phase_events if e["ts"] < first]
+    assert [e["name"] for e in primer][-2:] == ["tick:dispatch",
+                                                "tick:publish"]
+    phase_events = phase_events[len(primer):]
     assert len(phase_events) == sum(len(r["phases"]) for r in recs)
     it = iter(phase_events)
     for rec in recs:
@@ -223,15 +250,22 @@ def test_each_phase_is_a_profiler_annotation_left_at_the_next_mark(
         assert (a, b) == ("enter", "leave") and name_a == name_b
     entered = [(n, kw) for what, n, kw in log if what == "enter"]
     groups = _iterations([n for n, _ in entered])
-    # The executed ticks, then the drained exit, which ran the top of the
-    # loop and abandoned its stamps.
-    assert groups[:-1] == [["tick:" + p[0] for p in r["phases"]]
-                           for r in recs]
-    assert groups[-1] == ["tick:ingest", "tick:sweep", "tick:admit"]
+    # The first iteration dispatches and lands nothing; each one after it
+    # dispatches its program and lands the one before (that record's
+    # phases); the drained exit lands the last, looks again for work and
+    # abandons the stamps it began at ``admit``.
+    assert groups[0][-2:] == ["tick:dispatch", "tick:publish"]
+    assert "tick:fetch" not in groups[0]
+    assert groups[1:-1] == [["tick:" + p[0] for p in r["phases"]]
+                            for r in recs[:-1]]
+    assert groups[-1] == ["tick:" + p[0] for p in recs[-1]["phases"]] \
+        + ["tick:admit"]
+    assert [p[0] for p in recs[-1]["phases"]] == [
+        "ingest", "sweep", "admit", "fetch", "emit", "account"]
     dispatches = [kw for n, kw in entered if n == "tick:dispatch"]
     assert dispatches == [
-        {"tick": r["tick"], "kind": r["kind"], "tq": r["tq"]}
-        for r in recs if "dispatch" in dict(r["phases"])]
+        {"tick": r["tick"], "kind": r["kind"], "tq": r["tq"],
+         "ahead": r["ahead"]} for r in recs]
     assert all(kw == {} for n, kw in entered if n != "tick:dispatch")
 
 
@@ -292,12 +326,17 @@ def test_an_idle_iteration_leaves_no_record_and_no_open_annotation(
     assert [w for w, _, _ in log[0::2]] == ["enter"] * (len(log) // 2)
     assert [w for w, _, _ in log[1::2]] == ["leave"] * (len(log) // 2)
     groups = _iterations([n for w, n, _ in log if w == "enter"])
-    idle = [g for g in groups if g[-1] != "tick:account"]
-    # The fast-forward between the arrivals and the drained exit: stamped
-    # to the end of admission, then abandoned with nothing left behind.
-    assert len(idle) == 2 and len(groups) == len(recs) + 2
-    assert all(g == ["tick:ingest", "tick:sweep", "tick:admit"]
-               for g in idle)
+    # Two busy spells: each opens with an iteration that dispatches and
+    # lands nothing, and closes with one that lands the last program,
+    # finds nothing more (the fast-forward to tick 50, the drained exit)
+    # and abandons the stamps it began again at ``admit``.
+    primers = [g for g in groups if "tick:account" not in g]
+    idle = [g for g in groups if g[-1] == "tick:admit"]
+    assert len(primers) == 2 and len(groups) == len(recs) + 2
+    assert all(g[-1] == "tick:publish" for g in primers)
+    assert len(idle) == 2 and all(
+        g[:3] == ["tick:ingest", "tick:sweep", "tick:admit"]
+        and g[-2] == "tick:account" for g in idle)
 
 
 def test_off_means_no_clock_read_and_no_allocation(monkeypatch):

@@ -27,6 +27,9 @@ from tree_attention_tpu.parallel import (
     tree_attention,
     unshard_zigzag,
 )
+from tests.jitted import jitted
+
+tree_attention = jitted(tree_attention)  # one program a call (tests/jitted.py)
 
 
 def _qkv(rng, B=1, H=2, T=512, D=32, dtype=np.float32):

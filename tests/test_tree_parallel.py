@@ -8,6 +8,9 @@ import jax.numpy as jnp
 
 from tree_attention_tpu.ops import attention_naive
 from tree_attention_tpu.parallel import cpu_mesh, tree_attention, tree_decode
+from tests.jitted import jitted
+
+tree_attention = jitted(tree_attention)  # one program a call (tests/jitted.py)
 
 
 def make_qkv(rng, B=2, Hq=4, Hkv=4, Tq=8, Tk=256, D=32, dtype=np.float32):

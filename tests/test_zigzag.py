@@ -21,6 +21,9 @@ from tree_attention_tpu.parallel import (
     unshard_zigzag,
     zigzag_perm,
 )
+from tests.jitted import jitted
+
+tree_attention = jitted(tree_attention)  # one program a call (tests/jitted.py)
 
 
 def _qkv(rng, B=1, H=4, T=256, D=32, dtype=np.float32):

@@ -210,9 +210,11 @@ class TickPhases:
         self._open: Any = None            # the entered TraceAnnotation
         self._annotation: Any = None      # jax.profiler.TraceAnnotation
 
-    def begin(self, now: float) -> None:
+    def begin(self, now: float, name: str = "ingest") -> None:
         """Tick top: latch on/off; ``now`` (the tick's own
-        ``time.monotonic()`` stamp) opens ``ingest``."""
+        ``time.monotonic()`` stamp) opens ``ingest``. An iteration that
+        closed a record halfway (it had to land the program before it
+        ahead of its own planning) begins again at ``name``."""
         self.on = FLIGHT.enabled or TRACER.active
         if not self.on:
             return
@@ -223,28 +225,32 @@ class TickPhases:
 
             self._annotation = TraceAnnotation
         self._marks = []
-        self._enter("ingest", now, None, None, None)
+        self._enter(name, now, None, None, None, None)
 
     def mark(self, name: str, tick: Optional[int] = None,
-             kind: Optional[str] = None, tq: Optional[int] = None) -> None:
+             kind: Optional[str] = None, tq: Optional[int] = None,
+             ahead: Optional[bool] = None) -> None:
         """Close the open phase and open ``name``; a mark that names the
-        phase already open changes nothing. ``tick``/``kind``/``tq`` ride
-        on the profiler annotation (the ``dispatch`` mark passes them)."""
+        phase already open changes nothing. ``tick``/``kind``/``tq``/
+        ``ahead`` ride on the profiler annotation (the ``dispatch`` mark
+        passes them: ``ahead`` says the program went out before the one
+        before it was fetched)."""
         if not self.on:
             return
         if self._marks[-1][0] == name:
             return
         now = time.monotonic()
         self._open.__exit__(None, None, None)
-        self._enter(name, now, tick, kind, tq)
+        self._enter(name, now, tick, kind, tq, ahead)
 
-    def _enter(self, name, now, tick, kind, tq) -> None:
+    def _enter(self, name, now, tick, kind, tq, ahead) -> None:
         self._marks.append([name, now])
         if kind is None:
             self._open = self._annotation(_ANNOTATION[name])
         else:
             self._open = self._annotation(_ANNOTATION[name], tick=tick,
-                                          kind=kind, tq=tq)
+                                          kind=kind, tq=tq,
+                                          ahead=bool(ahead))
         self._open.__enter__()
 
     def _leave(self) -> List[List[Any]]:

@@ -111,6 +111,16 @@ Variants:
   no-leak invariant the chaos harness asserts. The HTTP front door
   lives in :mod:`~tree_attention_tpu.serving.ingress`.
 
+- **One program ahead** (ISSUE 32): ``serve()`` dispatches tick t+1
+  before it fetches tick t whenever t+1 can be planned from counts alone
+  (the token vector is carried on the device), so the host's work and the
+  fetch's round trip run under the device's. A slot that turns out to
+  have left (EOS, cancel, deadline) has one row computed and thrown away;
+  speculation, token-tree and fork families, staged int8 prefill and
+  whole admission keep the synchronous order, chosen tick by tick from
+  engine state (``_plan_tick``; the flight record's ``ahead`` /
+  ``sync_reason``, ``serving_ticks_dispatched_ahead_total``).
+
 Works on one device and on a mesh: the pool is replicated (flash/Pallas
 paths) or, with ``kv_shard="seq"``, range-partitioned over the mesh's
 sequence shards, where every tick's decode attention runs the tree merge.
@@ -201,6 +211,11 @@ _REQUESTS = obs.counter(
 _PREFILL_CHUNKS = obs.counter(
     "serving_prefill_chunks_total",
     "prefill chunks scheduled into serving ticks (fused or staged)",
+)
+_TICKS_AHEAD = obs.counter(
+    "serving_ticks_dispatched_ahead_total",
+    "tick programs dispatched before the host fetched the tokens of the "
+    "program before them (the look-ahead engaged)",
 )
 _MOE_ROWS = obs.histogram(
     "moe_rows_per_expert",
@@ -424,6 +439,59 @@ class _ForkFamily:
     br_live: List[bool] = dataclasses.field(default_factory=list)
     br_index: List[int] = dataclasses.field(default_factory=list)
     br_ttft: List[float] = dataclasses.field(default_factory=list)
+
+
+@dataclasses.dataclass
+class _Tail:
+    """What one dispatched tick program still owes the host: its fetch,
+    the tokens it sampled, the retirements they cause and its flight
+    record. ``SlotServer.serve`` lands a tail one tick late, after the
+    NEXT program's dispatch, whenever that program can be planned from
+    counts alone (ISSUE 32); until then ``SlotServer._tail`` holds it and
+    the head plans each of its rows as one token in flight.
+
+    Every field describes THIS program: the record built when the tail
+    lands takes ``kind``/``tq``/``group``/the rows/the chunk plan/the
+    live slots from here, not from the iteration that lands it."""
+
+    tick: int
+    t_s: float             # when the device could start it (monotonic):
+    #                        the tick's top, or the landing of the
+    #                        program it was dispatched behind
+    ahead: bool            # dispatched before the tail before it landed
+    why: Optional[str]     # sync_reason where it was not
+    kind: str
+    tq: int
+    group: int             # members of a packed tick's chunk group
+    rows_useful: int
+    chunk_tokens: int
+    plan: List[Tuple[int, int, bool]]
+    live: List[int]        # slots with a decode row in the program
+    awaits: List[int]      # slots whose first token rides it
+    reqs: List[Optional[Request]]   # slot -> the request it ran for
+    queue_depth: int
+    pending: int
+    draining: bool
+    fused: Any = None      # the (S [+ expert rows], 2) fetch vehicle
+    all_tok: Any = None    # a verify tick's (S, 1 + Tq, 2) instead
+    spec_plan: Any = None
+    tree_plan: Any = None
+    spec_width: int = 0
+    # The head's per-tick counters, frozen when the tail is left pending
+    # (the iteration that lands it has counted its own by then).
+    counts: Optional[Dict[str, Any]] = None
+
+    def __post_init__(self):
+        # The slots with a token in flight: the head asks per slot.
+        self.rows = frozenset(self.live).union(self.awaits)
+
+    def flying(self, slot: int, req: Optional[Request]) -> bool:
+        """True when ``slot`` has a token in flight for ``req``: a row
+        of this program whose sample the host has not seen. A slot the
+        sweep retired (or re-admitted) since the dispatch has none: its
+        row is computed and thrown away."""
+        return (req is not None and self.reqs[slot] is req
+                and slot in self.rows)
 
 
 @dataclasses.dataclass
@@ -1111,6 +1179,12 @@ class SlotServer:
         self._last_tok_t: List[float] = [0.0] * slots
         self._slot_wait: List[float] = [0.0] * slots
         self._tok_host = np.zeros((slots,), np.int32)
+        # The dispatched program whose tail (fetch, emit, record) the
+        # serve loop has not landed yet (ISSUE 32), and the switch a test
+        # flips to hold the loop to the synchronous order. Not an option:
+        # which ticks look ahead follows from engine state alone.
+        self._tail: Optional[_Tail] = None
+        self._lookahead = True
 
         # Thread-safe control mailboxes (ISSUE 10): ingress handler
         # threads only ever touch these two under the control lock —
@@ -2215,8 +2289,11 @@ class SlotServer:
         ONE host→device transfer a table update costs (a few hundred
         int32s)."""
         if self._table_dirty:
+            # A copy: ``jnp.asarray`` may alias the numpy array on the CPU
+            # backend, and a program still in flight reads this table
+            # while a retire clears its rows for the next one.
             self.cache = dataclasses.replace(
-                self.cache, table=jnp.asarray(self._host_table)
+                self.cache, table=jnp.asarray(self._host_table.copy())
             )
             self._table_dirty = False
 
@@ -2642,6 +2719,89 @@ class SlotServer:
             plan.append((slot, n, pos + n == plen))
         return plan
 
+    def _planned_len(self, slot: int) -> int:
+        """Tokens ``slot`` has sampled, the one in flight included: what
+        the head plans with while a program's tail is pending (blocks to
+        map, the sample index of the next draw). Counts, never values:
+        the token itself stays in the device vector."""
+        n = len(self._slot_tokens[slot])
+        tail = self._tail
+        if tail is not None and tail.flying(slot, self._slot_req[slot]):
+            n += 1
+        return n
+
+    def _plan_tick(self) -> Tuple[List[Tuple[int, int, bool]], List[int],
+                                  Optional[str]]:
+        """What the next program holds and whether it may be dispatched
+        before the pending tail lands: the chunk plan, the slots that get
+        a decode row, and the reason the tick must be synchronous
+        (``None``: it can look ahead).
+
+        With a tail pending a slot's state is one tick old: a live slot
+        whose token in flight is its last gets no row (it retires when
+        the tail lands), and a slot whose final chunk is in flight
+        decodes already, its first token read from the device vector.
+        The synchronous ticks are the ones that need token VALUES on the
+        host before they can be planned, told from engine state alone:
+        draft-and-verify, token-tree and fork families (and a fork still
+        carried), staged int8 prefill, whole admission, and a tick with
+        nothing to dispatch."""
+        plan = (self._plan_chunks(max_n=32 if self._tree_fams else None)
+                if self.admission == "chunked" else [])
+        tail = self._tail
+        live_idx = []
+        for i, st in enumerate(self._slot_state):
+            req = self._slot_req[i]
+            if tail is not None and tail.flying(i, req):
+                # Live or awaiting its first token: a row unless the
+                # token in flight is the request's last.
+                if len(self._slot_tokens[i]) + 1 < req.max_new_tokens:
+                    live_idx.append(i)
+            elif st == "live":
+                live_idx.append(i)
+        if self._speculate:
+            why: Optional[str] = "spec"
+        elif self._tree_fams:
+            why = "tree"
+        elif self._families or self._live_reset or self._fork_carry:
+            why = "fork"
+        elif self._staged_prefill and plan:
+            why = "staged"
+        elif self.admission != "chunked":
+            why = "whole"
+        elif not plan and not live_idx:
+            why = "awaits"
+        else:
+            why = None
+        return plan, live_idx, why
+
+    def _tick_counts(self) -> Dict[str, Any]:
+        """The per-tick counters, as the flight record spells them."""
+        out: Dict[str, Any] = {
+            "prefix_hits": self._tick_prefix_hits,
+            "prefix_reused": self._tick_prefix_reused,
+            # Robustness arcs this tick (ISSUE 10): the black box must
+            # show a storm the way it showed a wedge.
+            "cancelled": self._tick_cancelled,
+            "deadline_expired": self._tick_deadline,
+            "shed": self._tick_shed,
+            # Copy-on-write forks this tick (ISSUE 15) and the ancestor
+            # blocks they shared instead of copying.
+            "forks": self._tick_forks,
+            "shared_blocks": self._tick_fork_shared,
+            # Token-tree sibling decode this tick (ISSUE 20): branches
+            # advanced in-slot, branches retired out of their bundles.
+            "tree_branches": self._tick_tree_branches,
+            "branch_retired": self._tick_branch_retired,
+        }
+        if self._host_pool is not None:
+            out["restored_blocks"] = self._tick_restored
+        if self._speculate:
+            s_slots, s_prop, s_acc = self._tick_spec
+            out["spec_verify"] = {"slots": s_slots, "proposed": s_prop,
+                                  "accepted": s_acc}
+        return out
+
     # -- copy-on-write forking (ISSUE 15) ---------------------------------
 
     def _admit_family(self, req: Request, parent_slot: int,
@@ -2896,6 +3056,10 @@ class SlotServer:
         for i, rq in enumerate(self._slot_req):
             if rq is None or rq.uid != uid:
                 continue
+            if self._tail is not None and self._tail.flying(i, rq):
+                return "wait"  # a token in flight: the branch point is
+                # not on the host yet; the carry makes the next tick
+                # synchronous and the fork applies there
             if self._slot_state[i] == "live":
                 if parent is None \
                         or self._slot_index[i] < self._slot_index[parent]:
@@ -3906,7 +4070,17 @@ class SlotServer:
         with the control sweep: mailboxed cancellations apply, expired
         deadlines shed their requests, and a requested drain stops
         admission and sheds the queue. ``max_ticks`` bounds runaway loops
-        (raises if work remains)."""
+        (raises if work remains).
+
+        The loop runs one program ahead (ISSUE 32): an iteration
+        dispatches its tick program first and only then lands the TAIL
+        (fetch, emit, account, flight record: ``land``) of the program
+        before it, so the host's part of a tick and the fetch's round
+        trip lie under the device's work. Ticks whose plan needs token
+        values on the host land the pending tail first and run in the
+        old order (:meth:`_plan_tick` says which, from engine state
+        alone); the tail is also landed before the loop idles, breaks
+        or raises."""
         live = isinstance(requests, RequestSource)
         if live:
             source: RequestSource = requests
@@ -3931,6 +4105,11 @@ class SlotServer:
         visible_wall: Dict[int, float] = {}
         tbt: Any = deque(maxlen=1 << 16) if live else []
         tick = 0
+        # A program went out since the loop last idled: what a tick that
+        # finds no tail pending gives as its ``sync_reason`` ("first"
+        # before any, "drain" once the tail before it landed early).
+        primed = False
+        self._tail = None  # a run that raised may have left one
         decode_ticks = 0
         occupancy = 0
         tokens = 0
@@ -3950,6 +4129,366 @@ class SlotServer:
         # on. Marks sit BETWEEN the mirror[...] regions below.
         phases = TickPhases()
 
+        def admit() -> None:
+            """Admit: oldest visible request per free slot. Chunked
+            admission is pure bookkeeping (the chunks run inside the
+            tick); the staged (quantized) variant holds one prompt in
+            flight at a time, so admission waits for the stage. Run
+            again in an iteration that had to land a pending tail
+            before planning: the retirements freed slots."""
+            phases.mark("admit")
+            free = self._free_slots()
+            while free and pending:
+                if self._staged_prefill and self._prefill_fifo:
+                    break
+                # An n>1 / best-of-n family admits ATOMICALLY: the
+                # parent's slot plus one fpend slot per sibling
+                # (FIFO — the family waits rather than skip-ahead),
+                # so two half-admitted families can never deadlock
+                # each other's slots.
+                branches = self._branches(pending[0])
+                # A tree-sibling family (ISSUE 20) needs ONE slot
+                # however many branches it decodes.
+                tree_adm = (branches > 1
+                            and self._tree_sibling_ok(pending[0]))
+                if (1 if tree_adm else branches) > len(free):
+                    break
+                # Worst-case block reservation (minus what a
+                # prefix hit shares). Failure DEFERS: the
+                # request stays queued — FIFO, no skip-ahead —
+                # until retires/evictions free blocks. This is
+                # what lets --slots exceed what the pool holds at
+                # full length instead of failing on a shape. The
+                # generation latch skips the O(prompt) re-match
+                # + O(tree) evictability recount on ticks where
+                # availability cannot have grown since the last
+                # failed attempt.
+                if self._defer_gen == self._pool.gen:
+                    break
+                resv = self._paged_reserve(pending[0])
+                if resv is None:
+                    self._defer_gen = self._pool.gen
+                    break
+                req = pending.popleft()
+                slot = free.pop(0)
+                vis = visible_wall.pop(req.uid, now)
+                if branches > 1:
+                    # The family exists BEFORE the admission runs:
+                    # whole-admission prefill stashes the family's
+                    # prompt-end logits synchronously inside _admit.
+                    if tree_adm:
+                        self._admit_tree_family(req, slot)
+                    else:
+                        self._admit_family(req, slot, free, resv)
+                self._admit(req, slot, tick, vis, resv)
+
+        # A landing in progress: the error arc must not land the other
+        # pending program over a half-emitted one.
+        landing = False
+        # What the iteration's landings fetched, for its span's tags.
+        span_sync = False
+        span_tokens = 0
+
+        def land(p: _Tail) -> float:
+            """The tail of program ``p``: fetch, emit, account, flight
+            record. Run at the end of ``p``'s own iteration by a
+            synchronous tick; one iteration later, after the next
+            program's dispatch, when the loop looks ahead; and before
+            the loop idles, breaks or raises. A slot whose request is no
+            longer the one ``p`` was dispatched for (cancelled, expired,
+            or retired on the EOS of the program before) is skipped: its
+            row was computed and is thrown away. That row's KV write
+            landed at the first unwritten position of a block that was
+            the slot's own when ``p``'s table was uploaded (the radix
+            tree only ever holds a prompt's FULL blocks,
+            ``_publish_prefix``), and whatever reuses the block is
+            dispatched after ``p``, so it overwrites before it reads.
+            Returns the moment the fetch came back: from then the
+            program dispatched behind ``p`` has the device."""
+            nonlocal tokens, decode_ticks, occupancy, landing
+            nonlocal span_sync, span_tokens
+            landing = True
+            host_sync = bool(p.awaits or p.live)
+            tokens_this_tick = 0
+            expert_rows = None
+            alltok_host = None
+            alllp_host = None
+            t_done = None
+            if host_sync:
+                # THE per-tick host sync: every new token of this
+                # program — decode samples, fused final-chunk first
+                # tokens, legacy insert first tokens — in one batched
+                # fetch. Only programs that produced a token pay it: a
+                # fused tick of nothing but mid-prompt chunks skips the
+                # fetch (like the staged path), letting consecutive
+                # chunks pipeline in the dispatch queue. A verify tick
+                # fetches its fused (S, 1+Tq) output instead: the token
+                # vector AND every row argmax in the same sync. When the
+                # loop looks ahead the NEXT program is already queued
+                # behind this one, so the device works through the
+                # round trip.
+                phases.mark("fetch")
+                if p.all_tok is not None:
+                    # lint: allow[host-sync] THE one per-tick fetch (verify ticks: token/logprob vectors + every row draw, one fused array)
+                    fused_host = np.asarray(p.all_tok)
+                    self._tok_host = fused_host[:, 0, 0]
+                    self._lp_host = np.ascontiguousarray(
+                        fused_host[:, 0, 1]
+                    ).view(np.float32)
+                    alltok_host = fused_host[:, 1:, 0]
+                    alllp_host = np.ascontiguousarray(
+                        fused_host[:, 1:, 1]
+                    ).view(np.float32)
+                elif p.fused is not None:
+                    # lint: allow[host-sync] THE one per-tick fetch (token vector + bitcast logprobs, one fused array)
+                    fh = np.asarray(p.fused)
+                    self._tok_host = fh[:self.slots, 0]
+                    self._lp_host = np.ascontiguousarray(
+                        fh[:self.slots, 1]
+                    ).view(np.float32)
+                    if self._expert_rows_shape is not None and (
+                            FLIGHT.enabled or obs.REGISTRY.enabled):
+                        expert_rows = self._account_expert_rows(
+                            fh[self.slots:])
+                else:
+                    # Awaits-only tick (a synchronous whole admission
+                    # parked tokens, nothing stepped): fetch the carried
+                    # vectors directly.
+                    # lint: allow[host-sync] THE one per-tick fetch (the batched token vector)
+                    self._tok_host = np.asarray(self.tok)
+                    # lint: allow[host-sync] rides the same sync point (the parked first-token logprobs)
+                    self._lp_host = np.asarray(self._lp)
+                phases.mark("emit")
+                now2 = t_done = time.monotonic()
+                if p.live:
+                    decode_ticks += 1
+                    occupancy += len(p.live)
+                for i in p.awaits:
+                    req = self._slot_req[i]
+                    if req is not p.reqs[i]:
+                        continue  # retired since: the row is thrown away
+                    first = int(self._tok_host[i])
+                    self._slot_tokens[i] = [first]
+                    self._slot_cum_lp[i] = float(self._lp_host[i])
+                    self._push_token(req, first, self._slot_index[i])
+                    self._slot_state[i] = "live"
+                    # Committed cache rows = the prompt; the first token
+                    # is the pending tip (spec mode's rollback ledger
+                    # starts here).
+                    self._slot_clen[i] = len(req.prompt)
+                    if self._speculate:
+                        hl = self._hist_len[i]
+                        self._hist_buf[i, hl] = first
+                        self._hist_len[i] = hl + 1
+                    _, vis = self._slot_admit[i]
+                    self._slot_ttft[i] = max(now2 - vis, 0.0)
+                    self._last_tok_t[i] = now2
+                    tokens += 1  # the prefill-sampled first token
+                    tokens_this_tick += 1
+                    self.slo.observe_ttft(self._slot_ttft[i])
+                    if obs.REGISTRY.enabled:
+                        _TOKENS.inc()  # the prefill's first token
+                        _TTFT.observe(self._slot_ttft[i])
+                    if obs.TRACER.active:
+                        obs.instant(
+                            "first_token", cat="serving", args={
+                                "rid": req.uid, "slot": i,
+                                "tick": p.tick,
+                                "ttft_s": round(self._slot_ttft[i], 6),
+                            })
+                    if obs.REQLOG.enabled:
+                        obs.REQLOG.first_token(req.uid, now=now2)
+                    # Family forks happen HERE — before the parent's
+                    # EOS/budget check, so even a one-token parent
+                    # yields n independent samples (each sibling
+                    # re-consumes the last prompt token and draws its
+                    # own first token under its own key).
+                    fam = self._families.get(req.uid)
+                    if fam is not None and not fam.forked \
+                            and i == fam.parent_slot:
+                        if fam.tree:
+                            # Tree-sibling start (ISSUE 20): every
+                            # branch's first token — branch 0's
+                            # EOS/budget included — is handled inside,
+                            # so the generic checks below must not run.
+                            n_new = self._tree_family_start(
+                                fam, i, first, p.tick, now2, results,
+                            )
+                            tokens += n_new
+                            tokens_this_tick += n_new
+                            continue
+                        n_new = self._fork_family(
+                            fam, i, p.tick, now2, results
+                        )
+                        tokens += n_new
+                        tokens_this_tick += n_new
+                    if req.eos_id is not None and first == req.eos_id:
+                        self._retire(i, p.tick, OUTCOME_EOS, results)
+                    elif req.max_new_tokens <= 1:
+                        self._retire(i, p.tick, OUTCOME_BUDGET, results)
+                if self._speculate:
+                    # Spec mode: live-slot tokens come from the verify
+                    # walk over the fetched row draws, 1..draft_k+1 of
+                    # them per slot per tick.
+                    if p.spec_plan:
+                        n_new = self._spec_commit_all(
+                            p.spec_plan, alltok_host, alllp_host,
+                            p.spec_width, now2, p.tick, results, tbt,
+                        )
+                        tokens += n_new
+                        tokens_this_tick += n_new
+                else:
+                    if p.tree_plan:
+                        # Tree mode: each live branch's token is its
+                        # last packed row's draw; retires shrink the
+                        # family the same tick.
+                        n_new = self._tree_commit_all(
+                            p.tree_plan, alltok_host, alllp_host,
+                            now2, p.tick, results, tbt,
+                        )
+                        tokens += n_new
+                        tokens_this_tick += n_new
+                    for i in p.live:
+                        if p.tree_plan and i in p.tree_plan:
+                            continue
+                        req = self._slot_req[i]
+                        if req is not p.reqs[i]:
+                            continue  # retired since: thrown away
+                        tok_i = int(self._tok_host[i])
+                        # Every live slot enters this loop with a first
+                        # token already emitted (the awaits pass of this
+                        # tail or the one before, or _fork_family for
+                        # siblings) — this is always an inter-token gap.
+                        self._slot_tokens[i].append(tok_i)
+                        self._slot_cum_lp[i] += float(self._lp_host[i])
+                        self._push_token(req, tok_i, self._slot_index[i])
+                        tokens += 1
+                        tokens_this_tick += 1
+                        gap = max(now2 - self._last_tok_t[i], 0.0)
+                        tbt.append(gap)
+                        self._last_tok_t[i] = now2
+                        if gap > self._slot_max_tbt[i]:
+                            self._slot_max_tbt[i] = gap
+                        self.slo.observe_tbt(gap)
+                        if obs.REGISTRY.enabled:
+                            _TOKENS.inc()
+                            _TBT.observe(gap)
+                        if (req.fork_at is not None
+                                and self._slot_index[i] == 0
+                                and len(self._slot_tokens[i])
+                                == req.fork_at):
+                            # Replayable mid-generation branch (trace
+                            # knob): the request forks itself through
+                            # the same mailbox an API caller would use.
+                            self.fork(req.uid)
+                        if req.eos_id is not None and tok_i == req.eos_id:
+                            self._retire(i, p.tick, OUTCOME_EOS, results)
+                        elif (len(self._slot_tokens[i])
+                                >= req.max_new_tokens):
+                            self._retire(i, p.tick, OUTCOME_BUDGET,
+                                         results)
+            if t_done is None:
+                t_done = time.monotonic()
+            span_sync = span_sync or host_sync
+            span_tokens += tokens_this_tick
+            if obs.TRACER.active:
+                tick_span.set(host_sync=span_sync, tokens=span_tokens)
+
+            phases.mark("account")
+            if self._pool.used > self._peak_blocks_used:
+                self._peak_blocks_used = self._pool.used
+            self._pool.publish_gauges()  # registry-guarded inside
+            if self._host_pool is not None:
+                if self._tail is None:
+                    # The staged D2H flush point: demotions this tick's
+                    # evictions enqueued complete as ONE batched gather,
+                    # after the tick's dispatches. Never behind a program
+                    # whose tail is still pending: the loop lands that
+                    # one first when something is staged.
+                    self._flush_demotions()
+                self._host_pool.publish_gauge()  # registry-guarded
+
+            # The flight recorder's per-tick record (the black box a
+            # post-mortem replays); record dict built only when armed.
+            # It describes ONE program, ``p``: what it ran as and held
+            # comes from the tail, the slots' states and the pool are the
+            # engine's as the record closes, ``phases``/``t_end`` are the
+            # stamps of the iteration that closes it.
+            if FLIGHT.enabled:
+                rec = {
+                    "tick": p.tick,
+                    # The instant the device could start this program:
+                    # the tick's top, or the landing of the program it
+                    # was dispatched behind. A tick lasts to the next
+                    # record's stamp (benchmark/ticks.py).
+                    "t_s": round(p.t_s - t0, 6),
+                    # What the tick program ran as: its kind, the Tq
+                    # bucket (0: nothing dispatched; a staged tick's is
+                    # its decode program's), the members of its chunk
+                    # group (0: a padded program), the rows it computed
+                    # (a packed tick: the chunk group's and one a slot)
+                    # and those that carried a token.
+                    "kind": p.kind,
+                    "tq": p.tq,
+                    "chunk_group": p.group,
+                    "rows_computed": (
+                        p.group * p.tq + self.slots
+                        if p.group else self.slots * p.tq),
+                    "rows_useful": p.rows_useful,
+                    # Dispatched before the tail of the program before
+                    # it landed (ISSUE 32), or why not.
+                    "ahead": p.ahead,
+                    "occupancy": len(p.live),
+                    "states": list(self._slot_state),
+                    "lengths": [self._prefill_pos[i]
+                                if self._slot_state[i] == "prefill"
+                                else len(self._slot_tokens[i])
+                                for i in range(self.slots)],
+                    "chunk_plan": [[s, int(n), bool(last)]
+                                   for s, n, last in p.plan],
+                    "chunk_tokens": p.chunk_tokens,
+                    "tokens_emitted": tokens_this_tick,
+                    "host_sync": host_sync,
+                    "queue_depth": p.queue_depth,
+                    "pending": p.pending,
+                    "draining": p.draining,
+                }
+                if not p.ahead:
+                    rec["sync_reason"] = p.why
+                rec.update(p.counts if p.counts is not None
+                           else self._tick_counts())
+                # Block occupancy + internal fragmentation (the
+                # fraction of mapped block capacity no written
+                # token occupies) — the paged black-box truths.
+                mapped = sum(self._slot_nblocks)
+                written = 0
+                for i in range(self.slots):
+                    st = self._slot_state[i]
+                    if st == "prefill":
+                        written += self._prefill_pos[i]
+                    elif st in ("await", "live"):
+                        written += (
+                            len(self._slot_req[i].prompt)
+                            + max(len(self._slot_tokens[i]) - 1, 0)
+                        )
+                rec["kv_blocks_used"] = self._pool.used
+                rec["kv_blocks_free"] = self._pool.free_count
+                rec["kv_frag"] = round(
+                    1.0 - written / (mapped * self.kv_block), 4
+                ) if mapped else 0.0
+                if self._host_pool is not None:
+                    rec["host_blocks_used"] = self._host_pool.used
+                if expert_rows is not None:
+                    rec.update(expert_rows)
+                # finish() stamps t_end as the record is built
+                # and puts the iteration's phases into it.
+                phases.finish(rec)
+                FLIGHT.record(rec)
+            else:
+                phases.finish(None)  # the span trace alone
+            landing = False
+            return t_done
+
         try:
             while True:
                 if max_ticks is not None and tick >= max_ticks:
@@ -3960,6 +4499,7 @@ class SlotServer:
                 # Created at the tick's top so that every phase nests in
                 # it; an iteration that executes no tick drops it unsaid.
                 tick_span = obs.span("serving:tick", cat="serving")
+                span_sync, span_tokens = False, 0
                 now = time.monotonic()
                 phases.begin(now)  # opens 'ingest'
                 self._tick_prefix_hits = 0
@@ -4087,55 +4627,33 @@ class SlotServer:
                     self._apply_forks(forks, tick, pending)
                 # lint: mirror[fork] end
 
-                # Admit: oldest visible request per free slot. Chunked
-                # admission is pure bookkeeping (the chunks run inside the
-                # tick); the staged (quantized) variant holds one prompt in
-                # flight at a time, so admission waits for the stage.
-                phases.mark("admit")
-                free = self._free_slots()
-                while free and pending:
-                    if self._staged_prefill and self._prefill_fifo:
-                        break
-                    # An n>1 / best-of-n family admits ATOMICALLY: the
-                    # parent's slot plus one fpend slot per sibling
-                    # (FIFO — the family waits rather than skip-ahead),
-                    # so two half-admitted families can never deadlock
-                    # each other's slots.
-                    branches = self._branches(pending[0])
-                    # A tree-sibling family (ISSUE 20) needs ONE slot
-                    # however many branches it decodes.
-                    tree_adm = (branches > 1
-                                and self._tree_sibling_ok(pending[0]))
-                    if (1 if tree_adm else branches) > len(free):
-                        break
-                    # Worst-case block reservation (minus what a
-                    # prefix hit shares). Failure DEFERS: the
-                    # request stays queued — FIFO, no skip-ahead —
-                    # until retires/evictions free blocks. This is
-                    # what lets --slots exceed what the pool holds at
-                    # full length instead of failing on a shape. The
-                    # generation latch skips the O(prompt) re-match
-                    # + O(tree) evictability recount on ticks where
-                    # availability cannot have grown since the last
-                    # failed attempt.
-                    if self._defer_gen == self._pool.gen:
-                        break
-                    resv = self._paged_reserve(pending[0])
-                    if resv is None:
-                        self._defer_gen = self._pool.gen
-                        break
-                    req = pending.popleft()
-                    slot = free.pop(0)
-                    vis = visible_wall.pop(req.uid, now)
-                    if branches > 1:
-                        # The family exists BEFORE the admission runs:
-                        # whole-admission prefill stashes the family's
-                        # prompt-end logits synchronously inside _admit.
-                        if tree_adm:
-                            self._admit_tree_family(req, slot)
-                        else:
-                            self._admit_family(req, slot, free, resv)
-                    self._admit(req, slot, tick, vis, resv)
+                admit()
+                # What the next program holds, and whether it can go out
+                # before the pending tail lands (ISSUE 32). A tick that
+                # cannot look ahead (its plan needs token values on the
+                # host, or there is nothing to dispatch: every row in
+                # flight was its request's last) first lands the pending
+                # tail in a span of its own, then runs as a synchronous
+                # tick: it admits into the slots the landing freed, plans
+                # again from what the host now knows, and idles below if
+                # that is nothing.
+                plan, live_idx, why = self._plan_tick()
+                if self._tail is not None and why is not None:
+                    prev, self._tail = self._tail, None
+                    if obs.TRACER.active:
+                        tick_span.set(
+                            tick=prev.tick, drain=True,
+                            occupancy=len(prev.live), prefilling=0,
+                            chunk_tokens=prev.chunk_tokens,
+                            queue_depth=prev.queue_depth,
+                        )
+                    with tick_span:
+                        land(prev)
+                    tick_span = obs.span("serving:tick", cat="serving")
+                    span_sync, span_tokens = False, 0
+                    phases.begin(time.monotonic(), "admit")
+                    admit()
+                    plan, live_idx, why = self._plan_tick()
                 queue_depth = len(pending)  # visible but still unadmitted
 
                 if not pending and all(st == "free"
@@ -4148,6 +4666,7 @@ class SlotServer:
                     # stalled one) and block briefly for submissions
                     # (wakes early on submit/close).
                     phases.abandon()
+                    primed = False
                     if FLIGHT.enabled:
                         rec = None
                         # lint: mirror[sweep-only] begin
@@ -4184,22 +4703,22 @@ class SlotServer:
                     continue
                     # lint: mirror[idle] end
 
-                # Plan this tick's prefill chunks (chunked admission
-                # only). While a tree family decodes, chunks clamp to
-                # the int32 tree-bitmask width — the sibling bundle
-                # must never be forced onto a Tq > 32 program.
+                # The tick's prefill chunks (chunked admission only) and
+                # its decode rows were settled by :meth:`_plan_tick`,
+                # above the idle check: ``live_idx`` is the slots that
+                # get a decode row, a token in flight counted.
                 phases.mark("plan")
-                plan = (self._plan_chunks(
-                            max_n=32 if self._tree_fams else None)
-                        if self.admission == "chunked" else [])
                 chunk_tokens = sum(n for _, n, _ in plan)
                 # The staged path rebinds ``plan`` to []; keep the tick's
                 # real chunk plan reachable for the flight record (a
                 # reference, not a copy — free when the recorder is off).
                 plan_rec = plan
-                live_idx = [i for i, st in enumerate(self._slot_state)
-                            if st == "live"]
+                # Dispatched before the tail of the program before it
+                # lands: the look-ahead, counted where it engages.
+                ahead = self._tail is not None
                 if obs.REGISTRY.enabled:
+                    if ahead:
+                        _TICKS_AHEAD.inc()
                     _SLOTS_OCCUPIED.set(len(live_idx))
                     _TREE_BRANCHES.set(sum(
                         sum(f.br_live) for f in self._tree_fams.values()
@@ -4208,14 +4727,17 @@ class SlotServer:
 
                 # The per-tick span: occupancy, chunk-budget spent, and
                 # queue depth tagged on the one program the tick
-                # dispatches (host_sync set before close). It closes after
-                # the flight record, so it covers the tick's every phase.
+                # dispatches; ``host_sync``/``tokens`` are what the
+                # iteration fetched and emitted (the program before it
+                # when the loop looks ahead), set before close. It covers
+                # the iteration's every phase.
                 if obs.TRACER.active:
                     tick_span.set(
                         tick=tick, occupancy=len(live_idx),
                         prefilling=len(self._prefill_fifo),
                         chunk_tokens=chunk_tokens,
-                        queue_depth=queue_depth,
+                        queue_depth=queue_depth, ahead=ahead,
+                        host_sync=False, tokens=0,
                     )
                 with tick_span:
                     ran_staged = False
@@ -4387,7 +4909,8 @@ class SlotServer:
                             emit[slot] = last
                         phases.mark("table_sync")
                         self._sync_table()
-                        phases.mark("dispatch", tick, tick_kind, tick_tq)
+                        phases.mark("dispatch", tick, tick_kind, tick_tq,
+                                    ahead)
                         args = (
                             self.params, jnp.asarray(mat), self.tok,
                             jnp.asarray(use_dev0), jnp.asarray(n_vec),
@@ -4456,17 +4979,21 @@ class SlotServer:
                         phases.mark("pack")
                         tick_kind, tick_tq = "mixed", tq
                         tick_group = self._chunk_group
-                        dec_tok = np.zeros((self.slots,), np.int32)
                         n_vec = np.zeros((self.slots,), np.int32)
                         reset = np.zeros((self.slots,), bool)
                         reset_val = np.zeros((self.slots,), np.int32)
                         emit = np.zeros((self.slots,), bool)
+                        # Sample indices: tokens sampled so far, the
+                        # one in flight counted (they also map the blocks).
+                        sidx = np.asarray(
+                            [self._planned_len(i)
+                             for i in range(self.slots)], np.int32
+                        )
                         for i in live_idx:
                             self._ensure_blocks(
                                 i, len(self._slot_req[i].prompt)
-                                + len(self._slot_tokens[i])
+                                + int(sidx[i])
                             )
-                            dec_tok[i] = self._tok_host[i]
                             n_vec[i] = 1
                             emit[i] = True
                         # Freshly forked children (ISSUE 15): their one
@@ -4481,22 +5008,26 @@ class SlotServer:
                         chunk_tok, chunk_slot, chunk_n = \
                             self._pack_chunk_group(plan, tq, reset,
                                                    reset_val, emit)
-                        sidx = np.asarray(
-                            [len(t) for t in self._slot_tokens], np.int32
-                        )
                         phases.mark("table_sync")
                         self._sync_table()
-                        phases.mark("dispatch", tick, tick_kind, tick_tq)
+                        phases.mark("dispatch", tick, tick_kind, tick_tq,
+                                    ahead)
+                        # The decode rows' tokens are the device vector:
+                        # a row may consume a token the host has not
+                        # fetched yet (ISSUE 32). The per-request vectors
+                        # go up as copies (see _sync_table): an admission
+                        # writes them while this program may be queued.
                         self.tok, self._lp, fused_dev, last_dev, \
                             self.cache = self._packed(
                                 self.params, jnp.asarray(chunk_tok),
                                 jnp.asarray(chunk_slot),
-                                jnp.asarray(chunk_n), jnp.asarray(dec_tok),
+                                jnp.asarray(chunk_n), self.tok,
                                 jnp.asarray(n_vec), jnp.asarray(reset),
                                 jnp.asarray(reset_val),
                                 jnp.asarray(emit), self.cache,
-                                self._keys, jnp.asarray(self._temp_np),
-                                jnp.asarray(self._topk_np),
+                                self._keys,
+                                jnp.asarray(self._temp_np.copy()),
+                                jnp.asarray(self._topk_np.copy()),
                                 jnp.asarray(sidx), self._lp,
                             )
                         phases.mark("publish")
@@ -4537,17 +5068,19 @@ class SlotServer:
                             if self._slot_state[i] == "live":
                                 reset[i] = True
                                 reset_val[i] = self._live_reset.pop(i)
+                        sidx = np.asarray(
+                            [self._planned_len(i)
+                             for i in range(self.slots)], np.int32
+                        )
                         for i in live_idx:
                             self._ensure_blocks(
                                 i, len(self._slot_req[i].prompt)
-                                + len(self._slot_tokens[i])
+                                + int(sidx[i])
                             )
-                        sidx = np.asarray(
-                            [len(t) for t in self._slot_tokens], np.int32
-                        )
                         phases.mark("table_sync")
                         self._sync_table()
-                        phases.mark("dispatch", tick, tick_kind, tick_tq)
+                        phases.mark("dispatch", tick, tick_kind, tick_tq,
+                                    ahead)
                         self.tok, self._lp, fused_dev, _, \
                             self.cache = self._mixed(
                                 self.params, self.tok[:, None],
@@ -4555,319 +5088,65 @@ class SlotServer:
                                 jnp.asarray(reset),
                                 jnp.asarray(reset_val),
                                 jnp.asarray(emit), self.cache,
-                                self._keys, jnp.asarray(self._temp_np),
-                                jnp.asarray(self._topk_np),
+                                self._keys,
+                                jnp.asarray(self._temp_np.copy()),
+                                jnp.asarray(self._topk_np.copy()),
                                 jnp.asarray(sidx), self._lp,
                             )
                         phases.mark("publish")
                         stepped = True
 
-                    awaits = [i for i, st in enumerate(self._slot_state)
-                              if st == "await"]
-                    host_sync = bool(awaits or live_idx)
-                    tokens_this_tick = 0
-                    expert_rows = None
-                    alltok_host = None
-                    alllp_host = None
-                    if host_sync:
-                        # THE per-tick host sync: every new token of this
-                        # tick — decode samples, fused final-chunk first
-                        # tokens, legacy insert first tokens — in one
-                        # batched fetch. Only ticks that produced a token
-                        # pay it: a fused tick of nothing but mid-prompt
-                        # chunks skips the fetch (like the staged path
-                        # below), letting consecutive chunks pipeline in
-                        # the dispatch queue. A live slot always enters
-                        # its tick with a fresh ``_tok_host`` — it went
-                        # live inside this block. A verify tick fetches
-                        # its fused (S, 1+Tq) output instead: the token
-                        # vector AND every row argmax in the same sync.
-                        phases.mark("fetch")
-                        lp_valid = False
-                        if all_tok_dev is not None:
-                            # lint: allow[host-sync] THE one per-tick fetch (verify ticks: token/logprob vectors + every row draw, one fused array)
-                            fused_host = np.asarray(all_tok_dev)
-                            self._tok_host = fused_host[:, 0, 0]
-                            self._lp_host = np.ascontiguousarray(
-                                fused_host[:, 0, 1]
-                            ).view(np.float32)
-                            alltok_host = fused_host[:, 1:, 0]
-                            alllp_host = np.ascontiguousarray(
-                                fused_host[:, 1:, 1]
-                            ).view(np.float32)
-                            lp_valid = True
-                        elif fused_dev is not None:
-                            # lint: allow[host-sync] THE one per-tick fetch (token vector + bitcast logprobs, one fused array)
-                            fh = np.asarray(fused_dev)
-                            self._tok_host = fh[:self.slots, 0]
-                            self._lp_host = np.ascontiguousarray(
-                                fh[:self.slots, 1]
-                            ).view(np.float32)
-                            lp_valid = True
-                            if self._expert_rows_shape is not None and (
-                                    FLIGHT.enabled or obs.REGISTRY.enabled):
-                                expert_rows = self._account_expert_rows(
-                                    fh[self.slots:])
-                        else:
-                            # Awaits-only tick (a synchronous whole
-                            # admission parked tokens, nothing stepped):
-                            # fetch the carried vectors directly.
-                            # lint: allow[host-sync] THE one per-tick fetch (the batched token vector)
-                            self._tok_host = np.asarray(self.tok)
-                            # lint: allow[host-sync] rides the same sync point (the parked first-token logprobs)
-                            self._lp_host = np.asarray(self._lp)
-                            lp_valid = True
-                        phases.mark("emit")
-                        now2 = time.monotonic()
-                        if live_idx:
-                            decode_ticks += 1
-                            occupancy += len(live_idx)
-                        for i in awaits:
-                            req = self._slot_req[i]
-                            first = int(self._tok_host[i])
-                            self._slot_tokens[i] = [first]
-                            if lp_valid:
-                                self._slot_cum_lp[i] = float(
-                                    self._lp_host[i]
-                                )
-                            self._push_token(req, first,
-                                             self._slot_index[i])
-                            self._slot_state[i] = "live"
-                            # Committed cache rows = the prompt; the
-                            # first token is the pending tip (spec mode's
-                            # rollback ledger starts here).
-                            self._slot_clen[i] = len(req.prompt)
-                            if self._speculate:
-                                hl = self._hist_len[i]
-                                self._hist_buf[i, hl] = first
-                                self._hist_len[i] = hl + 1
-                            _, vis = self._slot_admit[i]
-                            self._slot_ttft[i] = max(now2 - vis, 0.0)
-                            self._last_tok_t[i] = now2
-                            tokens += 1  # the prefill-sampled first token
-                            tokens_this_tick += 1
-                            self.slo.observe_ttft(self._slot_ttft[i])
-                            if obs.REGISTRY.enabled:
-                                _TOKENS.inc()  # the prefill's first token
-                                _TTFT.observe(self._slot_ttft[i])
-                            if obs.TRACER.active:
-                                obs.instant(
-                                    "first_token", cat="serving", args={
-                                        "rid": req.uid, "slot": i,
-                                        "tick": tick,
-                                        "ttft_s": round(
-                                            self._slot_ttft[i], 6),
-                                    })
-                            if obs.REQLOG.enabled:
-                                obs.REQLOG.first_token(req.uid, now=now2)
-                            # Family forks happen HERE — before the
-                            # parent's EOS/budget check, so even a
-                            # one-token parent yields n independent
-                            # samples (each sibling re-consumes the
-                            # last prompt token and draws its own
-                            # first token under its own key).
-                            fam = self._families.get(req.uid)
-                            if fam is not None and not fam.forked \
-                                    and i == fam.parent_slot:
-                                if fam.tree:
-                                    # Tree-sibling start (ISSUE 20):
-                                    # every branch's first token —
-                                    # branch 0's EOS/budget included —
-                                    # is handled inside, so the generic
-                                    # checks below must not run.
-                                    n_new = self._tree_family_start(
-                                        fam, i, first, tick, now2,
-                                        results,
-                                    )
-                                    tokens += n_new
-                                    tokens_this_tick += n_new
-                                    continue
-                                n_new = self._fork_family(
-                                    fam, i, tick, now2, results
-                                )
-                                tokens += n_new
-                                tokens_this_tick += n_new
-                            if req.eos_id is not None \
-                                    and first == req.eos_id:
-                                self._retire(i, tick, OUTCOME_EOS, results)
-                            elif req.max_new_tokens <= 1:
-                                self._retire(i, tick, OUTCOME_BUDGET,
-                                             results)
-                        if self._speculate:
-                            # Spec mode: live-slot tokens come from the
-                            # verify walk over the fetched row draws,
-                            # 1..draft_k+1 of them per slot per tick.
-                            if spec_plan:
-                                n_new = self._spec_commit_all(
-                                    spec_plan, alltok_host, alllp_host,
-                                    spec_width, now2, tick, results, tbt,
-                                )
-                                tokens += n_new
-                                tokens_this_tick += n_new
-                        else:
-                            if tree_plan:
-                                # Tree mode: each live branch's token is
-                                # its last packed row's draw; retires
-                                # shrink the family the same tick.
-                                n_new = self._tree_commit_all(
-                                    tree_plan, alltok_host, alllp_host,
-                                    now2, tick, results, tbt,
-                                )
-                                tokens += n_new
-                                tokens_this_tick += n_new
-                            for i in live_idx:
-                                if i in tree_plan:
-                                    continue
-                                req = self._slot_req[i]
-                                tok_i = int(self._tok_host[i])
-                                # Every live slot enters this loop with
-                                # a first token already emitted (awaits
-                                # pass, or _fork_family for siblings) —
-                                # this is always an inter-token gap.
-                                self._slot_tokens[i].append(tok_i)
-                                if lp_valid:
-                                    self._slot_cum_lp[i] += float(
-                                        self._lp_host[i]
-                                    )
-                                self._push_token(req, tok_i,
-                                                 self._slot_index[i])
-                                tokens += 1
-                                tokens_this_tick += 1
-                                gap = max(now2 - self._last_tok_t[i], 0.0)
-                                tbt.append(gap)
-                                self._last_tok_t[i] = now2
-                                if gap > self._slot_max_tbt[i]:
-                                    self._slot_max_tbt[i] = gap
-                                self.slo.observe_tbt(gap)
-                                if obs.REGISTRY.enabled:
-                                    _TOKENS.inc()
-                                    _TBT.observe(gap)
-                                if (req.fork_at is not None
-                                        and self._slot_index[i] == 0
-                                        and len(self._slot_tokens[i])
-                                        == req.fork_at):
-                                    # Replayable mid-generation branch
-                                    # (trace knob): the request forks
-                                    # itself through the same mailbox
-                                    # an API caller would use.
-                                    self.fork(req.uid)
-                                if req.eos_id is not None \
-                                        and tok_i == req.eos_id:
-                                    self._retire(i, tick, OUTCOME_EOS,
-                                                 results)
-                                elif (len(self._slot_tokens[i])
-                                        >= req.max_new_tokens):
-                                    self._retire(i, tick, OUTCOME_BUDGET,
-                                                 results)
-                    if obs.TRACER.active:
-                        tick_span.set(host_sync=host_sync,
-                                      tokens=tokens_this_tick)
-
-                    phases.mark("account")
-                    if self._pool.used > self._peak_blocks_used:
-                        self._peak_blocks_used = self._pool.used
-                    self._pool.publish_gauges()  # registry-guarded inside
-                    if self._host_pool is not None:
-                        # The staged D2H flush point: demotions this tick's
-                        # evictions enqueued complete as ONE batched gather,
-                        # after the tick's dispatches (the fetch overlaps
-                        # where the loop would otherwise idle toward the
-                        # next tick's host work).
-                        self._flush_demotions()
-                        self._host_pool.publish_gauge()  # registry-guarded
-
-                    # The flight recorder's per-tick record (the black box a
-                    # post-mortem replays); record dict built only when armed.
-                    if FLIGHT.enabled:
-                        rec = {
-                            "tick": tick,
-                            "t_s": round(now - t0, 6),
-                            # What the tick program ran as: its kind, the
-                            # Tq bucket (0: nothing dispatched; a staged
-                            # tick's is its decode program's), the members
-                            # of its chunk group (0: a padded program), the
-                            # rows it computed (a packed tick: the chunk
-                            # group's and one a slot) and those that
-                            # carried a token.
-                            "kind": tick_kind,
-                            "tq": tick_tq,
-                            "chunk_group": tick_group,
-                            "rows_computed": (
-                                tick_group * tick_tq + self.slots
-                                if tick_group else self.slots * tick_tq),
-                            "rows_useful": (
-                                0 if n_vec is None else int(n_vec.sum())
-                                + (chunk_tokens if tick_group else 0)),
-                            "occupancy": len(live_idx),
-                            "states": list(self._slot_state),
-                            "lengths": [self._prefill_pos[i]
-                                        if self._slot_state[i] == "prefill"
-                                        else len(self._slot_tokens[i])
-                                        for i in range(self.slots)],
-                            "chunk_plan": [[s, int(n), bool(last)]
-                                           for s, n, last in plan_rec],
-                            "chunk_tokens": chunk_tokens,
-                            "tokens_emitted": tokens_this_tick,
-                            "host_sync": host_sync,
-                            "queue_depth": queue_depth,
-                            "pending": len(pending),
-                            "prefix_hits": self._tick_prefix_hits,
-                            "prefix_reused": self._tick_prefix_reused,
-                            # Robustness arcs this tick (ISSUE 10): the
-                            # black box must show a storm the way it showed
-                            # a wedge.
-                            "cancelled": self._tick_cancelled,
-                            "deadline_expired": self._tick_deadline,
-                            "shed": self._tick_shed,
-                            # Copy-on-write forks this tick (ISSUE 15) and
-                            # the ancestor blocks they shared instead of
-                            # copying.
-                            "forks": self._tick_forks,
-                            "shared_blocks": self._tick_fork_shared,
-                            # Token-tree sibling decode this tick (ISSUE
-                            # 20): branches advanced in-slot, branches
-                            # retired out of their bundles.
-                            "tree_branches": self._tick_tree_branches,
-                            "branch_retired": self._tick_branch_retired,
-                            "draining": draining,
-                        }
-                        # Block occupancy + internal fragmentation (the
-                        # fraction of mapped block capacity no written
-                        # token occupies) — the paged black-box truths.
-                        mapped = sum(self._slot_nblocks)
-                        written = 0
-                        for i in range(self.slots):
-                            st = self._slot_state[i]
-                            if st == "prefill":
-                                written += self._prefill_pos[i]
-                            elif st in ("await", "live"):
-                                written += (
-                                    len(self._slot_req[i].prompt)
-                                    + max(len(self._slot_tokens[i]) - 1, 0)
-                                )
-                        rec["kv_blocks_used"] = self._pool.used
-                        rec["kv_blocks_free"] = self._pool.free_count
-                        rec["kv_frag"] = round(
-                            1.0 - written / (mapped * self.kv_block), 4
-                        ) if mapped else 0.0
-                        if self._host_pool is not None:
-                            rec["host_blocks_used"] = self._host_pool.used
-                            rec["restored_blocks"] = self._tick_restored
-                        if expert_rows is not None:
-                            rec.update(expert_rows)
-                        if self._speculate:
-                            s_slots, s_prop, s_acc = self._tick_spec
-                            rec["spec_verify"] = {
-                                "slots": s_slots,
-                                "proposed": s_prop,
-                                "accepted": s_acc,
-                            }
-                        # finish() stamps t_end as the record is built
-                        # and puts the tick's phases into it.
-                        phases.finish(rec)
-                        FLIGHT.record(rec)
+                    # The program's tail, to be landed: what it ran as,
+                    # the slots it holds a row for, and the request each
+                    # ran for. Awaiting slots whose first token rode the
+                    # PENDING program belong to that tail, not this one.
+                    prev = self._tail
+                    cur = _Tail(
+                        tick=tick, t_s=now, ahead=ahead,
+                        why=(None if ahead
+                             else why or ("drain" if primed else "first")),
+                        kind=tick_kind, tq=tick_tq, group=tick_group,
+                        rows_useful=(
+                            0 if n_vec is None else int(n_vec.sum())
+                            + (chunk_tokens if tick_group else 0)),
+                        chunk_tokens=chunk_tokens, plan=plan_rec,
+                        live=live_idx,
+                        awaits=[
+                            i for i, st in enumerate(self._slot_state)
+                            if st == "await" and not (
+                                ahead
+                                and prev.flying(i, self._slot_req[i]))
+                        ],
+                        reqs=list(self._slot_req),
+                        queue_depth=queue_depth, pending=len(pending),
+                        draining=draining,
+                        fused=fused_dev, all_tok=all_tok_dev,
+                        spec_plan=spec_plan, tree_plan=tree_plan,
+                        spec_width=spec_width,
+                    )
+                    primed = True
+                    self._tail = cur
+                    if ahead:
+                        # THE look-ahead: this program is queued behind
+                        # the one before it, and only now does the host
+                        # wait for that one's tokens. From the moment
+                        # they are back the device is this program's.
+                        cur.t_s = land(prev)
+                    if (why is not None or not self._lookahead
+                            or (self._host_pool is not None
+                                and self._host_pool.pending)):
+                        # A synchronous tick lands its own tail as it
+                        # ends; so does one that staged demotions, since
+                        # their flush fetches behind every dispatch.
+                        self._tail = None
+                        land(cur)
                     else:
-                        phases.finish(None)  # the span trace alone
+                        # Left pending for the next iteration; the head's
+                        # counters freeze with it.
+                        cur.counts = self._tick_counts()
+                        if not ahead:
+                            phases.finish(None)  # nothing landed: the
+                            # stamps go to the span trace alone
                 self.slo.maybe_export(now)
 
                 # Every executed tick advances the clock by exactly one;
@@ -4877,7 +5156,16 @@ class SlotServer:
                 tick += 1
         except BaseException as e:
             # The black-box contract: a wedged/crashed tick loop leaves
-            # its last ticks on disk before the exception propagates.
+            # its last ticks on disk before the exception propagates,
+            # the program still in flight among them: its tail lands
+            # first, unless the error came out of a landing.
+            last, self._tail = self._tail, None
+            if last is not None and not landing:
+                try:
+                    land(last)
+                except Exception:
+                    log.exception("could not land tick %d's tail after "
+                                  "%s", last.tick, type(e).__name__)
             phases.abandon()
             FLIGHT.dump_if_armed(f"engine_error:{type(e).__name__}")
             if obs.TRACER.active:
