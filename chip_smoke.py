@@ -33,6 +33,11 @@ Phases of the default run, one JSON object per line on stdout:
   programs   f  the compiled tick programs and the train step contain
                 ``tpu_custom_call`` (run after ``train`` so its compile is
                 a cache hit); the dispatch counters are printed beside
+  hybrid     i  the second kind of model the repo serves whole: the conv /
+                attention hybrid of ``benchmark/configs/lfm2-8b-a1b.json`` at
+                its published widths through ``serve`` (K/V rows beside
+                conv tails in one pool), with a prefix hit and a fork,
+                its served tokens held to the plain reference's logits
   times      h  wall time per phase and compile-cache traffic — set-up
                 information only; nothing here is a rate or a benchmark
 
@@ -1126,6 +1131,102 @@ def phase_fleet_placement(s: Sizes) -> Dict[str, Any]:
 
 
 # ---------------------------------------------------------------------------
+# hybrid: conv tails beside K/V rows, through serve, against the reference
+# ---------------------------------------------------------------------------
+
+
+def phase_hybrid(s: Sizes, config: Optional[Dict[str, Any]] = None, *,
+                 device: str = "tpu", block: int = 64, chunk: int = 256,
+                 tol_gap: float = 0.4) -> Dict[str, Any]:
+    """The conv / attention hybrid (``benchmark/configs/lfm2-8b-a1b.json``:
+    9 conv + 3 attention layers, 2 dense FFNs, 10 expert layers, at their
+    published widths) served on one chip through ``cli.build_serve_engine``
+    and ``SlotServer.serve`` with the reference's seeded weights: a cold
+    request; after it retired, one that shares its first four blocks (a
+    prefix hit: the conv state comes from a published block's tail) and a
+    family of two forked inside a block (the copy carries the tail). Every
+    served token is held to the plain reference's logits
+    (``benchmark/references/lfm2_moe.py``, the full forward pass, no
+    cache): a token's gap is the reference's best logit at its position
+    less the reference's logit for the token served there, and every
+    branch's MEAN gap lies under ``tol_gap``. Not its largest: bf16 through
+    12 layers leaves the logits 0.13-0.18 rms off the float32 reference on
+    logits of std 0.9, so a near-tied choice flips now and then and one
+    token of a sound run reads 0.4-1.2 (0.445 of 48 tokens on the chip, PR
+    33), while a branch's mean over its 12 tokens read 0.02-0.07; a stale
+    tail or a wrong block leaves every later token at the logits' own
+    spread, a mean of 1-3."""
+    import numpy as np
+
+    from benchmark import check as served
+    from benchmark.spec import Spec
+    from tree_attention_tpu import cli
+    from tree_attention_tpu.serving.engine import Request
+    from tree_attention_tpu.utils.config import parse_args
+
+    # The family's two files, found as the harness finds them.
+    spec = Spec(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                             "BENCHMARK.json"))
+    if config is None:
+        config = spec.load_json("configs", "lfm2-8b-a1b.json")
+    ref = spec.load_module("references", config["family"] + ".py")
+    adapter = spec.load_module("adapters", config["family"] + ".py")
+    w = ref.Widths.of(config)
+    weights = ref.init_weights(3, w)
+    rng = np.random.default_rng(17)
+    vocab = int(config["vocab_size"])
+    a = rng.integers(0, vocab, (5 * block + 7,)).tolist()
+    b = a[:4 * block] + rng.integers(0, vocab, (block // 2 + 3,)).tolist()
+    c = a[:3 * block + 20]
+    new = 12
+    flags = ["--mode", "serve", "--device", device,
+             "--dtype", str(config["torch_dtype"]), "--slots", "4",
+             "--prompt-len", str(len(a)), "--prompt-jitter", "0",
+             "--max-new-tokens", str(new), "--prefill-chunk", str(chunk),
+             "--prefix-cache", "--prefix-block", str(block),
+             "--temperature", "0", "--seed", "1"]
+    setup = cli.build_serve_engine(
+        parse_args(flags), None, model=config,
+        params=adapter.engine_params(weights, w))
+    check(setup.tcfg.cache_kind == "hybrid", "the model caches hybrid state")
+    server = setup.make_engine()
+    first = server.serve([Request(uid=0, prompt=a, max_new_tokens=new)])
+    second = server.serve([
+        Request(uid=1, prompt=b, max_new_tokens=new),
+        Request(uid=2, prompt=c, max_new_tokens=new, n=2)])
+    results = list(first.results) + list(second.results)
+    prompts = {0: a, 1: b, 2: c}
+    check(len(results) == 4 and all(
+        r.outcome == "budget" and len(r.tokens) == new for r in results),
+        "four branches served to their budgets")
+    hit = [r for r in results if r.uid == 1][0].prefix_hit_tokens
+    check(hit == 4 * block, f"a prefix hit of four blocks (got {hit})")
+    check(second.kv.get("forks") == 1, f"one fork (kv: {second.kv})")
+    twins = [r.tokens for r in results if r.uid == 2]
+    check(twins[0] == twins[1], "the greedy twins agree")
+    leak = server.leak_report()
+    check(leak["blocks_used"] == leak["blocks_cached"]
+          and not (leak["blocks_private"] or leak["blocks_reserved"]
+                   or leak["pins"]), f"no block leaked ({leak})")
+    gaps = [served.served_gaps(ref, weights, w, np.asarray(prompts[r.uid]),
+                              np.asarray(r.tokens))[0] for r in results]
+    means = [float(g.mean()) for g in gaps]
+    check(max(means) <= tol_gap,
+          f"a branch's served tokens lie {max(means):.3f} under the "
+          f"reference's best on average (limit {tol_gap}; {means})")
+    return {
+        "layers": list(setup.tcfg.layer_types), "prefix_hit_tokens": hit,
+        "forks": second.kv["forks"],
+        "kv_token_bytes": second.kv["token_bytes"],
+        "kv_block_fixed_bytes": second.kv["block_fixed_bytes"],
+        "tokens_compared": int(sum(len(g) for g in gaps)),
+        "gap_max": float(max(g.max() for g in gaps)),
+        "gap_mean": float(np.concatenate(gaps).mean()),
+        "gap_mean_by_branch": means,
+    }
+
+
+# ---------------------------------------------------------------------------
 # main
 # ---------------------------------------------------------------------------
 
@@ -1138,6 +1239,7 @@ def run_default(run: Run, s: Sizes) -> None:
     run.phase("agreement", phase_agreement, s)
     run.phase("train", phase_train, s)
     run.phase("programs", phase_programs, s)
+    run.phase("hybrid", phase_hybrid, s)
 
 
 def run_four_chips(run: Run, s: Sizes) -> None:

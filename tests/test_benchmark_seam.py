@@ -1,7 +1,7 @@
 """The seam by which the benchmark finds a model family by name, guarded by
 tier-1 (``benchmark/tests/test_seam.py`` holds the slower rehearsals, which
-tier-1 does not run), and the cells that came through it: ``dsv2_codegen_sat``
-and ``lcflash_agentturn_sat``.
+tier-1 does not run), and the cells that came through it: ``dsv2_codegen_sat``,
+``lcflash_agentturn_sat`` and ``lfm2_agentturn_sat``.
 """
 
 import json
@@ -31,10 +31,12 @@ FAMILY_WORDS = ("hidden_size", "num_key_value_heads", "num_attention_heads",
                 "q_lora_rank", "n_routed_experts", "first_k_dense_replace",
                 "num_experts_per_tok", "rope_scaling", "ffn_hidden_size",
                 "expert_ffn_hidden_size", "moe_topk", "zero_expert_num",
-                "mla_scale_q_lora", "mla_scale_kv_lora", "routed_branch")
+                "mla_scale_q_lora", "mla_scale_kv_lora", "routed_branch",
+                "layer_types", "conv_L_cache", "num_dense_layers",
+                "use_expert_bias", "tie_word_embeddings", "router_scoring")
 LEAVES = ("embed", "ln1", "wq", "wk", "wv", "wo", "ln2", "w1", "w3", "w2",
           "ln_f", "wout", "wqa", "wqb", "wkva", "wkvb", "router", "we1",
-          "router_bias", "sub")
+          "router_bias", "sub", "w_in", "w_conv", "w_out", "q_ln", "k_ln")
 THE_FAMILYS_OWN = ("references", "adapters", "configs", "kernel_costs",
                    "tests")
 
@@ -276,7 +278,7 @@ def test_the_double_layer_cell_resolves_every_file_it_names():
     for w in spec.data["workloads"][:4]:
         assert "zero_expert_pairs_pct" not in [
             m["name"] for m in spec.cell(w["name"]).per_layer]
-    assert len(spec.data["workloads"]) == 5
+    assert [w["name"] for w in spec.data["workloads"]].index(LC_CELL) == 4
     assert all(w["chips"] == 1 for w in spec.data["workloads"])
     # The traffic: the grids the issue gives, in 4 balanced groups of 4.
     from benchmark import grid
@@ -424,13 +426,220 @@ def test_tick_ahead_pct_reads_the_look_ahead_counter(flight, want):
 
     spec = Spec(BENCH)
     listed = json.load(open(BENCH))["per_layer"]
-    assert listed[-1] == {
+    at = [m["name"] for m in listed].index("tick_ahead_pct")
+    assert listed[at] == {
         "name": "tick_ahead_pct", "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "admission and scheduling",
         "moves": "tbt_p50_ms"}
+    # Only what a later PR appended for its own cell comes after it (PR 33's).
+    assert [m["name"] for m in listed[at + 1:]] == [
+        "mixer_rest_ms_tick", "mixer_rest_stream_roofline"]
     for w in json.load(open(BENCH))["workloads"]:
         assert "tick_ahead_pct" in [
             m["name"] for m in spec.cell(w["name"]).per_layer]
     read = spec.load_module("layer_metrics", "tick_ahead_pct.py").read
     run = types.SimpleNamespace(flight=flight, t_open=1.0, t_end=10.0)
     assert read(run) == (want if want is None else pytest.approx(want))
+
+
+# -- the conv / attention hybrid's cell (ISSUE 33) ---------------------------
+
+LFM_CELL = "lfm2_agentturn_sat"
+
+
+def test_the_hybrid_cell_resolves_every_file_it_names():
+    spec = Spec(BENCH)
+    cell, lc = spec.cell(LFM_CELL), spec.cell(LC_CELL)
+    assert cell.chips == 1 and cell.config["family"] == "lfm2_moe"
+    assert cell.config["name"] == "lfm2-8b-a1b"
+    for d, mod in (("references", cell.reference()),
+                   ("adapters", cell.adapter())):
+        assert mod.__file__.endswith(os.path.join(d, "lfm2_moe.py"))
+    with open(cell.reference().__file__) as f:
+        text = f.read()
+    assert "tree_attention_tpu" not in text.split('"""', 2)[2]
+    assert "import benchmark" not in text and "from benchmark" not in text
+    # The traffic file that was there, and the cell the sixth of six.
+    assert cell.traffic == lc.traffic and cell.traffic["kind"] == "backlog"
+    assert [w["name"] for w in spec.data["workloads"]][5:] == [LFM_CELL]
+    assert all(w["chips"] == 1 for w in spec.data["workloads"])
+    assert [m["name"] for m in cell.end_to_end] == [
+        "out_tok_s", "tbt_p50_ms", "tbt_p99_ms", "setup_s"]
+    names = [m["name"] for m in cell.per_layer]
+    for name in names:
+        assert spec.load_module("layer_metrics", name + ".py").read
+    # The dense cells' attention kernel, the latent cells' expert product
+    # and counters, and its own two, which no other cell lists.
+    for name in ("occupancy_pct", "kv_blocks_peak_pct", "hbm_peak_gb",
+                 "tick_rows_useful_pct", "attn_kernel_ms_tick",
+                 "flash_decode_paged_roofline", "moe_ffn_ms_tick",
+                 "moe_grouped_matmul_roofline", "experts_touched_pct",
+                 "expert_rows_max_over_mean", "tick_ahead_pct",
+                 "device_idle_pct", "decode_tick_p50_ms"):
+        assert name in names, name
+    assert names[-2:] == ["mixer_rest_ms_tick", "mixer_rest_stream_roofline"]
+    for m in cell.per_layer[-2:]:
+        assert m["workloads"] == [LFM_CELL] and m["layer"] == "kernels"
+        assert (m["source"], m["moves"]) == ("device_trace", "tbt_p50_ms")
+    for name in ("mla_decode_ms_tick", "zero_expert_pairs_pct"):
+        assert name not in names
+    for w in spec.data["workloads"][:5]:
+        assert "mixer_rest_ms_tick" not in [
+            m["name"] for m in spec.cell(w["name"]).per_layer]
+    assert cell.config["serving"] == {
+        "slots": 64, "cache_len": 2560, "kv_layout": "paged", "kv_block": 64,
+        "admission": "chunked", "prefill_chunk": 256, "prefix_cache": True}
+    assert list(cell.config["correct"]["limits"]) == ["gap_mean"]
+    for kernel in ("flash_decode_paged", "moe_grouped_matmul", "mixer_rest"):
+        assert cell.adapter().kernel_call(cell.config, kernel) is not None
+        assert spec.load_module("kernel_costs", kernel + ".py").cost
+    assert cell.adapter().kernel_call(cell.config, "mla_decode_paged") is None
+
+
+def test_the_hybrids_cut_is_depth_alone_and_3_93_billion():
+    with open(BENCH) as f:
+        entry = next(c for c in json.load(f)["configs"]
+                     if c["name"] == "lfm2-8b-a1b")
+    with open(os.path.join(ROOT, entry["file"])) as f:
+        c = json.load(f)
+    assert entry["reduced"] == c["reduced"] == [
+        "num_hidden_layers", "layer_types"]
+    pub = c["published"]
+    assert sorted(pub) == ["layer_types", "num_hidden_layers"]
+    assert pub["num_hidden_layers"] == len(pub["layer_types"]) == 24
+    # The cut is the first 12 layers as published: three periods c c A c.
+    assert c["layer_types"] == pub["layer_types"][:12] \
+        == ["conv", "conv", "full_attention", "conv"] * 3
+    assert c["num_hidden_layers"] == 12
+    assert entry["source"] == c["source"]
+    dep = c["deployment"]
+    assert (dep["chips"], dep["pipeline_stages"], dep["stage"]) == (2, 2, 0)
+    assert dep["experts_total"] == c["num_experts"] == 32
+    assert dep["expert_share"] == 0
+    assert c["block"]["qk_norm"] and c["block"]["router_scoring"] == "sigmoid"
+    assert c["tie_word_embeddings"] and c["assumed"]["tie_word_embeddings"]
+    assert c["assumed"]["seeded_scales"]["router_bias_std"] > 0
+    # Every published key of the catalog's entry, uncut but the two.
+    catalog = "/opt/skills/guides/model-configs/architectures.jsonl"
+    if os.path.exists(catalog):
+        with open(catalog) as f:
+            row = next(json.loads(l) for l in f
+                       if json.loads(l)["name"] == "LFM2-8B-A1B")
+        assert entry["source"] == row["source_url"]
+        for k, v in row["config"].items():
+            assert (pub if k in c["reduced"] else c)[k] == v, k
+    # The held parameters, reckoned from the file; the whole model's too.
+    D = c["hidden_size"]
+    kv = D // c["num_attention_heads"] * c["num_key_value_heads"]
+    conv = D * 3 * D + D * D + c["conv_L_cache"] * D
+    attn = 2 * D * D + 2 * D * kv
+    dense = 3 * D * c["intermediate_size"]
+    moe = c["num_experts"] * 3 * D * c["moe_intermediate_size"] \
+        + D * c["num_experts"]
+    embed = c["vocab_size"] * D
+
+    def held(types):
+        return sum((conv if t == "conv" else attn)
+                   + (dense if l < c["num_dense_layers"] else moe)
+                   for l, t in enumerate(types)) + embed
+
+    assert held(c["layer_types"]) == pytest.approx(3.929e9, rel=0.001)
+    assert held(pub["layer_types"]) == pytest.approx(8.34e9, rel=0.001)
+    assert 2 * held(pub["layer_types"]) > 16e9      # the whole: over a chip
+    assert c["num_hidden_layers"] - c["num_dense_layers"] >= 4
+    # A token's bytes in the pool: 3 K/V layers, and 9 tails a block of 64.
+    token = 3 * 2 * kv * 2 + 9 * 2 * D * 2 / c["serving"]["kv_block"]
+    assert token == 7296
+
+
+def test_the_hybrids_cost_functions_against_a_hand_count_at_one_tick():
+    spec = Spec(BENCH)
+    cell = spec.cell(LFM_CELL)
+    kernel_call = cell.adapter().kernel_call
+    of_model, calls = kernel_call(cell.config, "flash_decode_paged")
+    assert of_model == {"heads": 32, "kv_heads": 8, "head": 64,
+                        "dtype_bytes": 2} and calls == 3
+    flash = spec.load_module("kernel_costs", "flash_decode_paged.py").cost
+    # Two slots of 1,000 and 500 tokens: 8 heads x 64 of keys and of values
+    # a token once (the pool packs them two a row of lanes: the same
+    # bytes), 32 queries in and 32 outputs out a slot.
+    one = flash(contexts=[1000, 500], q_rows=1, **of_model)
+    assert one["bytes"] == 1500 * 2 * 8 * 64 * 2 + 2 * 2 * 32 * 64 * 2
+
+    of_model, calls = kernel_call(cell.config, "moe_grouped_matmul")
+    assert calls == 10 and of_model == {
+        "hidden": 2048, "width": 1792, "experts_held": 32, "dtype_bytes": 2}
+    moe = spec.load_module("kernel_costs", "moe_grouped_matmul.py").cost
+    # A decode tick of 64 rows touches all 32 experts of a layer with 256
+    # pairs: 7.05 GB over the ten layers, what the cell's why counts.
+    one = moe(experts_touched=32, pairs=256, **of_model)
+    assert one["bytes"] == 32 * 3 * 2048 * 1792 * 2 \
+        + 256 * 2 * (2048 + 1792) * 2
+    assert 10 * one["bytes"] == pytest.approx(7.05e9, rel=0.01)
+
+    of_model, calls = kernel_call(cell.config, "mixer_rest")
+    assert calls == 1
+    rest = spec.load_module("kernel_costs", "mixer_rest.py").cost
+    one = rest(contexts=[900] * 64, q_rows=1, **of_model)
+    # Every weight outside the experts once: 9 conv mixers, 3 attention
+    # mixers, 2 dense FFNs, 10 routers, the tied embedding as the head.
+    weights = (9 * (2048 * 6144 + 2048 * 2048 + 3 * 2048)
+               + 3 * (2 * 2048 * 2048 + 2 * 2048 * 512)
+               + 2 * 3 * 2048 * 7168 + 10 * 2048 * 32 + 65536 * 2048)
+    assert weights * 2 == pytest.approx(0.81e9, rel=0.01)
+    # 64 rows: the residual in and out of both halves of 12 layers, two
+    # tail rows read and one written a conv layer, the logits in float32.
+    rows = 64 * 2048 * (2 * 2 * 12 + 3 * 9) * 2 + 64 * 65536 * 4
+    assert one["bytes"] == weights * 2 + rows
+    assert one["flops"] == 2 * 64 * weights
+    # 64 operations a byte: under the chip's ridge, so the bytes bound it
+    # and a reading over 100% of this roofline cannot be.
+    peaks = spec.load_json("peaks.json")["TPU v5 lite"]
+    ridge = peaks["bf16_flops_per_s"] / peaks["hbm_bytes_per_s"]
+    assert one["flops"] / one["bytes"] < 64 < ridge
+    assert rest(contexts=[], q_rows=1, **of_model)["flops"] == 0
+
+
+def test_the_hybrids_adapter_refuses_another_model_at_once():
+    """Before a weight is drawn: a model read otherwise than the file says,
+    and one without the layers' fields (what the parent's reader makes of
+    the file: a dense model, in silence)."""
+    import types
+
+    from tree_attention_tpu.models.transformer import model_from_config
+
+    spec = Spec(BENCH)
+    cell = spec.cell(LFM_CELL)
+    adapter = cell.adapter()
+    adapter._hold_to_file(model_from_config(cell.config), cell.config)
+    dense = {k: v for k, v in cell.config.items()
+             if k not in ("layer_types", "num_experts", "use_expert_bias")}
+    with pytest.raises(SpecError, match="built otherwise"):
+        adapter._hold_to_file(model_from_config(dense), cell.config)
+    old = types.SimpleNamespace(mla=None, moe=None, d_model=2048, d_ff=7168,
+                                n_layers=12)
+    with pytest.raises(SpecError, match="cannot express"):
+        adapter._hold_to_file(old, cell.config)
+    with pytest.raises(SpecError, match="cannot read"):
+        adapter.build({"family": "lfm2_moe"}, [], 0, "cpu", None)
+
+
+@pytest.mark.parametrize("name", ["mixer_rest_ms_tick",
+                                  "mixer_rest_stream_roofline"])
+def test_the_rest_metrics_read_nothing_where_there_is_nothing(name):
+    """An untraced run, a run without flight records and a trace without a
+    decode tick in it give None, never a number and never an exception."""
+    import types
+
+    spec = Spec(BENCH)
+    cell = spec.cell(LFM_CELL)
+    read = spec.load_module("layer_metrics", name + ".py").read
+    peaks = spec.load_json("peaks.json")["TPU v5 lite"]
+    empty = {"offset_s": 0.0, "t0": 5.0, "t1": 8.0, "devices": 1,
+             "events": {}}
+    for trace, flight in ((None, None), (None, []), (empty, None),
+                          (empty, [{"t_s": 1.0}])):
+        run = types.SimpleNamespace(trace=trace, flight=flight, cell=cell,
+                                    recs=[], peaks=peaks, t_open=1.0,
+                                    t_end=10.0)
+        assert read(run) is None

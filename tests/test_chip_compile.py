@@ -615,3 +615,104 @@ def test_latent_step_compiles_and_keeps_the_pool_in_place(
     assert mem.alias_size_in_bytes >= pool * 2, mem
     if tq == 1:
         assert mem.temp_size_in_bytes < pool * 2, mem
+
+
+# -- the hybrid pool: conv tails beside K/V rows of 64-lane heads (ISSUE 33) -
+#
+# The conv / attention hybrid's tick programs at ``benchmark/configs/
+# lfm2-8b-a1b.json``'s widths: 9 conv + 3 attention layers in 7 runs, 2 dense
+# FFNs, 10 expert layers of 32 experts of 2048 x 1792, 64 slots. What the
+# compiler did at the shapes first tried, and must not do again: K/V pools
+# whose rows are one head of 64 lanes it holds in a layout of its own (the
+# block axis minor) and copies to the kernel's row-major layout and back, 2 x
+# 2 x 1 GB a tick, so two KV heads lie side by side on 128 lanes
+# (``TransformerConfig.kv_pack``); a tail pool ``(layers, N, 2, hidden)`` it
+# tiles by the pair of rows, so that every flat view the gather and the
+# scatter take is a copy of the pool (8 x 189 MB in the decode tick), so a
+# block's two rows lie side by side too, ``(layers, N, 2 x hidden)``.
+
+
+@pytest.mark.parametrize("tq,packed", [(1, False), (256, True), (16, True)],
+                         ids=["tq1", "packed256", "packed16"])
+def test_hybrid_step_compiles_and_keeps_the_pools_in_place(
+        monkeypatch, tq, packed):
+    if _chip() is None:
+        pytest.skip("the v5e:2x2 topology cannot be described here")
+    from tree_attention_tpu.models import decode
+    from tree_attention_tpu.models.transformer import init_params
+
+    c, cfg = _latent_config("lfm2-8b-a1b")
+    slots, blk = c["serving"]["slots"], c["serving"]["kv_block"]
+    blocks = slots * c["serving"]["cache_len"] // blk
+    chip = lambda tree: jax.tree.map(lambda a: _s(a.shape, a.dtype), tree)
+    params = chip(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    assert sum(math.prod(a.shape) for a in jax.tree.leaves(params)) \
+        == pytest.approx(3.929e9, rel=0.001)
+    cache = chip(jax.eval_shape(lambda: decode.init_paged_cache(
+        cfg, slots, c["serving"]["cache_len"], blocks, block=blk)))
+    assert cache.k.shape == (3, blocks, 4, blk, 128)      # two heads a row
+    assert cache.tail.shape == (9, blocks, 2 * cfg.d_model)
+
+    def step(params, tokens, cache, n_tokens):
+        stats = {}
+        logits, cache = decode.forward_step(params, tokens, cache, cfg,
+                                            n_tokens=n_tokens, stats=stats)
+        return logits, cache, stats
+
+    def packed_step(params, chunk, cache, members, slots_i32):
+        stats = {}
+        logits, cache = decode.forward_packed_step(
+            params, chunk, members, members, slots_i32, slots_i32, cache,
+            cfg, stats=stats)
+        return logits, cache, stats
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if packed:
+        compiled = jax.jit(packed_step, donate_argnums=(2,)).lower(
+            params, _s((1, tq), jnp.int32), cache, _s((1,), jnp.int32),
+            _s((slots,), jnp.int32)).compile()
+    else:
+        compiled = jax.jit(step, donate_argnums=(2,)).lower(
+            params, _s((slots, tq), jnp.int32), cache,
+            _s((slots,), jnp.int32)).compile()
+    text = compiled.as_text()
+    kernels = pallas_kernels(text)
+    assert any(k.startswith("flash_decode_paged") for k in kernels), kernels
+    assert "moe_grouped_matmul" in kernels, kernels
+    if packed:
+        padding = _padding_arrays(text, slots, tq, cfg.vocab_size,
+                                  cfg.d_model)
+        assert not padding, padding
+    kv_layer = blocks * 4 * blk * 128
+    tail_layer = blocks * 2 * cfg.d_model
+    experts = cfg.moe.held * cfg.d_model * cfg.moe.width
+    moved, writes = [], []
+    for name, result, opcode, inner in _materialised(text):
+        if opcode in _MOVES_NOTHING:
+            continue
+        sizes = [math.prod(int(d) for d in dims.split(","))
+                 for dims in re.findall(r"\bbf16\[([\d,]+)\]", result)
+                 if dims.endswith((f",4,{blk},128", f",{2 * cfg.d_model}"))]
+        of_experts = [math.prod(int(d) for d in dims.split(","))
+                      for dims in re.findall(r"\bbf16\[([\d,]+)\]", result)
+                      if dims.endswith((f"{cfg.d_model},{cfg.moe.width}",
+                                        f"{cfg.moe.width},{cfg.d_model}"))]
+        if max(sizes, default=0) < min(kv_layer, tail_layer) \
+                and max(of_experts, default=0) < experts:
+            continue
+        if opcode == "scatter" or " scatter(" in inner:
+            writes.append(name)      # the pools' writes, in place (below)
+        else:
+            moved.append((name, opcode, result))
+    # No copy of a K/V pool or of the tail pool (whole or a layer of it),
+    # no slice of a layer's experts or tails out of their stack.
+    assert not moved, moved
+    # K's and V's in each of the 3 attention layers (each a run of one) and
+    # the tails' in each of the 4 runs of conv layers, for every group.
+    groups = 2 if packed else 1
+    assert len(writes) == groups * (2 * 3 + 4), writes
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * (2 * 3 * kv_layer
+                                           + 9 * tail_layer), mem
+    assert mem.temp_size_in_bytes < 2 * kv_layer, mem

@@ -126,6 +126,20 @@ def test_phase_agreement_with_the_plain_forward():
     assert d["max_abs_err"] <= TINY.tol_logits
 
 
+def test_phase_hybrid_serves_a_hit_and_a_fork_against_the_reference():
+    """The hybrid phase at ``tests/test_hybrid_conv.py``'s small preset:
+    blocks of 8, float32 (a served token lies at the reference's best to
+    1e-4)."""
+    from tests.test_hybrid_conv import SMALL
+
+    d = smoke.phase_hybrid(TINY, dict(SMALL), device="cpu", block=8,
+                           chunk=16, tol_gap=1e-4)
+    assert d["prefix_hit_tokens"] == 32 and d["forks"] == 1
+    assert d["tokens_compared"] == 4 * 12 and d["gap_max"] <= 1e-4
+    assert d["layers"].count("conv") == 5
+    assert d["kv_block_fixed_bytes"] == 5 * 2 * 64 * 4
+
+
 @pytest.mark.slow
 def test_phase_train_and_programs():
     assert smoke.phase_train(TINY)["losses"][-1] < 6.3

@@ -522,8 +522,24 @@ class ServeSetup:
 
 def load_model_config(path: str) -> dict:
     """``--model-config``'s file: a JSON object in a published
-    ``config.json``'s own keys (``models.transformer.model_from_config``
-    says which, and what a file cut to one chip's share adds)."""
+    ``config.json``'s own keys. ``models.transformer.model_from_config``
+    reads it, and its docstring lists the keys a family at a time: a
+    Llama-style dense decoder's (``hidden_size``, ``num_hidden_layers``,
+    ``num_attention_heads``, ``num_key_value_heads``, ``intermediate_size``,
+    ``vocab_size``, ``rms_norm_eps``, ``rope_theta``); a latent-attention
+    expert model's (``kv_lora_rank``, ``q_lora_rank``, ``qk_nope_head_dim``,
+    ``qk_rope_head_dim``, ``v_head_dim``, ``n_routed_experts``,
+    ``n_shared_experts``, ``num_experts_per_tok``, ``moe_intermediate_size``,
+    ``first_k_dense_replace``, ``topk_method``, ``n_group``, ``topk_group``,
+    ``routed_scaling_factor``, ``norm_topk_prob``, ``scoring_func``,
+    ``rope_scaling``); the shortcut-connected double layer's (``num_layers``,
+    ``ffn_hidden_size``, ``expert_ffn_hidden_size``, ``moe_topk``,
+    ``zero_expert_num``, ``mla_scale_q_lora``, ``mla_scale_kv_lora``); a
+    conv / attention hybrid's (``layer_types``, ``conv_L_cache``,
+    ``conv_bias``, ``num_experts``, ``num_dense_layers``, ``norm_eps``,
+    ``use_expert_bias``, ``tie_word_embeddings``); and this repo's own two
+    groups, ``deployment`` (a file cut to one chip's share) and ``block``
+    (what only the modelling code says)."""
     import json
 
     try:
@@ -539,18 +555,20 @@ def load_model_config(path: str) -> dict:
     return model
 
 
-# What a latent-attention / expert model is not served with: the flag's
-# test and the mechanism's name, refused at build and never served wrong.
-_LATENT_REFUSALS = (
-    (lambda c: c.kv_quant != "none", "--kv-quant (int8 latent rows)"),
+# What a model served from the latent pool or the hybrid pool
+# (``TransformerConfig.cache_kind``) is not served with: the flag's test and
+# the mechanism's name, refused at build and never served wrong.
+_POOL_REFUSALS = (
+    (lambda c: c.kv_quant != "none", "--kv-quant (int8 rows)"),
     (lambda c: c.kv_shard == "seq",
-     "--kv-shard seq (a sequence-sharded latent pool)"),
+     "--kv-shard seq (a sequence-sharded pool)"),
     (lambda c: c.kv_tiering == "on" and c.host_blocks > 0,
-     "--host-blocks (the host tier for a one-array pool)"),
+     "--host-blocks (the host tier)"),
     (lambda c: c.speculate,
-     "--speculate (the latent kernel takes no tree_mask)"),
+     "--speculate (the latent kernel takes no tree_mask; a conv layer's "
+     "tail cannot roll a rejected draft back)"),
     (lambda c: c.serve_disagg,
-     "--serve-disagg (the handoff for a one-array pool)"),
+     "--serve-disagg (the handoff of that pool's arrays)"),
     (lambda c: c.admission != "chunked", "--admission whole"),
 )
 
@@ -691,12 +709,13 @@ def build_serve_engine(cfg: RunConfig, mesh, *, model=None,
             )
         except (KeyError, ValueError) as e:
             raise SystemExit(f"model configuration: {e!r}") from None
-        if not tcfg.dense_block:
-            for refused, what in _LATENT_REFUSALS:
+        if tcfg.cache_kind != "kv":
+            for refused, what in _POOL_REFUSALS:
                 if refused(cfg):
                     raise SystemExit(
-                        f"a latent-attention / expert model is not served "
-                        f"with {what}: not built yet (ROADMAP 2A)")
+                        f"a model served from the {tcfg.cache_kind} pool is "
+                        f"not served with {what}: not built yet (ROADMAP "
+                        f"2A)")
     else:
         tcfg = _transformer_config(
             dataclasses.replace(cfg, seq_len=cache_len))

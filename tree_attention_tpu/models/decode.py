@@ -56,11 +56,11 @@ from tree_attention_tpu import obs
 from tree_attention_tpu.models.transformer import (
     Params,
     TransformerConfig,
-    _heads,
     _unheads,
     _mlp_block,
+    gqa_qkv,
     rms_norm,
-    rope,
+    unembed,
 )
 
 # Cache observability. forward_step is normally jitted (generate() scans
@@ -191,6 +191,57 @@ class PagedLatentCache:
         return self.kv.shape[1]
 
 
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class PagedHybridCache:
+    """The paged cache of a model whose layers are of several kinds: K/V
+    pools for its rotary-GQA attention layers, as :class:`PagedKVCache`
+    holds them but for heads of fewer than 128 lanes laid side by side
+    (``TransformerConfig.kv_pack``): ``(attention layers, N, Hkv / p,
+    block, p x D)``; and beside them a ``tail`` pool ``(conv layers, N, 2 x
+    hidden)`` for its gated short-convolution layers, a block's two rows
+    side by side on the lanes, under the SAME block ids and the SAME table.
+    (As ``(N, 2, hidden)`` the compiler tiles the pair of rows on its own
+    and every flat view of the pool is a copy of it: PERF.md, PR 33.)
+
+    A conv layer's state at position ``p`` is its last two gated inputs
+    ``z_{p-1}``, ``z_{p-2}``, whatever the context. Block ``j``'s tail
+    entry holds, for every conv layer, the ``z`` of the two highest
+    positions written into the block so far, position ``q`` in half ``q %
+    2`` of the row (a block is even, so of any two neighbouring positions
+    each has a half of its own): a row at ``p`` reads ``z_{p-1}`` and ``z_{p-2}``
+    through the table entries of the blocks that hold those positions
+    (zero before position 0) and writes its own ``z_p`` over ``z_{p-2}``.
+    So a FULL block's tail is final, and it is exactly the state the
+    position after the block needs: a prefix hit of whole blocks stays a
+    table update, a fork's copy of its partial block
+    (:func:`copy_pool_block`) carries the tail with it, a freed slot needs
+    no reset, and the allocator, the radix tree and the engine's per-slot
+    lists hold nothing for it. What the tail cannot do is roll back: a
+    draft that is rejected has overwritten it.
+
+    A model with expert layers under rotary-GQA attention and no conv
+    layer is served from this cache too, its tail pool of depth 0."""
+
+    k: jax.Array       # (attention layers, N, Hkv / p, block, p x D) pool
+    v: jax.Array       # (attention layers, N, Hkv / p, block, p x D) pool
+    tail: jax.Array    # (conv layers, N, 2 x hidden) pool
+    table: jax.Array   # (B, NB) int32 — physical block per logical block
+    length: jax.Array  # (B,) int32 — tokens written so far, per slot
+
+    @property
+    def capacity(self) -> int:
+        return self.table.shape[1] * self.k.shape[3]
+
+    @property
+    def block(self) -> int:
+        return self.k.shape[3]
+
+    @property
+    def blocks(self) -> int:
+        return self.k.shape[1]
+
+
 def cache_pools(cache) -> Dict[str, jax.Array]:
     """A paged cache's block pools by field name, every one ``(L, N, ...)``
     with the block on axis 1: what a block copy, a leak check or a byte
@@ -200,17 +251,30 @@ def cache_pools(cache) -> Dict[str, jax.Array]:
     pools = {"k": cache.k, "v": cache.v}
     if isinstance(cache, PagedQuantKVCache):
         pools.update(k_scale=cache.k_scale, v_scale=cache.v_scale)
+    if isinstance(cache, PagedHybridCache):
+        pools.update(tail=cache.tail)
     return pools
 
 
 def cache_token_bytes(cache) -> int:
     """Bytes one cached token takes over all layers, read from the arrays
-    (scales of an int8 pool not counted: they are per block)."""
+    (what a block holds whatever its tokens is not counted: the scales of
+    an int8 pool, a conv layer's tail; :func:`cache_block_fixed_bytes`)."""
     if isinstance(cache, PagedLatentCache):
         return int(cache.kv.shape[0] * cache.kv.shape[3]
                    * cache.kv.dtype.itemsize)
     k = cache.k  # (L, B|N, Hkv, T|block, D)
     return int(2 * k.shape[0] * k.shape[2] * k.shape[4] * k.dtype.itemsize)
+
+
+def cache_block_fixed_bytes(cache) -> int:
+    """Bytes a pool block takes beside its tokens' rows: the conv layers'
+    two-row tails of a :class:`PagedHybridCache`, 0 for every other
+    cache."""
+    if not isinstance(cache, PagedHybridCache):
+        return 0
+    t = cache.tail  # (conv layers, N, 2 x hidden)
+    return int(t.shape[0] * t.shape[2] * t.dtype.itemsize)
 
 
 @jax.tree_util.register_dataclass
@@ -532,8 +596,10 @@ def init_paged_cache(
     quantize: bool = False,
     kv_shard: str = "replicated",
     seq_axis: str = AXIS_SEQ,
-) -> Union[PagedKVCache, PagedQuantKVCache]:
-    """Allocate a paged cache: one ``blocks``-block pool + empty tables.
+) -> Union[PagedKVCache, PagedQuantKVCache, PagedLatentCache,
+           PagedHybridCache]:
+    """Allocate a paged cache: one ``blocks``-block pool + empty tables,
+    of the kind the model caches (``cfg.cache_kind``).
 
     ``max_len`` is the logical per-slot capacity (rounded up to a whole
     number of blocks — the table width); ``blocks`` is the POOL capacity
@@ -597,6 +663,33 @@ def init_paged_cache(
             length=jnp.zeros((batch_size,), jnp.int32),
         )
     shape = (cfg.cache_layers, blocks, cfg.n_kv_heads, block, cfg.d_head)
+    if cfg.cache_kind == "hybrid":
+        if quantize:
+            raise ValueError(
+                "int8 rows beside conv tails are not built: the hybrid "
+                "pool is served exact")
+        if seq_sharded:
+            raise ValueError(
+                "a sequence-sharded hybrid pool (kv_shard='seq') is not "
+                "built: the conv tails are read through the whole table")
+        if block % 2:
+            raise ValueError(
+                f"a conv layer's state is a two-row tail of a block: the "
+                f"block ({block}) is even")
+        kv = (cfg.cache_layers, blocks, cfg.n_kv_heads // cfg.kv_pack,
+              block, cfg.d_head * cfg.kv_pack)
+        shapes = (kv, kv, (cfg.conv_layers, blocks, 2 * cfg.d_model))
+        k, v, tail = (
+            jax.jit(lambda: tuple(jnp.zeros(s, cfg.dtype) for s in shapes),
+                    out_shardings=NamedSharding(mesh, P()))()
+            if mesh is not None
+            else tuple(jnp.zeros(s, cfg.dtype) for s in shapes)
+        )
+        return PagedHybridCache(
+            k=k, v=v, tail=tail,
+            table=jnp.zeros((batch_size, nb), jnp.int32),
+            length=jnp.zeros((batch_size,), jnp.int32),
+        )
     dtype = jnp.int8 if quantize else cfg.dtype
     sscale = None
     if mesh is not None:
@@ -928,13 +1021,266 @@ def _join_rows(groups: Tuple[_RowGroup, ...], outs) -> jax.Array:
     ], axis=1)[None]
 
 
+@dataclasses.dataclass(frozen=True)
+class _Attend:
+    """The attention half of a rotary-GQA layer, for one group of a step's
+    rows: what :func:`_step_layers` settled once for the step (which cache
+    it steps, on which path) and, called, the write of the group's new K/V
+    rows and its queries against what the cache then holds. Every layer
+    loop whose layers cache K/V rows calls it (:func:`gqa_mixer`): the
+    dense block's and the loop over layers of several kinds
+    (``models/hybrid.py``)."""
+
+    groups: Tuple["_RowGroup", ...]
+    cfg: TransformerConfig
+    mesh: Optional[Mesh]
+    axes: Dict[str, Optional[str]]
+    num_splits: Optional[int]
+    quant_kernel: str
+    paged: bool
+    quant: bool
+    carried: bool
+    seq_sharded: bool
+    hoist_view: bool
+    anchors: Any
+    scale: Optional[float] = None   # None: the kernels' own, D^-1/2
+
+    def __call__(self, gi, q, k_new, v_new, k_cache, v_cache, k_s, v_s,
+                 views, l, base):
+        """The attention half of a layer for group ``gi``: its new K/V
+        rows into the cache, its queries against what the cache then
+        holds. Returns the heads' output and the cache arrays."""
+        groups, cfg, mesh, axes = self.groups, self.cfg, self.mesh, self.axes
+        paged, quant, carried = self.paged, self.quant, self.carried
+        seq_sharded, hoist_view = self.seq_sharded, self.hoist_view
+        anchors, num_splits = self.anchors, self.num_splits
+        quant_kernel = self.quant_kernel
+        g = groups[gi]
+        B, Tq = g.batch, g.tq
+        start, n_valid = g.start, g.n_valid
+        k_view = v_view = None
+        if hoist_view:
+            k_view, v_view = views[2 * gi:2 * gi + 2]
+        # Write member i's new rows at its own [start[i], start[i]+Tq): a
+        # vmapped dynamic-update over batch (per-slot token offsets). Under
+        # a mesh GSPMD turns it into per-shard masked writes on the seq dim.
+        # Quantized caches quantize the rows first — under the per-slot
+        # frozen scales (contiguous) or the per-block anchor scale
+        # (paged; entered blocks inherit it, see above).
+        k_deq = v_deq = None
+        k_sf = v_sf = None
+        if quant and paged:
+            anchor_pb, write_pb, entered = anchors[gi]
+            # The scales as (blocks, Hkv) rows: every layer's when carried
+            # (a bitcast), this layer's (base 0) when scanned.
+            hkv = k_s.shape[-1]
+            k_sf, v_sf = k_s.reshape(-1, hkv), v_s.reshape(-1, hkv)
+            k_anchor = k_sf[base + anchor_pb][:, :, None, None]
+            v_anchor = v_sf[base + anchor_pb][:, :, None, None]  # (B,Hkv,1,1)
+            k_new = _quantize_rows(k_new, k_anchor)
+            v_new = _quantize_rows(v_new, v_anchor)
+            vals_k = jnp.broadcast_to(
+                k_anchor[:, None, :, 0, 0], (B, Tq, hkv)
+            ).reshape(-1, hkv)
+            vals_v = jnp.broadcast_to(
+                v_anchor[:, None, :, 0, 0], (B, Tq, hkv)
+            ).reshape(-1, hkv)
+            # Rows that enter no block scatter past every layer and drop.
+            scale_tgt = jnp.where(
+                entered, base + write_pb, k_sf.shape[0]
+            ).reshape(-1)
+            k_sf = k_sf.at[scale_tgt].set(vals_k, mode="drop")
+            v_sf = v_sf.at[scale_tgt].set(vals_v, mode="drop")
+            k_s, v_s = k_sf.reshape(k_s.shape), v_sf.reshape(v_s.shape)
+            if hoist_view:
+                # The view holds DEQUANTIZED rows: mirror exactly what
+                # the pool now holds (quantize-then-dequantize), so
+                # attention over the view == attention over the pool.
+                k_deq = (
+                    k_new.astype(jnp.float32) * k_anchor
+                ).astype(k_view.dtype)
+                v_deq = (
+                    v_new.astype(jnp.float32) * v_anchor
+                ).astype(v_view.dtype)
+        elif quant:
+            k_new = _quantize_rows(k_new, k_s)
+            v_new = _quantize_rows(v_new, v_s)
+        if paged:
+            # Paged write: scatter through the block table — valid rows
+            # land in their slot's mapped blocks, padded rows drop. The
+            # contiguous path's window clamp machinery is unnecessary
+            # here (see _paged_pool_write).
+            if seq_sharded:
+                k_cache = _paged_pool_write_seq(
+                    k_cache, k_new, g.table, start, n_valid,
+                    mesh=mesh, seq_axis=axes["seq"],
+                )
+                v_cache = _paged_pool_write_seq(
+                    v_cache, v_new, g.table, start, n_valid,
+                    mesh=mesh, seq_axis=axes["seq"],
+                )
+            else:
+                k_cache = _paged_pool_write(
+                    k_cache, k_new, g.table, start, n_valid, l
+                )
+                v_cache = _paged_pool_write(
+                    v_cache, v_new, g.table, start, n_valid, l
+                )
+            if hoist_view:
+                # Mirror the new rows into the hoisted logical view (the
+                # pre-scan gather predates this layer's write) — a cheap
+                # Tq-row window write, vs re-gathering the whole pool.
+                wv = jax.vmap(_masked_window_write, in_axes=(0, 0, 0, 0))
+                mk = k_new if k_deq is None else k_deq
+                mv = v_new if v_deq is None else v_deq
+                k_view = wv(
+                    k_view, mk.astype(k_view.dtype), start, n_valid
+                )
+                v_view = wv(
+                    v_view, mv.astype(v_view.dtype), start, n_valid
+                )
+        elif g.n is None:
+            write = jax.vmap(
+                lambda buf, rows, s: lax.dynamic_update_slice_in_dim(
+                    buf, rows, s, axis=1
+                )
+            )
+            k_cache = write(k_cache, k_new.astype(k_cache.dtype), start)
+            v_cache = write(v_cache, v_new.astype(v_cache.dtype), start)
+        else:
+            # Mixed-Tq masked write: only rows < n_tokens[i] may land. A
+            # plain Tq-row dynamic-update would (a) write pad garbage the
+            # causal mask has to hide until it is overwritten and (b)
+            # CLAMP near capacity (dynamic_update_slice semantics), sliding
+            # garbage over a decode slot's newest valid rows. Instead:
+            # read the Tq-row window at a clamped offset, overlay exactly
+            # the valid rows at their true absolute positions, write it
+            # back — cache bytes outside [start, start+n) are untouched.
+            write = jax.vmap(_masked_window_write, in_axes=(0, 0, 0, 0))
+            k_cache = write(
+                k_cache, k_new.astype(k_cache.dtype), start, g.n
+            )
+            v_cache = write(
+                v_cache, v_new.astype(v_cache.dtype), start, g.n
+            )
+
+        data = axes["data"]
+        if data and mesh is not None and B % mesh.shape[data]:
+            data = None  # a packed group need not divide over the batch axis
+        attn_kw = dict(
+            q_position=start,
+            mesh=mesh,
+            data_axis=data,
+            seq_axis=axes["seq"],
+            model_axis=axes["model"],
+            block_size=cfg.attn_block_size,
+            tree_mask=g.tree_mask,
+        )
+        if self.scale is not None:
+            attn_kw["scale"] = self.scale
+        ak, av, ak_s, av_s = k_cache, v_cache, k_s, v_s
+        if hoist_view:
+            ak, av = k_view, v_view
+        elif carried:
+            # The kernels and the reference gather take a pool and a
+            # table: hand them every layer's blocks (a bitcast of the
+            # carry) and this layer's addresses.
+            ak = k_cache.reshape((-1,) + k_cache.shape[2:])
+            av = v_cache.reshape((-1,) + v_cache.shape[2:])
+            attn_kw["block_table"] = base + g.table
+            if quant:
+                ak_s, av_s = k_sf, v_sf
+        elif paged:
+            attn_kw["block_table"] = g.table
+            attn_kw["kv_shard"] = "seq"
+        if quant and not (paged and hoist_view):
+            out, _ = decode_attention(
+                q, ak, av, k_scale=ak_s, v_scale=av_s,
+                quant_kernel=quant_kernel, **attn_kw,
+            )
+        else:
+            # Exact caches — and the paged-quant DEQUANTIZED view (the
+            # off-kernel path; see the hoist_view comment above).
+            out, _ = decode_attention(
+                q, ak, av,
+                impl=cfg.attn_impl, num_splits=num_splits, **attn_kw,
+            )
+        return out, k_cache, v_cache, k_s, v_s
+
+
+def gqa_mixer(attend: _Attend, layer: Params, x: jax.Array,
+              positions: jax.Array, k_cache, v_cache, k_s, v_s, views, l,
+              base):
+    """A rotary-GQA mixer over every group of the step's rows: norm,
+    projections (:func:`~.transformer.gqa_qkv`), each group's rows into the
+    cache and against it (:class:`_Attend`), the output projection. Returns
+    the residual with the mixer's output added and the cache arrays."""
+    cfg, groups = attend.cfg, attend.groups
+    h = rms_norm(x, layer["ln1"], cfg.norm_eps)
+    q, k_new, v_new = gqa_qkv(layer, h, positions, cfg)
+    if cfg.kv_pack > 1:
+        q, k_new, v_new = _pack_heads(q, k_new, v_new, cfg)
+    outs = []
+    for gi, g in enumerate(groups):
+        out, k_cache, v_cache, k_s, v_s = attend(
+            gi, g.take(q), g.take(k_new), g.take(v_new),
+            k_cache, v_cache, k_s, v_s, views, l, base,
+        )
+        outs.append(out)
+    out = _join_rows(groups, outs)
+    if cfg.kv_pack > 1:
+        out = _unpack_heads(out, cfg)
+    x = x + _unheads(out) @ layer["wo"]
+    return x, k_cache, v_cache, k_s, v_s
+
+
+def _head_lanes(cfg: TransformerConfig) -> jax.Array:
+    """``(heads, kv_pack)`` one-hot: which of a packed row's ``kv_pack``
+    spans of ``d_head`` lanes query head ``h`` reads (its KV head's)."""
+    kv_head = jnp.arange(cfg.n_heads) // (cfg.n_heads // cfg.n_kv_heads)
+    return jax.nn.one_hot(kv_head % cfg.kv_pack, cfg.kv_pack)
+
+
+def _pack_heads(q, k, v, cfg: TransformerConfig):
+    """``cfg.kv_pack`` neighbouring KV heads side by side on a row's lanes
+    (``(B, Hkv, T, D)`` -> ``(B, Hkv / p, T, p x D)``), and every query
+    head's values in its KV head's lanes with zeros beside them: q . k over
+    the packed row is the head's own dot product, and query head ``h``
+    still reads packed KV head ``h // (group x p)``."""
+    p = cfg.kv_pack
+    B, Hkv, T, D = k.shape
+
+    def side_by_side(a):
+        return a.reshape(B, Hkv // p, p, T, D).transpose(0, 1, 3, 2, 4) \
+            .reshape(B, Hkv // p, T, p * D)
+
+    lanes = _head_lanes(cfg).astype(q.dtype)
+    q = (q[:, :, :, None, :] * lanes[None, :, None, :, None]).reshape(
+        B, cfg.n_heads, T, p * D)
+    return q, side_by_side(k), side_by_side(v)
+
+
+def _unpack_heads(out: jax.Array, cfg: TransformerConfig) -> jax.Array:
+    """Of a packed output row ``(B, H, T, p x D)`` the span of the head's
+    own KV head: ``(B, H, T, D)``."""
+    B, H, T, _ = out.shape
+    out = out.reshape(B, H, T, cfg.kv_pack, cfg.d_head)
+    return jnp.einsum("bhtpd,hp->bhtd", out,
+                      _head_lanes(cfg).astype(out.dtype))
+
+
 def _check_block_cache(cache: Any, cfg: TransformerConfig) -> None:
-    """The model's block and the cache's kind go together."""
-    if isinstance(cache, PagedLatentCache) != (not cfg.dense_block):
+    """The model's layers and the cache's kind go together."""
+    kind = ("latent" if isinstance(cache, PagedLatentCache)
+            else "hybrid" if isinstance(cache, PagedHybridCache) else "kv")
+    if kind != cfg.cache_kind:
         raise ValueError(
-            "a latent-attention / expert model is served from the paged "
-            "latent pool (init_paged_cache) and no other cache; the dense "
-            f"block from no latent pool (got {type(cache).__name__})"
+            f"this model caches {cfg.cache_kind!r} state "
+            f"(TransformerConfig.cache_kind: a latent pool for latent "
+            f"attention, the hybrid pool for conv layers or experts under "
+            f"rotary GQA, K/V buffers for the dense block) and is served "
+            f"from the cache init_paged_cache builds for it and no other; "
+            f"got {type(cache).__name__}"
         )
 
 
@@ -945,6 +1291,8 @@ def _count_step(cache: Any) -> None:
     quant = isinstance(cache, (QuantKVCache, PagedQuantKVCache))
     if isinstance(cache, PagedLatentCache):
         kind = "paged_latent"
+    elif isinstance(cache, PagedHybridCache):
+        kind = "paged_hybrid"
     elif isinstance(cache, (PagedKVCache, PagedQuantKVCache)):
         kind = "paged_quant" if quant else "paged"
     else:
@@ -1081,6 +1429,18 @@ def _step_layers(
         x, pool = _latent_layers(
             params, x, cache, cfg, positions, groups, stats)
         return x, {"kv": pool}
+    if isinstance(cache, PagedHybridCache):
+        from tree_attention_tpu.models.hybrid import hybrid_layers
+
+        attend = _Attend(
+            groups=groups, cfg=cfg, mesh=mesh, axes=axes,
+            num_splits=num_splits, quant_kernel=quant_kernel, paged=True,
+            quant=False, carried=True, seq_sharded=False, hoist_view=False,
+            anchors=(),
+            scale=cfg.d_head ** -0.5 if cfg.kv_pack > 1 else None,
+        )
+        return hybrid_layers(
+            params, x, positions, cache, cfg, attend, stats)
     paged = isinstance(cache, (PagedKVCache, PagedQuantKVCache))
     quant = isinstance(cache, (QuantKVCache, PagedQuantKVCache))
 
@@ -1207,160 +1567,11 @@ def _step_layers(
     # contiguous caches.
     carried = paged and not seq_sharded
 
-    def attend(gi, q, k_new, v_new, k_cache, v_cache, k_s, v_s, views,
-               l, base):
-        """The attention half of a layer for group ``gi``: its new K/V
-        rows into the cache, its queries against what the cache then
-        holds. Returns the heads' output and the cache arrays."""
-        g = groups[gi]
-        B, Tq = g.batch, g.tq
-        start, n_valid = g.start, g.n_valid
-        k_view = v_view = None
-        if hoist_view:
-            k_view, v_view = views[2 * gi:2 * gi + 2]
-        # Write member i's new rows at its own [start[i], start[i]+Tq): a
-        # vmapped dynamic-update over batch (per-slot token offsets). Under
-        # a mesh GSPMD turns it into per-shard masked writes on the seq dim.
-        # Quantized caches quantize the rows first — under the per-slot
-        # frozen scales (contiguous) or the per-block anchor scale
-        # (paged; entered blocks inherit it, see above).
-        k_deq = v_deq = None
-        k_sf = v_sf = None
-        if quant and paged:
-            anchor_pb, write_pb, entered = anchors[gi]
-            # The scales as (blocks, Hkv) rows: every layer's when carried
-            # (a bitcast), this layer's (base 0) when scanned.
-            hkv = k_s.shape[-1]
-            k_sf, v_sf = k_s.reshape(-1, hkv), v_s.reshape(-1, hkv)
-            k_anchor = k_sf[base + anchor_pb][:, :, None, None]
-            v_anchor = v_sf[base + anchor_pb][:, :, None, None]  # (B,Hkv,1,1)
-            k_new = _quantize_rows(k_new, k_anchor)
-            v_new = _quantize_rows(v_new, v_anchor)
-            vals_k = jnp.broadcast_to(
-                k_anchor[:, None, :, 0, 0], (B, Tq, hkv)
-            ).reshape(-1, hkv)
-            vals_v = jnp.broadcast_to(
-                v_anchor[:, None, :, 0, 0], (B, Tq, hkv)
-            ).reshape(-1, hkv)
-            # Rows that enter no block scatter past every layer and drop.
-            scale_tgt = jnp.where(
-                entered, base + write_pb, k_sf.shape[0]
-            ).reshape(-1)
-            k_sf = k_sf.at[scale_tgt].set(vals_k, mode="drop")
-            v_sf = v_sf.at[scale_tgt].set(vals_v, mode="drop")
-            k_s, v_s = k_sf.reshape(k_s.shape), v_sf.reshape(v_s.shape)
-            if hoist_view:
-                # The view holds DEQUANTIZED rows: mirror exactly what
-                # the pool now holds (quantize-then-dequantize), so
-                # attention over the view == attention over the pool.
-                k_deq = (
-                    k_new.astype(jnp.float32) * k_anchor
-                ).astype(k_view.dtype)
-                v_deq = (
-                    v_new.astype(jnp.float32) * v_anchor
-                ).astype(v_view.dtype)
-        elif quant:
-            k_new = _quantize_rows(k_new, k_s)
-            v_new = _quantize_rows(v_new, v_s)
-        if paged:
-            # Paged write: scatter through the block table — valid rows
-            # land in their slot's mapped blocks, padded rows drop. The
-            # contiguous path's window clamp machinery is unnecessary
-            # here (see _paged_pool_write).
-            if seq_sharded:
-                k_cache = _paged_pool_write_seq(
-                    k_cache, k_new, g.table, start, n_valid,
-                    mesh=mesh, seq_axis=axes["seq"],
-                )
-                v_cache = _paged_pool_write_seq(
-                    v_cache, v_new, g.table, start, n_valid,
-                    mesh=mesh, seq_axis=axes["seq"],
-                )
-            else:
-                k_cache = _paged_pool_write(
-                    k_cache, k_new, g.table, start, n_valid, l
-                )
-                v_cache = _paged_pool_write(
-                    v_cache, v_new, g.table, start, n_valid, l
-                )
-            if hoist_view:
-                # Mirror the new rows into the hoisted logical view (the
-                # pre-scan gather predates this layer's write) — a cheap
-                # Tq-row window write, vs re-gathering the whole pool.
-                wv = jax.vmap(_masked_window_write, in_axes=(0, 0, 0, 0))
-                mk = k_new if k_deq is None else k_deq
-                mv = v_new if v_deq is None else v_deq
-                k_view = wv(
-                    k_view, mk.astype(k_view.dtype), start, n_valid
-                )
-                v_view = wv(
-                    v_view, mv.astype(v_view.dtype), start, n_valid
-                )
-        elif g.n is None:
-            write = jax.vmap(
-                lambda buf, rows, s: lax.dynamic_update_slice_in_dim(
-                    buf, rows, s, axis=1
-                )
-            )
-            k_cache = write(k_cache, k_new.astype(k_cache.dtype), start)
-            v_cache = write(v_cache, v_new.astype(v_cache.dtype), start)
-        else:
-            # Mixed-Tq masked write: only rows < n_tokens[i] may land. A
-            # plain Tq-row dynamic-update would (a) write pad garbage the
-            # causal mask has to hide until it is overwritten and (b)
-            # CLAMP near capacity (dynamic_update_slice semantics), sliding
-            # garbage over a decode slot's newest valid rows. Instead:
-            # read the Tq-row window at a clamped offset, overlay exactly
-            # the valid rows at their true absolute positions, write it
-            # back — cache bytes outside [start, start+n) are untouched.
-            write = jax.vmap(_masked_window_write, in_axes=(0, 0, 0, 0))
-            k_cache = write(
-                k_cache, k_new.astype(k_cache.dtype), start, g.n
-            )
-            v_cache = write(
-                v_cache, v_new.astype(v_cache.dtype), start, g.n
-            )
-
-        data = axes["data"]
-        if data and mesh is not None and B % mesh.shape[data]:
-            data = None  # a packed group need not divide over the batch axis
-        attn_kw = dict(
-            q_position=start,
-            mesh=mesh,
-            data_axis=data,
-            seq_axis=axes["seq"],
-            model_axis=axes["model"],
-            block_size=cfg.attn_block_size,
-            tree_mask=g.tree_mask,
-        )
-        ak, av, ak_s, av_s = k_cache, v_cache, k_s, v_s
-        if hoist_view:
-            ak, av = k_view, v_view
-        elif carried:
-            # The kernels and the reference gather take a pool and a
-            # table: hand them every layer's blocks (a bitcast of the
-            # carry) and this layer's addresses.
-            ak = k_cache.reshape((-1,) + k_cache.shape[2:])
-            av = v_cache.reshape((-1,) + v_cache.shape[2:])
-            attn_kw["block_table"] = base + g.table
-            if quant:
-                ak_s, av_s = k_sf, v_sf
-        elif paged:
-            attn_kw["block_table"] = g.table
-            attn_kw["kv_shard"] = "seq"
-        if quant and not (paged and hoist_view):
-            out, _ = decode_attention(
-                q, ak, av, k_scale=ak_s, v_scale=av_s,
-                quant_kernel=quant_kernel, **attn_kw,
-            )
-        else:
-            # Exact caches — and the paged-quant DEQUANTIZED view (the
-            # off-kernel path; see the hoist_view comment above).
-            out, _ = decode_attention(
-                q, ak, av,
-                impl=cfg.attn_impl, num_splits=num_splits, **attn_kw,
-            )
-        return out, k_cache, v_cache, k_s, v_s
+    attend = _Attend(
+        groups=groups, cfg=cfg, mesh=mesh, axes=axes, num_splits=num_splits,
+        quant_kernel=quant_kernel, paged=paged, quant=quant, carried=carried,
+        seq_sharded=seq_sharded, hoist_view=hoist_view, anchors=anchors,
+    )
 
     def body(carry, xs):
         parts = list(xs)
@@ -1382,20 +1593,9 @@ def _step_layers(
             views, parts = parts[:len(views0)], parts[len(views0):]
         if quant and not carried:
             k_s, v_s = parts
-        h = rms_norm(x, layer["ln1"], cfg.norm_eps)
-        q = _heads(h @ layer["wq"], cfg.n_heads, cfg.d_head)
-        k_new = _heads(h @ layer["wk"], cfg.n_kv_heads, cfg.d_head)
-        v_new = _heads(h @ layer["wv"], cfg.n_kv_heads, cfg.d_head)
-        q = rope(q, positions, cfg.rope_theta)
-        k_new = rope(k_new, positions, cfg.rope_theta)
-        outs = []
-        for gi, g in enumerate(groups):
-            out, k_cache, v_cache, k_s, v_s = attend(
-                gi, g.take(q), g.take(k_new), g.take(v_new),
-                k_cache, v_cache, k_s, v_s, views, l, base,
-            )
-            outs.append(out)
-        x = x + _unheads(_join_rows(groups, outs)) @ layer["wo"]
+        x, k_cache, v_cache, k_s, v_s = gqa_mixer(
+            attend, layer, x, positions, k_cache, v_cache, k_s, v_s, views,
+            l, base)
         x = x + _mlp_block(layer, rms_norm(x, layer["ln2"], cfg.norm_eps))
         new = (k_cache, v_cache)
         if paged and quant:
@@ -1447,16 +1647,22 @@ def forward_step(
 
     The layer body (:func:`_step_layers`, shared with
     :func:`forward_packed_step`; this step is its one-group case) is
-    chosen by the model data: the rotary-GQA block with the dense SwiGLU,
-    or, where ``cfg.mla`` / ``cfg.moe`` are set, latent attention against
-    a :class:`PagedLatentCache` with a leading stack of dense-FFN layers
-    and a stack of expert layers (:func:`_latent_layers`). Checks,
-    positions, the embedding, the final norm, the head and the length
-    bookkeeping are the same code for both.
+    chosen by the model data: the rotary-GQA block with the dense SwiGLU;
+    where ``cfg.mla`` is set, latent attention against a
+    :class:`PagedLatentCache` with a leading stack of dense-FFN layers
+    and a stack of expert layers (:func:`_latent_layers`); where the
+    layers are of several kinds (``cfg.layer_types``: short-convolution
+    mixers beside rotary-GQA ones) or expert layers lie under rotary GQA,
+    the loop over runs of layers of one kind against a
+    :class:`PagedHybridCache` (``models/hybrid.py``). Checks, positions,
+    the embedding, the final norm, the head and the length bookkeeping
+    are the same code for all.
     ``stats``, if given, is filled with the step's counters as traced
     arrays (read them in the same trace): ``expert_rows`` ``(expert
     layers, held + 1)`` int32, the valid rows routed to each held expert
-    and, last, the pairs routed to experts held elsewhere.
+    and, last, the pairs routed to experts held elsewhere; for a model
+    with conv layers ``tail_blocks`` (a scalar), the block tails the
+    step wrote over all conv layers.
 
     ``kv_shard="seq"`` (paged caches under a >1-way ``seq_axis`` mesh
     only — see :func:`init_paged_cache`) declares the pool
@@ -1531,14 +1737,15 @@ def forward_step(
 
     B, Tq = tokens.shape
     start = cache.length  # (B,) per-slot offsets
-    latent = isinstance(cache, PagedLatentCache)
+    own_pool = isinstance(cache, (PagedLatentCache, PagedHybridCache))
     _check_block_cache(cache, cfg)
-    if latent and (tree_mask is not None or kv_shard == "seq"):
+    if own_pool and (tree_mask is not None or kv_shard == "seq"):
         raise ValueError(
-            "the latent kernel takes no tree_mask and no sequence-sharded "
-            "pool"
+            f"a {cfg.cache_kind} pool takes no tree_mask (the latent "
+            f"kernel has none; a conv tail cannot roll a draft back) and "
+            f"is not sequence-sharded"
         )
-    paged = latent or isinstance(cache, (PagedKVCache, PagedQuantKVCache))
+    paged = own_pool or isinstance(cache, (PagedKVCache, PagedQuantKVCache))
     if not paged and n_tokens is not None and Tq > cache.capacity:
         # The masked write is a Tq-row window into the token axis; a window
         # wider than the buffer cannot be placed at any offset.
@@ -1602,8 +1809,7 @@ def forward_step(
         num_splits=num_splits, quant_kernel=quant_kernel, kv_shard=kv_shard,
         stats=stats,
     )
-    x = rms_norm(x, params["ln_f"], cfg.norm_eps)
-    logits = (x @ params["wout"]).astype(jnp.float32)
+    logits = unembed(params, rms_norm(x, params["ln_f"], cfg.norm_eps))
     grew = Tq if n_tokens is None else n_tokens
     return logits, dataclasses.replace(cache, length=start + grew, **pools)
 
@@ -1615,7 +1821,8 @@ def forward_packed_step(
     chunk_n: jax.Array,
     tokens: jax.Array,
     n_tokens: jax.Array,
-    cache: Union[PagedKVCache, PagedQuantKVCache, PagedLatentCache],
+    cache: Union[PagedKVCache, PagedQuantKVCache, PagedLatentCache,
+                 PagedHybridCache],
     cfg: TransformerConfig,
     *,
     mesh: Optional[Mesh] = None,
@@ -1659,17 +1866,19 @@ def forward_packed_step(
     axes = prune_axes(
         mesh, {"data": data_axis, "seq": seq_axis, "model": model_axis}
     )
-    latent = isinstance(cache, PagedLatentCache)
-    if not (latent or isinstance(cache, (PagedKVCache, PagedQuantKVCache))):
+    own_pool = isinstance(cache, (PagedLatentCache, PagedHybridCache))
+    if not (own_pool
+            or isinstance(cache, (PagedKVCache, PagedQuantKVCache))):
         raise ValueError(
             "a packed step's groups are views of ONE paged pool; got "
             f"{type(cache).__name__}"
         )
     _check_block_cache(cache, cfg)
-    if kv_shard not in ("replicated", "seq") or (latent and kv_shard == "seq"):
+    if kv_shard not in ("replicated", "seq") or (
+            own_pool and kv_shard == "seq"):
         raise ValueError(
-            f"kv_shard must be 'replicated' or 'seq' (a latent pool: "
-            f"'replicated'), got {kv_shard!r}"
+            f"kv_shard must be 'replicated' or 'seq' (a latent or hybrid "
+            f"pool: 'replicated'), got {kv_shard!r}"
         )
     C, Tq = chunk_tokens.shape
     S = cache.table.shape[0]
@@ -1698,8 +1907,8 @@ def forward_packed_step(
         jnp.arange(C, dtype=jnp.int32) * Tq + jnp.maximum(chunk_n - 1, 0),
         mode="drop",
     )
-    last = rms_norm(x[0, src], params["ln_f"], cfg.norm_eps)
-    logits = (last @ params["wout"]).astype(jnp.float32)
+    logits = unembed(
+        params, rms_norm(x[0, src], params["ln_f"], cfg.norm_eps))
     new_len = (length + n_tokens).at[chunk_slot].add(chunk_n)
     return logits, dataclasses.replace(cache, length=new_len, **pools)
 
@@ -2037,6 +2246,7 @@ def decode_attention(
     block_table: Optional[jax.Array] = None,
     tree_mask: Optional[jax.Array] = None,
     kv_shard: str = "replicated",
+    scale: Optional[float] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Op-level decode entry: split-KV on one device, tree merge on a mesh.
 
@@ -2061,6 +2271,11 @@ def decode_attention(
     quant = k_scale is not None
     if quant and v_scale is None or (not quant and v_scale is not None):
         raise ValueError("pass both k_scale and v_scale, or neither")
+    if scale is not None and (quant or kv_shard == "seq" or (
+            mesh is not None and block_table is None)):
+        raise ValueError(
+            "a softmax scale other than D^-1/2 (rows of packed heads) is "
+            "taken by the exact single-device paths only")
     ax = prune_axes(
         mesh, {"data": data_axis, "seq": seq_axis, "model": model_axis}
     )
@@ -2106,7 +2321,7 @@ def decode_attention(
         return flash_decode(
             q, k, v, q_position=q_position, num_splits=num_splits,
             block_size=block_size, block_table=block_table,
-            tree_mask=tree_mask,
+            tree_mask=tree_mask, scale=scale,
         )
     if q_position is None:
         q_position = k.shape[2] - q.shape[2]
@@ -2152,7 +2367,7 @@ def decode_attention(
         )
     return flash_decode(
         q, k, v, q_position=q_position, num_splits=num_splits,
-        block_size=block_size, tree_mask=tree_mask,
+        block_size=block_size, tree_mask=tree_mask, scale=scale,
     )
 
 

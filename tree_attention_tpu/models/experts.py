@@ -1,6 +1,7 @@
 """A routed-expert feed-forward layer that is told which experts it holds.
 
-The router scores every expert at its published width in float32; the top
+The router scores every expert at its published width in float32 (a softmax
+over the experts, or each expert's own sigmoid); the top
 ``per_token`` are chosen (group-limited: the best ``top_groups`` of
 ``n_groups`` equal groups first, a group scored by its best expert; or
 corrected: the top of ``scores + bias``, weighted by the scores themselves;
@@ -45,8 +46,8 @@ Params = Dict[str, Any]
 
 def route(scores: jax.Array, ex: ExpertLayer,
           bias: Optional[jax.Array] = None) -> Tuple[jax.Array, jax.Array]:
-    """``scores`` ``(R, n_experts)`` float32 (the softmax of the router's
-    logits) -> chosen experts ``(R, per_token)`` int32 and their weights
+    """``scores`` ``(R, n_experts)`` float32 (:func:`router_scores`) ->
+    chosen experts ``(R, per_token)`` int32 and their weights
     ``(R, per_token)`` float32. With ``bias`` ``(n_experts,)`` the choice
     is the top of ``scores + bias``; the weights are the chosen experts'
     uncorrected scores."""
@@ -73,13 +74,17 @@ def _weigh(w: jax.Array, ex: ExpertLayer) -> jax.Array:
     return w * ex.scale
 
 
-def router_scores(p: Params, x: jax.Array) -> jax.Array:
-    """Softmax over the router's logits, float32 at the highest matmul
-    precision: a near-tie decided otherwise changes which expert a row
-    visits."""
+def router_scores(p: Params, x: jax.Array,
+                  scoring: str = "softmax") -> jax.Array:
+    """The router's scores by ``ExpertLayer.scoring``: a softmax over the
+    router's logits, or each logit's sigmoid. Float32 at the highest
+    matmul precision either way: a near-tie decided otherwise changes
+    which expert a row visits."""
     logits = jnp.matmul(x.astype(jnp.float32),
                         p["router"].astype(jnp.float32),
                         precision=lax.Precision.HIGHEST)
+    if scoring == "sigmoid":
+        return jax.nn.sigmoid(logits)
     return jax.nn.softmax(logits, axis=-1)
 
 
@@ -113,7 +118,7 @@ def expert_layer(p: Params, x: jax.Array, ex: ExpertLayer, *,
     R, K = B * T, ex.per_token
     xf = x.reshape(R, D)
     x_in = xf if router_input is None else router_input.reshape(R, D)
-    idx, w = route(router_scores(p, x_in), ex,
+    idx, w = route(router_scores(p, x_in, ex.scoring), ex,
                    p["router_bias"] if ex.corrected else None)
     local = idx - ex.held_first
     here = (local >= 0) & (local < ex.held)

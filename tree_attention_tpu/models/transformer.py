@@ -29,6 +29,7 @@ TPU-first design choices:
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -109,6 +110,9 @@ class ExpertLayer:
     # The top ``per_token`` are taken of ``scores + bias`` (a per-expert
     # correction held with the weights); the weights stay the scores.
     corrected: bool = False
+    # What the router makes of its logits: a softmax over the experts, or
+    # each expert's own sigmoid.
+    scoring: str = "softmax"
     # None: the expert layer stands in the layer's FFN's place. ``(leaves,
     # rejoins)``: a branch beside the dense FFNs, computed from the normed
     # residual that sublayer ``leaves``'s FFN reads and added to the
@@ -116,6 +120,10 @@ class ExpertLayer:
     branch: Optional[Tuple[int, int]] = None
 
     def __post_init__(self):
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"router scoring {self.scoring!r}: 'softmax' and 'sigmoid' "
+                f"are built")
         if self.n_experts % self.n_groups:
             raise ValueError(
                 f"{self.n_experts} experts do not split into "
@@ -144,13 +152,22 @@ class ExpertLayer:
 class TransformerConfig:
     """Static architecture hyperparameters (hashable: usable as a jit static).
 
-    The block is chosen by the data: ``mla`` set means latent attention in
-    every layer (else rotary GQA), ``moe`` set means every layer after its
-    ``first_dense`` is a routed-expert layer (else the dense SwiGLU). A
-    layer is ``sublayers`` pairs of one attention and one dense FFN; with
-    more than one, the routed experts are a branch beside them
-    (``moe.branch``). The cache holds one layer of rows per attention:
-    ``cache_layers``, not ``n_layers``, is its depth.
+    Every layer is a mixer and a feed-forward half, and the data says
+    which. The mixer: ``mla`` set means latent attention in every layer;
+    else ``layer_types`` names each layer's (``"attention"``: rotary GQA,
+    with an RMSNorm over each query and key head where ``qk_norm``;
+    ``"conv"``: a gated short convolution of ``conv_taps`` taps), None
+    meaning rotary GQA throughout. The feed-forward half: ``moe`` set means
+    every layer after its ``first_dense`` is a routed-expert layer (else
+    the dense SwiGLU). A latent layer may be ``sublayers`` pairs of one
+    attention and one dense FFN; with more than one, the routed experts are
+    a branch beside them (``moe.branch``). ``tied_head``: the head is the
+    embedding's transpose and the parameters hold no ``wout``.
+
+    What is cached follows (``cache_kind``): K/V rows for every attention
+    (``cache_layers`` of them, not ``n_layers``), one latent row where
+    ``mla`` is set, and for every conv layer (``conv_layers``) the last
+    ``conv_taps - 1`` gated inputs, held as a tail of each pool block.
     """
 
     vocab_size: int = 32768
@@ -175,6 +192,10 @@ class TransformerConfig:
     mla: Optional[LatentAttention] = None
     moe: Optional[ExpertLayer] = None
     sublayers: int = 1
+    layer_types: Optional[Tuple[str, ...]] = None
+    conv_taps: int = 3
+    qk_norm: bool = False
+    tied_head: bool = False
 
     def __post_init__(self):
         if self.n_heads % self.n_kv_heads:
@@ -182,6 +203,24 @@ class TransformerConfig:
                 f"n_heads ({self.n_heads}) must be a multiple of "
                 f"n_kv_heads ({self.n_kv_heads})"
             )
+        if self.layer_types is not None:
+            kinds = set(self.layer_types)
+            if len(self.layer_types) != self.n_layers \
+                    or not kinds <= {"attention", "conv"}:
+                raise ValueError(
+                    f"layer_types names {len(self.layer_types)} layers of "
+                    f"kinds {sorted(kinds)}: one of 'attention' / 'conv' "
+                    f"for each of the {self.n_layers} layers")
+            if self.mla is not None or self.sublayers > 1:
+                raise ValueError(
+                    "layers of several kinds are built over rotary-GQA "
+                    "attention, one mixer a layer: not with latent "
+                    "attention or several sublayers")
+            if "conv" in kinds and self.conv_taps != 3:
+                raise ValueError(
+                    f"a short convolution of {self.conv_taps} taps: its "
+                    f"state is built as the last 2 gated inputs, a two-row "
+                    f"tail of each pool block (3 taps)")
         branch = self.moe.branch if self.moe is not None else None
         if (self.sublayers > 1 and branch is None) or (
                 branch is not None and (
@@ -202,14 +241,44 @@ class TransformerConfig:
         return self.n_kv_heads * self.d_head
 
     @property
+    def conv_layers(self) -> int:
+        """Layers whose mixer is the short convolution: the tail pool's
+        depth."""
+        return (self.layer_types or ()).count("conv")
+
+    @property
     def dense_block(self) -> bool:
         """The Llama-style block this module's ``forward`` computes."""
-        return self.mla is None and self.moe is None
+        return self.mla is None and self.moe is None \
+            and not self.conv_layers
+
+    @property
+    def cache_kind(self) -> str:
+        """What serving caches: ``"kv"`` (K/V rows, the dense block),
+        ``"latent"`` (one latent row a token) or ``"hybrid"`` (K/V rows
+        for the attention layers beside a two-row tail a block for the
+        conv layers; also rotary-GQA attention over expert layers)."""
+        if self.mla is not None:
+            return "latent"
+        return "kv" if self.dense_block else "hybrid"
+
+    @property
+    def kv_pack(self) -> int:
+        """KV heads the hybrid pool lays side by side on a row's lanes: a
+        head of fewer than the chip's 128 lanes is packed with its
+        neighbours, ``d_head x kv_pack`` lanes a row (a query is then its
+        head's values in its KV head's lanes and zeros beside them). A
+        pool whose rows are 64 lanes wide the TPU compiler holds in a
+        layout of its own and copies, whole, to the kernel's and back in
+        every tick (PERF.md, PR 33). No option: 1 for every other cache."""
+        if self.cache_kind != "hybrid" or 128 % self.d_head:
+            return 1
+        return math.gcd(128 // self.d_head, self.n_kv_heads)
 
     @property
     def cache_layers(self) -> int:
         """The cache's depth: one layer of rows for every attention."""
-        return self.n_layers * self.sublayers
+        return (self.n_layers - self.conv_layers) * self.sublayers
 
     @property
     def n_dense_layers(self) -> int:
@@ -226,33 +295,64 @@ def _key(c: Dict[str, Any], *names: str) -> Any:
     raise KeyError(" / ".join(names))
 
 
+# The published keys with "expert" in their name that ``model_from_config``
+# reads (a family's alias beside the first family's name).
+_EXPERT_KEYS = frozenset({
+    "n_routed_experts", "num_experts", "n_shared_experts",
+    "num_experts_per_tok", "expert_ffn_hidden_size", "zero_expert_num",
+    "zero_expert_type", "use_expert_bias",
+})
+
+
 def model_from_config(config: Dict[str, Any], *, dtype: Any = None,
                       max_seq_len: int = 65536,
                       **overrides: Any) -> TransformerConfig:
     """The model as data: a :class:`TransformerConfig` from a model's
     published ``config.json`` keys, under the names its family publishes
-    them (layers: ``num_hidden_layers`` / ``num_layers``; the dense FFN:
-    ``intermediate_size`` / ``ffn_hidden_size``; an expert's:
-    ``moe_intermediate_size`` / ``expert_ffn_hidden_size``; experts a
-    token: ``num_experts_per_tok`` / ``moe_topk``). ``kv_lora_rank``
-    selects latent attention (``mla_scale_q_lora`` / ``mla_scale_kv_lora``:
-    the normed latents times ``(hidden / rank)^1/2``), ``n_routed_experts``
-    the expert layer (``zero_expert_num`` identity experts after the
-    routed ones in the router's width); without them the keys are those of
-    a Llama-style dense decoder.
+    them. What the data chooses, and the keys read for it:
+
+    - depth and widths: ``num_hidden_layers`` / ``num_layers``,
+      ``hidden_size``, ``num_attention_heads``, ``num_key_value_heads``,
+      ``head_dim``, ``vocab_size``; the dense FFN's ``intermediate_size`` /
+      ``ffn_hidden_size``; ``rms_norm_eps`` / ``norm_eps``; ``rope_theta``
+      (or ``rope_parameters.rope_theta``); ``tie_word_embeddings`` /
+      ``tie_embedding`` (the head is the embedding's transpose).
+    - each layer's mixer: ``kv_lora_rank`` selects latent attention in
+      every layer (``q_lora_rank``, ``qk_nope_head_dim``,
+      ``qk_rope_head_dim``, ``v_head_dim``, ``rope_scaling``;
+      ``mla_scale_q_lora`` / ``mla_scale_kv_lora``: the normed latents
+      times ``(hidden / rank)^1/2``). Else ``layer_types`` names every
+      layer's: ``full_attention`` / ``attention`` (rotary GQA) or ``conv``
+      (a gated short convolution: ``conv_L_cache`` taps, ``conv_bias``
+      false); without it every layer is rotary GQA.
+    - each layer's feed-forward half: ``n_routed_experts`` /
+      ``num_experts`` select the expert layer after the first
+      ``first_k_dense_replace`` / ``num_dense_layers`` layers (an expert's
+      width ``moe_intermediate_size`` / ``expert_ffn_hidden_size``; experts
+      a token ``num_experts_per_tok`` / ``moe_topk``; ``n_shared_experts``;
+      ``zero_expert_num`` identity experts after the routed ones in the
+      router's width; ``topk_method`` with ``n_group`` / ``topk_group``;
+      ``routed_scaling_factor``, ``norm_topk_prob``; the scoring
+      ``scoring_func``: ``softmax`` | ``sigmoid``; ``use_expert_bias``: the
+      top is taken of scores + a per-expert bias). A key that names
+      experts and is none of these is refused by name: a file whose
+      experts this reader cannot see is never built dense.
+
+    Without any of them the keys are those of a Llama-style dense decoder.
 
     What no published key says is said by two groups of this repo's own. A
     file cut to one chip's share says so under ``deployment``:
     ``experts_total`` (the routed experts of the whole layer;
     ``n_routed_experts`` is then how many are held here) and
     ``expert_share`` (which share of them, 0-based; consecutive ranges).
-    ``vocab_size`` is the rows held. A layer that is not one attention and
-    one FFN says what it is under ``block``: ``sublayers`` (attention +
-    dense-FFN pairs a layer), ``routed_branch`` ``[leaves, rejoins]`` (the
-    routed experts are computed beside the dense FFNs from the normed
-    residual sublayer ``leaves``'s FFN reads, and added after sublayer
-    ``rejoins``'s FFN) and ``corrected_choice`` (the top experts are taken
-    of scores + a per-expert bias)."""
+    ``vocab_size`` is the rows held. What only the modelling code says is
+    under ``block``: ``sublayers`` (attention + dense-FFN pairs a layer),
+    ``routed_branch`` ``[leaves, rejoins]`` (the routed experts are
+    computed beside the dense FFNs from the normed residual sublayer
+    ``leaves``'s FFN reads, and added after sublayer ``rejoins``'s FFN),
+    ``corrected_choice`` (as ``use_expert_bias``), ``router_scoring`` (as
+    ``scoring_func``) and ``qk_norm`` (an RMSNorm with a learned gain over
+    each query and key head, before the rotary embedding)."""
     c = config
     heads = int(c["num_attention_heads"])
     hidden = int(c["hidden_size"])
@@ -288,15 +388,15 @@ def model_from_config(config: Dict[str, Any], *, dtype: Any = None,
             if c.get("mla_scale_kv_lora") else 1.0,
         )
         d_head, kv_heads = mla.nope + mla.rope, heads
-    if c.get("n_routed_experts"):
-        if mla is None:
-            raise ValueError(
-                "routed experts under rotary-GQA attention are not built: "
-                "an expert layer is served with latent attention")
-        if c.get("scoring_func", "softmax") != "softmax":
-            raise ValueError(
-                f"router scoring {c['scoring_func']!r}: only 'softmax' "
-                f"is built")
+    unknown = sorted(k for k in c if "expert" in k and c[k]
+                     and k not in _EXPERT_KEYS)
+    if unknown:
+        raise ValueError(
+            f"the file names experts under {unknown}, which this reader "
+            f"does not know (it reads {sorted(_EXPERT_KEYS)}): refused, "
+            f"never built as a dense model")
+    n_held = c.get("n_routed_experts") or c.get("num_experts")
+    if n_held:
         if int(c.get("moe_layer_freq", 1)) != 1:
             raise ValueError("moe_layer_freq other than 1 is not built")
         n_zero = int(c.get("zero_expert_num") or 0)
@@ -305,7 +405,7 @@ def model_from_config(config: Dict[str, Any], *, dtype: Any = None,
                 f"zero-compute experts of type {c['zero_expert_type']!r}: "
                 f"only 'identity' is built")
         dep = c.get("deployment") or {}
-        held = int(c["n_routed_experts"])
+        held = int(n_held)
         total = int(dep.get("experts_total", held))
         grouped = c.get("topk_method", "greedy") == "group_limited_greedy"
         width = int(_key(c, "moe_intermediate_size",
@@ -321,23 +421,47 @@ def model_from_config(config: Dict[str, Any], *, dtype: Any = None,
             top_groups=int(c["topk_group"]) if grouped else 1,
             scale=float(c.get("routed_scaling_factor", 1.0)),
             renorm=bool(c.get("norm_topk_prob", False)),
-            first_dense=int(c.get("first_k_dense_replace", 0)),
+            first_dense=int(c.get("first_k_dense_replace")
+                            or c.get("num_dense_layers") or 0),
             n_zero=n_zero,
-            corrected=bool(block.get("corrected_choice", False)),
+            corrected=bool(block.get("corrected_choice")
+                           or c.get("use_expert_bias")),
+            scoring=str(c.get("scoring_func")
+                        or block.get("router_scoring") or "softmax"),
             branch=None if branch is None else (int(branch[0]),
                                                 int(branch[1])),
         )
     if dtype is None:
         dtype = jnp.dtype(str(c.get("torch_dtype", "bfloat16")))
+    layer_types = None
+    if c.get("layer_types") is not None:
+        names = {"full_attention": "attention", "attention": "attention",
+                 "conv": "conv"}
+        other = sorted({str(t) for t in c["layer_types"]} - set(names))
+        if other:
+            raise ValueError(
+                f"layer_types {other}: 'full_attention' and 'conv' layers "
+                f"are built")
+        layer_types = tuple(names[str(t)] for t in c["layer_types"])
+        if c.get("conv_bias"):
+            raise ValueError("a short convolution with a bias is not built")
     kw = dict(
         vocab_size=int(c["vocab_size"]), d_model=hidden,
         n_layers=int(_key(c, "num_hidden_layers", "num_layers")),
         n_heads=heads, n_kv_heads=kv_heads, d_head=d_head,
         d_ff=int(_key(c, "intermediate_size", "ffn_hidden_size")),
         max_seq_len=max_seq_len,
-        rope_theta=float(c.get("rope_theta", 10000.0)),
-        norm_eps=float(c.get("rms_norm_eps", 1e-6)), dtype=dtype,
+        rope_theta=float(
+            c.get("rope_theta")
+            or (c.get("rope_parameters") or {}).get("rope_theta", 10000.0)),
+        norm_eps=float(c.get("rms_norm_eps") or c.get("norm_eps") or 1e-6),
+        dtype=dtype,
         mla=mla, moe=moe, sublayers=int(block.get("sublayers", 1)),
+        layer_types=layer_types,
+        conv_taps=int(c.get("conv_L_cache", 3)),
+        qk_norm=bool(block.get("qk_norm", False)),
+        tied_head=bool(c.get("tie_word_embeddings")
+                       or c.get("tie_embedding")),
     )
     kw.update(overrides)
     return TransformerConfig(**kw)
@@ -356,6 +480,10 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Params:
     scaled by ``(2·n_layers)^-1/2`` so the residual stream's variance stays O(1)
     at init regardless of depth.
     """
+    if cfg.cache_kind == "hybrid":
+        from tree_attention_tpu.models.hybrid import init_hybrid_params
+
+        return init_hybrid_params(key, cfg)
     if not cfg.dense_block:
         from tree_attention_tpu.models.experts import init_block_params
 
@@ -380,12 +508,17 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Params:
         "w3": normal(ks[5], (L, D, cfg.d_ff), std),
         "w2": normal(jax.random.fold_in(ks[5], 1), (L, cfg.d_ff, D), res_std),
     }
-    return {
+    if cfg.qk_norm:
+        layers["q_ln"] = jnp.ones((L, cfg.d_head), jnp.float32)
+        layers["k_ln"] = jnp.ones((L, cfg.d_head), jnp.float32)
+    out = {
         "embed": normal(k_embed, (cfg.vocab_size, D), std),
         "layers": layers,
         "ln_f": jnp.ones((D,), jnp.float32),
-        "wout": normal(k_out, (D, cfg.vocab_size), std),
     }
+    if not cfg.tied_head:
+        out["wout"] = normal(k_out, (D, cfg.vocab_size), std)
+    return out
 
 
 def param_specs(
@@ -407,9 +540,13 @@ def param_specs(
     del data_axis
     if not cfg.dense_block:
         raise NotImplementedError(
-            "param_specs: sharding a latent-attention / expert block's "
-            "parameters (experts over the model axis) is not built")
+            "param_specs: sharding the parameters of a model with latent "
+            "attention, experts or conv layers is not built")
     m = model_axis
+    if cfg.qk_norm or cfg.tied_head:
+        raise NotImplementedError(
+            "param_specs: sharding a model with QK-norm or a tied head is "
+            "not built")
     return {
         "embed": P(None, m),
         "layers": {
@@ -476,6 +613,32 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return rotated.astype(x.dtype)
 
 
+def unembed(params: Params, x: jax.Array) -> jax.Array:
+    """The head: ``x`` times ``wout``, or, where the parameters hold none
+    (``TransformerConfig.tied_head``), times the embedding's transpose.
+    Float32 logits."""
+    if "wout" in params:
+        return (x @ params["wout"]).astype(jnp.float32)
+    return jnp.einsum("...d,vd->...v", x, params["embed"]).astype(jnp.float32)
+
+
+def gqa_qkv(p: Params, h: jax.Array, positions: jax.Array,
+            cfg: TransformerConfig) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The rotary-GQA projections of the normed residual ``h`` ``(B, T,
+    D)``: queries ``(B, H, T, d)`` and new keys and values ``(B, Hkv, T,
+    d)``, the rotary embedding applied; where ``cfg.qk_norm``, an RMSNorm
+    over each query and key head (one learned gain of ``d`` a layer)
+    before it."""
+    q = _heads(h @ p["wq"], cfg.n_heads, cfg.d_head)
+    k = _heads(h @ p["wk"], cfg.n_kv_heads, cfg.d_head)
+    v = _heads(h @ p["wv"], cfg.n_kv_heads, cfg.d_head)
+    if cfg.qk_norm:
+        q = rms_norm(q, p["q_ln"], cfg.norm_eps)
+        k = rms_norm(k, p["k_ln"], cfg.norm_eps)
+    return (rope(q, positions, cfg.rope_theta),
+            rope(k, positions, cfg.rope_theta), v)
+
+
 def _heads(x: jax.Array, n_heads: int, d_head: int) -> jax.Array:
     """(B, T, H·D) -> (B, H, T, D) — the ops-layer layout."""
     B, T, _ = x.shape
@@ -497,11 +660,7 @@ def _attention_block(
     axes: Dict[str, Optional[str]],
     layout: str = "contiguous",
 ) -> jax.Array:
-    q = _heads(x @ p["wq"], cfg.n_heads, cfg.d_head)
-    k = _heads(x @ p["wk"], cfg.n_kv_heads, cfg.d_head)
-    v = _heads(x @ p["wv"], cfg.n_kv_heads, cfg.d_head)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
+    q, k, v = gqa_qkv(p, x, positions, cfg)
 
     if mesh is not None and mesh.shape.get(axes["seq"], 1) > 1:
         from tree_attention_tpu.parallel.tree import tree_attention
@@ -569,8 +728,8 @@ def forward(
     if not cfg.dense_block:
         raise NotImplementedError(
             "forward: the full-sequence (training) pass builds the dense "
-            "block only; a latent-attention / expert model is served "
-            "through models.decode.forward_step")
+            "block only; a model with latent attention, experts or conv "
+            "layers is served through models.decode.forward_step")
     axes = prune_axes(
         mesh, {"data": data_axis, "seq": seq_axis, "model": model_axis}
     )
@@ -615,8 +774,7 @@ def forward(
         body = jax.checkpoint(body)
     x, _ = lax.scan(body, x, params["layers"])
 
-    x = rms_norm(x, params["ln_f"], cfg.norm_eps)
-    return (x @ params["wout"]).astype(jnp.float32)
+    return unembed(params, rms_norm(x, params["ln_f"], cfg.norm_eps))
 
 
 def cross_entropy_loss(

@@ -148,6 +148,7 @@ from tree_attention_tpu.obs.metrics import percentile
 from tree_attention_tpu.obs.slo import SLOMonitor
 from tree_attention_tpu.models.decode import (
     KVCache,
+    cache_block_fixed_bytes,
     cache_token_bytes,
     compact_decode_window,
     copy_pool_block,
@@ -234,6 +235,16 @@ _MOE_PAIRS_ZERO = obs.counter(
 _MOE_PAIRS_HERE = obs.counter(
     "moe_pairs_here",
     "row-expert pairs that fell on experts held here (computed)",
+)
+_BLOCK_FIXED_BYTES = obs.gauge(
+    "serving_cache_block_fixed_bytes",
+    "bytes a pool block holds beside its tokens' rows, all layers (the conv "
+    "layers' two-row tails of a hybrid pool; 0 for every other cache)",
+)
+_TAIL_BLOCKS = obs.counter(
+    "serving_conv_tail_blocks_written_total",
+    "block tails the conv layers wrote (blocks a tick's rows fell in x conv "
+    "layers)",
 )
 _TTFT = obs.histogram(
     "serving_ttft_seconds",
@@ -900,24 +911,29 @@ class SlotServer:
             raise ValueError(
                 f"prefill_budget must be >= 1, got {prefill_budget}"
             )
-        if not cfg.dense_block:
-            # What a one-array latent pool is not built for is refused
-            # here, by name, never served wrong.
+        if cfg.cache_kind != "kv":
+            # What the latent pool and the hybrid pool are not built for
+            # is refused here, by the cache kind's name, never served
+            # wrong.
+            why_not = {
+                "latent": "the latent kernel takes no tree_mask",
+                "hybrid": "a draft that is rejected has overwritten the "
+                          "conv layers' tails, which cannot roll back",
+            }[cfg.cache_kind]
             for on, what in (
-                (quantize, "int8 latent rows (quantize=True)"),
+                (quantize, f"int8 {cfg.cache_kind} rows (quantize=True)"),
                 (kv_shard == "seq",
-                 "a sequence-sharded latent pool (kv_shard='seq')"),
+                 "a sequence-sharded pool (kv_shard='seq')"),
                 (bool(host_blocks), "the host tier (host_blocks > 0)"),
-                (speculate, "speculation (the latent kernel takes no "
-                            "tree_mask)"),
+                (speculate, f"speculation ({why_not})"),
                 (admission != "chunked",
                  "whole-prompt admission (admission='whole')"),
             ):
                 if on:
                     raise ValueError(
-                        f"a latent-attention / expert model does not serve "
-                        f"with {what}: not built for a one-array latent "
-                        f"pool")
+                        f"a model served from the {cfg.cache_kind} pool "
+                        f"(TransformerConfig.cache_kind) does not serve "
+                        f"with {what}: not built for that pool")
         self.params = params
         self.cfg = cfg
         self.slots = slots
@@ -991,9 +1007,11 @@ class SlotServer:
         # here is also in _families (the join/best-of machinery is
         # shared); the per-tick counters feed the flight recorder.
         self._tree_fams: Dict[int, _ForkFamily] = {}
-        # The latent kernel takes no tree_mask: such a model's families
-        # fork into slots on shared blocks.
-        self._tree_sampling = bool(tree_sampling) and cfg.dense_block
+        # The latent kernel takes no tree_mask, and a tree's siblings
+        # would overwrite one another's conv tails: such a model's
+        # families fork into slots on shared blocks (a fork copies its
+        # partial block, tails and all).
+        self._tree_sampling = bool(tree_sampling) and cfg.cache_kind == "kv"
         self._tick_tree_branches = 0
         self._tick_branch_retired = 0
         self._slot_shared: List[set] = [set() for _ in range(slots)]
@@ -1146,6 +1164,12 @@ class SlotServer:
         # the model built (a latent row is not 2·Hkv·D): the report's
         # ``kv.token_bytes``.
         self._kv_token_bytes = cache_token_bytes(self.cache)
+        # What a block holds beside its tokens' rows (a hybrid pool's conv
+        # tails): the report's ``kv.block_fixed_bytes``.
+        self._kv_block_fixed_bytes = cache_block_fixed_bytes(self.cache)
+        self._conv_layers = cfg.conv_layers   # the tail pool's depth
+        if obs.REGISTRY.enabled:
+            _BLOCK_FIXED_BYTES.set(self._kv_block_fixed_bytes)
         # Expert layers' row counts on the tick's fetch: (layers, what
         # ``experts.held_counts`` gives a layer), None for a model without
         # experts.
@@ -1390,11 +1414,28 @@ class SlotServer:
                                                   axis=0)
         return cache, tok_vec
 
+    def _account_step_counters(self, extra: np.ndarray) -> Dict[str, int]:
+        """Read the step's counters off the tick's fetch (the rows below
+        the slots') into the registry, and return the flight record's
+        numbers: the expert layers' (:meth:`_account_expert_rows`), then
+        ``tail_blocks_written``, the block tails the conv layers wrote."""
+        flat, out = extra.reshape(-1), {}
+        at = 0
+        if self._expert_rows_shape is not None:
+            layers, width = self._expert_rows_shape
+            at = layers * width
+            out.update(self._account_expert_rows(flat[:at]))
+        if self._conv_layers:
+            out["tail_blocks_written"] = int(flat[at])
+            if obs.REGISTRY.enabled:
+                _TAIL_BLOCKS.inc(out["tail_blocks_written"])
+        return out
+
     def _account_expert_rows(self, extra: np.ndarray) -> Dict[str, int]:
-        """Read the expert layers' row counts off the tick's fetch (the
-        rows below the slots') into the registry, and return the flight
-        record's numbers, over the rows that carry a token and all expert
-        layers. Of the held routed experts: ``expert_pairs`` (row-expert
+        """Read the expert layers' row counts off the tick's fetch into
+        the registry, and return the flight record's numbers, over the
+        rows that carry a token and all expert layers. Of the held routed
+        experts: ``expert_pairs`` (row-expert
         pairs computed here), ``experts_touched`` (held experts with >= 1
         row, summed over layers), ``expert_rows_max`` (the fullest
         expert). Of the router: ``routed_rows`` (its decisions: rows x
@@ -1469,11 +1510,15 @@ class SlotServer:
              lax.bitcast_convert_type(lp_out, jnp.int32)[:, None]],
             axis=1,
         )
-        if "expert_rows" in stats:
-            # The expert layers' row counts ride the tick's one fetch as
-            # further rows of the same array (tracing on or off: one
-            # program), ``_expert_rows_shape`` says how to read them.
-            flat = stats["expert_rows"].reshape(-1)
+        if "expert_rows" in stats or "tail_blocks" in stats:
+            # The step's counters ride the tick's one fetch as further
+            # rows of the same array (tracing on or off: one program):
+            # the expert layers' row counts (``_expert_rows_shape`` says
+            # how to read them), then the conv layers' block tails
+            # written (one number), :meth:`_account_step_counters`.
+            parts = [stats[n].reshape(-1)
+                     for n in ("expert_rows", "tail_blocks") if n in stats]
+            flat = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
             flat = jnp.pad(flat, (0, flat.shape[0] % 2))
             fused = jnp.concatenate([fused, flat.reshape(-1, 2)], axis=0)
         return nxt, lp_out, fused
@@ -4246,9 +4291,10 @@ class SlotServer:
                     self._lp_host = np.ascontiguousarray(
                         fh[:self.slots, 1]
                     ).view(np.float32)
-                    if self._expert_rows_shape is not None and (
+                    if (self._expert_rows_shape is not None
+                            or self._conv_layers) and (
                             FLIGHT.enabled or obs.REGISTRY.enabled):
-                        expert_rows = self._account_expert_rows(
+                        expert_rows = self._account_step_counters(
                             fh[self.slots:])
                 else:
                     # Awaits-only tick (a synchronous whole admission
@@ -4480,6 +4526,10 @@ class SlotServer:
                     rec["host_blocks_used"] = self._host_pool.used
                 if expert_rows is not None:
                     rec.update(expert_rows)
+                    if self._conv_layers:
+                        # Rows that carried a token x conv layers: what
+                        # the conv mixers computed.
+                        rec["conv_rows"] = p.rows_useful * self._conv_layers
                 # finish() stamps t_end as the record is built
                 # and puts the iteration's phases into it.
                 phases.finish(rec)
@@ -5229,6 +5279,8 @@ class SlotServer:
             "peak_blocks_used": self._peak_blocks_used,
             # Read from the pool the model built, all layers.
             "token_bytes": self._kv_token_bytes,
+            # What a block holds whatever its tokens (conv tails).
+            "block_fixed_bytes": self._kv_block_fixed_bytes,
         }
         if self._forks_life - fork0[0]:
             # Copy-on-write fork accounting for THIS run (ISSUE 15).
