@@ -16,6 +16,7 @@ import json
 import math
 import os
 import re
+from typing import NamedTuple
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
@@ -283,67 +284,119 @@ def _materialised(text):
                 yield m.group(1), m.group(2), m.group(3), inner
 
 
-def _compile_step(monkeypatch, config, tq, int8, packed=False):
-    from tree_attention_tpu.models import decode
+@functools.lru_cache(maxsize=None)
+def _model(name):
+    """(the configuration file, the model it says) of any of the five."""
     from tree_attention_tpu.models.transformer import (
-        TransformerConfig, init_params)
+        TransformerConfig, model_from_config)
 
     with open(os.path.join(os.path.dirname(__file__), "..", "benchmark",
-                           "configs", f"{config}.json")) as f:
+                           "configs", f"{name}.json")) as f:
         c = json.load(f)
-    hkv, serving = c["num_key_value_heads"], c["serving"]
-    cfg = TransformerConfig(
+    if name not in STEP_CONFIGS:
+        return c, model_from_config(c, max_seq_len=c["serving"]["cache_len"])
+    return c, TransformerConfig(
         vocab_size=c["vocab_size"], d_model=c["hidden_size"],
-        n_heads=c["num_attention_heads"], n_kv_heads=hkv,
+        n_heads=c["num_attention_heads"],
+        n_kv_heads=c["num_key_value_heads"],
         d_head=c["assumed"]["head_dim"], d_ff=c["intermediate_size"],
         n_layers=c["num_hidden_layers"], dtype=jnp.bfloat16)
-    blk = serving["kv_block"]
-    slots, nb = serving["slots"], serving["cache_len"] // blk
-    # A spare block a slot: the logical view (slots x nb blocks, which the
-    # Q-tiled path gathers) is then smaller than one layer of the pool, and
-    # an array of either size says which of the two it is.
-    blocks = slots * (nb + 1)
+
+
+def _pool_blocks(name):
+    """The blocks of a configuration's pool as the cases below build it.
+    The dense ones get a spare block a slot: the logical view (slots x nb
+    blocks, which the Q-tiled path gathers) is then smaller than one layer
+    of the pool, and an array of either size says which of the two it is."""
+    serving = _model(name)[0]["serving"]
+    nb = serving["cache_len"] // serving["kv_block"]
+    return serving["slots"] * (nb + (name in STEP_CONFIGS))
+
+
+class _Tick(NamedTuple):
+    text: str            # the optimized HLO
+    alias_bytes: int     # donated buffers that are the output's
+    temp_bytes: int
+
+
+@functools.lru_cache(maxsize=None)
+def _tick_program(config, tq, packed=False, int8=False, served=True):
+    """One tick program of a benchmark cell compiled for the described
+    chip, as the engine calls it: the cell's slots and pool, the cache
+    donated, the parameters in the layout the engine serves from (its own
+    ``served_layout``; ``served=False``: the outer format, which the
+    engine never hands a program). ``packed``: ``forward_packed_step`` at C
+    = 1, the engine's default, one chunk of ``tq`` beside a row a slot;
+    else ``forward_step`` with ``n_tokens``. Memoised: the cases below read
+    one compile each."""
+    from tree_attention_tpu.models import decode
+    from tree_attention_tpu.models.transformer import (
+        init_params, served_layout)
+
+    c, cfg = _model(config)
+    serving = c["serving"]
+    slots = serving["slots"]
     chip = lambda tree: jax.tree.map(
         lambda a: _s(a.shape, a.dtype), tree)
+    layout = served_layout if served else (lambda p: p)
     params = chip(jax.eval_shape(
-        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+        lambda: layout(init_params(jax.random.PRNGKey(0), cfg))))
     cache = chip(jax.eval_shape(lambda: decode.init_paged_cache(
-        cfg, slots, serving["cache_len"], blocks, block=blk, quantize=int8)))
+        cfg, slots, serving["cache_len"], _pool_blocks(config),
+        block=serving["kv_block"], quantize=int8)))
 
     def step(params, tokens, cache, n_tokens):
-        return decode.forward_step(params, tokens, cache, cfg,
-                                   n_tokens=n_tokens)
+        stats = {}
+        logits, cache = decode.forward_step(params, tokens, cache, cfg,
+                                            n_tokens=n_tokens, stats=stats)
+        return logits, cache, stats
 
     def packed_step(params, chunk, cache, members, slots_i32):
-        # C = 1, the engine's default: one chunk beside a row a slot.
-        return decode.forward_packed_step(
+        stats = {}
+        logits, cache = decode.forward_packed_step(
             params, chunk, members, members, slots_i32, slots_i32, cache,
-            cfg)
+            cfg, stats=stats)
+        return logits, cache, stats
 
-    # forward_step asks the default backend whether the kernels apply; the
+    # The step asks the default backend whether the kernels apply; the
     # backend here is the CPU, the target the described chip.
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    if packed:
-        compiled = jax.jit(packed_step, donate_argnums=(2,)).lower(
-            params, _s((1, tq), jnp.int32), cache, _s((1,), jnp.int32),
-            _s((slots,), jnp.int32)).compile()
-    else:
-        compiled = jax.jit(step, donate_argnums=(2,)).lower(
-            params, _s((slots, tq), jnp.int32), cache,
-            _s((slots,), jnp.int32)).compile()
-    layer = blocks * hkv * blk * cfg.d_head
-    view = slots * nb * hkv * blk * cfg.d_head
-    return compiled, layer, view, cfg.n_layers
+    backend, jax.default_backend = jax.default_backend, lambda: "tpu"
+    try:
+        if packed:
+            compiled = jax.jit(packed_step, donate_argnums=(2,)).lower(
+                params, _s((1, tq), jnp.int32), cache, _s((1,), jnp.int32),
+                _s((slots,), jnp.int32)).compile()
+        else:
+            compiled = jax.jit(step, donate_argnums=(2,)).lower(
+                params, _s((slots, tq), jnp.int32), cache,
+                _s((slots,), jnp.int32)).compile()
+    finally:
+        jax.default_backend = backend
+    mem = compiled.memory_analysis()
+    return _Tick(compiled.as_text(), mem.alias_size_in_bytes,
+                 mem.temp_size_in_bytes)
+
+
+def _dense_sizes(config):
+    """(elements of a layer of the K pool, of the logical view of every
+    slot's blocks, the layers) of a dense configuration's case."""
+    c, cfg = _model(config)
+    serving = c["serving"]
+    per_block = cfg.n_kv_heads * serving["kv_block"] * cfg.d_head
+    return (_pool_blocks(config) * per_block,
+            serving["slots"] * serving["cache_len"] // serving["kv_block"]
+            * per_block, cfg.n_layers)
 
 
 @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
 @pytest.mark.parametrize("tq", [1, 256])
 @pytest.mark.parametrize("config", STEP_CONFIGS)
-def test_step_keeps_the_pool_in_place(monkeypatch, config, tq, int8):
+def test_step_keeps_the_pool_in_place(config, tq, int8):
     if _chip() is None:
         pytest.skip("the v5e:2x2 topology cannot be described here")
-    compiled, layer, view, layers = _compile_step(monkeypatch, config, tq, int8)
-    text = compiled.as_text()
+    tick = _tick_program(config, tq, int8=int8)
+    layer, view, layers = _dense_sizes(config)
+    text = tick.text
     # The kernels (flash_decode_paged*, or flash_fwd for a bf16 chunk), not
     # the hoisted view of the CPU's runs.
     assert pallas_kernels(text), "no Pallas kernel in the compiled step"
@@ -371,11 +424,10 @@ def test_step_keeps_the_pool_in_place(monkeypatch, config, tq, int8):
         assert not views, views
     # In place: the donated K and V pools are the output's buffers, and a
     # decode tick needs no scratch as large as a layer of the pool.
-    mem = compiled.memory_analysis()
     pool_bytes = layers * layer * (1 if int8 else 2)
-    assert mem.alias_size_in_bytes >= 2 * pool_bytes, mem
+    assert tick.alias_bytes >= 2 * pool_bytes, tick.alias_bytes
     if tq == 1:
-        assert mem.temp_size_in_bytes < layer * (1 if int8 else 2), mem
+        assert tick.temp_bytes < layer * (1 if int8 else 2), tick.temp_bytes
 
 
 # -- the packed tick: only the rows that carry a token (ISSUE 30) -----------
@@ -413,12 +465,12 @@ def _padding_arrays(text, slots, tq, vocab, d_model, packed_rows=None):
 @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
 @pytest.mark.parametrize("tq", [256, 16])
 @pytest.mark.parametrize("config", STEP_CONFIGS)
-def test_packed_tick_computes_only_its_rows(monkeypatch, config, tq, int8):
+def test_packed_tick_computes_only_its_rows(config, tq, int8):
     if _chip() is None:
         pytest.skip("the v5e:2x2 topology cannot be described here")
-    compiled, layer, _, layers = _compile_step(
-        monkeypatch, config, tq, int8, packed=True)
-    text = compiled.as_text()
+    tick = _tick_program(config, tq, packed=True, int8=int8)
+    layer, _, layers = _dense_sizes(config)
+    text = tick.text
     kernels = pallas_kernels(text)
     # The chunk group's kernel and the decode group's: Q-tiled flash_fwd
     # for a bf16 chunk of 128 rows or more, the paged decode kernels below
@@ -441,12 +493,9 @@ def test_packed_tick_computes_only_its_rows(monkeypatch, config, tq, int8):
     assert not moved, moved
     # K's and V's, for the chunk group and for the decode group.
     assert len(writes) == 4, writes
-    mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes >= 2 * layers * layer * (1 if int8 else 2)
+    assert tick.alias_bytes >= 2 * layers * layer * (1 if int8 else 2)
 
-    with open(os.path.join(os.path.dirname(__file__), "..", "benchmark",
-                           "configs", f"{config}.json")) as f:
-        c = json.load(f)
+    c = _model(config)[0]
     padding = _padding_arrays(text, c["serving"]["slots"], tq,
                               c["vocab_size"], c["hidden_size"])
     assert not padding, padding
@@ -468,20 +517,11 @@ def test_packed_tick_computes_only_its_rows(monkeypatch, config, tq, int8):
 LATENT_CONFIGS = ("deepseek-v2", "longcat-flash-omni")
 
 
-def _latent_config(name):
-    from tree_attention_tpu.models.transformer import model_from_config
-
-    with open(os.path.join(os.path.dirname(__file__), "..", "benchmark",
-                           "configs", f"{name}.json")) as f:
-        c = json.load(f)
-    return c, model_from_config(c, max_seq_len=c["serving"]["cache_len"])
-
-
 def _mla_kernel(name, tq):
     from tree_attention_tpu.ops.pallas_decode import (
         attention_pallas_mla_paged)
 
-    c, cfg = _latent_config(name)
+    c, cfg = _model(name)
     row = cfg.mla.row                      # 576 values on 640 lanes
     slots, blk = c["serving"]["slots"], c["serving"]["kv_block"]
     nb = c["serving"]["cache_len"] // blk
@@ -499,7 +539,7 @@ def _mla_kernel(name, tq):
 def _moe_kernel(name, m):
     from tree_attention_tpu.ops.pallas_moe import grouped_matmul
 
-    c, cfg = _latent_config(name)
+    c, cfg = _model(name)
     d, f, e = cfg.d_model, cfg.moe.width, 4 * cfg.moe.held
 
     def fn(x, w1, w3, w2, sizes, first):
@@ -543,47 +583,19 @@ def test_latent_and_expert_kernels_compile_for_v5e(config, case):
 @pytest.mark.parametrize("config, held_params", [
     ("deepseek-v2", 5.16e9), ("longcat-flash-omni", 5.17e9)])
 def test_latent_step_compiles_and_keeps_the_pool_in_place(
-        monkeypatch, config, held_params, tq, packed):
+        config, held_params, tq, packed):
     if _chip() is None:
         pytest.skip("the v5e:2x2 topology cannot be described here")
-    from tree_attention_tpu.models import decode
     from tree_attention_tpu.models.transformer import init_params
 
-    c, cfg = _latent_config(config)
+    c, cfg = _model(config)
     slots, blk = c["serving"]["slots"], c["serving"]["kv_block"]
-    blocks = slots * c["serving"]["cache_len"] // blk
-    chip = lambda tree: jax.tree.map(lambda a: _s(a.shape, a.dtype), tree)
-    params = chip(jax.eval_shape(
-        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    blocks = _pool_blocks(config)
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
     assert sum(math.prod(a.shape) for a in jax.tree.leaves(params)) \
         == pytest.approx(held_params, rel=0.01)
-    cache = chip(jax.eval_shape(lambda: decode.init_paged_cache(
-        cfg, slots, c["serving"]["cache_len"], blocks, block=blk)))
-
-    def step(params, tokens, cache, n_tokens):
-        stats = {}
-        logits, cache = decode.forward_step(params, tokens, cache, cfg,
-                                            n_tokens=n_tokens, stats=stats)
-        return logits, cache, stats["expert_rows"]
-
-    def packed_step(params, chunk, cache, members, slots_i32):
-        # The tick with a prompt chunk (ISSUE 30): C = 1 beside a row a slot.
-        stats = {}
-        logits, cache = decode.forward_packed_step(
-            params, chunk, members, members, slots_i32, slots_i32, cache,
-            cfg, stats=stats)
-        return logits, cache, stats["expert_rows"]
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    if packed:
-        compiled = jax.jit(packed_step, donate_argnums=(2,)).lower(
-            params, _s((1, tq), jnp.int32), cache, _s((1,), jnp.int32),
-            _s((slots,), jnp.int32)).compile()
-    else:
-        compiled = jax.jit(step, donate_argnums=(2,)).lower(
-            params, _s((slots, tq), jnp.int32), cache,
-            _s((slots,), jnp.int32)).compile()
-    text = compiled.as_text()
+    tick = _tick_program(config, tq, packed=packed)
+    text = tick.text
     kernels = pallas_kernels(text)
     assert "mla_decode_paged" in kernels and "moe_grouped_matmul" in kernels
     if packed:
@@ -611,10 +623,9 @@ def test_latent_step_compiles_and_keeps_the_pool_in_place(
             moved.append((name, opcode, result))
     # No copy of the pool, no slice of a layer's experts out of their stack.
     assert not moved, moved
-    mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes >= pool * 2, mem
+    assert tick.alias_bytes >= pool * 2, tick.alias_bytes
     if tq == 1:
-        assert mem.temp_size_in_bytes < pool * 2, mem
+        assert tick.temp_bytes < pool * 2, tick.temp_bytes
 
 
 # -- the hybrid pool: conv tails beside K/V rows of 64-lane heads (ISSUE 33) -
@@ -634,49 +645,24 @@ def test_latent_step_compiles_and_keeps_the_pool_in_place(
 
 @pytest.mark.parametrize("tq,packed", [(1, False), (256, True), (16, True)],
                          ids=["tq1", "packed256", "packed16"])
-def test_hybrid_step_compiles_and_keeps_the_pools_in_place(
-        monkeypatch, tq, packed):
+def test_hybrid_step_compiles_and_keeps_the_pools_in_place(tq, packed):
     if _chip() is None:
         pytest.skip("the v5e:2x2 topology cannot be described here")
     from tree_attention_tpu.models import decode
     from tree_attention_tpu.models.transformer import init_params
 
-    c, cfg = _latent_config("lfm2-8b-a1b")
+    c, cfg = _model("lfm2-8b-a1b")
     slots, blk = c["serving"]["slots"], c["serving"]["kv_block"]
-    blocks = slots * c["serving"]["cache_len"] // blk
-    chip = lambda tree: jax.tree.map(lambda a: _s(a.shape, a.dtype), tree)
-    params = chip(jax.eval_shape(
-        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    blocks = _pool_blocks("lfm2-8b-a1b")
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
     assert sum(math.prod(a.shape) for a in jax.tree.leaves(params)) \
         == pytest.approx(3.929e9, rel=0.001)
-    cache = chip(jax.eval_shape(lambda: decode.init_paged_cache(
-        cfg, slots, c["serving"]["cache_len"], blocks, block=blk)))
+    cache = jax.eval_shape(lambda: decode.init_paged_cache(
+        cfg, slots, c["serving"]["cache_len"], blocks, block=blk))
     assert cache.k.shape == (3, blocks, 4, blk, 128)      # two heads a row
     assert cache.tail.shape == (9, blocks, 2 * cfg.d_model)
-
-    def step(params, tokens, cache, n_tokens):
-        stats = {}
-        logits, cache = decode.forward_step(params, tokens, cache, cfg,
-                                            n_tokens=n_tokens, stats=stats)
-        return logits, cache, stats
-
-    def packed_step(params, chunk, cache, members, slots_i32):
-        stats = {}
-        logits, cache = decode.forward_packed_step(
-            params, chunk, members, members, slots_i32, slots_i32, cache,
-            cfg, stats=stats)
-        return logits, cache, stats
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    if packed:
-        compiled = jax.jit(packed_step, donate_argnums=(2,)).lower(
-            params, _s((1, tq), jnp.int32), cache, _s((1,), jnp.int32),
-            _s((slots,), jnp.int32)).compile()
-    else:
-        compiled = jax.jit(step, donate_argnums=(2,)).lower(
-            params, _s((slots, tq), jnp.int32), cache,
-            _s((slots,), jnp.int32)).compile()
-    text = compiled.as_text()
+    tick = _tick_program("lfm2-8b-a1b", tq, packed=packed)
+    text = tick.text
     kernels = pallas_kernels(text)
     assert any(k.startswith("flash_decode_paged") for k in kernels), kernels
     assert "moe_grouped_matmul" in kernels, kernels
@@ -712,7 +698,84 @@ def test_hybrid_step_compiles_and_keeps_the_pools_in_place(
     # the tails' in each of the 4 runs of conv layers, for every group.
     groups = 2 if packed else 1
     assert len(writes) == groups * (2 * 3 + 4), writes
-    mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes >= 2 * (2 * 3 * kv_layer
-                                           + 9 * tail_layer), mem
-    assert mem.temp_size_in_bytes < 2 * kv_layer, mem
+    assert tick.alias_bytes >= 2 * (2 * 3 * kv_layer + 9 * tail_layer), \
+        tick.alias_bytes
+    assert tick.temp_bytes < 2 * kv_layer, tick.temp_bytes
+
+
+# -- the attention input projections: read where they lie (ISSUE 34) --------
+#
+# The compiler multiplies by ``wq`` / ``wk`` / ``wv`` and a latent layer's
+# ``wqb`` with the contracted axis minor. Handed the outer format ``(L, in,
+# out)`` every layer of every tick sliced the weight out of its stack into
+# fast memory and transposed it there in a ``copy`` before the product (32 MB
+# + 2 x 4 MB a layer at Yi-6B's widths, 0.65-0.9 ms a tick in four cells;
+# ``deepseek-v2``'s 75 MB slice did not fit and made a round trip through HBM
+# first). The engine serves from ``served_layout``'s form, and the programs
+# compiled from it must hold neither.
+
+ALL_CONFIGS = STEP_CONFIGS + LATENT_CONFIGS + ("lfm2-8b-a1b",)
+# Tq 1 and the packed programs at both ends of the chunk buckets.
+TICK_PROGRAMS = {"tq1": (1, False), "packed16": (16, True),
+                 "packed256": (256, True)}
+
+
+def _projection_dims(cfg):
+    """The dimensions of a layer's attention input projections, in either
+    form and either order."""
+    if cfg.mla is not None:
+        dims = {(cfg.mla.q_rank or cfg.d_model,
+                 cfg.n_heads * (cfg.mla.nope + cfg.mla.rope))}
+    else:
+        dims = {(cfg.d_model, out) for out in (
+            cfg.q_dim, cfg.kv_dim, cfg.q_dim + 2 * cfg.kv_dim)}
+    return dims | {d[::-1] for d in dims}
+
+
+def _moved_projections(text, cfg):
+    """What the module holds of a projection weight beside the stack itself:
+    a ``copy`` of one layer's (the transposition), or any other result of
+    its dimensions that lies in HBM (the slice that did not fit fast
+    memory). The slice into fast memory, ``S(1)``, is the weight's one read
+    and stays where the compiler makes one. The packed programs hold larger
+    copies of gathered views, which are not weights: the dimensions are
+    matched whole, with or without a leading 1."""
+    dims = _projection_dims(cfg)
+    moved = []
+    for name, result, opcode, _ in _materialised(text):
+        if opcode in _MOVES_NOTHING:
+            continue
+        arrays = re.findall(r"\bbf16\[([\d,]+)\](\{[^}]*\})?", result)
+        if opcode == "copy-start":
+            # (destination, source, context): a one-layer stack prefetched
+            # from the parameter itself, the same read by another name.
+            arrays = arrays[:1]
+        for shape, layout in arrays:
+            shape = tuple(int(d) for d in shape.split(","))
+            if shape[:1] == (1,):
+                shape = shape[1:]
+            if shape in dims and (opcode == "copy" or "S(1)" not in layout):
+                moved.append((name, opcode, result))
+    return moved
+
+
+@pytest.mark.parametrize("program", sorted(TICK_PROGRAMS))
+@pytest.mark.parametrize("config", ALL_CONFIGS)
+def test_no_tick_program_moves_a_projection_weight(config, program):
+    if _chip() is None:
+        pytest.skip("the v5e:2x2 topology cannot be described here")
+    tq, packed = TICK_PROGRAMS[program]
+    moved = _moved_projections(_tick_program(config, tq, packed=packed).text,
+                               _model(config)[1])
+    assert not moved, moved
+
+
+def test_the_guard_catches_the_outer_format():
+    """The control: handed ``init_params``' own layout, which the engine
+    never hands a program, a dense layer's three projections are each copied
+    transposed, as they were in every tick before the engine re-laid them."""
+    if _chip() is None:
+        pytest.skip("the v5e:2x2 topology cannot be described here")
+    moved = _moved_projections(
+        _tick_program("yi-6b", 1, served=False).text, _model("yi-6b")[1])
+    assert sorted(op for _, op, _ in moved) == ["copy"] * 3, moved

@@ -584,12 +584,17 @@ def build_serve_engine(cfg: RunConfig, mesh, *, model=None,
     keys (what ``--model-config <file>`` loads); it takes the place of the
     model flags (``--model-dim``, ``--heads``, ...), which build the
     Llama-style block as before. ``params`` are the weights to serve, in
-    the served type and the block's own layout; without them the program
-    draws its own from ``--seed``, leaf by leaf in the served type."""
+    the served type and the block's own layout (``init_params``'); without
+    them the program draws its own from ``--seed``, leaf by leaf in the
+    served type. Either way ``ServeSetup.params`` is what the engines serve
+    from: the same tree with the attention input projections re-laid
+    (``serving.engine.serving_params``), which every function of the model
+    takes as it takes the outer format."""
     import jax
 
     from tree_attention_tpu.models import init_params
     from tree_attention_tpu.serving import SlotServer
+    from tree_attention_tpu.serving.engine import serving_params
 
     if model is None and cfg.model_config:
         model = load_model_config(cfg.model_config)
@@ -721,6 +726,10 @@ def build_serve_engine(cfg: RunConfig, mesh, *, model=None,
             dataclasses.replace(cfg, seq_len=cache_len))
     if params is None:
         params = init_params(jax.random.PRNGKey(cfg.seed), tcfg)
+    # Re-laid here, once, for every engine ``make_engine`` builds (a
+    # fleet's replicas share the tree), and so that nothing built here
+    # keeps the outer format's leaves alive beside the served ones.
+    params = serving_params(params)
     if cfg.slo_ttft <= 0 or cfg.slo_tbt <= 0:
         raise SystemExit("--slo-ttft and --slo-tbt must be > 0")
     # The paged pool has ONE device budget (--kv-blocks) and one host

@@ -37,9 +37,11 @@ import jax.numpy as jnp
 from jax import lax
 
 from tree_attention_tpu.models.transformer import (
+    LATENT_SERVED,
     LatentAttention,
     TransformerConfig,
     YarnRope,
+    times_out_major,
     rms_norm,
 )
 
@@ -125,7 +127,11 @@ def latent_qkv(p: Params, h: jax.Array, positions: jax.Array,
     if la.q_rank:
         c_q = rms_norm(h @ p["wqa"], gain(p["q_ln"], la.q_scale),
                        cfg.norm_eps)
-    q = (c_q @ p["wqb"]).reshape(B, T, H, la.nope + la.rope)
+    if LATENT_SERVED in p:
+        q = times_out_major(c_q, p[LATENT_SERVED])
+    else:
+        q = c_q @ p["wqb"]
+    q = q.reshape(B, T, H, la.nope + la.rope)
     q_nope, q_rope = q[..., :la.nope], q[..., la.nope:]
     q_rope = rope_halves(q_rope, positions, freqs, amp)
     kva = h @ p["wkva"]                                   # (B, T, rank+rope)

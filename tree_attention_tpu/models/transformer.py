@@ -622,6 +622,52 @@ def unembed(params: Params, x: jax.Array) -> jax.Array:
     return jnp.einsum("...d,vd->...v", x, params["embed"]).astype(jnp.float32)
 
 
+# The attention input projections as a serving engine holds them
+# (:func:`served_layout`): out-major, the contracted axis minor, under a name
+# of their own, since a square ``wq`` says nothing by its shape.
+GQA_SERVED, LATENT_SERVED = "wqkv_t", "wqb_t"
+
+
+def served_layout(params: Params) -> Params:
+    """``params`` with every attention input projection re-laid as the tick
+    programs want it. A layer's ``wq``, ``wk`` and ``wv`` ``(..., D, out)``
+    become one ``wqkv_t`` ``(..., q_dim + 2 x kv_dim, D)``; a latent layer's
+    ``wqb`` ``(..., rank, out)`` becomes ``wqb_t`` ``(..., out, rank)``.
+
+    The compiler for the chip multiplies by these with the contracted axis
+    minor. Handed the outer format, every layer of every tick slices the
+    weight out of its stack and transposes it in a copy of its own before
+    the product; handed this one, the product reads the stack where it lies
+    (``tests/test_chip_compile.py`` holds the compiled programs to that).
+    Every other leaf is handed on untouched, and so is a tree that holds none
+    of the three or holds them re-laid already. One jitted call a leaf: no
+    second copy of the model is ever made, and the outer leaves are the
+    caller's to drop."""
+    if isinstance(params, (list, tuple)):
+        return type(params)(served_layout(v) for v in params)
+    if not isinstance(params, dict):
+        return params
+    out = {k: served_layout(v) for k, v in params.items()}
+    if "wq" in out:
+        out[GQA_SERVED] = _out_major(*(out.pop(n) for n in ("wq", "wk", "wv")))
+    if "wqb" in out:
+        out[LATENT_SERVED] = _out_major(out.pop("wqb"))
+    return out
+
+
+@jax.jit
+def _out_major(*weights: jax.Array) -> jax.Array:
+    """``(..., in, out_i)`` weights of one input as one ``(..., sum of
+    out_i, in)``."""
+    return jnp.concatenate([jnp.swapaxes(w, -1, -2) for w in weights],
+                           axis=-2)
+
+
+def times_out_major(x: jax.Array, w_t: jax.Array) -> jax.Array:
+    """``x`` ``(..., in)`` times an out-major weight ``(out, in)``."""
+    return jnp.einsum("...i,oi->...o", x, w_t)
+
+
 def gqa_qkv(p: Params, h: jax.Array, positions: jax.Array,
             cfg: TransformerConfig) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """The rotary-GQA projections of the normed residual ``h`` ``(B, T,
@@ -629,9 +675,14 @@ def gqa_qkv(p: Params, h: jax.Array, positions: jax.Array,
     d)``, the rotary embedding applied; where ``cfg.qk_norm``, an RMSNorm
     over each query and key head (one learned gain of ``d`` a layer)
     before it."""
-    q = _heads(h @ p["wq"], cfg.n_heads, cfg.d_head)
-    k = _heads(h @ p["wk"], cfg.n_kv_heads, cfg.d_head)
-    v = _heads(h @ p["wv"], cfg.n_kv_heads, cfg.d_head)
+    if GQA_SERVED in p:      # the served form: one product, cut in three
+        q, k, v = jnp.split(times_out_major(h, p[GQA_SERVED]),
+                            [cfg.q_dim, cfg.q_dim + cfg.kv_dim], axis=-1)
+    else:
+        q, k, v = h @ p["wq"], h @ p["wk"], h @ p["wv"]
+    q = _heads(q, cfg.n_heads, cfg.d_head)
+    k = _heads(k, cfg.n_kv_heads, cfg.d_head)
+    v = _heads(v, cfg.n_kv_heads, cfg.d_head)
     if cfg.qk_norm:
         q = rms_norm(q, p["q_ln"], cfg.norm_eps)
         k = rms_norm(k, p["k_ln"], cfg.norm_eps)

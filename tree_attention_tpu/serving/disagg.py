@@ -103,6 +103,7 @@ from tree_attention_tpu.serving.engine import (
     _TBT,
     _TOKENS,
     _TTFT,
+    serving_params,
 )
 from tree_attention_tpu.serving.speculation import Drafter, PackedSpec
 from tree_attention_tpu.utils.logging import get_logger
@@ -198,7 +199,9 @@ class DisaggServer:
         self.slots = prefill_slots + decode_slots  # the ingress contract
         self.cache_len = cache_len
         self.cfg = cfg
-        self.params = params
+        # Re-laid once for the pair: each worker's own call then passes
+        # the served tree through.
+        self.params = params = serving_params(params)
         self.quantize = quantize
         self.kv_block = kv_block
         npb = -(-cache_len // kv_block)

@@ -183,7 +183,13 @@ from tree_attention_tpu.serving.speculation import (
     pack_proposal,
     pack_siblings,
 )
-from tree_attention_tpu.models.transformer import Params, TransformerConfig
+from tree_attention_tpu.models.transformer import (
+    GQA_SERVED,
+    LATENT_SERVED,
+    Params,
+    TransformerConfig,
+    served_layout,
+)
 from tree_attention_tpu.utils.logging import get_logger
 
 log = get_logger("serving")
@@ -240,6 +246,13 @@ _BLOCK_FIXED_BYTES = obs.gauge(
     "serving_cache_block_fixed_bytes",
     "bytes a pool block holds beside its tokens' rows, all layers (the conv "
     "layers' two-row tails of a hybrid pool; 0 for every other cache)",
+)
+_WEIGHTS_RELAID = obs.gauge(
+    "serving_weights_relaid_bytes",
+    "bytes of attention input projections the engine serves from in the "
+    "out-major served layout, by served leaf (set once at build; 0 for a "
+    "model with no such leaf)",
+    labels=("leaf",),
 )
 _TAIL_BLOCKS = obs.counter(
     "serving_conv_tail_blocks_written_total",
@@ -738,6 +751,32 @@ class StaticRequestSource(RequestSource):
         return self._pos >= len(self._reqs)
 
 
+def serving_params(params: Params) -> Params:
+    """``params`` as an engine serves from them: the attention input
+    projections in :func:`~tree_attention_tpu.models.transformer.
+    served_layout`'s form, every other leaf the caller's own array. The
+    weights' outer format (``init_params``', a caller's ``params=``) is what
+    comes in; a tree that was here before passes through, so the front ends
+    that build several engines from one model (a disaggregated pair, a
+    fleet's replicas) re-lay it once and share it. Sets
+    ``serving_weights_relaid_bytes`` to what the tree holds re-laid."""
+    # Waited for: an outer leaf the caller drops on return is then free
+    # before the pool is made, not whenever the queued transposition ends.
+    served = jax.block_until_ready(served_layout(params))
+    held = dict.fromkeys((GQA_SERVED, LATENT_SERVED), 0)
+    for path, leaf in jax.tree_util.tree_leaves_with_path(served):
+        name = getattr(path[-1], "key", None)
+        if name in held:
+            held[name] += leaf.size * leaf.dtype.itemsize
+    if obs.REGISTRY.enabled:
+        for name, nbytes in held.items():
+            _WEIGHTS_RELAID.labels(leaf=name).set(nbytes)
+    if jax.tree.structure(served) != jax.tree.structure(params):
+        log.info("serving layout: attention input projections re-laid "
+                 "out-major, bytes by leaf: %s", held)
+    return served
+
+
 def _bucket(n: int, cap: int, floor: int = 8, multiple: int = 1) -> int:
     """Pad a prompt length up to a power-of-two bucket (bounded compiles:
     one prefill program per bucket, not per distinct prompt length),
@@ -934,7 +973,7 @@ class SlotServer:
                         f"a model served from the {cfg.cache_kind} pool "
                         f"(TransformerConfig.cache_kind) does not serve "
                         f"with {what}: not built for that pool")
-        self.params = params
+        self.params = serving_params(params)
         self.cfg = cfg
         self.slots = slots
         self.cache_len = cache_len
