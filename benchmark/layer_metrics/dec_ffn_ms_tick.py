@@ -1,0 +1,9 @@
+"""Device milliseconds a decode tick (no chunk tokens) spends in the dense
+SwiGLUs with their norms, shared experts among them (scope ``ffn``), over
+such ticks of the traced window. An operation goes to a kind of tick by its
+program's table and to a part by its scope (``benchmark/parts.py``)."""
+from benchmark import parts
+
+
+def read(run):
+    return parts.ms_tick(run, parts.DEC, "ffn")

@@ -39,6 +39,15 @@ LEAVES = ("embed", "ln1", "wq", "wk", "wv", "wo", "ln2", "w1", "w3", "w2",
           "router_bias", "sub", "w_in", "w_conv", "w_out", "q_ln", "k_ln")
 THE_FAMILYS_OWN = ("references", "adapters", "configs", "kernel_costs",
                    "tests")
+# What PR 35 appended, in order: a tick's device time by part of the model.
+PARTS_DENSE = ["dec_proj_ms_tick", "dec_attn_ms_tick", "dec_ffn_ms_tick",
+               "dec_head_ms_tick", "mix_proj_ms_tick",
+               "mix_attn_decode_ms_tick", "mix_attn_chunk_ms_tick",
+               "mix_ffn_ms_tick", "mix_head_ms_tick", "tick_unscoped_pct"]
+PARTS_MOE = PARTS_DENSE[:4] + ["dec_moe_ms_tick"] + PARTS_DENSE[4:9] + [
+    "mix_moe_ms_tick", "tick_unscoped_pct"]
+PARTS_ALL = PARTS_MOE[:5] + ["dec_conv_ms_tick"] + PARTS_MOE[5:11] + [
+    "mix_conv_ms_tick", "tick_unscoped_pct"]
 
 
 def _sources(but=()):
@@ -266,9 +275,10 @@ def test_the_double_layer_cell_resolves_every_file_it_names():
     for name in names:
         assert spec.load_module("layer_metrics", name + ".py").read
     # Every per-layer metric the other latent cell reports, and its own two
-    # (what later PRs appended for every cell comes after them: PR 32's).
+    # (what later PRs appended for every cell comes after them: PR 32's,
+    # and PR 35's parts as the expert cells list them).
     theirs = [m["name"] for m in spec.cell(CELL).per_layer]
-    later = ["tick_ahead_pct"]
+    later = ["tick_ahead_pct"] + PARTS_MOE
     assert theirs[-len(later):] == later
     assert names == theirs[:-len(later)] + [
         "zero_expert_pairs_pct", "real_experts_row_max_over_mean"] + later
@@ -431,9 +441,10 @@ def test_tick_ahead_pct_reads_the_look_ahead_counter(flight, want):
         "name": "tick_ahead_pct", "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "admission and scheduling",
         "moves": "tbt_p50_ms"}
-    # Only what a later PR appended for its own cell comes after it (PR 33's).
+    # Only what later PRs appended comes after it (PR 33's for its own cell,
+    # PR 35's parts).
     assert [m["name"] for m in listed[at + 1:]] == [
-        "mixer_rest_ms_tick", "mixer_rest_stream_roofline"]
+        "mixer_rest_ms_tick", "mixer_rest_stream_roofline"] + PARTS_ALL
     for w in json.load(open(BENCH))["workloads"]:
         assert "tick_ahead_pct" in [
             m["name"] for m in spec.cell(w["name"]).per_layer]
@@ -477,8 +488,10 @@ def test_the_hybrid_cell_resolves_every_file_it_names():
                  "expert_rows_max_over_mean", "tick_ahead_pct",
                  "device_idle_pct", "decode_tick_p50_ms"):
         assert name in names, name
-    assert names[-2:] == ["mixer_rest_ms_tick", "mixer_rest_stream_roofline"]
-    for m in cell.per_layer[-2:]:
+    assert names[-len(PARTS_ALL):] == PARTS_ALL       # PR 35's, all of them
+    own = slice(-2 - len(PARTS_ALL), -len(PARTS_ALL))
+    assert names[own] == ["mixer_rest_ms_tick", "mixer_rest_stream_roofline"]
+    for m in cell.per_layer[own]:
         assert m["workloads"] == [LFM_CELL] and m["layer"] == "kernels"
         assert (m["source"], m["moves"]) == ("device_trace", "tbt_p50_ms")
     for name in ("mla_decode_ms_tick", "zero_expert_pairs_pct"):
@@ -643,3 +656,47 @@ def test_the_rest_metrics_read_nothing_where_there_is_nothing(name):
                                     recs=[], peaks=peaks, t_open=1.0,
                                     t_end=10.0)
         assert read(run) is None
+
+
+# -- a tick's device time by part (ISSUE 35) --------------------------------
+
+
+@pytest.mark.parametrize("name", PARTS_ALL)
+def test_a_parts_metric_is_listed_where_its_part_exists(name):
+    """Fourteen entries appended last: device-trace metrics of the tick
+    programs, the decode ones moving ``tbt_p50_ms`` and the mixed ones
+    ``tbt_p99_ms``; the expert parts in the three expert cells, the conv
+    parts in the hybrid's alone, the rest in all six."""
+    spec = Spec(BENCH)
+    listed = json.load(open(BENCH))["per_layer"]
+    assert [m["name"] for m in listed[-len(PARTS_ALL):]] == PARTS_ALL
+    entry = next(m for m in listed if m["name"] == name)
+    cells = [w["name"] for w in spec.data["workloads"]]
+    want = cells
+    if "_moe_" in name:
+        want = [CELL, LC_CELL, LFM_CELL]
+    elif "_conv_" in name:
+        want = [LFM_CELL]
+    assert entry == {
+        "name": name, "unit": "%" if name.endswith("_pct") else "ms",
+        "better": "lower", "source": "device_trace",
+        "layer": "tick programs",
+        "moves": "tbt_p99_ms" if name.startswith("mix_") else "tbt_p50_ms",
+        "workloads": want}
+    for cell in cells:
+        names = [m["name"] for m in spec.cell(cell).per_layer]
+        assert (name in names) == (cell in want)
+    assert spec.load_module("layer_metrics", name + ".py").read
+
+
+def test_the_parts_readers_name_no_leaf_of_a_family():
+    """``benchmark/parts.py`` takes the scopes' names from the program's own
+    vocabulary (``obs/scopes.py``); beside a program without it, it has
+    nothing to join and every reader gives None."""
+    from benchmark import parts
+    from tree_attention_tpu.obs import scopes
+
+    assert set(parts.PART_OF) == set(scopes.SCOPES)
+    with open(os.path.join(ROOT, "benchmark", "parts.py")) as f:
+        text = f.read()
+    assert "except ImportError" in text and '"embed"' not in text

@@ -779,3 +779,77 @@ def test_the_guard_catches_the_outer_format():
     moved = _moved_projections(
         _tick_program("yi-6b", 1, served=False).text, _model("yi-6b")[1])
     assert sorted(op for _, op, _ in moved) == ["copy"] * 3, moved
+
+
+# -- the parts of the model, named in the compiled programs (ISSUE 35) -------
+#
+# The layer bodies wrap their seams in ``jax.named_scope`` with one
+# vocabulary (``obs/scopes.py``), and the engine reads the optimized module's
+# ``op_name`` metadata back into a table from each operation to its part. A
+# scope is only worth its name if the compiler for the chip keeps it: on the
+# fusions it makes, and near enough to the copies and slices it adds (which
+# carry no scope of their own and take their users').
+
+
+def _scopes_expected(cfg, packed):
+    from tree_attention_tpu.obs import scopes
+
+    want = {scopes.EMBED, scopes.ATTN_IN, scopes.ATTN_CACHE,
+            scopes.ATTN_DECODE, scopes.ATTN_OUT, scopes.FFN, scopes.HEAD}
+    if packed:
+        want.add(scopes.ATTN_CHUNK)
+    if cfg.moe is not None:
+        want |= {scopes.ROUTE, scopes.EXPERTS}
+    if cfg.conv_layers:
+        want.add(scopes.CONV)
+    return want
+
+
+@pytest.mark.parametrize("program", sorted(TICK_PROGRAMS))
+@pytest.mark.parametrize("config", ALL_CONFIGS)
+def test_tick_programs_keep_the_scopes(config, program):
+    """Of the instructions that run as device operations of their own, those
+    that resolve to a name of the vocabulary (by their own ``op_name`` or
+    their neighbours') hold at least 95% of the result bytes, and every
+    scope the configuration has is found at least once."""
+    if _chip() is None:
+        pytest.skip("the v5e:2x2 topology cannot be described here")
+    from tree_attention_tpu.obs import scopes
+
+    tq, packed = TICK_PROGRAMS[program]
+    text = _tick_program(config, tq, packed=packed).text
+    leaf = [i for i in scopes.resolve(scopes.instructions(text))
+            if i.opcode not in scopes.MOVES_NOTHING | scopes.ENCLOSES]
+    assert len(leaf) > 50
+    named = [i for i in leaf if i.scope]
+    assert {i.scope.split("/")[0] for i in named} \
+        == _scopes_expected(_model(config)[1], packed)
+    # A result no instruction reads belongs to no part: the compiler leaves
+    # one dead prefetch in ``deepseek-v2``'s programs (the one-layer dense
+    # stack's ``wqb_t``, which the product then reads in place), 75 MB.
+    read = {(i.computation, o) for i in scopes.instructions(text)
+            for o in i.operands}
+    dead = [i for i in leaf if not i.scope and i.opcode == "copy-done"
+            and (i.computation, i.op) not in read]
+    assert len(dead) <= (config == "deepseek-v2"), dead
+    total = sum(i.nbytes for i in leaf) - sum(i.nbytes for i in dead)
+    share = sum(i.nbytes for i in named) / total
+    assert share >= 0.95, (share, sorted(
+        ((i.nbytes, i.op, i.result) for i in leaf if not i.scope),
+        reverse=True)[:10])
+    # By count as well: what is left is the step's own address arithmetic
+    # (the groups' tables and lengths, which every layer reads), small
+    # prefetches shared by several parts and the loops' counters: a quarter
+    # of the operations at most.
+    assert len(named) >= 0.75 * len(leaf), (len(named), len(leaf))
+    # The kernels are rows under their own names, in their scopes.
+    kernels = {i.op.rsplit(".", 1)[0]: i.scope.split("/")[0]
+               for i in leaf if i.opcode == "custom-call" and i.scope}
+    cfg = _model(config)[1]
+    if cfg.mla is not None:
+        assert kernels["mla_decode_paged"] in (
+            scopes.ATTN_DECODE, scopes.ATTN_CHUNK)
+    else:
+        assert kernels["flash_decode_paged"] == scopes.ATTN_DECODE
+    if cfg.moe is not None:
+        assert kernels["moe_grouped_matmul"] == scopes.EXPERTS

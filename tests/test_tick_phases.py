@@ -101,29 +101,41 @@ def test_phases_are_named_in_loop_order_and_tile_the_tick(served):
         assert ("fetch" in names) == rec["host_sync"] == ("emit" in names)
 
 
-def test_t_s_is_when_the_program_before_it_was_fetched(served):
+def test_t_s_is_when_the_program_before_it_was_fetched(chunked, monkeypatch):
     """A record is one program's and is written by the iteration that
     lands its tail, one later than the one that dispatched it (ISSUE 32).
     ``t_s`` of a program dispatched ahead is the moment the program
     before it came back: the clock read that follows the ``emit`` mark of
     the record before. The first program of a busy spell has the tick's
-    top, which precedes every stamp in its record."""
-    _, _, recs, _, _ = served
+    top, which precedes every stamp in its record. The engine and the
+    stamper read an injected clock that counts its reads, so the order of
+    the stamps is compared, not two reads of the wall clock."""
+    from tree_attention_tpu.serving import engine as engine_mod
+
+    reads = iter(range(1, 1 << 30))
+    clock = types.SimpleNamespace(monotonic=lambda: float(next(reads)),
+                                  strftime=time.strftime)
+    monkeypatch.setattr(engine_mod, "time", clock)
+    monkeypatch.setattr(flight_mod, "time", clock)
+    _, recs = _recorded(chunked, _requests(3, 9, 4))
     assert [r["tick"] for r in recs] == list(range(len(recs)))
     assert recs[0]["ahead"] is False and recs[0]["sync_reason"] == "first"
     assert all(r["ahead"] and "sync_reason" not in r for r in recs[1:])
     stamps = [r["t_s"] for r in recs]
     assert stamps == sorted(set(stamps))
-    # serve()'s own zero, from each pair: one value to a clock read's cost.
-    zero = [dict(before["phases"])["emit"] - rec["t_s"]
-            for before, rec in zip(recs, recs[1:]) if before["host_sync"]]
-    assert zero and max(zero) - min(zero) < 1e-3
+    # serve()'s own zero, from each pair: ``t_s`` is the read after the
+    # ``emit`` mark, so every pair gives the same value, to the read.
+    zero = {dict(before["phases"])["emit"] + 1.0 - rec["t_s"]
+            for before, rec in zip(recs, recs[1:]) if before["host_sync"]}
+    assert len(zero) == 1
+    t0 = zero.pop()
     for before, rec in zip(recs, recs[1:]):
         # Behind the dispatch of its own program, inside the iteration
         # that landed the one before.
-        at = rec["t_s"] + min(zero)
+        at = rec["t_s"] + t0
         assert dict(before["phases"])["dispatch"] < at <= before["t_end"]
-    assert recs[0]["t_s"] + max(zero) <= recs[0]["phases"][0][1]
+        assert at == float(int(at))          # a read of the injected clock
+    assert recs[0]["t_s"] + t0 <= recs[0]["phases"][0][1]
 
 
 def test_kind_and_tq_agree_with_the_chunk_plan(served):

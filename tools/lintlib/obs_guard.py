@@ -63,7 +63,7 @@ _TRACER_FNS = {"instant", "span"}
 #: dict, keyword defaults) before REQLOG's internal early-return, so the
 #: call site owns the guard.
 _REQLOG_SEAMS = {"open", "note", "blocks", "first_token", "park",
-                 "resume", "finish", "drop"}
+                 "resume", "finish"}
 
 
 def _in_scope(path: str) -> bool:

@@ -34,17 +34,16 @@ from tree_attention_tpu.utils.logging import get_logger, setup_logging
 
 log = get_logger("cli")
 
-# Execution-true host-loop totals (the train/generate loops run eagerly on
-# the host; each counted unit is real work the process finished).
-_TRAIN_STEPS = obs.counter(
-    "train_steps_total", "optimizer steps completed by the CLI train loop"
-)
-_TRAIN_TOKENS = obs.counter(
-    "train_tokens_total", "tokens consumed by completed train steps"
-)
-_GENERATED_TOKENS = obs.counter(
-    "generated_tokens_total", "tokens sampled by the CLI generate mode"
-)
+
+def _report_record(report) -> dict:
+    """``ServeReport.as_dict()`` for the one-line record: of the tick
+    programs' tables (there while tracing is on) the labels alone; the
+    rows are in the flight recorder's dump."""
+    rec = report.as_dict()
+    if "programs" in rec:
+        rec["programs"] = [dict(t["program"], ops=len(t["ops"]))
+                           for t in rec["programs"]]
+    return rec
 
 
 def _pick_free_port() -> int:
@@ -413,8 +412,6 @@ def _run_train(cfg: RunConfig, mesh) -> int:
             state, loss = step(state, batch)
             losses.append(float(loss))
             heartbeat()  # after the fetch: real per-step progress, not dispatch
-            _TRAIN_STEPS.inc()
-            _TRAIN_TOKENS.inc(cfg.batch * cfg.seq_len)
             log.info("step %d: loss %.4f", i, losses[-1])
             if ckpt is not None:
                 saved_last = ckpt.save(i, state, cfg=tcfg)
@@ -492,7 +489,6 @@ def _run_generate(cfg: RunConfig, mesh) -> int:
     )
     toks = jax.block_until_ready(toks)
     heartbeat()
-    _GENERATED_TOKENS.inc(cfg.batch * n_new)
     log.info(
         "generated %s tokens from a %s prompt%s",
         toks.shape, prompt.shape,
@@ -917,7 +913,7 @@ def _run_serve(cfg: RunConfig, mesh) -> int:
             **({"disagg": {"prefill_slots": cfg.prefill_slots,
                            "decode_slots": decode_slots}}
                if cfg.serve_disagg else {}),
-            **(report.as_dict() if report is not None else {}),
+            **(_report_record(report) if report is not None else {}),
         })
         return 0
 
@@ -959,7 +955,7 @@ def _run_serve(cfg: RunConfig, mesh) -> int:
            if host_blocks else {}),
         # Outcome counts ride ServeReport.as_dict (the ISSUE 10 outcome
         # vocabulary threaded through the report).
-        **report.as_dict(),
+        **_report_record(report),
         **({"kv_quant": cfg.kv_quant} if cfg.kv_quant != "none" else {}),
     })
     return 0

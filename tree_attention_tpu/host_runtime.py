@@ -793,10 +793,6 @@ def _launch_elastic(
             "gang attempt %d/%d failed (statuses %s); restarting",
             attempt, restarts + 1, statuses,
         )
-        if obs.TRACER.active:
-            obs.instant("gang_attempt_failed", cat="launcher",
-                        args={"attempt": attempt,
-                              "statuses": list(statuses)})
         # Retried attempts' exits must land in the counters too — the
         # caller only accounts the FINAL attempt's statuses, and a stall
         # that elastic recovery papered over is exactly what

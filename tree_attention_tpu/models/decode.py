@@ -53,6 +53,7 @@ from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from tree_attention_tpu import obs
+from tree_attention_tpu.obs import scopes
 from tree_attention_tpu.models.transformer import (
     Params,
     TransformerConfig,
@@ -987,6 +988,7 @@ class _RowGroup(NamedTuple):
     n: Optional[jax.Array]
     table: Optional[jax.Array]
     tree_mask: Optional[jax.Array]
+    chunk: bool = False   # a packed step's chunk group (``scopes.ATTN_CHUNK``)
 
     @property
     def n_valid(self) -> jax.Array:
@@ -1047,9 +1049,11 @@ class _Attend:
 
     def __call__(self, gi, q, k_new, v_new, k_cache, v_cache, k_s, v_s,
                  views, l, base):
-        """The attention half of a layer for group ``gi``: its new K/V
-        rows into the cache, its queries against what the cache then
-        holds. Returns the heads' output and the cache arrays."""
+        """The attention half of a layer for group ``gi`` of the step's
+        ``q`` / ``k_new`` / ``v_new``: its new K/V rows into the cache
+        (``scopes.ATTN_CACHE``), its queries against what the cache then
+        holds (``ATTN_DECODE``; a packed step's chunk group:
+        ``ATTN_CHUNK``). Returns the heads' output and the cache arrays."""
         groups, cfg, mesh, axes = self.groups, self.cfg, self.mesh, self.axes
         paged, quant, carried = self.paged, self.quant, self.carried
         seq_sharded, hoist_view = self.seq_sharded, self.hoist_view
@@ -1058,153 +1062,160 @@ class _Attend:
         g = groups[gi]
         B, Tq = g.batch, g.tq
         start, n_valid = g.start, g.n_valid
-        k_view = v_view = None
-        if hoist_view:
-            k_view, v_view = views[2 * gi:2 * gi + 2]
-        # Write member i's new rows at its own [start[i], start[i]+Tq): a
-        # vmapped dynamic-update over batch (per-slot token offsets). Under
-        # a mesh GSPMD turns it into per-shard masked writes on the seq dim.
-        # Quantized caches quantize the rows first — under the per-slot
-        # frozen scales (contiguous) or the per-block anchor scale
-        # (paged; entered blocks inherit it, see above).
-        k_deq = v_deq = None
-        k_sf = v_sf = None
-        if quant and paged:
-            anchor_pb, write_pb, entered = anchors[gi]
-            # The scales as (blocks, Hkv) rows: every layer's when carried
-            # (a bitcast), this layer's (base 0) when scanned.
-            hkv = k_s.shape[-1]
-            k_sf, v_sf = k_s.reshape(-1, hkv), v_s.reshape(-1, hkv)
-            k_anchor = k_sf[base + anchor_pb][:, :, None, None]
-            v_anchor = v_sf[base + anchor_pb][:, :, None, None]  # (B,Hkv,1,1)
-            k_new = _quantize_rows(k_new, k_anchor)
-            v_new = _quantize_rows(v_new, v_anchor)
-            vals_k = jnp.broadcast_to(
-                k_anchor[:, None, :, 0, 0], (B, Tq, hkv)
-            ).reshape(-1, hkv)
-            vals_v = jnp.broadcast_to(
-                v_anchor[:, None, :, 0, 0], (B, Tq, hkv)
-            ).reshape(-1, hkv)
-            # Rows that enter no block scatter past every layer and drop.
-            scale_tgt = jnp.where(
-                entered, base + write_pb, k_sf.shape[0]
-            ).reshape(-1)
-            k_sf = k_sf.at[scale_tgt].set(vals_k, mode="drop")
-            v_sf = v_sf.at[scale_tgt].set(vals_v, mode="drop")
-            k_s, v_s = k_sf.reshape(k_s.shape), v_sf.reshape(v_s.shape)
+        with jax.named_scope(scopes.ATTN_CACHE):
+            k_new, v_new = g.take(k_new), g.take(v_new)
+            k_view = v_view = None
             if hoist_view:
-                # The view holds DEQUANTIZED rows: mirror exactly what
-                # the pool now holds (quantize-then-dequantize), so
-                # attention over the view == attention over the pool.
-                k_deq = (
-                    k_new.astype(jnp.float32) * k_anchor
-                ).astype(k_view.dtype)
-                v_deq = (
-                    v_new.astype(jnp.float32) * v_anchor
-                ).astype(v_view.dtype)
-        elif quant:
-            k_new = _quantize_rows(k_new, k_s)
-            v_new = _quantize_rows(v_new, v_s)
-        if paged:
-            # Paged write: scatter through the block table — valid rows
-            # land in their slot's mapped blocks, padded rows drop. The
-            # contiguous path's window clamp machinery is unnecessary
-            # here (see _paged_pool_write).
-            if seq_sharded:
-                k_cache = _paged_pool_write_seq(
-                    k_cache, k_new, g.table, start, n_valid,
-                    mesh=mesh, seq_axis=axes["seq"],
+                k_view, v_view = views[2 * gi:2 * gi + 2]
+            # Write member i's new rows at its own [start[i], start[i]+Tq):
+            # a vmapped dynamic-update over batch (per-slot token offsets).
+            # Under a mesh GSPMD turns it into per-shard masked writes on
+            # the seq dim. Quantized caches quantize the rows first — under
+            # the per-slot frozen scales (contiguous) or the per-block
+            # anchor scale (paged; entered blocks inherit it, see above).
+            k_deq = v_deq = None
+            k_sf = v_sf = None
+            if quant and paged:
+                anchor_pb, write_pb, entered = anchors[gi]
+                # The scales as (blocks, Hkv) rows: every layer's when carried
+                # (a bitcast), this layer's (base 0) when scanned.
+                hkv = k_s.shape[-1]
+                k_sf, v_sf = k_s.reshape(-1, hkv), v_s.reshape(-1, hkv)
+                k_anchor = k_sf[base + anchor_pb][:, :, None, None]
+                # (B, Hkv, 1, 1)
+                v_anchor = v_sf[base + anchor_pb][:, :, None, None]
+                k_new = _quantize_rows(k_new, k_anchor)
+                v_new = _quantize_rows(v_new, v_anchor)
+                vals_k = jnp.broadcast_to(
+                    k_anchor[:, None, :, 0, 0], (B, Tq, hkv)
+                ).reshape(-1, hkv)
+                vals_v = jnp.broadcast_to(
+                    v_anchor[:, None, :, 0, 0], (B, Tq, hkv)
+                ).reshape(-1, hkv)
+                # Rows that enter no block scatter past every layer and drop.
+                scale_tgt = jnp.where(
+                    entered, base + write_pb, k_sf.shape[0]
+                ).reshape(-1)
+                k_sf = k_sf.at[scale_tgt].set(vals_k, mode="drop")
+                v_sf = v_sf.at[scale_tgt].set(vals_v, mode="drop")
+                k_s, v_s = k_sf.reshape(k_s.shape), v_sf.reshape(v_s.shape)
+                if hoist_view:
+                    # The view holds DEQUANTIZED rows: mirror exactly what
+                    # the pool now holds (quantize-then-dequantize), so
+                    # attention over the view == attention over the pool.
+                    k_deq = (
+                        k_new.astype(jnp.float32) * k_anchor
+                    ).astype(k_view.dtype)
+                    v_deq = (
+                        v_new.astype(jnp.float32) * v_anchor
+                    ).astype(v_view.dtype)
+            elif quant:
+                k_new = _quantize_rows(k_new, k_s)
+                v_new = _quantize_rows(v_new, v_s)
+            if paged:
+                # Paged write: scatter through the block table — valid rows
+                # land in their slot's mapped blocks, padded rows drop. The
+                # contiguous path's window clamp machinery is unnecessary
+                # here (see _paged_pool_write).
+                if seq_sharded:
+                    k_cache = _paged_pool_write_seq(
+                        k_cache, k_new, g.table, start, n_valid,
+                        mesh=mesh, seq_axis=axes["seq"],
+                    )
+                    v_cache = _paged_pool_write_seq(
+                        v_cache, v_new, g.table, start, n_valid,
+                        mesh=mesh, seq_axis=axes["seq"],
+                    )
+                else:
+                    k_cache = _paged_pool_write(
+                        k_cache, k_new, g.table, start, n_valid, l
+                    )
+                    v_cache = _paged_pool_write(
+                        v_cache, v_new, g.table, start, n_valid, l
+                    )
+                if hoist_view:
+                    # Mirror the new rows into the hoisted logical view (the
+                    # pre-scan gather predates this layer's write) — a cheap
+                    # Tq-row window write, vs re-gathering the whole pool.
+                    wv = jax.vmap(_masked_window_write, in_axes=(0, 0, 0, 0))
+                    mk = k_new if k_deq is None else k_deq
+                    mv = v_new if v_deq is None else v_deq
+                    k_view = wv(
+                        k_view, mk.astype(k_view.dtype), start, n_valid
+                    )
+                    v_view = wv(
+                        v_view, mv.astype(v_view.dtype), start, n_valid
+                    )
+            elif g.n is None:
+                write = jax.vmap(
+                    lambda buf, rows, s: lax.dynamic_update_slice_in_dim(
+                        buf, rows, s, axis=1
+                    )
                 )
-                v_cache = _paged_pool_write_seq(
-                    v_cache, v_new, g.table, start, n_valid,
-                    mesh=mesh, seq_axis=axes["seq"],
+                k_cache = write(k_cache, k_new.astype(k_cache.dtype), start)
+                v_cache = write(v_cache, v_new.astype(v_cache.dtype), start)
+            else:
+                # Mixed-Tq masked write: only rows < n_tokens[i] may land. A
+                # plain Tq-row dynamic-update would (a) write pad garbage the
+                # causal mask has to hide until it is overwritten and (b)
+                # CLAMP near capacity (dynamic_update_slice semantics), sliding
+                # garbage over a decode slot's newest valid rows. Instead:
+                # read the Tq-row window at a clamped offset, overlay exactly
+                # the valid rows at their true absolute positions, write it
+                # back — cache bytes outside [start, start+n) are untouched.
+                write = jax.vmap(_masked_window_write, in_axes=(0, 0, 0, 0))
+                k_cache = write(
+                    k_cache, k_new.astype(k_cache.dtype), start, g.n
+                )
+                v_cache = write(
+                    v_cache, v_new.astype(v_cache.dtype), start, g.n
+                )
+
+        with jax.named_scope(
+                scopes.ATTN_CHUNK if g.chunk else scopes.ATTN_DECODE):
+            q = g.take(q)
+            data = axes["data"]
+            if data and mesh is not None and B % mesh.shape[data]:
+                # A packed group need not divide over the batch axis.
+                data = None
+            attn_kw = dict(
+                q_position=start,
+                mesh=mesh,
+                data_axis=data,
+                seq_axis=axes["seq"],
+                model_axis=axes["model"],
+                block_size=cfg.attn_block_size,
+                tree_mask=g.tree_mask,
+            )
+            if self.scale is not None:
+                attn_kw["scale"] = self.scale
+            ak, av, ak_s, av_s = k_cache, v_cache, k_s, v_s
+            if hoist_view:
+                ak, av = k_view, v_view
+            elif carried:
+                # The kernels and the reference gather take a pool and a
+                # table: hand them every layer's blocks (a bitcast of the
+                # carry) and this layer's addresses.
+                ak = k_cache.reshape((-1,) + k_cache.shape[2:])
+                av = v_cache.reshape((-1,) + v_cache.shape[2:])
+                attn_kw["block_table"] = base + g.table
+                if quant:
+                    ak_s, av_s = k_sf, v_sf
+            elif paged:
+                attn_kw["block_table"] = g.table
+                attn_kw["kv_shard"] = "seq"
+            if quant and not (paged and hoist_view):
+                out, _ = decode_attention(
+                    q, ak, av, k_scale=ak_s, v_scale=av_s,
+                    quant_kernel=quant_kernel, **attn_kw,
                 )
             else:
-                k_cache = _paged_pool_write(
-                    k_cache, k_new, g.table, start, n_valid, l
+                # Exact caches — and the paged-quant DEQUANTIZED view (the
+                # off-kernel path; see the hoist_view comment above).
+                out, _ = decode_attention(
+                    q, ak, av,
+                    impl=cfg.attn_impl, num_splits=num_splits, **attn_kw,
                 )
-                v_cache = _paged_pool_write(
-                    v_cache, v_new, g.table, start, n_valid, l
-                )
-            if hoist_view:
-                # Mirror the new rows into the hoisted logical view (the
-                # pre-scan gather predates this layer's write) — a cheap
-                # Tq-row window write, vs re-gathering the whole pool.
-                wv = jax.vmap(_masked_window_write, in_axes=(0, 0, 0, 0))
-                mk = k_new if k_deq is None else k_deq
-                mv = v_new if v_deq is None else v_deq
-                k_view = wv(
-                    k_view, mk.astype(k_view.dtype), start, n_valid
-                )
-                v_view = wv(
-                    v_view, mv.astype(v_view.dtype), start, n_valid
-                )
-        elif g.n is None:
-            write = jax.vmap(
-                lambda buf, rows, s: lax.dynamic_update_slice_in_dim(
-                    buf, rows, s, axis=1
-                )
-            )
-            k_cache = write(k_cache, k_new.astype(k_cache.dtype), start)
-            v_cache = write(v_cache, v_new.astype(v_cache.dtype), start)
-        else:
-            # Mixed-Tq masked write: only rows < n_tokens[i] may land. A
-            # plain Tq-row dynamic-update would (a) write pad garbage the
-            # causal mask has to hide until it is overwritten and (b)
-            # CLAMP near capacity (dynamic_update_slice semantics), sliding
-            # garbage over a decode slot's newest valid rows. Instead:
-            # read the Tq-row window at a clamped offset, overlay exactly
-            # the valid rows at their true absolute positions, write it
-            # back — cache bytes outside [start, start+n) are untouched.
-            write = jax.vmap(_masked_window_write, in_axes=(0, 0, 0, 0))
-            k_cache = write(
-                k_cache, k_new.astype(k_cache.dtype), start, g.n
-            )
-            v_cache = write(
-                v_cache, v_new.astype(v_cache.dtype), start, g.n
-            )
-
-        data = axes["data"]
-        if data and mesh is not None and B % mesh.shape[data]:
-            data = None  # a packed group need not divide over the batch axis
-        attn_kw = dict(
-            q_position=start,
-            mesh=mesh,
-            data_axis=data,
-            seq_axis=axes["seq"],
-            model_axis=axes["model"],
-            block_size=cfg.attn_block_size,
-            tree_mask=g.tree_mask,
-        )
-        if self.scale is not None:
-            attn_kw["scale"] = self.scale
-        ak, av, ak_s, av_s = k_cache, v_cache, k_s, v_s
-        if hoist_view:
-            ak, av = k_view, v_view
-        elif carried:
-            # The kernels and the reference gather take a pool and a
-            # table: hand them every layer's blocks (a bitcast of the
-            # carry) and this layer's addresses.
-            ak = k_cache.reshape((-1,) + k_cache.shape[2:])
-            av = v_cache.reshape((-1,) + v_cache.shape[2:])
-            attn_kw["block_table"] = base + g.table
-            if quant:
-                ak_s, av_s = k_sf, v_sf
-        elif paged:
-            attn_kw["block_table"] = g.table
-            attn_kw["kv_shard"] = "seq"
-        if quant and not (paged and hoist_view):
-            out, _ = decode_attention(
-                q, ak, av, k_scale=ak_s, v_scale=av_s,
-                quant_kernel=quant_kernel, **attn_kw,
-            )
-        else:
-            # Exact caches — and the paged-quant DEQUANTIZED view (the
-            # off-kernel path; see the hoist_view comment above).
-            out, _ = decode_attention(
-                q, ak, av,
-                impl=cfg.attn_impl, num_splits=num_splits, **attn_kw,
-            )
         return out, k_cache, v_cache, k_s, v_s
 
 
@@ -1216,21 +1227,23 @@ def gqa_mixer(attend: _Attend, layer: Params, x: jax.Array,
     cache and against it (:class:`_Attend`), the output projection. Returns
     the residual with the mixer's output added and the cache arrays."""
     cfg, groups = attend.cfg, attend.groups
-    h = rms_norm(x, layer["ln1"], cfg.norm_eps)
-    q, k_new, v_new = gqa_qkv(layer, h, positions, cfg)
-    if cfg.kv_pack > 1:
-        q, k_new, v_new = _pack_heads(q, k_new, v_new, cfg)
+    with jax.named_scope(scopes.ATTN_IN):
+        h = rms_norm(x, layer["ln1"], cfg.norm_eps)
+        q, k_new, v_new = gqa_qkv(layer, h, positions, cfg)
+        if cfg.kv_pack > 1:
+            q, k_new, v_new = _pack_heads(q, k_new, v_new, cfg)
     outs = []
-    for gi, g in enumerate(groups):
+    for gi in range(len(groups)):
         out, k_cache, v_cache, k_s, v_s = attend(
-            gi, g.take(q), g.take(k_new), g.take(v_new),
-            k_cache, v_cache, k_s, v_s, views, l, base,
+            gi, q, k_new, v_new, k_cache, v_cache, k_s, v_s, views, l, base,
         )
         outs.append(out)
-    out = _join_rows(groups, outs)
-    if cfg.kv_pack > 1:
-        out = _unpack_heads(out, cfg)
-    x = x + _unheads(out) @ layer["wo"]
+    with jax.named_scope(scopes.ATTN_DECODE):
+        out = _join_rows(groups, outs)
+        if cfg.kv_pack > 1:
+            out = _unpack_heads(out, cfg)
+    with jax.named_scope(scopes.ATTN_OUT):
+        x = x + _unheads(out) @ layer["wo"]
     return x, k_cache, v_cache, k_s, v_s
 
 
@@ -1333,35 +1346,48 @@ def _latent_layers(
         valid = jnp.concatenate([g.valid.reshape(-1) for g in groups])[None]
 
     def attend(layer, x, pool, l):
-        h = rms_norm(x, layer["ln1"], cfg.norm_eps)
-        rows, q_abs = latent_qkv(layer, h, positions, cfg)
+        with jax.named_scope(scopes.ATTN_IN):
+            h = rms_norm(x, layer["ln1"], cfg.norm_eps)
+            rows, q_abs = latent_qkv(layer, h, positions, cfg)
         outs = []
         for g in groups:
-            pool = _paged_pool_write(
-                pool[:, :, None], g.take(rows), g.table, g.start, g.n_valid,
-                l)[:, :, 0]
-            out_lat, _ = latent_attention(
-                g.take(q_abs), pool.reshape((-1,) + pool.shape[2:]),
-                l * N + g.table, q_offset=g.start, cfg=cfg,
-            )
+            with jax.named_scope(scopes.ATTN_CACHE):
+                pool = _paged_pool_write(
+                    pool[:, :, None], g.take(rows), g.table, g.start,
+                    g.n_valid, l)[:, :, 0]
+            with jax.named_scope(
+                    scopes.ATTN_CHUNK if g.chunk else scopes.ATTN_DECODE):
+                out_lat, _ = latent_attention(
+                    g.take(q_abs), pool.reshape((-1,) + pool.shape[2:]),
+                    l * N + g.table, q_offset=g.start, cfg=cfg,
+                )
             outs.append(out_lat)
-        return x + latent_out(layer, _join_rows(groups, outs)), pool
+        with jax.named_scope(scopes.ATTN_DECODE):
+            out_lat = _join_rows(groups, outs)
+        with jax.named_scope(scopes.ATTN_OUT):
+            return x + latent_out(layer, out_lat), pool
 
     def dense_body(carry, xs):
         layer, l = xs
         x, pool = attend(layer, *carry, l)
-        x = x + _mlp_block(layer, rms_norm(x, layer["ln2"], cfg.norm_eps))
+        with jax.named_scope(scopes.FFN):
+            x = x + _mlp_block(
+                layer, rms_norm(x, layer["ln2"], cfg.norm_eps))
         return (x, pool), None
 
     def expert_body(carry, xs):
         layer, l = xs
         x, pool = attend(layer, *carry, l)
-        h32 = rms_norm(x.astype(jnp.float32), layer["ln2"], cfg.norm_eps)
+        with jax.named_scope(scopes.ROUTE):
+            h32 = rms_norm(
+                x.astype(jnp.float32), layer["ln2"], cfg.norm_eps)
+            h = h32.astype(x.dtype)
         y, chosen = expert_layer(
-            layer, h32.astype(x.dtype), cfg.moe, router_input=h32,
+            layer, h, cfg.moe, router_input=h32,
             experts=experts, first=(l - n_dense) * cfg.moe.held,
         )
-        return (x + y, pool), held_counts(chosen, valid, cfg.moe)
+        with jax.named_scope(scopes.ROUTE):
+            return (x + y, pool), held_counts(chosen, valid, cfg.moe)
 
     def branch_body(carry, xs):
         layer, i = xs
@@ -1370,17 +1396,22 @@ def _latent_layers(
         for j in range(cfg.sublayers):
             sub = layer["sub"][j]
             x, pool = attend(sub, x, pool, i * cfg.sublayers + j)
-            h32 = rms_norm(x.astype(jnp.float32), sub["ln2"], cfg.norm_eps)
-            h = h32.astype(x.dtype)
+            with jax.named_scope(scopes.FFN):
+                h32 = rms_norm(
+                    x.astype(jnp.float32), sub["ln2"], cfg.norm_eps)
+                h = h32.astype(x.dtype)
             if j == leaves:
                 m, chosen = expert_layer(
                     layer, h, cfg.moe, router_input=h32,
                     experts=experts, first=i * cfg.moe.held,
                 )
-            x = x + _mlp_block(sub, h)
+            with jax.named_scope(scopes.FFN):
+                x = x + _mlp_block(sub, h)
             if j == rejoins:
-                x = x + m
-        return (x, pool), held_counts(chosen, valid, cfg.moe)
+                with jax.named_scope(scopes.ROUTE):
+                    x = x + m
+        with jax.named_scope(scopes.ROUTE):
+            return (x, pool), held_counts(chosen, valid, cfg.moe)
 
     carry = (x, cache.kv)
     n_dense = cfg.n_dense_layers
@@ -1596,7 +1627,9 @@ def _step_layers(
         x, k_cache, v_cache, k_s, v_s = gqa_mixer(
             attend, layer, x, positions, k_cache, v_cache, k_s, v_s, views,
             l, base)
-        x = x + _mlp_block(layer, rms_norm(x, layer["ln2"], cfg.norm_eps))
+        with jax.named_scope(scopes.FFN):
+            x = x + _mlp_block(
+                layer, rms_norm(x, layer["ln2"], cfg.norm_eps))
         new = (k_cache, v_cache)
         if paged and quant:
             new = new + (k_s, v_s)  # entered blocks' inherited scales
@@ -1798,7 +1831,8 @@ def forward_step(
             "caches shard the token axis via the mesh instead"
         )
 
-    x = jnp.take(params["embed"], tokens, axis=0)
+    with jax.named_scope(scopes.EMBED):
+        x = jnp.take(params["embed"], tokens, axis=0)
     _count_step(cache)
     group = _RowGroup(
         lo=None, batch=B, tq=Tq, start=start, n=n_tokens,
@@ -1809,7 +1843,8 @@ def forward_step(
         num_splits=num_splits, quant_kernel=quant_kernel, kv_shard=kv_shard,
         stats=stats,
     )
-    logits = unembed(params, rms_norm(x, params["ln_f"], cfg.norm_eps))
+    with jax.named_scope(scopes.HEAD):
+        logits = unembed(params, rms_norm(x, params["ln_f"], cfg.norm_eps))
     grew = Tq if n_tokens is None else n_tokens
     return logits, dataclasses.replace(cache, length=start + grew, **pools)
 
@@ -1886,29 +1921,34 @@ def forward_packed_step(
     c_start = length[chunk_slot]
     groups = (
         _RowGroup(lo=0, batch=C, tq=Tq, start=c_start, n=chunk_n,
-                  table=cache.table[chunk_slot], tree_mask=None),
+                  table=cache.table[chunk_slot], tree_mask=None, chunk=True),
         _RowGroup(lo=C * Tq, batch=S, tq=1, start=length, n=n_tokens,
                   table=cache.table, tree_mask=None),
     )
     c_pos = c_start[:, None] + jnp.arange(Tq, dtype=jnp.int32)
     positions = jnp.concatenate([c_pos.reshape(-1), length])[None]
-    rows = jnp.concatenate([chunk_tokens.reshape(-1), tokens.reshape(-1)])
-    x = jnp.take(params["embed"], rows[None], axis=0)  # (1, C·Tq + S, D)
+    with jax.named_scope(scopes.EMBED):
+        rows = jnp.concatenate(
+            [chunk_tokens.reshape(-1), tokens.reshape(-1)])
+        x = jnp.take(params["embed"], rows[None], axis=0)  # (1, C·Tq + S, D)
     _count_step(cache)
     x, pools = _step_layers(
         params, x, positions, groups, cache, cfg, mesh=mesh, axes=axes,
         num_splits=num_splits, quant_kernel=quant_kernel, kv_shard=kv_shard,
         stats=stats,
     )
-    # One row a slot: padding members scatter past the last slot and drop.
-    src = (C * Tq + jnp.arange(S, dtype=jnp.int32)).at[
-        jnp.where(chunk_n > 0, chunk_slot, S)
-    ].set(
-        jnp.arange(C, dtype=jnp.int32) * Tq + jnp.maximum(chunk_n - 1, 0),
-        mode="drop",
-    )
-    logits = unembed(
-        params, rms_norm(x[0, src], params["ln_f"], cfg.norm_eps))
+    with jax.named_scope(scopes.HEAD):
+        # One row a slot: padding members scatter past the last slot and
+        # drop.
+        src = (C * Tq + jnp.arange(S, dtype=jnp.int32)).at[
+            jnp.where(chunk_n > 0, chunk_slot, S)
+        ].set(
+            jnp.arange(C, dtype=jnp.int32) * Tq
+            + jnp.maximum(chunk_n - 1, 0),
+            mode="drop",
+        )
+        logits = unembed(
+            params, rms_norm(x[0, src], params["ln_f"], cfg.norm_eps))
     new_len = (length + n_tokens).at[chunk_slot].add(chunk_n)
     return logits, dataclasses.replace(cache, length=new_len, **pools)
 

@@ -36,6 +36,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from tree_attention_tpu.models.latent import init_latent_layer
+from tree_attention_tpu.obs import scopes
 from tree_attention_tpu.models.transformer import (
     ExpertLayer,
     TransformerConfig,
@@ -118,38 +119,47 @@ def expert_layer(p: Params, x: jax.Array, ex: ExpertLayer, *,
     R, K = B * T, ex.per_token
     xf = x.reshape(R, D)
     x_in = xf if router_input is None else router_input.reshape(R, D)
-    idx, w = route(router_scores(p, x_in, ex.scoring), ex,
-                   p["router_bias"] if ex.corrected else None)
-    local = idx - ex.held_first
-    here = (local >= 0) & (local < ex.held)
-    # Pairs sorted by held expert; a pair whose expert lives elsewhere
-    # sorts past every held one and belongs to no group.
-    key = jnp.where(here, local, ex.held).reshape(-1)
-    m = R * K
-    pad = -m % row_tile(m)
-    if pad:
-        key = jnp.concatenate([key, jnp.full((pad,), ex.held, jnp.int32)])
-    order = jnp.argsort(key, stable=True)
-    sizes = jnp.zeros((ex.held + 1,), jnp.int32).at[key].add(1)[:ex.held]
-    rows = jnp.minimum(order // K, R - 1)
-    hidden = grouped_matmul(xf[rows], (we1, we3), sizes, first_group=first)
-    out = grouped_matmul(hidden, (we2,), sizes, first_group=first)
-    # Back to (row, choice) order: pair j sits at sorted position inv[j].
-    inv = jnp.zeros((m + pad,), jnp.int32).at[order].set(
-        jnp.arange(m + pad, dtype=jnp.int32))[:m]
-    pairs = out[inv].reshape(R, K, D)
-    y = jnp.sum(
-        jnp.where(here[..., None],
-                  pairs.astype(jnp.float32) * w[..., None], 0.0),
-        axis=1,
-    )
-    if ex.n_zero:
-        # Identity experts: one weighted sum a row, no weights to read.
-        w_zero = jnp.sum(jnp.where(idx >= ex.n_routed, w, 0.0), axis=-1)
-        y = y + w_zero[:, None] * x_in.astype(jnp.float32)
-    y = y.astype(x.dtype)
+    with jax.named_scope(scopes.ROUTE):
+        idx, w = route(router_scores(p, x_in, ex.scoring), ex,
+                       p["router_bias"] if ex.corrected else None)
+        local = idx - ex.held_first
+        here = (local >= 0) & (local < ex.held)
+        # Pairs sorted by held expert; a pair whose expert lives elsewhere
+        # sorts past every held one and belongs to no group.
+        key = jnp.where(here, local, ex.held).reshape(-1)
+        m = R * K
+        pad = -m % row_tile(m)
+        if pad:
+            key = jnp.concatenate(
+                [key, jnp.full((pad,), ex.held, jnp.int32)])
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.zeros(
+            (ex.held + 1,), jnp.int32).at[key].add(1)[:ex.held]
+        rows = jnp.minimum(order // K, R - 1)
+        gathered = xf[rows]
+    with jax.named_scope(scopes.EXPERTS):
+        hidden = grouped_matmul(
+            gathered, (we1, we3), sizes, first_group=first)
+        out = grouped_matmul(hidden, (we2,), sizes, first_group=first)
+    with jax.named_scope(scopes.ROUTE):
+        # Back to (row, choice) order: pair j sits at sorted position
+        # inv[j].
+        inv = jnp.zeros((m + pad,), jnp.int32).at[order].set(
+            jnp.arange(m + pad, dtype=jnp.int32))[:m]
+        pairs = out[inv].reshape(R, K, D)
+        y = jnp.sum(
+            jnp.where(here[..., None],
+                      pairs.astype(jnp.float32) * w[..., None], 0.0),
+            axis=1,
+        )
+        if ex.n_zero:
+            # Identity experts: one weighted sum a row, no weights to read.
+            w_zero = jnp.sum(jnp.where(idx >= ex.n_routed, w, 0.0), axis=-1)
+            y = y + w_zero[:, None] * x_in.astype(jnp.float32)
+        y = y.astype(x.dtype)
     if ex.shared_width:
-        y = y + swiglu(xf, p["ws1"], p["ws3"], p["ws2"])
+        with jax.named_scope(scopes.FFN):
+            y = y + swiglu(xf, p["ws1"], p["ws3"], p["ws2"])
     return y.reshape(B, T, D), idx.reshape(B, T, K)
 
 

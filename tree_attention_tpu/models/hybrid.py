@@ -48,6 +48,7 @@ from tree_attention_tpu.models.transformer import (
     _mlp_block,
     rms_norm,
 )
+from tree_attention_tpu.obs import scopes
 
 
 def _tail_rows(flat: jax.Array, g: _RowGroup, c, back: int, n_blocks: int,
@@ -208,21 +209,28 @@ def hybrid_layers(
                     attend, of(params["attn"], mi), x, positions, k, v,
                     None, None, None, mi, mi * N)
             else:
-                x, tail, wrote = conv_mixer(
-                    of(params["conv"], mi), x, tail, mi, groups, cfg, block)
+                with jax.named_scope(scopes.CONV):
+                    x, tail, wrote = conv_mixer(
+                        of(params["conv"], mi), x, tail, mi, groups, cfg,
+                        block)
             if ffn == "dense":
-                layer = of(params["dense"], fi)
-                x = x + _mlp_block(
-                    layer, rms_norm(x, layer["ln2"], cfg.norm_eps))
+                with jax.named_scope(scopes.FFN):
+                    layer = of(params["dense"], fi)
+                    x = x + _mlp_block(
+                        layer, rms_norm(x, layer["ln2"], cfg.norm_eps))
                 return (x, k, v, tail), (None, wrote)
-            layer = of(routers, fi)
-            h32 = rms_norm(x.astype(jnp.float32), layer["ln2"], cfg.norm_eps)
+            with jax.named_scope(scopes.ROUTE):
+                layer = of(routers, fi)
+                h32 = rms_norm(
+                    x.astype(jnp.float32), layer["ln2"], cfg.norm_eps)
+                h = h32.astype(x.dtype)
             y, chosen = expert_layer(
-                layer, h32.astype(x.dtype), cfg.moe, router_input=h32,
+                layer, h, cfg.moe, router_input=h32,
                 experts=experts, first=fi * cfg.moe.held,
             )
-            return (x + y, k, v, tail), (
-                held_counts(chosen, valid, cfg.moe), wrote)
+            with jax.named_scope(scopes.ROUTE):
+                return (x + y, k, v, tail), (
+                    held_counts(chosen, valid, cfg.moe), wrote)
 
         return body
 

@@ -69,6 +69,7 @@ class FlightRecorder:
         self._last_tick_t: Optional[float] = None
         self._idle = True
         self._dump_path: Optional[str] = None
+        self._programs: List[Dict[str, Any]] = []
         self.enabled = False
 
     # -- lifecycle --------------------------------------------------------
@@ -92,13 +93,10 @@ class FlightRecorder:
     def clear(self) -> None:
         with self._lock:
             self._ring.clear()
+            self._programs = []
             self._ticks_recorded = 0
             self._last_tick_t = None
             self._idle = True
-
-    @property
-    def dump_path(self) -> Optional[str]:
-        return self._dump_path
 
     @property
     def capacity(self) -> int:
@@ -123,6 +121,15 @@ class FlightRecorder:
             self._last_tick_t = now
             self._idle = False
 
+    def describe_programs(self, tables: List[Dict[str, Any]]) -> None:
+        """Keep the engine's tables from its tick programs' operations to
+        the parts of the model (``obs/scopes.py``; the engine's own list,
+        which grows as it builds programs) for the dumps: a device trace
+        taken beside the ring names operations ``fusion.453``, and the
+        table says which part of the model each is."""
+        with self._lock:
+            self._programs = tables
+
     def mark_idle(self) -> None:
         """Declare the tick loop drained (a serve() run completed): the
         engine is between runs, not wedged — ``/healthz`` must not count
@@ -146,6 +153,7 @@ class FlightRecorder:
         with self._lock:
             records: List[Dict[str, Any]] = list(self._ring)
             ticks = self._ticks_recorded
+            programs = list(self._programs)
         age = self.last_tick_age()
         return {
             "captured_at": time.strftime("%Y-%m-%dT%H:%M:%S"),
@@ -153,6 +161,7 @@ class FlightRecorder:
             "ticks_recorded": ticks,
             "last_tick_age_s": None if age is None else round(age, 3),
             "records": records,
+            **({"programs": programs} if programs else {}),
         }
 
     def dump(self, path: str, reason: str = "on_demand") -> None:

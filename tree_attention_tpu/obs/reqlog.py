@@ -328,13 +328,6 @@ class ReqLog:
             })
         return out
 
-    def drop(self, uid: int) -> None:
-        """Forget a live ledger without ringing it (rejected pre-admit)."""
-        if not self.enabled:
-            return
-        with self._lock:
-            self._live.pop(uid, None)
-
     # -- read side (HTTP handler threads) ---------------------------------
 
     def get(self, uid: int) -> Optional[Dict[str, Any]]:

@@ -36,18 +36,11 @@ from tree_attention_tpu.utils.logging import get_logger
 log = get_logger("ops")
 
 # Dispatch accounting (trace-time under an enclosing jit — see
-# obs.metrics): which decode path served the call, and how many KV/query
-# tokens one executed step of it scans/produces. Execution-true token
-# totals live in the host loops (bench/harness.py, cli.py).
+# obs.metrics): which decode path served the call. Execution-true token
+# totals live in the host loops (bench/harness.py).
 _DECODE_DISPATCH = obs.counter(
     "decode_dispatch_total",
     "flash_decode dispatches by kernel path (trace-time under jit)",
-    labels=("path",),
-)
-_DECODE_KV_TOKENS = obs.counter(
-    "decode_dispatch_kv_tokens_total",
-    "KV tokens one executed step of each dispatched decode call scans "
-    "(trace-time under jit)",
     labels=("path",),
 )
 
@@ -69,7 +62,6 @@ def _account_dispatch(path: str, kv_tokens: int) -> None:
     if not obs.REGISTRY.enabled:
         return
     _DECODE_DISPATCH.labels(path=path).inc()
-    _DECODE_KV_TOKENS.labels(path=path).inc(int(kv_tokens))
 
 
 def default_num_splits(kv_len: int, block_size: int) -> int:

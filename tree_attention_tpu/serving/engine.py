@@ -129,6 +129,7 @@ sequence shards, where every tick's decode attention runs the tree merge.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import threading
 import time
 from collections import deque
@@ -143,6 +144,7 @@ from jax import lax
 from jax.sharding import Mesh
 
 from tree_attention_tpu import obs
+from tree_attention_tpu.obs import scopes
 from tree_attention_tpu.obs.flight import FLIGHT, TickPhases
 from tree_attention_tpu.obs.metrics import percentile
 from tree_attention_tpu.obs.slo import SLOMonitor
@@ -546,6 +548,10 @@ class ServeReport:
     # results — phase-wall sums/p50s, token and KV-block totals. Empty
     # when the request ledger is disarmed.
     requests: Dict[str, Any] = dataclasses.field(default_factory=dict)
+    # One table a tick program built by the run's end, from its operations
+    # to the parts of the model (``SlotServer.program_tables``); empty
+    # unless the flight recorder or the span tracer was on.
+    programs: List[Dict[str, Any]] = dataclasses.field(default_factory=list)
 
     @property
     def tokens_per_sec(self) -> float:
@@ -599,6 +605,7 @@ class ServeReport:
             **({"spec": self.spec} if self.spec else {}),
             **({"handoff": self.handoff} if self.handoff else {}),
             **({"request_ledgers": self.requests} if self.requests else {}),
+            **({"programs": self.programs} if self.programs else {}),
         }
 
 
@@ -1360,8 +1367,15 @@ class SlotServer:
         # call's outputs, so the old buffers are donated — each call
         # updates the (L,S,Hkv,Tmax,D) cache in place instead of copying
         # it (backends without donation just copy).
-        self._mixed = jax.jit(self._mixed_fn, donate_argnums=(6,))
-        self._packed = jax.jit(self._packed_fn, donate_argnums=(9,))
+        # The tick programs also note each Tq bucket they are traced for
+        # (:meth:`_noting`): what :meth:`program_tables` describes.
+        self._tick_programs: Dict[Tuple[str, int], Optional[Dict[str, Any]]] \
+            = {}
+        self._untabled = False
+        self._mixed = jax.jit(
+            self._noting("_mixed", self._mixed_fn), donate_argnums=(6,))
+        self._packed = jax.jit(
+            self._noting("_packed", self._packed_fn), donate_argnums=(9,))
         self._prefill = jax.jit(self._prefill_fn)
         self._insert = jax.jit(self._insert_fn, donate_argnums=(0, 1, 2))
         if self._needs_staging:
@@ -1421,11 +1435,18 @@ class SlotServer:
         # tree sibling decode (ISSUE 20) — jitted unconditionally; an
         # engine that never runs a verify tick never compiles them.
         self._spec_lin = jax.jit(
-            self._spec_lin_fn, donate_argnums=(8,)
+            self._noting("_spec_lin", self._spec_lin_fn),
+            donate_argnums=(8,)
         )
         self._spec_tree = jax.jit(
-            self._spec_tree_fn, donate_argnums=(10,)
+            self._noting("_spec_tree", self._spec_tree_fn),
+            donate_argnums=(10,)
         )
+        # The jitted tick programs by name, for :meth:`program_tables`
+        # (a test may put a spy in an attribute's place).
+        self._tick_jits = {
+            "_mixed": self._mixed, "_packed": self._packed,
+            "_spec_lin": self._spec_lin, "_spec_tree": self._spec_tree}
         self._compact = jax.jit(self._compact_fn, donate_argnums=(0,))
 
     # -- compiled pieces --------------------------------------------------
@@ -1512,7 +1533,8 @@ class SlotServer:
         temperature is 0 — value-identical to the legacy greedy path —
         temperature/top-k categorical under fold_in(key, idx)
         otherwise. Returns (tokens, model logprobs of the choices)."""
-        return sample_slots(last, temp, topk, keys, idx)
+        with jax.named_scope(scopes.HEAD):
+            return sample_slots(last, temp, topk, keys, idx)
 
     def _chunk_bucket(self, n: int) -> int:
         """Tq bucket for a chunk of ``n`` prompt tokens: power-of-two with
@@ -1542,13 +1564,14 @@ class SlotServer:
         + bitcast logprobs — the per-tick host sync stays a single array).
         """
         tok_s, lp_s = self._sample_emit(last, keys, temp, topk, idx)
-        nxt = jnp.where(emit, tok_s, held)
-        lp_out = jnp.where(emit, lp_s, lp_vec)
-        fused = jnp.concatenate(
-            [nxt[:, None],
-             lax.bitcast_convert_type(lp_out, jnp.int32)[:, None]],
-            axis=1,
-        )
+        with jax.named_scope(scopes.HEAD):
+            nxt = jnp.where(emit, tok_s, held)
+            lp_out = jnp.where(emit, lp_s, lp_vec)
+            fused = jnp.concatenate(
+                [nxt[:, None],
+                 lax.bitcast_convert_type(lp_out, jnp.int32)[:, None]],
+                axis=1,
+            )
         if "expert_rows" in stats or "tail_blocks" in stats:
             # The step's counters ride the tick's one fetch as further
             # rows of the same array (tracing on or off: one program):
@@ -1584,8 +1607,10 @@ class SlotServer:
             params, tokens, cache, self.cfg, n_tokens=n_tok, stats=stats,
             **self._step_kw()
         )
-        row = jnp.maximum(n_tok - 1, 0)
-        last = jnp.take_along_axis(logits, row[:, None, None], axis=1)[:, 0]
+        with jax.named_scope(scopes.HEAD):
+            row = jnp.maximum(n_tok - 1, 0)
+            last = jnp.take_along_axis(
+                logits, row[:, None, None], axis=1)[:, 0]
         nxt, lp_out, fused = self._emit_fused(
             last, tokens[:, 0], emit, keys, temp, topk, idx, lp_vec, stats)
         # ``last`` rides out as a device carry: a fork family samples
@@ -1725,38 +1750,41 @@ class SlotServer:
         logits, new_cache = forward_step(
             params, tokens, cache, self.cfg, n_tokens=n_tok, **kw
         )
-        row = jnp.maximum(n_tok - 1, 0)
-        last = jnp.take_along_axis(logits, row[:, None, None], axis=1)[:, 0]
+        with jax.named_scope(scopes.HEAD):
+            row = jnp.maximum(n_tok - 1, 0)
+            last = jnp.take_along_axis(
+                logits, row[:, None, None], axis=1)[:, 0]
         # Column 0 keeps the mixed-step emit contract verbatim (final
         # chunks sample their first token under the slot key, parked
         # tokens/logprobs ride through) — temperature-0 slots reduce to
         # the legacy greedy argmax bit-for-bit.
         tok_s, lp_s = self._sample_emit(last, keys, temp, topk, idx)
-        nxt = jnp.where(emit, tok_s, tokens[:, 0])
-        lp_out = jnp.where(emit, lp_s, lp_vec)
+        with jax.named_scope(scopes.HEAD):
+            nxt = jnp.where(emit, tok_s, tokens[:, 0])
+            lp_out = jnp.where(emit, lp_s, lp_vec)
 
-        def _row_key(key, s, b, r):
-            tree_k = jax.random.fold_in(jax.random.fold_in(
-                jax.random.fold_in(self._base_key, s), b), r)
-            return jnp.where(b < 0, jax.random.fold_in(key, r), tree_k)
+            def _row_key(key, s, b, r):
+                tree_k = jax.random.fold_in(jax.random.fold_in(
+                    jax.random.fold_in(self._base_key, s), b), r)
+                return jnp.where(b < 0, jax.random.fold_in(key, r), tree_k)
 
-        row_keys = jax.vmap(
-            lambda key, s, bs, rs: jax.vmap(
-                lambda b, r: _row_key(key, s, b, r))(bs, rs)
-        )(keys, salt, branch_m, ridx_m)
-        all_tok, all_lp = sample_rows(logits, temp, topk, row_keys)
-        # One fused (S, 1+Tq, 2) output = ONE host fetch per tick: lane
-        # 0 tokens, lane 1 bitcast logprobs; row 0 the token/logprob
-        # vectors (the awaits/parked contract), the rest the per-row
-        # draws.
-        col0 = jnp.stack(
-            [nxt, lax.bitcast_convert_type(lp_out, jnp.int32)], axis=-1,
-        )[:, None]
-        rest = jnp.stack(
-            [all_tok, lax.bitcast_convert_type(all_lp, jnp.int32)],
-            axis=-1,
-        )
-        fused = jnp.concatenate([col0, rest], axis=1)
+            row_keys = jax.vmap(
+                lambda key, s, bs, rs: jax.vmap(
+                    lambda b, r: _row_key(key, s, b, r))(bs, rs)
+            )(keys, salt, branch_m, ridx_m)
+            all_tok, all_lp = sample_rows(logits, temp, topk, row_keys)
+            # One fused (S, 1+Tq, 2) output = ONE host fetch per tick: lane
+            # 0 tokens, lane 1 bitcast logprobs; row 0 the token/logprob
+            # vectors (the awaits/parked contract), the rest the per-row
+            # draws.
+            col0 = jnp.stack(
+                [nxt, lax.bitcast_convert_type(lp_out, jnp.int32)], axis=-1,
+            )[:, None]
+            rest = jnp.stack(
+                [all_tok, lax.bitcast_convert_type(all_lp, jnp.int32)],
+                axis=-1,
+            )
+            fused = jnp.concatenate([col0, rest], axis=1)
         # ``last`` rides out as a device carry exactly like the mixed
         # step's: a family admitted on a verify tick still stashes its
         # prompt-end logits row for the fork/tree start. Fetched never.
@@ -1907,6 +1935,66 @@ class SlotServer:
                                                   slot, axis=0)
         return staging, new_cache, tok_vec, lp_vec, last
 
+    # -- the tick programs, described ---------------------------------------
+
+    def _noting(self, name: str, fn):
+        """A tick program's function (``name``: the attribute its jit is
+        kept under) that notes each Tq bucket it is traced for. Python runs
+        the note only while jit traces a new shape, never when a tick
+        dispatches: knowing which programs exist costs a tick nothing."""
+        @functools.wraps(fn)
+        def traced(*args):
+            # Every tick program's second operand is its ``(·, Tq)`` rows.
+            self._tick_programs.setdefault((name, args[1].shape[1]), None)
+            self._untabled = True
+            return fn(*args)
+
+        return traced
+
+    def _tick_operands(self, name: str, tq: int) -> Tuple[Any, ...]:
+        """The abstract operands of tick program ``name`` at Tq bucket
+        ``tq``, as the serve loop dispatches it, from the engine's live
+        state: a device array's shape, type and, where it is committed,
+        its sharding; a host operand's shape and type (``jnp.asarray`` of
+        it is uncommitted). That is what jit keys a dispatch by, so
+        lowering from these finds the program the loop runs (no array is
+        held: nothing is kept alive)."""
+        S, C = self.slots, self._chunk_group
+
+        def dev(a, shape=None):
+            return jax.ShapeDtypeStruct(
+                a.shape if shape is None else shape, a.dtype,
+                sharding=a.sharding if getattr(a, "committed", False)
+                else None, weak_type=getattr(a, "weak_type", False))
+
+        def host(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype)
+
+        i32, flag = jnp.int32, jnp.bool_
+        params = jax.tree.map(dev, self.params)
+        cache = jax.tree.map(dev, self.cache)
+        tok = dev(self.tok)
+        # n_vec, reset, reset_val, emit | keys, temp, topk, sidx, lp
+        per_slot = (host((S,), i32), host((S,), flag), host((S,), i32),
+                    host((S,), flag))
+        sampling = (dev(self._keys), host((S,), self._temp_np.dtype),
+                    host((S,), self._topk_np.dtype), host((S,), i32),
+                    dev(self._lp))
+        if name == "_mixed":
+            return (params, dev(self.tok, (S, tq)), *per_slot, cache,
+                    *sampling)
+        if name == "_packed":
+            return (params, host((C, tq), i32), host((C,), i32),
+                    host((C,), i32), tok, *per_slot, cache, *sampling)
+        if name in ("_spec_lin", "_spec_tree"):
+            tree = () if name == "_spec_lin" else (
+                host((S, tq), i32), host((S, tq, tq), flag))
+            return (params, host((S, tq), i32), tok, host((S,), flag),
+                    *per_slot, *tree, cache, *sampling,
+                    host((S,), self._salt_np.dtype), host((S, tq), i32),
+                    host((S, tq), i32))
+        raise ValueError(f"no tick program {name!r}")
+
     def lower_programs(self, tq: int) -> Dict[str, Any]:
         """Lower — from the engine's live state, dispatching and donating
         nothing — the programs a tick at Tq bucket ``tq`` runs: the fused
@@ -1915,30 +2003,49 @@ class SlotServer:
         stages prompts (int8 chunked), the ``stage_chunk`` program.
         ``.compile().as_text()`` of each shows what a served tick
         executes, e.g. which Pallas kernels are in it; with the programs
-        already run, the compile is a cache hit."""
-        S, C = self.slots, self._chunk_group
-        zeros = lambda n, dt: jnp.zeros((n,), dt)
-        per_slot = (
-            zeros(S, jnp.int32), zeros(S, bool), zeros(S, jnp.int32),
-            zeros(S, bool), self.cache, self._keys,
-            jnp.asarray(self._temp_np), jnp.asarray(self._topk_np),
-            zeros(S, jnp.int32), self._lp,
-        )
-        if tq == 1:
-            lowered = {"mixed": self._mixed.lower(
-                self.params, jnp.zeros((S, 1), jnp.int32), *per_slot)}
-        else:
-            lowered = {"mixed": self._packed.lower(
-                self.params, jnp.zeros((C, tq), jnp.int32),
-                zeros(C, jnp.int32), zeros(C, jnp.int32),
-                zeros(S, jnp.int32), *per_slot)}
+        already run, the compile is the loop's own executable."""
+        name = "_mixed" if tq == 1 else "_packed"
+        lowered = {"mixed": self._tick_jits[name].lower(
+            *self._tick_operands(name, tq))}
         if self._staged_prefill:
+            zeros = lambda n, dt: jnp.zeros((n,), dt)
             lowered["stage_chunk"] = self._stage_chunk.lower(
                 self.params, jnp.zeros((1, tq), jnp.int32),
                 zeros(1, jnp.int32), self._staging, zeros(1, bool),
                 zeros(1, jnp.int32),
             )
         return lowered
+
+    def program_tables(self) -> List[Dict[str, Any]]:
+        """One table for each tick program built so far, from its
+        operations to the parts of the model (``obs/scopes.py``):
+        ``program`` (``fn``: the engine's name for it, and what a flight
+        record of its ticks holds: ``kind``, ``tq``, ``chunk_group``) and
+        ``ops``, rows ``[op, result, scope]`` of the optimized module.
+        A table is made once a program: the program is lowered from the
+        operands the loop dispatches it with (:meth:`_tick_operands`),
+        which finds the loop's own executable, compiling nothing, where
+        jit keys them alike; where it does not (a mesh that re-lays an
+        operand) that is one compile of a twin. The serve loop calls this
+        only while tracing is on, at the top of a run and after a tick
+        that built a new program."""
+        kinds = {"_mixed": "decode", "_packed": "mixed",
+                 "_spec_lin": "verify", "_spec_tree": "verify"}
+        for (name, tq), made in sorted(self._tick_programs.items()):
+            if made is not None:
+                continue
+            text = self._tick_jits[name].lower(
+                *self._tick_operands(name, tq)).compile().as_text()
+            self._tick_programs[name, tq] = {
+                "program": {
+                    "fn": name, "kind": kinds[name], "tq": tq,
+                    "chunk_group": self._chunk_group
+                    if name == "_packed" else 0},
+                "ops": scopes.table(text),
+            }
+        self._untabled = False
+        return [t for _, t in sorted(self._tick_programs.items())
+                if t is not None]
 
     # -- ingress-facing control (thread-safe) ------------------------------
 
@@ -2433,10 +2540,6 @@ class SlotServer:
             # A dry allocator forced this flush mid-admission: charge
             # the demotions to the admitting request's ledger scratch.
             self._adm_demoted += len(bids)
-        if obs.TRACER.active:
-            obs.instant("kv_demote_flush", cat="serving", args={
-                "blocks": len(bids),
-            })
         return len(bids)
 
     def _admit(self, req: Request, slot: int, tick: int,
@@ -3605,11 +3708,6 @@ class SlotServer:
         need = -(-(len(req.prompt) + req.max_new_tokens)
                  // self.kv_block)
         self._slot_trim(slot, need)
-        if obs.TRACER.active:
-            obs.instant("tree_collapse", cat="serving", args={
-                "rid": req.uid, "slot": slot, "index": fam.br_index[b],
-                "suffix": s,
-            })
 
     def _tree_close(self, slot: int, fam: _ForkFamily,
                     tick: int) -> None:
@@ -4212,6 +4310,12 @@ class SlotServer:
         # boundary, off unless the flight recorder or the span tracer is
         # on. Marks sit BETWEEN the mirror[...] regions below.
         phases = TickPhases()
+        tables: List[Dict[str, Any]] = []
+        if FLIGHT.enabled or obs.TRACER.active:
+            # Before the first tick: describing a program reads its text.
+            tables = self.program_tables()
+            if FLIGHT.enabled:
+                FLIGHT.describe_programs(tables)
 
         def admit() -> None:
             """Admit: oldest visible request per free slot. Chunked
@@ -4500,6 +4604,11 @@ class SlotServer:
             # engine's as the record closes, ``phases``/``t_end`` are the
             # stamps of the iteration that closes it.
             if FLIGHT.enabled:
+                if self._untabled:
+                    # A tick built a new program (its compile was that
+                    # tick's cost): describe it for the dumps.
+                    tables[:] = self.program_tables()
+                    FLIGHT.describe_programs(tables)
                 rec = {
                     "tick": p.tick,
                     # The instant the device could start this program:
@@ -5383,4 +5492,5 @@ class SlotServer:
             requests=obs.aggregate_ledgers(
                 [r.ledger for r in results if r.ledger is not None]
             ) or {},
+            programs=tables,
         )
