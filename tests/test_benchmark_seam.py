@@ -48,6 +48,8 @@ PARTS_MOE = PARTS_DENSE[:4] + ["dec_moe_ms_tick"] + PARTS_DENSE[4:9] + [
     "mix_moe_ms_tick", "tick_unscoped_pct"]
 PARTS_ALL = PARTS_MOE[:5] + ["dec_conv_ms_tick"] + PARTS_MOE[5:11] + [
     "mix_conv_ms_tick", "tick_unscoped_pct"]
+# What PR 37 appended after them, for every cell.
+AFTER_PARTS = ["paged_steps_run_pct"]
 
 
 def _sources(but=()):
@@ -278,7 +280,7 @@ def test_the_double_layer_cell_resolves_every_file_it_names():
     # (what later PRs appended for every cell comes after them: PR 32's,
     # and PR 35's parts as the expert cells list them).
     theirs = [m["name"] for m in spec.cell(CELL).per_layer]
-    later = ["tick_ahead_pct"] + PARTS_MOE
+    later = ["tick_ahead_pct"] + PARTS_MOE + AFTER_PARTS
     assert theirs[-len(later):] == later
     assert names == theirs[:-len(later)] + [
         "zero_expert_pairs_pct", "real_experts_row_max_over_mean"] + later
@@ -444,7 +446,8 @@ def test_tick_ahead_pct_reads_the_look_ahead_counter(flight, want):
     # Only what later PRs appended comes after it (PR 33's for its own cell,
     # PR 35's parts).
     assert [m["name"] for m in listed[at + 1:]] == [
-        "mixer_rest_ms_tick", "mixer_rest_stream_roofline"] + PARTS_ALL
+        "mixer_rest_ms_tick", "mixer_rest_stream_roofline"] + PARTS_ALL \
+        + AFTER_PARTS
     for w in json.load(open(BENCH))["workloads"]:
         assert "tick_ahead_pct" in [
             m["name"] for m in spec.cell(w["name"]).per_layer]
@@ -488,8 +491,9 @@ def test_the_hybrid_cell_resolves_every_file_it_names():
                  "expert_rows_max_over_mean", "tick_ahead_pct",
                  "device_idle_pct", "decode_tick_p50_ms"):
         assert name in names, name
-    assert names[-len(PARTS_ALL):] == PARTS_ALL       # PR 35's, all of them
-    own = slice(-2 - len(PARTS_ALL), -len(PARTS_ALL))
+    later = PARTS_ALL + AFTER_PARTS           # PR 35's, all of them; PR 37's
+    assert names[-len(later):] == later
+    own = slice(-2 - len(later), -len(later))
     assert names[own] == ["mixer_rest_ms_tick", "mixer_rest_stream_roofline"]
     for m in cell.per_layer[own]:
         assert m["workloads"] == [LFM_CELL] and m["layer"] == "kernels"
@@ -669,7 +673,8 @@ def test_a_parts_metric_is_listed_where_its_part_exists(name):
     parts in the hybrid's alone, the rest in all six."""
     spec = Spec(BENCH)
     listed = json.load(open(BENCH))["per_layer"]
-    assert [m["name"] for m in listed[-len(PARTS_ALL):]] == PARTS_ALL
+    assert [m["name"] for m in listed[-len(PARTS_ALL) - len(AFTER_PARTS):]] \
+        == PARTS_ALL + AFTER_PARTS
     entry = next(m for m in listed if m["name"] == name)
     cells = [w["name"] for w in spec.data["workloads"]]
     want = cells
@@ -700,3 +705,42 @@ def test_the_parts_readers_name_no_leaf_of_a_family():
     with open(os.path.join(ROOT, "benchmark", "parts.py")) as f:
         text = f.read()
     assert "except ImportError" in text and '"embed"' not in text
+
+
+# -- the paged kernels' work lists (ISSUE 37) --------------------------------
+
+
+def test_paged_steps_run_pct_reads_the_lists_the_kernels_walk():
+    """One entry appended last, for all six cells: the flight records'
+    ``kv_steps_run`` over ``kv_steps_grid`` in the window's decode ticks. A
+    program without the counters (the parent), an untraced run and a window
+    without a decode tick give None."""
+    import types
+
+    spec = Spec(BENCH)
+    listed = json.load(open(BENCH))["per_layer"]
+    cells = [w["name"] for w in spec.data["workloads"]]
+    assert listed[-1] == {
+        "name": "paged_steps_run_pct", "unit": "%", "better": "lower",
+        "source": "program_counter", "layer": "kernels",
+        "moves": "tbt_p50_ms", "workloads": cells}
+    for cell in cells:
+        assert spec.cell(cell).per_layer[-1]["name"] == "paged_steps_run_pct"
+    read = spec.load_module("layer_metrics", "paged_steps_run_pct.py").read
+    tick = {"occupancy": 16, "chunk_tokens": 0, "kv_steps_grid": 160}
+    flight = [
+        dict(tick, t_s=2.0, kv_steps_run=50),
+        dict(tick, t_s=3.0, kv_steps_run=70),
+        dict(tick, t_s=4.0, kv_steps_run=170, kv_steps_grid=176,
+             chunk_tokens=64),                       # a mixed tick
+        dict(tick, t_s=5.0, kv_steps_run=16, occupancy=0),   # no live slot
+        dict(tick, t_s=0.5, kv_steps_run=160),       # before the window
+        dict(tick, t_s=12.0, kv_steps_run=160),      # after it
+    ]
+    run = types.SimpleNamespace(flight=flight, t_open=1.0, t_end=10.0)
+    assert read(run) == pytest.approx(100.0 * 120 / 320)
+    parent = [{k: v for k, v in r.items() if not k.startswith("kv_steps")}
+              for r in flight]
+    for none in (parent, [], None, flight[2:]):
+        run = types.SimpleNamespace(flight=none, t_open=1.0, t_end=10.0)
+        assert read(run) is None

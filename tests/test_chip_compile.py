@@ -84,6 +84,8 @@ def _s(shape, dtype=jnp.bfloat16):
 # Their pools hold 16 layers, as the tick programs' do.
 MISTRAL = dict(slots=16, hkv=8, nb=40, layers=16)
 YI = dict(slots=8, hkv=4, nb=64, layers=16)
+# The hybrid's 3 attention layers: 8 KV heads of 64 lanes, two to a row.
+LFM2 = dict(slots=64, hkv=4, nb=40, layers=3)
 
 
 def _paged(kernel, tq, *, int8=False, tree=False, slots=B, hkv=HKV, nb=NB,
@@ -195,6 +197,21 @@ CASES = {
         lambda: _paged(attention_pallas_decode_q8q, 8, int8=True, tree=True,
                        **YI),
         "flash_decode_paged_q8q"),
+    # The other cells' slots (32 and 64; the latent cells' kernel is below)
+    # and the packed programs' smallest chunk bucket.
+    "paged_decode_lfm2_tq1": (
+        lambda: _paged(attention_pallas_decode, 1, **LFM2),
+        "flash_decode_paged"),
+    "paged_chunk_lfm2_tq16": (
+        lambda: _paged(attention_pallas_decode, 16, **LFM2),
+        "flash_decode_paged"),
+    "paged_chunk_mistral7b_tq8": (
+        lambda: _paged(attention_pallas_decode, 8, **MISTRAL),
+        "flash_decode_paged"),
+    "paged_decode_32_slots_tq1": (
+        lambda: _paged(attention_pallas_decode, 1, slots=32, hkv=8, nb=40,
+                       layers=8),
+        "flash_decode_paged"),
     # 32 KV heads at a chunk's 128 packed rows a head: every head's Q-side
     # state in one step is refused for VMEM; the step rule cuts the heads.
     "paged_chunk_mha32_tq127_heads_cut": (
@@ -206,6 +223,19 @@ CASES = {
     "bwd_dq": (_train_fwd_bwd, "flash_bwd_dq"),
     "bwd_dkv": (_train_fwd_bwd, "flash_bwd_dkv"),
 }
+
+
+def _dynamic_grids(text, kernel):
+    """For each launch of ``kernel`` in the module, whether its grid has a
+    dynamic bound: the scalar that leads its operands, before the five
+    scalar-prefetch lists (offsets, table, slot, step, flags). The
+    rectangular grid's launches led with the offsets."""
+    return [
+        "operand_layout_constraints={s32[], s32[2," in line
+        for line in text.splitlines()
+        if re.match(rf"\s+(?:ROOT )?%{kernel}(\.\d+)? = .* custom-call\(",
+                    line)
+    ]
 
 
 @functools.lru_cache(maxsize=None)
@@ -222,7 +252,9 @@ def test_kernel_compiles_for_v5e(case):
     text = _compiled_text(builder)
     assert "tpu_custom_call" in text
     assert kernel in pallas_kernels(text), pallas_kernels(text)
-    if "_mistral7b" in case or "_yi6b" in case:
+    if kernel.startswith("flash_decode_paged"):
+        assert _dynamic_grids(text, kernel) == [True]
+    if "_mistral7b" in case or "_yi6b" in case or "_lfm2" in case:
         # The pool goes into the call as it is: no copy, slice or change of
         # layout of a pool-sized array before the launch (what a 576-lane
         # latent row cost before PR 27 padded it). Not asked of the smoke's
@@ -554,6 +586,7 @@ def _moe_kernel(name, m):
 
 LATENT_CASES = {
     "mla_decode_tq1": (lambda n: _mla_kernel(n, 1), "mla_decode_paged"),
+    "mla_chunk_tq16": (lambda n: _mla_kernel(n, 16), "mla_decode_paged"),
     "mla_chunk_tq256": (lambda n: _mla_kernel(n, 256), "mla_decode_paged"),
     "moe_decode_pairs": (lambda n: _moe_kernel(n, 128),
                          "moe_grouped_matmul"),
@@ -572,6 +605,7 @@ def test_latent_and_expert_kernels_compile_for_v5e(config, case):
     assert "tpu_custom_call" in text
     assert kernel in pallas_kernels(text), pallas_kernels(text)
     if kernel == "mla_decode_paged":
+        assert _dynamic_grids(text, kernel) == [True]
         # No operand of the kernel is copied on its way in.
         assert not re.search(r"= bf16\[[\d,]+\]\S* copy\(", text), text[:0] \
             + "an operand of mla_decode_paged is copied before the launch"
@@ -853,3 +887,49 @@ def test_tick_programs_keep_the_scopes(config, program):
         assert kernels["flash_decode_paged"] == scopes.ATTN_DECODE
     if cfg.moe is not None:
         assert kernels["moe_grouped_matmul"] == scopes.EXPERTS
+
+
+# -- the paged kernels' work lists: built once a tick (ISSUE 37) -------------
+#
+# The paged decode kernels walk a list of the (slot, step) pairs that hold a
+# live token, and the list's length is their grid's dynamic bound. The list
+# follows from a group's lengths, not from the layer, so a step program
+# builds it before its layer loop (``models/decode.py`` ``_plan_groups``) and
+# a layer's call only shifts the table in it. Built inside the call, the
+# compiler left about eight small operations of it in the loop's body: a
+# tenth of what the list saves, in every layer.
+
+
+@pytest.mark.parametrize("program", sorted(TICK_PROGRAMS))
+@pytest.mark.parametrize("config", ALL_CONFIGS)
+def test_tick_programs_build_the_work_lists_outside_the_layer_loops(
+        config, program):
+    if _chip() is None:
+        pytest.skip("the v5e:2x2 topology cannot be described here")
+    from tree_attention_tpu.obs import scopes
+    from tree_attention_tpu.ops.pallas_decode import PLAN_SCOPE
+
+    tq, packed = TICK_PROGRAMS[program]
+    text = _tick_program(config, tq, packed=packed).text
+    kernel = "mla_decode_paged" if _model(config)[1].mla is not None \
+        else "flash_decode_paged"
+    # Every launch on the list's dynamic bound: the decode group's, and the
+    # chunk group's where the paged kernel serves it.
+    grids = _dynamic_grids(text, kernel)
+    assert grids and all(grids), grids
+    entry = re.search(r"^ENTRY (%[\w.\-]+)", text, re.M).group(1)[1:]
+    instrs = list(scopes.instructions(text))
+    plans = [i for i in instrs if f"/{PLAN_SCOPE}/" in f"/{i.scope}/"]
+    assert plans, "no operation of a plan is named in the module"
+    assert {i.scope.split("/")[0] for i in plans} <= {
+        scopes.ATTN_DECODE, scopes.ATTN_CHUNK}
+    # The computations that launch the kernel from inside a loop: the layer
+    # loops' bodies (the hybrid's attention layers are runs of one, no loop).
+    bodies = {i.computation for i in instrs
+              if i.opcode == "custom-call" and i.op.startswith(kernel)
+              and i.computation != entry}
+    if config != "lfm2-8b-a1b":
+        assert bodies
+    inside = [(i.computation, i.op, i.scope) for i in plans
+              if i.computation in bodies]
+    assert not inside, inside

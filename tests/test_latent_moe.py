@@ -266,6 +266,61 @@ def test_paged_kernel_equals_the_gathered_reference(tq):
     np.testing.assert_allclose(l1, l2, atol=1e-5)
 
 
+# (slots, table width, block, rows a slot, heads, where each slot's first row
+# sits: None = ragged over the capacity): the table widths take 4, 2 and 1
+# blocks a grid step; lengths at 0, a step's edge and the capacity; Tq 1 and
+# a chunk of rows; one Q tile and several.
+MLA_KERNEL = {
+    "ragged_width12_tq5": (3, 12, 4, 5, 4, None),
+    "ragged_width6_tq1": (5, 6, 4, 1, 4, None),
+    "ragged_width7_one_block_a_step_tq1": (4, 7, 8, 1, 4, None),
+    "edges_width12_tq1": (6, 12, 4, 1, 4, [0, 15, 16, 31, 32, 47]),
+    "full_width8_tq1": (3, 8, 4, 1, 4, [31, 31, 31]),
+    "chunk_two_q_tiles_width8_tq300": (2, 8, 64, 300, 4, [0, 130]),
+    "chunk_tail_reaches_a_step_width12_tq9": (4, 12, 4, 9, 8, [7, 8, 23, 39]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(MLA_KERNEL))
+def test_paged_kernel_walks_its_live_steps(name):
+    """``mla_decode_paged`` on its list of live steps (ISSUE 37): held to
+    the plain reference, and to the bits of the rectangular grid it launched
+    before (``tests/paged_rectangle.py``), with the plan built in the call
+    and handed in."""
+    from tests import paged_rectangle
+    from tree_attention_tpu.ops.pallas_decode import (
+        attention_pallas_mla_paged, mla_plan,
+    )
+
+    B, NB, block, Tq, H, starts = MLA_KERNEL[name]
+    rng = np.random.default_rng(sorted(MLA_KERNEL).index(name))
+    rank, rope = 32, 8
+    W = rank + rope
+    N = B * NB + 2
+    q = jnp.asarray(rng.normal(size=(B, H, Tq, W)), jnp.float32)
+    pool = jnp.asarray(rng.normal(size=(N, block, W)), jnp.float32)
+    table = jnp.asarray(rng.permutation(N)[:B * NB].reshape(B, NB), jnp.int32)
+    if starts is None:
+        starts = rng.integers(0, NB * block - Tq + 1, size=B)
+    q_offset = jnp.asarray(starts, jnp.int32)
+    kw = dict(q_offset=q_offset, scale=0.3, rank=rank)
+    o1, l1 = attention_pallas_mla_paged(q, pool, table, interpret=True, **kw)
+    o2, l2 = latent.latent_attention_reference(q, pool, table, **kw)
+    np.testing.assert_allclose(o1, o2, atol=2e-5)
+    np.testing.assert_allclose(l1, l2, atol=2e-5)
+    o3, l3 = paged_rectangle.attention_pallas_mla_paged(
+        q, pool, table, interpret=True, **kw)
+    np.testing.assert_array_equal(np.asarray(o1), np.asarray(o3))
+    np.testing.assert_array_equal(np.asarray(l1), np.asarray(l3))
+    # A layer loop's call: the plan built once, shifted with the table.
+    o4, l4 = attention_pallas_mla_paged(
+        q, jnp.concatenate([jnp.zeros_like(pool), pool]), N + table,
+        interpret=True, step_plan=mla_plan(Tq, pool, table, q_offset)
+        .shifted(N), **kw)
+    np.testing.assert_array_equal(np.asarray(o1), np.asarray(o4))
+    np.testing.assert_array_equal(np.asarray(l1), np.asarray(l4))
+
+
 # -- the whole model through the paged latent pool ---------------------------
 
 

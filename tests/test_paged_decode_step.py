@@ -1,5 +1,6 @@
 """The paged decode kernels' grid step (ISSUE 28): every KV head of several
-table entries a step.
+table entries a step; and their grid (ISSUE 37): a list of the steps that
+hold a live token, none past a slot's length.
 
 ``ops/tuning.paged_decode_step`` reads a call's shapes and says how many KV
 heads and table entries one grid step of ``flash_decode_paged`` /
@@ -9,21 +10,28 @@ hands out the most entries that divide the table width: widths 5 and 7 take
 one entry a step, 6 two, 12 four, 40 and 64 eight. Every case is held to
 ``ops/reference.py`` (a signed table to ``paged_local_partial``'s reference
 route, which masks the blocks another shard owns), over contexts that end at
-0, 1, a block's edge, a step's edge and ragged across slots. Whether the
-TPU's compiler takes the same kernels is ``tests/test_chip_compile.py``'s to
-say.
+0, 1, a block's edge, a step's edge and ragged across slots, and to the bits
+of the rectangular grid the kernels launched before the list
+(``tests/paged_rectangle.py``, the old call kept for this). The list itself
+(``paged_step_plan``) is held to the old body's ``live`` test as a function.
+Whether the TPU's compiler takes the same kernels is
+``tests/test_chip_compile.py``'s to say.
 """
 
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
-from tree_attention_tpu.ops import tuning
+from tests import paged_rectangle
+from tree_attention_tpu.ops import pallas_decode, tuning
 from tree_attention_tpu.ops.decode import gather_paged_kv, paged_local_partial
 from tree_attention_tpu.ops.pallas_decode import (
     attention_pallas_decode,
     attention_pallas_decode_q8,
     attention_pallas_decode_q8q,
+    paged_plan,
+    paged_step_plan,
 )
 from tree_attention_tpu.ops.reference import attention_naive
 
@@ -82,6 +90,18 @@ CASES = {
     "heads4_cut_to_1_width7_tq17": _case(nb=7, tq=17, heads=1),
     "heads8_cut_to_4_q8q_width64": _case("q8q", hkv=8, group=1, nb=64,
                                          heads=4),
+    # The list over more than one head group and Q tile at once, with what
+    # else indexes by the entry's slot or step: block scales, tree bits, a
+    # signed table.
+    "heads8_cut_to_2_q8_block_scales_tq17_width40": _case(
+        "q8", hkv=8, group=1, nb=40, tq=17, heads=2),
+    "heads4_cut_to_2_tree_tq8_width40": _case(nb=40, tq=8, tree=True,
+                                              heads=2),
+    "heads4_cut_to_2_local_remote_width40": _case(nb=40, local="remote",
+                                                  heads=2),
+    "heads4_cut_to_1_two_q_tiles_tq64_width12": _case(
+        nb=12, tq=64, group=4, blk=8, heads=1),
+    "bf16_chunk_tail_tq17_width64": _case("bf16", nb=64, tq=17),
 }
 
 
@@ -111,6 +131,21 @@ def _naive(q, k, v, offsets, tree_mask):
         outs.append(o)
         lses.append(l)
     return np.concatenate(outs).astype(np.float32), np.concatenate(lses)
+
+
+def _rectangle(monkeypatch, fn, *args, **kw):
+    """``fn`` (a paged kernel's wrapper) on the rectangular grid it launched
+    before the list: every step of every slot's table."""
+    with monkeypatch.context() as m:
+        m.setattr(pallas_decode, "_paged_decode_call",
+                  paged_rectangle._paged_decode_call)
+        static = [n for n in ("causal", "local_blocks") if n in kw]
+        return jax.jit(fn.__wrapped__, static_argnames=static)(*args, **kw)
+
+
+def _same_bits(got, want):
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 @pytest.mark.parametrize("name", sorted(CASES))
@@ -159,9 +194,12 @@ def test_paged_step_matches_reference(name, monkeypatch):
             k_ref, v_ref = gather_paged_kv(
                 jnp.asarray(k_q * ks[:, :, None, None]),
                 jnp.asarray(v_q * vs[:, :, None, None]), jnp.asarray(table))
-        out, lse = fn(q, jnp.asarray(k_q), jnp.asarray(v_q), jnp.asarray(ks),
-                      jnp.asarray(vs), causal=True, q_offset=offsets,
-                      block_table=jnp.asarray(table), tree_mask=tree_mask)
+        args = (q, jnp.asarray(k_q), jnp.asarray(v_q), jnp.asarray(ks),
+                jnp.asarray(vs))
+        kw = dict(causal=True, q_offset=offsets,
+                  block_table=jnp.asarray(table), tree_mask=tree_mask)
+        out, lse = fn(*args, **kw)
+        _same_bits((out, lse), _rectangle(monkeypatch, fn, *args, **kw))
         ref_o, ref_l = _naive(q, k_ref, v_ref, offsets, tree_mask)
         # int8 resolution (q8q rounds the queries to int8 too).
         tol = dict(atol=6e-2, rtol=6e-2)
@@ -187,9 +225,11 @@ def test_paged_step_matches_reference(name, monkeypatch):
             signed[-1, :] = -1
         ref_o, ref_l = paged_local_partial(
             q, pool_k, pool_v, jnp.asarray(signed), q_position=offsets)
-        out, lse = attention_pallas_decode(
-            q, pool_k, pool_v, causal=True, q_offset=offsets,
-            block_table=jnp.asarray(signed), local_blocks=True)
+        kw = dict(causal=True, q_offset=offsets,
+                  block_table=jnp.asarray(signed), local_blocks=True)
+        out, lse = attention_pallas_decode(q, pool_k, pool_v, **kw)
+        _same_bits((out, lse), _rectangle(
+            monkeypatch, attention_pallas_decode, q, pool_k, pool_v, **kw))
         ref_l, lse = np.asarray(ref_l), np.asarray(lse)
         empty = np.isneginf(ref_l)
         if c["local"] == "remote":
@@ -201,9 +241,11 @@ def test_paged_step_matches_reference(name, monkeypatch):
         np.testing.assert_allclose(lse[~empty], ref_l[~empty], **tol)
         return
 
-    out, lse = attention_pallas_decode(
-        q, pool_k, pool_v, causal=True, q_offset=offsets,
-        block_table=jnp.asarray(table), tree_mask=tree_mask)
+    kw = dict(causal=True, q_offset=offsets, block_table=jnp.asarray(table),
+              tree_mask=tree_mask)
+    out, lse = attention_pallas_decode(q, pool_k, pool_v, **kw)
+    _same_bits((out, lse), _rectangle(
+        monkeypatch, attention_pallas_decode, q, pool_k, pool_v, **kw))
     kg, vg = gather_paged_kv(pool_k, pool_v, jnp.asarray(table))
     ref_o, ref_l = _naive(q, kg, vg, offsets, tree_mask)
     np.testing.assert_allclose(np.asarray(out, np.float32), ref_o, **tol)
@@ -272,3 +314,128 @@ def test_builds_counter_says_what_a_step_takes():
             if l.startswith("pallas_decode_kernel_builds_total{")
             and 'kernel="paged"' in l and 'entries="4"' in l]
     assert line and 'heads="2"' in line[0], text
+
+
+# -- the work list (ISSUE 37) -------------------------------------------------
+
+
+def _old_live(q_off, kv_off, tq, bk, n_steps):
+    """Steps the rectangle's body computed for a slot: its ``live`` test."""
+    return [si for si in range(n_steps)
+            if kv_off + si * bk <= q_off + tq - 1]
+
+
+def _check_plan(q_off, kv_off, tq, bk, n_steps):
+    B = len(q_off)
+    live = tuning.paged_live_steps(
+        np.asarray(q_off), np.asarray(kv_off), tq, bk, n_steps)
+    slot, step, flags, count = (np.asarray(a) for a in paged_step_plan(
+        jnp.asarray(live), n_steps))
+    # Static capacity: the rectangle, which every slot full fills.
+    assert slot.shape == step.shape == flags.shape == (B * n_steps,)
+    want = []
+    for b in range(B):
+        steps = _old_live(int(q_off[b]), int(kv_off[b]), tq, bk, n_steps)
+        assert len(steps) == live[b]
+        assert steps == list(range(len(steps)))     # a prefix of the table
+        # A slot with nothing to attend to still gets an entry: its rows
+        # are initialised and written out, and nothing is computed.
+        for si in steps or [0]:
+            want.append((b, si,
+                         (si == 0) * pallas_decode._PLAN_FIRST
+                         | (si == max(len(steps), 1) - 1)
+                         * pallas_decode._PLAN_LAST
+                         | bool(steps) * pallas_decode._PLAN_LIVE))
+    assert count == len(want) and B <= count <= B * n_steps
+    got = list(zip(slot[:count], step[:count], flags[:count]))
+    assert got == want          # slots in order, a slot's steps in order
+    return int(count)
+
+
+@pytest.mark.parametrize("seed", range(8))
+def test_plan_holds_the_steps_the_live_test_kept(seed):
+    rng = np.random.default_rng(seed)
+    B = int(rng.integers(1, 17))
+    n_steps = int(rng.integers(1, 12))
+    bk = int(rng.choice([4, 32, 256]))
+    tq = int(rng.choice([1, 8, 17, 64]))
+    cap = n_steps * bk
+    q_off = rng.integers(0, max(cap - tq, 0) + 1, size=B)
+    # Mostly the serving shape (kv_offset 0); some slots whose pool starts
+    # past their rows, which see nothing.
+    kv_off = np.where(rng.random(B) < 0.2, rng.integers(0, cap, size=B), 0)
+    _check_plan(q_off, kv_off, tq, bk, n_steps)
+
+
+PLAN_EDGES = {
+    # (q_offset, tq, step tokens, steps of the table) -> entries of a slot
+    "length_0": ((0, 1, 256, 10), 1),
+    "last_row_of_a_step": ((255, 1, 256, 10), 1),
+    "first_row_of_the_next": ((256, 1, 256, 10), 2),
+    "chunk_tail_reaches_the_edge": ((240, 17, 256, 10), 2),
+    "chunk_tail_stops_short": ((239, 17, 256, 10), 1),
+    "full_capacity": ((2559, 1, 256, 10), 10),
+    "one_step_table": ((5, 1, 64, 1), 1),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PLAN_EDGES))
+def test_plan_at_the_edges(name):
+    (q_off, tq, bk, n_steps), want = PLAN_EDGES[name]
+    assert _check_plan([q_off], [0], tq, bk, n_steps) == want
+    # Beside a full slot and an empty one the count is the sum.
+    assert _check_plan([n_steps * bk - tq, q_off, 0], [0, 0, 0], tq, bk,
+                       n_steps) == n_steps + want + 1
+
+
+def test_full_slots_are_the_rectangle_and_the_table_lies_in_list_order():
+    rng = np.random.default_rng(0)
+    B, NB, entries, blk = 5, 12, 4, 8
+    table = jnp.asarray(rng.permutation(B * NB).reshape(B, NB), jnp.int32)
+    full = paged_plan(jnp.full((B,), NB * blk - 1, jnp.int32), 0, table,
+                      tq=1, entries=entries, block=blk)
+    assert int(full.count) == B * NB // entries
+    np.testing.assert_array_equal(full.table, np.asarray(table).reshape(-1))
+    np.testing.assert_array_equal(
+        full.slot, np.repeat(np.arange(B), NB // entries))
+    # Ragged: entry e holds its slot's blocks step * entries ...; a layer's
+    # shift moves the table and nothing else.
+    pos = jnp.asarray([0, 31, 32, 70, 95], jnp.int32)
+    plan = paged_plan(pos, 0, table, tq=1, entries=entries, block=blk)
+    assert int(plan.count) == 1 + 1 + 2 + 3 + 3
+    for e in range(int(plan.count)):
+        b, si = int(plan.slot[e]), int(plan.step[e])
+        np.testing.assert_array_equal(
+            plan.table[e * entries:(e + 1) * entries],
+            np.asarray(table)[b, si * entries:(si + 1) * entries])
+    moved = plan.shifted(1000)
+    np.testing.assert_array_equal(moved.table, np.asarray(plan.table) + 1000)
+    assert all(a is b for a, b in zip(moved[2:], plan[2:]))
+    # Not causal: nothing is past a slot's length.
+    assert int(paged_plan(pos, 0, table, tq=1, entries=entries, block=blk,
+                          causal=False).count) == B * NB // entries
+
+
+def test_a_plan_handed_in_is_the_plan_built_in_the_call():
+    """What a step program does: one plan a group of rows, shifted with the
+    table to each layer's blocks. A plan of another call's shapes is
+    refused."""
+    rng = np.random.default_rng(1)
+    B, hkv, nb, blk, layers = 6, 2, 12, 4, 3
+    n = B * nb
+    pool = jnp.asarray(rng.normal(size=(layers * n, hkv, blk, D)),
+                       jnp.float32)
+    q = jnp.asarray(rng.normal(size=(B, 2 * hkv, 1, D)), jnp.float32)
+    table = jnp.asarray(rng.permutation(n).reshape(B, nb), jnp.int32)
+    pos = jnp.asarray(rng.integers(0, nb * blk, size=B), jnp.int32)
+    plan = pallas_decode.decode_plan(2 * hkv, 1, pool, table, pos)
+    for l in range(layers):
+        kw = dict(causal=True, q_offset=pos, block_table=l * n + table)
+        _same_bits(
+            attention_pallas_decode(q, pool, pool, **kw,
+                                    step_plan=plan.shifted(l * n)),
+            attention_pallas_decode(q, pool, pool, **kw))
+    with pytest.raises(ValueError, match="a plan for 6 slots of 12 blocks"):
+        attention_pallas_decode(
+            q[:3], pool, pool, causal=True, q_offset=pos[:3],
+            block_table=table[:3], step_plan=plan)

@@ -110,3 +110,69 @@ def test_the_default_budget_is_one_chunk(params):
                       kv_block=KV_BLOCK, prefill_chunk=CHUNK,
                       prefill_budget=10 * CHUNK)
     assert wide._chunk_group == 2
+
+
+def test_kv_steps_are_what_the_kernels_lists_hold(params, monkeypatch):
+    """The flight record's ``kv_steps_run`` / ``kv_steps_grid`` (ISSUE 37):
+    the host counts, from the lengths it packs, what the paged decode
+    kernels' work lists hold on the device. Each tick program's own
+    operands say the truth: its slots' lengths after the resets, through
+    the function the kernels build their lists with."""
+    import jax.numpy as jnp
+
+    from tree_attention_tpu.models.decode import paged_step_tokens
+    from tree_attention_tpu.ops import tuning
+    from tree_attention_tpu.ops.pallas_decode import paged_plan
+
+    # One table entry a grid step: lengths of a few blocks tell steps apart.
+    monkeypatch.setattr(tuning, "PAGED_STEP_ENTRIES", (1,))
+    server = SlotServer(params, CFG, slots=SLOTS, cache_len=128,
+                        kv_block=KV_BLOCK, prefill_chunk=CHUNK)
+    truth = []
+
+    def count(tq, lengths, table):
+        step = paged_step_tokens(server.cache, CFG, tq)
+        plan = paged_plan(lengths, 0, table, tq=tq,
+                          entries=step // KV_BLOCK, block=KV_BLOCK)
+        return np.array([int(plan.count), table.size * KV_BLOCK // step])
+
+    mixed, packed = server._mixed, server._packed
+
+    def spy_mixed(p, tokens, n_tok, reset, reset_val, emit, cache, *rest):
+        length = jnp.where(reset, reset_val, cache.length)
+        truth.append(count(tokens.shape[1], length, cache.table))
+        return mixed(p, tokens, n_tok, reset, reset_val, emit, cache, *rest)
+
+    def spy_packed(p, chunk_tok, chunk_slot, chunk_n, dec_tok, dec_n, reset,
+                   reset_val, emit, cache, *rest):
+        length = jnp.where(reset, reset_val, cache.length)
+        truth.append(
+            count(chunk_tok.shape[1], length[chunk_slot],
+                  cache.table[chunk_slot])
+            + count(1, length, cache.table))
+        return packed(p, chunk_tok, chunk_slot, chunk_n, dec_tok, dec_n,
+                      reset, reset_val, emit, cache, *rest)
+
+    server._mixed, server._packed = spy_mixed, spy_packed
+    rng = np.random.default_rng(3)
+    reqs = [Request(uid=i, max_new_tokens=int(rng.integers(2, 12)),
+                    prompt=rng.integers(0, CFG.vocab_size, int(n))
+                    .astype(np.int32))
+            for i, n in enumerate(rng.integers(3, 100, size=20))]
+    FLIGHT.clear()
+    FLIGHT.arm()
+    try:
+        server.serve(reqs)
+    finally:
+        FLIGHT.disarm()
+    recs = [r for r in FLIGHT.snapshot()["records"]
+            if r["kind"] in ("decode", "mixed")]
+    FLIGHT.clear()
+    got = [(r["kv_steps_run"], r["kv_steps_grid"]) for r in recs]
+    assert got == [tuple(t) for t in truth] and len(got) > 40
+    # Ragged slots fill a part of the rectangle, never none of it: a slot
+    # with nothing to attend to still holds one entry.
+    assert all(SLOTS <= run <= grid for run, grid in got)
+    assert sum(run for run, _ in got) < 0.5 * sum(g for _, g in got)
+    decode = [r for r in recs if r["kind"] == "decode"]
+    assert all(r["kv_steps_grid"] == SLOTS * 128 // KV_BLOCK for r in decode)

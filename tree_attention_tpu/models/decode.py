@@ -989,6 +989,7 @@ class _RowGroup(NamedTuple):
     table: Optional[jax.Array]
     tree_mask: Optional[jax.Array]
     chunk: bool = False   # a packed step's chunk group (``scopes.ATTN_CHUNK``)
+    plan: Any = None      # the paged kernels' work list (:func:`_plan_groups`)
 
     @property
     def n_valid(self) -> jax.Array:
@@ -1010,6 +1011,59 @@ class _RowGroup(NamedTuple):
         _, H, _, D = a.shape
         rows = a[0, :, self.lo:self.lo + self.batch * self.tq]
         return rows.reshape(H, self.batch, self.tq, D).transpose(1, 0, 2, 3)
+
+
+def _plan_groups(groups: Tuple[_RowGroup, ...], cache: Any,
+                 cfg: TransformerConfig) -> Tuple[_RowGroup, ...]:
+    """Each group with the paged decode kernels' work list for its rows
+    (``ops/pallas_decode.py`` ``PagedPlan``: which steps of which member's
+    table hold a position its rows may see, and the table in that order).
+    The list follows from the group's lengths and the shapes, not from the
+    layer, so a step builds it here, once, and every layer's call is handed
+    it shifted to the layer's blocks, as the table is; built inside the
+    call it would be built again in every pass of the layer loop. A group the
+    kernels will not serve (a chunk of 128 rows or more on the Q-tiled
+    kernel) leaves its list unused, and the compiler drops it."""
+    from tree_attention_tpu.ops.pallas_decode import decode_plan, mla_plan
+
+    planned = []
+    for g in groups:
+        with jax.named_scope(
+                scopes.ATTN_CHUNK if g.chunk else scopes.ATTN_DECODE):
+            if isinstance(cache, PagedLatentCache):
+                plan = mla_plan(g.tq, cache.kv, g.table, g.start)
+            else:
+                plan = decode_plan(
+                    cfg.n_heads, g.tq, cache.k, g.table, g.start)
+            # Behind a barrier: the compiler otherwise clones the cheapest
+            # of a short list's operations back into the loop's body.
+            plan = type(plan)(*lax.optimization_barrier(tuple(plan)))
+        planned.append(g._replace(plan=plan))
+    return tuple(planned)
+
+
+def paged_step_tokens(cache: Any, cfg: TransformerConfig,
+                      tq: int) -> Optional[int]:
+    """Tokens one grid step of the paged decode kernel takes that serves a
+    group of ``tq`` rows a slot against ``cache``; None where no paged
+    kernel serves such a group (a contiguous cache; an exact chunk of 128
+    rows or more, the Q-tiled kernel's over a gathered view). What the
+    serve loop counts a tick's work list with (``kv_steps_run``)."""
+    from tree_attention_tpu.ops.pallas_decode import (
+        decode_step_entries, mla_step_entries,
+    )
+    from tree_attention_tpu.ops.tuning import tpu_kernel_for
+
+    if isinstance(cache, PagedLatentCache):
+        return mla_step_entries(cache.table.shape[1]) * cache.block
+    if not isinstance(
+            cache, (PagedKVCache, PagedQuantKVCache, PagedHybridCache)):
+        return None
+    if not isinstance(cache, PagedQuantKVCache) \
+            and tpu_kernel_for(tq) != "pallas_decode":
+        return None
+    return cache.block * decode_step_entries(
+        cfg.n_heads, tq, cache.k, cache.table.shape[1])
 
 
 def _join_rows(groups: Tuple[_RowGroup, ...], outs) -> jax.Array:
@@ -1199,6 +1253,8 @@ class _Attend:
                 ak = k_cache.reshape((-1,) + k_cache.shape[2:])
                 av = v_cache.reshape((-1,) + v_cache.shape[2:])
                 attn_kw["block_table"] = base + g.table
+                if g.plan is not None:
+                    attn_kw["step_plan"] = g.plan.shifted(base)
                 if quant:
                     ak_s, av_s = k_sf, v_sf
             elif paged:
@@ -1360,6 +1416,7 @@ def _latent_layers(
                 out_lat, _ = latent_attention(
                     g.take(q_abs), pool.reshape((-1,) + pool.shape[2:]),
                     l * N + g.table, q_offset=g.start, cfg=cfg,
+                    step_plan=g.plan and g.plan.shifted(l * N),
                 )
             outs.append(out_lat)
         with jax.named_scope(scopes.ATTN_DECODE):
@@ -1456,6 +1513,17 @@ def _step_layers(
     decode group) share. ``x`` and ``positions`` lie as :class:`_RowGroup`
     says. Returns the final residual and the cache's arrays the step
     rewrote, by field name."""
+    from tree_attention_tpu.ops import _on_tpu, _pallas_available
+
+    paged = isinstance(cache, (PagedKVCache, PagedQuantKVCache))
+    on_kernels = _on_tpu(params["embed"]) and _pallas_available()
+    seq_shards = (
+        max(mesh.shape.get(axes["seq"] or "", 1), 1)
+        if mesh is not None else 1
+    )
+    seq_sharded = paged and kv_shard == "seq" and seq_shards > 1
+    if on_kernels and groups[0].table is not None and not seq_sharded:
+        groups = _plan_groups(groups, cache, cfg)
     if isinstance(cache, PagedLatentCache):
         x, pool = _latent_layers(
             params, x, cache, cfg, positions, groups, stats)
@@ -1472,7 +1540,6 @@ def _step_layers(
         )
         return hybrid_layers(
             params, x, positions, cache, cfg, attend, stats)
-    paged = isinstance(cache, (PagedKVCache, PagedQuantKVCache))
     quant = isinstance(cache, (QuantKVCache, PagedQuantKVCache))
 
     # Satellite fix (ISSUE 8): off the TPU Pallas kernels — the eager/CPU
@@ -1487,19 +1554,10 @@ def _step_layers(
     # the paged kernels stream blocks in place and this path never runs.
     hoist_view = False
     paged_quant = paged and quant
-    seq_sharded = False
     if paged:
-        from tree_attention_tpu.ops import _on_tpu, _pallas_available
-
-        on_kernels = _on_tpu(params["embed"]) and _pallas_available()
         # Under a >1-way seq mesh the contiguous view would re-route
         # decode_attention onto the tree-merge branch (the view is
         # replicated, not seq-sharded) — keep the block-table path there.
-        seq_shards = (
-            max(mesh.shape.get(axes["seq"] or "", 1), 1)
-            if mesh is not None else 1
-        )
-        seq_sharded = kv_shard == "seq" and seq_shards > 1
         if seq_sharded and any(g.tree_mask is not None for g in groups):
             raise ValueError(
                 "tree_mask is not supported under kv_shard='seq' "
@@ -2287,6 +2345,7 @@ def decode_attention(
     tree_mask: Optional[jax.Array] = None,
     kv_shard: str = "replicated",
     scale: Optional[float] = None,
+    step_plan: Any = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Op-level decode entry: split-KV on one device, tree merge on a mesh.
 
@@ -2306,7 +2365,9 @@ def decode_attention(
     With ``block_table`` the call is **paged**: ``k``/``v`` are
     ``(N, Hkv, block, D)`` pools and each batch row reads KV through its
     ``(B, NB)`` table row (see :class:`PagedKVCache`); the pool is
-    replicated under a mesh, so the tree merge never applies.
+    replicated under a mesh, so the tree merge never applies. ``step_plan``
+    is the paged kernels' work list where the caller built it for several
+    calls (a step's layers: :func:`_plan_groups`).
     """
     quant = k_scale is not None
     if quant and v_scale is None or (not quant and v_scale is not None):
@@ -2357,11 +2418,12 @@ def decode_attention(
                 q, k, v, k_scale, v_scale, causal=True,
                 q_offset=q_position, block_size=block_size,
                 block_table=block_table, tree_mask=tree_mask,
+                step_plan=step_plan,
             )
         return flash_decode(
             q, k, v, q_position=q_position, num_splits=num_splits,
             block_size=block_size, block_table=block_table,
-            tree_mask=tree_mask, scale=scale,
+            tree_mask=tree_mask, scale=scale, step_plan=step_plan,
         )
     if q_position is None:
         q_position = k.shape[2] - q.shape[2]

@@ -187,11 +187,12 @@ def latent_attention_reference(
 
 def latent_attention(
     q: jax.Array, pool: jax.Array, block_table: jax.Array, *,
-    q_offset: jax.Array, cfg: TransformerConfig,
+    q_offset: jax.Array, cfg: TransformerConfig, step_plan=None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Absorbed attention of ``q`` ``(B, H, Tq, row)`` against the paged
-    latent pool ``(N, block, row)``: the block-table kernel on TPU, the
-    gathered reference elsewhere. Returns ``(out_lat, lse)``."""
+    latent pool ``(N, block, row)``: the block-table kernel on TPU (handed
+    ``step_plan``, its work list, where the caller built it for every
+    layer), the gathered reference elsewhere. Returns ``(out_lat, lse)``."""
     from tree_attention_tpu.ops import _on_tpu, _pallas_available
     from tree_attention_tpu.ops.decode import _account_dispatch
 
@@ -204,7 +205,8 @@ def latent_attention(
         )
 
         _account_dispatch("mla_paged_decode", kv_tokens)
-        return attention_pallas_mla_paged(q, pool, block_table, **kw)
+        return attention_pallas_mla_paged(
+            q, pool, block_table, step_plan=step_plan, **kw)
     _account_dispatch("mla_paged_reference", kv_tokens)
     return latent_attention_reference(q, pool, block_table, **kw)
 

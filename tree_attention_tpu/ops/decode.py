@@ -112,6 +112,7 @@ def flash_decode(
     block_size: Optional[int] = None,
     block_table: Optional[jax.Array] = None,
     tree_mask: Optional[jax.Array] = None,
+    step_plan=None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Causal decode attention of a few new queries against a long KV buffer.
 
@@ -150,7 +151,10 @@ def flash_decode(
     kernel (no gather); everywhere else — the chunked-vmap CPU path and
     prefill-sized Tq on the Q-tiled kernel — the logical view is
     gathered once via :func:`gather_paged_kv` and the contiguous path
-    runs unchanged, which keeps eager and Pallas bit-exact.
+    runs unchanged, which keeps eager and Pallas bit-exact. ``step_plan``
+    (``ops.pallas_decode.decode_plan``) is the paged kernel's work list
+    where the caller has built it for several calls; the other paths have
+    no use for it.
 
     ``tree_mask`` (a ``(B, Tq, Tq)`` bool array; requires a ``(B,)``
     ``q_position`` and ``Tq <= 32``) switches on the speculative
@@ -228,6 +232,7 @@ def flash_decode(
                     q, k, v, causal=True, scale=scale,
                     q_offset=q_position, kv_offset=0,
                     block_table=block_table, tree_mask=tree_mask,
+                    step_plan=step_plan,
                 )
             # Prefill-sized Tq rides the Q-tiled kernel, which has no
             # table path — one gather materialises the logical view

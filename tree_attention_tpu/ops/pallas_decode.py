@@ -27,7 +27,8 @@ Layout (chosen by measurement on v5e; see the design notes below):
   ``(out, lse)`` plugs into the cross-device tree merge unchanged.
 - **The paged kernels take fat steps.** A pool block is a few tens of KB a
   head, so a step of one head of one block is all fixed cost: the paged
-  grid runs over slots, and a step takes every KV head of several table
+  grid walks a list of the (slot, step) pairs that hold a live token
+  (``paged_step_plan``), and a step takes every KV head of several table
   entries (``_paged_decode_step``; ``ops/tuning.py`` ``paged_decode_step``).
 - Causal masking uses global offsets from SMEM (they are traced values
   inside jitted decode steps); tiles whose every KV position is masked skip
@@ -37,7 +38,7 @@ Layout (chosen by measurement on v5e; see the design notes below):
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -62,6 +63,17 @@ from tree_attention_tpu.ops.block_utils import (
 # ``heads`` and ``entries`` say what one grid step takes of the KV stream: KV
 # heads, and table entries of a paged pool (0: no table, the step is a
 # ``block_k`` tile of a contiguous buffer).
+# Execution-true, unlike the builds: the serve loop adds, tick by tick, what
+# the paged kernels' work lists held (``run``: ``paged_step_plan``'s count,
+# worked out on the host from the lengths it packed by the same rule,
+# ``tuning.paged_live_steps``) and what the whole slots x steps rectangle
+# would have (``grid``), over a tick's groups of rows.
+PAGED_STEPS = obs.counter(
+    "pallas_decode_paged_steps_total",
+    "grid steps of the paged decode kernels a layer, summed over ticks: "
+    "run (live steps on the work list) and grid (slots x table steps)",
+    labels=("steps",),
+)
 _KERNEL_BUILDS = obs.counter(
     "pallas_decode_kernel_builds_total",
     "flash-decode kernel program builds (one per distinct shape/config), "
@@ -353,11 +365,124 @@ def _flash_decode_q8q_kernel(
         _decode_finalize(out_ref, lse_ref, m_scr, l_scr, acc_scr)
 
 
+# What a plan's flag word says of its entry: the slot's first entry (the
+# online-softmax state starts here), its last (the rows are written out), and
+# whether the step holds a token at all (a slot whose rows see nothing still
+# gets one entry, which only starts and writes out).
+_PLAN_FIRST, _PLAN_LAST, _PLAN_LIVE = 1, 2, 4
+_PLAN_PREFETCH = 5  # offsets, table, slot, step, flags (``PagedPlan``)
+PLAN_SCOPE = "paged_plan"
+
+
+def paged_step_plan(live: jax.Array, n_steps: int
+                    ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """The paged decode kernels' work list: ``(slot of entry e, step of
+    entry e, flags of entry e, number of entries)`` from ``live`` ``(B,)``,
+    the steps of each slot's table that hold a token its rows may see
+    (``tuning.paged_live_steps``, 0 to ``n_steps``). Slot ``b`` gets entries
+    for steps ``0 .. live[b] - 1``, slots in order and a slot's steps in
+    order; a slot with none gets step 0 all the same, without
+    ``_PLAN_LIVE``, so that its rows are still written. The lists have the
+    static capacity ``B * n_steps`` (every slot full: the rectangle); what
+    lies past the count is never visited. The count is the grid's last,
+    dynamic, dimension, as ``pallas_moe.tile_plan``'s is."""
+    B = live.shape[0]
+    live = live.astype(jnp.int32)
+    held = jnp.maximum(live, 1)
+    ends = jnp.cumsum(held)
+    e = jnp.arange(B * n_steps, dtype=jnp.int32)
+    # A compare against every slot's end: no loop (a binary search lowers
+    # to one, which the compiler would leave inside a layer loop's body).
+    slot = jnp.minimum(
+        jnp.sum(ends[None, :] <= e[:, None], axis=1, dtype=jnp.int32), B - 1)
+    step = jnp.clip(e - (ends - held)[slot], 0, n_steps - 1)
+    flags = (
+        jnp.where(step == 0, _PLAN_FIRST, 0)
+        | jnp.where(step == held[slot] - 1, _PLAN_LAST, 0)
+        | jnp.where(live[slot] > 0, _PLAN_LIVE, 0)
+    ).astype(jnp.int32)
+    return slot, step, flags, ends[-1]
+
+
+class PagedPlan(NamedTuple):
+    """A paged decode call's scalar-prefetch operands and its dynamic grid
+    bound: what the call works out from its offsets and its table before it
+    reads a block. A layer loop shifts the table by a constant (``l * N +
+    table``) and nothing else, so a step program builds one plan a group of
+    rows (:func:`decode_plan`, :func:`mla_plan`) and hands every layer's
+    call that plan :meth:`shifted` as the layer's table is; a call handed
+    none builds its own."""
+
+    offsets: jax.Array  # (2, B): per-slot [q_offset | kv_offset]
+    table: jax.Array    # (E * entries,): the block table in list order,
+                        # block j of entry e at [e * entries + j], so that a
+                        # K/V index map reads its block with one scalar
+                        # load, no slot or step to look up first
+    slot: jax.Array     # (E,) the work list (``paged_step_plan``)
+    step: jax.Array     # (E,)
+    flags: jax.Array    # (E,)
+    count: jax.Array    # (): the entries in the list
+
+    def shifted(self, base) -> "PagedPlan":
+        """The plan of the same call on ``base + block_table``."""
+        return self._replace(table=self.table + base)
+
+
+def paged_plan(q_offset, kv_offset, block_table: jax.Array, *, tq: int,
+               entries: int, block: int, causal: bool = True) -> PagedPlan:
+    """The plan of a paged call of ``tq`` rows a slot against
+    ``block_table`` ``(B, NB)`` of blocks of ``block`` tokens, ``entries``
+    of them a grid step."""
+    from tree_attention_tpu.ops.tuning import paged_live_steps
+
+    B, NB = block_table.shape
+    n_steps = NB // entries
+    # Named, so that a compiled program says where its plans are built
+    # (``tests/test_chip_compile.py``: not in a layer loop's body).
+    with jax.named_scope(PLAN_SCOPE):
+        offs = _offsets_smem(q_offset, kv_offset, B)
+        if causal:
+            live = paged_live_steps(
+                offs[0], offs[1], tq, entries * block, n_steps)
+        else:
+            live = jnp.full((B,), n_steps, jnp.int32)
+        slot, step, flags, count = paged_step_plan(live, n_steps)
+        at = (slot * NB + step * entries)[:, None] \
+            + jnp.arange(entries, dtype=jnp.int32)[None, :]
+        table = jnp.asarray(block_table, jnp.int32).reshape(-1)[
+            at.reshape(-1)]
+    return PagedPlan(offs, table, slot, step, flags, count)
+
+
+def _plan_operands(plan: Optional[PagedPlan], q_offset, kv_offset,
+                   block_table: jax.Array, *, tq: int, entries: int,
+                   block: int, causal: bool):
+    """``(the five scalar-prefetch operands, the dynamic grid bound)`` of a
+    paged call, from the caller's plan or one built here."""
+    B, NB = block_table.shape
+    if plan is None:
+        plan = paged_plan(q_offset, kv_offset, block_table, tq=tq,
+                          entries=entries, block=block, causal=causal)
+    elif plan.offsets.shape != (2, B) or plan.table.shape != (B * NB,) \
+            or plan.slot.shape != (B * NB // entries,):
+        raise ValueError(
+            f"a plan for {plan.offsets.shape[1]} slots of "
+            f"{plan.table.shape[0] // plan.offsets.shape[1]} blocks, "
+            f"{plan.table.shape[0] // plan.slot.shape[0]} a step, handed to "
+            f"a call on {B} slots of {NB} blocks, {entries} a step"
+        )
+    return plan[:_PLAN_PREFETCH], plan.count
+
+
 def _paged_decode_step(
     offs_ref,  # SMEM (2, B) scalar-prefetch: per-batch [q_offset|kv_offset]
-    tbl_ref,   # SMEM (B, NB) scalar-prefetch block table — read by the
-               # K/V index maps (PagedAttention, arXiv:2309.06180); the
-               # body reads it only for a signed table's ownership
+    tbl_ref,   # SMEM (E * entries,) scalar-prefetch: the block table in list
+               # order — read by the K/V index maps (PagedAttention,
+               # arXiv:2309.06180); the body reads it only for a signed
+               # table's ownership
+    slot_ref,  # SMEM (E,) x 3 scalar-prefetch: the work list
+    step_ref,  # (``paged_step_plan``): entry e is step ``step[e]`` of slot
+    flag_ref,  # ``slot[e]``'s table
     refs,      # lead (q_ref, or q_ref and qs_ref), [tb_ref when tree],
                # k_ref x entries, v_ref x entries, [ks_ref, vs_ref when
                # block_scales], out_ref, lse_ref, m_scr, l_scr, acc_scr:
@@ -366,8 +491,8 @@ def _paged_decode_step(
                #   qs_ref  VMEM (1, heads, bq, LANES) f32 — per-row Q scales
                #   tb_ref  VMEM (1, heads, bq, LANES) int32 — tree bitmasks
                #   k/v_ref VMEM (1, heads, block, D) — every head of pool
-               #           block tbl[b, si * entries + j], one operand an
-               #           entry (the same pool through its own index map)
+               #           block tbl[e * entries + j], one operand an entry
+               #           (the same pool through its own index map)
                #   ks/vs_ref VMEM (1, heads, 8, LANES) f32 — the 8-row scale
                #           tile that holds this step's entries
                #   out_ref VMEM (1, heads, bq, D)
@@ -381,10 +506,10 @@ def _paged_decode_step(
     n_lead: int,
     causal: bool,
     tq: int,
+    tk: int,
     block_q: int,
     block: int,
     entries: int,
-    head_groups: int,
     tree: bool,
     block_scales: bool,
     local_blocks: bool,
@@ -392,25 +517,26 @@ def _paged_decode_step(
     """One grid step of the paged decode kernels: every KV head of
     ``entries`` consecutive table entries of one slot.
 
-    The split-KV grid dimension walks a slot's LOGICAL blocks ``entries``
-    at a time and the index maps dereference the scalar-prefetched table,
-    so fragmented / non-monotone physical layouts stream like a contiguous
-    buffer. The step's blocks are put end to end in logical order into one
-    ``(heads, entries * block, D)`` K and V tile, so the mask and the
-    online-softmax fold see a tile of ``entries * block`` columns exactly
-    as the contiguous kernel sees one of its own, and every head's scores,
-    softmax state and accumulator are worked as one batched array: a loop
-    over the heads, each with its own slice of the scratch, ran the heads
-    one after the other and took 1.5-1.8 times as long on the chip
-    (``ops/tuning.py``). A pool block is
+    The split-KV grid dimension walks the work list (``paged_step_plan``):
+    a slot's LOGICAL blocks ``entries`` at a time, as far as the slot's
+    length reaches and no further, then the next slot's. The index maps
+    read the scalar-prefetched table, so fragmented / non-monotone physical
+    layouts stream like a contiguous buffer. The step's blocks are put end
+    to end in logical order into one ``(heads, entries * block, D)`` K and
+    V tile, so the mask and the online-softmax fold see a tile of
+    ``entries * block`` columns exactly as the contiguous kernel sees one
+    of its own, and every head's scores, softmax state and accumulator are
+    worked as one batched array: a loop over the heads, each with its own
+    slice of the scratch, ran the heads one after the other and took
+    1.5-1.8 times as long on the chip (``ops/tuning.py``). A pool block is
     a few tens of KB a head: one head of one entry a step left the step's
     fixed cost (a quarter of a microsecond) in charge of the kernel's time
     (``ops/tuning.py`` ``paged_decode_step`` has the numbers and the rule
-    for ``heads`` and ``entries``). The logical capacity ``NB * block`` is
-    step-divisible by construction, so the ragged-tail mask is statically
-    off; the causal mask against each slot's own ``q_offset`` hides every
-    unwritten (or garbage-mapped) position, and the per-slot liveness cull
-    skips whole steps past the slot's length.
+    for ``heads`` and ``entries``). The logical capacity ``tk = NB *
+    block`` is step-divisible by construction, so the ragged-tail mask is
+    statically off; the causal mask against each slot's own ``q_offset``
+    hides every unwritten (or garbage-mapped) position of a slot's last
+    step.
 
     ``block_scales`` (ISSUE 13, the shareable-int8 pool): two extra
     lane-broadcast operands carry each logical block's K and V
@@ -437,26 +563,21 @@ def _paged_decode_step(
         else (None, None)
     out_ref, lse_ref, m_scr, l_scr, acc_scr = refs
     qi = pl.program_id(1)
-    si = pl.program_id(2)
-    n_s = pl.num_programs(2)
+    e = pl.program_id(2)
+    b, si, flags = slot_ref[e], step_ref[e], flag_ref[e]
     bq, bk = block_q, entries * block
-    tk = n_s * bk  # logical capacity; step-divisible by construction
-
-    b = pl.program_id(0) // head_groups
     q_offset = offs_ref[0, b]
     kv_offset = offs_ref[1, b]
 
-    @pl.when(si == 0)
+    @pl.when(flags & _PLAN_FIRST != 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    live = si * bk < tk
-    if causal:
-        live &= (kv_offset + si * bk) <= (q_offset + tq - 1)
+    live = flags & _PLAN_LIVE != 0
     if local_blocks:
-        owner = [tbl_ref[b, si * entries + j] for j in range(entries)]
+        owner = [tbl_ref[e * entries + j] for j in range(entries)]
         live &= functools.reduce(jnp.maximum, owner) >= 0
 
     def by_entry(values):
@@ -511,13 +632,12 @@ def _paged_decode_step(
             v_scale=None if vs_ref is None else block_scale(vs_ref),
         )
 
-    @pl.when(si == n_s - 1)
+    @pl.when(flags & _PLAN_LAST != 0)
     def _finalize():
         _decode_finalize(out_ref, lse_ref, m_scr, l_scr, acc_scr)
 
 
-def _flash_decode_paged_kernel(offs_ref, tbl_ref, *refs, scale: float,
-                               **step):
+def _flash_decode_paged_kernel(*refs, scale: float, **step):
     """Block-table variant of :func:`_flash_decode_kernel`; the step is
     :func:`_paged_decode_step`'s. bf16 (or any float) pool, or an int8
     pool cast tile by tile to bf16 (exact for [-127, 127])."""
@@ -533,10 +653,11 @@ def _flash_decode_paged_kernel(offs_ref, tbl_ref, *refs, scale: float,
             precision=matmul_precision(q_ref.dtype, k_tile.dtype),
         ) * scale
 
-    _paged_decode_step(offs_ref, tbl_ref, refs, scores, n_lead=1, **step)
+    _paged_decode_step(*refs[:_PLAN_PREFETCH], refs[_PLAN_PREFETCH:], scores,
+                       n_lead=1, **step)
 
 
-def _flash_decode_paged_q8q_kernel(offs_ref, tbl_ref, *refs, **step):
+def _flash_decode_paged_q8q_kernel(*refs, **step):
     """Block-table variant of :func:`_flash_decode_q8q_kernel` — same
     int8-MXU score path (``q_ref`` int8, per-row-quantized and scale-folded;
     ``qs_ref`` its per-row scales), KV streamed through the
@@ -554,54 +675,46 @@ def _flash_decode_paged_q8q_kernel(offs_ref, tbl_ref, *refs, **step):
         )
         return s_i.astype(jnp.float32) * qs_ref[0][..., :1]
 
-    _paged_decode_step(offs_ref, tbl_ref, refs, scores, n_lead=2, **step)
+    _paged_decode_step(*refs[:_PLAN_PREFETCH], refs[_PLAN_PREFETCH:], scores,
+                       n_lead=2, **step)
 
 
-def _paged_q_map(bh, qi, si, offs_ref, tbl_ref):
-    """Q/out/lse index map of the latent paged decode grid (table unused)."""
-    del si, offs_ref, tbl_ref
-    return (bh, qi, 0)
+def _paged_q_map(qi, e, offs_ref, tbl_ref, slot_ref, step_ref, flag_ref):
+    """Q/out/lse index map of the latent paged decode grid: the entry's
+    slot."""
+    del offs_ref, tbl_ref, step_ref, flag_ref
+    return (slot_ref[e], qi, 0)
 
 
-def _paged_rows_map(head_groups: int):
+def _paged_rows_map(hg, qi, e, offs_ref, tbl_ref, slot_ref, step_ref,
+                    flag_ref):
     """Index map of the paged decode grid's per-row operands (q, q scales,
-    tree bits, out, lse), all ``(B, Hkv, rows, lanes)``: grid dim 0 runs
-    over slots x head groups (one group, every head, unless
+    tree bits, out, lse), all ``(B, Hkv, rows, lanes)``: the entry's slot,
+    and the head group of grid dim 0 (one group, every head, unless
     ``paged_decode_step`` had to split them)."""
-
-    def index_map(bh, qi, si, offs_ref, tbl_ref):
-        del si, offs_ref, tbl_ref
-        return (bh // head_groups, bh % head_groups, qi, 0)
-
-    return index_map
+    del offs_ref, tbl_ref, step_ref, flag_ref
+    return (slot_ref[e], hg, qi, 0)
 
 
-def _paged_kv_map(j: int, entries: int, head_groups: int,
-                  local: bool = False):
-    """K/V index map of a step's ``j``-th entry: grid step ``si`` loads
-    every head (of this head group) of pool block
-    ``table[b, si * entries + j]`` — the block-table indirection happens
-    HERE, in the prefetch-driven DMA schedule, not in the body.
+def _paged_kv_map(j: int, entries: int, local: bool = False):
+    """K/V index map of a step's ``j``-th entry: list entry ``e`` loads
+    every head (of this head group) of pool block ``table[e * entries +
+    j]``, the table in list order (``_plan_operands``) — the block-table
+    indirection happens HERE, in the prefetch-driven DMA schedule, not in
+    the body, and costs a map one scalar load.
 
     ``local`` (ISSUE 18): the table is signed; a negative entry marks a
     block this shard does not own. The DMA engine still needs SOME valid
     pool row, so the map clamps to 0 — the body masks the entry's columns
-    before they touch the softmax state.
+    before they touch the softmax state."""
 
-    Entries past a slot's length all read 0 (the engine keeps them there),
-    so the first step past the length streams ``entries`` copies of pool
-    block 0 and the steps after it, whose indices no longer change, stream
-    nothing. Holding those steps at the slot's last live step instead (so
-    that they stream nothing at all) was tried on the chip and lost: the
-    extra scalar work in every index map cost more than the fetch it saved
-    (``ops/tuning.py``)."""
-
-    def index_map(bh, qi, si, offs_ref, tbl_ref):
-        del qi, offs_ref
-        t = tbl_ref[bh // head_groups, si * entries + j]
+    def index_map(hg, qi, e, offs_ref, tbl_ref, slot_ref, step_ref,
+                  flag_ref):
+        del qi, offs_ref, slot_ref, step_ref, flag_ref
+        t = tbl_ref[e * entries + j]
         if local:
             t = jnp.maximum(t, 0)
-        return (t, bh % head_groups, 0, 0)
+        return (t, hg, 0, 0)
 
     return index_map
 
@@ -614,17 +727,17 @@ def _paged_kv_map(j: int, entries: int, head_groups: int,
 _SCALE_ROWS = 8
 
 
-def _paged_scale_map(entries: int, head_groups: int):
+def _paged_scale_map(entries: int):
     """Per-block scale operand map (ISSUE 13): the scales were pre-
-    gathered per LOGICAL block (see :func:`_block_scale_rows`), so grid
-    step ``si`` reads the 8-row tile holding rows ``si * entries ...`` —
-    no second table dereference, and no re-fetch while the step stays
-    inside the tile."""
+    gathered per LOGICAL block (see :func:`_block_scale_rows`), so the
+    entry for step ``si`` of a slot reads the 8-row tile holding rows ``si
+    * entries ...`` — no second table dereference, and no re-fetch while
+    the step stays inside the tile."""
 
-    def index_map(bh, qi, si, offs_ref, tbl_ref):
-        del qi, offs_ref, tbl_ref
-        return (bh // head_groups, bh % head_groups,
-                (si * entries) // _SCALE_ROWS, 0)
+    def index_map(hg, qi, e, offs_ref, tbl_ref, slot_ref, step_ref,
+                  flag_ref):
+        del qi, offs_ref, tbl_ref, flag_ref
+        return (slot_ref[e], hg, (step_ref[e] * entries) // _SCALE_ROWS, 0)
 
     return index_map
 
@@ -661,6 +774,7 @@ def _paged_decode_call(
     q_offset,
     kv_offset,
     block_table: jax.Array,
+    step_plan: Optional[PagedPlan] = None,
     out_dtype,
     interpret: bool,
 ) -> Tuple[jax.Array, jax.Array]:
@@ -670,13 +784,16 @@ def _paged_decode_call(
     too; last the tree bitmasks when ``tree``), each
     ``(B, Hkv, n_q * bq, lanes)``; ``k`` / ``v`` are the
     ``(N, Hkv, block, D)`` pools and ``scales`` the per-block ``(N, Hkv)``
-    pair of an int8 pool, if it has them. Per-batch offsets AND the
-    ``(B, NB)`` block table ride scalar prefetch
-    (``PrefetchScalarGridSpec``). ``tuning.paged_decode_step`` reads the
-    shapes and says how many heads and table entries a grid step takes: the
-    grid is ``(B x head groups, n_q, NB / entries)``, the pools are handed
-    in once an entry, each with its own index map into the table, so the
-    DMA pipeline prefetches physical blocks in logical order with no gather
+    pair of an int8 pool, if it has them. ``tuning.paged_decode_step`` reads
+    the shapes and says how many heads and table entries a grid step takes;
+    the offsets say which steps of which slots hold a token the rows may
+    see (``paged_step_plan``), and the grid is ``(head groups, n_q, entries
+    of that list)``: a slot at a third of its capacity costs a third of its
+    steps, and with every slot full the list is the whole ``B x NB /
+    entries`` rectangle. Offsets, list and the table in list order ride
+    scalar prefetch (``PrefetchScalarGridSpec``); the pools are handed in
+    once an entry, each with its own index map into the table, so the DMA
+    pipeline prefetches physical blocks in logical order with no gather
     copy. Returns ``(out, lse)`` as ``(B, Hq, Tq, D)`` in ``out_dtype`` and
     ``(B, Hq, Tq)``."""
     from tree_attention_tpu.ops.tuning import paged_decode_step
@@ -691,32 +808,34 @@ def _paged_decode_call(
     if obs.REGISTRY.enabled:
         _KERNEL_BUILDS.labels(
             kernel=label, heads=heads, entries=entries).inc()
-    rows_map = _paged_rows_map(head_groups)
+    prefetch, n_entries = _plan_operands(
+        step_plan, q_offset, kv_offset, block_table, tq=tq, entries=entries,
+        block=block, causal=causal)
     tensors = list(rows)
     in_specs = [
-        pl.BlockSpec((1, heads, bq, t.shape[3]), rows_map) for t in tensors
+        pl.BlockSpec((1, heads, bq, t.shape[3]), _paged_rows_map)
+        for t in tensors
     ]
     for pool in (k, v):
         tensors += [pool] * entries
         in_specs += [
-            pl.BlockSpec(
-                (1, heads, block, D),
-                _paged_kv_map(j, entries, head_groups, local=local_blocks))
+            pl.BlockSpec((1, heads, block, D),
+                         _paged_kv_map(j, entries, local=local_blocks))
             for j in range(entries)
         ]
     if scales is not None:
-        scale_map = _paged_scale_map(entries, head_groups)
         tensors += [_block_scale_rows(s, block_table) for s in scales]
         in_specs += [
-            pl.BlockSpec((1, heads, _SCALE_ROWS, _LANES), scale_map)
+            pl.BlockSpec((1, heads, _SCALE_ROWS, _LANES),
+                         _paged_scale_map(entries))
         ] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B * head_groups, n_q, NB // entries),
+        num_scalar_prefetch=_PLAN_PREFETCH,
+        grid=(head_groups, n_q, n_entries),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, heads, bq, D), rows_map),
-            pl.BlockSpec((1, heads, bq, _LANES), rows_map),
+            pl.BlockSpec((1, heads, bq, D), _paged_rows_map),
+            pl.BlockSpec((1, heads, bq, _LANES), _paged_rows_map),
         ],
         scratch_shapes=[
             pltpu.VMEM((heads, bq, _LANES), jnp.float32),
@@ -726,17 +845,18 @@ def _paged_decode_call(
     )
     out, lse = pl.pallas_call(
         functools.partial(
-            kernel_body, **kernel_kwargs, causal=causal, tq=tq, block_q=bq,
-            block=block, entries=entries, head_groups=head_groups, tree=tree,
-            block_scales=scales is not None, local_blocks=local_blocks,
+            kernel_body, **kernel_kwargs, causal=causal, tq=tq,
+            tk=NB * block, block_q=bq, block=block, entries=entries,
+            tree=tree, block_scales=scales is not None,
+            local_blocks=local_blocks,
         ),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, Hkv, n_rows, D), out_dtype),
             jax.ShapeDtypeStruct((B, Hkv, n_rows, _LANES), jnp.float32),
         ],
-        # Only the split-KV (table) dim is sequential, as in the
-        # contiguous kernels.
+        # Only the list is sequential (a slot's entries carry its softmax
+        # state), as the split-KV dim is in the contiguous kernels.
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
@@ -744,8 +864,7 @@ def _paged_decode_call(
         # A stable name per kernel body, carried into the compiled module
         # (the custom call's op_name) and the profiler trace.
         name=kernel_body.__name__.strip("_").removesuffix("_kernel"),
-    )(_offsets_smem(q_offset, kv_offset, B),
-      jnp.asarray(block_table, jnp.int32), *tensors)
+    )(*prefetch, *tensors)
     r = group * tq
     return (out[:, :, :r].reshape(B, Hkv * group, tq, D),
             lse[:, :, :r, 0].reshape(B, Hkv * group, tq))
@@ -753,17 +872,22 @@ def _paged_decode_call(
 
 def _mla_decode_paged_kernel(
     offs_ref,  # SMEM (2, B) scalar-prefetch: per-batch [q_offset|kv_offset]
-    tbl_ref,   # SMEM (B, NB) scalar-prefetch block table (index maps only)
+    tbl_ref,   # SMEM (E * per,) scalar-prefetch: the block table in list
+               # order (index maps only)
+    slot_ref,  # SMEM (E,) x 3 scalar-prefetch: the work list
+    step_ref,  # (``paged_step_plan``)
+    flag_ref,
     *refs,     # q_ref, kv_ref x blocks_per_step, out_ref, lse_ref,
                # m_scr, l_scr, acc_scr:
                #   q_ref   VMEM (1, bq, W) — packed (head x Tq) queries,
                #           each row [q_lat rank | q_rope]
                #   kv_ref  VMEM (1, block, W) — latent pool block
-               #           tbl[b, si * blocks_per_step + j]
+               #           tbl[e * blocks_per_step + j]
                #   out_ref VMEM (1, bq, rank); lse_ref VMEM (1, bq, LANES)
                #   m/l_scr VMEM (bq, LANES) f32; acc_scr VMEM (bq, rank) f32
     scale: float,
     tq: int,
+    tk: int,
     block_q: int,
     block: int,
     rank: int,
@@ -777,28 +901,27 @@ def _mla_decode_paged_kernel(
     streamed once and used for both. A grid step folds
     ``blocks_per_step`` logical blocks (one operand each, every one the
     same pool through its own table entry): a latent block is a few tens
-    of KB, so one a step would leave the step's fixed cost in charge."""
+    of KB, so one a step would leave the step's fixed cost in charge. The
+    steps are the work list's (``paged_step_plan``), as the GQA kernels'
+    are: none past a slot's length."""
     del tbl_ref  # consumed by the index maps
     q_ref = refs[0]
     kv_refs = refs[1:1 + blocks_per_step]
     out_ref, lse_ref, m_scr, l_scr, acc_scr = refs[1 + blocks_per_step:]
-    qi = pl.program_id(1)
-    si = pl.program_id(2)
-    n_s = pl.num_programs(2)
+    qi = pl.program_id(0)
+    e = pl.program_id(1)
+    b, si, flags = slot_ref[e], step_ref[e], flag_ref[e]
     bq, bk = block_q, block * blocks_per_step
-    tk = n_s * bk
-
-    b = pl.program_id(0)
     q_offset = offs_ref[0, b]
     kv_offset = offs_ref[1, b]
 
-    @pl.when(si == 0)
+    @pl.when(flags & _PLAN_FIRST != 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    @pl.when((kv_offset + si * bk) <= (q_offset + tq - 1))
+    @pl.when(flags & _PLAN_LIVE != 0)
     def _compute():
         kv = kv_refs[0][0] if blocks_per_step == 1 else jnp.concatenate(
             [r[0] for r in kv_refs], axis=0)           # (bk, W)
@@ -816,15 +939,30 @@ def _mla_decode_paged_kernel(
             s, kv[:, :rank], m_scr, l_scr, acc_scr, si=si, bk=bk, tk=tk,
         )
 
-    @pl.when(si == n_s - 1)
+    @pl.when(flags & _PLAN_LAST != 0)
     def _finalize():
         _decode_finalize(out_ref, lse_ref, m_scr, l_scr, acc_scr)
 
 
+def mla_step_entries(table_width: int) -> int:
+    """Table entries a grid step of ``mla_decode_paged`` folds."""
+    return next(p for p in (4, 2, 1) if table_width % p == 0)
+
+
+def mla_plan(tq: int, pool: jax.Array, block_table: jax.Array, q_offset
+             ) -> PagedPlan:
+    """The plan :func:`attention_pallas_mla_paged` builds for ``tq`` rows a
+    slot at ``q_offset`` against this ``(..., block, W)`` pool through
+    ``block_table``."""
+    return paged_plan(
+        q_offset, 0, block_table, tq=tq, block=pool.shape[-2],
+        entries=mla_step_entries(block_table.shape[1]))
+
+
 def _mla_kv_map(j: int, blocks_per_step: int):
-    def index_map(b, qi, si, offs_ref, tbl_ref):
-        del qi, offs_ref
-        return (tbl_ref[b, si * blocks_per_step + j], 0, 0)
+    def index_map(qi, e, offs_ref, tbl_ref, slot_ref, step_ref, flag_ref):
+        del qi, offs_ref, slot_ref, step_ref, flag_ref
+        return (tbl_ref[e * blocks_per_step + j], 0, 0)
 
     return index_map
 
@@ -837,6 +975,7 @@ def attention_pallas_mla_paged(
     q_offset,
     scale: float,
     rank: int,
+    step_plan: Optional[PagedPlan] = None,
     interpret: Optional[bool] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Absorbed latent attention against a paged latent pool.
@@ -851,6 +990,8 @@ def attention_pallas_mla_paged(
     by the value up-projection), ``lse`` ``(B, H, Tq)`` float32, so a
     partial over some of the blocks merges with any other by the repo's
     ``(out, lse)`` monoid. The device event is ``mla_decode_paged``.
+    ``step_plan``: the call's work list, if the caller built it already
+    (:func:`mla_plan`: a layer loop builds it once for all its layers).
     """
     B, H, Tq, W = q.shape
     if pool.ndim != 3 or pool.shape[2] != W:
@@ -867,19 +1008,22 @@ def attention_pallas_mla_paged(
     # 64 heads); chunk rows take tiles of 1024 so that a slot's blocks are
     # walked by few tiles.
     r = H * Tq
-    bq = min(-(-r // 8) * 8, 128 if Tq == 1 else 1024)
+    bq = _packed_rows(r, 128 if Tq == 1 else 1024)
     qp = _pad_dim(q.reshape(B, r, W), 1, bq)
     n_q = qp.shape[1] // bq
-    per = next(p for p in (4, 2, 1) if NB % p == 0)
+    per = mla_step_entries(NB)
     if obs.REGISTRY.enabled:
         _KERNEL_BUILDS.labels(kernel="mla_paged", heads=H, entries=per).inc()
     in_specs = [pl.BlockSpec((1, bq, W), _paged_q_map)] + [
         pl.BlockSpec((1, block, W), _mla_kv_map(j, per))
         for j in range(per)
     ]
+    prefetch, n_entries = _plan_operands(
+        step_plan, q_offset, 0, block_table, tq=Tq, entries=per, block=block,
+        causal=True)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, n_q, NB // per),
+        num_scalar_prefetch=_PLAN_PREFETCH,
+        grid=(n_q, n_entries),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, bq, rank), _paged_q_map),
@@ -893,8 +1037,8 @@ def attention_pallas_mla_paged(
     )
     out, lse = pl.pallas_call(
         functools.partial(
-            _mla_decode_paged_kernel, scale=scale, tq=Tq, block_q=bq,
-            block=block, rank=rank, blocks_per_step=per,
+            _mla_decode_paged_kernel, scale=scale, tq=Tq, tk=NB * block,
+            block_q=bq, block=block, rank=rank, blocks_per_step=per,
         ),
         grid_spec=grid_spec,
         out_shape=[
@@ -902,14 +1046,44 @@ def attention_pallas_mla_paged(
             jax.ShapeDtypeStruct((B, n_q * bq, _LANES), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="mla_decode_paged",
-    )(_offsets_smem(q_offset, 0, B), jnp.asarray(block_table, jnp.int32),
-      qp, *([pool] * per))
+    )(*prefetch, qp, *([pool] * per))
     return (out[:, :r].reshape(B, H, Tq, rank),
             lse[:, :r, 0].reshape(B, H, Tq))
+
+
+def _packed_rows(r: int, cap: int = 128) -> int:
+    """Rows of a Q tile for ``r`` packed rows a KV head: a multiple of the
+    8 sublanes, ``cap`` at most."""
+    return min(-(-r // 8) * 8, cap)
+
+
+def decode_step_entries(q_heads: int, tq: int, pool: jax.Array,
+                        table_width: int) -> int:
+    """Table entries a grid step of the GQA paged kernels takes for
+    ``q_heads`` query heads x ``tq`` rows a slot against this ``(..., Hkv,
+    block, D)`` pool (``tuning.paged_decode_step`` at the call's shapes)."""
+    from tree_attention_tpu.ops.tuning import paged_decode_step
+
+    Hkv, block, D = pool.shape[-3:]
+    return paged_decode_step(
+        Hkv, block, D, pool.dtype.itemsize, table_width,
+        _packed_rows(q_heads // Hkv * tq))[1]
+
+
+def decode_plan(q_heads: int, tq: int, pool: jax.Array,
+                block_table: jax.Array, q_offset) -> PagedPlan:
+    """The plan the GQA paged kernels (:func:`attention_pallas_decode`,
+    ``_q8``, ``_q8q`` with a ``block_table``) build for a causal call of
+    ``q_heads`` query heads x ``tq`` rows a slot against this pool
+    through ``block_table``."""
+    return paged_plan(
+        q_offset, 0, block_table, tq=tq, block=pool.shape[-2],
+        entries=decode_step_entries(
+            q_heads, tq, pool, block_table.shape[1]))
 
 
 def _tree_bits_rows(
@@ -1008,6 +1182,7 @@ def attention_pallas_decode_q8(
     interpret: Optional[bool] = None,
     block_table: Optional[jax.Array] = None,
     tree_mask: Optional[jax.Array] = None,
+    step_plan: Optional[PagedPlan] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Split-KV flash decode over an int8-quantized KV buffer.
 
@@ -1057,7 +1232,7 @@ def attention_pallas_decode_q8(
             interpret = jax.default_backend() != "tpu"
         out_dtype = q.dtype
         sm = (D ** -0.5) if scale is None else scale
-        bq = min(-(-(G * Tq) // 8) * 8, 128)
+        bq = _packed_rows(G * Tq)
         qp = _pad_dim(
             q.astype(jnp.bfloat16).reshape(B, Hkv, G * Tq, D), 2, bq)
         tb = None if tree_mask is None else _tree_bits_rows(
@@ -1067,8 +1242,8 @@ def attention_pallas_decode_q8(
             _paged_rows([qp], tb), k_q, v_q, scales=(k_scale, v_scale),
             tree=tb is not None, group=G, tq=Tq, bq=bq, causal=causal,
             q_offset=q_offset, kv_offset=kv_offset,
-            block_table=block_table, out_dtype=jnp.bfloat16,
-            interpret=interpret,
+            block_table=block_table, step_plan=step_plan,
+            out_dtype=jnp.bfloat16, interpret=interpret,
         )
         return out.astype(out_dtype), lse
     if k_scale.shape != (B, Hkv, 1, D) or v_scale.shape != (B, Hkv, 1, D):
@@ -1094,6 +1269,7 @@ def attention_pallas_decode_q8(
         qf, k_q, v_q, causal=causal, scale=scale,
         q_offset=q_offset, kv_offset=kv_offset, block_size=block_size,
         interpret=interpret, block_table=block_table, tree_mask=tree_mask,
+        step_plan=step_plan,
     )
     # V's per-channel scale applies to the normalised accumulator.
     out = (
@@ -1121,6 +1297,7 @@ def attention_pallas_decode_q8q(
     interpret: Optional[bool] = None,
     block_table: Optional[jax.Array] = None,
     tree_mask: Optional[jax.Array] = None,
+    step_plan: Optional[PagedPlan] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """int8-MXU flash decode over an int8 KV buffer: Q quantized too.
 
@@ -1206,7 +1383,7 @@ def attention_pallas_decode_q8q(
     )
     q_i, qs = quantize_symmetric_int8(qf, axis=3)
 
-    bq = min(-(-r // 8) * 8, 128)
+    bq = _packed_rows(r)
     qp = _pad_dim(q_i, 2, bq)                       # (B, Hkv, n_q * bq, D)
     n_q = qp.shape[2] // bq
     # Padded rows get scale 0 — their int32 scores then rescale to exactly
@@ -1224,8 +1401,8 @@ def attention_pallas_decode_q8q(
             scales=(k_scale, v_scale) if per_block else None,
             tree=tb is not None, group=G, tq=Tq, bq=bq, causal=causal,
             q_offset=q_offset, kv_offset=kv_offset,
-            block_table=block_table, out_dtype=jnp.bfloat16,
-            interpret=interpret,
+            block_table=block_table, step_plan=step_plan,
+            out_dtype=jnp.bfloat16, interpret=interpret,
         )
         if not per_block:
             # (per-block scalars dequantized V in-kernel, folded into p;
@@ -1321,6 +1498,7 @@ def attention_pallas_decode(
     block_table: Optional[jax.Array] = None,
     tree_mask: Optional[jax.Array] = None,
     local_blocks: bool = False,
+    step_plan: Optional[PagedPlan] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Split-KV flash decode. Same ``(out, lse)`` contract as the other impls.
 
@@ -1343,11 +1521,18 @@ def attention_pallas_decode(
     (``ops/tuning.py`` ``paged_decode_step`` works out how many from the
     shapes: 1 MB of K + V a step, 4 entries at 8 KV heads and 8 at 4 for
     64-token bf16 blocks of 128; a width that 2 does not divide gets one
-    entry a step), so the split-KV tile is that many pool blocks end to end
-    and the grid is ``(B, Q tiles, NB / entries)``; ``block_size`` is
-    ignored. On a real TPU keep the pool block >= the dtype's min sublane
-    tile, 8/16/32 for f32/bf16/int8. Every entry must be a valid pool
-    index; entries past a slot's length are masked but still dereferenced
+    entry a step), so the split-KV tile is that many pool blocks end to end.
+    The grid is ``(head groups, Q tiles, live steps)``: a list of the steps
+    of each slot's table that hold a position its rows may see
+    (:func:`paged_step_plan`), none past a slot's length, so a call costs
+    what its slots hold and not what their tables could. ``step_plan`` is
+    that list, if the caller built it already (:func:`decode_plan`: a
+    layer loop builds it once and shifts it as it shifts the table,
+    :meth:`PagedPlan.shifted`; it then is what addresses the pools, and
+    ``block_table`` must be the table it holds); ``block_size`` is ignored. On a real TPU keep the pool
+    block >= the dtype's min sublane tile, 8/16/32 for f32/bf16/int8. The
+    entries of a slot's live steps must be valid pool indices; the last
+    step's entries past the slot's length are masked but still dereferenced
     (the engine keeps them at 0). Bit-exact with gathering ``pool[table]``
     into a contiguous buffer and calling the unpaged kernel at
     ``block_size = entries * block`` — the tiles stream identical rows in
@@ -1414,7 +1599,7 @@ def attention_pallas_decode(
     # Pack each KV head's queries (its whole GQA group × Tq rows) into the
     # Q-tile sublanes: (B, Hq, Tq, D) -> (B, Hkv, r8, D).
     r = G * Tq
-    bq = min(-(-r // 8) * 8, 128)
+    bq = _packed_rows(r)
     qp = _pad_dim(q.reshape(B, Hkv, r, D), 2, bq)
     n_q = qp.shape[2] // bq
 
@@ -1427,7 +1612,8 @@ def attention_pallas_decode(
             _paged_rows([qp], tb), k, v, tree=tb is not None, group=G,
             tq=Tq, bq=bq, causal=causal, local_blocks=local_blocks,
             q_offset=q_offset, kv_offset=kv_offset,
-            block_table=block_table, out_dtype=q.dtype, interpret=interpret,
+            block_table=block_table, step_plan=step_plan, out_dtype=q.dtype,
+            interpret=interpret,
         )
         return out.astype(out_dtype), lse
     qp = qp.reshape(B * Hkv, n_q * bq, D)

@@ -89,19 +89,63 @@ def decode_block_k_q8(tk: int) -> int:
 # and the step wants 1 MB: (8, 4) at Mistral's 8 KV heads, (4, 8) at Yi's 4;
 # past it nothing is gained and the steps that straddle a slot's end waste
 # more. A step costs 0.33 us (Mistral, one entry) to 2.9 us (2 MB): no
-# longer a constant, the bytes show. What is left in "served" is the steps
-# past a slot's length (each still costs a step, ~0.3 us) and the whole
-# steps at its tail. The heads are folded as one batched array: a Python
-# loop over the heads, each on its own slice of the scratch, took 223 / 416
-# us at (8, 4) and 142 / 203 at (4, 4) (the stores to one scratch ordered
-# the heads one after the other). Also tried and dropped: holding a step
-# past a slot's length at the slot's last live step, so that the pipeline
-# streams nothing for it (else the first such step fetches pool block 0
-# `entries` times). With the live step worked out in the index maps (a
-# division each) served / full read 258 / 458 (Mistral, head loop); handed
-# in as a third row of the offsets and a `min` in each map, 153 / 235
-# against 144 / 234 without (Mistral) and 91 / 104 against 86 / 101 (Yi):
-# the scalar work in every map of every step costs more than the fetch.
+# longer a constant, the bytes show. The heads are folded as one batched
+# array: a Python loop over the heads, each on its own slice of the scratch,
+# took 223 / 416 us at (8, 4) and 142 / 203 at (4, 4) (the stores to one
+# scratch ordered the heads one after the other).
+#
+# What was left in "served" on that grid, a rectangle of slots x table steps,
+# was the steps past a slot's length: culled in the body, but each still a
+# grid step whose every operand's index map the pipeline ran (~0.3 us at
+# Mistral's 8 operands, about a live step's cost at Yi's 16). Two tries to
+# make those steps cheap kept them in the grid and lost: holding a step past
+# a slot's length at the slot's last live step, so that the pipeline streams
+# nothing for it (else the first such step fetches pool block 0 `entries`
+# times). With the live step worked out in the index maps (a division each)
+# served / full read 258 / 458 (Mistral, head loop); handed in as a third row
+# of the offsets and a `min` in each map, 153 / 235 against 144 / 234 without
+# (Mistral) and 91 / 104 against 86 / 101 (Yi): the scalar work in every map
+# of every step cost more than the fetch it saved.
+#
+# Since PR 37 those steps are not in the grid: its last dimension is the
+# dynamic length of a list of the (slot, step) pairs that hold a live token
+# (`pallas_decode.paged_step_plan`, from `paged_live_steps` below), as
+# `pallas_moe.tile_plan`'s is. Not a third of those tries: it adds no work to
+# a step that runs (a K/V map reads the table in list order with one scalar
+# load, fewer operations than `table[b, si * entries + j]` was) and removes
+# the others. Measured on v5e 2026-09-30, the same sweep (128 calls in one
+# program over a 16-layer pool, best of 7; served lengths drawn uniformly,
+# Mistral 200-1,500 of 2,560, Yi 300-3,700 of 4,096 and 100-900 as a paced
+# cell's; the plan built once outside the loop and shifted a call, as a tick
+# program does, and in brackets built inside every call):
+#
+#   us a call (share of 819 GB/s)   served                    full
+#   Mistral 16 x 8 x 40   list      105 (73%)  [111]          231 (89%)  [237]
+#                         rectangle 151 (51%)                 233 (88%)
+#   Yi-6B 8 x 4 x 64      list       68 (69%)  [ 74]           99 (82%)  [105]
+#                         rectangle  88 (53%)                 101 (81%)
+#   Yi-6B, paced lengths  list       28 (42%)  [ 34]          100 (82%)
+#                         rectangle  66 (18%)                 101 (82%)
+#   LFM2 64 x 4 x 40      list      214 (66%)  [234]          467 (88%)  [486]
+#                         rectangle 370 (38%)                 469 (87%)
+#   mla, dsv2 16 x 128h   list       75 (32%)  [ 80]          163 (39%)  [167]
+#                         rectangle 103 (23%)                 164 (39%)
+#   mla, LongCat 32 x 64h list      121 (36%)  [129]          278 (46%)  [290]
+#                         rectangle 176 (25%)                 280 (46%)
+#
+# (The rectangle's rows: the parent commit in the same sweep on the same
+# draw of lengths, its tables zero past each slot's mapped blocks as the
+# engine keeps them, so that a dead step's indices do not change and it
+# streams nothing; with other blocks there the rectangle streamed them all
+# and read its full-slot time at any length. PR 28's rows above, 144 / 234
+# and 86 / 101, were another draw of the same ranges.)
+#
+# Full slots read what the rectangle read (the list IS the rectangle there);
+# the plan costs 5-6 us a call where a call builds its own, which is why a
+# step program builds it once (`models/decode.py` `_plan_groups`). What is
+# left in "served": the whole step at each slot's tail (half a step a slot
+# on average: 16 x 128 tokens of Mistral's 15,300 live ones) and, for the
+# latent kernel, the operations (near the ridge at 128 heads).
 PAGED_STEP_TARGET_BYTES = 1 << 20
 PAGED_STEP_ENTRIES = (1, 2, 4, 8)  # divisors of the 8-row scale tile
 # What a step may hold of the 16 MB of scoped VMEM a v5e kernel gets by
@@ -154,6 +198,20 @@ def paged_decode_step(
         if heads * per * entry >= PAGED_STEP_TARGET_BYTES:
             break
     return heads, entries
+
+
+def paged_live_steps(q_offset, kv_offset, tq: int, step_tokens: int,
+                     n_steps: int):
+    """Steps of each slot's table that hold a token its rows may see, 0 to
+    ``n_steps``: step ``si`` covers positions ``kv_offset + si *
+    step_tokens ...`` and the slot's last row sits at ``q_offset + tq -
+    1``, so the steps ``0 .. (q_offset + tq - 1 - kv_offset) // step_tokens``
+    do. Offsets are ``(B,)`` integer arrays of numpy's or of jax's: the
+    kernels build their work list from this on the device
+    (``pallas_decode.paged_step_plan``), and the serve loop counts with it
+    on the host what a tick's list will hold (``kv_steps_run``)."""
+    return ((q_offset + (tq - 1) - kv_offset) // step_tokens + 1).clip(
+        0, n_steps)
 
 
 # The one home of the TPU kernel-dispatch policy shared by flash_attention's

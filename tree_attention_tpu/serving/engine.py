@@ -161,11 +161,14 @@ from tree_attention_tpu.models.decode import (
     init_paged_cache,
     insert_dequant_prefix,
     paged_insert_slot,
+    paged_step_tokens,
     quantize_paged_blocks,
     sample_rows,
     sample_slots,
     scatter_kv_blocks,
 )
+from tree_attention_tpu.ops.pallas_decode import PAGED_STEPS
+from tree_attention_tpu.ops.tuning import paged_live_steps
 from tree_attention_tpu.serving.block_pool import (
     BlockAllocator,
     ShardedBlockAllocator,
@@ -503,6 +506,9 @@ class _Tail:
     spec_plan: Any = None
     tree_plan: Any = None
     spec_width: int = 0
+    # (entries of the paged kernels' work lists, of the whole slots x steps
+    # rectangles) over the program's groups of rows, a layer.
+    kv_steps: Tuple[int, int] = (0, 0)
     # The head's per-tick counters, frozen when the tail is left pending
     # (the iteration that lands it has counted its own by then).
     counts: Optional[Dict[str, Any]] = None
@@ -1226,6 +1232,11 @@ class SlotServer:
             self._expert_rows_shape = (
                 cfg.n_layers - cfg.n_dense_layers, counts_width(cfg.moe))
         self.tok = jnp.zeros((slots,), jnp.int32)
+        # The host's view of each slot's length on the device, as the
+        # tick programs leave it (``_count_kv_steps``), and the tokens a
+        # grid step of the paged kernel takes by a group's rows a slot.
+        self._kv_len = np.zeros((slots,), np.int64)
+        self._kv_step_tokens: Dict[int, Optional[int]] = {}
 
         # Host mirror of slot state (the scheduler's view; device state is
         # the cache + the token vector the mixed step carries). States:
@@ -1526,6 +1537,50 @@ class SlotServer:
                 "routed_rows": routed // ex.per_token,
                 "routed_pairs": routed, "zero_pairs": zero,
                 "real_row_max": real_max}
+
+    def _count_kv_steps(self, tq, n_vec, reset, reset_val, decode_rows,
+                        chunk) -> Tuple[int, int]:
+        """What the paged decode kernels' work lists hold for the program
+        being dispatched and what the whole rectangles would, a layer:
+        ``(kv_steps_run, kv_steps_grid)`` of its flight record. The kernels
+        build their lists on the device from the slots' lengths
+        (``ops/pallas_decode.py`` ``paged_step_plan``); the host counts
+        with the same rule (``tuning.paged_live_steps``) from the lengths
+        it packed: a reset is its value, a live slot's decode row
+        (``decode_rows``: the slots and their sample indices) sits at its
+        prompt and samples so far, and any other slot where the programs
+        before left it (``_kv_len``, which this call moves on by the rows
+        the program writes). ``chunk``: a packed program's chunk group
+        ``(slots, counts)`` beside one row a slot; else one group of
+        ``tq`` rows a slot. A slot no program here has written since a
+        side program moved it (a whole admission's insert) is counted at a
+        stale length until its next decode row."""
+        pre = np.where(reset, reset_val, self._kv_len)
+        if decode_rows is not None:
+            for i in decode_rows[0]:
+                pre[i] = len(self._slot_req[i].prompt) \
+                    + int(decode_rows[1][i]) - 1
+        groups = [(tq, pre)] if chunk is None \
+            else [(tq, pre[chunk[0]]), (1, pre)]
+        run = grid = 0
+        for g_tq, lengths in groups:
+            if g_tq not in self._kv_step_tokens:
+                self._kv_step_tokens[g_tq] = paged_step_tokens(
+                    self.cache, self.cfg, g_tq)
+            step = self._kv_step_tokens[g_tq]
+            if step is None:
+                continue
+            n_steps = self.cache.capacity // step
+            live = paged_live_steps(lengths, 0, g_tq, step, n_steps)
+            run += int(np.maximum(live, 1).sum())
+            grid += len(lengths) * n_steps
+        self._kv_len = pre + n_vec
+        if chunk is not None:
+            np.add.at(self._kv_len, chunk[0], chunk[1])
+        if obs.REGISTRY.enabled:
+            PAGED_STEPS.labels(steps="run").inc(run)
+            PAGED_STEPS.labels(steps="grid").inc(grid)
+        return run, grid
 
     def _sample_emit(self, last, keys, temp, topk, idx):
         """The ONE per-slot sampling call every emitting program shares
@@ -4629,6 +4684,12 @@ class SlotServer:
                         p.group * p.tq + self.slots
                         if p.group else self.slots * p.tq),
                     "rows_useful": p.rows_useful,
+                    # Grid steps of the paged decode kernels, a layer:
+                    # the entries of their work lists (no step past a
+                    # slot's length) and of the whole slots x steps
+                    # rectangles (``_count_kv_steps``).
+                    "kv_steps_run": p.kv_steps[0],
+                    "kv_steps_grid": p.kv_steps[1],
                     # Dispatched before the tail of the program before
                     # it landed (ISSUE 32), or why not.
                     "ahead": p.ahead,
@@ -4945,6 +5006,7 @@ class SlotServer:
                     tick_tq = 0
                     tick_group = 0  # members of a packed tick's chunk group
                     n_vec = None
+                    kv_rows = kv_chunk = None   # _count_kv_steps' operands
                     if self._staged_prefill and plan:
                         # The stage programs pack and launch per chunk.
                         phases.mark("pack")
@@ -5206,6 +5268,8 @@ class SlotServer:
                         chunk_tok, chunk_slot, chunk_n = \
                             self._pack_chunk_group(plan, tq, reset,
                                                    reset_val, emit)
+                        kv_rows = (live_idx, sidx)
+                        kv_chunk = (chunk_slot, chunk_n)
                         phases.mark("table_sync")
                         self._sync_table()
                         phases.mark("dispatch", tick, tick_kind, tick_tq,
@@ -5275,6 +5339,7 @@ class SlotServer:
                                 i, len(self._slot_req[i].prompt)
                                 + int(sidx[i])
                             )
+                        kv_rows = (live_idx, sidx)
                         phases.mark("table_sync")
                         self._sync_table()
                         phases.mark("dispatch", tick, tick_kind, tick_tq,
@@ -5321,6 +5386,11 @@ class SlotServer:
                         fused=fused_dev, all_tok=all_tok_dev,
                         spec_plan=spec_plan, tree_plan=tree_plan,
                         spec_width=spec_width,
+                        kv_steps=(
+                            (0, 0) if n_vec is None
+                            else self._count_kv_steps(
+                                tick_tq, n_vec, reset, reset_val, kv_rows,
+                                kv_chunk)),
                     )
                     primed = True
                     self._tail = cur
