@@ -1226,6 +1226,118 @@ def phase_hybrid(s: Sizes, config: Optional[Dict[str, Any]] = None, *,
     }
 
 
+def phase_window(s: Sizes, config: Optional[Dict[str, Any]] = None, *,
+                 device: str = "tpu", block: int = 64, chunk: int = 256,
+                 tol_gap: float = 0.4) -> Dict[str, Any]:
+    """Sliding-window layers beside full-attention layers
+    (``benchmark/configs/k-exaone-236b-a23b.json``: 6 window + 2 full
+    layers, 1 dense FFN, 7 expert layers of the chip's 8 of 128 experts, at
+    their published widths) served on one chip through
+    ``cli.build_serve_engine`` and ``SlotServer.serve`` with the
+    reference's seeded weights, from two K/V pools under two tables: a cold
+    request longer than three windows and three blocks (its window blocks
+    go back as it grows); after it retired, one that shares its whole
+    prompt (a prefix hit at the deepest published boundary: the tree kept
+    the window blocks of the prompt's last full blocks) and a family of two
+    forked inside a block (window blocks shared by reference, the partial
+    one copied); then a shorter request in a slot a longer one used (both
+    tables reset). Every served token is held to the plain reference's
+    logits (``benchmark/references/exaone_moe.py``, the full forward pass,
+    the window a mask, no cache) as :func:`phase_hybrid` holds its own: a
+    branch's MEAN gap lies under ``tol_gap``; a window that sees too much
+    or too little, a rotated full layer or a block given back too early
+    leaves every later token at the logits' own spread."""
+    import numpy as np
+
+    from benchmark import check as served
+    from benchmark.spec import Spec
+    from tree_attention_tpu import cli
+    from tree_attention_tpu.serving.engine import Request
+    from tree_attention_tpu.utils.config import parse_args
+
+    spec = Spec(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                             "BENCHMARK.json"))
+    if config is None:
+        config = spec.load_json("configs", "k-exaone-236b-a23b.json")
+    ref = spec.load_module("references", config["family"] + ".py")
+    adapter = spec.load_module("adapters", config["family"] + ".py")
+    w = ref.Widths.of(config)
+    weights = ref.init_weights(3, w)
+    window = int(config["sliding_window"])
+    rng = np.random.default_rng(19)
+    vocab = int(config["vocab_size"])
+    new = window // 2 + 16
+    # The later requests' budgets: long enough that a branch's mean is not
+    # two near-tied choices (the hit request's first 12 tokens read 0.319
+    # on the chip, its first 48 0.087, served by a hit and cold alike;
+    # PERF.md, PR 38).
+    later = min(48, new)
+    n_a = max(3 * window, 3 * block) + block + 7 - new
+    a = rng.integers(0, vocab, (n_a,)).tolist()
+    b = a + rng.integers(0, vocab, (block // 2 + 3,)).tolist()
+    c = rng.integers(0, vocab, (2 * block + block // 3,)).tolist()
+    d = rng.integers(0, vocab, (block // 2,)).tolist()
+    flags = ["--mode", "serve", "--device", device,
+             "--dtype", str(config["torch_dtype"]), "--slots", "3",
+             "--prompt-len", str(len(b)), "--prompt-jitter", "0",
+             "--max-new-tokens", str(new), "--prefill-chunk", str(chunk),
+             "--prefix-cache", "--prefix-block", str(block),
+             "--temperature", "0", "--seed", "1"]
+    setup = cli.build_serve_engine(
+        parse_args(flags), None, model=config,
+        params=adapter.engine_params(weights, w))
+    check(setup.tcfg.cache_kind == "window", "the model caches window state")
+    server = setup.make_engine()
+    first = server.serve([Request(uid=0, prompt=a, max_new_tokens=new)])
+    second = server.serve([
+        Request(uid=1, prompt=b, max_new_tokens=later),
+        Request(uid=2, prompt=c, max_new_tokens=later, n=2)])
+    third = server.serve([Request(uid=3, prompt=d, max_new_tokens=later)])
+    results = list(first.results) + list(second.results) \
+        + list(third.results)
+    prompts = {0: a, 1: b, 2: c, 3: d}
+    check(len(results) == 5 and all(
+        r.outcome == "budget" for r in results),
+        "five branches served to their budgets")
+    check(len(a) + new > max(3 * window, 3 * block),
+          "the cold request outgrew three windows and three blocks")
+    hit = [r for r in results if r.uid == 1][0].prefix_hit_tokens
+    check(hit == len(a) // block * block,
+          f"a prefix hit at the prompt's last whole block (got {hit})")
+    check(second.kv.get("forks") == 1, f"one fork (kv: {second.kv})")
+    twins = [r.tokens for r in results if r.uid == 2]
+    check(twins[0] == twins[1], "the greedy twins agree")
+    kv = third.kv
+    check(first.kv["window_blocks_peak_slot"] <= kv["window_blocks_bound"]
+          and first.kv["window_blocks_freed"] > 0,
+          f"a slot's window blocks stay under the bound and go back "
+          f"({first.kv})")
+    leak = server.leak_report()
+    check(leak["blocks_used"] == leak["blocks_cached"]
+          and not (leak["blocks_private"] or leak["blocks_reserved"]
+                   or leak["pins"] or leak["window_blocks_held"]),
+          f"no block leaked ({leak})")
+    gaps = [served.served_gaps(ref, weights, w, np.asarray(prompts[r.uid]),
+                              np.asarray(r.tokens))[0] for r in results]
+    means = [float(g.mean()) for g in gaps]
+    check(max(means) <= tol_gap,
+          f"a branch's served tokens lie {max(means):.3f} under the "
+          f"reference's best on average (limit {tol_gap}; {means})")
+    return {
+        "layers": list(setup.tcfg.layer_types), "prefix_hit_tokens": hit,
+        "forks": second.kv["forks"],
+        "window_blocks_bound": kv["window_blocks_bound"],
+        "window_blocks_peak_slot": first.kv["window_blocks_peak_slot"],
+        "window_blocks_freed": first.kv["window_blocks_freed"],
+        "kv_token_bytes": kv["token_bytes"],
+        "window_token_bytes": kv["window_token_bytes"],
+        "tokens_compared": int(sum(len(g) for g in gaps)),
+        "gap_max": float(max(g.max() for g in gaps)),
+        "gap_mean": float(np.concatenate(gaps).mean()),
+        "gap_mean_by_branch": means,
+    }
+
+
 # ---------------------------------------------------------------------------
 # main
 # ---------------------------------------------------------------------------
@@ -1240,6 +1352,7 @@ def run_default(run: Run, s: Sizes) -> None:
     run.phase("train", phase_train, s)
     run.phase("programs", phase_programs, s)
     run.phase("hybrid", phase_hybrid, s)
+    run.phase("window", phase_window, s)
 
 
 def run_four_chips(run: Run, s: Sizes) -> None:

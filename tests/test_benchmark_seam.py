@@ -1,7 +1,8 @@
 """The seam by which the benchmark finds a model family by name, guarded by
 tier-1 (``benchmark/tests/test_seam.py`` holds the slower rehearsals, which
 tier-1 does not run), and the cells that came through it: ``dsv2_codegen_sat``,
-``lcflash_agentturn_sat`` and ``lfm2_agentturn_sat``.
+``lcflash_agentturn_sat``, ``lfm2_agentturn_sat`` and
+``kexaone_reasoning_long_sat``.
 """
 
 import json
@@ -33,10 +34,13 @@ FAMILY_WORDS = ("hidden_size", "num_key_value_heads", "num_attention_heads",
                 "expert_ffn_hidden_size", "moe_topk", "zero_expert_num",
                 "mla_scale_q_lora", "mla_scale_kv_lora", "routed_branch",
                 "layer_types", "conv_L_cache", "num_dense_layers",
-                "use_expert_bias", "tie_word_embeddings", "router_scoring")
+                "use_expert_bias", "tie_word_embeddings", "router_scoring",
+                "sliding_window", "mlp_layer_types", "num_shared_experts",
+                "rotary_layers", "scale_renormed", "norm_placement")
 LEAVES = ("embed", "ln1", "wq", "wk", "wv", "wo", "ln2", "w1", "w3", "w2",
           "ln_f", "wout", "wqa", "wqb", "wkva", "wkvb", "router", "we1",
-          "router_bias", "sub", "w_in", "w_conv", "w_out", "q_ln", "k_ln")
+          "router_bias", "sub", "w_in", "w_conv", "w_out", "q_ln", "k_ln",
+          "wattn")
 THE_FAMILYS_OWN = ("references", "adapters", "configs", "kernel_costs",
                    "tests")
 # What PR 35 appended, in order: a tick's device time by part of the model.
@@ -50,6 +54,10 @@ PARTS_ALL = PARTS_MOE[:5] + ["dec_conv_ms_tick"] + PARTS_MOE[5:11] + [
     "mix_conv_ms_tick", "tick_unscoped_pct"]
 # What PR 37 appended after them, for every cell.
 AFTER_PARTS = ["paged_steps_run_pct"]
+# What PR 38 appended last, for its own cell.
+WINDOW_METRICS = ["window_attn_ms_tick", "window_decode_paged_roofline",
+                  "window_blocks_held_pct"]
+KX_CELL = "kexaone_reasoning_long_sat"
 
 
 def _sources(but=()):
@@ -447,7 +455,7 @@ def test_tick_ahead_pct_reads_the_look_ahead_counter(flight, want):
     # PR 35's parts).
     assert [m["name"] for m in listed[at + 1:]] == [
         "mixer_rest_ms_tick", "mixer_rest_stream_roofline"] + PARTS_ALL \
-        + AFTER_PARTS
+        + AFTER_PARTS + WINDOW_METRICS
     for w in json.load(open(BENCH))["workloads"]:
         assert "tick_ahead_pct" in [
             m["name"] for m in spec.cell(w["name"]).per_layer]
@@ -475,7 +483,8 @@ def test_the_hybrid_cell_resolves_every_file_it_names():
     assert "import benchmark" not in text and "from benchmark" not in text
     # The traffic file that was there, and the cell the sixth of six.
     assert cell.traffic == lc.traffic and cell.traffic["kind"] == "backlog"
-    assert [w["name"] for w in spec.data["workloads"]][5:] == [LFM_CELL]
+    assert [w["name"] for w in spec.data["workloads"]][5:] == [
+        LFM_CELL, KX_CELL]
     assert all(w["chips"] == 1 for w in spec.data["workloads"])
     assert [m["name"] for m in cell.end_to_end] == [
         "out_tok_s", "tbt_p50_ms", "tbt_p99_ms", "setup_s"]
@@ -667,21 +676,27 @@ def test_the_rest_metrics_read_nothing_where_there_is_nothing(name):
 
 @pytest.mark.parametrize("name", PARTS_ALL)
 def test_a_parts_metric_is_listed_where_its_part_exists(name):
-    """Fourteen entries appended last: device-trace metrics of the tick
+    """Fourteen entries appended by PR 35: device-trace metrics of the tick
     programs, the decode ones moving ``tbt_p50_ms`` and the mixed ones
-    ``tbt_p99_ms``; the expert parts in the three expert cells, the conv
-    parts in the hybrid's alone, the rest in all six."""
+    ``tbt_p99_ms``; the expert parts in the four expert cells, the conv
+    parts in the hybrid's alone, the rest in all seven."""
     spec = Spec(BENCH)
     listed = json.load(open(BENCH))["per_layer"]
-    assert [m["name"] for m in listed[-len(PARTS_ALL) - len(AFTER_PARTS):]] \
-        == PARTS_ALL + AFTER_PARTS
+    tail = len(PARTS_ALL) + len(AFTER_PARTS) + len(WINDOW_METRICS)
+    assert [m["name"] for m in listed[-tail:]] \
+        == PARTS_ALL + AFTER_PARTS + WINDOW_METRICS
     entry = next(m for m in listed if m["name"] == name)
     cells = [w["name"] for w in spec.data["workloads"]]
     want = cells
     if "_moe_" in name:
-        want = [CELL, LC_CELL, LFM_CELL]
+        want = [CELL, LC_CELL, LFM_CELL, KX_CELL]
     elif "_conv_" in name:
         want = [LFM_CELL]
+    if name.startswith("mix_"):
+        # The window cell's traced 3 s hold a chunk tick in most runs and
+        # none in some (1.5 a second, in clusters): a reader that finds
+        # nothing to read there is not listed there (PERF.md section 7).
+        want = [c for c in want if c != KX_CELL]
     assert entry == {
         "name": name, "unit": "%" if name.endswith("_pct") else "ms",
         "better": "lower", "source": "device_trace",
@@ -711,7 +726,7 @@ def test_the_parts_readers_name_no_leaf_of_a_family():
 
 
 def test_paged_steps_run_pct_reads_the_lists_the_kernels_walk():
-    """One entry appended last, for all six cells: the flight records'
+    """One entry appended by PR 37, for every cell: the flight records'
     ``kv_steps_run`` over ``kv_steps_grid`` in the window's decode ticks. A
     program without the counters (the parent), an untraced run and a window
     without a decode tick give None."""
@@ -720,12 +735,14 @@ def test_paged_steps_run_pct_reads_the_lists_the_kernels_walk():
     spec = Spec(BENCH)
     listed = json.load(open(BENCH))["per_layer"]
     cells = [w["name"] for w in spec.data["workloads"]]
-    assert listed[-1] == {
+    assert listed[-1 - len(WINDOW_METRICS)] == {
         "name": "paged_steps_run_pct", "unit": "%", "better": "lower",
         "source": "program_counter", "layer": "kernels",
         "moves": "tbt_p50_ms", "workloads": cells}
     for cell in cells:
-        assert spec.cell(cell).per_layer[-1]["name"] == "paged_steps_run_pct"
+        last = -1 - len(WINDOW_METRICS) * (cell == KX_CELL)
+        assert spec.cell(cell).per_layer[last]["name"] \
+            == "paged_steps_run_pct"
     read = spec.load_module("layer_metrics", "paged_steps_run_pct.py").read
     tick = {"occupancy": 16, "chunk_tokens": 0, "kv_steps_grid": 160}
     flight = [
@@ -744,3 +761,210 @@ def test_paged_steps_run_pct_reads_the_lists_the_kernels_walk():
     for none in (parent, [], None, flight[2:]):
         run = types.SimpleNamespace(flight=none, t_open=1.0, t_end=10.0)
         assert read(run) is None
+
+
+# -- layers whose block counts differ: the window cell (ISSUE 38) ------------
+
+
+def test_the_window_cell_resolves_every_file_it_names():
+    spec = Spec(BENCH)
+    cell = spec.cell(KX_CELL)
+    assert cell.chips == 1 and cell.config["family"] == "exaone_moe"
+    assert cell.config["name"] == "k-exaone-236b-a23b"
+    for d, mod in (("references", cell.reference()),
+                   ("adapters", cell.adapter())):
+        assert mod.__file__.endswith(os.path.join(d, "exaone_moe.py"))
+    with open(cell.reference().__file__) as f:
+        text = f.read()
+    assert "tree_attention_tpu" not in text.split('"""', 2)[2]
+    assert "import benchmark" not in text and "from benchmark" not in text
+    # A traffic file of its own (data only), the cell the seventh of seven,
+    # none on four chips.
+    assert cell.traffic["kind"] == "backlog"
+    assert spec.find("traffic", "reasoning_long_backlog.json")
+    assert [w["name"] for w in spec.data["workloads"]][6:] == [KX_CELL]
+    assert all(w["chips"] == 1 for w in spec.data["workloads"])
+    assert [m["name"] for m in cell.end_to_end] == [
+        "out_tok_s", "tbt_p50_ms", "tbt_p99_ms", "setup_s"]
+    names = [m["name"] for m in cell.per_layer]
+    for name in names:
+        assert spec.load_module("layer_metrics", name + ".py").read
+    for name in ("occupancy_pct", "kv_blocks_peak_pct", "hbm_peak_gb",
+                 "tick_rows_useful_pct", "attn_kernel_ms_tick",
+                 "flash_decode_paged_roofline", "moe_ffn_ms_tick",
+                 "moe_grouped_matmul_roofline", "experts_touched_pct",
+                 "expert_rows_max_over_mean", "tick_ahead_pct",
+                 "device_idle_pct", "decode_tick_p50_ms",
+                 "paged_steps_run_pct", "tick_unscoped_pct"):
+        assert name in names, name
+    assert names[-3:] == WINDOW_METRICS
+    for name in ("mla_decode_ms_tick", "zero_expert_pairs_pct",
+                 "dec_conv_ms_tick", "mix_conv_ms_tick",
+                 "mixer_rest_ms_tick", "mix_attn_chunk_ms_tick",
+                 "mix_moe_ms_tick"):
+        assert name not in names
+    for name in ("dec_proj_ms_tick", "dec_attn_ms_tick", "dec_ffn_ms_tick",
+                 "dec_head_ms_tick", "dec_moe_ms_tick", "mixed_tick_p50_ms"):
+        assert name in names, name
+    listed = {m["name"]: m for m in spec.data["per_layer"]}
+    for name in WINDOW_METRICS:
+        assert listed[name]["workloads"] == [KX_CELL]
+    assert (listed["window_attn_ms_tick"]["layer"],
+            listed["window_attn_ms_tick"]["moves"],
+            listed["window_attn_ms_tick"]["source"]) == (
+        "kernels", "tbt_p50_ms", "device_trace")
+    assert listed["window_decode_paged_roofline"]["unit"] == "%"
+    assert (listed["window_blocks_held_pct"]["layer"],
+            listed["window_blocks_held_pct"]["better"],
+            listed["window_blocks_held_pct"]["moves"]) == (
+        "block pool", "lower", "out_tok_s")
+    for w in spec.data["workloads"][:6]:
+        assert not set(WINDOW_METRICS) & {
+            m["name"] for m in spec.cell(w["name"]).per_layer}
+    assert cell.config["serving"] == {
+        "slots": 32, "cache_len": 9216, "kv_layout": "paged", "kv_block": 64,
+        "admission": "chunked", "prefill_chunk": 256, "prefix_cache": True}
+    assert list(cell.config["correct"]["limits"]) == ["gap_mean"]
+    calls = {k: cell.adapter().kernel_call(cell.config, k)
+             for k in ("flash_decode_paged", "window_decode_paged",
+                       "moe_grouped_matmul", "mla_decode_paged")}
+    assert calls["flash_decode_paged"][1] == 2
+    assert calls["window_decode_paged"][1] == 6
+    assert calls["window_decode_paged"][0]["window"] == 128
+    assert calls["moe_grouped_matmul"] == (
+        {"hidden": 6144, "width": 2048, "experts_held": 8,
+         "dtype_bytes": 2}, 7)
+    assert calls["mla_decode_paged"] is None
+    for kernel in ("flash_decode_paged", "window_decode_paged",
+                   "moe_grouped_matmul"):
+        assert spec.load_module("kernel_costs", kernel + ".py").cost
+
+
+def test_the_window_configurations_file_against_the_catalog():
+    """Every number of the catalog's ``config`` under the same key but the
+    keys ``reduced`` lists; the cut's arithmetic; every assumed rule marked
+    unconfirmed; the drafter named as not built."""
+    path = "/opt/skills/guides/model-configs/architectures.jsonl"
+    with open(BENCH) as f:
+        entry = next(c for c in json.load(f)["configs"]
+                     if c["name"] == "k-exaone-236b-a23b")
+    with open(os.path.join(ROOT, entry["file"])) as f:
+        c = json.load(f)
+    assert entry["reduced"] == c["reduced"] == [
+        "num_hidden_layers", "layer_types", "mlp_layer_types",
+        "sliding_windows", "num_experts", "vocab_size"]
+    assert c["source"] == entry["source"]
+    if os.path.exists(path):
+        row = next(r for r in map(json.loads, open(path))
+                   if r["name"] == "K-EXAONE-236B-A23B")
+        assert entry["source"] == row["source_url"]
+        for k, v in row["config"].items():
+            if k not in c["reduced"]:
+                assert c[k] == v, k
+        pub = row["config"]
+        assert c["layer_types"] == pub["layer_types"][:8]
+        assert c["mlp_layer_types"] == pub["mlp_layer_types"][:8]
+        assert c["sliding_windows"] == pub["sliding_windows"][:8]
+    assert (c["num_hidden_layers"], c["num_experts"], c["vocab_size"]) == (
+        8, 8, 19200)
+    assert c["published"]["num_experts"] == 128 \
+        and c["published"]["vocab_size"] == 153600
+    # Floors: two whole periods, 7 >= 4 layers after the dense one, 8
+    # experts, an eighth of the vocabulary.
+    assert c["layer_types"] == (["sliding_attention"] * 3
+                                + ["full_attention"]) * 2
+    assert c["vocab_size"] * 8 == 153600
+    h, ffn, e = c["hidden_size"], c["intermediate_size"], \
+        c["moe_intermediate_size"]
+    attn = 2 * h * 64 * 128 + 2 * h * 8 * 128
+    layer = attn + 3 * h * e + h * 128            # + shared expert + router
+    held = (attn + 3 * h * ffn) + 7 * (layer + 8 * 3 * h * e) \
+        + 2 * c["vocab_size"] * h
+    # The file sums its rounded parts (3,865.5M); to the parameter it is
+    # 3,865.3M before the norms' gains.
+    assert abs(held / 1e6 - 3865.5) < 0.3 and "3,865.5M" in c["why_reduced"]
+    dep = c["deployment"]
+    assert (dep["chips"], dep["chips_a_layer"], dep["pipeline_stages"],
+            dep["experts_total"], dep["expert_share"]) == (64, 16, 4, 128, 0)
+    assert c["block"] == dict(
+        c["block"], qk_norm=True, rotary_layers="sliding_attention",
+        corrected_choice=True, scale_renormed=True, norm_placement="pre")
+    for rule in ("qk_norm", "rotary_layers", "corrected_choice",
+                 "norm_placement"):
+        assert "unconfirmed" in c["assumed"]["unconfirmed"][rule]
+    assert "num_nextn_predict_layers" in c["not_built"]
+    assert set(c["assumed"]["seeded_scales"]) == {
+        "embedding_std", "head_std", "attn_out_std", "dense_down_std",
+        "expert_down_std", "shared_down_std", "qk_gain_mean", "qk_gain_std",
+        "router_bias_std"}
+
+
+def test_the_window_kernels_cost_against_a_hand_count_at_one_tick():
+    spec = Spec(BENCH)
+    cell = spec.cell(KX_CELL)
+    of_model, calls = cell.adapter().kernel_call(
+        cell.config, "window_decode_paged")
+    cost = spec.load_module("kernel_costs", "window_decode_paged.py").cost
+    c = cost(contexts=[50, 128, 9000], q_rows=1, **of_model)
+    kv = 2 * (50 + 128 + 128) * 8 * 128 * 2
+    qo = 3 * 2 * 1 * 64 * 128 * 2
+    assert c["bytes"] == kv + qo and calls == 6
+    assert c["flops"] == 4 * 64 * 128 * (50 + 128 + 128)
+    # The full layers' cost grows with the context; this one does not.
+    full = spec.load_module("kernel_costs", "flash_decode_paged.py").cost
+    args = {k: v for k, v in of_model.items() if k != "window"}
+    assert full(contexts=[9000], q_rows=1, **args)["bytes"] \
+        > 30 * cost(contexts=[9000], q_rows=1, **of_model)["bytes"]
+
+
+def test_the_window_adapter_refuses_another_model_at_once():
+    spec = Spec(BENCH)
+    adapter = spec.cell(KX_CELL).adapter()
+    with pytest.raises(SpecError, match="cannot read"):
+        adapter.build({"family": "exaone_moe"}, [], 0, "cpu", None)
+    # A file that says another block than the engine would build.
+    config = dict(spec.cell(KX_CELL).config)
+    config["block"] = dict(config["block"], rotary_layers="all")
+    from tree_attention_tpu.models.transformer import model_from_config
+    model = model_from_config(spec.cell(KX_CELL).config)
+    with pytest.raises(SpecError, match="built otherwise"):
+        adapter._hold_to_file(model, config)
+
+
+@pytest.mark.parametrize("name", WINDOW_METRICS)
+def test_the_window_metrics_read_nothing_where_there_is_nothing(name):
+    """An untraced run, a run without flight records, a trace without a
+    decode tick and a program without the counters (the parent's records)
+    give None, never a number and never an exception."""
+    import types
+
+    spec = Spec(BENCH)
+    cell = spec.cell(KX_CELL)
+    read = spec.load_module("layer_metrics", name + ".py").read
+    peaks = spec.load_json("peaks.json")["TPU v5 lite"]
+    empty = {"offset_s": 0.0, "t0": 5.0, "t1": 8.0, "devices": 1,
+             "events": {}}
+    parent = [{"t_s": 2.0, "occupancy": 4, "chunk_tokens": 0},
+              {"t_s": 3.0, "occupancy": 4, "chunk_tokens": 0}]
+    for trace, flight in ((None, None), (None, []), (empty, None),
+                          (empty, [{"t_s": 1.0}]), (empty, parent)):
+        run = types.SimpleNamespace(trace=trace, flight=flight, cell=cell,
+                                    recs=[], peaks=peaks, t_open=1.0,
+                                    t_end=10.0)
+        assert read(run) is None
+
+
+def test_window_blocks_held_pct_reads_the_ledgers_counters():
+    import types
+
+    spec = Spec(BENCH)
+    read = spec.load_module("layer_metrics", "window_blocks_held_pct.py").read
+    tick = {"occupancy": 32, "chunk_tokens": 0, "window_blocks_full": 2000}
+    flight = [
+        dict(tick, t_s=2.0, window_blocks_held=90),
+        dict(tick, t_s=3.0, window_blocks_held=96),
+        dict(tick, t_s=4.0, window_blocks_held=130, chunk_tokens=256),
+        dict(tick, t_s=0.5, window_blocks_held=60),      # before the window
+    ]
+    run = types.SimpleNamespace(flight=flight, t_open=1.0, t_end=10.0)
+    assert read(run) == pytest.approx(100.0 * 186 / 4000)

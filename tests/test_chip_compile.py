@@ -373,9 +373,14 @@ def _tick_program(config, tq, packed=False, int8=False, served=True):
     layout = served_layout if served else (lambda p: p)
     params = chip(jax.eval_shape(
         lambda: layout(init_params(jax.random.PRNGKey(0), cfg))))
+    extra = {}
+    if cfg.cache_kind == "window":
+        # The engine's own size: (ceil((window + chunk) / block) + 2) a slot.
+        extra["window_blocks"] = slots * (-(-(
+            cfg.window + serving["prefill_chunk"]) // serving["kv_block"]) + 2)
     cache = chip(jax.eval_shape(lambda: decode.init_paged_cache(
         cfg, slots, serving["cache_len"], _pool_blocks(config),
-        block=serving["kv_block"], quantize=int8)))
+        block=serving["kv_block"], quantize=int8, **extra)))
 
     def step(params, tokens, cache, n_tokens):
         stats = {}
@@ -737,6 +742,86 @@ def test_hybrid_step_compiles_and_keeps_the_pools_in_place(tq, packed):
     assert tick.temp_bytes < 2 * kv_layer, tick.temp_bytes
 
 
+# -- layers whose block counts differ: two pools under two tables (ISSUE 38) -
+#
+# ``k-exaone-236b-a23b``: two full-attention layers' K/V pools ``(2, N, 8, 64,
+# 128)`` under the engine's table and six sliding-window layers' ``(6, Nw, 8,
+# 64, 128)`` under a second one, both carried whole through five runs of
+# layers. The program's own parameter count at the published widths is the
+# configuration file's arithmetic; the compile for the chip copies neither
+# pool, and every window layer's attention is the kernel named
+# ``window_decode_paged``, at Tq 1 and for a chunk's 256 rows alike.
+
+
+@pytest.mark.parametrize("tq,packed", [(1, False), (256, True), (16, True)],
+                         ids=["tq1", "packed256", "packed16"])
+def test_window_step_compiles_and_copies_neither_pool(tq, packed):
+    if _chip() is None:
+        pytest.skip("the v5e:2x2 topology cannot be described here")
+    from tree_attention_tpu.models import decode
+    from tree_attention_tpu.models.transformer import init_params
+
+    name = "k-exaone-236b-a23b"
+    c, cfg = _model(name)
+    slots, blk = c["serving"]["slots"], c["serving"]["kv_block"]
+    blocks = _pool_blocks(name)
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    assert sum(math.prod(a.shape) for a in jax.tree.leaves(params)) \
+        == pytest.approx(3.8655e9, rel=0.0002)
+    wblocks = slots * 8
+    cache = jax.eval_shape(lambda: decode.init_paged_cache(
+        cfg, slots, c["serving"]["cache_len"], blocks, block=blk,
+        window_blocks=wblocks))
+    assert cache.k.shape == (2, blocks, 8, blk, 128)
+    assert cache.wk.shape == (6, wblocks, 8, blk, 128)
+    assert cache.wtable.shape == cache.table.shape == (slots, 144)
+    tick = _tick_program(name, tq, packed=packed)
+    text = tick.text
+    kernels = pallas_kernels(text)
+    assert "window_decode_paged" in kernels, kernels
+    assert any(k.startswith("flash_decode_paged") for k in kernels), kernels
+    assert "moe_grouped_matmul" in kernels, kernels
+    if packed:
+        padding = _padding_arrays(text, slots, tq, cfg.vocab_size,
+                                  cfg.d_model)
+        assert not padding, padding
+    block_elems = 8 * blk * 128
+    full_layer, win_layer = blocks * block_elems, wblocks * block_elems
+    # What the Q-tiled kernel gathers for a chunk of 128 rows or more on a
+    # FULL layer: one member's logical view, far under a layer of the pool.
+    view = (c["serving"]["cache_len"] // blk) * block_elems
+    assert view < win_layer < full_layer
+    experts = cfg.moe.held * cfg.d_model * cfg.moe.width
+    moved, writes = [], []
+    for iname, result, opcode, inner in _materialised(text):
+        if opcode in _MOVES_NOTHING:
+            continue
+        sizes = [math.prod(int(d) for d in dims.split(","))
+                 for dims in re.findall(r"\bbf16\[([\d,]+)\]", result)
+                 if dims.endswith(f",8,{blk},128")]
+        of_experts = [math.prod(int(d) for d in dims.split(","))
+                      for dims in re.findall(r"\bbf16\[([\d,]+)\]", result)
+                      if dims.endswith((f"{cfg.d_model},{cfg.moe.width}",
+                                        f"{cfg.moe.width},{cfg.d_model}"))]
+        if max(sizes, default=0) < win_layer \
+                and max(of_experts, default=0) < experts:
+            continue
+        if opcode == "scatter" or " scatter(" in inner:
+            writes.append(iname)     # the pools' writes, in place (below)
+        else:
+            moved.append((iname, opcode, result))
+    # No copy of either kind's K/V pools (whole or a layer of one), no
+    # slice of a layer's experts out of their stack.
+    assert not moved, moved
+    # K's and V's, for every group: once in each of the 2 full layers (runs
+    # of one) and once in each of the 3 runs of window layers.
+    groups = 2 if packed else 1
+    assert len(writes) == groups * 2 * (2 + 3), writes
+    assert tick.alias_bytes >= 2 * 2 * (2 * full_layer + 6 * win_layer), \
+        tick.alias_bytes
+    assert tick.temp_bytes < full_layer * 2, tick.temp_bytes
+
+
 # -- the attention input projections: read where they lie (ISSUE 34) --------
 #
 # The compiler multiplies by ``wq`` / ``wk`` / ``wv`` and a latent layer's
@@ -748,7 +833,8 @@ def test_hybrid_step_compiles_and_keeps_the_pools_in_place(tq, packed):
 # first). The engine serves from ``served_layout``'s form, and the programs
 # compiled from it must hold neither.
 
-ALL_CONFIGS = STEP_CONFIGS + LATENT_CONFIGS + ("lfm2-8b-a1b",)
+ALL_CONFIGS = STEP_CONFIGS + LATENT_CONFIGS + ("lfm2-8b-a1b",
+                                               "k-exaone-236b-a23b")
 # Tq 1 and the packed programs at both ends of the chunk buckets.
 TICK_PROGRAMS = {"tq1": (1, False), "packed16": (16, True),
                  "packed256": (256, True)}
@@ -885,6 +971,11 @@ def test_tick_programs_keep_the_scopes(config, program):
             scopes.ATTN_DECODE, scopes.ATTN_CHUNK)
     else:
         assert kernels["flash_decode_paged"] == scopes.ATTN_DECODE
+    if cfg.window_layers:
+        # A window layer's calls: the decode group's, and the chunk
+        # group's at every Tq (the last row wins in this dict).
+        assert kernels["window_decode_paged"] in (
+            scopes.ATTN_DECODE, scopes.ATTN_CHUNK)
     if cfg.moe is not None:
         assert kernels["moe_grouped_matmul"] == scopes.EXPERTS
 
@@ -917,6 +1008,11 @@ def test_tick_programs_build_the_work_lists_outside_the_layer_loops(
     # chunk group's where the paged kernel serves it.
     grids = _dynamic_grids(text, kernel)
     assert grids and all(grids), grids
+    if _model(config)[1].window_layers:
+        # Two plans a tick, one a kind: the window layers' launches run on
+        # their own list's bound, the chunk group's at every Tq.
+        wgrids = _dynamic_grids(text, "window_decode_paged")
+        assert len(wgrids) >= (2 if packed else 1) and all(wgrids), wgrids
     entry = re.search(r"^ENTRY (%[\w.\-]+)", text, re.M).group(1)[1:]
     instrs = list(scopes.instructions(text))
     plans = [i for i in instrs if f"/{PLAN_SCOPE}/" in f"/{i.scope}/"]
@@ -926,7 +1022,8 @@ def test_tick_programs_build_the_work_lists_outside_the_layer_loops(
     # The computations that launch the kernel from inside a loop: the layer
     # loops' bodies (the hybrid's attention layers are runs of one, no loop).
     bodies = {i.computation for i in instrs
-              if i.opcode == "custom-call" and i.op.startswith(kernel)
+              if i.opcode == "custom-call"
+              and i.op.startswith((kernel, "window_decode_paged"))
               and i.computation != entry}
     if config != "lfm2-8b-a1b":
         assert bodies

@@ -140,6 +140,23 @@ def test_phase_hybrid_serves_a_hit_and_a_fork_against_the_reference():
     assert d["kv_block_fixed_bytes"] == 5 * 2 * 64 * 4
 
 
+def test_phase_window_serves_a_hit_a_fork_and_a_reused_slot():
+    """The window phase at ``tests/test_window_moe.py``'s small preset:
+    window 8, blocks of 4, float32 (a served token lies at the reference's
+    best to 1e-4); the cold request outgrows three windows and three
+    blocks, its slot never holds more than the bound."""
+    from tests.test_window_moe import SMALL
+
+    d = smoke.phase_window(TINY, dict(SMALL), device="cpu", block=4,
+                           chunk=8, tol_gap=1e-4)
+    assert d["prefix_hit_tokens"] == 12 and d["forks"] == 1
+    assert d["tokens_compared"] == 20 + 4 * 20 and d["gap_max"] <= 1e-4
+    assert d["layers"].count("window") == 3
+    assert d["window_blocks_peak_slot"] <= d["window_blocks_bound"] == 5
+    assert d["window_blocks_freed"] >= 4
+    assert d["window_token_bytes"] == 3 * d["kv_token_bytes"]
+
+
 @pytest.mark.slow
 def test_phase_train_and_programs():
     assert smoke.phase_train(TINY)["losses"][-1] < 6.3

@@ -211,8 +211,10 @@ def test_experts_under_a_key_the_reader_does_not_know_are_refused():
 
 @pytest.mark.parametrize("change, named", [
     ({"conv_bias": True}, "bias"),
-    ({"layer_types": ["conv"] * 6 + ["sliding_attention"]},
-     "sliding_attention"),
+    ({"layer_types": ["conv"] * 6 + ["linear_attention"]},
+     "linear_attention"),
+    ({"layer_types": ["conv"] * 6 + ["sliding_attention"],
+      "sliding_window": 8}, "beside conv layers"),
     ({"layer_types": ["conv"] * 6}, "layer_types names 6 layers"),
     ({"conv_L_cache": 4}, "4 taps"),
     ({"block": {"router_scoring": "tanh"}}, "tanh"),

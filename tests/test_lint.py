@@ -1822,14 +1822,15 @@ class TestReintroduction:
         eng = tmp_path / "tree_attention_tpu" / "serving" / "engine.py"
         text = eng.read_text()
         needle = (
-            "        if not self._pool.reserve(needed + fam_extra):\n"
+            "        if not ok:\n"
             "            if nodes:\n"
+            "                self._prefix.unpin_window(win_nodes)\n"
             "                self._prefix.release(nodes)\n"
             "            return None\n"
         )
         assert needle in text, "the reserve idiom moved; update this test"
         eng.write_text(text.replace(needle, (
-            "        if not self._pool.reserve(needed + fam_extra):\n"
+            "        if not ok:\n"
             "            return None\n"
         ), 1))
         rc = lint_main(["--root", root, "--rules", "ledger-leak",

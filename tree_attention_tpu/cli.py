@@ -551,8 +551,8 @@ def load_model_config(path: str) -> dict:
     return model
 
 
-# What a model served from the latent pool or the hybrid pool
-# (``TransformerConfig.cache_kind``) is not served with: the flag's test and
+# What a model served from the latent pool, the hybrid pool or the window
+# pools (``TransformerConfig.cache_kind``) is not served with: the flag's test and
 # the mechanism's name, refused at build and never served wrong.
 _POOL_REFUSALS = (
     (lambda c: c.kv_quant != "none", "--kv-quant (int8 rows)"),
@@ -562,7 +562,8 @@ _POOL_REFUSALS = (
      "--host-blocks (the host tier)"),
     (lambda c: c.speculate,
      "--speculate (the latent kernel takes no tree_mask; a conv layer's "
-     "tail cannot roll a rejected draft back)"),
+     "tail cannot roll a rejected draft back; a window layer's freed "
+     "blocks cannot come back)"),
     (lambda c: c.serve_disagg,
      "--serve-disagg (the handoff of that pool's arrays)"),
     (lambda c: c.admission != "chunked", "--admission whole"),

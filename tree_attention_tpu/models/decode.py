@@ -243,10 +243,62 @@ class PagedHybridCache:
         return self.k.shape[1]
 
 
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class PagedWindowCache:
+    """The paged cache of a model whose attention layers are of two kinds:
+    layers that see their whole context, whose K/V rows lie in the pools
+    ``k`` / ``v`` ``(full layers, N, Hkv, block, D)`` under ``table`` as
+    :class:`PagedKVCache`'s do, and sliding-window layers (a row at ``t``
+    sees ``(t - window, t]``), whose rows lie in pools of their own, ``wk``
+    / ``wv`` ``(window layers, Nw, Hkv, block, D)``, under a SECOND table
+    ``wtable`` of the same width and block size.
+
+    ``wtable[i, j]`` names the window-pool block that holds slot ``i``'s
+    tokens ``[j * block, (j + 1) * block)`` for every window layer, as long
+    as some row still to be computed for the slot can see one of them;
+    behind the slot's window the entry names no block of the slot's (it is
+    0, as an unwritten entry is) and the block has gone back to the window
+    pool's allocator (``serving/block_pool.py`` ``WindowBlocks``). So what
+    a slot holds for a window layer does not grow with its length: at most
+    ``ceil((window + chunk) / block) + 1`` blocks while a chunk is written,
+    ``ceil(window / block) + 1`` in decode. The kernels never read behind
+    the window: a window layer's work list starts at the step that holds
+    the lowest visible position (``ops/pallas_decode.py`` ``paged_plan``
+    with ``window``) and the mask hides the rest of that step. One
+    ``length`` serves both tables: a token is a row in every layer."""
+
+    k: jax.Array        # (full layers, N, Hkv, block, D) pool
+    v: jax.Array        # (full layers, N, Hkv, block, D) pool
+    wk: jax.Array       # (window layers, Nw, Hkv, block, D) pool
+    wv: jax.Array       # (window layers, Nw, Hkv, block, D) pool
+    table: jax.Array    # (B, NB) int32: the full layers' blocks
+    wtable: jax.Array   # (B, NB) int32: the window layers' blocks
+    length: jax.Array   # (B,) int32: tokens written so far, per slot
+
+    @property
+    def capacity(self) -> int:
+        return self.table.shape[1] * self.k.shape[3]
+
+    @property
+    def block(self) -> int:
+        return self.k.shape[3]
+
+    @property
+    def blocks(self) -> int:
+        return self.k.shape[1]
+
+    @property
+    def window_blocks(self) -> int:
+        return self.wk.shape[1]
+
+
 def cache_pools(cache) -> Dict[str, jax.Array]:
     """A paged cache's block pools by field name, every one ``(L, N, ...)``
     with the block on axis 1: what a block copy, a leak check or a byte
-    count has to visit, whatever the model caches."""
+    count has to visit, whatever the model caches. (A
+    :class:`PagedWindowCache`'s window pools are indexed by another
+    allocator's ids: :func:`window_pools`.)"""
     if isinstance(cache, PagedLatentCache):
         return {"kv": cache.kv}
     pools = {"k": cache.k, "v": cache.v}
@@ -266,6 +318,15 @@ def cache_token_bytes(cache) -> int:
                    * cache.kv.dtype.itemsize)
     k = cache.k  # (L, B|N, Hkv, T|block, D)
     return int(2 * k.shape[0] * k.shape[2] * k.shape[4] * k.dtype.itemsize)
+
+
+def window_pools(cache) -> Dict[str, jax.Array]:
+    """The pools under a cache's SECOND table (``wtable``), by field name:
+    a :class:`PagedWindowCache`'s window layers' K and V; none for every
+    other cache."""
+    if isinstance(cache, PagedWindowCache):
+        return {"wk": cache.wk, "wv": cache.wv}
+    return {}
 
 
 def cache_block_fixed_bytes(cache) -> int:
@@ -481,7 +542,9 @@ def scatter_kv_blocks(
     return tuple(out)
 
 
-def copy_pool_block(cache, src: jax.Array, dst: jax.Array):
+def copy_pool_block(cache, src: jax.Array, dst: jax.Array,
+                    wsrc: Optional[jax.Array] = None,
+                    wdst: Optional[jax.Array] = None):
     """The copy-on-write fork's ONE device copy (ISSUE 15): duplicate
     pool block ``src`` into freshly allocated block ``dst`` — K and V
     rows, plus the per-block scale scalars under int8, so the copy is
@@ -490,13 +553,23 @@ def copy_pool_block(cache, src: jax.Array, dst: jax.Array):
     block a forked branch will append into needs its own copy, and this
     is that copy. ``src == dst`` degenerates to an identical-bytes
     self-write (the engine's no-partial-tail arc reuses one compiled
-    program that way). Works on every paged cache (:func:`cache_pools`)."""
+    program that way). Works on every paged cache (:func:`cache_pools`);
+    ``wsrc`` / ``wdst`` name the same copy in the pools under a second
+    table (:func:`window_pools`), whose ids are another allocator's."""
     src = jnp.asarray(src, jnp.int32)
     dst = jnp.asarray(dst, jnp.int32)
-    return dataclasses.replace(cache, **{
+    new = {
         name: pool.at[:, dst].set(pool[:, src])
         for name, pool in cache_pools(cache).items()
-    })
+    }
+    if wsrc is not None:
+        wsrc = jnp.asarray(wsrc, jnp.int32)
+        wdst = jnp.asarray(wdst, jnp.int32)
+        new.update({
+            name: pool.at[:, wdst].set(pool[:, wsrc])
+            for name, pool in window_pools(cache).items()
+        })
+    return dataclasses.replace(cache, **new)
 
 
 def insert_dequant_prefix(
@@ -597,8 +670,9 @@ def init_paged_cache(
     quantize: bool = False,
     kv_shard: str = "replicated",
     seq_axis: str = AXIS_SEQ,
+    window_blocks: Optional[int] = None,
 ) -> Union[PagedKVCache, PagedQuantKVCache, PagedLatentCache,
-           PagedHybridCache]:
+           PagedHybridCache, "PagedWindowCache"]:
     """Allocate a paged cache: one ``blocks``-block pool + empty tables,
     of the kind the model caches (``cfg.cache_kind``).
 
@@ -626,6 +700,10 @@ def init_paged_cache(
     ``quantize`` allocates int8 pools with per-slot unit scales — the
     same empty-cache fallback :func:`quantize_cache` produces, so a
     paged and a contiguous int8 server start bit-identical.
+
+    ``window_blocks``: the capacity of the window layers' pool of a model
+    with sliding-window layers (:class:`PagedWindowCache`), which no other
+    model has.
     """
     if block < 1 or block & (block - 1):
         raise ValueError(f"kv block must be a power of two, got {block}")
@@ -664,6 +742,34 @@ def init_paged_cache(
             length=jnp.zeros((batch_size,), jnp.int32),
         )
     shape = (cfg.cache_layers, blocks, cfg.n_kv_heads, block, cfg.d_head)
+    if cfg.cache_kind == "window":
+        if quantize:
+            raise ValueError(
+                "int8 rows under two tables are not built: the window "
+                "pool is served exact")
+        if seq_sharded:
+            raise ValueError(
+                "a sequence-sharded window pool (kv_shard='seq') is not "
+                "built: the tree merge has no lower edge")
+        if not window_blocks or window_blocks < 1:
+            raise ValueError(
+                f"a model with sliding-window layers needs the window "
+                f"pool's capacity (window_blocks), got {window_blocks}")
+        shapes = (shape, shape) + 2 * ((
+            cfg.window_layers, window_blocks, cfg.n_kv_heads, block,
+            cfg.d_head),)
+        k, v, wk, wv = (
+            jax.jit(lambda: tuple(jnp.zeros(s, cfg.dtype) for s in shapes),
+                    out_shardings=NamedSharding(mesh, P()))()
+            if mesh is not None
+            else tuple(jnp.zeros(s, cfg.dtype) for s in shapes)
+        )
+        return PagedWindowCache(
+            k=k, v=v, wk=wk, wv=wv,
+            table=jnp.zeros((batch_size, nb), jnp.int32),
+            wtable=jnp.zeros((batch_size, nb), jnp.int32),
+            length=jnp.zeros((batch_size,), jnp.int32),
+        )
     if cfg.cache_kind == "hybrid":
         if quantize:
             raise ValueError(
@@ -990,6 +1096,8 @@ class _RowGroup(NamedTuple):
     tree_mask: Optional[jax.Array]
     chunk: bool = False   # a packed step's chunk group (``scopes.ATTN_CHUNK``)
     plan: Any = None      # the paged kernels' work list (:func:`_plan_groups`)
+    wtable: Optional[jax.Array] = None  # the window layers' table
+    wplan: Any = None     # ... and their work list (a window's steps)
 
     @property
     def n_valid(self) -> jax.Array:
@@ -1023,8 +1131,15 @@ def _plan_groups(groups: Tuple[_RowGroup, ...], cache: Any,
     it shifted to the layer's blocks, as the table is; built inside the
     call it would be built again in every pass of the layer loop. A group the
     kernels will not serve (a chunk of 128 rows or more on the Q-tiled
-    kernel) leaves its list unused, and the compiler drops it."""
+    kernel) leaves its list unused, and the compiler drops it. A cache
+    with a second table (:class:`PagedWindowCache`) gets a second list a
+    group, the window layers': two plans a tick, one a kind."""
     from tree_attention_tpu.ops.pallas_decode import decode_plan, mla_plan
+
+    def barrier(plan):
+        # Behind a barrier: the compiler otherwise clones the cheapest
+        # of a short list's operations back into the loop's body.
+        return type(plan)(*lax.optimization_barrier(tuple(plan)))
 
     planned = []
     for g in groups:
@@ -1035,29 +1150,38 @@ def _plan_groups(groups: Tuple[_RowGroup, ...], cache: Any,
             else:
                 plan = decode_plan(
                     cfg.n_heads, g.tq, cache.k, g.table, g.start)
-            # Behind a barrier: the compiler otherwise clones the cheapest
-            # of a short list's operations back into the loop's body.
-            plan = type(plan)(*lax.optimization_barrier(tuple(plan)))
-        planned.append(g._replace(plan=plan))
+            g = g._replace(plan=barrier(plan))
+            if g.wtable is not None:
+                g = g._replace(wplan=barrier(decode_plan(
+                    cfg.n_heads, g.tq, cache.wk, g.wtable, g.start,
+                    window=cfg.window)))
+        planned.append(g)
     return tuple(planned)
 
 
 def paged_step_tokens(cache: Any, cfg: TransformerConfig,
-                      tq: int) -> Optional[int]:
+                      tq: int, window: bool = False) -> Optional[int]:
     """Tokens one grid step of the paged decode kernel takes that serves a
     group of ``tq`` rows a slot against ``cache``; None where no paged
     kernel serves such a group (a contiguous cache; an exact chunk of 128
     rows or more, the Q-tiled kernel's over a gathered view). What the
-    serve loop counts a tick's work list with (``kv_steps_run``)."""
+    serve loop counts a tick's work list with (``kv_steps_run``).
+    ``window``: the step of a window layer's call against the window pool
+    (the paged kernel at every ``tq``; None for a cache without one)."""
     from tree_attention_tpu.ops.pallas_decode import (
         decode_step_entries, mla_step_entries,
     )
     from tree_attention_tpu.ops.tuning import tpu_kernel_for
 
+    if window:
+        if not isinstance(cache, PagedWindowCache):
+            return None
+        return cache.block * decode_step_entries(
+            cfg.n_heads, tq, cache.wk, cache.wtable.shape[1])
     if isinstance(cache, PagedLatentCache):
         return mla_step_entries(cache.table.shape[1]) * cache.block
-    if not isinstance(
-            cache, (PagedKVCache, PagedQuantKVCache, PagedHybridCache)):
+    if not isinstance(cache, (PagedKVCache, PagedQuantKVCache,
+                              PagedHybridCache, PagedWindowCache)):
         return None
     if not isinstance(cache, PagedQuantKVCache) \
             and tpu_kernel_for(tq) != "pallas_decode":
@@ -1100,6 +1224,12 @@ class _Attend:
     hoist_view: bool
     anchors: Any
     scale: Optional[float] = None   # None: the kernels' own, D^-1/2
+    # What the layer KIND settles, the one body being built with it
+    # (:func:`gqa_mixer`): a sliding-window layer's window (its rows go to,
+    # and are read from, the pools under the groups' second table, through
+    # the window's work list), and whether queries and keys are rotated.
+    window: Optional[int] = None
+    rotary: bool = True
 
     def __call__(self, gi, q, k_new, v_new, k_cache, v_cache, k_s, v_s,
                  views, l, base):
@@ -1114,6 +1244,9 @@ class _Attend:
         anchors, num_splits = self.anchors, self.num_splits
         quant_kernel = self.quant_kernel
         g = groups[gi]
+        if self.window is not None:
+            # A window layer's group: the same rows under the second table.
+            g = g._replace(table=g.wtable, plan=g.wplan)
         B, Tq = g.batch, g.tq
         start, n_valid = g.start, g.n_valid
         with jax.named_scope(scopes.ATTN_CACHE):
@@ -1243,6 +1376,8 @@ class _Attend:
             )
             if self.scale is not None:
                 attn_kw["scale"] = self.scale
+            if self.window is not None:
+                attn_kw["window"] = self.window
             ak, av, ak_s, av_s = k_cache, v_cache, k_s, v_s
             if hoist_view:
                 ak, av = k_view, v_view
@@ -1285,7 +1420,8 @@ def gqa_mixer(attend: _Attend, layer: Params, x: jax.Array,
     cfg, groups = attend.cfg, attend.groups
     with jax.named_scope(scopes.ATTN_IN):
         h = rms_norm(x, layer["ln1"], cfg.norm_eps)
-        q, k_new, v_new = gqa_qkv(layer, h, positions, cfg)
+        q, k_new, v_new = gqa_qkv(
+            layer, h, positions, cfg, rotary=attend.rotary)
         if cfg.kv_pack > 1:
             q, k_new, v_new = _pack_heads(q, k_new, v_new, cfg)
     outs = []
@@ -1341,13 +1477,15 @@ def _unpack_heads(out: jax.Array, cfg: TransformerConfig) -> jax.Array:
 def _check_block_cache(cache: Any, cfg: TransformerConfig) -> None:
     """The model's layers and the cache's kind go together."""
     kind = ("latent" if isinstance(cache, PagedLatentCache)
-            else "hybrid" if isinstance(cache, PagedHybridCache) else "kv")
+            else "hybrid" if isinstance(cache, PagedHybridCache)
+            else "window" if isinstance(cache, PagedWindowCache) else "kv")
     if kind != cfg.cache_kind:
         raise ValueError(
             f"this model caches {cfg.cache_kind!r} state "
             f"(TransformerConfig.cache_kind: a latent pool for latent "
             f"attention, the hybrid pool for conv layers or experts under "
-            f"rotary GQA, K/V buffers for the dense block) and is served "
+            f"rotary GQA, the window pools for sliding-window layers, K/V "
+            f"buffers for the dense block) and is served "
             f"from the cache init_paged_cache builds for it and no other; "
             f"got {type(cache).__name__}"
         )
@@ -1362,6 +1500,8 @@ def _count_step(cache: Any) -> None:
         kind = "paged_latent"
     elif isinstance(cache, PagedHybridCache):
         kind = "paged_hybrid"
+    elif isinstance(cache, PagedWindowCache):
+        kind = "paged_window"
     elif isinstance(cache, (PagedKVCache, PagedQuantKVCache)):
         kind = "paged_quant" if quant else "paged"
     else:
@@ -1528,7 +1668,7 @@ def _step_layers(
         x, pool = _latent_layers(
             params, x, cache, cfg, positions, groups, stats)
         return x, {"kv": pool}
-    if isinstance(cache, PagedHybridCache):
+    if isinstance(cache, (PagedHybridCache, PagedWindowCache)):
         from tree_attention_tpu.models.hybrid import hybrid_layers
 
         attend = _Attend(
@@ -1828,13 +1968,15 @@ def forward_step(
 
     B, Tq = tokens.shape
     start = cache.length  # (B,) per-slot offsets
-    own_pool = isinstance(cache, (PagedLatentCache, PagedHybridCache))
+    own_pool = isinstance(
+        cache, (PagedLatentCache, PagedHybridCache, PagedWindowCache))
     _check_block_cache(cache, cfg)
     if own_pool and (tree_mask is not None or kv_shard == "seq"):
         raise ValueError(
             f"a {cfg.cache_kind} pool takes no tree_mask (the latent "
-            f"kernel has none; a conv tail cannot roll a draft back) and "
-            f"is not sequence-sharded"
+            f"kernel has none; a conv tail cannot roll a draft back; a "
+            f"window's freed blocks cannot come back) and is not "
+            f"sequence-sharded"
         )
     paged = own_pool or isinstance(cache, (PagedKVCache, PagedQuantKVCache))
     if not paged and n_tokens is not None and Tq > cache.capacity:
@@ -1895,6 +2037,7 @@ def forward_step(
     group = _RowGroup(
         lo=None, batch=B, tq=Tq, start=start, n=n_tokens,
         table=cache.table if paged else None, tree_mask=tree_mask,
+        wtable=getattr(cache, "wtable", None),
     )
     x, pools = _step_layers(
         params, x, positions, (group,), cache, cfg, mesh=mesh, axes=axes,
@@ -1959,7 +2102,8 @@ def forward_packed_step(
     axes = prune_axes(
         mesh, {"data": data_axis, "seq": seq_axis, "model": model_axis}
     )
-    own_pool = isinstance(cache, (PagedLatentCache, PagedHybridCache))
+    own_pool = isinstance(
+        cache, (PagedLatentCache, PagedHybridCache, PagedWindowCache))
     if not (own_pool
             or isinstance(cache, (PagedKVCache, PagedQuantKVCache))):
         raise ValueError(
@@ -1970,18 +2114,20 @@ def forward_packed_step(
     if kv_shard not in ("replicated", "seq") or (
             own_pool and kv_shard == "seq"):
         raise ValueError(
-            f"kv_shard must be 'replicated' or 'seq' (a latent or hybrid "
-            f"pool: 'replicated'), got {kv_shard!r}"
+            f"kv_shard must be 'replicated' or 'seq' (a latent, hybrid or "
+            f"window pool: 'replicated'), got {kv_shard!r}"
         )
     C, Tq = chunk_tokens.shape
     S = cache.table.shape[0]
     length = cache.length
     c_start = length[chunk_slot]
+    wtable = getattr(cache, "wtable", None)
     groups = (
         _RowGroup(lo=0, batch=C, tq=Tq, start=c_start, n=chunk_n,
-                  table=cache.table[chunk_slot], tree_mask=None, chunk=True),
+                  table=cache.table[chunk_slot], tree_mask=None, chunk=True,
+                  wtable=None if wtable is None else wtable[chunk_slot]),
         _RowGroup(lo=C * Tq, batch=S, tq=1, start=length, n=n_tokens,
-                  table=cache.table, tree_mask=None),
+                  table=cache.table, tree_mask=None, wtable=wtable),
     )
     c_pos = c_start[:, None] + jnp.arange(Tq, dtype=jnp.int32)
     positions = jnp.concatenate([c_pos.reshape(-1), length])[None]
@@ -2346,6 +2492,7 @@ def decode_attention(
     kv_shard: str = "replicated",
     scale: Optional[float] = None,
     step_plan: Any = None,
+    window: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Op-level decode entry: split-KV on one device, tree merge on a mesh.
 
@@ -2367,9 +2514,15 @@ def decode_attention(
     ``(B, NB)`` table row (see :class:`PagedKVCache`); the pool is
     replicated under a mesh, so the tree merge never applies. ``step_plan``
     is the paged kernels' work list where the caller built it for several
-    calls (a step's layers: :func:`_plan_groups`).
+    calls (a step's layers: :func:`_plan_groups`). ``window``: a
+    sliding-window layer's call (the exact paged path only).
     """
     quant = k_scale is not None
+    if window is not None and (quant or block_table is None
+                               or kv_shard == "seq"):
+        raise ValueError(
+            "a sliding window is taken by the exact replicated paged path "
+            "only")
     if quant and v_scale is None or (not quant and v_scale is not None):
         raise ValueError("pass both k_scale and v_scale, or neither")
     if scale is not None and (quant or kv_shard == "seq" or (
@@ -2424,6 +2577,7 @@ def decode_attention(
             q, k, v, q_position=q_position, num_splits=num_splits,
             block_size=block_size, block_table=block_table,
             tree_mask=tree_mask, scale=scale, step_plan=step_plan,
+            window=window,
         )
     if q_position is None:
         q_position = k.shape[2] - q.shape[2]

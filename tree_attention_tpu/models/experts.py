@@ -71,7 +71,8 @@ def route(scores: jax.Array, ex: ExpertLayer,
 
 def _weigh(w: jax.Array, ex: ExpertLayer) -> jax.Array:
     if ex.renorm and ex.per_token > 1:
-        return w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        return w * ex.scale if ex.renorm_scaled else w
     return w * ex.scale
 
 

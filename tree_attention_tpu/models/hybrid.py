@@ -3,8 +3,11 @@ its state as a two-row tail of each pool block, and the layer loop over runs
 of layers of one kind.
 
 A layer is a mixer and a feed-forward half. The mixer is rotary-GQA attention
-(:func:`~.decode.gqa_mixer`, the dense block's own) or a gated short
-convolution (:func:`conv_mixer`); the feed-forward half the dense SwiGLU or the
+(:func:`~.decode.gqa_mixer`, the dense block's own: over the whole context,
+or, a ``window`` layer, over the last ``cfg.window`` positions, its rows in a
+pool and under a table of their own, :class:`~.decode.PagedWindowCache`) or a
+gated short convolution (:func:`conv_mixer`); the feed-forward half the dense
+SwiGLU or the
 routed-expert layer (:func:`~.experts.expert_layer`). ``cfg.layer_types`` and
 ``cfg.moe.first_dense`` say which layer is what; :func:`hybrid_layers` cuts the
 depth into runs of consecutive layers of one (mixer, feed-forward) kind and
@@ -12,7 +15,8 @@ scans each run under one body, with no branch on a layer's kind in the traced
 program. Every per-kind stack rides whole (the K/V pools, the tail pool, the
 experts' one stack, each kind's weights on a leading axis of ITS layers) and a
 layer's part is reached by offset: attention layer ``a`` at ``table + a·N``,
-conv layer ``c`` at ``table + c·N``, expert layer ``e`` at ``e·held``.
+window layer ``w`` at ``wtable + w·Nw``, conv layer ``c`` at ``table + c·N``,
+expert layer ``e`` at ``e·held``.
 
 The conv mixer, for the normed residual ``h``::
 
@@ -28,15 +32,18 @@ Its state at position ``t`` is ``z_{t-1}``, ``z_{t-2}``
 
 from __future__ import annotations
 
+import dataclasses
 import functools
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+
 from tree_attention_tpu.models.decode import (
     PagedHybridCache,
+    PagedWindowCache,
     _Attend,
     _RowGroup,
     _join_rows,
@@ -141,7 +148,7 @@ def layer_runs(cfg: TransformerConfig) -> List[Tuple[str, str, int, int, int]]:
     kind)``, the two offsets counted among the layers of that kind."""
     types = cfg.layer_types or ("attention",) * cfg.n_layers
     runs: List[list] = []
-    seen = {"attention": 0, "conv": 0, "dense": 0, "expert": 0}
+    seen = {"attention": 0, "window": 0, "conv": 0, "dense": 0, "expert": 0}
     for l, mixer in enumerate(types):
         ffn = "dense" if l < cfg.n_dense_layers else "expert"
         if runs and runs[-1][:2] == [mixer, ffn]:
@@ -157,7 +164,7 @@ def hybrid_layers(
     params: Params,
     x: jax.Array,
     positions: jax.Array,
-    cache: PagedHybridCache,
+    cache: Union[PagedHybridCache, PagedWindowCache],
     cfg: TransformerConfig,
     attend: _Attend,
     stats: Optional[Dict[str, Any]],
@@ -169,7 +176,8 @@ def hybrid_layers(
     tail pool and the residual the carry of them all. ``params`` holds a
     stack a kind on a leading axis of that kind's layers: ``attn``
     (``ln1``, ``wq``, ``wk``, ``wv``, ``wo``, with QK-norm ``q_ln`` /
-    ``k_ln``), ``conv`` (:func:`conv_mixer`'s leaves), ``dense`` (``ln2``,
+    ``k_ln``), ``wattn`` (the window layers: the same leaves), ``conv``
+    (:func:`conv_mixer`'s leaves), ``dense`` (``ln2``,
     ``w1``, ``w3``, ``w2``) and ``layers`` (the expert layers: ``ln2``,
     ``router``, ``router_bias``, ``we1`` / ``we3`` / ``we2`` and the shared
     experts' ``ws*``). Returns the residual and the pools by field name."""
@@ -179,6 +187,14 @@ def hybrid_layers(
 
     groups = attend.groups
     N, block = cache.blocks, cache.block
+    # The one rotary-GQA body, built a kind: a full layer's, and a window
+    # layer's (its window, the second table's pools, its own rotary rule).
+    attend = dataclasses.replace(attend, rotary=cfg.rotates("attention"))
+    attend_w, Nw = None, 0
+    if cfg.window_layers:
+        attend_w = dataclasses.replace(
+            attend, window=cfg.window, rotary=cfg.rotates("window"))
+        Nw = cache.window_blocks
     valid = groups[0].valid
     if groups[0].lo is not None:
         valid = jnp.concatenate([g.valid.reshape(-1) for g in groups])[None]
@@ -201,13 +217,17 @@ def hybrid_layers(
 
     def body_of(mixer, ffn, m0, f0):
         def body(carry, i):
-            x, k, v, tail = carry
+            x, k, v, tail, wk, wv = carry
             mi, fi = m0 + i, f0 + i
             wrote = jnp.int32(0)
             if mixer == "attention":
                 x, k, v, _, _ = gqa_mixer(
                     attend, of(params["attn"], mi), x, positions, k, v,
                     None, None, None, mi, mi * N)
+            elif mixer == "window":
+                x, wk, wv, _, _ = gqa_mixer(
+                    attend_w, of(params["wattn"], mi), x, positions, wk, wv,
+                    None, None, None, mi, mi * Nw)
             else:
                 with jax.named_scope(scopes.CONV):
                     x, tail, wrote = conv_mixer(
@@ -218,7 +238,7 @@ def hybrid_layers(
                     layer = of(params["dense"], fi)
                     x = x + _mlp_block(
                         layer, rms_norm(x, layer["ln2"], cfg.norm_eps))
-                return (x, k, v, tail), (None, wrote)
+                return (x, k, v, tail, wk, wv), (None, wrote)
             with jax.named_scope(scopes.ROUTE):
                 layer = of(routers, fi)
                 h32 = rms_norm(
@@ -229,12 +249,14 @@ def hybrid_layers(
                 experts=experts, first=fi * cfg.moe.held,
             )
             with jax.named_scope(scopes.ROUTE):
-                return (x + y, k, v, tail), (
+                return (x + y, k, v, tail, wk, wv), (
                     held_counts(chosen, valid, cfg.moe), wrote)
 
         return body
 
-    carry = (x, cache.k, cache.v, cache.tail)
+    # A pool the cache has not is None: an empty part of the carry.
+    carry = (x, cache.k, cache.v, getattr(cache, "tail", None),
+             getattr(cache, "wk", None), getattr(cache, "wv", None))
     counts, wrote = [], jnp.int32(0)
     for mixer, ffn, n, m0, f0 in layer_runs(cfg):
         body = body_of(mixer, ffn, m0, f0)
@@ -252,7 +274,9 @@ def hybrid_layers(
             stats["expert_rows"] = jnp.concatenate(counts, axis=0)
         if cfg.conv_layers:
             stats["tail_blocks"] = wrote
-    x, k, v, tail = carry
+    x, k, v, tail, wk, wv = carry
+    if cfg.window_layers:
+        return x, {"k": k, "v": v, "wk": wk, "wv": wv}
     return x, {"k": k, "v": v, "tail": tail}
 
 
@@ -348,6 +372,8 @@ def init_hybrid_params(key: jax.Array, cfg: TransformerConfig) -> Params:
         n_dense = cfg.n_dense_layers
         for name, make_one, n, k in (
                 ("attn", attn, cfg.cache_layers, ks[2]),
+                ("wattn", attn, cfg.window_layers,
+                 jax.random.fold_in(ks[2], 1)),
                 ("conv", conv, cfg.conv_layers, ks[3]),
                 ("dense", dense, n_dense, ks[4]),
                 ("layers", expert, cfg.n_layers - n_dense, ks[5])):

@@ -105,6 +105,9 @@ class ExpertLayer:
     top_groups: int = 1
     scale: float = 1.0     # on the chosen scores when they are not renormed
     renorm: bool = False
+    # The scale multiplies the renormed weights too (the later routers'
+    # rule: renorm, then scale), not only scores that were not renormed.
+    renorm_scaled: bool = False
     first_dense: int = 0   # leading layers that keep the dense SwiGLU
     n_zero: int = 0
     # The top ``per_token`` are taken of ``scores + bias`` (a per-expert
@@ -148,6 +151,16 @@ class ExpertLayer:
         return self.n_experts - self.n_zero
 
 
+# A layer's mixer kind, by the names published configurations give it in
+# ``layer_types``: the one table :func:`model_from_config` reads kinds from
+# and lists in its refusals.
+PUBLISHED_MIXERS = {
+    "full_attention": "attention", "attention": "attention",
+    "sliding_attention": "window", "conv": "conv",
+}
+MIXER_KINDS = frozenset(PUBLISHED_MIXERS.values())
+
+
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     """Static architecture hyperparameters (hashable: usable as a jit static).
@@ -156,8 +169,13 @@ class TransformerConfig:
     which. The mixer: ``mla`` set means latent attention in every layer;
     else ``layer_types`` names each layer's (``"attention"``: rotary GQA,
     with an RMSNorm over each query and key head where ``qk_norm``;
-    ``"conv"``: a gated short convolution of ``conv_taps`` taps), None
-    meaning rotary GQA throughout. The feed-forward half: ``moe`` set means
+    ``"window"``: the same attention over the last ``window`` positions
+    only, a row at ``t`` sees ``(t - window, t]``; ``"conv"``: a gated
+    short convolution of ``conv_taps`` taps), None meaning rotary GQA
+    throughout. ``rotary`` names the attention kinds whose queries and
+    keys take the rotary embedding (both by default; a model whose full
+    layers carry no positional term names ``("window",)``). The
+    feed-forward half: ``moe`` set means
     every layer after its ``first_dense`` is a routed-expert layer (else
     the dense SwiGLU). A latent layer may be ``sublayers`` pairs of one
     attention and one dense FFN; with more than one, the routed experts are
@@ -167,7 +185,9 @@ class TransformerConfig:
     What is cached follows (``cache_kind``): K/V rows for every attention
     (``cache_layers`` of them, not ``n_layers``), one latent row where
     ``mla`` is set, and for every conv layer (``conv_layers``) the last
-    ``conv_taps - 1`` gated inputs, held as a tail of each pool block.
+    ``conv_taps - 1`` gated inputs, held as a tail of each pool block; a
+    window layer's (``window_layers``) K/V rows live in a pool of their
+    own under a table of their own, a bounded number of blocks a slot.
     """
 
     vocab_size: int = 32768
@@ -196,6 +216,8 @@ class TransformerConfig:
     conv_taps: int = 3
     qk_norm: bool = False
     tied_head: bool = False
+    window: int = 0
+    rotary: Tuple[str, ...] = ("attention", "window")
 
     def __post_init__(self):
         if self.n_heads % self.n_kv_heads:
@@ -206,11 +228,19 @@ class TransformerConfig:
         if self.layer_types is not None:
             kinds = set(self.layer_types)
             if len(self.layer_types) != self.n_layers \
-                    or not kinds <= {"attention", "conv"}:
+                    or not kinds <= set(MIXER_KINDS):
                 raise ValueError(
                     f"layer_types names {len(self.layer_types)} layers of "
-                    f"kinds {sorted(kinds)}: one of 'attention' / 'conv' "
+                    f"kinds {sorted(kinds)}: one of {sorted(MIXER_KINDS)} "
                     f"for each of the {self.n_layers} layers")
+            if ("window" in kinds) != (self.window > 0) or (
+                    "window" in kinds and "conv" in kinds):
+                raise ValueError(
+                    f"window layers {'window' in kinds} with window="
+                    f"{self.window}: a model with sliding-window layers "
+                    f"states their window (> 0), a model without states "
+                    f"none, and window layers beside conv layers are not "
+                    f"built")
             if self.mla is not None or self.sublayers > 1:
                 raise ValueError(
                     "layers of several kinds are built over rotary-GQA "
@@ -221,6 +251,17 @@ class TransformerConfig:
                     f"a short convolution of {self.conv_taps} taps: its "
                     f"state is built as the last 2 gated inputs, a two-row "
                     f"tail of each pool block (3 taps)")
+        elif self.window:
+            raise ValueError(
+                f"window={self.window} without layer_types: which layers "
+                f"are sliding-window layers is said a layer")
+        # A configuration read back from JSON (a checkpoint's sidecar) holds
+        # a list: the dataclass stays hashable, a jit static.
+        object.__setattr__(self, "rotary", tuple(self.rotary))
+        if not set(self.rotary) <= {"attention", "window"}:
+            raise ValueError(
+                f"rotary {self.rotary}: names of attention kinds "
+                f"('attention', 'window')")
         branch = self.moe.branch if self.moe is not None else None
         if (self.sublayers > 1 and branch is None) or (
                 branch is not None and (
@@ -247,19 +288,36 @@ class TransformerConfig:
         return (self.layer_types or ()).count("conv")
 
     @property
+    def window_layers(self) -> int:
+        """Layers whose mixer is sliding-window attention: the window
+        pool's depth."""
+        return (self.layer_types or ()).count("window")
+
+    def rotates(self, kind: str) -> bool:
+        """Whether an attention layer of ``kind`` rotates its queries and
+        keys."""
+        return kind in self.rotary
+
+    @property
     def dense_block(self) -> bool:
         """The Llama-style block this module's ``forward`` computes."""
         return self.mla is None and self.moe is None \
-            and not self.conv_layers
+            and not self.conv_layers and not self.window_layers \
+            and self.rotates("attention")
 
     @property
     def cache_kind(self) -> str:
         """What serving caches: ``"kv"`` (K/V rows, the dense block),
-        ``"latent"`` (one latent row a token) or ``"hybrid"`` (K/V rows
+        ``"latent"`` (one latent row a token), ``"hybrid"`` (K/V rows
         for the attention layers beside a two-row tail a block for the
-        conv layers; also rotary-GQA attention over expert layers)."""
+        conv layers; also rotary-GQA attention over expert layers) or
+        ``"window"`` (two K/V pools under two tables: the full-attention
+        layers' rows a token, the sliding-window layers' a bounded number
+        of blocks a slot)."""
         if self.mla is not None:
             return "latent"
+        if self.window_layers:
+            return "window"
         return "kv" if self.dense_block else "hybrid"
 
     @property
@@ -277,8 +335,11 @@ class TransformerConfig:
 
     @property
     def cache_layers(self) -> int:
-        """The cache's depth: one layer of rows for every attention."""
-        return (self.n_layers - self.conv_layers) * self.sublayers
+        """The cache's depth: one layer of rows for every attention that
+        sees its whole context (a window layer's rows: ``window_layers``
+        deep, in the window pool)."""
+        return (self.n_layers - self.conv_layers
+                - self.window_layers) * self.sublayers
 
     @property
     def n_dense_layers(self) -> int:
@@ -299,8 +360,8 @@ def _key(c: Dict[str, Any], *names: str) -> Any:
 # reads (a family's alias beside the first family's name).
 _EXPERT_KEYS = frozenset({
     "n_routed_experts", "num_experts", "n_shared_experts",
-    "num_experts_per_tok", "expert_ffn_hidden_size", "zero_expert_num",
-    "zero_expert_type", "use_expert_bias",
+    "num_shared_experts", "num_experts_per_tok", "expert_ffn_hidden_size",
+    "zero_expert_num", "zero_expert_type", "use_expert_bias",
 })
 
 
@@ -322,16 +383,25 @@ def model_from_config(config: Dict[str, Any], *, dtype: Any = None,
       ``qk_rope_head_dim``, ``v_head_dim``, ``rope_scaling``;
       ``mla_scale_q_lora`` / ``mla_scale_kv_lora``: the normed latents
       times ``(hidden / rank)^1/2``). Else ``layer_types`` names every
-      layer's: ``full_attention`` / ``attention`` (rotary GQA) or ``conv``
-      (a gated short convolution: ``conv_L_cache`` taps, ``conv_bias``
-      false); without it every layer is rotary GQA.
+      layer's (:data:`PUBLISHED_MIXERS`): ``full_attention`` /
+      ``attention`` (rotary GQA), ``sliding_attention`` (the same over the
+      last ``sliding_window`` positions; ``sliding_windows``, a layer's
+      own, must agree) or ``conv`` (a gated short convolution:
+      ``conv_L_cache`` taps, ``conv_bias`` false); without it every layer
+      is rotary GQA. ``rope_parameters.rope_type`` other than ``default``
+      is refused.
     - each layer's feed-forward half: ``n_routed_experts`` /
       ``num_experts`` select the expert layer after the first
       ``first_k_dense_replace`` / ``num_dense_layers`` layers (an expert's
       width ``moe_intermediate_size`` / ``expert_ffn_hidden_size``; experts
-      a token ``num_experts_per_tok`` / ``moe_topk``; ``n_shared_experts``;
+      a token ``num_experts_per_tok`` / ``moe_topk``; ``n_shared_experts``
+      / ``num_shared_experts``; ``mlp_layer_types``, ``dense`` | ``sparse``
+      a layer, which must say what ``first_k_dense_replace`` says: leading
+      dense layers, expert layers after;
       ``zero_expert_num`` identity experts after the routed ones in the
-      router's width; ``topk_method`` with ``n_group`` / ``topk_group``;
+      router's width; ``topk_method`` with ``n_group`` / ``topk_group``
+      (without ``topk_method: group_limited_greedy`` only 1 / 1, no group
+      limit, is accepted);
       ``routed_scaling_factor``, ``norm_topk_prob``; the scoring
       ``scoring_func``: ``softmax`` | ``sigmoid``; ``use_expert_bias``: the
       top is taken of scores + a per-expert bias). A key that names
@@ -351,8 +421,15 @@ def model_from_config(config: Dict[str, Any], *, dtype: Any = None,
     computed beside the dense FFNs from the normed residual sublayer
     ``leaves``'s FFN reads, and added after sublayer ``rejoins``'s FFN),
     ``corrected_choice`` (as ``use_expert_bias``), ``router_scoring`` (as
-    ``scoring_func``) and ``qk_norm`` (an RMSNorm with a learned gain over
-    each query and key head, before the rotary embedding)."""
+    ``scoring_func``), ``qk_norm`` (an RMSNorm with a learned gain over
+    each query and key head, before the rotary embedding),
+    ``rotary_layers`` (the published name of the one attention kind whose
+    queries and keys are rotated, ``"sliding_attention"`` for a model
+    whose full layers carry no positional term; ``"all"`` or absent: every
+    attention layer), ``scale_renormed`` (``routed_scaling_factor``
+    multiplies the renormed weights too: renorm, then scale) and
+    ``norm_placement`` (``"pre"``, the only one built: any other is
+    refused by name)."""
     c = config
     heads = int(c["num_attention_heads"])
     hidden = int(c["hidden_size"])
@@ -404,10 +481,33 @@ def model_from_config(config: Dict[str, Any], *, dtype: Any = None,
             raise ValueError(
                 f"zero-compute experts of type {c['zero_expert_type']!r}: "
                 f"only 'identity' is built")
+        grouped = c.get("topk_method", "greedy") == "group_limited_greedy"
+        if not grouped:
+            for name in ("n_group", "topk_group"):
+                if int(c.get(name) or 1) != 1:
+                    raise ValueError(
+                        f"{name} {c[name]} without topk_method "
+                        f"'group_limited_greedy': a group limit is built "
+                        f"for that method only")
+        if not isinstance(block.get("corrected_choice", False), bool):
+            raise ValueError(
+                f"block.corrected_choice {block['corrected_choice']!r}: "
+                f"true (the top is taken of scores + a per-expert bias) "
+                f"or false")
+        mlp_types = c.get("mlp_layer_types")
+        if mlp_types is not None:
+            n_dense = int(c.get("first_k_dense_replace")
+                          or c.get("num_dense_layers") or 0)
+            said = [str(t) for t in mlp_types]
+            if said != ["dense"] * min(n_dense, len(said)) \
+                    + ["sparse"] * max(len(said) - n_dense, 0):
+                raise ValueError(
+                    f"mlp_layer_types {sorted(set(said))}: {n_dense} "
+                    f"leading 'dense' layers (first_k_dense_replace) and "
+                    f"'sparse' after them is what is built")
         dep = c.get("deployment") or {}
         held = int(n_held)
         total = int(dep.get("experts_total", held))
-        grouped = c.get("topk_method", "greedy") == "group_limited_greedy"
         width = int(_key(c, "moe_intermediate_size",
                          "expert_ffn_hidden_size"))
         branch = block.get("routed_branch")
@@ -416,11 +516,13 @@ def model_from_config(config: Dict[str, Any], *, dtype: Any = None,
             held_first=int(dep.get("expert_share", 0)) * held,
             per_token=int(_key(c, "num_experts_per_tok", "moe_topk")),
             width=width,
-            shared_width=int(c.get("n_shared_experts") or 0) * width,
+            shared_width=int(c.get("n_shared_experts")
+                             or c.get("num_shared_experts") or 0) * width,
             n_groups=int(c["n_group"]) if grouped else 1,
             top_groups=int(c["topk_group"]) if grouped else 1,
             scale=float(c.get("routed_scaling_factor", 1.0)),
             renorm=bool(c.get("norm_topk_prob", False)),
+            renorm_scaled=bool(block.get("scale_renormed", False)),
             first_dense=int(c.get("first_k_dense_replace")
                             or c.get("num_dense_layers") or 0),
             n_zero=n_zero,
@@ -433,18 +535,46 @@ def model_from_config(config: Dict[str, Any], *, dtype: Any = None,
         )
     if dtype is None:
         dtype = jnp.dtype(str(c.get("torch_dtype", "bfloat16")))
-    layer_types = None
+    layer_types, window = None, 0
     if c.get("layer_types") is not None:
-        names = {"full_attention": "attention", "attention": "attention",
-                 "conv": "conv"}
+        names = PUBLISHED_MIXERS
         other = sorted({str(t) for t in c["layer_types"]} - set(names))
         if other:
             raise ValueError(
-                f"layer_types {other}: 'full_attention' and 'conv' layers "
-                f"are built")
+                f"layer_types {other}: the layer kinds built are "
+                f"{sorted(names)}")
         layer_types = tuple(names[str(t)] for t in c["layer_types"])
         if c.get("conv_bias"):
             raise ValueError("a short convolution with a bias is not built")
+        if "window" in layer_types:
+            window = int(c.get("sliding_window") or 0)
+            own = c.get("sliding_windows")
+            if own is not None and [int(w) for w in own] != [
+                    window if t == "window" else 0 for t in layer_types]:
+                raise ValueError(
+                    f"sliding_windows {sorted(set(own))}: every "
+                    f"sliding_attention layer at sliding_window "
+                    f"({window}) and every other at 0 is what is built")
+    rp = c.get("rope_parameters") or {}
+    if not c.get("kv_lora_rank") and str(
+            rp.get("rope_type", "default")) != "default":
+        raise ValueError(
+            f"rope_parameters.rope_type {rp['rope_type']!r}: only "
+            f"'default' (no scaling) is built for rotary GQA")
+    if block.get("norm_placement", "pre") != "pre":
+        raise ValueError(
+            f"block.norm_placement {block['norm_placement']!r}: only 'pre' "
+            f"(a norm before each half, on the residual) is built")
+    rotary = ("attention", "window")
+    if block.get("rotary_layers", "all") != "all":
+        said = block["rotary_layers"]
+        said = [said] if isinstance(said, str) else list(said)
+        other = sorted(set(map(str, said)) - set(PUBLISHED_MIXERS))
+        if other or any(PUBLISHED_MIXERS[str(t)] == "conv" for t in said):
+            raise ValueError(
+                f"block.rotary_layers {said}: 'all' or attention kinds "
+                f"of {sorted(PUBLISHED_MIXERS)}")
+        rotary = tuple(sorted({PUBLISHED_MIXERS[str(t)] for t in said}))
     kw = dict(
         vocab_size=int(c["vocab_size"]), d_model=hidden,
         n_layers=int(_key(c, "num_hidden_layers", "num_layers")),
@@ -452,8 +582,7 @@ def model_from_config(config: Dict[str, Any], *, dtype: Any = None,
         d_ff=int(_key(c, "intermediate_size", "ffn_hidden_size")),
         max_seq_len=max_seq_len,
         rope_theta=float(
-            c.get("rope_theta")
-            or (c.get("rope_parameters") or {}).get("rope_theta", 10000.0)),
+            c.get("rope_theta") or rp.get("rope_theta", 10000.0)),
         norm_eps=float(c.get("rms_norm_eps") or c.get("norm_eps") or 1e-6),
         dtype=dtype,
         mla=mla, moe=moe, sublayers=int(block.get("sublayers", 1)),
@@ -462,6 +591,7 @@ def model_from_config(config: Dict[str, Any], *, dtype: Any = None,
         qk_norm=bool(block.get("qk_norm", False)),
         tied_head=bool(c.get("tie_word_embeddings")
                        or c.get("tie_embedding")),
+        window=window, rotary=rotary,
     )
     kw.update(overrides)
     return TransformerConfig(**kw)
@@ -480,7 +610,7 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Params:
     scaled by ``(2·n_layers)^-1/2`` so the residual stream's variance stays O(1)
     at init regardless of depth.
     """
-    if cfg.cache_kind == "hybrid":
+    if cfg.cache_kind in ("hybrid", "window"):
         from tree_attention_tpu.models.hybrid import init_hybrid_params
 
         return init_hybrid_params(key, cfg)
@@ -669,12 +799,14 @@ def times_out_major(x: jax.Array, w_t: jax.Array) -> jax.Array:
 
 
 def gqa_qkv(p: Params, h: jax.Array, positions: jax.Array,
-            cfg: TransformerConfig) -> Tuple[jax.Array, jax.Array, jax.Array]:
+            cfg: TransformerConfig, rotary: bool = True,
+            ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """The rotary-GQA projections of the normed residual ``h`` ``(B, T,
     D)``: queries ``(B, H, T, d)`` and new keys and values ``(B, Hkv, T,
-    d)``, the rotary embedding applied; where ``cfg.qk_norm``, an RMSNorm
-    over each query and key head (one learned gain of ``d`` a layer)
-    before it."""
+    d)``, the rotary embedding applied (not where ``rotary`` is false: a
+    layer kind with no positional term, ``TransformerConfig.rotates``);
+    where ``cfg.qk_norm``, an RMSNorm over each query and key head (one
+    learned gain of ``d`` a layer) before it."""
     if GQA_SERVED in p:      # the served form: one product, cut in three
         q, k, v = jnp.split(times_out_major(h, p[GQA_SERVED]),
                             [cfg.q_dim, cfg.q_dim + cfg.kv_dim], axis=-1)
@@ -686,6 +818,8 @@ def gqa_qkv(p: Params, h: jax.Array, positions: jax.Array,
     if cfg.qk_norm:
         q = rms_norm(q, p["q_ln"], cfg.norm_eps)
         k = rms_norm(k, p["k_ln"], cfg.norm_eps)
+    if not rotary:
+        return q, k, v
     return (rope(q, positions, cfg.rope_theta),
             rope(k, positions, cfg.rope_theta), v)
 

@@ -8,7 +8,7 @@ silently diverge from the forward — so the logic lives once, here.
 from __future__ import annotations
 
 import numbers
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -222,13 +222,16 @@ def tile_mask(
     q_offset,
     kv_offset,
     causal: bool,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """(tq, blk) visibility mask for one KV tile.
 
     Combines the ragged-tail range check (padded keys beyond ``tk`` are
     invalid) with cross-shard causality: query global position
     ``q_offset + row`` sees key global position ``kv_offset + start + col``
-    iff q_pos >= k_pos.
+    iff q_pos >= k_pos; with ``window`` also only iff ``k_pos > q_pos -
+    window`` (a sliding-window layer: a row sees its last ``window``
+    positions, itself included).
     """
     start = blk_idx * blk
     local_col = start + lax.broadcasted_iota(jnp.int32, (tq, blk), 1)
@@ -236,4 +239,6 @@ def tile_mask(
     if causal:
         q_pos = q_offset + lax.broadcasted_iota(jnp.int32, (tq, blk), 0)
         valid = valid & (q_pos >= kv_offset + local_col)
+        if window is not None:
+            valid = valid & (kv_offset + local_col > q_pos - window)
     return valid

@@ -113,6 +113,7 @@ def flash_decode(
     block_table: Optional[jax.Array] = None,
     tree_mask: Optional[jax.Array] = None,
     step_plan=None,
+    window: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Causal decode attention of a few new queries against a long KV buffer.
 
@@ -167,8 +168,18 @@ def flash_decode(
     causal rule, bit-for-bit. Supported on the chunked-vmap path and the
     Pallas decode kernels (as a packed bitmask in SMEM-adjacent VMEM
     lanes); the Q-tiled prefill kernel never sees spec-sized Tq.
+
+    ``window`` (a sliding-window layer; no ``tree_mask``): query row ``i``
+    sees only the ``window`` positions up to its own, ``(q_position + i -
+    window, q_position + i]``. A paged call on the TPU runs the paged
+    decode kernel at EVERY ``Tq`` (its work list starts at the step that
+    holds the lowest visible position, so a 256-row chunk reads ``window +
+    256`` tokens through the table and never gathers the context); every
+    other call masks on the chunked path.
     """
     B, Hq, Tq, D = q.shape
+    if window is not None and tree_mask is not None:
+        raise ValueError("a sliding window takes no tree_mask")
     if tree_mask is not None:
         if Tq > 32:
             raise ValueError(
@@ -205,7 +216,8 @@ def flash_decode(
     # state) and streams at the HBM roofline at any context length.
     from tree_attention_tpu.ops import _on_tpu, _pallas_available
 
-    if _on_tpu(q) and _pallas_available():
+    if _on_tpu(q) and _pallas_available() and (
+            window is None or block_table is not None):
         # Kernel choice and tile defaults live in ops.tuning (shared with
         # flash_attention's auto gate). Prefill-sized Tq takes the Q-tiled
         # kernel: the decode kernel's group packing would spill into
@@ -220,6 +232,8 @@ def flash_decode(
             # Spec-tree chunks are <= 32 rows, squarely the decode
             # kernel's regime; the Q-tiled kernel has no mask path.
             impl = "pallas_decode"
+        if window is not None:
+            impl = "pallas_decode"  # the one kernel with a lower edge
         if block_table is not None:
             if impl == "pallas_decode":
                 from tree_attention_tpu.ops.pallas_decode import (
@@ -232,7 +246,7 @@ def flash_decode(
                     q, k, v, causal=True, scale=scale,
                     q_offset=q_position, kv_offset=0,
                     block_table=block_table, tree_mask=tree_mask,
-                    step_plan=step_plan,
+                    step_plan=step_plan, window=window,
                 )
             # Prefill-sized Tq rides the Q-tiled kernel, which has no
             # table path — one gather materialises the logical view
@@ -309,6 +323,7 @@ def flash_decode(
                     q_offset=pos_b, kv_offset=off,
                     block_size=min(block_size, chunk),
                     tree_mask=tm_b[0][None] if tm_b else None,
+                    window=window,
                 )
                 return o[0], l[0]
 
@@ -320,7 +335,7 @@ def flash_decode(
             q, k_s, v_s,
             causal=True, scale=scale,
             q_offset=q_position, kv_offset=off,
-            block_size=min(block_size, chunk),
+            block_size=min(block_size, chunk), window=window,
         )
 
     _account_dispatch("chunked_vmap", Tk)

@@ -84,7 +84,8 @@ _KERNEL_BUILDS = obs.counter(
 
 
 def _decode_visibility_mask(s, qi, si, *, bq, bk, tq, tk,
-                            q_offset, kv_offset, causal, tree_bits=None):
+                            q_offset, kv_offset, causal, tree_bits=None,
+                            window=None):
     """Ragged-tail + causal masking for one (bq, bk) decode score tile —
     the ONE mask definition shared by the bf16-cast and int8-MXU kernels.
 
@@ -104,6 +105,11 @@ def _decode_visibility_mask(s, qi, si, *, bq, bk, tq, tk,
     position ``i`` iff bit ``i`` of its mask is set; positions below the
     window stay visible (committed history), positions past it never are.
     A lower-triangular bitmask reproduces causal masking bit-for-bit.
+
+    ``window`` (requires ``causal``, no ``tree_bits``): a sliding-window
+    layer's lower edge, one more compare beside the causal one: row ``j``
+    at position ``p`` sees the columns in ``(p - window, p]``, each row of
+    a chunk its own.
     """
     needs_ragged = tk % bk != 0
     if tree_bits is not None:
@@ -130,6 +136,8 @@ def _decode_visibility_mask(s, qi, si, *, bq, bk, tq, tk,
             (qi * bq + lax.broadcasted_iota(jnp.int32, (bq, 1), 0)) % tq
         )
         c = (kv_offset + col_idx) <= q_pos
+        if window is not None:
+            c &= (kv_offset + col_idx) > q_pos - window
         valid = c if valid is None else valid & c
     return jnp.where(valid, s, NEG_INF)
 
@@ -372,15 +380,22 @@ def _flash_decode_q8q_kernel(
 _PLAN_FIRST, _PLAN_LAST, _PLAN_LIVE = 1, 2, 4
 _PLAN_PREFETCH = 5  # offsets, table, slot, step, flags (``PagedPlan``)
 PLAN_SCOPE = "paged_plan"
+# The kernel name of a sliding-window layer's paged call: the one body under
+# a name that does not contain a full layer's ("flash_decode_paged"), which
+# the benchmark's readers match by substring.
+WINDOW_KERNEL = "window_decode_paged"
 
 
-def paged_step_plan(live: jax.Array, n_steps: int
+def paged_step_plan(live: jax.Array, n_steps: int,
+                    first: Optional[jax.Array] = None,
                     ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """The paged decode kernels' work list: ``(slot of entry e, step of
     entry e, flags of entry e, number of entries)`` from ``live`` ``(B,)``,
     the steps of each slot's table that hold a token its rows may see
     (``tuning.paged_live_steps``, 0 to ``n_steps``). Slot ``b`` gets entries
-    for steps ``0 .. live[b] - 1``, slots in order and a slot's steps in
+    for steps ``0 .. live[b] - 1`` (from ``first[b]`` on where ``first``,
+    ``tuning.paged_first_step``, is given: a layer whose rows see a window
+    of the context), slots in order and a slot's steps in
     order; a slot with none gets step 0 all the same, without
     ``_PLAN_LIVE``, so that its rows are still written. The lists have the
     static capacity ``B * n_steps`` (every slot full: the rectangle); what
@@ -401,6 +416,8 @@ def paged_step_plan(live: jax.Array, n_steps: int
         | jnp.where(step == held[slot] - 1, _PLAN_LAST, 0)
         | jnp.where(live[slot] > 0, _PLAN_LIVE, 0)
     ).astype(jnp.int32)
+    if first is not None:
+        step = jnp.clip(step + first.astype(jnp.int32)[slot], 0, n_steps - 1)
     return slot, step, flags, ends[-1]
 
 
@@ -429,11 +446,17 @@ class PagedPlan(NamedTuple):
 
 
 def paged_plan(q_offset, kv_offset, block_table: jax.Array, *, tq: int,
-               entries: int, block: int, causal: bool = True) -> PagedPlan:
+               entries: int, block: int, causal: bool = True,
+               window: Optional[int] = None) -> PagedPlan:
     """The plan of a paged call of ``tq`` rows a slot against
     ``block_table`` ``(B, NB)`` of blocks of ``block`` tokens, ``entries``
-    of them a grid step."""
-    from tree_attention_tpu.ops.tuning import paged_live_steps
+    of them a grid step. With ``window`` (a sliding-window layer) a slot's
+    list starts at the step that holds ``max(0, q_offset - window + 1)``,
+    the lowest position its first row sees, and so holds one or two steps
+    whatever the slot's length."""
+    from tree_attention_tpu.ops.tuning import (
+        paged_first_step, paged_live_steps,
+    )
 
     B, NB = block_table.shape
     n_steps = NB // entries
@@ -441,12 +464,20 @@ def paged_plan(q_offset, kv_offset, block_table: jax.Array, *, tq: int,
     # (``tests/test_chip_compile.py``: not in a layer loop's body).
     with jax.named_scope(PLAN_SCOPE):
         offs = _offsets_smem(q_offset, kv_offset, B)
-        if causal:
+        first = None
+        if window is not None:
+            if not causal:
+                raise ValueError("a sliding window requires causal=True")
+            low = jnp.maximum(offs[0] - (window - 1), 0)
+            first = paged_first_step(low, offs[1], entries * block, n_steps)
+            live = paged_live_steps(
+                offs[0], offs[1], tq, entries * block, n_steps, low)
+        elif causal:
             live = paged_live_steps(
                 offs[0], offs[1], tq, entries * block, n_steps)
         else:
             live = jnp.full((B,), n_steps, jnp.int32)
-        slot, step, flags, count = paged_step_plan(live, n_steps)
+        slot, step, flags, count = paged_step_plan(live, n_steps, first)
         at = (slot * NB + step * entries)[:, None] \
             + jnp.arange(entries, dtype=jnp.int32)[None, :]
         table = jnp.asarray(block_table, jnp.int32).reshape(-1)[
@@ -456,13 +487,14 @@ def paged_plan(q_offset, kv_offset, block_table: jax.Array, *, tq: int,
 
 def _plan_operands(plan: Optional[PagedPlan], q_offset, kv_offset,
                    block_table: jax.Array, *, tq: int, entries: int,
-                   block: int, causal: bool):
+                   block: int, causal: bool, window: Optional[int] = None):
     """``(the five scalar-prefetch operands, the dynamic grid bound)`` of a
     paged call, from the caller's plan or one built here."""
     B, NB = block_table.shape
     if plan is None:
         plan = paged_plan(q_offset, kv_offset, block_table, tq=tq,
-                          entries=entries, block=block, causal=causal)
+                          entries=entries, block=block, causal=causal,
+                          window=window)
     elif plan.offsets.shape != (2, B) or plan.table.shape != (B * NB,) \
             or plan.slot.shape != (B * NB // entries,):
         raise ValueError(
@@ -513,6 +545,7 @@ def _paged_decode_step(
     tree: bool,
     block_scales: bool,
     local_blocks: bool,
+    window: Optional[int] = None,
 ):
     """One grid step of the paged decode kernels: every KV head of
     ``entries`` consecutive table entries of one slot.
@@ -553,7 +586,14 @@ def _paged_decode_step(
     whose entries are all remote, so the online-softmax state accumulates
     exactly this shard's partial; rows whose every block is remote
     finalize to the ``(0, -inf)`` merge identity that
-    :func:`tree_attention_tpu.parallel.tree._weigh` absorbs."""
+    :func:`tree_attention_tpu.parallel.tree._weigh` absorbs.
+
+    ``window`` (a sliding-window layer): the list the call walks starts at
+    the step that holds the lowest position the slot's rows see
+    (:func:`paged_plan`), and the mask hides the columns under each row's
+    own lower edge; the table's entries behind the window name no block of
+    the slot's (block 0) and are streamed at most inside that first step,
+    masked."""
     refs = list(refs)
     lead, refs = refs[:n_lead], refs[n_lead:]
     tb_ref = refs.pop(0) if tree else None
@@ -622,6 +662,7 @@ def _paged_decode_step(
             s, qi, si, bq=bq, bk=bk, tq=tq, tk=tk,
             q_offset=q_offset, kv_offset=kv_offset, causal=causal,
             tree_bits=None if tb_ref is None else tb_ref[0, 0][:, :1],
+            window=window,
         )
         if local_blocks and entries > 1:
             # A remote entry inside a live step: its columns are masked
@@ -775,6 +816,7 @@ def _paged_decode_call(
     kv_offset,
     block_table: jax.Array,
     step_plan: Optional[PagedPlan] = None,
+    window: Optional[int] = None,
     out_dtype,
     interpret: bool,
 ) -> Tuple[jax.Array, jax.Array]:
@@ -795,7 +837,11 @@ def _paged_decode_call(
     once an entry, each with its own index map into the table, so the DMA
     pipeline prefetches physical blocks in logical order with no gather
     copy. Returns ``(out, lse)`` as ``(B, Hq, Tq, D)`` in ``out_dtype`` and
-    ``(B, Hq, Tq)``."""
+    ``(B, Hq, Tq)``. With ``window`` the call is a sliding-window layer's:
+    one body, one more compare in its mask and a list that starts at each
+    slot's window, under a kernel name of its own
+    (:data:`WINDOW_KERNEL`), so that a device trace tells a window
+    layer's calls from a full layer's."""
     from tree_attention_tpu.ops.tuning import paged_decode_step
 
     B, Hkv, n_rows, D = rows[0].shape
@@ -805,12 +851,19 @@ def _paged_decode_call(
     heads, entries = paged_decode_step(
         Hkv, block, D, k.dtype.itemsize, NB, bq)
     head_groups = Hkv // heads
+    name = kernel_body.__name__.strip("_").removesuffix("_kernel")
+    if window is not None:
+        if tree or local_blocks or scales is not None:
+            raise ValueError(
+                "a sliding window is built for the exact replicated pool "
+                "without a tree mask")
+        name = label = WINDOW_KERNEL
     if obs.REGISTRY.enabled:
         _KERNEL_BUILDS.labels(
             kernel=label, heads=heads, entries=entries).inc()
     prefetch, n_entries = _plan_operands(
         step_plan, q_offset, kv_offset, block_table, tq=tq, entries=entries,
-        block=block, causal=causal)
+        block=block, causal=causal, window=window)
     tensors = list(rows)
     in_specs = [
         pl.BlockSpec((1, heads, bq, t.shape[3]), _paged_rows_map)
@@ -848,7 +901,7 @@ def _paged_decode_call(
             kernel_body, **kernel_kwargs, causal=causal, tq=tq,
             tk=NB * block, block_q=bq, block=block, entries=entries,
             tree=tree, block_scales=scales is not None,
-            local_blocks=local_blocks,
+            local_blocks=local_blocks, window=window,
         ),
         grid_spec=grid_spec,
         out_shape=[
@@ -861,9 +914,10 @@ def _paged_decode_call(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-        # A stable name per kernel body, carried into the compiled module
-        # (the custom call's op_name) and the profiler trace.
-        name=kernel_body.__name__.strip("_").removesuffix("_kernel"),
+        # A stable name per kernel body (a window layer's call: its own),
+        # carried into the compiled module (the custom call's op_name) and
+        # the profiler trace.
+        name=name,
     )(*prefetch, *tensors)
     r = group * tq
     return (out[:, :, :r].reshape(B, Hkv * group, tq, D),
@@ -1075,7 +1129,8 @@ def decode_step_entries(q_heads: int, tq: int, pool: jax.Array,
 
 
 def decode_plan(q_heads: int, tq: int, pool: jax.Array,
-                block_table: jax.Array, q_offset) -> PagedPlan:
+                block_table: jax.Array, q_offset,
+                window: Optional[int] = None) -> PagedPlan:
     """The plan the GQA paged kernels (:func:`attention_pallas_decode`,
     ``_q8``, ``_q8q`` with a ``block_table``) build for a causal call of
     ``q_heads`` query heads x ``tq`` rows a slot against this pool
@@ -1083,7 +1138,7 @@ def decode_plan(q_heads: int, tq: int, pool: jax.Array,
     return paged_plan(
         q_offset, 0, block_table, tq=tq, block=pool.shape[-2],
         entries=decode_step_entries(
-            q_heads, tq, pool, block_table.shape[1]))
+            q_heads, tq, pool, block_table.shape[1]), window=window)
 
 
 def _tree_bits_rows(
@@ -1482,6 +1537,7 @@ def attention_pallas_decode_q8q(
     jax.jit,
     static_argnames=(
         "causal", "scale", "block_size", "interpret", "local_blocks",
+        "window",
     ),
 )
 def attention_pallas_decode(
@@ -1499,6 +1555,7 @@ def attention_pallas_decode(
     tree_mask: Optional[jax.Array] = None,
     local_blocks: bool = False,
     step_plan: Optional[PagedPlan] = None,
+    window: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Split-KV flash decode. Same ``(out, lse)`` contract as the other impls.
 
@@ -1552,10 +1609,21 @@ def attention_pallas_decode(
     none of its entries is skipped), so the returned
     ``(out, lse)`` is this shard's flash PARTIAL over its own blocks
     (rows with no local blocks emit the ``(0, -inf)`` merge identity).
+
+    ``window`` (requires ``block_table`` and ``causal``): a sliding-window
+    layer's call. Row ``i`` sees positions ``(q_offset + i - window,
+    q_offset + i]``; the work list starts at the step that holds the
+    lowest of them (``step_plan``, if handed in, must have been built with
+    the same ``window``: :func:`decode_plan`), so the call walks one or
+    two steps a slot whatever its length, at any ``Tq`` (a chunk's rows
+    take Q tiles of 128 packed rows over that short list). The kernel is
+    named :data:`WINDOW_KERNEL`.
     """
     B, Hq, Tq, D = q.shape
     if local_blocks and block_table is None:
         raise ValueError("local_blocks requires block_table")
+    if window is not None and (block_table is None or not causal):
+        raise ValueError("window requires block_table and causal=True")
     if tree_mask is not None:
         if not causal:
             raise ValueError("tree_mask requires causal=True")
@@ -1612,8 +1680,8 @@ def attention_pallas_decode(
             _paged_rows([qp], tb), k, v, tree=tb is not None, group=G,
             tq=Tq, bq=bq, causal=causal, local_blocks=local_blocks,
             q_offset=q_offset, kv_offset=kv_offset,
-            block_table=block_table, step_plan=step_plan, out_dtype=q.dtype,
-            interpret=interpret,
+            block_table=block_table, step_plan=step_plan, window=window,
+            out_dtype=q.dtype, interpret=interpret,
         )
         return out.astype(out_dtype), lse
     qp = qp.reshape(B * Hkv, n_q * bq, D)

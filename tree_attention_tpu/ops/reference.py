@@ -91,6 +91,7 @@ def attention_naive(
     q_offset=0,
     kv_offset=0,
     tree_mask: Optional[jax.Array] = None,
+    window: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Materialised-scores attention. Oracle implementation for tests.
 
@@ -99,6 +100,9 @@ def attention_naive(
     never replicated in memory — the same mapping the Pallas kernel's
     BlockSpec index does in VMEM. That keeps this path viable for big GQA
     decode caches, not just as a test oracle.
+
+    ``window`` (with ``causal``): a row at global position ``t`` sees the
+    keys at ``(t - window, t]`` only, a sliding-window layer's rule.
     """
     B, Hq, Tq, D = q.shape
     Hkv, Tk = k.shape[1], k.shape[2]
@@ -106,6 +110,8 @@ def attention_naive(
         raise ValueError(
             f"query heads ({Hq}) must be a multiple of kv heads ({Hkv})"
         )
+    if window is not None and (tree_mask is not None or not causal):
+        raise ValueError("window requires causal=True and no tree_mask")
     G = Hq // Hkv
     s = _default_scale(D, scale)
 
@@ -142,6 +148,8 @@ def attention_naive(
         logits = jnp.where(mask[:, None, None], logits, NEG_INF)
     elif causal:
         mask = _causal_mask(Tq, Tk, q_offset, kv_offset)
+        if window is not None:
+            mask &= ~_causal_mask(Tq, Tk, q_offset - window, kv_offset)
         logits = jnp.where(mask[None, None, None], logits, NEG_INF)
 
     m = jnp.max(logits, axis=-1)
@@ -165,7 +173,8 @@ def attention_naive(
     )
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "scale", "block_size"))
+@functools.partial(
+    jax.jit, static_argnames=("causal", "scale", "block_size", "window"))
 def attention_blockwise(
     q: jax.Array,
     k: jax.Array,
@@ -177,6 +186,7 @@ def attention_blockwise(
     kv_offset=0,
     block_size: int = 512,
     tree_mask: Optional[jax.Array] = None,
+    window: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Online-softmax attention: ``lax.scan`` over KV blocks, O(block) memory.
 
@@ -198,9 +208,14 @@ def attention_blockwise(
     with ``tree_mask[b, i, p - q_offset]`` set (an ancestor of ``i`` — or
     ``i`` itself). A lower-triangular mask reproduces plain causal
     masking bit-for-bit (same visibility sets, same arithmetic).
+
+    ``window`` (requires ``causal``, no ``tree_mask``): the sliding-window
+    rule, a row at global position ``t`` sees ``(t - window, t]``.
     """
     B, Hq, Tq, D = q.shape
     Hkv = k.shape[1]
+    if window is not None and (tree_mask is not None or not causal):
+        raise ValueError("window requires causal=True and no tree_mask")
     if Hq % Hkv != 0:
         raise ValueError(
             f"query heads ({Hq}) must be a multiple of kv heads ({Hkv})"
@@ -242,7 +257,7 @@ def attention_blockwise(
         )
         if tree_mask is None:
             valid = tile_mask(Tq, blk, blk_idx, Tk, q_offset, kv_offset,
-                              causal)
+                              causal, window)
             logits = jnp.where(valid[None, None, None], logits, NEG_INF)
         else:
             # Tree-window rule: below the window everything is visible,

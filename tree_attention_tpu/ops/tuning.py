@@ -200,18 +200,32 @@ def paged_decode_step(
     return heads, entries
 
 
+def paged_first_step(first_pos, kv_offset, step_tokens: int, n_steps: int):
+    """The step of each slot's table that holds position ``first_pos``, the
+    lowest its rows may see (a window layer's ``max(0, q_offset - window +
+    1)``): where such a slot's work list starts."""
+    return ((first_pos - kv_offset) // step_tokens).clip(0, n_steps - 1)
+
+
 def paged_live_steps(q_offset, kv_offset, tq: int, step_tokens: int,
-                     n_steps: int):
+                     n_steps: int, first_pos=None):
     """Steps of each slot's table that hold a token its rows may see, 0 to
     ``n_steps``: step ``si`` covers positions ``kv_offset + si *
     step_tokens ...`` and the slot's last row sits at ``q_offset + tq -
     1``, so the steps ``0 .. (q_offset + tq - 1 - kv_offset) // step_tokens``
-    do. Offsets are ``(B,)`` integer arrays of numpy's or of jax's: the
-    kernels build their work list from this on the device
+    do. With ``first_pos`` (the lowest position a slot's rows may see: a
+    layer whose rows see a window of the context) the steps under
+    :func:`paged_first_step` hold nothing live either and are not counted:
+    the list starts there. Offsets are ``(B,)`` integer arrays of numpy's
+    or of jax's: the kernels build their work list from this on the device
     (``pallas_decode.paged_step_plan``), and the serve loop counts with it
     on the host what a tick's list will hold (``kv_steps_run``)."""
-    return ((q_offset + (tq - 1) - kv_offset) // step_tokens + 1).clip(
+    live = ((q_offset + (tq - 1) - kv_offset) // step_tokens + 1).clip(
         0, n_steps)
+    if first_pos is None:
+        return live
+    return (live - paged_first_step(
+        first_pos, kv_offset, step_tokens, n_steps)).clip(0, n_steps)
 
 
 # The one home of the TPU kernel-dispatch policy shared by flash_attention's

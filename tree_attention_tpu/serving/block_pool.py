@@ -35,7 +35,9 @@ hundreds of random admit/retire/hit/evict interleavings per second.
 
 from __future__ import annotations
 
-from typing import Callable, Dict, Iterable, List, Optional
+from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
+
+import numpy as np
 
 from tree_attention_tpu import obs
 from tree_attention_tpu.utils.logging import get_logger
@@ -62,6 +64,20 @@ _BLOCKS_FREE_SHARD = obs.gauge(
     "serving_kv_blocks_free_shard",
     "KV pool blocks free per mesh shard (sequence-sharded pool)",
     labels=("shard",),
+)
+
+# The window layers' pool (a model with sliding-window layers): blocks a slot
+# or the prefix tree holds for them, and blocks given back behind a window.
+_WINDOW_BLOCKS = obs.gauge(
+    "serving_kv_window_blocks",
+    "window-layer pool blocks by state: held (mapped in a slot's window "
+    "table), cached (kept by the prefix tree), free",
+    labels=("state",),
+)
+_WINDOW_FREED = obs.counter(
+    "serving_kv_window_blocks_freed_total",
+    "window-layer blocks slots gave back because they fell behind the "
+    "window of every row still to be computed",
 )
 
 # Block ownership states (the debug ledger's vocabulary). A _DEMOTED
@@ -457,3 +473,206 @@ class ShardedBlockAllocator(BlockAllocator):
                 _BLOCKS_USED_SHARD.labels(shard=s).set(
                     self.shard_blocks - nfree
                 )
+
+
+class WindowBlocks:
+    """The sliding-window layers' blocks: their pool's ledger (a second
+    :class:`BlockAllocator`: same refcounts, same fork and publish rules),
+    their table, and what each slot holds of them.
+
+    A window layer's row at ``t`` sees positions ``(t - window, t]``, so of
+    a slot's logical blocks only those that hold a position some row still
+    to be computed can see need a physical block. Before every dispatch
+    that writes rows ``[t0, end)`` of a slot, :meth:`advance` gives back
+    every block whose last token lies at or under ``t0 - window`` (its
+    table entry goes back to 0: it names no block of the slot's) and maps
+    the blocks the rows fall in. What a slot holds is therefore bounded by
+    :attr:`bound` = ``ceil((window + chunk) / block) + 1`` whatever its
+    length (``ceil(window / block) + 1`` in decode).
+
+    **Reservation.** Every admission reserves the constant :attr:`bound`
+    and every block a slot maps that it does not own alone (a fork's shared
+    ancestor, a block the prefix tree keeps) is charged to it as one it
+    allocated would be: a slot's mapped blocks plus its unspent reservation
+    are :attr:`bound`, always. The pool holds ``slots x (bound + 1)``
+    blocks, so by counting alone the reservations in force never exceed the
+    free blocks plus the tree's unmapped ones (each mapped block is charged
+    to at least one slot): an admission never waits for window blocks and
+    a long request never runs the pool dry. The blocks over ``slots x
+    bound`` are what the prefix tree may keep for later hits.
+
+    Pure host integers, like the allocator's."""
+
+    PRIVATE, SHARED = "private", "shared"
+
+    def __init__(self, *, slots: int, table_width: int, block: int,
+                 window: int, chunk: int):
+        if window < 1:
+            raise ValueError(f"a window of {window} tokens")
+        self.block, self.window = block, window
+        self.bound = -(-(window + chunk) // block) + 1
+        # The published blocks a hit needs at its boundary: those that
+        # hold the ``window - 1`` positions under it.
+        self.hit_blocks = -(-(window - 1) // block)
+        self.blocks = slots * (self.bound + 1)
+        self.alloc = BlockAllocator(self.blocks)
+        self.table = np.zeros((slots, table_width), np.int32)
+        self.dirty = False
+        # slot -> logical block -> (pool block, owner): PRIVATE, SHARED or
+        # the prefix node that keeps it.
+        self._held: List[Dict[int, Tuple[int, Any]]] = [
+            {} for _ in range(slots)]
+        self._reserve = [0] * slots
+        self.freed = 0        # lifetime blocks given back behind a window
+        self.peak_slot = 0    # the most one slot ever held at once
+
+    # -- numbers ------------------------------------------------------------
+
+    def held(self, slot: Optional[int] = None) -> int:
+        """Window-table entries mapped: one slot's, or summed over slots."""
+        if slot is not None:
+            return len(self._held[slot])
+        return sum(len(h) for h in self._held)
+
+    def private(self) -> int:
+        return sum(1 for h in self._held for _, o in h.values()
+                   if o is self.PRIVATE)
+
+    def reserved(self, slot: int) -> int:
+        return self._reserve[slot]
+
+    def publish_gauges(self, cached: int = 0) -> None:
+        if obs.REGISTRY.enabled:
+            _WINDOW_BLOCKS.labels(state="held").set(self.held())
+            _WINDOW_BLOCKS.labels(state="cached").set(cached)
+            _WINDOW_BLOCKS.labels(state="free").set(self.alloc.free_count)
+
+    # -- a slot's life ------------------------------------------------------
+
+    def reserve(self) -> bool:
+        """One admission's constant; False defers it (cannot happen at
+        the pool's own size, see the class docstring)."""
+        return self.alloc.reserve(self.bound)
+
+    def cancel(self) -> None:
+        """Return a reservation no slot took (a deferred admission)."""
+        self.alloc.unreserve(self.bound)
+
+    def admit(self, slot: int) -> None:
+        assert not self._held[slot] and not self._reserve[slot], (
+            f"slot {slot} admitted over window blocks it still holds")
+        self._reserve[slot] = self.bound
+
+    def _charge(self, slot: int) -> None:
+        # A mapped block the slot did not allocate counts against its
+        # constant like one it did.
+        assert self._reserve[slot] > 0, (
+            f"slot {slot} maps more window blocks than its bound")
+        self._reserve[slot] -= 1
+        self.alloc.unreserve(1)
+
+    def _refund(self, slot: int) -> None:
+        self._reserve[slot] += 1
+        self.alloc.reserved += 1
+
+    def _set(self, slot: int, j: int, bid: int, owner: Any) -> None:
+        self._held[slot][j] = (bid, owner)
+        self.table[slot, j] = bid
+        self.dirty = True
+        self.peak_slot = max(self.peak_slot, len(self._held[slot]))
+
+    def hit(self, slot: int, at: List[Tuple[int, Any]]) -> None:
+        """Map the prefix nodes' window blocks at a hit's boundary:
+        ``(logical block, node)`` pairs whose ``wrefs`` the caller has
+        taken (``PagedPrefixIndex.pin_window``)."""
+        for j, node in at:
+            self._charge(slot)
+            self._set(slot, j, node.wblock, node)
+
+    def advance(self, slot: int, t0: int, end: int) -> int:
+        """Before a dispatch that writes rows ``[t0, end)`` of ``slot``:
+        give back what lies behind the window of row ``t0`` (and so of
+        every later row), map the blocks the rows fall in. Returns how
+        many blocks were given back."""
+        held = self._held[slot]
+        behind = [j for j in held
+                  if (j + 1) * self.block - 1 <= t0 - self.window]
+        for j in behind:
+            self._drop(slot, j, refund=True)
+        self.freed += len(behind)
+        if behind and obs.REGISTRY.enabled:
+            _WINDOW_FREED.inc(len(behind))
+        for j in range(t0 // self.block, (end - 1) // self.block + 1):
+            if j not in held:
+                assert self._reserve[slot] > 0, (
+                    f"slot {slot} outgrew its window-block bound "
+                    f"{self.bound}")
+                self._reserve[slot] -= 1
+                self._set(slot, j, self.alloc.alloc(), self.PRIVATE)
+        return len(behind)
+
+    def _drop(self, slot: int, j: int, refund: bool) -> None:
+        bid, owner = self._held[slot].pop(j)
+        self.table[slot, j] = 0   # names no block of the slot's any more
+        self.dirty = True
+        if owner is self.PRIVATE:
+            if refund:
+                self.alloc.unmap_private(bid)   # free, and reserved again
+                self._reserve[slot] += 1
+            else:
+                self.alloc.free_private(bid)
+            return
+        if owner is self.SHARED:
+            self.alloc.release_shared(bid)
+        else:
+            owner.wrefs -= 1
+            assert owner.wrefs >= 0, "prefix node window-ref underflow"
+        if refund:
+            self._refund(slot)
+
+    def publish(self, slot: int, j: int, node: Any, index: Any) -> bool:
+        """Hand the block ``slot`` privately holds at logical ``j`` to
+        prefix ``node`` (which keeps none yet): ownership moves, the slot
+        goes on reading it, charged as before."""
+        got = self._held[slot].get(j)
+        if got is None or got[1] is not self.PRIVATE or node.wblock >= 0:
+            return False
+        index.adopt_window(node, got[0])
+        self._held[slot][j] = (got[0], node)
+        return True
+
+    def fork(self, parent: int, child: int, nshare: int,
+             partial: bool) -> Tuple[int, int]:
+        """A fork's window half: ``child`` (admitted) shares every block
+        ``parent`` holds under logical ``nshare`` by reference, as a full
+        layer's ancestors are shared, and takes a block of its own for the
+        partial one at ``nshare``. Returns ``(source, destination)`` of
+        the one device copy (0, 0: none)."""
+        for j, (bid, owner) in list(self._held[parent].items()):
+            if j >= nshare:
+                continue
+            if owner is self.PRIVATE or owner is self.SHARED:
+                self.alloc.fork_shared([bid])
+                self._held[parent][j] = (bid, self.SHARED)
+                owner = self.SHARED
+            else:
+                owner.wrefs += 1
+            self._charge(child)
+            self._set(child, j, bid, owner)
+        if not partial or nshare not in self._held[parent]:
+            return 0, 0
+        src = self._held[parent][nshare][0]
+        self._reserve[child] -= 1
+        dst = self.alloc.alloc()
+        self._set(child, nshare, dst, self.PRIVATE)
+        return src, dst
+
+    def free_slot(self, slot: int) -> None:
+        """Everything ``slot`` holds goes back; its table row is reset."""
+        for j in list(self._held[slot]):
+            self._drop(slot, j, refund=False)
+        if self._reserve[slot]:
+            self.alloc.unreserve(self._reserve[slot])
+            self._reserve[slot] = 0
+        self.table[slot, :] = 0
+        self.dirty = True

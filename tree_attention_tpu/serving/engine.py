@@ -172,6 +172,7 @@ from tree_attention_tpu.ops.tuning import paged_live_steps
 from tree_attention_tpu.serving.block_pool import (
     BlockAllocator,
     ShardedBlockAllocator,
+    WindowBlocks,
 )
 from tree_attention_tpu.serving.host_pool import HostBlockPool
 from tree_attention_tpu.serving.prefix_cache import (
@@ -971,6 +972,8 @@ class SlotServer:
                 "latent": "the latent kernel takes no tree_mask",
                 "hybrid": "a draft that is rejected has overwritten the "
                           "conv layers' tails, which cannot roll back",
+                "window": "a rollback would need window blocks that were "
+                          "given back",
             }[cfg.cache_kind]
             for on, what in (
                 (quantize, f"int8 {cfg.cache_kind} rows (quantize=True)"),
@@ -980,6 +983,10 @@ class SlotServer:
                 (speculate, f"speculation ({why_not})"),
                 (admission != "chunked",
                  "whole-prompt admission (admission='whole')"),
+                (cfg.cache_kind == "window" and (
+                    block_pool is not None or prefix_index is not None),
+                 "disaggregation (a shared block_pool / prefix_index: the "
+                 "window layers' blocks are one engine's)"),
             ):
                 if on:
                     raise ValueError(
@@ -1208,6 +1215,17 @@ class SlotServer:
         self._slot_reserve = [0] * slots
         self._peak_blocks_used = 0
         self._defer_gen = -1  # see the admit loop's generation latch
+        # Layers whose block counts differ (a model with sliding-window
+        # layers): their pool's ledger, their table and what each slot
+        # holds of them. None for every other model.
+        self._win: Optional[WindowBlocks] = None
+        self._tick_wfreed = 0
+        self._tick_kv_kinds: Dict[str, Tuple[int, int]] = {}
+        if cfg.cache_kind == "window":
+            self._win = WindowBlocks(
+                slots=slots, table_width=self._npb, block=kv_block,
+                window=cfg.window, chunk=self.prefill_chunk)
+            kw = dict(kw, window_blocks=self._win.blocks)
         self.cache = init_paged_cache(
             cfg, slots, cache_len, self.kv_blocks,
             block=kv_block, quantize=quantize, kv_shard=kv_shard, **kw
@@ -1236,7 +1254,7 @@ class SlotServer:
         # tick programs leave it (``_count_kv_steps``), and the tokens a
         # grid step of the paged kernel takes by a group's rows a slot.
         self._kv_len = np.zeros((slots,), np.int64)
-        self._kv_step_tokens: Dict[int, Optional[int]] = {}
+        self._kv_step_tokens: Dict[Tuple[str, int], Optional[int]] = {}
 
         # Host mirror of slot state (the scheduler's view; device state is
         # the cache + the token vector the mixed step carries). States:
@@ -1336,6 +1354,9 @@ class SlotServer:
                 block=self.kv_block, alloc=self._pool,
                 max_cached=prefix_pool_blocks,
                 host_pool=self._host_pool,
+                **({} if self._win is None else {
+                    "window_alloc": self._win.alloc,
+                    "window_need": self._win.hit_blocks}),
             )
 
         # Reusable host scratch for the legacy whole-prompt admission's
@@ -1472,7 +1493,8 @@ class SlotServer:
                                branch)
         return keys.at[slot].set(k)
 
-    def _fork_copy_fn(self, cache, tok_vec, src, dst, slot, tip):
+    def _fork_copy_fn(self, cache, tok_vec, src, dst, slot, tip,
+                      wsrc=None, wdst=None):
         """The fork's ONE device dispatch: copy-on-write the partial
         tail block ``src`` into the child's fresh block ``dst`` (a
         no-op self-copy when the fork point is block-aligned and no
@@ -1480,7 +1502,7 @@ class SlotServer:
         token in the device token vector (the pure-decode tick reads
         tokens from there). Everything else about a fork is host
         bookkeeping: table row, refcounts, pins."""
-        cache = copy_pool_block(cache, src, dst)
+        cache = copy_pool_block(cache, src, dst, wsrc, wdst)
         tok_vec = lax.dynamic_update_index_in_dim(tok_vec, tip, slot,
                                                   axis=0)
         return cache, tok_vec
@@ -1563,17 +1585,31 @@ class SlotServer:
         groups = [(tq, pre)] if chunk is None \
             else [(tq, pre[chunk[0]]), (1, pre)]
         run = grid = 0
-        for g_tq, lengths in groups:
-            if g_tq not in self._kv_step_tokens:
-                self._kv_step_tokens[g_tq] = paged_step_tokens(
-                    self.cache, self.cfg, g_tq)
-            step = self._kv_step_tokens[g_tq]
-            if step is None:
-                continue
-            n_steps = self.cache.capacity // step
-            live = paged_live_steps(lengths, 0, g_tq, step, n_steps)
-            run += int(np.maximum(live, 1).sum())
-            grid += len(lengths) * n_steps
+        # One list a kind of layer: the full layers', and the window
+        # layers' (which starts at the step that holds the lowest position
+        # a slot's rows see).
+        kinds = {}
+        for kind in (("full", "window") if self._win is not None
+                     else ("full",)):
+            k_run = k_grid = 0
+            for g_tq, lengths in groups:
+                key = (kind, g_tq)
+                if key not in self._kv_step_tokens:
+                    self._kv_step_tokens[key] = paged_step_tokens(
+                        self.cache, self.cfg, g_tq, window=kind == "window")
+                step = self._kv_step_tokens[key]
+                if step is None:
+                    continue
+                n_steps = self.cache.capacity // step
+                low = None if kind == "full" else np.maximum(
+                    lengths - (self.cfg.window - 1), 0)
+                live = paged_live_steps(
+                    lengths, 0, g_tq, step, n_steps, low)
+                k_run += int(np.maximum(live, 1).sum())
+                k_grid += len(lengths) * n_steps
+            kinds[kind] = (k_run, k_grid)
+            run, grid = run + k_run, grid + k_grid
+        self._tick_kv_kinds = kinds if self._win is not None else {}
         self._kv_len = pre + n_vec
         if chunk is not None:
             np.add.at(self._kv_len, chunk[0], chunk[1])
@@ -2199,6 +2235,23 @@ class SlotServer:
         if self._prefix is not None:
             out["blocks_cached"] = self._prefix.blocks_used
             out["pins"] = self._prefix.total_pins()
+        if self._win is not None:
+            # The window layers' pool under the same invariant: its own
+            # numbers, and folded into the totals above, so that whoever
+            # holds a drained engine to ``used == cached`` holds both
+            # pools to it.
+            w = self._win
+            own = {
+                "window_blocks_private": w.private(),
+                "window_blocks_used": w.alloc.used,
+                "window_blocks_reserved": w.alloc.reserved,
+                "window_blocks_shared": w.alloc.shared_count,
+                "window_blocks_cached": self._window_cached(),
+                "window_blocks_held": w.held(),
+            }
+            for name in ("private", "used", "reserved", "shared", "cached"):
+                out["blocks_" + name] += own["window_blocks_" + name]
+            out.update(own)
         # With no prefix tree every used block is slot-private, so a
         # drained engine must be at used == 0 exactly.
         return out
@@ -2485,6 +2538,19 @@ class SlotServer:
             matched, nodes = self._prefix.match(
                 np.asarray(req.prompt, np.int32), record=False
             )
+        win_nodes: List[Any] = []
+        if self._win is not None and nodes:
+            # A hit restores every kind of layer's state or none: it ends
+            # at the deepest boundary whose window blocks the tree still
+            # keeps, else shallower, else nowhere. The blocks it will map
+            # are pinned before the reservation is asked for, as the path
+            # is.
+            deep = self._prefix.window_depth(nodes)
+            self._prefix.release(nodes[deep:])
+            nodes = nodes[:deep]
+            matched = deep * self.kv_block
+            win_nodes = [n for _, n in self._prefix.window_nodes(nodes)]
+            self._prefix.pin_window(win_nodes)
         dev_matched = sum(1 for n in nodes if n.tier == TIER_DEVICE)
         branches = self._branches(req)
         fam_extra = 0
@@ -2498,19 +2564,33 @@ class SlotServer:
                 sib = total - (len(req.prompt) - 1) // self.kv_block
                 fam_extra = (branches - 1) * sib
         needed = total - dev_matched
-        if not self._pool.reserve(needed + fam_extra):
+        ok = self._pool.reserve(needed + fam_extra)
+        if ok and self._win is not None and not self._win.reserve():
+            # By kind: full blocks by the request's length, a constant of
+            # window blocks (which the window pool's size always grants).
+            self._pool.unreserve(needed + fam_extra)
+            ok = False
+        if not ok:
             if nodes:
+                self._prefix.unpin_window(win_nodes)
                 self._prefix.release(nodes)
             return None
         if self._prefix is not None:
             self._prefix.record_match(matched)
         return matched, nodes, needed, fam_extra
 
-    def _ensure_blocks(self, slot: int, tokens_needed: int) -> None:
+    def _ensure_blocks(self, slot: int, tokens_needed: int,
+                       rows: int = 1) -> None:
         """Map physical blocks covering ``[0, tokens_needed)`` tokens of
         ``slot`` — called before every dispatch that writes the slot.
         Allocation is backed by the admission's reservation, so it cannot
-        fail; a full free list recycles LRU refcount-0 prefix leaves."""
+        fail; a full free list recycles LRU refcount-0 prefix leaves.
+        The dispatch writes the last ``rows`` of those tokens: in the
+        window layers' table the blocks they fall in are mapped and every
+        block behind the window of the first of them is given back."""
+        if self._win is not None:
+            self._tick_wfreed += self._win.advance(
+                slot, tokens_needed - rows, tokens_needed)
         need = -(-tokens_needed // self.kv_block)
         grew = self._slot_nblocks[slot] < need
         while self._slot_nblocks[slot] < need:
@@ -2530,6 +2610,11 @@ class SlotServer:
             if rq is not None:
                 obs.REQLOG.blocks(rq.uid, self._slot_nblocks[slot])
 
+    def _window_cached(self) -> int:
+        """Window-pool blocks the prefix tree keeps (0 without one)."""
+        return 0 if self._prefix is None \
+            else self._prefix.window_blocks_used
+
     def _sync_table(self) -> None:
         """Push the host block table to the device when it changed — the
         ONE host→device transfer a table update costs (a few hundred
@@ -2542,6 +2627,11 @@ class SlotServer:
                 self.cache, table=jnp.asarray(self._host_table.copy())
             )
             self._table_dirty = False
+        if self._win is not None and self._win.dirty:
+            self.cache = dataclasses.replace(
+                self.cache, wtable=jnp.asarray(self._win.table.copy())
+            )
+            self._win.dirty = False
 
     def attach_host_tier(self, host_pool: HostBlockPool) -> None:
         """Wire ``host_pool`` as this engine's KV demotion tier: build
@@ -2647,6 +2737,8 @@ class SlotServer:
         self._slot_reserve[slot] = needed
         self._slot_private[slot] = set()
         self._slot_nblocks[slot] = 0
+        if self._win is not None:
+            self._win.admit(slot)
         matched = self._paged_hit(req, slot, tick, resv)
         self._prefill_start[slot] = matched
         self._slot_prefix_hit[slot] = matched
@@ -2784,6 +2876,10 @@ class SlotServer:
             self._host_table[slot, j] = node.block_id
         self._slot_nblocks[slot] = matched // self.kv_block
         self._table_dirty = True
+        if self._win is not None:
+            # The window layers' state at the boundary: the tree's own
+            # blocks, pinned by ``_paged_reserve``.
+            self._win.hit(slot, self._prefix.window_nodes(nodes))
         moved = 0
         if self.quantize:
             # Dequantize the matched int8 blocks into staging slot 0 so
@@ -2845,6 +2941,12 @@ class SlotServer:
         # The admit-time pins carried over into ``path`` (plus the
         # freshly created nodes); retire releases them all at once.
         self._slot_nodes[slot] = path
+        if self._win is not None:
+            # The window blocks the slot still holds of its prompt's full
+            # blocks (the last ones: the rest went back as the chunks
+            # went by) are the tree's to keep from now on.
+            for j, node in enumerate(path[:nb_full]):
+                self._win.publish(slot, j, node, self._prefix)
 
     def _admit_whole(self, req: Request, slot: int, matched: int = 0) -> None:
         """Blocking admission: the whole remaining prompt prefills before
@@ -3036,6 +3138,17 @@ class SlotServer:
             "tree_branches": self._tick_tree_branches,
             "branch_retired": self._tick_branch_retired,
         }
+        if self._win is not None:
+            # Layers whose block counts differ: window-table entries
+            # mapped over all slots, those given back this tick, what the
+            # table would hold had none been (the full layers' entries),
+            # and the kernel lists' counts by kind.
+            out["window_blocks_held"] = self._win.held()
+            out["window_blocks_freed"] = self._tick_wfreed
+            out["window_blocks_full"] = sum(self._slot_nblocks)
+            for kind, (k_run, k_grid) in self._tick_kv_kinds.items():
+                out[f"kv_steps_run_{kind}"] = k_run
+                out[f"kv_steps_grid_{kind}"] = k_grid
         if self._host_pool is not None:
             out["restored_blocks"] = self._tick_restored
         if self._speculate:
@@ -3198,9 +3311,19 @@ class SlotServer:
             src = dst = 0  # block-aligned fork: the copy degenerates to
             # a self-write and the program only parks the tip
         self._table_dirty = True
+        wcopy: Tuple[Any, ...] = ()
+        if self._win is not None:
+            # The window layers' half: the blocks the parent holds are
+            # shared by reference like the full ones, the partial block
+            # copied into one of the child's own constant.
+            ok = self._win.reserve()
+            assert ok, "the window pool refused a slot its constant"
+            self._win.admit(child_slot)
+            wcopy = tuple(jnp.int32(b) for b in self._win.fork(
+                parent_slot, child_slot, nshare, need_copy))
         self.cache, self.tok = self._fork_copy(
             self.cache, self.tok, jnp.int32(src), jnp.int32(dst),
-            jnp.int32(child_slot), jnp.int32(tip),
+            jnp.int32(child_slot), jnp.int32(tip), *wcopy,
         )
         salt = (req.seed if req.seed is not None else req.uid) & 0x7FFFFFFF
         self._salt_np[child_slot] = salt
@@ -4108,7 +4231,7 @@ class SlotServer:
         chunk_slot = np.zeros((C,), np.int32)
         chunk_n = np.zeros((C,), np.int32)
         for j, (slot, n, last) in enumerate(plan):
-            self._ensure_blocks(slot, self._prefill_pos[slot] + n)
+            self._ensure_blocks(slot, self._prefill_pos[slot] + n, n)
             rows, first = self._consume_chunk(slot, n, last)
             chunk_tok[j, :n] = rows
             chunk_slot[j] = slot
@@ -4185,6 +4308,8 @@ class SlotServer:
         self._host_table[slot, :] = 0  # stale ids must never be read
         self._slot_nblocks[slot] = 0
         self._table_dirty = True
+        if self._win is not None:
+            self._win.free_slot(slot)   # both tables are reset
         # The pin releases above can grow EVICTABILITY without
         # touching the free list — clear the admit loop's deferral
         # latch so the queue head retries.
@@ -4642,6 +4767,8 @@ class SlotServer:
             if self._pool.used > self._peak_blocks_used:
                 self._peak_blocks_used = self._pool.used
             self._pool.publish_gauges()  # registry-guarded inside
+            if self._win is not None:
+                self._win.publish_gauges(self._window_cached())
             if self._host_pool is not None:
                 if self._tail is None:
                     # The staged D2H flush point: demotions this tick's
@@ -4770,6 +4897,8 @@ class SlotServer:
                 self._tick_shed = 0
                 self._tick_forks = 0
                 self._tick_fork_shared = 0
+                self._tick_wfreed = 0
+                self._tick_kv_kinds = {}
                 self._tick_tree_branches = 0
                 self._tick_branch_retired = 0
 
@@ -5158,7 +5287,7 @@ class SlotServer:
                                         self._live_reset.pop(i)
                         for slot, n, last in plan:
                             self._ensure_blocks(
-                                slot, self._prefill_pos[slot] + n
+                                slot, self._prefill_pos[slot] + n, n
                             )
                             rows, first = self._consume_chunk(slot, n,
                                                               last)
@@ -5500,6 +5629,24 @@ class SlotServer:
             # What a block holds whatever its tokens (conv tails).
             "block_fixed_bytes": self._kv_block_fixed_bytes,
         }
+        if self._win is not None:
+            # Both pools' bytes: the full layers' grow with the tokens
+            # held, the window layers' is the bounded one.
+            wk = self.cache.wk
+            wtok = 2 * wk.shape[0] * wk.shape[2] * wk.shape[4] \
+                * wk.dtype.itemsize
+            kv_snap.update({
+                "pool_bytes": self.kv_blocks * self.kv_block
+                * self._kv_token_bytes,
+                "window_pool_blocks": self._win.blocks,
+                "window_pool_bytes": self._win.blocks * self.kv_block
+                * wtok,
+                "window_token_bytes": wtok,
+                "window_blocks_bound": self._win.bound,
+                "window_blocks_peak_slot": self._win.peak_slot,
+                "window_blocks_used": self._win.alloc.used,
+                "window_blocks_freed": self._win.freed,
+            })
         if self._forks_life - fork0[0]:
             # Copy-on-write fork accounting for THIS run (ISSUE 15).
             kv_snap["forks"] = self._forks_life - fork0[0]

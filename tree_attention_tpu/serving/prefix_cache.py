@@ -84,7 +84,7 @@ class _Node:
     (device tier) or one host-tier row (demoted)."""
 
     __slots__ = ("key", "parent", "children", "block_id", "refs",
-                 "last_use", "tier")
+                 "last_use", "tier", "wblock", "wrefs")
 
     def __init__(self, key: Tuple[int, ...], parent: Optional["_Node"],
                  block_id: int):
@@ -95,6 +95,11 @@ class _Node:
         self.refs = 0
         self.last_use = 0
         self.tier = TIER_DEVICE
+        # A model with sliding-window layers: the window-pool block that
+        # holds this span's rows for them (-1: given back, or never kept)
+        # and the slots whose window tables map it.
+        self.wblock = -1
+        self.wrefs = 0
 
 
 class PagedPrefixIndex:
@@ -127,11 +132,24 @@ class PagedPrefixIndex:
     matching, pinning, adoption, and eviction. A hit under
     ``kv_shard="seq"`` is the same host-side table update; the decode
     merge finds the reused rows wherever they live.
+
+    **Layers whose block counts differ** (a model with sliding-window
+    layers, ``window_alloc`` given): a node may also keep the WINDOW-pool
+    block of its span (``wblock``), adopted from the slot that published
+    it while that slot still held it, i.e. for the last blocks of a
+    published prompt only. A hit must restore every kind's state, so it
+    ends at the deepest boundary whose last ``window_need`` nodes still
+    keep theirs (:meth:`window_depth`), else shallower, else nowhere. A
+    kept window block no slot maps (``wrefs`` 0) is what the window
+    allocator's evictor frees, least recently used first, whatever the
+    node's place in the tree; the node stays, and hits past it fall back.
     """
 
     def __init__(self, *, block: int, alloc: BlockAllocator,
                  max_cached: Optional[int] = None,
-                 host_pool: Optional[Any] = None):
+                 host_pool: Optional[Any] = None,
+                 window_alloc: Optional[BlockAllocator] = None,
+                 window_need: int = 0):
         if block < 1 or block & (block - 1):
             raise ValueError(f"prefix block must be a power of two, "
                              f"got {block}")
@@ -155,6 +173,15 @@ class PagedPrefixIndex:
         # docstring for the block's full journey.
         self.host = host_pool
         alloc.set_evictor(self.evict_one, self.evictable_blocks)
+        self.walloc = window_alloc
+        self.window_need = window_need
+        self._wcached = 0     # window blocks the tree keeps
+        if window_alloc is not None:
+            if host_pool is not None:
+                raise ValueError(
+                    "a host tier under window-layer blocks is not built")
+            window_alloc.set_evictor(
+                self._evict_window_one, self._window_evictable)
 
     # -- stats (the engine snapshots + diffs these per run) ---------------
 
@@ -172,6 +199,8 @@ class PagedPrefixIndex:
             "pool_blocks": (self.max_cached if self.max_cached is not None
                             else self.alloc.blocks),
         }
+        if self.walloc is not None:
+            out["window_blocks_used"] = self._wcached
         if self.host is not None:
             out.update(self.host.stats())
         return out
@@ -266,6 +295,73 @@ class PagedPrefixIndex:
             if best is None or n.last_use < best.last_use:
                 best = n
         return best
+
+    # -- layers whose block counts differ ---------------------------------
+
+    @property
+    def window_blocks_used(self) -> int:
+        return self._wcached
+
+    def window_depth(self, path: List[_Node]) -> int:
+        """The deepest prefix of a matched ``path``, in blocks, at which
+        the window layers' state can be restored too: its last
+        ``window_need`` nodes all keep their window block. 0: no hit."""
+        if self.walloc is None:
+            return len(path)
+        for d in range(len(path), 0, -1):
+            if all(n.wblock >= 0
+                   for n in path[max(0, d - self.window_need):d]):
+                return d
+        return 0
+
+    def window_nodes(self, path: List[_Node]) -> List[Tuple[int, _Node]]:
+        """``(logical block, node)`` of the nodes a hit at the end of
+        ``path`` maps window blocks from."""
+        lo = max(0, len(path) - self.window_need)
+        return list(enumerate(path))[lo:]
+
+    def pin_window(self, nodes: List[_Node]) -> None:
+        """One more slot maps each node's window block."""
+        for n in nodes:
+            assert n.wblock >= 0, "window pin of a node that keeps none"
+            n.wrefs += 1
+
+    def unpin_window(self, nodes: List[_Node]) -> None:
+        for n in nodes:
+            n.wrefs -= 1
+            assert n.wrefs >= 0, "prefix node window-ref underflow"
+
+    def adopt_window(self, node: _Node, bid: int) -> None:
+        """``node`` keeps window-pool block ``bid`` from now on, handed
+        over by the slot that wrote it (which goes on mapping it)."""
+        assert node.wblock < 0 and self.walloc is not None
+        self.walloc.publish(bid)
+        node.wblock, node.wrefs = bid, 1
+        self._wcached += 1
+
+    def _window_victim(self) -> Optional[_Node]:
+        return self._lru_scan(lambda n: n.wblock >= 0 and not n.wrefs)
+
+    def _evict_window_one(self) -> bool:
+        victim = self._window_victim()
+        if victim is None:
+            return False
+        self._free_window(victim)
+        return True
+
+    def _free_window(self, node: _Node) -> None:
+        self.walloc.free_cached(node.wblock)
+        node.wblock = -1
+        self._wcached -= 1
+
+    def _window_evictable(self) -> int:
+        n = 0
+        stack = list(self._root.children.values())
+        while stack:
+            node = stack.pop()
+            stack.extend(node.children.values())
+            n += node.wblock >= 0 and not node.wrefs
+        return n
 
     # -- match / pin ------------------------------------------------------
 
@@ -419,6 +515,10 @@ class PagedPrefixIndex:
         if victim.children:
             return False
         del victim.parent.children[victim.key]
+        if victim.wblock >= 0:
+            # Unpinned, so no slot maps its window block either.
+            assert not victim.wrefs, "evicting a node a window table maps"
+            self._free_window(victim)
         self.alloc.free_cached(victim.block_id)
         self._cached -= 1
         self.evictions += 1
