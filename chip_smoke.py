@@ -323,6 +323,10 @@ def phase_kernels(s: Sizes) -> Dict[str, Any]:
     import jax.numpy as jnp
     import numpy as np
 
+    from tree_attention_tpu.models.decode import (
+        _paged_pool_write,
+        _row_targets,
+    )
     from tree_attention_tpu.ops.decode import gather_paged_kv
     from tree_attention_tpu.ops.pallas_attention import attention_pallas_fwd
     from tree_attention_tpu.ops.pallas_bwd import attention_bwd_pallas
@@ -330,6 +334,7 @@ def phase_kernels(s: Sizes) -> Dict[str, Any]:
         attention_pallas_decode,
         attention_pallas_decode_q8,
         attention_pallas_decode_q8q,
+        paged_row_write,
     )
     from tree_attention_tpu.ops.reference import (
         attention_naive,
@@ -447,6 +452,28 @@ def phase_kernels(s: Sizes) -> Dict[str, Any]:
     got, first, later = _timed(jax.jit(partials))
     record("paged_local_blocks_partial", got, reference(q, *exact, pos),
            first, later, s.tol_kernel)
+
+    # The decode rows' write (ISSUE 39): one new row a slot through the
+    # 8-row tile that holds it, K and V in one call, against the block
+    # path's pools, bit for bit. Slot 0 is idle and (of more than two) the
+    # last slot past its capacity: both write nothing.
+    rows = normal((B, Hkv, 1, D)), normal((B, Hkv, 1, D))
+    start = positions(1)
+    if B > 2:
+        start = start.at[B - 1].set(NB * blk)
+    n_new = jnp.ones((B,), jnp.int32).at[0].set(0)
+
+    def row_write():
+        ids, off = _row_targets(table, start, n_new, N, blk)
+        return paged_row_write(
+            (k_pool, v_pool), rows, ids, off, 0, interpret=it)
+
+    got, first, later = _timed(jax.jit(row_write))
+    want = [_paged_pool_write(p[None], r, table, start, n_new, 0)[0]
+            for p, r in zip((k_pool, v_pool), rows)]
+    record("paged_row_write_tq1", got, want, first, later, 0.0)
+    check(not np.array_equal(np.asarray(got[0]), np.asarray(k_pool)),
+          "paged_row_write_tq1 wrote nothing")
 
     # Prefill forward: a chunk-wide Q tile against the gathered view, each
     # slot at its own offset (what a mixed tick at the chunk bucket runs).

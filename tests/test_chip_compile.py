@@ -11,6 +11,7 @@ HLO. Nothing runs, so this says nothing about results or times — those come
 from ``python chip_smoke.py`` on the chip.
 """
 
+import dataclasses
 import functools
 import json
 import math
@@ -31,7 +32,9 @@ from tree_attention_tpu.ops.pallas_bwd import attention_bwd_pallas
 from tree_attention_tpu.ops.pallas_decode import (
     attention_pallas_decode,
     attention_pallas_decode_q8,
+    ROW_WRITE_KERNEL as ROW_WRITE,
     attention_pallas_decode_q8q,
+    paged_row_write,
 )
 from tree_attention_tpu.ops.tuning import (
     default_block_q,
@@ -46,17 +49,23 @@ T_TRAIN = 2048
 
 
 @functools.lru_cache(maxsize=None)
-def _chip():
-    """One described v5e chip, or None where the topology cannot be
-    described (no TPU compiler in the installation)."""
+def _topology():
+    """The described v5e 2x2 host's devices, or None where the topology
+    cannot be described (no TPU compiler in the installation)."""
     try:
         from jax.experimental import topologies
 
-        topo = topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2")
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2").devices
     except Exception:  # whatever the plugin raises: no compiler, no test
         return None
-    return SingleDeviceSharding(topo.devices[0])
+
+
+@functools.lru_cache(maxsize=None)
+def _chip():
+    """One described v5e chip, or None (see :func:`_topology`)."""
+    devices = _topology()
+    return None if devices is None else SingleDeviceSharding(devices[0])
 
 
 @pytest.fixture(autouse=True, scope="module")
@@ -107,6 +116,23 @@ def _paged(kernel, tq, *, int8=False, tree=False, slots=B, hkv=HKV, nb=NB,
                       **kw)
 
     return fn, args
+
+
+def _row_write(*, int8=False, pools=2, slots=B, hkv=HKV, nb=NB, layers=1,
+               d=D):
+    """(fn, abstract args) of one ``paged_row_write`` call: a row a slot
+    into ``pools`` pools (K and V; the one latent pool: 1 head of 640
+    lanes)."""
+    dtype = jnp.int8 if int8 else jnp.bfloat16
+    args = [_s((layers * slots * nb, hkv, BLK, d), dtype)] * pools \
+        + [_s((slots, hkv, 1, d), dtype)] * pools \
+        + [_s((slots,), jnp.int32)] * 2 + [_s((), jnp.int32)]
+
+    def fn(*a):
+        return paged_row_write(a[:pools], a[pools:2 * pools], *a[2 * pools:],
+                               interpret=False)
+
+    return fn, args, tuple(range(pools))    # the pools donated, as a tick's
 
 
 def _prefill():
@@ -218,6 +244,16 @@ CASES = {
         lambda: _paged(attention_pallas_decode, 127, hkv=32, nb=64,
                        layers=16),
         "flash_decode_paged"),
+    # The decode rows' write (ISSUE 39): K and V in one call at the cells'
+    # shapes, an int8 pool's 8-row tile (a quarter of its packed sublane
+    # tile), the latent pools' one head of 640 lanes, the hybrid's 64 slots.
+    "row_write_mistral7b": (lambda: _row_write(**MISTRAL), ROW_WRITE),
+    "row_write_yi6b": (lambda: _row_write(**YI), ROW_WRITE),
+    "row_write_int8_yi6b": (lambda: _row_write(int8=True, **YI), ROW_WRITE),
+    "row_write_lfm2": (lambda: _row_write(**LFM2), ROW_WRITE),
+    "row_write_latent_32_slots": (
+        lambda: _row_write(pools=1, slots=32, hkv=1, nb=50, layers=8, d=640),
+        ROW_WRITE),
     "prefill_fwd": (_prefill, "flash_fwd"),
     "train_fwd": (_train_fwd_bwd, "flash_fwd"),
     "bwd_dq": (_train_fwd_bwd, "flash_bwd_dq"),
@@ -240,8 +276,9 @@ def _dynamic_grids(text, kernel):
 
 @functools.lru_cache(maxsize=None)
 def _compiled_text(builder) -> str:
-    fn, args = builder()
-    return jax.jit(fn).lower(*args).compile().as_text()
+    fn, args, *donated = builder()
+    return jax.jit(fn, donate_argnums=tuple(*donated)).lower(
+        *args).compile().as_text()
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
@@ -254,6 +291,8 @@ def test_kernel_compiles_for_v5e(case):
     assert kernel in pallas_kernels(text), pallas_kernels(text)
     if kernel.startswith("flash_decode_paged"):
         assert _dynamic_grids(text, kernel) == [True]
+    if kernel == ROW_WRITE:
+        assert _row_writes(text) == 1
     if "_mistral7b" in case or "_yi6b" in case or "_lfm2" in case:
         # The pool goes into the call as it is: no copy, slice or change of
         # layout of a pool-sized array before the launch (what a 576-lane
@@ -263,7 +302,8 @@ def test_kernel_compiles_for_v5e(case):
         moved = [
             (name, opcode, result)
             for name, result, opcode, _ in _materialised(text)
-            if opcode not in _MOVES_NOTHING and any(
+            if opcode not in _MOVES_NOTHING
+            and not name.startswith(f"%{ROW_WRITE}") and any(
                 math.prod(int(d) for d in dims.split(",")) >= pool
                 for dims in re.findall(r"\[([\d,]+)\]", result))
         ]
@@ -314,6 +354,37 @@ def _materialised(text):
                 inner = "\n".join(comps.get(called.group(1), ())) \
                     if called else ""
                 yield m.group(1), m.group(2), m.group(3), inner
+
+
+def _row_writes(text):
+    """The launches of ``paged_row_write`` in the module (ISSUE 39): one row
+    a slot into every pool of a layer, each pool aliased through the call
+    (output ``i`` is the operand the pool came in as), so that the loop's
+    carry, the kernel's operand and its result are one buffer."""
+    lines = [
+        line for line in text.splitlines()
+        if re.match(rf"\s+(?:ROOT )?%{ROW_WRITE}(\.\d+)? = .* custom-call\(",
+                    line)
+    ]
+    for line in lines:
+        pools = line.split(" custom-call(")[0].count("[")
+        aliases = re.search(
+            r"output_to_operand_aliasing=\{(.*?)\}, \w+=", line).group(1)
+        assert len(re.findall(r"\{\d*\}: \(\d+, \{\}\)", aliases)) == pools, \
+            aliases
+    return len(lines)
+
+
+def _block_moves(text, tail, dtype="bf16"):
+    """The gathers and scatters anywhere in the module (fused computations
+    too) whose result is made of pool blocks ``(..., tail)``: what the block
+    path of the pool write holds (the old blocks gathered, the overlaid ones
+    scattered back) and a group of one row a slot must not."""
+    found = [
+        re.match(r"\s+(?:ROOT )?(%[\w.\-]+) = (\S+) (gather|scatter)\(", line)
+        for line in text.splitlines()]
+    return [(m.group(1), m.group(3), m.group(2)) for m in found
+            if m and re.search(rf"\b{dtype}\[[\d,]+,{tail}\]", m.group(2))]
 
 
 @functools.lru_cache(maxsize=None)
@@ -447,12 +518,22 @@ def test_step_keeps_the_pool_in_place(config, tq, int8):
             continue
         if opcode == "scatter" or " scatter(" in inner:
             writes.append(name)       # _paged_pool_write, in place (below)
+        elif name.startswith(f"%{ROW_WRITE}"):
+            continue                  # aliased through (_row_writes)
         elif max(sizes) * D == view:
             views.append((name, opcode, result))
         else:
             moved.append((name, opcode, result))
     assert not moved, moved
-    assert len(writes) == 2, writes  # K's and V's, once in the loop's body
+    hkv = _model(config)[1].n_kv_heads
+    if tq == 1:
+        # One row a slot: K's and V's through one launch of the row kernel
+        # in the loop's body, and no block of the pool gathered or scattered.
+        assert _row_writes(text) == 1 and not writes, writes
+        assert not _block_moves(text, f"{hkv},{BLK},{D}", dtype)
+    else:
+        # A chunk keeps the block path: K's and V's, once in the loop's body.
+        assert len(writes) == 2 and not _row_writes(text), writes
     # The logical view of a slot's blocks, gathered per layer for the
     # Q-tiled prefill kernel of a chunk tick: ROADMAP queue 1 item 3 (the
     # mixed tick), not the pool. A decode tick holds none.
@@ -465,6 +546,54 @@ def test_step_keeps_the_pool_in_place(config, tq, int8):
     assert tick.alias_bytes >= 2 * pool_bytes, tick.alias_bytes
     if tq == 1:
         assert tick.temp_bytes < layer * (1 if int8 else 2), tick.temp_bytes
+
+
+def test_seq_sharded_decode_tick_takes_the_row_kernel_on_four_chips():
+    """The sequence-sharded pool's write is the local call under
+    ``shard_map`` (ISSUE 39): compiled for the four described chips, a
+    decode tick over a pool sharded on its block axis launches
+    ``paged_row_write`` once in the layer loop's body (K and V together, the
+    shard's slice of both aliased through it) and scatters no block."""
+    if _chip() is None:
+        pytest.skip("the v5e:2x2 topology cannot be described here")
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from tree_attention_tpu.models import decode
+    from tree_attention_tpu.models.transformer import (
+        init_params, served_layout)
+
+    c, cfg = _model("yi-6b")
+    cfg = dataclasses.replace(cfg, n_layers=2)
+    mesh = Mesh(np.asarray(_topology()).reshape(4), ("seq",))
+    on = lambda spec: NamedSharding(mesh, spec)
+    shaped = lambda a, spec=P(): jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=on(spec))
+    params = jax.tree.map(shaped, jax.eval_shape(
+        lambda: served_layout(init_params(jax.random.PRNGKey(0), cfg))))
+    slots, blk = 8, c["serving"]["kv_block"]
+    cache = jax.eval_shape(lambda: decode.init_paged_cache(
+        cfg, slots, 2048, slots * 32, block=blk))
+    cache = decode.PagedKVCache(
+        k=shaped(cache.k, P(None, "seq")), v=shaped(cache.v, P(None, "seq")),
+        table=shaped(cache.table), length=shaped(cache.length))
+
+    def step(params, tokens, cache, n_tokens):
+        return decode.forward_step(params, tokens, cache, cfg, mesh=mesh,
+                                   n_tokens=n_tokens, kv_shard="seq")
+
+    i32 = lambda shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                             sharding=on(P()))
+    backend, jax.default_backend = jax.default_backend, lambda: "tpu"
+    try:
+        text = jax.jit(step, donate_argnums=(2,)).lower(
+            params, i32((slots, 1)), cache, i32((slots,))).compile().as_text()
+    finally:
+        jax.default_backend = backend
+    assert _row_writes(text) == 1
+    assert any(k.startswith("flash_decode_paged")
+               for k in pallas_kernels(text))
+    assert not _block_moves(text, f"{cfg.n_kv_heads},{blk},{D}")
 
 
 # -- the packed tick: only the rows that carry a token (ISSUE 30) -----------
@@ -525,11 +654,16 @@ def test_packed_tick_computes_only_its_rows(config, tq, int8):
             continue
         if opcode == "scatter" or " scatter(" in inner:
             writes.append(name)
-        else:
+        elif not name.startswith(f"%{ROW_WRITE}"):
             moved.append((name, opcode, result))
     assert not moved, moved
-    # K's and V's, for the chunk group and for the decode group.
-    assert len(writes) == 4, writes
+    # K's and V's scatters for the chunk group; the decode group's row a
+    # slot goes through the row kernel and scatters nothing.
+    assert len(writes) == 2 and _row_writes(text) == 1, writes
+    hkv = _model(config)[1].n_kv_heads
+    scatters = [m for m in _block_moves(text, f"{hkv},{BLK},{D}", dtype)
+                if m[1] == "scatter"]
+    assert len(scatters) == 2, scatters
     assert tick.alias_bytes >= 2 * layers * layer * (1 if int8 else 2)
 
     c = _model(config)[0]
@@ -647,7 +781,8 @@ def test_latent_step_compiles_and_keeps_the_pool_in_place(
     moved = []
     for name, result, opcode, inner in _materialised(text):
         if opcode in _MOVES_NOTHING or opcode == "scatter" \
-                or " scatter(" in inner:     # _paged_pool_write, in place
+                or " scatter(" in inner \
+                or name.startswith(f"%{ROW_WRITE}"):   # the writes, in place
             continue
         # Blocks of rows, a layer of the pool or more; or a layer's experts.
         of_pool = [math.prod(int(d) for d in dims.split(",")) * blk * row
@@ -665,6 +800,19 @@ def test_latent_step_compiles_and_keeps_the_pool_in_place(
     assert tick.alias_bytes >= pool * 2, tick.alias_bytes
     if tq == 1:
         assert tick.temp_bytes < pool * 2, tick.temp_bytes
+    # The one latent pool's write, once an attention of a layer loop's body
+    # (the leading dense stack's; the expert stack's, two in a double
+    # layer): a group of one row a slot through the row kernel, with no
+    # block gathered or scattered; a chunk group by the block path.
+    loops = bool(cfg.n_dense_layers) + cfg.sublayers
+    scatters = [m for m in _block_moves(text, f"{blk},{row}")
+                if m[1] == "scatter"]
+    if tq == 1:
+        assert _row_writes(text) == loops
+        assert not _block_moves(text, f"{blk},{row}")
+    else:
+        assert _row_writes(text) == (loops if packed else 0)
+        assert scatters       # (a fused computation may be held twice)
 
 
 # -- the hybrid pool: conv tails beside K/V rows of 64-lane heads (ISSUE 33) -
@@ -728,15 +876,23 @@ def test_hybrid_step_compiles_and_keeps_the_pools_in_place(tq, packed):
             continue
         if opcode == "scatter" or " scatter(" in inner:
             writes.append(name)      # the pools' writes, in place (below)
-        else:
+        elif not name.startswith(f"%{ROW_WRITE}"):
             moved.append((name, opcode, result))
     # No copy of a K/V pool or of the tail pool (whole or a layer of it),
     # no slice of a layer's experts or tails out of their stack.
     assert not moved, moved
-    # K's and V's in each of the 3 attention layers (each a run of one) and
-    # the tails' in each of the 4 runs of conv layers, for every group.
+    # The tails' scatter in each of the 4 runs of conv layers, for every
+    # group; K's and V's in each of the 3 attention layers (each a run of
+    # one): a chunk group's by two scatters, a row a slot by one launch of
+    # the row kernel and no block of the K/V pools gathered or scattered.
     groups = 2 if packed else 1
-    assert len(writes) == groups * (2 * 3 + 4), writes
+    assert len(writes) == groups * 4 + (2 * 3 if packed else 0), writes
+    assert _row_writes(text) == 3
+    kv_moves = _block_moves(text, f"4,{blk},128")
+    assert len([m for m in kv_moves if m[1] == "scatter"]) \
+        == (2 * 3 if packed else 0), kv_moves
+    if not packed:
+        assert not kv_moves, kv_moves
     assert tick.alias_bytes >= 2 * (2 * 3 * kv_layer + 9 * tail_layer), \
         tick.alias_bytes
     assert tick.temp_bytes < 2 * kv_layer, tick.temp_bytes
@@ -808,15 +964,21 @@ def test_window_step_compiles_and_copies_neither_pool(tq, packed):
             continue
         if opcode == "scatter" or " scatter(" in inner:
             writes.append(iname)     # the pools' writes, in place (below)
-        else:
+        elif not iname.startswith(f"%{ROW_WRITE}"):
             moved.append((iname, opcode, result))
     # No copy of either kind's K/V pools (whole or a layer of one), no
     # slice of a layer's experts out of their stack.
     assert not moved, moved
-    # K's and V's, for every group: once in each of the 2 full layers (runs
-    # of one) and once in each of the 3 runs of window layers.
-    groups = 2 if packed else 1
-    assert len(writes) == groups * 2 * (2 + 3), writes
+    # Once in each of the 2 full layers (runs of one) and once in each of
+    # the 3 runs of window layers: K's and V's scatters for a chunk group,
+    # one launch of the row kernel for a row a slot (both tables' pools take
+    # it) and no block of either kind's pools gathered or scattered.
+    assert len(writes) == (2 * (2 + 3) if packed else 0), writes
+    assert _row_writes(text) == 2 + 3
+    kv_moves = _block_moves(text, f"8,{blk},128")
+    assert len([m for m in kv_moves if m[1] == "scatter"]) == len(writes)
+    if not packed:
+        assert not kv_moves, kv_moves
     assert tick.alias_bytes >= 2 * 2 * (2 * full_layer + 6 * win_layer), \
         tick.alias_bytes
     assert tick.temp_bytes < full_layer * 2, tick.temp_bytes
@@ -978,6 +1140,8 @@ def test_tick_programs_keep_the_scopes(config, program):
             scopes.ATTN_DECODE, scopes.ATTN_CHUNK)
     if cfg.moe is not None:
         assert kernels["moe_grouped_matmul"] == scopes.EXPERTS
+    # The decode group's row a slot reaches the pool inside the write's part.
+    assert kernels[ROW_WRITE] == scopes.ATTN_CACHE
 
 
 # -- the paged kernels' work lists: built once a tick (ISSUE 37) -------------

@@ -176,3 +176,86 @@ def test_kv_steps_are_what_the_kernels_lists_hold(params, monkeypatch):
     assert sum(run for run, _ in got) < 0.5 * sum(g for _, g in got)
     decode = [r for r in recs if r["kind"] == "decode"]
     assert all(r["kv_steps_grid"] == SLOTS * 128 // KV_BLOCK for r in decode)
+
+
+@pytest.mark.parametrize("path", ["block", "row"])
+def test_pool_rows_are_what_the_device_wrote(params, monkeypatch, path):
+    """The flight record's ``pool_rows_row`` / ``pool_rows_block``
+    (ISSUE 39): the host counts, from the rows it packs, the token rows a
+    tick program writes into the pool (x the layers that cache one) by the
+    write they take. The device's truth is the pool itself: the rows of the
+    K pool that a program changed. ``path`` row: a group of one row a slot
+    goes through ``paged_row_write`` (interpret mode here; what a TPU's
+    programs do), so a decode tick's rows all count there and a packed
+    tick's chunk rows stay on the block path."""
+    from tree_attention_tpu import obs
+    from tree_attention_tpu.models import decode
+
+    if path == "row":
+        monkeypatch.setattr(
+            decode, "pool_write_path",
+            lambda tq: "row" if tq == 1 else "block")
+        from tree_attention_tpu.serving import engine
+        monkeypatch.setattr(engine, "pool_write_path", decode.pool_write_path)
+    server = SlotServer(params, CFG, slots=SLOTS, cache_len=128,
+                        kv_block=KV_BLOCK, prefill_chunk=CHUNK)
+    truth = []
+
+    def spied(program):
+        def call(*args):
+            cache = next(a for a in args if hasattr(a, "table"))
+            before = np.asarray(cache.k[-1]).copy()
+            out = program(*args)
+            after = np.asarray(next(
+                o for o in out if hasattr(o, "table")).k[-1])
+            # (block, row) positions whose K row changed, in the last
+            # layer: there a row is its whole context's, where the first
+            # layer's is its token's and position's alone and a reused
+            # block may be handed the very bits it held.
+            truth.append(
+                CFG.n_layers * int((before != after).any(axis=(1, 3)).sum()))
+            return out
+        return call
+
+    server._mixed, server._packed = spied(server._mixed), \
+        spied(server._packed)
+    rng = np.random.default_rng(4)
+    reqs = [Request(uid=i, max_new_tokens=int(rng.integers(2, 10)),
+                    prompt=rng.integers(0, CFG.vocab_size, int(n))
+                    .astype(np.int32))
+            for i, n in enumerate(rng.integers(3, 60, size=14))]
+    def counter():
+        return {p: obs.REGISTRY.get(
+            "serving_kv_pool_rows_written_total").labels(path=p).value()
+            for p in ("row", "block")}
+
+    FLIGHT.clear()
+    FLIGHT.arm()
+    obs.REGISTRY.enable()
+    try:
+        before = counter()      # another test of this process may have served
+        server.serve(reqs)
+        counted = {p: n - before[p] for p, n in counter().items()}
+    finally:
+        FLIGHT.disarm()
+        obs.REGISTRY.disable()
+        obs.REGISTRY.reset()
+    recs = [r for r in FLIGHT.snapshot()["records"]
+            if r["kind"] in ("decode", "mixed")]
+    FLIGHT.clear()
+    got = [r["pool_rows_row"] + r["pool_rows_block"] for r in recs]
+    assert got == truth and len(got) > 20
+    layers = CFG.n_layers
+    for r in recs:
+        if path == "block":
+            assert r["pool_rows_row"] == 0
+        elif r["kind"] == "decode":
+            assert r["pool_rows_block"] == 0
+            assert r["pool_rows_row"] == r["occupancy"] * layers
+        else:
+            assert r["pool_rows_row"] == r["occupancy"] * layers
+            assert r["pool_rows_block"] == r["chunk_tokens"] * layers
+    assert counted == {
+        "row": sum(r["pool_rows_row"] for r in recs),
+        "block": sum(r["pool_rows_block"] for r in recs)}
+    assert (counted["row"] > 0) == (path == "row")

@@ -138,7 +138,8 @@ class PagedKVCache:
     ``l`` by offset, ``table + l·N`` into the ``(L·N, Hkv, block, D)``
     view — a bitcast, so the pool that enters a tick, the kernels'
     operand and the pool that leaves are one buffer (see
-    :func:`_paged_pool_write` for what that asks of the write).
+    :func:`_paged_pool_write` for what that asks of the write, and
+    :func:`_pool_write` for the two granules it is made at).
     """
 
     k: jax.Array       # (L, N, Hkv, block, D) pool
@@ -172,7 +173,7 @@ class PagedLatentCache:
     TPU compiler copied the whole pool into a layout of its own before
     every launch of the kernel (PERF.md, PR 27). It rides
     :func:`forward_step`'s layer loops as carry and is written by the one
-    :func:`_paged_pool_write`, which sees it as ``(L, N, 1, block, row)``
+    :func:`_pool_write`, which sees it as ``(L, N, 1, block, row)``
     (a bitcast)."""
 
     kv: jax.Array      # (L, N, block, row) pool
@@ -928,38 +929,101 @@ def _paged_pool_write(
     return flat.reshape(pool.shape)
 
 
-def _paged_pool_write_seq(
-    pool: jax.Array,
-    rows: jax.Array,
+def pool_write_path(tq: int) -> str:
+    """Which of the paged pool's two writes a group of ``tq`` rows a slot
+    takes: ``"row"`` (:func:`~tree_attention_tpu.ops.pallas_decode.paged_row_write`:
+    the sublane tile that holds a slot's one new row, in one kernel for
+    every pool of the layer) where a TPU serves a group of one row a slot,
+    ``"block"`` (:func:`_paged_pool_write`) for a chunk group, a verify or
+    tree tick, and off the TPU. One algorithm whose granule follows the row
+    count: decided from what the program observes, the same for every
+    model."""
+    from tree_attention_tpu.ops import _on_tpu, _pallas_available
+
+    return "row" if tq == 1 and _on_tpu() and _pallas_available() \
+        else "block"
+
+
+def _row_targets(table: jax.Array, start: jax.Array, n: jax.Array,
+                 blocks: int, block: int) -> Tuple[jax.Array, jax.Array]:
+    """``(block, row)`` a slot's ONE new token lands in, through its table
+    of ``blocks``-block layers: what :func:`_paged_pool_write` works out for
+    a group of one row a slot, with block -1 for the rows it drops (a slot
+    with ``n`` 0, a position at or past ``NB * block``, a table entry
+    outside ``[0, blocks)``). The same for every layer, so a step program
+    works it out once before its layer loop (:func:`_plan_groups`)."""
+    NB = table.shape[1]
+    pb = jnp.take_along_axis(
+        table, jnp.clip(start // block, 0, NB - 1)[:, None], axis=1)[:, 0]
+    live = (n > 0) & (start < NB * block) & (pb >= 0) & (pb < blocks)
+    return jnp.where(live, pb, -1), start % block
+
+
+def _pool_write(
+    pools: Tuple[jax.Array, ...],
+    rows: Tuple[jax.Array, ...],
+    table: jax.Array,
+    start: jax.Array,
+    n: jax.Array,
+    layer: Union[int, jax.Array],
+    at: Optional[Tuple[jax.Array, jax.Array]] = None,
+) -> Tuple[jax.Array, ...]:
+    """A group's new rows into layer ``layer`` of every pool of that layer
+    (K and V; the one latent pool), by the path :func:`pool_write_path`
+    names. Both leave the same bits: the row path is handed the ONE block
+    and row a slot's token lands in (:func:`_row_targets`; ``at``, where
+    the step worked them out before its layer loop) and no block for the
+    rows the block path drops."""
+    if pool_write_path(rows[0].shape[2]) == "block":
+        return tuple(
+            _paged_pool_write(p, r, table, start, n, layer)
+            for p, r in zip(pools, rows))
+    from tree_attention_tpu.ops.pallas_decode import paged_row_write
+
+    L, N, Hkv, block, D = pools[0].shape
+    ids, off = at if at is not None else _row_targets(
+        table, start, n, N, block)
+    out = paged_row_write(
+        tuple(p.reshape(L * N, Hkv, block, D) for p in pools),
+        tuple(r.astype(p.dtype) for p, r in zip(pools, rows)),
+        ids, off, layer * N)
+    return tuple(o.reshape(p.shape) for o, p in zip(out, pools))
+
+
+def _pool_write_seq(
+    pools: Tuple[jax.Array, ...],
+    rows: Tuple[jax.Array, ...],
     table: jax.Array,
     start: jax.Array,
     n: jax.Array,
     *,
     mesh: Mesh,
     seq_axis: str,
-) -> jax.Array:
-    """:func:`_paged_pool_write` over a sequence-SHARDED pool (ISSUE 18).
+) -> Tuple[jax.Array, ...]:
+    """:func:`_pool_write` over sequence-SHARDED pools (ISSUE 18).
 
-    ``pool`` is one layer's ``(N, Hkv, block, D)`` slice sharded on the
+    Each pool is one layer's ``(N, Hkv, block, D)`` slice sharded on the
     block axis over ``seq_axis`` (a flat ``(L·N)`` view would cut that
-    axis by layers, so this pool is not carried whole: the layer loop
-    slices it, see :func:`forward_step`); the (replicated) ``table``
+    axis by layers, so these pools are not carried whole: the layer loop
+    slices them, see :func:`forward_step`); the (replicated) ``table``
     carries GLOBAL block ids. Under ``shard_map`` each shard rebases the
     table to its own id range ``[s·N/W, (s+1)·N/W)`` and points every
     entry it does NOT own at ``N/W``, outside its local pool — which
-    :func:`_paged_pool_write` drops, so the local scatter writes
-    precisely the rows whose blocks live here and drops the rest.
+    either write drops, so the local call writes precisely the rows whose
+    blocks live here and drops the rest.
     No collectives: a block is owned by exactly one shard, so the union
     of the local writes IS the replicated write, bit for bit.
     """
     n_sh = mesh.shape[seq_axis]
-    n_local = pool.shape[0] // n_sh
+    n_local = pools[0].shape[0] // n_sh
 
-    def body(pool_l, rows_l, table_l, start_l, n_l):
+    def body(pools_l, rows_l, table_l, start_l, n_l):
         s = lax.axis_index(seq_axis)
         loc = table_l - s * n_local
         loc = jnp.where((loc >= 0) & (loc < n_local), loc, n_local)
-        return _paged_pool_write(pool_l[None], rows_l, loc, start_l, n_l, 0)[0]
+        out = _pool_write(
+            tuple(p[None] for p in pools_l), rows_l, loc, start_l, n_l, 0)
+        return tuple(o[0] for o in out)
 
     return shard_map(
         body,
@@ -967,7 +1031,7 @@ def _paged_pool_write_seq(
         in_specs=(P(seq_axis), P(), P(), P(), P()),
         out_specs=P(seq_axis),
         check_vma=False,
-    )(pool, rows, table, start, n)
+    )(pools, rows, table, start, n)
 
 
 def paged_insert_slot(
@@ -1098,6 +1162,8 @@ class _RowGroup(NamedTuple):
     plan: Any = None      # the paged kernels' work list (:func:`_plan_groups`)
     wtable: Optional[jax.Array] = None  # the window layers' table
     wplan: Any = None     # ... and their work list (a window's steps)
+    at: Any = None        # one row a slot: its (block, row) (_row_targets)
+    wat: Any = None       # ... under the window layers' table
 
     @property
     def n_valid(self) -> jax.Array:
@@ -1133,13 +1199,19 @@ def _plan_groups(groups: Tuple[_RowGroup, ...], cache: Any,
     kernels will not serve (a chunk of 128 rows or more on the Q-tiled
     kernel) leaves its list unused, and the compiler drops it. A cache
     with a second table (:class:`PagedWindowCache`) gets a second list a
-    group, the window layers': two plans a tick, one a kind."""
+    group, the window layers': two plans a tick, one a kind. A group of one
+    row a slot also gets, for the same reason and behind the same barrier,
+    the ``(block, row)`` its write lands in (:func:`_row_targets`, under
+    either table): a layer's :func:`_pool_write` then adds ``l * N`` inside
+    its kernel and the loop's body holds nothing of the write but the
+    launch."""
     from tree_attention_tpu.ops.pallas_decode import decode_plan, mla_plan
 
     def barrier(plan):
         # Behind a barrier: the compiler otherwise clones the cheapest
         # of a short list's operations back into the loop's body.
-        return type(plan)(*lax.optimization_barrier(tuple(plan)))
+        out = lax.optimization_barrier(tuple(plan))
+        return type(plan)(*out) if hasattr(plan, "_fields") else out
 
     planned = []
     for g in groups:
@@ -1155,6 +1227,14 @@ def _plan_groups(groups: Tuple[_RowGroup, ...], cache: Any,
                 g = g._replace(wplan=barrier(decode_plan(
                     cfg.n_heads, g.tq, cache.wk, g.wtable, g.start,
                     window=cfg.window)))
+        if pool_write_path(g.tq) == "row":
+            with jax.named_scope(scopes.ATTN_CACHE):
+                g = g._replace(at=barrier(_row_targets(
+                    g.table, g.start, g.n_valid, cache.blocks, cache.block)))
+                if g.wtable is not None:
+                    g = g._replace(wat=barrier(_row_targets(
+                        g.wtable, g.start, g.n_valid, cache.window_blocks,
+                        cache.block)))
         planned.append(g)
     return tuple(planned)
 
@@ -1246,7 +1326,7 @@ class _Attend:
         g = groups[gi]
         if self.window is not None:
             # A window layer's group: the same rows under the second table.
-            g = g._replace(table=g.wtable, plan=g.wplan)
+            g = g._replace(table=g.wtable, plan=g.wplan, at=g.wat)
         B, Tq = g.batch, g.tq
         start, n_valid = g.start, g.n_valid
         with jax.named_scope(scopes.ATTN_CACHE):
@@ -1300,25 +1380,19 @@ class _Attend:
                 k_new = _quantize_rows(k_new, k_s)
                 v_new = _quantize_rows(v_new, v_s)
             if paged:
-                # Paged write: scatter through the block table — valid rows
-                # land in their slot's mapped blocks, padded rows drop. The
-                # contiguous path's window clamp machinery is unnecessary
-                # here (see _paged_pool_write).
+                # Paged write, through the block table: valid rows land
+                # in their slot's mapped blocks, padded rows drop (K and V
+                # in one call: _pool_write). The contiguous path's window
+                # clamp machinery is unnecessary here.
                 if seq_sharded:
-                    k_cache = _paged_pool_write_seq(
-                        k_cache, k_new, g.table, start, n_valid,
-                        mesh=mesh, seq_axis=axes["seq"],
-                    )
-                    v_cache = _paged_pool_write_seq(
-                        v_cache, v_new, g.table, start, n_valid,
-                        mesh=mesh, seq_axis=axes["seq"],
+                    k_cache, v_cache = _pool_write_seq(
+                        (k_cache, v_cache), (k_new, v_new), g.table, start,
+                        n_valid, mesh=mesh, seq_axis=axes["seq"],
                     )
                 else:
-                    k_cache = _paged_pool_write(
-                        k_cache, k_new, g.table, start, n_valid, l
-                    )
-                    v_cache = _paged_pool_write(
-                        v_cache, v_new, g.table, start, n_valid, l
+                    k_cache, v_cache = _pool_write(
+                        (k_cache, v_cache), (k_new, v_new), g.table, start,
+                        n_valid, l, g.at,
                     )
                 if hoist_view:
                     # Mirror the new rows into the hoisted logical view (the
@@ -1548,9 +1622,9 @@ def _latent_layers(
         outs = []
         for g in groups:
             with jax.named_scope(scopes.ATTN_CACHE):
-                pool = _paged_pool_write(
-                    pool[:, :, None], g.take(rows), g.table, g.start,
-                    g.n_valid, l)[:, :, 0]
+                pool = _pool_write(
+                    (pool[:, :, None],), (g.take(rows),), g.table, g.start,
+                    g.n_valid, l, g.at)[0][:, :, 0]
             with jax.named_scope(
                     scopes.ATTN_CHUNK if g.chunk else scopes.ATTN_DECODE):
                 out_lat, _ = latent_attention(
@@ -1898,7 +1972,7 @@ def forward_step(
     ``kv_shard="seq"`` (paged caches under a >1-way ``seq_axis`` mesh
     only — see :func:`init_paged_cache`) declares the pool
     block-sharded: per-layer KV writes and attention both run under
-    ``shard_map`` (:func:`_paged_pool_write_seq`,
+    ``shard_map`` (:func:`_pool_write_seq`,
     :func:`~tree_attention_tpu.parallel.tree.paged_tree_decode` — each
     shard computes flash partials over only its local blocks, merged by
     the 3-collective tree monoid). ``tree_mask`` is not supported there
@@ -1944,7 +2018,7 @@ def forward_step(
     A replicated paged pool (:class:`PagedKVCache`,
     :class:`PagedQuantKVCache`) rides the layer loop as loop-carried
     state: each layer's rows are written into the whole pool in place
-    (:func:`_paged_pool_write`) and attention — the block-table kernels
+    (:func:`_pool_write`) and attention — the block-table kernels
     on TPU, the hoisted reference view elsewhere — addresses layer ``l``
     through ``table + l·N``. Nothing slices, restacks or copies the pool,
     and the compiled tick must stay so

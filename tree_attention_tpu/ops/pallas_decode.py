@@ -1109,6 +1109,154 @@ def attention_pallas_mla_paged(
             lse[:, :r, 0].reshape(B, H, Tq))
 
 
+ROW_WRITE_KERNEL = "paged_row_write"
+
+
+def row_write_tile(block: int) -> int:
+    """Rows of a pool block that :func:`paged_row_write` moves for one new
+    row: the 8 rows of the tile the compiler lays a pool out in on the chip
+    (``T(8,128)`` whatever the dtype; a packed dtype's rows share words
+    inside it), the least a copy may cut out of the pool; the whole block
+    where 8 does not divide it. (The packed sublane tile, 16 rows of bf16,
+    moves twice the bytes for 0.1-1.8 us more a call: ``ops/tuning.py``.)"""
+    return block if block % 8 else 8
+
+
+def _paged_row_write_kernel(
+    ids_ref,   # SMEM (B,) scalar-prefetch: slot b's pool block in its
+               # layer, or -1: the slot writes nothing
+    off_ref,   # SMEM (B,) scalar-prefetch: the row of that block
+    base_ref,  # SMEM (1,) scalar-prefetch: the layer's first block (l * N)
+    *refs,     # rows x P     VMEM (B, Hkv, 1, D): the new rows, a pool each
+               # pools_in x P HBM: the aliased inputs, reached as outputs
+               # pools x P    HBM (M, Hkv, block, D)
+               # tiles x P    VMEM (B, Hkv, tile, D) scratch
+               # sem          DMA semaphores (P, B)
+    n_pools: int,
+    tile: int,
+):
+    """One new row a slot into every pool, through the 8-row tile that
+    holds it (:func:`row_write_tile`): each live slot's tile is copied in,
+    the row overlaid at its sublane, the tile copied back to where it came
+    from. Every slot's copy
+    in is started before any is awaited, and every copy back before any of
+    those, so a call costs two copies' latency and not ``2 x B``. A slot
+    with ``ids[b] < 0`` starts no copy at all: its table entry may be a
+    stale name of the block a live slot writes in this very call, and a
+    tile read and written back unchanged would race that row. Live slots
+    never share a writable block, so no two copies meet."""
+    P = n_pools
+    rows, pools, tiles, sem = (
+        refs[:P], refs[2 * P:3 * P], refs[3 * P:4 * P], refs[4 * P])
+    B = rows[0].shape[0]
+
+    def home(p, b):
+        at = pl.multiple_of(off_ref[b] // tile * tile, tile)
+        return pools[p].at[base_ref[0] + ids_ref[b], :, pl.ds(at, tile), :]
+
+    def fetch(p, b):
+        return pltpu.make_async_copy(home(p, b), tiles[p].at[b], sem.at[p, b])
+
+    def store(p, b):
+        return pltpu.make_async_copy(tiles[p].at[b], home(p, b), sem.at[p, b])
+
+    def every_live(do):
+        def slot(b, carry):
+            @pl.when(ids_ref[b] >= 0)
+            def _live():
+                for p in range(P):
+                    do(p, b)
+            return carry
+
+        lax.fori_loop(0, B, slot, 0)
+
+    def overlay(p, b):
+        fetch(p, b).wait()
+        old = tiles[p][b]
+        here = lax.broadcasted_iota(jnp.int32, old.shape, 1) \
+            == off_ref[b] % tile
+        tiles[p][b] = jnp.where(here, rows[p][b], old)
+        store(p, b).start()
+
+    every_live(lambda p, b: fetch(p, b).start())
+    every_live(overlay)
+    every_live(lambda p, b: store(p, b).wait())
+
+
+def paged_row_write(
+    pools: Tuple[jax.Array, ...],
+    rows: Tuple[jax.Array, ...],
+    block_ids: jax.Array,
+    offsets: jax.Array,
+    base,
+    *,
+    interpret: Optional[bool] = None,
+) -> Tuple[jax.Array, ...]:
+    """Write one new row a slot into paged pools, in place.
+
+    ``pools`` are ``(M, Hkv, block, D)`` arrays of one shape and dtype (K
+    and V of every layer as the decode kernels see them, or the one latent
+    pool with ``Hkv`` 1), ``rows`` a ``(B, Hkv, 1, D)`` array a pool in the
+    pool's dtype. Slot ``b``'s row lands at ``pool[base + block_ids[b], :,
+    offsets[b], :]``; a slot with ``block_ids[b] < 0`` writes nothing and
+    touches nothing. The caller keeps live slots' blocks distinct and
+    ``base + block_ids`` inside the pool. Each pool is aliased to its
+    output, so under a donating ``jit`` the pool that enters is the one
+    that leaves, and a row moves :func:`row_write_tile` rows of its block
+    each way and no more. The device event is ``paged_row_write``."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    return _paged_row_write_call(
+        tuple(pools), tuple(rows), jnp.asarray(block_ids, jnp.int32),
+        jnp.asarray(offsets, jnp.int32),
+        jnp.asarray(base, jnp.int32).reshape(1), interpret=interpret)
+
+
+# Jitted, as the kernels above: a step program launches it once for every run
+# of layers (five in a window configuration's) and every one of its calls
+# traces and lowers the kernel body afresh otherwise, 0.03-0.08 s a call site
+# in every tick program's set-up, compile cache or not.
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _paged_row_write_call(pools, rows, block_ids, offsets, base, *,
+                          interpret: bool):
+    P = len(pools)
+    M, Hkv, block, D = pools[0].shape
+    B = rows[0].shape[0]
+    dtype = pools[0].dtype
+    if any(p.shape != pools[0].shape or p.dtype != dtype for p in pools) \
+            or any(r.shape != (B, Hkv, 1, D) or r.dtype != dtype
+                   for r in rows) or len(rows) != P:
+        raise ValueError(
+            f"paged_row_write takes pools of one shape and dtype and a "
+            f"(B, {Hkv}, 1, {D}) {dtype} row array a pool, got pools "
+            f"{[(p.shape, p.dtype) for p in pools]} and rows "
+            f"{[(r.shape, r.dtype) for r in rows]}"
+        )
+    tile = row_write_tile(block)
+    if obs.REGISTRY.enabled:
+        # One step a call: every KV head of one table entry a slot.
+        _KERNEL_BUILDS.labels(
+            kernel=ROW_WRITE_KERNEL, heads=Hkv, entries=B).inc()
+    scalars = (block_ids, offsets, base)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(scalars),
+        grid=(),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)] * P
+        + [pl.BlockSpec(memory_space=pl.ANY)] * P,
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * P,
+        scratch_shapes=[pltpu.VMEM((B, Hkv, tile, D), dtype)] * P
+        + [pltpu.SemaphoreType.DMA((P, B))],
+    )
+    return tuple(pl.pallas_call(
+        functools.partial(_paged_row_write_kernel, n_pools=P, tile=tile),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools],
+        input_output_aliases={len(scalars) + P + i: i for i in range(P)},
+        interpret=interpret,
+        name=ROW_WRITE_KERNEL,
+    )(*scalars, *rows, *pools))
+
+
 def _packed_rows(r: int, cap: int = 128) -> int:
     """Rows of a Q tile for ``r`` packed rows a KV head: a multiple of the
     8 sublanes, ``cap`` at most."""

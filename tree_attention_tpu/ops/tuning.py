@@ -146,6 +146,49 @@ def decode_block_k_q8(tk: int) -> int:
 # left in "served": the whole step at each slot's tail (half a step a slot
 # on average: 16 x 128 tokens of Mistral's 15,300 live ones) and, for the
 # latent kernel, the operations (near the ridge at 128 heads).
+#
+# The pool WRITE of a decode tick (ISSUE 39): one new row a slot into the
+# pools of a layer. Measured on v5e 2026-09-30, a scratch sweep of the write
+# alone at each cell's decode shape (slots x KV heads x lanes; blocks of 64
+# rows; K and V together, or the one latent pool): 128 calls in one program
+# over a 16-layer pool donated to it, a call's layer i % 16, one slot idle,
+# the (block, row) targets worked out once outside the loop as a tick program
+# does; us a call, best of 7; every variant's pool bit-equal to the block
+# path's.
+#
+#   us a call                 block path   row kernel   its tile   pipelined
+#                             (_paged_     (8-row tile, the packed  grid, a
+#                             pool_write)  chosen)      sublane's  row a step
+#   Mistral 16 x 8 x 128 K+V     42.2          9.7        10.5       15.9
+#   K-EXAONE 32 x 8 x 128 K+V    71.1         12.1        13.7       22.9
+#   LFM2 64 x 4 x 128 K+V        92.9         17.4        19.4       31.2
+#   LongCat 32 x 1 x 640         41.0          8.3         8.3       16.5
+#   Yi-6B 8 x 4 x 128 K+V        22.2          7.2         7.4       10.3
+#   DeepSeek-V2 16 x 1 x 640     23.9          6.2         6.1       11.8
+#   Yi-6B int8 8 x 4 x 128 K+V   20.8          7.4         7.6       10.6
+#
+# The block path gathers, overlays and scatters a whole block a slot (64-131
+# KB for one 2 KB row): 1.1-1.4 us a slot-block and pool. The row kernel
+# (`pallas_decode.paged_row_write`) is ONE launch for every pool of the layer
+# and no grid: it starts a copy of every live slot's 8-row tile into fast
+# memory, then for each awaits it, overlays the row under an iota mask and
+# starts the copy back, then awaits those: ~6 us a call (the launch and two
+# copies' latency) + 0.2-0.25 us a slot (four copies' descriptors). Not
+# chosen, and why:
+# - the rows copied straight into the pool from fast memory (2 KB a slot
+#   where a tile is 16): the compile for the chip refuses a one-row slice of
+#   a packed array, on either side of the copy ("Slice shape along dimension
+#   2 must be aligned to tiling (2), but is 1"; 4 for int8);
+# - a pipelined grid, a live row a step on a list of dynamic length, the tile
+#   as an aliased in/out block (the issue's first shape): 0.3-0.45 us a step
+#   for its operands' index maps and a tile's copy in awaited before its copy
+#   out starts, 1.4-2.0x the chosen one at every shape;
+# - the packed dtype's sublane tile (16 rows of bf16, 32 of int8): twice to
+#   four times the bytes for nothing; the pool's layout on the chip is tiled
+#   by 8 rows whatever the dtype (`T(8,128)(2,1)`, `T(8,128)(4,1)`), and an
+#   8-row cut compiles and reads back bit-equal for bf16 and int8 alike.
+# A chunk group (Tq > 1) keeps the block path: PR 25 measured a row scatter
+# at ~70 ns a row there and a block is what 5 blocks of 64 rows want.
 PAGED_STEP_TARGET_BYTES = 1 << 20
 PAGED_STEP_ENTRIES = (1, 2, 4, 8)  # divisors of the 8-row scale tile
 # What a step may hold of the 16 MB of scoped VMEM a v5e kernel gets by

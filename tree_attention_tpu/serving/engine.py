@@ -162,6 +162,7 @@ from tree_attention_tpu.models.decode import (
     insert_dequant_prefix,
     paged_insert_slot,
     paged_step_tokens,
+    pool_write_path,
     quantize_paged_blocks,
     sample_rows,
     sample_slots,
@@ -264,6 +265,14 @@ _TAIL_BLOCKS = obs.counter(
     "serving_conv_tail_blocks_written_total",
     "block tails the conv layers wrote (blocks a tick's rows fell in x conv "
     "layers)",
+)
+_POOL_ROWS = obs.counter(
+    "serving_kv_pool_rows_written_total",
+    "token rows the tick programs wrote into the paged pool (a tick's rows x "
+    "the layers that cache a row), by the write they took: row (one row a "
+    "slot through paged_row_write) or block (whole blocks gathered, "
+    "overlaid and scattered back)",
+    labels=("path",),
 )
 _TTFT = obs.histogram(
     "serving_ttft_seconds",
@@ -510,6 +519,9 @@ class _Tail:
     # (entries of the paged kernels' work lists, of the whole slots x steps
     # rectangles) over the program's groups of rows, a layer.
     kv_steps: Tuple[int, int] = (0, 0)
+    # Rows x layers the program wrote into the pool: (by the row kernel, by
+    # the block path) (``_count_pool_rows``).
+    pool_rows: Tuple[int, int] = (0, 0)
     # The head's per-tick counters, frozen when the tail is left pending
     # (the iteration that lands it has counted its own by then).
     counts: Optional[Dict[str, Any]] = None
@@ -1255,6 +1267,11 @@ class SlotServer:
         # grid step of the paged kernel takes by a group's rows a slot.
         self._kv_len = np.zeros((slots,), np.int64)
         self._kv_step_tokens: Dict[Tuple[str, int], Optional[int]] = {}
+        # The layers that cache a token row (``_count_pool_rows``): every
+        # pool's under either table, K and V counted once.
+        self._pool_layers = sum(
+            getattr(self.cache, name).shape[0]
+            for name in ("k", "wk", "kv") if hasattr(self.cache, name))
 
         # Host mirror of slot state (the scheduler's view; device state is
         # the cache + the token vector the mixed step carries). States:
@@ -1617,6 +1634,27 @@ class SlotServer:
             PAGED_STEPS.labels(steps="run").inc(run)
             PAGED_STEPS.labels(steps="grid").inc(grid)
         return run, grid
+
+    def _count_pool_rows(self, tq, n_vec, chunk) -> Tuple[int, int]:
+        """Rows the program being dispatched writes into the paged pool,
+        times the layers that cache a row: ``(pool_rows_row,
+        pool_rows_block)`` of its flight record, by the write each group
+        of rows takes (``models/decode.py`` ``pool_write_path``: one row a
+        slot on a TPU goes through ``paged_row_write``, everything else
+        moves whole blocks). Counted from the rows the host packed:
+        ``n_vec`` a slot and, for a packed program, the chunk group's
+        ``chunk`` ``(slots, counts)`` beside them."""
+        rows = {"row": 0, "block": 0}
+        if chunk is not None:
+            rows[pool_write_path(tq)] += int(np.sum(chunk[1]))
+            tq = 1
+        rows[pool_write_path(tq)] += int(n_vec.sum())
+        out = (rows["row"] * self._pool_layers,
+               rows["block"] * self._pool_layers)
+        if obs.REGISTRY.enabled:
+            _POOL_ROWS.labels(path="row").inc(out[0])
+            _POOL_ROWS.labels(path="block").inc(out[1])
+        return out
 
     def _sample_emit(self, last, keys, temp, topk, idx):
         """The ONE per-slot sampling call every emitting program shares
@@ -4817,6 +4855,10 @@ class SlotServer:
                     # rectangles (``_count_kv_steps``).
                     "kv_steps_run": p.kv_steps[0],
                     "kv_steps_grid": p.kv_steps[1],
+                    # Token rows x layers written into the pool, by the
+                    # write they took (``_count_pool_rows``).
+                    "pool_rows_row": p.pool_rows[0],
+                    "pool_rows_block": p.pool_rows[1],
                     # Dispatched before the tail of the program before
                     # it landed (ISSUE 32), or why not.
                     "ahead": p.ahead,
@@ -5520,6 +5562,10 @@ class SlotServer:
                             else self._count_kv_steps(
                                 tick_tq, n_vec, reset, reset_val, kv_rows,
                                 kv_chunk)),
+                        pool_rows=(
+                            (0, 0) if n_vec is None
+                            else self._count_pool_rows(
+                                tick_tq, n_vec, kv_chunk)),
                     )
                     primed = True
                     self._tail = cur
