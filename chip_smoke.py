@@ -1365,6 +1365,98 @@ def phase_window(s: Sizes, config: Optional[Dict[str, Any]] = None, *,
     }
 
 
+def phase_state(s: Sizes, config: Optional[Dict[str, Any]] = None, *,
+                device: str = "tpu", block: int = 64, chunk: int = 256,
+                tol_gap: float = 0.4) -> Dict[str, Any]:
+    """State-space mixers beside one attention layer
+    (``benchmark/configs/nemotron-3-super-120b-a12b.json``: 5 Mamba-2
+    layers, 1 GQA layer with no positional term, 5 LatentMoE parts at their
+    published widths) served on one chip through ``cli.build_serve_engine``
+    and ``SlotServer.serve`` with the reference's seeded weights, from a
+    cache that is a paged K/V pool and a recurrent state a slot. In an
+    engine of two slots: a long request whose prompt leaves chunks that do
+    not end on a multiple of the scan's block (two whole chunks and a rest
+    of 77 rows); beside it a short one; once the short one has retired, a
+    third in its slot (it finds the last request's state and tail there and
+    starts from zero all the same) while the long one still decodes; then a
+    fourth alone, the other slot sitting every tick out. Every served token
+    is held to the plain reference's logits (``benchmark/references/nemotron_h.py``: the
+    token-by-token recurrence, no cache) as :func:`phase_hybrid` holds its
+    own: a request's MEAN gap lies under ``tol_gap``; a stale state leaves
+    every later token at the logits' own spread."""
+    import numpy as np
+
+    from benchmark import check as served
+    from benchmark.spec import Spec
+    from tree_attention_tpu import cli
+    from tree_attention_tpu.serving.engine import Request
+    from tree_attention_tpu.utils.config import parse_args
+
+    spec = Spec(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                             "BENCHMARK.json"))
+    if config is None:
+        config = spec.load_json("configs", "nemotron-3-super-120b-a12b.json")
+    ref = spec.load_module("references", config["family"] + ".py")
+    adapter = spec.load_module("adapters", config["family"] + ".py")
+    w = ref.Widths.of(config)
+    weights = ref.init_weights(3, w)
+    rng = np.random.default_rng(23)
+    vocab = int(config["vocab_size"])
+    rest = chunk * 77 // 256 or 1
+    a = rng.integers(0, vocab, (2 * chunk + rest,)).tolist()
+    b = rng.integers(0, vocab, (block // 2 + 3,)).tolist()
+    c = rng.integers(0, vocab, (block + block // 3,)).tolist()
+    new_a, new = 3 * block // 2, block // 4 + 4
+    flags = ["--mode", "serve", "--device", device,
+             "--dtype", str(config["torch_dtype"]), "--slots", "2",
+             "--prompt-len", str(len(a)), "--prompt-jitter", "0",
+             "--max-new-tokens", str(new_a), "--prefill-chunk", str(chunk),
+             "--prefix-block", str(block),
+             "--temperature", "0", "--seed", "1"]
+    setup = cli.build_serve_engine(
+        parse_args(flags), None, model=config,
+        params=adapter.engine_params(weights, w))
+    check(setup.tcfg.cache_kind == "state", "the model caches a state a slot")
+    server = setup.make_engine()
+
+    # Two slots: the third request waits for the short one's slot, and
+    # the fourth, alone, leaves the other slot without a row in any tick.
+    report = server.serve([
+        Request(uid=0, prompt=a, max_new_tokens=new_a),
+        Request(uid=1, prompt=b, max_new_tokens=new),
+        Request(uid=2, prompt=c, max_new_tokens=new)])
+    alone = server.serve([Request(uid=3, prompt=b[::-1], max_new_tokens=new)])
+    results = list(report.results) + list(alone.results)
+    prompts = {0: a, 1: b, 2: c, 3: b[::-1]}
+    check(len(results) == 4 and all(r.outcome == "budget" for r in results),
+          "four requests served to their budgets through two slots")
+    check(len(a) % int(config.get("chunk_size", 128)) != 0,
+          "the long prompt does not end on a multiple of the scan's block")
+    leak = server.leak_report()
+    check(not (leak["blocks_used"] or leak["blocks_private"]
+               or leak["blocks_reserved"] or leak["pins"]),
+          f"no block leaked ({leak})")
+    gaps = [served.served_gaps(ref, weights, w, np.asarray(prompts[r.uid]),
+                              np.asarray(r.tokens))[0] for r in results]
+    means = [float(g.mean()) for g in gaps]
+    check(max(means) <= tol_gap,
+          f"a request's served tokens lie {max(means):.3f} under the "
+          f"reference's best on average (limit {tol_gap}; {means})")
+    cache = server.cache
+    return {
+        "layers": list(setup.tcfg.layer_types),
+        "ffn": list(setup.tcfg.ffn_kinds),
+        "ssm_state": list(cache.ssm_state.shape),
+        "ssm_state_dtype": str(cache.ssm_state.dtype),
+        "ssm_tail": list(cache.ssm_tail.shape),
+        "kv_token_bytes": report.kv["token_bytes"],
+        "tokens_compared": int(sum(len(g) for g in gaps)),
+        "gap_max": float(max(g.max() for g in gaps)),
+        "gap_mean": float(np.concatenate(gaps).mean()),
+        "gap_mean_by_request": means,
+    }
+
+
 # ---------------------------------------------------------------------------
 # main
 # ---------------------------------------------------------------------------
@@ -1380,6 +1472,7 @@ def run_default(run: Run, s: Sizes) -> None:
     run.phase("programs", phase_programs, s)
     run.phase("hybrid", phase_hybrid, s)
     run.phase("window", phase_window, s)
+    run.phase("state", phase_state, s)
 
 
 def run_four_chips(run: Run, s: Sizes) -> None:

@@ -58,6 +58,11 @@ AFTER_PARTS = ["paged_steps_run_pct"]
 WINDOW_METRICS = ["window_attn_ms_tick", "window_decode_paged_roofline",
                   "window_blocks_held_pct"]
 KX_CELL = "kexaone_reasoning_long_sat"
+# What PR 40 appended last, for its own cell.
+STATE_METRICS = ["ssm_update_ms_tick", "ssm_decode_update_roofline",
+                 "moe_ungated_ms_tick", "moe_ungated_matmul_roofline",
+                 "ssm_states_advanced_pct"]
+NS_CELL = "nemotron3s_agentturn_sat"
 
 
 def _sources(but=()):
@@ -455,7 +460,7 @@ def test_tick_ahead_pct_reads_the_look_ahead_counter(flight, want):
     # PR 35's parts).
     assert [m["name"] for m in listed[at + 1:]] == [
         "mixer_rest_ms_tick", "mixer_rest_stream_roofline"] + PARTS_ALL \
-        + AFTER_PARTS + WINDOW_METRICS
+        + AFTER_PARTS + WINDOW_METRICS + STATE_METRICS
     for w in json.load(open(BENCH))["workloads"]:
         assert "tick_ahead_pct" in [
             m["name"] for m in spec.cell(w["name"]).per_layer]
@@ -484,7 +489,7 @@ def test_the_hybrid_cell_resolves_every_file_it_names():
     # The traffic file that was there, and the cell the sixth of six.
     assert cell.traffic == lc.traffic and cell.traffic["kind"] == "backlog"
     assert [w["name"] for w in spec.data["workloads"]][5:] == [
-        LFM_CELL, KX_CELL]
+        LFM_CELL, KX_CELL, NS_CELL]
     assert all(w["chips"] == 1 for w in spec.data["workloads"])
     assert [m["name"] for m in cell.end_to_end] == [
         "out_tok_s", "tbt_p50_ms", "tbt_p99_ms", "setup_s"]
@@ -678,20 +683,24 @@ def test_the_rest_metrics_read_nothing_where_there_is_nothing(name):
 def test_a_parts_metric_is_listed_where_its_part_exists(name):
     """Fourteen entries appended by PR 35: device-trace metrics of the tick
     programs, the decode ones moving ``tbt_p50_ms`` and the mixed ones
-    ``tbt_p99_ms``; the expert parts in the four expert cells, the conv
-    parts in the hybrid's alone, the rest in all seven."""
+    ``tbt_p99_ms``; the expert parts in the five expert cells, the conv
+    parts in the hybrid's and the state configuration's, the rest in all
+    eight."""
     spec = Spec(BENCH)
     listed = json.load(open(BENCH))["per_layer"]
-    tail = len(PARTS_ALL) + len(AFTER_PARTS) + len(WINDOW_METRICS)
+    tail = len(PARTS_ALL) + len(AFTER_PARTS) + len(WINDOW_METRICS) \
+        + len(STATE_METRICS)
     assert [m["name"] for m in listed[-tail:]] \
-        == PARTS_ALL + AFTER_PARTS + WINDOW_METRICS
+        == PARTS_ALL + AFTER_PARTS + WINDOW_METRICS + STATE_METRICS
     entry = next(m for m in listed if m["name"] == name)
     cells = [w["name"] for w in spec.data["workloads"]]
     want = cells
     if "_moe_" in name:
-        want = [CELL, LC_CELL, LFM_CELL, KX_CELL]
+        want = [CELL, LC_CELL, LFM_CELL, KX_CELL, NS_CELL]
     elif "_conv_" in name:
-        want = [LFM_CELL]
+        # The part ``conv``: a mixer with a fixed-size state (the short
+        # convolution; a state-space mixer between its projections).
+        want = [LFM_CELL, NS_CELL]
     if name.startswith("mix_"):
         # The window cell's traced 3 s hold a chunk tick in most runs and
         # none in some (1.5 a second, in clusters): a reader that finds
@@ -735,12 +744,13 @@ def test_paged_steps_run_pct_reads_the_lists_the_kernels_walk():
     spec = Spec(BENCH)
     listed = json.load(open(BENCH))["per_layer"]
     cells = [w["name"] for w in spec.data["workloads"]]
-    assert listed[-1 - len(WINDOW_METRICS)] == {
+    assert listed[-1 - len(WINDOW_METRICS) - len(STATE_METRICS)] == {
         "name": "paged_steps_run_pct", "unit": "%", "better": "lower",
         "source": "program_counter", "layer": "kernels",
         "moves": "tbt_p50_ms", "workloads": cells}
     for cell in cells:
-        last = -1 - len(WINDOW_METRICS) * (cell == KX_CELL)
+        last = -1 - len(WINDOW_METRICS) * (cell == KX_CELL) \
+            - len(STATE_METRICS) * (cell == NS_CELL)
         assert spec.cell(cell).per_layer[last]["name"] \
             == "paged_steps_run_pct"
     read = spec.load_module("layer_metrics", "paged_steps_run_pct.py").read
@@ -782,7 +792,8 @@ def test_the_window_cell_resolves_every_file_it_names():
     # none on four chips.
     assert cell.traffic["kind"] == "backlog"
     assert spec.find("traffic", "reasoning_long_backlog.json")
-    assert [w["name"] for w in spec.data["workloads"]][6:] == [KX_CELL]
+    assert [w["name"] for w in spec.data["workloads"]][6:] == [
+        KX_CELL, NS_CELL]
     assert all(w["chips"] == 1 for w in spec.data["workloads"])
     assert [m["name"] for m in cell.end_to_end] == [
         "out_tok_s", "tbt_p50_ms", "tbt_p99_ms", "setup_s"]
@@ -968,3 +979,193 @@ def test_window_blocks_held_pct_reads_the_ledgers_counters():
     ]
     run = types.SimpleNamespace(flight=flight, t_open=1.0, t_end=10.0)
     assert read(run) == pytest.approx(100.0 * 186 / 4000)
+
+
+# -- a recurrent state a slot: the state cell (ISSUE 40) ---------------------
+
+
+def test_the_state_cell_resolves_every_file_it_names():
+    spec = Spec(BENCH)
+    cell, lc = spec.cell(NS_CELL), spec.cell(LC_CELL)
+    assert cell.chips == 1 and cell.config["family"] == "nemotron_h"
+    assert cell.config["name"] == "nemotron-3-super-120b-a12b"
+    for d, mod in (("references", cell.reference()),
+                   ("adapters", cell.adapter())):
+        assert mod.__file__.endswith(os.path.join(d, "nemotron_h.py"))
+    with open(cell.reference().__file__) as f:
+        text = f.read()
+    assert "tree_attention_tpu" not in text.split('"""', 2)[2]
+    assert "import benchmark" not in text and "from benchmark" not in text
+    assert "lax.scan(token" in text       # the recurrence, token by token
+    # The traffic file that was there, the cell the eighth of eight, none
+    # on four chips.
+    assert cell.traffic == lc.traffic and cell.traffic["kind"] == "backlog"
+    assert [w["name"] for w in spec.data["workloads"]][7:] == [NS_CELL]
+    assert all(w["chips"] == 1 for w in spec.data["workloads"])
+    assert [m["name"] for m in cell.end_to_end] == [
+        "out_tok_s", "tbt_p50_ms", "tbt_p99_ms", "setup_s"]
+    names = [m["name"] for m in cell.per_layer]
+    for name in names:
+        assert spec.load_module("layer_metrics", name + ".py").read
+    for name in ("occupancy_pct", "kv_blocks_peak_pct", "hbm_peak_gb",
+                 "tick_rows_useful_pct", "attn_kernel_ms_tick",
+                 "flash_decode_paged_roofline", "experts_touched_pct",
+                 "expert_rows_max_over_mean", "tick_ahead_pct",
+                 "device_idle_pct", "decode_tick_p50_ms",
+                 "paged_steps_run_pct") + tuple(PARTS_ALL):
+        assert name in names, name
+    assert names[-5:] == STATE_METRICS
+    # The gated product's time and roofline are not this cell's (its cost
+    # file counts three matrices of hidden x width an expert).
+    for name in ("moe_ffn_ms_tick", "moe_grouped_matmul_roofline",
+                 "mixer_rest_ms_tick", "mla_decode_ms_tick",
+                 "window_attn_ms_tick", "zero_expert_pairs_pct"):
+        assert name not in names
+    listed = {m["name"]: m for m in spec.data["per_layer"]}
+    for name in STATE_METRICS:
+        assert listed[name]["workloads"] == [NS_CELL]
+        assert listed[name]["moves"] == "tbt_p50_ms"
+    assert [listed[n]["layer"] for n in STATE_METRICS] == [
+        "kernels"] * 4 + ["state pool"]
+    assert listed["ssm_decode_update_roofline"]["unit"] == "%" \
+        == listed["moe_ungated_matmul_roofline"]["unit"]
+    assert listed["ssm_states_advanced_pct"]["source"] == "program_counter"
+    for w in spec.data["workloads"][:7]:
+        assert not set(STATE_METRICS) & {
+            m["name"] for m in spec.cell(w["name"]).per_layer}
+    assert cell.config["serving"] == {
+        "slots": 64, "cache_len": 2560, "kv_layout": "paged", "kv_block": 64,
+        "admission": "chunked", "prefill_chunk": 256, "prefix_cache": False}
+    assert list(cell.config["correct"]["limits"]) == ["gap_mean"]
+    for kernel in ("flash_decode_paged", "ssm_decode_update",
+                   "moe_ungated_matmul", "moe_grouped_matmul"):
+        assert cell.adapter().kernel_call(cell.config, kernel) is not None
+        assert spec.load_module("kernel_costs", kernel + ".py").cost
+    assert cell.adapter().kernel_call(cell.config, "mla_decode_paged") is None
+
+
+def test_the_state_configurations_file_against_the_catalog():
+    """Every number of the catalog's ``config`` under the same key but the
+    keys ``reduced`` lists; the cut's arithmetic; every assumed rule marked
+    unconfirmed; the drafter named as not built."""
+    path = "/opt/skills/guides/model-configs/architectures.jsonl"
+    with open(BENCH) as f:
+        entry = next(c for c in json.load(f)["configs"]
+                     if c["name"] == "nemotron-3-super-120b-a12b")
+    with open(os.path.join(ROOT, entry["file"])) as f:
+        c = json.load(f)
+    assert entry["reduced"] == c["reduced"] == [
+        "num_hidden_layers", "hybrid_override_pattern", "n_routed_experts",
+        "vocab_size"]
+    assert c["source"] == entry["source"]
+    if os.path.exists(path):
+        row = next(r for r in map(json.loads, open(path))
+                   if r["name"] == "NVIDIA-Nemotron-3-Super-120B-A12B-BF16")
+        assert entry["source"] == row["source_url"]
+        for k, v in row["config"].items():
+            if k not in c["reduced"]:
+                assert c[k] == v, k
+        pub = row["config"]["hybrid_override_pattern"]
+        assert c["hybrid_override_pattern"] == pub[:11]
+        assert c["published"]["hybrid_override_pattern"] == pub
+    assert (c["num_hidden_layers"], c["n_routed_experts"],
+            c["vocab_size"]) == (11, 128, 32768)
+    assert c["published"]["n_routed_experts"] == 512 \
+        and c["published"]["vocab_size"] == 131072 \
+        and c["published"]["num_hidden_layers"] == 88
+    # Floors: a whole period (5 M, 5 E, 1 *: the published 40 : 40 : 8), 128
+    # experts, a quarter of the vocabulary.
+    pat = c["hybrid_override_pattern"]
+    assert (pat.count("M"), pat.count("E"), pat.count("*")) == (5, 5, 1)
+    h, lat, e, sh = (c["hidden_size"], c["moe_latent_size"],
+                     c["moe_intermediate_size"],
+                     c["moe_shared_expert_intermediate_size"])
+    inner = c["mamba_num_heads"] * c["mamba_head_dim"]
+    conv = inner + 2 * c["n_groups"] * c["ssm_state_size"]
+    m = h * (inner + conv + c["mamba_num_heads"]) + inner * h \
+        + (c["conv_kernel"] + 1) * conv + 3 * c["mamba_num_heads"] \
+        + inner + h
+    attn = 2 * h * 32 * 128 + 2 * h * 2 * 128 + h
+    moe = h * 512 + 512 + 2 * h * lat + 2 * h * sh + h
+    held = 5 * m + attn + 5 * (moe + 128 * 2 * lat * e) \
+        + 2 * c["vocab_size"] * h + h
+    assert abs(held / 1e6 - 4648.2) < 0.3 and "4,648.2M" in c["why_reduced"]
+    assert abs(m / 1e6 - 109.64) < 0.01 and abs(attn / 1e6 - 35.66) < 0.01
+    dep = c["deployment"]
+    assert (dep["chips"], dep["chips_a_layer"], dep["pipeline_stages"],
+            dep["experts_total"], dep["expert_share"]) == (32, 4, 8, 512, 0)
+    assert c["block"] == dict(
+        c["block"], rotary_layers="none", router_scoring="sigmoid",
+        corrected_choice=True, scale_renormed=True, latent_proj_plain=True,
+        gate_before_norm=True)
+    for rule in ("rotary_layers", "corrected_choice", "latent_proj_plain",
+                 "gate_before_norm"):
+        assert "unconfirmed" in c["assumed"]["unconfirmed"][rule]
+    assert "float32" in c["assumed"]["state_dtype"]
+    assert "num_nextn_predict_layers" in c["not_built"]
+    assert set(c["assumed"]["seeded_scales"]) == {
+        "embedding_std", "head_std", "ssm_out_std", "attn_out_std",
+        "expert_down_std", "latent_up_std", "shared_down_std", "gain_mean",
+        "gain_std", "router_bias_std"}
+
+
+def test_the_state_adapter_refuses_another_model_at_once():
+    spec = Spec(BENCH)
+    adapter = spec.cell(NS_CELL).adapter()
+    with pytest.raises(SpecError, match="cannot read"):
+        adapter.build({"family": "nemotron_h"}, [], 0, "cpu", None)
+    # A file that says another block than the engine would build.
+    config = dict(spec.cell(NS_CELL).config)
+    config["block"] = dict(config["block"], corrected_choice=False)
+    from tree_attention_tpu.models.transformer import model_from_config
+    model = model_from_config(spec.cell(NS_CELL).config)
+    with pytest.raises(SpecError, match="built otherwise"):
+        adapter._hold_to_file(model, config)
+    # A program whose model has no state-space widths.
+    other = model_from_config(spec.cell(LFM_CELL).config)
+    with pytest.raises(SpecError, match="cannot express|built otherwise"):
+        adapter._hold_to_file(other, spec.cell(NS_CELL).config)
+
+
+@pytest.mark.parametrize("name", STATE_METRICS)
+def test_the_state_metrics_read_nothing_where_there_is_nothing(name):
+    """An untraced run, a run without flight records, a trace without a
+    decode tick and a program without the counters or the kernels (the
+    parent's records) give None, never a number and never an exception."""
+    import types
+
+    spec = Spec(BENCH)
+    cell = spec.cell(NS_CELL)
+    read = spec.load_module("layer_metrics", name + ".py").read
+    peaks = spec.load_json("peaks.json")["TPU v5 lite"]
+    empty = {"offset_s": 0.0, "t0": 5.0, "t1": 8.0, "devices": 1,
+             "events": {}}
+    parent = [{"t_s": 2.0, "occupancy": 4, "chunk_tokens": 0},
+              {"t_s": 3.0, "occupancy": 4, "chunk_tokens": 0}]
+    for trace, flight in ((None, None), (None, []), (empty, None),
+                          (empty, [{"t_s": 1.0}]), (empty, parent)):
+        run = types.SimpleNamespace(trace=trace, flight=flight, cell=cell,
+                                    recs=[], peaks=peaks, t_open=1.0,
+                                    t_end=10.0)
+        assert read(run) is None
+
+
+def test_ssm_states_advanced_pct_reads_the_steps_counter():
+    import types
+
+    spec = Spec(BENCH)
+    cell = spec.cell(NS_CELL)
+    read = spec.load_module("layer_metrics",
+                            "ssm_states_advanced_pct.py").read
+    tick = {"occupancy": 60, "chunk_tokens": 0}
+    flight = [
+        dict(tick, t_s=2.0, ssm_states_advanced=300),
+        dict(tick, t_s=3.0, ssm_states_advanced=320, occupancy=64),
+        dict(tick, t_s=4.0, ssm_states_advanced=320, chunk_tokens=256),
+        dict(tick, t_s=0.5, ssm_states_advanced=5),      # before the window
+    ]
+    run = types.SimpleNamespace(flight=flight, cell=cell, t_open=1.0,
+                                t_end=10.0)
+    assert read(run) == pytest.approx(100.0)
+    flight[0]["ssm_states_advanced"] = 320       # an idle slot rewritten
+    assert read(run) > 100.0

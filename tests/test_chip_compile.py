@@ -95,6 +95,9 @@ MISTRAL = dict(slots=16, hkv=8, nb=40, layers=16)
 YI = dict(slots=8, hkv=4, nb=64, layers=16)
 # The hybrid's 3 attention layers: 8 KV heads of 64 lanes, two to a row.
 LFM2 = dict(slots=64, hkv=4, nb=40, layers=3)
+# The state configuration's one attention layer: 2 KV heads x 128 under 32
+# query heads, 16 a KV head.
+NEMOTRON3S = dict(slots=64, hkv=2, nb=40, layers=1)
 
 
 def _paged(kernel, tq, *, int8=False, tree=False, slots=B, hkv=HKV, nb=NB,
@@ -133,6 +136,41 @@ def _row_write(*, int8=False, pools=2, slots=B, hkv=HKV, nb=NB, layers=1,
                                interpret=False)
 
     return fn, args, tuple(range(pools))    # the pools donated, as a tick's
+
+
+def _ssm_update(slots=64, layers=5, hp=64, n=128, lanes=128, groups=8):
+    """(fn, abstract args, donated) of one ``ssm_decode_update`` call at the
+    state configuration's widths: 128 heads x 64 x 128, two heads a row of
+    lanes, the five layers' pool of 64 slots (1.34 GB) donated."""
+    from tree_attention_tpu.ops.pallas_ssm import ssm_decode_update
+
+    f32 = jnp.float32
+    args = [_s((layers * slots, hp, n, lanes), f32),
+            _s((slots, hp, lanes), f32), _s((slots, hp, lanes), f32),
+            _s((slots, n, groups), f32), _s((slots, n, groups), f32),
+            _s((slots,), jnp.int32), _s((1,), jnp.int32), _s((), jnp.int32)]
+
+    def fn(*a):
+        return ssm_decode_update(*a, interpret=False)
+
+    return fn, args, (0,)
+
+
+def _moe_ungated(m, latent=1024, width=2688, held=128, layers=5):
+    """(fn, abstract args) of an ungated expert layer's two products over
+    ``m`` pairs: one matrix in with relu squared, one out, in the latent."""
+    from tree_attention_tpu.ops.pallas_moe import (
+        UNGATED_KERNEL, grouped_matmul)
+
+    def fn(x, w1, w2, sizes, first):
+        h = grouped_matmul(x, (w1,), sizes, first_group=first, relu2=True,
+                           interpret=False, name=UNGATED_KERNEL)
+        return grouped_matmul(h, (w2,), sizes, first_group=first,
+                              interpret=False, name=UNGATED_KERNEL)
+
+    return fn, [_s((m, latent)), _s((layers * held, latent, width)),
+                _s((layers * held, width, latent)),
+                _s((held,), jnp.int32), _s((), jnp.int32)]
 
 
 def _prefill():
@@ -254,6 +292,18 @@ CASES = {
     "row_write_latent_32_slots": (
         lambda: _row_write(pools=1, slots=32, hkv=1, nb=50, layers=8, d=640),
         ROW_WRITE),
+    # The state configuration (ISSUE 40): the paged kernel at 2 KV heads x
+    # 128 with 16 query heads each, its row write, the state-space layers'
+    # in-place step and the ungated expert products at 1024 x 2688.
+    "paged_decode_nemotron3s_tq1": (
+        lambda: _paged(attention_pallas_decode, 1, **NEMOTRON3S),
+        "flash_decode_paged"),
+    "row_write_nemotron3s": (lambda: _row_write(**NEMOTRON3S), ROW_WRITE),
+    "ssm_update_nemotron3s": (_ssm_update, "ssm_decode_update"),
+    "moe_ungated_decode_pairs": (lambda: _moe_ungated(1408),
+                                 "moe_ungated_matmul"),
+    "moe_ungated_chunk_pairs": (lambda: _moe_ungated(7168),
+                                "moe_ungated_matmul"),
     "prefill_fwd": (_prefill, "flash_fwd"),
     "train_fwd": (_train_fwd_bwd, "flash_fwd"),
     "bwd_dq": (_train_fwd_bwd, "flash_bwd_dq"),
@@ -293,6 +343,15 @@ def test_kernel_compiles_for_v5e(case):
         assert _dynamic_grids(text, kernel) == [True]
     if kernel == ROW_WRITE:
         assert _row_writes(text) == 1
+    if kernel == "ssm_decode_update":
+        # The pool that goes in is the pool that comes out, and nothing of
+        # its size is made beside it.
+        mem = jax.jit(builder()[0], donate_argnums=(0,)).lower(
+            *builder()[1]).compile().memory_analysis()
+        pool = math.prod(builder()[1][0].shape) * 4
+        assert mem.alias_size_in_bytes >= pool and mem.temp_size_in_bytes \
+            < pool // 64, (mem.alias_size_in_bytes, mem.temp_size_in_bytes)
+        assert "moe_grouped_matmul" not in "moe_ungated_matmul"
     if "_mistral7b" in case or "_yi6b" in case or "_lfm2" in case:
         # The pool goes into the call as it is: no copy, slice or change of
         # layout of a pool-sized array before the launch (what a 576-lane
@@ -898,6 +957,88 @@ def test_hybrid_step_compiles_and_keeps_the_pools_in_place(tq, packed):
     assert tick.temp_bytes < 2 * kv_layer, tick.temp_bytes
 
 
+# -- a recurrent state a slot (ISSUE 40) -------------------------------------
+#
+# ``nemotron-3-super-120b-a12b``: five state-space layers' state ``(5, 64, 64,
+# 128, 128)`` float32 (1.34 GB: 128 heads x 64 x 128 a slot a layer, two heads
+# a row of lanes) and conv tails ``(5, 64, 30720)`` beside ONE attention
+# layer's K/V pool ``(1, N, 2, 64, 128)``, carried whole through four runs of
+# layers. The program's own parameter count at the published widths is the
+# configuration file's arithmetic; the compile for the chip copies neither
+# the state, the tails nor the K/V pool: a decode tick's states go through
+# ``ssm_decode_update`` in place, a chunk member's through an in-place
+# update of its one slice.
+
+STATE_CONFIG = "nemotron-3-super-120b-a12b"
+
+
+@pytest.mark.parametrize("tq,packed", [(1, False), (256, True)],
+                         ids=["tq1", "packed256"])
+def test_state_step_compiles_and_keeps_the_pools_in_place(tq, packed):
+    if _chip() is None:
+        pytest.skip("the v5e:2x2 topology cannot be described here")
+    from tree_attention_tpu.models import decode
+    from tree_attention_tpu.models.transformer import init_params
+
+    c, cfg = _model(STATE_CONFIG)
+    slots, blk = c["serving"]["slots"], c["serving"]["kv_block"]
+    blocks = _pool_blocks(STATE_CONFIG)
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    assert sum(math.prod(a.shape) for a in jax.tree.leaves(params)) \
+        == pytest.approx(4648.2e6, rel=0.001)
+    cache = jax.eval_shape(lambda: decode.init_paged_cache(
+        cfg, slots, c["serving"]["cache_len"], blocks, block=blk))
+    assert cache.k.shape == (1, blocks, 2, blk, 128)
+    assert cache.ssm_state.shape == (5, slots, 64, 128, 128)
+    assert cache.ssm_state.dtype == jnp.float32
+    assert cache.ssm_tail.shape == (5, slots, 3 * 10240)
+    tick = _tick_program(STATE_CONFIG, tq, packed=packed)
+    text = tick.text
+    kernels = pallas_kernels(text)
+    assert {"flash_decode_paged", "moe_ungated_matmul",
+            "ssm_decode_update"} <= set(kernels), kernels
+    assert "moe_grouped_matmul" not in kernels, kernels
+    if packed:
+        padding = _padding_arrays(text, slots, tq, cfg.vocab_size,
+                                  cfg.d_model)
+        assert not padding, padding
+    state_layer = slots * 64 * 128 * 128
+    tails, kv_layer = 5 * slots * 3 * 10240, blocks * 2 * blk * 128
+    moved, in_place = [], []
+    for name, result, opcode, inner in _materialised(text):
+        if opcode in _MOVES_NOTHING:
+            continue
+        sizes = {dt: max((math.prod(int(d) for d in dims.split(","))
+                          for dims in re.findall(rf"\b{dt}\[([\d,]+)\]",
+                                                 result)), default=0)
+                 for dt in ("f32", "bf16")}
+        if sizes["f32"] >= state_layer:
+            # The state: the kernel's aliased output, or the update of a
+            # chunk member's one slice in place.
+            if name.startswith("%ssm_decode_update") \
+                    or "dynamic-update-slice(" in inner \
+                    or opcode == "dynamic-update-slice":
+                in_place.append(name)
+            else:
+                moved.append((name, opcode, result))
+        elif sizes["bf16"] >= min(tails, kv_layer) and opcode == "copy":
+            # A change of layout of the tails or of the K/V pool. (The
+            # compiler stages the 20 MB of tails in fast memory and back,
+            # ``copy-start`` / ``slice-start`` into ``S(1)``: its own
+            # prefetch, no other layout.)
+            moved.append((name, opcode, result))
+    assert not moved, moved
+    # Five decode launches a tick (a run of three layers is one loop: one
+    # launch in its body); a chunk member's state written back once a run.
+    launches = [n for n in in_place if n.startswith("%ssm_decode_update")]
+    assert len(launches) == 3, in_place
+    assert len(in_place) - len(launches) == (3 if packed else 0), in_place
+    assert _row_writes(text) == 1
+    assert tick.alias_bytes >= 4 * 5 * state_layer + 2 * tails \
+        + 2 * 2 * kv_layer, tick.alias_bytes
+    assert tick.temp_bytes < state_layer, tick.temp_bytes
+
+
 # -- layers whose block counts differ: two pools under two tables (ISSUE 38) -
 #
 # ``k-exaone-236b-a23b``: two full-attention layers' K/V pools ``(2, N, 8, 64,
@@ -995,8 +1136,8 @@ def test_window_step_compiles_and_copies_neither_pool(tq, packed):
 # first). The engine serves from ``served_layout``'s form, and the programs
 # compiled from it must hold neither.
 
-ALL_CONFIGS = STEP_CONFIGS + LATENT_CONFIGS + ("lfm2-8b-a1b",
-                                               "k-exaone-236b-a23b")
+ALL_CONFIGS = STEP_CONFIGS + LATENT_CONFIGS + (
+    "lfm2-8b-a1b", "k-exaone-236b-a23b", "nemotron-3-super-120b-a12b")
 # Tq 1 and the packed programs at both ends of the chunk buckets.
 TICK_PROGRAMS = {"tq1": (1, False), "packed16": (16, True),
                  "packed256": (256, True)}
@@ -1082,7 +1223,7 @@ def _scopes_expected(cfg, packed):
         want.add(scopes.ATTN_CHUNK)
     if cfg.moe is not None:
         want |= {scopes.ROUTE, scopes.EXPERTS}
-    if cfg.conv_layers:
+    if cfg.conv_layers or cfg.ssm_layers:
         want.add(scopes.CONV)
     return want
 
@@ -1139,7 +1280,10 @@ def test_tick_programs_keep_the_scopes(config, program):
         assert kernels["window_decode_paged"] in (
             scopes.ATTN_DECODE, scopes.ATTN_CHUNK)
     if cfg.moe is not None:
-        assert kernels["moe_grouped_matmul"] == scopes.EXPERTS
+        assert kernels["moe_grouped_matmul" if cfg.moe.gated
+                       else "moe_ungated_matmul"] == scopes.EXPERTS
+    if cfg.ssm_layers:
+        assert kernels["ssm_decode_update"] == scopes.CONV
     # The decode group's row a slot reaches the pool inside the write's part.
     assert kernels[ROW_WRITE] == scopes.ATTN_CACHE
 
@@ -1189,7 +1333,7 @@ def test_tick_programs_build_the_work_lists_outside_the_layer_loops(
               if i.opcode == "custom-call"
               and i.op.startswith((kernel, "window_decode_paged"))
               and i.computation != entry}
-    if config != "lfm2-8b-a1b":
+    if config not in ("lfm2-8b-a1b", STATE_CONFIG):
         assert bodies
     inside = [(i.computation, i.op, i.scope) for i in plans
               if i.computation in bodies]

@@ -160,6 +160,21 @@ def test_phase_window_serves_a_hit_a_fork_and_a_reused_slot():
     assert d["window_token_bytes"] == 3 * d["kv_token_bytes"]
 
 
+def test_phase_state_serves_a_reused_slot_and_ragged_chunks():
+    """The state phase at ``tests/test_state_space.py``'s small preset:
+    blocks of 4, chunks of 16 over a scan blocked in 8, float32 (a served
+    token lies at the reference's best to 1e-4)."""
+    from tests.test_state_space import SMALL
+
+    d = smoke.phase_state(TINY, dict(SMALL), device="cpu", block=4,
+                          chunk=16, tol_gap=1e-4)
+    assert d["layers"] == ["ssm", "ssm", "attention", "ssm"]
+    assert d["ffn"] == ["expert", "none", "expert", "none"]
+    assert d["ssm_state"] == [3, 2, 2, 16, 64]
+    assert d["ssm_state_dtype"] == "float32"
+    assert d["tokens_compared"] == 6 + 3 * 5 and d["gap_max"] <= 1e-4
+
+
 @pytest.mark.slow
 def test_phase_train_and_programs():
     assert smoke.phase_train(TINY)["losses"][-1] < 6.3

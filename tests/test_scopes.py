@@ -175,12 +175,13 @@ MODEL_FILES = sorted(
 @pytest.mark.parametrize("rel", MODEL_FILES)
 def test_every_named_scope_is_of_the_vocabulary(rel):
     """A layer body names a scope as ``scopes.<NAME>``, never by a string of
-    its own: the models, the table and the readers cannot drift."""
+    its own: the models, the table and the readers cannot drift. (The
+    names inside ``conv``, ``scopes.INNER``, are of the vocabulary too.)"""
     for arg in _named_scope_args(os.path.join(ROOT, rel)):
         assert isinstance(arg, ast.Attribute) \
             and isinstance(arg.value, ast.Name) \
             and arg.value.id == "scopes", ast.dump(arg)
-        assert getattr(scopes, arg.attr) in scopes.SCOPES
+        assert getattr(scopes, arg.attr) in scopes.SCOPES + scopes.INNER
 
 
 def test_the_layer_bodies_use_every_scope():
@@ -188,7 +189,7 @@ def test_the_layer_bodies_use_every_scope():
     for rel in MODEL_FILES:
         used |= {getattr(scopes, a.attr)
                  for a in _named_scope_args(os.path.join(ROOT, rel))}
-    assert used == set(scopes.SCOPES)
+    assert used == set(scopes.SCOPES + scopes.INNER)
 
 
 def test_the_readers_know_every_scope():

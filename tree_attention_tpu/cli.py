@@ -562,8 +562,8 @@ _POOL_REFUSALS = (
      "--host-blocks (the host tier)"),
     (lambda c: c.speculate,
      "--speculate (the latent kernel takes no tree_mask; a conv layer's "
-     "tail cannot roll a rejected draft back; a window layer's freed "
-     "blocks cannot come back)"),
+     "tail or a recurrent state cannot roll a rejected draft back; a window "
+     "layer's freed blocks cannot come back)"),
     (lambda c: c.serve_disagg,
      "--serve-disagg (the handoff of that pool's arrays)"),
     (lambda c: c.admission != "chunked", "--admission whole"),
@@ -718,6 +718,11 @@ def build_serve_engine(cfg: RunConfig, mesh, *, model=None,
                         f"a model served from the {tcfg.cache_kind} pool is "
                         f"not served with {what}: not built yet (ROADMAP "
                         f"2A)")
+        if tcfg.cache_kind == "state" and cfg.prefix_cache:
+            raise SystemExit(
+                "a model served from the state pool is not served with "
+                "--prefix-cache (a hit needs the recurrent state at the "
+                "matched boundary): not built yet (ROADMAP 2A)")
     else:
         tcfg = _transformer_config(
             dataclasses.replace(cfg, seq_len=cache_len))

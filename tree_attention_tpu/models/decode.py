@@ -294,6 +294,51 @@ class PagedWindowCache:
         return self.wk.shape[1]
 
 
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class PagedStateCache:
+    """The cache of a model whose mixers are state-space layers beside a
+    few attention layers: the attention layers' K/V rows in paged pools
+    under the block table, as :class:`PagedKVCache` holds them, AND a
+    recurrent state a slot for every state-space layer, which no table
+    indexes: ``ssm_state`` ``(ssm layers, slots) + StateSpace.state_shape``
+    float32 and ``ssm_tail`` ``(ssm layers, slots, (taps - 1) x
+    conv_dim)``, the last pre-activation rows of the mixer's short
+    convolution, a slot's rows side by side on the lanes (as ``(slots, taps
+    - 1, conv_dim)`` the compiler tiles the three rows on its own and copies
+    the pool to and from the layout its scatter wants, in every tick
+    program: the hybrid pool's tails taught the same, PERF.md, PR 33).
+
+    Every token rewrites a layer's state whole, so a state has no block
+    structure and nothing of it can be shared, restored or rolled back: a
+    prefix hit, a fork and a rejected draft would each need the state as it
+    was at another position, and are refused by this cache kind's name
+    (``"state"``). Three rules keep the engine's life simple
+    (``models/hybrid.py`` ``ssm_mixer``): a member whose first position is
+    0 starts from a zero state and a zero tail, so a reused slot needs no
+    reset; a row past a member's valid count leaves the state bit for bit;
+    a slot with no row in a program is neither read nor written."""
+
+    k: jax.Array          # (attention layers, N, Hkv, block, D) pool
+    v: jax.Array          # (attention layers, N, Hkv, block, D) pool
+    ssm_state: jax.Array  # (ssm layers, slots, H / pack, d_state, pack x P)
+    ssm_tail: jax.Array   # (ssm layers, slots, (taps - 1) x conv_dim)
+    table: jax.Array      # (B, NB) int32: physical block per logical block
+    length: jax.Array     # (B,) int32: tokens written so far, per slot
+
+    @property
+    def capacity(self) -> int:
+        return self.table.shape[1] * self.k.shape[3]
+
+    @property
+    def block(self) -> int:
+        return self.k.shape[3]
+
+    @property
+    def blocks(self) -> int:
+        return self.k.shape[1]
+
+
 def cache_pools(cache) -> Dict[str, jax.Array]:
     """A paged cache's block pools by field name, every one ``(L, N, ...)``
     with the block on axis 1: what a block copy, a leak check or a byte
@@ -673,7 +718,7 @@ def init_paged_cache(
     seq_axis: str = AXIS_SEQ,
     window_blocks: Optional[int] = None,
 ) -> Union[PagedKVCache, PagedQuantKVCache, PagedLatentCache,
-           PagedHybridCache, "PagedWindowCache"]:
+           PagedHybridCache, "PagedWindowCache", "PagedStateCache"]:
     """Allocate a paged cache: one ``blocks``-block pool + empty tables,
     of the kind the model caches (``cfg.cache_kind``).
 
@@ -769,6 +814,30 @@ def init_paged_cache(
             k=k, v=v, wk=wk, wv=wv,
             table=jnp.zeros((batch_size, nb), jnp.int32),
             wtable=jnp.zeros((batch_size, nb), jnp.int32),
+            length=jnp.zeros((batch_size,), jnp.int32),
+        )
+    if cfg.cache_kind == "state":
+        if quantize:
+            raise ValueError(
+                "int8 rows beside a recurrent state are not built: the "
+                "state pool is served exact")
+        if seq_sharded:
+            raise ValueError(
+                "a sequence-sharded state pool (kv_shard='seq') is not "
+                "built: a slot's state is one array, not blocks")
+        sm = cfg.ssm
+        shapes = (
+            (shape, cfg.dtype), (shape, cfg.dtype),
+            ((cfg.ssm_layers, batch_size) + sm.state_shape, jnp.float32),
+            ((cfg.ssm_layers, batch_size, (sm.taps - 1) * sm.conv_dim),
+             cfg.dtype))
+        make = lambda: tuple(jnp.zeros(s, d) for s, d in shapes)  # noqa: E731
+        k, v, state, tail = (
+            jax.jit(make, out_shardings=NamedSharding(mesh, P()))()
+            if mesh is not None else make())
+        return PagedStateCache(
+            k=k, v=v, ssm_state=state, ssm_tail=tail,
+            table=jnp.zeros((batch_size, nb), jnp.int32),
             length=jnp.zeros((batch_size,), jnp.int32),
         )
     if cfg.cache_kind == "hybrid":
@@ -1164,6 +1233,8 @@ class _RowGroup(NamedTuple):
     wplan: Any = None     # ... and their work list (a window's steps)
     at: Any = None        # one row a slot: its (block, row) (_row_targets)
     wat: Any = None       # ... under the window layers' table
+    slot: Optional[jax.Array] = None  # each member's slot (a per-slot state)
+    live: Any = None      # one row a slot: the slots that have one (live_list)
 
     @property
     def n_valid(self) -> jax.Array:
@@ -1204,7 +1275,8 @@ def _plan_groups(groups: Tuple[_RowGroup, ...], cache: Any,
     the ``(block, row)`` its write lands in (:func:`_row_targets`, under
     either table): a layer's :func:`_pool_write` then adds ``l * N`` inside
     its kernel and the loop's body holds nothing of the write but the
-    launch."""
+    launch. Over a :class:`PagedStateCache` such a group gets the list of
+    the slots that have a row too (``ops/pallas_ssm.py`` ``live_list``)."""
     from tree_attention_tpu.ops.pallas_decode import decode_plan, mla_plan
 
     def barrier(plan):
@@ -1227,6 +1299,12 @@ def _plan_groups(groups: Tuple[_RowGroup, ...], cache: Any,
                 g = g._replace(wplan=barrier(decode_plan(
                     cfg.n_heads, g.tq, cache.wk, g.wtable, g.start,
                     window=cfg.window)))
+        if g.tq == 1 and isinstance(cache, PagedStateCache):
+            # The slots a state-space layer's in-place step visits.
+            from tree_attention_tpu.ops.pallas_ssm import live_list
+
+            with jax.named_scope(scopes.CONV):
+                g = g._replace(live=barrier(live_list(g.n_valid)))
         if pool_write_path(g.tq) == "row":
             with jax.named_scope(scopes.ATTN_CACHE):
                 g = g._replace(at=barrier(_row_targets(
@@ -1261,13 +1339,22 @@ def paged_step_tokens(cache: Any, cfg: TransformerConfig,
     if isinstance(cache, PagedLatentCache):
         return mla_step_entries(cache.table.shape[1]) * cache.block
     if not isinstance(cache, (PagedKVCache, PagedQuantKVCache,
-                              PagedHybridCache, PagedWindowCache)):
+                              PagedHybridCache, PagedWindowCache,
+                              PagedStateCache)):
         return None
     if not isinstance(cache, PagedQuantKVCache) \
             and tpu_kernel_for(tq) != "pallas_decode":
         return None
     return cache.block * decode_step_entries(
         cfg.n_heads, tq, cache.k, cache.table.shape[1])
+
+
+def _slots(cache: Any, batch: int) -> Optional[jax.Array]:
+    """Every slot's own index, for a cache that holds a state a slot; None
+    for every other cache (whose groups are tables and lengths alone)."""
+    if not isinstance(cache, PagedStateCache):
+        return None
+    return jnp.arange(batch, dtype=jnp.int32)
 
 
 def _join_rows(groups: Tuple[_RowGroup, ...], outs) -> jax.Array:
@@ -1548,18 +1635,25 @@ def _unpack_heads(out: jax.Array, cfg: TransformerConfig) -> jax.Array:
                       _head_lanes(cfg).astype(out.dtype))
 
 
+# The caches a model's own layer loop steps (not the dense block's scan).
+_OWN_POOLS = (PagedLatentCache, PagedHybridCache, PagedWindowCache,
+              PagedStateCache)
+
+
 def _check_block_cache(cache: Any, cfg: TransformerConfig) -> None:
     """The model's layers and the cache's kind go together."""
     kind = ("latent" if isinstance(cache, PagedLatentCache)
             else "hybrid" if isinstance(cache, PagedHybridCache)
-            else "window" if isinstance(cache, PagedWindowCache) else "kv")
+            else "window" if isinstance(cache, PagedWindowCache)
+            else "state" if isinstance(cache, PagedStateCache) else "kv")
     if kind != cfg.cache_kind:
         raise ValueError(
             f"this model caches {cfg.cache_kind!r} state "
             f"(TransformerConfig.cache_kind: a latent pool for latent "
             f"attention, the hybrid pool for conv layers or experts under "
-            f"rotary GQA, the window pools for sliding-window layers, K/V "
-            f"buffers for the dense block) and is served "
+            f"rotary GQA, the window pools for sliding-window layers, the "
+            f"state pool for state-space layers, K/V buffers for the dense "
+            f"block) and is served "
             f"from the cache init_paged_cache builds for it and no other; "
             f"got {type(cache).__name__}"
         )
@@ -1576,6 +1670,8 @@ def _count_step(cache: Any) -> None:
         kind = "paged_hybrid"
     elif isinstance(cache, PagedWindowCache):
         kind = "paged_window"
+    elif isinstance(cache, PagedStateCache):
+        kind = "paged_state"
     elif isinstance(cache, (PagedKVCache, PagedQuantKVCache)):
         kind = "paged_quant" if quant else "paged"
     else:
@@ -1742,7 +1838,8 @@ def _step_layers(
         x, pool = _latent_layers(
             params, x, cache, cfg, positions, groups, stats)
         return x, {"kv": pool}
-    if isinstance(cache, (PagedHybridCache, PagedWindowCache)):
+    if isinstance(cache, (PagedHybridCache, PagedWindowCache,
+                          PagedStateCache)):
         from tree_attention_tpu.models.hybrid import hybrid_layers
 
         attend = _Attend(
@@ -2042,15 +2139,14 @@ def forward_step(
 
     B, Tq = tokens.shape
     start = cache.length  # (B,) per-slot offsets
-    own_pool = isinstance(
-        cache, (PagedLatentCache, PagedHybridCache, PagedWindowCache))
+    own_pool = isinstance(cache, _OWN_POOLS)
     _check_block_cache(cache, cfg)
     if own_pool and (tree_mask is not None or kv_shard == "seq"):
         raise ValueError(
             f"a {cfg.cache_kind} pool takes no tree_mask (the latent "
-            f"kernel has none; a conv tail cannot roll a draft back; a "
-            f"window's freed blocks cannot come back) and is not "
-            f"sequence-sharded"
+            f"kernel has none; a conv tail or a recurrent state cannot roll "
+            f"a draft back; a window's freed blocks cannot come back) and "
+            f"is not sequence-sharded"
         )
     paged = own_pool or isinstance(cache, (PagedKVCache, PagedQuantKVCache))
     if not paged and n_tokens is not None and Tq > cache.capacity:
@@ -2112,6 +2208,7 @@ def forward_step(
         lo=None, batch=B, tq=Tq, start=start, n=n_tokens,
         table=cache.table if paged else None, tree_mask=tree_mask,
         wtable=getattr(cache, "wtable", None),
+        slot=_slots(cache, B),
     )
     x, pools = _step_layers(
         params, x, positions, (group,), cache, cfg, mesh=mesh, axes=axes,
@@ -2176,8 +2273,7 @@ def forward_packed_step(
     axes = prune_axes(
         mesh, {"data": data_axis, "seq": seq_axis, "model": model_axis}
     )
-    own_pool = isinstance(
-        cache, (PagedLatentCache, PagedHybridCache, PagedWindowCache))
+    own_pool = isinstance(cache, _OWN_POOLS)
     if not (own_pool
             or isinstance(cache, (PagedKVCache, PagedQuantKVCache))):
         raise ValueError(
@@ -2188,20 +2284,23 @@ def forward_packed_step(
     if kv_shard not in ("replicated", "seq") or (
             own_pool and kv_shard == "seq"):
         raise ValueError(
-            f"kv_shard must be 'replicated' or 'seq' (a latent, hybrid or "
-            f"window pool: 'replicated'), got {kv_shard!r}"
+            f"kv_shard must be 'replicated' or 'seq' (a latent, hybrid, "
+            f"window or state pool: 'replicated'), got {kv_shard!r}"
         )
     C, Tq = chunk_tokens.shape
     S = cache.table.shape[0]
     length = cache.length
     c_start = length[chunk_slot]
     wtable = getattr(cache, "wtable", None)
+    slots = _slots(cache, S)
     groups = (
         _RowGroup(lo=0, batch=C, tq=Tq, start=c_start, n=chunk_n,
                   table=cache.table[chunk_slot], tree_mask=None, chunk=True,
-                  wtable=None if wtable is None else wtable[chunk_slot]),
+                  wtable=None if wtable is None else wtable[chunk_slot],
+                  slot=None if slots is None else chunk_slot),
         _RowGroup(lo=C * Tq, batch=S, tq=1, start=length, n=n_tokens,
-                  table=cache.table, tree_mask=None, wtable=wtable),
+                  table=cache.table, tree_mask=None, wtable=wtable,
+                  slot=slots),
     )
     c_pos = c_start[:, None] + jnp.arange(Tq, dtype=jnp.int32)
     positions = jnp.concatenate([c_pos.reshape(-1), length])[None]

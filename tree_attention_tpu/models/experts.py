@@ -95,6 +95,12 @@ def swiglu(x: jax.Array, w1: jax.Array, w3: jax.Array,
     return (jax.nn.silu(x @ w1) * (x @ w3)) @ w2
 
 
+def relu2_ffn(x: jax.Array, w1: jax.Array, w2: jax.Array) -> jax.Array:
+    """An ungated feed-forward part: one matrix in, relu squared, one
+    out."""
+    return jnp.square(jax.nn.relu(x @ w1)) @ w2
+
+
 EXPERT_LEAVES = ("we1", "we3", "we2")
 
 
@@ -110,12 +116,21 @@ def expert_layer(p: Params, x: jax.Array, ex: ExpertLayer, *,
     layer the one stack and never slices it. ``router_input`` is ``x``
     before it was rounded to the served type (float32), where the caller
     has it: one rounding fewer before the near-ties are decided, and
-    before a zero-compute expert hands it back."""
-    from tree_attention_tpu.ops.pallas_moe import grouped_matmul, row_tile
+    before a zero-compute expert hands it back.
+
+    Where ``ex.latent``, the routed experts live in a latent narrower than
+    the residual: a row goes down (``w_down``) once before the gather, the
+    experts' matrices are of the latent's width, and the weighed sum of
+    what the experts held HERE give comes up (``w_up``) once; the router
+    and the shared expert see the full width. Where not ``ex.gated``, an
+    expert is ``w2 . relu(w1 . u)^2`` (``experts`` holds two stacks, no
+    gate) and its products carry the ungated kernel's name."""
+    from tree_attention_tpu.ops.pallas_moe import (
+        UNGATED_KERNEL, grouped_matmul, row_tile,
+    )
 
     if experts is None:
-        experts = tuple(p[n] for n in EXPERT_LEAVES)
-    we1, we3, we2 = experts
+        experts = tuple(p[n] for n in ex.leaves)
     B, T, D = x.shape
     R, K = B * T, ex.per_token
     xf = x.reshape(R, D)
@@ -137,17 +152,25 @@ def expert_layer(p: Params, x: jax.Array, ex: ExpertLayer, *,
         sizes = jnp.zeros(
             (ex.held + 1,), jnp.int32).at[key].add(1)[:ex.held]
         rows = jnp.minimum(order // K, R - 1)
-        gathered = xf[rows]
+        gathered = (xf @ p["w_down"] if ex.latent else xf)[rows]
     with jax.named_scope(scopes.EXPERTS):
-        hidden = grouped_matmul(
-            gathered, (we1, we3), sizes, first_group=first)
-        out = grouped_matmul(hidden, (we2,), sizes, first_group=first)
+        if ex.gated:
+            hidden = grouped_matmul(
+                gathered, experts[:2], sizes, first_group=first)
+            out = grouped_matmul(hidden, experts[2:], sizes,
+                                 first_group=first)
+        else:
+            hidden = grouped_matmul(
+                gathered, experts[:1], sizes, first_group=first,
+                relu2=True, name=UNGATED_KERNEL)
+            out = grouped_matmul(hidden, experts[1:], sizes,
+                                 first_group=first, name=UNGATED_KERNEL)
     with jax.named_scope(scopes.ROUTE):
         # Back to (row, choice) order: pair j sits at sorted position
         # inv[j].
         inv = jnp.zeros((m + pad,), jnp.int32).at[order].set(
             jnp.arange(m + pad, dtype=jnp.int32))[:m]
-        pairs = out[inv].reshape(R, K, D)
+        pairs = out[inv].reshape(R, K, out.shape[-1])
         y = jnp.sum(
             jnp.where(here[..., None],
                       pairs.astype(jnp.float32) * w[..., None], 0.0),
@@ -158,9 +181,12 @@ def expert_layer(p: Params, x: jax.Array, ex: ExpertLayer, *,
             w_zero = jnp.sum(jnp.where(idx >= ex.n_routed, w, 0.0), axis=-1)
             y = y + w_zero[:, None] * x_in.astype(jnp.float32)
         y = y.astype(x.dtype)
+        if ex.latent:
+            y = y @ p["w_up"]
     if ex.shared_width:
         with jax.named_scope(scopes.FFN):
-            y = y + swiglu(xf, p["ws1"], p["ws3"], p["ws2"])
+            y = y + (swiglu(xf, p["ws1"], p["ws3"], p["ws2"]) if ex.gated
+                     else relu2_ffn(xf, p["ws1"], p["ws2"]))
     return y.reshape(B, T, D), idx.reshape(B, T, K)
 
 

@@ -1,22 +1,23 @@
 """Layers of several kinds in one model: the gated short-convolution mixer,
-its state as a two-row tail of each pool block, and the layer loop over runs
-of layers of one kind.
+its state as a two-row tail of each pool block, the state-space mixer, its
+state an array a slot, and the layer loop over runs of layers of one kind.
 
-A layer is a mixer and a feed-forward half. The mixer is rotary-GQA attention
+A layer is a mixer and at most one feed-forward half. The mixer is
+rotary-GQA attention
 (:func:`~.decode.gqa_mixer`, the dense block's own: over the whole context,
 or, a ``window`` layer, over the last ``cfg.window`` positions, its rows in a
-pool and under a table of their own, :class:`~.decode.PagedWindowCache`) or a
-gated short convolution (:func:`conv_mixer`); the feed-forward half the dense
-SwiGLU or the
-routed-expert layer (:func:`~.experts.expert_layer`). ``cfg.layer_types`` and
-``cfg.moe.first_dense`` say which layer is what; :func:`hybrid_layers` cuts the
+pool and under a table of their own, :class:`~.decode.PagedWindowCache`), a
+gated short convolution (:func:`conv_mixer`) or a state-space mixer
+(:func:`ssm_mixer`); the feed-forward half the dense SwiGLU, the
+routed-expert layer (:func:`~.experts.expert_layer`) or none. ``cfg.layer_types``
+and ``cfg.ffn_kinds`` say which layer is what; :func:`hybrid_layers` cuts the
 depth into runs of consecutive layers of one (mixer, feed-forward) kind and
 scans each run under one body, with no branch on a layer's kind in the traced
 program. Every per-kind stack rides whole (the K/V pools, the tail pool, the
 experts' one stack, each kind's weights on a leading axis of ITS layers) and a
 layer's part is reached by offset: attention layer ``a`` at ``table + a·N``,
 window layer ``w`` at ``wtable + w·Nw``, conv layer ``c`` at ``table + c·N``,
-expert layer ``e`` at ``e·held``.
+state-space layer ``m`` at ``m·S + slot``, expert layer ``e`` at ``e·held``.
 
 The conv mixer, for the normed residual ``h``::
 
@@ -28,6 +29,20 @@ The conv mixer, for the normed residual ``h``::
 
 Its state at position ``t`` is ``z_{t-1}``, ``z_{t-2}``
 (:class:`~.decode.PagedHybridCache` says where they live and why).
+
+The state-space (Mamba-2) mixer, for the normed residual ``h``, head ``i`` of
+group ``i // (heads / groups)``::
+
+    [z | xBC | dt] = h · W_in       (D -> inner + conv_dim + heads)
+    xBC_t <- silu(Σ_k w_k ⊙ xBC_{t-taps+1+k} + b)   (depthwise, causal; zero
+                                     before the sequence's start)
+    Δ_t = softplus(dt_t + dt_bias_i),  a_t = exp(Δ_t · A_i),  A_i = -exp(A_log_i)
+    S_t = a_t · S_{t-1} + Δ_t · x_t ⊗ B_t           (d_head x d_state, float32)
+    y_t = S_t · C_t + D_i · x_t
+    y <- RMSNorm_groups(y ⊙ silu(z)) · W_out
+
+Its state at position ``t`` is ``S_{t-1}`` and the last ``taps - 1``
+pre-activation ``xBC`` rows (:class:`~.decode.PagedStateCache`).
 """
 
 from __future__ import annotations
@@ -43,6 +58,7 @@ from jax import lax
 
 from tree_attention_tpu.models.decode import (
     PagedHybridCache,
+    PagedStateCache,
     PagedWindowCache,
     _Attend,
     _RowGroup,
@@ -51,6 +67,7 @@ from tree_attention_tpu.models.decode import (
 )
 from tree_attention_tpu.models.transformer import (
     Params,
+    StateSpace,
     TransformerConfig,
     _mlp_block,
     rms_norm,
@@ -142,15 +159,223 @@ def conv_mixer(layer: Params, x: jax.Array, tail: jax.Array, c,
     return x + y, flat.reshape(tail.shape), wrote
 
 
+
+# ---------------------------------------------------------------------------
+# The state-space mixer
+# ---------------------------------------------------------------------------
+
+_HI = lax.Precision.HIGHEST
+
+
+def pack_state(s: jax.Array, sm: StateSpace) -> jax.Array:
+    """``(..., heads, d_head, d_state)`` as the pool holds it
+    (``StateSpace.state_shape``): ``pack`` heads side by side on the
+    lanes."""
+    lead = s.shape[:-3]
+    s = s.reshape(lead + (sm.n_heads // sm.pack, sm.pack, sm.d_head,
+                          sm.d_state))
+    return jnp.moveaxis(s, -1, -3).reshape(lead + sm.state_shape)
+
+
+def unpack_state(s: jax.Array, sm: StateSpace) -> jax.Array:
+    """:func:`pack_state`'s inverse."""
+    lead = s.shape[:-3]
+    s = s.reshape(lead + (sm.n_heads // sm.pack, sm.d_state, sm.pack,
+                          sm.d_head))
+    return jnp.moveaxis(s, -3, -1).reshape(
+        lead + (sm.n_heads, sm.d_head, sm.d_state))
+
+
+def ssm_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
+             C: jax.Array, s0: jax.Array, chunk: int
+             ) -> Tuple[jax.Array, jax.Array]:
+    """The recurrence ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t``, ``y_t
+    = S_t . C_t`` over ``T`` rows in its chunked form: blocks of ``chunk``
+    rows, the products inside a block as matrix products over the block's
+    rows, the state carried block to block. ``x`` ``(b, T, H, P)``, ``dt``
+    ``(b, T, H)`` (0: the row leaves the state as it is and adds nothing),
+    ``A`` ``(H,)``, ``B`` / ``C`` ``(b, T, G, N)``, ``s0`` ``(b, H, P, N)``;
+    all float32. Returns ``y`` ``(b, T, H, P)`` and the state after the last
+    row. Float32 at the highest matmul precision: what is added to a state
+    is never rounded below it."""
+    b, T, H, P = x.shape
+    G = B.shape[2]
+    L = min(T, chunk)
+    pad = -T % L
+    if pad:
+        # Rows with dt 0: they move no state and their y is dropped.
+        x, dt, B, C = (jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+                       for t in (x, dt, B, C))
+    rep = H // G
+    tri = jnp.tril(jnp.ones((L, L), bool))
+    s, ys = s0, []
+    for j in range((T + pad) // L):
+        xb, dtb, Bb, Cb = (t[:, j * L:(j + 1) * L] for t in (x, dt, B, C))
+        cum = jnp.cumsum(dtb * A, axis=1)                    # (b, L, H) <= 0
+        dx = dtb[..., None] * xb                             # (b, L, H, P)
+        # Inside the block: row l sees row s <= l through exp(cum_l - cum_s).
+        cb = jnp.einsum("blgn,bsgn->blsg", Cb, Bb, precision=_HI)
+        seen = jnp.where(tri[None, :, :, None],
+                         cum[:, :, None, :] - cum[:, None, :, :], -jnp.inf)
+        w = jnp.exp(seen) * jnp.repeat(cb, rep, axis=3)      # (b, l, s, H)
+        y = jnp.einsum("blsh,bshp->blhp", w, dx, precision=_HI)
+        # From the state the block started with.
+        Ch = jnp.repeat(Cb, rep, axis=2)                     # (b, L, H, N)
+        y = y + jnp.exp(cum)[..., None] * jnp.einsum(
+            "blhn,bhpn->blhp", Ch, s, precision=_HI)
+        ys.append(y)
+        # The state the block leaves.
+        to_end = jnp.exp(cum[:, -1:, :] - cum)               # (b, L, H)
+        s = jnp.exp(cum[:, -1])[..., None, None] * s + jnp.einsum(
+            "blhp,blhn->bhpn", to_end[..., None] * dx,
+            jnp.repeat(Bb, rep, axis=2), precision=_HI)
+    y = ys[0] if len(ys) == 1 else jnp.concatenate(ys, axis=1)
+    return y[:, :T], s
+
+
+def ssm_step(state: jax.Array, x: jax.Array, a: jax.Array, b: jax.Array,
+             c: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """One token's update of packed states ``(batch, Hp, N, L)``: what
+    ``ops/pallas_ssm.py`` ``ssm_decode_update`` computes in place, here in
+    ``jax.numpy`` (off the TPU, and the kernel's oracle). ``x`` / ``a``
+    ``(batch, Hp, L)``, ``b`` / ``c`` ``(batch, G, N)``. Returns the new
+    states and ``y`` ``(batch, Hp, L)``."""
+    rep = state.shape[1] // b.shape[1]
+    b, c = (jnp.repeat(t, rep, axis=1)[..., None] for t in (b, c))
+    new = a[:, :, None, :] * state + b * x[:, :, None, :]
+    return new, jnp.sum(new * c, axis=2)
+
+
+def ssm_mixer(layer: Params, x: jax.Array, state: jax.Array,
+              tail: jax.Array, m, groups: Tuple[_RowGroup, ...],
+              cfg: TransformerConfig
+              ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """The state-space mixer over every group of the step's rows.
+    ``layer``: this layer's leaves (``ln1`` ``(D,)``, ``w_in`` ``(D,
+    in_dim)``, ``conv_w`` ``(taps, conv_dim)`` with tap ``k`` on
+    ``xBC_{t-taps+1+k}``, ``conv_b``, ``dt_bias`` / ``A_log`` / ``D``
+    ``(heads,)`` float32, ``norm`` ``(inner,)``, ``w_out`` ``(inner, D)``);
+    ``state`` / ``tail`` the WHOLE pools of a :class:`PagedStateCache`, slot
+    ``s`` of this layer at ``m·S + s`` of their flat views.
+
+    A **decode group** (one row a slot) takes the single-step recurrence:
+    on a TPU in place, through ``ssm_decode_update`` over the list of slots
+    that have a row (``g.live``). A **chunk group** runs the chunked scan
+    (:func:`ssm_scan`) from each member's state and puts the state it
+    leaves back. Three rules: a member whose first position is 0 starts
+    from a zero state and a zero tail, whatever the pools hold; a row past
+    a member's valid count has ``Δ = 0`` and no input, so it leaves the
+    state bit for bit; a member with no row writes nothing (and a decode
+    slot with no row is not read either). The state, ``Δ``, ``a`` and ``S .
+    C`` in float32, the projections in the served type. Returns the
+    residual with the mixer's output added, the two pools, and how many
+    states were written."""
+    from tree_attention_tpu.ops.pallas_ssm import ssm_decode_update
+
+    sm = cfg.ssm
+    H, P, G, N = sm.n_heads, sm.d_head, sm.n_groups, sm.d_state
+    inner, cd, back = sm.inner, sm.conv_dim, sm.taps - 1
+    S = state.shape[1]
+    with jax.named_scope(scopes.ATTN_IN):
+        h = rms_norm(x, layer["ln1"], cfg.norm_eps)
+        zxd = h @ layer["w_in"]
+    with jax.named_scope(scopes.CONV):
+        z, xbc, dt = jnp.split(zxd, [inner, inner + cd], axis=-1)
+        A = -jnp.exp(layer["A_log"].astype(jnp.float32))
+        taps = layer["conv_w"].astype(jnp.float32)
+        flat_s = state.reshape((-1,) + state.shape[2:])
+        flat_t = tail.reshape(-1, tail.shape[2])     # a slot's rows, flat
+        outs, wrote = [], jnp.int32(0)
+        for g in groups:
+            at = m * S + g.slot
+            has = g.n_valid > 0
+            fresh = g.start == 0
+            to = jnp.where(has, at, flat_s.shape[0])     # no row: dropped
+            wrote = wrote + jnp.sum(has, dtype=jnp.int32)
+            with jax.named_scope(scopes.SSM_TAPS):
+                xg = g.take(xbc[:, None])[:, 0]          # (batch, tq, cd)
+                old = jnp.where(fresh[:, None], 0, flat_t[at])
+                bias = layer["conv_b"].astype(jnp.float32)
+                if g.tq == 1:
+                    # A slot's rows as they lie on the lanes: a (slots, 3,
+                    # conv_dim) view of them is a copy in another tiling.
+                    rows = [old[:, k * cd:(k + 1) * cd]
+                            for k in range(back)] + [xg[:, 0].astype(old.dtype)]
+                    conv = sum(taps[k] * rows[k].astype(jnp.float32)
+                               for k in range(sm.taps))[:, None]
+                    left = jnp.concatenate(rows[1:], axis=-1)
+                else:
+                    pre = jnp.concatenate(
+                        [old.reshape(g.batch, back, cd),
+                         xg.astype(old.dtype)], axis=1)
+                    conv = sum(
+                        taps[k] * pre[:, k:k + g.tq].astype(jnp.float32)
+                        for k in range(sm.taps))
+                    # The tail a member leaves: the rows before its next.
+                    keep = g.n_valid[:, None] + jnp.arange(
+                        back, dtype=jnp.int32)
+                    left = jnp.take_along_axis(
+                        pre, keep[:, :, None], axis=1).reshape(g.batch, -1)
+                conv = jax.nn.silu(conv + bias)
+                flat_t = flat_t.at[to].set(left, mode="drop")
+                xs = conv[..., :inner].reshape(g.batch, g.tq, H, P)
+                Bm = conv[..., inner:inner + G * N].reshape(
+                    g.batch, g.tq, G, N)
+                Cm = conv[..., inner + G * N:].reshape(g.batch, g.tq, G, N)
+                dts = jax.nn.softplus(
+                    g.take(dt[:, None])[:, 0].astype(jnp.float32)
+                    + layer["dt_bias"].astype(jnp.float32))
+                dts = jnp.where(g.valid[..., None], dts, 0.0)
+            if g.tq == 1:
+                with jax.named_scope(scopes.SSM_UPDATE):
+                    # A fresh member's old state decays to nothing.
+                    a = jnp.where(fresh[:, None], 0.0,
+                                  jnp.exp(dts[:, 0] * A))            # (b, H)
+                    rows = (g.batch, H // sm.pack, sm.pack * P)
+                    dx = (dts[:, 0, :, None] * xs[:, 0]).reshape(rows)
+                    a = jnp.repeat(a, P, axis=-1).reshape(rows)
+                    if g.live is not None:
+                        flat_s, y = ssm_decode_update(
+                            flat_s, dx, a, jnp.swapaxes(Bm[:, 0], 1, 2),
+                            jnp.swapaxes(Cm[:, 0], 1, 2), *g.live, m * S)
+                        y = jnp.where(has[:, None, None], y, 0.0)
+                    else:
+                        new, y = ssm_step(flat_s[at], dx, a, Bm[:, 0],
+                                          Cm[:, 0])
+                        flat_s = flat_s.at[to].set(new, mode="drop")
+                    y = y.reshape(g.batch, 1, H, P)
+            else:
+                with jax.named_scope(scopes.SSM_SCAN):
+                    s0 = jnp.where(fresh[:, None, None, None], 0.0,
+                                   unpack_state(flat_s[at], sm))
+                    y, s1 = ssm_scan(xs, dts, A, Bm, Cm, s0, sm.chunk)
+                    flat_s = flat_s.at[to].set(pack_state(s1, sm),
+                                               mode="drop")
+            with jax.named_scope(scopes.SSM_NORM):
+                y = y + layer["D"].astype(jnp.float32)[:, None] * xs
+                y = y.reshape(g.batch, g.tq, inner) * jax.nn.silu(
+                    g.take(z[:, None])[:, 0].astype(jnp.float32))
+                yg = y.reshape(g.batch, g.tq, G, inner // G)
+                yg = yg * lax.rsqrt(jnp.mean(yg * yg, axis=-1, keepdims=True)
+                                    + cfg.norm_eps)
+                y = yg.reshape(y.shape) * layer["norm"]
+                outs.append(y.astype(x.dtype)[:, None])
+        y = _join_rows(groups, outs)[:, 0]
+    with jax.named_scope(scopes.ATTN_OUT):
+        x = x + y @ layer["w_out"]
+    return (x, flat_s.reshape(state.shape), flat_t.reshape(tail.shape),
+            wrote)
+
+
 def layer_runs(cfg: TransformerConfig) -> List[Tuple[str, str, int, int, int]]:
     """The depth cut into runs of consecutive layers of one kind:
     ``(mixer, ffn, layers, first of its mixer kind, first of its ffn
     kind)``, the two offsets counted among the layers of that kind."""
     types = cfg.layer_types or ("attention",) * cfg.n_layers
     runs: List[list] = []
-    seen = {"attention": 0, "window": 0, "conv": 0, "dense": 0, "expert": 0}
-    for l, mixer in enumerate(types):
-        ffn = "dense" if l < cfg.n_dense_layers else "expert"
+    seen = {"attention": 0, "window": 0, "conv": 0, "ssm": 0, "dense": 0,
+            "expert": 0, "none": 0}
+    for mixer, ffn in zip(types, cfg.ffn_kinds):
         if runs and runs[-1][:2] == [mixer, ffn]:
             runs[-1][2] += 1
         else:
@@ -164,7 +389,7 @@ def hybrid_layers(
     params: Params,
     x: jax.Array,
     positions: jax.Array,
-    cache: Union[PagedHybridCache, PagedWindowCache],
+    cache: Union[PagedHybridCache, PagedWindowCache, PagedStateCache],
     cfg: TransformerConfig,
     attend: _Attend,
     stats: Optional[Dict[str, Any]],
@@ -177,13 +402,14 @@ def hybrid_layers(
     stack a kind on a leading axis of that kind's layers: ``attn``
     (``ln1``, ``wq``, ``wk``, ``wv``, ``wo``, with QK-norm ``q_ln`` /
     ``k_ln``), ``wattn`` (the window layers: the same leaves), ``conv``
-    (:func:`conv_mixer`'s leaves), ``dense`` (``ln2``,
-    ``w1``, ``w3``, ``w2``) and ``layers`` (the expert layers: ``ln2``,
-    ``router``, ``router_bias``, ``we1`` / ``we3`` / ``we2`` and the shared
-    experts' ``ws*``). Returns the residual and the pools by field name."""
-    from tree_attention_tpu.models.experts import (
-        EXPERT_LEAVES, expert_layer, held_counts,
-    )
+    (:func:`conv_mixer`'s leaves), ``ssm`` (:func:`ssm_mixer`'s), ``dense``
+    (``ln2``, ``w1``, ``w3``, ``w2``) and ``layers`` (the expert layers:
+    ``ln2``, ``router``, ``router_bias``, ``we1`` / ``we3`` / ``we2`` and
+    the shared experts' ``ws*``; experts in a latent: ``w_down`` / ``w_up``
+    too; ungated experts: no ``we3`` / ``ws3``). A layer whose feed-forward
+    kind is ``"none"`` is its mixer alone. Returns the residual and the
+    pools by field name."""
+    from tree_attention_tpu.models.experts import expert_layer, held_counts
 
     groups = attend.groups
     N, block = cache.blocks, cache.block
@@ -199,13 +425,13 @@ def hybrid_layers(
     if groups[0].lo is not None:
         valid = jnp.concatenate([g.valid.reshape(-1) for g in groups])[None]
     experts = routers = None
-    if cfg.n_layers > cfg.n_dense_layers:
+    if cfg.n_expert_layers:
         # Every layer's experts as ONE stack (a bitcast), a layer's reached
         # by offset: sliced out, the kernel would be handed a copy.
         stack = params["layers"]
         experts = tuple(stack[n].reshape((-1,) + stack[n].shape[2:])
-                        for n in EXPERT_LEAVES)
-        routers = {n: a for n, a in stack.items() if n not in EXPERT_LEAVES}
+                        for n in cfg.moe.leaves)
+        routers = {n: a for n, a in stack.items() if n not in cfg.moe.leaves}
 
     def of(stack, i):
         """Layer ``i`` of a kind's stack: ``i`` a Python int (a run of
@@ -217,10 +443,13 @@ def hybrid_layers(
 
     def body_of(mixer, ffn, m0, f0):
         def body(carry, i):
-            x, k, v, tail, wk, wv = carry
+            x, k, v, tail, wk, wv, state, stail = carry
             mi, fi = m0 + i, f0 + i
             wrote = jnp.int32(0)
-            if mixer == "attention":
+            if mixer == "ssm":
+                x, state, stail, wrote = ssm_mixer(
+                    of(params["ssm"], mi), x, state, stail, mi, groups, cfg)
+            elif mixer == "attention":
                 x, k, v, _, _ = gqa_mixer(
                     attend, of(params["attn"], mi), x, positions, k, v,
                     None, None, None, mi, mi * N)
@@ -238,7 +467,8 @@ def hybrid_layers(
                     layer = of(params["dense"], fi)
                     x = x + _mlp_block(
                         layer, rms_norm(x, layer["ln2"], cfg.norm_eps))
-                return (x, k, v, tail, wk, wv), (None, wrote)
+            if ffn != "expert":
+                return (x, k, v, tail, wk, wv, state, stail), (None, wrote)
             with jax.named_scope(scopes.ROUTE):
                 layer = of(routers, fi)
                 h32 = rms_norm(
@@ -249,14 +479,16 @@ def hybrid_layers(
                 experts=experts, first=fi * cfg.moe.held,
             )
             with jax.named_scope(scopes.ROUTE):
-                return (x + y, k, v, tail, wk, wv), (
+                return (x + y, k, v, tail, wk, wv, state, stail), (
                     held_counts(chosen, valid, cfg.moe), wrote)
 
         return body
 
     # A pool the cache has not is None: an empty part of the carry.
     carry = (x, cache.k, cache.v, getattr(cache, "tail", None),
-             getattr(cache, "wk", None), getattr(cache, "wv", None))
+             getattr(cache, "wk", None), getattr(cache, "wv", None),
+             getattr(cache, "ssm_state", None),
+             getattr(cache, "ssm_tail", None))
     counts, wrote = [], jnp.int32(0)
     for mixer, ffn, n, m0, f0 in layer_runs(cfg):
         body = body_of(mixer, ffn, m0, f0)
@@ -274,9 +506,13 @@ def hybrid_layers(
             stats["expert_rows"] = jnp.concatenate(counts, axis=0)
         if cfg.conv_layers:
             stats["tail_blocks"] = wrote
-    x, k, v, tail, wk, wv = carry
+        if cfg.ssm_layers:
+            stats["ssm_states"] = wrote
+    x, k, v, tail, wk, wv, state, stail = carry
     if cfg.window_layers:
         return x, {"k": k, "v": v, "wk": wk, "wv": wv}
+    if cfg.ssm_layers:
+        return x, {"k": k, "v": v, "ssm_state": state, "ssm_tail": stail}
     return x, {"k": k, "v": v, "tail": tail}
 
 
@@ -323,6 +559,28 @@ def init_hybrid_params(key: jax.Array, cfg: TransformerConfig) -> Params:
                 "w_out": normal(k[2], (D, D), res_std),
             }
 
+        def ssm(k):
+            # The published init: -A over 1-16, dt log-uniform over the
+            # published time-step range, D at one.
+            sm = cfg.ssm
+            k = jax.random.split(k, 5)
+            dt = jnp.exp(jax.random.uniform(
+                k[3], (sm.n_heads,), jnp.float32,
+                jnp.log(1e-3), jnp.log(1e-1)))
+            return {
+                "ln1": jnp.ones((D,), jnp.float32),
+                "w_in": normal(k[0], (D, sm.in_dim), 0.02),
+                "conv_w": normal(k[1], (sm.taps, sm.conv_dim),
+                                 sm.taps ** -0.5),
+                "conv_b": jnp.zeros((sm.conv_dim,), cfg.dtype),
+                "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+                "A_log": jnp.log(jax.random.uniform(
+                    k[4], (sm.n_heads,), jnp.float32, 1.0, 16.0)),
+                "D": jnp.ones((sm.n_heads,), jnp.float32),
+                "norm": jnp.ones((sm.inner,), jnp.float32),
+                "w_out": normal(k[2], (sm.inner, D), res_std),
+            }
+
         def dense(k):
             k = jax.random.split(k, 3)
             return {
@@ -334,19 +592,25 @@ def init_hybrid_params(key: jax.Array, cfg: TransformerConfig) -> Params:
 
         def expert(k):
             k_r, k_e, k_s = jax.random.split(k, 3)
+            De = ex.latent or D      # the width the routed experts see
 
             def one(k):
                 k = jax.random.split(k, 3)
-                return (normal(k[0], (D, ex.width), 0.02),
-                        normal(k[1], (D, ex.width), 0.02),
-                        normal(k[2], (ex.width, D), res_std))
+                w = {"we1": normal(k[0], (De, ex.width), 0.02),
+                     "we3": normal(k[1], (De, ex.width), 0.02),
+                     "we2": normal(k[2], (ex.width, De), res_std)}
+                return tuple(w[n] for n in ex.leaves)
 
-            we1, we3, we2 = lax.map(one, jax.random.split(k_e, ex.held))
             out = {
                 "ln2": jnp.ones((D,), jnp.float32),
                 "router": normal(k_r, (D, ex.n_experts), 0.02),
-                "we1": we1, "we3": we3, "we2": we2,
+                **dict(zip(ex.leaves, lax.map(
+                    one, jax.random.split(k_e, ex.held)))),
             }
+            if ex.latent:
+                k_d, k_u = jax.random.split(jax.random.fold_in(k_r, 2))
+                out.update(w_down=normal(k_d, (D, De), 0.02),
+                           w_up=normal(k_u, (De, D), 0.02))
             if ex.corrected:
                 # Of the order of the gaps between neighbouring scores at
                 # the top: a sigmoid's lie some twenty times wider apart
@@ -359,8 +623,10 @@ def init_hybrid_params(key: jax.Array, cfg: TransformerConfig) -> Params:
                 s = jax.random.split(k_s, 3)
                 out.update(
                     ws1=normal(s[0], (D, ex.shared_width), 0.02),
-                    ws3=normal(s[1], (D, ex.shared_width), 0.02),
                     ws2=normal(s[2], (ex.shared_width, D), res_std))
+                if ex.gated:
+                    out.update(
+                        ws3=normal(s[1], (D, ex.shared_width), 0.02))
             return out
 
         out = {
@@ -375,8 +641,9 @@ def init_hybrid_params(key: jax.Array, cfg: TransformerConfig) -> Params:
                 ("wattn", attn, cfg.window_layers,
                  jax.random.fold_in(ks[2], 1)),
                 ("conv", conv, cfg.conv_layers, ks[3]),
+                ("ssm", ssm, cfg.ssm_layers, jax.random.fold_in(ks[3], 1)),
                 ("dense", dense, n_dense, ks[4]),
-                ("layers", expert, cfg.n_layers - n_dense, ks[5])):
+                ("layers", expert, cfg.n_expert_layers, ks[5])):
             if n:
                 out[name] = lax.map(make_one, jax.random.split(k, n))
         return out

@@ -121,6 +121,14 @@ class ExpertLayer:
     # residual that sublayer ``leaves``'s FFN reads and added to the
     # residual after sublayer ``rejoins``'s FFN.
     branch: Optional[Tuple[int, int]] = None
+    # The routed experts live in a latent of this width, narrower than the
+    # residual: a row goes down (``w_down``) before the gather and the
+    # weighed sum comes up (``w_up``) after it; the router and the shared
+    # expert see the full width. 0: the experts see the residual's width.
+    latent: int = 0
+    # False: an expert (the shared one too) is one matrix in, relu squared,
+    # one matrix out; there is no gate matrix (``we3`` / ``ws3``).
+    gated: bool = True
 
     def __post_init__(self):
         if self.scoring not in ("softmax", "sigmoid"):
@@ -150,6 +158,69 @@ class ExpertLayer:
         """Experts with weights: the router's ids below this."""
         return self.n_experts - self.n_zero
 
+    @property
+    def leaves(self) -> Tuple[str, ...]:
+        """The routed experts' stacked matrices, in the order the grouped
+        products take them: in (``we1``, and ``we3`` where gated), out."""
+        return ("we1", "we3", "we2") if self.gated else ("we1", "we2")
+
+
+@dataclasses.dataclass(frozen=True)
+class StateSpace:
+    """A state-space (Mamba-2) mixer's widths (``models/hybrid.py``
+    ``ssm_mixer``): ``n_heads`` heads of ``d_head``, in ``n_groups`` groups
+    that share their ``B`` and ``C`` rows of ``d_state``; a depthwise causal
+    convolution of ``taps`` taps with a bias over ``[x | B | C]``; a scan
+    blocked in ``chunk`` rows."""
+
+    n_heads: int
+    d_head: int
+    n_groups: int
+    d_state: int
+    taps: int
+    chunk: int = 128
+
+    def __post_init__(self):
+        if self.n_heads % self.n_groups or self.taps < 2:
+            raise ValueError(
+                f"a state-space mixer of {self.n_heads} heads in "
+                f"{self.n_groups} groups with {self.taps} taps: the groups "
+                f"divide the heads and the convolution has a tail (>= 2 "
+                f"taps)")
+
+    @property
+    def inner(self) -> int:
+        """The mixer's inner width, ``heads x head``."""
+        return self.n_heads * self.d_head
+
+    @property
+    def conv_dim(self) -> int:
+        """The convolved width: ``x`` and the groups' ``B`` and ``C``."""
+        return self.inner + 2 * self.n_groups * self.d_state
+
+    @property
+    def in_dim(self) -> int:
+        """``W_in``'s output: ``[z | xBC | dt]``."""
+        return self.inner + self.conv_dim + self.n_heads
+
+    @property
+    def pack(self) -> int:
+        """Heads the state pool lays side by side on a row's 128 lanes
+        (``d_head x pack`` lanes, as ``TransformerConfig.kv_pack`` does for
+        KV heads): as many as divide both the lanes and a group, so that a
+        row's heads share their ``B`` and ``C``. No option."""
+        if 128 % self.d_head:
+            return 1
+        return math.gcd(128 // self.d_head, self.n_heads // self.n_groups)
+
+    @property
+    def state_shape(self) -> Tuple[int, int, int]:
+        """One slot's state in one layer as the pool holds it: ``(heads /
+        pack, d_state, pack x d_head)``, ``S[h, p, n]`` at ``[h // pack, n,
+        (h % pack) x d_head + p]``."""
+        return (self.n_heads // self.pack, self.d_state,
+                self.pack * self.d_head)
+
 
 # A layer's mixer kind, by the names published configurations give it in
 # ``layer_types``: the one table :func:`model_from_config` reads kinds from
@@ -158,7 +229,10 @@ PUBLISHED_MIXERS = {
     "full_attention": "attention", "attention": "attention",
     "sliding_attention": "window", "conv": "conv",
 }
-MIXER_KINDS = frozenset(PUBLISHED_MIXERS.values())
+# ``"ssm"`` has no name in ``layer_types``: its family says what a layer is
+# in ``hybrid_override_pattern``, a character a part (:func:`_pattern_layers`).
+MIXER_KINDS = frozenset(PUBLISHED_MIXERS.values()) | {"ssm"}
+FFN_KINDS = frozenset({"dense", "expert", "none"})
 
 
 @dataclasses.dataclass(frozen=True)
@@ -171,13 +245,17 @@ class TransformerConfig:
     with an RMSNorm over each query and key head where ``qk_norm``;
     ``"window"``: the same attention over the last ``window`` positions
     only, a row at ``t`` sees ``(t - window, t]``; ``"conv"``: a gated
-    short convolution of ``conv_taps`` taps), None meaning rotary GQA
+    short convolution of ``conv_taps`` taps; ``"ssm"``: a state-space
+    mixer of ``ssm``'s widths), None meaning rotary GQA
     throughout. ``rotary`` names the attention kinds whose queries and
     keys take the rotary embedding (both by default; a model whose full
-    layers carry no positional term names ``("window",)``). The
+    layers carry no positional term names ``("window",)``, one with no
+    positional term at all ``()``). The
     feed-forward half: ``moe`` set means
     every layer after its ``first_dense`` is a routed-expert layer (else
-    the dense SwiGLU). A latent layer may be ``sublayers`` pairs of one
+    the dense SwiGLU); or ``ffn_types`` says it a layer (``"dense"`` |
+    ``"expert"`` | ``"none"``: a layer that is its mixer alone). A latent
+    layer may be ``sublayers`` pairs of one
     attention and one dense FFN; with more than one, the routed experts are
     a branch beside them (``moe.branch``). ``tied_head``: the head is the
     embedding's transpose and the parameters hold no ``wout``.
@@ -187,7 +265,9 @@ class TransformerConfig:
     ``mla`` is set, and for every conv layer (``conv_layers``) the last
     ``conv_taps - 1`` gated inputs, held as a tail of each pool block; a
     window layer's (``window_layers``) K/V rows live in a pool of their
-    own under a table of their own, a bounded number of blocks a slot.
+    own under a table of their own, a bounded number of blocks a slot; a
+    state-space layer's (``ssm_layers``) state and conv tail are one array
+    a slot, which no table indexes.
     """
 
     vocab_size: int = 32768
@@ -218,6 +298,8 @@ class TransformerConfig:
     tied_head: bool = False
     window: int = 0
     rotary: Tuple[str, ...] = ("attention", "window")
+    ssm: Optional[StateSpace] = None
+    ffn_types: Optional[Tuple[str, ...]] = None
 
     def __post_init__(self):
         if self.n_heads % self.n_kv_heads:
@@ -251,6 +333,18 @@ class TransformerConfig:
                     f"a short convolution of {self.conv_taps} taps: its "
                     f"state is built as the last 2 gated inputs, a two-row "
                     f"tail of each pool block (3 taps)")
+            if ("ssm" in kinds) != (self.ssm is not None) or (
+                    "ssm" in kinds and kinds & {"conv", "window"}):
+                raise ValueError(
+                    f"state-space layers {'ssm' in kinds} with ssm="
+                    f"{self.ssm}: a model with state-space layers states "
+                    f"their widths, a model without states none, and a "
+                    f"recurrent state beside conv or sliding-window layers "
+                    f"is not built")
+        elif self.ssm is not None:
+            raise ValueError(
+                "state-space widths without layer_types: which layers are "
+                "state-space layers is said a layer")
         elif self.window:
             raise ValueError(
                 f"window={self.window} without layer_types: which layers "
@@ -261,7 +355,22 @@ class TransformerConfig:
         if not set(self.rotary) <= {"attention", "window"}:
             raise ValueError(
                 f"rotary {self.rotary}: names of attention kinds "
-                f"('attention', 'window')")
+                f"('attention', 'window'), or none")
+        if self.ffn_types is not None:
+            object.__setattr__(self, "ffn_types", tuple(self.ffn_types))
+            kinds = set(self.ffn_types)
+            if len(self.ffn_types) != self.n_layers \
+                    or not kinds <= set(FFN_KINDS) \
+                    or ("expert" in kinds) != (self.moe is not None) \
+                    or self.layer_types is None \
+                    or (self.moe is not None and self.moe.first_dense):
+                raise ValueError(
+                    f"ffn_types names {len(self.ffn_types)} layers' "
+                    f"feed-forward kinds {sorted(kinds)}: one of "
+                    f"{sorted(FFN_KINDS)} for each of the {self.n_layers} "
+                    f"layers of a model that says its mixers a layer too "
+                    f"(layer_types), 'expert' where the model has experts "
+                    f"(moe, with no first_dense beside it)")
         branch = self.moe.branch if self.moe is not None else None
         if (self.sublayers > 1 and branch is None) or (
                 branch is not None and (
@@ -293,6 +402,27 @@ class TransformerConfig:
         pool's depth."""
         return (self.layer_types or ()).count("window")
 
+    @property
+    def ssm_layers(self) -> int:
+        """Layers whose mixer is the state-space one: the state pool's
+        depth."""
+        return (self.layer_types or ()).count("ssm")
+
+    @property
+    def ffn_kinds(self) -> Tuple[str, ...]:
+        """Each layer's feed-forward kind: ``ffn_types`` where the data says
+        it a layer, else ``moe.first_dense`` leading dense layers and expert
+        layers after them."""
+        if self.ffn_types is not None:
+            return self.ffn_types
+        n = self.n_dense_layers
+        return ("dense",) * n + ("expert",) * (self.n_layers - n)
+
+    @property
+    def n_expert_layers(self) -> int:
+        """Layers whose feed-forward half is the routed experts."""
+        return self.ffn_kinds.count("expert")
+
     def rotates(self, kind: str) -> bool:
         """Whether an attention layer of ``kind`` rotates its queries and
         keys."""
@@ -303,6 +433,7 @@ class TransformerConfig:
         """The Llama-style block this module's ``forward`` computes."""
         return self.mla is None and self.moe is None \
             and not self.conv_layers and not self.window_layers \
+            and not self.ssm_layers and self.ffn_types is None \
             and self.rotates("attention")
 
     @property
@@ -313,11 +444,15 @@ class TransformerConfig:
         conv layers; also rotary-GQA attention over expert layers) or
         ``"window"`` (two K/V pools under two tables: the full-attention
         layers' rows a token, the sliding-window layers' a bounded number
-        of blocks a slot)."""
+        of blocks a slot) or ``"state"`` (K/V rows for the attention layers
+        beside a recurrent state and a conv tail a slot for the
+        state-space layers, which every token rewrites whole)."""
         if self.mla is not None:
             return "latent"
         if self.window_layers:
             return "window"
+        if self.ssm_layers:
+            return "state"
         return "kv" if self.dense_block else "hybrid"
 
     @property
@@ -338,11 +473,13 @@ class TransformerConfig:
         """The cache's depth: one layer of rows for every attention that
         sees its whole context (a window layer's rows: ``window_layers``
         deep, in the window pool)."""
-        return (self.n_layers - self.conv_layers
-                - self.window_layers) * self.sublayers
+        return (self.n_layers - self.conv_layers - self.window_layers
+                - self.ssm_layers) * self.sublayers
 
     @property
     def n_dense_layers(self) -> int:
+        if self.ffn_types is not None:
+            return self.ffn_types.count("dense")
         if self.moe is None:
             return self.n_layers
         return min(self.moe.first_dense, self.n_layers)
@@ -362,7 +499,80 @@ _EXPERT_KEYS = frozenset({
     "n_routed_experts", "num_experts", "n_shared_experts",
     "num_shared_experts", "num_experts_per_tok", "expert_ffn_hidden_size",
     "zero_expert_num", "zero_expert_type", "use_expert_bias",
+    "moe_shared_expert_intermediate_size",
 })
+
+# ``hybrid_override_pattern``'s characters: a layer of the published count
+# is ONE part.
+_PATTERN_PARTS = {"M": "ssm", "*": "attention", "E": "expert", "-": "dense"}
+
+
+def _pattern_layers(pattern: str) -> Tuple[Tuple[str, ...], Tuple[str, ...]]:
+    """``hybrid_override_pattern`` as this repo's layers, a mixer and a
+    feed-forward half each: ``(layer_types, ffn_types)``. A mixer part
+    (``M`` state-space, ``*`` attention) opens a layer; a feed-forward part
+    (``E`` experts, ``-`` dense) right after it is that layer's half, and a
+    mixer followed by another mixer is a layer with no feed-forward half.
+    A feed-forward part with no mixer before it cannot be said so and is
+    refused."""
+    mixers, ffns = [], []
+    for i, ch in enumerate(pattern):
+        part = _PATTERN_PARTS.get(ch)
+        if part is None:
+            raise ValueError(
+                f"hybrid_override_pattern {pattern!r}: character {ch!r} at "
+                f"{i}; the parts built are {sorted(_PATTERN_PARTS)}")
+        if part in ("ssm", "attention"):
+            mixers.append(part)
+            ffns.append("none")
+        elif not mixers or ffns[-1] != "none":
+            raise ValueError(
+                f"hybrid_override_pattern {pattern!r}: the {ch!r} at {i} "
+                f"has no mixer before it; a layer here is a mixer with at "
+                f"most one feed-forward part after it")
+        else:
+            ffns[-1] = part
+    return tuple(mixers), tuple(ffns)
+
+
+def _state_space_from_config(c: Dict[str, Any], block: Dict[str, Any],
+                             hidden: int) -> StateSpace:
+    """The ``nemotron_h`` family's state-space keys, each refused by name
+    where it says what is not built."""
+    for key, built in (("use_conv_bias", True), ("mamba_proj_bias", False),
+                       ("mamba_hidden_act", "silu"), ("use_bias", False),
+                       ("mlp_bias", False), ("attention_bias", False)):
+        if c.get(key, built) != built:
+            raise ValueError(
+                f"{key} {c[key]!r}: the state-space block is built with "
+                f"{key} {built!r}")
+    for key in ("gate_before_norm", "latent_proj_plain"):
+        if block.get(key, True) is not True:
+            raise ValueError(
+                f"block.{key} {block[key]!r}: only true is built (silu(z) "
+                f"gates before the grouped norm; no norm and no bias on "
+                f"the latent projections)")
+    ssm = StateSpace(
+        n_heads=int(c["mamba_num_heads"]), d_head=int(c["mamba_head_dim"]),
+        n_groups=int(c["n_groups"]), d_state=int(c["ssm_state_size"]),
+        taps=int(c["conv_kernel"]), chunk=int(c.get("chunk_size", 128)))
+    if "expand" in c and int(c["expand"]) * hidden != ssm.inner:
+        raise ValueError(
+            f"expand {c['expand']} x hidden_size {hidden} is not "
+            f"mamba_num_heads x mamba_head_dim ({ssm.inner})")
+    return ssm
+
+
+def _gated(c: Dict[str, Any]) -> bool:
+    """Whether the feed-forward parts have a gate matrix, by
+    ``mlp_hidden_act``: ``relu2`` is one matrix in, relu squared, one out;
+    ``silu`` (or no key) the SwiGLU."""
+    act = str(c.get("mlp_hidden_act", "silu"))
+    if act not in ("silu", "relu2"):
+        raise ValueError(
+            f"mlp_hidden_act {act!r}: 'silu' (a gated SwiGLU) and 'relu2' "
+            f"(ungated, relu squared) are built")
+    return act == "silu"
 
 
 def model_from_config(config: Dict[str, Any], *, dtype: Any = None,
@@ -389,7 +599,17 @@ def model_from_config(config: Dict[str, Any], *, dtype: Any = None,
       own, must agree) or ``conv`` (a gated short convolution:
       ``conv_L_cache`` taps, ``conv_bias`` false); without it every layer
       is rotary GQA. ``rope_parameters.rope_type`` other than ``default``
-      is refused.
+      is refused. Or ``hybrid_override_pattern`` (the ``nemotron_h``
+      family) names every PART of the published depth, a character each:
+      ``M`` a state-space mixer (``mamba_num_heads``, ``mamba_head_dim``,
+      ``n_groups``, ``ssm_state_size``, ``conv_kernel``, ``chunk_size``;
+      ``expand`` must agree; ``use_conv_bias`` true, ``mamba_proj_bias`` /
+      ``use_bias`` / ``mlp_bias`` / ``attention_bias`` false,
+      ``mamba_hidden_act`` ``silu``: any other refused by name), ``*``
+      attention, ``E`` routed experts, ``-`` a dense FFN. A mixer and the
+      feed-forward part right after it are one of this repo's layers, a
+      mixer followed by a mixer a layer with no feed-forward half
+      (:func:`_pattern_layers`).
     - each layer's feed-forward half: ``n_routed_experts`` /
       ``num_experts`` select the expert layer after the first
       ``first_k_dense_replace`` / ``num_dense_layers`` layers (an expert's
@@ -404,7 +624,11 @@ def model_from_config(config: Dict[str, Any], *, dtype: Any = None,
       limit, is accepted);
       ``routed_scaling_factor``, ``norm_topk_prob``; the scoring
       ``scoring_func``: ``softmax`` | ``sigmoid``; ``use_expert_bias``: the
-      top is taken of scores + a per-expert bias). A key that names
+      top is taken of scores + a per-expert bias;
+      ``moe_shared_expert_intermediate_size``: the shared expert's width,
+      said outright; ``moe_latent_size``: the routed experts live in a
+      latent of that width; ``mlp_hidden_act`` ``relu2``: the experts have
+      no gate matrix). A key that names
       experts and is none of these is refused by name: a file whose
       experts this reader cannot see is never built dense.
 
@@ -425,8 +649,12 @@ def model_from_config(config: Dict[str, Any], *, dtype: Any = None,
     each query and key head, before the rotary embedding),
     ``rotary_layers`` (the published name of the one attention kind whose
     queries and keys are rotated, ``"sliding_attention"`` for a model
-    whose full layers carry no positional term; ``"all"`` or absent: every
-    attention layer), ``scale_renormed`` (``routed_scaling_factor``
+    whose full layers carry no positional term; ``"none"``: no attention
+    layer has one; ``"all"`` or absent: every
+    attention layer), ``gate_before_norm`` and ``latent_proj_plain`` (a
+    state-space mixer's ``silu(z)`` gates before its grouped norm; the
+    latent projections have no norm and no bias: true, the only rule built,
+    any other refused by name), ``scale_renormed`` (``routed_scaling_factor``
     multiplies the renormed weights too: renorm, then scale) and
     ``norm_placement`` (``"pre"``, the only one built: any other is
     refused by name)."""
@@ -516,8 +744,10 @@ def model_from_config(config: Dict[str, Any], *, dtype: Any = None,
             held_first=int(dep.get("expert_share", 0)) * held,
             per_token=int(_key(c, "num_experts_per_tok", "moe_topk")),
             width=width,
-            shared_width=int(c.get("n_shared_experts")
-                             or c.get("num_shared_experts") or 0) * width,
+            shared_width=int(
+                c.get("moe_shared_expert_intermediate_size")
+                or int(c.get("n_shared_experts")
+                       or c.get("num_shared_experts") or 0) * width),
             n_groups=int(c["n_group"]) if grouped else 1,
             top_groups=int(c["topk_group"]) if grouped else 1,
             scale=float(c.get("routed_scaling_factor", 1.0)),
@@ -532,11 +762,30 @@ def model_from_config(config: Dict[str, Any], *, dtype: Any = None,
                         or block.get("router_scoring") or "softmax"),
             branch=None if branch is None else (int(branch[0]),
                                                 int(branch[1])),
+            latent=int(c.get("moe_latent_size") or 0),
+            gated=_gated(c),
         )
     if dtype is None:
         dtype = jnp.dtype(str(c.get("torch_dtype", "bfloat16")))
-    layer_types, window = None, 0
-    if c.get("layer_types") is not None:
+    layer_types, window, ssm, ffn_types = None, 0, None, None
+    if c.get("hybrid_override_pattern") is not None:
+        if c.get("layer_types") is not None:
+            raise ValueError(
+                "hybrid_override_pattern beside layer_types: a file says "
+                "its layers' kinds one way")
+        pattern = str(c["hybrid_override_pattern"])
+        if len(pattern) != int(c["num_hidden_layers"]):
+            raise ValueError(
+                f"hybrid_override_pattern names {len(pattern)} parts for "
+                f"num_hidden_layers {c['num_hidden_layers']}")
+        layer_types, ffn_types = _pattern_layers(pattern)
+        if "ssm" in layer_types:
+            ssm = _state_space_from_config(c, block, hidden)
+        if "dense" in ffn_types and not _gated(c):
+            raise ValueError(
+                "a dense feed-forward part ('-') with mlp_hidden_act "
+                "relu2 is not built")
+    elif c.get("layer_types") is not None:
         names = PUBLISHED_MIXERS
         other = sorted({str(t) for t in c["layer_types"]} - set(names))
         if other:
@@ -566,24 +815,28 @@ def model_from_config(config: Dict[str, Any], *, dtype: Any = None,
             f"block.norm_placement {block['norm_placement']!r}: only 'pre' "
             f"(a norm before each half, on the residual) is built")
     rotary = ("attention", "window")
-    if block.get("rotary_layers", "all") != "all":
+    if block.get("rotary_layers", "all") == "none":
+        rotary = ()
+    elif block.get("rotary_layers", "all") != "all":
         said = block["rotary_layers"]
         said = [said] if isinstance(said, str) else list(said)
         other = sorted(set(map(str, said)) - set(PUBLISHED_MIXERS))
         if other or any(PUBLISHED_MIXERS[str(t)] == "conv" for t in said):
             raise ValueError(
-                f"block.rotary_layers {said}: 'all' or attention kinds "
-                f"of {sorted(PUBLISHED_MIXERS)}")
+                f"block.rotary_layers {said}: 'all', 'none' or attention "
+                f"kinds of {sorted(PUBLISHED_MIXERS)}")
         rotary = tuple(sorted({PUBLISHED_MIXERS[str(t)] for t in said}))
     kw = dict(
         vocab_size=int(c["vocab_size"]), d_model=hidden,
-        n_layers=int(_key(c, "num_hidden_layers", "num_layers")),
+        n_layers=len(layer_types) if ffn_types is not None
+        else int(_key(c, "num_hidden_layers", "num_layers")),
         n_heads=heads, n_kv_heads=kv_heads, d_head=d_head,
         d_ff=int(_key(c, "intermediate_size", "ffn_hidden_size")),
         max_seq_len=max_seq_len,
         rope_theta=float(
             c.get("rope_theta") or rp.get("rope_theta", 10000.0)),
-        norm_eps=float(c.get("rms_norm_eps") or c.get("norm_eps") or 1e-6),
+        norm_eps=float(c.get("rms_norm_eps") or c.get("norm_eps")
+                       or c.get("layer_norm_epsilon") or 1e-6),
         dtype=dtype,
         mla=mla, moe=moe, sublayers=int(block.get("sublayers", 1)),
         layer_types=layer_types,
@@ -591,7 +844,7 @@ def model_from_config(config: Dict[str, Any], *, dtype: Any = None,
         qk_norm=bool(block.get("qk_norm", False)),
         tied_head=bool(c.get("tie_word_embeddings")
                        or c.get("tie_embedding")),
-        window=window, rotary=rotary,
+        window=window, rotary=rotary, ssm=ssm, ffn_types=ffn_types,
     )
     kw.update(overrides)
     return TransformerConfig(**kw)
@@ -610,7 +863,7 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Params:
     scaled by ``(2·n_layers)^-1/2`` so the residual stream's variance stays O(1)
     at init regardless of depth.
     """
-    if cfg.cache_kind in ("hybrid", "window"):
+    if cfg.cache_kind in ("hybrid", "window", "state"):
         from tree_attention_tpu.models.hybrid import init_hybrid_params
 
         return init_hybrid_params(key, cfg)

@@ -30,13 +30,22 @@ ATTN_CACHE = "attn_cache"    # the new rows' write into the paged pool
 ATTN_DECODE = "attn_decode"  # the decode rows' attention, merge, unpacking
 ATTN_CHUNK = "attn_chunk"    # a packed tick's chunk rows' attention
 ATTN_OUT = "attn_out"        # the output projection and the residual add
-CONV = "conv"                # the gated short convolution, whole
+CONV = "conv"                # a mixer with a fixed-size state: the gated
+                             # short convolution, whole; a state-space mixer
+                             # between its projections
 FFN = "ffn"                  # a dense SwiGLU with its norm; shared experts
 ROUTE = "route"              # router, choice, sort / gather / weigh / add
 EXPERTS = "experts"          # the grouped expert products
 HEAD = "head"                # final norm, logits, float32 convert, sampling
 SCOPES = (EMBED, ATTN_IN, ATTN_CACHE, ATTN_DECODE, ATTN_CHUNK, ATTN_OUT,
           CONV, FFN, ROUTE, EXPERTS, HEAD)
+#: Names INSIDE ``conv`` (never outermost; a part is read by the outermost
+#: name alone and the table keeps the rest of the path, ``conv/ssm_update``):
+#: a state-space mixer's taps with its step sizes and tail, a chunk group's
+#: scan, the decode step, the gate with the grouped norm.
+SSM_TAPS, SSM_SCAN, SSM_UPDATE, SSM_NORM = (
+    "ssm_taps", "ssm_scan", "ssm_update", "ssm_norm")
+INNER = (SSM_TAPS, SSM_SCAN, SSM_UPDATE, SSM_NORM)
 
 #: Opcodes whose result names a buffer and moves no byte of it: they run as
 #: no device operation, so a table leaves them out.

@@ -12,7 +12,12 @@ the DYNAMIC length of that list.
 Two products share the kernel body: the gate/up pair with the SwiGLU fused
 (``silu(x @ w1[g]) * (x @ w3[g])``: the rows are read once for both), and the
 down projection. Both are named ``moe_grouped_matmul`` in the compiled module
-and the profiler trace.
+and the profiler trace. An UNGATED expert layer (one matrix in, relu squared,
+one matrix out: no gate) takes the same body with the activation on its one
+accumulator, and both its products carry a name of their own,
+``moe_ungated_matmul`` (:data:`UNGATED_KERNEL`): the benchmark's readers
+match kernels by substring and count a gated layer's three matrices an
+expert under the other name.
 
 Off the TPU :func:`grouped_matmul` is ``lax.ragged_dot`` (the CPU reference
 path the tests hold the kernel against in interpret mode).
@@ -43,6 +48,13 @@ _KERNEL_DISPATCH = obs.counter(
 )
 
 
+GATED_KERNEL, UNGATED_KERNEL = "moe_grouped_matmul", "moe_ungated_matmul"
+
+
+def _relu2(x: jax.Array) -> jax.Array:
+    return jnp.square(jnp.maximum(x, 0.0))
+
+
 def tile_plan(group_sizes: jax.Array, m: int, tm: int
               ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """The work list for ``m`` rows in tiles of ``tm``: ``(offsets (G+1,),
@@ -67,7 +79,8 @@ def tile_plan(group_sizes: jax.Array, m: int, tm: int
 
 
 def _grouped_kernel(offs_ref, gid_ref, mt_ref, first_ref, lhs_ref, *refs,
-                    n_rhs: int, tm: int, tn: int, tiles_k: int):
+                    n_rhs: int, tm: int, tn: int, tiles_k: int,
+                    relu2: bool = False):
     del first_ref  # the index maps' (where the groups start in the stack)
     rhs_refs = refs[:n_rhs]
     out_ref = refs[n_rhs]
@@ -95,6 +108,8 @@ def _grouped_kernel(offs_ref, gid_ref, mt_ref, first_ref, lhs_ref, *refs,
         val = accs[0][...]
         if n_rhs == 2:  # the gate/up pair: SwiGLU on the accumulators
             val = jax.nn.silu(val) * accs[1][...]
+        elif relu2:     # an ungated expert's one matrix in
+            val = _relu2(val)
         # Another expert's rows of this tile were stored by its own entry
         # (the output block stays resident while the row tile repeats).
         out_ref[...] = jnp.where(
@@ -113,7 +128,8 @@ def _divisor_tile(n: int, cap: int) -> int:
 
 def _grouped_pallas(lhs: jax.Array, rhs: Sequence[jax.Array],
                     group_sizes: jax.Array, first_group: jax.Array, *,
-                    tm: int, interpret: bool) -> jax.Array:
+                    tm: int, interpret: bool, relu2: bool = False,
+                    name: str = GATED_KERNEL) -> jax.Array:
     m, k = lhs.shape
     n = rhs[0].shape[2]
     tk, tn = _divisor_tile(k, 1024), _divisor_tile(n, 1024 // len(rhs))
@@ -125,7 +141,8 @@ def _grouped_pallas(lhs: jax.Array, rhs: Sequence[jax.Array],
     first_group = jnp.asarray(first_group, jnp.int32)
     if obs.REGISTRY.enabled:
         _KERNEL_BUILDS.labels(
-            kernel="moe_up" if len(rhs) == 2 else "moe_down").inc()
+            kernel="moe_up" if len(rhs) == 2 else "moe_ungated_up" if relu2
+            else "moe_down").inc()
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(n // tn, jnp.maximum(n_entries, 1), tiles_k),
@@ -142,22 +159,25 @@ def _grouped_pallas(lhs: jax.Array, rhs: Sequence[jax.Array],
     )
     return pl.pallas_call(
         functools.partial(_grouped_kernel, n_rhs=len(rhs), tm=tm, tn=tn,
-                          tiles_k=tiles_k),
+                          tiles_k=tiles_k, relu2=relu2),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
-        name="moe_grouped_matmul",
+        name=name,
     )(offsets, gid, m_tile, first_group, lhs, *rhs)
 
 
 def grouped_matmul(lhs: jax.Array, rhs: Sequence[jax.Array],
                    group_sizes: jax.Array, *, first_group=0,
-                   interpret: Optional[bool] = None) -> jax.Array:
+                   interpret: Optional[bool] = None, relu2: bool = False,
+                   name: str = GATED_KERNEL) -> jax.Array:
     """``lhs`` ``(m, k)`` rows sorted by group; ``rhs`` one ``(S, k, n)``
-    stack (the product) or two (gate and up: ``silu(x@a) * (x@b)``);
+    stack (the product; with ``relu2`` an ungated expert's matrix in:
+    ``relu(x@a)^2``) or two (gate and up: ``silu(x@a) * (x@b)``); ``name``
+    the kernel's, a gated layer's or :data:`UNGATED_KERNEL`;
     ``group_sizes`` ``(G,)`` int32 rows a group, in order from row 0; group
     ``g``'s matrix is ``rhs[first_group + g]`` (``first_group`` may be
     traced: every layer's experts in one stack, a layer's reached by
@@ -179,16 +199,19 @@ def grouped_matmul(lhs: jax.Array, rhs: Sequence[jax.Array],
                                group_sizes.astype(jnp.int32),
                                preferred_element_type=jnp.float32)
                 for w in rhs]
-        val = outs[0] if len(rhs) == 1 else jax.nn.silu(outs[0]) * outs[1]
+        if len(rhs) == 2:
+            val = jax.nn.silu(outs[0]) * outs[1]
+        else:
+            val = _relu2(outs[0]) if relu2 else outs[0]
         return val.astype(lhs.dtype)
     if obs.REGISTRY.enabled:
-        _KERNEL_DISPATCH.labels(path="moe_grouped_matmul").inc()
+        _KERNEL_DISPATCH.labels(path=name).inc()
     tm = row_tile(m)
     if m % tm:
         raise ValueError(f"grouped_matmul: {m} rows do not divide by {tm}")
     return _grouped_pallas(lhs, list(rhs), group_sizes.astype(jnp.int32),
                            first.reshape(1), tm=tm,
-                           interpret=bool(interpret))
+                           interpret=bool(interpret), relu2=relu2, name=name)
 
 
 def row_tile(m: int) -> int:

@@ -266,6 +266,14 @@ _TAIL_BLOCKS = obs.counter(
     "block tails the conv layers wrote (blocks a tick's rows fell in x conv "
     "layers)",
 )
+_SSM_STATES = obs.counter(
+    "serving_ssm_states_advanced_total",
+    "per-slot recurrent states the state-space layers wrote (slots with a "
+    "row x state-space layers)",
+)
+# A step's counters that ride the tick's fetch below the slots' rows, in
+# this order (``models/decode.py`` ``forward_step``'s ``stats``).
+_STEP_COUNTERS = ("expert_rows", "tail_blocks", "ssm_states")
 _POOL_ROWS = obs.counter(
     "serving_kv_pool_rows_written_total",
     "token rows the tick programs wrote into the paged pool (a tick's rows x "
@@ -977,15 +985,17 @@ class SlotServer:
                 f"prefill_budget must be >= 1, got {prefill_budget}"
             )
         if cfg.cache_kind != "kv":
-            # What the latent pool and the hybrid pool are not built for
-            # is refused here, by the cache kind's name, never served
-            # wrong.
+            # What the latent pool, the hybrid pool, the window pools and
+            # the state pool are not built for is refused here, by the
+            # cache kind's name, never served wrong.
             why_not = {
                 "latent": "the latent kernel takes no tree_mask",
                 "hybrid": "a draft that is rejected has overwritten the "
                           "conv layers' tails, which cannot roll back",
                 "window": "a rollback would need window blocks that were "
                           "given back",
+                "state": "a draft that is rejected has rewritten the "
+                         "recurrent state, which cannot roll back",
             }[cfg.cache_kind]
             for on, what in (
                 (quantize, f"int8 {cfg.cache_kind} rows (quantize=True)"),
@@ -999,6 +1009,16 @@ class SlotServer:
                     block_pool is not None or prefix_index is not None),
                  "disaggregation (a shared block_pool / prefix_index: the "
                  "window layers' blocks are one engine's)"),
+                # A recurrent state is an array a slot that every token
+                # rewrites whole: nothing holds it as it was at a block
+                # boundary (ROADMAP 2A item 9: snapshots).
+                (cfg.cache_kind == "state" and (
+                    block_pool is not None or prefix_index is not None),
+                 "disaggregation (a shared block_pool / prefix_index: the "
+                 "hand-over would need the slot's state)"),
+                (cfg.cache_kind == "state" and prefix_cache,
+                 "the prefix cache (prefix_cache=True: a hit needs the "
+                 "state at the matched boundary)"),
             ):
                 if on:
                     raise ValueError(
@@ -1250,17 +1270,18 @@ class SlotServer:
         # tails): the report's ``kv.block_fixed_bytes``.
         self._kv_block_fixed_bytes = cache_block_fixed_bytes(self.cache)
         self._conv_layers = cfg.conv_layers   # the tail pool's depth
+        self._ssm_layers = cfg.ssm_layers     # the state pool's depth
         if obs.REGISTRY.enabled:
             _BLOCK_FIXED_BYTES.set(self._kv_block_fixed_bytes)
         # Expert layers' row counts on the tick's fetch: (layers, what
         # ``experts.held_counts`` gives a layer), None for a model without
         # experts.
         self._expert_rows_shape: Optional[Tuple[int, int]] = None
-        if cfg.moe is not None and cfg.n_layers > cfg.n_dense_layers:
+        if cfg.moe is not None and cfg.n_expert_layers:
             from tree_attention_tpu.models.experts import counts_width
 
             self._expert_rows_shape = (
-                cfg.n_layers - cfg.n_dense_layers, counts_width(cfg.moe))
+                cfg.n_expert_layers, counts_width(cfg.moe))
         self.tok = jnp.zeros((slots,), jnp.int32)
         # The host's view of each slot's length on the device, as the
         # tick programs leave it (``_count_kv_steps``), and the tokens a
@@ -1528,7 +1549,9 @@ class SlotServer:
         """Read the step's counters off the tick's fetch (the rows below
         the slots') into the registry, and return the flight record's
         numbers: the expert layers' (:meth:`_account_expert_rows`), then
-        ``tail_blocks_written``, the block tails the conv layers wrote."""
+        ``tail_blocks_written``, the block tails the conv layers wrote, or
+        ``ssm_states_advanced``, the (slot, layer) states the state-space
+        layers wrote."""
         flat, out = extra.reshape(-1), {}
         at = 0
         if self._expert_rows_shape is not None:
@@ -1539,6 +1562,10 @@ class SlotServer:
             out["tail_blocks_written"] = int(flat[at])
             if obs.REGISTRY.enabled:
                 _TAIL_BLOCKS.inc(out["tail_blocks_written"])
+        if self._ssm_layers:
+            out["ssm_states_advanced"] = int(flat[at])
+            if obs.REGISTRY.enabled:
+                _SSM_STATES.inc(out["ssm_states_advanced"])
         return out
 
     def _account_expert_rows(self, extra: np.ndarray) -> Dict[str, int]:
@@ -1701,14 +1728,15 @@ class SlotServer:
                  lax.bitcast_convert_type(lp_out, jnp.int32)[:, None]],
                 axis=1,
             )
-        if "expert_rows" in stats or "tail_blocks" in stats:
+        if any(n in stats for n in _STEP_COUNTERS):
             # The step's counters ride the tick's one fetch as further
             # rows of the same array (tracing on or off: one program):
             # the expert layers' row counts (``_expert_rows_shape`` says
             # how to read them), then the conv layers' block tails
-            # written (one number), :meth:`_account_step_counters`.
+            # written or the state-space layers' states advanced (one
+            # number), :meth:`_account_step_counters`.
             parts = [stats[n].reshape(-1)
-                     for n in ("expert_rows", "tail_blocks") if n in stats]
+                     for n in _STEP_COUNTERS if n in stats]
             flat = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
             flat = jnp.pad(flat, (0, flat.shape[0] % 2))
             fused = jnp.concatenate([fused, flat.reshape(-1, 2)], axis=0)
@@ -2209,8 +2237,20 @@ class SlotServer:
         under the same uid ("join" = the family's results/callbacks).
         Scarce slots/blocks defer the fork a couple of sweeps; a uid
         that is not (or no longer) live ages out as a no-op."""
+        self._refuse_fork("fork(uid)")
         with self._ctl_lock:
             self._fork_uids.append(uid)
+
+    def _refuse_fork(self, what: str) -> None:
+        """A fork shares its ancestor's blocks and copies the partial one;
+        a recurrent state is in no block, so a branch would start from a
+        state that is not its own: refused by the cache kind's name."""
+        if self.cfg.cache_kind == "state":
+            raise ValueError(
+                f"a model served from the state pool "
+                f"(TransformerConfig.cache_kind) does not serve with "
+                f"{what}: a branch needs the recurrent state at the fork "
+                f"point, which nothing holds (not built for that pool)")
 
     def _take_forks(self) -> List[int]:
         """Drain the fork mailbox (loop side), oldest first."""
@@ -2477,6 +2517,9 @@ class SlotServer:
         if req.fork_at is not None and req.fork_at < 1:
             raise ValueError(f"request {req.uid}: fork_at must be >= 1")
         branches = self._branches(req)
+        if branches > 1 or req.fork_at is not None:
+            self._refuse_fork(
+                f"request {req.uid}'s n / best_of > 1 or fork_at (forks)")
         if branches > 1:
             if self._speculate:
                 raise ValueError(
@@ -4653,7 +4696,7 @@ class SlotServer:
                         fh[:self.slots, 1]
                     ).view(np.float32)
                     if (self._expert_rows_shape is not None
-                            or self._conv_layers) and (
+                            or self._conv_layers or self._ssm_layers) and (
                             FLIGHT.enabled or obs.REGISTRY.enabled):
                         expert_rows = self._account_step_counters(
                             fh[self.slots:])
