@@ -1134,7 +1134,11 @@ def test_window_step_compiles_and_copies_neither_pool(tq, packed):
 # + 2 x 4 MB a layer at Yi-6B's widths, 0.65-0.9 ms a tick in four cells;
 # ``deepseek-v2``'s 75 MB slice did not fit and made a round trip through HBM
 # first). The engine serves from ``served_layout``'s form, and the programs
-# compiled from it must hold neither.
+# compiled from it must hold neither. A latent layer's ``wqb_t`` went on
+# through fast memory all the same (a ``kLoop`` slice of the layer's 38-75
+# MB, then the product from there, one after the other) until
+# ``latent_qkv`` held its reshape to heads off the product (ISSUE 41): the
+# latent programs must hold no one-layer ``wqb_t`` at all.
 
 ALL_CONFIGS = STEP_CONFIGS + LATENT_CONFIGS + (
     "lfm2-8b-a1b", "k-exaone-236b-a23b", "nemotron-3-super-120b-a12b")
@@ -1159,16 +1163,25 @@ def _moved_projections(text, cfg):
     """What the module holds of a projection weight beside the stack itself:
     a ``copy`` of one layer's (the transposition), or any other result of
     its dimensions that lies in HBM (the slice that did not fit fast
-    memory). The slice into fast memory, ``S(1)``, is the weight's one read
-    and stays where the compiler makes one. The packed programs hold larger
-    copies of gathered views, which are not weights: the dimensions are
-    matched whole, with or without a leading 1."""
+    memory). For a latent configuration (ISSUE 41) a one-layer ``wqb_t`` in
+    ANY memory space: the ``kLoop`` slice of a layer into fast memory,
+    ``S(1)``, was the weight's one read, but nothing ran under it and the
+    product from there took as long again, where a product whose fusion
+    takes the stack reads the layer's rows in place. What stays allowed
+    there is the asynchronous ``copy-start`` / ``copy-done`` of a one-layer
+    stack (``deepseek-v2``'s leading dense layer, prefetched across
+    programs). The other configurations' slices into ``S(1)`` stay where the
+    compiler makes one. The packed programs hold larger copies of gathered
+    views, which are not weights: the dimensions are matched whole, with or
+    without a leading 1."""
     dims = _projection_dims(cfg)
+    in_place = cfg.mla is not None
     moved = []
     for name, result, opcode, _ in _materialised(text):
         if opcode in _MOVES_NOTHING:
             continue
         arrays = re.findall(r"\bbf16\[([\d,]+)\](\{[^}]*\})?", result)
+        prefetch = opcode in ("copy-start", "copy-done")
         if opcode == "copy-start":
             # (destination, source, context): a one-layer stack prefetched
             # from the parameter itself, the same read by another name.
@@ -1177,7 +1190,8 @@ def _moved_projections(text, cfg):
             shape = tuple(int(d) for d in shape.split(","))
             if shape[:1] == (1,):
                 shape = shape[1:]
-            if shape in dims and (opcode == "copy" or "S(1)" not in layout):
+            staged = "S(1)" in layout and (prefetch or not in_place)
+            if shape in dims and (opcode == "copy" or not staged):
                 moved.append((name, opcode, result))
     return moved
 
@@ -1202,6 +1216,31 @@ def test_the_guard_catches_the_outer_format():
     moved = _moved_projections(
         _tick_program("yi-6b", 1, served=False).text, _model("yi-6b")[1])
     assert sorted(op for _, op, _ in moved) == ["copy"] * 3, moved
+
+
+@pytest.mark.parametrize("config", LATENT_CONFIGS)
+def test_the_guard_catches_the_reshape_folded_into_the_product(
+        config, monkeypatch):
+    """The control for the latent rule (ISSUE 41): with ``latent_qkv``'s
+    barrier taken out, the compiler folds the reshape to heads into the
+    product, as it did until then, and every layer body that holds a latent
+    sublayer slices that layer's whole ``wqb_t`` into fast memory first: one
+    ``kLoop`` fusion a sublayer of the loop's body (``deepseek-v2``'s one
+    expert body; ``longcat-flash-omni``'s two sublayers a double layer)."""
+    if _chip() is None:
+        pytest.skip("the v5e:2x2 topology cannot be described here")
+    from jax import lax
+
+    cfg, barrier = _model(config)[1], lax.optimization_barrier
+    with monkeypatch.context() as m:
+        # The work lists' barrier (a tuple: ``_plan_groups``) stays.
+        m.setattr(lax, "optimization_barrier",
+                  lambda x: barrier(x) if isinstance(x, tuple) else x)
+        text = _tick_program.__wrapped__(config, 1).text     # not memoised
+    moved = _moved_projections(text, cfg)
+    assert len(moved) == cfg.sublayers, moved
+    assert all(op == "fusion" and "S(1)" in result
+               for _, op, result in moved), moved
 
 
 # -- the parts of the model, named in the compiled programs (ISSUE 35) -------

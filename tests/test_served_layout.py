@@ -124,6 +124,74 @@ def test_served_layout_gives_the_outer_formats_logits(body, program):
                                    np.asarray(b, np.float32), atol=ATOL)
 
 
+LATENT_BODIES = sorted(b for b, (_, leaf) in BODIES.items()
+                       if leaf == LATENT_SERVED)
+
+
+@pytest.mark.parametrize("program", ["tq1", "packed"])
+@pytest.mark.parametrize("body", LATENT_BODIES)
+def test_the_barrier_before_the_reshape_changes_no_bit(
+        body, program, monkeypatch):
+    """``latent_qkv``'s served branch holds its reshape to heads behind a
+    ``lax.optimization_barrier`` (ISSUE 41: what makes the chip's compiler
+    read ``wqb_t`` in place). With the barrier taken out the program is the
+    one served before it: the same logits and pool rows, to the bit."""
+    from jax import lax
+
+    cfg, params, _ = _model(body)
+    served = served_layout(params)
+    got, cache = _tick_logits(served, cfg, program)
+    barriers = []
+
+    def no_barrier(operand):
+        barriers.append(operand)
+        return operand
+
+    monkeypatch.setattr(lax, "optimization_barrier", no_barrier)
+    want, cache_before = _tick_logits(served, cfg, program)
+    # The products of both steps of ``_tick_logits`` went through it: flat
+    # ``(B, T, H x (nope + rope))``, every latent sublayer.
+    width = cfg.n_heads * (cfg.mla.nope + cfg.mla.rope)
+    flat = [b for b in barriers
+            if not isinstance(b, tuple) and b.shape[-1] == width]
+    assert len(flat) >= 2 and all(b.ndim == 3 for b in flat)
+    assert np.abs(want).max() > 0.1
+    np.testing.assert_array_equal(got, want)
+    for a, b in zip(jax.tree.leaves(cache), jax.tree.leaves(cache_before)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("body", LATENT_BODIES)
+def test_a_served_latent_tree_differentiates_as_the_outer_one(body):
+    """Every function of the model takes either tree (ISSUE 34), the
+    gradient included: the loss of a prefill step's logits (``forward()``
+    builds the dense block only; ``forward_step`` is the full-sequence pass a
+    latent model has) differentiates through the served branch's barrier,
+    and the served tree's gradient is the outer tree's, re-laid."""
+    from tree_attention_tpu.models.transformer import cross_entropy_loss
+
+    cfg, params, leaf = _model(body)
+    rng = np.random.default_rng(5)
+    toks, targets = (
+        jnp.asarray(rng.integers(1, cfg.vocab_size, (SLOTS, 12)), jnp.int32)
+        for _ in range(2))
+    n_tokens = jnp.asarray([5, 0, 11], jnp.int32)
+
+    @jax.grad
+    def grad(p):
+        logits, _ = forward_step(p, toks, _cache(cfg), cfg, n_tokens=n_tokens)
+        return cross_entropy_loss(
+            logits, targets, jnp.arange(12)[None] < n_tokens[:, None])
+
+    want, got = served_layout(grad(params)), grad(served_layout(params))
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for name in (leaf, "wqa", "wkb"):       # the product's and its neighbours'
+        assert all(np.abs(np.asarray(g)).max() > 1e-5
+                   for g in _leaves_named(got, name)), name
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=1e-6)
+
+
 def test_the_plain_forward_takes_either_form():
     """``gqa_qkv`` also serves ``forward()`` (generate, train, the smoke's
     reference logits): one function, told by the leaves it is given."""

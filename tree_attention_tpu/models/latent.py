@@ -128,7 +128,12 @@ def latent_qkv(p: Params, h: jax.Array, positions: jax.Array,
         c_q = rms_norm(h @ p["wqa"], gain(p["q_ln"], la.q_scale),
                        cfg.norm_eps)
     if LATENT_SERVED in p:
-        q = times_out_major(c_q, p[LATENT_SERVED])
+        # The reshape to heads waits behind a barrier: folded into the
+        # product, the compiler for the chip first slices the layer's whole
+        # ``wqb_t`` out of its stack into fast memory and multiplies from
+        # there, two passes of the weight's length; left flat, the product's
+        # own fusion reads the stack's rows where they lie.
+        q = lax.optimization_barrier(times_out_major(c_q, p[LATENT_SERVED]))
     else:
         q = c_q @ p["wqb"]
     q = q.reshape(B, T, H, la.nope + la.rope)

@@ -1022,6 +1022,10 @@ def served_layout(params: Params) -> Params:
     weight out of its stack and transposes it in a copy of its own before
     the product; handed this one, the product reads the stack where it lies
     (``tests/test_chip_compile.py`` holds the compiled programs to that).
+    For ``wqb_t`` that also takes the product's result left flat: with the
+    reshape to heads folded in, the compiler stages the layer's whole weight
+    in fast memory first, so ``latent_qkv`` holds the reshape behind a
+    barrier.
     Every other leaf is handed on untouched, and so is a tree that holds none
     of the three or holds them re-laid already. One jitted call a leaf: no
     second copy of the model is ever made, and the outer leaves are the

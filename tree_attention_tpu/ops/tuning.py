@@ -189,6 +189,49 @@ def decode_block_k_q8(tk: int) -> int:
 #   8-row cut compiles and reads back bit-equal for bf16 and int8 alike.
 # A chunk group (Tq > 1) keeps the block path: PR 25 measured a row scatter
 # at ~70 ns a row there and a block is what 5 blocks of 64 rows want.
+#
+# A latent layer's query up-projection, `c_q x wqb_t` (ISSUE 41; no tile of
+# this module's: the product is plain XLA in `models/latent.py` `latent_qkv`,
+# and what was tried is the form it reaches the compiler in). Measured on v5e
+# 2026-10-01, a scratch sweep of `latent_qkv`'s arithmetic alone (the q-norm,
+# `wqa`, `wkva`, the rotary, the `wkb` einsum and the product) in a scan over
+# the cell's stack of layers, 32 x L layers in one program, best of 7, us a
+# layer; every form's result bit-equal to the folded one's on the chip:
+#
+#   us a layer                       dsv2 24576 x 1536     LongCat 12288 x 1536
+#                                    16 rows   272 rows    32 rows   288 rows
+#   folded (the reshape to heads in  274.5     451.9       112.7     246.9
+#     the product: until PR 41)
+#   heads einsum (`bti,hni->bthn`)   273.7     452.8       113.2     246.7
+#   flat behind a barrier (chosen)   164.3     343.0       104.1     216.7
+#   ... and `c_q` behind one too     164.5     343.3       104.2     216.4
+#   `(out, rows)`, transposed back   164.4     340.9       104.7     215.6
+#   float32 result, then cast        164.0     343.5       104.2     216.4
+#   everything but the product        56.8     149.5        48.1     106.2
+#
+# The two upper forms compile to one program: the layer's whole `wqb_t` (75.5
+# / 37.7 MB) sliced into fast memory by an operation that does nothing else,
+# then the product from there, whose result the compiler lays out by heads
+# (`bf16[16,128,192]{1,0,2}` and a copy). That product is slow at 128 heads
+# (in `dsv2_codegen_sat`'s traced ~3 s it took 0.113 s where the slice took
+# 0.112 s; the leading dense layer's, from its prefetched copy, 0.0282 s) and
+# cheap at 64 (`lcflash_agentturn_sat`: 0.0117 s a sublayer where its slice
+# took 0.0436 s), which is why the cure is worth 110 us a layer in one family
+# and 9 (30 at 288 rows) in the other. The four lower forms compile to the
+# other: no slice, the product's fusion takes the `(L, out, rank)` stack and
+# reads the layer's rows in place with the q-norm fused in (0.1165 s of
+# dsv2's traced ~3 s, 0.0436 s a sublayer of LongCat's: what the slice alone
+# took; here 107 and 56 us a layer over "everything but": 86% and 82% of
+# 819 GB/s for the weight's bytes), and the flat product from the dense
+# layer's prefetched copy takes 0.0077 s where the folded one took 0.0282 s:
+# it was the result's layout by heads that was slow, not the MXU at 16 rows.
+# Nothing is left to win in this product but the last tenth of the memory's
+# pace. The four tie to 1%, so the one that adds least to the code stays (one
+# `lax.optimization_barrier` on the flat result). Not tried on the chip:
+# `nope` and `rope` columns as two weights (a second layout of the leaf;
+# ISSUE 41's compile for the chip sliced both) and a Pallas product
+# streaming `wqb_t`'s row tiles (the fallback, had no plain-XLA form held in
+# the packed programs: the barrier holds in all six).
 PAGED_STEP_TARGET_BYTES = 1 << 20
 PAGED_STEP_ENTRIES = (1, 2, 4, 8)  # divisors of the 8-row scale tile
 # What a step may hold of the 16 MB of scoped VMEM a v5e kernel gets by
