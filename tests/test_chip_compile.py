@@ -304,6 +304,20 @@ CASES = {
                                  "moe_ungated_matmul"),
     "moe_ungated_chunk_pairs": (lambda: _moe_ungated(7168),
                                 "moe_ungated_matmul"),
+    # The gated product's plan (`ops/tuning.py` `grouped_plan`) at the
+    # widths DeepSeek-V2's constants were not chosen for (ISSUE 42): LFM2
+    # 2048 x 1792 and K-EXAONE / LongCat 6144 x 2048, a decode tick's pairs
+    # and a mixed tick's: a plan over the chip's fast memory fails here.
+    "moe_lfm2_decode_pairs": (lambda: _moe_kernel("lfm2-8b-a1b", 256),
+                              "moe_grouped_matmul"),
+    "moe_lfm2_chunk_pairs": (lambda: _moe_kernel("lfm2-8b-a1b", 1280),
+                             "moe_grouped_matmul"),
+    "moe_kexaone_decode_pairs": (
+        lambda: _moe_kernel("k-exaone-236b-a23b", 256),
+        "moe_grouped_matmul"),
+    "moe_longcat_chunk_pairs": (
+        lambda: _moe_kernel("longcat-flash-omni", 3584),
+        "moe_grouped_matmul"),
     "prefill_fwd": (_prefill, "flash_fwd"),
     "train_fwd": (_train_fwd_bwd, "flash_fwd"),
     "bwd_dq": (_train_fwd_bwd, "flash_bwd_dq"),
@@ -769,7 +783,7 @@ def _mla_kernel(name, tq):
 def _moe_kernel(name, m):
     from tree_attention_tpu.ops.pallas_moe import grouped_matmul
 
-    c, cfg = _model(name)
+    cfg = _model(name)[1]
     d, f, e = cfg.d_model, cfg.moe.width, 4 * cfg.moe.held
 
     def fn(x, w1, w3, w2, sizes, first):
@@ -779,7 +793,39 @@ def _moe_kernel(name, m):
                               interpret=False)
 
     return fn, [_s((m, d)), _s((e, d, f)), _s((e, d, f)), _s((e, f, d)),
-                _s((c["n_routed_experts"],), jnp.int32), _s((), jnp.int32)]
+                _s((cfg.moe.held,), jnp.int32), _s((), jnp.int32)]
+
+
+EXPERT_CONFIGS = ("deepseek-v2", "k-exaone-236b-a23b", "lfm2-8b-a1b",
+                  "longcat-flash-omni", "nemotron-3-super-120b-a12b")
+
+
+@pytest.mark.parametrize("pairs", [128, 256, 384, 1280, 1408, 1664, 2304,
+                                   3584, 7168, 24576])
+@pytest.mark.parametrize("config", EXPERT_CONFIGS)
+def test_the_expert_products_plan_divides_the_shape_and_fits_its_limit(
+        config, pairs):
+    """``grouped_plan`` at every ``(hidden or latent, width)`` under
+    ``benchmark/configs`` and every row count the cells' ticks make: the
+    blocks divide the operands, a block's last two dimensions are whole
+    tiles, and the blocks fit the fast memory the plan itself asks for,
+    which stays under what one kernel may take of the chip's."""
+    from tree_attention_tpu.ops import tuning
+
+    cfg = _model(config)[1]
+    ex, hidden = cfg.moe, cfg.moe.latent or cfg.d_model
+    for k, n, n_rhs in ((hidden, ex.width, 2 if ex.gated else 1),
+                        (ex.width, hidden, 1)):
+        plan = tuning.grouped_plan(pairs, k, n, n_rhs)
+        assert pairs % plan.tm == 0 and k % plan.tk == 0 \
+            and n % plan.tn == 0, plan
+        assert plan.tm % 16 == 0 and plan.tk % 128 == 0 \
+            and plan.tn % 128 == 0, plan
+        assert plan.rows_whole is False or plan.tk < k, plan
+        need = plan.vmem_bytes(k, n_rhs, 2)
+        limit = plan.vmem_limit_bytes(k, n_rhs, 2)
+        assert need <= (limit or tuning.DEFAULT_SCOPED_VMEM_BYTES), plan
+        assert (limit or 0) <= tuning.GROUPED_VMEM_CEILING_BYTES, plan
 
 
 LATENT_CASES = {

@@ -419,21 +419,49 @@ def test_the_shares_add_up_to_the_uncut_layer(ref):
     np.testing.assert_allclose(total, uncut, atol=1e-5)
 
 
-def test_grouped_matmul_kernel_equals_ragged_dot():
+# The branches of the kernel's block plan (``ops/tuning.py`` ``GroupedPlan``)
+# at a small shape, k 256 x n 384: ``None`` is the plan function's own.
+# (tk, tn, the rows' tile at whole k), the row tile being the layout's.
+_PLANS = {
+    "plan_function": None,
+    "k_tiles_and_strips": (128, 128, False),
+    "k_tiles_rows_whole": (128, 384, True),
+    "whole_k_whole_n": (256, 384, False),
+    "whole_k_strips": (256, 128, False),
+}
+# (rows, row tile, rows an expert): today's three layouts first.
+_LAYOUTS = {
+    "an_empty_expert": (128, 128, [3, 0, 10, 5]),
+    "tile_256_straddled": (2048, 256, [700, 0, 0, 900]),
+    "every_pair_past_the_held": (128, 128, [0, 0, 0, 0]),
+    "tile_128_straddled_twice": (384, 128, [100, 60, 0, 150]),
+    "tile_128_of_chunk_rows": (2048, 128, [700, 0, 1, 900]),
+}
+
+
+@pytest.mark.parametrize("layout", sorted(_LAYOUTS))
+@pytest.mark.parametrize("plan", sorted(_PLANS))
+@pytest.mark.parametrize("product", ["one_rhs", "two_rhs", "relu2"])
+def test_grouped_matmul_kernel_equals_ragged_dot(product, plan, layout):
+    """Interpret mode against ``lax.ragged_dot``. An expert whose rows
+    straddle a row tile has two entries: with no ``k`` dimension the second
+    finds the first's strip resident, and its rows must still be its own."""
     from tree_attention_tpu.ops.pallas_moe import grouped_matmul
+    from tree_attention_tpu.ops.tuning import GroupedPlan
 
     rng = np.random.default_rng(0)
     k, n, G = 256, 384, 4
-    a = jnp.asarray(rng.normal(size=(2 * G, k, n)) * 0.1, jnp.float32)
-    b = jnp.asarray(rng.normal(size=(2 * G, k, n)) * 0.1, jnp.float32)
-    for m, sizes in ((128, [3, 0, 10, 5]), (2048, [700, 0, 0, 900]),
-                     (128, [0, 0, 0, 0])):
-        lhs = jnp.asarray(rng.normal(size=(m, k)), jnp.float32)
-        gs, tot = jnp.asarray(sizes, jnp.int32), sum(sizes)
-        for rhs in ((a,), (a, b)):
-            x = grouped_matmul(lhs, rhs, gs, first_group=G, interpret=True)
-            y = grouped_matmul(lhs, rhs, gs, first_group=G)
-            np.testing.assert_allclose(x[:tot], y[:tot], atol=1e-4)
+    m, tm, sizes = _LAYOUTS[layout]
+    rhs = tuple(jnp.asarray(rng.normal(size=(2 * G, k, n)) * 0.1, jnp.float32)
+                for _ in range(2 if product == "two_rhs" else 1))
+    lhs = jnp.asarray(rng.normal(size=(m, k)), jnp.float32)
+    gs, tot = jnp.asarray(sizes, jnp.int32), sum(sizes)
+    blocks = _PLANS[plan]  # the plan function takes its own row tile
+    chosen = None if blocks is None else GroupedPlan(tm, *blocks)
+    kw = dict(first_group=G, relu2=product == "relu2")
+    x = grouped_matmul(lhs, rhs, gs, interpret=True, plan=chosen, **kw)
+    y = grouped_matmul(lhs, rhs, gs, **kw)
+    np.testing.assert_allclose(x[:tot], y[:tot], atol=1e-4)
 
 
 # -- the engine --------------------------------------------------------------

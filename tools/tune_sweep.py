@@ -6,6 +6,7 @@ Prints one JSON line per measurement; the winners go into
     python tools/tune_sweep.py decode   # flash-decode kernel block_k sweep
     python tools/tune_sweep.py fwd      # training fwd kernel (bq, bk) sweep
     python tools/tune_sweep.py bwd      # fwd+bwd through the custom VJP
+    python tools/tune_sweep.py --grouped  # the grouped expert product's plans
 
 Uses the slope-timing protocol (utils.profiling.slope_per_step, min-stat
 over repeated cycles), so fixed per-call costs cancel and each cell
@@ -14,7 +15,9 @@ carries its own spread.
 
 import dataclasses
 import json
+import os
 import sys
+import time
 
 import jax
 import jax.numpy as jnp
@@ -222,6 +225,223 @@ def sweep_fwd(bwd=False):
                         "bk": bk, "error": f"{type(e).__name__}: {e}"[:200],
                     }), flush=True)
 
+# The grouped expert product (ops/pallas_moe.py) at the shapes the benchmark's
+# five expert configurations serve: (name, hidden or latent, width, gated,
+# experts held, share of a tick's pairs that land on a held expert, pairs a
+# decode tick, pairs a mixed tick). A pair that lands here lands on any held
+# expert alike, which gives the touched shares the cells read (ledger, PR 41:
+# 99.9 / 84 / 46 / 39 / 88%).
+GROUPED_SHAPES = (
+    ("lfm2-8b-a1b", 2048, 1792, True, 32, 1.0, 256, 1280),
+    ("k-exaone-236b-a23b", 6144, 2048, True, 8, 1 / 16, 256, 2304),
+    ("deepseek-v2", 5120, 1536, True, 40, 1 / 4, 96, 1632),
+    ("longcat-flash-omni", 6144, 2048, True, 16, 1 / 48, 384, 3456),
+    ("nemotron-3-super-120b-a12b", 1024, 2688, False, 128, 1 / 4, 1408, 7040),
+)
+
+
+def grouped_candidates(m, k, n, n_rhs):
+    """Today's plan first, then every plan of (whole k, the largest divisors
+    of k up to 2048 and 1024) x (whole n, the largest divisors up to n / 2,
+    1024, 512, 256) whose step moves 1-16 MB of weights; the rows' tile at
+    whole k wherever k is tiled; both row tiles from 2,048 rows on."""
+    from tree_attention_tpu.ops.tuning import (
+        GroupedPlan, _divisor_tile, grouped_plan_unmeasured, row_tile)
+
+    today = grouped_plan_unmeasured(m, k, n, n_rhs)
+    plans = [today]
+    if today.tk < k:
+        plans.append(today._replace(rows_whole=True))
+    tks = sorted({k, _divisor_tile(k, 2048), _divisor_tile(k, 1024)})
+    tns = sorted({n} | {_divisor_tile(n, c)
+                        for c in (n // 2, 1024, 512, 256) if c >= 128})
+    tms = (row_tile(m),) if m < 2048 else (256, 128)
+    for tm in tms:
+        for tk in tks:
+            for tn in tns:
+                step = n_rhs * tk * tn * 2
+                plan = GroupedPlan(tm, tk, tn, rows_whole=tk < k)
+                if (1 << 20) <= step <= (16 << 20) and plan not in plans:
+                    plans.append(plan)
+    return plans
+
+
+def _grouped_layout(rng, pairs, here, held, m):
+    """Rows a held expert of one call: ``pairs`` pairs, each here with
+    probability ``here`` and then on any held expert alike."""
+    import numpy as np
+
+    mine = rng.random(pairs) < here
+    sizes = np.bincount(rng.integers(0, held, pairs)[mine], minlength=held)
+    assert sizes.sum() <= m
+    return sizes.astype(np.int32)
+
+
+def _kernel_events(trace_dir, kernel):
+    """Device durations (s) of the events named ``kernel`` in the newest
+    xplane under ``trace_dir``, in the order they ran."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    path = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")),
+        key=os.path.getmtime)[-1]
+    with open(path, "rb") as f:
+        data = ProfileData.from_serialized_xspace(f.read())
+    found = []
+    for plane in data.planes:
+        if not plane.name.startswith("/device:TPU:0"):
+            continue
+        for line in plane.lines:
+            if line.name == "XLA Ops":
+                found += [(e.start_ns, e.duration_ns * 1e-9)
+                          for e in line.events
+                          if e.name.lstrip("%").startswith(kernel)]
+    return [d for _, d in sorted(found)]
+
+
+def sweep_grouped(only=None, calls=None):
+    """One JSON line a (configuration, rows, product, plan). A program is
+    ``calls`` expert layers in a loop, as a tick makes them: the pair rows
+    gathered from the token rows, the product in, the product out on its
+    result, over a stack of several layers' experts (a call's layer ``i %
+    layers``). The product under test takes the candidate plan and the other
+    one today's. ``kernel_us``: the mean device time of that product's
+    events in one traced run of the program (what the benchmark's
+    ``moe_ffn_ms_tick`` sums), ``pct_of_hbm`` the share of the memory's
+    pace the cost function's bytes reach over it (the touched experts'
+    matrices once, the pairs' rows in and out); ``us``: the whole layer by
+    the host's clock, best of 5. Also written to
+    ``chiprun_out/grouped_sweep.jsonl``."""
+    import shutil
+
+    import numpy as np
+
+    from tree_attention_tpu.ops.pallas_moe import (
+        GATED_KERNEL, UNGATED_KERNEL, grouped_matmul)
+    from tree_attention_tpu.ops.tuning import (
+        grouped_plan_unmeasured, row_tile)
+
+    hbm_bw = peaks().hbm_bytes_per_s
+    os.makedirs("chiprun_out", exist_ok=True)
+    log = open("chiprun_out/grouped_sweep.jsonl", "a")
+    trace_dir = "chiprun_out/.grouped_sweep_trace"
+    quiet = jax.profiler.ProfileOptions()
+    quiet.python_tracer_level = 0
+    quiet.host_tracer_level = 0
+
+    def emit(rec):
+        line = json.dumps(rec)
+        print(line, flush=True)
+        log.write(line + "\n")
+        log.flush()
+
+    for name, hidden, width, gated, held, here, dec, mix in GROUPED_SHAPES:
+        if only and only not in name:
+            continue
+        kernel = GATED_KERNEL if gated else UNGATED_KERNEL
+        n_in = 2 if gated else 1
+        layers = max(2, min(8, int(3e9 // (held * hidden * width * 2
+                                          * (n_in + 1)))))
+        keys = jax.random.split(jax.random.PRNGKey(0), n_in + 2)
+        w_in = tuple(
+            jax.random.normal(kk, (layers * held, hidden, width),
+                              jnp.bfloat16) * 0.02 for kk in keys[:n_in])
+        w_out = jax.random.normal(
+            keys[n_in], (layers * held, width, hidden), jnp.bfloat16) * 0.02
+        for pairs in (dec, mix):
+            m = -(-pairs // row_tile(pairs)) * row_tile(pairs)
+            sizes = _grouped_layout(
+                np.random.default_rng(pairs), pairs, here, held, m)
+            touched, rows = int((sizes > 0).sum()), int(sizes.sum())
+            gs = jnp.asarray(sizes)
+            tokens = jax.random.normal(
+                keys[-1], (m // 4, hidden), jnp.bfloat16)
+            n_calls = calls or (32 if pairs == dec else 8)
+            shapes = {"in": (hidden, width, n_in), "out": (width, hidden, 1)}
+            today = {p: grouped_plan_unmeasured(m, *shapes[p])
+                     for p in shapes}
+            tests = [(p, plan) for p in ("in", "out")
+                     for plan in grouped_candidates(m, *shapes[p])]
+
+            def build(product, plan):
+                plans = dict(today, **{product: plan})
+
+                def layer(i, x, w_in, w_out, gs):
+                    first = (i % layers) * held
+                    pair_rows = (jnp.arange(m) // 4 + i) % (m // 4)
+                    h = grouped_matmul(
+                        x[pair_rows], w_in, gs, first_group=first,
+                        relu2=not gated, name=kernel, plan=plans["in"])
+                    return grouped_matmul(
+                        h, (w_out,), gs, first_group=first, name=kernel,
+                        plan=plans["out"])
+
+                @jax.jit
+                def program(x, w_in, w_out, gs):
+                    def body(i, acc):
+                        out = layer(i, x, w_in, w_out, gs)
+                        return acc + out[0, :128].astype(jnp.float32)
+
+                    acc = lax.fori_loop(0, n_calls - 1, body,
+                                        jnp.zeros((128,), jnp.float32))
+                    return acc, layer(n_calls - 1, x, w_in, w_out, gs)[:rows]
+
+                return program
+
+            args = (tokens, w_in, w_out, gs)
+            ran, first = [], None
+            for product, plan in tests:
+                k, n, n_rhs = shapes[product]
+                rec = {"kernel": kernel, "config": name, "product": product,
+                       "k": k, "n": n, "n_rhs": n_rhs, "m": m, "held": held,
+                       "touched": touched, "rows_here": rows,
+                       "plan": plan.label,
+                       "vmem_limit": plan.vmem_limit_bytes(k, n_rhs, 2)}
+                try:
+                    program = build(product, plan)
+                    got = program(*args)[1].astype(jnp.float32)
+                    if first is None:
+                        first = got
+                    rec["max_abs_diff_from_first"] = float(
+                        jnp.max(jnp.abs(got - first))) if rows else 0.0
+                    best = float("inf")
+                    for _ in range(5):
+                        t0 = time.perf_counter()
+                        jax.block_until_ready(program(*args))
+                        best = min(best, time.perf_counter() - t0)
+                    rec["us"] = round(best / n_calls * 1e6, 1)
+                    ran.append((rec, program))
+                except Exception as e:
+                    rec["error"] = f"{type(e).__name__}: {e}"[:300]
+                    emit(rec)
+            shutil.rmtree(trace_dir, ignore_errors=True)
+            jax.profiler.start_trace(trace_dir, profiler_options=quiet)
+            for _, program in ran:
+                jax.block_until_ready(program(*args))
+            jax.profiler.stop_trace()
+            events = _kernel_events(trace_dir, kernel)
+            if len(events) != 2 * n_calls * len(ran):
+                for rec, _ in ran:  # the host's clock alone
+                    emit(dict(rec, error=f"{len(events)} kernel events "
+                              f"for {len(ran)} programs"))
+                continue
+            for j, (rec, _) in enumerate(ran):
+                mine = events[2 * n_calls * j:2 * n_calls * (j + 1)]
+                k, n, n_rhs = shapes[rec["product"]]
+                for product, part in (("in", mine[0::2]), ("out", mine[1::2])):
+                    rec[f"{product}_kernel_us"] = round(
+                        sum(part) / len(part) * 1e6, 1)
+                rec["kernel_us"] = rec[rec["product"] + "_kernel_us"]
+                nbytes = (touched * n_rhs * k * n + rows * (k + n)) * 2
+                rec["pct_of_hbm"] = round(
+                    nbytes / (rec["kernel_us"] * 1e-6) / hbm_bw * 100, 1)
+                emit(rec)
+        del w_in, w_out
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    log.close()
+
 
 if __name__ == "__main__":
     from tree_attention_tpu import obs
@@ -229,9 +449,10 @@ if __name__ == "__main__":
     # Env-armed like bench.py (TA_METRICS_OUT / TA_TRACE_EVENTS): without
     # this the guard verdicts filed above would hit a disabled registry.
     obs.configure()
-    mode = sys.argv[1] if len(sys.argv) > 1 else "decode"
+    mode = sys.argv[1].lstrip("-") if len(sys.argv) > 1 else "decode"
     try:
         {"decode": sweep_decode, "fwd": sweep_fwd,
-         "bwd": lambda: sweep_fwd(bwd=True)}[mode]()
+         "bwd": lambda: sweep_fwd(bwd=True),
+         "grouped": lambda: sweep_grouped(*sys.argv[2:3])}[mode]()
     finally:
         obs.shutdown()

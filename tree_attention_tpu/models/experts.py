@@ -126,8 +126,9 @@ def expert_layer(p: Params, x: jax.Array, ex: ExpertLayer, *,
     expert is ``w2 . relu(w1 . u)^2`` (``experts`` holds two stacks, no
     gate) and its products carry the ungated kernel's name."""
     from tree_attention_tpu.ops.pallas_moe import (
-        UNGATED_KERNEL, grouped_matmul, row_tile,
+        UNGATED_KERNEL, grouped_matmul,
     )
+    from tree_attention_tpu.ops.tuning import row_tile
 
     if experts is None:
         experts = tuple(p[n] for n in ex.leaves)

@@ -9,6 +9,16 @@ weights are never read, and rows past the last expert's range (pairs whose
 expert another chip holds) are never computed. The grid's middle dimension is
 the DYNAMIC length of that list.
 
+The blocks a launch moves (the row tile, how much of the contraction a grid
+step takes, the column strips' width, whether the rows' tile holds the whole
+contraction) are a :class:`~tree_attention_tpu.ops.tuning.GroupedPlan`,
+chosen from the operands' shapes by ``ops/tuning.py`` ``grouped_plan`` (a
+measured table; a shape it has no row for takes the two constants the kernel
+was written with). With the whole contraction a step (``tk == k``) the body
+carries nothing between steps, and an expert whose rows straddle a row tile
+(two entries) is read once: its second entry asks for the block the first
+left resident.
+
 Two products share the kernel body: the gate/up pair with the SwiGLU fused
 (``silu(x @ w1[g]) * (x @ w3[g])``: the rows are read once for both), and the
 down projection. Both are named ``moe_grouped_matmul`` in the compiled module
@@ -35,11 +45,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from tree_attention_tpu import obs
+from tree_attention_tpu.ops.tuning import GroupedPlan, grouped_plan
 
 _KERNEL_BUILDS = obs.counter(
     "pallas_moe_kernel_builds_total",
-    "grouped-matmul kernel program builds (one per distinct shape/config)",
-    labels=("kernel",),
+    "grouped-matmul kernel program builds (one per distinct shape/config), "
+    "by the block plan each took (ops/tuning.py grouped_plan)",
+    labels=("kernel", "plan"),
 )
 _KERNEL_DISPATCH = obs.counter(
     "pallas_moe_dispatch_total",
@@ -79,35 +91,32 @@ def tile_plan(group_sizes: jax.Array, m: int, tm: int
 
 
 def _grouped_kernel(offs_ref, gid_ref, mt_ref, first_ref, lhs_ref, *refs,
-                    n_rhs: int, tm: int, tn: int, tiles_k: int,
+                    n_rhs: int, plan: GroupedPlan, tiles_k: int,
                     relu2: bool = False):
     del first_ref  # the index maps' (where the groups start in the stack)
+    tm, tk, tn = plan.tm, plan.tk, plan.tn
     rhs_refs = refs[:n_rhs]
     out_ref = refs[n_rhs]
     accs = refs[n_rhs + 1:]
     e = pl.program_id(1)
     k_i = pl.program_id(2)
 
-    @pl.when(k_i == 0)
-    def _zero():
-        for acc in accs:
-            acc[...] = jnp.zeros_like(acc)
+    def products():
+        if plan.rows_whole:  # the tile holds every k: this step's part
+            x = lhs_ref[:, pl.ds(pl.multiple_of(k_i * tk, tk), tk)]
+        else:
+            x = lhs_ref[...]
+        return [lax.dot_general(x, w[...], (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+                for w in rhs_refs]
 
-    x = lhs_ref[...]
-    for acc, w in zip(accs, rhs_refs):
-        acc[...] += lax.dot_general(
-            x, w[...], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    @pl.when(k_i == tiles_k - 1)
-    def _store():
+    def store(vals):
         g = gid_ref[e]
         rows = mt_ref[e] * tm + lax.broadcasted_iota(jnp.int32, (tm, tn), 0)
         mine = (rows >= offs_ref[g]) & (rows < offs_ref[g + 1])
-        val = accs[0][...]
+        val = vals[0]
         if n_rhs == 2:  # the gate/up pair: SwiGLU on the accumulators
-            val = jax.nn.silu(val) * accs[1][...]
+            val = jax.nn.silu(val) * vals[1]
         elif relu2:     # an ungated expert's one matrix in
             val = _relu2(val)
         # Another expert's rows of this tile were stored by its own entry
@@ -116,23 +125,33 @@ def _grouped_kernel(offs_ref, gid_ref, mt_ref, first_ref, lhs_ref, *refs,
             mine, val, out_ref[...].astype(jnp.float32)
         ).astype(out_ref.dtype)
 
+    if tiles_k == 1:  # one step an entry: nothing to carry between steps
+        store(products())
+        return
 
-def _divisor_tile(n: int, cap: int) -> int:
-    """The largest multiple of 128 that divides ``n`` and is <= ``cap``;
-    ``n`` itself where none does (a small test size)."""
-    for t in range(cap - cap % 128, 0, -128):
-        if n % t == 0:
-            return t
-    return n
+    @pl.when(k_i == 0)
+    def _zero():
+        for acc in accs:
+            acc[...] = jnp.zeros_like(acc)
+
+    for acc, part in zip(accs, products()):
+        acc[...] += part
+
+    @pl.when(k_i == tiles_k - 1)
+    def _store():
+        store([acc[...] for acc in accs])
 
 
 def _grouped_pallas(lhs: jax.Array, rhs: Sequence[jax.Array],
                     group_sizes: jax.Array, first_group: jax.Array, *,
-                    tm: int, interpret: bool, relu2: bool = False,
+                    plan: GroupedPlan, interpret: bool, relu2: bool = False,
                     name: str = GATED_KERNEL) -> jax.Array:
     m, k = lhs.shape
     n = rhs[0].shape[2]
-    tk, tn = _divisor_tile(k, 1024), _divisor_tile(n, 1024 // len(rhs))
+    tm, tk, tn = plan.tm, plan.tk, plan.tn
+    if m % tm or k % tk or n % tn:
+        raise ValueError(
+            f"grouped_matmul: {plan} does not divide ({m}, {k}) x ({k}, {n})")
     tiles_k = k // tk
     offsets, gid, m_tile, n_entries = tile_plan(group_sizes, m, tm)
     offsets = jnp.asarray(offsets, jnp.int32)
@@ -142,28 +161,34 @@ def _grouped_pallas(lhs: jax.Array, rhs: Sequence[jax.Array],
     if obs.REGISTRY.enabled:
         _KERNEL_BUILDS.labels(
             kernel="moe_up" if len(rhs) == 2 else "moe_ungated_up" if relu2
-            else "moe_down").inc()
+            else "moe_down", plan=plan.label).inc()
+    if plan.rows_whole:
+        rows = pl.BlockSpec((tm, k), lambda ni, e, ki, o, g, t, f: (t[e], 0))
+    else:
+        rows = pl.BlockSpec((tm, tk),
+                            lambda ni, e, ki, o, g, t, f: (t[e], ki))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(n // tn, jnp.maximum(n_entries, 1), tiles_k),
-        in_specs=[
-            pl.BlockSpec((tm, tk), lambda ni, e, ki, o, g, t, f: (t[e], ki)),
-        ] + [
+        in_specs=[rows] + [
             pl.BlockSpec((None, tk, tn),
                          lambda ni, e, ki, o, g, t, f: (f[0] + g[e], ki, ni))
             for _ in rhs
         ],
         out_specs=pl.BlockSpec((tm, tn),
                                lambda ni, e, ki, o, g, t, f: (t[e], ni)),
-        scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32) for _ in rhs],
+        scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)
+                        for _ in rhs if tiles_k > 1],
     )
     return pl.pallas_call(
-        functools.partial(_grouped_kernel, n_rhs=len(rhs), tm=tm, tn=tn,
+        functools.partial(_grouped_kernel, n_rhs=len(rhs), plan=plan,
                           tiles_k=tiles_k, relu2=relu2),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=plan.vmem_limit_bytes(
+                k, len(rhs), lhs.dtype.itemsize),
         ),
         interpret=interpret,
         name=name,
@@ -173,7 +198,8 @@ def _grouped_pallas(lhs: jax.Array, rhs: Sequence[jax.Array],
 def grouped_matmul(lhs: jax.Array, rhs: Sequence[jax.Array],
                    group_sizes: jax.Array, *, first_group=0,
                    interpret: Optional[bool] = None, relu2: bool = False,
-                   name: str = GATED_KERNEL) -> jax.Array:
+                   name: str = GATED_KERNEL,
+                   plan: Optional[GroupedPlan] = None) -> jax.Array:
     """``lhs`` ``(m, k)`` rows sorted by group; ``rhs`` one ``(S, k, n)``
     stack (the product; with ``relu2`` an ungated expert's matrix in:
     ``relu(x@a)^2``) or two (gate and up: ``silu(x@a) * (x@b)``); ``name``
@@ -184,8 +210,11 @@ def grouped_matmul(lhs: jax.Array, rhs: Sequence[jax.Array],
     offset, so that a layer loop never slices the stack). Rows past
     ``sum(group_sizes)`` belong to no group: the kernel leaves them
     unwritten (whatever the buffer held) and the reference path zero, so
-    the caller masks them. ``m`` must divide by the row tile (128, or 256
-    from 2,048 rows on)."""
+    the caller masks them. ``plan`` (default: ``ops/tuning.py``
+    ``grouped_plan`` of the operands' shapes) is the kernel's blocks, and
+    ``m`` must divide by its row tile (``tuning.row_tile(m)`` rows always
+    do); an explicit one wins unchanged (the sweep and the tests measure
+    what they label)."""
     from tree_attention_tpu.ops import _on_tpu, _pallas_available
 
     m = lhs.shape[0]
@@ -206,16 +235,8 @@ def grouped_matmul(lhs: jax.Array, rhs: Sequence[jax.Array],
         return val.astype(lhs.dtype)
     if obs.REGISTRY.enabled:
         _KERNEL_DISPATCH.labels(path=name).inc()
-    tm = row_tile(m)
-    if m % tm:
-        raise ValueError(f"grouped_matmul: {m} rows do not divide by {tm}")
+    if plan is None:
+        plan = grouped_plan(m, lhs.shape[1], rhs[0].shape[2], len(rhs))
     return _grouped_pallas(lhs, list(rhs), group_sizes.astype(jnp.int32),
-                           first.reshape(1), tm=tm,
+                           first.reshape(1), plan=plan,
                            interpret=bool(interpret), relu2=relu2, name=name)
-
-
-def row_tile(m: int) -> int:
-    """Rows a tile: 128 (a decode tick's pairs fit one), 256 once a tick
-    carries chunk rows (each expert's weights are then streamed for fewer
-    tiles)."""
-    return 128 if m < 2048 else 256

@@ -20,7 +20,7 @@ dispatcher and the custom VJP.
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 # context-length upper bound -> block_k. Measured on v5e
 # (tools/tune_sweep.py, 2026-07-31): bigger contexts amortise the
@@ -411,3 +411,224 @@ def default_block_q_bwd(tq: int, tk: int, block_k: Optional[int] = None) -> int:
         BWD_MAX_BLOCK_Q,
         max(8, BWD_MAX_TILE_ELEMS // max(block_k, 1)),
     )
+
+
+# ---------------------------------------------------------------------------
+# The grouped expert product's blocks (ops/pallas_moe.py)
+# ---------------------------------------------------------------------------
+
+# What a kernel gets of fast memory without asking (v5e: 16 MB scoped).
+DEFAULT_SCOPED_VMEM_BYTES = 16 << 20
+# The most a plan may ask for: the chip holds 128 MB; the rest is XLA's.
+GROUPED_VMEM_CEILING_BYTES = 64 << 20
+
+
+class GroupedPlan(NamedTuple):
+    """The blocks one launch of the grouped product moves: a row tile of
+    ``tm`` pair rows, ``tk`` of the contraction a grid step (``tk == k``:
+    one step an entry, no accumulator carried between steps, and an expert
+    whose rows straddle a row tile finds its strip resident at its second
+    entry), column strips of ``tn``; ``rows_whole``: the rows' tile holds
+    the whole contraction (fetched once a row tile, sliced in the body)
+    where ``tk < k``."""
+    tm: int
+    tk: int
+    tn: int
+    rows_whole: bool = False
+
+    @property
+    def label(self) -> str:
+        return (f"tm{self.tm}_tk{self.tk}_tn{self.tn}"
+                + ("_rows" if self.rows_whole else ""))
+
+    def vmem_bytes(self, k: int, n_rhs: int, itemsize: int) -> int:
+        """Fast memory the blocks take: every operand's block twice (the
+        pipeline fetches a step ahead), the accumulators where ``tk < k``,
+        and the float32 values the body makes of a ``(tm, tn)`` tile."""
+        rows = self.tm * (k if self.rows_whole else self.tk)
+        blocks = rows + n_rhs * self.tk * self.tn + self.tm * self.tn
+        acc = n_rhs if self.tk < k else 0
+        return (2 * blocks * itemsize
+                + (acc + n_rhs + 2) * self.tm * self.tn * 4)
+
+    def vmem_limit_bytes(self, k: int, n_rhs: int,
+                         itemsize: int) -> Optional[int]:
+        """``None`` (the default scope) where the blocks leave it a
+        quarter; else the blocks and 4 MB for what the compiler adds."""
+        need = self.vmem_bytes(k, n_rhs, itemsize)
+        if need <= DEFAULT_SCOPED_VMEM_BYTES * 3 // 4:
+            return None
+        return need + (4 << 20)
+
+
+def row_tile(m: int) -> int:
+    """Rows the expert layer pads its pairs to, and the row tile of a shape
+    with no measured plan: 128 (a decode tick's pairs fit one), 256 once a
+    tick carries chunk rows."""
+    return 128 if m < 2048 else 256
+
+
+def _divisor_tile(n: int, cap: int) -> int:
+    """The largest multiple of 128 that divides ``n`` and is <= ``cap``;
+    ``n`` itself where none does (a small test size)."""
+    for t in range(cap - cap % 128, 0, -128):
+        if n % t == 0:
+            return t
+    return n
+
+
+def grouped_plan_unmeasured(m: int, k: int, n: int, n_rhs: int) -> GroupedPlan:
+    """The plan of a shape the sweep has not seen: the two constants the
+    kernel was written with (PR 27, for DeepSeek-V2's widths)."""
+    return GroupedPlan(row_tile(m), _divisor_tile(k, 1024),
+                       _divisor_tile(n, 1024 // n_rhs))
+
+
+# Measured on v5e 2026-10-01 (`tools/tune_sweep.py --grouped`, ISSUE 42): a
+# program of 32 (8 at a mixed tick's rows) expert layers in a loop as a tick
+# makes them (the pair rows gathered from the token rows, the product in, the
+# product out on its result; a call's layer `i % layers` of a stack of 2-4
+# layers' experts), the product under test on the candidate plan and the
+# other on the first row's; us = the mean device time of that product's
+# events in one traced run (what `moe_ffn_ms_tick` sums; a plan measured
+# 12-21 times as "the other" repeats to 0.1 us), in brackets the share of
+# 819 GB/s the cost function's bytes reach (the touched experts' matrices
+# once, the pairs' rows in and out). Rows: pairs a tick / experts held /
+# touched, as the cells lay them (a pair lands on a held expert with the
+# deployment's share, on any of them alike). `*` the plan chosen; the first
+# row of a block is the plan until PR 42 (`grouped_plan_unmeasured`); `_rows`
+# the rows' tile at whole k. Every plan's result equal to the first row's to
+# a bf16 rounding of the float32 sum's order (0.0002-0.03 at |values| ~ 4).
+#
+#   LFM2 2048 x 1792         256 pairs / 32 / 32          1,280 / 32 / 32
+#   gate/up  tk1024 tn256     678.5 (84.9)                 823.6 (71.1)
+#            tk1024 tn256_rows 677.3 (85.0)                820.3 (71.4)
+#            tk1024 tn896_rows 645.5 (89.2)                781.7 (74.9)
+#            tk1024 tn1792_rows 646.8 (89.1)               782.5 (74.8)
+#            tk2048 tn256     637.4 (90.4) *               739.1 (79.2)
+#            tk2048 tn896     639.1 (90.1)                 716.5 (81.7) *
+#            tk2048 tn1792    641.1 (89.8)                 712.5 (82.2)
+#   down     tk896 tn1024     322.9 (89.6)                 392.3 (76.2)
+#            tk896 tn2048_rows 323.5 (89.4)                392.4 (76.1)
+#            tk1792 tn512     321.2 (90.0)                 375.7 (79.5)
+#            tk1792 tn1024    320.5 (90.2) *               365.7 (81.7)
+#            tk1792 tn2048    321.4 (90.0)                 360.2 (83.0) *
+#
+#   6144 x 2048              K-EXAONE 256 / 8 / 7   LongCat 384 / 16 / 8   2,304 / 8 / 8      3,584 / 16 / 16
+#   gate/up  tm256 tk1024 tn512     (row tile 128 under 2,048 rows)        631.6 (78.2)       1252.4 (78.6)
+#            tm256 tk1024 tn2048_rows                                      580.1 (85.2)       1148.3 (85.7)
+#            tm256 tk6144 tn512                                            558.1 (88.6)       1097.3 (89.7)
+#            tm128 tk1024 tn512     469.3 (91.7) *  535.5 (91.9) *         -                  -
+#            tm128 tk1024 tn512_rows 469.2 (91.8)   535.5 (91.9)           535.8 (92.2) *     1068.0 (92.2) *
+#            tm128 tk1024 tn256_rows 505.5 (85.2)   575.8 (85.4)           577.2 (85.6)       1152.8 (85.4)
+#            tm128 tk1024 tn2048_rows 473.2 (91.0)  539.6 (91.2)           539.6 (91.6)       1071.5 (91.9)
+#            tm128 tk2048 tn512_rows 470.7 (91.5)   537.2 (91.6)           537.3 (92.0)       1069.6 (92.1)
+#            tm128 tk2048 tn2048_rows 478.6 (90.0)  545.1 (90.2)           545.1 (90.7)       1077.1 (91.4)
+#            tm128 tk6144 tn256     501.4 (85.9)    573.0 (85.8)           572.6 (86.3)       1140.0 (86.4)
+#            tm128 tk6144 tn512     475.9 (90.5)    542.3 (90.7)           542.4 (91.1)       1075.1 (91.6)
+#   down     tm256 tk1024 tn1024    (row tile 128 under 2,048 rows)        314.8 (78.9)       626.4 (78.7)
+#            tm256 tk2048 tn1024                                           286.9 (86.6)       567.7 (86.8)
+#            tm128 tk1024 tn1024    235.3 (91.5) *  268.5 (91.6) *         -                  -
+#            tm128 tk1024 tn1024_rows 235.3 (91.5)  268.5 (91.6)           268.5 (92.5)       534.4 (92.2)
+#            tm128 tk1024 tn6144_rows 242.3 (88.9)  275.6 (89.3)           275.6 (90.1)       541.5 (91.0)
+#            tm128 tk2048 tn256     249.1 (86.5)    280.7 (87.7)           283.6 (87.6)       560.5 (88.0)
+#            tm128 tk2048 tn512     235.1 (91.6)    268.3 (91.7)           268.3 (92.6) *     534.3 (92.3) *
+#            tm128 tk2048 tn3072    242.6 (88.8)    275.8 (89.2)           276.0 (90.0)       542.4 (90.9)
+#
+#   DeepSeek-V2 5120 x 1536  128 rows (96 pairs) / 40 / 17     1,664 (1,632) / 40 / 40
+#   gate/up  tk1024 tn512     710.1 (92.0) *               1791.6 (86.2)
+#            tk1024 tn256_rows 733.5 (89.1)                1852.0 (83.3)
+#            tk1024 tn1536_rows 712.4 (91.7)               1792.8 (86.1)
+#            tk1280 tn512_rows 710.5 (92.0)                1792.2 (86.1)
+#            tk5120 tn256     711.1 (91.9)                 1741.2 (88.6) *
+#            tk5120 tn512     715.5 (91.3)                 1741.4 (88.6)
+#            tk5120 tn768     725.7 (90.0)                 1766.2 (87.4)
+#   down     tk768 tn1024     355.9 (91.8) *               896.9 (86.4)
+#            tk768 tn5120_rows 359.6 (90.9)                899.9 (86.2)
+#            tk1536 tn512     357.1 (91.5)                 890.0 (87.1)
+#            tk1536 tn1024    356.6 (91.7)                 876.9 (88.4) *
+#            tk1536 tn2560    359.7 (90.9)                 873.4 (88.8)
+#            tk1536 tn5120    364.4 (89.7)                 874.8 (88.6)
+#
+#   Nemotron 1024 x 2688 (ungated)  1,408 / 128 / 120      7,168 (7,040) / 128 / 128
+#   in       tm256 tk1024 tn896     (row tile 128)         1101.3 (79.5)
+#            tm256 tk1024 tn2688                           1018.4 (86.0)
+#            tm128 tk1024 tn896     884.3 (91.6) *         1006.0 (87.1)
+#            tm128 tk1024 tn2688    881.3 (91.9)           985.4 (88.9) *
+#   out      tm256 tk896 tn1024     (row tile 128)         1151.6 (76.1)
+#            tm256 tk2688 tn1024                           1017.3 (86.1)
+#            tm128 tk896 tn1024     882.2 (91.8) *         -
+#            tm128 tk896 tn1024_rows 882.1 (91.8)          1020.3 (85.8)
+#            tm128 tk2688 tn256     972.7 (83.3)           1105.0 (79.3)
+#            tm128 tk2688 tn512     880.4 (92.0)           992.8 (88.2)
+#            tm128 tk2688 tn1024    881.3 (91.9)           986.1 (88.8) *
+#
+# What the rows say. (1) A step wants 2 MB or more of weights: LFM2's
+# gate/up moved 1 MB a step (1792 divides by no multiple of 128 between 256
+# and 896, and `1024 // 2` capped the strip at 512: two 512 KB blocks, 14
+# steps an expert) and read 84.9; the same strip at whole k (2 MB, 7 steps)
+# reads 90.4, as the 896- and 1792-wide strips do (89.1-90.1). 256-wide
+# strips of a 2048-wide matrix (512 B segments at a 4 KB stride) read 85-86%
+# at 1, 2 and 6 MB a step alike where at 1792 and 1536 wide they read 90-92:
+# the stride, not the segment alone. Past 2 MB a step nothing more is won
+# at a decode tick's rows: every shape's best rows tie to 0.5%, and the
+# largest blocks lose 1-2 points (a launch's first block is fetched with
+# nothing under it: 12-16 MB is 15-20 us of a 470-540 us launch).
+# (2) At a mixed tick's rows
+# an expert whose rows straddle a row tile has two entries, and with k
+# tiled its weight blocks alternate, so its matrices are read twice: whole
+# k (one step an entry; the second entry finds the strip resident) is worth
+# 3-13% there (LFM2 823.6 -> 712.5, DeepSeek-V2 1791.6 -> 1741.2, Nemotron
+# out 1151.6 -> 986.1) and nothing at a decode tick's. (3) The 256-row tile
+# that 2,048 rows and more took loses 14-15% to the 128-row one at every
+# plan: an entry computes its whole tile whatever rows of it are its
+# expert's, and at 256 rows the MXU's time nears the DMA's (12.9 GFLOP
+# against 75 MB at 6144 x 2048 x 2); what it was chosen for, fewer entries
+# an expert and so fewer second reads, whole k or the rows' tile gives
+# without it. (4) The rows' tile at whole k is worth nothing at a decode
+# tick's rows inside a layer (the compiler leaves the gathered rows, and
+# the product's result, in fast memory: `bf16[256,6144]{...S(1)}` in the
+# tick programs) and 1-3% at a mixed tick's with k tiled. Known to push against each other: a step's fixed
+# cost and short segments (small blocks lose) and a launch's first block
+# (large blocks lose); the optimum is flat between 2 and 8 MB a step.
+# A plan that ties the one until PR 42 at a decode tick's rows (within
+# 0.5%) is not taken there: the program stays what the parent compiled.
+# Not the blocks': K-EXAONE's cell reads 84% where its shape reads 91.7
+# here. A launch over ALL 8 held experts (most of its decode ticks') takes
+# 569.0 us alone (71.1 an expert; 611.5 in the cell) where 7 of the 8 take
+# 469.3 (67.0), at stacks of 40-64 experts, with or without a spacer
+# between the two stacks: the sweep's K-EXAONE row should touch every held
+# expert before the next look (PERF.md section 7).
+def _plans(k, n, n_rhs, few, many, bound=512):
+    """``few``: (tk, tn, rows_whole) under ``bound`` rows (``None``: the
+    plan until PR 42), ``many`` from there on; the row tile 128 at any
+    measured row."""
+    return (k, n, n_rhs), (
+        (bound, None if few is None else GroupedPlan(128, *few)),
+        (float("inf"), GroupedPlan(128, *many)))
+
+
+# (k, n, n_rhs) -> ((rows under, plan or None), ...): the first whose bound
+# holds; None: :func:`grouped_plan_unmeasured`.
+_GROUPED_PLANS = dict((
+    _plans(2048, 1792, 2, (2048, 256, False), (2048, 896, False)),   # LFM2
+    _plans(1792, 2048, 1, (1792, 1024, False), (1792, 2048, False)),
+    _plans(6144, 2048, 2, None, (1024, 512, True)),    # K-EXAONE, LongCat
+    _plans(2048, 6144, 1, None, (2048, 512, False)),
+    _plans(5120, 1536, 2, None, (5120, 256, False)),   # DeepSeek-V2
+    _plans(1536, 5120, 1, None, (1536, 1024, False)),
+    _plans(1024, 2688, 1, None, (1024, 2688, False), bound=2048),  # Nemotron
+    _plans(2688, 1024, 1, None, (2688, 1024, False), bound=2048),
+))
+
+
+def grouped_plan(m: int, k: int, n: int, n_rhs: int) -> GroupedPlan:
+    """The blocks for ``(m, k) x n_rhs (k, n)``: the measured row of the
+    shape, else :func:`grouped_plan_unmeasured`. Reads the operands'
+    shapes and nothing else."""
+    for bound, plan in _GROUPED_PLANS.get((k, n, n_rhs), ()):
+        if m < bound:
+            if plan is not None and m % plan.tm == 0:
+                return plan
+            break
+    return grouped_plan_unmeasured(m, k, n, n_rhs)
