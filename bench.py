@@ -607,18 +607,6 @@ def _serving_record():
     return bench_serving()
 
 
-def _serving_flood_record():
-    """Long-prompt flood (ISSUE 3): p95 inter-token latency with chunked
-    admission (prefill fused into the per-tick mixed step, Sarathi-style
-    token budget) vs legacy whole-prompt blocking admission, plus the
-    chain_slope-priced stall ratio of one whole prefill vs one mixed
-    chunk tick. CPU proxy; the stall structure transfers. See
-    tree_attention_tpu/bench/serving.py."""
-    from tree_attention_tpu.bench.serving import bench_serving_flood
-
-    return bench_serving_flood()
-
-
 def _serving_prefix_record():
     """Shared-prefix flood (ISSUE 5): TTFT p50/p95 with the radix prefix
     KV cache on vs off over a trace where >= 50% of requests share a
@@ -937,7 +925,6 @@ def _run_suite():
     run("tree_vs_ring_cpu8", _tree_vs_ring_record)
     run("tree_vs_ring_decode_cpu8", _tree_vs_ring_decode_record)
     run("serving_continuous_batching", _serving_record)
-    run("serving_chunked_prefill_flood", _serving_flood_record)
     run("serving_prefix_flood", _serving_prefix_record)
     run("serving_paged_flood", _serving_paged_record)
     run("serving_speculative", _serving_spec_record)
@@ -1022,18 +1009,6 @@ def _summarize_record(name, rec):
             out["trace_speedup_vs_sequential"] = (
                 trace["trace_speedup_vs_sequential"]
             )
-    if name == "serving_chunked_prefill_flood":
-        slope = rec.get("slope", {})
-        if "stall_ratio" in slope:
-            out["stall_ratio"] = slope["stall_ratio"]
-        trace = rec.get("trace", {})
-        for key in ("tbt_p95_improvement", "tokens_per_sec_ratio"):
-            if key in trace:
-                out[key] = trace[key]
-        for mode in ("chunked", "whole"):
-            g = trace.get(mode, {}).get("goodput")
-            if g is not None:
-                out[f"goodput_{mode}"] = g
     if name == "serving_prefix_flood":
         slope = rec.get("slope", {})
         if "prefill_avoided_ratio" in slope:

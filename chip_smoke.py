@@ -562,7 +562,7 @@ def _check_serve_record(rc: int, rec: Dict[str, Any], n: int,
 
 def _serve_summary(rec: Dict[str, Any]) -> Dict[str, Any]:
     keep = ("requests", "tokens_generated", "ticks", "outcomes", "cache_len",
-            "admission", "prefill_chunk", "kv_quant", "prefix", "kv")
+            "prefill_chunk", "kv_quant", "prefix", "kv")
     out = {k: rec[k] for k in keep if k in rec}
     out["serve_wall_s"] = rec.get("wall_s")
     return out

@@ -43,3 +43,40 @@ def _clear_jax_caches_per_module():
     """
     yield
     jax.clear_caches()
+
+
+def instruments_left_on():
+    """What a test module left of the process-wide instruments
+    (``obs.FLIGHT``, ``obs.TRACER``) that a later module in the same
+    process would inherit: a worker runs several files one after another."""
+    from tree_attention_tpu import obs
+
+    left = []
+    if obs.FLIGHT.enabled:
+        left.append("the flight recorder armed")
+    snap = obs.FLIGHT.snapshot()
+    if snap["records"] or "programs" in snap:
+        left.append("the flight recorder's ring or program tables uncleared")
+    if obs.TRACER.active:
+        left.append("the span tracer active")
+    return left
+
+
+def instruments_off():
+    """The state :func:`instruments_left_on` calls clean."""
+    from tree_attention_tpu import obs
+
+    obs.FLIGHT.disarm()
+    obs.FLIGHT.clear()
+    obs.TRACER.close()
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _instruments_off_at_module_end(request):
+    """A module that leaves an instrument on fails here, by its own name,
+    and not in whichever file the worker runs next; the state is put right
+    first, so one leak fails once."""
+    yield
+    left = instruments_left_on()
+    instruments_off()
+    assert not left, f"{request.module.__name__} left " + ", ".join(left)

@@ -469,7 +469,7 @@ def test_grouped_matmul_kernel_equals_ragged_dot(product, plan, layout):
 
 def _engine(tcfg, params, **kw):
     args = dict(slots=3, cache_len=96, prefill_chunk=16, prefix_cache=True,
-                prefix_block=8, admission="chunked")
+                prefix_block=8)
     args.update(kw)
     return SlotServer(params, tcfg, **args)
 
@@ -580,6 +580,7 @@ def test_expert_counters_ride_the_fetch_and_stay_off_when_off(fam):
         zero = obs.REGISTRY.counter("moe_pairs_zero").value()
     finally:
         FLIGHT.disarm()
+        FLIGHT.clear()
         obs.REGISTRY.disable()
         obs.REGISTRY.reset()
     assert recs and here == sum(r["expert_pairs"] for r in recs)
@@ -607,7 +608,6 @@ def test_expert_counters_ride_the_fetch_and_stay_off_when_off(fam):
     (dict(kv_shard="seq"), "sequence-sharded"),
     (dict(host_blocks=4), "host tier"),
     (dict(speculate=True), "tree_mask"),
-    (dict(admission="whole"), "whole-prompt admission"),
 ])
 def test_engine_refuses_what_a_latent_pool_does_not_carry(fam, kw, named):
     _, _, _, tcfg, params = _model(fam)
@@ -622,7 +622,6 @@ def test_engine_refuses_what_a_latent_pool_does_not_carry(fam, kw, named):
      "--host-blocks"),
     (["--speculate"], "--speculate"),
     (["--serve-disagg"], "--serve-disagg"),
-    (["--admission", "whole"], "--admission whole"),
 ])
 @pytest.mark.parametrize("preset", sorted(PRESETS))
 def test_cli_refuses_by_name_with_a_system_exit(tmp_path, preset, flags,

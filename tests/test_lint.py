@@ -1922,8 +1922,8 @@ class TestFullPackage:
     def test_engine_tick_fetch_is_annotated(self):
         # The per-tick host syncs are allow[]-annotated, not unscoped:
         # the verify-tick fused fetch, the mixed tick's token+logprob
-        # fused fetch (ISSUE 15), and the awaits-only tick's token +
-        # logprob pair.
+        # fused fetch (ISSUE 15), and the token + logprob pair of
+        # a tick that stepped nothing (a staged first token alone).
         path = os.path.join(lintlib.REPO_ROOT, ENGINE)
         with open(path) as fh:
             text = fh.read()

@@ -17,6 +17,8 @@ from tree_attention_tpu.obs import scopes
 from tree_attention_tpu.obs.flight import FLIGHT
 from tree_attention_tpu.serving import Request, SlotServer
 
+from tests.conftest import instruments_left_on, instruments_off
+
 CFG = TransformerConfig(
     vocab_size=128, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
     d_head=16, d_ff=128, max_seq_len=256, dtype=jnp.float32,
@@ -67,7 +69,12 @@ def _requests(n=3, prompt_len=9, n_new=4, key=31):
 @pytest.fixture(scope="module")
 def runs(server, watch):
     """The same engine three times: tracing off (it builds its programs),
-    off again (nothing left to build), then with the recorder armed."""
+    off again (nothing left to build), then with the recorder armed. "Off"
+    is this fixture's to establish: a worker runs other files before this
+    one, and ``conftest.py`` holds each of them to the same state at its
+    end."""
+    instruments_off()
+    assert instruments_left_on() == []
     out = {}
     for name in ("cold", "off", "on"):
         if name == "on":
@@ -83,6 +90,26 @@ def runs(server, watch):
             FLIGHT.clear()
         out[name] = (report, snap, t0, t1)
     return out
+
+
+def test_what_a_module_leaves_on_is_named():
+    """The check ``conftest.py`` makes at every module's end: an armed
+    recorder, a ring or tables left in it after it was disarmed (what
+    ``snapshot`` and ``/flight`` keep serving) and an open tracer each
+    have a name; a module that cleans up has none."""
+    assert instruments_left_on() == []
+    FLIGHT.arm()
+    try:
+        assert instruments_left_on() == ["the flight recorder armed"]
+        FLIGHT.describe_programs([{"program": {}, "ops": []}])
+    finally:
+        FLIGHT.disarm()
+    try:
+        assert instruments_left_on() == [
+            "the flight recorder's ring or program tables uncleared"]
+    finally:
+        FLIGHT.clear()
+    assert instruments_left_on() == []
 
 
 def test_off_the_report_holds_no_table(runs):

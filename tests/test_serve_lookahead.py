@@ -293,9 +293,7 @@ def test_dispatch_runs_ahead_of_the_fetch_before_it(params, monkeypatch):
     (dict(temperature=1.0, tree_sampling=False, prefix_cache=False),
      dict(n=2), {"fork"}, True),
     (dict(quantize=True, prefix_cache=False), {}, {"staged"}, True),
-    (dict(admission="whole", prefix_cache=False), {}, {"whole"}, False),
-], ids=["speculation", "tree-family", "fork-family", "staged-int8",
-        "whole-admission"])
+], ids=["speculation", "tree-family", "fork-family", "staged-int8"])
 def test_ticks_that_need_token_values_stay_synchronous(
         params, kw, req_kw, reasons, some_ahead):
     """Which ticks look ahead follows from the engine's own state: a tick
@@ -309,7 +307,7 @@ def test_ticks_that_need_token_values_stay_synchronous(
     report, recs = _recorded(server, reqs)
     assert len(recs) == report.ticks
     said = {r["sync_reason"] for r in recs if not r["ahead"]}
-    assert reasons <= said <= reasons | {"first", "drain", "awaits"}
+    assert reasons <= said <= reasons | {"first", "drain"}
     assert any(r["ahead"] for r in recs) == some_ahead
     for r in recs:
         if r["kind"] in ("staged", "verify"):

@@ -14,8 +14,8 @@ Five contracts, mirroring the layered design:
     ``fold_in(request_key, stream_index)`` — the reproducibility root.
 (c) **Parity** — a temperature-0 ``n = k`` family is token-for-token
     identical to k independent greedy requests, across exact/int8 ×
-    chunked/whole admission × single-device/compat cpu_mesh (all on the
-    paged layout — forking is a paged feature); fixed-seed SAMPLED runs
+    single-device/compat cpu_mesh (all on the paged layout — forking is
+    a paged feature); fixed-seed SAMPLED runs
     are bit-identical across two serves. Mid-generation forks
     (``fork_at`` / the ``fork(uid)`` mailbox) share the stream prefix
     and diverge after it.
@@ -264,11 +264,6 @@ def test_greedy_family_matches_independent_unaligned_prompt(params):
 def test_greedy_family_matches_independent_int8(params):
     eng = engine(params, slots=5, quantize=True)
     _family_vs_independent(eng, _prompt(3), 3)
-
-
-def test_greedy_family_matches_independent_whole_admission(params):
-    eng = engine(params, slots=4, admission="whole")
-    _family_vs_independent(eng, _prompt(4), 2)
 
 
 def test_greedy_family_mesh_parity(params):

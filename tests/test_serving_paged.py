@@ -16,8 +16,7 @@ Three contracts, mirroring the layered design:
 (c) **Serving parity** — the engine emits token-for-token what a
     reference that is not the engine emits (exact: lockstep ``generate``
     on a contiguous ``KVCache``; int8: a plain ``forward_step`` loop over
-    a hand-built B=1 int8 pool, :func:`paged_int8_stream`; × chunked AND
-    whole admission), a radix hit moves ZERO device KV bytes (span args
+    a hand-built B=1 int8 pool, :func:`paged_int8_stream`), a radix hit moves ZERO device KV bytes (span args
     + pool counters prove it, not just code inspection), admissions
     DEFER when the pool is over-subscribed instead of corrupting state,
     and a request that can never fit fails with a clear message.
@@ -471,16 +470,14 @@ def test_allocator_reserve_then_evict():
 
 
 @pytest.mark.parametrize("quantize", [False, True], ids=["exact", "int8"])
-@pytest.mark.parametrize("admission", ["chunked", "whole"])
-def test_paged_serving_matches_reference(params, quantize, admission):
-    """The engine (prefill, insert, per-tick mixed step, retire) emits
+def test_paged_serving_matches_reference(params, quantize):
+    """The engine (chunked prefill, per-tick mixed step, retire) emits
     token-for-token what a reference that is not the engine emits:
     lockstep ``generate`` on a contiguous ``KVCache`` (exact), a plain
     ``forward_step`` loop over a hand-built int8 pool (int8)."""
     prompt = _prompt(11)
     server = SlotServer(params, CFG, slots=2, cache_len=32,
-                        admission=admission, quantize=quantize,
-                        **CHUNK_KW, **PAGED_KW)
+                        quantize=quantize, **CHUNK_KW, **PAGED_KW)
     # One request per serve: the multi-request/occupancy machinery is
     # pinned by test_serving.py — this cell pins the reference parity.
     rep = server.serve([_req(0, prompt)], max_ticks=400)
@@ -612,8 +609,10 @@ def test_paged_cli_flags_parse():
     from tree_attention_tpu.utils.config import parse_args
 
     cfg = parse_args(["--mode", "serve", "--kv-layout", "paged",
+                      "--admission", "chunked",
                       "--kv-block", "32", "--kv-blocks", "64"])
-    assert not hasattr(cfg, "kv_layout")  # accepted, read nowhere
+    # accepted, read nowhere
+    assert not hasattr(cfg, "kv_layout") and not hasattr(cfg, "admission")
     assert cfg.kv_block == 32 and cfg.kv_blocks == 64
     cfg = parse_args(["--mode", "serve", "--host-blocks", "16",
                       "--kv-tiering", "off"])
@@ -623,15 +622,20 @@ def test_paged_cli_flags_parse():
         parse_args(["--mode", "serve", "--prefix-pool-blocks", "8"])
 
 
-def test_cli_contiguous_layout_is_a_parse_error(capsys):
-    """Serving has one KV layout: the flag survives for callers that
-    pass ``--kv-layout paged``, any other value exits with a message
-    that names the paged pool."""
+@pytest.mark.parametrize("flag, gone, kept", [
+    ("--kv-layout", "contiguous", "paged"),
+    ("--admission", "whole", "chunked"),
+])
+def test_cli_a_path_that_is_gone_is_a_parse_error(capsys, flag, gone, kept):
+    """Serving has one KV layout and one admission path: each flag
+    survives for callers that pass the one value left
+    (``benchmark/harness.py``), any other value exits at the parser, for
+    every model, with a message that names the value left."""
     from tree_attention_tpu.utils.config import parse_args
 
     with pytest.raises(SystemExit) as e:
-        parse_args(["--mode", "serve", "--kv-layout", "contiguous"])
+        parse_args(["--mode", "serve", flag, gone])
     assert e.value.code == 2
     said = capsys.readouterr().err.strip().splitlines()[-1]
-    assert "--kv-layout" in said and "'contiguous'" in said
-    assert "paged" in said
+    assert flag in said and f"'{gone}'" in said
+    assert kept in said

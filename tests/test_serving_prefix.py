@@ -4,8 +4,8 @@ The reuse contract has two halves:
 
 (a) **bit-exact parity** — a prefix-hit admission (the matched blocks
     referenced in place + suffix prefill) emits token-for-token what a
-    cold full prefill of the same prompt emits, on the exact AND int8 cache, under chunked AND whole
-    admission, single device and compat ``cpu_mesh``. The test configs
+    cold full prefill of the same prompt emits, on the exact AND int8
+    cache, single device and compat ``cpu_mesh``. The test configs
     align chunk and block boundaries so every compiled program a hit runs
     is literally the cold run's program over the same rows — any
     divergence is a real reuse bug, not float noise.
@@ -89,7 +89,7 @@ def _prompt(seed, n=13):
 
 @pytest.mark.parametrize("quantize", [False, True],
                          ids=["exact", "int8"])
-def test_prefix_hit_matches_cold_chunked(params, quantize):
+def test_prefix_hit_matches_cold(params, quantize):
     """Serve a prompt twice on one prefix-enabled server: the second
     admission must hit the pool (stats prove it) and emit exactly the
     first run's tokens — which are exactly a prefix-less server's."""
@@ -110,32 +110,6 @@ def test_prefix_hit_matches_cold_chunked(params, quantize):
     if not quantize:
         assert hit.results[0].tokens == _single_stream(params, prompt, 5,
                                                        cache_len=32)
-
-
-@pytest.mark.parametrize("quantize", [False, True],
-                         ids=["exact", "int8"])
-def test_prefix_hit_matches_cold_whole_admission(params, quantize):
-    """Same parity under blocking whole-prompt admission: the hit path
-    prefills only the suffix (exact: synchronous single-slot chunks
-    through the mixed-step family; int8: the staged path)."""
-    prompt = _prompt(2)
-    server = SlotServer(params, CFG, slots=2, cache_len=32,
-                        admission="whole", quantize=quantize,
-                        **CHUNK_KW, **PREFIX_KW)
-    cold = server.serve([_req(0, prompt)])
-    hit = server.serve([_req(1, prompt)])
-    assert hit.prefix["hits"] == 1
-    assert hit.results[0].tokens == cold.results[0].tokens
-    ref = SlotServer(params, CFG, slots=2, cache_len=32,
-                     admission="whole", quantize=quantize)
-    base = ref.serve([_req(0, prompt)])
-    if quantize:
-        # With the prefix cache on, whole int8 admission routes through
-        # the staged path; its parity with the legacy mini-cache path is
-        # the PR-3 chunked==whole contract, re-anchored here.
-        assert hit.results[0].tokens == base.results[0].tokens
-    else:
-        assert hit.results[0].tokens == base.results[0].tokens
 
 
 def test_prefix_full_block_prompt_keeps_one_suffix_token(params):

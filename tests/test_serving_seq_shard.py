@@ -16,8 +16,8 @@ the compat ``cpu_mesh(2)``:
   EXACTLY three collectives (asserted through the accounting counters,
   the same artifact the serving bench gates on);
 - end-to-end ``SlotServer`` parity: seq-sharded serving is
-  token-for-token the replicated oracle, exact and int8, chunked and
-  whole admission, including a randomized admit/retire/prefix-hit
+  token-for-token the replicated oracle, exact and int8, including a
+  randomized admit/retire/prefix-hit
   interleaving (the property the layout must survive: ANY allocation
   history maps to the same logical attention).
 
@@ -183,16 +183,10 @@ def _clone(r):
                    arrival_tick=r.arrival_tick)
 
 
-@pytest.mark.parametrize("quantize,admission", [
-    (False, "chunked"),
-    (True, "whole"),
-    pytest.param(True, "chunked", marks=pytest.mark.slow),
-    pytest.param(False, "whole", marks=pytest.mark.slow),
-])
-def test_seq_sharded_matches_replicated_oracle(params, mesh, quantize,
-                                               admission):
-    kw = dict(slots=2, cache_len=32, admission=admission,
-              quantize=quantize, **CHUNK_KW, **PAGED_KW)
+@pytest.mark.parametrize("quantize", [False, True], ids=["exact", "int8"])
+def test_seq_sharded_matches_replicated_oracle(params, mesh, quantize):
+    kw = dict(slots=2, cache_len=32, quantize=quantize, **CHUNK_KW,
+              **PAGED_KW)
     reqs = [_req(0, _prompt(11))]
     rep = SlotServer(params, CFG, mesh=mesh, **kw)
     seq = SlotServer(params, CFG, mesh=mesh, kv_shard="seq", **kw)
@@ -234,8 +228,7 @@ def _interleaving_case(params, mesh, *, seed):
             max_new_tokens=int(rng.integers(2, 5)),
             arrival_tick=int(rng.integers(0, 5)),
         ))
-    kw = dict(slots=2, cache_len=32, admission="chunked",
-              **CHUNK_KW, **PAGED_KW, **PREFIX_KW)
+    kw = dict(slots=2, cache_len=32, **CHUNK_KW, **PAGED_KW, **PREFIX_KW)
     rep = SlotServer(params, CFG, mesh=mesh, **kw)
     seq = SlotServer(params, CFG, mesh=mesh, kv_shard="seq", **kw)
     assert _serve_tokens(seq, reqs) == _serve_tokens(rep, reqs)

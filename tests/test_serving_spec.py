@@ -3,7 +3,7 @@
 The hard contract: **token-for-token parity with greedy non-speculative
 decode** — whatever the drafter proposes, however much gets rejected, the
 committed stream is identical; speculation may only change *when* tokens
-arrive, never *which*. Pinned here across exact/int8 × chunked/whole ×
+arrive, never *which*. Pinned here across exact/int8 ×
 single-device/compat-cpu_mesh, with free (n-gram), tree, and adversarial
 oracle drafters.
 
@@ -606,12 +606,8 @@ def _lookup_walk(prompt, stream, draft_k):
     return proposed, accepted
 
 
-@pytest.mark.parametrize("kw", [
-    {},                                           # chunked exact
-    {"quantize": True},                           # chunked int8
-    {"admission": "whole"},
-    {"quantize": True, "admission": "whole"},
-], ids=["paged", "paged-int8", "whole", "whole-int8"])
+@pytest.mark.parametrize("kw", [{}, {"quantize": True}],
+                         ids=["paged", "paged-int8"])
 def test_spec_parity_ngram_all_combos(params, kw):
     """A workload the n-gram drafter MUST be accepted on, made by the
     model itself: the non-speculative engine's greedy continuation of
@@ -681,12 +677,12 @@ def test_spec_parity_oracle_chain_and_tree(params):
                 assert rep.spec["acceptance_rate"] == 1.0
 
 
-def test_spec_oracle_tree_int8_and_whole(params):
+def test_spec_oracle_tree_int8(params):
     prompts = {0: LOOP_PROMPT, 1: ALT_PROMPT}
-    for kw in ({"quantize": True}, {"admission": "whole"}):
-        refs = _ref_tokens(params, **kw)
-        d = OracleDrafter(prompts, refs, wrong_every=2, tree=True)
-        _assert_parity(params, kw, d)
+    kw = {"quantize": True}
+    refs = _ref_tokens(params, **kw)
+    d = OracleDrafter(prompts, refs, wrong_every=2, tree=True)
+    _assert_parity(params, kw, d)
 
 
 # ---------------------------------------------------------------------------
@@ -795,8 +791,9 @@ def test_randomized_accept_reject_cache_bytes_property(params):
       not commit;
     - bytes inside the committed prefix equal sequential stepping to
       float-association tolerance (a Tq=k chunk and k Tq=1 steps batch
-      the same row math differently — the chunked==whole contract is
-      token-level for the same reason);
+      the same row math differently — the parity of chunked
+      admission with a whole-prompt prefill is token-level for the
+      same reason);
     - the committed token stream is the reference stream by
       construction of the accept rule (asserted via the argmax walk).
     """
@@ -974,6 +971,7 @@ def test_spec_metrics_flight_and_report(params):
         assert sum(r["spec_verify"]["accepted"] for r in spec_recs) == acc
     finally:
         FLIGHT.disarm()
+        FLIGHT.clear()
         obs.REGISTRY.disable()
         obs.REGISTRY.reset()
 
@@ -994,6 +992,7 @@ def test_spec_disabled_off_path_untouched(params):
                    for r in FLIGHT.snapshot()["records"])
     finally:
         FLIGHT.disarm()
+        FLIGHT.clear()
 
 
 # ---------------------------------------------------------------------------

@@ -486,6 +486,7 @@ def test_a_reused_slot_serves_what_a_fresh_engine_serves(ref, model):
         text = obs.REGISTRY.to_prometheus()
     finally:
         FLIGHT.disarm()
+        FLIGHT.clear()
         obs.REGISTRY.disable()
         obs.REGISTRY.reset()
     by_uid = {r.uid: r.tokens for r in rep.results}
@@ -616,7 +617,6 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer(ref, model):
     (dict(host_blocks=4, prefix_cache=True, prefix_block=BLOCK),
      "state pool.*host tier"),
     (dict(speculate=True), "state pool.*recurrent state"),
-    (dict(admission="whole"), "state pool.*whole-prompt admission"),
     (dict(prefix_cache=True, prefix_block=BLOCK),
      "state pool.*prefix cache"),
 ])

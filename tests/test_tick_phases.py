@@ -202,18 +202,15 @@ def test_trace_events_hold_the_phases_inside_the_tick_span(served):
 
 
 @pytest.mark.parametrize("kw, want", [
-    (dict(admission="whole"), {"awaits", "decode"}),
     (dict(prefill_chunk=CHUNK, quantize=True), {"staged", "decode"}),
     (dict(prefill_chunk=CHUNK, speculate=True, draft_k=3), {"verify"}),
-], ids=["whole", "staged", "verify"])
+], ids=["staged", "verify"])
 def test_the_other_tick_kinds(params, kw, want):
     server = SlotServer(params, CFG, **ENGINE_KW, **kw)
     _, recs = _recorded(server, _requests(2, 9, 4, key=33))
     assert {r["kind"] for r in recs} == want
     for r in recs:
         assert r["rows_useful"] <= r["rows_computed"] == SLOTS * r["tq"]
-        if r["kind"] == "awaits":
-            assert r["tq"] == 0 and "dispatch" not in dict(r["phases"])
         if r["kind"] == "staged":
             # The stage programs run under 'pack'; tq is the decode
             # program's, if one ran in the same tick.

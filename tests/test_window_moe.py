@@ -514,6 +514,7 @@ def test_a_long_request_never_waits_on_window_blocks(ref, model):
         text = obs.REGISTRY.to_prometheus()
     finally:
         FLIGHT.disarm()
+        FLIGHT.clear()
         obs.REGISTRY.disable()
         obs.REGISTRY.reset()
     by_uid = {r.uid: r for r in rep.results}
@@ -619,7 +620,6 @@ def test_a_hit_whose_window_blocks_were_evicted_falls_back_and_is_exact(
     (dict(kv_shard="seq"), "sequence-sharded"),
     (dict(host_blocks=4), "host tier"),
     (dict(speculate=True), "given back"),
-    (dict(admission="whole"), "whole-prompt admission"),
 ])
 def test_engine_refuses_what_the_window_pools_do_not_carry(model, kw, named):
     _, _, tcfg, params = model

@@ -62,10 +62,8 @@ _SCOPE = (
 SLOTSERVER_DONATIONS: Dict[str, Tuple[int, ...]] = {
     "_mixed": (6,),
     "_packed": (9,),
-    "_insert": (0, 1, 2),
     "_stage_chunk": (3,),
     "_stage_final": (3, 4, 5, 6),
-    "_whole_suffix": (7,),
     "_spec_lin": (8,),
     "_spec_tree": (10,),
     "_compact": (0,),

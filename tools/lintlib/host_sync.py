@@ -60,9 +60,8 @@ _ZERO_ARG_SYNC_METHODS = {"item", "block_until_ready"}
 
 #: Engine program families whose results live on device.
 _DEVICE_FAMILIES = {
-    "self._mixed", "self._prefill", "self._insert", "self._stage_chunk",
-    "self._stage_final", "self._whole_suffix", "self._spec_lin",
-    "self._spec_tree", "self._compact",
+    "self._mixed", "self._stage_chunk", "self._stage_final",
+    "self._spec_lin", "self._spec_tree", "self._compact",
 }
 _DEVICE_ATTRS = {"self.tok", "self.cache", "self._key"}
 

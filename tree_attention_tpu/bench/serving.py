@@ -132,10 +132,10 @@ def _trace_cell(
 ) -> Dict[str, Any]:
     """One engine run over the synthetic trace.
 
-    The jit compiles (one step program + one prefill program per prompt
-    bucket) are paid by a warmup serve on the SAME server — a jitted bound
-    method caches per instance, so a fresh server would recompile — and the
-    timed run then measures the loop, not the compiler."""
+    The jit compiles (one tick program per Tq bucket) are paid by a
+    warmup serve on the SAME server — a jitted bound method caches per
+    instance, so a fresh server would recompile — and the timed run then
+    measures the loop, not the compiler."""
     server = SlotServer(params, cfg, slots=slots, cache_len=cache_len)
     trace = synthetic_trace(**trace_kw)
     buckets = sorted({_bucket(len(r.prompt), cache_len) for r in trace})
@@ -258,7 +258,7 @@ def bench_serving(
 
 
 # ---------------------------------------------------------------------------
-# ISSUE 3: long-prompt flood — chunked vs whole-prompt admission
+# Slopes of one mixed tick and of one whole-prompt B=1 prefill
 # ---------------------------------------------------------------------------
 
 
@@ -308,9 +308,8 @@ def slope_whole_prefill(
     iters: int = 3,
     repeats: int = 3,
 ):
-    """chain_slope the legacy blocking admission's unit of stall: one
-    whole-prompt B=1 prefill at its prompt bucket (every live slot waits
-    this long per admission under ``admission='whole'``)."""
+    """chain_slope one whole-prompt B=1 prefill at its prompt bucket:
+    what a prefix hit of that length saves an admission."""
     cache = init_cache(cfg, 1, bucket)
     tok0 = jnp.zeros((1,), jnp.int32)
 
@@ -323,202 +322,6 @@ def slope_whole_prefill(
         step, tok0, n_small=n_small, n_large=n_large,
         iters=iters, repeats=repeats,
     )
-
-
-def _flood_trace(
-    *,
-    slots: int,
-    wave_size: int,
-    short_len: int,
-    short_new: int,
-    long_len: int,
-    long_new: int,
-    n_waves: int,
-    wave_gap: int,
-    vocab_size: int,
-    seed: int,
-) -> List[Request]:
-    """``slots - wave_size`` short requests queued at start keep the
-    server busy decoding; ``n_waves`` waves of ``wave_size`` long prompts
-    then arrive into the open slots — the head-of-line shape chunked
-    admission exists for. The shorts' token budget spans the whole flood,
-    so every long admission lands while the batch is decoding and its
-    stall shows up in the shorts' inter-token gaps (under whole-prompt
-    admission a wave stalls every live slot for ``wave_size`` back-to-back
-    prefills; under chunked admission the wave's chunks share the same
-    mixed ticks)."""
-    rng = np.random.default_rng(seed)
-    reqs = [
-        Request(
-            uid=i,
-            prompt=rng.integers(0, vocab_size, size=short_len).astype(
-                np.int32),
-            max_new_tokens=short_new,
-            arrival_tick=0,
-        )
-        for i in range(slots - wave_size)
-    ]
-    uid = slots - wave_size
-    for w in range(n_waves):
-        for _ in range(wave_size):
-            reqs.append(Request(
-                uid=uid,
-                prompt=rng.integers(0, vocab_size, size=long_len).astype(
-                    np.int32),
-                max_new_tokens=long_new,
-                arrival_tick=4 + w * wave_gap,
-            ))
-            uid += 1
-    return reqs
-
-
-def bench_serving_flood(
-    *,
-    slots: int = 2,
-    cache_len: int = 512,
-    short_len: int = 16,
-    short_new: int = 140,
-    long_len: int = 260,
-    long_new: int = 1,
-    wave_size: int = 1,
-    n_waves: int = 8,
-    wave_gap: int = 16,
-    prefill_chunk: int = 16,
-    repeats: int = 3,
-    cfg: Optional[TransformerConfig] = None,
-    seed: int = 0,
-) -> Dict[str, Any]:
-    """The stall-free record: p95 inter-token latency under a long-prompt
-    flood, chunked vs whole-prompt admission.
-
-    Two measurements, same conclusion:
-
-    - **Slope** — chain_slope (repeats >= 3, min-stat) prices the three
-      per-tick programs: the pure decode tick, the mixed tick carrying one
-      ``prefill_chunk``-token chunk, and the whole-prompt B=1 prefill at
-      its bucket. ``stall_ratio`` = whole-prefill time / mixed-tick time:
-      the deterministic factor by which one admission's worst-case pause
-      shrinks when the prompt rides the tick in chunks.
-    - **Trace** — the real engine over the identical flood
-      (:func:`_flood_trace`) per admission mode, ``repeats`` timed runs on
-      a warmed server, min-over-repeats p95/p50 of the pooled inter-token
-      gaps (the same noise discipline as the slope protocol) plus
-      aggregate tokens/sec. ``tbt_p95_improvement`` is the headline:
-      whole-admission p95 TBT over chunked p95 TBT.
-
-    CPU proxy by design: the measured structure (a prompt-length stall vs
-    a chunk-length one) transfers; absolute seconds do not.
-    """
-    cfg = cfg or serving_model_config(max_seq_len=cache_len)
-    params = init_params(jax.random.PRNGKey(seed), cfg)
-    trace_kw = dict(
-        slots=slots, wave_size=wave_size, short_len=short_len,
-        short_new=short_new, long_len=long_len, long_new=long_new,
-        n_waves=n_waves, wave_gap=wave_gap, vocab_size=cfg.vocab_size,
-        seed=seed + 1,
-    )
-    bucket = _bucket(long_len, cache_len)
-
-    # --- slope: the three per-tick programs, blessed harness ---
-    lens = _ragged_lengths(slots, cache_len)
-    np.minimum(lens, cache_len - prefill_chunk, out=lens)
-    with obs.span("bench_serving_flood:slope", cat="bench"):
-        s_decode = slope_decode_step(
-            params, cfg, slots=slots, cache_len=cache_len, lengths=lens
-        )
-        s_mixed = slope_mixed_tick(
-            params, cfg, slots=slots, cache_len=cache_len,
-            chunk=prefill_chunk, lengths=lens,
-        )
-        s_whole = slope_whole_prefill(params, cfg, bucket=bucket)
-    slope_rec = {
-        "us_per_decode_tick": round(s_decode.per_step * 1e6, 1),
-        "us_per_mixed_chunk_tick": round(s_mixed.per_step * 1e6, 1),
-        "us_per_whole_prefill": round(s_whole.per_step * 1e6, 1),
-        "prefill_chunk": prefill_chunk,
-        "prompt_bucket": bucket,
-        # One admission's worst-case pause for the live slots, whole vs
-        # chunked: the whole prefill blocks a full prompt bucket; chunked
-        # blocks one mixed tick.
-        "stall_ratio": round(s_whole.per_step / s_mixed.per_step, 2),
-        "spread_pct": round(
-            max(s_decode.spread_pct, s_mixed.spread_pct,
-                s_whole.spread_pct), 1
-        ),
-    }
-
-    # --- trace: the real engine, per admission mode ---
-    # The SLO monitor turns the same runs into a goodput comparison —
-    # chunked admission's whole pitch is SLO attainment under flood, so
-    # the record carries it. CPU-proxy-sized targets, measured on this
-    # box: whole-admission worst gaps reach ~18-30 ms when a request's
-    # life overlaps a flood prefill, chunked stays <= ~6 ms — 10 ms sits
-    # between the two populations, so goodput separates the modes the
-    # way p95 TBT does (the *ratio* is the transferable part, like every
-    # flood number; absolute goodput on a contended box is noise).
-    slo_kw = dict(slo_ttft=2.0, slo_tbt=0.01)
-
-    def run_mode(admission: str) -> Dict[str, Any]:
-        server = SlotServer(
-            params, cfg, slots=slots, cache_len=cache_len,
-            prefill_chunk=prefill_chunk, admission=admission, **slo_kw,
-        )
-        server.serve(_flood_trace(**trace_kw))  # warmup: pays the compiles
-        runs = []
-        for _ in range(repeats):
-            # Each repeat's goodput is ITS run's verdicts: the window is
-            # larger than one flood, so without a reset the warmup's
-            # compile-stalled requests would depress every repeat.
-            server.slo.reset()
-            report = server.serve(_flood_trace(**trace_kw))
-            runs.append(report.as_dict())
-        return {
-            "repeats": runs,
-            "tbt_p95_s": min(r["tbt_p95_s"] for r in runs),
-            "tbt_p50_s": min(r["tbt_p50_s"] for r in runs),
-            "ttft_p95_s": min(r["ttft_p95_s"] for r in runs),
-            "tokens_per_sec": max(r["tokens_per_sec"] for r in runs),
-            # Best-over-repeats, same noise discipline as the latencies.
-            "goodput": max(
-                r.get("slo", {}).get("goodput", 0.0) for r in runs
-            ),
-        }
-
-    trace_rec: Dict[str, Any] = {}
-    with obs.span("bench_serving_flood:trace", cat="bench"):
-        for admission in ("whole", "chunked"):
-            trace_rec[admission] = run_mode(admission)
-    whole_p95 = trace_rec["whole"]["tbt_p95_s"]
-    chunk_p95 = trace_rec["chunked"]["tbt_p95_s"]
-    if chunk_p95 > 0:
-        trace_rec["tbt_p95_improvement"] = round(whole_p95 / chunk_p95, 2)
-    whole_tps = trace_rec["whole"]["tokens_per_sec"]
-    if whole_tps > 0:
-        trace_rec["tokens_per_sec_ratio"] = round(
-            trace_rec["chunked"]["tokens_per_sec"] / whole_tps, 3
-        )
-    trace_rec["goodput_slo"] = slo_kw
-
-    log.info(
-        "flood: stall ratio %(sr).1fx (slope); trace p95 TBT %(w).4fs "
-        "whole vs %(c).4fs chunked -> %(i)sx; tok/s ratio %(t)s",
-        dict(sr=slope_rec["stall_ratio"], w=whole_p95, c=chunk_p95,
-             i=trace_rec.get("tbt_p95_improvement", "?"),
-             t=trace_rec.get("tokens_per_sec_ratio", "?")),
-    )
-    return {
-        "workload": {
-            "model": {
-                "d_model": cfg.d_model, "n_layers": cfg.n_layers,
-                "heads": cfg.n_heads, "kv_heads": cfg.n_kv_heads,
-                "vocab": cfg.vocab_size, "dtype": str(cfg.dtype),
-            },
-            "cache_len": cache_len,
-            "flood": {k: v for k, v in trace_kw.items() if k != "seed"},
-        },
-        "slope": slope_rec,
-        "trace": trace_rec,
-    }
 
 
 # ---------------------------------------------------------------------------

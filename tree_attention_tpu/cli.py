@@ -566,7 +566,6 @@ _POOL_REFUSALS = (
      "layer's freed blocks cannot come back)"),
     (lambda c: c.serve_disagg,
      "--serve-disagg (the handoff of that pool's arrays)"),
-    (lambda c: c.admission != "chunked", "--admission whole"),
 )
 
 
@@ -627,11 +626,6 @@ def build_serve_engine(cfg: RunConfig, mesh, *, model=None,
                 "--serve-disagg and --serve-fleet are exclusive (a "
                 "disaggregated fleet tier is not built yet; run one "
                 "disaggregated pair per process)"
-            )
-        if cfg.admission != "chunked":
-            raise SystemExit(
-                "--serve-disagg requires --admission chunked (the "
-                "prefill pool is a chunked-prefill worker)"
             )
         if cfg.prefill_slots < 1:
             raise SystemExit("--prefill-slots must be >= 1")
@@ -763,7 +757,6 @@ def build_serve_engine(cfg: RunConfig, mesh, *, model=None,
         temperature=cfg.temperature, top_k=cfg.top_k, seed=cfg.seed + 2,
         prefill_chunk=cfg.prefill_chunk,
         prefill_budget=cfg.prefill_budget,
-        admission=cfg.admission,
         slo_ttft=cfg.slo_ttft,
         slo_tbt=cfg.slo_tbt,
         prefix_cache=cfg.prefix_cache,
@@ -784,7 +777,7 @@ def build_serve_engine(cfg: RunConfig, mesh, *, model=None,
             from tree_attention_tpu.serving.disagg import DisaggServer
 
             disagg_kw = {k: v for k, v in engine_kw.items()
-                         if k not in ("slots", "admission")}
+                         if k != "slots"}
             return DisaggServer(
                 params, tcfg, prefill_slots=cfg.prefill_slots,
                 decode_slots=decode_slots, **disagg_kw,
@@ -947,7 +940,6 @@ def _run_serve(cfg: RunConfig, mesh) -> int:
         "mode": "serve",
         "slots": cfg.slots,
         "cache_len": cache_len,
-        "admission": cfg.admission,
         "prefill_chunk": cfg.prefill_chunk,
         **({"disagg": {"prefill_slots": cfg.prefill_slots,
                        "decode_slots": decode_slots}}

@@ -1115,9 +1115,9 @@ def paged_insert_slot(
 ) -> Union[PagedKVCache, PagedQuantKVCache]:
     """Place a B=1 prefilled cache's rows into one slot's mapped blocks.
 
-    What whole-prompt and int8 staged admission end with: ``k_rows`` /
-    ``v_rows`` are ``(L, 1, Hkv, T, D)`` (a mini/staging cache, possibly
-    already int8), token positions ``[lo, plen)`` scatter through the
+    What int8 staged admission ends with: ``k_rows`` / ``v_rows`` are
+    ``(L, 1, Hkv, T, D)`` (the staging cache's rows, possibly already
+    int8), token positions ``[lo, plen)`` scatter through the
     slot's table row (``plen``/``lo`` may be traced; rows outside drop),
     the slot's ``length`` becomes ``plen``, and — for a quantized cache —
     the prompt blocks' per-BLOCK scales (``(L, nb, Hkv)``, from

@@ -265,8 +265,7 @@ class DisaggServer:
         common = dict(
             cache_len=cache_len, mesh=mesh, quantize=quantize,
             quant_kernel=quant_kernel, temperature=temperature,
-            top_k=top_k,
-            admission="chunked", slo_ttft=slo_ttft, slo_tbt=slo_tbt,
+            top_k=top_k, slo_ttft=slo_ttft, slo_tbt=slo_tbt,
             slo_window=slo_window, kv_block=kv_block,
             kv_shard=kv_shard,
             block_pool=self.pool, prefix_index=self.prefix_index,

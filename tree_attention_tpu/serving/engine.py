@@ -38,12 +38,7 @@ loop already pays (one host sync per tick, total).
 
 Variants:
 
-- ``admission="whole"`` keeps the legacy blocking path — the whole prompt
-  prefills into a prompt-bucket-sized B=1 mini cache and is inserted into
-  the slot in one shot. Its first sampled token ALSO rides the per-tick
-  fetch (the slot sits out one step while the token parks in the device
-  token vector).
-- ``quantize=True`` serves from an int8 cache. Chunked admission then runs
+- ``quantize=True`` serves from an int8 cache. Admission then runs
   its chunks against ONE preallocated exact **staging** cache (int8 rows
   cannot hold exact prefill activations), and at final-chunk completion the
   staged prefix is masked, quantized block by block under each block's
@@ -93,7 +88,7 @@ Variants:
   capacity. Greedy only: committed tokens are token-for-token identical
   to non-speculative decode, the hard parity contract
   (``tests/test_serving_spec.py`` pins it across exact/int8 ×
-  chunked/whole × device/mesh).
+  device/mesh).
 
 - **Robustness lifecycle** (ISSUE 10): ``serve()`` takes a pre-built
   trace OR a live :class:`RequestSource`; each tick starts with a
@@ -116,10 +111,10 @@ Variants:
   (the token vector is carried on the device), so the host's work and the
   fetch's round trip run under the device's. A slot that turns out to
   have left (EOS, cancel, deadline) has one row computed and thrown away;
-  speculation, token-tree and fork families, staged int8 prefill and
-  whole admission keep the synchronous order, chosen tick by tick from
-  engine state (``_plan_tick``; the flight record's ``ahead`` /
-  ``sync_reason``, ``serving_ticks_dispatched_ahead_total``).
+  speculation, token-tree and fork families and staged int8 prefill
+  keep the synchronous order, chosen tick by tick from engine state
+  (``_plan_tick``; the flight record's ``ahead`` / ``sync_reason``,
+  ``serving_ticks_dispatched_ahead_total``).
 
 Works on one device and on a mesh: the pool is replicated (flash/Pallas
 paths) or, with ``kv_shard="seq"``, range-partitioned over the mesh's
@@ -811,15 +806,12 @@ def serving_params(params: Params) -> Params:
     return served
 
 
-def _bucket(n: int, cap: int, floor: int = 8, multiple: int = 1) -> int:
-    """Pad a prompt length up to a power-of-two bucket (bounded compiles:
-    one prefill program per bucket, not per distinct prompt length),
-    rounded to ``multiple`` (a seq-sharded mini cache must divide over the
-    mesh) and capped at ``cap``."""
+def _bucket(n: int, cap: int, floor: int = 8) -> int:
+    """Pad a count up to a power-of-two bucket (bounded compiles: one
+    program per bucket, not per distinct count), capped at ``cap``."""
     b = floor
     while b < n:
         b *= 2
-    b = -(-b // max(multiple, 1)) * max(multiple, 1)
     return min(b, cap)
 
 
@@ -835,9 +827,8 @@ class SlotServer:
         ``kv_shard="seq"`` the pool is sharded over its sequence axis and
         the ragged decode step runs the tree merge per tick.
       quantize: serve from an int8 pool — each request prefills exactly
-        (staged, under chunked admission) then quantizes its blocks, each
-        under its own frozen scales (the quantize-after-prefill contract,
-        per block).
+        (staged) then quantizes its blocks, each under its own frozen
+        scales (the quantize-after-prefill contract, per block).
       quant_kernel: which q8 kernel decode ticks run (``"q8q"`` / ``"q8"``).
       temperature / seed: sampling (0 = greedy, the deterministic default).
       prefill_chunk: max prompt tokens one tick may write for one slot
@@ -851,8 +842,6 @@ class SlotServer:
         chunk group, fixed at build), so the budget is what a tick with
         prompt work costs every live slot in inter-token latency: a
         larger one admits prompts faster and stretches those ticks.
-      admission: ``"chunked"`` (default — stall-free, fused into the tick)
-        or ``"whole"`` (legacy blocking whole-prompt prefill + insert).
       slo_ttft / slo_tbt / slo_window: the sliding-window SLO monitor's
         targets (seconds) and sample window — a retired request counts
         toward goodput iff its TTFT and worst inter-token gap both met
@@ -946,7 +935,6 @@ class SlotServer:
         seed: int = 0,
         prefill_chunk: int = 256,
         prefill_budget: Optional[int] = None,
-        admission: str = "chunked",
         slo_ttft: float = 1.0,
         slo_tbt: float = 0.2,
         slo_window: int = 1024,
@@ -966,10 +954,6 @@ class SlotServer:
     ):
         if slots < 1:
             raise ValueError(f"slots must be >= 1, got {slots}")
-        if admission not in ("chunked", "whole"):
-            raise ValueError(
-                f"admission must be 'chunked' or 'whole', got {admission!r}"
-            )
         if kv_shard not in ("replicated", "seq"):
             raise ValueError(
                 f"kv_shard must be 'replicated' or 'seq', got {kv_shard!r}"
@@ -1003,8 +987,6 @@ class SlotServer:
                  "a sequence-sharded pool (kv_shard='seq')"),
                 (bool(host_blocks), "the host tier (host_blocks > 0)"),
                 (speculate, f"speculation ({why_not})"),
-                (admission != "chunked",
-                 "whole-prompt admission (admission='whole')"),
                 (cfg.cache_kind == "window" and (
                     block_pool is not None or prefix_index is not None),
                  "disaggregation (a shared block_pool / prefix_index: the "
@@ -1054,7 +1036,6 @@ class SlotServer:
                     f"got {draft_k}"
                 )
         self.draft_k = draft_k
-        self.admission = admission
         self.prefill_chunk = min(prefill_chunk, cache_len)
         self.prefill_budget = (
             self.prefill_chunk if prefill_budget is None else prefill_budget
@@ -1128,13 +1109,12 @@ class SlotServer:
         self._fs_kw = dict(kw)
         if kv_shard == "seq":
             # Only the batched per-tick steps run on the sharded pool;
-            # the B=1 prefill/staging programs below use CONTIGUOUS
-            # mini-caches and must not see the flag.
+            # the B=1 staging programs below use a CONTIGUOUS cache and
+            # must not see the flag.
             self._fs_kw["kv_shard"] = "seq"
-        # B=1 programs (the legacy mini-cache prefill and the quantized
-        # staging cache) cannot shard over a data axis (1 does not divide
-        # it) — and need no data parallelism anyway; the batched per-tick
-        # step keeps the full mesh spec.
+        # B=1 programs (the quantized staging cache's) cannot shard over a
+        # data axis (1 does not divide it) — and need no data parallelism
+        # anyway; the batched per-tick step keeps the full mesh spec.
         self._prefill_kw = (
             dict(kw, data_axis=None) if mesh is not None else {}
         )
@@ -1397,24 +1377,12 @@ class SlotServer:
                     "window_need": self._win.hit_blocks}),
             )
 
-        # Reusable host scratch for the legacy whole-prompt admission's
-        # padded prompt matrix, one per bucket — the chunked path never
-        # allocates per admit, and neither should this one.
-        self._whole_scratch: Dict[int, np.ndarray] = {}
-
-        # Quantized + chunked admission stages the exact prefill in ONE
+        # Quantized admission stages the exact prefill in ONE
         # preallocated B=1 cache (int8 slots cannot hold exact chunk
         # activations; allocating per admit is the cost this engine
-        # removes). One prompt stages at a time. With the prefix cache on,
-        # WHOLE int8 admission routes through the same staging cache too
-        # (a hit's matched blocks land there dequantized, for the
-        # suffix to attend), so it is allocated for that combination as
-        # well.
-        self._staged_prefill = quantize and admission == "chunked"
-        self._needs_staging = quantize and (
-            admission == "chunked" or self._prefix is not None
-        )
-        if self._needs_staging:
+        # removes). One prompt stages at a time.
+        self._staged_prefill = quantize
+        if self._staged_prefill:
             self._staging: KVCache = init_cache(
                 cfg, 1, cache_len, **self._prefill_kw
             )
@@ -1429,8 +1397,8 @@ class SlotServer:
 
         # jax.jit caches one executable per Tq bucket for the mixed step
         # (pure-decode ticks are the Tq=1 bucket, chunk ticks one of a
-        # small power-of-two set) and per prompt bucket for the legacy
-        # prefill — bounded compiles for every occupancy/chunk mixture.
+        # small power-of-two set) — bounded compiles for every
+        # occupancy/chunk mixture.
         # The jit caches are per INSTANCE (bound methods), so a fresh
         # server recompiles — bench/serving.py warms the same server it
         # times. The tick loop reassigns self.cache/self.tok from each
@@ -1446,23 +1414,13 @@ class SlotServer:
             self._noting("_mixed", self._mixed_fn), donate_argnums=(6,))
         self._packed = jax.jit(
             self._noting("_packed", self._packed_fn), donate_argnums=(9,))
-        self._prefill = jax.jit(self._prefill_fn)
-        self._insert = jax.jit(self._insert_fn, donate_argnums=(0, 1, 2))
-        if self._needs_staging:
+        if self._staged_prefill:
             self._stage_chunk = jax.jit(
                 self._stage_chunk_fn, donate_argnums=(3,)
             )
             self._stage_final = jax.jit(
                 self._stage_final_fn, donate_argnums=(3, 4, 5, 6)
             )
-        if self._prefix is not None:
-            # Whole-admission prefix hits prefill only the suffix — device-
-            # built single-slot chunks through the packed tick's step
-            # (every other slot rides inert with its parked token intact).
-            self._whole_suffix = jax.jit(
-                self._whole_suffix_fn, donate_argnums=(7,)
-            )
-
         # Speculative decoding (ISSUE 8): the host drafter, the per-slot
         # committed-length ledger (the rollback truth — the device length
         # over-counts by the rejected rows until the next step's reset),
@@ -1619,7 +1577,7 @@ class SlotServer:
         the program writes). ``chunk``: a packed program's chunk group
         ``(slots, counts)`` beside one row a slot; else one group of
         ``tq`` rows a slot. A slot no program here has written since a
-        side program moved it (a whole admission's insert) is counted at a
+        side program moved it (a staged int8 prompt's insert) is counted at a
         stale length until its next decode row."""
         pre = np.where(reset, reset_val, self._kv_len)
         if decode_rows is not None:
@@ -1809,26 +1767,6 @@ class SlotServer:
             last, dec_tok, emit, keys, temp, topk, idx, lp_vec, stats)
         return nxt, lp_out, fused, last, new_cache
 
-    def _whole_suffix_fn(self, params, rows, slot, n, last, first, start,
-                         cache, tok_vec, keys, temp, topk, idx, lp_vec):
-        """One suffix chunk of a whole-admission prefix hit: slot ``slot``
-        consumes ``n`` of the ``rows`` (a padded ``(Tq,)`` chunk of its
-        prompt) as the one member of a packed step's chunk group while
-        every other slot rides inert — their parked tokens pass through
-        untouched because the held tokens are the DEVICE token vector (an
-        ``await`` slot's first token only exists there until the next
-        batched fetch). On the FIRST suffix chunk the slot's length resets
-        to ``start`` (= the matched prefix length): the one place the
-        device learns the hit. Emits the first sampled token into the
-        token vector on the final chunk."""
-        one_hot = jnp.arange(self.slots, dtype=jnp.int32) == slot
-        return self._packed_fn(
-            params, rows[None, :], jnp.reshape(slot, (1,)),
-            jnp.reshape(n, (1,)).astype(jnp.int32), tok_vec,
-            jnp.zeros((self.slots,), jnp.int32), one_hot & first,
-            jnp.where(one_hot, start, 0).astype(jnp.int32), one_hot & last,
-            cache, keys, temp, topk, idx, lp_vec)
-
     def _sibling_first_fn(self, tok_vec, lp_vec, row, key, temp, topk,
                           slot):
         """Park a forked sibling's FIRST token: sample from the parent's
@@ -1873,9 +1811,10 @@ class SlotServer:
         verify extras —
 
         - row 0 of each slot comes from the DEVICE token vector when
-          ``use_dev0`` (a whole-admission ``await`` slot's parked first
-          token only exists there); every other row from the host-built
-          matrix (the host knows every committed/replayed token);
+          ``use_dev0`` (an ``await`` slot's parked first token, a staged
+          int8 prompt's, only exists there); every other row from the
+          host-built matrix (the host knows every committed/replayed
+          token);
         - ``depth``/``bits`` (tree ticks only): packed tree rows take
           RoPE position ``length + depth[row]`` and attend under the
           per-slot ancestor mask instead of row-order causal — chain
@@ -1974,71 +1913,10 @@ class SlotServer:
         slots with n=0 are bit-identically untouched."""
         return compact_decode_window(cache, start, src, n)
 
-    def _prefill_fn(self, params, prompt, plen, key, temp, topk):
-        """Legacy whole-prompt admission: prefill one request into a fresh
-        prompt-bucket-sized B=1 cache (NOT a full-capacity one — the
-        bucket bounds both the allocation and the attention work).
-
-        ``prompt`` is padded to its bucket; rows at positions >= plen are
-        pad garbage, so after the step they are zeroed — the inserted slot
-        (and, under ``quantize``, its blocks' frozen scales) is then
-        bit-identical to an unpadded prefill, and one compile serves the
-        whole bucket.
-        """
-        cfg = self.cfg
-        bucket = prompt.shape[1]
-        shape = (cfg.cache_layers, 1, cfg.n_kv_heads, bucket, cfg.d_head)
-        mini = KVCache(
-            k=jnp.zeros(shape, cfg.dtype),
-            v=jnp.zeros(shape, cfg.dtype),
-            length=jnp.zeros((1,), jnp.int32),
-        )
-        logits, mini = forward_step(params, prompt, mini, cfg,
-                                    **self._prefill_kw)
-        valid = (
-            jnp.arange(bucket, dtype=jnp.int32) < plen
-        )[None, None, None, :, None]
-        k = jnp.where(valid, mini.k, 0)
-        v = jnp.where(valid, mini.v, 0)
-        last = lax.dynamic_index_in_dim(logits, plen - 1, axis=1,
-                                        keepdims=False)  # (1, V)
-        tok_s, lp_s = self._sample_emit(
-            last, key[None], jnp.reshape(temp, (1,)),
-            jnp.reshape(topk, (1,)), jnp.zeros((1,), jnp.int32),
-        )
-        tok, lp = tok_s[0], lp_s[0]
-        if self.quantize:
-            # Per-BLOCK quantization (ISSUE 13): each prompt block's
-            # scale is its own absmax, so the published blocks are
-            # self-contained and shareable through the radix tree.
-            kq, vq, ks, vs = quantize_paged_blocks(
-                k, v, self.kv_block, plen
-            )
-            return (kq, vq, ks, vs, tok, lp), last
-        return (k, v, tok, lp), last
-
-    def _insert_fn(self, cache, tok_vec, lp_vec, slot, payload, plen):
-        """Place a bucket-sized prefilled B=1 cache into slot ``slot`` of
-        the batch cache (k/v rows, per-slot length, first token): the
-        rows scatter through the slot's block table (the engine mapped
-        blocks covering ``[0, plen)`` first)."""
-        plen_i = jnp.asarray(plen, jnp.int32)
-        if self.quantize:
-            k_new, v_new, ks_new, vs_new, first, lp = payload
-            new_cache = paged_insert_slot(
-                cache, slot, k_new, v_new, plen_i, ks_new, vs_new
-            )
-        else:
-            k_new, v_new, first, lp = payload
-            new_cache = paged_insert_slot(cache, slot, k_new, v_new, plen_i)
-        lp_vec = lax.dynamic_update_index_in_dim(lp_vec, lp, slot, axis=0)
-        tok_vec = lax.dynamic_update_index_in_dim(tok_vec, first, slot, axis=0)
-        return new_cache, tok_vec, lp_vec
-
     def _stage_chunk_fn(self, params, tokens, n_tok, staging, reset,
                         reset_val):
         """One mid-prompt chunk into the exact staging cache (quantized
-        chunked admission). Logits are unused here, so XLA prunes the
+        admission). Logits are unused here, so XLA prunes the
         output head. ``reset_val`` mirrors the mixed step's: the first
         chunk sets the staged length to the prefix-hit match (0 cold)."""
         length = jnp.where(reset, reset_val, staging.length)
@@ -2157,7 +2035,7 @@ class SlotServer:
         nothing — the programs a tick at Tq bucket ``tq`` runs: the fused
         ``mixed`` step (``tq == 1`` is the pure-decode tick, the padded
         program; a chunk bucket is the packed one) and, where admission
-        stages prompts (int8 chunked), the ``stage_chunk`` program.
+        stages prompts (int8), the ``stage_chunk`` program.
         ``.compile().as_text()`` of each shows what a served tick
         executes, e.g. which Pallas kernels are in it; with the programs
         already run, the compile is the loop's own executable."""
@@ -2863,16 +2741,9 @@ class SlotServer:
                                 host_restores=self._adm_restored,
                                 host_demotes=self._adm_demoted)
         self._admitting = False
-        if self.admission == "chunked":
-            self._prefill_pos[slot] = matched
-            self._slot_state[slot] = "prefill"
-            self._prefill_fifo.append(slot)
-        else:
-            self._admit_whole(req, slot, matched)
-            # First token parked in the device token vector; the slot sits
-            # out this tick's step (n=0 holds it) and goes live when the
-            # per-tick batched fetch reads it — no per-admit host sync.
-            self._slot_state[slot] = "await"
+        self._prefill_pos[slot] = matched
+        self._slot_state[slot] = "prefill"
+        self._prefill_fifo.append(slot)
         if obs.REGISTRY.enabled:
             _QUEUE_WAIT.observe(waited)
         return waited
@@ -3029,93 +2900,6 @@ class SlotServer:
             for j, node in enumerate(path[:nb_full]):
                 self._win.publish(slot, j, node, self._prefix)
 
-    def _admit_whole(self, req: Request, slot: int, matched: int = 0) -> None:
-        """Blocking admission: the whole remaining prompt prefills before
-        the admit returns (the slot parks in ``await`` either way).
-
-        Three shapes:
-
-        - cold, exact (the legacy path): whole-prompt prefill on a
-          bucket-sized mini cache, then insert through the slot's table;
-        - prefix hit, exact: the slot's table already references the
-          ``matched`` tokens' blocks, so only the suffix runs —
-          synchronous single-slot chunks through a mixed-step-shaped program (one compile per
-          chunk bucket, same bounded set as the tick's; other slots ride
-          inert);
-        - int8 with the prefix cache on (hit or cold): the staged path
-          runs to completion synchronously — exact chunks into the
-          staging cache, quantize + insert at the final chunk — because
-          a hit's suffix attends the dequantized prefix as exact staged
-          rows.
-        """
-        plen = len(req.prompt)
-        if self.quantize and self._prefix is not None:
-            self._prefill_pos[slot] = matched
-            pos = matched
-            while pos < plen:
-                n = min(self.prefill_chunk, plen - pos)
-                self._run_staged_chunk(slot, n, pos + n == plen)
-                pos += n  # the final chunk published from staging
-            return
-        if matched:
-            self._prefill_pos[slot] = matched
-            pos = matched
-            while pos < plen:
-                n = min(self.prefill_chunk, plen - pos)
-                last = pos + n == plen
-                self._ensure_blocks(slot, pos + n)
-                rows, first = self._consume_chunk(slot, n, last)
-                tq = self._chunk_bucket(n)
-                # Same no-per-admit-alloc discipline as the cold path's
-                # scratch below, keyed by (1, tq) row shape.
-                pad = self._whole_scratch.get(tq)
-                if pad is None:
-                    pad = self._whole_scratch[tq] = np.zeros((1, tq),
-                                                             np.int32)
-                else:
-                    pad[0, n:] = 0
-                pad[0, :n] = rows
-                self._sync_table()
-                self.tok, self._lp, _, last_dev, \
-                    self.cache = self._whole_suffix(
-                        self.params, jnp.asarray(pad[0]), jnp.int32(slot),
-                        jnp.int32(n), jnp.asarray(last), jnp.asarray(first),
-                        jnp.int32(self._prefill_start[slot]), self.cache,
-                        self.tok, self._keys, jnp.asarray(self._temp_np),
-                        jnp.asarray(self._topk_np),
-                        jnp.zeros((self.slots,), jnp.int32), self._lp,
-                    )
-                if last and req.uid in self._families:
-                    self._slot_logits[slot] = last_dev[slot]
-                pos += n
-            self._publish_prefix(slot)
-            return
-        self._ensure_blocks(slot, plen)
-        bucket = _bucket(plen, self.cache_len, multiple=self._seq_shards)
-        # Reusable per-bucket scratch: zero the tail a longer previous
-        # occupant may have left, then lay the prompt in — jnp.asarray
-        # copies to a fresh device buffer, so immediate reuse is safe.
-        padded = self._whole_scratch.get(bucket)
-        if padded is None:
-            padded = self._whole_scratch[bucket] = np.zeros((1, bucket),
-                                                            np.int32)
-        else:
-            padded[0, plen:] = 0
-        padded[0, :plen] = np.asarray(req.prompt, np.int32)
-        payload, last_row = self._prefill(
-            self.params, jnp.asarray(padded), jnp.int32(plen),
-            self._keys[slot], jnp.float32(self._temp_np[slot]),
-            jnp.int32(self._topk_np[slot]),
-        )
-        if req.uid in self._families:
-            self._slot_logits[slot] = last_row[0]
-        self._sync_table()
-        self.cache, self.tok, self._lp = self._insert(
-            self.cache, self.tok, self._lp, jnp.int32(slot), payload, plen
-        )
-        if self._prefix is not None:
-            self._publish_prefix(slot)
-
     def _plan_chunks(
         self, max_n: Optional[int] = None
     ) -> List[Tuple[int, int, bool]]:
@@ -3169,10 +2953,9 @@ class SlotServer:
         The synchronous ticks are the ones that need token VALUES on the
         host before they can be planned, told from engine state alone:
         draft-and-verify, token-tree and fork families (and a fork still
-        carried), staged int8 prefill, whole admission, and a tick with
-        nothing to dispatch."""
-        plan = (self._plan_chunks(max_n=32 if self._tree_fams else None)
-                if self.admission == "chunked" else [])
+        carried), staged int8 prefill, and a tick with nothing to
+        dispatch."""
+        plan = self._plan_chunks(max_n=32 if self._tree_fams else None)
         tail = self._tail
         live_idx = []
         for i, st in enumerate(self._slot_state):
@@ -3192,8 +2975,6 @@ class SlotServer:
             why = "fork"
         elif self._staged_prefill and plan:
             why = "staged"
-        elif self.admission != "chunked":
-            why = "whole"
         elif not plan and not live_idx:
             why = "awaits"
         else:
@@ -4281,8 +4062,7 @@ class SlotServer:
         self._chunk_k[slot] += 1
         if last:
             self._slot_state[slot] = "await"
-            if slot in self._prefill_fifo:  # whole-admission suffix
-                self._prefill_fifo.remove(slot)  # chunks never enqueue
+            self._prefill_fifo.remove(slot)
         if obs.REGISTRY.enabled:
             _PREFILL_CHUNKS.inc()
         if obs.TRACER.active:
@@ -4323,7 +4103,7 @@ class SlotServer:
         return chunk_tok, chunk_slot, chunk_n
 
     def _run_staged_chunk(self, slot: int, n: int, last: bool) -> None:
-        """Quantized chunked admission: advance one slot's staged exact
+        """Quantized admission: advance one slot's staged exact
         prefill by ``n`` tokens; the final chunk quantizes + inserts."""
         plen = len(self._slot_req[slot].prompt)
         rows, first = self._consume_chunk(slot, n, last)
@@ -4579,10 +4359,10 @@ class SlotServer:
                 FLIGHT.describe_programs(tables)
 
         def admit() -> None:
-            """Admit: oldest visible request per free slot. Chunked
-            admission is pure bookkeeping (the chunks run inside the
-            tick); the staged (quantized) variant holds one prompt in
-            flight at a time, so admission waits for the stage. Run
+            """Admit: oldest visible request per free slot. Admission is
+            pure bookkeeping (the chunks run inside the tick); the staged
+            (quantized) variant holds one prompt in flight at a time, so
+            admission waits for the stage. Run
             again in an iteration that had to land a pending tail
             before planning: the retirements freed slots."""
             phases.mark("admit")
@@ -4622,9 +4402,9 @@ class SlotServer:
                 slot = free.pop(0)
                 vis = visible_wall.pop(req.uid, now)
                 if branches > 1:
-                    # The family exists BEFORE the admission runs:
-                    # whole-admission prefill stashes the family's
-                    # prompt-end logits synchronously inside _admit.
+                    # The family exists before its prompt's final
+                    # chunk runs: that tick stashes the prompt-end
+                    # logits the siblings sample from.
                     if tree_adm:
                         self._admit_tree_family(req, slot)
                     else:
@@ -4666,7 +4446,7 @@ class SlotServer:
             if host_sync:
                 # THE per-tick host sync: every new token of this
                 # program — decode samples, fused final-chunk first
-                # tokens, legacy insert first tokens — in one batched
+                # tokens, staged insert first tokens — in one batched
                 # fetch. Only programs that produced a token pay it: a
                 # fused tick of nothing but mid-prompt chunks skips the
                 # fetch (like the staged path), letting consecutive
@@ -4701,9 +4481,9 @@ class SlotServer:
                         expert_rows = self._account_step_counters(
                             fh[self.slots:])
                 else:
-                    # Awaits-only tick (a synchronous whole admission
-                    # parked tokens, nothing stepped): fetch the carried
-                    # vectors directly.
+                    # Nothing stepped (a staged final chunk parked its
+                    # first token while no slot was live): fetch the
+                    # carried vectors directly.
                     # lint: allow[host-sync] THE one per-tick fetch (the batched token vector)
                     self._tok_host = np.asarray(self.tok)
                     # lint: allow[host-sync] rides the same sync point (the parked first-token logprobs)
@@ -5176,10 +4956,10 @@ class SlotServer:
                     continue
                     # lint: mirror[idle] end
 
-                # The tick's prefill chunks (chunked admission only) and
-                # its decode rows were settled by :meth:`_plan_tick`,
-                # above the idle check: ``live_idx`` is the slots that
-                # get a decode row, a token in flight counted.
+                # The tick's prefill chunks and its decode rows were
+                # settled by :meth:`_plan_tick`, above the idle check:
+                # ``live_idx`` is the slots that get a decode row, a token
+                # in flight counted.
                 phases.mark("plan")
                 chunk_tokens = sum(n for _, n, _ in plan)
                 # The staged path rebinds ``plan`` to []; keep the tick's
@@ -5215,8 +4995,10 @@ class SlotServer:
                 with tick_span:
                     ran_staged = False
                     # What the tick program ran as, for the flight record
-                    # and the profiler's tick:dispatch annotation.
-                    tick_kind = "awaits"
+                    # and the profiler's tick:dispatch annotation ("none"
+                    # until something goes out; no tick the loop is known
+                    # to run keeps it).
+                    tick_kind = "none"
                     tick_tq = 0
                     tick_group = 0  # members of a packed tick's chunk group
                     n_vec = None
@@ -5295,11 +5077,12 @@ class SlotServer:
                         reset = np.zeros((self.slots,), bool)
                         reset_val = np.zeros((self.slots,), np.int32)
                         emit = np.zeros((self.slots,), bool)
-                        # Parked first tokens (whole-admission awaits)
-                        # exist only in the device token vector — their
-                        # row 0 must come from there, everyone else's
-                        # from the host matrix. Computed BEFORE chunk
-                        # consumption flips final-chunk slots to await.
+                        # Parked first tokens (a staged final chunk's,
+                        # just above) exist only in the device token
+                        # vector — their row 0 must come from there,
+                        # everyone else's from the host matrix. Computed
+                        # BEFORE chunk consumption flips final-chunk
+                        # slots to await.
                         use_dev0 = np.asarray(
                             [st == "await" for st in self._slot_state]
                         )

@@ -92,7 +92,6 @@ class RunConfig:
     arrival_every: int = 0   # ticks between arrivals (0 = all queued at start)
     prefill_chunk: int = 256  # max prompt tokens one tick writes per slot
     prefill_budget: Optional[int] = None  # per-tick prompt-token budget
-    admission: str = "chunked"  # "chunked" (stall-free) | "whole" (legacy)
     slo_ttft: float = 1.0    # TTFT target (s) for the goodput SLO
     slo_tbt: float = 0.2     # worst inter-token-gap target (s), ditto
     prefix_cache: bool = False  # radix prefix KV reuse across requests
@@ -302,11 +301,12 @@ def build_arg_parser() -> argparse.ArgumentParser:
                         "tick computes the rows it carries, so this is what "
                         "a tick with prompt work costs the live slots — "
                         "the Sarathi-style stall-free token budget")
-    p.add_argument("--admission", choices=["chunked", "whole"],
-                   default=d.admission,
-                   help="serve mode: 'chunked' fuses prefill chunks into "
-                        "the per-tick mixed step (stall-free); 'whole' is "
-                        "the legacy blocking whole-prompt prefill + insert")
+    p.add_argument("--admission", choices=["chunked"], default="chunked",
+                   help="serve mode: accepted and read nowhere — serving "
+                        "has ONE admission path, prefill chunks fused into "
+                        "the per-tick step (benchmark/harness.py still "
+                        "passes the flag from each configuration's "
+                        "serving.admission)")
     p.add_argument("--slo-ttft", type=float, default=d.slo_ttft,
                    metavar="SEC",
                    help="serve mode: TTFT target of the goodput SLO — a "
