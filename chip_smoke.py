@@ -38,6 +38,13 @@ Phases of the default run, one JSON object per line on stdout:
                 its published widths through ``serve`` (K/V rows beside
                 conv tails in one pool), with a prefix hit and a fork,
                 its served tokens held to the plain reference's logits
+  window     j  ``benchmark/configs/k-exaone-236b-a23b.json`` from its two
+                pools; state: ``nemotron-3-super-120b-a12b.json`` from its
+                K/V pool and its per-slot state; eva:
+                ``benchmark/configs/evabyte.json`` from its exact rows' and
+                its summary rows' pools (a request across three window
+                boundaries, a reused slot, a slot that sits ticks out),
+                each held to its family's plain reference likewise
   times      h  wall time per phase and compile-cache traffic — set-up
                 information only; nothing here is a rate or a benchmark
 
@@ -1457,6 +1464,108 @@ def phase_state(s: Sizes, config: Optional[Dict[str, Any]] = None, *,
     }
 
 
+def phase_eva(s: Sizes, config: Optional[Dict[str, Any]] = None, *,
+              device: str = "tpu", block: int = 64, chunk: int = 256,
+              tol_gap: float = 0.4) -> Dict[str, Any]:
+    """EVA attention in every layer (``benchmark/configs/evabyte.json``: 8
+    layers at their published widths, MHA 32 x 128, a window of 2,048 in
+    chunks of 16) served on one chip through ``cli.build_serve_engine`` and
+    ``SlotServer.serve`` with the reference's seeded weights, from a cache
+    of two pools of every layer under two tables: exact rows of the open
+    window, one summary row a chunk of every closed one. In an engine of two
+    slots: a long request whose prompt (two and a half windows and one
+    position: no multiple of the chunk, so decode takes over mid-chunk)
+    crosses two window boundaries in chunk groups and whose output crosses a
+    third a row at a time; beside it a short one that never closes a window;
+    once the short one has retired, a third in its slot; then a fourth alone
+    (the other slot sits every tick out), past one boundary, in a slot whose
+    last request wrote more summary rows than it may see. Every served token
+    is held to the plain reference's logits
+    (``benchmark/references/evabyte.py``: one dense softmax over exact rows
+    and summaries, no cache) as :func:`phase_hybrid` holds its own: a
+    request's MEAN gap lies under ``tol_gap``; a row that reads a stale
+    summary, or none, leaves every later token off."""
+    import numpy as np
+
+    from benchmark import check as served
+    from benchmark.spec import Spec
+    from tree_attention_tpu import cli
+    from tree_attention_tpu.serving.engine import Request
+    from tree_attention_tpu.utils.config import parse_args
+
+    spec = Spec(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                             "BENCHMARK.json"))
+    if config is None:
+        config = spec.load_json("configs", "evabyte.json")
+    ref = spec.load_module("references", config["family"] + ".py")
+    adapter = spec.load_module("adapters", config["family"] + ".py")
+    w = ref.Widths.of(config)
+    weights = ref.init_weights(3, w)
+    rng = np.random.default_rng(29)
+    vocab, W = int(config["vocab_size"]), int(config["window_size"])
+    a = rng.integers(0, vocab, (2 * W + W // 2 + 1,)).tolist()
+    b = rng.integers(0, vocab, (block // 2 + 3,)).tolist()
+    c = rng.integers(0, vocab, (block + block // 3,)).tolist()
+    d = rng.integers(0, vocab, (W + block // 2 + 5,)).tolist()
+    new_a, new = W // 2 + W // 8, block // 4 + 4
+    flags = ["--mode", "serve", "--device", device,
+             "--dtype", str(config["torch_dtype"]), "--slots", "2",
+             "--prompt-len", str(len(a)), "--prompt-jitter", "0",
+             "--max-new-tokens", str(new_a), "--prefill-chunk", str(chunk),
+             "--kv-block", str(block),
+             "--temperature", "0", "--seed", "1"]
+    setup = cli.build_serve_engine(
+        parse_args(flags), None, model=config,
+        params=adapter.engine_params(weights, w))
+    check(setup.tcfg.cache_kind == "eva",
+          "the model caches exact rows and summary rows")
+    server = setup.make_engine()
+
+    report = server.serve([
+        Request(uid=0, prompt=a, max_new_tokens=new_a),
+        Request(uid=1, prompt=b, max_new_tokens=new),
+        Request(uid=2, prompt=c, max_new_tokens=new)])
+    alone = server.serve([Request(uid=3, prompt=d, max_new_tokens=W // 8)])
+    results = list(report.results) + list(alone.results)
+    prompts = {0: a, 1: b, 2: c, 3: d}
+    check(len(results) == 4 and all(r.outcome == "budget" for r in results),
+          "four requests served to their budgets through two slots")
+    check(len(a) % int(config["chunk_size"]) != 0
+          and (len(a) + new_a) // W == 3 and len(a) // W == 2,
+          "the long request crosses two boundaries in its prompt and a "
+          "third in decode, its prompt no multiple of the chunk")
+    leak = server.leak_report()
+    check(not (leak["blocks_used"] or leak["blocks_private"]
+               or leak["blocks_reserved"] or leak["pins"]
+               or leak["window_blocks_held"]),
+          f"no block of either pool leaked ({leak})")
+    kv = report.kv
+    check(kv["window_blocks_peak_slot"] <= kv["window_blocks_bound"]
+          and kv["window_blocks_freed"] >= 3 * (W // block),
+          f"a slot held at most its bound of exact rows' blocks and gave "
+          f"three windows back ({kv})")
+    gaps = [served.served_gaps(ref, weights, w, np.asarray(prompts[r.uid]),
+                              np.asarray(r.tokens))[0] for r in results]
+    means = [float(g.mean()) for g in gaps]
+    check(max(means) <= tol_gap,
+          f"a request's served tokens lie {max(means):.3f} under the "
+          f"reference's best on average (limit {tol_gap}; {means})")
+    cache = server.cache
+    return {
+        "layers": sorted(set(setup.tcfg.layer_types)),
+        "summary_pool": list(cache.k.shape),
+        "local_pool": list(cache.wk.shape),
+        "tables": [list(cache.table.shape), list(cache.wtable.shape)],
+        "window_blocks_bound": kv["window_blocks_bound"],
+        "window_blocks_peak_slot": kv["window_blocks_peak_slot"],
+        "window_blocks_freed": kv["window_blocks_freed"],
+        "tokens_compared": int(sum(len(g) for g in gaps)),
+        "gap_max": float(max(g.max() for g in gaps)),
+        "gap_mean": float(np.concatenate(gaps).mean()),
+        "gap_mean_by_request": means,
+    }
+
+
 # ---------------------------------------------------------------------------
 # main
 # ---------------------------------------------------------------------------
@@ -1473,6 +1582,7 @@ def run_default(run: Run, s: Sizes) -> None:
     run.phase("hybrid", phase_hybrid, s)
     run.phase("window", phase_window, s)
     run.phase("state", phase_state, s)
+    run.phase("eva", phase_eva, s)
 
 
 def run_four_chips(run: Run, s: Sizes) -> None:

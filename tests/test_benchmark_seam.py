@@ -1,8 +1,9 @@
 """The seam by which the benchmark finds a model family by name, guarded by
 tier-1 (``benchmark/tests/test_seam.py`` holds the slower rehearsals, which
 tier-1 does not run), and the cells that came through it: ``dsv2_codegen_sat``,
-``lcflash_agentturn_sat``, ``lfm2_agentturn_sat`` and
-``kexaone_reasoning_long_sat``.
+``lcflash_agentturn_sat``, ``lfm2_agentturn_sat``,
+``kexaone_reasoning_long_sat``, ``nemotron3s_agentturn_sat`` and
+``evabyte_bytedoc_sat``.
 """
 
 import json
@@ -63,6 +64,11 @@ STATE_METRICS = ["ssm_update_ms_tick", "ssm_decode_update_roofline",
                  "moe_ungated_ms_tick", "moe_ungated_matmul_roofline",
                  "ssm_states_advanced_pct"]
 NS_CELL = "nemotron3s_agentturn_sat"
+# What PR 44 appended last, for its own cell.
+EVA_METRICS = ["eva_local_ms_tick", "eva_local_decode_roofline",
+               "eva_summary_ms_tick", "eva_summary_decode_roofline",
+               "eva_summaries_written_pct"]
+EVA_CELL = "evabyte_bytedoc_sat"
 
 
 def _sources(but=()):
@@ -460,7 +466,7 @@ def test_tick_ahead_pct_reads_the_look_ahead_counter(flight, want):
     # PR 35's parts).
     assert [m["name"] for m in listed[at + 1:]] == [
         "mixer_rest_ms_tick", "mixer_rest_stream_roofline"] + PARTS_ALL \
-        + AFTER_PARTS + WINDOW_METRICS + STATE_METRICS
+        + AFTER_PARTS + WINDOW_METRICS + STATE_METRICS + EVA_METRICS
     for w in json.load(open(BENCH))["workloads"]:
         assert "tick_ahead_pct" in [
             m["name"] for m in spec.cell(w["name"]).per_layer]
@@ -489,7 +495,7 @@ def test_the_hybrid_cell_resolves_every_file_it_names():
     # The traffic file that was there, and the cell the sixth of six.
     assert cell.traffic == lc.traffic and cell.traffic["kind"] == "backlog"
     assert [w["name"] for w in spec.data["workloads"]][5:] == [
-        LFM_CELL, KX_CELL, NS_CELL]
+        LFM_CELL, KX_CELL, NS_CELL, EVA_CELL]
     assert all(w["chips"] == 1 for w in spec.data["workloads"])
     assert [m["name"] for m in cell.end_to_end] == [
         "out_tok_s", "tbt_p50_ms", "tbt_p99_ms", "setup_s"]
@@ -685,13 +691,13 @@ def test_a_parts_metric_is_listed_where_its_part_exists(name):
     programs, the decode ones moving ``tbt_p50_ms`` and the mixed ones
     ``tbt_p99_ms``; the expert parts in the five expert cells, the conv
     parts in the hybrid's and the state configuration's, the rest in all
-    eight."""
+    nine."""
     spec = Spec(BENCH)
     listed = json.load(open(BENCH))["per_layer"]
     tail = len(PARTS_ALL) + len(AFTER_PARTS) + len(WINDOW_METRICS) \
-        + len(STATE_METRICS)
-    assert [m["name"] for m in listed[-tail:]] \
-        == PARTS_ALL + AFTER_PARTS + WINDOW_METRICS + STATE_METRICS
+        + len(STATE_METRICS) + len(EVA_METRICS)
+    assert [m["name"] for m in listed[-tail:]] == PARTS_ALL + AFTER_PARTS \
+        + WINDOW_METRICS + STATE_METRICS + EVA_METRICS
     entry = next(m for m in listed if m["name"] == name)
     cells = [w["name"] for w in spec.data["workloads"]]
     want = cells
@@ -744,13 +750,15 @@ def test_paged_steps_run_pct_reads_the_lists_the_kernels_walk():
     spec = Spec(BENCH)
     listed = json.load(open(BENCH))["per_layer"]
     cells = [w["name"] for w in spec.data["workloads"]]
-    assert listed[-1 - len(WINDOW_METRICS) - len(STATE_METRICS)] == {
+    assert listed[-1 - len(WINDOW_METRICS) - len(STATE_METRICS)
+                  - len(EVA_METRICS)] == {
         "name": "paged_steps_run_pct", "unit": "%", "better": "lower",
         "source": "program_counter", "layer": "kernels",
         "moves": "tbt_p50_ms", "workloads": cells}
     for cell in cells:
         last = -1 - len(WINDOW_METRICS) * (cell == KX_CELL) \
-            - len(STATE_METRICS) * (cell == NS_CELL)
+            - len(STATE_METRICS) * (cell == NS_CELL) \
+            - (1 + len(EVA_METRICS)) * (cell == EVA_CELL)
         assert spec.cell(cell).per_layer[last]["name"] \
             == "paged_steps_run_pct"
     read = spec.load_module("layer_metrics", "paged_steps_run_pct.py").read
@@ -793,7 +801,7 @@ def test_the_window_cell_resolves_every_file_it_names():
     assert cell.traffic["kind"] == "backlog"
     assert spec.find("traffic", "reasoning_long_backlog.json")
     assert [w["name"] for w in spec.data["workloads"]][6:] == [
-        KX_CELL, NS_CELL]
+        KX_CELL, NS_CELL, EVA_CELL]
     assert all(w["chips"] == 1 for w in spec.data["workloads"])
     assert [m["name"] for m in cell.end_to_end] == [
         "out_tok_s", "tbt_p50_ms", "tbt_p99_ms", "setup_s"]
@@ -818,8 +826,11 @@ def test_the_window_cell_resolves_every_file_it_names():
                  "dec_head_ms_tick", "dec_moe_ms_tick", "mixed_tick_p50_ms"):
         assert name in names, name
     listed = {m["name"]: m for m in spec.data["per_layer"]}
+    # (The ledger's share is the one of the three a later cell reports:
+    # its exact rows lie under the same ledger, by another rule.)
     for name in WINDOW_METRICS:
-        assert listed[name]["workloads"] == [KX_CELL]
+        assert listed[name]["workloads"] == [KX_CELL] + [EVA_CELL] * (
+            name == "window_blocks_held_pct")
     assert (listed["window_attn_ms_tick"]["layer"],
             listed["window_attn_ms_tick"]["moves"],
             listed["window_attn_ms_tick"]["source"]) == (
@@ -1000,7 +1011,8 @@ def test_the_state_cell_resolves_every_file_it_names():
     # The traffic file that was there, the cell the eighth of eight, none
     # on four chips.
     assert cell.traffic == lc.traffic and cell.traffic["kind"] == "backlog"
-    assert [w["name"] for w in spec.data["workloads"]][7:] == [NS_CELL]
+    assert [w["name"] for w in spec.data["workloads"]][7:] == [
+        NS_CELL, EVA_CELL]
     assert all(w["chips"] == 1 for w in spec.data["workloads"])
     assert [m["name"] for m in cell.end_to_end] == [
         "out_tok_s", "tbt_p50_ms", "tbt_p99_ms", "setup_s"]
@@ -1169,3 +1181,219 @@ def test_ssm_states_advanced_pct_reads_the_steps_counter():
     assert read(run) == pytest.approx(100.0)
     flight[0]["ssm_states_advanced"] = 320       # an idle slot rewritten
     assert read(run) > 100.0
+
+
+# -- two kinds of row for the same tokens: the EVA cell (ISSUE 44) -----------
+
+
+def test_the_eva_cell_resolves_every_file_it_names():
+    spec = Spec(BENCH)
+    cell = spec.cell(EVA_CELL)
+    assert cell.chips == 1 and cell.config["family"] == "evabyte"
+    assert cell.config["name"] == "evabyte"
+    for d, mod in (("references", cell.reference()),
+                   ("adapters", cell.adapter())):
+        assert mod.__file__.endswith(os.path.join(d, "evabyte.py"))
+    with open(cell.reference().__file__) as f:
+        text = f.read()
+    assert "tree_attention_tpu" not in text.split('"""', 2)[2]
+    assert "import benchmark" not in text and "from benchmark" not in text
+    # One dense softmax over both sets: the reference never merges partials.
+    assert "jax.nn.softmax(jnp.where(see" in text \
+        and "merge_partials" not in text and "logsumexp" not in text
+    # A traffic file of its own (data only), the cell the ninth of nine,
+    # none on four chips.
+    assert cell.traffic["kind"] == "backlog"
+    assert spec.find("traffic", "bytedoc_backlog.json")
+    assert [w["name"] for w in spec.data["workloads"]][8:] == [EVA_CELL]
+    assert all(w["chips"] == 1 for w in spec.data["workloads"])
+    assert [m["name"] for m in cell.end_to_end] == [
+        "out_tok_s", "tbt_p50_ms", "tbt_p99_ms", "setup_s"]
+    names = [m["name"] for m in cell.per_layer]
+    for name in names:
+        assert spec.load_module("layer_metrics", name + ".py").read
+    for name in ("occupancy_pct", "kv_blocks_peak_pct", "hbm_peak_gb",
+                 "tick_rows_useful_pct", "paged_steps_run_pct",
+                 "window_blocks_held_pct", "tick_ahead_pct",
+                 "device_idle_pct", "decode_tick_p50_ms",
+                 "mixed_tick_p50_ms") + tuple(PARTS_DENSE):
+        assert name in names, name
+    assert names[-5:] == EVA_METRICS
+    # Another kernel's name would read another kernel's cost file.
+    for name in ("attn_kernel_ms_tick", "flash_decode_paged_roofline",
+                 "window_attn_ms_tick", "window_decode_paged_roofline",
+                 "mla_decode_ms_tick", "moe_ffn_ms_tick", "dec_moe_ms_tick",
+                 "dec_conv_ms_tick", "mixer_rest_ms_tick",
+                 "ssm_update_ms_tick", "ttft_p50_ms"):
+        assert name not in names
+    listed = {m["name"]: m for m in spec.data["per_layer"]}
+    for name in EVA_METRICS:
+        assert listed[name]["workloads"] == [EVA_CELL]
+        assert listed[name]["moves"] == "tbt_p50_ms"
+    assert [listed[n]["layer"] for n in EVA_METRICS] == [
+        "kernels"] * 4 + ["block pool"]
+    assert listed["eva_local_decode_roofline"]["unit"] == "%" \
+        == listed["eva_summary_decode_roofline"]["unit"]
+    assert listed["eva_summaries_written_pct"]["source"] == "program_counter"
+    for w in spec.data["workloads"][:8]:
+        assert not set(EVA_METRICS) & {
+            m["name"] for m in spec.cell(w["name"]).per_layer}
+    assert cell.config["serving"] == {
+        "slots": 16, "cache_len": 16384, "kv_layout": "paged",
+        "kv_block": 64, "admission": "chunked", "prefill_chunk": 256,
+        "prefix_cache": False}
+    assert list(cell.config["correct"]["limits"]) == ["gap_mean"]
+    for kernel in ("eva_local_decode", "eva_summary_decode"):
+        of_model, calls = cell.adapter().kernel_call(cell.config, kernel)
+        assert calls == 8 and of_model == {
+            "heads": 32, "kv_heads": 32, "head": 128, "dtype_bytes": 2,
+            "window": 2048, "chunk": 16}
+        assert spec.load_module("kernel_costs", kernel + ".py").cost
+    for kernel in ("flash_decode_paged", "window_decode_paged",
+                   "mla_decode_paged"):
+        assert cell.adapter().kernel_call(cell.config, kernel) is None
+    # The traffic: 16 values each in 4 balanced groups of 4, every value a
+    # multiple of 64, the largest pair inside the cache.
+    from benchmark import grid
+    prompts, outputs = (grid.values(cell.traffic[k])
+                        for k in ("prompts", "outputs"))
+    assert (min(prompts), max(prompts), sum(prompts) / 16) == (
+        4096, 12288, 8192)
+    assert (min(outputs), max(outputs), sum(outputs) / 16) == (
+        1024, 3072, 2048)
+    assert all(v % 64 == 0 for v in prompts + outputs)
+    assert max(prompts) + max(outputs) == 15360 \
+        <= cell.config["serving"]["cache_len"]
+    for k, vals in (("prompts", prompts), ("outputs", outputs)):
+        assert cell.traffic[k] == grid.balanced_groups(vals, 4)
+    assert cell.config["vocab_size"] == 320
+
+
+def test_the_eva_configurations_file_against_the_catalog():
+    """Every number of the catalog's ``config`` under the same key but the
+    one ``reduced`` lists; the cut's arithmetic; every assumed rule marked
+    unconfirmed; the drafter named as not built."""
+    path = "/opt/skills/guides/model-configs/architectures.jsonl"
+    with open(BENCH) as f:
+        entry = next(c for c in json.load(f)["configs"]
+                     if c["name"] == "evabyte")
+    with open(os.path.join(ROOT, entry["file"])) as f:
+        c = json.load(f)
+    assert entry["reduced"] == c["reduced"] == ["num_hidden_layers"]
+    assert c["source"] == entry["source"]
+    if os.path.exists(path):
+        row = next(r for r in map(json.loads, open(path))
+                   if r["name"] == "EvaByte")
+        assert entry["source"] == row["source_url"]
+        for k, v in row["config"].items():
+            if k not in c["reduced"]:
+                assert c[k] == v, k
+    assert (c["num_hidden_layers"], c["published"]["num_hidden_layers"]) \
+        == (8, 32)
+    assert (c["hidden_size"], c["intermediate_size"],
+            c["num_attention_heads"], c["num_key_value_heads"],
+            c["window_size"], c["chunk_size"], c["vocab_size"],
+            c["num_pred_heads"]) == (4096, 11008, 32, 32, 2048, 16, 320, 8)
+    h, ffn = c["hidden_size"], c["intermediate_size"]
+    layer = 4 * h * h + 3 * h * ffn + 2 * 32 * 128 + 2 * h
+    ends = 320 * h + 8 * 320 * h
+    assert abs(layer / 1e6 - 202.4) < 0.05
+    assert abs((32 * layer + ends) / 1e9 - 6.49) < 0.005
+    assert abs((8 * layer + ends) / 1e6 - 1630.9) < 0.2 \
+        and "1,630.9M" in c["why_reduced"]
+    dep = c["deployment"]
+    assert (dep["chips"], dep["pipeline_stages"], dep["stage"]) == (4, 4, 0)
+    assert c["block"] == dict(
+        c["block"], summary_key="weighted_plus_mu",
+        summary_logits_scaled=False, summary_after_rotary=True,
+        window_rule="aligned")
+    for rule in ("summary_key", "summary_logits_scaled",
+                 "summary_after_rotary", "window_rule", "head_0"):
+        assert "unconfirmed" in c["assumed"]["unconfirmed"][rule]
+    assert "multibyte_self_speculation" in c["not_built"]
+    assert set(c["assumed"]["seeded_scales"]) == {
+        "embedding_std", "head_std", "attn_out_std", "dense_down_std",
+        "norm_gain_std", "phi_std", "mu_std"}
+    # The two pools the file's arithmetic names.
+    pools = Spec(BENCH).cell(EVA_CELL).adapter().pools(c)
+    assert pools["wk"] == (8, 608, 32, 64, 128) \
+        and pools["k"] == (8, 256, 32, 64, 128)
+    assert (pools["table"], pools["wtable"]) == ((16, 16), (16, 256))
+    block_mb = 2 * 8 * 32 * 64 * 128 * 2 / 1e6
+    assert abs(608 * block_mb / 1e3 - 5.10) < 0.01 \
+        and abs(256 * block_mb / 1e3 - 2.15) < 0.01
+
+
+def test_the_eva_adapter_refuses_another_model_at_once():
+    spec = Spec(BENCH)
+    cell = spec.cell(EVA_CELL)
+    adapter = cell.adapter()
+    with pytest.raises(SpecError, match="cannot read"):
+        adapter.build({"family": "evabyte"}, [], 0, "cpu", None)
+    from tree_attention_tpu.models.transformer import model_from_config
+    # A program that knows no ``attention_class`` reads the file as a dense
+    # rotary model (the parent commit does): what it built is held to the
+    # file and refused, before a weight is drawn.
+    dense = {k: v for k, v in cell.config.items()
+             if k not in ("attention_class", "window_size", "chunk_size")}
+    with pytest.raises(SpecError, match="built otherwise"):
+        adapter._hold_to_file(model_from_config(dense), cell.config)
+    import types
+    with pytest.raises(SpecError, match="cannot express"):
+        adapter._hold_to_file(types.SimpleNamespace(d_model=4096),
+                              cell.config)
+    # A file that says another rule than the one built.
+    config = dict(cell.config)
+    config["block"] = dict(config["block"], summary_key="mean_plus_mu")
+    with pytest.raises(SpecError, match="summary_key"):
+        adapter._hold_to_file(model_from_config(cell.config), config)
+
+
+@pytest.mark.parametrize("name", EVA_METRICS)
+def test_the_eva_metrics_read_nothing_where_there_is_nothing(name):
+    """An untraced run, a run without flight records, a trace without a
+    decode tick and a program without the counters (the parent's records)
+    give None, never a number and never an exception."""
+    import types
+
+    spec = Spec(BENCH)
+    cell = spec.cell(EVA_CELL)
+    read = spec.load_module("layer_metrics", name + ".py").read
+    peaks = spec.load_json("peaks.json")["TPU v5 lite"]
+    empty = {"offset_s": 0.0, "t0": 5.0, "t1": 8.0, "devices": 1,
+             "events": {}}
+    parent = [{"t_s": 2.0, "occupancy": 4, "chunk_tokens": 0},
+              {"t_s": 3.0, "occupancy": 4, "chunk_tokens": 0}]
+    for trace, flight in ((None, None), (None, []), (empty, None),
+                          (empty, [{"t_s": 1.0}]), (empty, parent)):
+        run = types.SimpleNamespace(trace=trace, flight=flight, cell=cell,
+                                    recs=[], peaks=peaks, t_open=1.0,
+                                    t_end=10.0)
+        assert read(run) is None
+
+
+def test_eva_summaries_written_pct_reads_the_steps_counter():
+    import types
+
+    spec = Spec(BENCH)
+    read = spec.load_module("layer_metrics",
+                            "eva_summaries_written_pct.py").read
+    tick = {"occupancy": 16, "chunk_tokens": 0}
+    flight = [
+        dict(tick, t_s=2.0, eva_summaries_written=8, eva_summaries_due=8),
+        dict(tick, t_s=3.0, eva_summaries_written=16, eva_summaries_due=16),
+        dict(tick, t_s=3.5, eva_summaries_written=0, eva_summaries_due=0),
+        dict(tick, t_s=4.0, eva_summaries_written=128, eva_summaries_due=128,
+             chunk_tokens=256),
+        dict(tick, t_s=0.5, eva_summaries_written=5,     # before the window
+             eva_summaries_due=8),
+    ]
+    run = types.SimpleNamespace(flight=flight, t_open=1.0, t_end=10.0)
+    assert read(run) == pytest.approx(100.0)
+    flight[0]["eva_summaries_written"] = 16      # a row written twice
+    assert read(run) > 100.0
+    flight[0]["eva_summaries_written"] = 0       # a closed chunk skipped
+    assert read(run) < 100.0
+    # Nothing due: nothing to read, not 0 over 0.
+    run.flight = [flight[2], dict(flight[2], t_s=3.7)]
+    assert read(run) is None

@@ -175,6 +175,27 @@ def test_phase_state_serves_a_reused_slot_and_ragged_chunks():
     assert d["tokens_compared"] == 6 + 3 * 5 and d["gap_max"] <= 1e-4
 
 
+def test_phase_eva_serves_three_boundaries_and_a_reused_slot():
+    """The EVA phase at ``tests/test_eva.py``'s small preset: window 32 in
+    chunks of 4, blocks of 8, float32 (a served token lies at the
+    reference's best to 1e-4); the long request crosses three window
+    boundaries, its slot never holds more exact rows' blocks than the
+    bound."""
+    from tests.test_eva import SMALL
+
+    d = smoke.phase_eva(TINY, dict(SMALL), device="cpu", block=8, chunk=16,
+                        tol_gap=1e-4)
+    assert d["layers"] == ["eva"] and d["gap_max"] <= 1e-4
+    assert d["tokens_compared"] == 20 + 2 * 6 + 4
+    assert d["window_blocks_peak_slot"] <= d["window_blocks_bound"] == 7
+    assert d["window_blocks_freed"] >= 3 * 4
+    # 101 positions a slot: 13 blocks of exact rows a table row, 4 of
+    # summaries; the exact rows' pool a constant of blocks a slot.
+    assert d["tables"] == [[2, 4], [2, 13]]
+    assert d["local_pool"][:2] == [2, 2 * 8] \
+        and d["summary_pool"][:2] == [2, 2 * 4]
+
+
 @pytest.mark.slow
 def test_phase_train_and_programs():
     assert smoke.phase_train(TINY)["losses"][-1] < 6.3

@@ -563,7 +563,8 @@ _POOL_REFUSALS = (
     (lambda c: c.speculate,
      "--speculate (the latent kernel takes no tree_mask; a conv layer's "
      "tail or a recurrent state cannot roll a rejected draft back; a window "
-     "layer's freed blocks cannot come back)"),
+     "layer's freed blocks cannot come back, nor can a summary row an EVA "
+     "layer wrote be unwritten)"),
     (lambda c: c.serve_disagg,
      "--serve-disagg (the handoff of that pool's arrays)"),
 )
@@ -712,11 +713,12 @@ def build_serve_engine(cfg: RunConfig, mesh, *, model=None,
                         f"a model served from the {tcfg.cache_kind} pool is "
                         f"not served with {what}: not built yet (ROADMAP "
                         f"2A)")
-        if tcfg.cache_kind == "state" and cfg.prefix_cache:
+        if tcfg.cache_kind in ("state", "eva") and cfg.prefix_cache:
             raise SystemExit(
-                "a model served from the state pool is not served with "
-                "--prefix-cache (a hit needs the recurrent state at the "
-                "matched boundary): not built yet (ROADMAP 2A)")
+                f"a model served from the {tcfg.cache_kind} pool is not "
+                f"served with --prefix-cache (a hit needs the recurrent "
+                f"state, or the summary rows, at the matched boundary): "
+                f"not built yet (ROADMAP 2A)")
     else:
         tcfg = _transformer_config(
             dataclasses.replace(cfg, seq_len=cache_len))

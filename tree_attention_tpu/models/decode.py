@@ -86,6 +86,7 @@ _CACHE_QUANTIZE = obs.counter(
     "kv_cache_quantize_total",
     "whole-cache int8 quantizations (quantize-after-prefill)",
 )
+from tree_attention_tpu.ops.block_utils import AlignedWindow, ChunkSummaries
 from tree_attention_tpu.ops.decode import flash_decode
 from tree_attention_tpu.parallel.mesh import (
     AXIS_DATA,
@@ -267,7 +268,18 @@ class PagedWindowCache:
     the window: a window layer's work list starts at the step that holds
     the lowest visible position (``ops/pallas_decode.py`` ``paged_plan``
     with ``window``) and the mask hides the rest of that step. One
-    ``length`` serves both tables: a token is a row in every layer."""
+    ``length`` serves both tables: a token is a row in every layer.
+
+    A model of EVA layers (``cfg.cache_kind == "eva"``) is served from the
+    same two pools under another row rule, EVERY layer in both: ``wk`` /
+    ``wv`` hold its exact rows, a token a row, for as long as the slot's
+    ALIGNED window is open (``wtable`` as above; behind ``w0 = (t // window)
+    * window`` the blocks go back, a whole window's at once), and ``k`` /
+    ``v`` hold one SUMMARY row for every ``chunk`` positions, written when
+    the chunk's last position is and kept for the request's life. Row ``c``
+    of a slot's summaries is row ``c % block`` of block ``table[i, c //
+    block]``: a block of ``table`` spans ``block * chunk`` positions, so
+    that table is ``chunk`` times narrower than ``wtable``."""
 
     k: jax.Array        # (full layers, N, Hkv, block, D) pool
     v: jax.Array        # (full layers, N, Hkv, block, D) pool
@@ -279,7 +291,8 @@ class PagedWindowCache:
 
     @property
     def capacity(self) -> int:
-        return self.table.shape[1] * self.k.shape[3]
+        # The table with an entry for every ``block`` positions.
+        return self.wtable.shape[1] * self.k.shape[3]
 
     @property
     def block(self) -> int:
@@ -748,8 +761,8 @@ def init_paged_cache(
     paged and a contiguous int8 server start bit-identical.
 
     ``window_blocks``: the capacity of the window layers' pool of a model
-    with sliding-window layers (:class:`PagedWindowCache`), which no other
-    model has.
+    with sliding-window layers, or of the exact rows' pool of a model of
+    EVA layers (:class:`PagedWindowCache`), which no other model has.
     """
     if block < 1 or block & (block - 1):
         raise ValueError(f"kv block must be a power of two, got {block}")
@@ -788,22 +801,26 @@ def init_paged_cache(
             length=jnp.zeros((batch_size,), jnp.int32),
         )
     shape = (cfg.cache_layers, blocks, cfg.n_kv_heads, block, cfg.d_head)
-    if cfg.cache_kind == "window":
+    if cfg.cache_kind in ("window", "eva"):
+        kind = cfg.cache_kind
         if quantize:
             raise ValueError(
-                "int8 rows under two tables are not built: the window "
-                "pool is served exact")
+                f"int8 rows under two tables are not built: the {kind} "
+                f"pool is served exact")
         if seq_sharded:
             raise ValueError(
-                "a sequence-sharded window pool (kv_shard='seq') is not "
-                "built: the tree merge has no lower edge")
+                f"a sequence-sharded {kind} pool (kv_shard='seq') is not "
+                f"built: the tree merge has no lower edge")
         if not window_blocks or window_blocks < 1:
             raise ValueError(
-                f"a model with sliding-window layers needs the window "
-                f"pool's capacity (window_blocks), got {window_blocks}")
+                f"a model with sliding-window or EVA layers needs the "
+                f"window pool's capacity (window_blocks), got "
+                f"{window_blocks}")
         shapes = (shape, shape) + 2 * ((
-            cfg.window_layers, window_blocks, cfg.n_kv_heads, block,
-            cfg.d_head),)
+            cfg.window_layers or cfg.eva_layers, window_blocks,
+            cfg.n_kv_heads, block, cfg.d_head),)
+        # An EVA layer's first table indexes summary rows, one a chunk.
+        nb_first = -(-nb // cfg.chunk) if kind == "eva" else nb
         k, v, wk, wv = (
             jax.jit(lambda: tuple(jnp.zeros(s, cfg.dtype) for s in shapes),
                     out_shardings=NamedSharding(mesh, P()))()
@@ -812,7 +829,7 @@ def init_paged_cache(
         )
         return PagedWindowCache(
             k=k, v=v, wk=wk, wv=wv,
-            table=jnp.zeros((batch_size, nb), jnp.int32),
+            table=jnp.zeros((batch_size, nb_first), jnp.int32),
             wtable=jnp.zeros((batch_size, nb), jnp.int32),
             length=jnp.zeros((batch_size,), jnp.int32),
         )
@@ -1258,6 +1275,25 @@ class _RowGroup(NamedTuple):
         return rows.reshape(H, self.batch, self.tq, D).transpose(1, 0, 2, 3)
 
 
+def window_rules(cfg: TransformerConfig) -> Tuple[Any, Any]:
+    """The visibility rule (``ops/block_utils.py`` ``WindowRule``, or None)
+    of a paged call under a cache's first table and of one under its second:
+    a sliding-window layer's window under the second; an EVA layer's summary
+    rule under the first and its aligned window under the second."""
+    if cfg.cache_kind == "eva":
+        return (ChunkSummaries(cfg.window, cfg.chunk),
+                AlignedWindow(cfg.window))
+    return None, cfg.window or None
+
+
+def chunks_closed(start, n, chunk: int):
+    """``(first, count)`` of the chunks of ``chunk`` positions whose LAST
+    position lies among rows ``[start, start + n)``: the summary rows an EVA
+    layer writes for those rows, consecutive from ``first``. Arrays of
+    numpy's or of jax's (the serve loop counts with it what a tick is due)."""
+    return start // chunk, (start + n) // chunk - start // chunk
+
+
 def _plan_groups(groups: Tuple[_RowGroup, ...], cache: Any,
                  cfg: TransformerConfig) -> Tuple[_RowGroup, ...]:
     """Each group with the paged decode kernels' work list for its rows
@@ -1276,8 +1312,13 @@ def _plan_groups(groups: Tuple[_RowGroup, ...], cache: Any,
     either table): a layer's :func:`_pool_write` then adds ``l * N`` inside
     its kernel and the loop's body holds nothing of the write but the
     launch. Over a :class:`PagedStateCache` such a group gets the list of
-    the slots that have a row too (``ops/pallas_ssm.py`` ``live_list``)."""
+    the slots that have a row too (``ops/pallas_ssm.py`` ``live_list``).
+    An EVA model's lists follow its two rules (:func:`window_rules`), and
+    its first table's ``(block, row)`` is that of the SUMMARY row the
+    slot's token closes, if it closes one (:func:`chunks_closed`)."""
     from tree_attention_tpu.ops.pallas_decode import decode_plan, mla_plan
+
+    rule, wrule = window_rules(cfg)
 
     def barrier(plan):
         # Behind a barrier: the compiler otherwise clones the cheapest
@@ -1293,12 +1334,13 @@ def _plan_groups(groups: Tuple[_RowGroup, ...], cache: Any,
                 plan = mla_plan(g.tq, cache.kv, g.table, g.start)
             else:
                 plan = decode_plan(
-                    cfg.n_heads, g.tq, cache.k, g.table, g.start)
+                    cfg.n_heads, g.tq, cache.k, g.table, g.start,
+                    window=rule)
             g = g._replace(plan=barrier(plan))
             if g.wtable is not None:
                 g = g._replace(wplan=barrier(decode_plan(
                     cfg.n_heads, g.tq, cache.wk, g.wtable, g.start,
-                    window=cfg.window)))
+                    window=wrule)))
         if g.tq == 1 and isinstance(cache, PagedStateCache):
             # The slots a state-space layer's in-place step visits.
             from tree_attention_tpu.ops.pallas_ssm import live_list
@@ -1307,8 +1349,11 @@ def _plan_groups(groups: Tuple[_RowGroup, ...], cache: Any,
                 g = g._replace(live=barrier(live_list(g.n_valid)))
         if pool_write_path(g.tq) == "row":
             with jax.named_scope(scopes.ATTN_CACHE):
+                first, n = g.start, g.n_valid
+                if cfg.eva_layers:
+                    first, n = chunks_closed(first, n, cfg.chunk)
                 g = g._replace(at=barrier(_row_targets(
-                    g.table, g.start, g.n_valid, cache.blocks, cache.block)))
+                    g.table, first, n, cache.blocks, cache.block)))
                 if g.wtable is not None:
                     g = g._replace(wat=barrier(_row_targets(
                         g.wtable, g.start, g.n_valid, cache.window_blocks,
@@ -1325,7 +1370,9 @@ def paged_step_tokens(cache: Any, cfg: TransformerConfig,
     rows or more, the Q-tiled kernel's over a gathered view). What the
     serve loop counts a tick's work list with (``kv_steps_run``).
     ``window``: the step of a window layer's call against the window pool
-    (the paged kernel at every ``tq``; None for a cache without one)."""
+    (the paged kernel at every ``tq``; None for a cache without one). An
+    EVA model's call against its first pool, the summary rows', is the
+    paged kernel at every ``tq`` too."""
     from tree_attention_tpu.ops.pallas_decode import (
         decode_step_entries, mla_step_entries,
     )
@@ -1342,7 +1389,7 @@ def paged_step_tokens(cache: Any, cfg: TransformerConfig,
                               PagedHybridCache, PagedWindowCache,
                               PagedStateCache)):
         return None
-    if not isinstance(cache, PagedQuantKVCache) \
+    if not isinstance(cache, PagedQuantKVCache) and not cfg.eva_layers \
             and tpu_kernel_for(tq) != "pallas_decode":
         return None
     return cache.block * decode_step_entries(
@@ -1395,8 +1442,11 @@ class _Attend:
     # (:func:`gqa_mixer`): a sliding-window layer's window (its rows go to,
     # and are read from, the pools under the groups' second table, through
     # the window's work list), and whether queries and keys are rotated.
-    window: Optional[int] = None
+    window: Any = None
     rotary: bool = True
+    # The caller merges this call's partial with another's (an EVA layer):
+    # the output comes back as ``(out, lse)``.
+    partial: bool = False
 
     def __call__(self, gi, q, k_new, v_new, k_cache, v_cache, k_s, v_s,
                  views, l, base):
@@ -1564,10 +1614,12 @@ class _Attend:
             else:
                 # Exact caches — and the paged-quant DEQUANTIZED view (the
                 # off-kernel path; see the hoist_view comment above).
-                out, _ = decode_attention(
+                out, lse = decode_attention(
                     q, ak, av,
                     impl=cfg.attn_impl, num_splits=num_splits, **attn_kw,
                 )
+                if self.partial:
+                    out = (out, lse)
         return out, k_cache, v_cache, k_s, v_s
 
 
@@ -1646,12 +1698,15 @@ def _check_block_cache(cache: Any, cfg: TransformerConfig) -> None:
             else "hybrid" if isinstance(cache, PagedHybridCache)
             else "window" if isinstance(cache, PagedWindowCache)
             else "state" if isinstance(cache, PagedStateCache) else "kv")
+    if kind == "window" and cfg.cache_kind == "eva":
+        kind = "eva"   # the same two pools under the EVA row rule
     if kind != cfg.cache_kind:
         raise ValueError(
             f"this model caches {cfg.cache_kind!r} state "
             f"(TransformerConfig.cache_kind: a latent pool for latent "
             f"attention, the hybrid pool for conv layers or experts under "
-            f"rotary GQA, the window pools for sliding-window layers, the "
+            f"rotary GQA, the window pools for sliding-window layers or EVA "
+            f"layers, the "
             f"state pool for state-space layers, K/V buffers for the dense "
             f"block) and is served "
             f"from the cache init_paged_cache builds for it and no other; "
@@ -2665,7 +2720,7 @@ def decode_attention(
     kv_shard: str = "replicated",
     scale: Optional[float] = None,
     step_plan: Any = None,
-    window: Optional[int] = None,
+    window: Any = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Op-level decode entry: split-KV on one device, tree merge on a mesh.
 

@@ -1,14 +1,15 @@
 """Layers of several kinds in one model: the gated short-convolution mixer,
 its state as a two-row tail of each pool block, the state-space mixer, its
-state an array a slot, and the layer loop over runs of layers of one kind.
+state an array a slot, the EVA mixer, its cache two kinds of row, and the
+layer loop over runs of layers of one kind.
 
 A layer is a mixer and at most one feed-forward half. The mixer is
 rotary-GQA attention
 (:func:`~.decode.gqa_mixer`, the dense block's own: over the whole context,
 or, a ``window`` layer, over the last ``cfg.window`` positions, its rows in a
 pool and under a table of their own, :class:`~.decode.PagedWindowCache`), a
-gated short convolution (:func:`conv_mixer`) or a state-space mixer
-(:func:`ssm_mixer`); the feed-forward half the dense SwiGLU, the
+gated short convolution (:func:`conv_mixer`), a state-space mixer
+(:func:`ssm_mixer`) or EVA attention (:func:`eva_mixer`); the feed-forward half the dense SwiGLU, the
 routed-expert layer (:func:`~.experts.expert_layer`) or none. ``cfg.layer_types``
 and ``cfg.ffn_kinds`` say which layer is what; :func:`hybrid_layers` cuts the
 depth into runs of consecutive layers of one (mixer, feed-forward) kind and
@@ -43,6 +44,19 @@ group ``i // (heads / groups)``::
 
 Its state at position ``t`` is ``S_{t-1}`` and the last ``taps - 1``
 pre-activation ``xBC`` rows (:class:`~.decode.PagedStateCache`).
+
+The EVA mixer (``W`` = ``cfg.window``, ``C`` = ``cfg.chunk``, ``s = d^-1/2``,
+``phi`` and ``mu`` two learned vectors a head), for rotated ``q, k, v`` and a
+row at ``t``, ``w0 = (t // W) * W``::
+
+    alpha_m = softmax_{m in chunk c}(k_m . phi)      (no s; after the rotary)
+    k~_c = sum_m alpha_m k_m + mu,   v~_c = sum_m alpha_m v_m
+    o_t = [sum_{j=w0..t} e^{s q_t.k_j} v_j + sum_{c < w0/C} e^{s q_t.k~_c} v~_c]
+          / [the same sums without v]
+
+one softmax over the exact rows of the row's own window and one summary row
+for every chunk of every window closed before it, computed as two partials
+(each pool's paged call) joined by ``ops/reference.py`` ``merge_partials``.
 """
 
 from __future__ import annotations
@@ -63,15 +77,22 @@ from tree_attention_tpu.models.decode import (
     _Attend,
     _RowGroup,
     _join_rows,
+    _pool_write,
+    chunks_closed,
+    decode_attention,
     gqa_mixer,
+    window_rules,
 )
 from tree_attention_tpu.models.transformer import (
     Params,
     StateSpace,
     TransformerConfig,
     _mlp_block,
+    _unheads,
+    gqa_qkv,
     rms_norm,
 )
+from tree_attention_tpu.ops.reference import merge_partials
 from tree_attention_tpu.obs import scopes
 
 
@@ -367,14 +388,142 @@ def ssm_mixer(layer: Params, x: jax.Array, state: jax.Array,
             wrote)
 
 
+# ---------------------------------------------------------------------------
+# The EVA mixer
+# ---------------------------------------------------------------------------
+
+
+def _chunk_rows(pools: Tuple[jax.Array, ...], blk: jax.Array,
+                row: jax.Array, count: jax.Array, chunk: int
+                ) -> Tuple[jax.Array, ...]:
+    """``chunk`` consecutive rows from row ``row`` of block ``blk`` of every
+    flat pool ``(blocks, Hkv, block, D)``, for ``(batch, J)`` of each:
+    ``(batch, J, Hkv, chunk, D)`` a pool. A slice a chunk, never the block:
+    on a TPU one kernel's copies (``ops/pallas_decode.py``
+    ``paged_chunk_read``, the first ``count`` of a member's only; handed to
+    XLA as a gather, the compiler re-laid the whole pool for it), elsewhere
+    a gather."""
+    from tree_attention_tpu.ops import _on_tpu, _pallas_available
+
+    if _on_tpu() and _pallas_available():
+        from tree_attention_tpu.ops.pallas_decode import paged_chunk_read
+
+        return paged_chunk_read(pools, blk, row, count, chunk)
+    _, Hkv, _, D = pools[0].shape
+
+    def one(flat):
+        def cut(b, r):
+            return lax.dynamic_slice(flat, (b, 0, r, 0), (1, Hkv, chunk, D))[0]
+
+        return jax.vmap(jax.vmap(cut))(blk, row)
+
+    return tuple(one(flat) for flat in pools)
+
+
+def eva_summaries(layer: Params, g: _RowGroup, wk: jax.Array, wv: jax.Array,
+                  l, cfg: TransformerConfig
+                  ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """The summaries of the chunks that the rows of ``g`` close, from the
+    chunk's rows AS THE LOCAL POOL HOLDS THEM (``wk`` / ``wv``, this layer's
+    blocks at ``l * Nw``, the group's rows already written): a pure
+    function of sixteen pool rows, so nothing is kept between ticks, and a
+    chunk a prompt began and a decode row ends is formed like any other. A
+    chunk closes inside its own window, whose blocks the slot still holds.
+    Returns ``(k~, v~)`` ``(batch, Hkv, J, D)`` in the pool's type, ``J`` the
+    most chunks ``tq`` rows can close, and ``(first, count)``: member ``i``'s
+    are summary rows ``first[i] .. first[i] + count[i] - 1``, candidates
+    past ``count`` are of rows not written and dropped by the write."""
+    C = cfg.chunk
+    L, Nw, Hkv, block, D = wk.shape
+    first, count = chunks_closed(g.start, g.n_valid, C)
+    J = -(-g.tq // C)
+    pos = (first[:, None] + jnp.arange(J, dtype=jnp.int32)) * C
+    pb = jnp.take_along_axis(
+        g.wtable, jnp.clip(pos // block, 0, g.wtable.shape[1] - 1), axis=1)
+    blk = l * Nw + jnp.clip(pb, 0, Nw - 1)
+    kc, vc = (a.astype(jnp.float32) for a in _chunk_rows(
+        (wk.reshape(L * Nw, Hkv, block, D), wv.reshape(L * Nw, Hkv, block, D)),
+        blk, pos % block, count, C))
+    phi = layer["phi"].astype(jnp.float32)[:, None, :]       # (Hkv, 1, D)
+    # Products and sums of float32 on the vector unit: a weight is never
+    # rounded on its way into a matrix unit.
+    alpha = jax.nn.softmax(jnp.sum(kc * phi, axis=-1), axis=-1)[..., None]
+    ks = jnp.sum(alpha * kc, axis=-2) + layer["mu"].astype(jnp.float32)
+    vs = jnp.sum(alpha * vc, axis=-2)                        # (b, J, Hkv, D)
+    return (jnp.swapaxes(ks, 1, 2).astype(wk.dtype),
+            jnp.swapaxes(vs, 1, 2).astype(wv.dtype), first, count)
+
+
+def eva_mixer(attend: _Attend, layer: Params, x: jax.Array,
+              positions: jax.Array, k: jax.Array, v: jax.Array,
+              wk: jax.Array, wv: jax.Array, l):
+    """EVA attention over every group of the step's rows. ``attend`` is the
+    rotary-GQA body built for the LOCAL pool (the aligned window, the second
+    table, partials returned); ``k`` / ``v`` the summary pools, ``wk`` /
+    ``wv`` the exact rows', whole, this layer's blocks at ``l * N`` / ``l *
+    Nw``. A group's rows go into the local pool; the chunks they close are
+    summarised from it and written to the summary pool
+    (:func:`eva_summaries`); its queries take one partial from each pool,
+    and ``merge_partials`` makes of the two the one softmax. A group none
+    of whose rows has a closed window behind it makes no second call.
+    Returns the residual with the mixer's output added, the four pools, and
+    the summary rows written."""
+    cfg, groups = attend.cfg, attend.groups
+    rule, _ = window_rules(cfg)
+    N, Nw = k.shape[1], wk.shape[1]
+    with jax.named_scope(scopes.ATTN_IN):
+        h = rms_norm(x, layer["ln1"], cfg.norm_eps)
+        q, k_new, v_new = gqa_qkv(layer, h, positions, cfg)
+    outs, wrote = [], jnp.int32(0)
+    for gi, g in enumerate(groups):
+        (out, lse), wk, wv, _, _ = attend(
+            gi, q, k_new, v_new, wk, wv, None, None, None, l, l * Nw)
+        with jax.named_scope(scopes.ATTN_CACHE), \
+                jax.named_scope(scopes.EVA_SUMMARY):
+            ks, vs, first, count = eva_summaries(layer, g, wk, wv, l, cfg)
+            k, v = _pool_write((k, v), (ks, vs), g.table, first, count, l,
+                               g.at)
+            wrote = wrote + jnp.sum(count, dtype=jnp.int32)
+        with jax.named_scope(
+                scopes.ATTN_CHUNK if g.chunk else scopes.ATTN_DECODE):
+            qg = g.take(q)
+
+            def both(out, lse):
+                # (Traced here and now: the loop's names are this group's.)
+                o2, l2 = decode_attention(
+                    qg, k.reshape((-1,) + k.shape[2:]),
+                    v.reshape((-1,) + v.shape[2:]), q_position=g.start,
+                    block_table=l * N + g.table,
+                    step_plan=g.plan and g.plan.shifted(l * N),
+                    window=rule, mesh=attend.mesh,
+                    data_axis=None, seq_axis=attend.axes["seq"],
+                    model_axis=attend.axes["model"],
+                    block_size=cfg.attn_block_size, impl=cfg.attn_impl,
+                    num_splits=attend.num_splits)
+                return merge_partials(
+                    jnp.stack([out, o2]), jnp.stack([lse, l2]))[0]
+
+            # A row sees a summary once its position has passed a window.
+            last = g.start + g.n_valid - 1
+            out = lax.cond(
+                jnp.any((g.n_valid > 0) & (last >= cfg.window)),
+                both, lambda out, lse: out, out, lse)
+        outs.append(out)
+    with jax.named_scope(scopes.ATTN_DECODE):
+        out = _join_rows(groups, outs)
+    with jax.named_scope(scopes.ATTN_OUT):
+        x = x + _unheads(out) @ layer["wo"]
+    return x, k, v, wk, wv, wrote
+
+
 def layer_runs(cfg: TransformerConfig) -> List[Tuple[str, str, int, int, int]]:
     """The depth cut into runs of consecutive layers of one kind:
     ``(mixer, ffn, layers, first of its mixer kind, first of its ffn
     kind)``, the two offsets counted among the layers of that kind."""
     types = cfg.layer_types or ("attention",) * cfg.n_layers
     runs: List[list] = []
-    seen = {"attention": 0, "window": 0, "conv": 0, "ssm": 0, "dense": 0,
-            "expert": 0, "none": 0}
+    seen = {"attention": 0, "window": 0, "conv": 0, "ssm": 0, "eva": 0,
+            "dense": 0, "expert": 0, "none": 0}
     for mixer, ffn in zip(types, cfg.ffn_kinds):
         if runs and runs[-1][:2] == [mixer, ffn]:
             runs[-1][2] += 1
@@ -402,7 +551,8 @@ def hybrid_layers(
     stack a kind on a leading axis of that kind's layers: ``attn``
     (``ln1``, ``wq``, ``wk``, ``wv``, ``wo``, with QK-norm ``q_ln`` /
     ``k_ln``), ``wattn`` (the window layers: the same leaves), ``conv``
-    (:func:`conv_mixer`'s leaves), ``ssm`` (:func:`ssm_mixer`'s), ``dense``
+    (:func:`conv_mixer`'s leaves), ``ssm`` (:func:`ssm_mixer`'s), ``eva``
+    (the attention leaves and ``phi`` / ``mu`` ``(Hkv, d)``), ``dense``
     (``ln2``, ``w1``, ``w3``, ``w2``) and ``layers`` (the expert layers:
     ``ln2``, ``router``, ``router_bias``, ``we1`` / ``we3`` / ``we2`` and
     the shared experts' ``ws*``; experts in a latent: ``w_down`` / ``w_up``
@@ -421,6 +571,11 @@ def hybrid_layers(
         attend_w = dataclasses.replace(
             attend, window=cfg.window, rotary=cfg.rotates("window"))
         Nw = cache.window_blocks
+    if cfg.eva_layers:
+        # ... and an EVA layer's, for its exact rows: the aligned window
+        # over the second table's pools, its partial handed back.
+        attend_w = dataclasses.replace(
+            attend, window=window_rules(cfg)[1], partial=True)
     valid = groups[0].valid
     if groups[0].lo is not None:
         valid = jnp.concatenate([g.valid.reshape(-1) for g in groups])[None]
@@ -457,6 +612,10 @@ def hybrid_layers(
                 x, wk, wv, _, _ = gqa_mixer(
                     attend_w, of(params["wattn"], mi), x, positions, wk, wv,
                     None, None, None, mi, mi * Nw)
+            elif mixer == "eva":
+                x, k, v, wk, wv, wrote = eva_mixer(
+                    attend_w, of(params["eva"], mi), x, positions, k, v,
+                    wk, wv, mi)
             else:
                 with jax.named_scope(scopes.CONV):
                     x, tail, wrote = conv_mixer(
@@ -508,8 +667,10 @@ def hybrid_layers(
             stats["tail_blocks"] = wrote
         if cfg.ssm_layers:
             stats["ssm_states"] = wrote
+        if cfg.eva_layers:
+            stats["eva_summaries"] = wrote
     x, k, v, tail, wk, wv, state, stail = carry
-    if cfg.window_layers:
+    if cfg.window_layers or cfg.eva_layers:
         return x, {"k": k, "v": v, "wk": wk, "wv": wv}
     if cfg.ssm_layers:
         return x, {"k": k, "v": v, "ssm_state": state, "ssm_tail": stail}
@@ -548,6 +709,14 @@ def init_hybrid_params(key: jax.Array, cfg: TransformerConfig) -> Params:
                 out["q_ln"] = jnp.ones((cfg.d_head,), jnp.float32)
                 out["k_ln"] = jnp.ones((cfg.d_head,), jnp.float32)
             return out
+
+        def eva(k):
+            # phi spreads a chunk's weights (k . phi of the order of 1), mu
+            # is of a pooled key's size.
+            kp, km = jax.random.split(jax.random.fold_in(k, 1))
+            hd = (cfg.n_kv_heads, cfg.d_head)
+            return {**attn(k), "phi": normal(kp, hd, 1.0),
+                    "mu": normal(km, hd, 0.02 * D ** 0.5)}
 
         def conv(k):
             k = jax.random.split(k, 3)
@@ -634,10 +803,12 @@ def init_hybrid_params(key: jax.Array, cfg: TransformerConfig) -> Params:
             "ln_f": jnp.ones((D,), jnp.float32),
         }
         if not cfg.tied_head:
-            out["wout"] = normal(ks[1], (D, cfg.vocab_size), 0.02)
+            out["wout"] = normal(
+                ks[1], (D, cfg.pred_heads * cfg.vocab_size), 0.02)
         n_dense = cfg.n_dense_layers
         for name, make_one, n, k in (
-                ("attn", attn, cfg.cache_layers, ks[2]),
+                ("attn", attn, cfg.cache_layers - cfg.eva_layers, ks[2]),
+                ("eva", eva, cfg.eva_layers, jax.random.fold_in(ks[2], 2)),
                 ("wattn", attn, cfg.window_layers,
                  jax.random.fold_in(ks[2], 1)),
                 ("conv", conv, cfg.conv_layers, ks[3]),

@@ -231,7 +231,12 @@ PUBLISHED_MIXERS = {
 }
 # ``"ssm"`` has no name in ``layer_types``: its family says what a layer is
 # in ``hybrid_override_pattern``, a character a part (:func:`_pattern_layers`).
-MIXER_KINDS = frozenset(PUBLISHED_MIXERS.values()) | {"ssm"}
+# Nor has ``"eva"``: its family says every layer's in ``attention_class``.
+MIXER_KINDS = frozenset(PUBLISHED_MIXERS.values()) | {"ssm", "eva"}
+# The published ``attention_class`` values built, by the mixer kind they give
+# every layer.
+ATTENTION_CLASSES = {"eva": "eva"}
+WINDOW_RULES = ("sliding", "aligned")
 FFN_KINDS = frozenset({"dense", "expert", "none"})
 
 
@@ -246,7 +251,11 @@ class TransformerConfig:
     ``"window"``: the same attention over the last ``window`` positions
     only, a row at ``t`` sees ``(t - window, t]``; ``"conv"``: a gated
     short convolution of ``conv_taps`` taps; ``"ssm"``: a state-space
-    mixer of ``ssm``'s widths), None meaning rotary GQA
+    mixer of ``ssm``'s widths; ``"eva"``: rotary attention that is exact
+    inside the row's own ALIGNED window, ``[(t // window) * window, t]``
+    (``window_rule`` ``"aligned"``), and sees every window closed before it
+    as one learned summary row a ``chunk`` of positions, both under one
+    softmax), None meaning rotary GQA
     throughout. ``rotary`` names the attention kinds whose queries and
     keys take the rotary embedding (both by default; a model whose full
     layers carry no positional term names ``("window",)``, one with no
@@ -267,7 +276,16 @@ class TransformerConfig:
     window layer's (``window_layers``) K/V rows live in a pool of their
     own under a table of their own, a bounded number of blocks a slot; a
     state-space layer's (``ssm_layers``) state and conv tail are one array
-    a slot, which no table indexes.
+    a slot, which no table indexes; an EVA layer's (``eva_layers``) exact
+    rows live in the window pool as a window layer's do and its summary
+    rows, one a ``chunk`` positions and kept for the request's life, in the
+    pool under the first table.
+
+    ``pred_heads``: the head scores that many next positions a row
+    (``wout`` ``(D, pred_heads x vocab)``); the served logits are the
+    first's. ``norm_offset``: the published norms' gain is ``1 + g``; a
+    norm leaf of these parameters holds the GAIN (float32, so ``1 + g``
+    loses nothing), and whoever loads published weights adds the one.
     """
 
     vocab_size: int = 32768
@@ -300,6 +318,10 @@ class TransformerConfig:
     rotary: Tuple[str, ...] = ("attention", "window")
     ssm: Optional[StateSpace] = None
     ffn_types: Optional[Tuple[str, ...]] = None
+    window_rule: str = "sliding"
+    chunk: int = 0
+    pred_heads: int = 1
+    norm_offset: bool = False
 
     def __post_init__(self):
         if self.n_heads % self.n_kv_heads:
@@ -315,14 +337,32 @@ class TransformerConfig:
                     f"layer_types names {len(self.layer_types)} layers of "
                     f"kinds {sorted(kinds)}: one of {sorted(MIXER_KINDS)} "
                     f"for each of the {self.n_layers} layers")
-            if ("window" in kinds) != (self.window > 0) or (
+            if bool(kinds & {"window", "eva"}) != (self.window > 0) or (
                     "window" in kinds and "conv" in kinds):
                 raise ValueError(
-                    f"window layers {'window' in kinds} with window="
-                    f"{self.window}: a model with sliding-window layers "
-                    f"states their window (> 0), a model without states "
-                    f"none, and window layers beside conv layers are not "
-                    f"built")
+                    f"window layers {bool(kinds & {'window', 'eva'})} with "
+                    f"window={self.window}: a model with sliding-window "
+                    f"layers states their window (> 0), a model without "
+                    f"states none, and window layers beside conv layers "
+                    f"are not built")
+            if "eva" in kinds and (
+                    kinds != {"eva"} or self.moe is not None
+                    or self.window_rule != "aligned" or self.chunk < 1
+                    or self.window % self.chunk):
+                raise ValueError(
+                    f"an 'eva' layer beside layers of kinds "
+                    f"{sorted(kinds - {'eva'})} (experts: "
+                    f"{self.moe is not None}), window_rule "
+                    f"{self.window_rule!r}, window {self.window}, chunk "
+                    f"{self.chunk}: EVA attention is built in every layer "
+                    f"of a model, over the dense FFN, under the 'aligned' "
+                    f"window rule, its window a whole number of chunks")
+            if "eva" not in kinds and (
+                    self.window_rule != "sliding" or self.chunk):
+                raise ValueError(
+                    f"window_rule {self.window_rule!r}, chunk {self.chunk} "
+                    f"without 'eva' layers: a sliding-window layer's "
+                    f"window slides and has no chunk summaries")
             if self.mla is not None or self.sublayers > 1:
                 raise ValueError(
                     "layers of several kinds are built over rotary-GQA "
@@ -345,10 +385,15 @@ class TransformerConfig:
             raise ValueError(
                 "state-space widths without layer_types: which layers are "
                 "state-space layers is said a layer")
-        elif self.window:
+        elif self.window or self.chunk or self.window_rule != "sliding":
             raise ValueError(
-                f"window={self.window} without layer_types: which layers "
-                f"are sliding-window layers is said a layer")
+                f"window={self.window} (rule {self.window_rule!r}, chunk "
+                f"{self.chunk}) without layer_types: which layers are "
+                f"sliding-window or EVA layers is said a layer")
+        if self.window_rule not in WINDOW_RULES or self.pred_heads < 1:
+            raise ValueError(
+                f"window_rule {self.window_rule!r} (one of {WINDOW_RULES}) "
+                f"with pred_heads {self.pred_heads} (>= 1)")
         # A configuration read back from JSON (a checkpoint's sidecar) holds
         # a list: the dataclass stays hashable, a jit static.
         object.__setattr__(self, "rotary", tuple(self.rotary))
@@ -409,6 +454,13 @@ class TransformerConfig:
         return (self.layer_types or ()).count("ssm")
 
     @property
+    def eva_layers(self) -> int:
+        """Layers whose mixer is EVA attention: the depth of both of their
+        pools (exact rows under the second table, summary rows under the
+        first)."""
+        return (self.layer_types or ()).count("eva")
+
+    @property
     def ffn_kinds(self) -> Tuple[str, ...]:
         """Each layer's feed-forward kind: ``ffn_types`` where the data says
         it a layer, else ``moe.first_dense`` leading dense layers and expert
@@ -433,8 +485,8 @@ class TransformerConfig:
         """The Llama-style block this module's ``forward`` computes."""
         return self.mla is None and self.moe is None \
             and not self.conv_layers and not self.window_layers \
-            and not self.ssm_layers and self.ffn_types is None \
-            and self.rotates("attention")
+            and not self.ssm_layers and not self.eva_layers \
+            and self.ffn_types is None and self.rotates("attention")
 
     @property
     def cache_kind(self) -> str:
@@ -446,9 +498,14 @@ class TransformerConfig:
         layers' rows a token, the sliding-window layers' a bounded number
         of blocks a slot) or ``"state"`` (K/V rows for the attention layers
         beside a recurrent state and a conv tail a slot for the
-        state-space layers, which every token rewrites whole)."""
+        state-space layers, which every token rewrites whole) or ``"eva"``
+        (two K/V pools of every layer under two tables: exact rows of the
+        open window, a bounded number of blocks a slot, and one summary row
+        a chunk of every closed window, kept)."""
         if self.mla is not None:
             return "latent"
+        if self.eva_layers:
+            return "eva"
         if self.window_layers:
             return "window"
         if self.ssm_layers:
@@ -609,7 +666,15 @@ def model_from_config(config: Dict[str, Any], *, dtype: Any = None,
       attention, ``E`` routed experts, ``-`` a dense FFN. A mixer and the
       feed-forward part right after it are one of this repo's layers, a
       mixer followed by a mixer a layer with no feed-forward half
-      (:func:`_pattern_layers`).
+      (:func:`_pattern_layers`). Or ``attention_class`` (the ``evabyte``
+      family; :data:`ATTENTION_CLASSES`, any other value refused by name)
+      gives every layer one kind: ``eva``, with ``window_size`` and
+      ``chunk_size``; the family's ``num_pred_heads`` (next-position blocks
+      of the head; the served logits are the first's),
+      ``norm_add_unit_offset`` (``TransformerConfig.norm_offset``), and
+      ``fp32_skip_add`` / ``fp32_logits``, which must be true where given:
+      the residual's add rounds once from the exact sum and the logits are
+      float32 in every program here.
     - each layer's feed-forward half: ``n_routed_experts`` /
       ``num_experts`` select the expert layer after the first
       ``first_k_dense_replace`` / ``num_dense_layers`` layers (an expert's
@@ -804,6 +869,35 @@ def model_from_config(config: Dict[str, Any], *, dtype: Any = None,
                     f"sliding_windows {sorted(set(own))}: every "
                     f"sliding_attention layer at sliding_window "
                     f"({window}) and every other at 0 is what is built")
+    window_rule, chunk = "sliding", 0
+    if c.get("attention_class") is not None:
+        said = str(c["attention_class"])
+        if said not in ATTENTION_CLASSES or layer_types is not None:
+            raise ValueError(
+                f"attention_class {said!r} (beside layer_types or "
+                f"hybrid_override_pattern: {layer_types is not None}): the "
+                f"classes built are {sorted(ATTENTION_CLASSES)}, each "
+                f"every layer's kind")
+        # What only the modelling code says, each rule with the one value
+        # built; another reading is refused by name, never built wrong.
+        for key, built in (("summary_key", "weighted_plus_mu"),
+                           ("summary_logits_scaled", False),
+                           ("summary_after_rotary", True),
+                           ("window_rule", "aligned")):
+            if block.get(key, built) != built:
+                raise ValueError(
+                    f"block.{key} {block[key]!r}: only {built!r} is built "
+                    f"for attention_class {said!r}")
+        for key in ("fp32_skip_add", "fp32_logits"):
+            if c.get(key, True) is not True:
+                raise ValueError(
+                    f"{key} {c[key]!r}: the residual's add and the logits "
+                    f"are float32 in every program here; only true is "
+                    f"built")
+        n = int(_key(c, "num_hidden_layers", "num_layers"))
+        layer_types = (ATTENTION_CLASSES[said],) * n
+        window, chunk = int(c["window_size"]), int(c["chunk_size"])
+        window_rule = "aligned"
     rp = c.get("rope_parameters") or {}
     if not c.get("kv_lora_rank") and str(
             rp.get("rope_type", "default")) != "default":
@@ -845,6 +939,9 @@ def model_from_config(config: Dict[str, Any], *, dtype: Any = None,
         tied_head=bool(c.get("tie_word_embeddings")
                        or c.get("tie_embedding")),
         window=window, rotary=rotary, ssm=ssm, ffn_types=ffn_types,
+        window_rule=window_rule, chunk=chunk,
+        pred_heads=int(c.get("num_pred_heads", 1)),
+        norm_offset=bool(c.get("norm_add_unit_offset", False)),
     )
     kw.update(overrides)
     return TransformerConfig(**kw)
@@ -863,7 +960,7 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Params:
     scaled by ``(2·n_layers)^-1/2`` so the residual stream's variance stays O(1)
     at init regardless of depth.
     """
-    if cfg.cache_kind in ("hybrid", "window", "state"):
+    if cfg.cache_kind in ("hybrid", "window", "state", "eva"):
         from tree_attention_tpu.models.hybrid import init_hybrid_params
 
         return init_hybrid_params(key, cfg)
@@ -999,9 +1096,14 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 def unembed(params: Params, x: jax.Array) -> jax.Array:
     """The head: ``x`` times ``wout``, or, where the parameters hold none
     (``TransformerConfig.tied_head``), times the embedding's transpose.
-    Float32 logits."""
+    Float32 logits. A head of several next-position blocks
+    (``TransformerConfig.pred_heads``: ``wout`` wider than the vocabulary)
+    serves its first block, the next position's."""
     if "wout" in params:
-        return (x @ params["wout"]).astype(jnp.float32)
+        wout, vocab = params["wout"], params["embed"].shape[0]
+        if wout.shape[-1] != vocab:
+            wout = wout[:, :vocab]
+        return (x @ wout).astype(jnp.float32)
     return jnp.einsum("...d,vd->...v", x, params["embed"]).astype(jnp.float32)
 
 

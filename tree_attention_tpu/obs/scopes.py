@@ -26,7 +26,8 @@ from typing import Dict, Iterator, List, NamedTuple, Optional, Tuple
 #: One vocabulary (ARCHITECTURE.md "Observability", PERF.md section 3).
 EMBED = "embed"              # the token rows' gather
 ATTN_IN = "attn_in"          # pre-norm, q/k/v or the latent products, rotary
-ATTN_CACHE = "attn_cache"    # the new rows' write into the paged pool
+ATTN_CACHE = "attn_cache"    # a pool write, and a summary formed from
+                             # written rows (an EVA layer's, one a chunk)
 ATTN_DECODE = "attn_decode"  # the decode rows' attention, merge, unpacking
 ATTN_CHUNK = "attn_chunk"    # a packed tick's chunk rows' attention
 ATTN_OUT = "attn_out"        # the output projection and the residual add
@@ -45,7 +46,10 @@ SCOPES = (EMBED, ATTN_IN, ATTN_CACHE, ATTN_DECODE, ATTN_CHUNK, ATTN_OUT,
 #: scan, the decode step, the gate with the grouped norm.
 SSM_TAPS, SSM_SCAN, SSM_UPDATE, SSM_NORM = (
     "ssm_taps", "ssm_scan", "ssm_update", "ssm_norm")
-INNER = (SSM_TAPS, SSM_SCAN, SSM_UPDATE, SSM_NORM)
+#: ... and inside ``attn_cache``: an EVA layer's chunk summaries, read back
+#: from the rows as the pool holds them, pooled and written.
+EVA_SUMMARY = "eva_summary"
+INNER = (SSM_TAPS, SSM_SCAN, SSM_UPDATE, SSM_NORM, EVA_SUMMARY)
 
 #: Opcodes whose result names a buffer and moves no byte of it: they run as
 #: no device operation, so a table leaves them out.

@@ -8,7 +8,7 @@ silently diverge from the forward — so the logic lives once, here.
 from __future__ import annotations
 
 import numbers
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -214,6 +214,54 @@ def culled_qi(ki, qi, cull, block_q: int, block_k: int, n_q: int):
     )
 
 
+class AlignedWindow(NamedTuple):
+    """A window that is a BLOCK of positions: a row at ``t`` sees ``[w0,
+    t]``, ``w0 = (t // window) * window`` (the last ``window`` positions
+    only at a window's last row)."""
+
+    window: int
+
+
+class ChunkSummaries(NamedTuple):
+    """Columns that are not positions but one row a ``chunk`` of positions
+    (column ``c`` stands for ``[c * chunk, (c + 1) * chunk)``): a row at
+    ``t`` sees the chunks of every window closed before its own, ``c * chunk
+    < (t // window) * window``. The partner of :class:`AlignedWindow`."""
+
+    window: int
+    chunk: int
+
+
+# What ``window=`` may say, everywhere it is taken: an int is the sliding
+# rule, a row at ``t`` sees ``(t - window, t]``.
+WindowRule = Union[int, AlignedWindow, ChunkSummaries]
+
+
+def window_low(window: "WindowRule", q_pos):
+    """The lowest POSITION a row at ``q_pos`` sees under a position rule
+    (sliding or aligned), not clipped at 0."""
+    if isinstance(window, AlignedWindow):
+        return q_pos - q_pos % window.window
+    return q_pos - (window - 1)
+
+
+def summaries_seen(window: ChunkSummaries, q_pos):
+    """Summary rows a row at ``q_pos`` sees: the chunks under its window."""
+    return (q_pos - q_pos % window.window) // window.chunk
+
+
+def window_visible(window: "WindowRule", q_pos, k_pos):
+    """Whether a row at ``q_pos`` sees column ``k_pos`` under ``window``,
+    beside the causal term (which every rule keeps: a summary's column
+    index lies under its row's position too). Compares, a remainder and a
+    product only, so that a kernel body can say it."""
+    if isinstance(window, ChunkSummaries):
+        return k_pos * window.chunk < q_pos - q_pos % window.window
+    if isinstance(window, AlignedWindow):
+        return k_pos >= window_low(window, q_pos)
+    return k_pos > q_pos - window
+
+
 def tile_mask(
     tq: int,
     blk: int,
@@ -222,16 +270,17 @@ def tile_mask(
     q_offset,
     kv_offset,
     causal: bool,
-    window: Optional[int] = None,
+    window: Optional[WindowRule] = None,
 ) -> jax.Array:
     """(tq, blk) visibility mask for one KV tile.
 
     Combines the ragged-tail range check (padded keys beyond ``tk`` are
     invalid) with cross-shard causality: query global position
     ``q_offset + row`` sees key global position ``kv_offset + start + col``
-    iff q_pos >= k_pos; with ``window`` also only iff ``k_pos > q_pos -
-    window`` (a sliding-window layer: a row sees its last ``window``
-    positions, itself included).
+    iff q_pos >= k_pos; with ``window`` also only iff the rule says so
+    (:func:`window_visible`; an int: ``k_pos > q_pos - window``, a
+    sliding-window layer: a row sees its last ``window`` positions, itself
+    included).
     """
     start = blk_idx * blk
     local_col = start + lax.broadcasted_iota(jnp.int32, (tq, blk), 1)
@@ -240,5 +289,6 @@ def tile_mask(
         q_pos = q_offset + lax.broadcasted_iota(jnp.int32, (tq, blk), 0)
         valid = valid & (q_pos >= kv_offset + local_col)
         if window is not None:
-            valid = valid & (kv_offset + local_col > q_pos - window)
+            valid = valid & window_visible(
+                window, q_pos, kv_offset + local_col)
     return valid

@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from tree_attention_tpu import obs
-from tree_attention_tpu.ops.block_utils import pad_to_block
+from tree_attention_tpu.ops.block_utils import WindowRule, pad_to_block
 from tree_attention_tpu.ops.reference import attention_blockwise, merge_partials
 from tree_attention_tpu.utils.logging import get_logger
 
@@ -113,7 +113,7 @@ def flash_decode(
     block_table: Optional[jax.Array] = None,
     tree_mask: Optional[jax.Array] = None,
     step_plan=None,
-    window: Optional[int] = None,
+    window: Optional[WindowRule] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Causal decode attention of a few new queries against a long KV buffer.
 
@@ -175,7 +175,11 @@ def flash_decode(
     decode kernel at EVERY ``Tq`` (its work list starts at the step that
     holds the lowest visible position, so a 256-row chunk reads ``window +
     256`` tokens through the table and never gathers the context); every
-    other call masks on the chunked path.
+    other call masks on the chunked path. ``window`` may also be one of
+    ``block_utils``' other rules: :class:`~.block_utils.AlignedWindow` (a
+    row sees its own block of ``window`` positions up to itself) or
+    :class:`~.block_utils.ChunkSummaries` (the pool's rows are one summary
+    a chunk; a row sees those of the windows closed before its own).
     """
     B, Hq, Tq, D = q.shape
     if window is not None and tree_mask is not None:

@@ -50,9 +50,13 @@ from tree_attention_tpu import obs
 from tree_attention_tpu.ops.block_utils import (
     LANES as _LANES,
     NEG_INF,
+    AlignedWindow,
+    ChunkSummaries,
+    WindowRule,
     matmul_precision,
     offsets_smem as _offsets_smem,
     pad_to_block as _pad_dim,
+    window_visible,
 )
 
 # The wrappers below are jitted, so their Python bodies run once per
@@ -109,7 +113,11 @@ def _decode_visibility_mask(s, qi, si, *, bq, bk, tq, tk,
     ``window`` (requires ``causal``, no ``tree_bits``): a sliding-window
     layer's lower edge, one more compare beside the causal one: row ``j``
     at position ``p`` sees the columns in ``(p - window, p]``, each row of
-    a chunk its own.
+    a chunk its own. It may also be an aligned window's lower edge (``col
+    >= (p // W) * W``) or the summary rule (``col < (p // W) * W / C``,
+    the causal term then always true): ``block_utils.window_visible`` is
+    the one definition, per row, so that a chunk group whose rows straddle
+    a window boundary gives each row its own window.
     """
     needs_ragged = tk % bk != 0
     if tree_bits is not None:
@@ -137,7 +145,7 @@ def _decode_visibility_mask(s, qi, si, *, bq, bk, tq, tk,
         )
         c = (kv_offset + col_idx) <= q_pos
         if window is not None:
-            c &= (kv_offset + col_idx) > q_pos - window
+            c &= window_visible(window, q_pos, kv_offset + col_idx)
         valid = c if valid is None else valid & c
     return jnp.where(valid, s, NEG_INF)
 
@@ -384,6 +392,19 @@ PLAN_SCOPE = "paged_plan"
 # a name that does not contain a full layer's ("flash_decode_paged"), which
 # the benchmark's readers match by substring.
 WINDOW_KERNEL = "window_decode_paged"
+# ... and of an EVA layer's two calls (``models/hybrid.py`` ``eva_mixer``):
+# its exact rows under the aligned lower edge, and its summary rows. Neither
+# contains another paged kernel's name: their cost files count other rows.
+EVA_LOCAL_KERNEL, EVA_SUMMARY_KERNEL = "eva_local_decode", "eva_summary_decode"
+
+
+def window_kernel_name(window: WindowRule) -> str:
+    """The kernel name of a paged call under ``window``."""
+    if isinstance(window, AlignedWindow):
+        return EVA_LOCAL_KERNEL
+    if isinstance(window, ChunkSummaries):
+        return EVA_SUMMARY_KERNEL
+    return WINDOW_KERNEL
 
 
 def paged_step_plan(live: jax.Array, n_steps: int,
@@ -447,16 +468,18 @@ class PagedPlan(NamedTuple):
 
 def paged_plan(q_offset, kv_offset, block_table: jax.Array, *, tq: int,
                entries: int, block: int, causal: bool = True,
-               window: Optional[int] = None) -> PagedPlan:
+               window: Optional[WindowRule] = None) -> PagedPlan:
     """The plan of a paged call of ``tq`` rows a slot against
     ``block_table`` ``(B, NB)`` of blocks of ``block`` tokens, ``entries``
     of them a grid step. With ``window`` (a sliding-window layer) a slot's
     list starts at the step that holds ``max(0, q_offset - window + 1)``,
     the lowest position its first row sees, and so holds one or two steps
-    whatever the slot's length."""
-    from tree_attention_tpu.ops.tuning import (
-        paged_first_step, paged_live_steps,
-    )
+    whatever the slot's length. An aligned window's list starts at the step
+    that holds the first row's ``w0`` (the lowest of the group's, where its
+    rows straddle a boundary); a summary call's list holds the steps under
+    the LAST row's ``w0 / chunk`` summary rows and none for a slot whose
+    rows have no closed window behind them."""
+    from tree_attention_tpu.ops.tuning import paged_rule_steps
 
     B, NB = block_table.shape
     n_steps = NB // entries
@@ -464,19 +487,14 @@ def paged_plan(q_offset, kv_offset, block_table: jax.Array, *, tq: int,
     # (``tests/test_chip_compile.py``: not in a layer loop's body).
     with jax.named_scope(PLAN_SCOPE):
         offs = _offsets_smem(q_offset, kv_offset, B)
-        first = None
-        if window is not None:
-            if not causal:
-                raise ValueError("a sliding window requires causal=True")
-            low = jnp.maximum(offs[0] - (window - 1), 0)
-            first = paged_first_step(low, offs[1], entries * block, n_steps)
-            live = paged_live_steps(
-                offs[0], offs[1], tq, entries * block, n_steps, low)
-        elif causal:
-            live = paged_live_steps(
-                offs[0], offs[1], tq, entries * block, n_steps)
+        if window is not None and not causal:
+            raise ValueError("a sliding window requires causal=True")
+        if causal:
+            first, live = paged_rule_steps(
+                offs[0], offs[1], tq, entries * block, n_steps, window,
+                jnp.maximum)
         else:
-            live = jnp.full((B,), n_steps, jnp.int32)
+            first, live = None, jnp.full((B,), n_steps, jnp.int32)
         slot, step, flags, count = paged_step_plan(live, n_steps, first)
         at = (slot * NB + step * entries)[:, None] \
             + jnp.arange(entries, dtype=jnp.int32)[None, :]
@@ -487,7 +505,7 @@ def paged_plan(q_offset, kv_offset, block_table: jax.Array, *, tq: int,
 
 def _plan_operands(plan: Optional[PagedPlan], q_offset, kv_offset,
                    block_table: jax.Array, *, tq: int, entries: int,
-                   block: int, causal: bool, window: Optional[int] = None):
+                   block: int, causal: bool, window: Optional[WindowRule] = None):
     """``(the five scalar-prefetch operands, the dynamic grid bound)`` of a
     paged call, from the caller's plan or one built here."""
     B, NB = block_table.shape
@@ -545,7 +563,7 @@ def _paged_decode_step(
     tree: bool,
     block_scales: bool,
     local_blocks: bool,
-    window: Optional[int] = None,
+    window: Optional[WindowRule] = None,
 ):
     """One grid step of the paged decode kernels: every KV head of
     ``entries`` consecutive table entries of one slot.
@@ -816,7 +834,7 @@ def _paged_decode_call(
     kv_offset,
     block_table: jax.Array,
     step_plan: Optional[PagedPlan] = None,
-    window: Optional[int] = None,
+    window: Optional[WindowRule] = None,
     out_dtype,
     interpret: bool,
 ) -> Tuple[jax.Array, jax.Array]:
@@ -840,8 +858,9 @@ def _paged_decode_call(
     ``(B, Hq, Tq)``. With ``window`` the call is a sliding-window layer's:
     one body, one more compare in its mask and a list that starts at each
     slot's window, under a kernel name of its own
-    (:data:`WINDOW_KERNEL`), so that a device trace tells a window
-    layer's calls from a full layer's."""
+    (:func:`window_kernel_name`), so that a device trace tells a window
+    layer's calls from a full layer's, and an EVA layer's two calls from
+    each other."""
     from tree_attention_tpu.ops.tuning import paged_decode_step
 
     B, Hkv, n_rows, D = rows[0].shape
@@ -857,7 +876,7 @@ def _paged_decode_call(
             raise ValueError(
                 "a sliding window is built for the exact replicated pool "
                 "without a tree mask")
-        name = label = WINDOW_KERNEL
+        name = label = window_kernel_name(window)
     if obs.REGISTRY.enabled:
         _KERNEL_BUILDS.labels(
             kernel=label, heads=heads, entries=entries).inc()
@@ -1257,6 +1276,113 @@ def _paged_row_write_call(pools, rows, block_ids, offsets, base, *,
     )(*scalars, *rows, *pools))
 
 
+CHUNK_READ_KERNEL = "paged_chunk_read"
+
+
+def _paged_chunk_read_kernel(
+    blk_ref,   # SMEM (E,) scalar-prefetch: entry e's pool block
+    row_ref,   # SMEM (E,): the first of its ``chunk`` rows in that block
+    n_ref,     # SMEM (B,): member b's live entries, its first ``n[b]`` of
+               # ``per``
+    *refs,     # pools x P  HBM (M, Hkv, block, D)
+               # outs x P   VMEM (E, Hkv, chunk, D)
+               # sem        DMA semaphores (P, E)
+    n_pools: int,
+    chunk: int,
+    per: int,
+):
+    """``chunk`` consecutive rows of a pool block an entry, out of every
+    pool, by one copy each: every live entry's copy is started before any is
+    awaited. An entry past its member's count starts none, and its rows of
+    the output are whatever the buffer held."""
+    P = n_pools
+    pools, outs, sem = refs[:P], refs[P:2 * P], refs[2 * P]
+    E = outs[0].shape[0]
+
+    def copy(p, e):
+        at = pl.multiple_of(row_ref[e], chunk)
+        return pltpu.make_async_copy(
+            pools[p].at[blk_ref[e], :, pl.ds(at, chunk), :], outs[p].at[e],
+            sem.at[p, e])
+
+    def every_live(do):
+        def entry(e, carry):
+            @pl.when(e % per < n_ref[e // per])
+            def _live():
+                for p in range(P):
+                    do(p, e)
+            return carry
+
+        lax.fori_loop(0, E, entry, 0)
+
+    every_live(lambda p, e: copy(p, e).start())
+    every_live(lambda p, e: copy(p, e).wait())
+
+
+def paged_chunk_read(
+    pools: Tuple[jax.Array, ...],
+    block_ids: jax.Array,
+    rows: jax.Array,
+    counts: jax.Array,
+    chunk: int,
+    *,
+    interpret: Optional[bool] = None,
+) -> Tuple[jax.Array, ...]:
+    """Read ``chunk`` consecutive rows of a pool block, for ``(B, J)`` of
+    them, out of every pool: ``pools`` are ``(M, Hkv, block, D)`` arrays of
+    one shape and dtype, entry ``(b, j)`` is rows ``rows[b, j] .. rows[b,
+    j] + chunk - 1`` (``rows`` a multiple of ``chunk``) of block
+    ``block_ids[b, j]``, read only where ``j < counts[b]``. Returns a
+    ``(B, J, Hkv, chunk, D)`` array a pool; an entry not read is not
+    defined. What an EVA layer forms a chunk's summary from
+    (``models/hybrid.py`` ``eva_summaries``): a gather of the same rows in
+    plain XLA made the compiler re-lay the whole pool for it
+    (``tests/test_chip_compile.py``). The device event is
+    ``paged_chunk_read``."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    B, J = block_ids.shape
+    out = _paged_chunk_read_call(
+        tuple(pools), jnp.asarray(block_ids, jnp.int32).reshape(-1),
+        jnp.asarray(rows, jnp.int32).reshape(-1),
+        jnp.asarray(counts, jnp.int32), chunk=chunk, interpret=interpret)
+    return tuple(o.reshape((B, J) + o.shape[1:]) for o in out)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def _paged_chunk_read_call(pools, block_ids, rows, counts, *, chunk: int,
+                           interpret: bool):
+    P = len(pools)
+    M, Hkv, block, D = pools[0].shape
+    E, B = block_ids.shape[0], counts.shape[0]
+    dtype = pools[0].dtype
+    if any(p.shape != pools[0].shape or p.dtype != dtype for p in pools) \
+            or block % chunk or E % B:
+        raise ValueError(
+            f"paged_chunk_read takes pools of one shape and dtype whose "
+            f"block is a whole number of chunks of {chunk}, got "
+            f"{[(p.shape, p.dtype) for p in pools]}")
+    if obs.REGISTRY.enabled:
+        _KERNEL_BUILDS.labels(
+            kernel=CHUNK_READ_KERNEL, heads=Hkv, entries=E).inc()
+    scalars = (block_ids, rows, counts)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(scalars),
+        grid=(),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * P,
+        out_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)] * P,
+        scratch_shapes=[pltpu.SemaphoreType.DMA((P, E))],
+    )
+    return tuple(pl.pallas_call(
+        functools.partial(_paged_chunk_read_kernel, n_pools=P, chunk=chunk,
+                          per=E // B),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((E, Hkv, chunk, D), dtype)] * P,
+        interpret=interpret,
+        name=CHUNK_READ_KERNEL,
+    )(*scalars, *pools))
+
+
 def _packed_rows(r: int, cap: int = 128) -> int:
     """Rows of a Q tile for ``r`` packed rows a KV head: a multiple of the
     8 sublanes, ``cap`` at most."""
@@ -1278,7 +1404,7 @@ def decode_step_entries(q_heads: int, tq: int, pool: jax.Array,
 
 def decode_plan(q_heads: int, tq: int, pool: jax.Array,
                 block_table: jax.Array, q_offset,
-                window: Optional[int] = None) -> PagedPlan:
+                window: Optional[WindowRule] = None) -> PagedPlan:
     """The plan the GQA paged kernels (:func:`attention_pallas_decode`,
     ``_q8``, ``_q8q`` with a ``block_table``) build for a causal call of
     ``q_heads`` query heads x ``tq`` rows a slot against this pool
@@ -1703,7 +1829,7 @@ def attention_pallas_decode(
     tree_mask: Optional[jax.Array] = None,
     local_blocks: bool = False,
     step_plan: Optional[PagedPlan] = None,
-    window: Optional[int] = None,
+    window: Optional[WindowRule] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Split-KV flash decode. Same ``(out, lse)`` contract as the other impls.
 
@@ -1765,7 +1891,13 @@ def attention_pallas_decode(
     the same ``window``: :func:`decode_plan`), so the call walks one or
     two steps a slot whatever its length, at any ``Tq`` (a chunk's rows
     take Q tiles of 128 packed rows over that short list). The kernel is
-    named :data:`WINDOW_KERNEL`.
+    named :data:`WINDOW_KERNEL`. ``window`` may also be an
+    :class:`~.block_utils.AlignedWindow` (row ``i`` sees ``[w0, q_offset +
+    i]``, ``w0`` the start of its own block of ``window`` positions) or
+    :class:`~.block_utils.ChunkSummaries` (the pool holds one summary row a
+    chunk, row ``i`` sees those of the windows closed before its own; a
+    slot with none returns ``(0, -inf)``, the merge identity): the same
+    body under :func:`window_kernel_name`'s names.
     """
     B, Hq, Tq, D = q.shape
     if local_blocks and block_table is None:

@@ -43,7 +43,9 @@ from jax import lax
 
 from tree_attention_tpu.ops.block_utils import (  # noqa: F401  (canonical home)
     NEG_INF,
+    WindowRule,
     matmul_precision,
+    window_visible,
 )
 
 
@@ -91,7 +93,7 @@ def attention_naive(
     q_offset=0,
     kv_offset=0,
     tree_mask: Optional[jax.Array] = None,
-    window: Optional[int] = None,
+    window: Optional[WindowRule] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Materialised-scores attention. Oracle implementation for tests.
 
@@ -102,7 +104,9 @@ def attention_naive(
     decode caches, not just as a test oracle.
 
     ``window`` (with ``causal``): a row at global position ``t`` sees the
-    keys at ``(t - window, t]`` only, a sliding-window layer's rule.
+    keys at ``(t - window, t]`` only, a sliding-window layer's rule; or one
+    of ``block_utils``' other rules (:class:`~.block_utils.AlignedWindow`,
+    :class:`~.block_utils.ChunkSummaries`).
     """
     B, Hq, Tq, D = q.shape
     Hkv, Tk = k.shape[1], k.shape[2]
@@ -149,7 +153,10 @@ def attention_naive(
     elif causal:
         mask = _causal_mask(Tq, Tk, q_offset, kv_offset)
         if window is not None:
-            mask &= ~_causal_mask(Tq, Tk, q_offset - window, kv_offset)
+            mask &= window_visible(
+                window,
+                q_offset + lax.broadcasted_iota(jnp.int32, (Tq, Tk), 0),
+                kv_offset + lax.broadcasted_iota(jnp.int32, (Tq, Tk), 1))
         logits = jnp.where(mask[None, None, None], logits, NEG_INF)
 
     m = jnp.max(logits, axis=-1)
@@ -186,7 +193,7 @@ def attention_blockwise(
     kv_offset=0,
     block_size: int = 512,
     tree_mask: Optional[jax.Array] = None,
-    window: Optional[int] = None,
+    window: Optional[WindowRule] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Online-softmax attention: ``lax.scan`` over KV blocks, O(block) memory.
 
@@ -210,7 +217,8 @@ def attention_blockwise(
     masking bit-for-bit (same visibility sets, same arithmetic).
 
     ``window`` (requires ``causal``, no ``tree_mask``): the sliding-window
-    rule, a row at global position ``t`` sees ``(t - window, t]``.
+    rule, a row at global position ``t`` sees ``(t - window, t]``, or one of
+    ``block_utils``' other rules (``tile_mask``).
     """
     B, Hq, Tq, D = q.shape
     Hkv = k.shape[1]
@@ -321,7 +329,10 @@ def merge_partials(outs: jax.Array, lses: jax.Array) -> Tuple[jax.Array, jax.Arr
 
     This is what the reference's three allreduces compute across ranks
     (``model.py:108,114-115``) — here as a pure function, reusable both in
-    tests and inside the split-KV decode kernel.
+    tests and inside the split-KV decode kernel. Its second caller on the
+    serving path joins two partials of ONE layer on one chip: an EVA
+    layer's exact rows of the open window and its summary rows of the
+    closed ones (``models/hybrid.py`` ``eva_mixer``), one softmax over both.
     """
     m = jnp.max(lses, axis=0)
     m_safe = jnp.where(jnp.isneginf(m), 0.0, m)

@@ -232,6 +232,19 @@ def decode_block_k_q8(tk: int) -> int:
 # ISSUE 41's compile for the chip sliced both) and a Pallas product
 # streaming `wqb_t`'s row tiles (the fallback, had no plain-XLA form held in
 # the packed programs: the barrier holds in all six).
+# An EVA layer's two calls (ISSUE 44; `eva_local_decode`, `eva_summary_decode`:
+# the paged decode body under the aligned and the summary rule) at EvaByte's
+# MHA 32 x 128 over blocks of 64 rows: an entry is 1 MB of K + V, so the rule
+# below hands a row a slot every head of ONE entry a step (a fourth tiling
+# beside 8 heads x 4, 4 x 8 and 2 x 8 entries), and a chunk group's 128 packed
+# rows a Q tile 8 heads of 4 entries (the heads' Q-side state at 128 rows does
+# not fit beside 32 heads' K and V). Measured on v5e 2026-10-02 in the cell
+# `evabyte_bytedoc_sat` (a traced run, decode ticks, 16 slots, contexts of
+# 4k-15k): the exact rows' call 451 us a layer at 89.2% of 819 GB/s for the
+# rows its cost file counts (a slot's list is the steps from its window's
+# start to its row: half a step of dead tail a slot), the summary rows' call
+# 178 us at 90.7% (2-14 whole blocks a slot). Nothing was swept: the rule's
+# first choice reads nine tenths of the memory's pace.
 PAGED_STEP_TARGET_BYTES = 1 << 20
 PAGED_STEP_ENTRIES = (1, 2, 4, 8)  # divisors of the 8-row scale tile
 # What a step may hold of the 16 MB of scoped VMEM a v5e kernel gets by
@@ -312,6 +325,39 @@ def paged_live_steps(q_offset, kv_offset, tq: int, step_tokens: int,
         return live
     return (live - paged_first_step(
         first_pos, kv_offset, step_tokens, n_steps)).clip(0, n_steps)
+
+
+def paged_rule_steps(q_offset, kv_offset, tq: int, step_tokens: int,
+                     n_steps: int, window=None, maximum=None):
+    """``(first, live)`` of each slot's work list under a window rule
+    (``block_utils.WindowRule``; None: the whole causal context): the step
+    its list starts at (None: 0) and the steps it holds. A position rule
+    (sliding, aligned) starts at the step that holds the lowest position the
+    slot's FIRST row sees; the summary rule's columns are summary rows, and
+    the list holds the steps under those its LAST row sees, none where that
+    row has no closed window behind it. The one rule the kernels' plan
+    (``pallas_decode.paged_plan``) and the serve loop's count share;
+    ``maximum``: the array library's (numpy's unless the caller hands
+    jax's)."""
+    from tree_attention_tpu.ops.block_utils import (
+        ChunkSummaries, summaries_seen, window_low,
+    )
+
+    if window is None:
+        return None, paged_live_steps(
+            q_offset, kv_offset, tq, step_tokens, n_steps)
+    if isinstance(window, ChunkSummaries):
+        seen = summaries_seen(window, q_offset + (tq - 1))
+        return None, paged_live_steps(
+            seen - 1, kv_offset, 1, step_tokens, n_steps)
+    if maximum is None:
+        import numpy as np
+
+        maximum = np.maximum
+    low = maximum(window_low(window, q_offset), 0)
+    return (paged_first_step(low, kv_offset, step_tokens, n_steps),
+            paged_live_steps(
+                q_offset, kv_offset, tq, step_tokens, n_steps, low))
 
 
 # The one home of the TPU kernel-dispatch policy shared by flash_attention's

@@ -490,6 +490,17 @@ class WindowBlocks:
     :attr:`bound` = ``ceil((window + chunk) / block) + 1`` whatever its
     length (``ceil(window / block) + 1`` in decode).
 
+    The give-back rule is data (``rule``). ``"sliding"``, above: a block
+    goes back when its last token lies at or under ``t0 - window``.
+    ``"aligned"`` (an EVA layer's exact rows; ``models/decode.py``
+    ``PagedWindowCache``): a row at ``t`` sees ``[(t // window) * window,
+    t]``, so a block goes back when it lies under ``(t0 // window) *
+    window``, the window of the FIRST row still to be computed: a chunk
+    whose rows straddle a boundary keeps the old window until its tick has
+    been dispatched, and the next dispatch gives the old window's ``window
+    / block`` blocks back at once. The bound holds as it is: the old window
+    and the rows past it are fewer than ``window + chunk`` tokens.
+
     **Reservation.** Every admission reserves the constant :attr:`bound`
     and every block a slot maps that it does not own alone (a fork's shared
     ancestor, a block the prefix tree keeps) is charged to it as one it
@@ -506,10 +517,12 @@ class WindowBlocks:
     PRIVATE, SHARED = "private", "shared"
 
     def __init__(self, *, slots: int, table_width: int, block: int,
-                 window: int, chunk: int):
-        if window < 1:
-            raise ValueError(f"a window of {window} tokens")
-        self.block, self.window = block, window
+                 window: int, chunk: int, rule: str = "sliding"):
+        if window < 1 or rule not in ("sliding", "aligned"):
+            raise ValueError(
+                f"a window of {window} tokens under the {rule!r} rule "
+                f"('sliding' or 'aligned')")
+        self.block, self.window, self.rule = block, window, rule
         self.bound = -(-(window + chunk) // block) + 1
         # The published blocks a hit needs at its boundary: those that
         # hold the ``window - 1`` positions under it.
@@ -595,8 +608,10 @@ class WindowBlocks:
         every later row), map the blocks the rows fall in. Returns how
         many blocks were given back."""
         held = self._held[slot]
-        behind = [j for j in held
-                  if (j + 1) * self.block - 1 <= t0 - self.window]
+        # The lowest position row ``t0`` sees, by the rule.
+        low = t0 // self.window * self.window if self.rule == "aligned" \
+            else t0 - self.window + 1
+        behind = [j for j in held if (j + 1) * self.block - 1 < low]
         for j in behind:
             self._drop(slot, j, refund=True)
         self.freed += len(behind)

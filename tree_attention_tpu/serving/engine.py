@@ -147,6 +147,7 @@ from tree_attention_tpu.models.decode import (
     KVCache,
     cache_block_fixed_bytes,
     cache_token_bytes,
+    chunks_closed,
     compact_decode_window,
     copy_pool_block,
     forward_packed_step,
@@ -162,9 +163,10 @@ from tree_attention_tpu.models.decode import (
     sample_rows,
     sample_slots,
     scatter_kv_blocks,
+    window_rules,
 )
 from tree_attention_tpu.ops.pallas_decode import PAGED_STEPS
-from tree_attention_tpu.ops.tuning import paged_live_steps
+from tree_attention_tpu.ops.tuning import paged_rule_steps
 from tree_attention_tpu.serving.block_pool import (
     BlockAllocator,
     ShardedBlockAllocator,
@@ -268,7 +270,13 @@ _SSM_STATES = obs.counter(
 )
 # A step's counters that ride the tick's fetch below the slots' rows, in
 # this order (``models/decode.py`` ``forward_step``'s ``stats``).
-_STEP_COUNTERS = ("expert_rows", "tail_blocks", "ssm_states")
+_EVA_SUMMARIES = obs.counter(
+    "serving_eva_summaries_written_total",
+    "chunk summary rows the EVA layers wrote (chunks a tick's rows closed x "
+    "EVA layers)",
+)
+_STEP_COUNTERS = ("expert_rows", "tail_blocks", "ssm_states",
+                  "eva_summaries")
 _POOL_ROWS = obs.counter(
     "serving_kv_pool_rows_written_total",
     "token rows the tick programs wrote into the paged pool (a tick's rows x "
@@ -980,6 +988,9 @@ class SlotServer:
                           "given back",
                 "state": "a draft that is rejected has rewritten the "
                          "recurrent state, which cannot roll back",
+                "eva": "a draft that is rejected and crossed a chunk or "
+                       "window boundary has written a summary row or given "
+                       "blocks back",
             }[cfg.cache_kind]
             for on, what in (
                 (quantize, f"int8 {cfg.cache_kind} rows (quantize=True)"),
@@ -987,10 +998,15 @@ class SlotServer:
                  "a sequence-sharded pool (kv_shard='seq')"),
                 (bool(host_blocks), "the host tier (host_blocks > 0)"),
                 (speculate, f"speculation ({why_not})"),
-                (cfg.cache_kind == "window" and (
+                (cfg.cache_kind in ("window", "eva") and (
                     block_pool is not None or prefix_index is not None),
                  "disaggregation (a shared block_pool / prefix_index: the "
                  "window layers' blocks are one engine's)"),
+                # A summary block spans block x chunk positions where the
+                # prefix tree publishes a block of positions (ROADMAP 2A).
+                (cfg.cache_kind == "eva" and prefix_cache,
+                 "the prefix cache (prefix_cache=True: a hit needs the "
+                 "summary rows of the matched prefix)"),
                 # A recurrent state is an array a slot that every token
                 # rewrites whole: nothing holds it as it was at a block
                 # boundary (ROADMAP 2A item 9: snapshots).
@@ -1149,7 +1165,11 @@ class SlotServer:
                 f"equal values)"
             )
         self.kv_block = kv_block
-        self._npb = -(-cache_len // kv_block)  # table width (blocks)
+        # Positions a ROW of the pool under the first table stands for: 1,
+        # but an EVA model's, whose rows there are one summary a chunk (a
+        # block of that table then spans ``kv_block x chunk`` positions).
+        self._row_span = cfg.chunk if cfg.cache_kind == "eva" else 1
+        self._npb = -(-cache_len // (kv_block * self._row_span))  # table width (blocks)
         if block_pool is not None:
             # Shared-pool mode (disaggregation): the allocator is the
             # ONE ledger both workers admit/retire against, so this
@@ -1233,10 +1253,21 @@ class SlotServer:
         self._win: Optional[WindowBlocks] = None
         self._tick_wfreed = 0
         self._tick_kv_kinds: Dict[str, Tuple[int, int]] = {}
-        if cfg.cache_kind == "window":
+        self._tick_eva: Dict[str, int] = {}
+        # The paged kernels' lists a tick, by kind of layer, each with the
+        # rule its rows see by (``models/decode.py`` ``window_rules``).
+        # (name, rule, whether its pool lies under the second table)
+        rule, wrule = window_rules(cfg)
+        first, second = ("summary", "local") if cfg.cache_kind == "eva" \
+            else ("full", "window")
+        self._kv_kinds: Tuple[Tuple[str, Any, bool], ...] = (
+            (first, rule, False),) + (
+            ((second, wrule, True),) if wrule is not None else ())
+        if cfg.cache_kind in ("window", "eva"):
             self._win = WindowBlocks(
-                slots=slots, table_width=self._npb, block=kv_block,
-                window=cfg.window, chunk=self.prefill_chunk)
+                slots=slots, table_width=-(-cache_len // kv_block),
+                block=kv_block, window=cfg.window, chunk=self.prefill_chunk,
+                rule=cfg.window_rule)
             kw = dict(kw, window_blocks=self._win.blocks)
         self.cache = init_paged_cache(
             cfg, slots, cache_len, self.kv_blocks,
@@ -1251,6 +1282,7 @@ class SlotServer:
         self._kv_block_fixed_bytes = cache_block_fixed_bytes(self.cache)
         self._conv_layers = cfg.conv_layers   # the tail pool's depth
         self._ssm_layers = cfg.ssm_layers     # the state pool's depth
+        self._eva_layers = cfg.eva_layers     # both EVA pools' depth
         if obs.REGISTRY.enabled:
             _BLOCK_FIXED_BYTES.set(self._kv_block_fixed_bytes)
         # Expert layers' row counts on the tick's fetch: (layers, what
@@ -1509,7 +1541,8 @@ class SlotServer:
         numbers: the expert layers' (:meth:`_account_expert_rows`), then
         ``tail_blocks_written``, the block tails the conv layers wrote, or
         ``ssm_states_advanced``, the (slot, layer) states the state-space
-        layers wrote."""
+        layers wrote, or ``eva_summaries_written``, the (slot, layer)
+        summary rows the EVA layers wrote."""
         flat, out = extra.reshape(-1), {}
         at = 0
         if self._expert_rows_shape is not None:
@@ -1524,6 +1557,10 @@ class SlotServer:
             out["ssm_states_advanced"] = int(flat[at])
             if obs.REGISTRY.enabled:
                 _SSM_STATES.inc(out["ssm_states_advanced"])
+        if self._eva_layers:
+            out["eva_summaries_written"] = int(flat[at])
+            if obs.REGISTRY.enabled:
+                _EVA_SUMMARIES.inc(out["eva_summaries_written"])
         return out
 
     def _account_expert_rows(self, extra: np.ndarray) -> Dict[str, int]:
@@ -1569,7 +1606,7 @@ class SlotServer:
         ``(kv_steps_run, kv_steps_grid)`` of its flight record. The kernels
         build their lists on the device from the slots' lengths
         (``ops/pallas_decode.py`` ``paged_step_plan``); the host counts
-        with the same rule (``tuning.paged_live_steps``) from the lengths
+        with the same rule (``tuning.paged_rule_steps``) from the lengths
         it packed: a reset is its value, a live slot's decode row
         (``decode_rows``: the slots and their sample indices) sits at its
         prompt and samples so far, and any other slot where the programs
@@ -1589,29 +1626,30 @@ class SlotServer:
         run = grid = 0
         # One list a kind of layer: the full layers', and the window
         # layers' (which starts at the step that holds the lowest position
-        # a slot's rows see).
+        # a slot's rows see); an EVA model's two, by its two rules.
         kinds = {}
-        for kind in (("full", "window") if self._win is not None
-                     else ("full",)):
+        for kind, rule, second in self._kv_kinds:
             k_run = k_grid = 0
+            width = (self.cache.wtable if second else self.cache.table
+                     ).shape[1] * self.kv_block   # the table's rows
             for g_tq, lengths in groups:
                 key = (kind, g_tq)
                 if key not in self._kv_step_tokens:
                     self._kv_step_tokens[key] = paged_step_tokens(
-                        self.cache, self.cfg, g_tq, window=kind == "window")
+                        self.cache, self.cfg, g_tq, window=second)
                 step = self._kv_step_tokens[key]
                 if step is None:
                     continue
-                n_steps = self.cache.capacity // step
-                low = None if kind == "full" else np.maximum(
-                    lengths - (self.cfg.window - 1), 0)
-                live = paged_live_steps(
-                    lengths, 0, g_tq, step, n_steps, low)
+                n_steps = width // step
+                _, live = paged_rule_steps(
+                    lengths, 0, g_tq, step, n_steps, rule)
                 k_run += int(np.maximum(live, 1).sum())
                 k_grid += len(lengths) * n_steps
             kinds[kind] = (k_run, k_grid)
             run, grid = run + k_run, grid + k_grid
         self._tick_kv_kinds = kinds if self._win is not None else {}
+        if self._eva_layers:
+            self._tick_eva = self._count_eva(pre, n_vec, chunk)
         self._kv_len = pre + n_vec
         if chunk is not None:
             np.add.at(self._kv_len, chunk[0], chunk[1])
@@ -1619,6 +1657,31 @@ class SlotServer:
             PAGED_STEPS.labels(steps="run").inc(run)
             PAGED_STEPS.labels(steps="grid").inc(grid)
         return run, grid
+
+    def _count_eva(self, pre, n_vec, chunk) -> Dict[str, int]:
+        """What an EVA model's program is due, from the rows the host
+        packed (``pre``: every slot's length before them; ``n_vec``: its
+        rows beside the chunk group's ``(slots, counts)``):
+        ``eva_summaries_due``, the chunks those rows close x the layers
+        (what the program reports back as ``eva_summaries_written``), and,
+        a layer, the rows its two calls make visible over the members that
+        have a row: ``eva_local_rows`` (from the first row's window start
+        to the last row) and ``eva_summary_rows`` (the summaries under the
+        last row's window)."""
+        W, C = self.cfg.window, self.cfg.chunk
+        start, n = pre, n_vec
+        if chunk is not None:
+            start = np.concatenate([pre[chunk[0]], pre])
+            n = np.concatenate([chunk[1], n_vec])
+        has = n > 0
+        start, end = start[has], (start + n)[has]
+        return {
+            "eva_summaries_due": int(
+                chunks_closed(start, end - start, C)[1].sum())
+            * self._eva_layers,
+            "eva_local_rows": int((end - start // W * W).sum()),
+            "eva_summary_rows": int(((end - 1) // W * (W // C)).sum()),
+        }
 
     def _count_pool_rows(self, tq, n_vec, chunk) -> Tuple[int, int]:
         """Rows the program being dispatched writes into the paged pool,
@@ -2122,13 +2185,16 @@ class SlotServer:
     def _refuse_fork(self, what: str) -> None:
         """A fork shares its ancestor's blocks and copies the partial one;
         a recurrent state is in no block, so a branch would start from a
-        state that is not its own: refused by the cache kind's name."""
-        if self.cfg.cache_kind == "state":
+        state that is not its own, and an EVA model's partial summary
+        block is not the partial block of positions the fork copies:
+        refused by the cache kind's name."""
+        if self.cfg.cache_kind in ("state", "eva"):
             raise ValueError(
-                f"a model served from the state pool "
+                f"a model served from the {self.cfg.cache_kind} pool "
                 f"(TransformerConfig.cache_kind) does not serve with "
                 f"{what}: a branch needs the recurrent state at the fork "
-                f"point, which nothing holds (not built for that pool)")
+                f"point, which nothing holds, or its own copy of the "
+                f"partial summary block (not built for that pool)")
 
     def _take_forks(self) -> List[int]:
         """Drain the fork mailbox (loop side), oldest first."""
@@ -2437,7 +2503,7 @@ class SlotServer:
         # case exceeds the WHOLE pool can never be admitted — reject
         # it here, in English, instead of wedging the queue (a
         # merely-scarce pool defers admission instead; see serve()).
-        need = -(-(plen + req.max_new_tokens) // self.kv_block)
+        need = self._blocks_for(plen + req.max_new_tokens)
         if need > self.kv_blocks:
             raise ValueError(
                 f"request {req.uid}: worst case needs {need} KV "
@@ -2491,7 +2557,7 @@ class SlotServer:
         families can never deadlock the pool against each other. The
         family extra is returned separately and held by the family
         until the forks consume it."""
-        total = -(-(len(req.prompt) + req.max_new_tokens) // self.kv_block)
+        total = self._blocks_for(len(req.prompt) + req.max_new_tokens)
         matched, nodes = 0, []
         if self._prefix is not None:
             matched, nodes = self._prefix.match(
@@ -2550,7 +2616,7 @@ class SlotServer:
         if self._win is not None:
             self._tick_wfreed += self._win.advance(
                 slot, tokens_needed - rows, tokens_needed)
-        need = -(-tokens_needed // self.kv_block)
+        need = self._blocks_for(tokens_needed)
         grew = self._slot_nblocks[slot] < need
         while self._slot_nblocks[slot] < need:
             assert self._slot_reserve[slot] > 0, (
@@ -2568,6 +2634,12 @@ class SlotServer:
             rq = self._slot_req[slot]
             if rq is not None:
                 obs.REQLOG.blocks(rq.uid, self._slot_nblocks[slot])
+
+    def _blocks_for(self, tokens: int) -> int:
+        """Blocks of the first table's pool a slot of ``tokens`` positions
+        has rows in: a row a position, or (an EVA model: ``_row_span``) a
+        summary row for every chunk those positions have closed."""
+        return -(-(tokens // self._row_span) // self.kv_block)
 
     def _window_cached(self) -> int:
         """Window-pool blocks the prefix tree keeps (0 without one)."""
@@ -3007,7 +3079,12 @@ class SlotServer:
             # and the kernel lists' counts by kind.
             out["window_blocks_held"] = self._win.held()
             out["window_blocks_freed"] = self._tick_wfreed
-            out["window_blocks_full"] = sum(self._slot_nblocks)
+            # (An EVA model's first table counts summary blocks: its
+            # positions' blocks are counted from the lengths.)
+            out["window_blocks_full"] = sum(self._slot_nblocks) \
+                if not self._eva_layers else int(
+                    (-(-self._kv_len // self.kv_block)).sum())
+            out.update(self._tick_eva)
             for kind, (k_run, k_grid) in self._tick_kv_kinds.items():
                 out[f"kv_steps_run_{kind}"] = k_run
                 out[f"kv_steps_grid_{kind}"] = k_grid
@@ -4476,7 +4553,8 @@ class SlotServer:
                         fh[:self.slots, 1]
                     ).view(np.float32)
                     if (self._expert_rows_shape is not None
-                            or self._conv_layers or self._ssm_layers) and (
+                            or self._conv_layers or self._ssm_layers
+                            or self._eva_layers) and (
                             FLIGHT.enabled or obs.REGISTRY.enabled):
                         expert_rows = self._account_step_counters(
                             fh[self.slots:])
@@ -4712,12 +4790,12 @@ class SlotServer:
                 for i in range(self.slots):
                     st = self._slot_state[i]
                     if st == "prefill":
-                        written += self._prefill_pos[i]
+                        written += self._prefill_pos[i] // self._row_span
                     elif st in ("await", "live"):
                         written += (
                             len(self._slot_req[i].prompt)
                             + max(len(self._slot_tokens[i]) - 1, 0)
-                        )
+                        ) // self._row_span
                 rec["kv_blocks_used"] = self._pool.used
                 rec["kv_blocks_free"] = self._pool.free_count
                 rec["kv_frag"] = round(
@@ -4764,6 +4842,7 @@ class SlotServer:
                 self._tick_fork_shared = 0
                 self._tick_wfreed = 0
                 self._tick_kv_kinds = {}
+                self._tick_eva = {}
                 self._tick_tree_branches = 0
                 self._tick_branch_retired = 0
 
