@@ -166,7 +166,7 @@ def _ssm_update(slots=64, layers=5, hp=64, n=128, lanes=128, groups=8):
     f32 = jnp.float32
     args = [_s((layers * slots, hp, n, lanes), f32),
             _s((slots, hp, lanes), f32), _s((slots, hp, lanes), f32),
-            _s((slots, n, groups), f32), _s((slots, n, groups), f32),
+            _s((slots, groups, n), f32), _s((slots, groups, n), f32),
             _s((slots,), jnp.int32), _s((1,), jnp.int32), _s((), jnp.int32)]
 
     def fn(*a):
@@ -406,6 +406,16 @@ def test_kernel_compiles_for_v5e(case):
         pool = math.prod(builder()[1][0].shape) * 4
         assert mem.alias_size_in_bytes >= pool and mem.temp_size_in_bytes \
             < pool // 64, (mem.alias_size_in_bytes, mem.temp_size_in_bytes)
+        # Two phases of the rule's four slots, in the fast memory the call
+        # asks for, under the ceiling the expert product's plans keep to
+        # (half the chip's 128 MB: the rest is XLA's).
+        from tree_attention_tpu.ops import tuning
+        shape = builder()[1][0].shape[1:] + (builder()[1][3].shape[1],)
+        q = tuning.ssm_phase_slots(*shape)
+        limit = tuning.ssm_phase_vmem_limit(q, *shape)
+        assert q == 4 and tuning.ssm_phase_vmem_bytes(q, *shape) < limit \
+            <= tuning.GROUPED_VMEM_CEILING_BYTES, (q, limit)
+        assert f'"size":"{limit}"' in text, limit
         assert "moe_grouped_matmul" not in "moe_ungated_matmul"
     if "_mistral7b" in case or "_yi6b" in case or "_lfm2" in case \
             or ("_evabyte" in case and kernel != ROW_WRITE):

@@ -52,6 +52,7 @@ from tree_attention_tpu.obs.flight import FLIGHT
 from tree_attention_tpu.ops.pallas_moe import UNGATED_KERNEL, grouped_matmul
 from tree_attention_tpu.ops.pallas_ssm import (
     SSM_KERNEL,
+    _ssm_update_call,
     live_list,
     ssm_decode_update,
 )
@@ -290,15 +291,14 @@ def test_the_pool_layout_packs_heads_side_by_side_and_back():
 # -- the kernel --------------------------------------------------------------
 
 
-@pytest.mark.parametrize("live", [(1, 0, 1, 1, 0), (0, 0, 0, 0, 0),
-                                  (1, 1, 1, 1, 1)])
-def test_the_decode_kernel_advances_the_live_slots_in_place(live):
-    """Interpret mode against ``ssm_step``: the slots in the list get the
-    recurrence's next state and ``S . C``; a slot with no row keeps its
-    state bit for bit; so does every other layer's; an empty list changes
-    nothing."""
-    rng = np.random.default_rng(sum(live))
-    S, layers, Hp, N, L, G, m = 5, 3, 4, 16, 128, 2, 1
+def _kernel_case(live, Hp, G, slots, seed):
+    """One call of the kernel on layer 1 of 3 in interpret mode, at ``slots``
+    slots a phase (``None``: the rule's), against ``ssm_step`` under the
+    same ``jit`` (XLA's CPU backend fuses ``a * S + b * x`` there as it does
+    in the interpreted kernel): ``(pool, new pool, y, wanted states, wanted
+    y)``."""
+    rng = np.random.default_rng(seed)
+    S, layers, N, L, m = len(live), 3, 16, 128, 1
     state = jnp.asarray(rng.normal(size=(layers * S, Hp, N, L)), jnp.float32)
     x, a = (jnp.asarray(rng.normal(size=(S, Hp, L)), jnp.float32)
             for _ in range(2))
@@ -307,22 +307,87 @@ def test_the_decode_kernel_advances_the_live_slots_in_place(live):
     ids, count = live_list(jnp.asarray(live, jnp.int32))
     assert int(count[0]) == sum(live)
     assert ids[:sum(live)].tolist() == [i for i, v in enumerate(live) if v]
-    new, y = ssm_decode_update(
-        state, x, a, jnp.swapaxes(b, 1, 2), jnp.swapaxes(c, 1, 2), ids,
-        count, m * S, interpret=True)
-    want, wy = ssm_step(state[m * S:(m + 1) * S], x, a, b, c)
+    if slots is None:
+        new, y = ssm_decode_update(state, x, a, b, c, ids, count, m * S,
+                                   interpret=True)
+    else:
+        new, y = _ssm_update_call(state, x, a, b, c, ids, count,
+                                  jnp.full((1,), m * S, jnp.int32),
+                                  interpret=True, slots=slots)
+    want, wy = jax.jit(ssm_step)(state[m * S:(m + 1) * S], x, a, b, c)
+    return tuple(np.asarray(t) for t in (state, new, y, want, wy))
+
+
+@pytest.mark.parametrize("live", [(1, 0, 1, 1, 0), (0, 0, 0, 0, 0),
+                                  (1, 1, 1, 1, 1)])
+def test_the_decode_kernel_advances_the_live_slots_in_place(live):
+    """Interpret mode against ``ssm_step``: the slots in the list get the
+    recurrence's next state and ``S . C``; a slot with no row keeps its
+    state bit for bit; so does every other layer's; an empty list changes
+    nothing."""
+    S, m = len(live), 1
+    state, new, y, want, wy = _kernel_case(live, Hp=4, G=2, slots=None,
+                                           seed=sum(live))
     for s in range(S):
         if live[s]:
             np.testing.assert_allclose(new[m * S + s], want[s], atol=1e-5)
             np.testing.assert_allclose(y[s], wy[s], atol=1e-4)
         else:
-            np.testing.assert_array_equal(np.asarray(new[m * S + s]),
-                                          np.asarray(state[m * S + s]))
+            np.testing.assert_array_equal(new[m * S + s], state[m * S + s])
     for other in (0, 2):
-        np.testing.assert_array_equal(
-            np.asarray(new[other * S:(other + 1) * S]),
-            np.asarray(state[other * S:(other + 1) * S]))
+        np.testing.assert_array_equal(new[other * S:(other + 1) * S],
+                                      state[other * S:(other + 1) * S])
     assert SSM_KERNEL == "ssm_decode_update"
+
+
+# Slots a phase against the live count: phases that divide the list and one
+# that does not, one phase that holds it all, a list shorter than a phase,
+# an empty list, a list with gaps; several groups of heads and one. (The
+# list is data: the cases of one shape and one phase size share a compile,
+# three in all: tier-1 runs close to its time limit.)
+@pytest.mark.parametrize("live,Hp,G,slots", [
+    (live, Hp, G, slots)
+    for Hp, G, sizes in ((4, 2, (1, 4)), (2, 1, (2,)))
+    for slots in sizes
+    for live in ((1, 1, 1, 1, 1, 1, 1, 1),      # whole phases at 1, 2, 4
+                 (1, 0, 1, 1, 0, 1, 1, 0),      # 5 live, gaps: a short last
+                 (0, 0, 0, 0, 0, 1, 0, 0),      # 1 live: under a phase
+                 (0, 0, 0, 0, 0, 0, 0, 0),      # an empty list
+                 (1, 1, 1, 0, 1, 1, 1, 1))      # 7 live: a last of 1 or 3
+], ids=lambda v: "".join(map(str, v)) if isinstance(v, tuple) else str(v))
+def test_the_decode_kernel_in_phases_writes_ssm_steps_bits(live, Hp, G, slots):
+    """Whatever the phases: the state of a live slot is BIT-EQUAL to
+    ``ssm_step``'s, a slot off the list holds the bits it held, ``y`` agrees
+    to 1e-6."""
+    S, m = len(live), 1
+    state, new, y, want, wy = _kernel_case(live, Hp, G, slots,
+                                           seed=slots + Hp)
+    on = np.asarray(live, bool)
+    mine = new[m * S:(m + 1) * S]
+    np.testing.assert_array_equal(mine[on], want[on])
+    np.testing.assert_allclose(y[on], wy[on], atol=1e-6, rtol=0)
+    np.testing.assert_array_equal(mine[~on], state[m * S:(m + 1) * S][~on])
+    np.testing.assert_array_equal(new[:m * S], state[:m * S])
+    np.testing.assert_array_equal(new[(m + 1) * S:], state[(m + 1) * S:])
+
+
+def test_the_phase_rule_follows_the_shapes_and_fits_its_limit():
+    """``ops/tuning.py`` ``ssm_phase_slots``: the served shape takes the
+    measured four slots a phase; a state too large for that takes fewer,
+    down to one; the limit the call asks for holds the buffers."""
+    from tree_attention_tpu.ops import tuning
+
+    assert tuning.ssm_phase_slots(64, 128, 128, 8) == 4
+    assert tuning.ssm_phase_slots(128, 128, 128, 8) == 2
+    assert tuning.ssm_phase_slots(256, 128, 128, 8) == 1
+    assert tuning.ssm_phase_slots(4096, 128, 128, 8) == 1
+    assert tuning.ssm_phase_slots(4, 16, 128, 2) == 4
+    for hp in (4, 64, 128, 256):
+        q = tuning.ssm_phase_slots(hp, 128, 128, 8)
+        need = tuning.ssm_phase_vmem_bytes(q, hp, 128, 128, 8)
+        assert need >= 2 * q * hp * 128 * 128 * 4
+        assert need < tuning.ssm_phase_vmem_limit(q, hp, 128, 128, 8)
+        assert q == 1 or need <= tuning.SSM_PHASE_VMEM_BYTES
 
 
 # -- the engine's steps against the reference (a), (d) -----------------------

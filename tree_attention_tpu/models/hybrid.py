@@ -357,8 +357,8 @@ def ssm_mixer(layer: Params, x: jax.Array, state: jax.Array,
                     a = jnp.repeat(a, P, axis=-1).reshape(rows)
                     if g.live is not None:
                         flat_s, y = ssm_decode_update(
-                            flat_s, dx, a, jnp.swapaxes(Bm[:, 0], 1, 2),
-                            jnp.swapaxes(Cm[:, 0], 1, 2), *g.live, m * S)
+                            flat_s, dx, a, Bm[:, 0], Cm[:, 0], *g.live,
+                            m * S)
                         y = jnp.where(has[:, None, None], y, 0.0)
                     else:
                         new, y = ssm_step(flat_s[at], dx, a, Bm[:, 0],

@@ -460,6 +460,114 @@ def default_block_q_bwd(tq: int, tk: int, block_k: Optional[int] = None) -> int:
 
 
 # ---------------------------------------------------------------------------
+# The state step's phases (ops/pallas_ssm.py)
+# ---------------------------------------------------------------------------
+
+# ``ssm_decode_update`` reads a live slot's float32 state of a layer and
+# writes it back: at nemotron-3-super-120b-a12b's shapes (128 heads x 64 x
+# 128 as ``(Hp, N, L) = (64, 128, 128)``, ``G`` = 8) 4.19 MB each way and
+# 74 KB of rows, 8.46 MB a slot by ``benchmark/kernel_costs/
+# ssm_decode_update.py``. Until PR 45 a grid step took one slot through a
+# BlockSpec pipeline (a 4 MB block in and one out, double-buffered) and read
+# 80.9% of 819 GB/s in the cell (ledger, PRs 40 and 44: 816 us a launch).
+#
+# Measured on v5e 2026-10-02 (ISSUE 45 step 1 and what followed: scratch
+# sweeps of the kernel alone, three chip calls; the pool ``(5 x 64, 64, 128,
+# 128)`` float32 donated, 64 live slots, 128 calls in one program, a call's
+# layer ``i % 5``, best of 7 with the median within 0.3%; us a call, and the
+# share of 819 GB/s by the cost file's 541.6 MB a call; every form's state
+# bit-equal to ``ssm_step``'s on the chip, ``y`` equal too but the MXU's).
+# The program's own loop adds ~16 us a call over the cell's 816.
+#
+#   (a) the kernel as it stood (one slot a grid step)         832.3  79.5%
+#   (b) the same specs, the body ``o_ref[...] = s_ref[...]``  832.1  79.5%
+#   (c) (b), the block cut along Hp in 2 / 4 / 8       833.7 / 836.9 / 835.0
+#       (a) cut likewise                               833.9 / 835.6 / 837.2
+#   (d) (a), B / C lane-dense (S, G, N), transposed a step    834.8  79.2%
+#       ... as a (L, N) broadcast transposed                  832.2  79.5%
+#   the broadcasts of b, c from VMEM scratch, once a group    832.9  79.4%
+#   ... and the rows a ``fori_loop`` (by 1 / by 2)     833.8 / 832.7
+#   ``y``'s reduce on the MXU (HIGHEST; y off by 9.5e-7)      832.9  79.4%
+#   the kernel's own ring (pool in pl.ANY, chunks of Hp/4 or Hp/8, 4 or 8
+#     buffers, 2 or 4 reads ahead), copy only          835.2 - 836.7
+#     ... with the body                                835.4 - 837.7
+#   ``pipeline_mode=pl.Buffered(3)``: refused by this jaxlib's lowering
+#     ("Only single (1) and double (2) buffering are supported")
+#
+# Nothing in the body binds (the scheduler's dump of (a): 3,392 bundles a
+# step, ~2.3 us beside 10.3 us of bytes) and nothing in the blocks' shape:
+# (b) IS the ceiling of a stream in and out with a read and a write in
+# flight together, whatever issues them. What binds is the memory's own pace
+# by direction (the same 64 x 4.19 MB, two phases of copies in flight;
+# 1, 4 or 16 copies a slot read the same):
+#
+#   reads alone    365.2 us   735 GB/s   89.8% of 819
+#   writes alone   418.6 us   641 GB/s   78.3%
+#   one after the other: 783.8 us, 84.4% by the cost file's bytes
+#
+# and a read beside a write costs 48 us a call MORE than the two apart. So
+# the kernel keeps them apart: PHASES of Q slots, phase p + 1 read whole with
+# nothing else in flight, computed where it landed while phase p is written
+# back whole (the body hides under the writes; under the reads of phase 1
+# for phase 0).
+#
+#   Q slots a phase                      1      2      4      8
+#   copies only (reads, then writes)   806.3  790.7  782.4  777.4
+#   the kernel, computed under the
+#     NEXT phase's reads (the last
+#     phase's body shows: Q x 2.3 us)  814.9  801.2  797.3  800.9
+#   the kernel as it is (under the
+#     writes of the phase before)      812.7  799.1  788.4  784.1
+#   ... of 819 GB/s                    81.4%  82.8%  83.9%  84.3%
+#
+# Q = 4 (33 MB of buffers): 44 us a call under (a), 6 us over its own
+# copies; Q = 8 wins 4 us more for 66 MB. In the cell (one traced run a
+# side, seed 2450004504): 815.6 -> 771.5 us a launch, 80.96 -> 85.55% of the
+# roofline, `tbt_p50_ms` 16.98 -> 16.74 over six seeds a side. Not 88-92%, which ISSUE 45
+# predicted before anyone had timed a write: 84.4% is what the two
+# directions allow, and what is left over it is the rows' own copies and 32
+# turns between reading and writing a launch (~0.25 us each, from the Q
+# column). PR 40's two untried ideas: "two slots a step" is Q = 2 (wins 4%
+# only because it is phased; as BlockSpecs a pair of slots is not one block
+# of the pool, the list's slots lie anywhere); "y's reduce on the MXU" is the
+# line above (no faster: the body never showed; and y moves in its last
+# bits).
+SSM_PHASE_SLOTS = (4, 2, 1)
+# What two phases' buffers may take of the chip's 128 MB of fast memory
+# (the one-slot pipeline asked for 32 MB).
+SSM_PHASE_VMEM_BYTES = 48 << 20
+
+
+def _tiles(rows: int, lanes: int) -> int:
+    """Bytes of a float32 ``(rows, lanes)`` array in fast memory: whole
+    ``(8, 128)`` tiles."""
+    return -(-rows // 8) * 8 * -(-lanes // 128) * 128 * 4
+
+
+def ssm_phase_vmem_bytes(slots: int, hp: int, n: int, l: int, g: int) -> int:
+    """Fast memory ``ssm_decode_update``'s buffers take at ``slots`` slots a
+    phase: two phases of a slot's state, ``x``, ``a``, ``y`` and the ``B`` /
+    ``C`` rows."""
+    return 2 * slots * (hp * _tiles(n, l) + 3 * _tiles(hp, l)
+                        + 2 * _tiles(g, n))
+
+
+def ssm_phase_slots(hp: int, n: int, l: int, g: int) -> int:
+    """Slots a phase of ``ssm_decode_update`` moves, worked out from the
+    shapes the call sees: the most of :data:`SSM_PHASE_SLOTS` whose two
+    phases fit :data:`SSM_PHASE_VMEM_BYTES`; one where none does."""
+    return next((q for q in SSM_PHASE_SLOTS
+                 if ssm_phase_vmem_bytes(q, hp, n, l, g)
+                 <= SSM_PHASE_VMEM_BYTES), 1)
+
+
+def ssm_phase_vmem_limit(slots: int, hp: int, n: int, l: int, g: int) -> int:
+    """``vmem_limit_bytes`` of the call: the buffers and 8 MB for a row's
+    values in flight and what the compiler adds."""
+    return ssm_phase_vmem_bytes(slots, hp, n, l, g) + (8 << 20)
+
+
+# ---------------------------------------------------------------------------
 # The grouped expert product's blocks (ops/pallas_moe.py)
 # ---------------------------------------------------------------------------
 
