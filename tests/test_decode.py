@@ -84,16 +84,24 @@ def test_flash_decode_traced_position():
 # ---------------------------------------------------------------------------
 
 
+def _jit_step(cfg, **kw):
+    """``forward_step`` as one program a (cache type, token width): dispatched
+    eagerly a step is a launch a primitive, sixteen steps sixteen times
+    that."""
+    return jax.jit(lambda p, t, c: forward_step(p, t, c, cfg, **kw))
+
+
 def _stepwise_logits(params, tokens, cfg, mesh=None, cache_len=64):
     """Prefill then 1-token steps; returns logits at every position."""
     kw = {"mesh": mesh} if mesh is not None else {}
     B, T = tokens.shape
     split = T // 2
     cache = init_cache(cfg, B, cache_len, **kw)
-    logits_pre, cache = forward_step(params, tokens[:, :split], cache, cfg, **kw)
+    step = _jit_step(cfg, **kw)
+    logits_pre, cache = step(params, tokens[:, :split], cache)
     chunks = [logits_pre]
     for t in range(split, T):
-        logits_t, cache = forward_step(params, tokens[:, t : t + 1], cache, cfg, **kw)
+        logits_t, cache = step(params, tokens[:, t : t + 1], cache)
         chunks.append(logits_t)
     assert np.all(np.asarray(cache.length) == T)  # per-slot (B,) lengths
     return jnp.concatenate(chunks, axis=1)
@@ -232,14 +240,16 @@ def test_quantized_incremental_decode_tracks_exact():
     tokens = jax.random.randint(jax.random.PRNGKey(1), (1, 32), 0, CFG.vocab_size)
     Tp = 16
 
+    step = _jit_step(CFG)
+
     def run(quant):
         cache = init_cache(CFG, 1, 32)
-        logits, cache = forward_step(params, tokens[:, :Tp], cache, CFG)
+        logits, cache = step(params, tokens[:, :Tp], cache)
         if quant:
             cache = quantize_cache(cache)
         outs = [logits]
         for t in range(Tp, 32):
-            logits, cache = forward_step(params, tokens[:, t:t + 1], cache, CFG)
+            logits, cache = step(params, tokens[:, t:t + 1], cache)
             outs.append(logits)
         return np.concatenate([np.asarray(o) for o in outs], axis=1)
 
@@ -275,14 +285,12 @@ def test_quantized_decode_sharded_matches_unsharded(quant_kernel):
     def run(mesh_arg, cache_len=32):
         kw = {} if mesh_arg is None else {"mesh": mesh_arg}
         cache = init_cache(CFG, 1, cache_len, **kw)
-        logits, cache = forward_step(params, tokens[:, :16], cache, CFG, **kw)
+        step = _jit_step(CFG, quant_kernel=quant_kernel, **kw)
+        logits, cache = step(params, tokens[:, :16], cache)
         cache = quantize_cache(cache)
         outs = []
         for t in range(16, 24):
-            logits, cache = forward_step(
-                params, tokens[:, t:t + 1], cache, CFG,
-                quant_kernel=quant_kernel, **kw,
-            )
+            logits, cache = step(params, tokens[:, t:t + 1], cache)
             outs.append(np.asarray(logits))
         return np.concatenate(outs, axis=1)
 

@@ -19,7 +19,6 @@ folded tile by tile, 1e-5.
 """
 
 import dataclasses
-import functools
 import importlib.util
 import json
 import os
@@ -34,8 +33,6 @@ from tree_attention_tpu import obs
 from tree_attention_tpu.models.decode import (
     PagedWindowCache,
     chunks_closed,
-    forward_packed_step,
-    forward_step,
     init_paged_cache,
     window_rules,
 )
@@ -59,9 +56,15 @@ from tree_attention_tpu.serving import SlotServer
 from tree_attention_tpu.serving.block_pool import WindowBlocks
 from tree_attention_tpu.serving.engine import Request
 
+from tests.jitted import serve_step_stats
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ATOL = 2e-5
 BLOCK, WINDOW, CHUNK = 8, 32, 4
+# The one chunk width the step helpers compile: three blocks, the widest
+# chunk (the one that straddles a window boundary); a step of 3, 4 or 8 rows
+# is the same program with the count in the length vector.
+WIDTH = 24
 LOCAL, FAR = AlignedWindow(WINDOW), ChunkSummaries(WINDOW, CHUNK)
 
 # The family's published keys at a small size.
@@ -345,21 +348,6 @@ def test_the_chunk_read_kernel_reads_what_a_gather_reads():
 # -- the two pools against the reference --------------------------------------
 
 
-@functools.partial(jax.jit, static_argnames=("tcfg",))
-def _step(params, t, cache, n, tcfg):
-    st = {}
-    logits, cache = forward_step(params, t, cache, tcfg, n_tokens=n, stats=st)
-    return logits, cache, st["eva_summaries"]
-
-
-@functools.partial(jax.jit, static_argnames=("tcfg",))
-def _packed_step(params, ct, cs, cn, dec, dn, cache, tcfg):
-    st = {}
-    logits, cache = forward_packed_step(
-        params, ct, cs, cn, dec, dn, cache, tcfg, stats=st)
-    return logits, cache, st["eva_summaries"]
-
-
 def _serve_rows(params, tcfg, toks, steps, slots=2, nb=24, chunk=24,
                 packed=False, stats=None):
     """Run ``steps`` (rows a slot a step) through the two pools with the
@@ -367,7 +355,9 @@ def _serve_rows(params, tcfg, toks, steps, slots=2, nb=24, chunk=24,
     rule (blocks behind the window given back before each step, scrambled
     ids) and the summary table mapped whole: the logits of the rows that
     carried a token, the ledger, the most blocks a slot held, and the
-    cache."""
+    cache. ``chunk`` is the most rows a step carries (what the ledger's
+    bound counts); the token block is ``WIDTH`` rows wide, or one, whatever
+    the counts (``tests/jitted.py``)."""
     nbs = -(-nb // CHUNK)
     cache = init_paged_cache(tcfg, slots, nb * BLOCK, slots * nbs + 1,
                              block=BLOCK, window_blocks=64)
@@ -389,33 +379,11 @@ def _serve_rows(params, tcfg, toks, steps, slots=2, nb=24, chunk=24,
                 win.advance(i, pos[i], pos[i] + n)
         peak = max(peak, max(win.held(i) for i in range(slots)))
         cache = dataclasses.replace(cache, wtable=jnp.asarray(win.table))
-        tq = max(ns)
-        if packed:
-            c = int(np.argmax(ns))
-            ct = np.zeros((1, tq), np.int32)
-            ct[0, :ns[c]] = toks[c][pos[c]:pos[c] + ns[c]]
-            dec = np.asarray([toks[i][pos[i]] if i != c and ns[i] else 0
-                              for i in range(slots)], np.int32)
-            dn = np.asarray([int(i != c and ns[i] > 0)
-                             for i in range(slots)], np.int32)
-            logits, cache, wrote = _packed_step(
-                params, jnp.asarray(ct), jnp.asarray([c], jnp.int32),
-                jnp.asarray([ns[c]], jnp.int32), jnp.asarray(dec),
-                jnp.asarray(dn), cache, tcfg)
-            for i, n in enumerate(ns):
-                if n:
-                    got[i].append((pos[i] + n - 1, np.asarray(logits[i])))
-        else:
-            t = np.zeros((slots, tq), np.int32)
-            for i, n in enumerate(ns):
-                t[i, :n] = toks[i][pos[i]:pos[i] + n]
-            logits, cache, wrote = _step(
-                params, jnp.asarray(t), cache, jnp.asarray(ns, jnp.int32),
-                tcfg)
-            logits = np.asarray(logits)
-            for i, n in enumerate(ns):
-                for j in range(n):
-                    got[i].append((pos[i] + j, logits[i, j]))
+        rows, cache, st = serve_step_stats(
+            params, tcfg, cache, toks, pos, ns, WIDTH, packed=packed)
+        for i, row, lg in rows:
+            got[i].append((row, lg))
+        wrote = st["eva_summaries"]
         if stats is not None:
             due = sum(int(chunks_closed(pos[i], n, CHUNK)[1])
                       for i, n in enumerate(ns) if n)

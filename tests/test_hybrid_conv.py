@@ -37,9 +37,14 @@ from tree_attention_tpu.models.transformer import (
     model_from_config,
 )
 
+from tests.jitted import serve_step
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ATOL = 2e-5
 BLOCK = 8
+# The one chunk width the step helpers compile: three blocks, the widest
+# step any case takes (a first chunk of 19 rows).
+WIDTH = 24
 
 # The family's published keys at a small size: the period ``c c A c`` and
 # the irregular end (``... A c A``), 2 leading dense FFNs, 8 experts top 2.
@@ -111,21 +116,20 @@ def _cache(tcfg, slots, nb=8):
 
 def _run(params, tcfg, cache, toks, steps):
     """``steps``: per step one row count a slot; the logits of the rows
-    that carried a token, per slot, and the cache."""
+    that carried a token, per slot, and the cache. A step's token block is
+    ``WIDTH`` wide (or one row, the decode step) whatever its counts: two
+    compiled programs a model (``tests/jitted.py``), the true counts in
+    ``n_tokens`` as a tick carries them."""
     B = len(toks)
     got, pos = [[] for _ in range(B)], [int(x) for x in cache.length]
     for ns in steps:
-        tq = max(ns)
-        t = np.zeros((B, tq), np.int32)
+        rows, cache = serve_step(params, tcfg, cache, toks, pos, ns, WIDTH)
+        for i, _, lg in rows:
+            got[i].append(lg)
         for i, n in enumerate(ns):
-            t[i, :n] = toks[i][pos[i]:pos[i] + n]
-        logits, cache = forward_step(
-            params, jnp.asarray(t), cache, tcfg,
-            n_tokens=jnp.asarray(ns, jnp.int32))
-        for i, n in enumerate(ns):
-            got[i].append(np.asarray(logits[i, :n]))
             pos[i] += n
-    return [np.concatenate(g) for g in got], cache
+    return [np.stack(g) if g else np.zeros((0, tcfg.vocab_size), np.float32)
+            for g in got], cache
 
 
 # -- the model as data -------------------------------------------------------

@@ -18,7 +18,6 @@ from tree_attention_tpu.models.decode import (
     PagedHybridCache,
     cache_block_fixed_bytes,
     cache_token_bytes,
-    forward_packed_step,
     forward_step,
     init_paged_cache,
 )
@@ -28,6 +27,7 @@ from tree_attention_tpu.obs.flight import FLIGHT
 from tree_attention_tpu.serving import SlotServer
 from tree_attention_tpu.serving.engine import Request
 
+from tests.jitted import packed_step
 from tests.test_hybrid_conv import (  # noqa: F401  (fixtures by name)
     ATOL,
     BLOCK,
@@ -69,7 +69,7 @@ def test_two_slots_at_different_positions_in_one_packed_tick(ref, model):
     _, cache = _run(params, tcfg, cache, toks, [[16, 10, 9]])
     chunk = np.zeros((1, 16), np.int32)
     chunk[0, :12] = toks[2, 9:21]
-    logits, cache = forward_packed_step(
+    logits, cache = packed_step(
         params, jnp.asarray(chunk), jnp.asarray([2], jnp.int32),
         jnp.asarray([12], jnp.int32),
         jnp.asarray([toks[0, 16], toks[1, 10], 0], jnp.int32),
@@ -115,8 +115,9 @@ def test_experts_under_rotary_gqa_without_a_conv_layer(ref, adapter):
     cache = _cache(tcfg, 1)
     assert cache.tail.shape == (0, 8, 128) and cache.k.shape[0] == 3
     toks = np.random.default_rng(2).integers(0, 128, (1, 14))
-    stats = {}
-    forward_step(params, jnp.asarray(toks[:, :4]), cache, tcfg, stats=stats)
+    stats = {}      # the counters' shapes need no run: traced, not computed
+    jax.eval_shape(lambda: forward_step(
+        params, jnp.asarray(toks[:, :4]), cache, tcfg, stats=stats))
     assert stats["expert_rows"].shape == (2, 9) and "tail_blocks" not in stats
     got, _ = _run(params, tcfg, cache, toks, [[9]] + [[1]] * 5)
     np.testing.assert_allclose(got[0], _want(ref, w, weights, toks[0]),

@@ -8,6 +8,7 @@ double layer has is in ``tests/test_shortcut_moe.py``.
 """
 
 import dataclasses
+import functools
 import importlib.util
 import math
 import os
@@ -23,7 +24,6 @@ from tree_attention_tpu.models import experts, latent
 from tree_attention_tpu.models.decode import (
     PagedLatentCache,
     cache_token_bytes,
-    forward_step,
     init_paged_cache,
 )
 from tree_attention_tpu.models.transformer import (
@@ -33,6 +33,8 @@ from tree_attention_tpu.models.transformer import (
 from tree_attention_tpu.obs.flight import FLIGHT
 from tree_attention_tpu.serving import SlotServer
 from tree_attention_tpu.serving.engine import Request
+
+from tests.jitted import step_stats
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -90,7 +92,7 @@ def _load(path, name):
     return mod
 
 
-@dataclasses.dataclass
+@dataclasses.dataclass(eq=False)     # hashed by identity: ``_model``'s key
 class Family:
     """A latent family's small preset and its two benchmark files."""
 
@@ -116,6 +118,7 @@ def ref():
     return load_family("deepseek_mla_moe").ref
 
 
+@functools.lru_cache(maxsize=None)
 def _model(fam, dtype="float32", seed=7):
     config = dict(fam.config, torch_dtype=dtype)
     w = fam.ref.Widths.of(config)
@@ -128,7 +131,8 @@ def _serve_chunks(params, tcfg, toks, lens, *, block=8, nb=8, chunk=16,
                   quantize_rows=False):
     """Prefill in chunks of ``chunk`` then decode one token a step through
     the paged latent pool (a scrambled block table); the logits of every
-    real row, per slot."""
+    real row, per slot. Two widths, ``chunk`` and one, each one compiled
+    program a model (``tests/jitted.py``)."""
     B = len(lens)
     cache = init_paged_cache(tcfg, B, nb * block, B * nb, block=block)
     table = jnp.arange(B * nb, dtype=jnp.int32).reshape(B, nb)[:, ::-1]
@@ -142,9 +146,8 @@ def _serve_chunks(params, tcfg, toks, lens, *, block=8, nb=8, chunk=16,
         for i in range(B):
             n[i] = min(tq, left[i])
             t[i, :n[i]] = toks[i, pos[i]:pos[i] + n[i]]
-        stats = {}
-        logits, cache = forward_step(params, jnp.asarray(t), cache, tcfg,
-                                     n_tokens=jnp.asarray(n), stats=stats)
+        logits, cache, stats = step_stats(
+            params, jnp.asarray(t), cache, jnp.asarray(n), tcfg)
         if quantize_rows:            # an int8 latent row, re-read as such
             kv = cache.kv.astype(jnp.float32)
             s = jnp.max(jnp.abs(kv), axis=-1, keepdims=True) / 127.0

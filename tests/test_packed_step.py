@@ -31,6 +31,8 @@ from tree_attention_tpu.models.decode import cache_pools
 from tree_attention_tpu.models.experts import init_block_params
 from tree_attention_tpu.models.transformer import model_from_config
 
+from tests.jitted import packed_step_stats, step_stats
+
 S, TQ, BLK, CACHE_LEN = 4, 8, 4, 32
 NB = CACHE_LEN // BLK
 
@@ -119,15 +121,12 @@ def _tick(cfg, cache, name):
 def test_packed_step_matches_padded(kind, tick):
     cfg, params, cache = _setup(kind)
     cache, packed, (mat, n_vec) = _tick(cfg, cache, tick)
-    stats_a, stats_b = {}, {}
-    ref_logits, ref_cache = jax.jit(
-        lambda c: forward_step(params, jnp.asarray(mat), c, cfg,
-                               n_tokens=jnp.asarray(n_vec), stats=stats_a)
-    )(cache)
-    got_logits, got_cache = jax.jit(
-        lambda c: forward_packed_step(
-            params, *(jnp.asarray(a) for a in packed), c, cfg, stats=stats_b)
-    )(cache)
+    # The tick's tokens and counts are operands: the ticks of one kind and
+    # one ``C`` share a compiled program (``tests/jitted.py``).
+    ref_logits, ref_cache, _ = step_stats(
+        params, jnp.asarray(mat), cache, jnp.asarray(n_vec), cfg)
+    got_logits, got_cache, _ = packed_step_stats(
+        params, *(jnp.asarray(a) for a in packed), cache, cfg)
     np.testing.assert_array_equal(np.asarray(got_cache.length),
                                   np.asarray(ref_cache.length))
     for name, pool in cache_pools(ref_cache).items():
@@ -158,21 +157,11 @@ def test_packed_step_counts_the_rows_that_carry_a_token():
     the padded matrix's: the same pairs, whichever way the tick is laid."""
     cfg, params, cache = _setup("latent")
     cache, packed, (mat, n_vec) = _tick(cfg, cache, "c2_two_members")
-
-    def padded(c):
-        st = {}
-        forward_step(params, jnp.asarray(mat), c, cfg,
-                     n_tokens=jnp.asarray(n_vec), stats=st)
-        return st["expert_rows"]
-
-    def compact(c):
-        st = {}
-        forward_packed_step(
-            params, *(jnp.asarray(a) for a in packed), c, cfg, stats=st)
-        return st["expert_rows"]
-
-    a, b = np.asarray(jax.jit(padded)(cache)), np.asarray(
-        jax.jit(compact)(cache))
+    a = np.asarray(step_stats(params, jnp.asarray(mat), cache,
+                              jnp.asarray(n_vec), cfg)[2]["expert_rows"])
+    b = np.asarray(packed_step_stats(
+        params, *(jnp.asarray(x) for x in packed), cache,
+        cfg)[2]["expert_rows"])
     layers = cfg.n_layers - cfg.n_dense_layers
     assert a.sum() == int(n_vec.sum()) * cfg.moe.per_token * layers
     np.testing.assert_array_equal(a, b)

@@ -8,7 +8,17 @@ import jax
 import jax.numpy as jnp
 
 from tree_attention_tpu.ops import attention_naive
-from tree_attention_tpu.parallel import cpu_mesh, ring_attention, tree_attention
+from tree_attention_tpu import parallel
+from tree_attention_tpu.parallel import cpu_mesh
+
+from tests.jitted import jitted
+
+# One program a call, as a user's jitted step runs them: eagerly a sharded
+# call is a launch a primitive a shard (``tests/jitted.py``).
+ring_attention = jitted(parallel.ring_attention)
+tree_attention = jitted(parallel.tree_attention)
+ring_decode = jitted(parallel.ring_decode)
+tree_decode = jitted(parallel.tree_decode)
 
 
 def make_qkv(rng, B=2, Hq=4, Hkv=4, Tq=128, Tk=128, D=32, dtype=np.float32):
@@ -88,8 +98,6 @@ def test_ring_chunked_prefill_alignment():
 def test_ring_decode_matches_unsharded(n_shards, causal):
     """Replicated-Q decode via the unrolled partial-rotation ring: exact
     parity with the unsharded oracle (same monoid as the tree merge)."""
-    from tree_attention_tpu.parallel import ring_decode
-
     rng = np.random.default_rng(7)
     q, k, v = make_qkv(rng, B=1, Hq=4, Hkv=2, Tq=1, Tk=256)
     mesh = cpu_mesh(n_shards)
@@ -104,8 +112,6 @@ def test_ring_decode_matches_unsharded(n_shards, causal):
 def test_ring_decode_matches_tree_decode():
     """The decode comparator races identical math: ring_decode == tree_decode
     bit-for-allclose on the same data/mesh."""
-    from tree_attention_tpu.parallel import ring_decode, tree_decode
-
     rng = np.random.default_rng(8)
     q, k, v = make_qkv(rng, B=2, Hq=4, Hkv=4, Tq=4, Tk=128)
     mesh = cpu_mesh(4)
@@ -116,8 +122,6 @@ def test_ring_decode_matches_tree_decode():
 
 
 def test_ring_decode_composes_with_dp_and_tp():
-    from tree_attention_tpu.parallel import ring_decode
-
     rng = np.random.default_rng(9)
     q, k, v = make_qkv(rng, B=4, Hq=4, Hkv=4, Tq=1, Tk=64)
     mesh = cpu_mesh(8, {"data": 2, "model": 2, "seq": 2})

@@ -16,12 +16,14 @@ these bodies use (``tests/test_hybrid_conv.py``).
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from tests.jitted import packed_step, step
 from tests.test_hybrid_conv import SMALL as SMALL_HYBRID
 from tests.test_latent_moe import SMALL as SMALL_LATENT, SMALL_SC
 
@@ -72,6 +74,7 @@ def _leaves_named(tree, name):
             if getattr(path[-1], "key", None) == name]
 
 
+@functools.lru_cache(maxsize=None)
 def _model(body):
     make, leaf = BODIES[body]
     cfg = make()
@@ -85,25 +88,33 @@ def _cache(cfg):
     return dataclasses.replace(cache, table=table)
 
 
-def _tick_logits(params, cfg, program):
+def _tick_logits(params, cfg, program, eager=False):
     """A prefill step that leaves the slots at different lengths, then the
     program under test on top of it: a decode step of every slot (``tq1``)
-    or a packed tick (slot 1 takes a chunk of 6, the others a row each)."""
+    or a packed tick (slot 1 takes a chunk of 6, the others a row each).
+    Each step is one compiled program a (body, form) (``tests/jitted.py``),
+    so the two programs' cases share the prefill's; ``eager`` dispatches
+    primitive by primitive and traces anew every call."""
     rng = np.random.default_rng(11)
     toks = jnp.asarray(rng.integers(1, cfg.vocab_size, (SLOTS, 12)), jnp.int32)
-    _, cache = forward_step(params, toks, _cache(cfg), cfg,
-                            n_tokens=jnp.asarray([5, 0, 11], jnp.int32))
+    run, run_packed = (_eager_step, forward_packed_step) if eager \
+        else (step, packed_step)
+    _, cache = run(params, toks, _cache(cfg),
+                   jnp.asarray([5, 0, 11], jnp.int32), cfg)
     if program == "tq1":
-        logits, cache = forward_step(
-            params, toks[:, :1], cache, cfg,
-            n_tokens=jnp.ones((SLOTS,), jnp.int32))
+        logits, cache = run(params, toks[:, :1], cache,
+                            jnp.ones((SLOTS,), jnp.int32), cfg)
     else:
         chunk = jnp.asarray(rng.integers(1, cfg.vocab_size, (1, 8)), jnp.int32)
-        logits, cache = forward_packed_step(
+        logits, cache = run_packed(
             params, chunk, jnp.asarray([1], jnp.int32),
             jnp.asarray([6], jnp.int32), toks[:, 0],
             jnp.asarray([1, 0, 1], jnp.int32), cache, cfg)
     return np.asarray(logits), cache
+
+
+def _eager_step(params, tokens, cache, n_tokens, cfg):
+    return forward_step(params, tokens, cache, cfg, n_tokens=n_tokens)
 
 
 @pytest.mark.parametrize("program", ["tq1", "packed"])
@@ -140,7 +151,9 @@ def test_the_barrier_before_the_reshape_changes_no_bit(
 
     cfg, params, _ = _model(body)
     served = served_layout(params)
-    got, cache = _tick_logits(served, cfg, program)
+    # Eagerly: a compiled program would be found again, not traced again,
+    # and the patched barrier never called.
+    got, cache = _tick_logits(served, cfg, program, eager=True)
     barriers = []
 
     def no_barrier(operand):
@@ -148,7 +161,7 @@ def test_the_barrier_before_the_reshape_changes_no_bit(
         return operand
 
     monkeypatch.setattr(lax, "optimization_barrier", no_barrier)
-    want, cache_before = _tick_logits(served, cfg, program)
+    want, cache_before = _tick_logits(served, cfg, program, eager=True)
     # The products of both steps of ``_tick_logits`` went through it: flat
     # ``(B, T, H x (nope + rope))``, every latent sublayer.
     width = cfg.n_heads * (cfg.mla.nope + cfg.mla.rope)
@@ -177,6 +190,7 @@ def test_a_served_latent_tree_differentiates_as_the_outer_one(body):
         for _ in range(2))
     n_tokens = jnp.asarray([5, 0, 11], jnp.int32)
 
+    @jax.jit
     @jax.grad
     def grad(p):
         logits, _ = forward_step(p, toks, _cache(cfg), cfg, n_tokens=n_tokens)

@@ -96,17 +96,45 @@ def _reqs(n_new=24, eos=None):
 
 
 _REF_CACHE = {}
+_SERVERS = {}
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _servers_go_with_the_module():
+    yield
+    _SERVERS.clear()
+    _REF_CACHE.clear()
+
+
+def _server(params, drafter=None, **kw):
+    """ONE two-slot server a shape for the cases that only differ in what
+    they serve or in who drafts: the constructor jits bound methods, so
+    every new server compiles its tick programs again whatever JAX has
+    cached. ``drafter`` (a name or an object; ``None``: no speculation) is
+    host state the verify tick asks each tick, put in place per case; the
+    report's ``spec`` block counts from the start of its own ``serve``.
+    The pool is checked clean before a case gets the server, so no case
+    sees another's blocks. A case that reads process-wide counters or the
+    flight record over a whole run builds its own."""
+    key = (drafter is not None, tuple(sorted(kw.items())))
+    if key not in _SERVERS:
+        spec = dict(speculate=True, draft_k=5) if drafter is not None else {}
+        _SERVERS[key] = SlotServer(params, CFG, slots=2, cache_len=64,
+                                   **spec, **kw)
+    s = _SERVERS[key]
+    if drafter is not None:
+        s._drafter = (make_drafter(drafter) if isinstance(drafter, str)
+                      else drafter)
+    assert s._pool.used == 0 == s._pool.reserved
+    return s
 
 
 def _ref_tokens(params, n_new=24, eos=None, **kw):
-    """Non-speculative reference streams, memoized per server shape —
-    several parity tests share the same reference run, and every fresh
-    server pays its own jit compiles (the tier-1 time budget)."""
+    """Non-speculative reference streams, memoized per server shape and
+    request budget: several parity tests share the same reference run."""
     key = (n_new, eos, tuple(sorted(kw.items())))
     if key not in _REF_CACHE:
-        rep = SlotServer(params, CFG, slots=2, cache_len=64, **kw).serve(
-            _reqs(n_new, eos)
-        )
+        rep = _server(params, **kw).serve(_reqs(n_new, eos))
         _REF_CACHE[key] = {r.uid: r.tokens for r in rep.results}
     return _REF_CACHE[key]
 
@@ -441,11 +469,15 @@ def test_forward_step_tree_rows_equal_per_path_sequential(params):
         perm = np.array([7, 2, 9, 0, 5, 1, 8, 3], np.int32)  # fragmented
         return dc.replace(c, table=jnp.asarray(perm)[None])
 
+    # One program a (layout, token width): the prompt, the packed tree, and
+    # every root path at the deepest path's width with its length in
+    # ``n_tokens``, so that paths of 1, 2 and 3 rows share one.
+    step = jax.jit(lambda p, t, c, **kw: forward_step(p, t, c, CFG, **kw))
+    deepest = int(pack.depth.max()) + 1
     for mk in (lambda: init_cache(CFG, 1, 32), mk_paged):
-        _, cache = forward_step(params, jnp.asarray(prompt)[None], mk(),
-                                CFG)
-        logits, _ = forward_step(
-            params, jnp.asarray(pack.row_tokens)[None], cache, CFG,
+        _, cache = step(params, jnp.asarray(prompt)[None], mk())
+        logits, _ = step(
+            params, jnp.asarray(pack.row_tokens)[None], cache,
             n_tokens=jnp.asarray([Tq], jnp.int32),
             positions=jnp.asarray(7 + pack.depth)[None],
             tree_mask=jnp.asarray(pack.anc)[None],
@@ -456,14 +488,15 @@ def test_forward_step_tree_rows_equal_per_path_sequential(params):
                 path.append(j)
                 j = int(pack.row_parents[j])
             path = path[::-1]
+            rows = np.zeros((1, deepest), np.int32)
+            rows[0, :len(path)] = pack.row_tokens[path]
             # ``cache`` is the untouched prefilled base (functional
             # updates): every path replays from it directly.
-            lr, _ = forward_step(
-                params,
-                jnp.asarray(pack.row_tokens[path])[None], cache, CFG,
-            )
+            lr, _ = step(params, jnp.asarray(rows), cache,
+                         n_tokens=jnp.asarray([len(path)], jnp.int32))
             np.testing.assert_allclose(
-                np.asarray(lr[0, -1]), np.asarray(logits[0, i]), atol=2e-4
+                np.asarray(lr[0, len(path) - 1]), np.asarray(logits[0, i]),
+                atol=2e-4
             )
 
 
@@ -568,8 +601,7 @@ class OracleDrafter(Drafter):
 def _assert_parity(params, server_kw, drafter, n_new=24, eos=None,
                    min_accept=None):
     ref = _ref_tokens(params, n_new, eos, **server_kw)
-    s = SlotServer(params, CFG, slots=2, cache_len=64, speculate=True,
-                   draft_k=5, drafter=drafter, **server_kw)
+    s = _server(params, drafter, **server_kw)
     rep = s.serve(_reqs(n_new, eos))
     for r in rep.results:
         assert r.tokens == ref[r.uid], (
@@ -626,10 +658,9 @@ def test_spec_parity_ngram_all_combos(params, kw):
         return [Request(uid=u, prompt=p, max_new_tokens=n_new)
                 for u, p in prompts.items()]
 
-    ref = SlotServer(params, CFG, slots=2, cache_len=64, **kw).serve(reqs())
+    ref = _server(params, **kw).serve(reqs())
     ref = {r.uid: np.asarray(r.tokens, np.int32) for r in ref.results}
-    s = SlotServer(params, CFG, slots=2, cache_len=64, speculate=True,
-                   draft_k=draft_k, drafter="ngram", **kw)
+    s = _server(params, "ngram", **kw)
     rep = s.serve(reqs())
     for r in rep.results:
         assert r.tokens == ref[r.uid].tolist()
@@ -726,8 +757,7 @@ def test_draftless_ticks_run_narrow_and_match(params):
     verify — the engine must not pay the padded verify bucket (review
     finding) and the stream stays identical."""
     ref = _ref_tokens(params, n_new=10)
-    s = SlotServer(params, CFG, slots=2, cache_len=64, speculate=True,
-                   draft_k=5, drafter=_NeverDrafter())
+    s = _server(params, _NeverDrafter())
     rep = s.serve(_reqs(10))
     for r in rep.results:
         assert r.tokens == ref[r.uid]
@@ -757,8 +787,7 @@ def test_eos_inside_committed_burst_retires_same_tick(params):
     # The oracle drafts the NO-EOS continuation, so the EOS can land
     # anywhere inside an accepted burst.
     d = OracleDrafter(prompts, base)
-    s = SlotServer(params, CFG, slots=2, cache_len=64, speculate=True,
-                   draft_k=5, drafter=d)
+    s = _server(params, d)
     rep = s.serve(_reqs(24, eos))
     for r in rep.results:
         assert r.tokens == ref[r.uid]

@@ -33,7 +33,6 @@ from jax import lax
 from tree_attention_tpu import obs
 from tree_attention_tpu.models.decode import (
     PagedStateCache,
-    forward_packed_step,
     forward_step,
     init_paged_cache,
 )
@@ -59,9 +58,15 @@ from tree_attention_tpu.ops.pallas_ssm import (
 from tree_attention_tpu.serving import SlotServer
 from tree_attention_tpu.serving.engine import Request
 
+from tests.jitted import serve_step
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ATOL = 2e-5
 BLOCK = 4
+# The one chunk width the step helpers compile: two blocks of the scan (its
+# ``chunk_size`` is 8), so a step of 3, 8, 13 or 16 rows is the same program
+# and its raggedness rides in the length vector.
+WIDTH = 16
 
 # The family's published keys at a small size: four of this repo's layers in
 # four runs (an ssm layer with experts, one with no feed-forward half, the
@@ -393,40 +398,22 @@ def test_the_phase_rule_follows_the_shapes_and_fits_its_limit():
 # -- the engine's steps against the reference (a), (d) -----------------------
 
 
-def _serve_rows(params, tcfg, toks, steps, packed=False):
-    """Run ``steps`` (rows a slot a step) through the state cache: the
-    logits of the rows that carried a token, and the cache."""
+def _serve_rows(params, tcfg, toks, steps, packed=False, cache=None):
+    """Run ``steps`` (rows a slot a step) through the state cache (a new one,
+    or ``cache`` from where its lengths stand): the logits of the rows that
+    carried a token, and the cache. Every step is one of two compiled
+    programs a kind (``tests/jitted.py``): ``WIDTH`` tokens a slot with the
+    true counts in ``n_tokens`` / ``chunk_n``, as the engine's tick carries
+    them, or one."""
     slots = len(toks)
-    cache = _cache(tcfg, slots)
+    cache = _cache(tcfg, slots) if cache is None else cache
     got, pos = [[] for _ in range(slots)], [0] * slots
     for ns in steps:
-        tq = max(ns)
-        if packed:
-            c = int(np.argmax(ns))
-            ct = np.zeros((1, tq), np.int32)
-            ct[0, :ns[c]] = toks[c][pos[c]:pos[c] + ns[c]]
-            dec = np.asarray([toks[i][pos[i]] if i != c and ns[i] else 0
-                              for i in range(slots)], np.int32)
-            dn = np.asarray([int(i != c and ns[i] > 0)
-                             for i in range(slots)], np.int32)
-            logits, cache = forward_packed_step(
-                params, jnp.asarray(ct), jnp.asarray([c], jnp.int32),
-                jnp.asarray([ns[c]], jnp.int32), jnp.asarray(dec),
-                jnp.asarray(dn), cache, tcfg)
-            for i, n in enumerate(ns):
-                if n:
-                    got[i].append((pos[i] + n - 1, np.asarray(logits[i])))
-                    pos[i] += n
-            continue
-        t = np.zeros((slots, tq), np.int32)
+        rows, cache = serve_step(params, tcfg, cache, toks, pos, ns, WIDTH,
+                                 packed=packed)
+        for i, row, lg in rows:
+            got[i].append((row, lg))
         for i, n in enumerate(ns):
-            t[i, :n] = toks[i][pos[i]:pos[i] + n]
-        logits, cache = forward_step(
-            params, jnp.asarray(t), cache, tcfg,
-            n_tokens=jnp.asarray(ns, jnp.int32))
-        for i, n in enumerate(ns):
-            for j in range(n):
-                got[i].append((pos[i] + j, np.asarray(logits[i, j])))
             pos[i] += n
     return got, cache
 
@@ -435,7 +422,12 @@ def _serve_rows(params, tcfg, toks, steps, packed=False):
 def test_prefill_in_chunks_then_decode_equals_the_reference(ref, model, chunk):
     """Chunks under the scan's block of 8 (3), at it, off its multiples (13)
     and of two blocks (16), ragged between the slots, then decode: every
-    row's logits are the reference's full forward pass."""
+    row's logits are the reference's full forward pass. The chunk is how
+    many rows a step CARRIES (``n_tokens``): the state leaves a step at
+    positions 3, 6, ... or 13, 26 and the next step takes it up there; the
+    token block is ``WIDTH`` wide for all four, as an engine's tick is.
+    (What the scan does with a block of 5 or 13 rows that it must pad itself
+    is ``test_the_chunked_scan_equals_the_recurrence``'s.)"""
     w, weights, tcfg, params = model
     rng = np.random.default_rng(chunk)
     toks = [rng.integers(0, 128, (40,)), rng.integers(0, 128, (31,))]
@@ -472,8 +464,8 @@ def test_a_slot_with_no_row_keeps_its_state_and_tail_bit_for_bit(
     rng = np.random.default_rng(8)
     toks = [rng.integers(0, 128, (20,)), rng.integers(0, 128, (20,))]
     _, c0 = _serve_rows(params, tcfg, toks, [[7, 9]])
-    _, c1 = _serve_rows(params, tcfg, toks, [[7, 9], [5, 0], [1, 0]],
-                        packed=packed)
+    _, c1 = _serve_rows(params, tcfg, [toks[0][7:], toks[1][9:]],
+                        [[5, 0], [1, 0]], packed=packed, cache=c0)
     for name in ("ssm_state", "ssm_tail"):
         a, b = np.asarray(getattr(c0, name)), np.asarray(getattr(c1, name))
         np.testing.assert_array_equal(a[:, 1], b[:, 1])
@@ -497,15 +489,11 @@ def test_a_slot_whose_length_goes_back_to_0_starts_from_a_zero_state(
     new = rng.integers(0, 128, (first + 6,))
     cache = dataclasses.replace(cache, length=cache.length.at[0].set(0))
     want = _want(ref, w, weights, new)
-    pos = 0
-    for n in [first] + [1] * 6:
-        t = np.zeros((2, n), np.int32)
-        t[0] = new[pos:pos + n]
-        logits, cache = forward_step(
-            params, jnp.asarray(t), cache, tcfg,
-            n_tokens=jnp.asarray([n, 0], jnp.int32))
-        np.testing.assert_allclose(logits[0], want[pos:pos + n], atol=ATOL)
-        pos += n
+    got, _ = _serve_rows(params, tcfg, [new, old[1]],
+                         [[first, 0]] + [[1, 0]] * 6, cache=cache)
+    assert [row for row, _ in got[0]] == list(range(first + 6))
+    np.testing.assert_allclose(np.stack([lg for _, lg in got[0]]), want,
+                               atol=ATOL)
 
 
 @pytest.mark.parametrize("fault, least", [("state_bf16", 3e-6),
@@ -558,9 +546,14 @@ def test_a_reused_slot_serves_what_a_fresh_engine_serves(ref, model):
     assert by_uid[0] == _greedy(ref, weights, w, prompts[3], 40)
     for uid, p, n in ((1, 0, 6), (2, 1, 7), (3, 2, 5)):
         assert by_uid[uid] == _greedy(ref, weights, w, prompts[p], n)
-        fresh = _engine(tcfg, params, slots=1).serve(
-            [Request(uid=9, prompt=prompts[p], max_new_tokens=n)])
-        assert fresh.results[0].tokens == by_uid[uid]
+    # The last of the three found two requests' leavings in its slot: a
+    # fresh engine's first request, in a slot nothing has touched, is served
+    # the same. (One fresh engine, not one a request: every new engine
+    # compiles its tick programs again, and the first two are held to the
+    # reference's choice above like the third.)
+    fresh = _engine(tcfg, params, slots=1).serve(
+        [Request(uid=9, prompt=prompts[2], max_new_tokens=5)])
+    assert fresh.results[0].tokens == by_uid[3]
     dec = [r for r in recs if not r.get("chunk_tokens") and r["occupancy"]]
     assert dec and all(r["ssm_states_advanced"] == 3 * r["occupancy"]
                        for r in dec)

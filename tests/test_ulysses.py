@@ -9,12 +9,16 @@ import jax
 import jax.numpy as jnp
 
 from tree_attention_tpu.ops import attention_naive
-from tree_attention_tpu.parallel import (
-    cpu_mesh,
-    ring_attention,
-    tree_attention,
-    ulysses_attention,
-)
+from tree_attention_tpu import parallel
+from tree_attention_tpu.parallel import cpu_mesh
+
+from tests.jitted import jitted
+
+# One program a call, as a user's jitted step runs them: eagerly a sharded
+# call is a launch a primitive a shard (``tests/jitted.py``).
+ring_attention = jitted(parallel.ring_attention)
+tree_attention = jitted(parallel.tree_attention)
+ulysses_attention = jitted(parallel.ulysses_attention)
 
 
 def make_qkv(rng, B=2, Hq=8, Hkv=8, Tq=128, Tk=128, D=32, dtype=np.float32):

@@ -32,8 +32,6 @@ import pytest
 from tree_attention_tpu import obs
 from tree_attention_tpu.models.decode import (
     PagedWindowCache,
-    forward_packed_step,
-    forward_step,
     init_paged_cache,
 )
 from tree_attention_tpu.models.hybrid import layer_runs
@@ -51,9 +49,14 @@ from tree_attention_tpu.serving import SlotServer
 from tree_attention_tpu.serving.block_pool import WindowBlocks
 from tree_attention_tpu.serving.engine import Request
 
+from tests.jitted import serve_step
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ATOL = 2e-5
 BLOCK, WINDOW = 4, 8
+# The one chunk width the step helpers compile: two windows, so a step of 3,
+# 4, 8 or 16 rows (under, at and over the window) is the same program.
+WIDTH = 16
 
 # The family's published keys at a small size: the period ``L L L G``, one
 # leading dense FFN, the second of two shares of 4 of 8 experts, top 2.
@@ -304,7 +307,13 @@ def _serve_rows(params, tcfg, toks, steps, slots=2, nb=16, chunk=16,
     """Run ``steps`` (rows a slot a step) through the two pools with the
     window table kept by the engine's own ledger (blocks behind the window
     given back before each step, scrambled ids): the logits of the rows
-    that carried a token, the ledger, and the most blocks a slot held."""
+    that carried a token, the ledger, and the most blocks a slot held.
+    ``chunk`` is the most rows a step carries, which is what the ledger's
+    bound counts; the token block is ``WIDTH`` wide whatever the chunk (or
+    one row wide), two compiled programs a kind (``tests/jitted.py``), with
+    the true counts in ``n_tokens`` / ``chunk_n`` as a tick carries them:
+    the rows past a slot's count are written nowhere, so the ledger maps
+    blocks for the rows that are real."""
     cache = init_paged_cache(tcfg, slots, nb * BLOCK, slots * nb, block=BLOCK,
                              window_blocks=64)
     assert isinstance(cache, PagedWindowCache)
@@ -322,35 +331,11 @@ def _serve_rows(params, tcfg, toks, steps, slots=2, nb=16, chunk=16,
                 win.advance(i, pos[i], pos[i] + n)
         peak = max(peak, max(win.held(i) for i in range(slots)))
         cache = dataclasses.replace(cache, wtable=jnp.asarray(win.table))
-        tq = max(ns)
-        if packed:
-            # One chunk member (the slot with most rows) beside one decode
-            # row a slot.
-            c = int(np.argmax(ns))
-            ct = np.zeros((1, tq), np.int32)
-            ct[0, :ns[c]] = toks[c][pos[c]:pos[c] + ns[c]]
-            dec = np.asarray([toks[i][pos[i]] if i != c and ns[i] else 0
-                              for i in range(slots)], np.int32)
-            dn = np.asarray([int(i != c and ns[i] > 0)
-                             for i in range(slots)], np.int32)
-            logits, cache = forward_packed_step(
-                params, jnp.asarray(ct), jnp.asarray([c], jnp.int32),
-                jnp.asarray([ns[c]], jnp.int32), jnp.asarray(dec),
-                jnp.asarray(dn), cache, tcfg)
-            for i, n in enumerate(ns):
-                if n:
-                    got[i].append((pos[i] + n - 1, np.asarray(logits[i])))
-                    pos[i] += n
-            continue
-        t = np.zeros((slots, tq), np.int32)
+        rows, cache = serve_step(params, tcfg, cache, toks, pos, ns, WIDTH,
+                                 packed=packed)
+        for i, row, lg in rows:
+            got[i].append((row, lg))
         for i, n in enumerate(ns):
-            t[i, :n] = toks[i][pos[i]:pos[i] + n]
-        logits, cache = forward_step(
-            params, jnp.asarray(t), cache, tcfg,
-            n_tokens=jnp.asarray(ns, jnp.int32))
-        for i, n in enumerate(ns):
-            for j in range(n):
-                got[i].append((pos[i] + j, np.asarray(logits[i, j])))
             pos[i] += n
     return got, win, peak
 
