@@ -43,7 +43,10 @@ Phases of the default run, one JSON object per line on stdout:
                 K/V pool and its per-slot state; eva:
                 ``benchmark/configs/evabyte.json`` from its exact rows' and
                 its summary rows' pools (a request across three window
-                boundaries, a reused slot, a slot that sits ticks out),
+                boundaries, a reused slot, a slot that sits ticks out);
+                parallel: ``falcon-h1-34b-instruct.json`` at 3 layers, a
+                state AND K/V rows in every layer (a chunked prompt of odd
+                length, a reused slot, a slot that sits ticks out),
                 each held to its family's plain reference likewise
   times      h  wall time per phase and compile-cache traffic — set-up
                 information only; nothing here is a rate or a benchmark
@@ -1391,18 +1394,55 @@ def phase_state(s: Sizes, config: Optional[Dict[str, Any]] = None, *,
     token-by-token recurrence, no cache) as :func:`phase_hybrid` holds its
     own: a request's MEAN gap lies under ``tol_gap``; a stale state leaves
     every later token at the logits' own spread."""
+    return _serve_from_a_state_pool(
+        config or _benchmark_config("nemotron-3-super-120b-a12b.json"),
+        device=device, block=block, chunk=chunk, tol_gap=tol_gap)
+
+
+def phase_parallel(s: Sizes, config: Optional[Dict[str, Any]] = None, *,
+                   device: str = "tpu", block: int = 64, chunk: int = 256,
+                   tol_gap: float = 0.4) -> Dict[str, Any]:
+    """Two mixers side by side in every layer
+    (``benchmark/configs/falcon-h1-34b-instruct.json``: a Mamba-2 state of 32
+    heads x 128 x 256 and rotary GQA 20 / 4 x 128 on one normed residual, the
+    family's fixed multipliers, an MLP of 21,504, at the published widths and
+    a REDUCED depth: 3 of the cut's 9 layers), served as :func:`phase_state`
+    serves its own, from a cache whose every layer holds K/V rows AND a
+    state: a long request through a chunked prompt of odd length (two whole
+    chunks and a rest of 77 rows) and 96 decoded tokens, a short one beside
+    it, a third in the short one's slot once it has retired, a fourth alone
+    while the other slot sits every tick out; every served token held to the
+    plain reference's logits (``benchmark/references/falcon_h1.py``)."""
+    return _serve_from_a_state_pool(
+        config or dict(_benchmark_config("falcon-h1-34b-instruct.json"),
+                       num_hidden_layers=3),
+        device=device, block=block, chunk=chunk, tol_gap=tol_gap)
+
+
+def _benchmark_spec():
+    from benchmark.spec import Spec
+
+    return Spec(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                             "BENCHMARK.json"))
+
+
+def _benchmark_config(name: str) -> Dict[str, Any]:
+    return _benchmark_spec().load_json("configs", name)
+
+
+def _serve_from_a_state_pool(config: Dict[str, Any], *, device: str,
+                             block: int, chunk: int, tol_gap: float
+                             ) -> Dict[str, Any]:
+    """What :func:`phase_state` and :func:`phase_parallel` run, each for its
+    configuration."""
     import numpy as np
 
     from benchmark import check as served
-    from benchmark.spec import Spec
     from tree_attention_tpu import cli
     from tree_attention_tpu.serving.engine import Request
     from tree_attention_tpu.utils.config import parse_args
 
-    spec = Spec(os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                             "BENCHMARK.json"))
-    if config is None:
-        config = spec.load_json("configs", "nemotron-3-super-120b-a12b.json")
+    spec = _benchmark_spec()
     ref = spec.load_module("references", config["family"] + ".py")
     adapter = spec.load_module("adapters", config["family"] + ".py")
     w = ref.Widths.of(config)
@@ -1437,7 +1477,7 @@ def phase_state(s: Sizes, config: Optional[Dict[str, Any]] = None, *,
     prompts = {0: a, 1: b, 2: c, 3: b[::-1]}
     check(len(results) == 4 and all(r.outcome == "budget" for r in results),
           "four requests served to their budgets through two slots")
-    check(len(a) % int(config.get("chunk_size", 128)) != 0,
+    check(len(a) % setup.tcfg.ssm.chunk != 0,
           "the long prompt does not end on a multiple of the scan's block")
     leak = server.leak_report()
     check(not (leak["blocks_used"] or leak["blocks_private"]
@@ -1583,6 +1623,7 @@ def run_default(run: Run, s: Sizes) -> None:
     run.phase("window", phase_window, s)
     run.phase("state", phase_state, s)
     run.phase("eva", phase_eva, s)
+    run.phase("parallel", phase_parallel, s)
 
 
 def run_four_chips(run: Run, s: Sizes) -> None:

@@ -69,6 +69,8 @@ EVA_METRICS = ["eva_local_ms_tick", "eva_local_decode_roofline",
                "eva_summary_ms_tick", "eva_summary_decode_roofline",
                "eva_summaries_written_pct"]
 EVA_CELL = "evabyte_bytedoc_sat"
+# PR 47 appended no metric: its cell joins the lists of the metrics it reports.
+FH_CELL = "falconh1_agentturn_sat"
 
 
 def _sources(but=()):
@@ -495,7 +497,7 @@ def test_the_hybrid_cell_resolves_every_file_it_names():
     # The traffic file that was there, and the cell the sixth of six.
     assert cell.traffic == lc.traffic and cell.traffic["kind"] == "backlog"
     assert [w["name"] for w in spec.data["workloads"]][5:] == [
-        LFM_CELL, KX_CELL, NS_CELL, EVA_CELL]
+        LFM_CELL, KX_CELL, NS_CELL, EVA_CELL, FH_CELL]
     assert all(w["chips"] == 1 for w in spec.data["workloads"])
     assert [m["name"] for m in cell.end_to_end] == [
         "out_tok_s", "tbt_p50_ms", "tbt_p99_ms", "setup_s"]
@@ -690,8 +692,8 @@ def test_a_parts_metric_is_listed_where_its_part_exists(name):
     """Fourteen entries appended by PR 35: device-trace metrics of the tick
     programs, the decode ones moving ``tbt_p50_ms`` and the mixed ones
     ``tbt_p99_ms``; the expert parts in the five expert cells, the conv
-    parts in the hybrid's and the state configuration's, the rest in all
-    nine."""
+    parts in the hybrid's and the two state configurations', the rest in all
+    ten."""
     spec = Spec(BENCH)
     listed = json.load(open(BENCH))["per_layer"]
     tail = len(PARTS_ALL) + len(AFTER_PARTS) + len(WINDOW_METRICS) \
@@ -706,7 +708,7 @@ def test_a_parts_metric_is_listed_where_its_part_exists(name):
     elif "_conv_" in name:
         # The part ``conv``: a mixer with a fixed-size state (the short
         # convolution; a state-space mixer between its projections).
-        want = [LFM_CELL, NS_CELL]
+        want = [LFM_CELL, NS_CELL, FH_CELL]
     if name.startswith("mix_"):
         # The window cell's traced 3 s hold a chunk tick in most runs and
         # none in some (1.5 a second, in clusters): a reader that finds
@@ -758,7 +760,8 @@ def test_paged_steps_run_pct_reads_the_lists_the_kernels_walk():
     for cell in cells:
         last = -1 - len(WINDOW_METRICS) * (cell == KX_CELL) \
             - len(STATE_METRICS) * (cell == NS_CELL) \
-            - (1 + len(EVA_METRICS)) * (cell == EVA_CELL)
+            - (1 + len(EVA_METRICS)) * (cell == EVA_CELL) \
+            - 3 * (cell == FH_CELL)     # the kernel's two and the pool's
         assert spec.cell(cell).per_layer[last]["name"] \
             == "paged_steps_run_pct"
     read = spec.load_module("layer_metrics", "paged_steps_run_pct.py").read
@@ -801,7 +804,7 @@ def test_the_window_cell_resolves_every_file_it_names():
     assert cell.traffic["kind"] == "backlog"
     assert spec.find("traffic", "reasoning_long_backlog.json")
     assert [w["name"] for w in spec.data["workloads"]][6:] == [
-        KX_CELL, NS_CELL, EVA_CELL]
+        KX_CELL, NS_CELL, EVA_CELL, FH_CELL]
     assert all(w["chips"] == 1 for w in spec.data["workloads"])
     assert [m["name"] for m in cell.end_to_end] == [
         "out_tok_s", "tbt_p50_ms", "tbt_p99_ms", "setup_s"]
@@ -1012,7 +1015,7 @@ def test_the_state_cell_resolves_every_file_it_names():
     # on four chips.
     assert cell.traffic == lc.traffic and cell.traffic["kind"] == "backlog"
     assert [w["name"] for w in spec.data["workloads"]][7:] == [
-        NS_CELL, EVA_CELL]
+        NS_CELL, EVA_CELL, FH_CELL]
     assert all(w["chips"] == 1 for w in spec.data["workloads"])
     assert [m["name"] for m in cell.end_to_end] == [
         "out_tok_s", "tbt_p50_ms", "tbt_p99_ms", "setup_s"]
@@ -1035,7 +1038,10 @@ def test_the_state_cell_resolves_every_file_it_names():
         assert name not in names
     listed = {m["name"]: m for m in spec.data["per_layer"]}
     for name in STATE_METRICS:
-        assert listed[name]["workloads"] == [NS_CELL]
+        # (The second state-space configuration's cell reports the
+        # kernel's and the pool's metrics, not the ungated product's.)
+        assert listed[name]["workloads"] == [NS_CELL] + [FH_CELL] * (
+            not name.startswith("moe_ungated"))
         assert listed[name]["moves"] == "tbt_p50_ms"
     assert [listed[n]["layer"] for n in STATE_METRICS] == [
         "kernels"] * 4 + ["state pool"]
@@ -1205,7 +1211,8 @@ def test_the_eva_cell_resolves_every_file_it_names():
     # none on four chips.
     assert cell.traffic["kind"] == "backlog"
     assert spec.find("traffic", "bytedoc_backlog.json")
-    assert [w["name"] for w in spec.data["workloads"]][8:] == [EVA_CELL]
+    assert [w["name"] for w in spec.data["workloads"]][8:] == [
+        EVA_CELL, FH_CELL]
     assert all(w["chips"] == 1 for w in spec.data["workloads"])
     assert [m["name"] for m in cell.end_to_end] == [
         "out_tok_s", "tbt_p50_ms", "tbt_p99_ms", "setup_s"]
@@ -1397,3 +1404,140 @@ def test_eva_summaries_written_pct_reads_the_steps_counter():
     # Nothing due: nothing to read, not 0 over 0.
     run.flight = [flight[2], dict(flight[2], t_s=3.7)]
     assert read(run) is None
+
+
+# -- two mixers a layer on one norm: the two-branch cell (ISSUE 47) ----------
+
+
+def test_the_two_branch_cell_resolves_every_file_it_names():
+    spec = Spec(BENCH)
+    cell, ns = spec.cell(FH_CELL), spec.cell(NS_CELL)
+    assert cell.chips == 1 and cell.config["family"] == "falcon_h1"
+    assert cell.config["name"] == "falcon-h1-34b-instruct"
+    for d, mod in (("references", cell.reference()),
+                   ("adapters", cell.adapter())):
+        assert mod.__file__.endswith(os.path.join(d, "falcon_h1.py"))
+    with open(cell.reference().__file__) as f:
+        text = f.read()
+    body = text.split('"""', 2)[2]
+    assert "tree_attention_tpu" not in body and "benchmark" not in body
+    assert "lax.scan(token" in text       # the recurrence, token by token
+    assert 'jax.nn.softmax(jnp.where(see' in text   # one dense softmax
+    assert cell.reference().CONTROLS == (
+        "int8", "serial", "no_ssm_branch", "unit_multipliers")
+    # The traffic file that was there, the cell the tenth of ten, none on
+    # four chips; no metric, cost file, reader or mix of its own.
+    assert cell.traffic == ns.traffic and cell.traffic["kind"] == "backlog"
+    assert [w["name"] for w in spec.data["workloads"]][9:] == [FH_CELL]
+    assert all(w["chips"] == 1 for w in spec.data["workloads"])
+    assert [m["name"] for m in cell.end_to_end] == [
+        "out_tok_s", "tbt_p50_ms", "tbt_p99_ms", "setup_s"]
+    names = [m["name"] for m in cell.per_layer]
+    for name in names:
+        assert spec.load_module("layer_metrics", name + ".py").read
+    for name in ("occupancy_pct", "kv_blocks_peak_pct", "hbm_peak_gb",
+                 "tick_rows_useful_pct", "paged_steps_run_pct",
+                 "tick_unscoped_pct", "attn_kernel_ms_tick",
+                 "flash_decode_paged_roofline", "ssm_update_ms_tick",
+                 "ssm_decode_update_roofline", "ssm_states_advanced_pct",
+                 "device_idle_pct", "decode_tick_p50_ms",
+                 "mixed_tick_p50_ms", "tick_ahead_pct") + tuple(
+            n for n in PARTS_ALL if "_moe_" not in n):
+        assert name in names, name
+    for name in names:
+        assert not name.startswith(("moe_", "mla_", "window_", "eva_",
+                                    "mixer_rest", "zero_expert", "expert",
+                                    "real_experts", "dec_moe", "mix_moe",
+                                    "ttft", "gen_late", "queue_wait"))
+    assert cell.config["serving"] == {
+        "slots": 48, "cache_len": 2560, "kv_layout": "paged", "kv_block": 64,
+        "admission": "chunked", "prefill_chunk": 256, "prefix_cache": False}
+    assert list(cell.config["correct"]["limits"]) == ["gap_mean"]
+    for kernel in ("flash_decode_paged", "ssm_decode_update"):
+        assert cell.adapter().kernel_call(cell.config, kernel)[1] == 9
+        assert spec.load_module("kernel_costs", kernel + ".py").cost
+    assert cell.adapter().kernel_call(cell.config, "mla_decode_paged") is None
+
+
+def test_the_two_branch_configurations_file_against_the_catalog():
+    """Every number of the catalog's ``config`` under the same key but the
+    two keys ``reduced`` lists; the cut's arithmetic; every assumed rule
+    marked unconfirmed; every published multiplier as it is published."""
+    path = "/opt/skills/guides/model-configs/architectures.jsonl"
+    with open(BENCH) as f:
+        entry = next(c for c in json.load(f)["configs"]
+                     if c["name"] == "falcon-h1-34b-instruct")
+    with open(os.path.join(ROOT, entry["file"])) as f:
+        c = json.load(f)
+    assert entry["reduced"] == c["reduced"] == [
+        "num_hidden_layers", "vocab_size"]
+    assert c["source"] == entry["source"]
+    if os.path.exists(path):
+        row = next(r for r in map(json.loads, open(path))
+                   if r["name"] == "Falcon-H1-34B-Instruct")
+        assert entry["source"] == row["source_url"]
+        for k, v in row["config"].items():
+            if k not in c["reduced"]:
+                assert c[k] == v, k
+        assert c["published"] == {k: row["config"][k] for k in c["reduced"]}
+    assert (c["num_hidden_layers"], c["vocab_size"]) == (9, 32640)
+    assert c["published"] == {"num_hidden_layers": 72, "vocab_size": 261120}
+    # Floors: whole periods of one layer, 9 >= 4, exactly an eighth of the
+    # vocabulary.
+    assert c["vocab_size"] * 8 == c["published"]["vocab_size"]
+    h, f_, inner = c["hidden_size"], c["intermediate_size"], c["mamba_d_ssm"]
+    gn = c["mamba_n_groups"] * c["mamba_d_state"]
+    q = c["num_attention_heads"] * c["head_dim"]
+    kv = c["num_key_value_heads"] * c["head_dim"]
+    attn = 2 * h * q + 2 * h * kv
+    ssm = h * (2 * inner + 2 * gn + c["mamba_n_heads"]) + inner * h \
+        + (c["mamba_d_conv"] + 1) * (inner + 2 * gn) \
+        + 3 * c["mamba_n_heads"] + inner
+    layer = attn + ssm + 3 * h * f_ + 2 * h
+    assert (attn, ssm, 3 * h * f_) == (31_457_280, 68_351_072, 330_301_440)
+    assert abs(layer / 1e6 - 430.12) < 0.005
+    held = 9 * layer + 2 * c["vocab_size"] * h + h
+    assert abs(held / 1e6 - 4205.3) < 0.05 and "4,205.3M" in c["why_reduced"]
+    whole = 72 * layer + 2 * 261120 * h + h
+    assert abs(whole / 1e9 - 33.64) < 0.005 and "33.64B" in c["why_reduced"]
+    dep = c["deployment"]
+    assert (dep["chips"], dep["pipeline_stages"], dep["stage"]) == (8, 8, 0)
+    assert c["block"] == dict(
+        c["block"], mixer_arrangement="parallel_shared_norm",
+        mup_segments=["z", "x", "B", "C", "dt"],
+        rotary_convention="half_split")
+    for rule in ("mixer_arrangement", "mup_segments", "rotary_convention",
+                 "time_step_limit", "grouped_norm"):
+        assert "unconfirmed" in c["assumed"]["unconfirmed"][rule]
+    assert "float32" in c["assumed"]["state_dtype"]
+    assert set(c["assumed"]["seeded_scales"]) == {
+        "embedding_std", "head_std", "qk_std", "v_std", "attn_out_std",
+        "ssm_in_std", "ssm_out_std", "mlp_gate_std", "mlp_up_std",
+        "mlp_down_std", "gain_mean", "gain_std"}
+    assert len(c["ssm_multipliers"]) == 5 and len(c["mlp_multipliers"]) == 2
+
+
+def test_the_two_branch_adapter_refuses_another_model_at_once():
+    spec = Spec(BENCH)
+    cell = spec.cell(FH_CELL)
+    adapter = cell.adapter()
+    with pytest.raises(SpecError, match="cannot read"):
+        adapter.build({"family": "falcon_h1"}, [], 0, "cpu", None)
+    from tree_attention_tpu.models.transformer import model_from_config
+    model = model_from_config(cell.config)
+    adapter._hold_to_file(model, cell.config)
+    # A file that states another multiplier than the engine would apply.
+    for key, value in (("key_multiplier", 1.0),
+                       ("ssm_multipliers", [1.0] * 5),
+                       ("mamba_d_state", 128)):
+        with pytest.raises(SpecError, match="built otherwise"):
+            adapter._hold_to_file(model, dict(cell.config, **{key: value}))
+    # A program whose model has no two-branch layer (the serial state
+    # family's): refused before a weight is drawn.
+    other = model_from_config(spec.cell(NS_CELL).config)
+    with pytest.raises(SpecError, match="built otherwise"):
+        adapter._hold_to_file(other, cell.config)
+    # A program that knows no multipliers (what the parent commit builds).
+    import types
+    with pytest.raises(SpecError, match="cannot express"):
+        adapter._hold_to_file(types.SimpleNamespace(ssm=None), cell.config)

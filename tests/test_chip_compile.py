@@ -99,14 +99,18 @@ LFM2 = dict(slots=64, hkv=4, nb=40, layers=3)
 # The state configuration's one attention layer: 2 KV heads x 128 under 32
 # query heads, 16 a KV head.
 NEMOTRON3S = dict(slots=64, hkv=2, nb=40, layers=1)
+# The two-branch configuration: every one of its 9 layers holds K/V rows, 4
+# KV heads x 128 (Yi-6B's pool rows) under 20 query heads, a group of FIVE
+# (5 packed query rows in a tile of 8 sublanes).
+FALCONH1 = dict(slots=48, hkv=4, nb=40, layers=9, hq=20)
 
 
 def _paged(kernel, tq, *, int8=False, tree=False, slots=B, hkv=HKV, nb=NB,
-           layers=1, **kw):
+           layers=1, hq=HQ, **kw):
     """(fn, abstract args) of one paged decode kernel call at Tq = tq."""
     n = layers * slots * nb
     pool = _s((n, hkv, BLK, D), jnp.int8 if int8 else jnp.bfloat16)
-    args = [_s((slots, HQ, tq, D)), pool, pool]
+    args = [_s((slots, hq, tq, D)), pool, pool]
     if int8:
         args += [_s((n, hkv), jnp.float32)] * 2
     args += [_s((slots, nb), jnp.int32), _s((slots,), jnp.int32)]
@@ -160,7 +164,9 @@ def _row_write(*, int8=False, pools=2, slots=B, hkv=HKV, nb=NB, layers=1,
 def _ssm_update(slots=64, layers=5, hp=64, n=128, lanes=128, groups=8):
     """(fn, abstract args, donated) of one ``ssm_decode_update`` call at the
     state configuration's widths: 128 heads x 64 x 128, two heads a row of
-    lanes, the five layers' pool of 64 slots (1.34 GB) donated."""
+    lanes, the five layers' pool of 64 slots (1.34 GB) donated. (The
+    two-branch configuration's: 32 heads x 128 x 256 in 2 groups, a head a
+    row, nine layers' pool of 48 slots, 1.81 GB.)"""
     from tree_attention_tpu.ops.pallas_ssm import ssm_decode_update
 
     f32 = jnp.float32
@@ -335,6 +341,18 @@ CASES = {
     "row_write_evabyte": (
         lambda: _row_write(slots=16, hkv=32, nb=38, layers=8), ROW_WRITE),
     "ssm_update_nemotron3s": (_ssm_update, "ssm_decode_update"),
+    # The kernel's second shape: `pack` 1, a (2, 256) tile of B and C turned
+    # to columns, 16 unrolled rows a group.
+    "ssm_update_falconh1": (
+        lambda: _ssm_update(slots=48, layers=9, hp=32, n=256, groups=2),
+        "ssm_decode_update"),
+    "paged_decode_falconh1_tq1": (
+        lambda: _paged(attention_pallas_decode, 1, **FALCONH1),
+        "flash_decode_paged"),
+    # A 256-row chunk group's call (one member), on the Q-tiled path.
+    "paged_chunk_falconh1_tq256": (
+        lambda: _paged(attention_pallas_decode, 256,
+                       **dict(FALCONH1, slots=1)), "flash_decode_paged"),
     "moe_ungated_decode_pairs": (lambda: _moe_ungated(1408),
                                  "moe_ungated_matmul"),
     "moe_ungated_chunk_pairs": (lambda: _moe_ungated(7168),
@@ -418,6 +436,7 @@ def test_kernel_compiles_for_v5e(case):
         assert f'"size":"{limit}"' in text, limit
         assert "moe_grouped_matmul" not in "moe_ungated_matmul"
     if "_mistral7b" in case or "_yi6b" in case or "_lfm2" in case \
+            or ("_falconh1" in case and kernel != "ssm_decode_update") \
             or ("_evabyte" in case and kernel != ROW_WRITE):
         # The pool goes into the call as it is: no copy, slice or change of
         # layout of a pool-sized array before the launch (what a 576-lane
@@ -1139,6 +1158,66 @@ def test_state_step_compiles_and_keeps_the_pools_in_place(tq, packed):
     assert tick.temp_bytes < state_layer, tick.temp_bytes
 
 
+# ``falcon-h1-34b-instruct``: EVERY layer holds both, nine layers' state ``(9,
+# 48, 32, 256, 128)`` float32 (1.81 GB: 32 heads x 128 x 256 a slot a layer, a
+# head a row of lanes) and tails ``(9, 48, 15360)`` beside nine layers' K/V
+# pool ``(9, N, 4, 64, 128)``, all four carried whole through ONE run of
+# layers under one body that calls both branches.
+
+PARALLEL_CONFIG = "falcon-h1-34b-instruct"
+
+
+@pytest.mark.parametrize("tq,packed", [(1, False), (256, True)],
+                         ids=["tq1", "packed256"])
+def test_parallel_step_compiles_and_keeps_the_four_pools_in_place(tq, packed):
+    if _chip() is None:
+        pytest.skip("the v5e:2x2 topology cannot be described here")
+    c, cfg = _model(PARALLEL_CONFIG)
+    slots, blk = c["serving"]["slots"], c["serving"]["kv_block"]
+    blocks = _pool_blocks(PARALLEL_CONFIG)
+    tick = _tick_program(PARALLEL_CONFIG, tq, packed=packed)
+    text = tick.text
+    kernels = pallas_kernels(text)
+    assert {"flash_decode_paged", "ssm_decode_update", ROW_WRITE} \
+        <= set(kernels), kernels
+    if packed:
+        # (A state is 256 wide, as the chunk is long: the state pool and the
+        # decode group's B and C rows are no padded rows.)
+        padding = [(dt, dims) for dt, dims in _padding_arrays(
+            text, slots, tq, cfg.vocab_size, cfg.d_model)
+            if dims[-2:] != (256, 128) and dims != (slots, 2, 256)]
+        assert not padding, padding
+    state_layer = slots * 32 * 256 * 128
+    tails, kv_layer = 9 * slots * 3 * 5120, blocks * 4 * blk * 128
+    moved, in_place = [], []
+    for name, result, opcode, inner in _materialised(text):
+        if opcode in _MOVES_NOTHING:
+            continue
+        sizes = {dt: max((math.prod(int(d) for d in dims.split(","))
+                          for dims in re.findall(rf"\b{dt}\[([\d,]+)\]",
+                                                 result)), default=0)
+                 for dt in ("f32", "bf16")}
+        if sizes["f32"] >= state_layer:
+            if name.startswith("%ssm_decode_update") \
+                    or "dynamic-update-slice(" in inner \
+                    or opcode == "dynamic-update-slice":
+                in_place.append(name)
+            else:
+                moved.append((name, opcode, result))
+        elif sizes["bf16"] >= min(tails, kv_layer) and opcode == "copy":
+            moved.append((name, opcode, result))
+    assert not moved, moved
+    # One run of nine layers is one loop: one decode launch in its body, a
+    # chunk member's state written back once.
+    launches = [n for n in in_place if n.startswith("%ssm_decode_update")]
+    assert len(launches) == 1, in_place
+    assert len(in_place) - len(launches) == (1 if packed else 0), in_place
+    assert _row_writes(text) == 1
+    assert tick.alias_bytes >= 4 * 9 * state_layer + 2 * tails \
+        + 2 * 2 * 9 * kv_layer, tick.alias_bytes
+    assert tick.temp_bytes < state_layer, tick.temp_bytes
+
+
 # -- layers whose block counts differ: two pools under two tables (ISSUE 38) -
 #
 # ``k-exaone-236b-a23b``: two full-attention layers' K/V pools ``(2, N, 8, 64,
@@ -1321,7 +1400,7 @@ def test_eva_step_compiles_and_copies_neither_pool(tq, packed):
 
 ALL_CONFIGS = STEP_CONFIGS + LATENT_CONFIGS + (
     "lfm2-8b-a1b", "k-exaone-236b-a23b", "nemotron-3-super-120b-a12b",
-    "evabyte")
+    "evabyte", "falcon-h1-34b-instruct")
 # Tq 1 and the packed programs at both ends of the chunk buckets.
 TICK_PROGRAMS = {"tq1": (1, False), "packed16": (16, True),
                  "packed256": (256, True)}

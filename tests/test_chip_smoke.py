@@ -196,6 +196,23 @@ def test_phase_eva_serves_three_boundaries_and_a_reused_slot():
         and d["summary_pool"][:2] == [2, 2 * 4]
 
 
+def test_phase_parallel_serves_two_mixers_a_layer_through_a_reused_slot():
+    """The parallel phase at ``tests/test_parallel_mixer.py``'s small preset
+    (every layer a state-space mixer AND rotary GQA 5 / 1 on one norm, no
+    multiplier at 1): blocks of 4, chunks of 16 over a scan blocked in 8,
+    float32 (a served token lies at the reference's best to 1e-4)."""
+    from tests.test_parallel_mixer import SMALL
+
+    d = smoke.phase_parallel(TINY, dict(SMALL), device="cpu", block=4,
+                             chunk=16, tol_gap=1e-4)
+    assert d["layers"] == ["parallel"] * 3 and d["ffn"] == ["dense"] * 3
+    assert d["ssm_state"] == [3, 2, 2, 32, 32]
+    assert d["ssm_state_dtype"] == "float32"
+    # K/V rows in all three layers: 2 x 1 KV head x 16 x 4 B a layer.
+    assert d["kv_token_bytes"] == 3 * 2 * 16 * 4
+    assert d["tokens_compared"] == 6 + 3 * 5 and d["gap_max"] <= 1e-4
+
+
 @pytest.mark.slow
 def test_phase_train_and_programs():
     assert smoke.phase_train(TINY)["losses"][-1] < 6.3

@@ -296,14 +296,14 @@ def test_the_pool_layout_packs_heads_side_by_side_and_back():
 # -- the kernel --------------------------------------------------------------
 
 
-def _kernel_case(live, Hp, G, slots, seed):
+def _kernel_case(live, Hp, G, slots, seed, N=16):
     """One call of the kernel on layer 1 of 3 in interpret mode, at ``slots``
     slots a phase (``None``: the rule's), against ``ssm_step`` under the
     same ``jit`` (XLA's CPU backend fuses ``a * S + b * x`` there as it does
     in the interpreted kernel): ``(pool, new pool, y, wanted states, wanted
     y)``."""
     rng = np.random.default_rng(seed)
-    S, layers, N, L, m = len(live), 3, 16, 128, 1
+    S, layers, L, m = len(live), 3, 128, 1
     state = jnp.asarray(rng.normal(size=(layers * S, Hp, N, L)), jnp.float32)
     x, a = (jnp.asarray(rng.normal(size=(S, Hp, L)), jnp.float32)
             for _ in range(2))
@@ -374,6 +374,34 @@ def test_the_decode_kernel_in_phases_writes_ssm_steps_bits(live, Hp, G, slots):
     np.testing.assert_array_equal(mine[~on], state[m * S:(m + 1) * S][~on])
     np.testing.assert_array_equal(new[:m * S], state[:m * S])
     np.testing.assert_array_equal(new[(m + 1) * S:], state[(m + 1) * S:])
+
+
+@pytest.mark.parametrize("live", [(1, 1, 1, 1, 1), (1, 0, 1, 1, 0),
+                                  (0, 0, 0, 1, 0), (0, 0, 0, 0, 0)],
+                         ids=lambda v: "".join(map(str, v)))
+def test_the_decode_kernel_at_a_head_a_row_in_two_groups(live):
+    """The kernel's second served shape at a small copy (the two-branch
+    configuration's: ``pack`` 1, a head of 128 fills a row; G 2, so ``B`` and
+    ``C`` are a ``(2, N)`` tile turned to columns; ``N`` = 2 x ``L``; two
+    rows a group): the live slots' states bit-equal to ``ssm_step``'s, a
+    slot off the list holds the bits it held, at the rule's phase size and
+    at two slots a phase."""
+    from tree_attention_tpu.ops import tuning
+
+    S, m = len(live), 1
+    on = np.asarray(live, bool)
+    assert tuning.ssm_phase_slots(32, 256, 128, 2) == 4 \
+        == tuning.ssm_phase_slots(64, 128, 128, 8)
+    for slots in (None, 2):
+        state, new, y, want, wy = _kernel_case(live, Hp=4, G=2, slots=slots,
+                                               seed=7, N=256)
+        mine = new[m * S:(m + 1) * S]
+        np.testing.assert_array_equal(mine[on], want[on])
+        np.testing.assert_allclose(y[on], wy[on], atol=1e-5, rtol=0)
+        np.testing.assert_array_equal(mine[~on],
+                                      state[m * S:(m + 1) * S][~on])
+        np.testing.assert_array_equal(new[:m * S], state[:m * S])
+        np.testing.assert_array_equal(new[(m + 1) * S:], state[(m + 1) * S:])
 
 
 def test_the_phase_rule_follows_the_shapes_and_fits_its_limit():

@@ -59,6 +59,7 @@ from tree_attention_tpu.models.transformer import (
     TransformerConfig,
     _unheads,
     _mlp_block,
+    embed,
     gqa_qkv,
     rms_norm,
     unembed,
@@ -311,10 +312,12 @@ class PagedWindowCache:
 @dataclasses.dataclass
 class PagedStateCache:
     """The cache of a model whose mixers are state-space layers beside a
-    few attention layers: the attention layers' K/V rows in paged pools
-    under the block table, as :class:`PagedKVCache` holds them, AND a
-    recurrent state a slot for every state-space layer, which no table
-    indexes: ``ssm_state`` ``(ssm layers, slots) + StateSpace.state_shape``
+    few attention layers, or both side by side in every layer (a
+    ``"parallel"`` layer is an attention layer AND a state-space layer:
+    the two depths are then the model's): the attention layers' K/V rows
+    in paged pools under the block table, as :class:`PagedKVCache` holds
+    them, AND a recurrent state a slot for every state-space layer, which
+    no table indexes: ``ssm_state`` ``(ssm layers, slots) + StateSpace.state_shape``
     float32 and ``ssm_tail`` ``(ssm layers, slots, (taps - 1) x
     conv_dim)``, the last pre-activation rows of the mixer's short
     convolution, a slot's rows side by side on the lanes (as ``(slots, taps
@@ -1623,16 +1626,16 @@ class _Attend:
         return out, k_cache, v_cache, k_s, v_s
 
 
-def gqa_mixer(attend: _Attend, layer: Params, x: jax.Array,
-              positions: jax.Array, k_cache, v_cache, k_s, v_s, views, l,
-              base):
-    """A rotary-GQA mixer over every group of the step's rows: norm,
-    projections (:func:`~.transformer.gqa_qkv`), each group's rows into the
-    cache and against it (:class:`_Attend`), the output projection. Returns
-    the residual with the mixer's output added and the cache arrays."""
+def gqa_branch(attend: _Attend, layer: Params, h: jax.Array,
+               positions: jax.Array, k_cache, v_cache, k_s, v_s, views, l,
+               base):
+    """A rotary-GQA mixer's branch over every group of the step's rows,
+    from rows already normed: projections (:func:`~.transformer.gqa_qkv`),
+    each group's rows into the cache and against it (:class:`_Attend`), the
+    output projection. Returns what the mixer adds to the residual and the
+    cache arrays."""
     cfg, groups = attend.cfg, attend.groups
     with jax.named_scope(scopes.ATTN_IN):
-        h = rms_norm(x, layer["ln1"], cfg.norm_eps)
         q, k_new, v_new = gqa_qkv(
             layer, h, positions, cfg, rotary=attend.rotary)
         if cfg.kv_pack > 1:
@@ -1648,8 +1651,24 @@ def gqa_mixer(attend: _Attend, layer: Params, x: jax.Array,
         if cfg.kv_pack > 1:
             out = _unpack_heads(out, cfg)
     with jax.named_scope(scopes.ATTN_OUT):
-        x = x + _unheads(out) @ layer["wo"]
-    return x, k_cache, v_cache, k_s, v_s
+        y = _unheads(out) @ layer["wo"]
+    return y, k_cache, v_cache, k_s, v_s
+
+
+def gqa_mixer(attend: _Attend, layer: Params, x: jax.Array,
+              positions: jax.Array, k_cache, v_cache, k_s, v_s, views, l,
+              base):
+    """A rotary-GQA mixer as a layer of its own: the norm, the branch
+    (:func:`gqa_branch`), the add. Returns the residual with the mixer's
+    output added and the cache arrays."""
+    with jax.named_scope(scopes.ATTN_IN):
+        h = rms_norm(x, layer["ln1"], attend.cfg.norm_eps)
+    y, *pools = gqa_branch(
+        attend, layer, h, positions, k_cache, v_cache, k_s, v_s, views, l,
+        base)
+    with jax.named_scope(scopes.ATTN_OUT):
+        x = x + y
+    return (x, *pools)
 
 
 def _head_lanes(cfg: TransformerConfig) -> jax.Array:
@@ -2257,7 +2276,7 @@ def forward_step(
         )
 
     with jax.named_scope(scopes.EMBED):
-        x = jnp.take(params["embed"], tokens, axis=0)
+        x = embed(params, tokens, cfg.mup)
     _count_step(cache)
     group = _RowGroup(
         lo=None, batch=B, tq=Tq, start=start, n=n_tokens,
@@ -2271,7 +2290,8 @@ def forward_step(
         stats=stats,
     )
     with jax.named_scope(scopes.HEAD):
-        logits = unembed(params, rms_norm(x, params["ln_f"], cfg.norm_eps))
+        logits = unembed(
+            params, rms_norm(x, params["ln_f"], cfg.norm_eps), cfg.mup)
     grew = Tq if n_tokens is None else n_tokens
     return logits, dataclasses.replace(cache, length=start + grew, **pools)
 
@@ -2362,7 +2382,7 @@ def forward_packed_step(
     with jax.named_scope(scopes.EMBED):
         rows = jnp.concatenate(
             [chunk_tokens.reshape(-1), tokens.reshape(-1)])
-        x = jnp.take(params["embed"], rows[None], axis=0)  # (1, C·Tq + S, D)
+        x = embed(params, rows[None], cfg.mup)          # (1, C·Tq + S, D)
     _count_step(cache)
     x, pools = _step_layers(
         params, x, positions, groups, cache, cfg, mesh=mesh, axes=axes,
@@ -2380,7 +2400,8 @@ def forward_packed_step(
             mode="drop",
         )
         logits = unembed(
-            params, rms_norm(x[0, src], params["ln_f"], cfg.norm_eps))
+            params, rms_norm(x[0, src], params["ln_f"], cfg.norm_eps),
+            cfg.mup)
     new_len = (length + n_tokens).at[chunk_slot].add(chunk_n)
     return logits, dataclasses.replace(cache, length=new_len, **pools)
 

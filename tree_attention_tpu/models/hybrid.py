@@ -1,24 +1,48 @@
 """Layers of several kinds in one model: the gated short-convolution mixer,
 its state as a two-row tail of each pool block, the state-space mixer, its
-state an array a slot, the EVA mixer, its cache two kinds of row, and the
-layer loop over runs of layers of one kind.
+state an array a slot, the EVA mixer, its cache two kinds of row, the layer
+whose mixer is TWO mixers side by side, and the layer loop over runs of
+layers of one kind.
 
-A layer is a mixer and at most one feed-forward half. The mixer is
-rotary-GQA attention
+A layer is a mixer, or two mixers side by side on one norm, and at most one
+feed-forward half. The mixer is rotary-GQA attention
 (:func:`~.decode.gqa_mixer`, the dense block's own: over the whole context,
 or, a ``window`` layer, over the last ``cfg.window`` positions, its rows in a
 pool and under a table of their own, :class:`~.decode.PagedWindowCache`), a
 gated short convolution (:func:`conv_mixer`), a state-space mixer
-(:func:`ssm_mixer`) or EVA attention (:func:`eva_mixer`); the feed-forward half the dense SwiGLU, the
-routed-expert layer (:func:`~.experts.expert_layer`) or none. ``cfg.layer_types``
+(:func:`ssm_mixer`), EVA attention (:func:`eva_mixer`) or, a ``parallel``
+layer, the state-space mixer AND rotary GQA at once (below); the
+feed-forward half the dense SwiGLU, the routed-expert layer
+(:func:`~.experts.expert_layer`) or none. ``cfg.layer_types``
 and ``cfg.ffn_kinds`` say which layer is what; :func:`hybrid_layers` cuts the
-depth into runs of consecutive layers of one (mixer, feed-forward) kind and
-scans each run under one body, with no branch on a layer's kind in the traced
-program. Every per-kind stack rides whole (the K/V pools, the tail pool, the
-experts' one stack, each kind's weights on a leading axis of ITS layers) and a
-layer's part is reached by offset: attention layer ``a`` at ``table + a·N``,
-window layer ``w`` at ``wtable + w·Nw``, conv layer ``c`` at ``table + c·N``,
-state-space layer ``m`` at ``m·S + slot``, expert layer ``e`` at ``e·held``.
+depth into runs of consecutive layers of one (mixer, feed-forward) kind,
+``(ssm | attention | window | conv | eva | parallel) x (dense | expert |
+none)``, and scans each run under one body, with no branch on a layer's kind
+in the traced program. Every per-kind stack rides whole (the K/V pools, the
+tail pool, the experts' one stack, each kind's weights on a leading axis of
+ITS layers) and a layer's part is reached by offset: attention layer ``a`` at
+``table + a·N``, window layer ``w`` at ``wtable + w·Nw``, conv layer ``c`` at
+``table + c·N``, state-space layer ``m`` at ``m·S + slot``, expert layer
+``e`` at ``e·held``; a parallel layer is attention layer ``a`` and
+state-space layer ``m`` at once.
+
+A serial mixer is a thin wrapper, ``x + branch(norm(x))``, around its
+**branch** (:func:`ssm_branch`, :func:`~.decode.gqa_branch`: normed rows in,
+what the mixer adds and its cache arrays out). The two-branch layer calls the
+two branches itself, for the ONE normed residual ``h = norm(x)`` (``mup``:
+``cfg.mup``, the family's fixed multipliers, :class:`~.transformer.Multipliers`;
+``mu``: ``ssm_multipliers`` a segment of ``[z | x | B | C | dt]``)::
+
+    x <- x + s_out · SSM(s_in · h) + a_out · Attn(a_in · h)
+    SSM(u):   [z | xBC | dt] = (u · W_in) ⊙ mu, then as the state-space mixer
+    Attn(u):  q, k, v = u·W_q, kappa · (u·W_k), u·W_v, then as rotary GQA
+              (the cached row holds the scaled key)
+    MLP(h2) = g2 · (silu(g1 · (h2·W_1)) ⊙ (h2·W_3)) · W_2
+    x_0 = e · E[token],   logits = lam · (norm(x) · W_head)
+
+A multiplier of 1.0 emits no multiply, so a model without them compiles to
+what it compiled to before they existed; they are never folded into a stored
+weight.
 
 The conv mixer, for the normed residual ``h``::
 
@@ -80,6 +104,7 @@ from tree_attention_tpu.models.decode import (
     _pool_write,
     chunks_closed,
     decode_attention,
+    gqa_branch,
     gqa_mixer,
     window_rules,
 )
@@ -91,6 +116,7 @@ from tree_attention_tpu.models.transformer import (
     _unheads,
     gqa_qkv,
     rms_norm,
+    times,
 )
 from tree_attention_tpu.ops.reference import merge_partials
 from tree_attention_tpu.obs import scopes
@@ -267,13 +293,26 @@ def ssm_step(state: jax.Array, x: jax.Array, a: jax.Array, b: jax.Array,
     return new, jnp.sum(new * c, axis=2)
 
 
-def ssm_mixer(layer: Params, x: jax.Array, state: jax.Array,
-              tail: jax.Array, m, groups: Tuple[_RowGroup, ...],
-              cfg: TransformerConfig
-              ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """The state-space mixer over every group of the step's rows.
-    ``layer``: this layer's leaves (``ln1`` ``(D,)``, ``w_in`` ``(D,
-    in_dim)``, ``conv_w`` ``(taps, conv_dim)`` with tap ``k`` on
+def _in_multipliers(cfg: TransformerConfig) -> Optional[jax.Array]:
+    """``Multipliers.ssm_multipliers`` as the vector ``W_in``'s output is
+    multiplied by, a scalar a segment of ``[z | x | B | C | dt]``; None
+    where all five are 1.0 (no multiply)."""
+    sm, m = cfg.ssm, cfg.mup.ssm_multipliers
+    if all(v == 1.0 for v in m):
+        return None
+    gn = sm.n_groups * sm.d_state
+    return jnp.concatenate([
+        jnp.full((n,), v, jnp.float32)
+        for n, v in zip((sm.inner, sm.inner, gn, gn, sm.n_heads), m)])
+
+
+def ssm_branch(layer: Params, h: jax.Array, state: jax.Array,
+               tail: jax.Array, m, groups: Tuple[_RowGroup, ...],
+               cfg: TransformerConfig
+               ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """The state-space mixer's branch over every group of the step's rows,
+    from rows ``h`` already normed. ``layer``: this layer's leaves
+    (``w_in`` ``(D, in_dim)``, ``conv_w`` ``(taps, conv_dim)`` with tap ``k`` on
     ``xBC_{t-taps+1+k}``, ``conv_b``, ``dt_bias`` / ``A_log`` / ``D``
     ``(heads,)`` float32, ``norm`` ``(inner,)``, ``w_out`` ``(inner, D)``);
     ``state`` / ``tail`` the WHOLE pools of a :class:`PagedStateCache`, slot
@@ -288,9 +327,11 @@ def ssm_mixer(layer: Params, x: jax.Array, state: jax.Array,
     a member's valid count has ``Δ = 0`` and no input, so it leaves the
     state bit for bit; a member with no row writes nothing (and a decode
     slot with no row is not read either). The state, ``Δ``, ``a`` and ``S .
-    C`` in float32, the projections in the served type. Returns the
-    residual with the mixer's output added, the two pools, and how many
-    states were written."""
+    C`` in float32, the projections in the served type. ``W_in``'s output
+    is multiplied by ``cfg.mup.ssm_multipliers`` a segment, BEFORE the
+    convolution's bias and ``dt_bias`` (:func:`_in_multipliers`). Returns
+    what the mixer adds to the residual, the two pools, and how many states
+    were written."""
     from tree_attention_tpu.ops.pallas_ssm import ssm_decode_update
 
     sm = cfg.ssm
@@ -298,8 +339,10 @@ def ssm_mixer(layer: Params, x: jax.Array, state: jax.Array,
     inner, cd, back = sm.inner, sm.conv_dim, sm.taps - 1
     S = state.shape[1]
     with jax.named_scope(scopes.ATTN_IN):
-        h = rms_norm(x, layer["ln1"], cfg.norm_eps)
         zxd = h @ layer["w_in"]
+        mu = _in_multipliers(cfg)
+        if mu is not None:
+            zxd = (zxd.astype(jnp.float32) * mu).astype(zxd.dtype)
     with jax.named_scope(scopes.CONV):
         z, xbc, dt = jnp.split(zxd, [inner, inner + cd], axis=-1)
         A = -jnp.exp(layer["A_log"].astype(jnp.float32))
@@ -380,12 +423,28 @@ def ssm_mixer(layer: Params, x: jax.Array, state: jax.Array,
                 yg = yg * lax.rsqrt(jnp.mean(yg * yg, axis=-1, keepdims=True)
                                     + cfg.norm_eps)
                 y = yg.reshape(y.shape) * layer["norm"]
-                outs.append(y.astype(x.dtype)[:, None])
+                outs.append(y.astype(h.dtype)[:, None])
         y = _join_rows(groups, outs)[:, 0]
     with jax.named_scope(scopes.ATTN_OUT):
-        x = x + y @ layer["w_out"]
-    return (x, flat_s.reshape(state.shape), flat_t.reshape(tail.shape),
+        y = y @ layer["w_out"]
+    return (y, flat_s.reshape(state.shape), flat_t.reshape(tail.shape),
             wrote)
+
+
+def ssm_mixer(layer: Params, x: jax.Array, state: jax.Array,
+              tail: jax.Array, m, groups: Tuple[_RowGroup, ...],
+              cfg: TransformerConfig
+              ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """The state-space mixer as a layer of its own: the norm (``ln1``), the
+    branch (:func:`ssm_branch`), the add. Returns the residual with the
+    mixer's output added, the two pools, and how many states were
+    written."""
+    with jax.named_scope(scopes.ATTN_IN):
+        h = rms_norm(x, layer["ln1"], cfg.norm_eps)
+    y, state, tail, wrote = ssm_branch(layer, h, state, tail, m, groups, cfg)
+    with jax.named_scope(scopes.ATTN_OUT):
+        x = x + y
+    return x, state, tail, wrote
 
 
 # ---------------------------------------------------------------------------
@@ -519,11 +578,15 @@ def eva_mixer(attend: _Attend, layer: Params, x: jax.Array,
 def layer_runs(cfg: TransformerConfig) -> List[Tuple[str, str, int, int, int]]:
     """The depth cut into runs of consecutive layers of one kind:
     ``(mixer, ffn, layers, first of its mixer kind, first of its ffn
-    kind)``, the two offsets counted among the layers of that kind."""
+    kind)``, the two offsets counted among the layers of that kind. A
+    ``"parallel"`` layer is an attention layer and a state-space layer at
+    once; a model of them has no layer of another kind
+    (``TransformerConfig.__post_init__``), so its offset among either is
+    its offset among the parallel layers."""
     types = cfg.layer_types or ("attention",) * cfg.n_layers
     runs: List[list] = []
     seen = {"attention": 0, "window": 0, "conv": 0, "ssm": 0, "eva": 0,
-            "dense": 0, "expert": 0, "none": 0}
+            "parallel": 0, "dense": 0, "expert": 0, "none": 0}
     for mixer, ffn in zip(types, cfg.ffn_kinds):
         if runs and runs[-1][:2] == [mixer, ffn]:
             runs[-1][2] += 1
@@ -616,6 +679,30 @@ def hybrid_layers(
                 x, k, v, wk, wv, wrote = eva_mixer(
                     attend_w, of(params["eva"], mi), x, positions, k, v,
                     wk, wv, mi)
+            elif mixer == "parallel":
+                # Two mixers side by side on ONE normed residual (the
+                # module's docstring): the layer is attention layer ``mi``
+                # and state-space layer ``mi``; its one norm is ``ln1`` of
+                # its attention leaves. The two branches, and no third
+                # mixer; the sum in float32, rounded once.
+                mup, f32 = cfg.mup, jnp.float32
+                attn = of(params["attn"], mi)
+                with jax.named_scope(scopes.ATTN_IN):
+                    h = rms_norm(x, attn["ln1"], cfg.norm_eps)
+                    h_s = times(h, mup.ssm_in_multiplier)
+                    h_a = times(h, mup.attention_in_multiplier)
+                y_s, state, stail, wrote = ssm_branch(
+                    of(params["ssm"], mi), h_s, state, stail, mi, groups,
+                    cfg)
+                y_a, k, v, _, _ = gqa_branch(
+                    attend, attn, h_a, positions, k, v, None, None, None,
+                    mi, mi * N)
+                with jax.named_scope(scopes.ATTN_OUT):
+                    x = (x.astype(f32)
+                         + times(y_s.astype(f32), mup.ssm_out_multiplier)
+                         + times(y_a.astype(f32),
+                                 mup.attention_out_multiplier)
+                         ).astype(x.dtype)
             else:
                 with jax.named_scope(scopes.CONV):
                     x, tail, wrote = conv_mixer(
@@ -625,7 +712,8 @@ def hybrid_layers(
                 with jax.named_scope(scopes.FFN):
                     layer = of(params["dense"], fi)
                     x = x + _mlp_block(
-                        layer, rms_norm(x, layer["ln2"], cfg.norm_eps))
+                        layer, rms_norm(x, layer["ln2"], cfg.norm_eps),
+                        cfg.mup.mlp_multipliers)
             if ffn != "expert":
                 return (x, k, v, tail, wk, wv, state, stail), (None, wrote)
             with jax.named_scope(scopes.ROUTE):
@@ -736,8 +824,11 @@ def init_hybrid_params(key: jax.Array, cfg: TransformerConfig) -> Params:
             dt = jnp.exp(jax.random.uniform(
                 k[3], (sm.n_heads,), jnp.float32,
                 jnp.log(1e-3), jnp.log(1e-1)))
+            # (A parallel layer's one norm is its attention leaves' ln1.)
+            own_norm = {} if "parallel" in cfg.layer_types else {
+                "ln1": jnp.ones((D,), jnp.float32)}
             return {
-                "ln1": jnp.ones((D,), jnp.float32),
+                **own_norm,
                 "w_in": normal(k[0], (D, sm.in_dim), 0.02),
                 "conv_w": normal(k[1], (sm.taps, sm.conv_dim),
                                  sm.taps ** -0.5),

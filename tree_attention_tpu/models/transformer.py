@@ -208,7 +208,8 @@ class StateSpace:
         """Heads the state pool lays side by side on a row's 128 lanes
         (``d_head x pack`` lanes, as ``TransformerConfig.kv_pack`` does for
         KV heads): as many as divide both the lanes and a group, so that a
-        row's heads share their ``B`` and ``C``. No option."""
+        row's heads share their ``B`` and ``C`` (two heads of 64; a head of
+        128 fills a row by itself, ``pack`` 1). No option."""
         if 128 % self.d_head:
             return 1
         return math.gcd(128 // self.d_head, self.n_heads // self.n_groups)
@@ -222,6 +223,55 @@ class StateSpace:
                 self.pack * self.d_head)
 
 
+@dataclasses.dataclass(frozen=True)
+class Multipliers:
+    """Fixed scalars a family multiplies its activations by, under the names
+    the ``falcon_h1`` family publishes them (``models/hybrid.py`` says where
+    each lands): on the embedded rows and on the logits; on the two
+    branches' inputs (the one normed residual) and outputs; on the keys; on
+    ``W_in``'s output, a scalar a segment of ``[z | x | B | C | dt]``
+    (``ssm_multipliers``); on the MLP's gate inside its activation and on
+    its output (``mlp_multipliers``). Data, applied to activations and never
+    folded into a stored weight; every default is 1.0, and a 1.0 emits no
+    multiply (:func:`times`)."""
+
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_multipliers: Tuple[float, ...] = (1.0,) * 5
+    mlp_multipliers: Tuple[float, ...] = (1.0,) * 2
+
+    def __post_init__(self):
+        # (A published file holds whole numbers and lists.)
+        for f in dataclasses.fields(self):
+            value = getattr(self, f.name)
+            if isinstance(f.default, tuple):
+                value = tuple(float(v) for v in value)
+                if len(value) != len(f.default):
+                    raise ValueError(
+                        f"{f.name} {value}: {len(f.default)} scalars")
+            else:
+                value = float(value)
+            object.__setattr__(self, f.name, value)
+
+    @property
+    def unit(self) -> bool:
+        return self == Multipliers()
+
+
+def times(x: jax.Array, m: float) -> jax.Array:
+    """``x`` times the fixed scalar ``m``: the product in float32, rounded
+    once to ``x``'s type; ``x`` itself, and no operation, where ``m`` is
+    1.0."""
+    if m == 1.0:
+        return x
+    return (x.astype(jnp.float32) * m).astype(x.dtype)
+
+
 # A layer's mixer kind, by the names published configurations give it in
 # ``layer_types``: the one table :func:`model_from_config` reads kinds from
 # and lists in its refusals.
@@ -232,7 +282,9 @@ PUBLISHED_MIXERS = {
 # ``"ssm"`` has no name in ``layer_types``: its family says what a layer is
 # in ``hybrid_override_pattern``, a character a part (:func:`_pattern_layers`).
 # Nor has ``"eva"``: its family says every layer's in ``attention_class``.
-MIXER_KINDS = frozenset(PUBLISHED_MIXERS.values()) | {"ssm", "eva"}
+# Nor ``"parallel"``: ``model_type`` ``falcon_h1`` says every layer's.
+MIXER_KINDS = frozenset(PUBLISHED_MIXERS.values()) | {
+    "ssm", "eva", "parallel"}
 # The published ``attention_class`` values built, by the mixer kind they give
 # every layer.
 ATTENTION_CLASSES = {"eva": "eva"}
@@ -255,8 +307,13 @@ class TransformerConfig:
     inside the row's own ALIGNED window, ``[(t // window) * window, t]``
     (``window_rule`` ``"aligned"``), and sees every window closed before it
     as one learned summary row a ``chunk`` of positions, both under one
-    softmax), None meaning rotary GQA
-    throughout. ``rotary`` names the attention kinds whose queries and
+    softmax; ``"parallel"``: TWO mixers side by side, the state-space
+    mixer and rotary GQA over the whole context on ONE normed residual,
+    their outputs added to it together, each under its own fixed scale:
+    every layer of such a model, ``models/hybrid.py``), None meaning
+    rotary GQA throughout. ``mup``: the fixed scalars a family multiplies
+    its activations by (:class:`Multipliers`; built with ``"parallel"``
+    layers). ``rotary`` names the attention kinds whose queries and
     keys take the rotary embedding (both by default; a model whose full
     layers carry no positional term names ``("window",)``, one with no
     positional term at all ``()``). The
@@ -279,7 +336,9 @@ class TransformerConfig:
     a slot, which no table indexes; an EVA layer's (``eva_layers``) exact
     rows live in the window pool as a window layer's do and its summary
     rows, one a ``chunk`` positions and kept for the request's life, in the
-    pool under the first table.
+    pool under the first table. A ``"parallel"`` layer is an attention
+    layer AND a state-space layer: it counts in ``cache_layers`` and in
+    ``ssm_layers`` (``cache_kind`` ``"state"``, the two depths equal).
 
     ``pred_heads``: the head scores that many next positions a row
     (``wout`` ``(D, pred_heads x vocab)``); the served logits are the
@@ -322,8 +381,11 @@ class TransformerConfig:
     chunk: int = 0
     pred_heads: int = 1
     norm_offset: bool = False
+    mup: Multipliers = Multipliers()
 
     def __post_init__(self):
+        if isinstance(self.mup, dict):      # read back from JSON
+            object.__setattr__(self, "mup", Multipliers(**self.mup))
         if self.n_heads % self.n_kv_heads:
             raise ValueError(
                 f"n_heads ({self.n_heads}) must be a multiple of "
@@ -373,14 +435,26 @@ class TransformerConfig:
                     f"a short convolution of {self.conv_taps} taps: its "
                     f"state is built as the last 2 gated inputs, a two-row "
                     f"tail of each pool block (3 taps)")
-            if ("ssm" in kinds) != (self.ssm is not None) or (
-                    "ssm" in kinds and kinds & {"conv", "window"}):
+            has_state = bool(kinds & {"ssm", "parallel"})
+            if has_state != (self.ssm is not None) or (
+                    has_state and kinds & {"conv", "window"}):
                 raise ValueError(
-                    f"state-space layers {'ssm' in kinds} with ssm="
+                    f"state-space layers {has_state} with ssm="
                     f"{self.ssm}: a model with state-space layers states "
                     f"their widths, a model without states none, and a "
                     f"recurrent state beside conv or sliding-window layers "
                     f"is not built")
+            if "parallel" in kinds and (
+                    kinds != {"parallel"} or self.moe is not None
+                    or self.qk_norm or not self.rotates("attention")):
+                raise ValueError(
+                    f"a 'parallel' layer (a state-space mixer and rotary "
+                    f"GQA on one normed residual) beside layers of kinds "
+                    f"{sorted(kinds - {'parallel'})} (experts: "
+                    f"{self.moe is not None}, qk_norm: {self.qk_norm}, "
+                    f"rotary: {self.rotary}): it is built in every layer of "
+                    f"a model, its attention rotated and without QK-norm, "
+                    f"over the dense feed-forward half or none")
         elif self.ssm is not None:
             raise ValueError(
                 "state-space widths without layer_types: which layers are "
@@ -390,6 +464,11 @@ class TransformerConfig:
                 f"window={self.window} (rule {self.window_rule!r}, chunk "
                 f"{self.chunk}) without layer_types: which layers are "
                 f"sliding-window or EVA layers is said a layer")
+        if not self.mup.unit and "parallel" not in (self.layer_types or ()):
+            raise ValueError(
+                f"mup {self.mup}: fixed activation multipliers are built "
+                f"with 'parallel' layers (layer_types), where every one of "
+                f"them has its place; any other model's are all 1.0")
         if self.window_rule not in WINDOW_RULES or self.pred_heads < 1:
             raise ValueError(
                 f"window_rule {self.window_rule!r} (one of {WINDOW_RULES}) "
@@ -449,9 +528,10 @@ class TransformerConfig:
 
     @property
     def ssm_layers(self) -> int:
-        """Layers whose mixer is the state-space one: the state pool's
-        depth."""
-        return (self.layer_types or ()).count("ssm")
+        """Layers that hold a recurrent state, the state-space mixer alone
+        or beside attention: the state pool's depth."""
+        types = self.layer_types or ()
+        return types.count("ssm") + types.count("parallel")
 
     @property
     def eva_layers(self) -> int:
@@ -498,7 +578,9 @@ class TransformerConfig:
         layers' rows a token, the sliding-window layers' a bounded number
         of blocks a slot) or ``"state"`` (K/V rows for the attention layers
         beside a recurrent state and a conv tail a slot for the
-        state-space layers, which every token rewrites whole) or ``"eva"``
+        state-space layers, which every token rewrites whole; in a model
+        of ``"parallel"`` layers the attention layers and the state-space
+        layers are the same layers) or ``"eva"``
         (two K/V pools of every layer under two tables: exact rows of the
         open window, a bounded number of blocks a slot, and one summary row
         a chunk of every closed window, kept)."""
@@ -531,7 +613,7 @@ class TransformerConfig:
         sees its whole context (a window layer's rows: ``window_layers``
         deep, in the window pool)."""
         return (self.n_layers - self.conv_layers - self.window_layers
-                - self.ssm_layers) * self.sublayers
+                - (self.layer_types or ()).count("ssm")) * self.sublayers
 
     @property
     def n_dense_layers(self) -> int:
@@ -620,6 +702,50 @@ def _state_space_from_config(c: Dict[str, Any], block: Dict[str, Any],
     return ssm
 
 
+# What the ``falcon_h1`` family's modelling code says and no published key
+# does, each rule with the one value built (the file's ``block`` group).
+_FALCON_H1_BLOCK = {
+    "mixer_arrangement": "parallel_shared_norm",
+    "mup_segments": ["z", "x", "B", "C", "dt"],
+    "rotary_convention": "half_split",
+}
+
+
+def _falcon_h1_from_config(c: Dict[str, Any], block: Dict[str, Any]
+                           ) -> Tuple[StateSpace, Multipliers]:
+    """The ``falcon_h1`` family's state-space widths and fixed multipliers,
+    every key that says what is not built refused by name."""
+    for key, built in (
+            ("mamba_rms_norm", True), ("mamba_norm_before_gate", False),
+            ("attn_layer_indices", None), ("mamba_use_mlp", True),
+            ("mamba_conv_bias", True), ("mamba_proj_bias", False),
+            ("projectors_bias", False), ("attention_bias", False),
+            ("mlp_bias", False), ("hidden_act", "silu"),
+            ("rope_scaling", None)):
+        if c.get(key, built) != built:
+            raise ValueError(
+                f"{key} {c[key]!r}: the falcon_h1 layer is built with "
+                f"{key} {built!r}")
+    for key, built in _FALCON_H1_BLOCK.items():
+        if block.get(key, built) != built:
+            raise ValueError(
+                f"block.{key} {block[key]!r}: only {built!r} is built for "
+                f"model_type 'falcon_h1'")
+    ssm = StateSpace(
+        n_heads=int(c["mamba_n_heads"]), d_head=int(c["mamba_d_head"]),
+        n_groups=int(c["mamba_n_groups"]), d_state=int(c["mamba_d_state"]),
+        taps=int(c["mamba_d_conv"]),
+        chunk=int(c.get("mamba_chunk_size", 128)))
+    if int(c["mamba_d_ssm"]) != ssm.inner:
+        raise ValueError(
+            f"mamba_d_ssm {c['mamba_d_ssm']} is not mamba_n_heads x "
+            f"mamba_d_head ({ssm.inner})")
+    mup = Multipliers(**{
+        f.name: c[f.name] for f in dataclasses.fields(Multipliers)
+        if f.name in c})
+    return ssm, mup
+
+
 def _gated(c: Dict[str, Any]) -> bool:
     """Whether the feed-forward parts have a gate matrix, by
     ``mlp_hidden_act``: ``relu2`` is one matrix in, relu squared, one out;
@@ -675,6 +801,16 @@ def model_from_config(config: Dict[str, Any], *, dtype: Any = None,
       ``fp32_skip_add`` / ``fp32_logits``, which must be true where given:
       the residual's add rounds once from the exact sum and the logits are
       float32 in every program here.
+      Or ``model_type`` ``falcon_h1`` gives every layer the two-branch
+      mixer (``"parallel"``: a state-space mixer of ``mamba_n_heads`` x
+      ``mamba_d_head`` = ``mamba_d_ssm``, ``mamba_n_groups``,
+      ``mamba_d_state``, ``mamba_d_conv`` taps, ``mamba_chunk_size``,
+      beside rotary GQA on one normed residual) over the dense SwiGLU, and
+      the family's fixed multipliers (:class:`Multipliers`, by their
+      published names); ``mamba_rms_norm`` true, ``mamba_norm_before_gate``
+      false, ``mamba_use_mlp`` true, ``mamba_conv_bias`` true,
+      ``attn_layer_indices`` / ``rope_scaling`` null, every projection bias
+      false, ``hidden_act`` ``silu``: any other refused by name.
     - each layer's feed-forward half: ``n_routed_experts`` /
       ``num_experts`` select the expert layer after the first
       ``first_k_dense_replace`` / ``num_dense_layers`` layers (an expert's
@@ -720,7 +856,13 @@ def model_from_config(config: Dict[str, Any], *, dtype: Any = None,
     state-space mixer's ``silu(z)`` gates before its grouped norm; the
     latent projections have no norm and no bias: true, the only rule built,
     any other refused by name), ``scale_renormed`` (``routed_scaling_factor``
-    multiplies the renormed weights too: renorm, then scale) and
+    multiplies the renormed weights too: renorm, then scale),
+    ``mixer_arrangement`` / ``mup_segments`` / ``rotary_convention`` (the
+    ``falcon_h1`` family's: both branches read the one ``input_layernorm``,
+    ``"parallel_shared_norm"``; ``ssm_multipliers``' five scalars lie over
+    ``W_in``'s columns in the order ``["z", "x", "B", "C", "dt"]``; the
+    rotary pairs dimension ``i`` with ``i + d / 2``, ``"half_split"``: the
+    one value built each, any other refused by name) and
     ``norm_placement`` (``"pre"``, the only one built: any other is
     refused by name)."""
     c = config
@@ -869,6 +1011,16 @@ def model_from_config(config: Dict[str, Any], *, dtype: Any = None,
                     f"sliding_windows {sorted(set(own))}: every "
                     f"sliding_attention layer at sliding_window "
                     f"({window}) and every other at 0 is what is built")
+    mup = Multipliers()
+    if c.get("model_type") == "falcon_h1":
+        if layer_types is not None or n_held:
+            raise ValueError(
+                "model_type 'falcon_h1' beside layer_types, "
+                "hybrid_override_pattern or experts: every layer of the "
+                "family is the two-branch mixer over a dense feed-forward "
+                "half")
+        ssm, mup = _falcon_h1_from_config(c, block)
+        layer_types = ("parallel",) * int(c["num_hidden_layers"])
     window_rule, chunk = "sliding", 0
     if c.get("attention_class") is not None:
         said = str(c["attention_class"])
@@ -942,6 +1094,7 @@ def model_from_config(config: Dict[str, Any], *, dtype: Any = None,
         window_rule=window_rule, chunk=chunk,
         pred_heads=int(c.get("num_pred_heads", 1)),
         norm_offset=bool(c.get("norm_add_unit_offset", False)),
+        mup=mup,
     )
     kw.update(overrides)
     return TransformerConfig(**kw)
@@ -1093,18 +1246,29 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return rotated.astype(x.dtype)
 
 
-def unembed(params: Params, x: jax.Array) -> jax.Array:
+def embed(params: Params, tokens: jax.Array,
+          mup: Multipliers = Multipliers()) -> jax.Array:
+    """The embedded rows of ``tokens``, times ``embedding_multiplier``."""
+    return times(jnp.take(params["embed"], tokens, axis=0),
+                 mup.embedding_multiplier)
+
+
+def unembed(params: Params, x: jax.Array,
+            mup: Multipliers = Multipliers()) -> jax.Array:
     """The head: ``x`` times ``wout``, or, where the parameters hold none
     (``TransformerConfig.tied_head``), times the embedding's transpose.
-    Float32 logits. A head of several next-position blocks
+    Float32 logits, times ``lm_head_multiplier``. A head of several next-position blocks
     (``TransformerConfig.pred_heads``: ``wout`` wider than the vocabulary)
     serves its first block, the next position's."""
     if "wout" in params:
         wout, vocab = params["wout"], params["embed"].shape[0]
         if wout.shape[-1] != vocab:
             wout = wout[:, :vocab]
-        return (x @ wout).astype(jnp.float32)
-    return jnp.einsum("...d,vd->...v", x, params["embed"]).astype(jnp.float32)
+        logits = (x @ wout).astype(jnp.float32)
+    else:
+        logits = jnp.einsum(
+            "...d,vd->...v", x, params["embed"]).astype(jnp.float32)
+    return times(logits, mup.lm_head_multiplier)
 
 
 # The attention input projections as a serving engine holds them
@@ -1164,13 +1328,14 @@ def gqa_qkv(p: Params, h: jax.Array, positions: jax.Array,
     D)``: queries ``(B, H, T, d)`` and new keys and values ``(B, Hkv, T,
     d)``, the rotary embedding applied (not where ``rotary`` is false: a
     layer kind with no positional term, ``TransformerConfig.rotates``);
-    where ``cfg.qk_norm``, an RMSNorm over each query and key head (one
+    the keys times ``cfg.mup.key_multiplier``; where ``cfg.qk_norm``, an RMSNorm over each query and key head (one
     learned gain of ``d`` a layer) before it."""
     if GQA_SERVED in p:      # the served form: one product, cut in three
         q, k, v = jnp.split(times_out_major(h, p[GQA_SERVED]),
                             [cfg.q_dim, cfg.q_dim + cfg.kv_dim], axis=-1)
     else:
         q, k, v = h @ p["wq"], h @ p["wk"], h @ p["wv"]
+    k = times(k, cfg.mup.key_multiplier)    # the cached row holds it scaled
     q = _heads(q, cfg.n_heads, cfg.d_head)
     k = _heads(k, cfg.n_kv_heads, cfg.d_head)
     v = _heads(v, cfg.n_kv_heads, cfg.d_head)
@@ -1230,8 +1395,12 @@ def _attention_block(
     return _unheads(out) @ p["wo"]
 
 
-def _mlp_block(p: Params, x: jax.Array) -> jax.Array:
-    return (jax.nn.silu(x @ p["w1"]) * (x @ p["w3"])) @ p["w2"]
+def _mlp_block(p: Params, x: jax.Array,
+               mult: Tuple[float, float] = (1.0, 1.0)) -> jax.Array:
+    """The SwiGLU; ``mult`` (``Multipliers.mlp_multipliers``): the gate
+    scaled inside its activation, and the output."""
+    gate = jax.nn.silu(times(x @ p["w1"], mult[0]))
+    return times((gate * (x @ p["w3"])) @ p["w2"], mult[1])
 
 
 def _resolved_layout(cfg, mesh, axes) -> str:

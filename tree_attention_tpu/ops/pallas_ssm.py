@@ -30,6 +30,13 @@ vector register, and ``y`` a sum over registers with one cross-sublane
 reduce a row of heads: no transposed operand and no cross-lane reduce a
 head.
 
+Two shapes are served, ``(Hp, N, L, G)``: ``(64, 128, 128, 8)`` (128 heads of
+64, two a row, ``pack`` 2; eight rows a group) and ``(32, 256, 128, 2)`` (32
+heads of 128, a head a row, ``pack`` 1; ``B`` and ``C`` a ``(2, 256)`` tile
+turned to columns, sixteen rows a group). A slot's state of a layer is the
+same 4,194,304 B at both, so the phase rule gives both four slots a phase, and
+both run at 85% of the memory's pace (PERF.md, PRs 45 and 47).
+
 Off the TPU the layer body takes the same step in ``jax.numpy``
 (``models/hybrid.py``); the tests hold this kernel to it in interpret mode.
 """

@@ -251,6 +251,13 @@ _BLOCK_FIXED_BYTES = obs.gauge(
     "bytes a pool block holds beside its tokens' rows, all layers (the conv "
     "layers' two-row tails of a hybrid pool; 0 for every other cache)",
 )
+_STATE_POOL_BYTES = obs.gauge(
+    "serving_state_pool_bytes",
+    "bytes of a state-space model's per-slot arrays, all layers, by pool "
+    "(ssm_state, ssm_tail; set once at build): what the state weighs beside "
+    "the K/V pool",
+    labels=("pool",),
+)
 _WEIGHTS_RELAID = obs.gauge(
     "serving_weights_relaid_bytes",
     "bytes of attention input projections the engine serves from in the "
@@ -1283,8 +1290,15 @@ class SlotServer:
         self._conv_layers = cfg.conv_layers   # the tail pool's depth
         self._ssm_layers = cfg.ssm_layers     # the state pool's depth
         self._eva_layers = cfg.eva_layers     # both EVA pools' depth
+        # A state pool's per-slot arrays in bytes, by field name; empty for
+        # every other cache: the report's ``kv.state_pool_bytes``.
+        self._state_pool_bytes = {
+            name: int(getattr(self.cache, name).nbytes)
+            for name in ("ssm_state", "ssm_tail") if self._ssm_layers}
         if obs.REGISTRY.enabled:
             _BLOCK_FIXED_BYTES.set(self._kv_block_fixed_bytes)
+            for name, size in self._state_pool_bytes.items():
+                _STATE_POOL_BYTES.labels(pool=name).set(size)
         # Expert layers' row counts on the tick's fetch: (layers, what
         # ``experts.held_counts`` gives a layer), None for a model without
         # experts.
@@ -5597,6 +5611,14 @@ class SlotServer:
                 "window_blocks_peak_slot": self._win.peak_slot,
                 "window_blocks_used": self._win.alloc.used,
                 "window_blocks_freed": self._win.freed,
+            })
+        if self._state_pool_bytes:
+            # The state beside the K/V rows: which of the two a tick
+            # streams more of depends on the contexts held.
+            kv_snap.update({
+                "pool_bytes": self.kv_blocks * self.kv_block
+                * self._kv_token_bytes,
+                "state_pool_bytes": dict(self._state_pool_bytes),
             })
         if self._forks_life - fork0[0]:
             # Copy-on-write fork accounting for THIS run (ISSUE 15).
