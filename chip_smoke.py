@@ -332,6 +332,7 @@ def phase_kernels(s: Sizes) -> Dict[str, Any]:
     import jax
     import jax.numpy as jnp
     import numpy as np
+    from jax import lax
 
     from tree_attention_tpu.models.decode import (
         _paged_pool_write,
@@ -484,6 +485,70 @@ def phase_kernels(s: Sizes) -> Dict[str, Any]:
     record("paged_row_write_tq1", got, want, first, later, 0.0)
     check(not np.array_equal(np.asarray(got[0]), np.asarray(k_pool)),
           "paged_row_write_tq1 wrote nothing")
+
+    # The conv layers' decode step (ISSUE 48): a row a slot through the
+    # tail pool in one launch (the read of z at p-1 and p-2, the gates, the
+    # taps, the write of z at p), bf16 at the hybrid's width (2048), conv
+    # layer 3 of 4. Slot 0 is idle, slot 1 at a block's first position (its
+    # z before lie in the block before), the last slot (of more than two)
+    # past its capacity. The POOL against the XLA path's, bit for bit. The
+    # ROWS bit for bit against the XLA path's arithmetic with every
+    # rounding the source states pinned (`lax.reduce_precision`): on the
+    # chip XLA itself keeps `z` and `s` in float32 between a fusion's
+    # operations (`xla_allow_excess_precision`: a bf16 value converted up
+    # again is never rounded), so its own rows lie within one rounding of
+    # these, which is checked beside; on the CPU it rounds as stated, and
+    # tier-1 holds the kernel to that path's bits.
+    from tree_attention_tpu.models.decode import _RowGroup
+    from tree_attention_tpu.models.hybrid import _tail_rows, _tail_step
+    from tree_attention_tpu.ops.pallas_conv import (
+        conv_tail_plan, conv_tail_step)
+
+    dc, bf16, f32 = min(s.model_dim, 2048), jnp.bfloat16, jnp.float32
+    Nc = -(-N // 8) * 8       # a layer's blocks: a multiple of the cut
+    tails = jnp.asarray(
+        rng.standard_normal((4 * Nc, 2 * dc), np.float32), bf16)
+    bcu = jnp.asarray(rng.standard_normal((B, 3 * dc), np.float32), bf16)
+    taps = jnp.asarray(rng.standard_normal((3, dc), np.float32), bf16)
+    start_c = start.at[1].set(2 * blk) if B > 1 else start
+
+    def tail_step():
+        plan = conv_tail_plan(table, start_c, n_new, Nc, blk)
+        pool, out = conv_tail_step(tails, bcu, taps, plan, 3 * Nc,
+                                   interpret=it)
+        return pool, jnp.where((plan.ids >= 0)[:, None], out, 0)
+
+    @jax.jit
+    def tail_xla():
+        """(the XLA path's pool, its rows, its rows with the roundings
+        pinned)."""
+        g = _RowGroup(lo=None, batch=B, tq=1, start=start_c, n=n_new,
+                      table=table, tree_mask=None)
+        writes = (_row_targets(table, start_c, n_new, Nc, blk)[0]
+                  >= 0)[:, None]
+        w32 = taps.astype(f32)
+        pool, out, _ = _tail_step(tails, bcu[:, None], w32, g, 3, Nc, blk)
+        out = out[:, 0]
+        before = [_tail_rows(tails, g, 3, back, Nc, blk).astype(f32)
+                  for back in (2, 1)]
+        pin = lambda a: lax.reduce_precision(a, 8, 7)   # a bf16's bits
+        zp = pin(bcu[:, :dc].astype(f32) * bcu[:, 2 * dc:].astype(f32))
+        sp = pin((w32[0] * before[0] + w32[1] * before[1]) + w32[2] * zp)
+        pinned = pin(bcu[:, dc:2 * dc].astype(f32) * sp).astype(bf16)
+        return (pool, jnp.where(writes, out, 0),
+                jnp.where(writes, pinned, 0))
+
+    got, first, later = _timed(jax.jit(tail_step))
+    want_pool, xla_rows, want_rows = tail_xla()
+    record("conv_tail_step_tq1", got, (want_pool, want_rows), first, later,
+           0.0)
+    check(not np.array_equal(np.asarray(got[0]), np.asarray(tails)),
+          "conv_tail_step_tq1 wrote nothing")
+    results["conv_tail_step_tq1"]["xla_rows_max_rel_err"] = _errors(
+        got[1], xla_rows)[1]
+    check(results["conv_tail_step_tq1"]["xla_rows_max_rel_err"] <= 2 ** -7,
+          "conv_tail_step_tq1's rows lie further from XLA's own than a "
+          "rounding")
 
     # Prefill forward: a chunk-wide Q tile against the gathered view, each
     # slot at its own offset (what a mixed tick at the chunk bucket runs).

@@ -181,6 +181,26 @@ def _ssm_update(slots=64, layers=5, hp=64, n=128, lanes=128, groups=8):
     return fn, args, (0,)
 
 
+def _conv_tail(slots=64, layers=9, blocks=2560, d=2048):
+    """(fn, abstract args, donated) of one ``conv_tail_step`` call at the
+    hybrid's widths: nine conv layers' tails of 2,560 blocks x (2 x 2048)
+    lanes as they lie (189 MB, donated), 64 slots' ``h . W_in``."""
+    from tree_attention_tpu.ops.pallas_conv import (
+        MATES, ConvTailPlan, conv_tail_step)
+
+    i32 = jnp.int32
+    plan = ConvTailPlan(
+        _s((slots,), i32), _s((slots,), i32), _s((slots,), i32),
+        _s((slots * MATES,), i32), _s((slots, 1), i32), _s((), i32))
+    args = [_s((layers * blocks, 2 * d)), _s((slots, 3 * d)), _s((3, d)),
+            plan, _s((), i32)]
+
+    def fn(*a):
+        return conv_tail_step(*a, interpret=False)
+
+    return fn, args, (0,)
+
+
 def _moe_ungated(m, latent=1024, width=2688, held=128, layers=5):
     """(fn, abstract args) of an ungated expert layer's two products over
     ``m`` pairs: one matrix in with relu squared, one out, in the latent."""
@@ -314,6 +334,8 @@ CASES = {
     "row_write_yi6b": (lambda: _row_write(**YI), ROW_WRITE),
     "row_write_int8_yi6b": (lambda: _row_write(int8=True, **YI), ROW_WRITE),
     "row_write_lfm2": (lambda: _row_write(**LFM2), ROW_WRITE),
+    # The conv layers' one-launch step over the tail pool (ISSUE 48).
+    "conv_tail_lfm2": (_conv_tail, "conv_tail_step"),
     "row_write_latent_32_slots": (
         lambda: _row_write(pools=1, slots=32, hkv=1, nb=50, layers=8, d=640),
         ROW_WRITE),
@@ -416,6 +438,12 @@ def test_kernel_compiles_for_v5e(case):
             "flash_decode_paged", "window_decode_paged", "mla_decode_paged"))
     if kernel == ROW_WRITE:
         assert _row_writes(text) == 1
+    if kernel == "conv_tail_step":
+        # The pool that goes in is the pool that comes out (aliased through
+        # the call), under a name the two kernels' readers do not match.
+        assert _tail_steps(text) == 1
+        assert not any(other in kernel for other in (
+            "moe_grouped_matmul", "flash_decode_paged"))
     if kernel == "ssm_decode_update":
         # The pool that goes in is the pool that comes out, and nothing of
         # its size is made beside it.
@@ -442,12 +470,13 @@ def test_kernel_compiles_for_v5e(case):
         # layout of a pool-sized array before the launch (what a 576-lane
         # latent row cost before PR 27 padded it). Not asked of the smoke's
         # one-layer pools: the compiler stages those whole in fast memory.
-        pool = max(math.prod(a.shape) for a in builder()[1])
+        pool = max(math.prod(a.shape) for a in jax.tree.leaves(builder()[1]))
         moved = [
             (name, opcode, result)
             for name, result, opcode, _ in _materialised(text)
             if opcode not in _MOVES_NOTHING
-            and not name.startswith(f"%{ROW_WRITE}") and any(
+            and not name.startswith((f"%{ROW_WRITE}", "%conv_tail_step"))
+            and any(
                 math.prod(int(d) for d in dims.split(",")) >= pool
                 for dims in re.findall(r"\[([\d,]+)\]", result))
         ]
@@ -516,6 +545,22 @@ def _row_writes(text):
             r"output_to_operand_aliasing=\{(.*?)\}, \w+=", line).group(1)
         assert len(re.findall(r"\{\d*\}: \(\d+, \{\}\)", aliases)) == pools, \
             aliases
+    return len(lines)
+
+
+def _tail_steps(text):
+    """The launches of ``conv_tail_step`` in the module (ISSUE 48): a conv
+    layer's step for one row a slot, the tail pool aliased through the
+    call (output 0 is the operand the pool came in as)."""
+    lines = [
+        line for line in text.splitlines()
+        if re.match(r"\s+(?:ROOT )?%conv_tail_step(\.\d+)? = .* custom-call\(",
+                    line)
+    ]
+    for line in lines:
+        aliases = re.search(
+            r"output_to_operand_aliasing=\{(.*?)\}, \w+=", line).group(1)
+        assert re.findall(r"\{0\}: \(\d+, \{\}\)", aliases), aliases
     return len(lines)
 
 
@@ -1054,18 +1099,30 @@ def test_hybrid_step_compiles_and_keeps_the_pools_in_place(tq, packed):
             continue
         if opcode == "scatter" or " scatter(" in inner:
             writes.append(name)      # the pools' writes, in place (below)
-        elif not name.startswith(f"%{ROW_WRITE}"):
+        elif not name.startswith((f"%{ROW_WRITE}", "%conv_tail_step")):
             moved.append((name, opcode, result))
     # No copy of a K/V pool or of the tail pool (whole or a layer of it),
     # no slice of a layer's experts or tails out of their stack.
     assert not moved, moved
-    # The tails' scatter in each of the 4 runs of conv layers, for every
-    # group; K's and V's in each of the 3 attention layers (each a run of
-    # one): a chunk group's by two scatters, a row a slot by one launch of
-    # the row kernel and no block of the K/V pools gathered or scattered.
-    groups = 2 if packed else 1
-    assert len(writes) == groups * 4 + (2 * 3 if packed else 0), writes
+    # The tails: a row a slot goes through ONE launch of ``conv_tail_step``
+    # in each of the 4 runs of conv layers, the pool aliased through it; a
+    # chunk group's through the scatter of the block path, one a run, and
+    # no other group's. K's and V's in each of the 3 attention layers (each
+    # a run of one): a chunk group's by two scatters, a row a slot by one
+    # launch of the row kernel and no block of the K/V pools gathered or
+    # scattered.
+    assert len(writes) == (4 + 2 * 3 if packed else 0), writes
+    assert _tail_steps(text) == 4
     assert _row_writes(text) == 3
+    tail_moves = _block_moves(text, str(2 * cfg.d_model))
+    if not packed:
+        # Tq 1: no gather and no scatter of the tail pool anywhere.
+        assert not tail_moves, tail_moves
+    else:
+        # The chunk group's alone: its member's rows (a handful), never the
+        # 64 rows of the decode group.
+        assert not [m for m in tail_moves if f"[{slots}," in m[2]], \
+            tail_moves
     kv_moves = _block_moves(text, f"4,{blk},128")
     assert len([m for m in kv_moves if m[1] == "scatter"]) \
         == (2 * 3 if packed else 0), kv_moves
@@ -1653,3 +1710,21 @@ def test_tick_programs_build_the_work_lists_outside_the_layer_loops(
     inside = [(i.computation, i.op, i.scope) for i in plans
               if i.computation in bodies]
     assert not inside, inside
+    # The conv layers' tail targets likewise (ISSUE 48): built where the
+    # model has conv layers and nowhere else, and not in the body of a loop
+    # that launches the step.
+    from tree_attention_tpu.ops.pallas_conv import TAIL_PLAN_SCOPE
+
+    targets = [i for i in instrs if f"/{TAIL_PLAN_SCOPE}/" in f"/{i.scope}/"]
+    assert bool(targets) == bool(_model(config)[1].conv_layers), targets[:3]
+    steps = {i.computation for i in instrs if i.opcode == "custom-call"
+             and i.op.startswith("conv_tail_step")}
+    assert bool(steps) == bool(targets)
+    if targets:
+        # (A run of ONE conv layer is no loop: its launch sits in the entry
+        # computation, where the targets are built.)
+        assert steps - {entry}
+        assert {i.scope.split("/")[0] for i in targets} == {scopes.CONV}
+        inside = [(i.computation, i.op, i.scope) for i in targets
+                  if i.computation in steps - {entry}]
+        assert not inside, inside

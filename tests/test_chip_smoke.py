@@ -97,12 +97,14 @@ def test_phase_kernels_against_reference():
     assert set(d["kernels"]) == {
         "paged_decode_tq1", "paged_chunk_tq64", "paged_tree_verify_tq8",
         "paged_int8_q8q_block_scales", "paged_int8_q8_block_scales",
-        "paged_local_blocks_partial", "paged_row_write_tq1", "prefill_fwd",
-        "bwd_dq", "bwd_dkv",
+        "paged_local_blocks_partial", "paged_row_write_tq1",
+        "conv_tail_step_tq1", "prefill_fwd", "bwd_dq", "bwd_dkv",
     }
     assert all(k["ok"] for k in d["kernels"].values())
     # The row path leaves the block path's pool, bit for bit.
     assert d["kernels"]["paged_row_write_tq1"]["max_abs_err"] == 0.0
+    # ... and the conv layers' one-launch step the XLA path's rows and pool.
+    assert d["kernels"]["conv_tail_step_tq1"]["max_abs_err"] == 0.0
 
 
 @pytest.mark.parametrize("int8", [

@@ -209,6 +209,25 @@ def test_the_engine_serves_a_prefix_hit_a_fork_and_a_reused_slot(ref, model):
                                 n=2)])
         recs = [r for r in FLIGHT.snapshot()["records"] if "conv_rows" in r]
         text = obs.REGISTRY.to_prometheus()
+        # What a tick on a TPU counts (the tails' path steered to the row
+        # kernel's): one row a slot x 5 conv layers by it, a chunk's by
+        # the block path; a padded chunk tick takes the block path whole.
+        from tree_attention_tpu.serving import engine as engine_mod
+        steered = pytest.MonkeyPatch()
+        steered.setattr(engine_mod, "tail_write_path",
+                        lambda tq, tail: "row" if tq == 1 else "block")
+        try:
+            n_vec = np.asarray([1, 0, 1])
+            assert eng._count_tail_rows(1, n_vec, None) == 2
+            assert eng._count_tail_rows(16, n_vec, ([0], [9])) == 2
+            assert eng._count_tail_rows(16, n_vec, None) == 0
+            eng._account_step_counters(
+                np.asarray([0] * eng._expert_rows_shape[0]
+                           * eng._expert_rows_shape[1] + [20]), tail_rows=2)
+            steered_text = obs.REGISTRY.to_prometheus()
+        finally:
+            steered.undo()
+        assert eng._count_tail_rows(1, n_vec, None) == 0       # a CPU
     finally:
         FLIGHT.disarm()
         FLIGHT.clear()
@@ -239,8 +258,14 @@ def test_the_engine_serves_a_prefix_hit_a_fork_and_a_reused_slot(ref, model):
     assert all("experts_touched" in r for r in recs)
     assert "serving_cache_block_fixed_bytes 2560" in text
     wrote = sum(r["tail_blocks_written"] for r in recs)
-    assert f"serving_conv_tail_blocks_written_total {wrote}" in text \
-        or f"serving_conv_tail_blocks_written_total {float(wrote)}" in text
+    # ... all by the block path: off the TPU no tick takes the row kernel.
+    by = 'serving_conv_tail_blocks_written_total{path="%s"} '
+    assert by % "block" + str(wrote) in text \
+        or by % "block" + str(float(wrote)) in text
+    assert by % "row" + "0" in text
+    assert by % "row" + "10" in steered_text \
+        and (by % "block" + str(wrote + 10) in steered_text
+             or by % "block" + str(float(wrote + 10)) in steered_text)
 
 
 @pytest.mark.parametrize("kw, named", [

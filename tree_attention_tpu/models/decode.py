@@ -1255,6 +1255,7 @@ class _RowGroup(NamedTuple):
     wat: Any = None       # ... under the window layers' table
     slot: Optional[jax.Array] = None  # each member's slot (a per-slot state)
     live: Any = None      # one row a slot: the slots that have one (live_list)
+    tail: Any = None      # one row a slot, conv layers: ConvTailPlan
 
     @property
     def n_valid(self) -> jax.Array:
@@ -1316,6 +1317,9 @@ def _plan_groups(groups: Tuple[_RowGroup, ...], cache: Any,
     its kernel and the loop's body holds nothing of the write but the
     launch. Over a :class:`PagedStateCache` such a group gets the list of
     the slots that have a row too (``ops/pallas_ssm.py`` ``live_list``).
+    Over a model with conv layers it gets the places of positions ``p``,
+    ``p - 1`` and ``p - 2`` in the tail pool (``ops/pallas_conv.py``
+    ``conv_tail_plan``), which a conv layer's one launch is handed as they are.
     An EVA model's lists follow its two rules (:func:`window_rules`), and
     its first table's ``(block, row)`` is that of the SUMMARY row the
     slot's token closes, if it closes one (:func:`chunks_closed`)."""
@@ -1360,6 +1364,14 @@ def _plan_groups(groups: Tuple[_RowGroup, ...], cache: Any,
                 if g.wtable is not None:
                     g = g._replace(wat=barrier(_row_targets(
                         g.wtable, g.start, g.n_valid, cache.window_blocks,
+                        cache.block)))
+            if cfg.conv_layers:
+                # Where positions p, p-1 and p-2 lie in the tail pool.
+                from tree_attention_tpu.ops.pallas_conv import conv_tail_plan
+
+                with jax.named_scope(scopes.CONV):
+                    g = g._replace(tail=barrier(conv_tail_plan(
+                        g.table, g.start, g.n_valid, cache.blocks,
                         cache.block)))
         planned.append(g)
     return tuple(planned)

@@ -106,6 +106,7 @@ from tree_attention_tpu.models.decode import (
     decode_attention,
     gqa_branch,
     gqa_mixer,
+    pool_write_path,
     window_rules,
 )
 from tree_attention_tpu.models.transformer import (
@@ -173,6 +174,38 @@ def _tail_write(flat: jax.Array, z: jax.Array, g: _RowGroup, c,
     return flat, jnp.sum(live, dtype=jnp.int32)
 
 
+def _tail_step(flat: jax.Array, rows: jax.Array, w: jax.Array, g: _RowGroup,
+               c, n_blocks: int, block: int
+               ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The conv state's step for one group in plain XLA: from the group's
+    ``[b | c | u]`` rows ``(batch, tq, 3D)`` and the float32 taps ``w``, the
+    two rows before each member's first position read once, the taps by
+    shifts inside the rows, each touched block's tail written. Returns the
+    pool, ``c * s`` ``(batch, tq, D)`` and the block tails written."""
+    D = rows.shape[-1] // 3
+    zg = rows[..., :D] * rows[..., 2 * D:]
+    before = [_tail_rows(flat, g, c, back, n_blocks, block)[:, None]
+              for back in (2, 1)]
+    zz = jnp.concatenate(before + [zg], axis=1).astype(jnp.float32)
+    s = sum(w[k] * zz[:, k:k + g.tq] for k in range(3))
+    flat, n = _tail_write(flat, zg, g, c, n_blocks, block)
+    return flat, rows[..., D:2 * D] * s.astype(rows.dtype), n
+
+
+def tail_write_path(tq: int, tail: jax.Array) -> str:
+    """Which of the tail pool's two steps a group of ``tq`` rows a slot
+    takes: the answer :func:`~.decode.pool_write_path` gives every pool
+    (``"row"``: one row a slot, on a TPU), where the row kernel
+    (``ops/pallas_conv.py`` ``conv_tail_step``) can cut this pool (bf16,
+    a layer's blocks a multiple of its cut, whole lane tiles a half row);
+    else ``"block"``, the XLA path (:func:`_tail_step`)."""
+    from tree_attention_tpu.ops.pallas_conv import CUT
+
+    cuts = tail.dtype == jnp.bfloat16 and not tail.shape[1] % CUT \
+        and not tail.shape[2] % 256
+    return pool_write_path(tq) if cuts else "block"
+
+
 def conv_mixer(layer: Params, x: jax.Array, tail: jax.Array, c,
                groups: Tuple[_RowGroup, ...], cfg: TransformerConfig,
                block: int) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -180,29 +213,35 @@ def conv_mixer(layer: Params, x: jax.Array, tail: jax.Array, c,
     ``layer``: this layer's leaves (``ln1`` ``(D,)``, ``w_in`` ``(D, 3D)``,
     ``w_conv`` ``(3, D)`` with tap ``k`` on ``z_{t-2+k}``, ``w_out`` ``(D,
     D)``); ``tail`` the WHOLE tail pool ``(conv layers, N, 2·D)``, this
-    layer's entries reached by offset ``c·N``. A group reads the two rows
-    before its members' first positions once, convolves inside its rows by
-    shifts and writes each touched block's tail. Returns the residual with
+    layer's entries reached by offset ``c·N``. A group's state step is
+    :func:`_tail_step`, or, for a group of ONE row a slot on a TPU
+    (:func:`tail_write_path`), one launch of ``ops/pallas_conv.py``
+    ``conv_tail_step``; both leave the same bits. Returns the residual with
     the mixer's output added, the pool, and the block tails written."""
-    D = cfg.d_model
+    from tree_attention_tpu.ops.pallas_conv import (
+        conv_tail_plan, conv_tail_step,
+    )
+
     h = rms_norm(x, layer["ln1"], cfg.norm_eps)
     bcu = h @ layer["w_in"]
-    z = bcu[..., :D] * bcu[..., 2 * D:]
-    w = layer["w_conv"].astype(jnp.float32)
     n_blocks = tail.shape[1]
-    flat = tail.reshape(-1, 2 * D)
+    flat = tail.reshape(-1, 2 * cfg.d_model)
     outs, wrote = [], jnp.int32(0)
     for g in groups:
-        zg = g.take(z[:, None])[:, 0]                     # (batch, tq, D)
-        before = [_tail_rows(flat, g, c, back, n_blocks, block)[:, None]
-                  for back in (2, 1)]
-        zz = jnp.concatenate(before + [zg], axis=1).astype(jnp.float32)
-        s = sum(w[k] * zz[:, k:k + g.tq] for k in range(3))
-        outs.append(s.astype(x.dtype)[:, None])
-        flat, n = _tail_write(flat, zg, g, c, n_blocks, block)
+        rows = g.take(bcu[:, None])[:, 0]                 # (batch, tq, 3D)
+        if tail_write_path(g.tq, tail) == "row":
+            plan = g.tail if g.tail is not None else conv_tail_plan(
+                g.table, g.start, g.n_valid, n_blocks, block)
+            flat, gated = conv_tail_step(
+                flat, rows[:, 0], layer["w_conv"], plan, c * n_blocks)
+            gated, n = gated[:, None], plan.count
+        else:
+            flat, gated, n = _tail_step(
+                flat, rows, layer["w_conv"].astype(jnp.float32), g, c,
+                n_blocks, block)
+        outs.append(gated[:, None])
         wrote = wrote + n
-    s = _join_rows(groups, outs)[:, 0]
-    y = (bcu[..., D:2 * D] * s) @ layer["w_out"]
+    y = _join_rows(groups, outs)[:, 0] @ layer["w_out"]
     return x + y, flat.reshape(tail.shape), wrote
 
 

@@ -190,6 +190,84 @@ def decode_block_k_q8(tk: int) -> int:
 # A chunk group (Tq > 1) keeps the block path: PR 25 measured a row scatter
 # at ~70 ns a row there and a block is what 5 blocks of 64 rows want.
 #
+# A conv layer's decode step over the TAIL pool (ISSUE 48; no tile of this
+# module's: `ops/pallas_conv.py` `conv_tail_step`, chosen by `models/hybrid.py`
+# `tail_write_path`). LFM2's shape: 64 slots, one row of 2 x 2048 bf16 lanes a
+# block and layer, `(9 x 2560, 4096)` as it lies. Measured on v5e 2026-10-03 /
+# 04. In the cell (`lfm2_agentturn_sat`, traced decode ticks, the events joined
+# to the program tables, us a LAYER; nine layers a tick):
+#
+#   today's XLA chain between the layer's two products            44.0
+#     the scatter of 64 rows into bf16[23040,4096]                  25.0
+#     three gathers of 64 rows out of it (+ the half select's)      16.9
+#     table lookups s32[64], redone in every layer                   2.7
+#     the gates, taps, selects, broadcasts, copies (~30 operations)  ~6
+#   one launch, overlay-all on 8-row cuts (first form, below)     30.6
+#   one launch, a slot's pair of rows out of its cut (chosen)     24.8
+#
+# and alone (a scratch program of 36 calls over the pool donated to it, layer
+# i % 9, one slot idle, the plan built once outside the loop; the profiler's
+# device time of the program's leaf operations a call, less an empty loop's;
+# the kernel's own launch in brackets; blocks scattered / 64 slots' blocks
+# neighbours in the pool, eight to a cut):
+#
+#   us a call (the launch)              scattered      neighbours
+#   today's XLA chain                    48.5           44.1
+#   read-only kernel + XLA scatter       37.9 (15.9)    34.3 (15.8)
+#   overlay-all on 8-row cuts (first)    30.3 (31.0)    30.3 (31.0)
+#   a slot's pair out of its cut, six
+#     loops over the slots               28.8 (29.4)    39.1 (39.7)
+#   ... the loops merged to three
+#     (chosen)                           23.5 (24.1)    33.6 (34.1)
+#
+# (the empty loop 1.2; every variant's pool bit-equal to the XLA chain's on
+# the chip, its rows within 0.25% of the chain's own: below.) The chosen one
+# is 64 slots x three loops of scalar work and copies' descriptors (~0.3 us
+# a slot) round ~5 us of arithmetic; the first is flat in the layout and
+# wins where all 64 slots share 8 cuts, which a run's first ticks may and
+# its steady state does not (the cell's traced ticks read the scattered
+# column).
+#
+# The cut. The compile for the chip refuses a copy of fewer than 8 rows of a
+# 2-D bf16 array on either side ("Slice shape along dimension 0 must be
+# aligned to tiling (8), but is 2"; the same through a `uint32` view of the
+# pool, whose 4 rows are those 8; `(M/8, 8, 4096)` and `(M/2, 2, 4096)` views
+# refuse the 2-row slice of their middle dimension too): the chip lays the
+# array out `T(8,128)(2,1)`, rows 2j and 2j + 1 the halves of the same words.
+# So a slot's copy is 64 KB in (both halves of its row: z at p-1 and p-2) and
+# 32 KB back (the half its z went to: a lane slice compiles), 6 MB a layer at
+# 64 slots = 7.5 us at the memory's pace; the 16-row packed tile would double
+# it. A cut's 8 rows are 8 BLOCKS of perhaps 8 live slots (with 64 scattered
+# blocks among 2,560 some eleven slots a tick share a cut; neighbours at the
+# start of a run: all of them). Ways round the shared cut:
+# - every copy of a cut gets the z of EVERY live slot in it, all slots at
+#   once: the owners found by one (64, 64) compare, their z brought over by a
+#   one-hot `(512, 64) x (64, 2048)` product on the MXU (exact), the cuts
+#   blended word by word. Bit-equal on the chip, and 30.6-31.0 us a launch: not
+#   the product (2.7 us) but the layout. A `(slots, 4 pairs, 4096)` array of
+#   cuts puts a slot's pair in ONE sublane of its own tile, so every read of
+#   "pair j of every slot" and every write back is a single-sublane access a
+#   slot and lane tile: 8 such passes over 64 x 32 tiles each way, ~30k
+#   bundles of a 1.5 MB straight-line program. Not chosen.
+# - the chosen one: the cuts stay where the copies put them; a slot's OWN
+#   pair is copied out by one dynamic-sublane read into a `(slots, 4096)`
+#   array (64 x 32 loads), everything runs on that array (0.35 MB of code),
+#   the pair goes back the same way; the plan lists, once a tick, for each
+#   slot the other live slots of its cut that write the same half (at most
+#   7), and their z goes into the slot's copy row by row under `pl.when`.
+#   Cost follows the sharing: nothing where blocks are scattered.
+# - the cuts made distinct in the plan (one entry a cut, its sharers listed):
+#   not built; it saves copies only where slots share, which a run's steady
+#   state does not, and the arithmetic would have to be gathered by entry.
+# - the read-only fallback (the kernel reads, gates and convolves; the 64-row
+#   scatter stays in XLA): the scatter IS the largest piece (25 of the 44 us),
+#   so it was never a candidate for the cell; row "read + scatter" above.
+# The rows (`c * s`) differ from XLA's own on the CHIP by at most one bf16
+# rounding: XLA keeps z and s in float32 inside a fusion
+# (`xla_allow_excess_precision`), the kernel rounds where the source rounds,
+# as XLA does on the CPU; the pool is bit-equal on both (`chip_smoke.py`
+# `conv_tail_step_tq1`, `tests/test_conv_tail_step.py`).
+#
 # A latent layer's query up-projection, `c_q x wqb_t` (ISSUE 41; no tile of
 # this module's: the product is plain XLA in `models/latent.py` `latent_qkv`,
 # and what was tried is the form it reaches the compiler in). Measured on v5e

@@ -187,6 +187,7 @@ from tree_attention_tpu.serving.speculation import (
     pack_proposal,
     pack_siblings,
 )
+from tree_attention_tpu.models.hybrid import tail_write_path
 from tree_attention_tpu.models.transformer import (
     GQA_SERVED,
     LATENT_SERVED,
@@ -268,7 +269,9 @@ _WEIGHTS_RELAID = obs.gauge(
 _TAIL_BLOCKS = obs.counter(
     "serving_conv_tail_blocks_written_total",
     "block tails the conv layers wrote (blocks a tick's rows fell in x conv "
-    "layers)",
+    "layers), by the step they took: row (one row a slot through "
+    "conv_tail_step) or block (rows gathered, overlaid and scattered back)",
+    labels=("path",),
 )
 _SSM_STATES = obs.counter(
     "serving_ssm_states_advanced_total",
@@ -540,6 +543,9 @@ class _Tail:
     # Rows x layers the program wrote into the pool: (by the row kernel, by
     # the block path) (``_count_pool_rows``).
     pool_rows: Tuple[int, int] = (0, 0)
+    # Slots with one row whose conv tails take the row kernel
+    # (``_count_tail_rows``).
+    tail_rows: int = 0
     # The head's per-tick counters, frozen when the tail is left pending
     # (the iteration that lands it has counted its own by then).
     counts: Optional[Dict[str, Any]] = None
@@ -1288,6 +1294,11 @@ class SlotServer:
         # tails): the report's ``kv.block_fixed_bytes``.
         self._kv_block_fixed_bytes = cache_block_fixed_bytes(self.cache)
         self._conv_layers = cfg.conv_layers   # the tail pool's depth
+        # ... and its shape and dtype, for the path a tick's tails take
+        # (``_count_tail_rows``).
+        self._tail_pool = jax.ShapeDtypeStruct(
+            self.cache.tail.shape, self.cache.tail.dtype) \
+            if cfg.conv_layers else None
         self._ssm_layers = cfg.ssm_layers     # the state pool's depth
         self._eva_layers = cfg.eva_layers     # both EVA pools' depth
         # A state pool's per-slot arrays in bytes, by field name; empty for
@@ -1549,11 +1560,13 @@ class SlotServer:
                                                   axis=0)
         return cache, tok_vec
 
-    def _account_step_counters(self, extra: np.ndarray) -> Dict[str, int]:
+    def _account_step_counters(self, extra: np.ndarray,
+                               tail_rows: int = 0) -> Dict[str, int]:
         """Read the step's counters off the tick's fetch (the rows below
         the slots') into the registry, and return the flight record's
         numbers: the expert layers' (:meth:`_account_expert_rows`), then
-        ``tail_blocks_written``, the block tails the conv layers wrote, or
+        ``tail_blocks_written``, the block tails the conv layers wrote
+        (``tail_rows`` slots' by the row kernel, :meth:`_count_tail_rows`), or
         ``ssm_states_advanced``, the (slot, layer) states the state-space
         layers wrote, or ``eva_summaries_written``, the (slot, layer)
         summary rows the EVA layers wrote."""
@@ -1566,7 +1579,10 @@ class SlotServer:
         if self._conv_layers:
             out["tail_blocks_written"] = int(flat[at])
             if obs.REGISTRY.enabled:
-                _TAIL_BLOCKS.inc(out["tail_blocks_written"])
+                by_row = tail_rows * self._conv_layers
+                _TAIL_BLOCKS.labels(path="row").inc(by_row)
+                _TAIL_BLOCKS.labels(path="block").inc(
+                    out["tail_blocks_written"] - by_row)
         if self._ssm_layers:
             out["ssm_states_advanced"] = int(flat[at])
             if obs.REGISTRY.enabled:
@@ -1576,6 +1592,18 @@ class SlotServer:
             if obs.REGISTRY.enabled:
                 _EVA_SUMMARIES.inc(out["eva_summaries_written"])
         return out
+
+    def _count_tail_rows(self, tq, n_vec, chunk) -> int:
+        """Slots whose ONE row the program being dispatched takes through
+        the conv layers' row kernel (``models/hybrid.py``
+        ``tail_write_path``): the tails it writes by that path are these
+        times the conv layers, the rest of the program's own count moved
+        whole rows. Counted from the rows the host packed, as
+        :meth:`_count_pool_rows` counts the K/V rows."""
+        if not self._conv_layers or tail_write_path(
+                1 if chunk is not None else tq, self._tail_pool) != "row":
+            return 0
+        return int(n_vec.sum())
 
     def _account_expert_rows(self, extra: np.ndarray) -> Dict[str, int]:
         """Read the expert layers' row counts off the tick's fetch into
@@ -4571,7 +4599,7 @@ class SlotServer:
                             or self._eva_layers) and (
                             FLIGHT.enabled or obs.REGISTRY.enabled):
                         expert_rows = self._account_step_counters(
-                            fh[self.slots:])
+                            fh[self.slots:], p.tail_rows)
                 else:
                     # Nothing stepped (a staged final chunk parked its
                     # first token while no slot was live): fetch the
@@ -5484,6 +5512,10 @@ class SlotServer:
                         pool_rows=(
                             (0, 0) if n_vec is None
                             else self._count_pool_rows(
+                                tick_tq, n_vec, kv_chunk)),
+                        tail_rows=(
+                            0 if n_vec is None
+                            else self._count_tail_rows(
                                 tick_tq, n_vec, kv_chunk)),
                     )
                     primed = True
