@@ -55,6 +55,21 @@ PARTS_ALL = PARTS_MOE[:5] + ["dec_conv_ms_tick"] + PARTS_MOE[5:11] + [
     "mix_conv_ms_tick", "tick_unscoped_pct"]
 # What PR 37 appended after them, for every cell.
 AFTER_PARTS = ["paged_steps_run_pct"]
+# What PR 50 appended last of all, for every cell: set-up from inside.
+SETUP_METRICS = ["setup_import_s", "setup_engine_s", "setup_programs_s",
+                 "setup_programs_cached_pct", "setup_serve_s",
+                 "setup_unseen_s", "programs_built_in_window"]
+
+
+def before_setup(entries):
+    """A per-layer list (a cell's or the file's; entries or their names)
+    without PR 50's seven, which close every list: the older PRs' tails
+    are counted from what is left (``tests/test_startup_record.py`` holds
+    the seven themselves)."""
+    tail = [m["name"] if isinstance(m, dict) else m
+            for m in entries[-len(SETUP_METRICS):]]
+    assert tail == SETUP_METRICS
+    return entries[:-len(SETUP_METRICS)]
 # What PR 38 appended last, for its own cell.
 WINDOW_METRICS = ["window_attn_ms_tick", "window_decode_paged_roofline",
                   "window_blocks_held_pct"]
@@ -300,12 +315,12 @@ def test_the_double_layer_cell_resolves_every_file_it_names():
     # Every per-layer metric the other latent cell reports, and its own two
     # (what later PRs appended for every cell comes after them: PR 32's,
     # and PR 35's parts as the expert cells list them).
-    theirs = [m["name"] for m in spec.cell(CELL).per_layer]
+    theirs = before_setup([m["name"] for m in spec.cell(CELL).per_layer])
     later = ["tick_ahead_pct"] + PARTS_MOE + AFTER_PARTS
     assert theirs[-len(later):] == later
-    assert names == theirs[:-len(later)] + [
+    assert before_setup(names) == theirs[:-len(later)] + [
         "zero_expert_pairs_pct", "real_experts_row_max_over_mean"] + later
-    for m in cell.per_layer[-2 - len(later):-len(later)]:
+    for m in before_setup(cell.per_layer)[-2 - len(later):-len(later)]:
         assert m["workloads"] == [LC_CELL] and m["layer"] == "expert layer"
         assert (m["source"], m["moves"]) == ("program_counter", "tbt_p50_ms")
     for w in spec.data["workloads"][:4]:
@@ -466,7 +481,7 @@ def test_tick_ahead_pct_reads_the_look_ahead_counter(flight, want):
         "moves": "tbt_p50_ms"}
     # Only what later PRs appended comes after it (PR 33's for its own cell,
     # PR 35's parts).
-    assert [m["name"] for m in listed[at + 1:]] == [
+    assert [m["name"] for m in before_setup(listed)[at + 1:]] == [
         "mixer_rest_ms_tick", "mixer_rest_stream_roofline"] + PARTS_ALL \
         + AFTER_PARTS + WINDOW_METRICS + STATE_METRICS + EVA_METRICS
     for w in json.load(open(BENCH))["workloads"]:
@@ -514,10 +529,11 @@ def test_the_hybrid_cell_resolves_every_file_it_names():
                  "device_idle_pct", "decode_tick_p50_ms"):
         assert name in names, name
     later = PARTS_ALL + AFTER_PARTS           # PR 35's, all of them; PR 37's
-    assert names[-len(later):] == later
+    assert before_setup(names)[-len(later):] == later
     own = slice(-2 - len(later), -len(later))
-    assert names[own] == ["mixer_rest_ms_tick", "mixer_rest_stream_roofline"]
-    for m in cell.per_layer[own]:
+    assert before_setup(names)[own] == [
+        "mixer_rest_ms_tick", "mixer_rest_stream_roofline"]
+    for m in before_setup(cell.per_layer)[own]:
         assert m["workloads"] == [LFM_CELL] and m["layer"] == "kernels"
         assert (m["source"], m["moves"]) == ("device_trace", "tbt_p50_ms")
     for name in ("mla_decode_ms_tick", "zero_expert_pairs_pct"):
@@ -695,7 +711,7 @@ def test_a_parts_metric_is_listed_where_its_part_exists(name):
     parts in the hybrid's and the two state configurations', the rest in all
     ten."""
     spec = Spec(BENCH)
-    listed = json.load(open(BENCH))["per_layer"]
+    listed = before_setup(json.load(open(BENCH))["per_layer"])
     tail = len(PARTS_ALL) + len(AFTER_PARTS) + len(WINDOW_METRICS) \
         + len(STATE_METRICS) + len(EVA_METRICS)
     assert [m["name"] for m in listed[-tail:]] == PARTS_ALL + AFTER_PARTS \
@@ -750,7 +766,7 @@ def test_paged_steps_run_pct_reads_the_lists_the_kernels_walk():
     import types
 
     spec = Spec(BENCH)
-    listed = json.load(open(BENCH))["per_layer"]
+    listed = before_setup(json.load(open(BENCH))["per_layer"])
     cells = [w["name"] for w in spec.data["workloads"]]
     assert listed[-1 - len(WINDOW_METRICS) - len(STATE_METRICS)
                   - len(EVA_METRICS)] == {
@@ -762,7 +778,7 @@ def test_paged_steps_run_pct_reads_the_lists_the_kernels_walk():
             - len(STATE_METRICS) * (cell == NS_CELL) \
             - (1 + len(EVA_METRICS)) * (cell == EVA_CELL) \
             - 3 * (cell == FH_CELL)     # the kernel's two and the pool's
-        assert spec.cell(cell).per_layer[last]["name"] \
+        assert before_setup(spec.cell(cell).per_layer)[last]["name"] \
             == "paged_steps_run_pct"
     read = spec.load_module("layer_metrics", "paged_steps_run_pct.py").read
     tick = {"occupancy": 16, "chunk_tokens": 0, "kv_steps_grid": 160}
@@ -819,7 +835,7 @@ def test_the_window_cell_resolves_every_file_it_names():
                  "device_idle_pct", "decode_tick_p50_ms",
                  "paged_steps_run_pct", "tick_unscoped_pct"):
         assert name in names, name
-    assert names[-3:] == WINDOW_METRICS
+    assert before_setup(names)[-3:] == WINDOW_METRICS
     for name in ("mla_decode_ms_tick", "zero_expert_pairs_pct",
                  "dec_conv_ms_tick", "mix_conv_ms_tick",
                  "mixer_rest_ms_tick", "mix_attn_chunk_ms_tick",
@@ -1029,7 +1045,7 @@ def test_the_state_cell_resolves_every_file_it_names():
                  "device_idle_pct", "decode_tick_p50_ms",
                  "paged_steps_run_pct") + tuple(PARTS_ALL):
         assert name in names, name
-    assert names[-5:] == STATE_METRICS
+    assert before_setup(names)[-5:] == STATE_METRICS
     # The gated product's time and roofline are not this cell's (its cost
     # file counts three matrices of hidden x width an expert).
     for name in ("moe_ffn_ms_tick", "moe_grouped_matmul_roofline",
@@ -1225,7 +1241,7 @@ def test_the_eva_cell_resolves_every_file_it_names():
                  "device_idle_pct", "decode_tick_p50_ms",
                  "mixed_tick_p50_ms") + tuple(PARTS_DENSE):
         assert name in names, name
-    assert names[-5:] == EVA_METRICS
+    assert before_setup(names)[-5:] == EVA_METRICS
     # Another kernel's name would read another kernel's cost file.
     for name in ("attn_kernel_ms_tick", "flash_decode_paged_roofline",
                  "window_attn_ms_tick", "window_decode_paged_roofline",

@@ -233,6 +233,11 @@ class _FakeAnnotation:
     def __exit__(self, *exc):
         _FakeAnnotation.log.append(("leave", self.name, self.kwargs))
 
+    def set_metadata(self, **kwargs):
+        """What a phase learns while it is open (``built``, of a dispatch
+        that built its program: ``tests/test_startup_record.py``)."""
+        self.late = kwargs
+
 
 def _iterations(names):
     """Annotation names grouped by loop iteration (each opens 'ingest')."""

@@ -27,6 +27,13 @@ Public API highlights:
   on the above.
 """
 
+import time as _time
+
+#: When this package's import began (``time.monotonic()``), before JAX's:
+#: where the start-up record begins on a system whose ``/proc`` does not
+#: give the process's own start (``obs/flight.py`` ``StartupRecord``).
+_T_IMPORT = _time.monotonic()
+
 __version__ = "0.5.0"
 
 from tree_attention_tpu.ops import flash_attention, merge_partials  # noqa: F401

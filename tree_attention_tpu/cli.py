@@ -26,6 +26,7 @@ import json
 import logging
 import os
 import sys
+import time
 from typing import Any, Callable, Optional
 
 from tree_attention_tpu import obs
@@ -38,11 +39,16 @@ log = get_logger("cli")
 def _report_record(report) -> dict:
     """``ServeReport.as_dict()`` for the one-line record: of the tick
     programs' tables (there while tracing is on) the labels alone; the
-    rows are in the flight recorder's dump."""
+    rows are in the flight recorder's dump. Likewise of the start-up
+    record."""
     rec = report.as_dict()
     if "programs" in rec:
         rec["programs"] = [dict(t["program"], ops=len(t["ops"]))
                            for t in rec["programs"]]
+    if "startup" in rec:
+        # Of the start-up record the phases' seconds alone; the spans are
+        # in the flight recorder's dump and under /healthz.
+        rec["startup"] = rec["startup"]["seconds"]
     return rec
 
 
@@ -589,6 +595,15 @@ def build_serve_engine(cfg: RunConfig, mesh, *, model=None,
     takes as it takes the outer format."""
     import jax
 
+    # The start-up record (obs/flight.py): this call is four spans end to
+    # end. The device is resolved first; where nothing asked before (a
+    # caller that is not ``main``), that is the backend's client starting.
+    t_enter = time.monotonic()
+    devices = jax.devices()
+    t_devices = time.monotonic()
+    obs.STARTUP.add("backend", t_enter, t_devices,
+                    platform=devices[0].platform, devices=len(devices))
+
     from tree_attention_tpu.models import init_params
     from tree_attention_tpu.serving import SlotServer
     from tree_attention_tpu.serving.engine import serving_params
@@ -722,12 +737,20 @@ def build_serve_engine(cfg: RunConfig, mesh, *, model=None,
     else:
         tcfg = _transformer_config(
             dataclasses.replace(cfg, seq_len=cache_len))
+    # ``startup:engine`` so far: the flags held, the model read. Then
+    # ``startup:params``: the weights drawn, where none were handed in
+    # (the draw is queued: the span that waits for it is the next), and
+    # re-laid, which ``serving_params`` records itself.
+    t_params = time.monotonic()
+    obs.STARTUP.add("engine", t_devices, t_params)
     if params is None:
         params = init_params(jax.random.PRNGKey(cfg.seed), tcfg)
+        obs.STARTUP.add("params", t_params)
     # Re-laid here, once, for every engine ``make_engine`` builds (a
     # fleet's replicas share the tree), and so that nothing built here
     # keeps the outer format's leaves alive beside the served ones.
     params = serving_params(params)
+    t_served = time.monotonic()
     if cfg.slo_ttft <= 0 or cfg.slo_tbt <= 0:
         raise SystemExit("--slo-ttft and --slo-tbt must be > 0")
     # The paged pool has ONE device budget (--kv-blocks) and one host
@@ -786,6 +809,9 @@ def build_serve_engine(cfg: RunConfig, mesh, *, model=None,
             )
         return SlotServer(params, tcfg, **engine_kw)
 
+    # The rest of ``startup:engine`` here; ``SlotServer.__init__`` adds
+    # its own for every engine ``make_engine`` builds.
+    obs.STARTUP.add("engine", t_served)
     return ServeSetup(
         tcfg=tcfg, params=params, cache_len=cache_len,
         host_blocks=host_blocks, decode_slots=decode_slots,
@@ -977,6 +1003,7 @@ def _start_metrics_http(cfg: RunConfig):
     if cfg.metrics_port is None:
         return None
     obs.REGISTRY.enable()
+    obs.STARTUP.publish()   # the gauge, of what closed before the registry
     if not obs.FLIGHT.enabled:
         obs.FLIGHT.arm()
     if not obs.REQLOG.enabled:
@@ -1019,6 +1046,7 @@ def main(argv: Optional[list] = None) -> int:
             http_server = _start_metrics_http(cfg)
             obs.install_crash_handlers()
             return _relaunch(cfg, argv)
+        t_backend = time.monotonic()
         _configure_backend(cfg)
 
         import jax
@@ -1028,6 +1056,10 @@ def main(argv: Optional[list] = None) -> int:
 
         initialize_distributed()
         _require_devices(cfg)
+        # The program's first device query, so the backend's client
+        # starting (the TPU's takes seconds): ``startup:backend``.
+        obs.STARTUP.add("backend", t_backend, platform=jax.default_backend(),
+                        devices=jax.device_count())
         # Telemetry arms AFTER distributed init so the tracer's pid and the
         # metrics path's rank suffix see the real process index — on
         # auto-detected multi-host runs neither TA_COORDINATOR nor
@@ -1074,6 +1106,10 @@ def main(argv: Optional[list] = None) -> int:
                 sinks["flight_out"] or "-",
             )
 
+
+# The last line of this module's body: the process's start to here is the
+# interpreter, this package's import (JAX's inside it) and this module's.
+obs.STARTUP.add("import", obs.STARTUP.t_process)
 
 if __name__ == "__main__":
     sys.exit(main())

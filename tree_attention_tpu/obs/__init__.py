@@ -11,9 +11,10 @@ Two complementary instruments, both off (and free) by default:
   index so multi-host captures merge cleanly.
 
 The serving observability plane builds on both: the tick flight recorder
-(:mod:`~tree_attention_tpu.obs.flight`, ``--flight-out``), the
-sliding-window SLO monitor (:mod:`~tree_attention_tpu.obs.slo`), and the
-live HTTP exporter (:mod:`~tree_attention_tpu.obs.http`,
+(:mod:`~tree_attention_tpu.obs.flight`, ``--flight-out``) with the
+start-up record beside it (``STARTUP``: always on, what the process did
+before its first tick), the sliding-window SLO monitor
+(:mod:`~tree_attention_tpu.obs.slo`), and the live HTTP exporter (:mod:`~tree_attention_tpu.obs.http`,
 ``--metrics-port`` — imported lazily; mounting ``/metrics`` must not tax
 every library import). :func:`install_crash_handlers` makes all sinks
 crash-safe (atexit + SIGTERM flush, SIGUSR1 live dump).
@@ -67,7 +68,10 @@ from tree_attention_tpu.obs.tracing import (  # noqa: F401
 )
 from tree_attention_tpu.obs.flight import (  # noqa: F401
     FLIGHT,
+    STARTUP,
+    STARTUP_SPANS,
     FlightRecorder,
+    StartupRecord,
 )
 from tree_attention_tpu.obs.reqlog import (  # noqa: F401
     REQLOG,
@@ -143,6 +147,9 @@ def configure(
         # /requests view backs the metrics plane and its finish instant
         # lands in the trace; it has no sink file of its own.
         REQLOG.arm()
+        # The start-up record began before any sink: hand them the spans
+        # that closed before this call (the import, the backend).
+        STARTUP.publish()
 
 
 def shutdown() -> Dict[str, Any]:

@@ -32,6 +32,14 @@ process may run several engine threads) stamps the boundaries between the
 phases of one engine tick and hands the one set of stamps to three sinks —
 the tick's flight record, the ``jax.profiler`` trace, and the
 ``--trace-events`` JSONL.
+
+Start-up from inside: a :class:`StartupRecord` (the recorder's
+``startup``, always on, also reachable as :data:`STARTUP`) keeps what the
+process did before the first tick and whenever it built a program since:
+closed spans ``[name, t0, t1, fields]`` on ``time.monotonic()`` under the
+fixed vocabulary :data:`STARTUP_SPANS`, from the process's own start. It
+rides the recorder's dumps and ``/healthz`` whether or not the ring is
+armed, and feeds the span tracer and the registry as its spans close.
 """
 
 from __future__ import annotations
@@ -43,9 +51,286 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional
 
+from tree_attention_tpu.obs.metrics import REGISTRY, counter, gauge
 from tree_attention_tpu.obs.tracing import TRACER
 
 DEFAULT_CAPACITY = 256
+
+#: The start-up record's vocabulary, in the order a process runs them
+#: (ARCHITECTURE.md "Start-up from inside"); a span is named
+#: ``startup:<phase>``.
+STARTUP_SPANS = ("import", "backend", "params", "engine", "program",
+                 "tables", "serve")
+_STARTUP_NAME = {p: "startup:" + p for p in STARTUP_SPANS}
+
+_STARTUP_SECONDS = gauge(
+    "serving_startup_seconds",
+    "seconds of the start-up record's closed spans so far, by phase "
+    "(import, backend, params, engine, program, tables, serve): what a "
+    "restart spent where",
+    labels=("phase",),
+)
+_PROGRAMS_BUILT = counter(
+    "serving_tick_programs_built_total",
+    "tick programs an engine built, by program (the jit's name) and by "
+    "source: compiled, or fetched from the persistent cache",
+    labels=("program", "source"),
+)
+
+# The durations JAX reports while it builds one program, in the order it
+# reports them (``jax._src.dispatch`` / ``compiler``). The retrieval event
+# is there only on a hit of the persistent cache, and the backend's
+# duration then holds it.
+_EV_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_EV_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_EV_FETCH = "/jax/compilation_cache/cache_retrieval_time_sec"
+_EV_COMPILE = "/jax/core/compile/backend_compile_duration"
+
+
+def _process_start() -> Optional[float]:
+    """When the kernel started this process, on ``time.monotonic()``'s
+    clock: field 22 of ``/proc/self/stat`` (clock ticks since boot) against
+    the boot clock now. None where ``/proc`` has none or says nonsense."""
+    try:
+        with open("/proc/self/stat") as f:
+            # The command's name (field 2) may hold spaces: count from the
+            # parenthesis that closes it.
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        age = time.clock_gettime(time.CLOCK_BOOTTIME) \
+            - ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+    return time.monotonic() - age if age >= 0.0 else None
+
+
+class _Build:
+    """One tick program being built: the open ``startup:program`` span and
+    how far JAX has come with it. The stages keep a program that some
+    operation of the traced body compiles eagerly out of the sums: nothing
+    counts until the body has returned (stage 1), then the outer trace's
+    duration, the lowering's, the cache's and the backend's arrive in that
+    order, and the last closes the span."""
+
+    __slots__ = ("span", "stage", "done")
+
+    def __init__(self, span: List[Any], done):
+        self.span = span
+        self.stage = 0
+        self.done = done                # called with the closed span
+
+    def take(self, record: "StartupRecord", event: str,
+             seconds: float) -> None:
+        fields = self.span[3]
+        if self.stage == 1 and event == _EV_TRACE:
+            fields["trace_s"] = seconds
+            self.stage = 2
+        elif self.stage == 2 and event == _EV_LOWER:
+            fields["lower_s"] = seconds
+            self.stage = 3
+        elif self.stage == 3 and event == _EV_FETCH:
+            fields["fetch_s"] = seconds
+            fields["from_cache"] = True
+        elif self.stage == 3 and event == _EV_COMPILE:
+            fields["compile_s"] = max(seconds - fields["fetch_s"], 0.0)
+            record._built(self)
+
+
+class StartupRecord:
+    """What the process did before it served, and every program it built
+    since: closed spans ``[name, t0, t1, fields]``, bounded.
+
+    Always on, and nothing of it runs in a tick that builds no program: a
+    span costs its two clock reads and one list, where the caller has a
+    stamp already it hands it over, and the spans are few (a handful a
+    process, one a program built, one a ``serve()`` call). A span closes
+    when the host's call returns: work the device finishes later (a
+    placement, a queued transposition) shows in the span that first waits
+    for it. ``t_process`` is the process's own start as the kernel has it
+    (:func:`_process_start`), or the package's import stamp.
+
+    Bounded: the first :attr:`HEAD` spans stay for good (the start-up
+    proper), later ones turn over in a ring of :attr:`RING` (a server that
+    lives long keeps its newest builds and ``serve()`` calls);
+    ``dropped`` counts what the ring pushed out.
+
+    Three sinks, fed as a span closes: the record itself
+    (:meth:`snapshot`: ``ServeReport.startup``, the recorder's dumps,
+    ``/healthz``), the span tracer when it is active (a complete event
+    under the span's name; :meth:`publish` sends what closed before the
+    tracer started) and the registry (``serving_startup_seconds{phase}``,
+    ``serving_tick_programs_built_total{program, source}``).
+    """
+
+    HEAD = 256
+    RING = 256
+
+    def __init__(self, t_process: Optional[float] = None):
+        if t_process is None:
+            t_process = _process_start()
+        if t_process is None:
+            import tree_attention_tpu
+
+            t_process = tree_attention_tpu._T_IMPORT
+        self.t_process = t_process
+        self._lock = threading.RLock()  # reentrant: see FlightRecorder
+        self._head: List[List[Any]] = []
+        self._ring: deque = deque(maxlen=self.RING)
+        self._open: List[List[Any]] = []
+        self._seconds = dict.fromkeys(STARTUP_SPANS, 0.0)
+        self._closed = 0        # spans closed so far, kept or not
+        self._traced = 0        # of them, sent to the tracer
+        self._builds: Dict[int, _Build] = {}    # by the building thread
+        self._listening = False
+
+    # -- spans --------------------------------------------------------------
+
+    def begin(self, phase: str, t0: Optional[float] = None,
+              **fields: Any) -> List[Any]:
+        """Open a span of ``phase`` at ``t0`` (now, without one); it shows
+        in :meth:`snapshot` with ``t1`` None until :meth:`end`."""
+        span = [_STARTUP_NAME[phase],
+                time.monotonic() if t0 is None else t0, None, fields]
+        with self._lock:
+            self._open.append(span)
+        return span
+
+    def end(self, span: List[Any], t1: Optional[float] = None,
+            **fields: Any) -> None:
+        """Close ``span`` at ``t1`` (now, without one), with more fields.
+        Ending a span twice, or one this record never began, does
+        nothing."""
+        t1 = time.monotonic() if t1 is None else t1
+        with self._lock:
+            if self._forget(span):
+                span[3].update(fields)
+                span[2] = t1
+                self._keep(span)
+
+    def add(self, phase: str, t0: float, t1: Optional[float] = None,
+            **fields: Any) -> None:
+        """A span that is over: from ``t0`` to ``t1`` (now, without
+        one)."""
+        self._keep([_STARTUP_NAME[phase], t0,
+                    time.monotonic() if t1 is None else t1, fields])
+
+    def _forget(self, span: List[Any]) -> bool:
+        """Take ``span`` (that very list) off the open ones."""
+        with self._lock:
+            for i, held in enumerate(self._open):
+                if held is span:
+                    del self._open[i]
+                    return True
+        return False
+
+    def _keep(self, span: List[Any]) -> None:
+        name, t0, t1, fields = span
+        phase = name[len("startup:"):]
+        with self._lock:
+            if len(self._head) < self.HEAD:
+                self._head.append(span)
+            else:
+                self._ring.append(span)
+            self._closed += 1
+            self._seconds[phase] += t1 - t0
+            seconds = self._seconds[phase]
+        if REGISTRY.enabled:
+            _STARTUP_SECONDS.labels(phase=phase).set(seconds)
+            if phase == "program":
+                _PROGRAMS_BUILT.labels(
+                    program=fields["program"], source="cache"
+                    if fields["from_cache"] else "compiled").inc()
+        if TRACER.active:
+            self._send_new()
+
+    def _send_new(self) -> None:
+        """Every closed span the tracer has not had, as complete events."""
+        with self._lock:
+            spans = self._head + list(self._ring)
+            unsent = min(self._closed - self._traced, len(spans))
+            self._traced = self._closed
+        for name, t0, t1, fields in spans[len(spans) - unsent:]:
+            ts = round(t0 * 1e9) // 1000
+            TRACER._emit_complete(name, "startup", ts,
+                                  round(t1 * 1e9) // 1000 - ts,
+                                  fields or None)
+
+    def publish(self) -> None:
+        """Hand the sinks that were switched on late what closed before
+        them (the import and the backend precede ``obs.configure``): the
+        tracer the spans it has not had, the registry's gauge the phases'
+        seconds. The counter counts from when the registry was enabled."""
+        if TRACER.active:
+            self._send_new()
+        if REGISTRY.enabled:
+            with self._lock:
+                seconds = dict(self._seconds)
+            for phase, s in seconds.items():
+                if s:
+                    _STARTUP_SECONDS.labels(phase=phase).set(s)
+
+    # -- programs -----------------------------------------------------------
+
+    def building(self, program: str, tq: int, tick: Optional[int],
+                 done=None) -> _Build:
+        """A tick program's function is being traced on this thread: open
+        its ``startup:program`` span. The caller sets ``stage = 1`` on
+        what this returns once the traced body is back (or calls
+        :meth:`abandon`); the durations JAX then reports on this thread
+        fill the span's fields, and the backend's closes it and calls
+        ``done(span)``. ONE listener a process, registered here the first
+        time: an event outside a build costs it one dict lookup."""
+        build = _Build(self.begin(
+            "program", program=program, tq=tq, tick=tick, trace_s=0.0,
+            lower_s=0.0, compile_s=0.0, fetch_s=0.0, from_cache=False), done)
+        me = threading.get_ident()
+        with self._lock:
+            listen, self._listening = not self._listening, True
+            # A build this thread left half done (lowered and never
+            # compiled) is forgotten with its span.
+            stale, self._builds[me] = self._builds.get(me), build
+        if stale is not None:
+            self._forget(stale.span)
+        if listen:
+            from jax import monitoring
+
+            monitoring.register_event_duration_secs_listener(self._on_event)
+        return build
+
+    def abandon(self, build: _Build) -> None:
+        """The build came to nothing (the trace raised): drop its span."""
+        self._forget(build.span)
+        with self._lock:
+            if self._builds.get(threading.get_ident()) is build:
+                del self._builds[threading.get_ident()]
+
+    def _on_event(self, event: str, seconds: float, **_: Any) -> None:
+        build = self._builds.get(threading.get_ident())
+        if build is not None:
+            build.take(self, event, seconds)
+
+    def _built(self, build: _Build) -> None:
+        with self._lock:
+            del self._builds[threading.get_ident()]
+        self.end(build.span)
+        if build.done is not None:
+            build.done(build.span)
+
+    # -- export -------------------------------------------------------------
+
+    def snapshot(self) -> Dict[str, Any]:
+        """The record as JSON takes it: ``t_process``, ``spans`` in the
+        order they opened (an open one with ``t1`` None and the fields it
+        has so far), ``seconds`` of the closed ones by phase, and
+        ``dropped``."""
+        with self._lock:
+            spans = self._head + list(self._ring) + [
+                [name, t0, None, dict(fields)]
+                for name, t0, _, fields in self._open]
+            seconds = {p: round(s, 6) for p, s in self._seconds.items() if s}
+            dropped = max(self._closed - self.HEAD - self.RING, 0)
+        spans.sort(key=lambda s: s[1])
+        return {"t_process": self.t_process, "spans": spans,
+                "seconds": seconds, "dropped": dropped}
 
 #: The phases of one ``SlotServer.serve`` tick, in the order the loop runs
 #: them; each lasts until the next mark (ARCHITECTURE.md "Observability").
@@ -57,7 +342,8 @@ _ANNOTATION = {p: "tick:" + p for p in TICK_PHASES}
 class FlightRecorder:
     """Fixed-capacity ring of per-tick records; disarmed until enabled."""
 
-    def __init__(self, capacity: int = DEFAULT_CAPACITY):
+    def __init__(self, capacity: int = DEFAULT_CAPACITY,
+                 startup: Optional["StartupRecord"] = None):
         if capacity < 1:
             raise ValueError(f"capacity must be >= 1, got {capacity}")
         # Reentrant: the SIGTERM/SIGUSR1 flush runs on the main thread and
@@ -71,6 +357,10 @@ class FlightRecorder:
         self._dump_path: Optional[str] = None
         self._programs: List[Dict[str, Any]] = []
         self.enabled = False
+        # The start-up record rides this recorder's dumps and /healthz,
+        # armed or not; clear() leaves it (it is the process's, not a
+        # run's).
+        self.startup = StartupRecord() if startup is None else startup
 
     # -- lifecycle --------------------------------------------------------
 
@@ -162,6 +452,7 @@ class FlightRecorder:
             "last_tick_age_s": None if age is None else round(age, 3),
             "records": records,
             **({"programs": programs} if programs else {}),
+            "startup": self.startup.snapshot(),
         }
 
     def dump(self, path: str, reason: str = "on_demand") -> None:
@@ -190,6 +481,8 @@ class FlightRecorder:
 
 #: The process-wide recorder the serving engine feeds.
 FLIGHT = FlightRecorder()
+#: The process-wide start-up record (the recorder's own).
+STARTUP = FLIGHT.startup
 
 
 class TickPhases:
@@ -261,6 +554,14 @@ class TickPhases:
                                           kind=kind, tq=tq,
                                           ahead=bool(ahead))
         self._open.__enter__()
+
+    def built(self, built: List[Any]) -> None:
+        """The open phase's call built a tick program (the ``dispatch``
+        phase: the engine's ``_program_built``, inside the jit call): say
+        so on its profiler annotation, ``[program, tq, seconds,
+        from_cache]`` as text."""
+        if self.on:
+            self._open.set_metadata(built=str(built))
 
     def _leave(self) -> List[List[Any]]:
         """Switch off until the next :meth:`begin`, leave the open

@@ -13,6 +13,8 @@ dependencies, dies with the process) serving:
   engine is recording ticks (or idle before any tick), 503 once the last
   tick is older than ``stall_after`` — a wedged tick loop fails the check
   even though the HTTP thread still answers (that asymmetry is the point);
+  its ``startup`` field is the start-up record (``obs/flight.py``), there
+  whether or not the ring is armed;
 - ``/flight`` — the flight recorder ring as JSON, the live post-mortem;
 - ``/requests`` — the request ledger (ISSUE 16): live requests with
   their running wall segments plus the bounded ring of recently
@@ -59,6 +61,10 @@ def flight_health(flight: FlightRecorder,
         "ticks_recorded": flight.ticks_recorded,
         "last_tick_age_s": None if age is None else round(age, 3),
         "stall_after_s": stall_after,
+        # What the process did before its first tick and every program it
+        # built since (the start-up record), armed or not: a replica that
+        # came back slowly says here in which phase.
+        "startup": flight.startup.snapshot(),
     }
     if age is None or flight.idle:
         # No tick yet, or the engine drained its run and said so

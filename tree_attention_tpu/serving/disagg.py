@@ -81,7 +81,7 @@ from jax import lax
 from jax.sharding import Mesh
 
 from tree_attention_tpu import obs
-from tree_attention_tpu.obs.flight import FLIGHT
+from tree_attention_tpu.obs.flight import FLIGHT, STARTUP
 from tree_attention_tpu.models.transformer import Params, TransformerConfig
 from tree_attention_tpu.serving.block_pool import (
     BlockAllocator,
@@ -639,6 +639,9 @@ class DisaggServer:
                  dc._spec_verifies)
         pf._defer_gen = -1  # a stale latch must not defer a fresh run
         t0 = time.monotonic()
+        # The start-up record's span of this call (obs/flight.py), as
+        # ``SlotServer.serve`` keeps one: open until the report is built.
+        run_span = STARTUP.begin("serve", t0)
 
         try:
             while True:
@@ -1212,6 +1215,7 @@ class DisaggServer:
                 self.slo.maybe_export(now)
                 tick += 1
         except BaseException as e:
+            STARTUP.end(run_span, ticks=tick)
             FLIGHT.dump_if_armed(f"disagg_error:{type(e).__name__}")
             if obs.TRACER.active:
                 obs.instant("engine_error", cat="serving", args={
@@ -1229,7 +1233,8 @@ class DisaggServer:
         with self._lock:
             self._cancel_uids.clear()
             self._draining = False
-        wall = time.monotonic() - t0
+        t_end = time.monotonic()
+        wall = t_end - t0
         self.slo.export_gauges()
         slo_snap = self.slo.snapshot()
         prefix_snap: Dict[str, Any] = {}
@@ -1299,7 +1304,10 @@ class DisaggServer:
             decode_ticks, tokens / wall if wall > 0 else 0.0,
             occupancy / max(decode_ticks, 1), dc.slots,
         )
-        return ServeReport(
+        run_span[3].update(
+            ticks=tick, prompt_tokens=sum(r.prompt_len for r in results),
+            tokens_generated=tokens)
+        report = ServeReport(
             results=sorted(results, key=lambda r: r.uid),
             ticks=tick,
             wall_s=wall,
@@ -1314,4 +1322,7 @@ class DisaggServer:
             requests=obs.aggregate_ledgers(
                 [r.ledger for r in results if r.ledger is not None]
             ) or {},
+            startup=STARTUP.snapshot(),
         )
+        STARTUP.end(run_span, t_end)
+        return report

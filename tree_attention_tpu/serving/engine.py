@@ -140,7 +140,7 @@ from jax.sharding import Mesh
 
 from tree_attention_tpu import obs
 from tree_attention_tpu.obs import scopes
-from tree_attention_tpu.obs.flight import FLIGHT, TickPhases
+from tree_attention_tpu.obs.flight import FLIGHT, STARTUP, TickPhases
 from tree_attention_tpu.obs.metrics import percentile
 from tree_attention_tpu.obs.slo import SLOMonitor
 from tree_attention_tpu.models.decode import (
@@ -595,6 +595,11 @@ class ServeReport:
     # to the parts of the model (``SlotServer.program_tables``); empty
     # unless the flight recorder or the span tracer was on.
     programs: List[Dict[str, Any]] = dataclasses.field(default_factory=list)
+    # The start-up record as this run ends (``obs/flight.py``
+    # ``StartupRecord.snapshot``): what the process did before its first
+    # tick and every tick program built since, this ``serve()`` call the
+    # one open ``startup:serve`` span.
+    startup: Dict[str, Any] = dataclasses.field(default_factory=dict)
 
     @property
     def tokens_per_sec(self) -> float:
@@ -649,6 +654,7 @@ class ServeReport:
             **({"handoff": self.handoff} if self.handoff else {}),
             **({"request_ledgers": self.requests} if self.requests else {}),
             **({"programs": self.programs} if self.programs else {}),
+            **({"startup": self.startup} if self.startup else {}),
         }
 
 
@@ -809,7 +815,11 @@ def serving_params(params: Params) -> Params:
     comes in; a tree that was here before passes through, so the front ends
     that build several engines from one model (a disaggregated pair, a
     fleet's replicas) re-lay it once and share it. Sets
-    ``serving_weights_relaid_bytes`` to what the tree holds re-laid."""
+    ``serving_weights_relaid_bytes`` to what the tree holds re-laid, and
+    is a ``startup:params`` span of the start-up record with those
+    ``bytes`` (it waits for the tree, so a draw or a placement the caller
+    queued is in it)."""
+    t_enter = time.monotonic()
     # Waited for: an outer leaf the caller drops on return is then free
     # before the pool is made, not whenever the queued transposition ends.
     served = jax.block_until_ready(served_layout(params))
@@ -824,6 +834,7 @@ def serving_params(params: Params) -> Params:
     if jax.tree.structure(served) != jax.tree.structure(params):
         log.info("serving layout: attention input projections re-laid "
                  "out-major, bytes by leaf: %s", held)
+    STARTUP.add("params", t_enter, bytes=sum(held.values()))
     return served
 
 
@@ -1036,7 +1047,9 @@ class SlotServer:
                         f"a model served from the {cfg.cache_kind} pool "
                         f"(TransformerConfig.cache_kind) does not serve "
                         f"with {what}: not built for that pool")
-        self.params = serving_params(params)
+        self.params = serving_params(params)   # a startup:params span
+        # From here to the end of this constructor: ``startup:engine``.
+        t_build = time.monotonic()
         self.cfg = cfg
         self.slots = slots
         self.cache_len = cache_len
@@ -1533,6 +1546,18 @@ class SlotServer:
             "_mixed": self._mixed, "_packed": self._packed,
             "_spec_lin": self._spec_lin, "_spec_tree": self._spec_tree}
         self._compact = jax.jit(self._compact_fn, donate_argnums=(0,))
+        # The start-up record's view of a running loop (:meth:`serve` sets
+        # them, :meth:`_noting` reads them while it traces): the loop's
+        # tick, its phase stamper, and the programs built by ticks whose
+        # flight records are still to be written.
+        self._serve_tick: Optional[Callable[[], int]] = None
+        self._phases: Optional[TickPhases] = None
+        self._built: Dict[int, List[Any]] = {}
+        STARTUP.add(
+            "engine", t_build,
+            pool_bytes=sum(int(leaf.nbytes)
+                           for leaf in jax.tree.leaves(self.cache)),
+            state_pool_bytes=sum(self._state_pool_bytes.values()))
 
     # -- compiled pieces --------------------------------------------------
 
@@ -2081,15 +2106,41 @@ class SlotServer:
         """A tick program's function (``name``: the attribute its jit is
         kept under) that notes each Tq bucket it is traced for. Python runs
         the note only while jit traces a new shape, never when a tick
-        dispatches: knowing which programs exist costs a tick nothing."""
+        dispatches: knowing which programs exist costs a tick nothing.
+        The trace also opens the program's ``startup:program`` span of the
+        start-up record (``obs/flight.py``); JAX's own durations for the
+        dispatch fill and close it (:meth:`_program_built`)."""
         @functools.wraps(fn)
         def traced(*args):
             # Every tick program's second operand is its ``(·, Tq)`` rows.
-            self._tick_programs.setdefault((name, args[1].shape[1]), None)
+            tq = args[1].shape[1]
+            self._tick_programs.setdefault((name, tq), None)
             self._untabled = True
-            return fn(*args)
+            build = STARTUP.building(
+                name, tq,
+                None if self._serve_tick is None else self._serve_tick(),
+                self._program_built)
+            try:
+                out = fn(*args)
+            except BaseException:
+                STARTUP.abandon(build)
+                raise
+            build.stage = 1         # the body is back: JAX's turn
+            return out
 
         return traced
+
+    def _program_built(self, span: List[Any]) -> None:
+        """A tick program's ``startup:program`` span closed, inside the
+        dispatch that built it: put ``built`` (``[program, tq, seconds,
+        from_cache]``) on the open ``tick:dispatch`` annotation and keep it
+        for that tick's flight record."""
+        _, t0, t1, f = span
+        built = [f["program"], f["tq"], round(t1 - t0, 6), f["from_cache"]]
+        if self._phases is not None:
+            self._phases.built(built)
+        if FLIGHT.enabled and f["tick"] is not None:
+            self._built[f["tick"]] = built
 
     def _tick_operands(self, name: str, tq: int) -> Tuple[Any, ...]:
         """The abstract operands of tick program ``name`` at Tq bucket
@@ -2171,9 +2222,13 @@ class SlotServer:
         that built a new program."""
         kinds = {"_mixed": "decode", "_packed": "mixed",
                  "_spec_lin": "verify", "_spec_tree": "verify"}
+        t_first, n_made = None, 0   # a ``startup:tables`` span, if any
         for (name, tq), made in sorted(self._tick_programs.items()):
             if made is not None:
                 continue
+            if t_first is None:
+                t_first = time.monotonic()
+            n_made += 1
             text = self._tick_jits[name].lower(
                 *self._tick_operands(name, tq)).compile().as_text()
             self._tick_programs[name, tq] = {
@@ -2184,6 +2239,8 @@ class SlotServer:
                 "ops": scopes.table(text),
             }
         self._untabled = False
+        if t_first is not None:
+            STARTUP.add("tables", t_first, programs=n_made)
         return [t for _, t in sorted(self._tick_programs.items())
                 if t is not None]
 
@@ -4466,10 +4523,15 @@ class SlotServer:
         host0 = (self._host_pool.stats()
                  if self._host_pool is not None else None)
         t0 = time.monotonic()
+        # A program a tick of this loop builds reads the tick through
+        # ``_serve_tick`` while it is traced (the start-up record's
+        # ``startup:program`` span, obs/flight.py).
+        self._serve_tick = lambda: tick
+        self._built.clear()
         # The tick from inside (obs/flight.py): one stamp per phase
         # boundary, off unless the flight recorder or the span tracer is
         # on. Marks sit BETWEEN the mirror[...] regions below.
-        phases = TickPhases()
+        phases = self._phases = TickPhases()
         tables: List[Dict[str, Any]] = []
         if FLIGHT.enabled or obs.TRACER.active:
             # Before the first tick: describing a program reads its text.
@@ -4851,6 +4913,12 @@ class SlotServer:
                         # Rows that carried a token x conv layers: what
                         # the conv mixers computed.
                         rec["conv_rows"] = p.rows_useful * self._conv_layers
+                if self._built:
+                    # Ticks whose dispatch built a program, their records
+                    # not yet written (:meth:`_program_built`).
+                    built = self._built.pop(p.tick, None)
+                    if built is not None:
+                        rec["built"] = built
                 # finish() stamps t_end as the record is built
                 # and puts the iteration's phases into it.
                 phases.finish(rec)
@@ -4860,6 +4928,9 @@ class SlotServer:
             landing = False
             return t_done
 
+        # The start-up record's span of this call, from ``t0``: open until
+        # the report is built.
+        run_span = STARTUP.begin("serve", t0)
         try:
             while True:
                 if max_ticks is not None and tick >= max_ticks:
@@ -5561,6 +5632,8 @@ class SlotServer:
                     log.exception("could not land tick %d's tail after "
                                   "%s", last.tick, type(e).__name__)
             phases.abandon()
+            self._serve_tick = self._phases = None
+            STARTUP.end(run_span, ticks=tick)
             FLIGHT.dump_if_armed(f"engine_error:{type(e).__name__}")
             if obs.TRACER.active:
                 obs.instant("engine_error", cat="serving", args={
@@ -5590,7 +5663,9 @@ class SlotServer:
             # starting is honored, not wiped.
             self._cancel_uids.clear()
             self._draining = False
-        wall = time.monotonic() - t0
+        t_end = time.monotonic()
+        wall = t_end - t0
+        self._serve_tick = self._phases = None
         # Final SLO publication: the gauges reflect the run's end state and
         # the report carries the windowed snapshot (goodput + percentiles).
         self.slo.export_gauges()
@@ -5700,7 +5775,12 @@ class SlotServer:
             tokens / wall if wall > 0 else 0.0,
             occupancy / max(decode_ticks, 1), self.slots,
         )
-        return ServeReport(
+        # The report holds the start-up record with this call still open
+        # in it (``t1`` None, its fields as they stand); then it closes.
+        run_span[3].update(
+            ticks=tick, prompt_tokens=sum(r.prompt_len for r in results),
+            tokens_generated=tokens)
+        report = ServeReport(
             results=sorted(results, key=lambda r: r.uid),
             ticks=tick,
             wall_s=wall,
@@ -5715,4 +5795,7 @@ class SlotServer:
                 [r.ledger for r in results if r.ledger is not None]
             ) or {},
             programs=tables,
+            startup=STARTUP.snapshot(),
         )
+        STARTUP.end(run_span, t_end)
+        return report
