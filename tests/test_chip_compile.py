@@ -181,6 +181,27 @@ def _ssm_update(slots=64, layers=5, hp=64, n=128, lanes=128, groups=8):
     return fn, args, (0,)
 
 
+def _ssm_scan(tq, slots=64, layers=5, heads=128, d_head=64, n=128, groups=8):
+    """(fn, abstract args, donated) of one ``ssm_chunk_scan`` call over a
+    chunk group of one member of ``tq`` rows at the state configuration's
+    widths (the pool of :func:`_ssm_update`, donated; the two-branch
+    configuration's: 32 heads x 128 in 2 groups over a state of 256)."""
+    from tree_attention_tpu.ops.pallas_ssm import ssm_chunk_scan
+
+    f32, i32 = jnp.float32, jnp.int32
+    hp = heads * d_head // 128
+    args = [_s((layers * slots, hp, n, 128), f32),
+            _s((1, tq, heads * d_head), f32), _s((1, tq, heads), f32),
+            _s((heads,), f32), _s((1, tq, groups * n), f32),
+            _s((1, tq, groups * n), f32), _s((1,), i32), _s((1,), i32),
+            _s((1,), jnp.bool_)]
+
+    def fn(*a):
+        return ssm_chunk_scan(*a, interpret=False)
+
+    return fn, args, (0,)
+
+
 def _conv_tail(slots=64, layers=9, blocks=2560, d=2048):
     """(fn, abstract args, donated) of one ``conv_tail_step`` call at the
     hybrid's widths: nine conv layers' tails of 2,560 blocks x (2 x 2048)
@@ -368,6 +389,14 @@ CASES = {
     "ssm_update_falconh1": (
         lambda: _ssm_update(slots=48, layers=9, hp=32, n=256, groups=2),
         "ssm_decode_update"),
+    # A chunk group's scan (ISSUE 51) at the cells' three chunk lengths.
+    **{f"ssm_scan_nemotron3s_tq{tq}": (
+        functools.partial(_ssm_scan, tq), "ssm_chunk_scan")
+       for tq in (64, 128, 256)},
+    **{f"ssm_scan_falconh1_tq{tq}": (
+        functools.partial(_ssm_scan, tq, slots=48, layers=9, heads=32,
+                          d_head=128, n=256, groups=2), "ssm_chunk_scan")
+       for tq in (64, 128, 256)},
     "paged_decode_falconh1_tq1": (
         lambda: _paged(attention_pallas_decode, 1, **FALCONH1),
         "flash_decode_paged"),
@@ -463,8 +492,29 @@ def test_kernel_compiles_for_v5e(case):
             <= tuning.GROUPED_VMEM_CEILING_BYTES, (q, limit)
         assert f'"size":"{limit}"' in text, limit
         assert "moe_grouped_matmul" not in "moe_ungated_matmul"
+    if kernel == "ssm_chunk_scan":
+        # The pool is aliased through the call, nothing of its size beside
+        # it; the blocks of the rule's choice twice fit the limit the call
+        # asks for; under a name no other kernel's reader matches.
+        fn, args, _ = builder()
+        mem = jax.jit(fn, donate_argnums=(0,)).lower(
+            *args).compile().memory_analysis()
+        pool = math.prod(args[0].shape) * 4
+        assert mem.alias_size_in_bytes >= pool and mem.temp_size_in_bytes \
+            < pool // 64, (mem.alias_size_in_bytes, mem.temp_size_in_bytes)
+        from tree_attention_tpu.ops import tuning
+        (_, hp, n, lanes), tq = args[0].shape, args[1].shape[1]
+        blk = tuning.ssm_scan_block(tq)
+        rows = tuning.ssm_scan_rows(tq, hp * n // args[4].shape[2], n, lanes)
+        limit = tuning.ssm_scan_vmem_limit(tq, blk, rows, n, lanes)
+        assert tuning.ssm_scan_vmem_bytes(tq, blk, rows, n, lanes) < limit \
+            <= tuning.GROUPED_VMEM_CEILING_BYTES, limit
+        assert f'"size":"{limit}"' in text, limit
+        assert not any(other in kernel for other in (
+            "ssm_decode_update", "flash_decode_paged", "moe_grouped_matmul",
+            "moe_ungated_matmul"))
     if "_mistral7b" in case or "_yi6b" in case or "_lfm2" in case \
-            or ("_falconh1" in case and kernel != "ssm_decode_update") \
+            or ("_falconh1" in case and not kernel.startswith("ssm_")) \
             or ("_evabyte" in case and kernel != ROW_WRITE):
         # The pool goes into the call as it is: no copy, slice or change of
         # layout of a pool-sized array before the launch (what a 576-lane
@@ -1142,8 +1192,8 @@ def test_hybrid_step_compiles_and_keeps_the_pools_in_place(tq, packed):
 # layers. The program's own parameter count at the published widths is the
 # configuration file's arithmetic; the compile for the chip copies neither
 # the state, the tails nor the K/V pool: a decode tick's states go through
-# ``ssm_decode_update`` in place, a chunk member's through an in-place
-# update of its one slice.
+# ``ssm_decode_update`` in place, a chunk member's through ``ssm_chunk_scan``
+# in place.
 
 STATE_CONFIG = "nemotron-3-super-120b-a12b"
 
@@ -1175,8 +1225,12 @@ def test_state_step_compiles_and_keeps_the_pools_in_place(tq, packed):
             "ssm_decode_update"} <= set(kernels), kernels
     assert "moe_grouped_matmul" not in kernels, kernels
     if packed:
-        padding = _padding_arrays(text, slots, tq, cfg.vocab_size,
-                                  cfg.d_model)
+        # (The ONE chunk member's rows of heads number 64, as the slots do,
+        # and its chunk 256 rows: the scan kernel's operands are no padded
+        # rows.)
+        padding = [(dt, dims) for dt, dims in _padding_arrays(
+            text, slots, tq, cfg.vocab_size, cfg.d_model)
+            if dims[:2] != (1, 64)]
         assert not padding, padding
     state_layer = slots * 64 * 128 * 128
     tails, kv_layer = 5 * slots * 3 * 10240, blocks * 2 * blk * 128
@@ -1191,9 +1245,7 @@ def test_state_step_compiles_and_keeps_the_pools_in_place(tq, packed):
         if sizes["f32"] >= state_layer:
             # The state: the kernel's aliased output, or the update of a
             # chunk member's one slice in place.
-            if name.startswith("%ssm_decode_update") \
-                    or "dynamic-update-slice(" in inner \
-                    or opcode == "dynamic-update-slice":
+            if name.startswith(("%ssm_decode_update", "%ssm_chunk_scan")):
                 in_place.append(name)
             else:
                 moved.append((name, opcode, result))
@@ -1205,10 +1257,13 @@ def test_state_step_compiles_and_keeps_the_pools_in_place(tq, packed):
             moved.append((name, opcode, result))
     assert not moved, moved
     # Five decode launches a tick (a run of three layers is one loop: one
-    # launch in its body); a chunk member's state written back once a run.
+    # launch in its body); a chunk group's scan likewise one launch a run
+    # (ISSUE 51: the members' states go from and into the pool inside it; no
+    # gather, no scatter, no update of a slice is left of them).
     launches = [n for n in in_place if n.startswith("%ssm_decode_update")]
     assert len(launches) == 3, in_place
     assert len(in_place) - len(launches) == (3 if packed else 0), in_place
+    assert ("ssm_chunk_scan" in kernels) == packed, kernels
     assert _row_writes(text) == 1
     assert tick.alias_bytes >= 4 * 5 * state_layer + 2 * tails \
         + 2 * 2 * kv_layer, tick.alias_bytes
@@ -1255,20 +1310,19 @@ def test_parallel_step_compiles_and_keeps_the_four_pools_in_place(tq, packed):
                                                  result)), default=0)
                  for dt in ("f32", "bf16")}
         if sizes["f32"] >= state_layer:
-            if name.startswith("%ssm_decode_update") \
-                    or "dynamic-update-slice(" in inner \
-                    or opcode == "dynamic-update-slice":
+            if name.startswith(("%ssm_decode_update", "%ssm_chunk_scan")):
                 in_place.append(name)
             else:
                 moved.append((name, opcode, result))
         elif sizes["bf16"] >= min(tails, kv_layer) and opcode == "copy":
             moved.append((name, opcode, result))
     assert not moved, moved
-    # One run of nine layers is one loop: one decode launch in its body, a
-    # chunk member's state written back once.
+    # One run of nine layers is one loop: one decode launch in its body, and
+    # one of the chunk group's scan.
     launches = [n for n in in_place if n.startswith("%ssm_decode_update")]
     assert len(launches) == 1, in_place
     assert len(in_place) - len(launches) == (1 if packed else 0), in_place
+    assert ("ssm_chunk_scan" in kernels) == packed, kernels
     assert _row_writes(text) == 1
     assert tick.alias_bytes >= 4 * 9 * state_layer + 2 * tails \
         + 2 * 2 * 9 * kv_layer, tick.alias_bytes
@@ -1648,6 +1702,9 @@ def test_tick_programs_keep_the_scopes(config, program):
                        else "moe_ungated_matmul"] == scopes.EXPERTS
     if cfg.ssm_layers:
         assert kernels["ssm_decode_update"] == scopes.CONV
+        # A chunk group's scan is one kernel in the same part (ISSUE 51).
+        assert kernels.get("ssm_chunk_scan") == (
+            scopes.CONV if packed else None)
     # The decode group's row a slot reaches the pool inside the write's part.
     assert kernels[ROW_WRITE] == scopes.ATTN_CACHE
 

@@ -19,6 +19,7 @@ on inputs of order 1: 2e-4.
 """
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import os
@@ -39,6 +40,7 @@ from tree_attention_tpu.models.decode import (
 from tree_attention_tpu.models.hybrid import (
     layer_runs,
     pack_state,
+    scan_path,
     ssm_scan,
     ssm_step,
     unpack_state,
@@ -50,9 +52,12 @@ from tree_attention_tpu.models.transformer import (
 from tree_attention_tpu.obs.flight import FLIGHT
 from tree_attention_tpu.ops.pallas_moe import UNGATED_KERNEL, grouped_matmul
 from tree_attention_tpu.ops.pallas_ssm import (
+    SCAN_KERNEL,
     SSM_KERNEL,
+    _ssm_scan_call,
     _ssm_update_call,
     live_list,
+    ssm_chunk_scan,
     ssm_decode_update,
 )
 from tree_attention_tpu.serving import SlotServer
@@ -423,6 +428,208 @@ def test_the_phase_rule_follows_the_shapes_and_fits_its_limit():
         assert q == 1 or need <= tuning.SSM_PHASE_VMEM_BYTES
 
 
+# -- a chunk group's scan as one kernel (ISSUE 51) ---------------------------
+
+# The two served shapes at a small copy, ``(heads, d_head, groups, d_state)``:
+# two heads of 64 side by side on a row's lanes (``pack`` 2, the state
+# configuration's), and a head of 128 a row under a state wider than the row
+# (``pack`` 1, the two-branch configuration's); four rows of heads in two
+# groups both.
+SCAN_SHAPES = {"two_heads_a_row": (8, 64, 2, 16),
+               "a_head_a_row": (4, 128, 2, 256)}
+SCAN_CASES = [(shape, tq) for shape in SCAN_SHAPES for tq in (64, 128, 256)]
+SCAN_SLOTS, SCAN_LAYER = 5, 1
+
+
+@functools.lru_cache(maxsize=None)
+def _scan_case(shape, tq, none=False):
+    """One launch of ``ssm_chunk_scan`` in interpret mode on layer 1 of 3 of
+    a pool of five slots, a chunk group of three members: a whole chunk from
+    the state slot 3 holds; NO row, pointed at slot 3 too (a member that
+    sits a tick out names whatever slot); ``tq - 27`` rows from position 0
+    into slot 0, whose old state is poisoned. ``none``: no member has a row.
+    Returns the arrays a test compares, as numpy's."""
+    H, P, G, N = SCAN_SHAPES[shape]
+    sm = StateSpace(n_heads=H, d_head=P, n_groups=G, d_state=N, taps=4)
+    rng = np.random.default_rng(tq + H)
+    b, S, m = 3, SCAN_SLOTS, SCAN_LAYER
+    n_valid = np.asarray((0, 0, 0) if none else (tq, 0, tq - 27), np.int32)
+    fresh = np.asarray((0, 0, 1), np.int32)
+    home = m * S + np.asarray((3, 3, 0), np.int32)
+    pool = rng.normal(size=(3 * S,) + sm.state_shape).astype(np.float32)
+    pool[home[2]] = np.nan
+    x = rng.normal(size=(b, tq, H, P)).astype(np.float32)
+    B, C = (rng.normal(size=(b, tq, G, N)).astype(np.float32)
+            for _ in range(2))
+    dt = np.exp(rng.uniform(np.log(1e-3), np.log(0.3), (b, tq, H)))
+    valid = np.arange(tq)[None, :] < n_valid[:, None]
+    dt = np.where(valid[..., None], dt, 0.0).astype(np.float32)
+    A = -rng.uniform(1, 16, (H,)).astype(np.float32)
+    new, y = ssm_chunk_scan(
+        *(jnp.asarray(t) for t in (pool, _wide(x), dt, A, _wide(B), _wide(C),
+                                   home, n_valid, fresh)), interpret=True)
+    return dict(sm=sm, pool=pool, new=np.asarray(new),
+                y=np.asarray(y).reshape(x.shape), x=x, dt=dt, A=A, B=B, C=C,
+                home=home, n_valid=n_valid, fresh=fresh)
+
+
+def _wide(t):
+    """``(b, T, heads or groups, width)`` as the projection lays it, the
+    last two axes one: the kernel's operands."""
+    return t.reshape(t.shape[:2] + (-1,))
+
+
+def _scan_start(case):
+    """The states the members start from, unpacked: zeros for a fresh one."""
+    s0 = unpack_state(jnp.asarray(case["pool"][case["home"]]), case["sm"])
+    return jnp.where(jnp.asarray(case["fresh"], bool)[:, None, None, None],
+                     0.0, s0)
+
+
+@pytest.mark.parametrize("shape, tq", SCAN_CASES)
+def test_the_scan_kernel_equals_the_chunked_scan(shape, tq):
+    """``ssm_chunk_scan`` against ``ssm_scan`` from the same states: the
+    valid rows' ``y`` and the state a member with a row leaves, at the
+    tolerance the chunked scan is held to the recurrence at."""
+    case = _scan_case(shape, tq)
+    wy, ws = ssm_scan(*(jnp.asarray(case[k]) for k in "x dt A B C".split()),
+                      _scan_start(case), case["sm"].chunk)
+    for i, n in enumerate(case["n_valid"]):
+        if n:
+            np.testing.assert_allclose(case["y"][i, :n], wy[i, :n],
+                                       atol=2e-4, rtol=1e-4)
+            np.testing.assert_allclose(
+                case["new"][case["home"][i]], pack_state(ws[i], case["sm"]),
+                atol=2e-4, rtol=1e-4)
+    assert SCAN_KERNEL == "ssm_chunk_scan" and not any(
+        other in SCAN_KERNEL for other in (
+            "ssm_decode_update", "flash_decode_paged", "moe_grouped_matmul",
+            "moe_ungated_matmul"))
+
+
+@pytest.mark.parametrize("shape, tq", SCAN_CASES)
+def test_the_scan_kernel_equals_the_recurrence_row_by_row(shape, tq):
+    """... and against ``ssm_step`` taken ``n_valid`` times on the packed
+    state, the rows past a member's count left out of it: what a decode
+    tick a token would have left in the pool."""
+    case = _scan_case(shape, tq)
+    sm = case["sm"]
+    rows = (sm.n_heads // sm.pack, sm.pack * sm.d_head)
+
+    @jax.jit
+    def walk(s, x, dt, B, C):
+        def one(s, t):
+            x, dt, b, c = t
+            a = jnp.repeat(jnp.exp(dt * case["A"]), sm.d_head).reshape(rows)
+            s, y = ssm_step(s[None], (dt[:, None] * x).reshape(rows)[None],
+                            a[None], b[None], c[None])
+            return s[0], y[0]
+        return lax.scan(one, s, (x, dt, B, C))
+
+    start = pack_state(_scan_start(case), sm)
+    for i, n in enumerate(case["n_valid"]):
+        if n:
+            ws, wy = walk(start[i], *(jnp.asarray(case[k][i, :n])
+                                      for k in "x dt B C".split()))
+            np.testing.assert_allclose(
+                case["y"][i, :n].reshape(n, *rows), wy, atol=2e-4, rtol=1e-4)
+            np.testing.assert_allclose(case["new"][case["home"][i]], ws,
+                                       atol=2e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("shape, tq", SCAN_CASES)
+def test_the_scan_kernel_writes_the_members_rows_and_no_other(shape, tq):
+    """Every pool row of another slot or layer holds the bits it held; the
+    member with no row wrote nothing (it names the slot the first member
+    writes: that slot holds the first member's state, once) and its ``y`` is
+    zeros; the member from position 0 read nothing of the poison its slot
+    held."""
+    case = _scan_case(shape, tq)
+    mine = {int(h) for h, n in zip(case["home"], case["n_valid"]) if n}
+    assert len(mine) == 2
+    for row in range(case["pool"].shape[0]):
+        if row not in mine:
+            np.testing.assert_array_equal(case["new"][row],
+                                          case["pool"][row])
+        else:
+            assert np.isfinite(case["new"][row]).all()
+            assert np.abs(case["new"][row] - case["pool"][row]).max() > 1e-3 \
+                or np.isnan(case["pool"][row]).all()
+    assert not case["y"][1].any() and np.isfinite(case["y"]).all()
+
+
+@pytest.mark.parametrize("shape", sorted(SCAN_SHAPES))
+def test_the_scan_kernel_with_no_member_that_has_a_row_changes_nothing(shape):
+    """An empty list: the pool bit for bit (the poison too), ``y`` zeros."""
+    case = _scan_case(shape, 64, none=True)
+    np.testing.assert_array_equal(case["new"], case["pool"])
+    assert not case["y"].any()
+
+
+@pytest.mark.parametrize("block, rows", [(64, 1), (128, 2), (256, 1)])
+def test_the_scan_kernels_blocks_are_tuning_not_mathematics(block, rows):
+    """Any block length and any rows of heads a grid step: the same state
+    and ``y`` as the rule's choice, to rounding."""
+    case = _scan_case("two_heads_a_row", 256)
+    c = case
+    new, y = _ssm_scan_call(
+        *(jnp.asarray(t) for t in (
+            c["pool"], _wide(c["x"]), c["dt"], c["A"], _wide(c["B"]),
+            _wide(c["C"]), c["home"], c["n_valid"], c["fresh"])),
+        interpret=True, block=block, rows=rows)
+    y = np.asarray(y).reshape(case["y"].shape)
+    for i in (0, 2):
+        np.testing.assert_allclose(y[i], case["y"][i], atol=2e-4, rtol=1e-4)
+        np.testing.assert_allclose(new[case["home"][i]],
+                                   case["new"][case["home"][i]],
+                                   atol=2e-4, rtol=1e-4)
+
+
+def test_the_scan_path_follows_what_the_program_observes(monkeypatch):
+    """``scan_path``: the XLA scan off the TPU whatever the shape; on one,
+    the kernel for both served shapes at the cells' three chunk lengths, and
+    the XLA scan for a group it cannot cut (rows no multiple of 8, one row)
+    and a pool it cannot (not float32, a row of heads that is no whole lane
+    tile)."""
+    served = [StateSpace(128, 64, 8, 128, 4), StateSpace(32, 128, 2, 256, 4)]
+    small = StateSpace(8, 16, 2, 16, 4)
+    f32 = lambda sm, dt=jnp.float32: jax.ShapeDtypeStruct(
+        (5, 4) + sm.state_shape, dt)
+    assert small.pack * small.d_head == 64
+    for sm in served:
+        assert all(scan_path(tq, sm, f32(sm)) == "xla"
+                   for tq in (64, 128, 256))                    # a CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for sm in served:
+        assert sm.pack * sm.d_head == 128
+        assert all(scan_path(tq, sm, f32(sm)) == "kernel"
+                   for tq in (8, 64, 128, 256))
+        assert all(scan_path(tq, sm, f32(sm)) == "xla" for tq in (1, 13, 100))
+        assert scan_path(256, sm, f32(sm, jnp.bfloat16)) == "xla"
+    assert scan_path(256, small, f32(small)) == "xla"
+
+
+def test_the_scan_rule_follows_the_shapes_and_fits_its_limit():
+    """``ops/tuning.py`` ``ssm_scan_block`` / ``ssm_scan_rows``: the block
+    is the measured 128 rows (a shorter chunk is one block), the rows of
+    heads a grid step divide a group, and the limit the call asks for holds
+    its blocks twice, under the ceiling the other kernels' plans keep to."""
+    from tree_attention_tpu.ops import tuning
+
+    assert [tuning.ssm_scan_block(t) for t in (8, 64, 128, 256, 512)] \
+        == [8, 64, 128, 128, 128]
+    for hp, n, l, g in ((64, 128, 128, 8), (32, 256, 128, 2),
+                        (4, 16, 128, 2), (4096, 128, 128, 8)):
+        for tq in (64, 128, 256):
+            blk = tuning.ssm_scan_block(tq)
+            r = tuning.ssm_scan_rows(tq, hp // g, n, l)
+            assert (hp // g) % r == 0
+            need = tuning.ssm_scan_vmem_bytes(tq, blk, r, n, l)
+            assert need >= 2 * r * (2 * n * l + 2 * tq * l) * 4
+            assert need < tuning.ssm_scan_vmem_limit(tq, blk, r, n, l) \
+                <= tuning.GROUPED_VMEM_CEILING_BYTES
+
+
 # -- the engine's steps against the reference (a), (d) -----------------------
 
 
@@ -542,14 +749,13 @@ def test_the_controls_the_limits_are_held_against_show(ref, model, fault,
 # -- through SlotServer (a), (c) ---------------------------------------------
 
 
-def test_a_reused_slot_serves_what_a_fresh_engine_serves(ref, model):
-    """Three requests through one slot, one after another (each finds the
-    last one's state and tail in its slot and starts from zero all the
-    same), beside a long one in another slot; prompts that leave a chunk
-    of every size. Every token the reference's greedy choice; the flight
-    record counts a state a live slot a state-space layer in decode ticks;
-    nothing leaked."""
-    w, weights, tcfg, params = model
+@pytest.fixture(scope="module")
+def reused(model):
+    """Three requests through one slot, one after another, beside a long one
+    in another slot, with the flight recorder and the registry on: the
+    engine, the prompts, its report, the flight records and the registry's
+    text."""
+    _, _, tcfg, params = model
     rng = np.random.default_rng(9)
     prompts = [rng.integers(0, 128, (n,)).tolist() for n in (21, 9, 13, 30)]
     FLIGHT.clear()
@@ -562,14 +768,26 @@ def test_a_reused_slot_serves_what_a_fresh_engine_serves(ref, model):
             Request(uid=1, prompt=prompts[0], max_new_tokens=6),
             Request(uid=2, prompt=prompts[1], max_new_tokens=7),
             Request(uid=3, prompt=prompts[2], max_new_tokens=5)])
-        recs = [r for r in FLIGHT.snapshot()["records"]
-                if "ssm_states_advanced" in r]
+        recs = FLIGHT.snapshot()["records"]
         text = obs.REGISTRY.to_prometheus()
     finally:
         FLIGHT.disarm()
         FLIGHT.clear()
         obs.REGISTRY.disable()
         obs.REGISTRY.reset()
+    return eng, prompts, rep, recs, text
+
+
+def test_a_reused_slot_serves_what_a_fresh_engine_serves(ref, model, reused):
+    """Three requests through one slot, one after another (each finds the
+    last one's state and tail in its slot and starts from zero all the
+    same), beside a long one in another slot; prompts that leave a chunk
+    of every size. Every token the reference's greedy choice; the flight
+    record counts a state a live slot a state-space layer in decode ticks;
+    nothing leaked."""
+    w, weights, tcfg, params = model
+    eng, prompts, rep, recs, text = reused
+    recs = [r for r in recs if "ssm_states_advanced" in r]
     by_uid = {r.uid: r.tokens for r in rep.results}
     assert by_uid[0] == _greedy(ref, weights, w, prompts[3], 40)
     for uid, p, n in ((1, 0, 6), (2, 1, 7), (3, 2, 5)):
@@ -590,6 +808,82 @@ def test_a_reused_slot_serves_what_a_fresh_engine_serves(ref, model):
     assert 'cache="paged_state"' in text
     leak = eng.leak_report()
     assert leak["blocks_used"] == 0 == leak["blocks_reserved"]
+
+
+def test_a_tick_with_a_chunk_counts_its_scan_rows_by_their_path(
+        reused, monkeypatch):
+    """The flight record of a tick that carries a chunk: the chunk's rows
+    times the three state-space layers, under the scan they took (on a CPU
+    all ``xla``; the two fields sum to the rows on any backend), nothing in
+    a tick with no chunk; the registry's counter follows. The count is the
+    host's, from the rows it packed and ``scan_path``'s answer: steered to
+    the kernel, the same rows stand under ``kernel``."""
+    from tree_attention_tpu.serving import engine as engine_mod
+
+    eng, prompts, _, recs, text = reused
+    assert eng._ssm_layers == 3
+    for r in recs:
+        assert r["scan_rows_kernel"] + r["scan_rows_xla"] \
+            == 3 * r["chunk_tokens"]
+        assert r["scan_rows_kernel"] == 0                       # a CPU
+    rows = sum(r["scan_rows_xla"] for r in recs)
+    assert rows == 3 * sum(len(p) for p in prompts)
+    assert f'ssm_scan_rows_total{{path="xla"}} {rows}\n' in text
+    assert 'ssm_scan_rows_total{path="kernel"} 0\n' in text
+    n_vec = np.asarray([1, 0])
+    assert eng._count_scan_rows(8, n_vec, ([0], [5])) == (0, 15)
+    assert eng._count_scan_rows(1, n_vec, None) == (0, 0)
+    monkeypatch.setattr(engine_mod, "scan_path", lambda *a: "kernel")
+    assert eng._count_scan_rows(8, n_vec, ([0], [5])) == (15, 0)
+    assert eng._count_scan_rows(8, np.asarray([3, 4]), None) == (21, 0)
+
+
+def test_a_packed_tick_through_the_scan_kernel_serves_the_xla_scans_rows(
+        model, monkeypatch):
+    """``forward_packed_step`` with the chunk group's scan steered to the
+    kernel (``scan_path``, as on a TPU; interpret mode) against the XLA
+    scan, tick by tick from the same cache: a chunk of two of the kernel's
+    blocks from position 0 into a slot whose old state is poisoned, ragged
+    chunks after it, a decode row beside them, a tick whose chunk member has
+    NO row. The logits and the state pool agree to the rounding of two
+    orders of one float32 sum; a slot with no row holds its bits."""
+    from tree_attention_tpu.models import decode, hybrid
+
+    _, _, tcfg, params = model
+    rng = np.random.default_rng(12)
+    toks = [rng.integers(0, 128, (60,)), rng.integers(0, 128, (20,))]
+    cache = _cache(tcfg, 2)
+    cache = dataclasses.replace(
+        cache, ssm_state=cache.ssm_state.at[:, 0].set(jnp.nan))
+
+    def tick(params, chunk, slot, n, dec, dn, cache):
+        return decode.forward_packed_step(
+            params, chunk, slot, n, dec, dn, cache, tcfg)
+
+    by_xla = jax.jit(tick)
+    assert hybrid.scan_path(WIDTH, tcfg.ssm, cache.ssm_state) == "xla"
+    monkeypatch.setattr(hybrid, "scan_path", lambda *a: "kernel")
+    by_kernel = jax.jit(tick)
+    pos = 0
+    for n, dn in ((16, 0), (13, 1), (0, 1), (16, 1), (5, 0)):
+        chunk = np.zeros((1, WIDTH), np.int32)
+        chunk[0, :n] = toks[0][pos:pos + n]
+        args = (jnp.asarray(chunk), jnp.asarray([0], jnp.int32),
+                jnp.asarray([n], jnp.int32),
+                jnp.asarray([0, toks[1][pos % 20]], jnp.int32),
+                jnp.asarray([0, dn], jnp.int32))
+        got, kcache = by_kernel(params, *args, cache)
+        logits, cache = by_xla(params, *args, cache)
+        served = np.asarray([n > 0, dn > 0])
+        np.testing.assert_allclose(np.asarray(got)[served],
+                                   np.asarray(logits)[served], atol=ATOL)
+        np.testing.assert_allclose(np.asarray(kcache.ssm_state),
+                                   np.asarray(cache.ssm_state), atol=ATOL)
+        np.testing.assert_array_equal(np.asarray(kcache.ssm_tail),
+                                      np.asarray(cache.ssm_tail))
+        pos += n
+    assert int(cache.length[0]) == 50 and np.isfinite(
+        np.asarray(cache.ssm_state)).all()
 
 
 def test_model_config_serves_the_family_on_its_own_weights(tmp_path):

@@ -7,6 +7,7 @@ Prints one JSON line per measurement; the winners go into
     python tools/tune_sweep.py fwd      # training fwd kernel (bq, bk) sweep
     python tools/tune_sweep.py bwd      # fwd+bwd through the custom VJP
     python tools/tune_sweep.py --grouped  # the grouped expert product's plans
+    python tools/tune_sweep.py --scan     # a chunk group's scan: kernel and XLA
 
 Uses the slope-timing protocol (utils.profiling.slope_per_step, min-stat
 over repeated cycles), so fixed per-call costs cancel and each cell
@@ -14,6 +15,7 @@ carries its own spread.
 """
 
 import dataclasses
+import functools
 import json
 import os
 import sys
@@ -443,6 +445,155 @@ def sweep_grouped(only=None, calls=None):
     log.close()
 
 
+# The state-space cells' (name, slots, layers, heads, d_head, groups, d_state):
+# the two shapes ``ssm_chunk_scan`` serves.
+SCAN_SHAPES = (
+    ("nemotron-3-super-120b-a12b", 64, 5, 128, 64, 8, 128),
+    ("falcon-h1-34b-instruct", 48, 9, 32, 128, 2, 256),
+)
+
+
+def sweep_scan(only=None, calls=16):
+    """One JSON line a (configuration, chunk length, path): a chunk group
+    of ONE member through ``calls`` layers in a loop, as a mixed tick makes
+    them. ``xla`` is the branch ``ssm_branch`` takes off the TPU (the
+    member's state gathered and unpacked, ``ssm_scan``, packed and scattered
+    back); the others are ``ssm_chunk_scan`` at a block length and rows of
+    heads a grid step (``rule``: ``ops/tuning.py``'s choice). ``us``: a
+    layer by the host's clock, best of 6, the launch's own XLA operations
+    (the per-row vectors) and the loop's (``dt`` moved a layer, ``y``
+    masked and summed: not a cell's) included; ``kernel_us``: the mean
+    device time of the kernel's events in one traced run, the number
+    ``ops/tuning.py``'s table holds; ``max_diff`` from the XLA path's state
+    and ``y``. Also written to ``chiprun_out/scan_sweep.jsonl``."""
+    import shutil
+
+    import numpy as np
+
+    from tree_attention_tpu.models.hybrid import (
+        pack_state, ssm_scan, unpack_state)
+    from tree_attention_tpu.models.transformer import StateSpace
+    from tree_attention_tpu.ops import tuning
+    from tree_attention_tpu.ops.pallas_ssm import SCAN_KERNEL, _ssm_scan_call
+
+    interpret = jax.default_backend() != "tpu"   # (a rehearsal off the chip)
+    os.makedirs("chiprun_out", exist_ok=True)
+    log = open("chiprun_out/scan_sweep.jsonl", "a")
+    trace_dir = "chiprun_out/.scan_sweep_trace"
+    quiet = jax.profiler.ProfileOptions()
+    quiet.python_tracer_level = 0
+    quiet.host_tracer_level = 0
+
+    def emit(rec):
+        line = json.dumps(rec)
+        print(line, flush=True)
+        log.write(line + "\n")
+        log.flush()
+
+    for name, slots, layers, H, P, G, N in SCAN_SHAPES:
+        if only and only not in name:
+            continue
+        sm = StateSpace(n_heads=H, d_head=P, n_groups=G, d_state=N, taps=4)
+        per = H // sm.pack // G
+        rng = np.random.default_rng(0)
+        pool = jnp.asarray(rng.normal(
+            size=(layers * slots,) + sm.state_shape), jnp.float32)
+        A = jnp.asarray(-rng.uniform(1, 16, (H,)), jnp.float32)
+        for T in (256, 128, 64):
+            x = jnp.asarray(rng.normal(size=(1, T, H, P)), jnp.float32)
+            B, C = (jnp.asarray(rng.normal(size=(1, T, G, N)), jnp.float32)
+                    for _ in range(2))
+            dt = jnp.asarray(np.exp(rng.uniform(
+                np.log(1e-3), np.log(0.1), (1, T, H))), jnp.float32)
+            slot = jnp.asarray([slots // 3], jnp.int32)
+            n_valid = jnp.asarray([T - 5], jnp.int32)
+            dt = jnp.where(jnp.arange(T)[None, :, None] < n_valid[0], dt, 0.0)
+
+            def xla(pool, dt, m):
+                at = m * slots + slot
+                s0 = unpack_state(pool[at], sm)
+                y, s1 = ssm_scan(x, dt, A, B, C, s0, sm.chunk)
+                return pool.at[at].set(pack_state(s1, sm)), y
+
+            wide = [t.reshape(1, T, -1) for t in (x, B, C)]
+
+            def kernel(block, rows):
+                def step(pool, dt, m):
+                    pool, y = _ssm_scan_call(
+                        pool, wide[0], dt, A, *wide[1:], m * slots + slot,
+                        n_valid, jnp.zeros((1,), jnp.int32),
+                        interpret=interpret, block=block, rows=rows)
+                    return pool, y.reshape(x.shape)
+                return step
+
+            paths = [("xla", xla), ("rule", kernel(None, None))] + [
+                (f"b{block}_r{rows}", kernel(block, rows))
+                for block in (64, 128, 256) if block <= T
+                for rows in (1, 2, 4) if per % rows == 0]
+
+            def build(step):
+                @functools.partial(jax.jit, donate_argnums=(0,))
+                def program(pool):
+                    def body(i, carry):
+                        pool, acc = carry
+                        # dt moves a layer: nothing of the scan is lifted
+                        # out of the loop.
+                        pool, y = step(pool, dt * (1 + 1e-3 * i), i % layers)
+                        return pool, acc + y[0, :8, 0, :8]
+                    return lax.fori_loop(
+                        0, calls, body,
+                        (pool, jnp.zeros((8, min(P, 8)), jnp.float32)))
+                return program
+
+            # Every path from the same pool first (the timed programs move
+            # it), then the clocks.
+            checked, first = [], None
+            for label, step in paths:
+                rec = {"kernel": SCAN_KERNEL, "config": name, "tq": T,
+                       "path": label, "calls": calls}
+                if label == "rule":
+                    rec["block"] = tuning.ssm_scan_block(T)
+                    rec["rows"] = tuning.ssm_scan_rows(
+                        T, per, N, sm.pack * P)
+                try:
+                    new, y = jax.jit(step)(pool, dt, 1)
+                    got = (np.asarray(new[slots:2 * slots]), np.asarray(y))
+                    first = got if first is None else first
+                    rec["max_diff"] = [float(np.abs(g - f).max())
+                                       for g, f in zip(got, first)]
+                    del new, y, got
+                    checked.append((rec, build(step)))
+                except Exception as e:
+                    rec["error"] = f"{type(e).__name__}: {e}"[:300]
+                    emit(rec)
+            ran = []
+            for rec, program in checked:
+                best = float("inf")
+                for _ in range(6):
+                    t0 = time.perf_counter()
+                    pool, acc = program(pool)
+                    jax.block_until_ready(acc)
+                    best = min(best, time.perf_counter() - t0)
+                rec["us"] = round(best / calls * 1e6, 1)
+                ran.append((rec, program))
+            shutil.rmtree(trace_dir, ignore_errors=True)
+            jax.profiler.start_trace(trace_dir, profiler_options=quiet)
+            for _, program in ran:
+                pool, acc = program(pool)
+                jax.block_until_ready(acc)
+            jax.profiler.stop_trace()
+            events = _kernel_events(trace_dir, SCAN_KERNEL)
+            mine = [r for r, _ in ran if r["path"] != "xla"]
+            for j, rec in enumerate(mine):
+                if len(events) == calls * len(mine):
+                    part = events[calls * j:calls * (j + 1)]
+                    rec["kernel_us"] = round(sum(part) / calls * 1e6, 1)
+            for rec, _ in ran:
+                emit(rec)
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    log.close()
+
+
 if __name__ == "__main__":
     from tree_attention_tpu import obs
 
@@ -453,6 +604,7 @@ if __name__ == "__main__":
     try:
         {"decode": sweep_decode, "fwd": sweep_fwd,
          "bwd": lambda: sweep_fwd(bwd=True),
-         "grouped": lambda: sweep_grouped(*sys.argv[2:3])}[mode]()
+         "grouped": lambda: sweep_grouped(*sys.argv[2:3]),
+         "scan": lambda: sweep_scan(*sys.argv[2:3])}[mode]()
     finally:
         obs.shutdown()

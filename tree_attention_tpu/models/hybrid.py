@@ -319,6 +319,26 @@ def ssm_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
     return y[:, :T], s
 
 
+def scan_path(tq: int, sm: StateSpace, state: Any) -> str:
+    """Which of the two scans a chunk group of ``tq`` rows a member takes:
+    ``"kernel"`` (``ops/pallas_ssm.py`` ``ssm_chunk_scan``: one launch a
+    layer, from and into the state pool as it lies) on a TPU, where the
+    kernel can cut this pool (``state``: the pool or its shape and type;
+    float32, a row of heads and ``d_state`` whole lane tiles, at most four
+    heads a row: their ``cum`` and ``dt`` share a tile's eight sublanes)
+    and the group (8 divides its rows); else ``"xla"`` (:func:`ssm_scan`
+    between a gather and a scatter of the members' states), which is also
+    the kernel's oracle. One algorithm whose path follows what the program
+    observes, the same for every model, as
+    :func:`~.decode.pool_write_path`."""
+    from tree_attention_tpu.ops import _on_tpu, _pallas_available
+
+    cuts = state.dtype == jnp.float32 and sm.pack <= 4 \
+        and not (sm.pack * sm.d_head) % 128 and not sm.d_state % 128 \
+        and tq > 1 and not tq % 8
+    return "kernel" if cuts and _on_tpu() and _pallas_available() else "xla"
+
+
 def ssm_step(state: jax.Array, x: jax.Array, a: jax.Array, b: jax.Array,
              c: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """One token's update of packed states ``(batch, Hp, N, L)``: what
@@ -360,10 +380,17 @@ def ssm_branch(layer: Params, h: jax.Array, state: jax.Array,
     A **decode group** (one row a slot) takes the single-step recurrence:
     on a TPU in place, through ``ssm_decode_update`` over the list of slots
     that have a row (``g.live``). A **chunk group** runs the chunked scan
-    (:func:`ssm_scan`) from each member's state and puts the state it
-    leaves back. Three rules: a member whose first position is 0 starts
-    from a zero state and a zero tail, whatever the pools hold; a row past
-    a member's valid count has ``Δ = 0`` and no input, so it leaves the
+    from each member's state and puts the state it leaves back: on a TPU
+    one launch of ``ops/pallas_ssm.py`` ``ssm_chunk_scan``, from and into
+    the pool as it lies (the scan's ``(T, T, heads)`` intermediates and the
+    states' two changes of layout never reach HBM: ISSUE 51); where the
+    kernel cannot cut the pool or the group, and off the TPU,
+    :func:`ssm_scan` between a gather and a scatter of the members' states
+    (:func:`scan_path` says which, from the shapes alone; both are the one
+    recurrence in float32 at the highest precision, and the tests hold the
+    kernel to the scan). Three rules: a member whose first position is 0
+    starts from a zero state and a zero tail, whatever the pools hold; a row
+    past a member's valid count has ``Δ = 0`` and no input, so it leaves the
     state bit for bit; a member with no row writes nothing (and a decode
     slot with no row is not read either). The state, ``Δ``, ``a`` and ``S .
     C`` in float32, the projections in the served type. ``W_in``'s output
@@ -371,7 +398,9 @@ def ssm_branch(layer: Params, h: jax.Array, state: jax.Array,
     convolution's bias and ``dt_bias`` (:func:`_in_multipliers`). Returns
     what the mixer adds to the residual, the two pools, and how many states
     were written."""
-    from tree_attention_tpu.ops.pallas_ssm import ssm_decode_update
+    from tree_attention_tpu.ops.pallas_ssm import (
+        ssm_chunk_scan, ssm_decode_update,
+    )
 
     sm = cfg.ssm
     H, P, G, N = sm.n_heads, sm.d_head, sm.n_groups, sm.d_state
@@ -421,10 +450,12 @@ def ssm_branch(layer: Params, h: jax.Array, state: jax.Array,
                         pre, keep[:, :, None], axis=1).reshape(g.batch, -1)
                 conv = jax.nn.silu(conv + bias)
                 flat_t = flat_t.at[to].set(left, mode="drop")
-                xs = conv[..., :inner].reshape(g.batch, g.tq, H, P)
-                Bm = conv[..., inner:inner + G * N].reshape(
-                    g.batch, g.tq, G, N)
-                Cm = conv[..., inner + G * N:].reshape(g.batch, g.tq, G, N)
+                # [x | B | C] as the convolution lays them, and by head and
+                # by group.
+                wide = jnp.split(conv, [inner, inner + G * N], axis=-1)
+                xs = wide[0].reshape(g.batch, g.tq, H, P)
+                Bm = wide[1].reshape(g.batch, g.tq, G, N)
+                Cm = wide[2].reshape(g.batch, g.tq, G, N)
                 dts = jax.nn.softplus(
                     g.take(dt[:, None])[:, 0].astype(jnp.float32)
                     + layer["dt_bias"].astype(jnp.float32))
@@ -449,11 +480,17 @@ def ssm_branch(layer: Params, h: jax.Array, state: jax.Array,
                     y = y.reshape(g.batch, 1, H, P)
             else:
                 with jax.named_scope(scopes.SSM_SCAN):
-                    s0 = jnp.where(fresh[:, None, None, None], 0.0,
-                                   unpack_state(flat_s[at], sm))
-                    y, s1 = ssm_scan(xs, dts, A, Bm, Cm, s0, sm.chunk)
-                    flat_s = flat_s.at[to].set(pack_state(s1, sm),
-                                               mode="drop")
+                    if scan_path(g.tq, sm, state) == "kernel":
+                        flat_s, y = ssm_chunk_scan(
+                            flat_s, wide[0], dts, A, *wide[1:], at,
+                            g.n_valid, fresh)
+                        y = y.reshape(xs.shape)
+                    else:
+                        s0 = jnp.where(fresh[:, None, None, None], 0.0,
+                                       unpack_state(flat_s[at], sm))
+                        y, s1 = ssm_scan(xs, dts, A, Bm, Cm, s0, sm.chunk)
+                        flat_s = flat_s.at[to].set(pack_state(s1, sm),
+                                                   mode="drop")
             with jax.named_scope(scopes.SSM_NORM):
                 y = y + layer["D"].astype(jnp.float32)[:, None] * xs
                 y = y.reshape(g.batch, g.tq, inner) * jax.nn.silu(

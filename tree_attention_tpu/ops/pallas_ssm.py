@@ -1,5 +1,6 @@
-"""A state-space layer's decode step over the slots that have a row, in
-place (Pallas TPU).
+"""A state-space layer's two steps on the state pool, in place (Pallas TPU):
+the decode step over the slots that have a row (``ssm_decode_update``), and a
+chunk group's scan (``ssm_chunk_scan``, below the decode step).
 
 A state-space (Mamba-2) layer holds, for every slot, a state ``S`` of ``heads
 x d_head x d_state`` float32 that EVERY token rewrites whole::
@@ -37,8 +38,40 @@ turned to columns, sixteen rows a group). A slot's state of a layer is the
 same 4,194,304 B at both, so the phase rule gives both four slots a phase, and
 both run at 85% of the memory's pace (PERF.md, PRs 45 and 47).
 
-Off the TPU the layer body takes the same step in ``jax.numpy``
-(``models/hybrid.py``); the tests hold this kernel to it in interpret mode.
+A **chunk group** (a prompt's next ``tq`` rows a member) takes the chunked
+form of the same recurrence, blocks of ``T`` rows with the state carried block
+to block (``models/hybrid.py`` ``ssm_scan`` is the form in ``jax.numpy``)::
+
+    cum_l = sum_{r <= l} dt_r A                 (inside the block)
+    y_l   = sum_{s <= l} exp(cum_l - cum_s) (C_l . B_s) dt_s x_s
+            + exp(cum_l) C_l . S
+    S    <- exp(cum_T) S + sum_s exp(cum_T - cum_s) B_s (x) dt_s x_s
+
+As XLA operations that is a ``(T, T, heads)`` float32 array made three times
+over in HBM, ``B`` and ``C`` repeated to every head, and the members' states
+gathered, turned out of the pool's layout, turned back and scattered: ~150 MB
+a layer at the first shape beside 0.05 ms of products (ISSUE 51). The pool's
+layout IS the operand both state products want (``C (T, N) . S (N, L)`` and
+``B^T (N, T) . (.) (T, L)``), so ``ssm_chunk_scan`` is a grid over (member, a
+few rows of heads) whose blocks the pipeline moves: the rows' states from the
+pool and back into it (the pool aliased to the output, a member's block found
+through scalar prefetch), their ``x`` in and ``y`` out and the group's ``B``
+and ``C`` (fetched once a group) as the convolution lays them, ``(T, heads x
+d_head)`` and ``(T, groups x d_state)``: no operand changes its layout on its
+way in or out (the first form of this kernel took ``dt x`` and ``B^T`` turned
+by XLA and gave ``y`` back turned: those copies cost as much as the kernel
+saved, ``ops/tuning.py``). ``B^T`` and ``C . B^T`` are formed once a group
+into scratch; a head's ``(T, T)`` decays are made in registers from its
+``cum`` and ``dt`` as a row and as a column, the only operands XLA forms
+before the launch (kilobytes). A member with no row is not visited (its
+steps point at the last visited block and do nothing); a member whose first
+position is 0 starts from zeros whatever the pool holds. Every product is
+float32 at ``Precision.HIGHEST``, as the XLA form's. ``ops/tuning.py`` has
+the block length, the rows a step and the sweep they come from.
+
+Off the TPU the layer body takes the same steps in ``jax.numpy``
+(``models/hybrid.py`` ``ssm_step`` / ``ssm_scan``); the tests hold both
+kernels to them in interpret mode.
 """
 
 from __future__ import annotations
@@ -56,11 +89,13 @@ from tree_attention_tpu import obs
 from tree_attention_tpu.ops import tuning
 
 SSM_KERNEL = "ssm_decode_update"
+SCAN_KERNEL = "ssm_chunk_scan"
+_HI = lax.Precision.HIGHEST
 
 _KERNEL_BUILDS = obs.counter(
     "pallas_ssm_kernel_builds_total",
-    "state-space decode-update kernel program builds (one per distinct "
-    "shape)",
+    "state-space kernel program builds (one per distinct shape), by kernel: "
+    "ssm_decode_update, ssm_chunk_scan",
     labels=("kernel",),
 )
 
@@ -261,3 +296,248 @@ def _ssm_update_call(state, x, a, b, c, ids, count, base, *,
         interpret=interpret,
         name=SSM_KERNEL,
     )(ids, count, base, x, a, b, c, state)
+
+
+# ---------------------------------------------------------------------------
+# A chunk group's scan
+# ---------------------------------------------------------------------------
+
+
+def _dot(a, b):
+    """Float32 at the highest precision: what is added to a state is never
+    rounded below it."""
+    return jnp.dot(a, b, precision=_HI, preferred_element_type=jnp.float32)
+
+
+def _ssm_scan_kernel(
+    ids_ref,    # SMEM (b,) scalar-prefetch: the members that have a row
+    cnt_ref,    # SMEM (1,) scalar-prefetch: how many
+    home_ref,   # SMEM (b,) scalar-prefetch: a member's row of the pool
+    fresh_ref,  # SMEM (b,) scalar-prefetch: 1 where its first position is 0
+    x_ref,      # VMEM (1, T, R x L): x as the projection lays it, R rows of
+                # `pack` heads
+    hd_ref,     # VMEM (1, R, 8, T): a head a sublane, the first `pack`
+                # cumsum(dt * A) inside a block, the next `pack` dt
+    b_ref,      # VMEM (1, T, N): the group's B
+    c_ref,      # VMEM (1, T, N): the group's C
+    s_ref,      # VMEM (1, R, N, L): the member's state, these rows of heads
+    o_ref,      # ... and where it goes back (aliased to the pool)
+    y_ref,      # VMEM (1, T, R x L)
+    bt_ref,     # VMEM scratch (blocks, N, Tb): the group's B^T a block
+    cb_ref,     # VMEM scratch (blocks, Tb, Tb): the group's C . B^T a block
+    *,
+    block: int,
+    pack: int,
+    steps: int,
+):
+    """One grid step: ``R`` rows of heads of one member through every block
+    of the chunk, the state carried in registers and fast memory. ``steps``
+    grid steps make a group; its first turns ``B`` and forms ``C . B^T``
+    for the rest."""
+    i, q = pl.program_id(0), pl.program_id(1)
+    cnt = cnt_ref[0]
+    _, R, N, L = s_ref.shape
+    T = x_ref.shape[1]
+    Tb = block
+    head = L // pack
+    blocks = [slice(j * Tb, (j + 1) * Tb) for j in range(T // Tb)]
+
+    # No member has a row: the one block every step was pointed at goes
+    # back as it came.
+    @pl.when(cnt == 0)
+    def _():
+        o_ref[...] = s_ref[...]
+
+    @pl.when(i < cnt)
+    def _():
+        fresh = fresh_ref[ids_ref[i]] != 0
+
+        @pl.when(q % steps == 0)
+        def _():
+            for j, at in enumerate(blocks):
+                bt_ref[j] = b_ref[0, at, :].T
+                cb_ref[j] = _dot(c_ref[0, at, :], bt_ref[j])
+
+        tri = lax.broadcasted_iota(jnp.int32, (Tb, Tb), 0) \
+            >= lax.broadcasted_iota(jnp.int32, (Tb, Tb), 1)
+        lane = lax.broadcasted_iota(jnp.int32, (1, L), 1) // head
+        bottom = lax.broadcasted_iota(jnp.int32, (8, L), 0) == 7
+
+        def over_lanes(cols):
+            """Each head's value over the head's own lanes: ``pack``
+            columns ``(rows, 1)`` as ``(rows, L)``."""
+            out = jnp.broadcast_to(cols[0], (cols[0].shape[0], L))
+            for k in range(1, pack):
+                out = jnp.where(lane >= k, cols[k], out)
+            return out
+
+        for r in range(R):
+            lanes = slice(r * L, (r + 1) * L)
+            # A fresh member starts from zeros, whatever the pool holds.
+            s = jnp.where(fresh, 0.0, s_ref[0, r])            # (N, L)
+            across = hd_ref[0, r]                             # (8, T)
+            down = across.T                                   # (T, 8)
+            for j, at in enumerate(blocks):
+                x = x_ref[0, at, lanes]                       # (Tb, L)
+                y = None
+                into, left = [], []
+                for k in range(pack):
+                    cum = down[at, k:k + 1]                   # (Tb, 1)
+                    dt = down[at, pack + k:pack + k + 1]
+                    # Row l sees row s <= l through exp(cum_l - cum_s), and
+                    # takes dt_s x_s of it.
+                    w = jnp.exp(jnp.where(
+                        tri, cum - across[k:k + 1, at], -jnp.inf)) \
+                        * (cb_ref[j] * across[pack + k:pack + k + 1, at])
+                    mine = x if pack == 1 else jnp.where(lane == k, x, 0.0)
+                    part = _dot(w, mine)
+                    y = part if y is None else y + part
+                    into.append(jnp.exp(cum))
+                    left.append(jnp.exp(cum[Tb - 1:Tb, :] - cum) * dt)
+                # From the state the block started with; the state it
+                # leaves.
+                seen = over_lanes(into)                       # exp(cum_l)
+                y_ref[0, at, lanes] = y + seen * _dot(c_ref[0, at, :], s)
+                # exp(cum) of the block's last row, a head over its lanes
+                # (a sum over a tile's sublanes: Mosaic has no broadcast
+                # of one element over both).
+                whole = jnp.sum(jnp.where(bottom, seen[Tb - 8:Tb, :], 0.0),
+                                axis=0, keepdims=True)        # (1, L)
+                s = whole * s + _dot(bt_ref[j], over_lanes(left) * x)
+            o_ref[0, r] = s
+
+
+def ssm_chunk_scan(
+    state: jax.Array,
+    x: jax.Array,
+    dt: jax.Array,
+    A: jax.Array,
+    B: jax.Array,
+    C: jax.Array,
+    home: jax.Array,
+    n_valid: jax.Array,
+    fresh: jax.Array,
+    *,
+    interpret: Optional[bool] = None,
+) -> Tuple[jax.Array, jax.Array]:
+    """A chunk group's rows through one state-space layer, in place: what
+    ``models/hybrid.py`` ``ssm_scan`` computes, from and into the pool as it
+    lies and from the rows as the projection lays them.
+
+    ``state`` is the pool ``(layers x S, Hp, N, L)`` float32; member ``i``'s
+    state is row ``home[i]``. ``x`` ``(b, T, H x P)`` (head ``h`` on lanes
+    ``[h P, (h + 1) P)``), ``dt`` ``(b, T, H)`` (0: the row leaves the state
+    as it is and adds nothing), ``A`` ``(H,)``, ``B`` / ``C`` ``(b, T, G x
+    N)`` (a group's ``N`` side by side), all float32: :func:`ssm_scan`'s
+    operands with their last two axes as one, which is how they lie in
+    memory (a view of ``(T, H, P)`` with ``P`` under a lane tile is another
+    tiling: a copy). A member with ``fresh`` starts from zeros whatever the
+    pool holds; one with ``n_valid`` 0 is not visited. Returns the pool
+    (the buffer that came in, under a donating ``jit``) and ``y`` ``(b, T,
+    H x P)``, zero for a member not visited. The device event is
+    ``ssm_chunk_scan``."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    return _ssm_scan_call(state, x, dt, A, B, C, home, n_valid, fresh,
+                          interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "block", "rows"))
+def _ssm_scan_call(state, x, dt, A, B, C, home, n_valid, fresh, *,
+                   interpret: bool, block: Optional[int] = None,
+                   rows: Optional[int] = None):
+    M, Hp, N, L = state.shape
+    b, T, H = dt.shape
+    pack, G = H // max(Hp, 1), B.shape[2] // N
+    if H != Hp * pack or x.shape != (b, T, Hp * L) or not 1 <= pack <= 4 \
+            or L % pack or B.shape != (b, T, G * N) or C.shape != B.shape \
+            or G < 1 or Hp % G \
+            or any(t.dtype != jnp.float32 for t in (state, x, dt, B, C)):
+        raise ValueError(
+            f"ssm_chunk_scan takes a float32 pool (layers x S, Hp, N, L), x "
+            f"(b, T, Hp x L), dt (b, T, H) with H = pack x Hp and pack <= "
+            f"4, B and C (b, T, G x N) with G dividing Hp; got "
+            f"{[(t.shape, t.dtype) for t in (state, x, dt, B, C)]}")
+    if obs.REGISTRY.enabled:
+        _KERNEL_BUILDS.labels(kernel=SCAN_KERNEL).inc()
+    per = Hp // G
+    blk = block or tuning.ssm_scan_block(T)
+    R = rows or tuning.ssm_scan_rows(T, per, N, L)
+    if per % R:
+        raise ValueError(f"{R} rows a step do not divide a group's {per}")
+    pad = -T % blk
+    if pad:
+        # Rows with dt 0: they move no state and their y is dropped.
+        x, dt, B, C = (jnp.pad(t, ((0, 0), (0, pad), (0, 0)))
+                       for t in (x, dt, B, C))
+    Tp = T + pad
+    # The per-row vectors, formed here: kilobytes. Everything else goes in
+    # as the projections lay it.
+    cum = jnp.cumsum((dt * A).reshape(b, Tp // blk, blk, H), axis=2)
+    hd = jnp.stack([cum.reshape(b, Tp, Hp, pack),
+                    dt.reshape(b, Tp, Hp, pack)], axis=3)    # cum, then dt
+    hd = jnp.moveaxis(hd.reshape(b, Tp, Hp, 2 * pack), 1, 3)
+    hd = jnp.pad(hd, ((0, 0), (0, 0), (0, 8 - 2 * pack), (0, 0)))
+    ids, count = live_list(n_valid)
+    ids = jnp.asarray(ids, jnp.int32)
+    count = jnp.asarray(count, jnp.int32)
+    nq = Hp // R
+
+    def where(i, q, ids, cnt):
+        """The member and the rows of heads of step ``(i, q)``: past the
+        list, the last step's again (no block moves, nothing is written
+        twice)."""
+        dead = i >= cnt[0]
+        return (ids[jnp.minimum(i, jnp.maximum(cnt[0] - 1, 0))],
+                jnp.where(dead, nq - 1, q))
+
+    def lanes_of(i, q, ids, cnt, home, fresh):
+        j, q = where(i, q, ids, cnt)
+        return j, 0, q
+
+    def rows_of(i, q, ids, cnt, home, fresh):
+        j, q = where(i, q, ids, cnt)
+        return j, q, 0, 0
+
+    def group_of(i, q, ids, cnt, home, fresh):
+        j, q = where(i, q, ids, cnt)
+        return j, 0, q * R // per
+
+    def state_of(i, q, ids, cnt, home, fresh):
+        j, q = where(i, q, ids, cnt)
+        return home[j], q, 0, 0
+
+    f32 = jnp.float32
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(b, nq),
+        in_specs=[
+            pl.BlockSpec((1, Tp, R * L), lanes_of),
+            pl.BlockSpec((1, R, 8, Tp), rows_of),
+            pl.BlockSpec((1, Tp, N), group_of),
+            pl.BlockSpec((1, Tp, N), group_of),
+            pl.BlockSpec((1, R, N, L), state_of),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, R, N, L), state_of),
+            pl.BlockSpec((1, Tp, R * L), lanes_of),
+        ],
+        scratch_shapes=[pltpu.VMEM((Tp // blk, N, blk), f32),
+                        pltpu.VMEM((Tp // blk, blk, blk), f32)],
+    )
+    new, y = pl.pallas_call(
+        functools.partial(_ssm_scan_kernel, block=blk, pack=pack,
+                          steps=per // R),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
+                   jax.ShapeDtypeStruct((b, Tp, Hp * L), f32)],
+        input_output_aliases={8: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=tuning.ssm_scan_vmem_limit(Tp, blk, R, N, L),
+        ),
+        interpret=interpret,
+        name=SCAN_KERNEL,
+    )(ids, count, jnp.asarray(home, jnp.int32),
+      jnp.asarray(fresh, jnp.int32), x, hd, B, C, state)
+    return new, jnp.where((n_valid > 0)[:, None, None], y[:, :T], 0.0)

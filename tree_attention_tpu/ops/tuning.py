@@ -646,6 +646,99 @@ def ssm_phase_vmem_limit(slots: int, hp: int, n: int, l: int, g: int) -> int:
 
 
 # ---------------------------------------------------------------------------
+# A chunk group's scan (ops/pallas_ssm.py ssm_chunk_scan)
+# ---------------------------------------------------------------------------
+
+# Measured on v5e 2026-10-05 (ISSUE 51; `tools/tune_sweep.py --scan`, two
+# sweeps, `chiprun_out/scan_sweep*.jsonl`): a chunk group of ONE member through
+# 16 layers in a loop at the two served shapes `(Hp, N, L, G)`; microseconds
+# of ONE launch, the kernel's own device events in a traced run. (The sweep's
+# host-clock column also times the loop's other operations, which are not the
+# cell's; the cells' readings are in PERF.md, PR 51.)
+#
+#   rows of the chunk              256     128      64
+#   (64, 128, 128, 8)  `ssm_scan` in XLA, whole branch, host clock
+#                                 156.5   132.6   118.5
+#     block 128, 4 rows a step     89.0    50.8      -
+#     block 128, 2 / 1 rows        95.6 / 114.6    58.0 / 75.6
+#     block 64,  4 rows           114.6    62.5    36.6
+#     block 256, 4 rows           121.6      -       -
+#   (32, 256, 128, 2)  `ssm_scan` in XLA
+#                                 117.9   106.8   104.1
+#     block 128, 4 rows a step     54.4    28.0      -
+#     block 128, 2 / 1 rows        58.1 / 68.5     30.4 / 35.9
+#     block 64,  4 rows            84.4    42.9    22.2
+#     block 256, 4 rows            60.5      -       -
+#
+# The products alone, six bf16 passes at 197 TFLOP/s, are 67 us at the first
+# shape (13.3 GFLOP of passes: with two heads a row the product inside the
+# block runs once a head over all 128 lanes, the other head's zeroed) and 42
+# at the second: the launch reads 75% and 77% of that. What lost, so that
+# nobody retries it: blocks of 64 rows (half the MXU's depth a product, and
+# lane slices of `B^T` off the tiles); blocks of 256 at two heads a row (the
+# masked half of a `(256, 256)` product is computed: twice the work inside
+# the block; at a head a row it ties, 60.5 against 54.4); one row of heads a
+# grid step (a step's fixed cost, and `C . B^T` waits on fewer rows); AND THE
+# FIRST FORM OF THE KERNEL, which took `dt x` as `(Hp, T, L)`, `B^T` and `C`
+# a group turned by XLA, and gave `y` back as `(Hp, T, L)`: 98.2 / 61.6 us a
+# launch at 256 rows, but the five 8 MB changes of layout round it cost
+# ~50 us a layer in the cell, as much as it saved (`nemotron3s_agentturn_sat`
+# `tbt_p99_ms` 22.17 -> 21.85 where the form below reads 21.67). The kernel
+# now takes `x`, `B`, `C` and gives `y` as the convolution lays them, rows of
+# heads a lane block, and XLA forms only `cumsum(dt A)` and `dt` a head as
+# rows (seven operations on 128 KB, 7-12 us a launch together). Not tried: the
+# state kept turned `(L, N)` in fast memory so that `C^T` / `B` stay in the
+# MXU while every row of a group streams through (two more transposes a row
+# and block; the products already run at three quarters of their bound).
+SSM_SCAN_BLOCK = 128
+# What a grid step's blocks may weigh (the rows' states in and out, their
+# ``x`` in and ``y`` out): the pipeline holds them twice. Four rows of heads
+# fit at both served shapes and a 256-row chunk (1.6 and 2.1 MB).
+SSM_SCAN_STEP_BYTES = 5 << 19
+
+
+def ssm_scan_block(t: int) -> int:
+    """Rows a block of ``ssm_chunk_scan`` over a chunk of ``t`` rows: the
+    measured :data:`SSM_SCAN_BLOCK`; a shorter chunk is one block."""
+    return min(t, SSM_SCAN_BLOCK)
+
+
+def ssm_scan_step_bytes(t: int, rows: int, n: int, l: int) -> int:
+    """What one grid step of ``rows`` rows of heads moves: their states in
+    and out ``(n, l)``, ``x`` in and ``y`` out ``(t, l)``, the heads'
+    ``cum`` and ``dt`` ``(8, t)``."""
+    return rows * (2 * _tiles(n, l) + 2 * _tiles(t, l) + _tiles(8, t))
+
+
+def ssm_scan_rows(t: int, per: int, n: int, l: int) -> int:
+    """Rows of heads a grid step of ``ssm_chunk_scan`` takes, worked out
+    from the shapes the call sees: the most of 4, 2, 1 (the body is
+    unrolled over them) that divide a group's ``per`` rows (a step never
+    straddles two groups' ``B`` / ``C``) and whose blocks fit
+    :data:`SSM_SCAN_STEP_BYTES`; one where none does."""
+    return next((r for r in (4, 2, 1)
+                 if per % r == 0
+                 and ssm_scan_step_bytes(t, r, n, l) <= SSM_SCAN_STEP_BYTES),
+                1)
+
+
+def ssm_scan_vmem_bytes(t: int, block: int, rows: int, n: int, l: int) -> int:
+    """Fast memory ``ssm_chunk_scan``'s blocks take: a step's blocks and the
+    group's ``B`` and ``C`` ``(t, n)`` twice (the pipeline fetches a step
+    ahead), and the scratch: ``B^T`` ``(n, t)`` and ``C . B^T`` a block
+    ``(block, block)``."""
+    return 2 * (ssm_scan_step_bytes(t, rows, n, l) + 2 * _tiles(t, n)) \
+        + _tiles(n, t) + t // block * _tiles(block, block)
+
+
+def ssm_scan_vmem_limit(t: int, block: int, rows: int, n: int, l: int) -> int:
+    """``vmem_limit_bytes`` of the call: the blocks and 8 MB for a row's
+    values in flight (a head's ``(block, block)`` decays, the products'
+    results) and what the compiler adds."""
+    return ssm_scan_vmem_bytes(t, block, rows, n, l) + (8 << 20)
+
+
+# ---------------------------------------------------------------------------
 # The grouped expert product's blocks (ops/pallas_moe.py)
 # ---------------------------------------------------------------------------
 

@@ -187,7 +187,7 @@ from tree_attention_tpu.serving.speculation import (
     pack_proposal,
     pack_siblings,
 )
-from tree_attention_tpu.models.hybrid import tail_write_path
+from tree_attention_tpu.models.hybrid import scan_path, tail_write_path
 from tree_attention_tpu.models.transformer import (
     GQA_SERVED,
     LATENT_SERVED,
@@ -277,6 +277,14 @@ _SSM_STATES = obs.counter(
     "serving_ssm_states_advanced_total",
     "per-slot recurrent states the state-space layers wrote (slots with a "
     "row x state-space layers)",
+)
+_SCAN_ROWS = obs.counter(
+    "ssm_scan_rows_total",
+    "rows the state-space layers' chunk scans took in (a tick's chunk rows "
+    "x state-space layers), by the scan they took: kernel (one launch of "
+    "ssm_chunk_scan a layer, in place on the state pool) or xla (ssm_scan "
+    "between a gather and a scatter of the members' states)",
+    labels=("path",),
 )
 # A step's counters that ride the tick's fetch below the slots' rows, in
 # this order (``models/decode.py`` ``forward_step``'s ``stats``).
@@ -546,6 +554,9 @@ class _Tail:
     # Slots with one row whose conv tails take the row kernel
     # (``_count_tail_rows``).
     tail_rows: int = 0
+    # Chunk rows x state-space layers: (through the scan kernel, through
+    # the XLA scan) (``_count_scan_rows``).
+    scan_rows: Tuple[int, int] = (0, 0)
     # The head's per-tick counters, frozen when the tail is left pending
     # (the iteration that lands it has counted its own by then).
     counts: Optional[Dict[str, Any]] = None
@@ -1313,6 +1324,11 @@ class SlotServer:
             self.cache.tail.shape, self.cache.tail.dtype) \
             if cfg.conv_layers else None
         self._ssm_layers = cfg.ssm_layers     # the state pool's depth
+        # ... and its shape and dtype, for the scan a tick's chunk rows take
+        # (``_count_scan_rows``).
+        self._state_pool = jax.ShapeDtypeStruct(
+            self.cache.ssm_state.shape, self.cache.ssm_state.dtype) \
+            if cfg.ssm_layers else None
         self._eva_layers = cfg.eva_layers     # both EVA pools' depth
         # A state pool's per-slot arrays in bytes, by field name; empty for
         # every other cache: the report's ``kv.state_pool_bytes``.
@@ -1629,6 +1645,28 @@ class SlotServer:
                 1 if chunk is not None else tq, self._tail_pool) != "row":
             return 0
         return int(n_vec.sum())
+
+    def _count_scan_rows(self, tq, n_vec, chunk) -> Tuple[int, int]:
+        """Rows of the program being dispatched that go through the
+        state-space layers' chunked scan, times those layers:
+        ``(scan_rows_kernel, scan_rows_xla)`` of its flight record, by the
+        scan a group of ``tq`` rows takes (``models/hybrid.py``
+        ``scan_path``). A packed program's chunk group's rows, or every row
+        of a padded program of more than one row a slot; a group of one row
+        a slot takes the single-step update and counts nothing here. Counted
+        from the rows the host packed, as :meth:`_count_pool_rows` counts
+        the K/V rows."""
+        if not self._ssm_layers or tq == 1:
+            return (0, 0)
+        rows = int(np.sum(chunk[1])) if chunk is not None \
+            else int(n_vec.sum())
+        by = {"kernel": 0, "xla": 0}
+        by[scan_path(tq, self.cfg.ssm, self._state_pool)] = \
+            rows * self._ssm_layers
+        if obs.REGISTRY.enabled:
+            for path, n in by.items():
+                _SCAN_ROWS.labels(path=path).inc(n)
+        return by["kernel"], by["xla"]
 
     def _account_expert_rows(self, extra: np.ndarray) -> Dict[str, int]:
         """Read the expert layers' row counts off the tick's fetch into
@@ -4882,6 +4920,11 @@ class SlotServer:
                     "pending": p.pending,
                     "draining": p.draining,
                 }
+                if self._ssm_layers:
+                    # Chunk rows x state-space layers, by the scan they
+                    # took (``_count_scan_rows``).
+                    rec["scan_rows_kernel"], rec["scan_rows_xla"] = \
+                        p.scan_rows
                 if not p.ahead:
                     rec["sync_reason"] = p.why
                 rec.update(p.counts if p.counts is not None
@@ -5587,6 +5630,10 @@ class SlotServer:
                         tail_rows=(
                             0 if n_vec is None
                             else self._count_tail_rows(
+                                tick_tq, n_vec, kv_chunk)),
+                        scan_rows=(
+                            (0, 0) if n_vec is None
+                            else self._count_scan_rows(
                                 tick_tq, n_vec, kv_chunk)),
                     )
                     primed = True
