@@ -1484,6 +1484,96 @@ def phase_parallel(s: Sizes, config: Optional[Dict[str, Any]] = None, *,
         device=device, block=block, chunk=chunk, tol_gap=tol_gap)
 
 
+def phase_decoder_hybrid(s: Sizes, config: Optional[Dict[str, Any]] = None,
+                         *, device: str = "tpu", block: int = 64,
+                         chunk: int = 256, tol_gap: float = 0.4
+                         ) -> Dict[str, Any]:
+    """A decoder that feeds a second decoder
+    (``benchmark/configs/phi-4-mini-flash-reasoning.json``: Mamba-1 states
+    and window-512 rows below the seam, ONE full-attention layer whose rows
+    the cross layers read again, gated memory units, differential attention,
+    at the published widths and a REDUCED depth: 8 of 32 layers, every kind
+    of layer at least once), served on one chip through
+    ``cli.build_serve_engine`` and ``SlotServer.serve`` with the reference's
+    seeded weights, from a cache that is a state, a window and full rows at
+    once. In an engine of ONE slot: a request through a chunked prompt longer
+    than the window (two whole chunks and a rest of 77 rows: the prompt's
+    rows leave the stack at the seam, the window layers give blocks back)
+    and 48 decoded tokens; then a second in the slot it leaves (it finds the
+    last request's states, window rows and shared rows there and starts from
+    zero all the same). Every served token is held to the plain reference's
+    logits (``benchmark/references/phi4flash.py``: the token-by-token
+    recurrence, differential attention as its definition, every layer on
+    every row) as :func:`phase_state` holds its own."""
+    import numpy as np
+
+    from benchmark import check as served
+    from tree_attention_tpu import cli
+    from tree_attention_tpu.serving.engine import Request
+    from tree_attention_tpu.utils.config import parse_args
+
+    config = config or dict(
+        _benchmark_config("phi-4-mini-flash-reasoning.json"),
+        num_hidden_layers=8)
+    spec = _benchmark_spec()
+    ref = spec.load_module("references", config["family"] + ".py")
+    adapter = spec.load_module("adapters", config["family"] + ".py")
+    w = ref.Widths.of(config)
+    weights = ref.init_weights(3, w)
+    rng = np.random.default_rng(29)
+    vocab = int(config["vocab_size"])
+    a = rng.integers(0, vocab, (2 * chunk + (chunk * 77 // 256 or 1),)).tolist()
+    b = rng.integers(0, vocab, (block + block // 3,)).tolist()
+    new_a, new = 48, block // 4 + 4
+    flags = ["--mode", "serve", "--device", device,
+             "--dtype", str(config["torch_dtype"]), "--slots", "1",
+             "--prompt-len", str(len(a)), "--prompt-jitter", "0",
+             "--max-new-tokens", str(new_a), "--prefill-chunk", str(chunk),
+             "--prefix-block", str(block),
+             "--temperature", "0", "--seed", "1"]
+    setup = cli.build_serve_engine(
+        parse_args(flags), None, model=config,
+        params=adapter.engine_params(weights, w))
+    check(setup.tcfg.cache_kind == "state_window" and setup.tcfg.row_cut,
+          "the model caches a state, a window and shared rows, and cuts its "
+          "rows at the seam")
+    check(len(a) > w.window, "the prompt is longer than the window")
+    server = setup.make_engine()
+    report = server.serve([Request(uid=0, prompt=a, max_new_tokens=new_a),
+                           Request(uid=1, prompt=b, max_new_tokens=new)])
+    results = list(report.results)
+    prompts = {0: a, 1: b}
+    check(len(results) == 2 and all(r.outcome == "budget" for r in results),
+          "two requests served to their budgets through one slot")
+    leak = server.leak_report()
+    check(not (leak["blocks_used"] or leak["blocks_private"]
+               or leak["blocks_reserved"] or leak["pins"]),
+          f"no block leaked ({leak})")
+    check(report.kv["window_blocks_freed"] > 0
+          and report.kv["window_blocks_used"] == 0,
+          "the window layers gave the blocks behind the window back")
+    gaps = [served.served_gaps(ref, weights, w, np.asarray(prompts[r.uid]),
+                              np.asarray(r.tokens))[0] for r in results]
+    means = [float(g.mean()) for g in gaps]
+    check(max(means) <= tol_gap,
+          f"a request's served tokens lie {max(means):.3f} under the "
+          f"reference's best on average (limit {tol_gap}; {means})")
+    cache = server.cache
+    return {
+        "layers": list(setup.tcfg.layer_types),
+        "row_cut": setup.tcfg.row_cut,
+        "shared_rows": list(cache.k.shape),
+        "window_rows": list(cache.wk.shape),
+        "ssm_state": list(cache.ssm_state.shape),
+        "ssm_state_dtype": str(cache.ssm_state.dtype),
+        "window_blocks_freed": report.kv["window_blocks_freed"],
+        "tokens_compared": int(sum(len(g) for g in gaps)),
+        "gap_max": float(max(g.max() for g in gaps)),
+        "gap_mean": float(np.concatenate(gaps).mean()),
+        "gap_mean_by_request": means,
+    }
+
+
 def _benchmark_spec():
     from benchmark.spec import Spec
 
@@ -1689,6 +1779,7 @@ def run_default(run: Run, s: Sizes) -> None:
     run.phase("state", phase_state, s)
     run.phase("eva", phase_eva, s)
     run.phase("parallel", phase_parallel, s)
+    run.phase("decoder_hybrid", phase_decoder_hybrid, s)
 
 
 def run_four_chips(run: Run, s: Sizes) -> None:

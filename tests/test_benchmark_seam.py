@@ -61,14 +61,22 @@ SETUP_METRICS = ["setup_import_s", "setup_engine_s", "setup_programs_s",
                  "setup_unseen_s", "programs_built_in_window"]
 
 
+# What PR 52 appended after those, for its own cell.
+DECODER_HYBRID_METRICS = ["ssm1_scan_ms_tick", "ssm1_scan_roofline",
+                          "ssm1_states_advanced_pct", "rows_past_exit_pct"]
+
+
 def before_setup(entries):
     """A per-layer list (a cell's or the file's; entries or their names)
-    without PR 50's seven, which close every list: the older PRs' tails
-    are counted from what is left (``tests/test_startup_record.py`` holds
-    the seven themselves)."""
-    tail = [m["name"] if isinstance(m, dict) else m
-            for m in entries[-len(SETUP_METRICS):]]
-    assert tail == SETUP_METRICS
+    without PR 50's seven, which close every list but for PR 52's four
+    after them (the file's, and its own cell's): the older PRs' tails are
+    counted from what is left (``tests/test_startup_record.py`` holds the
+    seven themselves)."""
+    names = [m["name"] if isinstance(m, dict) else m for m in entries]
+    if names[-len(DECODER_HYBRID_METRICS):] == DECODER_HYBRID_METRICS:
+        entries = entries[:-len(DECODER_HYBRID_METRICS)]
+        names = names[:-len(DECODER_HYBRID_METRICS)]
+    assert names[-len(SETUP_METRICS):] == SETUP_METRICS
     return entries[:-len(SETUP_METRICS)]
 # What PR 38 appended last, for its own cell.
 WINDOW_METRICS = ["window_attn_ms_tick", "window_decode_paged_roofline",
@@ -86,6 +94,7 @@ EVA_METRICS = ["eva_local_ms_tick", "eva_local_decode_roofline",
 EVA_CELL = "evabyte_bytedoc_sat"
 # PR 47 appended no metric: its cell joins the lists of the metrics it reports.
 FH_CELL = "falconh1_agentturn_sat"
+PF_CELL = "phi4flash_reasoning_long_sat"
 
 
 def _sources(but=()):
@@ -512,7 +521,7 @@ def test_the_hybrid_cell_resolves_every_file_it_names():
     # The traffic file that was there, and the cell the sixth of six.
     assert cell.traffic == lc.traffic and cell.traffic["kind"] == "backlog"
     assert [w["name"] for w in spec.data["workloads"]][5:] == [
-        LFM_CELL, KX_CELL, NS_CELL, EVA_CELL, FH_CELL]
+        LFM_CELL, KX_CELL, NS_CELL, EVA_CELL, FH_CELL, PF_CELL]
     assert all(w["chips"] == 1 for w in spec.data["workloads"])
     assert [m["name"] for m in cell.end_to_end] == [
         "out_tok_s", "tbt_p50_ms", "tbt_p99_ms", "setup_s"]
@@ -724,12 +733,13 @@ def test_a_parts_metric_is_listed_where_its_part_exists(name):
     elif "_conv_" in name:
         # The part ``conv``: a mixer with a fixed-size state (the short
         # convolution; a state-space mixer between its projections).
-        want = [LFM_CELL, NS_CELL, FH_CELL]
+        want = [LFM_CELL, NS_CELL, FH_CELL, PF_CELL]
     if name.startswith("mix_"):
         # The window cell's traced 3 s hold a chunk tick in most runs and
         # none in some (1.5 a second, in clusters): a reader that finds
-        # nothing to read there is not listed there (PERF.md section 7).
-        want = [c for c in want if c != KX_CELL]
+        # nothing to read there is not listed there (PERF.md section 7);
+        # nor is the decoder-hybrid cell, on the same traffic.
+        want = [c for c in want if c not in (KX_CELL, PF_CELL)]
     assert entry == {
         "name": name, "unit": "%" if name.endswith("_pct") else "ms",
         "better": "lower", "source": "device_trace",
@@ -774,7 +784,7 @@ def test_paged_steps_run_pct_reads_the_lists_the_kernels_walk():
         "source": "program_counter", "layer": "kernels",
         "moves": "tbt_p50_ms", "workloads": cells}
     for cell in cells:
-        last = -1 - len(WINDOW_METRICS) * (cell == KX_CELL) \
+        last = -1 - len(WINDOW_METRICS) * (cell in (KX_CELL, PF_CELL)) \
             - len(STATE_METRICS) * (cell == NS_CELL) \
             - (1 + len(EVA_METRICS)) * (cell == EVA_CELL) \
             - 3 * (cell == FH_CELL)     # the kernel's two and the pool's
@@ -820,7 +830,7 @@ def test_the_window_cell_resolves_every_file_it_names():
     assert cell.traffic["kind"] == "backlog"
     assert spec.find("traffic", "reasoning_long_backlog.json")
     assert [w["name"] for w in spec.data["workloads"]][6:] == [
-        KX_CELL, NS_CELL, EVA_CELL, FH_CELL]
+        KX_CELL, NS_CELL, EVA_CELL, FH_CELL, PF_CELL]
     assert all(w["chips"] == 1 for w in spec.data["workloads"])
     assert [m["name"] for m in cell.end_to_end] == [
         "out_tok_s", "tbt_p50_ms", "tbt_p99_ms", "setup_s"]
@@ -849,7 +859,7 @@ def test_the_window_cell_resolves_every_file_it_names():
     # its exact rows lie under the same ledger, by another rule.)
     for name in WINDOW_METRICS:
         assert listed[name]["workloads"] == [KX_CELL] + [EVA_CELL] * (
-            name == "window_blocks_held_pct")
+            name == "window_blocks_held_pct") + [PF_CELL]
     assert (listed["window_attn_ms_tick"]["layer"],
             listed["window_attn_ms_tick"]["moves"],
             listed["window_attn_ms_tick"]["source"]) == (
@@ -1031,7 +1041,7 @@ def test_the_state_cell_resolves_every_file_it_names():
     # on four chips.
     assert cell.traffic == lc.traffic and cell.traffic["kind"] == "backlog"
     assert [w["name"] for w in spec.data["workloads"]][7:] == [
-        NS_CELL, EVA_CELL, FH_CELL]
+        NS_CELL, EVA_CELL, FH_CELL, PF_CELL]
     assert all(w["chips"] == 1 for w in spec.data["workloads"])
     assert [m["name"] for m in cell.end_to_end] == [
         "out_tok_s", "tbt_p50_ms", "tbt_p99_ms", "setup_s"]
@@ -1228,7 +1238,7 @@ def test_the_eva_cell_resolves_every_file_it_names():
     assert cell.traffic["kind"] == "backlog"
     assert spec.find("traffic", "bytedoc_backlog.json")
     assert [w["name"] for w in spec.data["workloads"]][8:] == [
-        EVA_CELL, FH_CELL]
+        EVA_CELL, FH_CELL, PF_CELL]
     assert all(w["chips"] == 1 for w in spec.data["workloads"])
     assert [m["name"] for m in cell.end_to_end] == [
         "out_tok_s", "tbt_p50_ms", "tbt_p99_ms", "setup_s"]
@@ -1444,7 +1454,8 @@ def test_the_two_branch_cell_resolves_every_file_it_names():
     # The traffic file that was there, the cell the tenth of ten, none on
     # four chips; no metric, cost file, reader or mix of its own.
     assert cell.traffic == ns.traffic and cell.traffic["kind"] == "backlog"
-    assert [w["name"] for w in spec.data["workloads"]][9:] == [FH_CELL]
+    assert [w["name"] for w in spec.data["workloads"]][9:] == [
+        FH_CELL, PF_CELL]
     assert all(w["chips"] == 1 for w in spec.data["workloads"])
     assert [m["name"] for m in cell.end_to_end] == [
         "out_tok_s", "tbt_p50_ms", "tbt_p99_ms", "setup_s"]
@@ -1557,3 +1568,199 @@ def test_the_two_branch_adapter_refuses_another_model_at_once():
     import types
     with pytest.raises(SpecError, match="cannot express"):
         adapter._hold_to_file(types.SimpleNamespace(ssm=None), cell.config)
+
+
+# -- a decoder that feeds a second decoder: the decoder-hybrid cell (ISSUE 52)
+
+
+def test_the_decoder_hybrid_cell_resolves_every_file_it_names():
+    spec = Spec(BENCH)
+    cell, kx = spec.cell(PF_CELL), spec.cell(KX_CELL)
+    assert cell.chips == 1 and cell.config["family"] == "phi4flash"
+    assert cell.config["name"] == "phi-4-mini-flash-reasoning"
+    for d, mod in (("references", cell.reference()),
+                   ("adapters", cell.adapter())):
+        assert mod.__file__.endswith(os.path.join(d, "phi4flash.py"))
+    with open(cell.reference().__file__) as f:
+        text = f.read()
+    body = text.split('"""', 2)[2]
+    assert "tree_attention_tpu" not in body and "benchmark" not in body
+    assert "lax.scan(token" in text       # the recurrence, token by token
+    assert "jax.nn.softmax(jnp.where(see" in text   # one dense softmax a head
+    assert "_pack" not in body            # the definition, no packed row
+    assert cell.reference().CONTROLS == (
+        "int8", "no_diff", "own_rows", "stale_memory", "scalar_decay")
+    # The traffic file that was there, the cell the eleventh of eleven, none
+    # on four chips; one cost file and four readers of its own.
+    assert cell.traffic == kx.traffic and cell.traffic["kind"] == "backlog"
+    assert [w["name"] for w in spec.data["workloads"]][10:] == [PF_CELL]
+    assert all(w["chips"] == 1 for w in spec.data["workloads"])
+    assert [m["name"] for m in cell.end_to_end] == [
+        "out_tok_s", "tbt_p50_ms", "tbt_p99_ms", "setup_s"]
+    names = [m["name"] for m in cell.per_layer]
+    assert names[-4:] == DECODER_HYBRID_METRICS
+    assert [m["name"] for m in spec.data["per_layer"]][-4:] \
+        == DECODER_HYBRID_METRICS
+    for m in spec.data["per_layer"][-4:]:
+        assert m["workloads"] == [PF_CELL]
+        assert m["moves"] == ("tbt_p99_ms" if m["name"]
+                              == "rows_past_exit_pct" else "tbt_p50_ms")
+    for name in names:
+        assert spec.load_module("layer_metrics", name + ".py").read
+    for name in ("occupancy_pct", "kv_blocks_peak_pct", "hbm_peak_gb",
+                 "tick_rows_useful_pct", "paged_steps_run_pct",
+                 "tick_unscoped_pct", "attn_kernel_ms_tick",
+                 "flash_decode_paged_roofline", "window_attn_ms_tick",
+                 "window_decode_paged_roofline", "window_blocks_held_pct",
+                 "device_idle_pct", "decode_tick_p50_ms",
+                 "mixed_tick_p50_ms", "tick_ahead_pct") + tuple(
+            n for n in PARTS_ALL if n.startswith("dec_")
+            and "_moe_" not in n) + tuple(SETUP_METRICS):
+        assert name in names, name
+    for name in names:
+        assert not name.startswith(("moe_", "mla_", "eva_", "mixer_rest",
+                                    "zero_expert", "expert", "real_experts",
+                                    "dec_moe", "mix_", "ssm_", "ttft",
+                                    "gen_late", "queue_wait"))
+    assert cell.config["serving"] == {
+        "slots": 48, "cache_len": 9216, "kv_layout": "paged", "kv_block": 64,
+        "admission": "chunked", "prefill_chunk": 256, "prefix_cache": False,
+        "sampling": "greedy"}
+    assert list(cell.config["correct"]["limits"]) == ["gap_mean"]
+    assert (cell.config["correct"]["min_tokens"],
+            cell.config["correct"]["max_requests"]) == (2048, 3)
+    calls = {k: cell.adapter().kernel_call(cell.config, k)[1]
+             for k in ("flash_decode_paged", "window_decode_paged",
+                       "ssm1_scan")}
+    assert calls == {"flash_decode_paged": 8, "window_decode_paged": 8,
+                     "ssm1_scan": 9}
+    assert spec.load_module("kernel_costs", "ssm1_scan.py").cost
+    assert cell.adapter().kernel_call(cell.config, "ssm_decode_update") is None
+
+
+def test_the_decoder_hybrid_configurations_file_against_the_catalog():
+    """Every number of the catalog's ``config`` under the same key, nothing
+    reduced; the count's arithmetic; every assumed rule marked unconfirmed."""
+    path = "/opt/skills/guides/model-configs/architectures.jsonl"
+    with open(BENCH) as f:
+        entry = next(c for c in json.load(f)["configs"]
+                     if c["name"] == "phi-4-mini-flash-reasoning")
+    with open(os.path.join(ROOT, entry["file"])) as f:
+        c = json.load(f)
+    assert entry["reduced"] == c["reduced"] == []
+    assert c["source"] == entry["source"]
+    if os.path.exists(path):
+        row = next(r for r in map(json.loads, open(path))
+                   if r["name"] == "Phi-4-mini-flash-reasoning")
+        assert entry["source"] == row["source_url"]
+        for k, v in row["config"].items():
+            assert c[k] == v, k
+    h, f_, n = c["hidden_size"], c["intermediate_size"], 32
+    a = c["assumed"]
+    inner, st, r = a["mamba_expand"] * h, a["mamba_d_state"], \
+        a["mamba_dt_rank"]
+    ln = 2 * h
+    mlp = 3 * h * f_ + ln
+    mamba = 2 * h * inner + (a["mamba_d_conv"] + 1) * inner \
+        + inner * (r + 2 * st) + r * inner + 2 * inner + inner * st \
+        + inner * h + ln
+    tail = 4 * (h // c["num_attention_heads"]) \
+        + 2 * (h // c["num_attention_heads"]) + ln
+    own = h * 2 * h + 2 * h + h * h + h + tail
+    cross = h * h + h + h * h + h + tail
+    gmu = 2 * h * inner + ln
+    whole = 32 * mlp + 9 * mamba + 9 * own + 7 * cross + 7 * gmu \
+        + c["vocab_size"] * h + ln
+    assert whole == 3_852_562_944 and "3,852,562,944" in c["why_reduced"]
+    assert c["deployment"]["chips"] == 1
+    assert c["block"] == dict(
+        c["block"], decoder_split="sambay", attention="differential",
+        diff_pairing="adjacent", cross_attention="differential",
+        lambda_depth="layer_index_from_0",
+        gmu_memory="scan_output_before_gate", mlp_order="gate_up",
+        window_span=512)
+    for rule in ("decoder_split", "diff_pairing", "lambda_depth",
+                 "cross_attention", "window_span", "mlp_order",
+                 "gmu_memory", "biases"):
+        assert "unconfirmed" in a["unconfirmed"][rule]
+    assert "float32" in a["state_dtype"]
+    assert set(a["seeded_scales"]) == {
+        "embedding_std", "ln_gain_std", "ln_bias_std", "mlp_in_std",
+        "mlp_out_std", "ssm_in_std", "ssm_x_std", "ssm_dt_std",
+        "ssm_out_std", "qkv_std", "attn_bias_std", "attn_out_bias_std",
+        "attn_out_std", "sub_gain_mean", "sub_gain_std", "gmu_in_std",
+        "gmu_out_std"}
+
+
+def test_the_decoder_hybrid_adapter_refuses_another_model_at_once():
+    spec = Spec(BENCH)
+    cell = spec.cell(PF_CELL)
+    adapter, ref = cell.adapter(), cell.reference()
+    with pytest.raises(SpecError, match="cannot read"):
+        adapter.build({"family": "phi4flash"}, [], 0, "cpu", ref)
+    from tree_attention_tpu.models.transformer import model_from_config
+    model = model_from_config(cell.config)
+    adapter._hold_to_file(model, cell.config, ref)
+    # A file that states another width than the engine would build.
+    for key, value in (("sliding_window", 256), ("num_hidden_layers", 28)):
+        with pytest.raises(SpecError, match="built otherwise"):
+            adapter._hold_to_file(
+                model, dict(cell.config, **{
+                    key: value, "block": dict(cell.config["block"],
+                                              window_span=value
+                                              if key == "sliding_window"
+                                              else 512)}), ref)
+    # A program whose model is another family's (the window family's), and
+    # one that knows no Mamba-1 widths (what the parent commit builds: a
+    # dense rotary model): refused before a weight is drawn.
+    other = model_from_config(spec.cell(KX_CELL).config)
+    with pytest.raises(SpecError, match="cannot express"):
+        adapter._hold_to_file(other, cell.config, ref)
+    import types
+    with pytest.raises(SpecError, match="cannot express"):
+        adapter._hold_to_file(types.SimpleNamespace(ssm1=None), cell.config,
+                              ref)
+
+
+@pytest.mark.parametrize("name", DECODER_HYBRID_METRICS)
+def test_the_decoder_hybrid_metrics_read_nothing_where_there_is_nothing(name):
+    """Beside a program without the kernel, the counters or the trace (a
+    parent commit, an untraced run) every one of the four gives None and
+    does not raise."""
+    import types
+
+    spec = Spec(BENCH)
+    read = spec.load_module("layer_metrics", name + ".py").read
+    cell = spec.cell(PF_CELL)
+    for flight in (None, [], [{"t_s": 1.0, "occupancy": 4,
+                               "chunk_tokens": 0}]):
+        run = types.SimpleNamespace(cell=cell, trace=None, flight=flight,
+                                    recs=[], peaks=None, t_open=0.0,
+                                    t_end=10.0, report={})
+        assert read(run) is None
+
+
+def test_rows_past_exit_pct_reads_the_mixed_ticks_counters():
+    import types
+
+    spec = Spec(BENCH)
+    read = spec.load_module("layer_metrics", "rows_past_exit_pct.py").read
+    flight = [
+        {"t_s": 1.0, "chunk_tokens": 256, "rows_self": 304, "rows_cross": 48},
+        {"t_s": 2.0, "chunk_tokens": 100, "rows_self": 176, "rows_cross": 48},
+        {"t_s": 3.0, "chunk_tokens": 0, "rows_self": 48, "rows_cross": 48},
+        {"t_s": 30.0, "chunk_tokens": 256, "rows_self": 304,
+         "rows_cross": 304},                     # outside the window
+    ]
+    run = types.SimpleNamespace(cell=spec.cell(PF_CELL), flight=flight,
+                                t_open=0.0, t_end=10.0)
+    assert read(run) == pytest.approx(100.0 * 96 / 480)
+    states = spec.load_module("layer_metrics",
+                              "ssm1_states_advanced_pct.py").read
+    run.flight = [{"t_s": 1.0, "chunk_tokens": 0, "occupancy": 40,
+                   "ssm_states_advanced": 360},
+                  {"t_s": 2.0, "chunk_tokens": 0, "occupancy": 48,
+                   "ssm_states_advanced": 432},
+                  {"t_s": 3.0, "chunk_tokens": 64, "occupancy": 48,
+                   "ssm_states_advanced": 441}]
+    assert states(run) == pytest.approx(100.0)

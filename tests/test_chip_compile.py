@@ -103,6 +103,10 @@ NEMOTRON3S = dict(slots=64, hkv=2, nb=40, layers=1)
 # KV heads x 128 (Yi-6B's pool rows) under 20 query heads, a group of FIVE
 # (5 packed query rows in a tile of 8 sublanes).
 FALCONH1 = dict(slots=48, hkv=4, nb=40, layers=9, hq=20)
+# The decoder-hybrid configuration: 20 KV heads of 64 laid in PAIRS, 10 rows
+# of 128 lanes, under 40 query heads (a group of 4), the one shared layer's
+# table of 144 entries; its eight window layers' pool under the same table.
+PHI4FLASH = dict(slots=48, hkv=10, nb=144, layers=1, hq=40)
 
 
 def _paged(kernel, tq, *, int8=False, tree=False, slots=B, hkv=HKV, nb=NB,
@@ -198,6 +202,26 @@ def _ssm_scan(tq, slots=64, layers=5, heads=128, d_head=64, n=128, groups=8):
 
     def fn(*a):
         return ssm_chunk_scan(*a, interpret=False)
+
+    return fn, args, (0,)
+
+
+def _ssm1_scan(tq, members, slots=48, layers=9, n=16, channels=5120):
+    """(fn, abstract args, donated) of one ``ssm1_scan`` call at the
+    decoder-hybrid configuration's widths: nine Mamba-1 layers' pool of 48
+    slots x (16, 5120) float32 (142 MB, donated), a decode group's row a
+    slot or one chunk member's ``tq`` rows."""
+    from tree_attention_tpu.ops.pallas_ssm import ssm1_scan
+
+    f32, i32 = jnp.float32, jnp.int32
+    args = [_s((layers * slots, n, channels), f32),
+            _s((members, tq, channels), f32), _s((members, tq, channels), f32),
+            _s((n, channels), f32), _s((members, tq, n), f32),
+            _s((members, tq, n), f32), _s((members,), i32),
+            _s((members,), i32), _s((members,), jnp.bool_)]
+
+    def fn(*a):
+        return ssm1_scan(*a, interpret=False)
 
     return fn, args, (0,)
 
@@ -404,6 +428,27 @@ CASES = {
     "paged_chunk_falconh1_tq256": (
         lambda: _paged(attention_pallas_decode, 256,
                        **dict(FALCONH1, slots=1)), "flash_decode_paged"),
+    # The decoder-hybrid configuration (ISSUE 52): the Mamba-1 scan at a row
+    # a slot and at a chunk member's 256 rows; the shared layer's call at a
+    # row a slot (the cross layers' call too: S rows under one table) and
+    # at a chunk's 256 rows; a window layer's at both.
+    "ssm1_scan_phi4flash_tq1": (
+        functools.partial(_ssm1_scan, 1, 48), "ssm1_scan"),
+    "ssm1_scan_phi4flash_tq256": (
+        functools.partial(_ssm1_scan, 256, 1), "ssm1_scan"),
+    "paged_decode_phi4flash_tq1": (
+        lambda: _paged(attention_pallas_decode, 1, **PHI4FLASH),
+        "flash_decode_paged"),
+    "paged_chunk_phi4flash_tq256": (
+        lambda: _paged(attention_pallas_decode, 256,
+                       **dict(PHI4FLASH, slots=1)), "flash_decode_paged"),
+    "window_decode_phi4flash_tq1": (
+        lambda: _paged(attention_pallas_decode, 1, window=512, **PHI4FLASH),
+        "window_decode_paged"),
+    "window_chunk_phi4flash_tq256": (
+        lambda: _paged(attention_pallas_decode, 256, window=512,
+                       **dict(PHI4FLASH, slots=1)),
+        "window_decode_paged"),
     "moe_ungated_decode_pairs": (lambda: _moe_ungated(1408),
                                  "moe_ungated_matmul"),
     "moe_ungated_chunk_pairs": (lambda: _moe_ungated(7168),
@@ -513,6 +558,26 @@ def test_kernel_compiles_for_v5e(case):
         assert not any(other in kernel for other in (
             "ssm_decode_update", "flash_decode_paged", "moe_grouped_matmul",
             "moe_ungated_matmul"))
+    if kernel == "ssm1_scan":
+        # The pool is aliased through the call, nothing of its size beside
+        # it; the blocks of the rule's choice fit the limit the call asks
+        # for; under a name no other kernel's reader matches.
+        fn, args, _ = builder()
+        mem = jax.jit(fn, donate_argnums=(0,)).lower(
+            *args).compile().memory_analysis()
+        pool = math.prod(args[0].shape) * 4
+        assert mem.alias_size_in_bytes >= pool and mem.temp_size_in_bytes \
+            < pool // 8, (mem.alias_size_in_bytes, mem.temp_size_in_bytes)
+        from tree_attention_tpu.ops import tuning
+        (_, n, channels), tq = args[0].shape, args[1].shape[1]
+        tb = tuning.ssm1_time_block(tq)
+        ct = tuning.ssm1_channel_tile(tb, n, channels)
+        limit = tuning.ssm1_vmem_limit(tb, n, ct)
+        assert ct == channels and tuning.ssm1_step_vmem_bytes(tb, n, ct) \
+            < limit <= tuning.GROUPED_VMEM_CEILING_BYTES, (ct, limit)
+        assert f'"size":"{limit}"' in text, limit
+        assert not any(other in kernel or kernel in other for other in (
+            "ssm_decode_update", "ssm_chunk_scan", "flash_decode_paged"))
     if "_mistral7b" in case or "_yi6b" in case or "_lfm2" in case \
             or ("_falconh1" in case and not kernel.startswith("ssm_")) \
             or ("_evabyte" in case and kernel != ROW_WRITE):
@@ -686,7 +751,7 @@ def _tick_program(config, tq, packed=False, int8=False, served=True):
     params = chip(jax.eval_shape(
         lambda: layout(init_params(jax.random.PRNGKey(0), cfg))))
     extra = {}
-    if cfg.cache_kind in ("window", "eva"):
+    if cfg.cache_kind in ("window", "eva", "state_window"):
         # The engine's own size: (ceil((window + chunk) / block) + 2) a slot.
         extra["window_blocks"] = slots * (-(-(
             cfg.window + serving["prefill_chunk"]) // serving["kv_block"]) + 2)
@@ -867,6 +932,53 @@ def _padding_arrays(text, slots, tq, vocab, d_model, packed_rows=None):
                 and tq in dims[dims.index(slots) + 1:]:
             out.add((dtype, tuple(dims)))
     return sorted(out)
+
+
+# The decoder-hybrid configuration (ISSUE 52): nine Mamba-1 layers' states,
+# eight window layers' rows and ONE shared layer's rows in one cache, carried
+# whole through four runs of layers (two of them a period of two kinds). The
+# compile for the chip holds the three kernels and the row write, copies none
+# of the six pools, and a packed tick's layers above the seam hold no array
+# of the chunk's rows.
+
+
+@pytest.mark.parametrize("tq,packed", [(1, False), (256, True)],
+                         ids=["tq1", "packed256"])
+def test_decoder_hybrid_step_compiles_and_copies_no_pool(tq, packed):
+    if _chip() is None:
+        pytest.skip("the v5e:2x2 topology cannot be described here")
+    name = "phi-4-mini-flash-reasoning"
+    c, cfg = _model(name)
+    slots = c["serving"]["slots"]
+    tick = _tick_program(name, tq, packed=packed)
+    text = tick.text
+    kernels = pallas_kernels(text)
+    assert {"ssm1_scan", "window_decode_paged", ROW_WRITE} <= set(kernels), \
+        kernels
+    assert any(k.startswith("flash_decode_paged") for k in kernels), kernels
+    assert not {"ssm_decode_update", "ssm_chunk_scan"} & set(kernels)
+    # Four loop bodies (two periods of two layers, two single layers): the
+    # Mamba-1 scan is launched in the first period's body and for layer 16,
+    # a decode group's and, packed, a chunk group's.
+    launches = len(re.findall(r"%ssm1_scan(\.\d+)? = ", text))
+    assert launches == (4 if packed else 2), launches
+    # Every pool goes through the program in place: what is aliased is at
+    # least the six arrays, and nothing pool-sized is made beside them.
+    pools = 2 * (6912 + 8 * slots * 14) * 10 * 64 * 128 * 2 \
+        + 9 * slots * (16 * 5120 * 4 + 3 * 5120 * 2)
+    assert tick.alias_bytes >= pools, (tick.alias_bytes, pools)
+    assert tick.temp_bytes < 1.5e9, tick.temp_bytes
+    if packed:
+        padding = _padding_arrays(text, slots, tq, cfg.vocab_size,
+                                  cfg.d_model)
+        assert not padding, padding
+        # Below the seam the chunk's rows and one a slot, above it one a
+        # slot alone: the memory is gathered to the slots' rows, and the
+        # gated units' products are of that many.
+        shapes = {dims for _, dims in _ARRAY.findall(text)}
+        inner = cfg.ssm1.inner
+        assert f"1,{tq + slots},{inner}" in shapes, sorted(shapes)[:40]
+        assert f"1,{slots},{inner}" in shapes or f"{slots},{inner}" in shapes
 
 
 @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])

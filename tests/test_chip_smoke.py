@@ -215,6 +215,25 @@ def test_phase_parallel_serves_two_mixers_a_layer_through_a_reused_slot():
     assert d["tokens_compared"] == 6 + 3 * 5 and d["gap_max"] <= 1e-4
 
 
+def test_phase_decoder_hybrid_serves_past_the_window_and_a_reused_slot():
+    """The decoder-hybrid phase at ``tests/test_decoder_hybrid.py``'s small
+    preset cut to 8 layers (every kind of layer, both periods single):
+    blocks of 4, chunks of 16, a window of 8, float32 (a served token lies
+    at the reference's best to 1e-3)."""
+    from tests.test_decoder_hybrid import SMALL
+
+    d = smoke.phase_decoder_hybrid(
+        TINY, dict(SMALL, num_hidden_layers=8), device="cpu", block=4,
+        chunk=16, tol_gap=1e-3)
+    assert d["layers"] == ["ssm1", "window"] * 2 + [
+        "ssm1", "attention", "gmu", "cross"] and d["row_cut"] == 6
+    assert d["shared_rows"][0] == 1 and d["window_rows"][0] == 2
+    assert d["ssm_state"] == [3, 1, 8, 256]
+    assert d["ssm_state_dtype"] == "float32"
+    assert d["window_blocks_freed"] > 0
+    assert d["tokens_compared"] == 48 + 5 and d["gap_max"] <= 1e-3
+
+
 @pytest.mark.slow
 def test_phase_train_and_programs():
     assert smoke.phase_train(TINY)["losses"][-1] < 6.3

@@ -728,7 +728,8 @@ def build_serve_engine(cfg: RunConfig, mesh, *, model=None,
                         f"a model served from the {tcfg.cache_kind} pool is "
                         f"not served with {what}: not built yet (ROADMAP "
                         f"2A)")
-        if tcfg.cache_kind in ("state", "eva") and cfg.prefix_cache:
+        if tcfg.cache_kind in ("state", "eva", "state_window") \
+                and cfg.prefix_cache:
             raise SystemExit(
                 f"a model served from the {tcfg.cache_kind} pool is not "
                 f"served with --prefix-cache (a hit needs the recurrent "

@@ -61,6 +61,7 @@ from tree_attention_tpu.models.transformer import (
     _mlp_block,
     embed,
     gqa_qkv,
+    norm_rows,
     rms_norm,
     unembed,
 )
@@ -355,6 +356,63 @@ class PagedStateCache:
         return self.k.shape[1]
 
 
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class PagedStateWindowCache:
+    """The cache of a decoder that feeds a second decoder (``cfg.cache_kind``
+    ``"state_window"``): a state AND a window at once. What
+    :class:`PagedWindowCache` holds, the sliding-window layers' rows in
+    ``wk`` / ``wv`` under ``wtable`` (a bounded number of blocks a slot) and
+    the full-context rows in ``k`` / ``v`` under ``table``; and what
+    :class:`PagedStateCache` holds, a recurrent state and a conv tail a slot
+    for every Mamba-1 layer: ``ssm_state`` ``(ssm1 layers, slots, d_state,
+    inner)`` float32 (``Mamba1.state_shape``) and ``ssm_tail`` ``(ssm1
+    layers, slots, (taps - 1) x inner)``.
+
+    ``k`` / ``v`` are ONE layer deep: the shared full-attention layer writes
+    a token's row and every cross layer above it reads the same rows again
+    (no ``W_k``, no ``W_v``, no write), so a token costs one layer's rows
+    however many layers attend to it. Every K/V pool lays a PAIR of
+    neighbouring heads side by side on a row (``(layers, N, Hkv / 2, block,
+    2 x D)``): differential attention's value pair (``models/hybrid.py``
+    ``diff_branch``). The state pool's three rules hold as they are (a
+    member at position 0 starts from zero, a row past the valid count
+    leaves state and tail bit for bit, a slot with no row is neither read
+    nor written), and nothing of a state can be shared, restored or rolled
+    back."""
+
+    k: jax.Array          # (1, N, Hkv / 2, block, 2 x D): the shared rows
+    v: jax.Array          # (1, N, Hkv / 2, block, 2 x D)
+    wk: jax.Array         # (window layers, Nw, Hkv / 2, block, 2 x D)
+    wv: jax.Array         # (window layers, Nw, Hkv / 2, block, 2 x D)
+    ssm_state: jax.Array  # (ssm1 layers, slots, d_state, inner) float32
+    ssm_tail: jax.Array   # (ssm1 layers, slots, (taps - 1) x inner)
+    table: jax.Array      # (B, NB) int32: the shared layer's blocks
+    wtable: jax.Array     # (B, NB) int32: the window layers' blocks
+    length: jax.Array     # (B,) int32: tokens written so far, per slot
+
+    @property
+    def capacity(self) -> int:
+        return self.table.shape[1] * self.k.shape[3]
+
+    @property
+    def block(self) -> int:
+        return self.k.shape[3]
+
+    @property
+    def blocks(self) -> int:
+        return self.k.shape[1]
+
+    @property
+    def window_blocks(self) -> int:
+        return self.wk.shape[1]
+
+
+# The caches with a second table, and those with a state a slot.
+_TWO_TABLES = (PagedWindowCache, PagedStateWindowCache)
+_SLOT_STATES = (PagedStateCache, PagedStateWindowCache)
+
+
 def cache_pools(cache) -> Dict[str, jax.Array]:
     """A paged cache's block pools by field name, every one ``(L, N, ...)``
     with the block on axis 1: what a block copy, a leak check or a byte
@@ -384,9 +442,9 @@ def cache_token_bytes(cache) -> int:
 
 def window_pools(cache) -> Dict[str, jax.Array]:
     """The pools under a cache's SECOND table (``wtable``), by field name:
-    a :class:`PagedWindowCache`'s window layers' K and V; none for every
-    other cache."""
-    if isinstance(cache, PagedWindowCache):
+    a :class:`PagedWindowCache`'s (or a :class:`PagedStateWindowCache`'s)
+    window layers' K and V; none for every other cache."""
+    if isinstance(cache, _TWO_TABLES):
         return {"wk": cache.wk, "wv": cache.wv}
     return {}
 
@@ -734,7 +792,8 @@ def init_paged_cache(
     seq_axis: str = AXIS_SEQ,
     window_blocks: Optional[int] = None,
 ) -> Union[PagedKVCache, PagedQuantKVCache, PagedLatentCache,
-           PagedHybridCache, "PagedWindowCache", "PagedStateCache"]:
+           PagedHybridCache, "PagedWindowCache", "PagedStateCache",
+           "PagedStateWindowCache"]:
     """Allocate a paged cache: one ``blocks``-block pool + empty tables,
     of the kind the model caches (``cfg.cache_kind``).
 
@@ -833,6 +892,36 @@ def init_paged_cache(
         return PagedWindowCache(
             k=k, v=v, wk=wk, wv=wv,
             table=jnp.zeros((batch_size, nb_first), jnp.int32),
+            wtable=jnp.zeros((batch_size, nb), jnp.int32),
+            length=jnp.zeros((batch_size,), jnp.int32),
+        )
+    if cfg.cache_kind == "state_window":
+        if quantize or seq_sharded:
+            raise ValueError(
+                "int8 rows or a sequence-sharded pool (kv_shard='seq') "
+                "beside a recurrent state and window blocks are not built: "
+                "the state_window pool is served exact, replicated")
+        if not window_blocks or window_blocks < 1:
+            raise ValueError(
+                f"a model with sliding-window layers needs the window "
+                f"pool's capacity (window_blocks), got {window_blocks}")
+        sm, p = cfg.ssm1, cfg.kv_pack
+        row = (cfg.n_kv_heads // p, block, cfg.d_head * p)
+        shapes = (
+            ((cfg.cache_layers, blocks) + row, cfg.dtype),
+            ((cfg.cache_layers, blocks) + row, cfg.dtype),
+            ((cfg.window_layers, window_blocks) + row, cfg.dtype),
+            ((cfg.window_layers, window_blocks) + row, cfg.dtype),
+            ((cfg.ssm_layers, batch_size) + sm.state_shape, jnp.float32),
+            ((cfg.ssm_layers, batch_size, (sm.taps - 1) * sm.inner),
+             cfg.dtype))
+        make = lambda: tuple(jnp.zeros(s, d) for s, d in shapes)  # noqa: E731
+        k, v, wk, wv, state, tail = (
+            jax.jit(make, out_shardings=NamedSharding(mesh, P()))()
+            if mesh is not None else make())
+        return PagedStateWindowCache(
+            k=k, v=v, wk=wk, wv=wv, ssm_state=state, ssm_tail=tail,
+            table=jnp.zeros((batch_size, nb), jnp.int32),
             wtable=jnp.zeros((batch_size, nb), jnp.int32),
             length=jnp.zeros((batch_size,), jnp.int32),
         )
@@ -1316,7 +1405,9 @@ def _plan_groups(groups: Tuple[_RowGroup, ...], cache: Any,
     either table): a layer's :func:`_pool_write` then adds ``l * N`` inside
     its kernel and the loop's body holds nothing of the write but the
     launch. Over a :class:`PagedStateCache` such a group gets the list of
-    the slots that have a row too (``ops/pallas_ssm.py`` ``live_list``).
+    the slots that have a row too (``ops/pallas_ssm.py`` ``live_list``);
+    over a :class:`PagedStateWindowCache` every group gets the list of its
+    members that have one (``ssm1_scan`` walks it at any ``tq``).
     Over a model with conv layers it gets the places of positions ``p``,
     ``p - 1`` and ``p - 2`` in the tail pool (``ops/pallas_conv.py``
     ``conv_tail_plan``), which a conv layer's one launch is handed as they are.
@@ -1348,7 +1439,8 @@ def _plan_groups(groups: Tuple[_RowGroup, ...], cache: Any,
                 g = g._replace(wplan=barrier(decode_plan(
                     cfg.n_heads, g.tq, cache.wk, g.wtable, g.start,
                     window=wrule)))
-        if g.tq == 1 and isinstance(cache, PagedStateCache):
+        if (g.tq == 1 and isinstance(cache, PagedStateCache)) \
+                or isinstance(cache, PagedStateWindowCache):
             # The slots a state-space layer's in-place step visits.
             from tree_attention_tpu.ops.pallas_ssm import live_list
 
@@ -1394,7 +1486,7 @@ def paged_step_tokens(cache: Any, cfg: TransformerConfig,
     from tree_attention_tpu.ops.tuning import tpu_kernel_for
 
     if window:
-        if not isinstance(cache, PagedWindowCache):
+        if not isinstance(cache, _TWO_TABLES):
             return None
         return cache.block * decode_step_entries(
             cfg.n_heads, tq, cache.wk, cache.wtable.shape[1])
@@ -1402,7 +1494,7 @@ def paged_step_tokens(cache: Any, cfg: TransformerConfig,
         return mla_step_entries(cache.table.shape[1]) * cache.block
     if not isinstance(cache, (PagedKVCache, PagedQuantKVCache,
                               PagedHybridCache, PagedWindowCache,
-                              PagedStateCache)):
+                              PagedStateCache, PagedStateWindowCache)):
         return None
     if not isinstance(cache, PagedQuantKVCache) and not cfg.eva_layers \
             and tpu_kernel_for(tq) != "pallas_decode":
@@ -1414,7 +1506,7 @@ def paged_step_tokens(cache: Any, cfg: TransformerConfig,
 def _slots(cache: Any, batch: int) -> Optional[jax.Array]:
     """Every slot's own index, for a cache that holds a state a slot; None
     for every other cache (whose groups are tables and lengths alone)."""
-    if not isinstance(cache, PagedStateCache):
+    if not isinstance(cache, _SLOT_STATES):
         return None
     return jnp.arange(batch, dtype=jnp.int32)
 
@@ -1462,6 +1554,10 @@ class _Attend:
     # The caller merges this call's partial with another's (an EVA layer):
     # the output comes back as ``(out, lse)``.
     partial: bool = False
+    # False: a cross layer's call. The group's rows attend to what another
+    # layer wrote into the cache in this step and before; nothing is
+    # written and ``k_new`` / ``v_new`` are None.
+    write: bool = True
 
     def __call__(self, gi, q, k_new, v_new, k_cache, v_cache, k_s, v_s,
                  views, l, base):
@@ -1482,7 +1578,8 @@ class _Attend:
         B, Tq = g.batch, g.tq
         start, n_valid = g.start, g.n_valid
         with jax.named_scope(scopes.ATTN_CACHE):
-            k_new, v_new = g.take(k_new), g.take(v_new)
+            if self.write:
+                k_new, v_new = g.take(k_new), g.take(v_new)
             k_view = v_view = None
             if hoist_view:
                 k_view, v_view = views[2 * gi:2 * gi + 2]
@@ -1531,7 +1628,9 @@ class _Attend:
             elif quant:
                 k_new = _quantize_rows(k_new, k_s)
                 v_new = _quantize_rows(v_new, v_s)
-            if paged:
+            if not self.write:
+                pass        # a cross layer: this scope stays empty
+            elif paged:
                 # Paged write, through the block table: valid rows land
                 # in their slot's mapped blocks, padded rows drop (K and V
                 # in one call: _pool_write). The contiguous path's window
@@ -1604,6 +1703,8 @@ class _Attend:
                 attn_kw["scale"] = self.scale
             if self.window is not None:
                 attn_kw["window"] = self.window
+            if not self.write:
+                attn_kw["launch"] = "shared"    # another layer's rows
             ak, av, ak_s, av_s = k_cache, v_cache, k_s, v_s
             if hoist_view:
                 ak, av = k_view, v_view
@@ -1685,7 +1786,13 @@ def gqa_mixer(attend: _Attend, layer: Params, x: jax.Array,
 
 def _head_lanes(cfg: TransformerConfig) -> jax.Array:
     """``(heads, kv_pack)`` one-hot: which of a packed row's ``kv_pack``
-    spans of ``d_head`` lanes query head ``h`` reads (its KV head's)."""
+    spans of ``d_head`` lanes query head ``h`` reads (its KV head's).
+    Under differential attention (``cfg.diff_attn``) the packed row is a
+    PAIR of KV heads and query head ``h = 2p + σ`` is half ``σ`` of its
+    pair: it reads key head ``2j + σ``, half ``h % 2`` of packed head ``j``
+    (standard GQA packing would put it in half ``(h // group) % 2``)."""
+    if cfg.diff_attn:
+        return jax.nn.one_hot(jnp.arange(cfg.n_heads) % 2, 2)
     kv_head = jnp.arange(cfg.n_heads) // (cfg.n_heads // cfg.n_kv_heads)
     return jax.nn.one_hot(kv_head % cfg.kv_pack, cfg.kv_pack)
 
@@ -1695,11 +1802,15 @@ def _pack_heads(q, k, v, cfg: TransformerConfig):
     (``(B, Hkv, T, D)`` -> ``(B, Hkv / p, T, p x D)``), and every query
     head's values in its KV head's lanes with zeros beside them: q . k over
     the packed row is the head's own dot product, and query head ``h``
-    still reads packed KV head ``h // (group x p)``."""
+    still reads packed KV head ``h // (group x p)``. ``k`` / ``v`` None (a
+    cross layer projects queries only) stay None."""
     p = cfg.kv_pack
-    B, Hkv, T, D = k.shape
+    B, _, T, D = q.shape
 
     def side_by_side(a):
+        if a is None:
+            return None
+        Hkv = a.shape[1]
         return a.reshape(B, Hkv // p, p, T, D).transpose(0, 1, 3, 2, 4) \
             .reshape(B, Hkv // p, T, p * D)
 
@@ -1711,7 +1822,9 @@ def _pack_heads(q, k, v, cfg: TransformerConfig):
 
 def _unpack_heads(out: jax.Array, cfg: TransformerConfig) -> jax.Array:
     """Of a packed output row ``(B, H, T, p x D)`` the span of the head's
-    own KV head: ``(B, H, T, D)``."""
+    own KV head: ``(B, H, T, D)``. (Differential attention keeps ALL the
+    lanes instead, ``[softmax . v_2j | softmax . v_2j+1]`` is its
+    ``a_{p,σ}``: ``models/hybrid.py`` ``diff_branch``.)"""
     B, H, T, _ = out.shape
     out = out.reshape(B, H, T, cfg.kv_pack, cfg.d_head)
     return jnp.einsum("bhtpd,hp->bhtd", out,
@@ -1720,7 +1833,7 @@ def _unpack_heads(out: jax.Array, cfg: TransformerConfig) -> jax.Array:
 
 # The caches a model's own layer loop steps (not the dense block's scan).
 _OWN_POOLS = (PagedLatentCache, PagedHybridCache, PagedWindowCache,
-              PagedStateCache)
+              PagedStateCache, PagedStateWindowCache)
 
 
 def _check_block_cache(cache: Any, cfg: TransformerConfig) -> None:
@@ -1728,7 +1841,9 @@ def _check_block_cache(cache: Any, cfg: TransformerConfig) -> None:
     kind = ("latent" if isinstance(cache, PagedLatentCache)
             else "hybrid" if isinstance(cache, PagedHybridCache)
             else "window" if isinstance(cache, PagedWindowCache)
-            else "state" if isinstance(cache, PagedStateCache) else "kv")
+            else "state" if isinstance(cache, PagedStateCache)
+            else "state_window" if isinstance(cache, PagedStateWindowCache)
+            else "kv")
     if kind == "window" and cfg.cache_kind == "eva":
         kind = "eva"   # the same two pools under the EVA row rule
     if kind != cfg.cache_kind:
@@ -1738,7 +1853,8 @@ def _check_block_cache(cache: Any, cfg: TransformerConfig) -> None:
             f"attention, the hybrid pool for conv layers or experts under "
             f"rotary GQA, the window pools for sliding-window layers or EVA "
             f"layers, the "
-            f"state pool for state-space layers, K/V buffers for the dense "
+            f"state pool for state-space layers, the state_window pool for "
+            f"a decoder that feeds a second decoder, K/V buffers for the dense "
             f"block) and is served "
             f"from the cache init_paged_cache builds for it and no other; "
             f"got {type(cache).__name__}"
@@ -1758,6 +1874,8 @@ def _count_step(cache: Any) -> None:
         kind = "paged_window"
     elif isinstance(cache, PagedStateCache):
         kind = "paged_state"
+    elif isinstance(cache, PagedStateWindowCache):
+        kind = "paged_state_window"
     elif isinstance(cache, (PagedKVCache, PagedQuantKVCache)):
         kind = "paged_quant" if quant else "paged"
     else:
@@ -1903,12 +2021,16 @@ def _step_layers(
     quant_kernel: str,
     kv_shard: str,
     stats: Optional[Dict[str, Any]],
+    cut: Optional[Tuple[jax.Array, _RowGroup]] = None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Every layer of a step over ``groups`` of rows: THE layer body, which
     the padded step (one group) and the packed step (a chunk group beside a
     decode group) share. ``x`` and ``positions`` lie as :class:`_RowGroup`
-    says. Returns the final residual and the cache's arrays the step
-    rewrote, by field name."""
+    says. ``cut`` (a packed step of a model with ``cfg.row_cut``): the rows
+    that go on above the seam, ``(their indices on the row axis, the group
+    they make)``; the residual comes back with those rows alone. Returns
+    the final residual and the cache's arrays the step rewrote, by field
+    name."""
     from tree_attention_tpu.ops import _on_tpu, _pallas_available
 
     paged = isinstance(cache, (PagedKVCache, PagedQuantKVCache))
@@ -1920,12 +2042,14 @@ def _step_layers(
     seq_sharded = paged and kv_shard == "seq" and seq_shards > 1
     if on_kernels and groups[0].table is not None and not seq_sharded:
         groups = _plan_groups(groups, cache, cfg)
+        if cut is not None:
+            cut = (cut[0], _plan_groups((cut[1],), cache, cfg)[0])
     if isinstance(cache, PagedLatentCache):
         x, pool = _latent_layers(
             params, x, cache, cfg, positions, groups, stats)
         return x, {"kv": pool}
     if isinstance(cache, (PagedHybridCache, PagedWindowCache,
-                          PagedStateCache)):
+                          PagedStateCache, PagedStateWindowCache)):
         from tree_attention_tpu.models.hybrid import hybrid_layers
 
         attend = _Attend(
@@ -1936,7 +2060,7 @@ def _step_layers(
             scale=cfg.d_head ** -0.5 if cfg.kv_pack > 1 else None,
         )
         return hybrid_layers(
-            params, x, positions, cache, cfg, attend, stats)
+            params, x, positions, cache, cfg, attend, stats, cut)
     quant = isinstance(cache, (QuantKVCache, PagedQuantKVCache))
 
     # Satellite fix (ISSUE 8): off the TPU Pallas kernels — the eager/CPU
@@ -2302,8 +2426,7 @@ def forward_step(
         stats=stats,
     )
     with jax.named_scope(scopes.HEAD):
-        logits = unembed(
-            params, rms_norm(x, params["ln_f"], cfg.norm_eps), cfg.mup)
+        logits = unembed(params, norm_rows(cfg, x, params, "ln_f"), cfg.mup)
     grew = Tq if n_tokens is None else n_tokens
     return logits, dataclasses.replace(cache, length=start + grew, **pools)
 
@@ -2351,7 +2474,14 @@ def forward_packed_step(
     weights stream once; the pool write and attention run per group through
     the code the padded step runs (:func:`_step_layers`). The head runs on
     ONE row a slot: its last valid chunk row where it is a member with rows,
-    its decode row otherwise.
+    its decode row otherwise. Where the model is a decoder that feeds a
+    second decoder (``cfg.row_cut``), the rows are cut to that one row a
+    slot EARLIER, at the seam: layers ``[0, row_cut)`` run on the ``C·Tq +
+    S`` rows, then the residual (and the memory the gated units read) is
+    gathered by the head's index and the layers above, the final norm and
+    the head run on ``S`` rows, a cross layer's row attending from its own
+    position. Exact: nothing above the seam carries anything from one
+    position to the next.
 
     Returns ``logits`` ``(S, vocab)`` float32 (a slot with no row anywhere
     gets its inert decode row's, which the caller ignores) and the cache
@@ -2396,24 +2526,39 @@ def forward_packed_step(
             [chunk_tokens.reshape(-1), tokens.reshape(-1)])
         x = embed(params, rows[None], cfg.mup)          # (1, C·Tq + S, D)
     _count_step(cache)
-    x, pools = _step_layers(
-        params, x, positions, groups, cache, cfg, mesh=mesh, axes=axes,
-        num_splits=num_splits, quant_kernel=quant_kernel, kv_shard=kv_shard,
-        stats=stats,
-    )
-    with jax.named_scope(scopes.HEAD):
+
+    def sampled_rows():
         # One row a slot: padding members scatter past the last slot and
         # drop.
-        src = (C * Tq + jnp.arange(S, dtype=jnp.int32)).at[
+        return (C * Tq + jnp.arange(S, dtype=jnp.int32)).at[
             jnp.where(chunk_n > 0, chunk_slot, S)
         ].set(
             jnp.arange(C, dtype=jnp.int32) * Tq
             + jnp.maximum(chunk_n - 1, 0),
             mode="drop",
         )
-        logits = unembed(
-            params, rms_norm(x[0, src], params["ln_f"], cfg.norm_eps),
-            cfg.mup)
+
+    cut = None
+    if cfg.row_cut is not None:
+        # The seam: above layer ``row_cut`` only a row some slot samples
+        # from goes on, each attending from its own position (a chunk
+        # member's last valid row sits at ``length + chunk_n - 1``).
+        with jax.named_scope(scopes.ATTN_OUT):
+            member = jnp.where(chunk_n > 0, chunk_slot, S)
+            at = length.at[member].set(c_start + chunk_n - 1, mode="drop")
+            has = n_tokens.at[member].set(1, mode="drop")
+            cut = (sampled_rows(), _RowGroup(
+                lo=0, batch=S, tq=1, start=at, n=has, table=cache.table,
+                tree_mask=None))
+    x, pools = _step_layers(
+        params, x, positions, groups, cache, cfg, mesh=mesh, axes=axes,
+        num_splits=num_splits, quant_kernel=quant_kernel, kv_shard=kv_shard,
+        stats=stats, cut=cut,
+    )
+    with jax.named_scope(scopes.HEAD):
+        rows = x[0] if cut is not None else x[0, sampled_rows()]
+        logits = unembed(params, norm_rows(cfg, rows, params, "ln_f"),
+                         cfg.mup)
     new_len = (length + n_tokens).at[chunk_slot].add(chunk_n)
     return logits, dataclasses.replace(cache, length=new_len, **pools)
 
@@ -2754,6 +2899,7 @@ def decode_attention(
     scale: Optional[float] = None,
     step_plan: Any = None,
     window: Any = None,
+    launch: Optional[str] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Op-level decode entry: split-KV on one device, tree merge on a mesh.
 
@@ -2838,7 +2984,7 @@ def decode_attention(
             q, k, v, q_position=q_position, num_splits=num_splits,
             block_size=block_size, block_table=block_table,
             tree_mask=tree_mask, scale=scale, step_plan=step_plan,
-            window=window,
+            window=window, launch=launch,
         )
     if q_position is None:
         q_position = k.shape[2] - q.shape[2]

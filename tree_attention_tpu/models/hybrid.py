@@ -5,7 +5,12 @@ whose mixer is TWO mixers side by side, and the layer loop over runs of
 layers of one kind.
 
 A layer is a mixer, or two mixers side by side on one norm, and at most one
-feed-forward half. The mixer is rotary-GQA attention
+feed-forward half. (A decoder that feeds a second decoder adds three kinds:
+the Mamba-1 mixer, :func:`ssm1_branch`; the gated memory unit,
+:func:`gmu_mixer`, which reads an ACTIVATION the last Mamba-1 layer made in
+the same step and the layer loop carries up; and the cross layer, differential
+attention over ANOTHER layer's rows, :func:`diff_branch`: their equations are
+below the others'.) The mixer is rotary-GQA attention
 (:func:`~.decode.gqa_mixer`, the dense block's own: over the whole context,
 or, a ``window`` layer, over the last ``cfg.window`` positions, its rows in a
 pool and under a table of their own, :class:`~.decode.PagedWindowCache`), a
@@ -81,13 +86,32 @@ row at ``t``, ``w0 = (t // W) * W``::
 one softmax over the exact rows of the row's own window and one summary row
 for every chunk of every window closed before it, computed as two partials
 (each pool's paged call) joined by ``ops/reference.py`` ``merge_partials``.
+
+The decoder that feeds a second decoder (``cfg.ssm1`` set; ``LN``: LayerNorm
+with bias, ``cfg.norm`` ``"layer"``), for the normed residual ``h``::
+
+    Mamba-1:  [x | z] = h · W_in;  x <- silu(conv(x) + b);  [δ | B | C] = x · W_x
+              Δ_t = softplus(δ · W_Δ + b_Δ),  A = -exp(A_log)     (d_state, inner)
+              S_t[n,c] = exp(Δ_t[c] A[n,c]) S_{t-1}[n,c] + B_t[n] Δ_t[c] x_t[c]
+              y_t[c] = Σ_n C_t[n] S_t[n,c] + D[c] x_t[c];  adds (y ⊙ silu(z)) · W_out
+    memory:   m = y of the LAST Mamba-1 layer (with the skip, before the gate)
+    GMU:      adds (silu(h · W_in) ⊙ m) · W_out        (m the SAME row's)
+    diff:     a_{p,σ} = softmax(s · q_{2p+σ} · k_{2j+σ} + mask) · [v_2j | v_2j+1]
+              λ = exp(λ_q1·λ_k1) - exp(λ_q2·λ_k2) + λ₀(l),  λ₀(l) = 0.8 - 0.6 e^(-0.3 l)
+              o_p = RMSNorm(a_{p,0} - λ a_{p,1}) (1 - λ₀(l));  adds concat(o) · W_o + b
+    cross:    diff with queries alone of its own, over the shared layer's k, v
+
+Its state at position ``t`` is ``S_{t-1}`` and the last ``taps - 1``
+pre-activation ``x`` rows (:class:`~.decode.PagedStateWindowCache`); in a
+packed step the rows no slot samples from leave the stack after the shared
+layer (``cfg.row_cut``; :func:`hybrid_layers`' ``cut``).
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, List, Optional, Tuple, Union
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -97,10 +121,12 @@ from jax import lax
 from tree_attention_tpu.models.decode import (
     PagedHybridCache,
     PagedStateCache,
+    PagedStateWindowCache,
     PagedWindowCache,
     _Attend,
     _RowGroup,
     _join_rows,
+    _pack_heads,
     _pool_write,
     chunks_closed,
     decode_attention,
@@ -110,14 +136,18 @@ from tree_attention_tpu.models.decode import (
     window_rules,
 )
 from tree_attention_tpu.models.transformer import (
+    GQA_SERVED,
     Params,
     StateSpace,
     TransformerConfig,
+    _heads,
     _mlp_block,
     _unheads,
     gqa_qkv,
+    norm_rows,
     rms_norm,
     times,
+    times_out_major,
 )
 from tree_attention_tpu.ops.reference import merge_partials
 from tree_attention_tpu.obs import scopes
@@ -365,6 +395,44 @@ def _in_multipliers(cfg: TransformerConfig) -> Optional[jax.Array]:
         for n, v in zip((sm.inner, sm.inner, gn, gn, sm.n_heads), m)])
 
 
+def _taps_step(g: _RowGroup, xg: jax.Array, flat_t: jax.Array, at, to,
+               fresh, taps: jax.Array, conv_b: jax.Array
+               ) -> Tuple[jax.Array, jax.Array]:
+    """A state-space mixer's short convolution over one group's rows ``xg``
+    ``(batch, tq, cd)``, from and into the flat tail pool ``(layers x S,
+    (taps - 1) x cd)``: member ``i``'s last pre-activation rows at ``at[i]``
+    (zero where ``fresh``), the rows it leaves written at ``to[i]`` (past
+    the pool, and dropped, for a member with no row). ``taps`` ``(taps,
+    cd)`` float32. Returns ``silu(conv + bias)`` ``(batch, tq, cd)`` float32
+    and the pool."""
+    n_taps, cd = taps.shape
+    back = n_taps - 1
+    old = jnp.where(fresh[:, None], 0, flat_t[at])
+    bias = conv_b.astype(jnp.float32)
+    if g.tq == 1:
+        # A slot's rows as they lie on the lanes: a (slots, 3,
+        # conv_dim) view of them is a copy in another tiling.
+        rows = [old[:, k * cd:(k + 1) * cd]
+                for k in range(back)] + [xg[:, 0].astype(old.dtype)]
+        conv = sum(taps[k] * rows[k].astype(jnp.float32)
+                   for k in range(n_taps))[:, None]
+        left = jnp.concatenate(rows[1:], axis=-1)
+    else:
+        pre = jnp.concatenate(
+            [old.reshape(g.batch, back, cd),
+             xg.astype(old.dtype)], axis=1)
+        conv = sum(
+            taps[k] * pre[:, k:k + g.tq].astype(jnp.float32)
+            for k in range(n_taps))
+        # The tail a member leaves: the rows before its next.
+        keep = g.n_valid[:, None] + jnp.arange(
+            back, dtype=jnp.int32)
+        left = jnp.take_along_axis(
+            pre, keep[:, :, None], axis=1).reshape(g.batch, -1)
+    conv = jax.nn.silu(conv + bias)
+    return conv, flat_t.at[to].set(left, mode="drop")
+
+
 def ssm_branch(layer: Params, h: jax.Array, state: jax.Array,
                tail: jax.Array, m, groups: Tuple[_RowGroup, ...],
                cfg: TransformerConfig
@@ -426,30 +494,8 @@ def ssm_branch(layer: Params, h: jax.Array, state: jax.Array,
             wrote = wrote + jnp.sum(has, dtype=jnp.int32)
             with jax.named_scope(scopes.SSM_TAPS):
                 xg = g.take(xbc[:, None])[:, 0]          # (batch, tq, cd)
-                old = jnp.where(fresh[:, None], 0, flat_t[at])
-                bias = layer["conv_b"].astype(jnp.float32)
-                if g.tq == 1:
-                    # A slot's rows as they lie on the lanes: a (slots, 3,
-                    # conv_dim) view of them is a copy in another tiling.
-                    rows = [old[:, k * cd:(k + 1) * cd]
-                            for k in range(back)] + [xg[:, 0].astype(old.dtype)]
-                    conv = sum(taps[k] * rows[k].astype(jnp.float32)
-                               for k in range(sm.taps))[:, None]
-                    left = jnp.concatenate(rows[1:], axis=-1)
-                else:
-                    pre = jnp.concatenate(
-                        [old.reshape(g.batch, back, cd),
-                         xg.astype(old.dtype)], axis=1)
-                    conv = sum(
-                        taps[k] * pre[:, k:k + g.tq].astype(jnp.float32)
-                        for k in range(sm.taps))
-                    # The tail a member leaves: the rows before its next.
-                    keep = g.n_valid[:, None] + jnp.arange(
-                        back, dtype=jnp.int32)
-                    left = jnp.take_along_axis(
-                        pre, keep[:, :, None], axis=1).reshape(g.batch, -1)
-                conv = jax.nn.silu(conv + bias)
-                flat_t = flat_t.at[to].set(left, mode="drop")
+                conv, flat_t = _taps_step(
+                    g, xg, flat_t, at, to, fresh, taps, layer["conv_b"])
                 # [x | B | C] as the convolution lays them, and by head and
                 # by group.
                 wide = jnp.split(conv, [inner, inner + G * N], axis=-1)
@@ -521,6 +567,210 @@ def ssm_mixer(layer: Params, x: jax.Array, state: jax.Array,
     with jax.named_scope(scopes.ATTN_OUT):
         x = x + y
     return x, state, tail, wrote
+
+
+# ---------------------------------------------------------------------------
+# The Mamba-1 mixer, the gated memory unit, differential attention
+# ---------------------------------------------------------------------------
+
+
+def ssm1_rows(s0: jax.Array, x: jax.Array, dt: jax.Array, A: jax.Array,
+              B: jax.Array, C: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """The Mamba-1 recurrence a row at a time, in ``jax.numpy``: ``S_t =
+    exp(dt_t (x) A) S_{t-1} + B_t (x) (dt_t x_t)``, ``y_t = C_t . S_t``.
+    ``s0`` ``(b, N, Ch)`` (the pool's layout, ``Mamba1.state_shape``), ``x``
+    / ``dt`` ``(b, T, Ch)`` (``dt`` 0: the row leaves the state as it is and
+    adds nothing), ``A`` ``(N, Ch)``, ``B`` / ``C`` ``(b, T, N)``; all
+    float32. Returns ``y`` ``(b, T, Ch)`` and the state after the last row.
+    The path off the TPU and the oracle of ``ops/pallas_ssm.py``
+    ``ssm1_scan``: a decay a (channel, state) pair has no chunked matrix
+    form."""
+    def step(s, row):
+        x_t, dt_t, b_t, c_t = row
+        s = jnp.exp(dt_t[:, None, :] * A) * s \
+            + b_t[:, :, None] * (dt_t * x_t)[:, None, :]
+        return s, jnp.sum(s * c_t[:, :, None], axis=1)
+
+    s, y = lax.scan(step, s0, tuple(jnp.moveaxis(t, 1, 0)
+                                    for t in (x, dt, B, C)))
+    return jnp.moveaxis(y, 0, 1), s
+
+
+def scan1_path(state: Any) -> str:
+    """Which of the two Mamba-1 recurrences a group takes, at any ``tq``:
+    ``"kernel"`` (``ops/pallas_ssm.py`` ``ssm1_scan``: one launch a layer,
+    from and into the state pool as it lies) on a TPU, where the kernel can
+    cut this pool (``state``: the pool or its shape and type; float32, the
+    states a whole number of sublane tiles, the channels of lane chunks);
+    else ``"xla"`` (:func:`ssm1_rows` between a gather and a scatter of the
+    members' states). The same for every model, as :func:`scan_path`."""
+    from tree_attention_tpu.ops import _on_tpu, _pallas_available
+    from tree_attention_tpu.ops.pallas_ssm import SSM1_LANES
+
+    cuts = state.dtype == jnp.float32 and not state.shape[-2] % 8 \
+        and not state.shape[-1] % SSM1_LANES
+    return "kernel" if cuts and _on_tpu() and _pallas_available() else "xla"
+
+
+def ssm1_branch(layer: Params, h: jax.Array, state: jax.Array,
+                tail: jax.Array, m, groups: Tuple[_RowGroup, ...],
+                cfg: TransformerConfig):
+    """The Mamba-1 mixer's branch over every group of the step's rows, from
+    rows ``h`` already normed. ``layer``: ``w_in`` ``(D, 2 x inner)`` (``[x
+    | z]``), ``conv_w`` ``(taps, inner)`` / ``conv_b``, ``w_x`` ``(inner,
+    dt_rank + 2 x d_state)`` (``[δ | B | C]``), ``w_dt`` ``(dt_rank,
+    inner)`` / ``dt_bias`` ``(inner,)`` float32, ``A_log`` ``(d_state,
+    inner)`` float32 (the pool's layout), ``D`` ``(inner,)`` float32,
+    ``w_out`` ``(inner, D)``; ``state`` / ``tail`` the WHOLE pools of a
+    :class:`PagedStateWindowCache`, slot ``s`` of this layer at ``m·S + s``
+    of their flat views. A group's rows go through the recurrence at any
+    ``tq`` by ONE launch of ``ssm1_scan`` on a TPU (:func:`scan1_path`),
+    else :func:`ssm1_rows`; :func:`ssm_branch`'s three rules hold as they
+    are (a fresh member starts from zeros, a row past the valid count
+    leaves state and tail bit for bit, a member with no row is neither read
+    nor written). Returns what the mixer adds to the residual, the two
+    pools, how many states were written, and the scan output ``y`` (with
+    the ``D·x`` skip, before the gate) on the step's row axis: the MEMORY a
+    gated memory unit reads, where this is the memory layer."""
+    from tree_attention_tpu.ops.pallas_ssm import ssm1_scan
+
+    sm, f32 = cfg.ssm1, jnp.float32
+    inner, N = sm.inner, sm.d_state
+    S = state.shape[1]
+    with jax.named_scope(scopes.ATTN_IN):
+        xz = h @ layer["w_in"]
+    with jax.named_scope(scopes.CONV):
+        x, z = jnp.split(xz, [inner], axis=-1)
+        A = -jnp.exp(layer["A_log"].astype(f32))
+        taps = layer["conv_w"].astype(f32)
+        flat_s = state.reshape((-1,) + state.shape[2:])
+        flat_t = tail.reshape(-1, tail.shape[2])
+        outs, mems, wrote = [], [], jnp.int32(0)
+        for g in groups:
+            at = m * S + g.slot
+            has = g.n_valid > 0
+            fresh = g.start == 0
+            to = jnp.where(has, at, flat_s.shape[0])     # no row: dropped
+            wrote = wrote + jnp.sum(has, dtype=jnp.int32)
+            with jax.named_scope(scopes.SSM_TAPS):
+                xg = g.take(x[:, None])[:, 0]            # (batch, tq, inner)
+                xc, flat_t = _taps_step(
+                    g, xg, flat_t, at, to, fresh, taps, layer["conv_b"])
+                dbc = jnp.dot(xc.astype(h.dtype), layer["w_x"],
+                              preferred_element_type=f32)
+                delta, Bm, Cm = jnp.split(
+                    dbc, [sm.dt_rank, sm.dt_rank + N], axis=-1)
+                dts = jax.nn.softplus(
+                    jnp.dot(delta.astype(h.dtype), layer["w_dt"],
+                            preferred_element_type=f32)
+                    + layer["dt_bias"].astype(f32))
+                dts = jnp.where(g.valid[..., None], dts, 0.0)
+            with jax.named_scope(
+                    scopes.SSM_UPDATE if g.tq == 1 else scopes.SSM_SCAN):
+                if scan1_path(state) == "kernel":
+                    flat_s, y = ssm1_scan(
+                        flat_s, xc, dts, A, Bm, Cm, at, g.n_valid, fresh,
+                        live=g.live)
+                else:
+                    s0 = jnp.where(fresh[:, None, None], 0.0, flat_s[at])
+                    y, s1 = ssm1_rows(s0, xc, dts, A, Bm, Cm)
+                    flat_s = flat_s.at[to].set(s1, mode="drop")
+            with jax.named_scope(scopes.SSM_NORM):
+                y = y + layer["D"].astype(f32) * xc
+                mems.append(y.astype(h.dtype)[:, None])
+                y = y * jax.nn.silu(g.take(z[:, None])[:, 0].astype(f32))
+                outs.append(y.astype(h.dtype)[:, None])
+        y = _join_rows(groups, outs)[:, 0]
+        mem = _join_rows(groups, mems)[:, 0]
+    with jax.named_scope(scopes.ATTN_OUT):
+        y = y @ layer["w_out"]
+    return (y, flat_s.reshape(state.shape), flat_t.reshape(tail.shape),
+            wrote, mem)
+
+
+def gmu_mixer(layer: Params, x: jax.Array, mem: jax.Array,
+              cfg: TransformerConfig) -> jax.Array:
+    """A gated memory unit as a layer's mixer: ``x + (silu(norm(x) · W_in)
+    ⊙ m) · W_out``, ``m`` the SAME row's memory (the memory layer's scan
+    output, carried up the layer loop). No cache. ``W_in`` and the ``silu``
+    under ``attn_in``, the product with ``m`` and ``W_out`` under
+    ``attn_out``."""
+    with jax.named_scope(scopes.ATTN_IN):
+        gate = jax.nn.silu(norm_rows(cfg, x, layer, "ln1") @ layer["w_in"])
+    with jax.named_scope(scopes.ATTN_OUT):
+        return x + (gate * mem) @ layer["w_out"]
+
+
+def lambda_init(l) -> jax.Array:
+    """``λ₀(l) = 0.8 - 0.6 · e^(-0.3 l)``, ``l`` the layer's index from 0
+    (an int, or the layer loop's traced index)."""
+    return 0.8 - 0.6 * jnp.exp(-0.3 * jnp.asarray(l, jnp.float32))
+
+
+def diff_tail(out: jax.Array, layer: Params, l,
+              cfg: TransformerConfig) -> jax.Array:
+    """Differential attention's few row-wise operations after the paged
+    call, from the packed output ``(B, H, T, 2 x d)`` whose head ``2p + σ``
+    holds ``a_{p,σ} = softmax(s · q_{2p+σ} · k_{2j+σ}) · [v_2j | v_2j+1]``
+    on ALL its lanes: ``o_p = RMSNorm(a_{p,0} - λ · a_{p,1}) · (1 - λ₀(l))``
+    with ``λ = exp(λ_q1 · λ_k1) - exp(λ_q2 · λ_k2) + λ₀(l)`` (``layer["lam"]``
+    ``(4, d)``: ``λ_q1, λ_k1, λ_q2, λ_k2``; the norm's gain ``sub_ln`` ``(2 x
+    d,)``). Float32, rounded once. Returns ``(B, H / 2, T, 2 x d)``."""
+    B, H, T, W = out.shape
+    lam = layer["lam"].astype(jnp.float32)
+    lam0 = lambda_init(l)
+    full = jnp.exp(jnp.sum(lam[0] * lam[1])) \
+        - jnp.exp(jnp.sum(lam[2] * lam[3])) + lam0
+    a = out.astype(jnp.float32).reshape(B, H // 2, 2, T, W)
+    o = a[:, :, 0] - full * a[:, :, 1]
+    o = o * lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + cfg.norm_eps)
+    return (o * layer["sub_ln"] * (1.0 - lam0)).astype(out.dtype)
+
+
+def diff_branch(attend: _Attend, layer: Params, h: jax.Array, k_cache,
+                v_cache, l, pool_l, base):
+    """A differential-attention mixer's branch over every group of the
+    step's rows, from rows already normed; window, full and cross layers
+    alike (``attend`` says which: its window, and ``write`` false for a
+    cross layer, whose ``layer`` holds ``W_q`` alone and whose rows read
+    what the shared layer wrote). The pool rows are PAIRS of KV heads
+    (``_pack_heads``); query head ``2p + σ`` lies in half ``σ`` of its row
+    with zeros beside it, so ``q · k`` over the packed row is its dot
+    product with key head ``2j + σ`` and the call's output row is ``a_{p,σ}``
+    itself: K and V are read ONCE a call by the kernels every attention
+    layer uses, and the subtraction, ``λ``, the norm and ``(1 - λ₀)`` follow
+    (:func:`diff_tail`). ``l``: the layer's index in the model (``λ₀``'s
+    depth); ``pool_l`` / ``base``: its layer and first block in the pool it
+    attends to. Returns what the mixer adds to the residual and the two
+    cache arrays."""
+    cfg, groups = attend.cfg, attend.groups
+    with jax.named_scope(scopes.ATTN_IN):
+        if GQA_SERVED in layer:  # the served form: one product
+            qkv = times_out_major(h, layer[GQA_SERVED])
+        else:
+            qkv = jnp.concatenate(
+                [h @ layer[n] for n in ("wq", "wk", "wv") if n in layer],
+                axis=-1)
+        qkv = qkv + layer["bqkv"].astype(qkv.dtype)
+        q, k_new, v_new = qkv, None, None
+        if attend.write:
+            q, k_new, v_new = jnp.split(
+                qkv, [cfg.q_dim, cfg.q_dim + cfg.kv_dim], axis=-1)
+            k_new = _heads(k_new, cfg.n_kv_heads, cfg.d_head)
+            v_new = _heads(v_new, cfg.n_kv_heads, cfg.d_head)
+        q, k_new, v_new = _pack_heads(
+            _heads(q, cfg.n_heads, cfg.d_head), k_new, v_new, cfg)
+    outs = []
+    for gi in range(len(groups)):
+        out, k_cache, v_cache, _, _ = attend(
+            gi, q, k_new, v_new, k_cache, v_cache, None, None, None, pool_l,
+            base)
+        outs.append(out)
+    with jax.named_scope(scopes.ATTN_DECODE):
+        out = diff_tail(_join_rows(groups, outs), layer, l, cfg)
+    with jax.named_scope(scopes.ATTN_OUT):
+        y = _unheads(out) @ layer["wo"] + layer["bo"].astype(out.dtype)
+    return y, k_cache, v_cache
 
 
 # ---------------------------------------------------------------------------
@@ -662,7 +912,8 @@ def layer_runs(cfg: TransformerConfig) -> List[Tuple[str, str, int, int, int]]:
     types = cfg.layer_types or ("attention",) * cfg.n_layers
     runs: List[list] = []
     seen = {"attention": 0, "window": 0, "conv": 0, "ssm": 0, "eva": 0,
-            "parallel": 0, "dense": 0, "expert": 0, "none": 0}
+            "parallel": 0, "ssm1": 0, "gmu": 0, "cross": 0, "dense": 0,
+            "expert": 0, "none": 0}
     for mixer, ffn in zip(types, cfg.ffn_kinds):
         if runs and runs[-1][:2] == [mixer, ffn]:
             runs[-1][2] += 1
@@ -673,14 +924,67 @@ def layer_runs(cfg: TransformerConfig) -> List[Tuple[str, str, int, int, int]]:
     return [tuple(r) for r in runs]
 
 
+class _Sub(NamedTuple):
+    """One layer of a run's period: its kinds, and where its first pass
+    finds its parts: ``m0`` among the layers of its mixer kind, ``f0`` among
+    those of its feed-forward kind, ``l0`` among all; pass ``i`` of the run
+    finds them ``i`` strides further (``dm``, ``df``, ``dl``: the layers of
+    that kind, and all, a period holds)."""
+
+    mixer: str
+    ffn: str
+    m0: int
+    f0: int
+    l0: int
+    dm: int = 1
+    df: int = 1
+    dl: int = 1
+
+
+def period_runs(cfg: TransformerConfig) -> List[Tuple[Tuple[_Sub, ...], int]]:
+    """:func:`layer_runs` as the layer loop scans it: ``(period, passes)``,
+    the period one layer for every model but a decoder that feeds a second
+    decoder, whose depth alternates by construction (a Mamba-1 layer and a
+    window layer; a gated memory unit and a cross layer): there a stretch
+    of ``A, B, A, B, ...`` single layers is ONE run whose period is the
+    pair, so that the body is still one a run, with no branch on a layer's
+    kind in the traced program; the memory layer and the shared layer stay
+    runs of one."""
+    singles, l = [], 0
+    for mixer, ffn, n, m0, f0 in layer_runs(cfg):
+        singles.append(((_Sub(mixer, ffn, m0, f0, l),), n))
+        l += n
+    if cfg.ssm1 is None:
+        return singles
+    kinds = [(sub.mixer, sub.ffn, n) for (sub,), n in singles]
+    runs, i = [], 0
+    while i < len(singles):
+        reps = 0
+        while i + 2 * reps + 1 < len(singles) and all(
+                kinds[i + 2 * reps + j] == kinds[i + j][:2] + (1,)
+                for j in range(2)):
+            reps += 1
+        if reps < 2:
+            runs.append(singles[i])
+            i += 1
+            continue
+        pair = (singles[i][0][0], singles[i + 1][0][0])
+        df = 2 if pair[0].ffn == pair[1].ffn else 1
+        runs.append((tuple(sub._replace(df=df, dl=2) for sub in pair), reps))
+        i += 2 * reps
+    return runs
+
+
 def hybrid_layers(
     params: Params,
     x: jax.Array,
     positions: jax.Array,
-    cache: Union[PagedHybridCache, PagedWindowCache, PagedStateCache],
+    cache: Union[PagedHybridCache, PagedWindowCache, PagedStateCache,
+                 PagedStateWindowCache],
     cfg: TransformerConfig,
     attend: _Attend,
     stats: Optional[Dict[str, Any]],
+    cut: Optional[Tuple[jax.Array, _RowGroup]] = None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Every layer of a step for a model whose layers are of several kinds
     (:func:`~.decode._step_layers`' third branch): one body a (mixer,
@@ -696,8 +1000,18 @@ def hybrid_layers(
     ``ln2``, ``router``, ``router_bias``, ``we1`` / ``we3`` / ``we2`` and
     the shared experts' ``ws*``; experts in a latent: ``w_down`` / ``w_up``
     too; ungated experts: no ``we3`` / ``ws3``). A layer whose feed-forward
-    kind is ``"none"`` is its mixer alone. Returns the residual and the
-    pools by field name."""
+    kind is ``"none"`` is its mixer alone. A decoder that feeds a second
+    decoder adds ``ssm1`` (:func:`ssm1_branch`'s leaves), ``gmu`` (``ln1``,
+    ``w_in``, ``w_out``) and ``xattn`` (the cross layers: ``wq``, ``bqkv``,
+    ``lam``, ``sub_ln``, ``wo``, ``bo``), its ``attn`` / ``wattn`` layers
+    the differential leaves too, every norm a bias (``ln1_b``, ``ln2_b``);
+    the carry gains the step's MEMORY rows ``(1, R, inner)``, set by the
+    last Mamba-1 layer and read by every gated memory unit; a cross layer
+    is "attention layer 0's pool, no write". ``cut`` (``(rows, group)``,
+    a packed step's): after layer ``cfg.row_cut`` the residual and the
+    memory are gathered to ``rows`` (one a slot) and the layers above run
+    on those alone, the cross layers over ``group``. Returns the residual
+    and the pools by field name."""
     from tree_attention_tpu.models.experts import expert_layer, held_counts
 
     groups = attend.groups
@@ -735,86 +1049,138 @@ def hybrid_layers(
         return jax.tree.map(
             lambda a: lax.dynamic_index_in_dim(a, i, keepdims=False), stack)
 
-    def body_of(mixer, ffn, m0, f0):
-        def body(carry, i):
-            x, k, v, tail, wk, wv, state, stail = carry
-            mi, fi = m0 + i, f0 + i
-            wrote = jnp.int32(0)
-            if mixer == "ssm":
-                x, state, stail, wrote = ssm_mixer(
-                    of(params["ssm"], mi), x, state, stail, mi, groups, cfg)
-            elif mixer == "attention":
-                x, k, v, _, _ = gqa_mixer(
-                    attend, of(params["attn"], mi), x, positions, k, v,
-                    None, None, None, mi, mi * N)
-            elif mixer == "window":
-                x, wk, wv, _, _ = gqa_mixer(
-                    attend_w, of(params["wattn"], mi), x, positions, wk, wv,
-                    None, None, None, mi, mi * Nw)
-            elif mixer == "eva":
-                x, k, v, wk, wv, wrote = eva_mixer(
-                    attend_w, of(params["eva"], mi), x, positions, k, v,
-                    wk, wv, mi)
-            elif mixer == "parallel":
-                # Two mixers side by side on ONE normed residual (the
-                # module's docstring): the layer is attention layer ``mi``
-                # and state-space layer ``mi``; its one norm is ``ln1`` of
-                # its attention leaves. The two branches, and no third
-                # mixer; the sum in float32, rounded once.
-                mup, f32 = cfg.mup, jnp.float32
-                attn = of(params["attn"], mi)
-                with jax.named_scope(scopes.ATTN_IN):
-                    h = rms_norm(x, attn["ln1"], cfg.norm_eps)
-                    h_s = times(h, mup.ssm_in_multiplier)
-                    h_a = times(h, mup.attention_in_multiplier)
-                y_s, state, stail, wrote = ssm_branch(
-                    of(params["ssm"], mi), h_s, state, stail, mi, groups,
-                    cfg)
-                y_a, k, v, _, _ = gqa_branch(
-                    attend, attn, h_a, positions, k, v, None, None, None,
-                    mi, mi * N)
-                with jax.named_scope(scopes.ATTN_OUT):
-                    x = (x.astype(f32)
-                         + times(y_s.astype(f32), mup.ssm_out_multiplier)
-                         + times(y_a.astype(f32),
-                                 mup.attention_out_multiplier)
-                         ).astype(x.dtype)
-            else:
-                with jax.named_scope(scopes.CONV):
-                    x, tail, wrote = conv_mixer(
-                        of(params["conv"], mi), x, tail, mi, groups, cfg,
-                        block)
-            if ffn == "dense":
-                with jax.named_scope(scopes.FFN):
-                    layer = of(params["dense"], fi)
-                    x = x + _mlp_block(
-                        layer, rms_norm(x, layer["ln2"], cfg.norm_eps),
-                        cfg.mup.mlp_multipliers)
-            if ffn != "expert":
-                return (x, k, v, tail, wk, wv, state, stail), (None, wrote)
-            with jax.named_scope(scopes.ROUTE):
-                layer = of(routers, fi)
-                h32 = rms_norm(
-                    x.astype(jnp.float32), layer["ln2"], cfg.norm_eps)
-                h = h32.astype(x.dtype)
-            y, chosen = expert_layer(
-                layer, h, cfg.moe, router_input=h32,
-                experts=experts, first=fi * cfg.moe.held,
-            )
-            with jax.named_scope(scopes.ROUTE):
-                return (x + y, k, v, tail, wk, wv, state, stail), (
-                    held_counts(chosen, valid, cfg.moe), wrote)
+    # A cross layer's body: the shared layer's pool, read and not written,
+    # by the rows that go on above the seam (a packed step's one row a
+    # slot, ``cut``; else the step's own groups).
+    attend_x = dataclasses.replace(
+        attend, write=False,
+        groups=groups if cut is None else (cut[1],))
 
-        return body
+    def layer_of(carry, sub, i):
+        """Layer ``sub`` of pass ``i`` of its run."""
+        x, k, v, tail, wk, wv, state, stail, mem = carry
+        mixer, ffn = sub.mixer, sub.ffn
+        mi, fi, l = (first + (i if d == 1 else d * i) for first, d in (
+            (sub.m0, sub.dm), (sub.f0, sub.df), (sub.l0, sub.dl)))
+        wrote = jnp.int32(0)
+        if mixer == "ssm":
+            x, state, stail, wrote = ssm_mixer(
+                of(params["ssm"], mi), x, state, stail, mi, groups, cfg)
+        elif mixer == "ssm1":
+            layer = of(params["ssm1"], mi)
+            with jax.named_scope(scopes.ATTN_IN):
+                h = norm_rows(cfg, x, layer, "ln1")
+            y, state, stail, wrote, scanned = ssm1_branch(
+                layer, h, state, stail, mi, groups, cfg)
+            with jax.named_scope(scopes.ATTN_OUT):
+                x = x + y
+            if sub.m0 == cfg.ssm_layers - 1:
+                mem = scanned        # the memory layer: the last of them
+        elif mixer == "gmu":
+            x = gmu_mixer(of(params["gmu"], mi), x, mem, cfg)
+        elif cfg.diff_attn:
+            # Differential attention, one body three ways: the shared
+            # layer's (pool ``k`` / ``v``), a window layer's (``wk`` /
+            # ``wv``), a cross layer's (the shared pool again, no write).
+            name, att, pool_l, n_blocks = {
+                "attention": ("attn", attend, mi, N),
+                "window": ("wattn", attend_w, mi, Nw),
+                "cross": ("xattn", attend_x, 0, N)}[mixer]
+            layer = of(params[name], mi)
+            with jax.named_scope(scopes.ATTN_IN):
+                h = norm_rows(cfg, x, layer, "ln1")
+            pools = (wk, wv) if mixer == "window" else (k, v)
+            y, *pools = diff_branch(
+                att, layer, h, *pools, l, pool_l, pool_l * n_blocks)
+            if mixer == "window":
+                wk, wv = pools
+            else:
+                k, v = pools
+            with jax.named_scope(scopes.ATTN_OUT):
+                x = x + y
+        elif mixer == "attention":
+            x, k, v, _, _ = gqa_mixer(
+                attend, of(params["attn"], mi), x, positions, k, v,
+                None, None, None, mi, mi * N)
+        elif mixer == "window":
+            x, wk, wv, _, _ = gqa_mixer(
+                attend_w, of(params["wattn"], mi), x, positions, wk, wv,
+                None, None, None, mi, mi * Nw)
+        elif mixer == "eva":
+            x, k, v, wk, wv, wrote = eva_mixer(
+                attend_w, of(params["eva"], mi), x, positions, k, v,
+                wk, wv, mi)
+        elif mixer == "parallel":
+            # Two mixers side by side on ONE normed residual (the
+            # module's docstring): the layer is attention layer ``mi``
+            # and state-space layer ``mi``; its one norm is ``ln1`` of
+            # its attention leaves. The two branches, and no third
+            # mixer; the sum in float32, rounded once.
+            mup, f32 = cfg.mup, jnp.float32
+            attn = of(params["attn"], mi)
+            with jax.named_scope(scopes.ATTN_IN):
+                h = rms_norm(x, attn["ln1"], cfg.norm_eps)
+                h_s = times(h, mup.ssm_in_multiplier)
+                h_a = times(h, mup.attention_in_multiplier)
+            y_s, state, stail, wrote = ssm_branch(
+                of(params["ssm"], mi), h_s, state, stail, mi, groups,
+                cfg)
+            y_a, k, v, _, _ = gqa_branch(
+                attend, attn, h_a, positions, k, v, None, None, None,
+                mi, mi * N)
+            with jax.named_scope(scopes.ATTN_OUT):
+                x = (x.astype(f32)
+                     + times(y_s.astype(f32), mup.ssm_out_multiplier)
+                     + times(y_a.astype(f32),
+                             mup.attention_out_multiplier)
+                     ).astype(x.dtype)
+        else:
+            with jax.named_scope(scopes.CONV):
+                x, tail, wrote = conv_mixer(
+                    of(params["conv"], mi), x, tail, mi, groups, cfg,
+                    block)
+        if ffn == "dense":
+            with jax.named_scope(scopes.FFN):
+                layer = of(params["dense"], fi)
+                x = x + _mlp_block(
+                    layer, norm_rows(cfg, x, layer, "ln2"),
+                    cfg.mup.mlp_multipliers)
+        if ffn != "expert":
+            return (x, k, v, tail, wk, wv, state, stail, mem), (None, wrote)
+        with jax.named_scope(scopes.ROUTE):
+            layer = of(routers, fi)
+            h32 = rms_norm(
+                x.astype(jnp.float32), layer["ln2"], cfg.norm_eps)
+            h = h32.astype(x.dtype)
+        y, chosen = expert_layer(
+            layer, h, cfg.moe, router_input=h32,
+            experts=experts, first=fi * cfg.moe.held,
+        )
+        with jax.named_scope(scopes.ROUTE):
+            return (x + y, k, v, tail, wk, wv, state, stail, mem), (
+                held_counts(chosen, valid, cfg.moe), wrote)
+
+    def body_of(period):
+        def body(carry, i):
+            rows, wrote = None, jnp.int32(0)
+            for sub in period:
+                carry, (r, w) = layer_of(carry, sub, i)
+                rows = r if r is not None else rows
+                wrote = wrote + w
+            return carry, (rows, wrote)
+
+        # (One layer a period: the layer's own outputs, as they are.)
+        return body if len(period) > 1 else (
+            lambda carry, i: layer_of(carry, period[0], i))
 
     # A pool the cache has not is None: an empty part of the carry.
     carry = (x, cache.k, cache.v, getattr(cache, "tail", None),
              getattr(cache, "wk", None), getattr(cache, "wv", None),
              getattr(cache, "ssm_state", None),
-             getattr(cache, "ssm_tail", None))
-    counts, wrote = [], jnp.int32(0)
-    for mixer, ffn, n, m0, f0 in layer_runs(cfg):
-        body = body_of(mixer, ffn, m0, f0)
+             getattr(cache, "ssm_tail", None), None)
+    counts, wrote, done = [], jnp.int32(0), 0
+    for period, n in period_runs(cfg):
+        body = body_of(period)
         if n == 1:
             carry, (rows, w) = body(carry, 0)
             rows = None if rows is None else rows[None]
@@ -824,6 +1190,12 @@ def hybrid_layers(
         if rows is not None:
             counts.append(rows)
         wrote = wrote + jnp.sum(w)
+        done += n * len(period)
+        if cut is not None and done == cfg.row_cut:
+            # The seam: the rows no slot samples from leave the stack.
+            with jax.named_scope(scopes.ATTN_OUT):
+                x, *pools, mem = carry
+                carry = (x[:, cut[0]], *pools, mem[:, cut[0]])
     if stats is not None:
         if counts:
             stats["expert_rows"] = jnp.concatenate(counts, axis=0)
@@ -833,7 +1205,10 @@ def hybrid_layers(
             stats["ssm_states"] = wrote
         if cfg.eva_layers:
             stats["eva_summaries"] = wrote
-    x, k, v, tail, wk, wv, state, stail = carry
+    x, k, v, tail, wk, wv, state, stail, _ = carry
+    if cfg.ssm1 is not None:
+        return x, {"k": k, "v": v, "wk": wk, "wv": wv, "ssm_state": state,
+                   "ssm_tail": stail}
     if cfg.window_layers or cfg.eva_layers:
         return x, {"k": k, "v": v, "wk": wk, "wv": wv}
     if cfg.ssm_layers:
@@ -860,19 +1235,71 @@ def init_hybrid_params(key: jax.Array, cfg: TransformerConfig) -> Params:
         res_std = 0.02 / (2 * cfg.n_layers) ** 0.5
         normal = functools.partial(_normal, dtype=cfg.dtype)
 
-        def attn(k):
+        def ln(name):
+            """A norm's leaves: the gain, and under LayerNorm the bias."""
+            out = {name: jnp.ones((D,), jnp.float32)}
+            if cfg.norm == "layer":
+                out[name + "_b"] = jnp.zeros((D,), jnp.float32)
+            return out
+
+        def attn(k, own_kv=True):
             k = jax.random.split(k, 4)
             out = {
-                "ln1": jnp.ones((D,), jnp.float32),
+                **ln("ln1"),
                 "wq": normal(k[0], (D, cfg.q_dim), 0.02),
-                "wk": normal(k[1], (D, cfg.kv_dim), 0.02),
-                "wv": normal(k[2], (D, cfg.kv_dim), 0.02),
                 "wo": normal(k[3], (cfg.q_dim, D), res_std),
             }
+            if own_kv:
+                out.update(wk=normal(k[1], (D, cfg.kv_dim), 0.02),
+                           wv=normal(k[2], (D, cfg.kv_dim), 0.02))
             if cfg.qk_norm:
                 out["q_ln"] = jnp.ones((cfg.d_head,), jnp.float32)
                 out["k_ln"] = jnp.ones((cfg.d_head,), jnp.float32)
+            if cfg.diff_attn:
+                # The published init: the four lambda vectors normal(0,
+                # 0.1), the pair's norm at one, zero biases.
+                wide = cfg.q_dim + (2 * cfg.kv_dim if own_kv else 0)
+                out.update(
+                    bqkv=jnp.zeros((wide,), cfg.dtype),
+                    bo=jnp.zeros((D,), cfg.dtype),
+                    lam=_normal(jax.random.fold_in(k[3], 1), (4, cfg.d_head), 0.1,
+                                jnp.float32),
+                    sub_ln=jnp.ones((2 * cfg.d_head,), jnp.float32))
             return out
+
+        def cross(k):
+            return attn(k, own_kv=False)
+
+        def ssm1(k):
+            # The family's init: -A over 1..d_state a channel, dt
+            # log-uniform over the published time-step range, D at one.
+            sm = cfg.ssm1
+            k = jax.random.split(k, 6)
+            dt = jnp.exp(jax.random.uniform(
+                k[4], (sm.inner,), jnp.float32, jnp.log(1e-3), jnp.log(1e-1)))
+            return {
+                **ln("ln1"),
+                "w_in": normal(k[0], (D, 2 * sm.inner), 0.02),
+                "conv_w": normal(k[1], (sm.taps, sm.inner), sm.taps ** -0.5),
+                "conv_b": jnp.zeros((sm.inner,), cfg.dtype),
+                "w_x": normal(k[2], (sm.inner, sm.x_dim), sm.inner ** -0.5),
+                "w_dt": normal(k[3], (sm.dt_rank, sm.inner),
+                               sm.dt_rank ** -0.5),
+                "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+                "A_log": jnp.log(jnp.broadcast_to(
+                    jnp.arange(1, sm.d_state + 1, dtype=jnp.float32)[:, None],
+                    sm.state_shape)),
+                "D": jnp.ones((sm.inner,), jnp.float32),
+                "w_out": normal(k[5], (sm.inner, D), res_std),
+            }
+
+        def gmu(k):
+            k = jax.random.split(k, 2)
+            return {
+                **ln("ln1"),
+                "w_in": normal(k[0], (D, cfg.ssm1.inner), 0.02),
+                "w_out": normal(k[1], (cfg.ssm1.inner, D), res_std),
+            }
 
         def eva(k):
             # phi spreads a chunk's weights (k . phi of the order of 1), mu
@@ -920,7 +1347,7 @@ def init_hybrid_params(key: jax.Array, cfg: TransformerConfig) -> Params:
         def dense(k):
             k = jax.random.split(k, 3)
             return {
-                "ln2": jnp.ones((D,), jnp.float32),
+                **ln("ln2"),
                 "w1": normal(k[0], (D, cfg.d_ff), 0.02),
                 "w3": normal(k[1], (D, cfg.d_ff), 0.02),
                 "w2": normal(k[2], (cfg.d_ff, D), res_std),
@@ -967,19 +1394,27 @@ def init_hybrid_params(key: jax.Array, cfg: TransformerConfig) -> Params:
 
         out = {
             "embed": normal(ks[0], (cfg.vocab_size, D), 0.02),
-            "ln_f": jnp.ones((D,), jnp.float32),
+            **ln("ln_f"),
         }
         if not cfg.tied_head:
             out["wout"] = normal(
                 ks[1], (D, cfg.pred_heads * cfg.vocab_size), 0.02)
         n_dense = cfg.n_dense_layers
+        types = cfg.layer_types or ()
         for name, make_one, n, k in (
                 ("attn", attn, cfg.cache_layers - cfg.eva_layers, ks[2]),
                 ("eva", eva, cfg.eva_layers, jax.random.fold_in(ks[2], 2)),
                 ("wattn", attn, cfg.window_layers,
                  jax.random.fold_in(ks[2], 1)),
+                ("xattn", cross, types.count("cross"),
+                 jax.random.fold_in(ks[2], 3)),
                 ("conv", conv, cfg.conv_layers, ks[3]),
-                ("ssm", ssm, cfg.ssm_layers, jax.random.fold_in(ks[3], 1)),
+                ("ssm", ssm, cfg.ssm_layers - types.count("ssm1"),
+                 jax.random.fold_in(ks[3], 1)),
+                ("ssm1", ssm1, types.count("ssm1"),
+                 jax.random.fold_in(ks[3], 2)),
+                ("gmu", gmu, types.count("gmu"),
+                 jax.random.fold_in(ks[3], 3)),
                 ("dense", dense, n_dense, ks[4]),
                 ("layers", expert, cfg.n_expert_layers, ks[5])):
             if n:

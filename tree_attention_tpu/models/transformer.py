@@ -224,6 +224,41 @@ class StateSpace:
 
 
 @dataclasses.dataclass(frozen=True)
+class Mamba1:
+    """A Mamba-1 mixer's widths (``models/hybrid.py`` ``ssm1_branch``):
+    ``inner`` channels, each with a state of ``d_state`` numbers and a
+    decay of its own for every one of them (``A`` is ``(inner, d_state)``,
+    where Mamba-2's is a scalar a head); ``Δ`` a vector over the channels,
+    up from a ``dt_rank``-wide row; a depthwise causal convolution of
+    ``taps`` taps with a bias over ``x`` alone."""
+
+    inner: int
+    d_state: int
+    taps: int
+    dt_rank: int
+
+    def __post_init__(self):
+        if self.taps < 2 or min(self.inner, self.d_state, self.dt_rank) < 1:
+            raise ValueError(
+                f"a Mamba-1 mixer of {self.inner} channels x {self.d_state} "
+                f"states, dt_rank {self.dt_rank}, {self.taps} taps: every "
+                f"width is positive and the convolution has a tail (>= 2 "
+                f"taps)")
+
+    @property
+    def x_dim(self) -> int:
+        """``W_x``'s output: ``[δ | B | C]``."""
+        return self.dt_rank + 2 * self.d_state
+
+    @property
+    def state_shape(self) -> Tuple[int, int]:
+        """One slot's state in one layer as the pool holds it: ``(d_state,
+        inner)``, ``S[c, n]`` at ``[n, c]``: the channels on the lanes, as
+        ``x`` and ``Δ`` come out of their projections."""
+        return (self.d_state, self.inner)
+
+
+@dataclasses.dataclass(frozen=True)
 class Multipliers:
     """Fixed scalars a family multiplies its activations by, under the names
     the ``falcon_h1`` family publishes them (``models/hybrid.py`` says where
@@ -283,8 +318,15 @@ PUBLISHED_MIXERS = {
 # in ``hybrid_override_pattern``, a character a part (:func:`_pattern_layers`).
 # Nor has ``"eva"``: its family says every layer's in ``attention_class``.
 # Nor ``"parallel"``: ``model_type`` ``falcon_h1`` says every layer's.
+# Nor ``"ssm1"`` / ``"gmu"`` / ``"cross"``: ``model_type`` ``phi4flash``
+# derives every layer's from the depth (:func:`decoder_hybrid_layers`).
 MIXER_KINDS = frozenset(PUBLISHED_MIXERS.values()) | {
-    "ssm", "eva", "parallel"}
+    "ssm", "eva", "parallel", "ssm1", "gmu", "cross"}
+# The kinds of a decoder that feeds a second decoder (below the seam, then
+# above it), and the one arrangement of them built.
+DECODER_HYBRID_KINDS = frozenset({"ssm1", "window", "attention", "gmu",
+                                  "cross"})
+NORM_KINDS = ("rms", "layer")
 # The published ``attention_class`` values built, by the mixer kind they give
 # every layer.
 ATTENTION_CLASSES = {"eva": "eva"}
@@ -310,8 +352,18 @@ class TransformerConfig:
     softmax; ``"parallel"``: TWO mixers side by side, the state-space
     mixer and rotary GQA over the whole context on ONE normed residual,
     their outputs added to it together, each under its own fixed scale:
-    every layer of such a model, ``models/hybrid.py``), None meaning
-    rotary GQA throughout. ``mup``: the fixed scalars a family multiplies
+    every layer of such a model, ``models/hybrid.py``; ``"ssm1"``: a
+    Mamba-1 mixer of ``ssm1``'s widths; ``"gmu"``: a gated memory unit, a
+    gate on the scan output the LAST ``"ssm1"`` layer made for the same row
+    in the same step; ``"cross"``: attention whose queries alone are the
+    layer's own, over the K/V rows the ONE ``"attention"`` layer before it
+    wrote: the upper half of a decoder that feeds a second decoder), None
+    meaning rotary GQA throughout. ``diff_attn``: every attention of the
+    model is differential (two softmaxes of a pair of heads subtracted
+    under a learned ``λ``, an RMSNorm over the pair's ``2 x d_head`` lanes:
+    ``models/hybrid.py`` ``diff_branch``); ``attn_bias``: the attention
+    projections have biases; ``norm``: ``"rms"``, or ``"layer"`` for a
+    LayerNorm with mean, gain and bias in every norm's place. ``mup``: the fixed scalars a family multiplies
     its activations by (:class:`Multipliers`; built with ``"parallel"``
     layers). ``rotary`` names the attention kinds whose queries and
     keys take the rotary embedding (both by default; a model whose full
@@ -382,10 +434,19 @@ class TransformerConfig:
     pred_heads: int = 1
     norm_offset: bool = False
     mup: Multipliers = Multipliers()
+    ssm1: Optional[Mamba1] = None
+    diff_attn: bool = False
+    attn_bias: bool = False
+    norm: str = "rms"
 
     def __post_init__(self):
         if isinstance(self.mup, dict):      # read back from JSON
             object.__setattr__(self, "mup", Multipliers(**self.mup))
+        if isinstance(self.ssm1, dict):
+            object.__setattr__(self, "ssm1", Mamba1(**self.ssm1))
+        if self.norm not in NORM_KINDS:
+            raise ValueError(
+                f"norm {self.norm!r}: one of {NORM_KINDS}")
         if self.n_heads % self.n_kv_heads:
             raise ValueError(
                 f"n_heads ({self.n_heads}) must be a multiple of "
@@ -444,6 +505,15 @@ class TransformerConfig:
                     f"their widths, a model without states none, and a "
                     f"recurrent state beside conv or sliding-window layers "
                     f"is not built")
+            upper = kinds & {"ssm1", "gmu", "cross"}
+            if bool(upper) != (self.ssm1 is not None):
+                raise ValueError(
+                    f"layers of kinds {sorted(upper)} with ssm1="
+                    f"{self.ssm1}: a model with Mamba-1, gated-memory or "
+                    f"cross layers states the Mamba-1 widths, a model "
+                    f"without states none")
+            if upper:
+                self._check_decoder_hybrid(kinds)
             if "parallel" in kinds and (
                     kinds != {"parallel"} or self.moe is not None
                     or self.qk_norm or not self.rotates("attention")):
@@ -455,7 +525,7 @@ class TransformerConfig:
                     f"rotary: {self.rotary}): it is built in every layer of "
                     f"a model, its attention rotated and without QK-norm, "
                     f"over the dense feed-forward half or none")
-        elif self.ssm is not None:
+        elif self.ssm is not None or self.ssm1 is not None:
             raise ValueError(
                 "state-space widths without layer_types: which layers are "
                 "state-space layers is said a layer")
@@ -469,6 +539,15 @@ class TransformerConfig:
                 f"mup {self.mup}: fixed activation multipliers are built "
                 f"with 'parallel' layers (layer_types), where every one of "
                 f"them has its place; any other model's are all 1.0")
+        if (self.diff_attn or self.attn_bias or self.norm != "rms") \
+                and self.ssm1 is None:
+            raise ValueError(
+                f"diff_attn {self.diff_attn}, attn_bias {self.attn_bias}, "
+                f"norm {self.norm!r} without Mamba-1 layers: differential "
+                f"attention, projection biases and LayerNorm are built in "
+                f"the decoder-hybrid layer loop ('ssm1' / 'gmu' / 'cross' "
+                f"layers); any other model's attention is one softmax, "
+                f"without biases, under RMSNorm")
         if self.window_rule not in WINDOW_RULES or self.pred_heads < 1:
             raise ValueError(
                 f"window_rule {self.window_rule!r} (one of {WINDOW_RULES}) "
@@ -506,6 +585,58 @@ class TransformerConfig:
                 f"attention + dense FFN pairs with the routed experts a "
                 f"branch that leaves and rejoins inside the layer")
 
+    def _check_decoder_hybrid(self, kinds) -> None:
+        """What the layer loop cannot run of a model with Mamba-1, gated
+        memory or cross layers, refused by name."""
+        types = self.layer_types
+        if not kinds <= DECODER_HYBRID_KINDS or self.moe is not None \
+                or self.ffn_types is not None:
+            raise ValueError(
+                f"'ssm1' / 'gmu' / 'cross' layers beside layers of kinds "
+                f"{sorted(kinds - DECODER_HYBRID_KINDS)} (experts: "
+                f"{self.moe is not None}, ffn_types: "
+                f"{self.ffn_types is not None}): they are built beside "
+                f"'window' and 'attention' layers over the dense "
+                f"feed-forward half, without experts or latent attention")
+        upper = [i for i, t in enumerate(types) if t in ("gmu", "cross")]
+        seam = upper[0] if upper else len(types)
+        shared = [i for i, t in enumerate(types) if t == "attention"]
+        if "cross" in kinds and len(shared) != 1:
+            raise ValueError(
+                f"cross layers with {len(shared)} full-attention "
+                f"('attention') layers: the cross layers read the rows of "
+                f"ONE shared layer; none and two are not built")
+        if "cross" in kinds and (shared[0] != seam - 1):
+            raise ValueError(
+                f"the shared full-attention layer at {shared} and the "
+                f"first gated-memory or cross layer at {seam}: a cross "
+                f"layer has the shared layer before it, and the shared "
+                f"layer is the last layer below the seam (the rows that "
+                f"no slot samples from leave the stack after it)")
+        if "gmu" in kinds and "ssm1" not in types[:seam]:
+            raise ValueError(
+                "a gated memory unit with no Mamba-1 layer before it: its "
+                "memory is the scan output of the last 'ssm1' layer below")
+        below = set(types[seam:]) - {"gmu", "cross"}
+        if below:
+            raise ValueError(
+                f"layers of kinds {sorted(below)} after the first "
+                f"gated-memory or cross layer (at {seam}): a second memory "
+                f"layer or a second shared layer is not built; above the "
+                f"seam every layer is 'gmu' or 'cross'")
+        if self.qk_norm or self.rotary or not self.diff_attn:
+            raise ValueError(
+                f"qk_norm {self.qk_norm}, rotary {self.rotary}, diff_attn "
+                f"{self.diff_attn} with Mamba-1 layers: the decoder-hybrid "
+                f"layers' attention is differential, with no rotary "
+                f"embedding (rotary ()) and no QK-norm")
+        if self.n_kv_heads % 2:
+            raise ValueError(
+                f"differential attention of {self.n_heads} / "
+                f"{self.n_kv_heads} heads: a pair of neighbouring query "
+                f"heads reads a pair of neighbouring KV heads, so both "
+                f"counts are even")
+
     @property
     def q_dim(self) -> int:
         return self.n_heads * self.d_head
@@ -529,9 +660,24 @@ class TransformerConfig:
     @property
     def ssm_layers(self) -> int:
         """Layers that hold a recurrent state, the state-space mixer alone
-        or beside attention: the state pool's depth."""
+        (Mamba-2's ``"ssm"`` or Mamba-1's ``"ssm1"``; a model has one of
+        the two) or beside attention: the state pool's depth."""
         types = self.layer_types or ()
-        return types.count("ssm") + types.count("parallel")
+        return types.count("ssm") + types.count("parallel") \
+            + types.count("ssm1")
+
+    @property
+    def row_cut(self) -> Optional[int]:
+        """The layers every row of a step runs through, where the model is
+        a decoder that feeds a second decoder: the layers up to and with
+        the shared full-attention layer. Above it nothing carries anything
+        from one position to the next (a cross layer reads the shared
+        layer's rows, a gated memory unit the same row's memory), so only
+        a row some slot samples from goes on. None: every other model."""
+        types = self.layer_types or ()
+        if "cross" not in types:
+            return None
+        return types.index("attention") + 1
 
     @property
     def eva_layers(self) -> int:
@@ -588,6 +734,8 @@ class TransformerConfig:
             return "latent"
         if self.eva_layers:
             return "eva"
+        if self.ssm1 is not None:
+            return "state_window"
         if self.window_layers:
             return "window"
         if self.ssm_layers:
@@ -603,6 +751,10 @@ class TransformerConfig:
         pool whose rows are 64 lanes wide the TPU compiler holds in a
         layout of its own and copies, whole, to the kernel's and back in
         every tick (PERF.md, PR 33). No option: 1 for every other cache."""
+        if self.diff_attn:
+            # Differential attention's value PAIR is the packed row: two
+            # heads, whatever their width (128 lanes at a head of 64).
+            return 2
         if self.cache_kind != "hybrid" or 128 % self.d_head:
             return 1
         return math.gcd(128 // self.d_head, self.n_kv_heads)
@@ -612,8 +764,10 @@ class TransformerConfig:
         """The cache's depth: one layer of rows for every attention that
         sees its whole context (a window layer's rows: ``window_layers``
         deep, in the window pool)."""
+        types = self.layer_types or ()
         return (self.n_layers - self.conv_layers - self.window_layers
-                - (self.layer_types or ()).count("ssm")) * self.sublayers
+                - sum(types.count(t) for t in ("ssm", "ssm1", "gmu", "cross"))
+                ) * self.sublayers
 
     @property
     def n_dense_layers(self) -> int:
@@ -746,6 +900,76 @@ def _falcon_h1_from_config(c: Dict[str, Any], block: Dict[str, Any]
     return ssm, mup
 
 
+# What the ``phi4flash`` family's paper and modelling code say and no
+# published key does, each rule with the one value built (the file's
+# ``block`` group).
+_PHI4FLASH_BLOCK = {
+    "decoder_split": "sambay",
+    "attention": "differential",
+    "diff_pairing": "adjacent",
+    "cross_attention": "differential",
+    "lambda_depth": "layer_index_from_0",
+    "gmu_memory": "scan_output_before_gate",
+    "mlp_order": "gate_up",
+}
+
+
+def decoder_hybrid_layers(n: int) -> Tuple[str, ...]:
+    """The ``"sambay"`` split of ``n`` layers (a multiple of 4), two mixers
+    a period: below the middle a Mamba-1 layer and a window layer in turn;
+    layer ``n / 2`` one more Mamba-1 layer, whose scan output is the
+    MEMORY; layer ``n / 2 + 1`` the ONE full-attention layer, whose rows
+    are shared; above it a gated memory unit and a cross layer in turn."""
+    half = n // 2
+    return tuple(
+        ("ssm1" if l % 2 == 0 else "window") if l < half
+        else "ssm1" if l == half else "attention" if l == half + 1
+        else ("gmu" if l % 2 == 0 else "cross") for l in range(n))
+
+
+def _phi4flash_from_config(c: Dict[str, Any], block: Dict[str, Any]
+                           ) -> Tuple[Tuple[str, ...], Mamba1, int]:
+    """The ``phi4flash`` family's layers, Mamba-1 widths and window, every
+    key that says what is not built refused by name."""
+    for key, built in (
+            ("mb_per_layer", 2), ("mlp_bias", False),
+            ("lm_head_bias", False), ("hidden_act", "silu"),
+            ("tie_word_embeddings", True)):
+        if c.get(key, built) != built:
+            raise ValueError(
+                f"{key} {c[key]!r}: the phi4flash layer is built with "
+                f"{key} {built!r}")
+    rope = sorted(k for k in c if k.startswith("rope_") or k == "rotary_dim")
+    if rope:
+        raise ValueError(
+            f"{rope}: the phi4flash family has no positional encoding; a "
+            f"file that names one is not this model")
+    for key, built in _PHI4FLASH_BLOCK.items():
+        if block.get(key, built) != built:
+            raise ValueError(
+                f"block.{key} {block[key]!r}: only {built!r} is built for "
+                f"model_type 'phi4flash'")
+    n, hidden = int(c["num_hidden_layers"]), int(c["hidden_size"])
+    if n % 4 or n < 4:
+        raise ValueError(
+            f"num_hidden_layers {n}: the 'sambay' split is built for a "
+            f"multiple of 4 (two decoders of whole periods of 2)")
+    window = int(c["sliding_window"])
+    if int(block.get("window_span", window)) != window:
+        raise ValueError(
+            f"block.window_span {block['window_span']} beside "
+            f"sliding_window {window}: a window layer's row at t sees "
+            f"(t - sliding_window, t], itself included; no other span is "
+            f"built")
+    a = c.get("assumed") or {}
+    ssm1 = Mamba1(
+        inner=int(a.get("mamba_expand", 2)) * hidden,
+        d_state=int(a.get("mamba_d_state", 16)),
+        taps=int(a.get("mamba_d_conv", 4)),
+        dt_rank=int(a.get("mamba_dt_rank", -(-hidden // 16))))
+    return decoder_hybrid_layers(n), ssm1, window
+
+
 def _gated(c: Dict[str, Any]) -> bool:
     """Whether the feed-forward parts have a gate matrix, by
     ``mlp_hidden_act``: ``relu2`` is one matrix in, relu squared, one out;
@@ -811,6 +1035,19 @@ def model_from_config(config: Dict[str, Any], *, dtype: Any = None,
       false, ``mamba_use_mlp`` true, ``mamba_conv_bias`` true,
       ``attn_layer_indices`` / ``rope_scaling`` null, every projection bias
       false, ``hidden_act`` ``silu``: any other refused by name.
+      Or ``model_type`` ``phi4flash`` gives the depth the ``"sambay"``
+      split (:func:`decoder_hybrid_layers`: Mamba-1 and window layers in
+      turn, one more Mamba-1 layer whose scan output is the memory, ONE
+      full-attention layer whose rows the cross layers read again, then
+      gated memory units and cross layers in turn), differential attention
+      with projection biases and no positional term, LayerNorm with bias
+      (``layer_norm_eps``), a tied head; ``sliding_window``; the Mamba-1
+      widths from the file's ``assumed`` group (``mamba_expand`` 2,
+      ``mamba_d_state`` 16, ``mamba_d_conv`` 4, ``mamba_dt_rank`` hidden /
+      16: the family's convention where absent); ``mb_per_layer`` 2,
+      ``mlp_bias`` / ``lm_head_bias`` false, ``hidden_act`` ``silu``,
+      ``tie_word_embeddings`` true, no ``rope_*`` key and a depth that is
+      a multiple of 4: any other refused by name.
     - each layer's feed-forward half: ``n_routed_experts`` /
       ``num_experts`` select the expert layer after the first
       ``first_k_dense_replace`` / ``num_dense_layers`` layers (an expert's
@@ -862,7 +1099,17 @@ def model_from_config(config: Dict[str, Any], *, dtype: Any = None,
     ``"parallel_shared_norm"``; ``ssm_multipliers``' five scalars lie over
     ``W_in``'s columns in the order ``["z", "x", "B", "C", "dt"]``; the
     rotary pairs dimension ``i`` with ``i + d / 2``, ``"half_split"``: the
-    one value built each, any other refused by name) and
+    one value built each, any other refused by name), ``decoder_split`` /
+    ``attention`` / ``diff_pairing`` / ``cross_attention`` /
+    ``lambda_depth`` / ``gmu_memory`` / ``mlp_order`` / ``window_span`` (the
+    ``phi4flash`` family's: ``"sambay"``; ``"differential"``; query head
+    ``2p + σ`` is half ``σ`` of pair ``p``, ``"adjacent"``; the cross layers
+    are differential too; ``λ₀(l)`` takes the layer's index from 0; a gated
+    memory unit multiplies by the memory layer's scan output with the
+    ``D·x`` skip and before ``silu(z)``, ``"scan_output_before_gate"``;
+    ``W₁``'s columns are ``[gate | up]``; the window's span is
+    ``sliding_window``: the one value built each, any other refused by
+    name) and
     ``norm_placement`` (``"pre"``, the only one built: any other is
     refused by name)."""
     c = config
@@ -1021,6 +1268,17 @@ def model_from_config(config: Dict[str, Any], *, dtype: Any = None,
                 "half")
         ssm, mup = _falcon_h1_from_config(c, block)
         layer_types = ("parallel",) * int(c["num_hidden_layers"])
+    ssm1, decoder_hybrid = None, {}
+    if c.get("model_type") == "phi4flash":
+        if layer_types is not None or n_held or c.get("kv_lora_rank"):
+            raise ValueError(
+                "model_type 'phi4flash' beside layer_types, "
+                "hybrid_override_pattern, experts or latent attention: the "
+                "family's layers follow from its depth alone")
+        layer_types, ssm1, window = _phi4flash_from_config(c, block)
+        block = dict(block, rotary_layers="none")
+        decoder_hybrid = dict(ssm1=ssm1, diff_attn=True, attn_bias=True,
+                              norm="layer")
     window_rule, chunk = "sliding", 0
     if c.get("attention_class") is not None:
         said = str(c["attention_class"])
@@ -1082,7 +1340,8 @@ def model_from_config(config: Dict[str, Any], *, dtype: Any = None,
         rope_theta=float(
             c.get("rope_theta") or rp.get("rope_theta", 10000.0)),
         norm_eps=float(c.get("rms_norm_eps") or c.get("norm_eps")
-                       or c.get("layer_norm_epsilon") or 1e-6),
+                       or c.get("layer_norm_epsilon")
+                       or c.get("layer_norm_eps") or 1e-6),
         dtype=dtype,
         mla=mla, moe=moe, sublayers=int(block.get("sublayers", 1)),
         layer_types=layer_types,
@@ -1094,7 +1353,7 @@ def model_from_config(config: Dict[str, Any], *, dtype: Any = None,
         window_rule=window_rule, chunk=chunk,
         pred_heads=int(c.get("num_pred_heads", 1)),
         norm_offset=bool(c.get("norm_add_unit_offset", False)),
-        mup=mup,
+        mup=mup, **decoder_hybrid,
     )
     kw.update(overrides)
     return TransformerConfig(**kw)
@@ -1113,7 +1372,8 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Params:
     scaled by ``(2·n_layers)^-1/2`` so the residual stream's variance stays O(1)
     at init regardless of depth.
     """
-    if cfg.cache_kind in ("hybrid", "window", "state", "eva"):
+    if cfg.cache_kind in ("hybrid", "window", "state", "eva",
+                          "state_window"):
         from tree_attention_tpu.models.hybrid import init_hybrid_params
 
         return init_hybrid_params(key, cfg)
@@ -1225,6 +1485,24 @@ def rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
     return ((x32 * rms) * w).astype(x.dtype)
 
 
+def layer_norm(x: jax.Array, w: jax.Array, b: jax.Array,
+               eps: float) -> jax.Array:
+    """LayerNorm with mean, gain and bias, reduced in float32."""
+    x32 = x.astype(jnp.float32)
+    x32 = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+    inv = lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return ((x32 * inv) * w + b).astype(x.dtype)
+
+
+def norm_rows(cfg: "TransformerConfig", x: jax.Array, p: Params,
+              name: str) -> jax.Array:
+    """The model's norm of ``x`` under leaf ``name`` of ``p``: RMSNorm, or
+    (``cfg.norm`` ``"layer"``) LayerNorm with the bias under ``name_b``."""
+    if cfg.norm == "layer":
+        return layer_norm(x, p[name], p[name + "_b"], cfg.norm_eps)
+    return rms_norm(x, p[name], cfg.norm_eps)
+
+
 def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     """Rotary position embedding on ``(B, H, T, D)``; ``positions`` is
     ``(T,)`` shared across the batch or ``(B, T)`` per-row (the ragged
@@ -1280,7 +1558,8 @@ GQA_SERVED, LATENT_SERVED = "wqkv_t", "wqb_t"
 def served_layout(params: Params) -> Params:
     """``params`` with every attention input projection re-laid as the tick
     programs want it. A layer's ``wq``, ``wk`` and ``wv`` ``(..., D, out)``
-    become one ``wqkv_t`` ``(..., q_dim + 2 x kv_dim, D)``; a latent layer's
+    become one ``wqkv_t`` ``(..., q_dim + 2 x kv_dim, D)`` (a layer that
+    holds ``wq`` alone, a cross layer: ``(..., q_dim, D)``); a latent layer's
     ``wqb`` ``(..., rank, out)`` becomes ``wqb_t`` ``(..., out, rank)``.
 
     The compiler for the chip multiplies by these with the contracted axis
@@ -1302,7 +1581,9 @@ def served_layout(params: Params) -> Params:
         return params
     out = {k: served_layout(v) for k, v in params.items()}
     if "wq" in out:
-        out[GQA_SERVED] = _out_major(*(out.pop(n) for n in ("wq", "wk", "wv")))
+        # (A cross layer projects queries only: its ``wqkv_t`` is ``wq``'s.)
+        out[GQA_SERVED] = _out_major(
+            *(out.pop(n) for n in ("wq", "wk", "wv") if n in out))
     if "wqb" in out:
         out[LATENT_SERVED] = _out_major(out.pop("wqb"))
     return out

@@ -531,28 +531,32 @@ class TickPhases:
 
     def mark(self, name: str, tick: Optional[int] = None,
              kind: Optional[str] = None, tq: Optional[int] = None,
-             ahead: Optional[bool] = None) -> None:
+             ahead: Optional[bool] = None,
+             rows_cross: Optional[int] = None) -> None:
         """Close the open phase and open ``name``; a mark that names the
         phase already open changes nothing. ``tick``/``kind``/``tq``/
         ``ahead`` ride on the profiler annotation (the ``dispatch`` mark
         passes them: ``ahead`` says the program went out before the one
-        before it was fetched)."""
+        before it was fetched), and ``rows_cross`` where the model cuts its
+        rows at a seam (the rows the layers above it compute)."""
         if not self.on:
             return
         if self._marks[-1][0] == name:
             return
         now = time.monotonic()
         self._open.__exit__(None, None, None)
-        self._enter(name, now, tick, kind, tq, ahead)
+        self._enter(name, now, tick, kind, tq, ahead, rows_cross)
 
-    def _enter(self, name, now, tick, kind, tq, ahead) -> None:
+    def _enter(self, name, now, tick, kind, tq, ahead,
+               rows_cross=None) -> None:
         self._marks.append([name, now])
         if kind is None:
             self._open = self._annotation(_ANNOTATION[name])
         else:
+            more = {} if rows_cross is None else {"rows_cross": rows_cross}
             self._open = self._annotation(_ANNOTATION[name], tick=tick,
                                           kind=kind, tq=tq,
-                                          ahead=bool(ahead))
+                                          ahead=bool(ahead), **more)
         self._open.__enter__()
 
     def built(self, built: List[Any]) -> None:

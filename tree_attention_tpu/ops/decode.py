@@ -114,6 +114,7 @@ def flash_decode(
     tree_mask: Optional[jax.Array] = None,
     step_plan=None,
     window: Optional[WindowRule] = None,
+    launch: Optional[str] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Causal decode attention of a few new queries against a long KV buffer.
 
@@ -250,7 +251,7 @@ def flash_decode(
                     q, k, v, causal=True, scale=scale,
                     q_offset=q_position, kv_offset=0,
                     block_table=block_table, tree_mask=tree_mask,
-                    step_plan=step_plan, window=window,
+                    step_plan=step_plan, window=window, launch=launch,
                 )
             # Prefill-sized Tq rides the Q-tiled kernel, which has no
             # table path — one gather materialises the logical view

@@ -835,6 +835,7 @@ def _paged_decode_call(
     block_table: jax.Array,
     step_plan: Optional[PagedPlan] = None,
     window: Optional[WindowRule] = None,
+    launch: Optional[str] = None,
     out_dtype,
     interpret: bool,
 ) -> Tuple[jax.Array, jax.Array]:
@@ -877,6 +878,12 @@ def _paged_decode_call(
                 "a sliding window is built for the exact replicated pool "
                 "without a tree mask")
         name = label = window_kernel_name(window)
+    if launch:
+        # The same kernel body under a launch name that says whose rows it
+        # reads (``"shared"``: another layer's, a cross layer's call): a
+        # trace tells the two apart, and no two tick programs give one
+        # operation name two parts of the model.
+        name = f"{name}_{launch}"
     if obs.REGISTRY.enabled:
         _KERNEL_BUILDS.labels(
             kernel=label, heads=heads, entries=entries).inc()
@@ -1811,7 +1818,7 @@ def attention_pallas_decode_q8q(
     jax.jit,
     static_argnames=(
         "causal", "scale", "block_size", "interpret", "local_blocks",
-        "window",
+        "window", "launch",
     ),
 )
 def attention_pallas_decode(
@@ -1830,6 +1837,7 @@ def attention_pallas_decode(
     local_blocks: bool = False,
     step_plan: Optional[PagedPlan] = None,
     window: Optional[WindowRule] = None,
+    launch: Optional[str] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Split-KV flash decode. Same ``(out, lse)`` contract as the other impls.
 
@@ -1891,7 +1899,9 @@ def attention_pallas_decode(
     the same ``window``: :func:`decode_plan`), so the call walks one or
     two steps a slot whatever its length, at any ``Tq`` (a chunk's rows
     take Q tiles of 128 packed rows over that short list). The kernel is
-    named :data:`WINDOW_KERNEL`. ``window`` may also be an
+    named :data:`WINDOW_KERNEL`; ``launch`` (a paged call's) is a suffix to
+    the launch's name (``flash_decode_paged_shared``: a cross layer's call
+    over another layer's rows). ``window`` may also be an
     :class:`~.block_utils.AlignedWindow` (row ``i`` sees ``[w0, q_offset +
     i]``, ``w0`` the start of its own block of ``window`` positions) or
     :class:`~.block_utils.ChunkSummaries` (the pool holds one summary row a
@@ -1961,7 +1971,7 @@ def attention_pallas_decode(
             tq=Tq, bq=bq, causal=causal, local_blocks=local_blocks,
             q_offset=q_offset, kv_offset=kv_offset,
             block_table=block_table, step_plan=step_plan, window=window,
-            out_dtype=q.dtype, interpret=interpret,
+            launch=launch, out_dtype=q.dtype, interpret=interpret,
         )
         return out.astype(out_dtype), lse
     qp = qp.reshape(B * Hkv, n_q * bq, D)

@@ -1,6 +1,10 @@
-"""A state-space layer's two steps on the state pool, in place (Pallas TPU):
-the decode step over the slots that have a row (``ssm_decode_update``), and a
-chunk group's scan (``ssm_chunk_scan``, below the decode step).
+"""A state-space layer's steps on the state pool, in place (Pallas TPU): a
+Mamba-2 layer's decode step over the slots that have a row
+(``ssm_decode_update``) and its chunk group's scan (``ssm_chunk_scan``, below
+the decode step); and a Mamba-1 layer's rows at any count a member
+(``ssm1_scan``, last: its decay is a number for every (channel, state) pair,
+so the chunked matrix form below does not apply to it and the rows pass one
+by one over a state tile held in fast memory).
 
 A state-space (Mamba-2) layer holds, for every slot, a state ``S`` of ``heads
 x d_head x d_state`` float32 that EVERY token rewrites whole::
@@ -90,12 +94,14 @@ from tree_attention_tpu.ops import tuning
 
 SSM_KERNEL = "ssm_decode_update"
 SCAN_KERNEL = "ssm_chunk_scan"
+SSM1_KERNEL = "ssm1_scan"
+SSM1_LANES = tuning.SSM1_LANES
 _HI = lax.Precision.HIGHEST
 
 _KERNEL_BUILDS = obs.counter(
     "pallas_ssm_kernel_builds_total",
     "state-space kernel program builds (one per distinct shape), by kernel: "
-    "ssm_decode_update, ssm_chunk_scan",
+    "ssm_decode_update, ssm_chunk_scan, ssm1_scan",
     labels=("kernel",),
 )
 
@@ -541,3 +547,199 @@ def _ssm_scan_call(state, x, dt, A, B, C, home, n_valid, fresh, *,
     )(ids, count, jnp.asarray(home, jnp.int32),
       jnp.asarray(fresh, jnp.int32), x, hd, B, C, state)
     return new, jnp.where((n_valid > 0)[:, None, None], y[:, :T], 0.0)
+
+
+# ---------------------------------------------------------------------------
+# A Mamba-1 layer's rows, a decode group's or a chunk group's
+# ---------------------------------------------------------------------------
+
+
+def _ssm1_kernel(
+    ids_ref,    # SMEM (b,) scalar-prefetch: the members that have a row
+    cnt_ref,    # SMEM (1,) scalar-prefetch: how many
+    home_ref,   # SMEM (b,) scalar-prefetch: a member's row of the pool
+    fresh_ref,  # SMEM (b,) scalar-prefetch: 1 where its first position is 0
+    n_ref,      # SMEM (b,) scalar-prefetch: its valid rows
+    x_ref,      # VMEM (1, tb, ct): x after the convolution, these channels
+    dt_ref,     # VMEM (1, tb, ct): the step sizes
+    a_ref,      # VMEM (N, ct): A, the layer's decays as the pool lies
+    b_ref,      # VMEM (1, tb, N, 128): B, a row's N over a lane tile
+    c_ref,      # VMEM (1, tb, N, 128): C, likewise
+    s_ref,      # VMEM (1, N, ct): the member's state, these channels
+    o_ref,      # ... and where it goes back (aliased to the pool)
+    y_ref,      # VMEM (1, tb, ct)
+):
+    """One grid step: ``tb`` rows of one member through ``ct`` channels of
+    its state, a row at a time. The state's block is the same for every
+    time block of a (channel tile, member), so it stays in fast memory from
+    the member's first row to its last; a lane chunk of it rides the row
+    loop in registers, ``exp(dt (x) A)`` formed there."""
+    i, k = pl.program_id(1), pl.program_id(2)
+    cnt = cnt_ref[0]
+    tb = x_ref.shape[1]
+    N, ct = a_ref.shape
+    lanes = 128 if ct % SSM1_LANES else SSM1_LANES
+
+    # No member has a row: the one block every step was pointed at goes
+    # back as it came.
+    @pl.when(cnt == 0)
+    def _():
+        o_ref[...] = s_ref[...]
+
+    @pl.when(i < cnt)
+    def _():
+        j = ids_ref[i]
+
+        @pl.when(k == 0)
+        def _():
+            # A fresh member starts from zeros, whatever the pool holds.
+            o_ref[0] = jnp.where(fresh_ref[j] != 0, 0.0, s_ref[0])
+
+        # A row past the member's valid count is never computed: it leaves
+        # the state bit for bit.
+        rows = jnp.clip(n_ref[j] - k * tb, 0, tb)
+        for c0 in range(0, ct, lanes):
+            at = slice(c0, c0 + lanes)
+            A = a_ref[:, at]
+
+            def row(r, s, at=at, A=A):
+                dt = dt_ref[0, pl.ds(r, 1), at]               # (1, lanes)
+                dx = dt * x_ref[0, pl.ds(r, 1), at]
+                b = jnp.tile(b_ref[0, r], (1, lanes // 128))  # (N, lanes)
+                c = jnp.tile(c_ref[0, r], (1, lanes // 128))
+                s = jnp.exp(dt * A) * s + b * dx
+                y_ref[0, pl.ds(r, 1), at] = jnp.sum(
+                    s * c, axis=0, keepdims=True)
+                return s
+
+            o_ref[0, :, at] = lax.fori_loop(0, rows, row, o_ref[0, :, at])
+
+
+def ssm1_scan(
+    state: jax.Array,
+    x: jax.Array,
+    dt: jax.Array,
+    A: jax.Array,
+    B: jax.Array,
+    C: jax.Array,
+    home: jax.Array,
+    n_valid: jax.Array,
+    fresh: jax.Array,
+    *,
+    live: Optional[Tuple[jax.Array, jax.Array]] = None,
+    interpret: Optional[bool] = None,
+) -> Tuple[jax.Array, jax.Array]:
+    """A group's rows through one Mamba-1 layer, in place: what
+    ``models/hybrid.py`` ``ssm1_rows`` computes, from and into the pool as
+    it lies, at any rows a member (1: a decode group; a chunk).
+
+    A Mamba-1 state decays by a number of its own for every (channel,
+    state) pair, ``S_t[n, c] = exp(dt_t[c] A[n, c]) S_{t-1}[n, c] + B_t[n]
+    dt_t[c] x_t[c]``, so the chunked matrix form of ``ssm_chunk_scan`` does
+    not exist for it (a block's decays would be a ``(T, T)`` matrix for
+    every pair), an associative scan materialises ``(T, N, channels)``
+    float32 pairs several times a layer, and a ``lax.scan`` is ``T``
+    launches' worth of loop overhead. Here the rows pass one by one over a
+    state tile that stays in fast memory.
+
+    ``state`` is the pool ``(layers x S, N, channels)`` float32
+    (``Mamba1.state_shape``); member ``i``'s state is row ``home[i]``.
+    ``x`` / ``dt`` ``(b, T, channels)``, ``A`` ``(N, channels)``, ``B`` /
+    ``C`` ``(b, T, N)``, all float32. A member with ``fresh`` starts from
+    zeros whatever the pool holds; its rows past ``n_valid`` are not
+    computed; one with ``n_valid`` 0 is not visited. ``live``:
+    :func:`live_list` of ``n_valid``, where the caller built it once for
+    every layer. Returns the pool (the buffer that came in, under a
+    donating ``jit``) and ``y = C . S`` ``(b, T, channels)``, zero for a row
+    not computed. The device event is ``ssm1_scan``."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    ids, count = live if live is not None else live_list(n_valid)
+    return _ssm1_call(state, x, dt, A, B, C, home, n_valid, fresh, ids,
+                      jnp.reshape(count, (1,)), interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _ssm1_call(state, x, dt, A, B, C, home, n_valid, fresh, ids, count, *,
+               interpret: bool):
+    M, N, Ch = state.shape
+    b, T, _ = x.shape
+    if x.shape != (b, T, Ch) or dt.shape != x.shape or A.shape != (N, Ch) \
+            or B.shape != (b, T, N) or C.shape != B.shape or N % 8 \
+            or Ch % 128 \
+            or any(t.dtype != jnp.float32 for t in (state, x, dt, A, B, C)):
+        raise ValueError(
+            f"ssm1_scan takes a float32 pool (layers x S, N, channels), x "
+            f"and dt (b, T, channels), A (N, channels), B and C (b, T, N), "
+            f"N whole sublane tiles and the channels whole lane tiles; "
+            f"got {[(t.shape, t.dtype) for t in (state, x, dt, A, B, C)]}")
+    if obs.REGISTRY.enabled:
+        _KERNEL_BUILDS.labels(kernel=SSM1_KERNEL).inc()
+    tb = tuning.ssm1_time_block(T)
+    ct = tuning.ssm1_channel_tile(tb, N, Ch) if Ch % SSM1_LANES == 0 else Ch
+    pad = -T % tb
+    if pad:
+        # Rows past every member's count: never computed.
+        x, dt, B, C = (jnp.pad(t, ((0, 0), (0, pad), (0, 0)))
+                       for t in (x, dt, B, C))
+    nk, nq = (T + pad) // tb, Ch // ct
+    # B and C as columns: a row's N numbers over a lane tile each, so that
+    # the body multiplies a (N, lanes) state by them with no transposed
+    # operand (kilobytes a row).
+    wide = tuple(jnp.broadcast_to(t[..., None], t.shape + (128,))
+                 for t in (B, C))
+
+    def where(i, k, ids, cnt):
+        """The member and time block of step ``(q, i, k)``: past the list,
+        the last step's again (no block moves, nothing is written
+        twice)."""
+        dead = i >= cnt[0]
+        return (ids[jnp.minimum(i, jnp.maximum(cnt[0] - 1, 0))],
+                jnp.where(dead, nk - 1, k))
+
+    def rows_of(q, i, k, ids, cnt, home, fresh, n):
+        j, k = where(i, k, ids, cnt)
+        return j, k, q
+
+    def cols_of(q, i, k, ids, cnt, home, fresh, n):
+        j, k = where(i, k, ids, cnt)
+        return j, k, 0, 0
+
+    def state_of(q, i, k, ids, cnt, home, fresh, n):
+        j, _ = where(i, k, ids, cnt)
+        return home[j], 0, q
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(nq, b, nk),
+        in_specs=[
+            pl.BlockSpec((1, tb, ct), rows_of),
+            pl.BlockSpec((1, tb, ct), rows_of),
+            pl.BlockSpec((N, ct), lambda q, i, k, *_: (0, q)),
+            pl.BlockSpec((1, tb, N, 128), cols_of),
+            pl.BlockSpec((1, tb, N, 128), cols_of),
+            pl.BlockSpec((1, N, ct), state_of),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, N, ct), state_of),
+            pl.BlockSpec((1, tb, ct), rows_of),
+        ],
+    )
+    new, y = pl.pallas_call(
+        _ssm1_kernel,
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
+                   jax.ShapeDtypeStruct((b, T + pad, Ch), jnp.float32)],
+        input_output_aliases={10: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 3,
+            vmem_limit_bytes=tuning.ssm1_vmem_limit(tb, N, ct),
+        ),
+        interpret=interpret,
+        name=SSM1_KERNEL,
+    )(jnp.asarray(ids, jnp.int32), jnp.asarray(count, jnp.int32),
+      jnp.asarray(home, jnp.int32), jnp.asarray(fresh, jnp.int32),
+      jnp.asarray(n_valid, jnp.int32), x, dt, A, *wide, state)
+    seen = jnp.arange(T, dtype=jnp.int32)[None, :, None] \
+        < n_valid[:, None, None]
+    return new, jnp.where(seen, y[:, :T], 0.0)

@@ -739,6 +739,55 @@ def ssm_scan_vmem_limit(t: int, block: int, rows: int, n: int, l: int) -> int:
 
 
 # ---------------------------------------------------------------------------
+# The Mamba-1 scan's blocks (ops/pallas_ssm.py ssm1_scan)
+# ---------------------------------------------------------------------------
+
+# Lanes of a state the row loop carries in registers: a ``(16, 512)`` float32
+# state is 8 vector registers, its ``A`` 8 more, a row's few values beside
+# them. Not measured against other widths: 1,024 spills.
+SSM1_LANES = 512
+# Rows a grid step takes of a chunk: B and C come in broadcast over a lane
+# tile (``(rows, d_state, 128)`` float32 each), 0.5 MB a block at 64.
+SSM1_TIME_BLOCK = 64
+# What the blocks of one grid step may take, fetched a step ahead.
+SSM1_STEP_VMEM_BYTES = 12 << 20
+
+
+def ssm1_time_block(t: int) -> int:
+    """Rows a grid step of ``ssm1_scan`` takes of a group of ``t`` rows a
+    member: the whole group up to :data:`SSM1_TIME_BLOCK`."""
+    return min(t, SSM1_TIME_BLOCK)
+
+
+def ssm1_step_vmem_bytes(tb: int, n: int, ct: int) -> int:
+    """Fast memory one grid step's blocks take, each twice (the pipeline
+    fetches a step ahead): ``x``, ``dt`` in and ``y`` out ``(tb, ct)``, the
+    state in and out and ``A`` ``(n, ct)``, ``B`` and ``C`` ``(tb, n, 128)``;
+    float32, a block's rows rounded up to a sublane tile."""
+    rows = -(-tb // 8) * 8
+    return 2 * 4 * (3 * rows * ct + 3 * n * ct + 2 * tb * n * 128)
+
+
+def ssm1_channel_tile(tb: int, n: int, channels: int) -> int:
+    """Channels a grid step of ``ssm1_scan`` takes, from bytes: the most
+    lane chunks (:data:`SSM1_LANES`) that divide ``channels`` and whose
+    blocks fit :data:`SSM1_STEP_VMEM_BYTES`; all of them where they fit (the
+    layer's ``A`` is then fetched once a launch, and a decode group is one
+    grid step a slot), one chunk where none does."""
+    chunks = channels // SSM1_LANES
+    return SSM1_LANES * next(
+        (c for c in range(chunks, 0, -1)
+         if chunks % c == 0 and ssm1_step_vmem_bytes(
+             tb, n, c * SSM1_LANES) <= SSM1_STEP_VMEM_BYTES), 1)
+
+
+def ssm1_vmem_limit(tb: int, n: int, ct: int) -> int:
+    """``vmem_limit_bytes`` of the call: the blocks and 8 MB for what the
+    compiler adds."""
+    return ssm1_step_vmem_bytes(tb, n, ct) + (8 << 20)
+
+
+# ---------------------------------------------------------------------------
 # The grouped expert product's blocks (ops/pallas_moe.py)
 # ---------------------------------------------------------------------------
 

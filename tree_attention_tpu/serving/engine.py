@@ -187,7 +187,11 @@ from tree_attention_tpu.serving.speculation import (
     pack_proposal,
     pack_siblings,
 )
-from tree_attention_tpu.models.hybrid import scan_path, tail_write_path
+from tree_attention_tpu.models.hybrid import (
+    scan1_path,
+    scan_path,
+    tail_write_path,
+)
 from tree_attention_tpu.models.transformer import (
     GQA_SERVED,
     LATENT_SERVED,
@@ -277,6 +281,14 @@ _SSM_STATES = obs.counter(
     "serving_ssm_states_advanced_total",
     "per-slot recurrent states the state-space layers wrote (slots with a "
     "row x state-space layers)",
+)
+_ROWS_PAST_EXIT = obs.counter(
+    "serving_rows_past_exit_total",
+    "rows a tick program computed of a model that cuts its rows at a seam "
+    "(TransformerConfig.row_cut), by stage: self (the layers up to the "
+    "shared full-attention layer: every row) or cross (the layers above it: "
+    "one row a slot of a packed program)",
+    labels=("stage",),
 )
 _SCAN_ROWS = obs.counter(
     "ssm_scan_rows_total",
@@ -1026,6 +1038,9 @@ class SlotServer:
                 "eva": "a draft that is rejected and crossed a chunk or "
                        "window boundary has written a summary row or given "
                        "blocks back",
+                "state_window": "a draft that is rejected has rewritten "
+                                "the recurrent state and given window "
+                                "blocks back",
             }[cfg.cache_kind]
             for on, what in (
                 (quantize, f"int8 {cfg.cache_kind} rows (quantize=True)"),
@@ -1033,7 +1048,7 @@ class SlotServer:
                  "a sequence-sharded pool (kv_shard='seq')"),
                 (bool(host_blocks), "the host tier (host_blocks > 0)"),
                 (speculate, f"speculation ({why_not})"),
-                (cfg.cache_kind in ("window", "eva") and (
+                (cfg.cache_kind in ("window", "eva", "state_window") and (
                     block_pool is not None or prefix_index is not None),
                  "disaggregation (a shared block_pool / prefix_index: the "
                  "window layers' blocks are one engine's)"),
@@ -1045,11 +1060,12 @@ class SlotServer:
                 # A recurrent state is an array a slot that every token
                 # rewrites whole: nothing holds it as it was at a block
                 # boundary (ROADMAP 2A item 9: snapshots).
-                (cfg.cache_kind == "state" and (
+                (cfg.cache_kind in ("state", "state_window") and (
                     block_pool is not None or prefix_index is not None),
                  "disaggregation (a shared block_pool / prefix_index: the "
                  "hand-over would need the slot's state)"),
-                (cfg.cache_kind == "state" and prefix_cache,
+                (cfg.cache_kind in ("state", "state_window")
+                 and prefix_cache,
                  "the prefix cache (prefix_cache=True: a hit needs the "
                  "state at the matched boundary)"),
             ):
@@ -1300,7 +1316,7 @@ class SlotServer:
         self._kv_kinds: Tuple[Tuple[str, Any, bool], ...] = (
             (first, rule, False),) + (
             ((second, wrule, True),) if wrule is not None else ())
-        if cfg.cache_kind in ("window", "eva"):
+        if cfg.cache_kind in ("window", "eva", "state_window"):
             self._win = WindowBlocks(
                 slots=slots, table_width=-(-cache_len // kv_block),
                 block=kv_block, window=cfg.window, chunk=self.prefill_chunk,
@@ -1330,6 +1346,10 @@ class SlotServer:
             self.cache.ssm_state.shape, self.cache.ssm_state.dtype) \
             if cfg.ssm_layers else None
         self._eva_layers = cfg.eva_layers     # both EVA pools' depth
+        # Calls a tick that read the shared full-attention layer's rows (the
+        # layer itself and the cross layers above it); 0: no such model.
+        self._shared_kv_calls = 0 if cfg.row_cut is None else \
+            1 + cfg.layer_types.count("cross")
         # A state pool's per-slot arrays in bytes, by field name; empty for
         # every other cache: the report's ``kv.state_pool_bytes``.
         self._state_pool_bytes = {
@@ -1634,6 +1654,15 @@ class SlotServer:
                 _EVA_SUMMARIES.inc(out["eva_summaries_written"])
         return out
 
+    def _rows_cross(self, tq: int, group: int) -> Optional[int]:
+        """Rows the layers above the seam compute in a program of ``tq``
+        rows (``group``: a packed program's chunk members, else 0): one a
+        slot of a packed program, every row of a padded one; None for a
+        model that cuts no rows."""
+        if not self._shared_kv_calls:
+            return None
+        return self.slots if group else self.slots * tq
+
     def _count_tail_rows(self, tq, n_vec, chunk) -> int:
         """Slots whose ONE row the program being dispatched takes through
         the conv layers' row kernel (``models/hybrid.py``
@@ -1651,7 +1680,8 @@ class SlotServer:
         state-space layers' chunked scan, times those layers:
         ``(scan_rows_kernel, scan_rows_xla)`` of its flight record, by the
         scan a group of ``tq`` rows takes (``models/hybrid.py``
-        ``scan_path``). A packed program's chunk group's rows, or every row
+        ``scan_path``; a Mamba-1 model's: ``scan1_path``, whose kernel takes
+        a group at any ``tq``). A packed program's chunk group's rows, or every row
         of a padded program of more than one row a slot; a group of one row
         a slot takes the single-step update and counts nothing here. Counted
         from the rows the host packed, as :meth:`_count_pool_rows` counts
@@ -1661,8 +1691,9 @@ class SlotServer:
         rows = int(np.sum(chunk[1])) if chunk is not None \
             else int(n_vec.sum())
         by = {"kernel": 0, "xla": 0}
-        by[scan_path(tq, self.cfg.ssm, self._state_pool)] = \
-            rows * self._ssm_layers
+        path = scan1_path(self._state_pool) if self.cfg.ssm1 is not None \
+            else scan_path(tq, self.cfg.ssm, self._state_pool)
+        by[path] = rows * self._ssm_layers
         if obs.REGISTRY.enabled:
             for path, n in by.items():
                 _SCAN_ROWS.labels(path=path).inc(n)
@@ -2325,7 +2356,7 @@ class SlotServer:
         state that is not its own, and an EVA model's partial summary
         block is not the partial block of positions the fork copies:
         refused by the cache kind's name."""
-        if self.cfg.cache_kind in ("state", "eva"):
+        if self.cfg.cache_kind in ("state", "eva", "state_window"):
             raise ValueError(
                 f"a model served from the {self.cfg.cache_kind} pool "
                 f"(TransformerConfig.cache_kind) does not serve with "
@@ -4925,6 +4956,19 @@ class SlotServer:
                     # took (``_count_scan_rows``).
                     rec["scan_rows_kernel"], rec["scan_rows_xla"] = \
                         p.scan_rows
+                if self._shared_kv_calls:
+                    # Rows the layers below the seam and those above it
+                    # computed (a packed program cuts to one row a slot),
+                    # and the calls that read the shared rows x live slots.
+                    rec["rows_self"] = rec["rows_computed"]
+                    rec["rows_cross"] = self._rows_cross(p.tq, p.group)
+                    rec["shared_kv_calls"] = \
+                        self._shared_kv_calls * len(p.live)
+                    if obs.REGISTRY.enabled:
+                        _ROWS_PAST_EXIT.labels(stage="self").inc(
+                            rec["rows_self"])
+                        _ROWS_PAST_EXIT.labels(stage="cross").inc(
+                            rec["rows_cross"])
                 if not p.ahead:
                     rec["sync_reason"] = p.why
                 rec.update(p.counts if p.counts is not None
@@ -5402,7 +5446,8 @@ class SlotServer:
                         phases.mark("table_sync")
                         self._sync_table()
                         phases.mark("dispatch", tick, tick_kind, tick_tq,
-                                    ahead)
+                                    ahead, self._rows_cross(
+                                        tick_tq, tick_group))
                         args = (
                             self.params, jnp.asarray(mat), self.tok,
                             jnp.asarray(use_dev0), jnp.asarray(n_vec),
@@ -5505,7 +5550,8 @@ class SlotServer:
                         phases.mark("table_sync")
                         self._sync_table()
                         phases.mark("dispatch", tick, tick_kind, tick_tq,
-                                    ahead)
+                                    ahead, self._rows_cross(
+                                        tick_tq, tick_group))
                         # The decode rows' tokens are the device vector:
                         # a row may consume a token the host has not
                         # fetched yet (ISSUE 32). The per-request vectors
@@ -5575,7 +5621,8 @@ class SlotServer:
                         phases.mark("table_sync")
                         self._sync_table()
                         phases.mark("dispatch", tick, tick_kind, tick_tq,
-                                    ahead)
+                                    ahead, self._rows_cross(
+                                        tick_tq, tick_group))
                         self.tok, self._lp, fused_dev, _, \
                             self.cache = self._mixed(
                                 self.params, self.tok[:, None],
